@@ -13,10 +13,6 @@ from typing import Any, Optional, Tuple
 __version__ = "0.1.0"
 version = __version__
 
-from deepspeed_tpu import compat as _compat  # noqa: E402
-
-_compat.install()   # graft jax.shard_map on older jax builds (no-op on new)
-
 from deepspeed_tpu import comm  # noqa: E402
 from deepspeed_tpu import telemetry  # noqa: E402
 from deepspeed_tpu.accelerator import get_accelerator  # noqa: E402

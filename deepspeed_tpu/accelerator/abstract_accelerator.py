@@ -52,10 +52,15 @@ class DeepSpeedTPUAccelerator(abc.ABC):
         return 0
 
     def synchronize(self, device_index: Optional[int] = None) -> None:
-        """Block the host until all outstanding device work is done."""
+        """Block the host until all outstanding work is done on every
+        local device (or the one named)."""
         import jax
 
-        (jax.device_put(0.0) + 0).block_until_ready()
+        devs = jax.local_devices()
+        if device_index is not None:
+            devs = [devs[device_index]]
+        for fence in [jax.device_put(0.0, d) + 0 for d in devs]:
+            fence.block_until_ready()
 
     # --- RNG (reference :63-90) — counter-based, functional on TPU ---
     def manual_seed(self, seed: int):

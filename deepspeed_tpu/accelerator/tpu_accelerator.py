@@ -39,23 +39,14 @@ class TPU_Accelerator(DeepSpeedTPUAccelerator):
     def memory_stats(self, device_index: Optional[int] = None) -> Dict[str, int]:
         import jax
 
-        devs = jax.local_devices()
-        dev = devs[device_index or 0]
-        try:
-            stats = dev.memory_stats() or {}
-        except Exception as e:
-            # PJRT plugins without the stats API raise backend-specific
-            # types; zeros mean "unknown", but leave a trace of why
-            from deepspeed_tpu.utils.logging import logger
-
-            logger.debug(f"device memory_stats unavailable "
-                         f"({type(e).__name__}: {e})")
-            stats = {}
-        return {
-            "bytes_in_use": stats.get("bytes_in_use", 0),
-            "peak_bytes_in_use": stats.get("peak_bytes_in_use", 0),
-            "bytes_limit": stats.get("bytes_limit", 0),
-        }
+        dev = jax.local_devices()[device_index or 0]
+        # a TPU always reports allocator stats; zeros in their place would
+        # read as "nothing ran on this chip"
+        stats = dev.memory_stats()
+        if not stats:
+            raise RuntimeError(f"{dev} reports no memory_stats()")
+        return {key: stats[key] for key in
+                ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
 
     def op_builder_dir(self) -> str:
         return "deepspeed_tpu.ops.op_builder"

@@ -601,6 +601,20 @@ class PlanEngine:
             counters[verdict] += 1
             self._tm_candidates.inc(verdict=verdict)
 
+        if not dry_run and self.confirm_top_k:
+            import jax
+
+            if jax.default_backend() == "tpu":
+                # this process lowers the candidates, so it holds the
+                # chip; the confirming child needs the same chip and a
+                # chip belongs to one process at a time — the child would
+                # fail or hang and the plan would fall back to the
+                # unconfirmed ranking without saying so
+                raise PlanError(
+                    "the confirm leg measures candidates in a child "
+                    "process, which cannot get the TPU this process "
+                    "holds; rerun with --top-k 0 (priced, unconfirmed "
+                    "plan) until confirm runs in-process")
         cands = self.enumerate_candidates()
         log_n = len(cands)
         logger.info(f"plan engine: {log_n} candidates "

@@ -12,8 +12,9 @@ you only need the observatory. Pieces:
   can never silently go null again)
 * :mod:`~deepspeed_tpu.bench.history` — append-only
   ``bench_history/history.jsonl``
-* :mod:`~deepspeed_tpu.bench.legacy`  — tolerant recovery of the
-  committed BENCH_r01–r05 tail blobs (r03–r05 were ``"parsed": null``)
+* :mod:`~deepspeed_tpu.bench.legacy`  — tolerant recovery of driver
+  round artifacts (``BENCH_rNN.json`` tail blobs; r03 is a committed
+  ``"parsed": null`` example)
 * :mod:`~deepspeed_tpu.bench.diff`    — direction-aware metric diffs +
   per-phase span diffs with regression attribution
 * :mod:`~deepspeed_tpu.bench.gate`    — 0/1/2 exit-code regression gate

@@ -1,9 +1,7 @@
 """``python -m deepspeed_tpu.bench`` — history maintenance subcommands.
 
 * ``recover``  — re-ingest committed ``BENCH_r*.json`` round artifacts
-  into ``bench_history/history.jsonl`` (skips rounds already recorded;
-  this is how the r01–r05 trajectory was recovered after r03–r05 went
-  ``"parsed": null``)
+  into ``bench_history/history.jsonl`` (skips rounds already recorded)
 * ``validate`` — validate a bench result / history file against the
   versioned schema (exit 0 valid, 1 invalid, 2 error)
 * ``history``  — print the recorded trajectory as a table

@@ -4,8 +4,10 @@ The driver records each round as ``BENCH_rNN.json`` = ``{n, cmd, rc, tail,
 parsed}`` where ``tail`` is the LAST ~2000 characters of the run's output
 and ``parsed`` is the driver's attempt at reading the final JSON line.
 When the bench line outgrew the tail window (r03) the line's FRONT was cut
-off, ``json.loads`` failed, and three rounds of perf evidence became
-``"parsed": null`` — write-only. r04 (rc=124) never printed a line at all.
+off, ``json.loads`` failed, and the round's perf evidence became
+``"parsed": null`` — write-only. A run killed at its time limit prints no
+line at all. Of the artifacts this was written for, ``BENCH_r02.json``
+(complete ``parsed``) and ``BENCH_r03.json`` (truncated tail) remain.
 
 This module re-ingests those blobs: a complete line upgrades to schema v2
 via :func:`upgrade_legacy_result`; a truncated line goes through a
@@ -71,7 +73,7 @@ KNOWN_ENTRY_NAMES = (
 )
 
 _EXTRA_TOP_KEYS = ("budget_s", "total_runtime_s", "entry_elapsed_s",
-                   "best_mfu_row", "gate", "schema_version")
+                   "gate", "schema_version")
 
 _KEY_RE = re.compile(r'"((?:[^"\\]|\\.)*)"\s*:\s*')
 _LEAD_KEY_RE = re.compile(r'\s*([A-Za-z0-9_.\-/]*)"\s*:\s*')
@@ -162,9 +164,6 @@ def upgrade_legacy_result(parsed: Dict[str, Any]) -> Dict[str, Any]:
         entries[name] = normalize_entry_row(row, elapsed.get(name))
     if "comm_bw" in rest:
         entries["comm_bw"] = normalize_entry_row(rest.pop("comm_bw"))
-    best = rest.pop("best_mfu_row", None)
-    if best is not None:
-        headline["best_row"] = best
     result: Dict[str, Any] = {"schema_version": SCHEMA_VERSION}
     for key in ("metric", "value", "unit", "vs_baseline"):
         if key in headline:
@@ -226,9 +225,6 @@ def recover_from_text(text: str) -> Tuple[Dict[str, Any], List[str]]:
             continue
         if key in ("telemetry", "trace_phases") and isinstance(val, dict):
             headline[key] = val
-            continue
-        if key == "best_mfu_row" and isinstance(val, dict):
-            headline["best_row"] = val
             continue
         if key in _EXTRA_TOP_KEYS:
             extras[key] = val

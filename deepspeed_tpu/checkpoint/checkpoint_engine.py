@@ -13,8 +13,8 @@ TPU mapping:
 * **orbax** — the TorchCheckpointEngine analog and the default: sharded
   global-array I/O, GCS-aware (used by ``checkpoint/engine.py``).
 * **fast** — per-host flat binary dumps through the ``csrc/aio`` C++ thread
-  pool (``build/libdstpu_aio.so``): tensors are staged to host numpy, then
-  written by N native threads with the python thread free to continue —
+  pool (``csrc/aio/aio.cpp``, built on demand under ``build/``): tensors
+  are staged to host numpy, then written by N native threads with the python thread free to continue —
   the double-buffered-writer design, for local NVMe scratch on TPU VMs.
 * **decoupled** — wraps any engine; save() enqueues and returns immediately,
   a daemon thread drains; commit semantics via ``wait()``.

@@ -25,6 +25,8 @@ from __future__ import annotations
 
 from typing import Dict
 
+from deepspeed_tpu.utils.chip_specs import lookup_chip
+
 #: canonical collective kinds (the ledger's vocabulary)
 ALL_REDUCE = "all_reduce"
 ALL_GATHER = "all_gather"
@@ -112,18 +114,15 @@ def bw_log(op: str, size_bytes: int, duration_s: float,
 ICI_GBPS = {"v4": 300.0, "v5e": 200.0, "v5 lite": 200.0,
             "v5p": 600.0, "v6e": 448.0, "v6 lite": 448.0}
 
-#: fallback when the device kind is unrecognized (CPU hosts, tests):
-#: software collectives through shared memory land in this order
+#: rate for a non-TPU device kind (CPU hosts, tests): software collectives
+#: through shared memory land in this order
 DEFAULT_LINK_GBPS = 10.0
 
 
 def chip_link_gbps(device_kind: str, default: float = DEFAULT_LINK_GBPS) -> float:
-    """Per-chip ICI GB/s for a PJRT ``device_kind`` string."""
-    kind = (device_kind or "").lower()
-    for key, gbps in ICI_GBPS.items():
-        if key in kind:
-            return gbps
-    return default
+    """Per-chip ICI GB/s for a PJRT ``device_kind`` string; a TPU kind
+    missing from :data:`ICI_GBPS` raises (``chip_specs.lookup_chip``)."""
+    return lookup_chip(ICI_GBPS, device_kind, default, "ICI GB/s")
 
 
 def predicted_seconds(op: str, size_bytes: int, n: int,

@@ -138,22 +138,9 @@ def initialize_mesh(
     devices = list(devices) if devices is not None else jax.devices()
     sizes = mesh_config.resolve(len(devices))
     shape = tuple(sizes[a] for a in DEFAULT_AXIS_ORDER)
-    # AxisType landed in newer jax; older builds default every axis to the
-    # same auto sharding behavior, so simply omit the kwarg there
-    axis_type_cls = getattr(jax.sharding, "AxisType", None)
-    kw = {} if axis_type_cls is None else {
-        "axis_types": tuple(axis_type_cls.Auto for _ in DEFAULT_AXIS_ORDER)}
-    try:
-        mesh = jax.make_mesh(shape, DEFAULT_AXIS_ORDER, devices=devices,
-                             **kw)
-    except Exception as e:
-        # make_mesh is missing on older jax and rejects kwargs across
-        # versions — the raw Mesh fallback is topology-order-naive but
-        # always constructible, so note WHY we degraded
-        logger.debug(f"jax.make_mesh unavailable/failed "
-                     f"({type(e).__name__}: {e}); using raw Mesh fallback")
-        dev_array = np.asarray(devices).reshape(shape)
-        mesh = Mesh(dev_array, DEFAULT_AXIS_ORDER, **kw)
+    mesh = jax.make_mesh(
+        shape, DEFAULT_AXIS_ORDER, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(DEFAULT_AXIS_ORDER))
     _GLOBAL_MESH = MeshManager(mesh)
     logger.info(f"initialized device mesh: {_GLOBAL_MESH}")
     return _GLOBAL_MESH
@@ -193,6 +180,16 @@ def maybe_mesh() -> Optional[Mesh]:
 
 def mesh_is_initialized() -> bool:
     return _GLOBAL_MESH is not None
+
+
+def already_manual_axes() -> set:
+    """Axes manualized by an ENCLOSING shard_map at trace time (e.g. the
+    engine's compressed-collective step is manual over data/zshard; the
+    pipeline over 'pipe') — a nested shard_map must not re-manualize
+    them, and inside that context arrays are already per-shard on them."""
+    am = jax.sharding.get_abstract_mesh()
+    return {n for n, t in zip(am.axis_names, am.axis_types)
+            if t == jax.sharding.AxisType.Manual}
 
 
 def reset_mesh() -> None:

@@ -905,6 +905,22 @@ _ARCH_TABLE = {
 }
 
 
+def _arch_row(hf_config, arch: Optional[str]):
+    arch = arch or getattr(hf_config, "model_type", None)
+    if arch not in _ARCH_TABLE:
+        raise ValueError(
+            f"unsupported HF architecture {arch!r}; "
+            f"supported: {sorted(_ARCH_TABLE)}")
+    return _ARCH_TABLE[arch]
+
+
+def config_from_hf(hf_config, arch: Optional[str] = None
+                   ) -> TransformerConfig:
+    """The zoo config for a ``transformers`` config object alone (no
+    weights) — random-weight runs at a published shape."""
+    return _arch_row(hf_config, arch)[0](hf_config)
+
+
 def import_hf_model(model, arch: Optional[str] = None
                     ) -> Tuple[TransformerConfig, PyTree]:
     """Convert a ``transformers`` model (or (state_dict, config) pair) into
@@ -913,11 +929,6 @@ def import_hf_model(model, arch: Optional[str] = None
         sd, hf_config = model
     else:
         sd, hf_config = model.state_dict(), model.config
-    arch = arch or getattr(hf_config, "model_type", None)
-    if arch not in _ARCH_TABLE:
-        raise ValueError(
-            f"unsupported HF architecture {arch!r}; "
-            f"supported: {sorted(_ARCH_TABLE)}")
-    cfg_fn, params_fn = _ARCH_TABLE[arch]
+    cfg_fn, params_fn = _arch_row(hf_config, arch)
     cfg = cfg_fn(hf_config)
     return cfg, params_fn(sd, cfg)

@@ -74,7 +74,7 @@ def _remat_wrap(body, remat: str):
         # around one sub-block; the scan body itself is not rematted, so
         # the other sub-block's activations are saved by ordinary AD and
         # XLA's scan fusion stays intact (the names-policy selective remat
-        # measurably disrupts it, PROFILE.md round-2 sweep)
+        # disrupts it)
         return body
     if remat == "selective":
         return jax.checkpoint(body, policy=_SELECTIVE_POLICY)
@@ -1438,9 +1438,7 @@ def fused_lm_loss(hidden: jax.Array, head: jax.Array, tokens: jax.Array,
     exact-fp32-logits path (``head_matmul`` + ``causal_lm_loss``) this
     halves every [B,S,V] buffer and the custom backward materializes ONE
     bf16 grad-logits array (softmax − onehot fused into its producing
-    pass) instead of AD's fp32 grad + scatter-add + convert chain —
-    measured ~40 GB → ~18 GB of vocab-axis traffic per micro-batch at
-    GPT-2-125M B32 (the loss was ~10%% of step time, PROFILE.md).
+    pass) instead of AD's fp32 grad + scatter-add + convert chain.
     Loss delta vs the exact path is the bf16 logit rounding (~1e-3),
     identical in class to the r2 ``head_matmul`` bf16-cotangent change."""
     B, S, H = hidden.shape
@@ -1543,7 +1541,8 @@ PRESETS: Dict[str, TransformerConfig] = {
     # matches dense matmul throughput (46-55 TF/s grouped vs 52 dense at
     # [32k,1536]x[8,1536,6144], same-harness A/B) — at moe_350m's K=768
     # shapes grouped and dense measure in the SAME low band, i.e. the
-    # contraction itself is the ceiling; full rung table in PROFILE.md r5
+    # contraction itself is the ceiling (earlier-round figures, not
+    # re-measured on this round's code)
     "moe_1b": TransformerConfig(vocab_size=32000, hidden_size=1536,
                                 num_layers=12, num_heads=12, max_seq_len=1024,
                                 ffn_hidden_size=6144, use_bias=False,

@@ -42,10 +42,10 @@ from deepspeed_tpu.comm.mesh import (
     SEQ_AXIS,
     TENSOR_AXIS,
     ZSHARD_AXIS,
+    already_manual_axes,
     maybe_mesh,
     on_reset_mesh,
 )
-from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.moe.gating import (
     GateOutput,
     IndexGateOutput,
@@ -156,7 +156,7 @@ def grouped_dot(x: jax.Array, w: jax.Array, group_sizes: jax.Array
 
         # Tile defaults: (512, K-whole-up-to-1024, 1024) — the r4-measured
         # optimum that fits the 16M scoped-vmem budget in-program for
-        # forward, dgrad AND tgmm. The r5 sweep (PROFILE.md) found wider
+        # forward, dgrad AND tgmm. An earlier-round sweep found wider
         # tiles ((1024, 768, 3072): 43 vs 30 TF/s standalone FORWARD) but
         # every variant either exceeds the in-program scoped-vmem limit
         # (fwd 17.9M, dgrad 36M at 16M/20M budgets) or — with the limit
@@ -403,27 +403,10 @@ def _ragged_dispatch_local(xt: jax.Array, weights: jax.Array, idx: jax.Array,
     return combine_gather(y_s, weights.astype(xt.dtype), order, inv2d)
 
 
-def _already_manual_axes() -> set:
-    """Axes manualized by an ENCLOSING shard_map at trace time (e.g. the
-    engine's compressed-collective step is manual over data/zshard; the
-    pipeline over 'pipe') — our shard_map must not re-manualize them, and
-    inside that context the tokens are already per-shard on those axes."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        return {n for n, t in zip(am.axis_names, am.axis_types)
-                if "Manual" in str(t)}
-    except Exception as e:
-        # abstract-mesh introspection only exists on newer jax; absence
-        # means no enclosing shard_map manualized anything
-        logger.debug(f"abstract-mesh probe unavailable "
-                     f"({type(e).__name__}: {e}); assuming no manual axes")
-        return set()
-
-
 def _token_axes(mesh) -> Tuple[Tuple[str, ...], Optional[str]]:
     """Mesh axes that shard the token stream: (batch axes, seq axis) —
     excluding axes an enclosing shard_map already made manual."""
-    manual = _already_manual_axes()
+    manual = already_manual_axes()
     batch = tuple(a for a in (DATA_AXIS, ZSHARD_AXIS, EXPERT_AXIS)
                   if mesh.shape.get(a, 1) > 1 and a not in manual)
     seq = SEQ_AXIS if (mesh.shape.get(SEQ_AXIS, 1) > 1
@@ -443,7 +426,7 @@ def ragged_mesh_plan(mesh, B: int, S: Optional[int], E: int):
     """
     if mesh is None:
         return "local", None
-    manual = _already_manual_axes()
+    manual = already_manual_axes()
     batch_axes, seq_ax = _token_axes(mesh)
     ep = mesh.shape.get(EXPERT_AXIS, 1) if EXPERT_AXIS not in manual else 1
     tp = TENSOR_AXIS if (mesh.shape.get(TENSOR_AXIS, 1) > 1
@@ -717,14 +700,14 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
     # pipeline manual over 'pipe') the nested shard_map must be built on the
     # context's abstract mesh — its axis_types record what is already manual
     sm_mesh = mesh
-    if _already_manual_axes():
+    if already_manual_axes():
         sm_mesh = jax.sharding.get_abstract_mesh()
     # trace-time: drop reporting is active only when a monitor is installed
     # AND we're not under an enclosing manual context (where the callback
     # can't lower) — gate BOTH the psums and the callback on it so the
     # unmonitored trace stays the zero-cost constant path
     monitored = (_DROP_MONITOR is not None and ep > 1
-                 and not _already_manual_axes())
+                 and not already_manual_axes())
     cache_key = (sm_mesh, k, activation, score_func, route_norm, n_group,
                  topk_group, x.shape, str(x.dtype), gate_w.shape,
                  monitored,

@@ -8,6 +8,7 @@ invocation, cached next to the package (no torch cpp_extension machinery).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,20 +21,36 @@ from deepspeed_tpu.analysis.racelint.sanitizer import make_lock
 _REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 _SRC = os.path.join(_REPO_ROOT, "csrc", "aio", "aio.cpp")
 _BUILD_DIR = os.path.join(_REPO_ROOT, "build")
-_SO_PATH = os.path.join(_BUILD_DIR, "libdstpu_aio.so")
 
 _lib = None
 _lib_lock = make_lock("aio._lib_lock")
 
 
+def _so_path() -> str:
+    """The built library is keyed by a hash of its source: in a copied or
+    freshly checked-out tree mtimes mean nothing, and ``build/`` is not
+    tracked, so what runs is always compiled from the tracked ``.cpp``."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libdstpu_aio.{digest}.so")
+
+
 def _build_library(force: bool = False) -> str:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    if (force or not os.path.exists(_SO_PATH)
-            or os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC)):
-        cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-               _SRC, "-o", _SO_PATH]
-        subprocess.run(cmd, check=True, capture_output=True)
-    return _SO_PATH
+    so_path = _so_path()
+    if force or not os.path.exists(so_path):
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        # compile beside the target and rename: another process loading
+        # the library must never see a half-written file
+        tmp = f"{so_path}.{os.getpid()}.tmp"
+        try:
+            subprocess.run(
+                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+                 _SRC, "-o", tmp], check=True, capture_output=True)
+            os.replace(tmp, so_path)
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
+    return so_path
 
 
 def _load() -> ctypes.CDLL:
@@ -47,7 +64,7 @@ def _load() -> ctypes.CDLL:
                 lib = ctypes.CDLL(_build_library())   # racelint: disable=lock-across-blocking
             except OSError:
                 # a cached .so built on another image (libstdc++/GLIBCXX
-                # mismatch) passes the mtime check but fails to load —
+                # mismatch) matches the source hash but fails to load —
                 # rebuild for THIS toolchain and retry
                 lib = ctypes.CDLL(_build_library(force=True))   # racelint: disable=lock-across-blocking
             lib.aio_handle_create.restype = ctypes.c_void_p

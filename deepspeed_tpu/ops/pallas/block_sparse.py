@@ -25,23 +25,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _vmem(shape, dtype):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemoryRef(shape, dtype)  # pragma: no cover
 
 
 # --------------------------------------------------------------------------- #
@@ -174,10 +164,7 @@ def _fwd(q, k, v, layout, *, scale, causal, seq_len, block_q, block_kv,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, seq_len=seq_len,
         block_q=block_q, block_kv=block_kv)
-    if pltpu is not None:
-        lay_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    else:  # pragma: no cover
-        lay_spec = pl.BlockSpec(memory_space=pl.ANY)
+    lay_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -196,9 +183,9 @@ def _fwd(q, k, v, layout, *, scale, causal, seq_len, block_q, block_kv,
             jax.ShapeDtypeStruct((bh, sq), jnp.float32),
         ],
         scratch_shapes=[
-            _vmem((block_q, d), jnp.float32),
-            _vmem((block_q, 1), jnp.float32),
-            _vmem((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(layout, q, k, v)
@@ -300,10 +287,7 @@ def _bwd(scale, causal, seq_len, block_q, block_kv, interpret,
     n_q, n_kv = sq // block_q, k.shape[1] // block_kv
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
 
-    if pltpu is not None:
-        lay_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    else:  # pragma: no cover
-        lay_spec = pl.BlockSpec(memory_space=pl.ANY)
+    lay_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0))
@@ -317,7 +301,7 @@ def _bwd(scale, causal, seq_len, block_q, block_kv, interpret,
                   row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[_vmem((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(layout, q, k, v, do, lse, delta)
 
@@ -334,8 +318,8 @@ def _bwd(scale, causal, seq_len, block_q, block_kv, interpret,
         out_specs=[kv_spec2, kv_spec2],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[_vmem((block_kv, d), jnp.float32),
-                        _vmem((block_kv, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
+                        pltpu.VMEM((block_kv, d), jnp.float32)],
         interpret=interpret,
     )(layout, q, k, v, do, lse, delta)
     return dq, dk, dv, None

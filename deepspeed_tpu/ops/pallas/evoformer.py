@@ -23,10 +23,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.flash_attention import (_block_mask,
                                                       _compiler_params,
-                                                      _use_interpret, _vmem,
+                                                      _use_interpret,
                                                       NEG_INF)
 
 
@@ -118,9 +119,9 @@ def _evo_flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, bias: jax.Array,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((G * N, Sq, D), q.dtype),
         scratch_shapes=[
-            _vmem((block_q, D), jnp.float32),
-            _vmem((block_q, 1), jnp.float32),
-            _vmem((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=_use_interpret(),

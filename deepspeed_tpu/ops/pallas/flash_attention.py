@@ -27,11 +27,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU builds of jax as well
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -40,14 +37,8 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _vmem(shape, dtype):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemoryRef(shape, dtype)  # pragma: no cover
-
-
 def _compiler_params():
-    if pltpu is not None and not _use_interpret():
+    if not _use_interpret():
         return pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return None
@@ -152,9 +143,9 @@ def _fwd(q, k, v, *, scale, causal, kv_len, rep, block_q, block_kv, interpret):
             jax.ShapeDtypeStruct((BN, S_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
-            _vmem((block_q, D), jnp.float32),
-            _vmem((block_q, 128), jnp.float32),
-            _vmem((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
@@ -308,7 +299,7 @@ def _bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BN, S_pad, D), q.dtype),
-        scratch_shapes=[_vmem((block_q, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -344,8 +335,8 @@ def _bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
             jax.ShapeDtypeStruct((BK, Skv_pad, D), v.dtype),
         ],
         scratch_shapes=[
-            _vmem((block_kv, D), jnp.float32),
-            _vmem((block_kv, D), jnp.float32),
+            pltpu.VMEM((block_kv, D), jnp.float32),
+            pltpu.VMEM((block_kv, D), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
@@ -380,6 +371,44 @@ def _flash_bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _mesh_partition(B: int, N: int, K: int):
+    """How to run the kernel on the live mesh → ``(mesh, spec,
+    axis_names)``, or ``None`` for a direct call.
+
+    Mosaic kernels cannot be partitioned by GSPMD: on more than one chip a
+    ``pallas_call`` is only accepted inside a ``shard_map`` that is manual
+    over EVERY mesh axis (interpret mode on the CPU lowers to plain XLA
+    and hides this). Each (batch row, head) is independent, so the batch
+    maps over the data-parallel axes and the heads over ('tensor', 'seq')
+    — the layouts the engine and Ulysses already use — with no
+    collective. A dim an axis does not divide stays replicated over it
+    (every shard computes it; correct, redundant)."""
+    from deepspeed_tpu.comm import mesh as M
+
+    if not M.mesh_is_initialized():
+        return None
+    mesh = M.get_mesh()
+    manual = M.already_manual_axes()
+    free = tuple(a for a in mesh.axis_names if a not in manual)
+    if not free or (mesh.size == 1 and not manual):
+        return None     # an enclosing shard_map owns every axis / one chip
+
+    def fit(axes, *dims):
+        kept, n = (), 1
+        for a in axes:
+            size = mesh.shape[a]
+            if a in free and size > 1 and all(
+                    d % (n * size) == 0 for d in dims):
+                kept, n = kept + (a,), n * size
+        return kept[0] if len(kept) == 1 else (kept or None)
+
+    spec = P(fit((M.DATA_AXIS, M.ZSHARD_AXIS, M.EXPERT_AXIS), B), None,
+             fit((M.TENSOR_AXIS, M.SEQ_AXIS), N, K), None)
+    if manual:   # nested: build on the context's mesh, which records them
+        return jax.sharding.get_abstract_mesh(), spec, frozenset(free)
+    return mesh, spec, frozenset()
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     segment_mask: Optional[jax.Array] = None,
@@ -390,10 +419,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     maps, no repetition in HBM). Arbitrary masks fall back to the XLA
     reference implementation (the Pallas kernel handles causal/full only).
 
-    Default blocks (512, 1024) are the measured v5e sweet spot — big tiles
-    amortize the per-grid-step overhead and keep the MXU fed; 128×128 blocks
-    measured ~2× slower end-to-end on GPT-2-125M grad steps. Blocks are
-    capped to the (pow2-rounded) sequence length for short sequences.
+    Default blocks (512, 1024): big tiles amortize the per-grid-step
+    overhead and keep the MXU fed — the fastest of five shapes tried on a
+    v5e at S=2048 with 32/8 heads of 128 (forward 1.4 ms, against 3.4 ms
+    at 128x128; PERF.md, PR 21). Blocks are capped to the (pow2-rounded)
+    sequence length for short sequences. Under a multi-device mesh the
+    kernel runs per shard (:func:`_mesh_partition`).
     """
     if segment_mask is not None:
         from deepspeed_tpu.models.transformer import dot_product_attention
@@ -401,11 +432,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return dot_product_attention(q, k, v, causal=causal,
                                      segment_mask=segment_mask)
 
-    B, S, N, D = q.shape
+    B, _, N, _ = q.shape
     K = k.shape[2]
     if N % K != 0:
         raise ValueError(f"q heads {N} not divisible by kv heads {K}")
-    rep = N // K
+    local = functools.partial(_flash_local, causal=causal, block_q=block_q,
+                              block_kv=block_kv)
+    part = _mesh_partition(B, N, K)
+    if part is None:
+        return local(q, k, v)
+    mesh, spec, axis_names = part
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names=axis_names,
+                         check_vma=False)(q, k, v)
+
+
+def _flash_local(q, k, v, *, causal, block_q, block_kv):
+    B, S, N, D = q.shape
+    rep = N // k.shape[2]
     Skv = k.shape[1]
     block_q = min(block_q, _round_pow2(S))
     block_kv = min(block_kv, _round_pow2(Skv))

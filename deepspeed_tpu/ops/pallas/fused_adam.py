@@ -19,11 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 _BLOCK = 4096  # elements per grid step (multiple of the 8x128 vreg tile)
 
 

@@ -24,11 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -96,10 +92,6 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                     tables: jax.Array, lengths: jax.Array,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Drop-in for ``models.paged.paged_attention_reference``."""
-    if pltpu is None:
-        raise ImportError(
-            "jax.experimental.pallas.tpu is unavailable — use "
-            "models.paged.paged_attention_reference instead")
     if interpret is None:
         interpret = _use_interpret()
     Tn, N, D = q.shape
@@ -127,7 +119,7 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     )
     kernel = functools.partial(_kernel, bs=bs, rep=rep, n_blocks_per_seq=MB)
     compiler_params = None
-    if pltpu is not None and not interpret:
+    if not interpret:
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(

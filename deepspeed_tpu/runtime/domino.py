@@ -78,8 +78,8 @@ def apply_overlap_flags() -> str:
     respected, not overridden, and not reported as armed). Every
     skipped flag is logged, not raised: an older jaxlib must degrade to
     its default scheduler, not crash. Call BEFORE the first jax backend
-    use — once a backend exists the env change is inert, and this logs
-    a warning instead of pretending otherwise."""
+    use — once a backend exists the env change only reaches
+    subprocesses (bench entries, launcher workers)."""
     import os
 
     from deepspeed_tpu.utils.logging import logger
@@ -100,18 +100,6 @@ def apply_overlap_flags() -> str:
     missing = [f for f in supported
                if f.split("=", 1)[0] not in present]
     if missing:
-        backend_up = False
-        try:
-            from jax._src import xla_bridge as _xb
-
-            backend_up = bool(getattr(_xb, "_backends", None))
-        except (ImportError, AttributeError):
-            pass   # private surface moved — best-effort warning only
-        if backend_up:
-            logger.warning(
-                "domino overlap flags applied AFTER jax backend "
-                "initialization — they take effect in subprocesses "
-                "(bench entries, launcher workers), not this process")
         os.environ["XLA_FLAGS"] = (current + " " + " ".join(missing)).strip()
     return " ".join(missing)
 

@@ -1,7 +1,7 @@
 """Circuit breaker around the FastGen engine tick.
 
 The serving loop's failure mode is not one bad request — it is a sick
-device (runtime crashed, HBM poisoned, remote tunnel dropped) making
+device (runtime crashed, HBM poisoned, device lost) making
 EVERY tick raise. Without a breaker each incoming request still pays a
 full tick attempt before failing, so a dead replica burns its whole
 queue at device-timeout speed. The breaker converts that into fail-fast:

@@ -8,7 +8,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-# bf16 peak TFLOP/s per chip, by TPU generation (fallback: v5e)
+# bf16 peak TFLOP/s per chip, by TPU generation
 PEAK_BF16_TFLOPS = {"v4": 275.0, "v5e": 197.0, "v5 lite": 197.0,
                     "v5p": 459.0, "v6e": 918.0, "v6 lite": 918.0}
 
@@ -31,36 +31,46 @@ HBM_CAPACITY_BYTES = {"v4": 32 * _GiB, "v5e": 16 * _GiB,
                       "v6e": 32 * _GiB, "v6 lite": 32 * _GiB}
 
 
+class UnknownChipError(LookupError):
+    """A TPU whose ``device_kind`` has no datasheet row."""
+
+
+def lookup_chip(table: dict, device_kind: str, default, what: str):
+    """``table``'s row for a PJRT ``device_kind`` string. A kind that names
+    no TPU (the CPU hosts of the test tier) gets ``default``; a TPU missing
+    from the table is an error — pricing it at another generation's rate
+    would put a wrong peak under every utilization computed from it."""
+    kind = (device_kind or "").lower()
+    for key, value in table.items():
+        if key in kind:
+            return value
+    if "tpu" in kind:
+        raise UnknownChipError(
+            f"TPU device_kind {device_kind!r} has no {what} row "
+            f"(known: {sorted(table)}) — add its datasheet value")
+    return default
+
+
 def chip_peak_tflops(device_kind: str,
                      default: Optional[float] = None) -> Optional[float]:
     """Peak bf16 TFLOP/s for a PJRT ``device_kind`` string; ``default``
-    when the kind is unrecognized (CPU hosts have no meaningful peak)."""
-    kind = (device_kind or "").lower()
-    for key, peak in PEAK_BF16_TFLOPS.items():
-        if key in kind:
-            return peak
-    return default
+    for a non-TPU kind (CPU hosts have no meaningful peak)."""
+    return lookup_chip(PEAK_BF16_TFLOPS, device_kind, default,
+                       "peak bf16 TFLOP/s")
 
 
 def chip_hbm_gbps(device_kind: str,
                   default: Optional[float] = None) -> Optional[float]:
-    """Datasheet HBM GB/s for a PJRT ``device_kind``; ``default`` when
-    unrecognized (CPU hosts: caller picks a documented host rate)."""
-    kind = (device_kind or "").lower()
-    for key, bw in HBM_GBPS.items():
-        if key in kind:
-            return bw
-    return default
+    """Datasheet HBM GB/s for a PJRT ``device_kind``; ``default`` for a
+    non-TPU kind (CPU hosts: caller picks a documented host rate)."""
+    return lookup_chip(HBM_GBPS, device_kind, default, "HBM GB/s")
 
 
 def chip_hbm_bytes(device_kind: str,
                    default: Optional[int] = None) -> Optional[int]:
     """Datasheet HBM capacity bytes for a PJRT ``device_kind``;
-    ``default`` (usually None) when unrecognized — the datasheet-less
+    ``default`` (usually None) for a non-TPU kind — the datasheet-less
     CPU tier must opt in with an explicit budget, never inherit a TPU
     part's capacity."""
-    kind = (device_kind or "").lower()
-    for key, cap in HBM_CAPACITY_BYTES.items():
-        if key in kind:
-            return cap
-    return default
+    return lookup_chip(HBM_CAPACITY_BYTES, device_kind, default,
+                       "HBM capacity")

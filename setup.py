@@ -20,7 +20,7 @@ setup(
                   "deepspeed_tpu.analysis.racelint": ["contracts/*.json",
                                                       "baseline.json"]},
     python_requires=">=3.10",
-    install_requires=["jax", "numpy", "orbax-checkpoint", "einops"],
+    install_requires=["jax", "numpy", "orbax-checkpoint"],
     extras_require={
         "hf": ["transformers", "torch"],
         "monitor": ["tensorboardX", "wandb", "comet-ml"],

@@ -3,8 +3,8 @@
 The acceptance scenario from the observatory issue is here verbatim: a
 synthetic ≥10% throughput regression whose fwd phase grew must be
 flagged WITH the responsible phase named, the gate must exit nonzero on
-it and zero on parity, and the recovered r05 record must be directly
-diffable from the CLI.
+it and zero on parity, and a recovered entries-only record must be
+directly diffable from the CLI.
 """
 import copy
 import json
@@ -368,7 +368,7 @@ class TestGate:
 
     def test_platform_declaring_fresh_run_skips_platformless_records(
             self, tmp_path):
-        """The committed r01–r05 records predate the platform field. A
+        """Records of earlier rounds predate the platform field. A
         fresh run that DOES declare one (every schema-v2 headline) must
         not numeric-gate against them — a CPU box vs the TPU-recorded
         r02 headline reads as a fake -99%. No qualifying baseline ⇒
@@ -514,11 +514,19 @@ class TestNoiseBand:
 
 
 class TestBenchDiffCli:
-    def test_r05_injected_regression_flagged_from_the_recovered_record(
+    def test_injected_regression_flagged_from_a_recovered_record(
             self, tmp_path, capsys):
-        """Acceptance: bench-diff against the RECOVERED r05 record flags
-        an injected ≥10% synthetic regression; exit 1 on it, 0 on parity."""
-        hist = os.path.join(REPO, "bench_history", "history.jsonl")
+        """Acceptance: bench-diff against a RECOVERED (entries-only, no
+        headline) history record flags an injected ≥10% synthetic
+        regression inside a row table; exit 1 on it, 0 on parity."""
+        hist = str(tmp_path / "history.jsonl")
+        recovered = {"schema_version": 2, "headline": {}, "entries": {
+            "comm_cpu_mesh_world8": {"metrics": {"compressed_wire_world8": [
+                {"op": "all_reduce_exact_fp32", "wire_reduction": 1.0},
+                {"op": "reduce_scatter_qgz_int8",
+                 "wire_reduction": 3.94}]}}}}
+        history_mod.append_record(
+            history_mod.record_from_result(recovered, "r05"), hist)
         r05 = history_mod.record_for_round("r05", path=hist)
         fresh = copy.deepcopy(r05["result"])
         wire = fresh["entries"]["comm_cpu_mesh_world8"]["metrics"][
@@ -569,7 +577,7 @@ class TestBenchDiffCli:
     def test_unpadded_round_spec_resolves_like_padded(self, tmp_path):
         """`r5` and `r05` are the same round — both must resolve through
         history first (a superseding record must not be bypassed in
-        favor of the committed BENCH_r05.json artifact)."""
+        favor of a committed BENCH_r05.json artifact)."""
         hist = str(tmp_path / "history.jsonl")
         superseding = make_result(tps=12345.0)
         history_mod.append_record(
@@ -586,14 +594,14 @@ class TestBenchDiffCli:
         """An unreadable spec (a directory) is an internal error (2),
         never a 'regression found' (1) — CI reads the dslint-shaped
         contract."""
-        assert cli.main([str(tmp_path), "r05", "--repo", REPO,
+        assert cli.main([str(tmp_path), "r03", "--repo", REPO,
                          "--history", str(tmp_path / "h.jsonl")]) \
             == gate.GATE_ERROR
         assert "error" in capsys.readouterr().err
 
     def test_malformed_round_spec_exits_2_not_traceback(self, tmp_path,
                                                         capsys):
-        assert cli.main(["rr3", "r05", "--repo", REPO,
+        assert cli.main(["rr3", "r03", "--repo", REPO,
                          "--history", str(tmp_path / "h.jsonl")]) \
             == gate.GATE_ERROR
         assert "error" in capsys.readouterr().err

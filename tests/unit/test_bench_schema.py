@@ -1,10 +1,9 @@
 """Perf-observatory schema + recovery tests (``deepspeed_tpu/bench``).
 
-The legacy-ingestion tests run against the REAL committed round
-artifacts (BENCH_r01–r05.json at the repo root) — r03/r05 are the
-actual truncated tails that produced ``"parsed": null``, r04 is the real
-rc=124 husk — and against the committed ``bench_history/history.jsonl``
-those artifacts were recovered into.
+The legacy-ingestion tests run against the committed round artifacts
+that remain (BENCH_r02/r03.json at the repo root — r03 is an actual
+truncated tail that produced ``"parsed": null``) and against small
+driver-shaped artifacts written into ``tmp_path``.
 """
 import json
 import os
@@ -252,13 +251,28 @@ class TestNormalizeEntryRow:
         assert again["elapsed_s"] == 3.0
 
 
+def write_artifact(tmp_path, name, rc=0, tail="", parsed=None):
+    """A driver round artifact ``{n, cmd, rc, tail, parsed}``."""
+    path = str(tmp_path / name)
+    with open(path, "w") as f:
+        json.dump({"n": 1, "cmd": "python bench.py", "rc": rc,
+                   "tail": tail, "parsed": parsed}, f)
+    return path
+
+
+V1_PARSED = {"metric": "tokens/sec/chip gpt2_125m zero1 bf16",
+             "value": 34443.1, "unit": "tokens/s/chip",
+             "vs_baseline": 0.206}
+
+
 # --------------------------------------------------------------------- #
-# legacy recovery against the REAL committed rounds
+# legacy recovery of driver round artifacts
 # --------------------------------------------------------------------- #
 class TestLegacyRecovery:
-    def test_r01_complete_from_parsed(self):
-        rec = legacy.recover_round_file(os.path.join(REPO,
-                                                     "BENCH_r01.json"))
+    def test_complete_from_parsed(self, tmp_path):
+        rec = legacy.recover_round_file(write_artifact(
+            tmp_path, "BENCH_r01.json", tail="WARNING: noise\n",
+            parsed=V1_PARSED))
         assert rec["complete"] and not rec["recovered"]
         assert rec["result"]["headline"]["value"] == 34443.1
         assert schema.validate_record(rec) == []
@@ -289,20 +303,29 @@ class TestLegacyRecovery:
         assert "mfu" not in rec["result"]["headline"]
         assert "value" not in rec["result"]["headline"]
 
-    def test_r04_rc124_husk_is_an_honest_empty_record(self):
-        rec = legacy.recover_round_file(os.path.join(REPO,
-                                                     "BENCH_r04.json"))
+    def test_rc124_husk_is_an_honest_empty_record(self, tmp_path):
+        """A run killed at its time limit printed no line at all."""
+        rec = legacy.recover_round_file(write_artifact(
+            tmp_path, "BENCH_r04.json", rc=124,
+            tail="WARNING: Platform initialized\nTerminated\n"))
         assert rec["rc"] == 124
         assert rec["result"]["entries"] == {}
         assert any("rc=124" in n for n in rec["notes"])
         assert schema.validate_record(rec) == []
 
-    def test_r05_recovers_best_row_and_trailing_entries(self):
-        rec = legacy.recover_round_file(os.path.join(REPO,
-                                                     "BENCH_r05.json"))
-        best = rec["result"]["headline"]["best_row"]
-        assert best["name"] == "zero3_llama_750m_bf16"
-        assert best["mfu"] == 0.543
+    def test_truncated_tail_recovers_trailing_entries_and_fields(
+            self, tmp_path):
+        """A tail cut inside an entry: the entry's internals are dropped,
+        the whole entries and the trailing top-level fields after it
+        come back."""
+        tail = ('rg_mb": 996, "host_arg_mb": 498, "temp_mb": 2510}, '
+                '"master_moved_to_host": true}, '
+                '"autotune_smoke": {"picked_micro_batch": 32}}, '
+                '"entry_elapsed_s": {"autotune_smoke": 59.6}, '
+                '"total_runtime_s": 693.6}')
+        rec = legacy.recover_round_file(write_artifact(
+            tmp_path, "BENCH_r05.json", tail=tail))
+        assert "host_arg_mb" not in rec["result"]["headline"]
         smoke = rec["result"]["entries"]["autotune_smoke"]
         assert smoke["metrics"]["picked_micro_batch"] == 32
         assert smoke["elapsed_s"] == 59.6     # from entry_elapsed_s
@@ -321,11 +344,7 @@ class TestLegacyRecovery:
         """A future damaged BENCH_rNN.json must not abort the whole
         recover run — the parser's contract is 'never raises on the
         garbage it exists to read'."""
-        good = str(tmp_path / "BENCH_r01.json")
-        with open(os.path.join(REPO, "BENCH_r01.json")) as f:
-            body = f.read()
-        with open(good, "w") as f:
-            f.write(body)
+        write_artifact(tmp_path, "BENCH_r01.json", parsed=V1_PARSED)
         corrupt = str(tmp_path / "BENCH_r06.json")
         with open(corrupt, "w") as f:
             f.write('{"rc": 0, "tail": "... \\"value\\": 123.0, '
@@ -383,21 +402,6 @@ class TestHistory:
             history_mod.record_from_result(make_result(2.0), "r7"), path)
         rec = history_mod.record_for_round("r7", path=path)
         assert rec["result"]["value"] == 2.0
-
-    def test_committed_trajectory_is_populated(self):
-        """The recovered r01–r05 records are a checked-in artifact: the
-        trajectory chart starts populated, not empty."""
-        path = os.path.join(REPO, "bench_history", "history.jsonl")
-        records, notes = history_mod.load_history(path)
-        assert notes == []
-        by_round = {r["round"]: r for r in records}
-        assert {"r01", "r02", "r03", "r04", "r05"} <= set(by_round)
-        for rec in records:
-            assert schema.validate_record(rec) == []
-        assert by_round["r02"]["result"]["headline"]["value"] == 89382.6
-        assert len(by_round["r03"]["result"]["entries"]) >= 8
-        assert by_round["r05"]["result"]["headline"]["best_row"]["mfu"] \
-            == 0.543
 
 
 # --------------------------------------------------------------------- #

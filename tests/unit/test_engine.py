@@ -260,7 +260,7 @@ class TestActivationCheckpointingConfig:
 
 def test_grad_accum_dtype_bf16():
     """data_types.grad_accum_dtype switches the GAS accumulator (at multi-B
-    params the fp32 grad buffer is the HBM ceiling — see PROFILE.md r5)."""
+    params the fp32 grad buffer is the HBM ceiling)."""
     import itertools
 
     import deepspeed_tpu as dst

@@ -439,7 +439,6 @@ def test_fastgen_throughput_vs_slot_engine():
     # compiled-program count — the slot engine compiles one prefill program
     # per prompt-length bucket (6 here, growing with diversity) plus its
     # step; the paged engine runs a fixed tier grid whatever arrives.
-    # Standalone wall-clock measures 2.2-2.3x cold (see PROFILE.md), but
     # XLA compile timing under pytest load is too noisy for a hard 2x
     # wall-clock gate, so the count carries the 2x claim and wall clock
     # gets a 1.5x floor.

@@ -91,3 +91,88 @@ def test_model_spec_flash_option():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 256)
     loss = spec.loss_fn(params, tokens)
     assert np.isfinite(float(loss))
+
+
+# --------------------------------------------------------------------- #
+# multi-device meshes: Mosaic refuses a pallas_call that GSPMD would have
+# to partition ("Mosaic kernels cannot be automatically partitioned" — the
+# first four-chip run of this kernel), and accepts it only inside a
+# shard_map manual over EVERY mesh axis. Interpret mode cannot reproduce
+# the refusal, so the rule is checked on the traced program.
+# --------------------------------------------------------------------- #
+def _unmapped_pallas_calls(jaxpr, manual=frozenset(), all_axes=None):
+    """pallas_call equations NOT under full-manual shard_maps."""
+    bad = []
+    for eqn in jaxpr.eqns:
+        inner_manual, inner_axes = manual, all_axes
+        if eqn.primitive.name == "shard_map":
+            inner_manual = manual | eqn.params["manual_axes"]
+            inner_axes = frozenset(eqn.params["mesh"].axis_names)
+        if eqn.primitive.name == "pallas_call" and (
+                all_axes is None or manual != all_axes):
+            bad.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            bad += _unmapped_pallas_calls(sub, inner_manual, inner_axes)
+    return bad
+
+
+@pytest.fixture
+def data_mesh():
+    from deepspeed_tpu.comm import mesh as M
+
+    M.reset_mesh()
+    yield M.initialize_mesh().mesh       # data = all 8 virtual devices
+    M.reset_mesh()
+
+
+@pytest.mark.parametrize("B", [8, 2])   # 8 shards over data; 2 replicates
+def test_kernel_runs_per_shard_under_a_mesh(data_mesh, B):
+    q, k, v = _rand_qkv(jax.random.PRNGKey(5), B, 64, 2, 16, K=1)
+
+    def grads(attn):
+        return jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v, causal=True) ** 2),
+            argnums=(0, 1, 2)))
+
+    traced = grads(flash_attention).trace(q, k, v)
+    assert "pallas_call" in str(traced.jaxpr)
+    assert _unmapped_pallas_calls(traced.jaxpr.jaxpr) == []
+    got = traced.lower().compile()(q, k, v)
+    for g, w in zip(got, grads(dot_product_attention)(q, k, v)):
+        np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4)
+
+
+def test_nested_under_a_partly_manual_step(data_mesh):
+    """Inside a shard_map that is manual over the data axis only (the
+    compressed-wire step builders), the kernel still ends up with every
+    axis manual."""
+    from jax.sharding import PartitionSpec as P
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(6), 8, 64, 2, 16)
+    spec = P("data")
+    fn = jax.jit(jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        mesh=data_mesh, in_specs=(spec,) * 3, out_specs=spec,
+        axis_names={"data"}, check_vma=False))
+    assert _unmapped_pallas_calls(jax.make_jaxpr(fn)(q, k, v).jaxpr) == []
+    np.testing.assert_allclose(
+        fn(q, k, v), dot_product_attention(q, k, v, causal=True),
+        atol=2e-5, rtol=2e-5)
+
+
+def test_zero3_train_step_maps_every_kernel(data_mesh):
+    import deepspeed_tpu as dst
+
+    engine, *_ = dst.initialize(
+        model=dst.causal_lm_spec("tiny_llama", attention="flash",
+                                 remat="full"),
+        config={"train_micro_batch_size_per_gpu": 1,
+                "optimizer": {"type": "adam", "params": {"lr": 1e-3}},
+                "zero_optimization": {"stage": 3},
+                "bf16": {"enabled": True}, "steps_per_print": 10 ** 9})
+    batch = {"tokens": jnp.zeros((1, 8, 64), jnp.int32)}
+    with engine.mesh:
+        traced = jax.make_jaxpr(engine._select_step_builder(1))(
+            engine.state, batch)
+    assert "pallas_call" in str(traced)
+    assert _unmapped_pallas_calls(traced.jaxpr) == []

@@ -73,18 +73,15 @@ def _telemetry_section() -> dict:
     init reconfigures the PROCESS-WIDE tracer from its config section
     (last-engine-wins), so any entry whose config omitted these keys
     would silently disarm the --entry wrapper's tracer mid-entry and
-    drop the row's trace_phases; measured-MFU stays opt-in because it
-    prices a cost-analysis compile a timeout-bounded entry can't afford."""
+    drop the row's trace_phases."""
     return {
-        "measure_mfu": os.environ.get("BENCH_TELEMETRY_MFU", "0") != "0",
         "tracing": os.environ.get("BENCH_TRACING", "1") != "0",
         "trace_buffer_events": 8192,
     }
 
 
 def chip_peak_tflops(device):
-    """Peak bf16 TFLOP/s — ONE table shared with the telemetry train_mfu
-    gauge (deepspeed_tpu/utils/chip_specs.py). None on a CPU host; a TPU
+    """Peak bf16 TFLOP/s (deepspeed_tpu/utils/chip_specs.py). None on a CPU host; a TPU
     missing from the table raises."""
     from deepspeed_tpu.utils.chip_specs import chip_peak_tflops as _peak
 

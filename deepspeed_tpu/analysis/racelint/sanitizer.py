@@ -196,11 +196,19 @@ class InstrumentedLock:
         self._inner.release()
 
     def __enter__(self) -> "InstrumentedLock":
-        self.acquire()
+        # unarmed (the production state) a ``with`` is the inner lock and
+        # one global read: the registry takes this on every span and count
+        if _env_checked and not _armed:
+            self._inner.acquire()
+        else:
+            self.acquire()
         return self
 
     def __exit__(self, *exc) -> None:
-        self.release()
+        if _env_checked and not _armed:
+            self._inner.release()
+        else:
+            self.release()
 
     def locked(self) -> bool:
         inner_locked = getattr(self._inner, "locked", None)

@@ -135,6 +135,7 @@ class FastGenEngine:
         self.seqs: Dict[int, _Seq] = {}
         self._admit_order: List[int] = []
         self._decode_rr = 0
+        self._ticks_run = 0     # step() ticks dispatched, for span attributes
         # HOST-side key stream: deriving per-call subkeys with an eager
         # jax.random.split is a whole device dispatch for an 8-byte op. Any
         # uint32[2] is a valid raw threefry key, so a host PCG stream
@@ -244,7 +245,8 @@ class FastGenEngine:
         self._tok_lat_win_intervals = 6
         self._tm_ticks = telemetry.counter(
             "fastgen_ticks_total",
-            "engine ticks by kind (mixed SplitFuse / fused decode / "
+            "engine ticks by kind (mixed: the tick held prompt rows / "
+            "decode: it held none, from step() or a fused window / "
             "planned) and block-table width tier")
         self._tm_gen_tok = telemetry.counter(
             "fastgen_generated_tokens_total", "tokens sampled and kept")
@@ -382,8 +384,10 @@ class FastGenEngine:
             logits, pool = PG.forward_paged(
                 params, tokens, positions, tables, pool, cfg,
                 attention_fn=attn)
-            sampled = sample_logits(logits, rng, self.temperature,
-                                    self.top_k, self.top_p).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                sampled = sample_logits(
+                    logits, rng, self.temperature, self.top_k,
+                    self.top_p).astype(jnp.int32)
             return sampled, pool
 
         return jax.jit(tick, donate_argnums=(1,))
@@ -966,38 +970,53 @@ class FastGenEngine:
         cold = key not in self._ticks
         if cold:
             self._ticks[key] = self._build_tick()
-        sub = self._next_key()
-        t0 = time.perf_counter()
-        with telemetry.span("decode_tick"):
-            sampled, self.pool = self._ticks[key](
-                self.params, self.pool, self._dev(tokens),
-                self._dev(positions), self._dev(tables[:, :mb]), sub)
-            sampled = np.asarray(jax.device_get(sampled))
         n_decode_rows = sum(1 for _, _, is_d in heads if is_d)
-        if not cold and n_decode_rows:
-            # per-token rate from the dynamic tick too (servers driving
-            # step() alone must still feed est_token_seconds for retry-
-            # after/deadline-slack estimates). Tick wall time over decode
-            # rows slightly OVERcounts when prefill shares the tick —
-            # conservative in the right direction for those hints. Cold
-            # keys fold the XLA compile into wall time and are skipped,
-            # same policy as decode_steps.
-            self._observe_tok_lat(
-                (time.perf_counter() - t0) / n_decode_rows,
-                n=n_decode_rows)
-        self._tm_ticks.inc(kind="mixed", mb_tier=self._mb_tier_name(mb))
-        self._tm_prefill_tok.inc(row - n_decode_rows)
-        self._tm_occup.set(row / Tn, phase="mixed")
-        self._tm_sched_gauges()
+        # a tick that holds no prompt row is a decode tick, whatever
+        # entry point ran it
+        kind = "decode" if n_decode_rows == row else "mixed"
+        tier = self._mb_tier_name(mb)
+        self._ticks_run += 1
+        t0 = time.perf_counter()
+        with telemetry.span("decode_tick", attrs={
+                "tick": self._ticks_run, "kind": kind, "rows": row,
+                "decode_rows": n_decode_rows,
+                "prefill_tokens": row - n_decode_rows, "bucket": Tn,
+                "mb_tier": tier}):
+            # enqueue: the host-to-device copies and the jitted call,
+            # until it returns (the device may still be running)
+            with telemetry.span("tick_dispatch"):
+                sampled, self.pool = self._ticks[key](
+                    self.params, self.pool, self._dev(tokens),
+                    self._dev(positions), self._dev(tables[:, :mb]),
+                    self._next_key())
+            # the wait for the device, then the copy back
+            with telemetry.span("tick_readback"):
+                sampled = np.asarray(jax.device_get(sampled))
+        with telemetry.span("tick_commit"):
+            if not cold and n_decode_rows:
+                # per-token rate from the dynamic tick too (servers
+                # driving step() alone must still feed est_token_seconds
+                # for retry-after/deadline-slack estimates). Tick wall
+                # time over decode rows slightly OVERcounts when prefill
+                # shares the tick — conservative in the right direction
+                # for those hints. Cold keys fold the XLA compile into
+                # wall time and are skipped, same policy as decode_steps.
+                self._observe_tok_lat(
+                    (time.perf_counter() - t0) / n_decode_rows,
+                    n=n_decode_rows)
+            self._tm_ticks.inc(kind=kind, mb_tier=tier)
+            self._tm_prefill_tok.inc(row - n_decode_rows)
+            self._tm_occup.set(row / Tn, phase="mixed")
+            self._tm_sched_gauges()
 
-        out: Dict[int, int] = {}
-        for r, seq, is_decode in heads:
-            tok = int(sampled[r])
-            if is_decode:
-                seq.pos += 1   # the decode input token entered the cache
-            seq.last_tok = tok
-            self._note_token(seq, tok)
-            out[seq.uid] = tok
+            out: Dict[int, int] = {}
+            for r, seq, is_decode in heads:
+                tok = int(sampled[r])
+                if is_decode:
+                    seq.pos += 1   # the decode input token entered the cache
+                seq.last_tok = tok
+                self._note_token(seq, tok)
+                out[seq.uid] = tok
         return out
 
     def _note_token(self, seq: _Seq, tok: int,

@@ -191,20 +191,23 @@ def causal_lm_spec(cfg: Union[str, T.TransformerConfig],
             activation_constraint=activation_constraint,
             pld_keep=pld_keep, random_ltd_idx=ltd_idx,
             param_sync=param_sync_fn)
-        if loss_tiles > 1:
-            from deepspeed_tpu.sequence.tiled import tiled_lm_loss
+        with jax.named_scope("lm_head_loss"):
+            if loss_tiles > 1:
+                from deepspeed_tpu.sequence.tiled import tiled_lm_loss
 
-            loss = tiled_lm_loss(hidden, head, tokens, _mask_of(batch),
-                                 num_tiles=loss_tiles)
-        elif loss_impl == "fused":
-            # default training loss: bf16 logits + fp32 softmax stats with
-            # a bandwidth-tuned custom VJP (torch-autocast CE semantics —
-            # the exact-fp32-logits path stays under loss_impl="exact";
-            # inference/apply_fn logits are always exact fp32)
-            loss = T.fused_lm_loss(hidden, head, tokens, _mask_of(batch))
-        else:
-            logits = T.head_matmul(hidden, head.astype(hidden.dtype))
-            loss = T.causal_lm_loss(logits, tokens, _mask_of(batch))
+                loss = tiled_lm_loss(hidden, head, tokens, _mask_of(batch),
+                                     num_tiles=loss_tiles)
+            elif loss_impl == "fused":
+                # default training loss: bf16 logits + fp32 softmax stats
+                # with a bandwidth-tuned custom VJP (torch-autocast CE
+                # semantics — the exact-fp32-logits path stays under
+                # loss_impl="exact"; inference/apply_fn logits are always
+                # exact fp32)
+                loss = T.fused_lm_loss(hidden, head, tokens,
+                                       _mask_of(batch))
+            else:
+                logits = T.head_matmul(hidden, head.astype(hidden.dtype))
+                loss = T.causal_lm_loss(logits, tokens, _mask_of(batch))
         if cfg.n_experts > 0:
             loss = loss + cfg.moe_aux_coef * aux
         return loss

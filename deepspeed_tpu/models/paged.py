@@ -204,11 +204,12 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     Tn = tokens.shape[0]
     bs = pool["k"].shape[2]
 
-    x = params["tok_emb"].astype(dt)[tokens]             # [T, H]
-    if cfg.pos_emb == "learned":
-        x = x + params["pos_emb"].astype(dt)[positions]
-    if cfg.emb_norm:
-        x = T._norm(x, params["emb_norm"], cfg.norm, cfg.norm_eps)
+    with jax.named_scope("embed"):
+        x = params["tok_emb"].astype(dt)[tokens]             # [T, H]
+        if cfg.pos_emb == "learned":
+            x = x + params["pos_emb"].astype(dt)[positions]
+        if cfg.emb_norm:
+            x = T._norm(x, params["emb_norm"], cfg.norm, cfg.norm_eps)
 
     max_pos = pool["k"].shape[1] * bs
     cos_t = sin_t = None
@@ -235,76 +236,79 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
 
         x, pk, pv, li = carry
         lp = dequant_params(lp, dt)   # weight-only quant: per-layer dequant
-        h = T._norm(x, lp["ln1"], cfg.norm, cfg.norm_eps)
+        with jax.named_scope("attn"):
+            h = T._norm(x, lp["ln1"], cfg.norm, cfg.norm_eps)
 
-        def proj(name, shape):
-            w = lp[f"w{name}"].astype(dt)
-            out = h @ w
-            if (cfg.attn_bias_enabled if name in ("q", "k", "v")
-                    else cfg.use_bias):
-                out = out + lp[f"b{name}"].astype(dt)
-            return out.reshape(shape)
+            def proj(name, shape):
+                w = lp[f"w{name}"].astype(dt)
+                out = h @ w
+                if (cfg.attn_bias_enabled if name in ("q", "k", "v")
+                        else cfg.use_bias):
+                    out = out + lp[f"b{name}"].astype(dt)
+                return out.reshape(shape)
 
-        q = proj("q", (Tn, cfg.num_heads, cfg.head_dim))
-        k = proj("k", (Tn, cfg.kv_heads, cfg.head_dim))
-        v = proj("v", (Tn, cfg.kv_heads, cfg.head_dim))
-        if cfg.qk_norm:
-            q = T._head_rmsnorm(q, lp["q_norm"], cfg.norm_eps)
-            k = T._head_rmsnorm(k, lp["k_norm"], cfg.norm_eps)
-        if cfg.pos_emb == "rope":
-            q = T.apply_rope_at(q[None], cos_t, sin_t, positions[None])[0]
-            k = T.apply_rope_at(k[None], cos_t, sin_t, positions[None])[0]
-        # blocked KV write (reference ragged_ops KV-copy kernels): token t →
-        # pool[l*NB + block_idx[t], offsets[t]]. Pad tokens hit this layer's
-        # trash block (block 0 of its range — never allocated).
-        base = li * NB
-        pk = pk.at[base + block_idx, offsets].set(k.astype(pk.dtype),
-                                                  mode="drop")
-        pv = pv.at[base + block_idx, offsets].set(v.astype(pv.dtype),
-                                                  mode="drop")
+            q = proj("q", (Tn, cfg.num_heads, cfg.head_dim))
+            k = proj("k", (Tn, cfg.kv_heads, cfg.head_dim))
+            v = proj("v", (Tn, cfg.kv_heads, cfg.head_dim))
+            if cfg.qk_norm:
+                q = T._head_rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+                k = T._head_rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+            if cfg.pos_emb == "rope":
+                q = T.apply_rope_at(q[None], cos_t, sin_t, positions[None])[0]
+                k = T.apply_rope_at(k[None], cos_t, sin_t, positions[None])[0]
+            # blocked KV write (reference ragged_ops KV-copy kernels): token t →
+            # pool[l*NB + block_idx[t], offsets[t]]. Pad tokens hit this layer's
+            # trash block (block 0 of its range — never allocated).
+            base = li * NB
+            pk = pk.at[base + block_idx, offsets].set(k.astype(pk.dtype),
+                                                      mode="drop")
+            pv = pv.at[base + block_idx, offsets].set(v.astype(pv.dtype),
+                                                      mode="drop")
 
-        if group_tables is not None:
-            parts = []
-            if n_decode:
-                parts.append(
-                    attention_fn(q[:n_decode], pk, pv,
-                                 tables[:n_decode] + base,
-                                 lengths[:n_decode],
-                                 **({"alibi": alibi} if alibi is not None
-                                    else {})))
-            parts.append(grouped_prefill_attention(
-                q[n_decode:], pk, pv, group_tables + base,
-                lengths[n_decode:], alibi=alibi))
-            attn = jnp.concatenate(parts, axis=0) if n_decode else parts[0]
-        elif alibi is not None:
-            attn = attention_fn(q, pk, pv, tables + base, lengths,
-                                alibi=alibi)                    # [T, N, D]
-        else:
-            attn = attention_fn(q, pk, pv, tables + base, lengths)
-        attn = attn.reshape(Tn, cfg.num_heads * cfg.head_dim)
-        attn_out = attn @ lp["wo"].astype(dt)
-        if cfg.use_bias:
-            attn_out = attn_out + lp["bo"].astype(dt)
-        if cfg.parallel_block:
-            h2 = h if cfg.shared_parallel_norm else \
-                T._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
+            if group_tables is not None:
+                parts = []
+                if n_decode:
+                    parts.append(
+                        attention_fn(q[:n_decode], pk, pv,
+                                     tables[:n_decode] + base,
+                                     lengths[:n_decode],
+                                     **({"alibi": alibi} if alibi is not None
+                                        else {})))
+                parts.append(grouped_prefill_attention(
+                    q[n_decode:], pk, pv, group_tables + base,
+                    lengths[n_decode:], alibi=alibi))
+                attn = jnp.concatenate(parts, axis=0) if n_decode else parts[0]
+            elif alibi is not None:
+                attn = attention_fn(q, pk, pv, tables + base, lengths,
+                                    alibi=alibi)                    # [T, N, D]
+            else:
+                attn = attention_fn(q, pk, pv, tables + base, lengths)
+            attn = attn.reshape(Tn, cfg.num_heads * cfg.head_dim)
+            attn_out = attn @ lp["wo"].astype(dt)
+            if cfg.use_bias:
+                attn_out = attn_out + lp["bo"].astype(dt)
+        with jax.named_scope("mlp"):
+            if cfg.parallel_block:
+                h2 = h if cfg.shared_parallel_norm else \
+                    T._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
+                down, _ = T._ffn(h2, lp, cfg)
+                return (x + attn_out + down, pk, pv, li + 1), None
+            x = x + attn_out
+            h2 = T._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
             down, _ = T._ffn(h2, lp, cfg)
-            return (x + attn_out + down, pk, pv, li + 1), None
-        x = x + attn_out
-        h2 = T._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
-        down, _ = T._ffn(h2, lp, cfg)
-        return (x + down, pk, pv, li + 1), None
+            return (x + down, pk, pv, li + 1), None
 
     carry0 = (x, pool["k"].reshape(flat), pool["v"].reshape(flat),
               jnp.int32(0))
     (x, new_k, new_v, _), _ = lax.scan(body, carry0, params["blocks"])
     new_k = new_k.reshape(pool["k"].shape)
     new_v = new_v.reshape(pool["v"].shape)
-    x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    head = T._lm_head_of(params, cfg)
-    logits = T.head_matmul(x, head.astype(x.dtype))
-    if cfg.lm_head_bias:
-        logits = logits + params["lm_head_b"].astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        head = T._lm_head_of(params, cfg)
+        logits = T.head_matmul(x, head.astype(x.dtype))
+        if cfg.lm_head_bias:
+            logits = logits + params["lm_head_b"].astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 
 

@@ -810,22 +810,30 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         return fake_quant_symmetric(
             h, float(2 ** (cfg.act_quant_bits - 1) - 1))
 
-    h = _aq(_norm(x, lp["ln1"], cfg.norm, cfg.norm_eps))
+    # the scopes a device trace sorts a block's operations by
+    # (``attn`` / ``mlp``, under the engine's ``loss_and_grads``)
+    with jax.named_scope("attn"):
+        h = _aq(_norm(x, lp["ln1"], cfg.norm, cfg.norm_eps))
     if cfg.mla:
-        q, k, v = _mla_qkv(h, lp, cfg,
-                           lambda t: apply_rope(t, cos, sin))
-        if cfg.mla_scale_mult != 1.0:
-            q = q * jnp.asarray(cfg.mla_scale_mult, q.dtype)
-        # flash kernels assume one head dim; MLA's split qk/v dims run on
-        # the XLA reference attention (scale = 1/sqrt(dn+dr) from q's D)
-        attn = dot_product_attention(q, k, v, causal=cfg.causal)
-        attn = attn.reshape(B, S, cfg.num_heads * cfg.v_head_dim)
-        attn = _ckpt_name(attn, "attn_out")
-        attn_out = attn @ lp["wo"].astype(dt)
-        x = x + attn_out
-        h2 = _aq(_norm(x, lp["ln2"], cfg.norm, cfg.norm_eps))
-        down, aux = _ffn(h2, lp, cfg)
-        return x + down, aux
+        with jax.named_scope("attn"):
+            q, k, v = _mla_qkv(h, lp, cfg,
+                               lambda t: apply_rope(t, cos, sin))
+            if cfg.mla_scale_mult != 1.0:
+                q = q * jnp.asarray(cfg.mla_scale_mult, q.dtype)
+            # flash kernels assume one head dim; MLA's split qk/v dims run
+            # on the XLA reference attention (scale = 1/sqrt(dn+dr) from
+            # q's D)
+            attn = dot_product_attention(q, k, v, causal=cfg.causal)
+            attn = attn.reshape(B, S, cfg.num_heads * cfg.v_head_dim)
+            attn = _ckpt_name(attn, "attn_out")
+            attn_out = attn @ lp["wo"].astype(dt)
+            x = x + attn_out
+        with jax.named_scope("mlp"):
+            h2 = _aq(_norm(x, lp["ln2"], cfg.norm, cfg.norm_eps))
+            down, aux = _ffn(h2, lp, cfg)
+            return x + down, aux
+
+    @jax.named_scope("attn")
     def _attn_from_norm(h):
         if cfg.fuse_qkv:
             qdim = cfg.num_heads * cfg.head_dim
@@ -876,13 +884,15 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         attn_out = _attn_from_norm(h)
 
     if cfg.parallel_block:
-        h2 = h if cfg.shared_parallel_norm else \
-            _aq(_norm(x, lp["ln2"], cfg.norm, cfg.norm_eps))
-        down, aux = _ffn(h2, lp, cfg)
-        return x + attn_out + down, aux
+        with jax.named_scope("mlp"):
+            h2 = h if cfg.shared_parallel_norm else \
+                _aq(_norm(x, lp["ln2"], cfg.norm, cfg.norm_eps))
+            down, aux = _ffn(h2, lp, cfg)
+            return x + attn_out + down, aux
 
     x = x + attn_out
 
+    @jax.named_scope("mlp")
     def _ffn_delta(xr):
         h2 = _aq(_norm(xr, lp["ln2"], cfg.norm, cfg.norm_eps))
         return _ffn(h2, lp, cfg)
@@ -974,12 +984,13 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     B, S = tokens.shape
     L = cfg.num_layers
 
-    x = params["tok_emb"].astype(dt)[tokens]
-    if cfg.pos_emb == "learned":
-        x = x + params["pos_emb"].astype(dt)[:S][None]
-    if cfg.emb_norm:
-        x = _norm(x, params["emb_norm"], cfg.norm, cfg.norm_eps)
-    x = constrain(x)
+    with jax.named_scope("embed"):
+        x = params["tok_emb"].astype(dt)[tokens]
+        if cfg.pos_emb == "learned":
+            x = x + params["pos_emb"].astype(dt)[:S][None]
+        if cfg.emb_norm:
+            x = _norm(x, params["emb_norm"], cfg.norm, cfg.norm_eps)
+        x = constrain(x)
 
     cos = sin = None
     if cfg.pos_emb == "rope":

@@ -188,6 +188,7 @@ def _fwd(q, k, v, layout, *, scale, causal, seq_len, block_q, block_kv,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="block_sparse_fwd",
     )(layout, q, k, v)
 
 
@@ -303,6 +304,7 @@ def _bwd(scale, causal, seq_len, block_q, block_kv, interpret,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="block_sparse_dq",
     )(layout, q, k, v, do, lse, delta)
 
     # dkv grid: kv outer, q inner — index maps swap (i, j) roles
@@ -321,6 +323,7 @@ def _bwd(scale, causal, seq_len, block_q, block_kv, interpret,
         scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
                         pltpu.VMEM((block_kv, d), jnp.float32)],
         interpret=interpret,
+        name="block_sparse_dkv",
     )(layout, q, k, v, do, lse, delta)
     return dq, dk, dv, None
 

@@ -125,6 +125,7 @@ def _evo_flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, bias: jax.Array,
         ],
         compiler_params=_compiler_params(),
         interpret=_use_interpret(),
+        name="evoformer_attention",
     )(qh, kh, vh, bh)
     return out[:, :S].reshape(G, N, S, D).transpose(0, 2, 1, 3)
 

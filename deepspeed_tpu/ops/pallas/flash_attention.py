@@ -149,6 +149,7 @@ def _fwd(q, k, v, *, scale, causal, kv_len, rep, block_q, block_kv, interpret):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -302,6 +303,7 @@ def _bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid batch dim is the KV batch; the inner dim flattens
@@ -340,6 +342,7 @@ def _bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -83,6 +83,7 @@ def fused_adam_flat(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct(p.shape, jnp.float32)] * 3,
         interpret=_use_interpret(),
+        name="fused_adam",
     )(p, g, m, v, scal)
     if pad:
         new_p, new_m, new_v = (x[:N] for x in (new_p, new_m, new_v))

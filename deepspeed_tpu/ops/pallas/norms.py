@@ -43,7 +43,7 @@ def _ln_kernel(x_ref, s_ref, b_ref, o_ref, *, eps: float):
                   + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _run_rows(kernel, x2d, *params):
+def _run_rows(name, kernel, x2d, *params):
     R, H = x2d.shape
     pad = (-R) % _ROW_BLOCK
     if pad:
@@ -56,6 +56,7 @@ def _run_rows(kernel, x2d, *params):
         out_specs=pl.BlockSpec((_ROW_BLOCK, H), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=_use_interpret(),
+        name=name,
     )(x2d, *params)
     return out[:R] if pad else out
 
@@ -65,7 +66,7 @@ def _run_rows(kernel, x2d, *params):
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     """x [..., H] * rsqrt(mean(x^2)) * scale, fp32 stats."""
     shape = x.shape
-    out = _run_rows(functools.partial(_rms_kernel, eps=eps),
+    out = _run_rows("rms_norm", functools.partial(_rms_kernel, eps=eps),
                     x.reshape(-1, shape[-1]), scale)
     return out.reshape(shape)
 
@@ -96,7 +97,7 @@ rms_norm.defvjp(_rms_fwd, _rms_bwd)
 def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
                eps: float = 1e-5) -> jax.Array:
     shape = x.shape
-    out = _run_rows(functools.partial(_ln_kernel, eps=eps),
+    out = _run_rows("layer_norm", functools.partial(_ln_kernel, eps=eps),
                     x.reshape(-1, shape[-1]), scale, bias)
     return out.reshape(shape)
 

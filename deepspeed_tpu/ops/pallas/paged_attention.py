@@ -128,4 +128,5 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
         out_shape=jax.ShapeDtypeStruct((Tn, N, D), q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
+        name="paged_attention",
     )(tables, lengths, q, kpool, vpool)

@@ -69,6 +69,7 @@ def quantize_int8_blocks(x: jax.Array, block: int = 2048
         out_shape=[jax.ShapeDtypeStruct((rows, block), jnp.int8),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
         interpret=_use_interpret(),
+        name="quantize_int8_blocks",
     )(x2)
     return q.reshape(-1), s[:, 0]
 
@@ -110,5 +111,6 @@ def dequant_reduce(q: jax.Array, scales: jax.Array, block: int = 2048,
         out_specs=pl.BlockSpec((tile, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, block), jnp.float32),
         interpret=_use_interpret(),
+        name="dequant_reduce",
     )(q3, s3)
     return out.reshape(-1)

@@ -65,7 +65,7 @@ def normalize_costs(raw: Any) -> Dict[str, float]:
 def cost_analysis_available(costs: Dict[str, float]) -> bool:
     """True when the normalized costs actually carry a FLOP count. Some
     jax/jaxlib builds return an empty dict or a list without 'flops' —
-    reporting those as 0 FLOPs silently poisons every measured-MFU gauge
+    reporting those as 0 FLOPs silently poisons every utilization figure
     downstream, so callers must branch on this instead."""
     return bool(costs) and "flops" in costs
 

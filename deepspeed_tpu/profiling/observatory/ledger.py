@@ -75,6 +75,10 @@ _WIRE_PARAM_MARKS = ("qwz_wire", "zpp_gather")
 #: INSIDE this scope, and it must price as the update phase, not the
 #: forward's.
 _UPDATE_MARK = "zero_param_update"
+#: the step function's own phase scopes (``runtime/engine.py``): every
+#: op of the step sits under one of them, so they say nothing about
+#: which subsystem issued a collective and must not trip the "grad" mark
+_STEP_SCOPES = ("loss_and_grads", "grad_reduce")
 _INT8_DTYPES = ("s8", "u8")
 
 
@@ -83,6 +87,8 @@ def attribute_subsystem(op: CollectiveOp, zero_stage: int = 0) -> str:
     rule table). Pure function of the op + ZeRO stage so fixtures test it
     without an engine."""
     path = f"{op.op_name or ''} {op.source_file or ''}".lower()
+    for scope in _STEP_SCOPES:
+        path = path.replace(scope, "")
     # update phase first — outermost scope: the deferred publish nests
     # the qwZ/zpp gather kernels inside zero_param_update, and those
     # collectives bill to the step phase (the fence-chained post-update
@@ -307,8 +313,7 @@ def build_ledger(hlo_text: str, program: str = "program",
 # ------------------------------------------------------------------ #
 def _lower_compiled(jitted, *abstract_args):
     """lower → compile → (hlo_text, costs, memory_stats). The compile is
-    the price of ground truth (same cost the measured-MFU gauge already
-    pays); callers cache the resulting ledger."""
+    the price of ground truth; callers cache the resulting ledger."""
     from deepspeed_tpu.profiling.flops_profiler import normalize_costs
 
     lowered = jitted.lower(*abstract_args)
@@ -419,12 +424,6 @@ def ledger_for_engine(engine, fold: bool = True,
         # the cache outlives this call by the engine's lifetime: keep
         # only the lines hlolint's text rules scan, not the full dump
         ledger.hlo_text = _trim_lint_text(hlo_text)
-        if ledger.cost_flops is not None and \
-                getattr(engine, "_tm_flops_cache", False) is None:
-            # seed the measured-MFU pricing cache with this lowering's
-            # flops so the scrape-time gauge doesn't pay a SECOND compile
-            # of the same program (bench ledgers before it snapshots)
-            engine._tm_flops_cache = ledger.cost_flops
         cached = cache[(gas, mb, seq)] = (ledger, memory_stats_dict(mem))
     if fold:
         cached[0].fold_into_telemetry(link_gbps)

@@ -268,9 +268,6 @@ class TelemetryConfig:
     http_port: int = -1
     stall_deadline_s: float = 0.0
     monitor_bridge: bool = True
-    # measured-MFU gauge prices ONE cost-analysis compile of the train step
-    # at first scrape — disable for huge models behind a live endpoint
-    measure_mfu: bool = True
     tracing: bool = False
     trace_buffer_events: int = 4096
     trace_sample_rate: float = 1.0

@@ -1160,23 +1160,17 @@ class DeepSpeedTPUEngine:
 
         Hot-path cost is a few dict/float ops per optimizer step (host
         side, no device fences). Everything priced — device_get of the
-        last step's metrics, the one-off FLOPS cost analysis behind
-        measured MFU — runs in a registry COLLECTOR, i.e. only when
+        last step's metrics — runs in a registry COLLECTOR, i.e. only when
         something scrapes ``telemetry.snapshot()`` / the ``/metrics``
         endpoint or the monitor bridge publishes."""
         tcfg = self.config.telemetry
         self._tm = None
         self._watchdog = None   # racelint: single-thread — every writer (telemetry setup/teardown and the SIGTERM handler, which CPython delivers between MAIN-thread bytecodes) runs on the main thread; the watchdog thread only calls beat()/check() through its own reference
         self._tm_bridge = None
-        self._tm_tokens_per_step = 0
         # device-side overflow/non-finite skip counter, delta-folded into
         # the monotone train_skipped_steps_total (set before the enabled
         # gate: the guardian folds through this path too)
         self._tm_skips_seen = 0
-        self._tm_fenced_best_s: Optional[float] = None
-        self._tm_flops_cache: Optional[float] = None
-        self._tm_flops_lock = make_lock("engine._tm_flops_lock")
-        self._tm_owner_thread = threading.get_ident()
         from deepspeed_tpu import telemetry
 
         # the registry gate is process-wide (last engine's config wins, as
@@ -1205,18 +1199,8 @@ class DeepSpeedTPUEngine:
             "(global batch, all chips)")
         self._tm_step_hist = telemetry.histogram(
             "train_step_seconds", "host wall time around each step "
-            "dispatch (async backends may record enqueue-only samples; "
-            "throughput/MFU gauges use fenced windows instead)")
-
-        def _on_fenced_window(duration: float, steps: int) -> None:
-            # fires inside ThroughputTimer._close_window — training thread
-            # only, AFTER a device fence, so per-step time is real
-            per = duration / steps
-            if self._tm_fenced_best_s is None \
-                    or per < self._tm_fenced_best_s:
-                self._tm_fenced_best_s = per
-
-        self.tput_timer.window_hook = _on_fenced_window
+            "dispatch (async backends may record enqueue-only samples: "
+            "for a rate take the increase of train_tokens_total)")
         self._tm_heartbeat = telemetry.gauge(
             "train_heartbeat_timestamp_seconds",
             "unix time the last optimizer step completed")
@@ -1303,70 +1287,15 @@ class DeepSpeedTPUEngine:
         # CPU backend etc.: no meaningful MFU referent → None
         return peak * 1e12 if peak else None
 
-    def _measured_flops_per_step(self) -> float:
-        """One-off XLA cost analysis of the train step (what the flops
-        profiler reports; PER-DEVICE flops of the SPMD executable); cached
-        under a lock so concurrent scrapes price at most one compile.
-        Disable via ``telemetry.measure_mfu: false`` when the scrape-time
-        compile is unwanted (e.g. a huge model behind a live endpoint)."""
-        with self._tm_flops_lock:
-            if self._tm_flops_cache is None:
-                if not self.config.telemetry.measure_mfu:
-                    self._tm_flops_cache = 0.0
-                else:
-                    try:
-                        from deepspeed_tpu.profiling.flops_profiler import (
-                            FlopsProfiler,
-                        )
-
-                        prof = FlopsProfiler(self)
-                        self._tm_flops_cache = prof.profile_train_step()
-                        if prof.cost_analysis_unavailable:
-                            # this jax build's cost_analysis() yields no
-                            # usable costs: the cached 0.0 means "unknown"
-                            # — say so once instead of silently leaving
-                            # train_mfu/train_model_flops_per_sec unset
-                            logger.warning(
-                                "telemetry MFU pricing: XLA cost analysis "
-                                "unavailable on this jax build — "
-                                "train_mfu/train_model_flops_per_sec stay "
-                                "unset (not 0)")
-                            from deepspeed_tpu import telemetry
-
-                            telemetry.counter(
-                                "telemetry_collector_errors_total",
-                                "collector callbacks that raised during a "
-                                "scrape").inc(
-                                    error="cost_analysis_unavailable")
-                    except Exception as e:
-                        # cache the failure (retrying an expensive broken
-                        # compile every scrape would be worse) but say so —
-                        # a silent 0.0 makes the missing MFU gauge
-                        # undiagnosable
-                        self._tm_flops_cache = 0.0
-                        logger.warning(
-                            "telemetry MFU pricing failed — train_mfu/"
-                            f"train_model_flops_per_sec stay unset: {e}")
-                        from deepspeed_tpu import telemetry
-
-                        telemetry.counter(
-                            "telemetry_collector_errors_total",
-                            "collector callbacks that raised during a "
-                            "scrape").inc(error="mfu_pricing")
-            return self._tm_flops_cache
-
     def _collect_telemetry(self) -> None:
         """Scrape-time collector: lazily-priced gauges (loss/grad-norm from
-        the device metrics of the last step, tokens/s from the step-latency
-        histogram, measured MFU from the FLOPS profiler).
+        the device metrics of the last step, the skip counter).
 
         May run on the /metrics HTTP thread concurrent with training, so it
-        avoids mutating engine state: the step histogram (registry-locked)
-        gives steps/sec without touching ThroughputTimer's unsynchronized
-        window state or fencing the device mid-step. The one exception is
-        the FIRST MFU pricing, which compiles a cost-analysis copy of the
-        step (lock-guarded, never stored on the engine; opt out with
-        ``telemetry.measure_mfu: false``)."""
+        avoids mutating engine state and never fences the device mid-step
+        from another thread. A rate is the scraper's to take
+        (``train_tokens_total`` over time); utilization is the benchmark's
+        (``benchmarks/``: model FLOPs from shapes, no recompute)."""
         from deepspeed_tpu import telemetry
 
         if self._last_metrics_dev:
@@ -1393,39 +1322,6 @@ class DeepSpeedTPUEngine:
                     skips = None
                 if skips is not None:
                     self._fold_skips_locked(skips, resync=True)
-        expensive = getattr(self._tm, "collecting_expensive", True)
-        if expensive and threading.get_ident() == self._tm_owner_thread:
-            # only the engine's own thread may close the fenced throughput
-            # window (it fences the device and mutates the timer's
-            # unsynchronized window state); HTTP-thread scrapes reuse the
-            # last fenced sample
-            self.tput_timer.avg_samples_per_sec()
-        # best FENCED per-step wall (bench best-window methodology): the
-        # un-fenced dispatch walls in the histogram can be enqueue-only
-        # under async dispatch, and an all-time mean would fold warmup/
-        # compile into the rate
-        steps_per_sec = (1.0 / self._tm_fenced_best_s
-                         if self._tm_fenced_best_s else 0.0)
-        if steps_per_sec > 0 and self._tm_tokens_per_step:
-            telemetry.gauge(
-                "train_tokens_per_sec", "global token throughput from the "
-                "best fenced throughput window").set(
-                steps_per_sec * self._tm_tokens_per_step)
-        if steps_per_sec > 0 and expensive:
-            flops = self._measured_flops_per_step()
-            if flops:
-                # cost analysis reports the per-device SPMD executable's
-                # flops, so rate/peak are already per-chip — no device_count
-                # factor (the same per-chip accounting bench.py's mfu uses)
-                telemetry.gauge(
-                    "train_model_flops_per_sec",
-                    "measured per-device FLOPS rate (XLA cost analysis x "
-                    "step rate)").set(flops * steps_per_sec)
-                peak = self._chip_peak_flops()
-                if peak:
-                    telemetry.gauge(
-                        "train_mfu", "model FLOPS utilization vs chip bf16 "
-                        "peak").set(flops * steps_per_sec / peak)
 
     def collective_ledger(self, fold: bool = True,
                           seq_len: Optional[int] = None):
@@ -1745,6 +1641,7 @@ class DeepSpeedTPUEngine:
         param_sh = self.policy.to_shardings(self.param_spec)
         return jax.tree.map(self._compute_param_leaf, master, param_sh)
 
+    @jax.named_scope("grad_reduce")
     def _constrain_grads(self, grads: PyTree) -> PyTree:
         grad_sh = self.policy.to_shardings(self.grad_spec)
         if getattr(self, "_overlap", None) is None \
@@ -1802,7 +1699,8 @@ class DeepSpeedTPUEngine:
         # GPipe reverse wavefront
         fn = getattr(self.model_spec, "loss_and_grads_fn", None)
         if fn is not None:
-            out = fn(self._compute_params(master), batch, scale)
+            with jax.named_scope("loss_and_grads"):
+                out = fn(self._compute_params(master), batch, scale)
             if out is not None:
                 loss, grads = out
                 grads = jax.tree.map(
@@ -1821,7 +1719,11 @@ class DeepSpeedTPUEngine:
             loss = self.model_spec.loss_fn(params, batch)
             return loss * scale if scale is not None else loss
 
-        loss, grads = jax.value_and_grad(scaled_loss)(master)
+        # the scope under which a device trace tells forward
+        # (``loss_and_grads/jvp(..)``), backward (``transpose(jvp(..))``)
+        # and recompute (``rematted_computation``) apart
+        with jax.named_scope("loss_and_grads"):
+            loss, grads = jax.value_and_grad(scaled_loss)(master)
         if scale is not None:
             loss = loss / scale
         return loss, self._constrain_grads(grads)
@@ -1831,6 +1733,7 @@ class DeepSpeedTPUEngine:
             return self.lr_scheduler.lr_at(step)
         return jnp.asarray(self.optimizer.lr, jnp.float32)
 
+    @jax.named_scope("optimizer")
     def _apply_update(self, state: Dict[str, Any], grads: PyTree,
                       grad_scale, lr_mult=None
                       ) -> Tuple[Dict[str, Any], Dict[str, jax.Array]]:
@@ -1966,8 +1869,9 @@ class DeepSpeedTPUEngine:
             else:
                 acc = carry
                 loss, grads = micro_fn(mb)
-            acc = jax.tree.map(
-                lambda a, g: a + g.astype(a.dtype), acc, grads)
+            with jax.named_scope("grad_accumulate"):
+                acc = jax.tree.map(
+                    lambda a, g: a + g.astype(a.dtype), acc, grads)
             acc = constrain(acc)
             return ((acc, extra) if with_extra else acc), loss
 
@@ -1991,8 +1895,9 @@ class DeepSpeedTPUEngine:
 
         def train_step(state, batch):
             scale = state["scaler"].scale if self.fp16_enabled else None
-            zeros = jax.tree.map(
-                lambda s: jnp.zeros(s.shape, acc_dt), self._shapes)
+            with jax.named_scope("grad_accumulate"):
+                zeros = jax.tree.map(
+                    lambda s: jnp.zeros(s.shape, acc_dt), self._shapes)
             zeros = self._constrain_grads(zeros)
 
             def micro_fn(mb):
@@ -2628,8 +2533,13 @@ class DeepSpeedTPUEngine:
     def train_batch(self, data_iter: Iterator[PyTree]) -> jax.Array:
         """Pull GAS micro-batches, run the fused jitted step. Returns mean loss."""
         gas = self.gradient_accumulation_steps()
-        stacked = self._stack_micros([next(data_iter) for _ in range(gas)])
-        stacked = self._inject_data_efficiency(stacked, gas)
+        # the caller's iterator, not the engine: its own span, so that a
+        # slow input pipeline is not read as a slow step
+        with self._train_span("train_batch_fetch"):
+            micros = [next(data_iter) for _ in range(gas)]
+        with self._train_span("train_batch_input"):
+            stacked = self._stack_micros(micros)
+            stacked = self._inject_data_efficiency(stacked, gas)
         return self._dispatch_train_step(stacked, gas)
 
     def _maybe_inject_nan_grads(self, stacked: PyTree, gas: int) -> PyTree:
@@ -2667,7 +2577,10 @@ class DeepSpeedTPUEngine:
                 self._compiled[key] = self._select_step_builder(gas)
             step_fn = self._compiled[key]
 
-        batch = self._shard_batch(stacked, leading=True)
+        # the batch's host-to-device copy (a second ``train_batch_input``
+        # of the step: the first stacked the micro-batches)
+        with self._train_span("train_batch_input"):
+            batch = self._shard_batch(stacked, leading=True)
         if self.config.wall_clock_breakdown:
             self.timers(TRAIN_BATCH_TIMER).start()
         self.tput_timer.start()
@@ -2801,14 +2714,15 @@ class DeepSpeedTPUEngine:
             logger.warning(f"flight dump on step failure failed too: {e}")
 
     def _train_span(self, name: str):
-        """telemetry.span when enabled; inert otherwise."""
+        """telemetry.span when enabled, with the step it belongs to as an
+        attribute; inert otherwise."""
         if self._tm is None:
             import contextlib
 
             return contextlib.nullcontext()
         from deepspeed_tpu import telemetry
 
-        return telemetry.span(name)
+        return telemetry.span(name, attrs={"step": self.global_steps + 1})
 
     def _after_step(self, metrics: Dict[str, jax.Array],
                     n_steps: int = 1, wall_s: Optional[float] = None,
@@ -2819,7 +2733,6 @@ class DeepSpeedTPUEngine:
             self._tm_steps.inc(n_steps)
             if tokens:
                 self._tm_tokens.inc(tokens)
-                self._tm_tokens_per_step = tokens // n_steps
             if wall_s is not None:
                 # amortize a fused window over its steps so the histogram
                 # stays per-step comparable across dispatch modes

@@ -98,7 +98,8 @@ class RequestResult:
 
 class _Request:
     __slots__ = ("uid", "max_new_tokens", "degraded", "submit_t", "order",
-                 "abs_deadline", "served", "tenant", "quota_blocks")
+                 "abs_deadline", "served", "first_token", "tenant",
+                 "quota_blocks")
 
     def __init__(self, uid: int, max_new_tokens: int, degraded: bool,
                  submit_t: float, order: int,
@@ -111,6 +112,7 @@ class _Request:
         self.order = order
         self.abs_deadline = abs_deadline   # frontend clock; None = none
         self.served = False                # first prefill progress seen
+        self.first_token = False           # first generated token seen
         self.tenant = tenant               # resolved tenant name
         self.quota_blocks = quota_blocks   # KV charge held in the registry
 
@@ -175,6 +177,7 @@ class ServingFrontend:
         # dropped or superseded are skipped at pop time)
         self._rejected_fifo: collections.deque = collections.deque()
         self._order_counter = 0
+        self._ticks_run = 0              # protected ticks entered, for spans
         self._suspects: List[int] = []   # admitted since last healthy tick
         # stamped by run_tick on the serving loop; the health-probe thread
         # only READS it (atomic float — tearing-tolerant by design)
@@ -271,8 +274,9 @@ class ServingFrontend:
         # share their router's clock, so last-wins is also all-win)
         self._tm_t_ttft = telemetry.histogram(
             "serving_tenant_ttft_seconds",
-            "submit() to first prefill progress, by tenant (per-tenant "
-            "p99 TTFT source)", window_s=600.0, window_intervals=60)
+            "submit() to the harvest that first saw a generated token, "
+            "by tenant (per-tenant p99 TTFT source)", window_s=600.0,
+            window_intervals=60)
         self._tm_t_ttft.set_window_clock(self.clock)
         self._tm_t_quar = telemetry.counter(
             "serving_tenant_quarantines_total",
@@ -411,7 +415,15 @@ class ServingFrontend:
         so replica-level (re)dispatches of the same request skip the
         rate check here (concurrency, KV quota, fairness and quarantine
         still apply — they meter live resources, not offered load)."""
-        prompt = list(prompt)
+        with telemetry.span("serving_submit", attrs={"uid": uid}):
+            return self._submit(uid, list(prompt), deadline_s,
+                                max_new_tokens, tenant, charge_quota)
+
+    def _submit(self, uid: int, prompt: List[int],
+                deadline_s: Optional[float],
+                max_new_tokens: Optional[int], tenant: Optional[str],
+                charge_quota: bool
+                ) -> Union[Admitted, Overloaded, Rejected]:
         tenant = self.tenancy.resolve(tenant)
         if max_new_tokens is None:
             max_new_tokens = self.cfg.default_max_new_tokens
@@ -692,7 +704,9 @@ class ServingFrontend:
         # happened to carry the probe
         probing = self.breaker.state == HALF_OPEN
         try:
-            with telemetry.span("serving_tick"):
+            self._ticks_run += 1
+            with telemetry.span("serving_tick",
+                                attrs={"tick": self._ticks_run}):
                 # hang FIRST (a stuck tick blocks before it fails), then
                 # the raise point; both scoped by replica name so fleet
                 # chaos can target one replica (point@name rules)
@@ -730,35 +744,45 @@ class ServingFrontend:
 
     def _harvest(self) -> None:
         """Fold engine state into request lifecycle: queue-wait
-        observation at first service, terminal resolution (+ flush, which
+        observation at first service, time to first token when the first
+        generated token is seen, terminal resolution (+ flush, which
         releases KV blocks) for expired / completed / grant-reached
         requests."""
-        for uid in list(self._reqs):
-            req = self._reqs[uid]
-            seq = self.engine.seqs.get(uid)
-            if seq is None:   # flushed behind our back — fail loudly-ish
-                self._resolve(uid, FAILED, [], reason="evicted",
-                              detail="sequence flushed outside the "
-                              "frontend", flush=False)
-                continue
-            if not req.served and (seq.prefilled > 0 or seq.done):
-                req.served = True
-                wait_s = self.clock() - req.submit_t
-                self._tm_wait.observe(wait_s)
-                self._tm_t_ttft.observe(
-                    wait_s, tenant=self.tenancy.label(req.tenant))
-                if self.observatory is not None:
-                    # fleet TTFT: first service on ANY replica counts
-                    # once (the observatory dedups hedge/failover copies)
-                    self.observatory.note_first_service(uid, wait_s)
-                self._tracer.request_event(uid, "first_service",
-                                           queue_wait_s=round(wait_s, 6))
-            if seq.expired:
-                self._resolve(uid, EXPIRED, list(seq.generated),
-                              reason="deadline")
-            elif seq.done or len(seq.generated) >= req.max_new_tokens:
-                self._resolve(uid, COMPLETED,
-                              list(seq.generated)[:req.max_new_tokens])
+        with telemetry.span("serving_harvest"):
+            for uid in list(self._reqs):
+                req = self._reqs[uid]
+                seq = self.engine.seqs.get(uid)
+                if seq is None:   # flushed behind our back — fail loudly-ish
+                    self._resolve(uid, FAILED, [], reason="evicted",
+                                  detail="sequence flushed outside the "
+                                  "frontend", flush=False)
+                    continue
+                if not req.served and (seq.prefilled > 0 or seq.done):
+                    req.served = True
+                    wait_s = self.clock() - req.submit_t
+                    self._tm_wait.observe(wait_s)
+                    if self.observatory is not None:
+                        # fleet TTFT: first service on ANY replica counts
+                        # once (the observatory dedups hedge/failover copies)
+                        self.observatory.note_first_service(uid, wait_s)
+                    self._tracer.request_event(uid, "first_service",
+                                               queue_wait_s=round(wait_s, 6))
+                if not req.first_token and seq.generated:
+                    # a token is visible to the caller at the harvest
+                    # after the tick that sampled it: a long prompt's
+                    # chunks lie between first service and this
+                    req.first_token = True
+                    ttft_s = self.clock() - req.submit_t
+                    self._tm_t_ttft.observe(
+                        ttft_s, tenant=self.tenancy.label(req.tenant))
+                    self._tracer.request_event(uid, "first_token",
+                                               ttft_s=round(ttft_s, 6))
+                if seq.expired:
+                    self._resolve(uid, EXPIRED, list(seq.generated),
+                                  reason="deadline")
+                elif seq.done or len(seq.generated) >= req.max_new_tokens:
+                    self._resolve(uid, COMPLETED,
+                                  list(seq.generated)[:req.max_new_tokens])
 
     def run_until_drained(self, max_ticks: int = 10_000,
                           open_wait_cap_s: float = 0.05,

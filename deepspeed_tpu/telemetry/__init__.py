@@ -42,6 +42,7 @@ from deepspeed_tpu.telemetry.exposition import (
 )
 from deepspeed_tpu.telemetry.registry import (
     DEFAULT_WINDOW_INTERVALS,
+    DEFAULT_REGISTRY as _default_registry,
     DEFAULT_WINDOW_S,
     Counter,
     Gauge,
@@ -66,8 +67,6 @@ __all__ = [
     "health_probe_names", "clear_health_probes", "unique_health_probe_name",
 ]
 
-_default_registry = MetricsRegistry()
-
 
 def get_registry() -> MetricsRegistry:
     return _default_registry
@@ -90,8 +89,9 @@ def histogram(name: str, description: str = "",
         window_s=window_s, window_intervals=window_intervals)
 
 
-def span(name: str, **labels):
-    return _span(name, _default_registry, **labels)
+#: ``span(name, attrs={...}, **labels)`` on the default registry: the class
+#: itself, so that a call site pays for no wrapper (``telemetry/spans.py``)
+span = _span
 
 
 def add_collector(fn) -> None:

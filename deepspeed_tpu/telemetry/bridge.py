@@ -34,10 +34,7 @@ class MonitorBridge:
         return f"{self.prefix}{name}{suffix}"
 
     def events(self, step: int) -> List[Tuple[str, float, int]]:
-        # cheap collection: publish runs ON the training thread at the
-        # print cadence — it must never trigger priced collector work
-        # (e.g. the measured-MFU cost-analysis compile)
-        self.registry.collect(expensive=False)
+        self.registry.collect()
         events: List[Tuple[str, float, int]] = []
         for metric in self.registry.metrics():
             if isinstance(metric, Histogram):

@@ -25,8 +25,8 @@ breaking the scrape.
 """
 from __future__ import annotations
 
+import bisect
 import collections
-import threading
 
 from deepspeed_tpu.analysis.racelint.sanitizer import make_lock
 import time
@@ -159,6 +159,15 @@ class _HistogramChild:
         self.min = float("inf")
         self.max = float("-inf")
 
+    def observe(self, idx: int, value: float, total: float, n: int) -> None:
+        self.bucket_counts[idx] += n
+        self.count += n
+        self.sum += total
+        if value < self.min:
+            self.min = value
+        if value > self.max:
+            self.max = value
+
     def merge(self, other: "_HistogramChild") -> None:
         for i, n in enumerate(other.bucket_counts):
             self.bucket_counts[i] += n
@@ -221,30 +230,24 @@ class Histogram(_Metric):
     def observe(self, value: float, n: int = 1, **labels) -> None:
         if not self._enabled() or n < 1:
             return
-        value = float(value)
-        key = _label_key(labels)
         with self._lock:
-            child = self._children.get(key)
-            if child is None:
-                child = self._children[key] = _HistogramChild(len(self.buckets))
-            idx = len(self.buckets)
-            for i, edge in enumerate(self.buckets):
-                if value <= edge:
-                    idx = i
-                    break
-            child.bucket_counts[idx] += n
-            child.count += n
-            child.sum += value * n
-            child.min = min(child.min, value)
-            child.max = max(child.max, value)
-            # the windowed twin: same observation lands in the current
-            # interval's snapshot; expired intervals fall off the ring
-            wchild = self._win_child(key)
-            wchild.bucket_counts[idx] += n
-            wchild.count += n
-            wchild.sum += value * n
-            wchild.min = min(wchild.min, value)
-            wchild.max = max(wchild.max, value)
+            self._observe_locked(_label_key(labels), float(value), n)
+
+    def _observe_locked(self, key: LabelKey, value: float, n: int) -> None:
+        """The observation itself; the caller holds the lock and has
+        checked ``_enabled()`` (``MetricsRegistry.observe_span`` brings a
+        key it built once and shares the lock with its own bookkeeping)."""
+        # first edge with value <= edge; past the last: the +Inf bucket
+        idx = bisect.bisect_left(self.buckets, value) if value == value \
+            else len(self.buckets)      # NaN compares False with every edge
+        total = value * n
+        child = self._children.get(key)
+        if child is None:
+            child = self._children[key] = _HistogramChild(len(self.buckets))
+        # the windowed twin: same observation lands in the current
+        # interval's snapshot; expired intervals fall off the ring
+        child.observe(idx, value, total, n)
+        self._win_child(key).observe(idx, value, total, n)
 
     def _win_child(self, key: LabelKey) -> _HistogramChild:
         """Current interval's child for ``key`` (caller holds the lock)."""
@@ -252,8 +255,11 @@ class Histogram(_Metric):
         ring = self._win.get(key)
         if ring is None:
             ring = self._win[key] = collections.deque()
-        if not ring or ring[-1][0] != now_idx:
-            ring.append((now_idx, _HistogramChild(len(self.buckets))))
+        elif ring and ring[-1][0] == now_idx:
+            # the common case; what had expired by this interval fell off
+            # when the interval was opened
+            return ring[-1][1]
+        ring.append((now_idx, _HistogramChild(len(self.buckets))))
         while ring and ring[0][0] <= now_idx - self.window_intervals:
             ring.popleft()
         return ring[-1][1]
@@ -391,16 +397,7 @@ class MetricsRegistry:
         # watchdog substrate: the last completed span as (name, monotonic
         # end time) — interval math only, never exported as a timestamp
         self.last_span: Optional[Tuple[str, float]] = None  # guarded-by: self._lock
-        # per-thread collection mode (see collect()): thread-local so a
-        # concurrent /metrics scrape can't flip a cheap bridge publish on
-        # the training thread into an expensive one mid-iteration
-        self._collect_tls = threading.local()
-
-    @property
-    def collecting_expensive(self) -> bool:
-        """Whether the CURRENT THREAD's in-flight collect() may price
-        expensive values (compiles, fences). True outside a collect()."""
-        return getattr(self._collect_tls, "expensive", True)
+        self._span_hist: Optional[Histogram] = None
 
     # -- metric construction (idempotent by name, kind-checked) ---------- #
     def _get_or_make(self, cls, name: str, description: str, **kw):
@@ -447,35 +444,40 @@ class MetricsRegistry:
         with self._lock:
             self._collectors.append(fn)
 
-    def collect(self, expensive: bool = True) -> None:
-        """Run collectors. ``expensive=False`` (the MonitorBridge's print-
-        cadence publish, which runs ON the training thread) tells
-        collectors to skip anything priced — one-off compiles, device
-        fences; they read the mode via ``self.collecting_expensive``."""
+    def collect(self) -> None:
+        """Run collectors (right before a snapshot, a render or a bridge
+        publish)."""
         with self._lock:
             collectors = list(self._collectors)
-        self._collect_tls.expensive = expensive
         dead = []
-        try:
-            for fn in collectors:
-                try:
-                    if fn() is False:
-                        dead.append(fn)
-                except Exception as e:  # broken collector must not kill scrapes
-                    self.counter(
-                        "telemetry_collector_errors_total",
-                        "collector callbacks that raised during a scrape",
-                    ).inc(error=type(e).__name__)
-        finally:
-            self._collect_tls.expensive = True
+        for fn in collectors:
+            try:
+                if fn() is False:
+                    dead.append(fn)
+            except Exception as e:  # broken collector must not kill scrapes
+                self.counter(
+                    "telemetry_collector_errors_total",
+                    "collector callbacks that raised during a scrape",
+                ).inc(error=type(e).__name__)
         if dead:
             with self._lock:
                 self._collectors = [f for f in self._collectors
                                     if f not in dead]
 
     # -- span bookkeeping (see telemetry/spans.py) ----------------------- #
-    def note_span_end(self, name: str) -> None:
+    def observe_span(self, key: LabelKey, name: str, seconds: float) -> None:
+        """What the end of a ``telemetry.span`` records, under one
+        acquisition of the lock: the wall time into ``span_seconds`` (made
+        at the first span and then held: the metric object outlives
+        ``reset()``) and the span as the last completed one."""
+        hist = self._span_hist
+        if hist is None:
+            hist = self._span_hist = self.histogram(
+                "span_seconds", "wall time of telemetry.span sections")
+        record = hist._enabled()
         with self._lock:
+            if record:
+                hist._observe_locked(key, seconds, 1)
             self.last_span = (name, time.monotonic())
 
     def reset(self) -> None:
@@ -493,3 +495,7 @@ class MetricsRegistry:
                     win.clear()
             self._collectors.clear()
             self.last_span = None
+
+
+#: the process-wide registry (``telemetry.get_registry()``)
+DEFAULT_REGISTRY = MetricsRegistry()

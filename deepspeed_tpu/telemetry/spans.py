@@ -21,45 +21,98 @@ device timings use ``utils/timer.py``'s fenced timers (which also feed the
 """
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
-from typing import Optional
+from typing import Any, Dict, Optional
 
 from deepspeed_tpu.analysis.racelint.sanitizer import make_lock
 from deepspeed_tpu.testing.chaos import sync_point
 from deepspeed_tpu.telemetry import tracing as _tracing
-from deepspeed_tpu.telemetry.registry import MetricsRegistry
-
-SPAN_HISTOGRAM = "span_seconds"
-
-
-def _trace_annotation(name: str):
-    """jax.profiler.TraceAnnotation when jax is importable; inert otherwise
-    (the registry itself is dependency-free and must stay usable without a
-    device runtime, e.g. from the HTTP scrape thread)."""
-    try:
-        from jax.profiler import TraceAnnotation
-
-        return TraceAnnotation(name)
-    # a span must NEVER raise into the section it brackets, whatever the
-    # profiler backend is doing — inert fallback, no logging on what can
-    # be a per-tick path  # dslint: disable=silent-except
-    except Exception:
-        return contextlib.nullcontext()
+from deepspeed_tpu.telemetry.registry import (
+    DEFAULT_REGISTRY,
+    MetricsRegistry,
+    _label_key,
+)
 
 
-@contextlib.contextmanager
-def span(name: str, registry: MetricsRegistry, **labels):
-    hist = registry.histogram(
-        SPAN_HISTOGRAM, "wall time of telemetry.span sections")
-    t0 = time.perf_counter()
-    with _trace_annotation(name), _tracing.get_tracer().span(name, **labels):
-        try:
-            yield
-        finally:
-            hist.observe(time.perf_counter() - t0, span=name, **labels)
-            registry.note_span_end(name)
+class _NoAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation`` where jax cannot be
+    imported (the registry is dependency-free and must stay usable without
+    a device runtime, e.g. from the HTTP scrape thread)."""
+
+    __slots__ = ()
+
+    def __init__(self, name: str, **attrs):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+try:   # resolved ONCE: a per-call import costs more than the annotation
+    from jax.profiler import TraceAnnotation as _Annotation
+# a span must NEVER raise into the section it brackets, whatever the
+# profiler backend is doing  # dslint: disable=silent-except
+except Exception:
+    _Annotation = _NoAnnotation
+
+#: the default tracer: configured in place, never replaced
+_TRACER = _tracing.get_tracer()
+
+
+class span:
+    """``with span(name, attrs={...}, **labels)``: one timed section
+    (module docstring), recorded in ``registry`` (the process-wide one
+    unless given). ``labels`` are LOW-cardinality: they key
+    the ``span_seconds`` histogram beside ``span=name``. ``attrs`` are
+    per-occurrence values (a uid, a tick count, a row count): they go to
+    the ``TraceAnnotation`` as keyword arguments (stats of the host event
+    in a profiler trace) and to the ``Tracer`` record when the flight
+    recorder is on, and never reach a histogram.
+
+    A slotted class, not a generator context manager; the histogram is
+    held by the registry and the annotation class and the tracer by this
+    module, so nothing is looked up by name on a call: a tick pays for
+    eight of these."""
+
+    __slots__ = ("_name", "_registry", "_key", "_attrs", "_labels",
+                 "_ann", "_rec", "_t0")
+
+    def __init__(self, name: str,
+                 registry: MetricsRegistry = DEFAULT_REGISTRY,
+                 attrs: Optional[Dict[str, Any]] = None, **labels):
+        self._name = name
+        self._registry = registry
+        self._attrs = attrs
+        self._labels = labels
+        # the histogram's key, as ``_label_key`` would build it
+        self._key = _label_key({"span": name, **labels}) if labels \
+            else (("span", name),)
+
+    def __enter__(self):
+        attrs = self._attrs
+        ann = self._ann = _Annotation(self._name, **attrs) if attrs \
+            else _Annotation(self._name)
+        ann.__enter__()
+        if _TRACER.enabled:
+            rec = self._rec = _TRACER.span(self._name, **self._labels,
+                                           **(attrs or {}))
+            rec.__enter__()
+        else:
+            self._rec = None
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = time.perf_counter() - self._t0
+        if self._rec is not None:
+            self._rec.__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
+        self._registry.observe_span(self._key, self._name, dt)
+        return False
 
 
 class StallWatchdog:

@@ -1,8 +1,7 @@
-"""Chip datasheet facts shared by bench.py and the telemetry MFU gauge.
-
-One table so the headline bench MFU and the scraped ``train_mfu`` gauge can
-never disagree about a chip's peak. Stdlib-only — importable from the bench
-orchestrator before jax loads.
+"""Chip datasheet facts shared by bench.py, the step report and the
+autotune planner: one table, so they can never disagree about a chip's
+peak. Stdlib-only — importable from the bench orchestrator before jax
+loads.
 """
 from __future__ import annotations
 

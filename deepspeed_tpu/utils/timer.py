@@ -162,10 +162,6 @@ class ThroughputTimer:
         self.local_step_count = 0
         self.total_elapsed_time = 0.0   # fenced wall time since start_step
         self._counted_steps = 0         # steps covered by total_elapsed_time
-        # optional (duration_s, steps) callback fired on every fenced window
-        # close — the telemetry feed for throughput gauges (async dispatch
-        # makes un-fenced per-step walls meaningless, see class docstring)
-        self.window_hook = None
         self._window_start: Optional[float] = None
         self._window_steps = 0
         self.started = False
@@ -219,12 +215,6 @@ class ThroughputTimer:
         self._counted_steps += steps
         self._window_start = time.perf_counter()
         self._window_steps = 0
-        if self.window_hook is not None and steps:
-            try:
-                self.window_hook(duration, steps)
-            except Exception as e:   # telemetry must never break the timer
-                logger.debug(f"throughput window_hook failed "
-                             f"({type(e).__name__}: {e})")
         return duration, steps
 
     def avg_samples_per_sec(self) -> float:

@@ -36,6 +36,22 @@ class TestCompileCache:
         assert compile_cache.ensure_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == before
 
+    def test_names_are_part_of_the_cache_key(self, monkeypatch, tmp_path):
+        """An executable cached under an older build's scopes and kernel
+        names must not be loaded for the same arithmetic: a device trace
+        carries the names of the executable that ran."""
+        from deepspeed_tpu.utils import compile_cache
+
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        flag = "jax_compilation_cache_include_metadata_in_key"
+        before = getattr(jax.config, flag)
+        try:
+            jax.config.update(flag, False)
+            compile_cache.ensure_compile_cache()
+            assert getattr(jax.config, flag) is True
+        finally:
+            jax.config.update(flag, before)
+
     def test_unset_is_one_path_under_the_checkout_for_every_process(
             self, monkeypatch, tmp_path):
         from deepspeed_tpu.utils import compile_cache

@@ -681,3 +681,80 @@ class TestServingConfig:
         assert fe.result(1).state == "failed"
         assert fe.result(1).reason == "shutdown"
         assert eng.allocator.free_blocks == free0
+
+
+# --------------------------------------------------------------------- #
+# what a tick says about itself: spans, attributes, kind, first token
+# --------------------------------------------------------------------- #
+class TestTickTelemetry:
+    def _run_one(self, prompt_len=26, new=3):
+        """One request whose prompt takes four ticks of an 8-token budget
+        (8 + 8 + 8 + 2): first service after tick 1, first token after
+        tick 4. The injected clock moves one second a tick."""
+        now = [0.0]
+        fe = ServingFrontend(_engine(token_budget=8),
+                             config=dict(SCFG), clock=lambda: now[0])
+        fe.submit(1, _prompt(prompt_len), max_new_tokens=new)
+        ticks = 0
+        while fe.active_count():
+            now[0] += 1.0
+            fe.run_tick()
+            ticks += 1
+        return fe, ticks
+
+    def test_ttft_is_the_first_token_and_queue_wait_the_first_service(self):
+        telemetry.configure_tracing(enabled=True)
+        fe, ticks = self._run_one()
+        assert ticks == 4 + 2         # 4 prompt ticks, then 2 more tokens
+        wait = telemetry.histogram("serving_queue_wait_seconds").summary()
+        ttft = telemetry.histogram("serving_tenant_ttft_seconds").summary(
+            tenant="default")
+        assert wait["count"] == ttft["count"] == 1
+        assert wait["sum"] == pytest.approx(1.0)    # after the first chunk
+        assert ttft["sum"] == pytest.approx(4.0)    # three ticks later
+        events = {e["name"]: e for e in
+                  telemetry.get_tracer().export_chrome()["traceEvents"]
+                  if e["ph"] == "i"}
+        assert events["first_service"]["ts"] < events["first_token"]["ts"]
+        assert events["first_token"]["args"]["ttft_s"] == pytest.approx(4.0)
+        fe.close()
+
+    def test_tick_kind_is_on_the_counter_and_on_the_span(self):
+        telemetry.configure_tracing(enabled=True)
+        fe, _ = self._run_one()
+        by_kind = {}
+        for key, n in telemetry.counter("fastgen_ticks_total").labels_items():
+            by_kind[dict(key)["kind"]] = by_kind.get(dict(key)["kind"], 0) + n
+        # four ticks held prompt rows, the two after them none
+        assert by_kind == {"mixed": 4, "decode": 2}
+        spans = [e for e in
+                 telemetry.get_tracer().export_chrome()["traceEvents"]
+                 if e["ph"] == "X"]
+        ticks = [e["args"] for e in spans if e["name"] == "decode_tick"]
+        assert [a["kind"] for a in ticks] == ["mixed"] * 4 + ["decode"] * 2
+        assert [a["tick"] for a in ticks] == [1, 2, 3, 4, 5, 6]
+        assert [a["prefill_tokens"] for a in ticks] == [8, 8, 8, 2, 0, 0]
+        assert [a["decode_rows"] for a in ticks] == [0, 0, 0, 0, 1, 1]
+        assert all(a["rows"] == a["decode_rows"] + a["prefill_tokens"]
+                   and a["bucket"] == 8 and a["mb_tier"] for a in ticks)
+        # every boundary of a tick has its span, nested as PERF.md says
+        names = [e["name"] for e in spans]
+        for name in ("serving_submit", "serving_tick", "schedule_tick",
+                     "tick_dispatch", "tick_readback", "tick_commit",
+                     "serving_harvest"):
+            assert names.count(name) >= 1, name
+        by_id = {e["args"]["trace_id"]: e for e in spans
+                 if "parent_span_id" not in e["args"]}
+        inner = {e["name"] for e in spans if e["name"] in
+                 ("tick_dispatch", "tick_readback")}
+        assert inner == {"tick_dispatch", "tick_readback"}
+        for e in spans:
+            if e["name"] in ("schedule_tick", "decode_tick", "tick_commit"):
+                assert by_id[e["args"]["trace_id"]]["name"] == "serving_tick"
+        submit = next(e for e in spans if e["name"] == "serving_submit")
+        assert submit["args"]["uid"] == 1
+        # attributes are per occurrence: none of them keys a histogram
+        keys = [dict(k) for k, _ in
+                telemetry.get_registry().get("span_seconds").labels_items()]
+        assert keys and all(set(k) == {"span"} for k in keys)
+        fe.close()

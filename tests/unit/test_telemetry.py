@@ -4,6 +4,7 @@ and the FastGen serving engine (the ISSUE-1 acceptance surface)."""
 import itertools
 import json
 import os
+import time
 import urllib.request
 
 import numpy as np
@@ -165,6 +166,57 @@ class TestSpans:
         assert s["count"] == 1 and s["sum"] >= 0
         assert reg.last_span[0] == "tick"
 
+    def test_span_attributes_reach_the_profiler_and_no_histogram(
+            self, tmp_path):
+        """Labels key ``span_seconds``; attributes are stats of the host
+        event in a profiler trace and never a label."""
+        import glob
+
+        import jax
+
+        reg = MetricsRegistry()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with span("decode_tick", reg, attrs={"kind": "mixed", "rows": 21},
+                      phase="x"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        (pb,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        stats = [dict(e.stats)
+                 for p in jax.profiler.ProfileData.from_file(pb).planes
+                 for line in p.lines for e in line.events
+                 if e.name == "decode_tick"]
+        assert stats == [{"kind": "mixed", "rows": 21}]
+        keys = [dict(k) for k, _ in reg.get("span_seconds").labels_items()]
+        assert keys == [{"span": "decode_tick", "phase": "x"}]
+
+    def test_span_records_when_the_section_raises(self):
+        reg = MetricsRegistry()
+        with pytest.raises(KeyError):
+            with span("boom", reg):
+                raise KeyError("x")
+        assert reg.histogram("span_seconds").summary(span="boom")["count"] == 1
+        assert reg.last_span[0] == "boom"
+
+    def test_span_overhead_guard(self):
+        """A tick pays for eight spans: with no profiler and no flight
+        recorder one must stay a few microseconds (2.3 us measured on the
+        sandbox's CPU, best of 25 x 20,000; the guard trips at 40 us so
+        that a loaded test machine cannot fail it)."""
+        reg = MetricsRegistry()
+        n = 20_000
+        t0 = time.perf_counter()
+        for i in range(n):
+            with span("hot", reg, attrs={"tick": i}):
+                pass
+        dt = time.perf_counter() - t0
+        assert dt < n * 40e-6, f"span cost {dt / n * 1e6:.1f} us a call"
+        assert reg.histogram("span_seconds").summary(span="hot")["count"] == n
+
     def test_watchdog_warns_once_with_last_span(self):
         reg = MetricsRegistry()
         warnings = []
@@ -285,8 +337,6 @@ class TestEndToEnd:
         engine, *_ = dst.initialize(model=spec, config=config)
         try:
             data = itertools.cycle(synthetic_lm_data(8, 64, 512, seed=0))
-            # 4 steps: the fenced throughput window (tokens/s source) only
-            # opens after ThroughputTimer's start_step=2 warmup
             for _ in range(4):
                 engine.train_batch(data)
             snap = telemetry.snapshot()
@@ -294,7 +344,6 @@ class TestEndToEnd:
             assert snap["counters"]["train_tokens_total"] == 4 * 8 * 64
             step_h = snap["histograms"]["train_step_seconds"]
             assert step_h["count"] == 4 and step_h["sum"] > 0
-            assert snap["gauges"]["train_tokens_per_sec"] > 0
             assert snap["gauges"]["train_loss"] > 0
             assert "train_grad_norm" in snap["gauges"]
             assert snap["gauges"]["train_heartbeat_timestamp_seconds"] > 0

@@ -1,0 +1,114 @@
+"""Names the program gives the device trace: ``name=`` on every
+``pallas_call`` and ``jax.named_scope`` phases in the training step and
+the serving tick, read from the lowered text at toy size. A device trace
+names a kernel's instruction after ``name=`` and carries each operation's
+scope stack (``tf_op``), which is how the benchmark tells forward,
+recompute, backward and optimizer apart."""
+import ast
+import glob
+import os
+import re
+
+import jax.numpy as jnp
+
+import deepspeed_tpu as dst
+from deepspeed_tpu.inference.fastgen import FastGenEngine
+
+PALLAS = os.path.join(os.path.dirname(dst.__file__), "ops", "pallas")
+KERNEL_NAMES = {
+    "flash_fwd", "flash_dq", "flash_dkv", "paged_attention",
+    "block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv",
+    "evoformer_attention", "fused_adam", "quantize_int8_blocks",
+    "dequant_reduce", "rms_norm", "layer_norm"}
+
+
+def _stacks(lowered, program):
+    """The name stack (``op_name``) of every instruction of the compiled
+    program, as a device trace carries it."""
+    names = set(re.findall(r'op_name="([^"]+)"', lowered.compile().as_text()))
+    return {n for n in names if n.startswith(f"jit({program})/")}
+
+
+def test_every_pallas_call_is_named():
+    sites, literal = 0, set()
+    for path in sorted(glob.glob(os.path.join(PALLAS, "*.py"))):
+        tree = ast.parse(open(path).read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "attr", "") == "pallas_call":
+                sites += 1
+                kw = {k.arg: k.value for k in node.keywords}
+                assert "name" in kw, f"{path}:{node.lineno} has no name="
+                if isinstance(kw["name"], ast.Constant):
+                    literal.add(kw["name"].value)
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "id", "") == "_run_rows":
+                literal.add(node.args[0].value)   # norms.py: by the caller
+    assert sites == 12
+    assert literal == KERNEL_NAMES
+
+
+def test_training_step_carries_phases_and_kernel_names():
+    spec = dst.causal_lm_spec("tiny", dtype="float32", num_layers=2,
+                              max_seq_len=128, remat="full",
+                              attention="flash")
+    engine, *_ = dst.initialize(model=spec, config={
+        "train_batch_size": 8, "train_micro_batch_size_per_gpu": 1,
+        "gradient_accumulation_steps": 1,
+        "optimizer": {"type": "adam", "params": {"lr": 1e-3}},
+        "zero_optimization": {"stage": 3}})
+    step = engine._select_step_builder(1)
+    batch = {"tokens": jnp.zeros((1, 8, 128), jnp.int32)}
+    with engine.mesh:
+        stacks = _stacks(step.lower(engine.state, batch), "train_step")
+    assert len(stacks) > 100
+
+    def some(*parts):
+        return any(all(p in s for p in parts) for s in stacks)
+
+    # forward, recompute and backward of a block, each under its scope
+    assert some("loss_and_grads/jvp(", "attn")
+    assert some("loss_and_grads/jvp(", "mlp")
+    assert some("rematted_computation", "attn")
+    assert some("rematted_computation", "mlp")
+    assert some("transpose(jvp(", "/mlp/")
+    assert some("loss_and_grads/jvp(embed)")
+    assert some("loss_and_grads/jvp(lm_head_loss)")
+    assert some("transpose(", "lm_head_loss")      # its custom derivative
+    assert some("/optimizer/")
+    # the three flash kernels by name: the forward one in the forward
+    # pass and again in the recompute, the two backward ones after it
+    assert some("loss_and_grads/jvp()", "/attn/", "/flash_fwd")
+    assert some("rematted_computation/attn", "/flash_fwd")
+    assert some("transpose(jvp())", "checkpoint/attn", "/flash_dq")
+    assert some("transpose(jvp())", "checkpoint/attn", "/flash_dkv")
+    # and the benchmark's reader sorts them into its four phases
+    from benchmarks import gap_chain
+
+    by_phase = {}
+    for s in stacks:
+        by_phase.setdefault(gap_chain.phase_of(s), []).append(s)
+    assert set(by_phase) - {None} == {"fwd", "recompute", "bwd", "optimizer"}
+    # outside every phase: the accumulator's zeros and the loss's mean
+    assert len(by_phase.get(None, [])) <= 4
+    assert gap_chain.phase_of(next(
+        s for s in stacks if "rematted_computation/attn" in s)) == "recompute"
+    engine.shutdown_telemetry()
+
+
+def test_serving_tick_carries_scopes_and_the_kernel_name():
+    eng = FastGenEngine("tiny", n_blocks=16, block_size=16,
+                        max_blocks_per_seq=8, token_budget=32,
+                        temperature=0.0, seed=0, use_pallas_kernel=True,
+                        hidden_size=64, num_layers=2, num_heads=4,
+                        max_seq_len=128, vocab_size=512, dtype="float32")
+    tick = eng._build_tick()
+    tn, mb = 32, eng.max_blocks_per_seq
+    stacks = _stacks(tick.lower(
+        eng.params, eng.pool, jnp.zeros((tn,), jnp.int32),
+        jnp.zeros((tn,), jnp.int32), jnp.zeros((tn, mb), jnp.int32),
+        jnp.zeros((2,), jnp.uint32)), "tick")
+    parts = {part for s in stacks for part in s.split("/")}
+    assert {"embed", "attn", "mlp", "lm_head", "sample"} <= parts
+    # the paged kernel by name, inside a layer's attention scope
+    assert any("/attn/paged_attention" in s for s in stacks)

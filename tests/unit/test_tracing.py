@@ -469,8 +469,7 @@ class TestEngineFlightDumps:
                   "gradient_accumulation_steps": 1,
                   "optimizer": {"type": "adam", "params": {"lr": 1e-3}},
                   "telemetry": {"stall_deadline_s": 300.0, "tracing": True,
-                                "flight_dump_dir": str(tmp_path),
-                                "measure_mfu": False},
+                                "flight_dump_dir": str(tmp_path)},
                   "fault_tolerance": {"on_stall": "dump_trace"}}
         engine, *_ = dst.initialize(model=spec, config=config)
         try:

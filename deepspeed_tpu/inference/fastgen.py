@@ -203,6 +203,12 @@ class FastGenEngine:
         if use_pallas_kernel is None:
             use_pallas_kernel = jax.default_backend() == "tpu"
         self._use_kernel = use_pallas_kernel
+        # rows of a kernel tile; 0 where a tick's attention is not the
+        # kernel's (forward_paged sends ALiBi and MLA to the reference)
+        self._tile_rows = 0
+        if use_pallas_kernel and not cfg.mla and cfg.pos_emb != "alibi":
+            from deepspeed_tpu.ops.pallas.paged_attention import tile_rows
+            self._tile_rows = tile_rows(cfg.num_heads, cfg.kv_heads)
 
     def _dev(self, x) -> jax.Array:
         """Host array → device; REPLICATED across the mesh under TP (a
@@ -253,6 +259,11 @@ class FastGenEngine:
         self._tm_prefill_tok = telemetry.counter(
             "fastgen_prefill_tokens_total",
             "prompt tokens written into the KV cache")
+        self._tm_shared_rows = telemetry.counter(
+            "fastgen_paged_shared_rows_total",
+            "prompt rows of step() ticks that sat in a kernel tile wholly "
+            "inside one chunk (their tile walked the sequence's blocks "
+            "once); over fastgen_prefill_tokens_total: the hit share")
         self._tm_preempt = telemetry.counter(
             "fastgen_preemptions_total",
             "sequences deferred a tick by KV-pool backpressure")
@@ -351,12 +362,13 @@ class FastGenEngine:
     def _mb_tier(self, mb_need: int) -> int:
         """Table-width tiers (quarter/half/full) — ONE rule for every
         compile-cache key (step / decode-scan / planned-serve must agree or
-        the small-grid property of the caches breaks). The tier bounds the
-        paged-attention grid, and the kernel DMAs every covered block
-        whether or not a row reaches it — a batch whose longest row fits
-        the HALF tier halves the per-tick KV read (decode is KV+weight
-        HBM-bound: ~600 MB/tick at full width for gpt2-125M b16, r5
-        profile)."""
+        the small-grid property of the caches breaks). The tier is the
+        width of the block tables a tick carries: the reference path
+        gathers every covered block, the Pallas kernel holds the table in
+        scalar memory and fetches only the blocks a row's length reaches
+        (measured in PR 22, and by construction since PR 24: its walks
+        stop at ``ceil(length / block_size)``), so for the kernel a
+        narrower tier saves table bytes, not KV reads."""
         quarter, half = self._mb_tier_bounds()
         if mb_need <= quarter:
             return quarter
@@ -901,6 +913,9 @@ class FastGenEngine:
             # (row, seq, is_decode): rows whose logits get sampled this tick
             heads: List[tuple] = []
             row = 0
+            # prompt rows in kernel tiles wholly inside one chunk
+            shared_rows = 0
+            R = self._tile_rows
 
             # 1) decode tokens — one per fully-prefilled live sequence,
             # starting from a rotating offset so tails never starve when
@@ -950,6 +965,9 @@ class FastGenEngine:
                 positions[row:row + chunk] = np.arange(seq.pos,
                                                        seq.pos + chunk)
                 tables[row:row + chunk] = seq.table
+                if R:
+                    shared_rows += R * max(
+                        0, (row + chunk) // R - -(-row // R))
                 row += chunk
                 seq.prefilled += chunk
                 seq.pos += chunk
@@ -980,7 +998,8 @@ class FastGenEngine:
         with telemetry.span("decode_tick", attrs={
                 "tick": self._ticks_run, "kind": kind, "rows": row,
                 "decode_rows": n_decode_rows,
-                "prefill_tokens": row - n_decode_rows, "bucket": Tn,
+                "prefill_tokens": row - n_decode_rows,
+                "shared_rows": shared_rows, "bucket": Tn,
                 "mb_tier": tier}):
             # enqueue: the host-to-device copies and the jitted call,
             # until it returns (the device may still be running)
@@ -1006,6 +1025,7 @@ class FastGenEngine:
                     n=n_decode_rows)
             self._tm_ticks.inc(kind=kind, mb_tier=tier)
             self._tm_prefill_tok.inc(row - n_decode_rows)
+            self._tm_shared_rows.inc(shared_rows)
             self._tm_occup.set(row / Tn, phase="mixed")
             self._tm_sched_gauges()
 

@@ -1,16 +1,42 @@
-"""Pallas paged-attention kernel: per-token block-table KV gather + online
-softmax, without materializing the gathered context in HBM.
+"""Pallas paged-attention kernel: block-table KV gather + online softmax over
+TILES of rows, without materializing the gathered context in HBM.
 
 Parity: reference ``inference/v2/kernels/ragged_ops`` (blocked flash attention
-over the blocked KV cache, ``linear_blocked_kv_rotary`` etc.) — the CUDA tree
-walks each sequence's block list; here the block list is a SCALAR-PREFETCH
-argument so the BlockSpec ``index_map`` itself chases the table: grid step
-(t, j) streams block ``tables[t, j]`` of the pool through VMEM for token t.
+over the blocked KV cache) — the CUDA tree walks each sequence's block list
+once for all of that sequence's query rows; so does this kernel.
 
-Decode attention is HBM-bandwidth-bound (read each live sequence's KV once);
-the win over the XLA reference path (``models/paged.py
-paged_attention_reference``) is avoiding the [T, MB*bs, K, D] gathered copy
-in HBM — the kernel reads pool blocks directly.
+Grid: one step per tile of ``R`` consecutive rows of the flat token batch
+(``tile_rows``: a constant of the head shapes). The pools stay in HBM
+(``memory_space=ANY``); a step splits its tile into RUNS of consecutive rows
+that carry the same block table and walks each run's table ONCE, ``P`` blocks
+at a time, with double-buffered ``make_async_copy`` fetches whose trip count
+is read from the prefetched lengths:
+
+- a run of two or more rows (a prompt chunk of one sequence, which
+  ``FastGenEngine._step_impl`` lays out contiguously; the pad rows of a tick)
+  meets each fetched block with all the tile's rows in one product per KV
+  head, ``[R*rep, D] x [D, P*bs]``, and the per-row causal limit
+  ``c < lengths[row]`` — 0 for the tile's rows outside the run — is a mask;
+- a run of one row (a decode row) walks alone, ``[rep, D] x [D, P*bs]``.
+
+Which rows share a table is DATA: ``same[t] = all(tables[t] == tables[t-1])``
+is computed on the device beside the call and rides in the second scalar
+operand behind the lengths, so a tick's composition never reaches a compile
+key and the result is right for any row layout. A walk fetches
+``ceil(length / bs)`` blocks, not the table's width; a pad row (all-zero
+table, length 1) costs at most one block, and a run of pad rows one.
+
+Arithmetic: bf16 values, float32 from the cast on (products, scores, softmax
+statistics, probabilities, accumulator). On the v5e bf16 operands into either
+product bought nothing: the kernel is not MXU-bound.
+
+A serving set-up builds a tick program per ``(Tn, mb)``, seven of them, and
+tracing this kernel's body for each was a tenth of the benchmark's
+``setup_s``. So the traced program is kept small (both kinds of run share one
+walk, whose step branches on the run's length; every loop over rows is a
+``fori_loop``) and is traced once a tick bucket: the call is an inlined inner
+``jit``, whose trace is cached by operand shapes, and the table is widened to
+a multiple of 64 columns so that every tier of a bucket has the same shapes.
 
 Shapes: q [T, N, D]; kpool/vpool [NB, bs, K, D]; tables [T, MB] int32;
 lengths [T] int32 (context length per token, pos+1). GQA via in-kernel
@@ -27,65 +53,204 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# one fetch step holds at most this many bytes of K plus V blocks (times two
+# slots in VMEM) and at most a lane width of cache positions
+_FETCH_BYTES = 1024 * 1024
+_LANES = 128
+# block tables are widened to a multiple of this many columns (see _tiles)
+_TABLE_COLS = 64
 
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _kernel(tables_ref, lengths_ref,           # scalar prefetch
-            q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref,
-            *, bs: int, rep: int, n_blocks_per_seq: int):
-    t = pl.program_id(0)
-    j = pl.program_id(1)
+def tile_rows(num_heads: int, kv_heads: int) -> int:
+    """Rows of the flat token batch a grid step works on: enough that the
+    ``R * rep`` query rows a KV head meets fill the MXU's 128 rows, held to
+    16-32 so the tile's float32 state stays a few hundred KB of VMEM."""
+    return max(16, min(32, _LANES // (num_heads // kv_heads)))
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    length = lengths_ref[t]
-    run = j * bs < length
+def _blocks_per_fetch(bs: int, K: int, D: int, itemsize: int) -> int:
+    pair = 2 * bs * K * D * itemsize
+    return max(1, min(_LANES // bs, _FETCH_BYTES // pair))
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                  # [N, D]
-        k = k_ref[0].astype(jnp.float32)                  # [bs, K, D]
-        v = v_ref[0].astype(jnp.float32)
-        N, D = q.shape
-        K = k.shape[1]
-        scale = 1.0 / jnp.sqrt(jnp.float32(D))
 
-        q3 = q.reshape(K, rep, D)
-        kt = jnp.swapaxes(k, 0, 1)                        # [K, bs, D]
+def _kernel(tables_ref, meta_ref,              # scalar prefetch
+            q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sems, q3_ref, len_ref, m_ref, l_ref, acc_ref):
+    _, P, bs, K, D = kbuf.shape
+    R, N, _ = q_ref.shape
+    rep, T = N // K, meta_ref.shape[0] // 2
+    M, C = R * rep, P * bs
+    scale = 1.0 / jnp.sqrt(jnp.float32(D))
+    t0 = pl.program_id(0) * R
+
+    def length(r):
+        return meta_ref[t0 + r]
+
+    def same(r):                       # row r carries row r-1's table
+        return meta_ref[T + t0 + r] != 0
+
+    @pl.when(pl.program_id(0) == 0)
+    def _clear():
+        # a fetch step skips the blocks past a walk's last; what the slots
+        # hold there is masked out of the scores but multiplies the (zero)
+        # probabilities, so it must be finite from the first step on
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    # the tile's queries head-major, [K, R*rep, D]: row r of the tile is
+    # rows r*rep .. of every KV head; its lengths beside them; its softmax
+    # statistics and accumulator opened once, each run closes its own rows
+    q = q_ref[...].astype(jnp.float32).reshape(R, K, rep, D)
+    q3_ref[...] = jnp.swapaxes(q, 0, 1).reshape(K, M, D)
+
+    def put_length(r, _):
+        len_ref[pl.ds(r * rep, rep), :] = jnp.full(
+            (rep, _LANES), length(r), jnp.int32)
+
+    jax.lax.fori_loop(0, R, put_length, None)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def online_softmax(i, kt, vt, rows, limit):
+        """One fetch step of the query rows ``rows`` (a slice of the tile's
+        ``R*rep``) against ``[K, C, D]`` keys and values; ``limit``
+        broadcasts against the ``[K, rows, C]`` scores."""
         s = jax.lax.dot_general(
-            q3, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale   # [K, rep, bs]
-        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(col < length, s, NEG_INF)
-
-        s2 = s.reshape(N, bs)
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1, keepdims=True))
-        p = jnp.exp(s2 - m_new)                           # [N, bs]
+            q3_ref[:, rows, :], kt, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale
+        col = i * C + jax.lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
+        live = col < limit
+        s = jnp.where(live, s, NEG_INF)
+        m_prev = m_ref[:, rows, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        # a row outside the run has no live column: its max stays NEG_INF,
+        # so the probabilities are zeroed by the mask, not by the exponent
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:, 0:1] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:, 0:1] = m_new
-
-        vt = jnp.swapaxes(v, 0, 1)                        # [K, bs, D]
         pv = jax.lax.dot_general(
-            p.reshape(K, rep, bs), vt, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)           # [K, rep, D]
-        acc_ref[:] = acc_ref[:] * alpha + pv.reshape(N, D)
+            p, vt, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_ref[:, rows, 0:1] = m_new
+        l_ref[:, rows, 0:1] = alpha * l_ref[:, rows, 0:1] + jnp.sum(
+            p, axis=2, keepdims=True)
+        acc_ref[:, rows, :] = acc_ref[:, rows, :] * alpha + pv
 
-    @pl.when(j == n_blocks_per_seq - 1)
-    def _finalize():
-        l = l_ref[:, 0:1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+    def fetch(t, nblk, i, slot, start):
+        """Start, or wait for, the copies of blocks ``i*P ..`` of row
+        ``t``'s table that lie under ``nblk``."""
+        def page(p, _):
+            j = i * P + p
+
+            @pl.when(j < nblk)
+            def _():
+                blk = tables_ref[t, j]
+                for pool, buf, sem in ((k_hbm, kbuf, sems.at[0, slot]),
+                                       (v_hbm, vbuf, sems.at[1, slot])):
+                    copy = pltpu.make_async_copy(
+                        pool.at[blk], buf.at[slot, p], sem)
+                    copy.start() if start else copy.wait()
+
+        jax.lax.fori_loop(0, P, page, None)
+
+    def walk(r0, r1):
+        """Rows ``r0 .. r1`` of the tile carry one table: walk it once,
+        ``P`` blocks a step, fetch i+1 in flight while i is computed."""
+        t = t0 + r0
+        nblk = pl.cdiv(jax.lax.fori_loop(
+            r0, r1, lambda r, n: jnp.maximum(n, length(r)), 0), bs)
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, M, 1), 1)
+        limit = jnp.where((row >= r0 * rep) & (row < r1 * rep),
+                          len_ref[:, 0:1][None], 0)         # [1, M, 1]
+
+        def step(i, _):
+            slot = i % 2
+
+            @pl.when((i + 1) * P < nblk)
+            def _():
+                fetch(t, nblk, i + 1, 1 - slot, True)
+
+            fetch(t, nblk, i, slot, False)
+            k = kbuf[slot].reshape(C, K, D).astype(jnp.float32)
+            v = vbuf[slot].reshape(C, K, D).astype(jnp.float32)
+            kt, vt = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)  # [K, C, D]
+            # a row alone (a decode row) meets the blocks alone; a run
+            # meets them with the whole tile, rows outside it masked out
+            jax.lax.cond(
+                r1 - r0 == 1,
+                lambda: online_softmax(i, kt, vt, pl.ds(r0 * rep, rep),
+                                       length(r0)),
+                lambda: online_softmax(i, kt, vt, slice(None), limit))
+
+        fetch(t, nblk, 0, 0, True)
+        jax.lax.fori_loop(0, pl.cdiv(nblk, P), step, None)
+
+        def put(r, _):
+            rows = pl.ds(r * rep, rep)
+            l = l_ref[:, rows, 0:1]
+            out = acc_ref[:, rows, :] / jnp.where(l == 0.0, 1.0, l)
+            o_ref[pl.ds(r, 1)] = out.reshape(1, N, D).astype(o_ref.dtype)
+
+        jax.lax.fori_loop(r0, r1, put, None)
+
+    def next_run(r0):
+        r1 = jax.lax.while_loop(
+            lambda r: jnp.logical_and(r < R, same(jnp.minimum(r, R - 1))),
+            lambda r: r + 1, r0 + 1)
+        walk(r0, r1)
+        return r1
+
+    jax.lax.while_loop(lambda r: r < R, next_run, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
+def _tiles(tables, meta, q, kpool, vpool, interpret):
+    """The kernel over whole tiles. Jitted (inlined into the caller's
+    program) for its trace cache alone: the kernel body is traced once per
+    set of operand shapes, not once per program that calls it."""
+    T, N, D = q.shape
+    _, bs, K, _ = kpool.shape
+    rep = N // K
+    R = tile_rows(N, K)
+    P = _blocks_per_fetch(bs, K, D, kpool.dtype.itemsize)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(T // R,),
+        in_specs=[
+            pl.BlockSpec((R, N, D), lambda i, tbl, meta: (i, 0, 0)),
+            hbm, hbm,
+        ],
+        out_specs=pl.BlockSpec((R, N, D), lambda i, tbl, meta: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, P, bs, K, D), kpool.dtype),
+            pltpu.VMEM((2, P, bs, K, D), vpool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((K, R * rep, D), jnp.float32),
+            pltpu.VMEM((R * rep, _LANES), jnp.int32),
+            pltpu.VMEM((K, R * rep, _LANES), jnp.float32),
+            pltpu.VMEM((K, R * rep, _LANES), jnp.float32),
+            pltpu.VMEM((K, R * rep, D), jnp.float32),
+        ],
+    )
+    compiler_params = None
+    if not interpret:
+        # the fetch slots are cleared on the first step and reused by the
+        # next, so the tiles run in order
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))
+    return pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, N, D), q.dtype),
+        compiler_params=compiler_params,
+        interpret=interpret,
+        name="paged_attention",
+    )(tables, meta, q, kpool, vpool)
 
 
 def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
@@ -95,38 +260,19 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     if interpret is None:
         interpret = _use_interpret()
     Tn, N, D = q.shape
-    NB, bs, K, D2 = kpool.shape
-    assert D == D2 and N % K == 0
-    rep = N // K
-    MB = tables.shape[1]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Tn, MB),
-        in_specs=[
-            pl.BlockSpec((1, N, D), lambda t, j, tbl, ln: (t, 0, 0)),
-            pl.BlockSpec((1, bs, K, D),
-                         lambda t, j, tbl, ln: (tbl[t, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, K, D),
-                         lambda t, j, tbl, ln: (tbl[t, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, N, D), lambda t, j, tbl, ln: (t, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((N, D), jnp.float32),
-            pltpu.VMEM((N, 128), jnp.float32),
-            pltpu.VMEM((N, 128), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_kernel, bs=bs, rep=rep, n_blocks_per_seq=MB)
-    compiler_params = None
-    if not interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tn, N, D), q.dtype),
-        compiler_params=compiler_params,
-        interpret=interpret,
-        name="paged_attention",
-    )(tables, lengths, q, kpool, vpool)
+    assert D == kpool.shape[3] and N % kpool.shape[2] == 0
+    pad = -Tn % tile_rows(N, kpool.shape[2])
+    if pad:                            # pad rows: zero table, length 1
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+        tables = jnp.pad(tables, ((0, pad), (0, 0)))
+        lengths = jnp.pad(lengths, (0, pad), constant_values=1)
+    # every table tier of a tick bucket hands the kernel the same shapes, so
+    # its body is traced once a bucket, not once a (Tn, mb) program; the
+    # columns added are never read (walks end at ceil(length / bs))
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % _TABLE_COLS)))
+    same = jnp.concatenate([
+        jnp.zeros((1,), jnp.bool_),
+        jnp.all(tables[1:] == tables[:-1], axis=1)])
+    meta = jnp.concatenate([lengths.astype(jnp.int32),
+                            same.astype(jnp.int32)])
+    return _tiles(tables, meta, q, kpool, vpool, interpret)[:Tn]

@@ -1,0 +1,58 @@
+"""Kernels of the serving path compiled for the v5e at the benchmark's
+widths, without a chip: the TPU's compiler is installed here and compiles
+for a described topology. What Mosaic refuses (a slice off the tiling, too
+much VMEM, an unsupported relayout) interpret mode never shows; this does,
+at no chip time. Nothing runs, so it says nothing about results or speed.
+
+Keep every such compile in THIS file: the topology is described inside a
+fixture, so only the pytest worker that is handed this file loads libtpu.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (rows of the tick bucket, query heads, KV heads, blocks of a layer's pool,
+# table tier) of the two serving cells, depth 16, blocks of 32, heads of 128
+PAGED_SHAPES = {
+    "mistral7b-512x64": (512, 32, 8, 2400, 64),
+    "mistral7b-64x16": (64, 32, 8, 2400, 16),
+    "pythia69b-512x24": (512, 32, 32, 640, 24),
+    "pythia69b-64x24": (64, 32, 32, 640, 24),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(PAGED_SHAPES))
+def test_paged_attention_compiles_for_v5e(one_chip, shape):
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    T, N, K, NB, MB = PAGED_SHAPES[shape]
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = arg((16 * NB, 32, K, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, t, n: paged_attention(q, k, v, t, n, interpret=False)
+    ).lower(arg((T, N, 128), jnp.bfloat16), pool, pool,
+            arg((T, MB), jnp.int32), arg((T,), jnp.int32)).compile()
+    text = compiled.as_text()
+    # one Mosaic call, operands (tables, lengths+same, q, kpool, vpool):
+    # benchmarks/roofline/paged_attention.py reads the pool at operand 3
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1

@@ -34,6 +34,10 @@ program spans that cover it; what no program span covers is the
 read-back after the device finished, the launch after the jitted call
 returned or after the enqueue). Only durations are compared across the two clocks.
 Every metric is None unless runs, enqueues and spans match one to one.
+
+The same join gives ``readers.traced_ticks`` its rows (``ticks``): the
+device time of a tick's run beside the wall time of the ``serving_tick``
+span around the span that dispatched it.
 """
 from __future__ import annotations
 
@@ -57,6 +61,10 @@ CHAINS = {
         "dispatch": "tick_dispatch",
         # the read-back follows the dispatch of its tick
         "fence": "tick_readback", "fence_encloses_dispatch": False,
+        # the frontend's span around a whole tick, and the runner's mark
+        # after it that says whether the tick held prompt rows
+        "tick": "serving_tick",
+        "marks": {"bench.tick.mixed": True, "bench.tick.decode": False},
         "owners": {"schedule_tick": "schedule", "tick_dispatch": "dispatch",
                    "decode_tick": "dispatch", "tick_readback": "dispatch",
                    "tick_commit": "commit", "serving_harvest": "frontend",
@@ -346,6 +354,36 @@ def chain(hd: HostDevice, spec: Dict[str, Any], window: tr.Interval
     return report, rows, [r for r, _, _ in joined]
 
 
+def ticks(hd: HostDevice, spec: Dict[str, Any], joined: List[Run],
+          window: tr.Interval) -> List[Dict[str, Any]]:
+    """One row per joined run of the tick program (``chain``'s) whose tick
+    the stretch holds whole on both clocks (the run on the device's, as
+    ``chain`` saw to; span and mark on the host's): the wall time of the
+    ``serving_tick`` span around its dispatching span, the device time of
+    the run, and whether the tick held prompt rows (the runner's mark
+    that follows the span, before the next tick begins). Run and span are
+    joined by ``run_id`` and by enclosure on the host's clock, never by
+    order or across the two clocks, so a stretch that cuts a tick on one
+    clock and not on the other loses that tick and no other."""
+    whole = hd.spans[spec["tick"]]
+    marks = sorted((a, mixed) for name, mixed in spec["marks"].items()
+                   for a, _ in hd.spans[name])
+    rows = []
+    for r in joined:
+        lo, hi = hd.spans[spec["dispatch"]][hd.last_started(
+            spec["dispatch"], hd.enqueue[r.run_id].start)]
+        t = hd.last_started(spec["tick"], lo)
+        if t is None or whole[t][1] < hi or whole[t][0] < window[0]:
+            continue                  # the stretch began inside this tick
+        m = bisect.bisect_left(marks, (whole[t][1],))
+        if m == len(marks) or marks[m][0] > window[1] or (
+                t + 1 < len(whole) and marks[m][0] > whole[t + 1][0]):
+            continue                  # it ended before the tick's mark
+        rows.append({"wall": whole[t][1] - whole[t][0],
+                     "device": r.end - r.start, "mixed": marks[m][1]})
+    return rows
+
+
 def idle_check(rows, joined: List[Run], reduced, chip: int
                ) -> Dict[str, Any]:
     """Gaps plus the idle time inside the program's runs, against the idle
@@ -446,9 +484,14 @@ def analyse(run) -> Dict[str, Any]:
         out["unmatched"] = {"reason": "no trace of this run"}
         return out
     spec, chip = CHAINS[kind], run.trace.chips[0]
-    hd = HostDevice(path, set(spec["owners"]) | {spec["fence"]}, chip)
+    hd = HostDevice(path, set(spec["owners"]) | {spec["fence"]}
+                    | set(spec.get("marks", ())), chip)
     report, rows, joined = chain(hd, spec, run.trace.window)
     out.update(report)
+    if "marks" in spec:
+        # rows for ``readers.traced_ticks``; too many for the report
+        run.cache["ticks"] = ticks(hd, spec, joined, run.trace.window)
+        out["ticks_whole"] = len(run.cache["ticks"])
     out["span_mean_us"] = span_means(run, hd, spec)
     if not rows:
         return out

@@ -217,5 +217,7 @@ class RunRecord:
     telemetry: Optional[Telemetry] = None
     trace: Any = None                # ReducedTrace of the traced stretch
     extras: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    #: what one reader worked out for the others; never printed
+    cache: Dict[str, Any] = dataclasses.field(default_factory=dict)
     attempted: int = 0
     failed: int = 0

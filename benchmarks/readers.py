@@ -10,10 +10,6 @@ from typing import Any, Dict, List, Optional
 from benchmarks import stats
 from benchmarks.runners import serve as _serve
 
-#: the tick program's name as the trace's ``XLA Modules`` line has it
-TICK_MODULE = "jit_tick"
-
-
 # ---------------- serving: the client's log ---------------- #
 def measured(run) -> List[Dict[str, Any]]:
     return _serve.measured(run.client)
@@ -67,23 +63,21 @@ def pct_ms(values, q) -> Optional[float]:
 
 # ---------------- serving: ticks in the trace ---------------- #
 def traced_ticks(run) -> Optional[List[Dict[str, Any]]]:
-    """One row per tick of the traced stretch: the ``serving_tick`` span's
-    wall time, the device time of the tick's program, and whether the tick
-    held prompt rows. Spans, programs and the benchmark's own per-tick
-    marks are matched by order (the clocks of host and device differ by
-    about a millisecond); None unless the three counts agree."""
-    tr = run.trace
-    if tr is None:
+    """One row per tick that the traced stretch holds whole: the
+    ``serving_tick`` span's wall time, the device time of the tick's
+    program, and whether the tick held prompt rows (the benchmark's own
+    per-tick mark). A run of the program finds its span through the
+    ``run_id`` it shares with its enqueue (``gap_chain.ticks``): the clocks
+    of host and device differ by a millisecond or two, so the window's edge
+    can cut a tick on one clock and not on the other (231 spans against
+    230 runs, PERF.md, PR 24), and counting the two would then match
+    nothing. None without a trace or where the join found nothing."""
+    from benchmarks import gap_chain
+
+    if run.trace is None:
         return None
-    spans = tr.spans("serving_tick")
-    mods = tr.module_runs(TICK_MODULE)
-    marks = sorted(tr.spans("bench.tick.mixed") + tr.spans("bench.tick.decode"),
-                   key=lambda s: s.start)
-    if not spans or not (len(spans) == len(mods) == len(marks)):
-        return None
-    return [{"wall": s.end - s.start, "device": m.end - m.start,
-             "mixed": k.name.endswith("mixed")}
-            for s, m, k in zip(spans, mods, marks)]
+    gap_chain.analyse(run)
+    return run.cache.get("ticks") or None
 
 
 # ---------------- kernels ---------------- #

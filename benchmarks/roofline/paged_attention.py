@@ -1,24 +1,29 @@
 """What ``ops/pallas/paged_attention.py``'s Mosaic kernel needs.
 
-Operands: ``tables s32[T, MB]``, ``lengths s32[T]``, ``q [T, N, D]``,
-``kpool, vpool [L*NB, bs, K, D]``. The kernel walks a (row, block) grid,
-one row of the flat token batch at a time. Paged attention is
-memory-bound at every shape the cells use (a row's N heads do
-``4 * D`` operations per cached position-head pair against ``4 * D *
-K / N`` bytes), so what it *needs* is bytes: every sequence with rows in
-the tick has its cached key and value blocks read once. How many blocks
-that is depends on the sequences' lengths, which are run-time values the
-trace does not hold, so the runner logs them per tick
-(``blocks`` in the client's tick log: for each sequence with rows in the
-tick, the blocks its cache holds after it) and the block's bytes come
-from the pool's shape in the trace.
+Operands: ``tables s32[T, MB]``, ``lengths s32[2 * T]`` (the rows' lengths
+and, behind them, whether a row shares its table with the row before),
+``q [T, N, D]``, ``kpool, vpool [L*NB, bs, K, D]``, the pools left in HBM.
+Since PR 24 the kernel's grid is tiles of 32 rows of the flat token
+batch; inside a tile it splits the rows into runs that share a block
+table (a prompt chunk, the pad rows; a decode row is a run of one) and
+walks each run's table once, a few blocks a fetch step, stopping at
+``ceil(length / bs)``. Paged attention is memory-bound at every shape the
+cells use (a row's N heads do ``4 * D`` operations per cached
+position-head pair against ``4 * D * K / N`` bytes), so what it *needs*
+is bytes: every sequence with rows in the tick has its cached key and
+value blocks read once. How many blocks that is depends on the
+sequences' lengths, which are run-time values the trace does not hold,
+so the runner logs them per tick (``blocks`` in the client's tick log:
+for each sequence with rows in the tick, the blocks its cache holds
+after it) and the block's bytes come from the pool's shape in the trace.
 
-Two byte models that use shapes alone were tried and are wrong (PR 22,
-PERF.md): "every block the table tier covers, for every row" reads 287 %
-in the decode cell, because the kernel does not fetch blocks past a row's
-length. A kernel that reads a sequence's blocks once per *row* (as this
-one does for the hundreds of prompt rows of a chunk) shows here as a
-small share: that is the headroom of grouping rows by sequence.
+What the kernel moves beyond that need, and the share therefore shows as
+headroom: a chunk that spans several tiles walks its table once per tile,
+not once per tick, and a walk's first fetch is not hidden behind the walk
+before it (54 % in the decode cell, 21 % in the chat cell; PERF.md, PR
+24). A byte model from shapes alone is wrong: "every block the table
+tier covers, for every row" read 287 % in the decode cell (PR 22),
+because no form of the kernel fetches blocks past a row's length.
 """
 from __future__ import annotations
 

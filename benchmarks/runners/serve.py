@@ -360,14 +360,14 @@ def run(cell: Cell, args, device: Dict[str, Any]) -> harness.RunRecord:
 
     rows = measured(client)
     marks = client["marks"]
+    telemetry = harness.Telemetry(marks["open"]["telemetry"],
+                                  marks["close"]["telemetry"])
     log(f"serve: window done, {len(rows)} requests measured, "
         f"{len(client['ticks'])} ticks")
     return harness.RunRecord(
         cell=cell, seconds=args.seconds, chips=cell.chips, device=device,
         peaks=None, model=session.cfg, setup_s=setup_s, client=client,
-        telemetry=harness.Telemetry(marks["open"]["telemetry"],
-                                    marks["close"]["telemetry"]),
-        trace=client["trace"],
+        telemetry=telemetry, trace=client["trace"],
         extras={"logits_rel_diff": rel,
                 "program_peak_bytes": devmod.program_peak_bytes(
                     peak_warm, peak_checked,
@@ -377,5 +377,11 @@ def run(cell: Cell, args, device: Dict[str, Any]) -> harness.RunRecord:
                 "active_at_open": marks["open"]["active"],
                 "active_at_close": marks["close"]["active"],
                 "compiles_after_warmup": compiles_in_run,
+                # a run that sheds or degrades measured another path:
+                # seen in untraced runs too (``tools/repeat.py`` keeps them)
+                "kv_pool_peak": telemetry.gauge(
+                    "fastgen_kv_pool_utilization_peak"),
+                "degraded": sum(1 for r in rows if r["degraded"]),
+                "refused": sum(1 for r in rows if not r["admitted"]),
                 "engine": dict(deploy["engine"])},
         attempted=len(rows), failed=sum(1 for r in rows if failed(r)))

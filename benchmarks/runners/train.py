@@ -20,8 +20,8 @@ from benchmarks import harness, model_config
 from benchmarks.harness import check, log
 from benchmarks.manifest import Cell, load_plugin
 
-#: the batch the reference comparison is made on: one the feed never
-#: reaches, trained on once after the warm-up
+#: the first batch the reference comparison is made on: ones the feed
+#: never reaches, each trained on once after the warm-up
 CHECKED_BATCH = 10 ** 9
 
 
@@ -75,24 +75,33 @@ def run(cell: Cell, args, device: Dict[str, Any]) -> harness.RunRecord:
     # every program of the window has run: the program's own peak
     peak_warm = devmod.memory_peak_bytes(chips)
 
-    # ---- reference loss on the next batch, from the weights the next
-    # step will compute with (fp32 master rounded to the compute type);
-    # after the warm-up, so that the peak above is the program's alone ----
+    # ---- reference loss on batches the feed never reaches, each from the
+    # weights its step will compute with (fp32 master rounded to the
+    # compute type; the step then trains on it, as any step does); after
+    # the warm-up, so that the peak above is the program's alone. How many
+    # batches is the cell file's ``loss_check.batches`` (1 where absent):
+    # the two means are compared ----
     hf = model_config.hf_kwargs(cell.config, "train")
     if args.rehearse:
         hf.update(cell.config["rehearse"])
     arch = reference.arch_from_config(cell.config, hf)
-    as_computed = jax.tree.map(
-        lambda x: x.astype(cfg.compute_dtype), engine.state["master"])
-    checked = batches.batch(CHECKED_BATCH, chips)
-    ref_loss = reference.next_token_loss(as_computed, checked, arch)
-    del as_computed
-    peak_checked = devmod.memory_peak_bytes(chips)
-    loss_checked = float(engine.train_batch(iter([{"tokens": checked}])))
+    pairs = []                                   # (engine loss, reference)
+    for b in range(int(deploy["loss_check"].get("batches", 1))):
+        as_computed = jax.tree.map(
+            lambda x: x.astype(cfg.compute_dtype), engine.state["master"])
+        checked = batches.batch(CHECKED_BATCH + b, chips)
+        ref = reference.next_token_loss(as_computed, checked, arch)
+        del as_computed
+        peak_checked = devmod.memory_peak_bytes(chips)   # a running peak
+        pairs.append((float(engine.train_batch(iter([{"tokens": checked}]))),
+                      ref))
+    loss_checked, ref_loss = (float(np.mean(x)) for x in zip(*pairs))
     tol = float(deploy["loss_check"]["rel_tol"])
     rel = abs(loss_checked - ref_loss) / abs(ref_loss)
-    log(f"train: loss on the checked batch {loss_checked:.5f}, reference "
-        f"{ref_loss:.5f} (rel. diff {rel:.2e}, tolerance {tol:.0e})")
+    log(f"train: loss on the {len(pairs)} checked batch(es) "
+        f"{loss_checked:.5f}, reference {ref_loss:.5f} (rel. diff {rel:.2e}, "
+        f"tolerance {tol:.0e}); each, signed: "
+        + " ".join(f"{(a - r) / abs(r):+.2e}" for a, r in pairs))
     # the compiled step's collectives and HLO: the only public way to them
     # lowers and compiles the step again (9.5 s warm on four chips), and
     # only per-layer metrics read it, so only a traced run pays for it
@@ -162,7 +171,7 @@ def run(cell: Cell, args, device: Dict[str, Any]) -> harness.RunRecord:
                                 ledger.totals_by_kind().items()}
                 if ledger else None,
                 "loss_first": loss0, "loss_checked": loss_checked,
-                "loss_ref": ref_loss,
+                "loss_ref": ref_loss, "loss_checked_pairs": pairs,
                 "loss_rel_diff": rel, "loss_last5": float(
                     np.mean(losses[-5:])) if losses else None,
                 "steps": len(steps),

@@ -4,7 +4,8 @@ from benchmarks.generators import closed_loop, common, open_poisson, zipf_batche
 from benchmarks.manifest import BENCH_DIR, load_json
 import os
 
-CHAT = load_json(os.path.join(BENCH_DIR, "traffic", "chat-steady.json"))["params"]
+CHAT = load_json(os.path.join(BENCH_DIR, "traffic", "chat-steady-v2.json"))["params"]
+PER_WINDOW = round(CHAT["rate_per_s"] * 50)     # arrivals in a 50 s window
 CLOSED = load_json(os.path.join(BENCH_DIR, "traffic", "decode-closed.json"))["params"]
 
 
@@ -39,7 +40,7 @@ def test_open_schedule_is_recorded_and_the_seed_draws_the_tokens():
 
 def test_open_fixed_work_in_every_stratum():
     fresh = {k: v for k, v in CHAT.items() if k != "schedule_seed"}
-    per = int(CHAT["rate_per_s"] * CHAT["stratum_s"])
+    per = round(CHAT["rate_per_s"] * CHAT["stratum_s"])
     for seed in (1, 2):
         src = _open(seed, fresh)
         assert len(src.requests) == 6 * per
@@ -64,7 +65,7 @@ def test_open_arrivals_are_poisson_bursts_and_lulls():
         dues = np.array([r.due for r in _open(seed, fresh, 0.0, 50.0).requests])
         gaps = np.diff(dues)
         cvs.append(gaps.std() / gaps.mean())
-        counts = np.bincount((dues // slot).astype(int), minlength=160)
+        counts = np.bincount((dues // slot).astype(int), minlength=PER_WINDOW)
         empty.append((counts == 0).mean())
         crowded.append((counts >= 3).mean())
     assert 0.9 < np.mean(cvs) < 1.1
@@ -72,7 +73,7 @@ def test_open_arrivals_are_poisson_bursts_and_lulls():
     assert 0.05 < np.mean(crowded) < 0.11        # 1 - 5/(2e) = 0.08
     # and the recorded draw is one of them, not a smoothed stream
     dues = np.array([r.due for r in _open(0, CHAT, 0.0, 50.0).requests])
-    counts = np.bincount((dues // slot).astype(int), minlength=160)
+    counts = np.bincount((dues // slot).astype(int), minlength=PER_WINDOW)
     assert (counts == 0).mean() > 0.3 and counts.max() >= 3
     assert np.diff(dues).max() > 3 * slot
 

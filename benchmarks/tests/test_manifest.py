@@ -86,6 +86,46 @@ def test_every_cell_finds_its_files_and_readers():
             assert set(role) == {"num_hidden_layers"}
 
 
+def _open_loop_cells():
+    cells = [manifest.load_cell(w["name"]) for w in M["workloads"]]
+    return [c for c in cells if c.traffic["generator"] == "open_poisson"]
+
+
+def test_open_loop_strata_hold_a_whole_number_of_arrivals():
+    """``rate_per_s * stratum_s`` is rounded by the generator: a rate that
+    does not divide would be offered as another rate than the file says."""
+    assert _open_loop_cells()
+    for cell in _open_loop_cells():
+        p = cell.traffic["params"]
+        per = p["rate_per_s"] * p["stratum_s"]
+        assert abs(per - round(per)) < 1e-9 and round(per) >= 1, cell.name
+        assert M["run_seconds"] % p["stratum_s"] == 0, cell.name
+
+
+def test_every_tail_has_ten_samples_beyond_it():
+    """A percentile with fewer than ten samples beyond it is a maximum of
+    a few (choosing-metrics, section 1). Counted from the mix as the
+    generator lays it over one window of ``run_seconds``: requests due for
+    a tail of the time to first token, gaps between their tokens for a
+    tail of the gap."""
+    from benchmarks.manifest import load_plugin
+
+    seen = 0
+    for cell in _open_loop_cells():
+        src = load_plugin("generators", cell.traffic["generator"]).build(
+            cell.traffic["params"], 0, 32000, 4096, 0.0,
+            float(M["run_seconds"]))
+        samples = {"ttft": len(src.requests),
+                   "itl": sum(r.max_new - 1 for r in src.requests)}
+        for m in cell.end_to_end:
+            tail = re.search(r"_(ttft|itl)_p(\d+)_", m.name)
+            if tail:
+                beyond = samples[tail.group(1)] * (1 - int(tail.group(2)) / 100)
+                assert beyond >= 10, (cell.name, m.name, beyond)
+                seen += 1
+    assert seen >= 2
+
+
 def test_files_under_paths_are_named_from_allowed_characters():
     ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
     for base, dirs, files in os.walk(manifest.BENCH_DIR):

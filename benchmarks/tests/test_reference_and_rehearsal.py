@@ -47,6 +47,22 @@ def test_reference_agrees_with_the_program_forward(name):
     assert abs(loss - np.log(cfg.vocab_size)) < 0.5
 
 
+@pytest.mark.parametrize("seed", [3, 3000000061, 2 ** 31 + 5])
+def test_weights_come_from_the_seed_whatever_its_size(seed):
+    """The seed is an argument of the one weight program (a driver's seeds
+    pass 2**31): the same seed gives the same weights, another seed
+    others."""
+    import jax
+
+    conf = manifest.load_json(os.path.join(manifest.ROOT,
+                                           M["configs"][0]["file"]))
+    cfg = model_config.build(conf, "serve", rehearse=True)
+    a, b, c = (jax.tree.leaves(weights.init_on_device(cfg, s))
+               for s in (seed, seed, seed + 1))
+    assert all((x == y).all() for x, y in zip(a, b))
+    assert all((x != y).any() for x, y in zip(a, c))
+
+
 def test_sliding_window_is_applied_by_the_reference():
     import jax.numpy as jnp
 

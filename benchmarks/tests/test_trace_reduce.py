@@ -107,3 +107,78 @@ def test_module_runs_and_spans(probe):
     assert len(probe.spans("probe.paged")) == 2
     top = probe.top_ops(10)
     assert len(top) <= 10 and top[0][1] >= top[-1][1]
+
+
+# --------------------------------------------------------------------- #
+# the ticks of a traced stretch (``readers.traced_ticks``) on the recorded
+# serving chain: 13 decode ticks, runs 0.0458-0.1161 on the device's clock
+# inside ``serving_tick`` spans 0.0450-0.1184 on the host's
+# --------------------------------------------------------------------- #
+CHAIN_SERVE = os.path.join(os.path.dirname(PROBE), "v5e_chain_serve.xplane.pb")
+TICK_READERS = ("tick_host_p50_ms", "tick_dev_decode_p50_ms",
+                "tick_dev_mixed_p50_ms")
+
+
+def _traced_serving_run(monkeypatch, window=None):
+    import types
+
+    from benchmarks import gap_chain
+
+    monkeypatch.setattr(gap_chain, "trace_file", lambda run: CHAIN_SERVE)
+    trace = tr.ReducedTrace.from_file(CHAIN_SERVE)
+    if window is not None:
+        trace.window = window
+    return types.SimpleNamespace(
+        trace=trace, extras={}, cache={}, telemetry=None, peaks=None,
+        cell=types.SimpleNamespace(runner="serve", name="recorded"))
+
+
+def test_traced_ticks_join_run_span_and_mark(monkeypatch):
+    from benchmarks import readers
+
+    run = _traced_serving_run(monkeypatch)
+    rows = readers.traced_ticks(run)
+    mods = run.trace.module_runs("jit_tick")
+    spans = run.trace.spans("serving_tick")
+    assert len(rows) == len(mods) == len(spans) == 13
+    assert [r["device"] for r in rows] == pytest.approx(
+        [m.end - m.start for m in mods])
+    assert [r["wall"] for r in rows] == pytest.approx(
+        [s.end - s.start for s in spans])
+    assert not any(r["mixed"] for r in rows)      # 13 ``bench.tick.decode``
+    assert run.extras["gap_chain"]["ticks_whole"] == 13
+    run.trace = None                              # an untraced run
+    assert readers.traced_ticks(run) is None
+
+
+@pytest.mark.parametrize("cut,lost", [
+    # the stretch begins inside the first tick: after its span began on the
+    # host's clock, before its run began on the device's (12 spans, 13 runs)
+    ("start", 0),
+    # ... ends inside the last tick's span, after its run (12 spans, 13 runs)
+    ("end_host", 12),
+    # ... ends inside the last run (PR 24's case: more spans than runs)
+    ("end_device", 12)])
+def test_traced_ticks_on_a_stretch_that_cuts_a_tick(monkeypatch, cut, lost):
+    """Counting spans against runs matched nothing here and every tick
+    reader returned None; the join by ``run_id`` loses the cut tick only."""
+    from benchmarks import readers
+    from benchmarks.manifest import load_plugin
+
+    whole = readers.traced_ticks(_traced_serving_run(monkeypatch))
+    lo, hi = tr.ReducedTrace.from_file(CHAIN_SERVE).window
+    window = {"start": (0.0455, hi), "end_host": (lo, 0.1183),
+              "end_device": (lo, 0.1150)}[cut]
+    run = _traced_serving_run(monkeypatch, window)
+    n_spans = len(run.trace.spans("serving_tick"))
+    n_runs = len(run.trace.module_runs("jit_tick"))
+    assert (n_spans, n_runs) == {"start": (12, 13), "end_host": (12, 13),
+                                 "end_device": (12, 12)}[cut]
+    rows = readers.traced_ticks(run)
+    assert rows == whole[:lost] + whole[lost + 1:]
+    host = load_plugin("layer_metrics", "tick_host_p50_ms").read(run)
+    dev = load_plugin("layer_metrics", "tick_dev_decode_p50_ms").read(run)
+    assert 2.0 < host < 4.0 and 2.5 < dev < 3.0          # ms, as recorded
+    # no tick of the recording held prompt rows: nothing to read, no error
+    assert load_plugin("layer_metrics", "tick_dev_mixed_p50_ms").read(run) \
+        is None

@@ -52,6 +52,7 @@ def main(argv=None) -> int:
     session = serve.Session(cell, args)
     session.warm()
     session.check_logits()
+    compiles = harness.CompileCounter()
     rows = []
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         traffic = dict(cell.traffic["params"])
@@ -92,12 +93,16 @@ def main(argv=None) -> int:
             "ticks": len(ticks),
             "mixed_tick_share_pct": 100.0 * sum(
                 1 for t in ticks if t[2] > 0) / max(1, len(ticks)),
+            "decode_rows_per_tick": sum(t[3] for t in ticks) / max(1, len(ticks)),
             "tick_p50_ms": readers.pct_ms([t[1] - t[0] for t in ticks], 50),
             "tick_max_ms": readers.pct_ms([t[1] - t[0] for t in ticks], 100),
             "gen_late_p99_ms": readers.pct_ms(readers.lateness_s(run), 99),
             "kv_peak": harness.Telemetry(
                 marks["open"]["telemetry"], marks["close"]["telemetry"]
             ).gauge("fastgen_kv_pool_utilization_peak"),
+            # programs lowered since the warm-up, all rates so far: a cell
+            # at this rate would fail ``correct`` on any
+            "compiles_after_warmup": compiles.count,
         }
         row["sustained"] = bool(
             row["backlog_close"] <= row["backlog_open"] * 1.25 + 5

@@ -45,12 +45,15 @@ def init_on_device(cfg, seed: int):
             return jax.lax.map(lambda k: make(path, shape[1:], k), keys)
         return make(path, shape, key)
 
+    # the seed is an argument, not a constant of the program: one program
+    # for every seed, so only the first run of a checkout compiles it (as a
+    # constant, every new seed compiled its own, 2.5-6 s of each set-up)
     @jax.jit
-    def init():
+    def init(seed):
         key = jax.random.key(seed, impl="rbg")
         keys = jax.random.split(key, len(leaves))
         return treedef.unflatten([
             stacked(path, leaf.shape, keys[i])
             for i, (path, leaf) in enumerate(leaves)])
 
-    return init()
+    return init(jnp.uint32(seed % 2 ** 32))

@@ -204,11 +204,17 @@ class FastGenEngine:
             use_pallas_kernel = jax.default_backend() == "tpu"
         self._use_kernel = use_pallas_kernel
         # rows of a kernel tile; 0 where a tick's attention is not the
-        # kernel's (forward_paged sends ALiBi and MLA to the reference)
+        # kernel's (forward_paged sends ALiBi to the reference). Latent
+        # attention is the kernel with one KV head.
         self._tile_rows = 0
-        if use_pallas_kernel and not cfg.mla and cfg.pos_emb != "alibi":
+        if use_pallas_kernel and cfg.pos_emb != "alibi":
             from deepspeed_tpu.ops.pallas.paged_attention import tile_rows
-            self._tile_rows = tile_rows(cfg.num_heads, cfg.kv_heads)
+            self._tile_rows = tile_rows(
+                cfg.num_heads, 1 if cfg.mla else cfg.kv_heads)
+        # expert layers whose per-expert row counts ride back with a
+        # tick's sampled tokens; 0 for a model without experts
+        self._expert_layers = sum(
+            c.num_layers for _, c in cfg.segments if c.n_experts)
 
     def _dev(self, x) -> jax.Array:
         """Host array → device; REPLICATED across the mesh under TP (a
@@ -264,6 +270,13 @@ class FastGenEngine:
             "prompt rows of step() ticks that sat in a kernel tile wholly "
             "inside one chunk (their tile walked the sequence's blocks "
             "once); over fastgen_prefill_tokens_total: the hit share")
+        self._tm_expert_imbalance = telemetry.histogram(
+            "fastgen_expert_load_imbalance",
+            "step() ticks of an expert model, by tick bucket: the busiest "
+            "expert's rows over the mean rows an expert got, each summed "
+            "over the expert layers (1 = even routing)",
+            buckets=(1.0, 1.25, 1.5, 1.75, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0,
+                     12.0, 16.0, 32.0, 64.0))
         self._tm_preempt = telemetry.counter(
             "fastgen_preemptions_total",
             "sequences deferred a tick by KV-pool backpressure")
@@ -393,13 +406,18 @@ class FastGenEngine:
             attn = PG.paged_attention_reference
 
         def tick(params, pool, tokens, positions, tables, rng):
-            logits, pool = PG.forward_paged(
+            logits, pool, *stats = PG.forward_paged(
                 params, tokens, positions, tables, pool, cfg,
-                attention_fn=attn)
+                attention_fn=attn, with_stats=bool(self._expert_layers))
             with jax.named_scope("sample"):
                 sampled = sample_logits(
                     logits, rng, self.temperature, self.top_k,
                     self.top_p).astype(jnp.int32)
+            if stats:
+                # one array, one read-back: the rows each expert got
+                # ([layers, E]) behind the sampled tokens
+                sampled = jnp.concatenate(
+                    [sampled, stats[0]["expert_rows"].reshape(-1)])
             return sampled, pool
 
         return jax.jit(tick, donate_argnums=(1,))
@@ -999,8 +1017,13 @@ class FastGenEngine:
                 "tick": self._ticks_run, "kind": kind, "rows": row,
                 "decode_rows": n_decode_rows,
                 "prefill_tokens": row - n_decode_rows,
+                # cache positions the prompt rows attend to (position + 1
+                # a row; decode rows come first in the tick): with the
+                # tick's device time, what its attention had to compute
+                "prompt_attended": int(positions[n_decode_rows:row].sum())
+                + row - n_decode_rows,
                 "shared_rows": shared_rows, "bucket": Tn,
-                "mb_tier": tier}):
+                "mb_tier": tier}) as tick_span:
             # enqueue: the host-to-device copies and the jitted call,
             # until it returns (the device may still be running)
             with telemetry.span("tick_dispatch"):
@@ -1011,7 +1034,25 @@ class FastGenEngine:
             # the wait for the device, then the copy back
             with telemetry.span("tick_readback"):
                 sampled = np.asarray(jax.device_get(sampled))
-        with telemetry.span("tick_commit"):
+            expert_rows, commit_attrs = None, None
+            if self._expert_layers:
+                expert_rows = sampled[Tn:].reshape(self._expert_layers, -1)
+                tick_span.note(
+                    experts_max_rows=int(expert_rows.max()),
+                    experts_mean_rows=float(expert_rows.mean()))
+                # the tick's own annotation was written at entry: what
+                # came back with the tokens rides on the span that follows.
+                # Experts with a row, summed over the expert layers: times
+                # an expert's bytes, the weights this tick had to read
+                commit_attrs = {
+                    "tick": self._ticks_run,
+                    "experts_active": int((expert_rows > 0).sum())}
+        with telemetry.span("tick_commit", attrs=commit_attrs):
+            if expert_rows is not None:
+                self._tm_expert_imbalance.observe(
+                    float(expert_rows.max(axis=1).sum())
+                    / max(float(expert_rows.mean(axis=1).sum()), 1e-9),
+                    bucket=str(Tn))
             if not cold and n_decode_rows:
                 # per-token rate from the dynamic tick too (servers
                 # driving step() alone must still feed est_token_seconds

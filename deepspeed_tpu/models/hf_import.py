@@ -33,9 +33,14 @@ def _np(t) -> np.ndarray:
     return np.asarray(t, np.float32)
 
 
-def _stack(sd: Dict[str, Any], fmt: str, L: int, transpose: bool = False
+def _layers(L) -> range:
+    """``L``: a depth (layers 0 .. L-1) or the range of layer indices."""
+    return range(L) if isinstance(L, int) else L
+
+
+def _stack(sd: Dict[str, Any], fmt: str, L, transpose: bool = False
            ) -> np.ndarray:
-    mats = [_np(sd[fmt.format(i)]) for i in range(L)]
+    mats = [_np(sd[fmt.format(i)]) for i in _layers(L)]
     if transpose:
         mats = [m.T for m in mats]
     return np.stack(mats)
@@ -255,13 +260,13 @@ def _llama_attn_blocks(sd: Dict[str, Any], cfg: TransformerConfig,
     return blocks, params
 
 
-def _qwen_moe_experts(sd: Dict[str, Any], moe_fmt: str, L: int, E: int):
+def _qwen_moe_experts(sd: Dict[str, Any], moe_fmt: str, L, E: int):
     """Stack per-expert gate/up/down ModuleList weights → [L, E, in, out]."""
     def experts(wname):
         return np.stack([
             np.stack([_np(sd[moe_fmt.format(i) + f"experts.{e}.{wname}.weight"]).T
                       for e in range(E)])
-            for i in range(L)])
+            for i in _layers(L)])
 
     return {"w_gate": experts("gate_proj"), "w_up": experts("up_proj"),
             "w_down": experts("down_proj")}
@@ -345,10 +350,11 @@ def params_from_qwen3_moe(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
 
 def config_from_deepseek_v3(hf_config) -> TransformerConfig:
     first_dense = int(getattr(hf_config, "first_k_dense_replace", 0) or 0)
-    if first_dense > 0:
+    if int(getattr(hf_config, "moe_layer_freq", 1) or 1) != 1:
         raise NotImplementedError(
-            f"first_k_dense_replace={first_dense}: heterogeneous dense/MoE "
-            "stacks are not supported by the stacked-layer zoo")
+            f"moe_layer_freq={hf_config.moe_layer_freq}: the zoo's stack is "
+            "leading dense layers, then expert layers; interleaved dense "
+            "layers are not supported")
     shared = int(getattr(hf_config, "n_shared_experts", 0) or 0)
     return TransformerConfig(
         vocab_size=hf_config.vocab_size,
@@ -379,7 +385,15 @@ def config_from_deepseek_v3(hf_config) -> TransformerConfig:
         moe_gate_bias=True,
         moe_n_group=int(getattr(hf_config, "n_group", 1) or 1),
         moe_topk_group=int(getattr(hf_config, "topk_group", 1) or 1),
-        moe_aux_coef=float(getattr(hf_config, "router_aux_loss_coef", 0.001)))
+        moe_aux_coef=float(getattr(hf_config, "router_aux_loss_coef", 0.001)),
+        # the published model has no capacity: never the GShard einsums,
+        # which "auto" falls back to where a batch does not divide the mesh
+        # and which then drop rows (without this line the parity tests
+        # test_hf_import.py::test_first_k_dense_matches_hf and
+        # test_latent_moe_serving.py::test_forward_matches_the_reference
+        # fail on an eight-device mesh)
+        moe_dispatch="ragged",
+        first_dense_layers=min(first_dense, hf_config.num_hidden_layers))
 
 
 def config_from_deepseek_v2(hf_config) -> TransformerConfig:
@@ -406,44 +420,62 @@ def config_from_deepseek_v2(hf_config) -> TransformerConfig:
 
 
 def params_from_deepseek(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
-    """Shared V2/V3 weight mapping (V3 adds gate.e_score_correction_bias)."""
-    L, E = cfg.num_layers, cfg.n_experts
+    """Shared V2/V3 weight mapping (V3 adds gate.e_score_correction_bias);
+    the leading dense layers (``first_k_dense_replace``) stack under
+    ``dense_blocks``, the expert layers under ``blocks``."""
+    E = cfg.n_experts
     pre = "model." if any(k.startswith("model.") for k in sd) else ""
     lyr = pre + "layers.{}."
     attn = lyr + "self_attn."
     moe = lyr + "mlp."
-    blocks = {
-        "ln1": {"scale": _stack(sd, lyr + "input_layernorm.weight", L)},
-        "ln2": {"scale": _stack(sd, lyr + "post_attention_layernorm.weight", L)},
-        "wkv_a": _stack(sd, attn + "kv_a_proj_with_mqa.weight", L,
-                        transpose=True),
-        "kv_a_norm": _stack(sd, attn + "kv_a_layernorm.weight", L),
-        "wkv_b": _stack(sd, attn + "kv_b_proj.weight", L, transpose=True),
-        "wo": _stack(sd, attn + "o_proj.weight", L, transpose=True),
-        "gate_w": _stack(sd, moe + "gate.weight", L, transpose=True),
-    }
-    if cfg.moe_gate_bias:
-        blocks["gate_bias"] = _stack(
-            sd, moe + "gate.e_score_correction_bias", L)
-    if cfg.moe_shared_size > 0:
-        blocks["sw_gate"] = _stack(
-            sd, moe + "shared_experts.gate_proj.weight", L, transpose=True)
-        blocks["sw_up"] = _stack(
-            sd, moe + "shared_experts.up_proj.weight", L, transpose=True)
-        blocks["sw_down"] = _stack(
-            sd, moe + "shared_experts.down_proj.weight", L, transpose=True)
-    if cfg.q_lora_rank:
-        blocks["wq_a"] = _stack(sd, attn + "q_a_proj.weight", L, transpose=True)
-        blocks["q_a_norm"] = _stack(sd, attn + "q_a_layernorm.weight", L)
-        blocks["wq_b"] = _stack(sd, attn + "q_b_proj.weight", L, transpose=True)
-    else:
-        blocks["wq"] = _stack(sd, attn + "q_proj.weight", L, transpose=True)
-    blocks.update(_qwen_moe_experts(sd, moe, L, E))
+
+    def stack_of(L, experts: bool):
+        blocks = {
+            "ln1": {"scale": _stack(sd, lyr + "input_layernorm.weight", L)},
+            "ln2": {"scale": _stack(
+                sd, lyr + "post_attention_layernorm.weight", L)},
+            "wkv_a": _stack(sd, attn + "kv_a_proj_with_mqa.weight", L,
+                            transpose=True),
+            "kv_a_norm": _stack(sd, attn + "kv_a_layernorm.weight", L),
+            "wkv_b": _stack(sd, attn + "kv_b_proj.weight", L, transpose=True),
+            "wo": _stack(sd, attn + "o_proj.weight", L, transpose=True),
+        }
+        if cfg.q_lora_rank:
+            blocks["wq_a"] = _stack(sd, attn + "q_a_proj.weight", L,
+                                    transpose=True)
+            blocks["q_a_norm"] = _stack(sd, attn + "q_a_layernorm.weight", L)
+            blocks["wq_b"] = _stack(sd, attn + "q_b_proj.weight", L,
+                                    transpose=True)
+        else:
+            blocks["wq"] = _stack(sd, attn + "q_proj.weight", L,
+                                  transpose=True)
+        if not experts:
+            for ours, theirs in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                                 ("w_down", "down_proj")):
+                blocks[ours] = _stack(sd, moe + theirs + ".weight", L,
+                                      transpose=True)
+            return blocks
+        blocks["gate_w"] = _stack(sd, moe + "gate.weight", L, transpose=True)
+        if cfg.moe_gate_bias:
+            blocks["gate_bias"] = _stack(
+                sd, moe + "gate.e_score_correction_bias", L)
+        if cfg.moe_shared_size > 0:
+            for ours, theirs in (("sw_gate", "gate_proj"), ("sw_up", "up_proj"),
+                                 ("sw_down", "down_proj")):
+                blocks[ours] = _stack(
+                    sd, moe + f"shared_experts.{theirs}.weight", L,
+                    transpose=True)
+        blocks.update(_qwen_moe_experts(sd, moe, L, E))
+        return blocks
+
+    d = cfg.first_dense_layers
     params = {
         "tok_emb": _np(sd[pre + "embed_tokens.weight"]),
-        "blocks": blocks,
+        "blocks": stack_of(range(d, cfg.num_layers), True),
         "final_norm": {"scale": _np(sd[pre + "norm.weight"])},
     }
+    if d:
+        params["dense_blocks"] = stack_of(range(d), False)
     if not cfg.tie_embeddings:
         params["lm_head"] = _np(sd["lm_head.weight"]).T
     return params

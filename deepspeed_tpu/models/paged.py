@@ -15,6 +15,7 @@ into reserved trash block 0.
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -26,22 +27,29 @@ from deepspeed_tpu.models import transformer as T
 PyTree = Any
 
 
+def latent_row_width(cfg: T.TransformerConfig) -> int:
+    """Columns of a latent pool row: kvr + dr rounded up to the TPU's 128
+    lanes, which is what the array occupies in HBM anyway (576 -> 640) and
+    what the kernel's block copies and products must be aligned to."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
 def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
                   dtype=None) -> Dict[str, jax.Array]:
     """Block pool per layer. Block 0 is the trash block for pad writes.
 
-    MLA models (DeepSeek) pool the LATENTS instead of per-head K/V —
-    c_kv [.., kv_lora_rank] + shared post-rope key [.., qk_rope_head_dim]
-    per slot (reference ``ragged/kv_cache.py`` + the v2 engine's DeepSeek
-    containers). That tiny row width (kvr+dr vs 2·K·D) is exactly where
-    paged KV pays off."""
+    MLA models (DeepSeek) pool the LATENTS instead of per-head K/V: one
+    row per slot, ``c_kv [kv_lora_rank] ++ k_pe [qk_rope_head_dim]`` (the
+    shared post-rope key) ++ zeros up to a lane multiple
+    (:func:`latent_row_width`; reference ``ragged/kv_cache.py`` + the v2
+    engine's DeepSeek containers). That small row (kvr+dr vs 2·K·D) is
+    where paged KV pays off, and one row a position is what lets the
+    kernel read each position once."""
     dt = dtype or cfg.compute_dtype
     L = cfg.num_layers
     if cfg.mla:
-        return {"ckv": jnp.zeros((L, n_blocks, block_size,
-                                  cfg.kv_lora_rank), dt),
-                "kpe": jnp.zeros((L, n_blocks, block_size,
-                                  cfg.qk_rope_head_dim), dt)}
+        return {"latent": jnp.zeros((L, n_blocks, block_size,
+                                     latent_row_width(cfg)), dt)}
     shape = (L, n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
@@ -129,40 +137,81 @@ def grouped_prefill_attention(q: jax.Array, kpool: jax.Array,
     return out.reshape(R, N, D).astype(q.dtype)
 
 
-def paged_mla_attention_reference(q: jax.Array, ckv_pool: jax.Array,
-                                  kpe_pool: jax.Array, tables: jax.Array,
-                                  lengths: jax.Array, w_kv_b: jax.Array,
-                                  cfg: T.TransformerConfig) -> jax.Array:
-    """Weight-absorbed MLA attention over the paged LATENT pool (the
-    DeepSeek decode trick of ``transformer._mla_absorbed_attention``, paged):
-    W_uk folds into the query and W_uv into the output, so each cache slot
-    is read ONCE at width kvr+dr and k/v are never re-expanded.
-
-    q [T, N, dn+dr] (post-rope); ckv_pool [NBf, bs, kvr];
-    kpe_pool [NBf, bs, dr]; tables [T, MB]; → [T, N, dv].
-    """
-    import math
-
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+def _absorbed(q: jax.Array, w_kv_b: jax.Array, cfg: T.TransformerConfig,
+              attend: Callable) -> jax.Array:
+    """Weight-absorbed MLA around ``attend``: W_uk folds into the query
+    and W_uv into the output, so attention runs in latent space against
+    pool rows as they are stored. q [T, N, dn+dr] (post-rope) -> the
+    latent-space query [T, N, W] (zero beyond kvr+dr, like a pool row);
+    ``attend`` returns the attended latents [T, N, kvr]; -> [T, N, dv]."""
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
     kvr, N = cfg.kv_lora_rank, cfg.num_heads
-    Tn = q.shape[0]
-    bs = ckv_pool.shape[1]
-    MB = tables.shape[1]
     dt = q.dtype
-    ckv = ckv_pool[tables].reshape(Tn, MB * bs, kvr)
-    kpe = kpe_pool[tables].reshape(Tn, MB * bs, dr)
     w_kv = w_kv_b.astype(dt).reshape(kvr, N, dn + dv)
     w_uk, w_uv = w_kv[..., :dn], w_kv[..., dn:]
-    q_nope, q_pe = q[..., :dn], q[..., dn:]
-    q_lat = jnp.einsum("tnd,knd->tnk", q_nope, w_uk)     # [T, N, kvr]
-    scale = cfg.mla_scale_mult / math.sqrt(dn + dr)
-    s = (jnp.einsum("tnk,tck->tnc", q_lat, ckv)
-         + jnp.einsum("tnr,tcr->tnc", q_pe, kpe)).astype(jnp.float32) * scale
-    mask = jnp.arange(MB * bs)[None, None, :] < lengths[:, None, None]
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(dt)
-    out_lat = jnp.einsum("tnc,tck->tnk", p, ckv)         # [T, N, kvr]
-    return jnp.einsum("tnk,knd->tnd", out_lat, w_uv)     # [T, N, dv]
+    q_lat = jnp.einsum("tnd,knd->tnk", q[..., :dn], w_uk)    # [T, N, kvr]
+    pad = latent_row_width(cfg) - kvr - cfg.qk_rope_head_dim
+    q_row = jnp.concatenate(
+        [q_lat, q[..., dn:], jnp.zeros(q.shape[:2] + (pad,), dt)], axis=-1)
+    return jnp.einsum("tnk,knd->tnd", attend(q_row), w_uv)   # [T, N, dv]
+
+
+def mla_softmax_scale(cfg: T.TransformerConfig) -> float:
+    return cfg.mla_scale_mult / math.sqrt(
+        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+
+def paged_mla_attention_reference(q: jax.Array, pool: jax.Array,
+                                  tables: jax.Array, lengths: jax.Array,
+                                  w_kv_b: jax.Array,
+                                  cfg: T.TransformerConfig) -> jax.Array:
+    """Weight-absorbed MLA attention over the paged LATENT pool (the
+    DeepSeek decode trick of ``transformer._mla_absorbed_attention``, paged)
+    in plain jnp: the CPU path and the kernel's oracle. It gathers every
+    row's whole table, so it is for short tables only.
+
+    q [T, N, dn+dr] (post-rope); pool [NBf, bs, W] (rows ``c_kv ++ k_pe ++
+    0``); tables [T, MB]; → [T, N, dv].
+    """
+    Tn, kvr = q.shape[0], cfg.kv_lora_rank
+    bs, MB = pool.shape[1], tables.shape[1]
+    dt = q.dtype
+
+    def attend(q_row):
+        rows = pool[tables].reshape(Tn, MB * bs, pool.shape[2])
+        s = jnp.einsum("tnw,tcw->tnc", q_row, rows).astype(jnp.float32) \
+            * mla_softmax_scale(cfg)
+        mask = jnp.arange(MB * bs)[None, None, :] < lengths[:, None, None]
+        p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1).astype(dt)
+        return jnp.einsum("tnc,tck->tnk", p, rows[..., :kvr])
+
+    return _absorbed(q, w_kv_b, cfg, attend)
+
+
+_EXPERT_LEAVES = ("w_up", "w_down", "w_gate")
+
+
+def _tick_experts(h: jax.Array, lp: Dict[str, jax.Array],
+                  cfg: T.TransformerConfig, valid: jax.Array,
+                  stack: Optional[Dict[str, jax.Array]] = None,
+                  layer: Optional[jax.Array] = None
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """A tick's expert layer on normed rows [T, H], DROPLESS
+    (``moe.layer.dropless_moe_ffn``): (output, rows per expert over the
+    ``valid`` rows). The experts' matrices are ``lp``'s, or the whole
+    layer ``stack``'s with the ``layer`` to use."""
+    from deepspeed_tpu.moe.layer import dropless_moe_ffn
+
+    experts = stack or {k: lp[k] for k in _EXPERT_LEAVES if k in lp}
+    shared = {k: lp[k] for k in ("sw_up", "sw_down", "sw_gate",
+                                 "shared_gate_w") if k in lp}
+    return dropless_moe_ffn(
+        h, lp["gate_w"], experts, cfg.activation, cfg.moe_top_k,
+        score_func=cfg.moe_score_func, route_norm=cfg.moe_route_norm,
+        route_scale=cfg.moe_route_scale, shared=shared or None,
+        gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
+        topk_group=cfg.moe_topk_group, valid=valid,
+        layer=layer if stack else None)
 
 
 def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
@@ -170,8 +219,7 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
                   cfg: T.TransformerConfig,
                   attention_fn: Optional[Callable] = None,
                   group_tables: Optional[jax.Array] = None,
-                  n_decode: int = 0
-                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+                  n_decode: int = 0, with_stats: bool = False):
     """One SplitFuse tick over a flat token batch.
 
     tokens [T] int32, positions [T] int32, tables [T, MB] int32 (rows shared
@@ -186,13 +234,22 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     first ``n_decode`` rows (per-row tables) walk the per-token path. The
     KV WRITE path always uses the per-row tables.
 
-    MLA (DeepSeek) models pool latents and attend weight-absorbed
-    (:func:`paged_mla_attention_reference`); ALiBi models (BLOOM/Falcon)
-    bias the paged scores by head slope × relative position.
+    MLA (DeepSeek) models pool latents and attend weight-absorbed: with
+    the latent instantiation of the Pallas kernel when ``attention_fn`` is
+    a kernel (any: which one is ``cfg``'s to say, not the caller's), with
+    :func:`paged_mla_attention_reference` otherwise; ALiBi models
+    (BLOOM/Falcon) bias the paged scores by head slope × relative position.
+
+    Expert layers run dropless (:func:`_tick_experts`). ``with_stats``
+    adds a third result, ``{"expert_rows": [expert layers, E] int32}``
+    (rows each expert got from the tick's real rows; ``{}`` for a model
+    without experts).
     """
     if cfg.mla:
-        return _forward_paged_mla(params, tokens, positions, tables, pool,
-                                  cfg)
+        out = _forward_paged_mla(
+            params, tokens, positions, tables, pool, cfg,
+            use_kernel=attention_fn not in (None, paged_attention_reference))
+        return out if with_stats else out[:2]
     attention_fn = attention_fn or paged_attention_reference
     alibi = None
     if cfg.pos_emb == "alibi":
@@ -220,6 +277,12 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
         tables, (positions // bs)[:, None], axis=1)[:, 0]  # [T]
     offsets = positions % bs
     lengths = positions + 1
+    valid = tables[:, 0] > 0     # a pad row's table is all trash block
+
+    def ffn(h2, lp):
+        if cfg.n_experts:
+            return _tick_experts(h2, lp, cfg, valid)
+        return T._ffn(h2, lp, cfg)[0], None
 
     # The pool rides the layer scan as a FLAT [L*NB, bs, K, D] carry that is
     # scattered in place (layer l owns block range [l*NB, (l+1)*NB)); the
@@ -291,16 +354,16 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
             if cfg.parallel_block:
                 h2 = h if cfg.shared_parallel_norm else \
                     T._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
-                down, _ = T._ffn(h2, lp, cfg)
-                return (x + attn_out + down, pk, pv, li + 1), None
+                down, rows = ffn(h2, lp)
+                return (x + attn_out + down, pk, pv, li + 1), rows
             x = x + attn_out
             h2 = T._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
-            down, _ = T._ffn(h2, lp, cfg)
-            return (x + down, pk, pv, li + 1), None
+            down, rows = ffn(h2, lp)
+            return (x + down, pk, pv, li + 1), rows
 
     carry0 = (x, pool["k"].reshape(flat), pool["v"].reshape(flat),
               jnp.int32(0))
-    (x, new_k, new_v, _), _ = lax.scan(body, carry0, params["blocks"])
+    (x, new_k, new_v, _), rows = lax.scan(body, carry0, params["blocks"])
     new_k = new_k.reshape(pool["k"].shape)
     new_v = new_v.reshape(pool["v"].shape)
     with jax.named_scope("lm_head"):
@@ -309,26 +372,35 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
         logits = T.head_matmul(x, head.astype(x.dtype))
         if cfg.lm_head_bias:
             logits = logits + params["lm_head_b"].astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    new_pool = {"k": new_k, "v": new_v}
+    if with_stats:
+        return logits, new_pool, \
+            {} if rows is None else {"expert_rows": rows}
+    return logits, new_pool
 
 
 def _forward_paged_mla(params: PyTree, tokens: jax.Array,
                        positions: jax.Array, tables: jax.Array,
-                       pool: Dict[str, jax.Array], cfg: T.TransformerConfig
-                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """MLA SplitFuse tick: write c_kv/k_pe LATENTS into the paged pool and
-    attend weight-absorbed (same flat in-place pool carry as the dense
-    path; same math as the v1 engine's latent-cache decode)."""
+                       pool: Dict[str, jax.Array], cfg: T.TransformerConfig,
+                       use_kernel: bool):
+    """MLA SplitFuse tick: write each row's latent (``c_kv ++ k_pe``) into
+    the paged pool and attend weight-absorbed (same flat in-place pool
+    carry as the dense path; same math as the v1 engine's latent-cache
+    decode). The stack is one scan per segment of ``cfg.segments`` (the
+    leading dense layers, then the expert layers), the pool's layers in
+    the same order. Returns (logits, pool, stats) as ``forward_paged``
+    with ``with_stats``."""
     dt = cfg.compute_dtype
     Tn = tokens.shape[0]
-    bs = pool["ckv"].shape[2]
+    lat = pool["latent"]
+    L, NB, bs, W = lat.shape
 
-    x = params["tok_emb"].astype(dt)[tokens]
-    if cfg.emb_norm:
-        x = T._norm(x, params["emb_norm"], cfg.norm, cfg.norm_eps)
+    with jax.named_scope("embed"):
+        x = params["tok_emb"].astype(dt)[tokens]
+        if cfg.emb_norm:
+            x = T._norm(x, params["emb_norm"], cfg.norm, cfg.norm_eps)
 
-    max_pos = pool["ckv"].shape[1] * bs
-    cos_t, sin_t = T.rope_table(max_pos, cfg.qk_rope_head_dim,
+    cos_t, sin_t = T.rope_table(NB * bs, cfg.qk_rope_head_dim,
                                 cfg.rope_theta, cfg.rope_scaling_dict)
 
     def rope_fn(v):                                   # v [T, 1, n, dr]
@@ -338,44 +410,75 @@ def _forward_paged_mla(params: PyTree, tokens: jax.Array,
         tables, (positions // bs)[:, None], axis=1)[:, 0]
     offsets = positions % bs
     lengths = positions + 1
-    L, NB = pool["ckv"].shape[0], pool["ckv"].shape[1]
-    fck = (L * NB,) + pool["ckv"].shape[2:]
-    fkp = (L * NB,) + pool["kpe"].shape[2:]
+    valid = tables[:, 0] > 0     # a pad row's table is all trash block
+    row_pad = jnp.zeros(
+        (Tn, W - cfg.kv_lora_rank - cfg.qk_rope_head_dim), lat.dtype)
 
-    def body(carry, lp):
-        from deepspeed_tpu.ops.quantization import dequant_params
+    if use_kernel:
+        from deepspeed_tpu.ops.pallas.paged_attention import \
+            latent_paged_attention
 
-        x, pck, pkp, li = carry
-        lp = dequant_params(lp, dt)
-        h = T._norm(x, lp["ln1"], cfg.norm, cfg.norm_eps)
-        hB = h[:, None, :]                            # [T, 1, H]
-        q = T._mla_q(hB, lp, cfg, rope_fn)[:, 0]      # [T, N, dn+dr]
-        c_kv, k_pe = T._mla_latents(hB, lp, cfg, rope_fn)
-        ckv_t = c_kv[:, 0]                            # [T, kvr]
-        kpe_t = k_pe[:, 0, 0]                         # [T, dr]
+        def attend(q, plat, rows_tables, w_kv_b):
+            return _absorbed(q, w_kv_b, cfg, lambda q_row:
+                             latent_paged_attention(
+                                 q_row, plat, rows_tables, lengths,
+                                 cfg.kv_lora_rank, mla_softmax_scale(cfg)))
+    else:
+        def attend(q, plat, rows_tables, w_kv_b):
+            return paged_mla_attention_reference(q, plat, rows_tables,
+                                                 lengths, w_kv_b, cfg)
 
-        base = li * NB
-        pck = pck.at[base + block_idx, offsets].set(
-            ckv_t.astype(pck.dtype), mode="drop")
-        pkp = pkp.at[base + block_idx, offsets].set(
-            kpe_t.astype(pkp.dtype), mode="drop")
+    def make_body(seg: T.TransformerConfig, first: int, stack):
+        def body(carry, lp):
+            from deepspeed_tpu.ops.quantization import dequant_params
 
-        attn = paged_mla_attention_reference(
-            q, pck, pkp, tables + base, lengths, lp["wkv_b"], cfg)
-        attn = attn.reshape(Tn, cfg.num_heads * cfg.v_head_dim)
-        attn_out = attn @ lp["wo"].astype(dt)
-        x = x + attn_out
-        h2 = T._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
-        down, _ = T._ffn(h2, lp, cfg)
-        return (x + down, pck, pkp, li + 1), None
+            x, plat, li = carry
+            lp = dequant_params(lp, dt)
+            with jax.named_scope("attn"):
+                h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
+                hB = h[:, None, :]                        # [T, 1, H]
+                q = T._mla_q(hB, lp, seg, rope_fn)[:, 0]  # [T, N, dn+dr]
+                c_kv, k_pe = T._mla_latents(hB, lp, seg, rope_fn)
+                row = jnp.concatenate(
+                    [c_kv[:, 0].astype(plat.dtype),
+                     k_pe[:, 0, 0].astype(plat.dtype), row_pad], axis=-1)
+                base = li * NB
+                plat = plat.at[base + block_idx, offsets].set(row,
+                                                              mode="drop")
+                attn = attend(q, plat, tables + base, lp["wkv_b"])
+                attn = attn.reshape(Tn, seg.num_heads * seg.v_head_dim)
+                x = x + attn @ lp["wo"].astype(dt)
+            h2 = T._norm(x, lp["ln2"], seg.norm, seg.norm_eps)
+            if seg.n_experts:
+                down, rows = _tick_experts(h2, lp, seg, valid, stack,
+                                           li - first)
+            else:
+                with jax.named_scope("mlp"):
+                    down, rows = T._ffn(h2, lp, seg)[0], None
+            return (x + down, plat, li + 1), rows
 
-    carry0 = (x, pool["ckv"].reshape(fck), pool["kpe"].reshape(fkp),
-              jnp.int32(0))
-    (x, new_ck, new_kp, _), _ = lax.scan(body, carry0, params["blocks"])
-    x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    head = T._lm_head_of(params, cfg)
-    logits = T.head_matmul(x, head.astype(x.dtype))
-    if cfg.lm_head_bias:
-        logits = logits + params["lm_head_b"].astype(jnp.float32)
-    return logits, {"ckv": new_ck.reshape(pool["ckv"].shape),
-                    "kpe": new_kp.reshape(pool["kpe"].shape)}
+        return body
+
+    carry = (x, lat.reshape(L * NB, bs, W), jnp.int32(0))
+    stats = {}
+    first = 0
+    for key, seg in cfg.segments:
+        # the experts' matrices stay out of the scan's sliced operands: the
+        # grouped matmul takes the stack whole (``moe.layer.grouped_dot``);
+        # quantised leaves ({"q", "scale", ...}) are dequantised a layer at
+        # a time and stay in
+        stack = {k: v for k, v in params[key].items() if seg.n_experts
+                 and k in _EXPERT_LEAVES and hasattr(v, "ndim")}
+        xs = {k: v for k, v in params[key].items() if k not in stack}
+        carry, rows = lax.scan(make_body(seg, first, stack), carry, xs)
+        first += seg.num_layers
+        if rows is not None:
+            stats["expert_rows"] = rows
+    x, new_lat, _ = carry
+    with jax.named_scope("lm_head"):
+        x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        head = T._lm_head_of(params, cfg)
+        logits = T.head_matmul(x, head.astype(x.dtype))
+        if cfg.lm_head_bias:
+            logits = logits + params["lm_head_b"].astype(jnp.float32)
+    return logits, {"latent": new_lat.reshape(lat.shape)}, stats

@@ -162,6 +162,11 @@ class TransformerConfig:
     moe_gate_bias: bool = False         # e_score_correction_bias parameter
     moe_n_group: int = 1                # node-limited routing groups
     moe_topk_group: int = 1
+    # leading dense layers of an expert model (DeepSeek
+    # ``first_k_dense_replace``): the stack is then TWO scans, the dense
+    # layers under ``params["dense_blocks"]`` (FFN width ``ffn_size``) and
+    # the expert layers under ``params["blocks"]``; see ``segments``
+    first_dense_layers: int = 0
     # compute-time QKV fusion: one [H, q+k+v] matmul instead of three (the
     # reference's fused-QKV transformer kernels, csrc/transformer
     # attn_quantizer/transform kernels). Params stay separate (importers,
@@ -237,7 +242,30 @@ class TransformerConfig:
     def has_ln2(self) -> bool:
         return not (self.parallel_block and self.shared_parallel_norm)
 
+    @property
+    def segments(self) -> Tuple[Tuple[str, "TransformerConfig"], ...]:
+        """The layer stack as (key under ``params``, config of that run of
+        layers) in order: one homogeneous scan each. ``num_layers`` of a
+        segment's config is the segment's depth."""
+        d = self.first_dense_layers
+        if not d:
+            return (("blocks", self),)
+        if not 0 < d < self.num_layers or self.n_experts == 0:
+            raise ValueError(
+                f"first_dense_layers={d} needs an expert model with more "
+                f"than {d} layers (num_layers={self.num_layers}, "
+                f"n_experts={self.n_experts})")
+        rest = dataclasses.replace(self, first_dense_layers=0,
+                                   num_layers=self.num_layers - d)
+        return (("dense_blocks", dataclasses.replace(
+            rest, num_layers=d, n_experts=0)), ("blocks", rest))
+
     def num_params(self) -> int:
+        if self.first_dense_layers:
+            shared = dataclasses.replace(self, first_dense_layers=0,
+                                         num_layers=0).num_params()
+            return shared + sum(c.num_params() - shared
+                                for _, c in self.segments)
         h, f, v, l = self.hidden_size, self.ffn_size, self.vocab_size, self.num_layers
         if self.mla:
             dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
@@ -284,6 +312,15 @@ class TransformerConfig:
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     """fp32 master parameters. Output projections scaled by 1/sqrt(2L) (GPT-2)."""
+    if cfg.first_dense_layers:
+        (dkey, dcfg), (_, rest) = cfg.segments
+        params = init_params(rest, rng)
+        # the dense layers' blocks alone: no embedding is built for them
+        params[dkey] = init_params(
+            dataclasses.replace(dcfg, vocab_size=1, tie_embeddings=True,
+                                pos_emb="none", emb_norm=False),
+            jax.random.fold_in(rng, 1))["blocks"]
+        return params
     h, f, L = cfg.hidden_size, cfg.ffn_size, cfg.num_layers
     qdim = cfg.num_heads * cfg.head_dim
     kvdim = cfg.kv_heads * cfg.head_dim
@@ -381,6 +418,12 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
 
 def param_logical_axes(cfg: TransformerConfig) -> PyTree:
     """Logical axis names per parameter dim (consumed by the sharding policy)."""
+    if cfg.first_dense_layers:
+        (dkey, dcfg), (_, rest) = cfg.segments
+        axes = param_logical_axes(rest)
+        axes[dkey] = param_logical_axes(dcfg)["blocks"]
+        return axes
+
     def norm_axes(prefix):
         p = {"scale": prefix + ("embed",)}
         if cfg.norm == "layernorm":
@@ -945,6 +988,14 @@ def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
     return down, aux
 
 
+def _require_one_stack(cfg: TransformerConfig, what: str) -> None:
+    if cfg.first_dense_layers:
+        raise NotImplementedError(
+            f"{what} runs one homogeneous layer stack; a model with leading "
+            f"dense layers (first_dense_layers={cfg.first_dense_layers}) is "
+            "served by FastGenEngine and trained without pipeline stages")
+
+
 # --------------------------------------------------------------------------- #
 # forward
 # --------------------------------------------------------------------------- #
@@ -982,7 +1033,16 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     constrain = activation_constraint or (lambda x: x)
     dt = cfg.compute_dtype
     B, S = tokens.shape
+    # a leading dense segment (``cfg.segments``) is one plain scan ahead of
+    # the stack; chunking, the sync hook and random-LTD act on the rest
+    lead = cfg.segments[:-1]
+    full_cfg, cfg = cfg, cfg.segments[-1][1]
     L = cfg.num_layers
+    if lead and random_ltd_idx is not None:
+        raise NotImplementedError(
+            "random-LTD over a stack with leading dense layers "
+            "(first_dense_layers) is unsupported: its first/middle/last "
+            "split assumes one homogeneous stack")
 
     with jax.named_scope("embed"):
         x = params["tok_emb"].astype(dt)[tokens]
@@ -997,7 +1057,7 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
         rd = cfg.qk_rope_head_dim if cfg.mla else cfg.rope_dim
         cos, sin = rope_table(S, rd, cfg.rope_theta, cfg.rope_scaling_dict)
 
-    def make_body(cos_b, sin_b, with_pld: bool):
+    def make_body(cos_b, sin_b, with_pld: bool, cfg=cfg):
         def body(carry, xs):
             if with_pld:
                 layer_params, keep = xs
@@ -1042,6 +1102,12 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
             "random-LTD with ALiBi positions is unsupported: the middle-stack "
             "bias would be computed from compacted indices (rope tables are "
             "index-gathered; ALiBi distances cannot be)")
+    for key, seg in lead:
+        xs = params[key]
+        if with_pld:
+            xs = (xs, pld_keep[:seg.num_layers])
+            pld_keep = pld_keep[seg.num_layers:]
+        x, _ = lax.scan(make_body(cos, sin, with_pld, seg), x, xs)
     if random_ltd_idx is None or L < 3:
         x, auxes = run_chunked(x, params["blocks"], cos, sin, pld_keep)
         aux_total = jnp.sum(auxes)
@@ -1064,7 +1130,7 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
         aux_total = jnp.sum(a1) + jnp.sum(a2) + jnp.sum(a3)
 
     x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    head = _lm_head_of(params, cfg)
+    head = _lm_head_of(params, full_cfg)
     return x, head, aux_total
 
 
@@ -1152,6 +1218,7 @@ def forward_decode(params: PyTree, tokens: jax.Array,
     inference transformer containers (``module_inject/containers``,
     ``inference/v2/model_implementations``).
     """
+    _require_one_stack(cfg, "forward_decode (the v1 slot engine)")
     B, T = tokens.shape
     dt = cfg.compute_dtype
     M = cache["k"].shape[2]
@@ -1259,6 +1326,7 @@ def _pipeline_parts(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     microbatched inputs, extra params, stage_fn and finalize_fn. Both
     schedules MUST consume this so the 1F1B-vs-GPipe parity tests stay
     meaningful."""
+    _require_one_stack(cfg, "the pipeline schedule")
     from deepspeed_tpu.comm.mesh import PIPE_AXIS, get_mesh_manager
     from deepspeed_tpu.parallel.pipeline import microbatch
 

@@ -133,9 +133,29 @@ def _pick_tile(dim: int, prefer: int) -> Optional[int]:
     return None
 
 
-def grouped_dot(x: jax.Array, w: jax.Array, group_sizes: jax.Array
-                ) -> jax.Array:
+#: a [tk, tn] tile of bf16 weights, double-buffered, that still fits the
+#: scoped VMEM beside a 128-row tile of rows and the accumulator
+_WHOLE_TILE_ELEMS = 2048 * 1536
+
+
+def grouped_dot(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+                layer: Optional[jax.Array] = None) -> jax.Array:
     """Grouped GEMM ``x[rows of group e] @ w[e]`` → [M, N].
+
+    ``layer`` (serving): ``w`` is a layer stack's ``[L, E, K, N]`` and the
+    (traced) index says whose experts to use. The stack goes to the kernel
+    whole, as ``L*E`` groups of which only that layer's have rows: a slice
+    of it is a copy of the layer's experts, because a custom call's operand
+    is materialised (a third of the device's busy time in a Moonlight tick:
+    1.1 GB a layer read and written beside the kernel's own read; PERF.md,
+    PR 27). That form is forward-only, and where its rows are few an
+    expert (``M / E`` within one 128-row tile: 48 in a 512-row tick of 64
+    experts, six a row) the m tile is 128 (a tile is revisited for every
+    group in it) and K and N stay whole where a ``[K, N]`` tile fits VMEM;
+    measured on the v5e at ``[3072, 2048] x [64, 2048, 1408]``: (128, 2048,
+    1408) 1.24 ms, the training ladder's (512, 1024, 128) 3.35 ms. Training
+    keeps its ladder whatever its shapes: those tiles were tuned for the
+    forward, dgrad and tgmm together under one VMEM budget.
 
     On TPU this is the Pallas megablocks kernel (``megablox.gmm``, custom
     VJP with ``tgmm`` weight grads) with explicitly-tuned tiles — measured
@@ -149,6 +169,18 @@ def grouped_dot(x: jax.Array, w: jax.Array, group_sizes: jax.Array
     """
     M, K = x.shape
     N = w.shape[-1]
+    if layer is not None:
+        L, E = w.shape[:2]
+        w = w.reshape(L * E, K, N)
+        group_sizes = lax.dynamic_update_slice(
+            jnp.zeros((L * E,), group_sizes.dtype), group_sizes,
+            (layer * E,))
+        tm = _pick_tile(M, 128)
+        if jax.default_backend() == "tpu" and tm and M <= 128 * E \
+                and K * N <= _WHOLE_TILE_ELEMS:
+            from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+            return gmm(x, w, group_sizes, x.dtype, (tm, K, N))
     if jax.default_backend() == "tpu":
         import os
 
@@ -187,27 +219,29 @@ def grouped_dot(x: jax.Array, w: jax.Array, group_sizes: jax.Array
 
 
 def ragged_expert_ffn(x_sorted: jax.Array, group_sizes: jax.Array,
-                      experts: Dict[str, jax.Array], activation: str
-                      ) -> jax.Array:
+                      experts: Dict[str, jax.Array], activation: str,
+                      layer: Optional[jax.Array] = None) -> jax.Array:
     """Grouped expert FFN on expert-sorted tokens.
 
     x_sorted [M, H] — rows grouped contiguously by expert; group_sizes [E]
     int32 summing to M. Each weight application is ONE grouped GEMM
     (:func:`grouped_dot`) instead of E small matmuls or a [T,E,C] einsum.
+    ``layer``: :func:`grouped_dot`'s (the leaves are a layer stack's).
     """
     dt = x_sorted.dtype
     # named so remat="moe_selective" can store up/act (backward then never
     # re-runs the grouped GEMMs); measured slower than recompute on v5e at
     # the bench shapes, kept for bigger-expert configs where the trade flips
     up = _ckpt_name(
-        grouped_dot(x_sorted, experts["w_up"].astype(dt), group_sizes),
-        "moe_up")
+        grouped_dot(x_sorted, experts["w_up"].astype(dt), group_sizes,
+                    layer), "moe_up")
     g = (_ckpt_name(
-        grouped_dot(x_sorted, experts["w_gate"].astype(dt), group_sizes),
-        "moe_up")
+        grouped_dot(x_sorted, experts["w_gate"].astype(dt), group_sizes,
+                    layer), "moe_up")
         if "w_gate" in experts else None)
     act = _ckpt_name(_expert_act(up, g, activation), "moe_act")
-    return grouped_dot(act, experts["w_down"].astype(dt), group_sizes)
+    return grouped_dot(act, experts["w_down"].astype(dt), group_sizes,
+                       layer)
 
 
 def expert_sort(flat: jax.Array, E: int
@@ -375,8 +409,8 @@ combine_gather.defvjp(_combine_gather_fwd, _combine_gather_bwd)
 
 
 def _ragged_dispatch_local(xt: jax.Array, weights: jax.Array, idx: jax.Array,
-                           experts: Dict[str, jax.Array], activation: str
-                           ) -> jax.Array:
+                           experts: Dict[str, jax.Array], activation: str,
+                           layer: Optional[jax.Array] = None) -> jax.Array:
     """Dropless dispatch on local tokens: sort → ragged matmul → un-sort.
 
     xt [T, H]; weights/idx [T, k]. Dispatch = :func:`dispatch_gather`
@@ -389,7 +423,7 @@ def _ragged_dispatch_local(xt: jax.Array, weights: jax.Array, idx: jax.Array,
     T, H = xt.shape
     k = idx.shape[-1]
     Tk = T * k
-    E = experts["w_up"].shape[0]
+    E = experts["w_up"].shape[-3]
     flat = idx.reshape(Tk)
     order, inv, group_sizes = expert_sort(flat, E)
     # tiny [Tk] ints + [T,k] weights: named so the selective remat policy
@@ -399,7 +433,7 @@ def _ragged_dispatch_local(xt: jax.Array, weights: jax.Array, idx: jax.Array,
     group_sizes = _ckpt_name(group_sizes, "moe_gate")
     weights = _ckpt_name(weights, "moe_gate")
     x_s = dispatch_gather(xt, order, inv2d)
-    y_s = ragged_expert_ffn(x_s, group_sizes, experts, activation)
+    y_s = ragged_expert_ffn(x_s, group_sizes, experts, activation, layer)
     return combine_gather(y_s, weights.astype(xt.dtype), order, inv2d)
 
 
@@ -795,11 +829,57 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
     if route_scale != 1.0:
         y = y * jnp.asarray(route_scale, dt)
     if shared:
+        y = y + _shared_experts(xt, shared, activation)
+    return y.reshape(B, S, H), aux
+
+
+def _shared_experts(xt: jax.Array, shared: Dict[str, jax.Array],
+                    activation: str) -> jax.Array:
+    """The always-on experts beside the routed ones (:func:`moe_ffn`'s
+    ``shared``) on flat rows ``xt [T, H]``."""
+    with jax.named_scope("shared_experts"):
         ys = _dense_ffn(xt, shared["sw_up"], shared["sw_down"],
                         shared.get("sw_gate"), activation)
         if "shared_gate_w" in shared:
-            sg = jax.nn.sigmoid(
-                xt.astype(jnp.float32) @ shared["shared_gate_w"].astype(jnp.float32))
-            ys = ys * sg.astype(dt)
-        y = y + ys
-    return y.reshape(B, S, H), aux
+            sg = jax.nn.sigmoid(xt.astype(jnp.float32)
+                                @ shared["shared_gate_w"].astype(jnp.float32))
+            ys = ys * sg.astype(xt.dtype)
+    return ys
+
+
+def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
+                     experts: Dict[str, jax.Array], activation: str, k: int,
+                     score_func: str = "softmax", route_norm: bool = True,
+                     route_scale: float = 1.0,
+                     shared: Optional[Dict[str, jax.Array]] = None,
+                     gate_bias: Optional[jax.Array] = None,
+                     n_group: int = 1, topk_group: int = 1,
+                     valid: Optional[jax.Array] = None,
+                     layer: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The serving form of :func:`moe_ffn` on flat local rows ``xt [T, H]``:
+    always the dropless sort + grouped matmul, whatever ``moe_dispatch``
+    says, because a served row's result must not depend on which rows
+    share its tick (a capacity would drop by the tick's composition).
+    Returns (y [T, H], rows per expert [E] int32 over the ``valid`` rows:
+    a tick's pad rows route too and are left out of the count).
+    ``layer``: ``experts`` are the layer stack's ``[L, E, ...]`` leaves and
+    this is the layer to use (:func:`grouped_dot`). The
+    scopes ``router`` / ``experts`` / ``shared_experts`` are what a device
+    trace sorts the layer's operations by."""
+    with jax.named_scope("router"):
+        gate = _gate_indices(xt, gate_w, gate_bias, k, score_func,
+                             route_norm, n_group, topk_group)
+        picked = jax.nn.one_hot(gate.experts, gate_w.shape[1],
+                                dtype=jnp.int32)              # [T, k, E]
+        if valid is not None:
+            picked = picked * valid.astype(jnp.int32)[:, None, None]
+        rows = jnp.sum(picked, axis=(0, 1))
+    with jax.named_scope("experts"):
+        y = _ragged_dispatch_local(xt, gate.weights, gate.experts, experts,
+                                   activation, layer)
+        if route_scale != 1.0:
+            y = y * jnp.asarray(route_scale, xt.dtype)
+    if shared:
+        y = y + _shared_experts(xt, shared, activation)
+    return y, rows

@@ -72,19 +72,27 @@ def tile_rows(num_heads: int, kv_heads: int) -> int:
     return max(16, min(32, _LANES // (num_heads // kv_heads)))
 
 
-def _blocks_per_fetch(bs: int, K: int, D: int, itemsize: int) -> int:
-    pair = 2 * bs * K * D * itemsize
-    return max(1, min(_LANES // bs, _FETCH_BYTES // pair))
+def _blocks_per_fetch(bs: int, row_bytes: int) -> int:
+    """``row_bytes``: what one cache position holds over every pool."""
+    return max(1, min(_LANES // bs, _FETCH_BYTES // (bs * row_bytes)))
 
 
-def _kernel(tables_ref, meta_ref,              # scalar prefetch
-            q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, q3_ref, len_ref, m_ref, l_ref, acc_ref):
-    _, P, bs, K, D = kbuf.shape
+def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
+            n_pool: int, scale: float, mxu_dtype):
+    """``refs``: the key pool and, where ``n_pool`` is 2, the value pool
+    (with one pool the value is the leading columns of the key's block:
+    latent attention, whose value is the latent itself), the output, then
+    the scratch: a fetch buffer per pool, the semaphores, the queries
+    head-major, lengths, softmax statistics and accumulator."""
+    pools, o_ref = refs[:n_pool], refs[n_pool]
+    bufs = refs[n_pool + 1:2 * n_pool + 1]
+    sems, q3_ref, len_ref, m_ref, l_ref, acc_ref = refs[2 * n_pool + 1:]
+    P, bs = bufs[0].shape[1:3]
+    K = bufs[0].shape[3] if bufs[0].ndim == 5 else 1
     R, N, _ = q_ref.shape
     rep, T = N // K, meta_ref.shape[0] // 2
     M, C = R * rep, P * bs
-    scale = 1.0 / jnp.sqrt(jnp.float32(D))
+    Dv = acc_ref.shape[2]
     t0 = pl.program_id(0) * R
 
     def length(r):
@@ -98,14 +106,14 @@ def _kernel(tables_ref, meta_ref,              # scalar prefetch
         # a fetch step skips the blocks past a walk's last; what the slots
         # hold there is masked out of the scores but multiplies the (zero)
         # probabilities, so it must be finite from the first step on
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
 
     # the tile's queries head-major, [K, R*rep, D]: row r of the tile is
     # rows r*rep .. of every KV head; its lengths beside them; its softmax
     # statistics and accumulator opened once, each run closes its own rows
-    q = q_ref[...].astype(jnp.float32).reshape(R, K, rep, D)
-    q3_ref[...] = jnp.swapaxes(q, 0, 1).reshape(K, M, D)
+    q = q_ref[...].astype(mxu_dtype).reshape(R, K, rep, q_ref.shape[2])
+    q3_ref[...] = jnp.swapaxes(q, 0, 1).reshape(K, M, q_ref.shape[2])
 
     def put_length(r, _):
         len_ref[pl.ds(r * rep, rep), :] = jnp.full(
@@ -115,6 +123,13 @@ def _kernel(tables_ref, meta_ref,              # scalar prefetch
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def head_major(buf, slot):
+        """A fetched slot as ``[K, C, D]``."""
+        x = buf[slot].astype(mxu_dtype)
+        if buf.ndim == 4:                      # a pool without a head axis
+            return x.reshape(1, C, x.shape[-1])
+        return jnp.swapaxes(x.reshape(C, K, x.shape[-1]), 0, 1)
 
     def online_softmax(i, kt, vt, rows, limit):
         """One fetch step of the query rows ``rows`` (a slice of the tile's
@@ -133,7 +148,7 @@ def _kernel(tables_ref, meta_ref,              # scalar prefetch
         p = jnp.where(live, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         pv = jax.lax.dot_general(
-            p, vt, (((2,), (1,)), ((0,), (0,))),
+            p.astype(mxu_dtype), vt, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         m_ref[:, rows, 0:1] = m_new
         l_ref[:, rows, 0:1] = alpha * l_ref[:, rows, 0:1] + jnp.sum(
@@ -149,10 +164,9 @@ def _kernel(tables_ref, meta_ref,              # scalar prefetch
             @pl.when(j < nblk)
             def _():
                 blk = tables_ref[t, j]
-                for pool, buf, sem in ((k_hbm, kbuf, sems.at[0, slot]),
-                                       (v_hbm, vbuf, sems.at[1, slot])):
+                for n, (pool, buf) in enumerate(zip(pools, bufs)):
                     copy = pltpu.make_async_copy(
-                        pool.at[blk], buf.at[slot, p], sem)
+                        pool.at[blk], buf.at[slot, p], sems.at[n, slot])
                     copy.start() if start else copy.wait()
 
         jax.lax.fori_loop(0, P, page, None)
@@ -175,9 +189,8 @@ def _kernel(tables_ref, meta_ref,              # scalar prefetch
                 fetch(t, nblk, i + 1, 1 - slot, True)
 
             fetch(t, nblk, i, slot, False)
-            k = kbuf[slot].reshape(C, K, D).astype(jnp.float32)
-            v = vbuf[slot].reshape(C, K, D).astype(jnp.float32)
-            kt, vt = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)  # [K, C, D]
+            kt = head_major(bufs[0], slot)
+            vt = kt[:, :, :Dv] if n_pool == 1 else head_major(bufs[1], slot)
             # a row alone (a decode row) meets the blocks alone; a run
             # meets them with the whole tile, rows outside it masked out
             jax.lax.cond(
@@ -193,7 +206,7 @@ def _kernel(tables_ref, meta_ref,              # scalar prefetch
             rows = pl.ds(r * rep, rep)
             l = l_ref[:, rows, 0:1]
             out = acc_ref[:, rows, :] / jnp.where(l == 0.0, 1.0, l)
-            o_ref[pl.ds(r, 1)] = out.reshape(1, N, D).astype(o_ref.dtype)
+            o_ref[pl.ds(r, 1)] = out.reshape(1, N, Dv).astype(o_ref.dtype)
 
         jax.lax.fori_loop(r0, r1, put, None)
 
@@ -207,34 +220,39 @@ def _kernel(tables_ref, meta_ref,              # scalar prefetch
     jax.lax.while_loop(lambda r: r < R, next_run, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
-def _tiles(tables, meta, q, kpool, vpool, interpret):
-    """The kernel over whole tiles. Jitted (inlined into the caller's
-    program) for its trace cache alone: the kernel body is traced once per
-    set of operand shapes, not once per program that calls it."""
-    T, N, D = q.shape
-    _, bs, K, _ = kpool.shape
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "value_dim", "scale", "name", "mxu_dtype", "interpret"))
+def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
+           interpret):
+    """The kernel over whole tiles: ``pools`` the key pool and the value
+    pool, or the key pool alone where the value is the first ``value_dim``
+    columns of the key's block. Jitted (inlined into the caller's program)
+    for its trace cache alone: the kernel body is traced once per set of
+    operand shapes, not once per program that calls it."""
+    T, N, _ = q.shape
+    bs = pools[0].shape[1]
+    K = pools[0].shape[2] if pools[0].ndim == 4 else 1
     rep = N // K
     R = tile_rows(N, K)
-    P = _blocks_per_fetch(bs, K, D, kpool.dtype.itemsize)
+    P = _blocks_per_fetch(bs, sum(
+        K * x.shape[-1] * x.dtype.itemsize for x in pools))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T // R,),
-        in_specs=[
-            pl.BlockSpec((R, N, D), lambda i, tbl, meta: (i, 0, 0)),
-            hbm, hbm,
-        ],
-        out_specs=pl.BlockSpec((R, N, D), lambda i, tbl, meta: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, P, bs, K, D), kpool.dtype),
-            pltpu.VMEM((2, P, bs, K, D), vpool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((K, R * rep, D), jnp.float32),
+        in_specs=[pl.BlockSpec((R,) + q.shape[1:],
+                               lambda i, tbl, meta: (i, 0, 0))]
+        + [hbm] * len(pools),
+        out_specs=pl.BlockSpec((R, N, value_dim),
+                               lambda i, tbl, meta: (i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, P) + x.shape[1:], x.dtype)
+                        for x in pools] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.VMEM((K, R * rep, q.shape[2]), mxu_dtype),
             pltpu.VMEM((R * rep, _LANES), jnp.int32),
             pltpu.VMEM((K, R * rep, _LANES), jnp.float32),
             pltpu.VMEM((K, R * rep, _LANES), jnp.float32),
-            pltpu.VMEM((K, R * rep, D), jnp.float32),
+            pltpu.VMEM((K, R * rep, value_dim), jnp.float32),
         ],
     )
     compiler_params = None
@@ -244,24 +262,24 @@ def _tiles(tables, meta, q, kpool, vpool, interpret):
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, n_pool=len(pools), scale=scale,
+                          mxu_dtype=mxu_dtype),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, N, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, N, value_dim), q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
-        name="paged_attention",
-    )(tables, meta, q, kpool, vpool)
+        name=name,
+    )(tables, meta, q, *pools)
 
 
-def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
-                    tables: jax.Array, lengths: jax.Array,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Drop-in for ``models.paged.paged_attention_reference``."""
+def _walk(q, pools, tables, lengths, *, kv_heads, value_dim, scale, name,
+          mxu_dtype, interpret):
+    """Pads the rows to whole tiles, says which rows share a table, and
+    runs the kernel: what both entry points below are."""
     if interpret is None:
         interpret = _use_interpret()
-    Tn, N, D = q.shape
-    assert D == kpool.shape[3] and N % kpool.shape[2] == 0
-    pad = -Tn % tile_rows(N, kpool.shape[2])
+    Tn, N, _ = q.shape
+    pad = -Tn % tile_rows(N, kv_heads)
     if pad:                            # pad rows: zero table, length 1
         q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
         tables = jnp.pad(tables, ((0, pad), (0, 0)))
@@ -275,4 +293,40 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
         jnp.all(tables[1:] == tables[:-1], axis=1)])
     meta = jnp.concatenate([lengths.astype(jnp.int32),
                             same.astype(jnp.int32)])
-    return _tiles(tables, meta, q, kpool, vpool, interpret)[:Tn]
+    return _tiles(tables, meta, q, *pools, value_dim=value_dim, scale=scale,
+                  name=name, mxu_dtype=mxu_dtype, interpret=interpret)[:Tn]
+
+
+def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
+                    tables: jax.Array, lengths: jax.Array,
+                    interpret: Optional[bool] = None) -> jax.Array:
+    """Drop-in for ``models.paged.paged_attention_reference``."""
+    D, K = q.shape[2], kpool.shape[2]
+    assert D == kpool.shape[3] and q.shape[1] % K == 0
+    return _walk(q, (kpool, vpool), tables, lengths, kv_heads=K,
+                 value_dim=D, scale=D ** -0.5, name="paged_attention",
+                 mxu_dtype=jnp.float32, interpret=interpret)
+
+
+def latent_paged_attention(q: jax.Array, pool: jax.Array,
+                           tables: jax.Array, lengths: jax.Array,
+                           value_dim: int, scale: float,
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """Weight-absorbed latent (MLA) attention over the paged latent pool:
+    multi-query attention with ONE KV head whose key is a cache position's
+    whole row (``c_kv ++ k_pe``, zero-padded to a lane multiple) and whose
+    value is that row's first ``value_dim`` columns (``c_kv``), so each
+    position is read once. The same walk as :func:`paged_attention`,
+    instantiated with one pool and no head axis.
+
+    q [T, N, W]: the queries in latent space (``W_uk`` folded in) beside
+    their rope part, padded like the pool's rows; pool [NB, bs, W]; returns
+    the attended latents [T, N, value_dim] (``W_uv`` is the caller's). The
+    products take bf16 operands (a prompt chunk against a long context is
+    MXU-bound, unlike the dense cells' shapes) and accumulate in float32;
+    the jnp path rounds its probabilities the same way."""
+    assert q.shape[2] == pool.shape[2] and pool.ndim == 3
+    return _walk(q, (pool,), tables, lengths, kv_heads=1,
+                 value_dim=value_dim, scale=float(scale),
+                 name="latent_paged_attention", mxu_dtype=jnp.bfloat16,
+                 interpret=interpret)

@@ -106,6 +106,13 @@ class span:
         self._t0 = time.perf_counter()
         return self
 
+    def note(self, **attrs) -> None:
+        """Attributes learned inside the span (a tick's expert counts come
+        back with its tokens): they reach the ``Tracer`` record; the
+        profiler's annotation was written at entry and stays as it is."""
+        if self._rec is not None and self._rec.rec is not None:
+            self._rec.rec.attrs.update(attrs)
+
     def __exit__(self, exc_type, exc, tb):
         dt = time.perf_counter() - self._t0
         if self._rec is not None:

@@ -56,3 +56,34 @@ def test_paged_attention_compiles_for_v5e(one_chip, shape):
     # one Mosaic call, operands (tables, lengths+same, q, kpool, vpool):
     # benchmarks/roofline/paged_attention.py reads the pool at operand 3
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+# (rows of the tick bucket, table tier) of the latent serving cell: 16 query
+# heads on one latent row of 512 + 64 values stored 640 wide, depth 9,
+# 4,352 blocks of 32 a layer
+LATENT_SHAPES = {"moonlight16b-512x256": (512, 256),
+                 "moonlight16b-64x64": (64, 64)}
+
+
+@pytest.mark.parametrize("shape", sorted(LATENT_SHAPES))
+def test_latent_paged_attention_compiles_for_v5e(one_chip, shape):
+    from deepspeed_tpu.ops.pallas.paged_attention import \
+        latent_paged_attention
+
+    T, MB = LATENT_SHAPES[shape]
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, pool, t, n: latent_paged_attention(
+            q, pool, t, n, 512, 192 ** -0.5, interpret=False)
+    ).lower(arg((T, 16, 640), jnp.bfloat16),
+            arg((9 * 4352, 32, 640), jnp.bfloat16),
+            arg((T, MB), jnp.int32), arg((T,), jnp.int32)).compile()
+    text = compiled.as_text()
+    # one Mosaic call under its own name, operands (tables, lengths+same,
+    # q, pool): benchmarks/roofline/latent_paged_attention.py classifies
+    # by the name and reads the pool at operand 3
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%latent_paged_attention" in text

@@ -497,7 +497,7 @@ def test_fastgen_mla_greedy_matches_slot_engine():
         fg = FastGenEngine(cfg, n_blocks=32, block_size=16,
                            max_blocks_per_seq=8, token_budget=32,
                            temperature=0.0, seed=0)
-        assert set(fg.pool) == {"ckv", "kpe"}   # latent pool layout
+        assert set(fg.pool) == {"latent"}      # latent pool layout
         got = fg.generate_all(uids, prompts, max_new_tokens=new,
                               planned=planned)
         for u in uids:

@@ -431,16 +431,34 @@ class TestDeepseekV3Import:
         np.testing.assert_allclose(np.asarray(step_logits[:, 0]),
                                    full_ext[:, -1], rtol=2e-3, atol=2e-3)
 
-    def test_first_k_dense_rejected(self):
+    def test_first_k_dense_matches_hf(self):
+        """A leading dense layer (``first_k_dense_replace``, Moonlight's and
+        DeepSeek-V3's stack) imports as a segment of its own and matches
+        the HF model: direct q projection, one group, top-2 of 4, a shared
+        expert, a non-zero selection bias."""
         hf_cfg = transformers.DeepseekV3Config(
-            vocab_size=64, hidden_size=32, num_hidden_layers=2,
-            num_attention_heads=2, n_routed_experts=4,
-            q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8,
-            qk_rope_head_dim=4, v_head_dim=8, first_k_dense_replace=1)
+            vocab_size=128, hidden_size=32, intermediate_size=64,
+            moe_intermediate_size=24, num_hidden_layers=3,
+            num_attention_heads=2, num_key_value_heads=2,
+            n_routed_experts=4, num_experts_per_tok=2, n_shared_experts=1,
+            q_lora_rank=None, kv_lora_rank=8, qk_nope_head_dim=8,
+            qk_rope_head_dim=4, v_head_dim=8, first_k_dense_replace=1,
+            n_group=1, topk_group=1, norm_topk_prob=True,
+            routed_scaling_factor=2.446, max_position_embeddings=64,
+            tie_word_embeddings=False)
         torch.manual_seed(44)
         model = transformers.DeepseekV3ForCausalLM(hf_cfg)
-        with pytest.raises(NotImplementedError, match="first_k_dense"):
-            import_hf_model(model)
+        with torch.no_grad():
+            for layer in model.model.layers[1:]:
+                layer.mlp.gate.e_score_correction_bias.add_(
+                    torch.tensor([0.3, 0.05, 0.2, 0.1]))
+        cfg, params = import_hf_model(model)
+        assert cfg.first_dense_layers == 1 and cfg.moe_dispatch == "ragged"
+        assert params["dense_blocks"]["w_up"].shape == (1, 32, 64)
+        assert params["blocks"]["w_up"].shape == (2, 4, 32, 24)
+        tokens = np.random.default_rng(44).integers(0, 128, (2, 16),
+                                                    dtype=np.int32)
+        _compare_logits(model, tokens, cfg, params, rtol=5e-4, atol=5e-4)
 
 
 class TestDeepseekV2Import:

@@ -17,6 +17,7 @@ from deepspeed_tpu.inference.fastgen import FastGenEngine
 PALLAS = os.path.join(os.path.dirname(dst.__file__), "ops", "pallas")
 KERNEL_NAMES = {
     "flash_fwd", "flash_dq", "flash_dkv", "paged_attention",
+    "latent_paged_attention",
     "block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv",
     "evoformer_attention", "fused_adam", "quantize_int8_blocks",
     "dequant_reduce", "rms_norm", "layer_norm"}
@@ -44,6 +45,12 @@ def test_every_pallas_call_is_named():
             if isinstance(node, ast.Call) and \
                     getattr(node.func, "id", "") == "_run_rows":
                 literal.add(node.args[0].value)   # norms.py: by the caller
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "id", "") == "_walk":
+                # paged_attention.py: one call site, named by each of the
+                # kernel's two instantiations
+                literal.update(k.value.value for k in node.keywords
+                               if k.arg == "name")
     assert sites == 12
     assert literal == KERNEL_NAMES
 

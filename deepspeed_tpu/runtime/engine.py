@@ -1841,26 +1841,44 @@ class DeepSpeedTPUEngine:
     def _grad_accum_dtype(self):
         """GAS accumulator dtype: fp32 default; data_types.grad_accum_dtype
         opts into bf16 (reference data_types section, including its
-        "bf16"/"fp16"/"fp32" spellings). Shared by every step builder —
-        at multi-B params the fp32 grad buffer IS the HBM ceiling."""
+        "bf16"/"fp16"/"fp32" spellings). Shared by every step builder
+        but the 1-bit wire step (fp32: its warmup all-reduces the sum) —
+        at multi-B params the fp32 grad buffer IS the HBM ceiling. The
+        buffer exists at gas > 1 only (``accumulate_microbatches``)."""
         name = self.config.data_types.grad_accum_dtype
         alias = {"bf16": "bfloat16", "fp16": "float16", "fp32": "float32"}
         return jnp.dtype(alias.get(name, name) if name else jnp.float32)
 
     @staticmethod
-    def accumulate_microbatches(micro_fn, zeros, batch, gas,
+    def accumulate_microbatches(micro_fn, like, acc_dtype, batch, gas,
                                 constrain=lambda x: x, extra0=None):
-        """Shared GAS loop: accumulate grads IN THE DTYPE OF ``zeros``
-        (callers build zeros via ``_grad_accum_dtype()``; fp32 default)
-        from ``micro_fn(mb) -> (loss, grads)`` over the leading micro-batch
-        dim (scan for gas>1).
-        Used by the fused step, the host-step runner, and available to
-        custom step builders — keep ONE copy of these semantics.
+        """Shared GAS loop: the sum, in ``acc_dtype`` (callers pass
+        ``_grad_accum_dtype()``; fp32 default), of the gradients of
+        ``micro_fn(mb) -> (loss, grads)`` over the leading micro-batch
+        dim, and the mean loss.
+        Used by every fused step builder and the host-step runner, and
+        available to custom step builders — keep ONE copy of these
+        semantics.
+
+        One micro-batch has nothing to accumulate: its gradients, cast to
+        ``acc_dtype`` (``0 + g`` rounds the same way) and constrained, ARE
+        the sum — no accumulator exists at gas == 1. At gas > 1 the carry
+        is zeros shaped like ``like`` (arrays or ShapeDtypeStructs; read
+        for its shapes only, and only then) and a ``lax.scan`` adds each
+        micro-batch's gradients into it.
 
         ``extra0``: optional extra carry threaded through the micros (LoCo
         residuals); micro_fn is then called as ``micro_fn(mb, extra) ->
         (loss, grads, extra)`` and the return gains the final extra."""
         with_extra = extra0 is not None
+
+        if gas == 1:
+            squeezed = jax.tree.map(lambda x: x[0], batch)
+            loss, grads, *extra = (micro_fn(squeezed, extra0) if with_extra
+                                   else micro_fn(squeezed))
+            grads = constrain(jax.tree.map(
+                lambda g: g.astype(acc_dtype), grads))
+            return (grads, loss, *extra)
 
         def micro(carry, mb):
             if with_extra:
@@ -1875,15 +1893,15 @@ class DeepSpeedTPUEngine:
             acc = constrain(acc)
             return ((acc, extra) if with_extra else acc), loss
 
-        carry0 = (zeros, extra0) if with_extra else zeros
-        if gas == 1:
-            squeezed = jax.tree.map(lambda x: x[0], batch)
-            carry, loss = micro(carry0, squeezed)
-        else:
-            carry, losses = jax.lax.scan(micro, carry0, batch)
-            loss = jnp.mean(losses)
+        with jax.named_scope("grad_accumulate"):
+            zeros = jax.tree.map(
+                lambda s: jnp.zeros(s.shape, acc_dtype), like)
+        zeros = constrain(zeros)
+        carry, losses = jax.lax.scan(
+            micro, (zeros, extra0) if with_extra else zeros, batch)
+        loss = jnp.mean(losses)
         if with_extra:
-            (grads_sum, extra) = carry
+            grads_sum, extra = carry
             return grads_sum, loss, extra
         return carry, loss
 
@@ -1895,10 +1913,6 @@ class DeepSpeedTPUEngine:
 
         def train_step(state, batch):
             scale = state["scaler"].scale if self.fp16_enabled else None
-            with jax.named_scope("grad_accumulate"):
-                zeros = jax.tree.map(
-                    lambda s: jnp.zeros(s.shape, acc_dt), self._shapes)
-            zeros = self._constrain_grads(zeros)
 
             def micro_fn(mb):
                 # chaos train/nan_grads injection (testing/chaos.py): the
@@ -1920,7 +1934,8 @@ class DeepSpeedTPUEngine:
                 return loss, grads
 
             grads_sum, mean_loss = self.accumulate_microbatches(
-                micro_fn, zeros, batch, gas, constrain=self._constrain_grads)
+                micro_fn, self._shapes, acc_dt, batch, gas,
+                constrain=self._constrain_grads)
 
             grad_scale = jnp.float32(gas) * (scale if scale is not None else 1.0)
             lr_mult = None
@@ -2110,8 +2125,6 @@ class DeepSpeedTPUEngine:
 
         def core(master_local, err0, batch_local, scale,
                  params_full=None):
-            zeros = jax.tree.map(
-                lambda x: jnp.zeros(x.shape, acc_dt), master_local)
             # loop-invariant: ONE (possibly quantized, possibly chunk-
             # fenced) param gather per step, not per micro — and with
             # the double buffer (overlap_step) ZERO: the forward
@@ -2134,7 +2147,8 @@ class DeepSpeedTPUEngine:
                     return loss, gl, err
 
                 grads_sum, losses_mean, err = self.accumulate_microbatches(
-                    micro, zeros, batch_local, gas, extra0=err0)
+                    micro, master_local, acc_dt, batch_local, gas,
+                    extra0=err0)
             else:
                 def micro(b):
                     loss, gfull = jax.value_and_grad(full_loss)(params, b)
@@ -2148,7 +2162,7 @@ class DeepSpeedTPUEngine:
                     return loss, gl
 
                 grads_sum, losses_mean = self.accumulate_microbatches(
-                    micro, zeros, batch_local, gas)
+                    micro, master_local, acc_dt, batch_local, gas)
                 err = None
             mean_loss = jax.lax.pmean(losses_mean, axes) / scale
             return grads_sum, err, mean_loss
@@ -2240,28 +2254,17 @@ class DeepSpeedTPUEngine:
             lambda s: C.manual_spec(s, axes), self.master_spec,
             is_leaf=lambda x: isinstance(x, P))
 
-        acc_dt_c = self._grad_accum_dtype()
+        acc_dt = self._grad_accum_dtype()
 
         def local(master_local, batch_local, scale):
-            zeros = jax.tree.map(
-                lambda x: jnp.zeros(x.shape, acc_dt_c), master_local)
-
             def scaled_loss(ml, b):
                 params = gather_tree(ml)
                 loss = self.model_spec.loss_fn(params, b)
                 return loss * scale
 
-            def micro(acc, b):
-                loss, g = jax.value_and_grad(scaled_loss)(master_local, b)
-                return jax.tree.map(jnp.add, acc, g), loss
-
-            if gas == 1:
-                squeezed = jax.tree.map(lambda x: x[0], batch_local)
-                grads_sum, loss = micro(zeros, squeezed)
-                losses_mean = loss
-            else:
-                grads_sum, losses = jax.lax.scan(micro, zeros, batch_local)
-                losses_mean = jnp.mean(losses)
+            grads_sum, losses_mean = self.accumulate_microbatches(
+                lambda b: jax.value_and_grad(scaled_loss)(master_local, b),
+                master_local, acc_dt, batch_local, gas)
             mean_loss = jax.lax.pmean(losses_mean, axes) / scale
             return grads_sum, mean_loss
 
@@ -2323,25 +2326,18 @@ class DeepSpeedTPUEngine:
             scale = st["scaler"].scale if self.fp16_enabled else None
             dtype = jnp.dtype(self.precision)
 
-            zeros = jax.tree.map(
-                lambda x: jnp.zeros(x.shape, jnp.float32), st["master"])
-
-            def micro(acc, b):
+            def micro(b):
                 def wrt_master(m):
                     p = jax.tree.map(lambda x: x.astype(dtype), m)
                     loss = self.model_spec.loss_fn(p, b)
                     return loss * scale if scale is not None else loss
 
-                loss, g = jax.value_and_grad(wrt_master)(st["master"])
-                return jax.tree.map(jnp.add, acc, g), loss
+                return jax.value_and_grad(wrt_master)(st["master"])
 
-            if gas == 1:
-                squeezed = jax.tree.map(lambda x: x[0], batch_local)
-                grads_sum, loss = micro(zeros, squeezed)
-                losses_mean = loss
-            else:
-                grads_sum, losses = jax.lax.scan(micro, zeros, batch_local)
-                losses_mean = jnp.mean(losses)
+            # fp32 whatever data_types.grad_accum_dtype says: the warmup's
+            # exact pmean reads this sum
+            grads_sum, losses_mean = self.accumulate_microbatches(
+                micro, st["master"], jnp.float32, batch_local, gas)
 
             # warmup: exact grad allreduce (identical ranks feed identical
             # momentum). frozen: gradients stay LOCAL — only the compressed

@@ -147,13 +147,10 @@ class HostStepRunner:
         eng = self.engine
 
         def grad_step(params, batch):
-            zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape,
-                                    self.engine._grad_accum_dtype()), params)
             return type(eng).accumulate_microbatches(
                 lambda mb: jax.value_and_grad(eng.model_spec.loss_fn)(
                     params, mb),
-                zeros, batch, gas)
+                params, eng._grad_accum_dtype(), batch, gas)
 
         return jax.jit(grad_step)
 

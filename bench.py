@@ -12,7 +12,7 @@ the chip's bf16 peak.
 The suite ``entries`` cover the driver's north-star milestone configs
 (BASELINE.json): ZeRO-2 + FusedAdam BERT-large fp16, ZeRO-3 llama-style
 (largest fitting 16G HBM single-chip), AutoTP-style inference generate,
-FastGen paged/planned serving, MoE + Ulysses SP (dropless ragged dispatch),
+FastGen paged serving under arrivals, MoE + Ulysses SP (dropless ragged dispatch),
 the 1F1B pipeline (CPU mesh — one chip can't host a pipe axis), an
 ``autotune_smoke`` proving the tuner picks the headline config on-chip,
 ``comm_busbw_cpu_mesh_world8`` (non-degenerate collective busbw), and
@@ -363,81 +363,6 @@ def inference_bench(model="gpt2_125m", batch=8, prompt_len=128, max_new=128):
         "decode_tokens_per_sec": round(batch * max_new / dt, 1),
         "batch": batch, "prompt_len": prompt_len, "max_new": max_new,
     }
-
-
-def fastgen_bench(model="gpt2_125m", n_seqs=16, max_new=48):
-    """FastGen-class serving (paged KV + SplitFuse + grouped-prefill planned
-    scan + fused decode tail — ONE dispatch for the whole mixed workload).
-    Emits the prefill/decode phase split the round-3 verdict asked for.
-    The v1-slot-engine comparison (speedup_vs_slot, r3-measured ~3x) runs
-    only under BENCH_LONG=1 — it doubles the entry's compile load for a
-    comparison whose result is already a committed artifact."""
-    import jax
-    import numpy as np
-
-    from deepspeed_tpu.inference.fastgen import FastGenEngine
-    from deepspeed_tpu.inference.ragged import RaggedInferenceEngine
-
-    rng = np.random.default_rng(0)
-    lens = [int(x) for x in rng.integers(16, 480, n_seqs)]
-    prompts = [rng.integers(0, 50000, n).tolist() for n in lens]
-    uids = list(range(n_seqs))
-
-    fg = FastGenEngine(model, n_blocks=512, block_size=64,
-                       max_blocks_per_seq=16, token_budget=512,
-                       temperature=0.0, seed=0, max_seq_len=1024)
-    # warm at FULL shape: the planned-serve and decode-scan tiers are
-    # max_new-dependent; a short warm run leaves them cold and the timed
-    # run pays their compiles
-    fg.generate_all(uids, prompts, max_new_tokens=max_new)
-    t0 = time.perf_counter()
-    out = fg.generate_all(uids, prompts, max_new_tokens=max_new)
-    t_fg = time.perf_counter() - t0
-    gen = sum(len(v) for v in out.values())
-
-    # phase split (separate dispatches so each phase is timeable): prefill-
-    # only planned scan, then decode-only windows. First cycle warms the
-    # unfused program shapes, second is timed.
-    t_prefill = t_decode = gen_decode = 0
-    for timed in (False, True):
-        cyc = [(1000 if timed else 100) + u for u in uids]
-        t0 = time.perf_counter()
-        fg.put(cyc, prompts)
-        assert fg.serve_planned(max_new, until_prefilled=True,
-                                fuse_decode_tail=False), \
-            "plan infeasible — phase split would time the wrong phases"
-        jax.block_until_ready(jax.tree.leaves(fg.pool)[0])
-        t_prefill = time.perf_counter() - t0
-        gen_planned = sum(len(fg.seqs[u].generated) for u in cyc)
-        t0 = time.perf_counter()
-        fg._generate_dynamic(cyc, max_new)
-        jax.block_until_ready(jax.tree.leaves(fg.pool)[0])
-        t_decode = time.perf_counter() - t0
-        gen_decode = sum(len(fg.seqs[u].generated) for u in cyc) - gen_planned
-        fg.flush(cyc)
-    del fg
-
-    res = {
-        "decode_tokens_per_sec": round(gen / t_fg, 1),
-        "decode_only_tokens_per_sec": round(gen_decode / t_decode, 1),
-        "prefill_tokens_per_sec": round(sum(lens) / t_prefill, 1),
-        "prefill_phase_s": round(t_prefill, 3),
-        "decode_phase_s": round(t_decode, 3),
-        "n_seqs": n_seqs, "prompt_lens": "16-480", "max_new": max_new,
-    }
-    if os.environ.get("BENCH_LONG", "0") != "0":
-        slot = RaggedInferenceEngine(model, max_slots=n_seqs, max_len=1024,
-                                     temperature=0.0, seed=0)
-        slot.generate_all(uids, prompts, max_new_tokens=max_new)  # warm
-        t0 = time.perf_counter()
-        out = slot.generate_all(uids, prompts, max_new_tokens=max_new)
-        t_slot = time.perf_counter() - t0
-        gen_slot = sum(len(v) for v in out.values())
-        del slot
-        res["slot_engine_tokens_per_sec"] = round(gen_slot / t_slot, 1)
-        res["speedup_vs_slot"] = round((gen / t_fg) / (gen_slot / t_slot), 2)
-    gc.collect()
-    return res
 
 
 def fastgen_sla_bench(model="gpt2_125m", n_req=24, max_new=48,
@@ -1410,7 +1335,6 @@ def qgz_llama_bench():
 # are worst-case guards (hung compile, lost device), not expectations.
 SUITE_SCHEDULE = [
     ("zero3_llama_3b_adafactor", llama_3b_bench, 540, 300),
-    ("fastgen_paged_splitfuse_gpt2", fastgen_bench, 360, 150),
     ("fastgen_sla_poisson_gpt2", fastgen_sla_bench, 360, 150),
     ("fleet_sla_poisson_gpt2", fleet_sla_bench, 420, 150),
     ("fleet_sla_multitenant_gpt2", fleet_sla_multitenant_bench, 420, 150),

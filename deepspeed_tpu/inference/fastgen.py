@@ -203,14 +203,10 @@ class FastGenEngine:
         if use_pallas_kernel is None:
             use_pallas_kernel = jax.default_backend() == "tpu"
         self._use_kernel = use_pallas_kernel
-        # rows of a kernel tile; 0 where a tick's attention is not the
-        # kernel's (forward_paged sends ALiBi to the reference). Latent
-        # attention is the kernel with one KV head.
-        self._tile_rows = 0
-        if use_pallas_kernel and cfg.pos_emb != "alibi":
-            from deepspeed_tpu.ops.pallas.paged_attention import tile_rows
-            self._tile_rows = tile_rows(
-                cfg.num_heads, 1 if cfg.mla else cfg.kv_heads)
+        # what every tick's attention is, and the rows of its kernel tile
+        # (0 where it is not a kernel)
+        self._attention, self._tile_rows = PG.tick_attention(
+            cfg, use_pallas_kernel)
         # expert layers whose per-expert row counts ride back with a
         # tick's sampled tokens; 0 for a model without experts
         self._expert_layers = sum(
@@ -258,8 +254,8 @@ class FastGenEngine:
         self._tm_ticks = telemetry.counter(
             "fastgen_ticks_total",
             "engine ticks by kind (mixed: the tick held prompt rows / "
-            "decode: it held none, from step() or a fused window / "
-            "planned) and block-table width tier")
+            "decode: it held none, from step() or a fused window) and "
+            "block-table width tier")
         self._tm_gen_tok = telemetry.counter(
             "fastgen_generated_tokens_total", "tokens sampled and kept")
         self._tm_prefill_tok = telemetry.counter(
@@ -357,9 +353,8 @@ class FastGenEngine:
 
     @staticmethod
     def _slot_tier(n_slots: int) -> int:
-        """Pow2 slot-count tier (min 4) — ONE rule shared by the grouped
-        plan layout (decode-row region) and the serve fn's carry shapes;
-        they must agree or decode rows map to wrong slots."""
+        """Pow2 slot-count tier (min 4): the rows of a fused decode
+        window's program, so that the live count never adds one."""
         ns = 4
         while ns < n_slots:
             ns *= 2
@@ -374,8 +369,8 @@ class FastGenEngine:
 
     def _mb_tier(self, mb_need: int) -> int:
         """Table-width tiers (quarter/half/full) — ONE rule for every
-        compile-cache key (step / decode-scan / planned-serve must agree or
-        the small-grid property of the caches breaks). The tier is the
+        compile-cache key (step and decode-scan must agree or the
+        small-grid property of the caches breaks). The tier is the
         width of the block tables a tick carries: the reference path
         gathers every covered block, the Pallas kernel holds the table in
         scalar memory and fetches only the blocks a row's length reaches
@@ -398,12 +393,7 @@ class FastGenEngine:
 
     # ------------------------------------------------------------------ #
     def _build_tick(self):
-        cfg = self.cfg
-        if self._use_kernel:
-            from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
-            attn = paged_attention
-        else:
-            attn = PG.paged_attention_reference
+        cfg, attn = self.cfg, self._attention
 
         def tick(params, pool, tokens, positions, tables, rng):
             logits, pool, *stats = PG.forward_paged(
@@ -435,12 +425,7 @@ class FastGenEngine:
         host loop is cheap per step; on TPU the scan is the idiomatic
         equivalent).
         """
-        cfg = self.cfg
-        if self._use_kernel:
-            from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
-            attn = paged_attention
-        else:
-            attn = PG.paged_attention_reference
+        cfg, attn = self.cfg, self._attention
 
         def decode_n(params, pool, tokens, positions, tables, rng):
             def body(carry, _):
@@ -844,14 +829,13 @@ class FastGenEngine:
 
     def _snapshot_host(self, seqs) -> tuple:
         """Snapshot every scheduler-mutated host field of ``seqs`` plus
-        the allocator free list — the ONE definition both rollback paths
-        (step() on tick failure, serve_planned() on plan/dispatch failure)
-        share, so a new ``_Seq`` field added here protects both. Already-
+        the allocator free list, for step()'s roll-back on a tick
+        failure: a new ``_Seq`` field the scheduler mutates goes here. Already-
         emitted metric OBSERVATIONS (TTFT, token counters) cannot be
         unobserved — a tick that fails after sampling may leave a phantom
         sample; state consistency is the contract here, not metric
         exactness."""
-        # generated is append-only within a tick/plan (nothing replaces or
+        # generated is append-only within a tick (nothing replaces or
         # shrinks it mid-dispatch), so its snapshot is just the LENGTH —
         # copying the full history would make every step() O(tokens
         # generated so far) for a failure path that almost never fires
@@ -1148,435 +1132,10 @@ class FastGenEngine:
                     self._admit_order.remove(uid)
         self._tm_sched_gauges()
 
-    # ------------------------------------------------------------------ #
-    # planned (offline) serving — the whole SplitFuse schedule in ONE scan
-    # ------------------------------------------------------------------ #
-    def _plan_layout(self, n_slots: int):
-        """Static row layout of a GROUPED planned tick: ``(Cd, C, G)`` —
-        ``Cd`` decode rows (slot tier), then ``G`` prefill groups of ``C``
-        rows each, every group owned by ONE sequence so its rows share a
-        block table (what :func:`models.paged.grouped_prefill_attention`
-        exploits). None → fall back to the per-token-attention layout
-        (MLA pools latents — no grouped path — and tiny budgets)."""
-        if self.cfg.mla:
-            return None
-        ns = self._slot_tier(n_slots)
-        C = max(16, min(64, self.token_budget // 4))
-        G = (self.token_budget - ns) // C
-        if G < 1:
-            return None
-        return ns, C, G
-
-    def _plan_schedule(self, max_new_tokens: int,
-                       until_prefilled: bool = True):
-        """Precompute SplitFuse ticks for the CURRENT admission set.
-
-        ``until_prefilled`` stops the plan once no live sequence still has
-        prompt tokens to write — mixed ticks (interleaved decode rows of
-        early-finished prompts) are planned at full width, but the pure-
-        decode phase is left to the decode-scan tiers whose ticks are
-        live-sequences wide instead of token-budget wide (a 256-row pad per
-        16-row decode tick would waste the fused dispatch's win).
-
-        With admissions fixed, the scheduler is deterministic: prefill
-        chunking, block growth, and decode row placement depend only on
-        prompt lengths — never on the sampled values (EOS can't stop a
-        planned serve early; extras are trimmed host-side). Each planned
-        tick is (tokens [T] — prompt tokens; kind [T] — 1 marks a decode
-        row that reads the carry's last sampled token for its slot, its
-        tokens entry being ignored; slots [T]; positions [T]; tables
-        [T, MB]; heads [T] bool; group_tables [G, MB] — zero-row [0, MB]
-        under the ungrouped layout). Under the grouped layout decode rows
-        live in [0, Cd) and prefill rows are group-aligned (group = one
-        sequence; leftover rows padded) — slightly more ticks, each ~10×
-        cheaper. Mutates real seq/allocator state — the device executes
-        exactly this plan. Returns None when the pool can't cover the full
-        plan (caller falls back to the dynamic tick loop's backpressure).
-        """
-        order = [u for u in self._admit_order
-                 if u in self.seqs and not self.seqs[u].done]
-        slot_of = {u: i for i, u in enumerate(order)}
-        layout = self._plan_layout(len(order))
-        ticks = []
-        planned_gen = {u: len(self.seqs[u].generated) for u in order}
-        guard = 0
-        while True:
-            live = [self.seqs[u] for u in order
-                    if not self.seqs[u].done
-                    and planned_gen[self.seqs[u].uid] < max_new_tokens]
-            if not live:
-                break
-            if until_prefilled and all(s.prefill_remaining == 0
-                                       for s in live):
-                break
-            guard += 1
-            if guard > 8 * max_new_tokens + sum(
-                    len(s.prompt) for s in live) // max(1, self.token_budget // 2):
-                return None  # defensive: schedule failed to converge
-            if layout is None:
-                need = sum(1 for s in live if s.prefill_remaining == 0) \
-                    + sum(s.prefill_remaining for s in live)
-                Tn = self._bucket(need)
-                Cd, C, G = Tn, 1, 0       # decode rows anywhere; no groups
-            else:
-                Cd, C, G = layout
-                Tn = Cd + G * C
-            tokens = np.full((Tn,), 0, np.int32)
-            kind = np.zeros((Tn,), np.int32)      # 1 ⇒ carry-fed decode row
-            slots = np.zeros((Tn,), np.int32)
-            positions = np.zeros((Tn,), np.int32)
-            tables = np.zeros((Tn, self.max_blocks_per_seq), np.int32)
-            gtables = np.zeros((max(G, 1), self.max_blocks_per_seq), np.int32)
-            heads = np.zeros((Tn,), bool)
-            packed = 0
-            row = 0
-            for s in live:                         # decode rows first
-                if s.prefill_remaining > 0 or row >= Cd:
-                    continue
-                if not self._ensure_blocks(s, s.pos):
-                    return None                    # pool can't cover the plan
-                kind[row] = 1
-                slots[row] = slot_of[s.uid]
-                positions[row] = s.pos
-                tables[row] = s.table
-                heads[row] = True
-                planned_gen[s.uid] += 1
-                s.pos += 1
-                if s.pos + 1 >= self.max_len:
-                    planned_gen[s.uid] = max_new_tokens  # hits max-len cap
-                row += 1
-                packed += 1
-            row = Cd if layout is not None else row
-            for s in live:                         # then prefill chunks
-                if s.prefill_remaining == 0 or row >= Tn:
-                    continue
-                while s.prefill_remaining > 0 and row < Tn:
-                    if layout is not None:
-                        # stay inside the current group; a group hosts ONE
-                        # sequence (pad rows close it out)
-                        room = C - ((row - Cd) % C)
-                    else:
-                        room = Tn - row
-                    chunk = min(s.prefill_remaining, room, Tn - row)
-                    if not self._ensure_blocks(s, s.pos + chunk - 1):
-                        return None
-                    if layout is not None:
-                        gtables[(row - Cd) // C] = s.table
-                    lo = s.prefilled
-                    tokens[row:row + chunk] = s.prompt[lo:lo + chunk]
-                    slots[row:row + chunk] = slot_of[s.uid]
-                    positions[row:row + chunk] = np.arange(
-                        s.pos, s.pos + chunk)
-                    tables[row:row + chunk] = s.table
-                    row += chunk
-                    packed += chunk
-                    s.prefilled += chunk
-                    s.pos += chunk
-                    if s.prefill_remaining == 0:
-                        heads[row - 1] = True
-                        planned_gen[s.uid] += 1
-                        if s.pos + 1 >= self.max_len:
-                            # same max-len stop the dynamic path applies in
-                            # _note_token: the prefill head's token is the
-                            # last
-                            planned_gen[s.uid] = max_new_tokens
-                        if layout is not None and (row - Cd) % C:
-                            row += C - ((row - Cd) % C)   # pad to boundary
-                        break
-            if packed == 0:
-                return None
-            ticks.append((tokens, kind, slots, positions, tables, heads,
-                          gtables))
-        return order, ticks, layout
-
-    def _build_planned_fn(self, n_decode: int = 0, decode_ticks: int = 0):
-        # every shape is derived from the inputs; the cache key in
-        # serve_planned is what distinguishes compiled variants.
-        # ``n_decode`` > 0 ⇒ grouped layout: rows [0, n_decode) are decode
-        # rows, the rest group-aligned prefill (grouped_prefill_attention).
-        # ``decode_ticks`` > 0 ⇒ the pure-decode tail runs INSIDE the same
-        # dispatch: after the planned scan, a decode scan of that many
-        # ticks over the per-slot carry — the whole mixed workload becomes
-        # ONE device call (the host loop between phases was worth ~2 more
-        # dispatch round-trips).
-        cfg = self.cfg
-        if self._use_kernel:
-            from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
-            attn = paged_attention
-        else:
-            attn = PG.paged_attention_reference
-        grouped = n_decode > 0
-
-        def serve(params, pool, toks, kind, slots, positions, tables, gtabs,
-                  heads, rng, last0, dec_pos, dec_tabs):
-            def body(carry, tick):
-                pool, last, rng = carry
-                tok_s, kind_s, slot_s, pos_s, tab_s, gtab_s, head_s = tick
-                rng, sub = jax.random.split(rng)
-                inputs = jnp.where(kind_s == 1, last[slot_s], tok_s)
-                logits, pool = PG.forward_paged(
-                    params, inputs, pos_s, tab_s, pool, cfg,
-                    attention_fn=attn,
-                    group_tables=gtab_s if grouped else None,
-                    n_decode=n_decode if grouped else 0)
-                sampled = sample_logits(
-                    logits, sub, self.temperature, self.top_k,
-                    self.top_p).astype(jnp.int32)
-                # exactly one head row per sequence per tick writes back;
-                # non-head rows scatter to the OOB sentinel and are dropped
-                ns = last.shape[0]
-                idx = jnp.where(head_s, slot_s, ns)
-                last = last.at[idx].set(sampled, mode="drop")
-                return (pool, last, rng), sampled
-
-            (pool, last, rng), out = jax.lax.scan(
-                body, (pool, last0, rng),
-                (toks, kind, slots, positions, tables, gtabs, heads))
-            if not decode_ticks:
-                return out, pool
-
-            def dbody(carry, _):
-                pool, toks_d, pos, rng = carry
-                rng, sub = jax.random.split(rng)
-                logits, pool = PG.forward_paged(
-                    params, toks_d, pos, dec_tabs, pool, cfg,
-                    attention_fn=attn)
-                sampled = sample_logits(
-                    logits, sub, self.temperature, self.top_k,
-                    self.top_p).astype(jnp.int32)
-                return (pool, sampled, pos + 1, rng), sampled
-
-            (pool, _, _, _), out2 = jax.lax.scan(
-                dbody, (pool, last, dec_pos, rng), None,
-                length=decode_ticks)                # out2 [decode_ticks, ns]
-            return (out, out2), pool
-
-        return jax.jit(serve, donate_argnums=(1,))
-
-    def serve_planned(self, max_new_tokens: int,
-                      until_prefilled: bool = True,
-                      fuse_decode_tail: bool = False) -> bool:
-        """Run the precomputed SplitFuse schedule in ONE device dispatch
-        (a scan; by default the prefill/mixed phase — see _plan_schedule).
-
-        Returns False — with all host state rolled back — when the plan is
-        infeasible (pool too small for the full run); the caller then uses
-        the dynamic tick loop, whose per-tick backpressure handles it.
-        EOS can't cut a planned serve short: post-EOS samples are computed
-        and trimmed host-side (the pool holds every seq's full-length
-        blocks for the plan's duration — that's the memory-for-dispatches
-        trade the planner makes).
-        """
-        snap = self._snapshot_host(self.seqs.values())
-
-        def restore():
-            self._restore_host(snap)
-
-        # any failure between planning (which advances seq positions /
-        # allocator state) and the device call landing (compile error,
-        # device OOM, interrupt) must roll the host bookkeeping back —
-        # otherwise positions stay advanced with no tokens recorded and the
-        # engine is permanently corrupted
-        prefilled_pre = sum(s.prefilled for s in self.seqs.values())
-        try:
-            plan = self._plan_schedule(max_new_tokens, until_prefilled)
-            if plan is None:
-                restore()
-                return False
-            nd = 0
-            if fuse_decode_tail and until_prefilled:
-                # append the pure-decode tail to the SAME dispatch when the
-                # pool/length headroom covers it (0 → the caller's decode-
-                # scan windows take over with per-window backpressure)
-                nd = self._plan_decode_tail(plan[0], plan[1], max_new_tokens)
-            ok = self._serve_planned_device(plan, max_new_tokens,
-                                            decode_ticks=nd)
-            if ok:
-                self._tm_prefill_tok.inc(
-                    sum(s.prefilled for s in self.seqs.values())
-                    - prefilled_pre)
-                self._tm_sched_gauges()
-            return ok
-        except BaseException:     # incl. KeyboardInterrupt mid-dispatch
-            restore()
-            raise
-
-    def _plan_decode_tail(self, order, ticks, max_new_tokens: int) -> int:
-        """How many fused decode ticks to append to the planned dispatch:
-        the max per-sequence remainder after the plan's own heads, rounded
-        up to a pow2 tier (compile cache). 0 when nothing remains or when
-        block/length headroom can't cover the tail (callers then run the
-        separate decode-scan phase with its per-window backpressure)."""
-        planned_heads = {u: 0 for u in order}
-        slot_arr = {i: u for i, u in enumerate(order)}
-        for t in ticks:
-            for r in np.nonzero(t[5])[0]:
-                planned_heads[slot_arr[int(t[2][r])]] += 1
-        live = [self.seqs[u] for u in order if not self.seqs[u].done]
-        if not live:
-            return 0
-        rem = 0
-        for u in order:
-            s = self.seqs[u]
-            if s.done:
-                continue
-            want = max_new_tokens - len(s.generated) - planned_heads[u]
-            want = min(want, self.max_len - 1 - s.pos)
-            rem = max(rem, want)
-        if rem <= 0:
-            return 0
-        # every slot runs every tail tick (the scan is rectangular), so the
-        # tail must fit the TIGHTEST sequence's block-table/length headroom
-        headroom = min(self.max_len - 1 - s.pos for s in live)
-        nd = 8
-        while nd < rem:
-            nd *= 2
-        if nd > headroom:
-            nd = min(rem, headroom)   # exact, rarely-cached tier — still 1 dispatch
-        if nd <= 0:
-            return 0
-        if sum(self._blocks_needed(s, s.pos + nd - 1)
-               for s in live) > self.allocator.free_blocks:
-            return 0
-        for s in live:
-            self._ensure_blocks(s, s.pos + nd - 1)
-        return nd
-
-    def _serve_planned_device(self, plan, max_new_tokens: int,
-                              decode_ticks: int = 0) -> bool:
-        order, ticks, layout = plan
-        if not ticks:
-            return True
-        # pad the tick count to a pow2 tier and every tick to the same
-        # (Tn, mb) so the compile cache stays a small grid; pad rows/ticks
-        # write into trash block 0 like any pad
-        n = len(ticks)
-        n_pad = max(4, -(-n // 4) * 4)   # multiple of 4: ≤3 wasted pad
-        #                                  ticks (pow2 wasted up to n-1)
-        Tn = max(t[0].shape[0] for t in ticks)
-        max_pos = max(int(t[3].max()) for t in ticks)
-        mb_need = max_pos // self.block_size + 1
-        if decode_ticks:
-            live_pos = [self.seqs[u].pos for u in order
-                        if not self.seqs[u].done]
-            if live_pos:
-                mb_need = max(mb_need, (max(live_pos) + decode_ticks - 1)
-                              // self.block_size + 1)
-        mb = self._mb_tier(mb_need)
-
-        def padded(j):
-            rows = [np.pad(t[j], [(0, Tn - t[j].shape[0])] +
-                           [(0, 0)] * (t[j].ndim - 1)) for t in ticks]
-            rows += [np.zeros_like(rows[0])] * (n_pad - n)
-            return np.stack(rows)
-
-        toks, kind, slots = padded(0), padded(1), padded(2)
-        positions, tables, heads = padded(3), padded(4)[:, :, :mb], padded(5)
-        # group tables: [G, MB] per tick — G is already constant across
-        # ticks (the static layout), only the tick count needs padding
-        g_rows = [t[6] for t in ticks] + \
-            [np.zeros_like(ticks[0][6])] * (n_pad - n)
-        gtabs = np.stack(g_rows)[:, :, :mb]
-        n_dec = layout[0] if layout is not None else 0
-
-        # admission count must not change the program shape
-        ns = self._slot_tier(len(order))
-        key = ("plan", n_pad, Tn, mb, ns, n_dec, decode_ticks)
-        if key not in self._ticks:
-            self._ticks[key] = self._build_planned_fn(
-                n_decode=n_dec, decode_ticks=decode_ticks)
-        last0 = np.zeros((ns,), np.int32)
-        dec_pos = np.zeros((ns,), np.int32)
-        dec_tabs = np.zeros((ns, mb), np.int32)
-        for i, u in enumerate(order):
-            s = self.seqs[u]
-            if s.last_tok is not None:
-                last0[i] = s.last_tok
-            if decode_ticks and not s.done:
-                dec_pos[i] = s.pos          # post-plan position
-                dec_tabs[i] = s.table[:mb]  # tail blocks pre-allocated
-        sub = self._next_key()
-        # no tick-count label here: planned tick counts are workload-shaped
-        # (unbounded cardinality); decode windows may label ticks because
-        # theirs come from the fixed DECODE_TIERS ladder
-        with telemetry.span("planned_serve"):
-            out, self.pool = self._ticks[key](
-                self.params, self.pool, self._dev(toks), self._dev(kind),
-                self._dev(slots), self._dev(positions), self._dev(tables),
-                self._dev(gtabs), self._dev(heads), sub, self._dev(last0),
-                self._dev(dec_pos), self._dev(dec_tabs))
-        tier = self._mb_tier_name(mb)
-        self._tm_ticks.inc(n, kind="planned", mb_tier=tier)
-        if decode_ticks:
-            self._tm_ticks.inc(decode_ticks, kind="decode", mb_tier=tier)
-        out2 = None
-        if decode_ticks:
-            out, out2 = jax.device_get(out)        # ONE host fetch for both
-            out2 = np.asarray(out2)                # [decode_ticks, ns]
-            out = np.asarray(out)
-        else:
-            out = np.asarray(jax.device_get(out))  # [n_pad, Tn]
-
-        eos_hit = set()
-        for t, (_, _, slot_arr, _, _, head_arr, _) in enumerate(ticks):
-            for r in np.nonzero(head_arr)[0]:
-                u = order[int(slot_arr[r])]
-                s = self.seqs[u]
-                tok = int(out[t, r])
-                s.last_tok = tok
-                if u in eos_hit or s.done:
-                    continue
-                # TTFT on the first sampled token even when it's EOS —
-                # same policy as _note_token, or the planned path would
-                # bias the distribution differently than the tick path
-                self._tm_first_token(s)
-                if self.eos_token_id is not None \
-                        and tok == self.eos_token_id:
-                    eos_hit.add(u)
-                    self._finish(s)
-                    continue
-                if len(s.generated) < max_new_tokens:
-                    s.generated.append(tok)
-                    self._tm_first_token(s)
-                    self._tm_gen_tok.inc()
-        if out2 is not None:                       # fused decode tail
-            for t in range(out2.shape[0]):
-                for i, u in enumerate(order):
-                    s = self.seqs[u]
-                    if s.done:
-                        continue
-                    tok = int(out2[t, i])
-                    s.pos += 1      # this tick's input token entered cache
-                    s.last_tok = tok
-                    if len(s.generated) < max_new_tokens:
-                        self._note_token(s, tok)
-        for u in order:                            # planner ran to max_new
-            s = self.seqs[u]
-            if not s.done and (len(s.generated) >= max_new_tokens
-                               or s.pos + 1 >= self.max_len):
-                self._finish(s)
-        return True
-
-    def generate_all(self, uids, prompts, max_new_tokens: int = 32,
-                     planned: Optional[bool] = None):
-        """Convenience driver: put + serve. A feasible plan runs the whole
-        workload in one dispatch (serve_planned); otherwise SplitFuse ticks
-        stream prefill and the fused decode scan covers pure-decode phases.
-
-        ``planned`` None → auto: planned serving pays per-token compute for
-        pad rows/ticks to eliminate per-tick dispatches — a win where
-        dispatch latency dominates (TPU) and where the Pallas kernel skips out-of-length blocks; the CPU
-        reference attention is rectangular, so dynamic ticks stay cheaper
-        there.
-        """
+    def generate_all(self, uids, prompts, max_new_tokens: int = 32):
+        """Convenience driver: put + serve. SplitFuse ticks stream the
+        prefill and the fused decode scan covers pure-decode phases."""
         self.put(uids, prompts)
-        if planned is None:
-            planned = self._use_kernel
-        if planned:
-            # best-effort fused prefill/mixed phase + decode tail, ONE
-            # dispatch (rolls back if the pool can't cover it); the dynamic
-            # loop's fused decode tiers serve whatever remains either way
-            self.serve_planned(max_new_tokens, fuse_decode_tail=True)
         self._generate_dynamic(uids, max_new_tokens)
         out = {u: self.query(u)[1][:max_new_tokens] for u in uids}
         self.flush(uids)

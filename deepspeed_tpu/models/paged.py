@@ -15,8 +15,9 @@ into reserved trash block 0.
 """
 from __future__ import annotations
 
+import contextlib
 import math
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -91,52 +92,6 @@ def paged_attention_reference(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     return jnp.einsum("tnc,tcnd->tnd", p, vg.astype(jnp.float32)).astype(q.dtype)
 
 
-def grouped_prefill_attention(q: jax.Array, kpool: jax.Array,
-                              vpool: jax.Array, group_tables: jax.Array,
-                              lengths: jax.Array,
-                              alibi: Optional[jax.Array] = None) -> jax.Array:
-    """Attention for CHUNK-ALIGNED prefill rows: one block gather per GROUP.
-
-    The planned SplitFuse schedule packs prefill rows so that each
-    consecutive group of C rows belongs to ONE sequence (pad rows allowed);
-    all rows of a group therefore share a block table and the group gathers
-    its KV blocks ONCE — C× less pool traffic and C× fewer table walks than
-    the per-token paths, which is what makes prefill ticks run at compute
-    speed instead of gather speed (measured 37 ms → ~3 ms per 512-row tick
-    on a v5e). q [R, N, D] with R = G·C; group_tables [G, MB];
-    lengths [R] (pos+1; pad rows have length ≤ 1 and head=False upstream).
-    Cache slot c of a group's gathered context IS absolute position c, so
-    causality is just ``c < length(row)`` — same mask rule as the per-token
-    reference.
-    """
-    R, N, D = q.shape
-    G, MB = group_tables.shape
-    C = R // G
-    bs = kpool.shape[1]
-    K = kpool.shape[2]
-    S = MB * bs
-    kg = kpool[group_tables].reshape(G, S, K, D)         # [G, S, K, D]
-    vg = vpool[group_tables].reshape(G, S, K, D)
-    if K != N:
-        kg = jnp.repeat(kg, N // K, axis=2)
-        vg = jnp.repeat(vg, N // K, axis=2)
-    qg = q.reshape(G, C, N, D)
-    lg = lengths.reshape(G, C)
-    scale = 1.0 / jnp.sqrt(jnp.float32(D))
-    s = jnp.einsum("gcnd,gsnd->gcns", qg.astype(jnp.float32),
-                   kg.astype(jnp.float32)) * scale       # [G, C, N, S]
-    if alibi is not None:
-        rel = (jnp.arange(S)[None, None, :]
-               - (lg[:, :, None] - 1)).astype(jnp.float32)     # [G, C, S]
-        s = s + alibi.astype(jnp.float32)[None, None, :, None] \
-            * rel[:, :, None, :]
-    mask = jnp.arange(S)[None, None, None, :] < lg[:, :, None, None]
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("gcns,gsnd->gcnd", p, vg.astype(jnp.float32))
-    return out.reshape(R, N, D).astype(q.dtype)
-
-
 def _absorbed(q: jax.Array, w_kv_b: jax.Array, cfg: T.TransformerConfig,
               attend: Callable) -> jax.Array:
     """Weight-absorbed MLA around ``attend``: W_uk folds into the query
@@ -161,31 +116,63 @@ def mla_softmax_scale(cfg: T.TransformerConfig) -> float:
         cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
 
 
+def latent_attention_reference(q_row: jax.Array, pool: jax.Array,
+                               tables: jax.Array, lengths: jax.Array,
+                               kvr: int, scale: float) -> jax.Array:
+    """``ops/pallas/paged_attention.latent_paged_attention`` in plain jnp
+    (the CPU path and the kernel's oracle): latent-space queries
+    q_row [T, N, W] against pool rows [NBf, bs, W] -> the attended
+    latents [T, N, kvr]. It gathers every row's whole table, so it is for
+    short tables only."""
+    Tn = q_row.shape[0]
+    bs, MB = pool.shape[1], tables.shape[1]
+    rows = pool[tables].reshape(Tn, MB * bs, pool.shape[2])
+    s = jnp.einsum("tnw,tcw->tnc", q_row, rows).astype(jnp.float32) * scale
+    mask = jnp.arange(MB * bs)[None, None, :] < lengths[:, None, None]
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1).astype(q_row.dtype)
+    return jnp.einsum("tnc,tck->tnk", p, rows[..., :kvr])
+
+
 def paged_mla_attention_reference(q: jax.Array, pool: jax.Array,
                                   tables: jax.Array, lengths: jax.Array,
                                   w_kv_b: jax.Array,
                                   cfg: T.TransformerConfig) -> jax.Array:
     """Weight-absorbed MLA attention over the paged LATENT pool (the
     DeepSeek decode trick of ``transformer._mla_absorbed_attention``, paged)
-    in plain jnp: the CPU path and the kernel's oracle. It gathers every
-    row's whole table, so it is for short tables only.
+    around :func:`latent_attention_reference`.
 
     q [T, N, dn+dr] (post-rope); pool [NBf, bs, W] (rows ``c_kv ++ k_pe ++
     0``); tables [T, MB]; → [T, N, dv].
     """
-    Tn, kvr = q.shape[0], cfg.kv_lora_rank
-    bs, MB = pool.shape[1], tables.shape[1]
-    dt = q.dtype
+    return _absorbed(q, w_kv_b, cfg, lambda q_row: latent_attention_reference(
+        q_row, pool, tables, lengths, cfg.kv_lora_rank,
+        mla_softmax_scale(cfg)))
 
-    def attend(q_row):
-        rows = pool[tables].reshape(Tn, MB * bs, pool.shape[2])
-        s = jnp.einsum("tnw,tcw->tnc", q_row, rows).astype(jnp.float32) \
-            * mla_softmax_scale(cfg)
-        mask = jnp.arange(MB * bs)[None, None, :] < lengths[:, None, None]
-        p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1).astype(dt)
-        return jnp.einsum("tnc,tck->tnk", p, rows[..., :kvr])
 
-    return _absorbed(q, w_kv_b, cfg, attend)
+#: what ``forward_paged(attention_fn=)`` reads as "no kernel"
+_REFERENCES = (None, paged_attention_reference, latent_attention_reference)
+
+
+def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
+                   ) -> Tuple[Callable, int]:
+    """The attention a tick of ``cfg`` runs over its pool, and the rows of
+    that kernel's tile (0 where the plain-jnp reference runs): the ONE
+    place that maps (model, kernels wanted) to a function. The Pallas
+    kernel has no bias input yet, so ALiBi ticks take the reference
+    (correct, rectangular-gather cost); a latent pool takes the kernel's
+    latent instantiation, which is the kernel with one KV head.
+
+    Dense pools: ``fn(q, kpool, vpool, tables, lengths[, alibi=])``;
+    the latent pool: ``fn(q_row, pool, tables, lengths, kvr, scale)``."""
+    if not use_kernel or cfg.pos_emb == "alibi":
+        return (latent_attention_reference if cfg.mla
+                else paged_attention_reference), 0
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        latent_paged_attention, paged_attention, tile_rows)
+
+    if cfg.mla:
+        return latent_paged_attention, tile_rows(cfg.num_heads, 1)
+    return paged_attention, tile_rows(cfg.num_heads, cfg.kv_heads)
 
 
 _EXPERT_LEAVES = ("w_up", "w_down", "w_gate")
@@ -193,13 +180,13 @@ _EXPERT_LEAVES = ("w_up", "w_down", "w_gate")
 
 def _tick_experts(h: jax.Array, lp: Dict[str, jax.Array],
                   cfg: T.TransformerConfig, valid: jax.Array,
-                  stack: Optional[Dict[str, jax.Array]] = None,
-                  layer: Optional[jax.Array] = None
+                  stack: Dict[str, jax.Array], layer: jax.Array
                   ) -> Tuple[jax.Array, jax.Array]:
     """A tick's expert layer on normed rows [T, H], DROPLESS
     (``moe.layer.dropless_moe_ffn``): (output, rows per expert over the
-    ``valid`` rows). The experts' matrices are ``lp``'s, or the whole
-    layer ``stack``'s with the ``layer`` to use."""
+    ``valid`` rows). The experts' matrices are the whole layer
+    ``stack``'s with the ``layer`` to use, or ``lp``'s where the stack is
+    empty (quantised leaves, dequantised a layer at a time)."""
     from deepspeed_tpu.moe.layer import dropless_moe_ffn
 
     experts = stack or {k: lp[k] for k in _EXPERT_LEAVES if k in lp}
@@ -214,12 +201,114 @@ def _tick_experts(h: jax.Array, lp: Dict[str, jax.Array],
         layer=layer if stack else None)
 
 
+class _Rows(NamedTuple):
+    """A tick's rows as the skeleton derives them once for every layer."""
+    positions: jax.Array    # [T]
+    tables: jax.Array       # [T, MB] blocks within one layer's range
+    block_idx: jax.Array    # [T] the block row t writes into
+    offsets: jax.Array      # [T] its slot in that block
+    lengths: jax.Array      # [T] cache slots row t attends to (= pos+1)
+
+
+# The two cache kinds. Each takes (cfg, pool as stored, rows, the function
+# ``tick_attention`` chose) and returns a layer's attention as
+# ``layer(h, lp, flat, base) -> (attn [T, N*dv], flat)``: from the normed
+# rows ``h [T, H]``, the layer's parameters and the flat pool carry
+# (``forward_paged``), project, write the tick's rows into the layer's
+# block range (which starts at block ``base``), attend, and return the
+# attention output before ``wo`` with the new carry.
+
+def _dense_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
+                 rows: _Rows, attend: Callable) -> Callable:
+    """Per-head K/V pools ``{"k", "v"}`` [L, NB, bs, K, D]: q/k/v
+    projections with their biases, ``qk_norm``, rotary at the rows'
+    positions; ALiBi models (BLOOM/Falcon) bias the paged scores by head
+    slope × relative position."""
+    dt = cfg.compute_dtype
+    Tn = rows.positions.shape[0]
+    NB, bs = pool["k"].shape[1:3]
+    cos_t = sin_t = None
+    if cfg.pos_emb == "rope":
+        cos_t, sin_t = T.rope_table(NB * bs, cfg.rope_dim, cfg.rope_theta,
+                                    cfg.rope_scaling_dict)
+    bias = {}
+    if cfg.pos_emb == "alibi":
+        bias["alibi"] = T.alibi_slopes(cfg.num_heads) * cfg.alibi_bias_scale
+
+    def layer(h, lp, flat, base):
+        def proj(name, shape):
+            w = lp[f"w{name}"].astype(dt)
+            out = h @ w
+            if cfg.attn_bias_enabled:
+                out = out + lp[f"b{name}"].astype(dt)
+            return out.reshape(shape)
+
+        q = proj("q", (Tn, cfg.num_heads, cfg.head_dim))
+        k = proj("k", (Tn, cfg.kv_heads, cfg.head_dim))
+        v = proj("v", (Tn, cfg.kv_heads, cfg.head_dim))
+        if cfg.qk_norm:
+            q = T._head_rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+            k = T._head_rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+        if cfg.pos_emb == "rope":
+            q = T.apply_rope_at(q[None], cos_t, sin_t, rows.positions[None])[0]
+            k = T.apply_rope_at(k[None], cos_t, sin_t, rows.positions[None])[0]
+        # blocked KV write (reference ragged_ops KV-copy kernels): token t →
+        # pool[base + block_idx[t], offsets[t]]. Pad tokens hit this layer's
+        # trash block (block 0 of its range — never allocated).
+        pk, pv = flat["k"], flat["v"]
+        pk = pk.at[base + rows.block_idx, rows.offsets].set(
+            k.astype(pk.dtype), mode="drop")
+        pv = pv.at[base + rows.block_idx, rows.offsets].set(
+            v.astype(pv.dtype), mode="drop")
+        attn = attend(q, pk, pv, rows.tables + base, rows.lengths, **bias)
+        return (attn.reshape(Tn, cfg.num_heads * cfg.head_dim),
+                {"k": pk, "v": pv})
+
+    return layer
+
+
+def _latent_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
+                  rows: _Rows, attend: Callable) -> Callable:
+    """The MLA (DeepSeek) pool ``{"latent"}`` [L, NB, bs, W]: a row's
+    latent (``c_kv ++ k_pe``, padded to the lanes) is what is written,
+    and attention is weight-absorbed (:func:`_absorbed`; same math as the
+    v1 engine's latent-cache decode)."""
+    lat = pool["latent"]
+    Tn = rows.positions.shape[0]
+    NB, bs, W = lat.shape[1:]
+    cos_t, sin_t = T.rope_table(NB * bs, cfg.qk_rope_head_dim,
+                                cfg.rope_theta, cfg.rope_scaling_dict)
+
+    def rope_fn(v):                                   # v [T, 1, n, dr]
+        return T.apply_rope_at(v, cos_t, sin_t, rows.positions[:, None])
+
+    row_pad = jnp.zeros(
+        (Tn, W - cfg.kv_lora_rank - cfg.qk_rope_head_dim), lat.dtype)
+
+    def layer(h, lp, flat, base):
+        plat = flat["latent"]
+        hB = h[:, None, :]                            # [T, 1, H]
+        q = T._mla_q(hB, lp, cfg, rope_fn)[:, 0]      # [T, N, dn+dr]
+        c_kv, k_pe = T._mla_latents(hB, lp, cfg, rope_fn)
+        row = jnp.concatenate(
+            [c_kv[:, 0].astype(plat.dtype),
+             k_pe[:, 0, 0].astype(plat.dtype), row_pad], axis=-1)
+        plat = plat.at[base + rows.block_idx, rows.offsets].set(
+            row, mode="drop")
+        attn = _absorbed(q, lp["wkv_b"], cfg, lambda q_row: attend(
+            q_row, plat, rows.tables + base, rows.lengths,
+            cfg.kv_lora_rank, mla_softmax_scale(cfg)))
+        return (attn.reshape(Tn, cfg.num_heads * cfg.v_head_dim),
+                {"latent": plat})
+
+    return layer
+
+
 def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
                   tables: jax.Array, pool: Dict[str, jax.Array],
                   cfg: T.TransformerConfig,
                   attention_fn: Optional[Callable] = None,
-                  group_tables: Optional[jax.Array] = None,
-                  n_decode: int = 0, with_stats: bool = False):
+                  with_stats: bool = False):
     """One SplitFuse tick over a flat token batch.
 
     tokens [T] int32, positions [T] int32, tables [T, MB] int32 (rows shared
@@ -227,39 +316,27 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     updated pool). Parity: the reference's model-implementation forward over
     a RaggedBatchWrapper (``inference/v2/model_implementations``).
 
-    ``group_tables`` [G, MB] (planned ticks): rows [n_decode:] are
-    chunk-aligned — group g of C = (T - n_decode)/G consecutive rows
-    belongs to one sequence with table ``group_tables[g]`` and attends via
-    :func:`grouped_prefill_attention` (one gather per group); only the
-    first ``n_decode`` rows (per-row tables) walk the per-token path. The
-    KV WRITE path always uses the per-row tables.
+    One skeleton for every model: embed, the rows' blocks and lengths, one
+    scan per segment of ``cfg.segments`` (leading dense layers, then the
+    stack; the pool's layers in the same order), the residual form, FFN or
+    experts, the head. What a layer's attention projects, writes into the
+    pool and attends to is the cache kind's (:func:`_dense_cache`,
+    :func:`_latent_cache`), picked once from ``cfg.mla``.
 
-    MLA (DeepSeek) models pool latents and attend weight-absorbed: with
-    the latent instantiation of the Pallas kernel when ``attention_fn`` is
-    a kernel (any: which one is ``cfg``'s to say, not the caller's), with
-    :func:`paged_mla_attention_reference` otherwise; ALiBi models
-    (BLOOM/Falcon) bias the paged scores by head slope × relative position.
+    ``attention_fn`` says whether kernels are wanted: ``None`` or a
+    reference means no, anything else yes; which function then runs is
+    ``cfg``'s to say, not the caller's (:func:`tick_attention`).
 
     Expert layers run dropless (:func:`_tick_experts`). ``with_stats``
     adds a third result, ``{"expert_rows": [expert layers, E] int32}``
     (rows each expert got from the tick's real rows; ``{}`` for a model
     without experts).
     """
-    if cfg.mla:
-        out = _forward_paged_mla(
-            params, tokens, positions, tables, pool, cfg,
-            use_kernel=attention_fn not in (None, paged_attention_reference))
-        return out if with_stats else out[:2]
-    attention_fn = attention_fn or paged_attention_reference
-    alibi = None
-    if cfg.pos_emb == "alibi":
-        # the Pallas kernel has no bias input yet — ALiBi ticks use the
-        # XLA reference path (correct, rectangular-gather cost)
-        attention_fn = paged_attention_reference
-        alibi = T.alibi_slopes(cfg.num_heads) * cfg.alibi_bias_scale
+    from deepspeed_tpu.ops.quantization import dequant_params
+
+    attend, _ = tick_attention(cfg, attention_fn not in _REFERENCES)
     dt = cfg.compute_dtype
-    Tn = tokens.shape[0]
-    bs = pool["k"].shape[2]
+    NB, bs = next(iter(pool.values())).shape[1:3]
 
     with jax.named_scope("embed"):
         x = params["tok_emb"].astype(dt)[tokens]             # [T, H]
@@ -268,217 +345,79 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
         if cfg.emb_norm:
             x = T._norm(x, params["emb_norm"], cfg.norm, cfg.norm_eps)
 
-    max_pos = pool["k"].shape[1] * bs
-    cos_t = sin_t = None
-    if cfg.pos_emb == "rope":
-        cos_t, sin_t = T.rope_table(max_pos, cfg.rope_dim, cfg.rope_theta,
-                                    cfg.rope_scaling_dict)
-    block_idx = jnp.take_along_axis(
-        tables, (positions // bs)[:, None], axis=1)[:, 0]  # [T]
-    offsets = positions % bs
-    lengths = positions + 1
+    rows = _Rows(positions, tables,
+                 block_idx=jnp.take_along_axis(
+                     tables, (positions // bs)[:, None], axis=1)[:, 0],
+                 offsets=positions % bs, lengths=positions + 1)
     valid = tables[:, 0] > 0     # a pad row's table is all trash block
+    attention = (_latent_cache if cfg.mla else _dense_cache)(
+        cfg, pool, rows, attend)
 
-    def ffn(h2, lp):
-        if cfg.n_experts:
-            return _tick_experts(h2, lp, cfg, valid)
-        return T._ffn(h2, lp, cfg)[0], None
+    def make_body(seg: T.TransformerConfig, first: int, stack):
+        def body(carry, lp):
+            x, flat, li = carry
+            lp = dequant_params(lp, dt)   # weight-only quant: per-layer dequant
+            with jax.named_scope("attn"):
+                h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
+                attn, flat = attention(h, lp, flat, li * NB)
+                attn_out = attn @ lp["wo"].astype(dt)
+                if seg.use_bias:
+                    attn_out = attn_out + lp["bo"].astype(dt)
+            # ``mlp`` is a dense FFN's scope; an expert layer's operations
+            # carry ``router`` / ``experts`` / ``shared_experts``
+            with contextlib.nullcontext() if seg.n_experts \
+                    else jax.named_scope("mlp"):
+                # the parallel residual norms the block's input (or shares
+                # ``ln1``'s output), the sequential one what attention left
+                resid = x + attn_out
+                if not seg.parallel_block:
+                    h2 = T._norm(resid, lp["ln2"], seg.norm, seg.norm_eps)
+                elif seg.shared_parallel_norm:
+                    h2 = h
+                else:
+                    h2 = T._norm(x, lp["ln2"], seg.norm, seg.norm_eps)
+                if seg.n_experts:
+                    down, n_rows = _tick_experts(h2, lp, seg, valid, stack,
+                                                 li - first)
+                else:
+                    down, n_rows = T._ffn(h2, lp, seg)[0], None
+                x = resid + down
+            return (x, flat, li + 1), n_rows
 
-    # The pool rides the layer scan as a FLAT [L*NB, bs, K, D] carry that is
+        return body
+
+    # The pool rides the layer scans as a FLAT [L*NB, bs, ...] carry that is
     # scattered in place (layer l owns block range [l*NB, (l+1)*NB)); the
     # attention kernel gathers through layer-offset tables, reading only the
     # listed blocks. Threading per-layer slices as scan xs→ys (the naive
     # layout) re-stacks the ENTIRE pool every call — measured 25 ms/tick at
     # 512 blocks inside a decode scan, linear in pool size — where the
     # in-place carry touches only the written rows.
-    L, NB = pool["k"].shape[0], pool["k"].shape[1]
-    flat = (L * NB,) + pool["k"].shape[2:]
-
-    def body(carry, lp):
-        from deepspeed_tpu.ops.quantization import dequant_params
-
-        x, pk, pv, li = carry
-        lp = dequant_params(lp, dt)   # weight-only quant: per-layer dequant
-        with jax.named_scope("attn"):
-            h = T._norm(x, lp["ln1"], cfg.norm, cfg.norm_eps)
-
-            def proj(name, shape):
-                w = lp[f"w{name}"].astype(dt)
-                out = h @ w
-                if (cfg.attn_bias_enabled if name in ("q", "k", "v")
-                        else cfg.use_bias):
-                    out = out + lp[f"b{name}"].astype(dt)
-                return out.reshape(shape)
-
-            q = proj("q", (Tn, cfg.num_heads, cfg.head_dim))
-            k = proj("k", (Tn, cfg.kv_heads, cfg.head_dim))
-            v = proj("v", (Tn, cfg.kv_heads, cfg.head_dim))
-            if cfg.qk_norm:
-                q = T._head_rmsnorm(q, lp["q_norm"], cfg.norm_eps)
-                k = T._head_rmsnorm(k, lp["k_norm"], cfg.norm_eps)
-            if cfg.pos_emb == "rope":
-                q = T.apply_rope_at(q[None], cos_t, sin_t, positions[None])[0]
-                k = T.apply_rope_at(k[None], cos_t, sin_t, positions[None])[0]
-            # blocked KV write (reference ragged_ops KV-copy kernels): token t →
-            # pool[l*NB + block_idx[t], offsets[t]]. Pad tokens hit this layer's
-            # trash block (block 0 of its range — never allocated).
-            base = li * NB
-            pk = pk.at[base + block_idx, offsets].set(k.astype(pk.dtype),
-                                                      mode="drop")
-            pv = pv.at[base + block_idx, offsets].set(v.astype(pv.dtype),
-                                                      mode="drop")
-
-            if group_tables is not None:
-                parts = []
-                if n_decode:
-                    parts.append(
-                        attention_fn(q[:n_decode], pk, pv,
-                                     tables[:n_decode] + base,
-                                     lengths[:n_decode],
-                                     **({"alibi": alibi} if alibi is not None
-                                        else {})))
-                parts.append(grouped_prefill_attention(
-                    q[n_decode:], pk, pv, group_tables + base,
-                    lengths[n_decode:], alibi=alibi))
-                attn = jnp.concatenate(parts, axis=0) if n_decode else parts[0]
-            elif alibi is not None:
-                attn = attention_fn(q, pk, pv, tables + base, lengths,
-                                    alibi=alibi)                    # [T, N, D]
-            else:
-                attn = attention_fn(q, pk, pv, tables + base, lengths)
-            attn = attn.reshape(Tn, cfg.num_heads * cfg.head_dim)
-            attn_out = attn @ lp["wo"].astype(dt)
-            if cfg.use_bias:
-                attn_out = attn_out + lp["bo"].astype(dt)
-        with jax.named_scope("mlp"):
-            if cfg.parallel_block:
-                h2 = h if cfg.shared_parallel_norm else \
-                    T._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
-                down, rows = ffn(h2, lp)
-                return (x + attn_out + down, pk, pv, li + 1), rows
-            x = x + attn_out
-            h2 = T._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
-            down, rows = ffn(h2, lp)
-            return (x + down, pk, pv, li + 1), rows
-
-    carry0 = (x, pool["k"].reshape(flat), pool["v"].reshape(flat),
-              jnp.int32(0))
-    (x, new_k, new_v, _), rows = lax.scan(body, carry0, params["blocks"])
-    new_k = new_k.reshape(pool["k"].shape)
-    new_v = new_v.reshape(pool["v"].shape)
-    with jax.named_scope("lm_head"):
-        x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-        head = T._lm_head_of(params, cfg)
-        logits = T.head_matmul(x, head.astype(x.dtype))
-        if cfg.lm_head_bias:
-            logits = logits + params["lm_head_b"].astype(jnp.float32)
-    new_pool = {"k": new_k, "v": new_v}
-    if with_stats:
-        return logits, new_pool, \
-            {} if rows is None else {"expert_rows": rows}
-    return logits, new_pool
-
-
-def _forward_paged_mla(params: PyTree, tokens: jax.Array,
-                       positions: jax.Array, tables: jax.Array,
-                       pool: Dict[str, jax.Array], cfg: T.TransformerConfig,
-                       use_kernel: bool):
-    """MLA SplitFuse tick: write each row's latent (``c_kv ++ k_pe``) into
-    the paged pool and attend weight-absorbed (same flat in-place pool
-    carry as the dense path; same math as the v1 engine's latent-cache
-    decode). The stack is one scan per segment of ``cfg.segments`` (the
-    leading dense layers, then the expert layers), the pool's layers in
-    the same order. Returns (logits, pool, stats) as ``forward_paged``
-    with ``with_stats``."""
-    dt = cfg.compute_dtype
-    Tn = tokens.shape[0]
-    lat = pool["latent"]
-    L, NB, bs, W = lat.shape
-
-    with jax.named_scope("embed"):
-        x = params["tok_emb"].astype(dt)[tokens]
-        if cfg.emb_norm:
-            x = T._norm(x, params["emb_norm"], cfg.norm, cfg.norm_eps)
-
-    cos_t, sin_t = T.rope_table(NB * bs, cfg.qk_rope_head_dim,
-                                cfg.rope_theta, cfg.rope_scaling_dict)
-
-    def rope_fn(v):                                   # v [T, 1, n, dr]
-        return T.apply_rope_at(v, cos_t, sin_t, positions[:, None])
-
-    block_idx = jnp.take_along_axis(
-        tables, (positions // bs)[:, None], axis=1)[:, 0]
-    offsets = positions % bs
-    lengths = positions + 1
-    valid = tables[:, 0] > 0     # a pad row's table is all trash block
-    row_pad = jnp.zeros(
-        (Tn, W - cfg.kv_lora_rank - cfg.qk_rope_head_dim), lat.dtype)
-
-    if use_kernel:
-        from deepspeed_tpu.ops.pallas.paged_attention import \
-            latent_paged_attention
-
-        def attend(q, plat, rows_tables, w_kv_b):
-            return _absorbed(q, w_kv_b, cfg, lambda q_row:
-                             latent_paged_attention(
-                                 q_row, plat, rows_tables, lengths,
-                                 cfg.kv_lora_rank, mla_softmax_scale(cfg)))
-    else:
-        def attend(q, plat, rows_tables, w_kv_b):
-            return paged_mla_attention_reference(q, plat, rows_tables,
-                                                 lengths, w_kv_b, cfg)
-
-    def make_body(seg: T.TransformerConfig, first: int, stack):
-        def body(carry, lp):
-            from deepspeed_tpu.ops.quantization import dequant_params
-
-            x, plat, li = carry
-            lp = dequant_params(lp, dt)
-            with jax.named_scope("attn"):
-                h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
-                hB = h[:, None, :]                        # [T, 1, H]
-                q = T._mla_q(hB, lp, seg, rope_fn)[:, 0]  # [T, N, dn+dr]
-                c_kv, k_pe = T._mla_latents(hB, lp, seg, rope_fn)
-                row = jnp.concatenate(
-                    [c_kv[:, 0].astype(plat.dtype),
-                     k_pe[:, 0, 0].astype(plat.dtype), row_pad], axis=-1)
-                base = li * NB
-                plat = plat.at[base + block_idx, offsets].set(row,
-                                                              mode="drop")
-                attn = attend(q, plat, tables + base, lp["wkv_b"])
-                attn = attn.reshape(Tn, seg.num_heads * seg.v_head_dim)
-                x = x + attn @ lp["wo"].astype(dt)
-            h2 = T._norm(x, lp["ln2"], seg.norm, seg.norm_eps)
-            if seg.n_experts:
-                down, rows = _tick_experts(h2, lp, seg, valid, stack,
-                                           li - first)
-            else:
-                with jax.named_scope("mlp"):
-                    down, rows = T._ffn(h2, lp, seg)[0], None
-            return (x + down, plat, li + 1), rows
-
-        return body
-
-    carry = (x, lat.reshape(L * NB, bs, W), jnp.int32(0))
+    carry = (x, {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()},
+             jnp.int32(0))
     stats = {}
     first = 0
     for key, seg in cfg.segments:
         # the experts' matrices stay out of the scan's sliced operands: the
-        # grouped matmul takes the stack whole (``moe.layer.grouped_dot``);
+        # grouped matmul takes the stack whole (``moe.layer.grouped_dot``;
+        # a slice is a copy of a layer's experts before each matmul);
         # quantised leaves ({"q", "scale", ...}) are dequantised a layer at
         # a time and stay in
         stack = {k: v for k, v in params[key].items() if seg.n_experts
                  and k in _EXPERT_LEAVES and hasattr(v, "ndim")}
         xs = {k: v for k, v in params[key].items() if k not in stack}
-        carry, rows = lax.scan(make_body(seg, first, stack), carry, xs)
+        carry, n_rows = lax.scan(make_body(seg, first, stack), carry, xs)
         first += seg.num_layers
-        if rows is not None:
-            stats["expert_rows"] = rows
-    x, new_lat, _ = carry
+        if n_rows is not None:
+            stats["expert_rows"] = n_rows
+    x, flat, _ = carry
     with jax.named_scope("lm_head"):
         x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
         head = T._lm_head_of(params, cfg)
         logits = T.head_matmul(x, head.astype(x.dtype))
         if cfg.lm_head_bias:
             logits = logits + params["lm_head_b"].astype(jnp.float32)
-    return logits, {"latent": new_lat.reshape(lat.shape)}, stats
+    new_pool = {k: flat[k].reshape(v.shape) for k, v in pool.items()}
+    if with_stats:
+        return logits, new_pool, stats
+    return logits, new_pool

@@ -98,76 +98,6 @@ def test_fastgen_greedy_matches_slot_engine():
         assert got[u] == want[u], (u, got[u], want[u])
 
 
-def test_planned_serve_matches_dynamic_greedy():
-    """serve_planned (whole workload in one scan dispatch) produces the
-    same greedy tokens as the dynamic tick loop and as the slot engine."""
-    rng = np.random.default_rng(6)
-    prompts = _prompts(rng, [5, 19, 33, 47])
-    uids = [1, 2, 3, 4]
-    new = 10
-
-    slot = RaggedInferenceEngine("tiny", max_slots=4, max_len=128,
-                                 temperature=0.0, seed=0, **CFG)
-    want = slot.generate_all(uids, prompts, max_new_tokens=new)
-
-    fg = FastGenEngine("tiny", n_blocks=32, block_size=16,
-                       max_blocks_per_seq=8, token_budget=32,
-                       temperature=0.0, seed=0, **CFG)
-    got = fg.generate_all(uids, prompts, max_new_tokens=new, planned=True)
-    for u in uids:
-        assert got[u] == want[u], (u, got[u], want[u])
-    # pool fully released after flush
-    assert fg.allocator.free_blocks == 31
-
-
-def test_planned_serve_infeasible_rolls_back():
-    """A pool too small for the full plan returns False with host state
-    untouched, and the dynamic loop still serves the workload."""
-    rng = np.random.default_rng(7)
-    fg = FastGenEngine("tiny", n_blocks=6, block_size=16,
-                       max_blocks_per_seq=8, token_budget=32,
-                       temperature=0.0, seed=0, **CFG)
-    fg.put([1, 2], _prompts(rng, [30, 40]))
-    pre = {u: (fg.seqs[u].prefilled, fg.seqs[u].pos,
-               list(fg.seqs[u].blocks)) for u in (1, 2)}
-    free_pre = fg.allocator.free_blocks
-    assert fg.serve_planned(16, until_prefilled=False) is False
-    assert fg.allocator.free_blocks == free_pre
-    for u in (1, 2):
-        assert (fg.seqs[u].prefilled, fg.seqs[u].pos,
-                list(fg.seqs[u].blocks)) == pre[u]
-    # the dynamic loop still makes progress under the same tight pool
-    # (per-tick backpressure; full completion may be capacity-limited —
-    # neither engine preempts running sequences)
-    fg._generate_dynamic([1, 2], 16)
-    assert all(len(fg.seqs[u].generated) > 0 for u in (1, 2))
-
-
-def test_planned_serve_eos_matches_dynamic():
-    """EOS mid-plan: planned serving (post-EOS samples computed then
-    trimmed) returns exactly what the dynamic loop (which stops at EOS)
-    returns, and releases the pool."""
-    rng = np.random.default_rng(8)
-    prompts = _prompts(rng, [9, 21])
-    ref = FastGenEngine("tiny", n_blocks=32, block_size=16,
-                        max_blocks_per_seq=8, token_budget=32,
-                        temperature=0.0, seed=0, **CFG)
-    base = ref.generate_all([1, 2], prompts, max_new_tokens=8, planned=False)
-    eos = base[1][2]  # a token the greedy stream emits early
-    for mode in (False, True):
-        fg = FastGenEngine("tiny", n_blocks=32, block_size=16,
-                           max_blocks_per_seq=8, token_budget=32,
-                           temperature=0.0, seed=0,
-                           eos_token_id=eos, **CFG)
-        got = fg.generate_all([1, 2], prompts, max_new_tokens=8,
-                              planned=mode)
-        if mode is False:
-            want = got
-        else:
-            assert got == want, (got, want)
-            assert fg.allocator.free_blocks == 31
-
-
 def test_decode_steps_matches_per_tick_steps():
     """The fused lax.scan decode (one dispatch) produces exactly the greedy
     tokens of N individual step() ticks, with identical host bookkeeping
@@ -373,7 +303,7 @@ def test_fastgen_generate_all_frees_blocks_of_done_seqs():
 def test_fastgen_alibi_greedy_matches_slot_engine():
     """BLOOM-style ALiBi models serve on the paged engine: head-slope
     relative-position bias in the paged scores reproduces the v1 slot
-    engine's greedy stream exactly (both planned and dynamic serving)."""
+    engine's greedy stream exactly."""
     cfg = dict(CFG, pos_emb="alibi")
     rng = np.random.default_rng(9)
     prompts = _prompts(rng, [5, 18, 31])
@@ -382,14 +312,12 @@ def test_fastgen_alibi_greedy_matches_slot_engine():
     slot = RaggedInferenceEngine("tiny", max_slots=4, max_len=128,
                                  temperature=0.0, seed=0, **cfg)
     want = slot.generate_all(uids, prompts, max_new_tokens=new)
-    for planned in (False, True):
-        fg = FastGenEngine("tiny", n_blocks=32, block_size=16,
-                           max_blocks_per_seq=8, token_budget=32,
-                           temperature=0.0, seed=0, **cfg)
-        got = fg.generate_all(uids, prompts, max_new_tokens=new,
-                              planned=planned)
-        for u in uids:
-            assert got[u] == want[u], (planned, u, got[u], want[u])
+    fg = FastGenEngine("tiny", n_blocks=32, block_size=16,
+                       max_blocks_per_seq=8, token_budget=32,
+                       temperature=0.0, seed=0, **cfg)
+    got = fg.generate_all(uids, prompts, max_new_tokens=new)
+    for u in uids:
+        assert got[u] == want[u], (u, got[u], want[u])
 
 
 def test_fastgen_prompt_longer_than_budget():
@@ -444,11 +372,10 @@ def test_fastgen_throughput_vs_slot_engine():
     # gets a 1.5x floor.
     slot_programs = len(slot._compiled)
     # count SplitFuse tick programs only: the fused decode-scan ("dec")
-    # and planned-serve ("plan") tiers are fixed grids independent of
-    # prompt diversity
+    # tiers are a fixed grid independent of prompt diversity
     fg_programs = len([k for k in fg._ticks
                        if not (isinstance(k, tuple) and k
-                               and k[0] in ("dec", "plan"))])
+                               and k[0] == "dec")])
     assert slot_programs > 2 * fg_programs, (slot_programs, fg_programs)
     assert t_fg_cold * 1.5 <= t_slot_cold, (
         f"FastGen cold {t_fg_cold:.2f}s not clearly faster than slot "
@@ -467,8 +394,8 @@ def test_fastgen_throughput_vs_slot_engine():
     # NOTE: on CPU the paged engine runs paged_attention_reference, whose
     # gather is rectangular (every token pays MB*bs context width); the
     # Pallas kernel used on TPU skips blocks beyond each token's length, so
-    # steady-state wins only materialize there (measured by bench.py's
-    # fastgen entry). This warm check is a regression guard only.
+    # steady-state wins only materialize there (measured by the benchmark's
+    # serving cells). This warm check is a regression guard only.
     assert t_fg_warm <= t_slot_warm * 3.5, (
         f"FastGen warm {t_fg_warm*1e3:.0f}ms vs slot {t_slot_warm*1e3:.0f}ms")
 
@@ -477,7 +404,7 @@ def test_fastgen_mla_greedy_matches_slot_engine():
     """DeepSeek-style MLA serves on the paged engine: the pool holds the
     LATENTS (c_kv + shared post-rope key — the tiny row paged KV is made
     for) and attention runs weight-absorbed. Greedy parity with the v1
-    engine's latent-cache decode, planned and dynamic."""
+    engine's latent-cache decode."""
     from deepspeed_tpu.models import transformer as T
 
     cfg = T.TransformerConfig(
@@ -493,15 +420,13 @@ def test_fastgen_mla_greedy_matches_slot_engine():
     slot = RaggedInferenceEngine(cfg, max_slots=4, max_len=128,
                                  temperature=0.0, seed=0)
     want = slot.generate_all(uids, prompts, max_new_tokens=new)
-    for planned in (False, True):
-        fg = FastGenEngine(cfg, n_blocks=32, block_size=16,
-                           max_blocks_per_seq=8, token_budget=32,
-                           temperature=0.0, seed=0)
-        assert set(fg.pool) == {"latent"}      # latent pool layout
-        got = fg.generate_all(uids, prompts, max_new_tokens=new,
-                              planned=planned)
-        for u in uids:
-            assert got[u] == want[u], (planned, u, got[u], want[u])
+    fg = FastGenEngine(cfg, n_blocks=32, block_size=16,
+                       max_blocks_per_seq=8, token_budget=32,
+                       temperature=0.0, seed=0)
+    assert set(fg.pool) == {"latent"}      # latent pool layout
+    got = fg.generate_all(uids, prompts, max_new_tokens=new)
+    for u in uids:
+        assert got[u] == want[u], (u, got[u], want[u])
 
 
 class TestFastGenTP:

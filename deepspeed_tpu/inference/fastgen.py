@@ -256,6 +256,10 @@ class FastGenEngine:
             "engine ticks by kind (mixed: the tick held prompt rows / "
             "decode: it held none, from step() or a fused window) and "
             "block-table width tier")
+        self._tm_h2d = telemetry.counter(
+            "fastgen_tick_h2d_bytes_total",
+            "bytes of the one host array a step() tick hands to its "
+            "program: tokens, positions, block tables and the key words")
         self._tm_gen_tok = telemetry.counter(
             "fastgen_generated_tokens_total", "tokens sampled and kept")
         self._tm_prefill_tok = telemetry.counter(
@@ -392,10 +396,30 @@ class FastGenEngine:
         return small if need <= small else self.token_budget
 
     # ------------------------------------------------------------------ #
-    def _build_tick(self):
-        cfg, attn = self.cfg, self._attention
+    @staticmethod
+    def _pack_tick(tokens: np.ndarray, positions: np.ndarray,
+                   tables: np.ndarray, key: np.ndarray) -> np.ndarray:
+        """All a tick sends to the device, as ONE fresh contiguous int32
+        host array: ``[tables row by row | tokens | positions | the two
+        key words]``. Fresh a tick: a numpy buffer handed to the runtime
+        may not change until its transfer is done."""
+        return np.concatenate(
+            (tables.ravel(), tokens, positions, key.view(np.int32)))
 
-        def tick(params, pool, tokens, positions, tables, rng):
+    def _build_tick(self, Tn: int, mb: int):
+        """The tick program of ``Tn`` rows over tables ``mb`` blocks wide.
+        It takes ``_pack_tick``'s array and cuts it apart with static
+        slices, so a tick crosses to the device once."""
+        cfg, attn = self.cfg, self._attention
+        n = Tn * mb
+
+        def tick(params, pool, packed):
+            tables = packed[:n].reshape(Tn, mb)
+            tokens = packed[n:n + Tn]
+            positions = packed[n + Tn:n + 2 * Tn]
+            # the same bits the host drew: a raw uint32[2] threefry key
+            rng = jax.lax.bitcast_convert_type(
+                packed[n + 2 * Tn:], jnp.uint32)
             logits, pool, *stats = PG.forward_paged(
                 params, tokens, positions, tables, pool, cfg,
                 attention_fn=attn, with_stats=bool(self._expert_layers))
@@ -989,7 +1013,7 @@ class FastGenEngine:
         key = (Tn, mb)
         cold = key not in self._ticks
         if cold:
-            self._ticks[key] = self._build_tick()
+            self._ticks[key] = self._build_tick(Tn, mb)
         n_decode_rows = sum(1 for _, _, is_d in heads if is_d)
         # a tick that holds no prompt row is a decode tick, whatever
         # entry point ran it
@@ -1008,16 +1032,23 @@ class FastGenEngine:
                 + row - n_decode_rows,
                 "shared_rows": shared_rows, "bucket": Tn,
                 "mb_tier": tier}) as tick_span:
-            # enqueue: the host-to-device copies and the jitted call,
-            # until it returns (the device may still be running)
-            with telemetry.span("tick_dispatch"):
+            packed = self._pack_tick(
+                tokens, positions, tables[:, :mb],
+                self._host_rng.integers(0, 2 ** 32, 2, dtype=np.uint32))
+            # enqueue: the jitted call on the one host array (its copy
+            # to the device is the call's own), until it returns (the
+            # device may still be running), and the copy back queued
+            # behind the program
+            with telemetry.span("tick_dispatch",
+                                attrs={"h2d_bytes": packed.nbytes}):
                 sampled, self.pool = self._ticks[key](
-                    self.params, self.pool, self._dev(tokens),
-                    self._dev(positions), self._dev(tables[:, :mb]),
-                    self._next_key())
-            # the wait for the device, then the copy back
+                    self.params, self.pool,
+                    packed if self._rep_sh is None
+                    else jax.device_put(packed, self._rep_sh))
+                sampled.copy_to_host_async()
+            # the wait for the device and for the copy queued behind it
             with telemetry.span("tick_readback"):
-                sampled = np.asarray(jax.device_get(sampled))
+                sampled = np.asarray(sampled)
             expert_rows, commit_attrs = None, None
             if self._expert_layers:
                 expert_rows = sampled[Tn:].reshape(self._expert_layers, -1)
@@ -1049,6 +1080,7 @@ class FastGenEngine:
                     (time.perf_counter() - t0) / n_decode_rows,
                     n=n_decode_rows)
             self._tm_ticks.inc(kind=kind, mb_tier=tier)
+            self._tm_h2d.inc(packed.nbytes)
             self._tm_prefill_tok.inc(row - n_decode_rows)
             self._tm_shared_rows.inc(shared_rows)
             self._tm_occup.set(row / Tn, phase="mixed")

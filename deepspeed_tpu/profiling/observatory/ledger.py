@@ -442,7 +442,7 @@ def ledger_for_fastgen(engine, n_tokens: Optional[int] = None,
     two tiers' gauges don't overwrite each other. Returns ``(ledger,
     memory_stats_dict_or_None)``.
     """
-    import jax.numpy as jnp
+    import numpy as np
 
     tn = engine._bucket(n_tokens or engine.token_budget)
     key = (tn, engine.max_blocks_per_seq)
@@ -453,14 +453,13 @@ def ledger_for_fastgen(engine, n_tokens: Optional[int] = None,
     if cached is None:
         tick = engine._ticks.get(key)
         if tick is None:
-            tick = engine._build_tick()
-        tokens = jnp.zeros((tn,), jnp.int32)
-        positions = jnp.zeros((tn,), jnp.int32)
-        tables = jnp.zeros((tn, engine.max_blocks_per_seq), jnp.int32)
-        rng = jnp.zeros((2,), jnp.uint32)
+            tick = engine._build_tick(*key)
+        # the tick's one operand, laid out by the engine
+        rows = np.zeros((tn,), np.int32)
+        packed = engine._pack_tick(
+            rows, rows, np.zeros(key, np.int32), np.zeros((2,), np.uint32))
         hlo_text, costs, mem = _lower_compiled(
-            tick, engine.params, engine.pool, tokens, positions, tables,
-            rng)
+            tick, engine.params, engine.pool, packed)
         world = 1
         if engine.mesh is not None:
             from deepspeed_tpu.comm.mesh import TENSOR_AXIS

@@ -634,3 +634,206 @@ def test_fastgen_decode_stream_drops_expired():
     list(fg.decode_stream(window=4))
     assert fg.expired(1) and fg.seqs[1].done
     assert not fg.seqs[1].blocks
+
+
+# ------------------------------------------------------------------ #
+# one host array into the tick, one copy back queued with the dispatch
+# ------------------------------------------------------------------ #
+_TOY = dict(vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
+            pos_emb="rope", dtype="float32", max_seq_len=128,
+            # untied and wide: a tied toy model only echoes its last token
+            tie_embeddings=False, init_std=0.1)
+PACKED_MODELS = {
+    # Mistral's shape: shared K/V heads, no bias
+    "dense-gqa": dict(_TOY, num_kv_heads=2, norm="rmsnorm",
+                      activation="swiglu", use_bias=False),
+    # Pythia's: a K/V head a query head, biases everywhere, parallel block
+    "dense-mha-biases": dict(_TOY, norm="layernorm", activation="gelu",
+                             use_bias=True, parallel_block=True,
+                             rope_fraction=0.25),
+    # Moonlight's: a latent cache, a leading dense layer, routed experts
+    # (their row counts come back behind the sampled tokens)
+    "latent-experts": dict(
+        _TOY, norm="rmsnorm", activation="swiglu", use_bias=False,
+        mla=True, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, q_lora_rank=0, n_experts=4, moe_top_k=2,
+        moe_ffn_size=32, moe_shared_size=32, moe_dispatch="ragged",
+        first_dense_layers=1),
+}
+
+
+@pytest.fixture(scope="module")
+def packed_models():
+    out = {}
+    for name, kw in PACKED_MODELS.items():
+        cfg = T.TransformerConfig(**kw)
+        out[name] = cfg, T.init_params(cfg, jax.random.PRNGKey(5))
+    return out
+
+
+def _recorded_ticks(eng):
+    """Every call of a ``step()`` tick program from here on: its operands
+    as the engine handed them over, and what it returned."""
+    calls = []
+    build = eng._build_tick
+
+    def _build_tick(Tn, mb):
+        fn = build(Tn, mb)
+
+        def tick(*operands):
+            packed = operands[-1]
+            rec = {"Tn": Tn, "mb": mb, "operands": operands,
+                   "packed": packed, "then": np.array(packed)}
+            # a write by anyone, at any later time, raises
+            packed.flags.writeable = False
+            rec["sampled"], _ = out = fn(*operands)
+            calls.append(rec)
+            return out
+        return tick
+    eng._build_tick = _build_tick
+    return calls
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+@pytest.mark.parametrize("model", sorted(PACKED_MODELS))
+def test_step_tick_on_one_packed_array_matches_the_unpacked_operands(
+        packed_models, model, temperature):
+    """``step()`` hands its tick ONE numpy array; the program behind it
+    gives the tokens that ``forward_paged`` + ``sample_logits`` give on the
+    separate operands with the key words the host stream drew."""
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.inference.sampling import sample_logits
+    from deepspeed_tpu.telemetry import tracing
+
+    cfg, params = packed_models[model]
+    seed = 11
+    eng = FastGenEngine(cfg, params, n_blocks=48, block_size=8,
+                        max_blocks_per_seq=16, token_budget=32,
+                        temperature=temperature, top_k=50, seed=seed)
+    calls = _recorded_ticks(eng)
+    h2d = telemetry.counter("fastgen_tick_h2d_bytes_total")
+    tracer = tracing.get_tracer()
+    was, tracer.enabled = tracer.enabled, True
+    h2d0 = h2d.total()
+    rng = np.random.default_rng(3)
+    try:
+        # a prompt of two chunks (the first inside the narrow table tier,
+        # 4 blocks of 8; the second past it) beside a short one, decode
+        # ticks in the small bucket, then a late arrival: a mixed tick
+        # with decode rows
+        eng.put([1, 2], _prompts(rng, [40, 5]))
+        for _ in range(8):
+            eng.step()
+        eng.put([3], _prompts(rng, [26]))
+        for _ in range(4):
+            eng.step()
+        events = tracer.export_chrome()["traceEvents"]
+    finally:
+        tracer.enabled = was
+    shapes = {(c["Tn"], c["mb"]) for c in calls}
+    assert {Tn for Tn, _ in shapes} == {8, 32}          # both buckets
+    assert len({mb for _, mb in shapes}) >= 2           # two table tiers
+    kinds = {e["args"]["kind"] for e in events
+             if e.get("name") == "decode_tick"}
+    assert kinds == {"mixed", "decode"}
+
+    attn = eng._attention
+    with_stats = bool(eng._expert_layers)
+
+    @jax.jit
+    def unpacked(params, pool, tokens, positions, tables, key):
+        logits, pool, *stats = PG.forward_paged(
+            params, tokens, positions, tables, pool, cfg,
+            attention_fn=attn, with_stats=with_stats)
+        sampled = sample_logits(logits, key, temperature, 50,
+                                1.0).astype(jnp.int32)
+        return sampled, pool, [s["expert_rows"] for s in stats]
+
+    draws = np.random.default_rng(seed)
+    pool = PG.init_paged_kv(cfg, 48, 8)
+    for c in calls:
+        Tn, mb, packed = c["Tn"], c["mb"], c["packed"]
+        # (params, pool, the one host array): nothing else crosses over
+        assert len(c["operands"]) == 3
+        assert isinstance(packed, np.ndarray) and packed.dtype == np.int32
+        assert packed.ndim == 1 and packed.flags.c_contiguous
+        # as it was when handed over: not written before its read-back,
+        # nor after
+        np.testing.assert_array_equal(packed, c["then"])
+        n = Tn * mb
+        assert packed.size == n + 2 * Tn + 2
+        key = packed[n + 2 * Tn:].view(np.uint32)
+        np.testing.assert_array_equal(
+            key, draws.integers(0, 2 ** 32, 2, dtype=np.uint32))
+        want, pool, rows = unpacked(
+            params, pool, packed[n:n + Tn], packed[n + Tn:n + 2 * Tn],
+            packed[:n].reshape(Tn, mb), key)
+        got = np.asarray(c["sampled"])
+        np.testing.assert_array_equal(got[:Tn], np.asarray(want))
+        if with_stats:
+            np.testing.assert_array_equal(
+                got[Tn:], np.asarray(rows[0]).reshape(-1))
+        else:
+            assert got.shape == (Tn,)
+    # a fresh array a tick: none is handed over twice
+    assert len({id(c["packed"]) for c in calls}) == len(calls)
+    # the counter and the span say what crossed
+    sent = [c["packed"].nbytes for c in calls]
+    assert h2d.total() - h2d0 == sum(sent)
+    spans = [e["args"] for e in events if e.get("name") == "tick_dispatch"]
+    assert [a["h2d_bytes"] for a in spans[-len(calls):]] == sent
+
+
+def test_step_tick_queues_the_copy_back_inside_the_dispatch(packed_models):
+    """The sampled tokens' copy to the host is asked for inside
+    ``tick_dispatch``, right after the jitted call returned, and
+    ``tick_readback`` only waits for it."""
+    from deepspeed_tpu.telemetry import tracing
+
+    cfg, params = packed_models["dense-gqa"]
+    eng = FastGenEngine(cfg, params, n_blocks=48, block_size=8,
+                        max_blocks_per_seq=16, token_budget=32,
+                        temperature=0.0, seed=0)
+    order = []
+    build = eng._build_tick
+
+    class Sampled:
+        def __init__(self, a):
+            self.a = a
+
+        def copy_to_host_async(self):
+            order.append(("copy_to_host_async", _open_spans()))
+            self.a.copy_to_host_async()
+
+        def __array__(self, dtype=None, copy=None):
+            order.append(("read", _open_spans()))
+            return np.asarray(self.a)
+
+    tracer = tracing.get_tracer()
+
+    def _open_spans():
+        return [ctx.rec.name for ctx in tracer._stack()]
+
+    def _build_tick(Tn, mb):
+        fn = build(Tn, mb)
+
+        def tick(*operands):
+            sampled, pool = fn(*operands)
+            order.append(("called", _open_spans()))
+            return Sampled(sampled), pool
+        return tick
+    eng._build_tick = _build_tick
+    was, tracer.enabled = tracer.enabled, True
+    try:
+        eng.put([1], [[3, 4, 5, 6, 7]])
+        for _ in range(3):
+            eng.step()
+    finally:
+        tracer.enabled = was
+    assert len(order) == 9 and eng.seqs[1].generated
+    for i in range(0, len(order), 3):
+        assert [what for what, _ in order[i:i + 3]] \
+            == ["called", "copy_to_host_async", "read"]
+        assert order[i][1][-2:] == ["decode_tick", "tick_dispatch"]
+        assert order[i + 1][1][-2:] == ["decode_tick", "tick_dispatch"]
+        assert order[i + 2][1][-2:] == ["decode_tick", "tick_readback"]

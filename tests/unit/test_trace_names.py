@@ -10,6 +10,7 @@ import os
 import re
 
 import jax.numpy as jnp
+import numpy as np
 
 import deepspeed_tpu as dst
 from deepspeed_tpu.inference.fastgen import FastGenEngine
@@ -109,12 +110,13 @@ def test_serving_tick_carries_scopes_and_the_kernel_name():
                         temperature=0.0, seed=0, use_pallas_kernel=True,
                         hidden_size=64, num_layers=2, num_heads=4,
                         max_seq_len=128, vocab_size=512, dtype="float32")
-    tick = eng._build_tick()
     tn, mb = 32, eng.max_blocks_per_seq
+    tick = eng._build_tick(tn, mb)
     stacks = _stacks(tick.lower(
-        eng.params, eng.pool, jnp.zeros((tn,), jnp.int32),
-        jnp.zeros((tn,), jnp.int32), jnp.zeros((tn, mb), jnp.int32),
-        jnp.zeros((2,), jnp.uint32)), "tick")
+        eng.params, eng.pool, eng._pack_tick(
+            np.zeros((tn,), np.int32), np.zeros((tn,), np.int32),
+            np.zeros((tn, mb), np.int32), np.zeros((2,), np.uint32))),
+        "tick")
     parts = {part for s in stacks for part in s.split("/")}
     assert {"embed", "attn", "mlp", "lm_head", "sample"} <= parts
     # the paged kernel by name, inside a layer's attention scope

@@ -9,6 +9,11 @@ TPU design — one compiled program for EVERYTHING:
 
 * KV lives in a block pool ``[L, NB, bs, K, D]``; each sequence owns a
   host-side block table (``BlockAllocator`` free list, block 0 = pad trash).
+  A model that keeps per-sequence state beside its blocks (window rings,
+  state-space state: ``models/paged.init_paged_kv``) also holds one of
+  ``state_slots`` sequence SLOTS, acquired with its first block (the slot
+  IS that block's id, so the device reads it from the table) and freed
+  with it; admission waits for a slot as it waits for blocks.
 * Every ``step()`` packs a fixed token budget T: one decode token per running
   sequence plus prefill CHUNKS of admitted prompts (long prompts split across
   ticks, short ones fused together — Dynamic SplitFuse), padded to T.
@@ -45,17 +50,57 @@ PyTree = Any
 class BlockAllocator:
     """Fixed-pool block allocator (reference ``blocked_allocator.py:1-105``).
 
-    Block 0 is reserved as the trash block pad tokens write into."""
+    Block 0 is reserved as the trash block pad tokens write into.
 
-    def __init__(self, n_blocks: int):
-        self.n_blocks = n_blocks
-        self._free: List[int] = list(range(1, n_blocks))
+    ``state_slots`` > 0 (a model that keeps per-sequence state beside its
+    blocks, ``models/paged.init_paged_kv``): blocks ``1 .. state_slots`` are
+    HEAD blocks, handed out only as a sequence's first block, so a
+    sequence's slot is its table's first entry and can be read from the
+    table on the device. :meth:`allocate` starts a sequence (its first
+    block is a head block), :meth:`grow` extends one; a head block is an
+    ordinary block of the pool otherwise (it holds the sequence's first
+    positions). With no slots the two are one free list and the same
+    call."""
+
+    def __init__(self, n_blocks: int, state_slots: int = 0):
+        if not 0 <= state_slots < n_blocks:
+            raise ValueError(f"state_slots={state_slots} of {n_blocks} blocks")
+        self.n_blocks, self.state_slots = n_blocks, state_slots
+        self._heads: List[int] = list(range(1, state_slots + 1))
+        self._free: List[int] = list(range(state_slots + 1, n_blocks))
 
     @property
     def free_blocks(self) -> int:
-        return len(self._free)
+        """Blocks not held by a sequence, head blocks included."""
+        return len(self._free) + len(self._heads)
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._heads)
+
+    @property
+    def slots_in_use(self) -> int:
+        return self.state_slots - len(self._heads)
+
+    def available(self, starting: bool) -> int:
+        """Blocks a sequence could be given now: one that ``starting``
+        (it holds none yet) needs a head block for its first."""
+        if not self.state_slots or not starting:
+            return len(self._free)
+        return 1 + len(self._free) if self._heads else 0
 
     def allocate(self, n: int = 1) -> List[int]:
+        """The ``n`` blocks of a NEW sequence."""
+        if not self.state_slots or n < 1:
+            return self.grow(n)
+        if not self._heads:
+            raise RuntimeError(
+                f"no sequence slot free ({self.state_slots} in use)")
+        rest = self.grow(n - 1)
+        return [self._heads.pop(0)] + rest
+
+    def grow(self, n: int = 1) -> List[int]:
+        """``n`` more blocks for a sequence that has its first."""
         if n > len(self._free):
             raise RuntimeError(
                 f"KV pool exhausted: want {n} blocks, {len(self._free)} free")
@@ -65,7 +110,14 @@ class BlockAllocator:
     def free(self, blocks: Sequence[int]) -> None:
         for b in blocks:
             if b:
-                self._free.append(b)
+                (self._heads if b <= self.state_slots
+                 else self._free).append(b)
+
+    def snapshot(self) -> tuple:
+        return list(self._free), list(self._heads)
+
+    def restore(self, snap: tuple) -> None:
+        self._free, self._heads = list(snap[0]), list(snap[1])
 
 
 class _Seq:
@@ -88,6 +140,7 @@ class _Seq:
         self.deadline = (self.admit_t + deadline_s
                          if deadline_s is not None else None)
         self.expired = False
+        self.slot_waited = False      # counted in state_slot_waits_total
 
     @property
     def prefill_remaining(self) -> int:
@@ -105,7 +158,8 @@ class FastGenEngine:
                  eos_token_id: Optional[int] = None, seed: int = 0,
                  use_pallas_kernel: Optional[bool] = None,
                  tp: Optional[bool] = None,
-                 request_deadline_s: Optional[float] = None, **overrides):
+                 request_deadline_s: Optional[float] = None,
+                 state_slots: Optional[int] = None, **overrides):
         ensure_compile_cache()
         if isinstance(cfg, str):
             cfg = T.get_model_config(cfg, **overrides)
@@ -130,8 +184,19 @@ class FastGenEngine:
         # queue slots forever. put() can override per request.
         self.request_deadline_s = request_deadline_s
 
-        self.allocator = BlockAllocator(n_blocks)
-        self.pool = PG.init_paged_kv(cfg, n_blocks, block_size)
+        # sequence slots: what a model of ``layer_kinds`` keeps per
+        # sequence beside its blocks (rings, recurrent state) lives in one
+        # of ``state_slots`` rows, acquired with a sequence's first block
+        # and freed with it; 0 for every other model. The default holds a
+        # small tick bucket's decode rows.
+        if not cfg.layer_kinds:
+            state_slots = 0
+        elif state_slots is None:
+            state_slots = max(1, min(token_budget // 8, (n_blocks - 1) // 2))
+        self.allocator = BlockAllocator(n_blocks, state_slots)
+        self.pool = PG.init_paged_kv(cfg, n_blocks, block_size,
+                                     state_slots=state_slots,
+                                     max_run=token_budget)
         self.seqs: Dict[int, _Seq] = {}
         self._admit_order: List[int] = []
         self._decode_rr = 0
@@ -174,6 +239,10 @@ class FastGenEngine:
             if cfg.mla:
                 problem = ("MLA latent-KV pools are per-head-free and not "
                            "sharded yet — serve MLA models single-replica")
+            elif cfg.layer_kinds:
+                problem = ("rings and per-sequence state are not sharded "
+                           "yet — serve a stack of layer kinds "
+                           "single-replica")
             elif cfg.kv_heads % tp_size != 0:
                 problem = (f"kv_heads {cfg.kv_heads} not divisible by "
                            f"tensor axis {tp_size}")
@@ -304,6 +373,17 @@ class FastGenEngine:
         self._tm_kv_peak = telemetry.gauge(
             "fastgen_kv_pool_utilization_peak",
             "high-water mark of KV pool utilization")
+        self._tm_slots = telemetry.gauge(
+            "fastgen_state_slots_in_use",
+            "sequence slots (rings and recurrent state of a model that "
+            "keeps them) held by live sequences; 0 for other models")
+        self._tm_slots_peak = telemetry.gauge(
+            "fastgen_state_slots_in_use_peak",
+            "high-water mark of fastgen_state_slots_in_use")
+        self._tm_slot_waits = telemetry.counter(
+            "fastgen_state_slot_waits_total",
+            "admitted sequences whose first prompt chunk waited a tick or "
+            "more for a sequence slot")
         self._tm_kv_tier = telemetry.gauge(
             "fastgen_kv_blocks_in_use",
             "allocated KV blocks bucketed by the owning sequence's "
@@ -327,6 +407,8 @@ class FastGenEngine:
         util = self.kv_utilization()
         self._tm_kv.set(util)
         self._tm_kv_peak.set_max(util)
+        self._tm_slots.set(self.allocator.slots_in_use)
+        self._tm_slots_peak.set_max(self.allocator.slots_in_use)
         in_use = {"quarter": 0, "half": 0, "full": 0}
         for s in live:
             if s.blocks:
@@ -516,7 +598,7 @@ class FastGenEngine:
         def fits(tier):
             return tier <= headroom and sum(
                 self._blocks_needed(s, s.pos + tier - 1)
-                for s in live) <= self.allocator.free_blocks
+                for s in live) <= self.allocator.available(False)
 
         n = 0
         if allow_overshoot:
@@ -722,7 +804,7 @@ class FastGenEngine:
         for tier in self.DECODE_TIERS:
             if tier <= min(cap, headroom) and sum(
                     self._blocks_needed(s, s.pos + tier - 1)
-                    for s in live) <= self.allocator.free_blocks:
+                    for s in live) <= self.allocator.available(False):
                 return tier
         return 0
 
@@ -867,7 +949,7 @@ class FastGenEngine:
                          len(s.generated), s.last_tok, s.done,
                          s.first_tok_seen)
                  for s in seqs},
-                list(self.allocator._free))
+                self.allocator.snapshot())
 
     def _restore_host(self, snap: tuple) -> None:
         seq_snap, free = snap
@@ -880,7 +962,7 @@ class FastGenEngine:
             del s.generated[st[4]:]
             s.last_tok, s.done = st[5], st[6]
             s.first_tok_seen = st[7]
-        self.allocator._free = free
+        self.allocator.restore(free)
 
     def _ensure_blocks(self, seq: _Seq, upto_pos: int) -> bool:
         """Grow the sequence's block table to cover ``upto_pos``. Returns
@@ -889,9 +971,12 @@ class FastGenEngine:
         backpressure, reference ``scheduling_utils`` CacheBlock result)."""
         need = upto_pos // self.block_size + 1
         grow = need - len(seq.blocks)
-        if grow > self.allocator.free_blocks:
+        if grow > self.allocator.available(starting=not seq.blocks):
             return False
-        for blk in self.allocator.allocate(max(grow, 0)):
+        # a sequence's first block comes with its slot (``BlockAllocator``)
+        new = self.allocator.grow(max(grow, 0)) if seq.blocks \
+            else self.allocator.allocate(grow)
+        for blk in new:
             seq.table[len(seq.blocks)] = blk
             seq.blocks.append(blk)
         return True
@@ -939,6 +1024,13 @@ class FastGenEngine:
             # (row, seq, is_decode): rows whose logits get sampled this tick
             heads: List[tuple] = []
             row = 0
+            # runs of rows that start from a sequence's stored state; and,
+            # of a model with window layers, the cache positions inside
+            # the rows' windows (what a window layer must read, a
+            # sequence) and those the prompt rows score (a row)
+            state_runs = 0
+            W = self.cfg.attn_window
+            window_positions = window_attended = 0
             # prompt rows in kernel tiles wholly inside one chunk
             shared_rows = 0
             R = self._tile_rows
@@ -964,6 +1056,8 @@ class FastGenEngine:
                 tables[row] = seq.table
                 heads.append((row, seq, True))
                 row += 1
+                state_runs += 1
+                window_positions += min(seq.pos + 1, W)
             self._decode_rr += 1
 
             # 2) prefill chunks — FIFO admission, split to fit the
@@ -979,13 +1073,25 @@ class FastGenEngine:
                 # capacity backpressure: shrink the chunk to the blocks
                 # the pool can actually supply; zero → the prompt waits
                 # for a flush
-                fits = (len(seq.blocks) + self.allocator.free_blocks) \
+                starting = not seq.blocks
+                fits = (len(seq.blocks) + self.allocator.available(starting)) \
                     * self.block_size - seq.pos
                 chunk = min(chunk, fits)
                 if chunk <= 0:
                     self._tm_preempt.inc(phase="prefill")
+                    if starting and self.allocator.state_slots \
+                            and not self.allocator.free_slots \
+                            and not seq.slot_waited:
+                        # waits as it would for blocks; counted once
+                        seq.slot_waited = True
+                        self._tm_slot_waits.inc()
                     continue
                 self._ensure_blocks(seq, seq.pos + chunk - 1)
+                state_runs += seq.pos > 0
+                if W:
+                    window_positions += min(seq.pos + chunk, chunk + W - 1)
+                    window_attended += int(np.minimum(
+                        np.arange(seq.pos, seq.pos + chunk) + 1, W).sum())
                 lo = seq.prefilled
                 tokens[row:row + chunk] = seq.prompt[lo:lo + chunk]
                 positions[row:row + chunk] = np.arange(seq.pos,
@@ -1021,7 +1127,14 @@ class FastGenEngine:
         tier = self._mb_tier_name(mb)
         self._ticks_run += 1
         t0 = time.perf_counter()
+        slot_attrs = {}
+        if self.allocator.state_slots:
+            slot_attrs = {"state_runs": int(state_runs),
+                          "state_slots": self.allocator.slots_in_use,
+                          "window_positions": int(window_positions),
+                          "window_attended": window_attended}
         with telemetry.span("decode_tick", attrs={
+                **slot_attrs,
                 "tick": self._ticks_run, "kind": kind, "rows": row,
                 "decode_rows": n_decode_rows,
                 "prefill_tokens": row - n_decode_rows,

@@ -913,7 +913,69 @@ def params_from_exaone(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
     return params_from_llama(out, cfg)
 
 
+# --------------------------------------------------------------------------- #
+# Phi-4-mini-flash (SambaY: state-space, windowed and shared-cache layers)
+# --------------------------------------------------------------------------- #
+
+def phi4flash_layer_kinds(n_layers: int, mb_per_layer: int) -> tuple:
+    """The kind of every layer of a ``phi4flash`` stack, by index (the
+    family's defaults, ``modeling_phi4flash.py``): the first half + 2
+    layers are the self-decoder, Mamba on every ``mb_per_layer``-th index
+    from 0 and windowed attention between, its last attention layer full
+    and the owner of the one growing cache; the cross-decoder after it
+    alternates gated memory units (on the Mamba indices) and cross
+    attention over that cache."""
+    if mb_per_layer != 2 or n_layers % 4 or n_layers < 4:
+        raise NotImplementedError(
+            f"phi4flash with mb_per_layer={mb_per_layer}, "
+            f"num_hidden_layers={n_layers}: layers pair up and the full "
+            "layer's index, depth/2 + 1, is odd (mb_per_layer 2, a depth "
+            "that is a multiple of 4)")
+    full = n_layers // 2 + 1
+    return tuple(
+        ("mamba" if l % 2 == 0 else "window" if l < full else "full")
+        if l <= full else ("gmu" if l % 2 == 0 else "cross")
+        for l in range(n_layers))
+
+
+def config_from_phi4flash(hf_config) -> TransformerConfig:
+    h = hf_config.hidden_size
+    window = getattr(hf_config, "sliding_window", None)
+    if isinstance(window, (list, tuple)):     # per layer in the published
+        window = max(w or 0 for w in window)  # file; one width throughout
+    inner = int(getattr(hf_config, "mamba_expand", 2)) * h
+    rank = getattr(hf_config, "mamba_dt_rank", "auto")
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size, hidden_size=h,
+        num_layers=hf_config.num_hidden_layers,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        ffn_hidden_size=hf_config.intermediate_size,
+        max_seq_len=hf_config.max_position_embeddings,
+        pos_emb="none", norm="layernorm", activation="swiglu",
+        use_bias=bool(getattr(hf_config, "mlp_bias", False)),
+        lm_head_bias=bool(getattr(hf_config, "lm_head_bias", False)),
+        tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", True)),
+        norm_eps=hf_config.layer_norm_eps, dtype="float32",
+        init_std=float(getattr(hf_config, "initializer_range", 0.02)),
+        layer_kinds=phi4flash_layer_kinds(
+            hf_config.num_hidden_layers,
+            int(getattr(hf_config, "mb_per_layer", 2))),
+        attn_window=int(window or 0),
+        ssm_inner=inner,
+        ssm_state=int(getattr(hf_config, "mamba_d_state", 16)),
+        ssm_conv=int(getattr(hf_config, "mamba_d_conv", 4)),
+        ssm_dt_rank=-(-h // 16) if rank == "auto" else int(rank))
+
+
+def params_from_phi4flash(sd, cfg):
+    raise NotImplementedError(
+        "phi4flash: the config importer is written (random-weight runs at "
+        "the published shape); the checkpoint's tensor names are not mapped")
+
+
 _ARCH_TABLE = {
+    "phi4flash": (config_from_phi4flash, params_from_phi4flash),
     "gpt2": (config_from_gpt2, params_from_gpt2),
     "llama": (config_from_llama, params_from_llama),
     "exaone": (config_from_exaone, params_from_exaone),

@@ -1,0 +1,295 @@
+"""Layers of more than one kind in one stack: the mixers of a stack whose
+``TransformerConfig.layer_kinds`` alternate (SambaY, arXiv:2507.06607; the
+``phi4flash`` family), as functions of ROWS.
+
+Every layer of such a stack is ``h += Mixer(LN1(h)); h += MLP(LN2(h))``
+and the mixer is one of
+
+* ``mamba``: a selective state-space layer (Mamba-1, arXiv:2312.00752): a
+  depthwise causal convolution and a diagonal linear recurrence, whose
+  state per sequence is the convolution's last inputs and one
+  ``[state, inner]`` matrix, whatever the sequence's length;
+* ``window`` / ``full``: differential attention (arXiv:2410.05258) over
+  the layer's own keys and values, under a window or fully causal;
+* ``cross``: the same attention with queries alone, over the keys and
+  values the stack's ``full`` layer wrote (YOCO, arXiv:2405.05254);
+* ``gmu``: a gated memory unit, which gates the scan output (``memory``)
+  the last ``mamba`` layer handed on.
+
+Rows are a flat batch ``[T, ...]`` in which a sequence's rows are
+consecutive and in order (a SplitFuse tick; a dense ``[B, S]`` batch
+flattened is the same thing with every run starting at position 0), so the
+convolution and the recurrence are SEGMENTED: a run's first row starts
+from the state handed in for it, and the state after every row comes back.
+Nothing here knows of pools, slots or engines (``models/paged.py``) and
+nothing imports ``models/transformer.py``.
+"""
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+#: kinds that attend, and of those the ones that project keys and values
+ATTENTION_KINDS = ("window", "full", "cross")
+KINDS = ("mamba", "gmu") + ATTENTION_KINDS
+#: the products' head layout: see :func:`paired_queries`
+PAIR = 2
+
+
+# --------------------------------------------------------------------------- #
+# parameters
+# --------------------------------------------------------------------------- #
+
+def mixer_specs(cfg: Any, kind: str) -> Dict[str, Tuple[tuple, tuple, str]]:
+    """A ``kind`` layer's mixer leaves: name -> (shape, logical axes, how
+    it starts). Matrices are stored ``[in, out]``; the state-space leaves
+    keep ``inner`` minor (the TPU's lanes)."""
+    h, di = cfg.hidden_size, cfg.ssm_inner
+    if kind == "mamba":
+        n, r, c = cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
+        return {
+            "w_in": ((h, 2 * di), ("embed", "mlp"), "std"),
+            "conv_w": ((c, di), (None, "mlp"), "conv"),
+            "conv_b": ((di,), ("mlp",), "zeros"),
+            "w_x": ((di, r + 2 * n), ("mlp", None), "std"),
+            "w_dt": ((r, di), (None, "mlp"), "std"),
+            "b_dt": ((di,), ("mlp",), "dt_bias"),
+            "a_log": ((n, di), (None, "mlp"), "a_log"),
+            "skip_scale": ((di,), ("mlp",), "ones"),
+            "wo": ((di, h), ("mlp", "embed"), "out"),
+        }
+    if kind == "gmu":
+        return {"w_in": ((h, di), ("embed", "mlp"), "std"),
+                "wo": ((di, h), ("mlp", "embed"), "out")}
+    if kind not in ATTENTION_KINDS:
+        raise ValueError(f"unknown layer kind {kind!r}; one of {KINDS}")
+    d = cfg.head_dim
+    qdim, kvdim = cfg.num_heads * d, cfg.kv_heads * d
+    specs = {"wq": ((h, qdim), ("embed", "heads"), "std"),
+             "wo": ((qdim, h), ("heads", "embed"), "out"),
+             "sub_norm": ((PAIR * d,), (None,), "ones")}
+    for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+        specs[name] = ((d,), (None,), "lambda")
+    if kind != "cross":
+        specs["wk"] = ((h, kvdim), ("embed", "kv_heads"), "std")
+        specs["wv"] = ((h, kvdim), ("embed", "kv_heads"), "std")
+    return specs
+
+
+def init_leaf(how: str, shape: tuple, key: jax.Array, std: float,
+              out_std: float) -> jax.Array:
+    """float32 start of one stacked leaf ``[layers, ...]``."""
+    if how in ("std", "out"):
+        return jax.random.normal(key, shape, jnp.float32) * (
+            std if how == "std" else out_std)
+    if how == "ones":
+        return jnp.ones(shape, jnp.float32)
+    if how == "zeros":
+        return jnp.zeros(shape, jnp.float32)
+    if how == "lambda":                  # DIFF Transformer: N(0, 0.1)
+        return jax.random.normal(key, shape, jnp.float32) * 0.1
+    if how == "conv":                    # U(+-1/sqrt(taps)), as a conv1d's
+        bound = 1.0 / math.sqrt(shape[-2])
+        return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+    if how == "a_log":                   # A = -(1 .. state), every channel
+        n = shape[-2]
+        return jnp.broadcast_to(
+            jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None], shape)
+    if how == "dt_bias":                 # softplus^-1 of dt ~ logU[1e-3, 1e-1]
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                     * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    raise ValueError(how)
+
+
+# --------------------------------------------------------------------------- #
+# runs of rows
+# --------------------------------------------------------------------------- #
+
+class Runs(NamedTuple):
+    """Which rows of a flat batch continue the row before them."""
+    start: jax.Array     # [T] bool: the row opens a run
+    last: jax.Array      # [T] bool: the row closes one
+    offset: jax.Array    # [T] int32: rows of its run before it
+    fresh: jax.Array     # [T] bool: its run starts at position 0
+
+
+def runs_of(owner: jax.Array, positions: jax.Array) -> Runs:
+    """``owner`` [T]: what a row's sequence is told by (its slot; a dense
+    batch's row index); a row continues the one before it where the owner
+    is the same and the position is the next."""
+    t = jnp.arange(owner.shape[0], dtype=jnp.int32)
+    start = jnp.concatenate([
+        jnp.ones((1,), jnp.bool_),
+        (owner[1:] != owner[:-1]) | (positions[1:] != positions[:-1] + 1)])
+    first = lax.cummax(jnp.where(start, t, 0))
+    offset = t - first
+    last = jnp.concatenate([start[1:], jnp.ones((1,), jnp.bool_)])
+    return Runs(start, last, offset, positions - offset == 0)
+
+
+# --------------------------------------------------------------------------- #
+# the state-space layer
+# --------------------------------------------------------------------------- #
+
+def _segmented_conv(x: jax.Array, taps: jax.Array, runs: Runs,
+                    conv0: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Depthwise causal convolution over runs. x [T, di]; taps [c, di]
+    (the last tap meets the row itself); conv0 [T, c-1, di]: the c-1 inputs
+    before each row's RUN (oldest first; read at the run's rows only).
+    Returns (the convolution [T, di] in float32, the c-1 inputs up to and
+    including each row [T, c-1, di])."""
+    c = taps.shape[0]
+    before = [x]                          # before[k][t]: the input k rows back
+    for k in range(1, c):
+        shifted = jnp.pad(x, ((k, 0), (0, 0)))[:-k]
+        # k rows back lies in the run, or (k - offset) rows before it
+        idx = jnp.clip(c - 1 - (k - runs.offset), 0, c - 2)
+        stored = jnp.take_along_axis(conv0, idx[:, None, None], axis=1)[:, 0]
+        before.append(jnp.where((runs.offset >= k)[:, None], shifted, stored))
+    window = jnp.stack(before[::-1], axis=1)             # [T, c, di]
+    out = jnp.einsum("tcd,cd->td", window.astype(jnp.float32),
+                     taps.astype(jnp.float32))
+    return out, window[:, 1:]
+
+
+def _selective_scan(delta: jax.Array, xc: jax.Array, bm: jax.Array,
+                    cm: jax.Array, a_neg: jax.Array, runs: Runs,
+                    ssm0: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence ``s[t] = exp(delta[t] A) * s[t-1] + (delta[t] x[t])
+    B[t]^T`` and its read-out ``y[t] = C[t] s[t]`` along rows, where a
+    run's first row takes ``ssm0[t]`` for ``s[t-1]``. delta, xc [T, di];
+    bm, cm [T, n]; a_neg [n, di]; ssm0 [T, n, di]; float32. Returns (the
+    state after every row [T, n, di], y [T, di]).
+
+    One row after the other with the state carried, in plain ``lax``:
+    which rows start a run is data, so one program serves every tick. On
+    the v5e a 512-row tick's nine scans take 10 ms this way; an
+    associative scan over ``(decay, drive)`` pairs took 66 ms (some twenty
+    passes over [512, n, di]) and a blocked form of it 36 (PERF.md,
+    PR 31)."""
+    def step(s, row):
+        d, x, b, c, start, s0 = row
+        s = jnp.exp(d[None, :] * a_neg) * jnp.where(start, s0, s) \
+            + (d * x)[None, :] * b[:, None]
+        return s, (s, c @ s)
+
+    _, (s, y) = lax.scan(step, jnp.zeros_like(ssm0[0]),
+                         (delta, xc, bm, cm, runs.start, ssm0), unroll=8)
+    return s, y
+
+
+def mamba(h: jax.Array, lp: Dict[str, Any], cfg: Any, runs: Runs,
+          conv0: jax.Array, ssm0: jax.Array
+          ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The selective state-space mixer on normed rows h [T, H], before its
+    output projection. conv0 [T, c-1, di], ssm0 [T, n, di]: the state each
+    row's run starts from (zeroed here for a run at position 0, whatever
+    was handed in). Returns (gated output [T, di], ungated scan output
+    ``y`` [T, di] (the memory a gated unit reads), and the state after
+    every row: conv [T, c-1, di], ssm [T, n, di] float32)."""
+    dt_, di, n = h.dtype, cfg.ssm_inner, cfg.ssm_state
+    r = cfg.ssm_dt_rank
+    fresh = runs.fresh[:, None, None]
+    conv0 = jnp.where(fresh, 0, conv0).astype(dt_)
+    ssm0 = jnp.where(fresh, 0.0, ssm0)
+    with jax.named_scope("ssm_proj"):
+        xz = h @ lp["w_in"].astype(dt_)
+        x, z = xz[:, :di], xz[:, di:]
+    with jax.named_scope("ssm_conv"):
+        conv, conv_new = _segmented_conv(x, lp["conv_w"], runs, conv0)
+        xc = jax.nn.silu(conv + lp["conv_b"].astype(jnp.float32))
+    with jax.named_scope("ssm_proj"):
+        dbc = (xc.astype(dt_) @ lp["w_x"].astype(dt_))
+        delta = jax.nn.softplus(
+            (dbc[:, :r] @ lp["w_dt"].astype(dt_)).astype(jnp.float32)
+            + lp["b_dt"].astype(jnp.float32))                    # [T, di]
+        bm = dbc[:, r:r + n].astype(jnp.float32)                 # [T, n]
+        cm = dbc[:, r + n:].astype(jnp.float32)
+    with jax.named_scope("ssm_scan"):
+        a_neg = -jnp.exp(lp["a_log"].astype(jnp.float32))        # [n, di]
+        s, y = _selective_scan(delta, xc, bm, cm, a_neg, runs, ssm0)
+        y = y + lp["skip_scale"].astype(jnp.float32) * xc
+    with jax.named_scope("ssm_gate"):
+        y = y.astype(dt_)
+        out = y * jax.nn.silu(z)
+    return out, y, conv_new, s
+
+
+def gmu(h: jax.Array, lp: Dict[str, Any], memory: jax.Array) -> jax.Array:
+    """Gated memory unit on normed rows, before its output projection."""
+    return jax.nn.silu(h @ lp["w_in"].astype(h.dtype)) * memory
+
+
+# --------------------------------------------------------------------------- #
+# differential attention
+# --------------------------------------------------------------------------- #
+
+def lambda_init(layer: jax.Array) -> jax.Array:
+    """``0.8 - 0.6 exp(-0.3 l)`` of the layer's index from 0."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, jnp.float32))
+
+
+def paired_queries(q: jax.Array) -> jax.Array:
+    """Differential attention as ONE grouped-query attention. Heads pair by
+    parity (``q1 = q[0::2]``, ``k1 = k[0::2]`` ...), so key heads ``2g,
+    2g+1`` side by side are one key of ``2 D`` columns ``[k1_g | k2_g]``,
+    the values likewise ``[v1_g | v2_g]``: exactly how ``[.., K, D]`` keys
+    and values lie in memory, read as ``[.., K/2, 2 D]``. A query of the
+    first softmax is ``[q1 | 0]`` and one of the second ``[0 | q2]``: the
+    score is ``q1 . k1`` or ``q2 . k2`` and either softmax's output is
+    over ``[v1 | v2]``, which are the four products. Query heads
+    ``4g .. 4g+3`` (``q1, q2, q1, q2``) belong to paired key head ``g``,
+    so no head moves. q [T, N, D] -> [T, N, 2 D]."""
+    zeros = jnp.zeros_like(q)
+    first = jnp.concatenate([q, zeros], axis=-1)
+    second = jnp.concatenate([zeros, q], axis=-1)
+    odd = (jnp.arange(q.shape[1]) % PAIR == 1)[None, :, None]
+    return jnp.where(odd, second, first)
+
+
+def paired_cache(x: jax.Array) -> jax.Array:
+    """Keys or values [..., K, D] -> [..., K/2, 2 D] (a reshape)."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] // PAIR, PAIR * x.shape[-1]))
+
+
+def differential_merge(o: jax.Array, lp: Dict[str, Any], layer: jax.Array,
+                       eps: float) -> jax.Array:
+    """From the paired attention's output o [T, N, 2 D] (head ``2j`` the
+    first softmax of pair ``j``, ``2j+1`` the second) to the layer's
+    attention before ``wo`` [T, N D]: ``o1 - lam o2``, RMSNorm over the
+    pair's ``2 D`` columns, times ``1 - lam0``."""
+    Tn, N, D2 = o.shape
+    lam0 = lambda_init(layer)
+    f32 = jnp.float32
+    lam = jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32)
+                          * lp["lambda_k1"].astype(f32))) \
+        - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32)
+                          * lp["lambda_k2"].astype(f32))) + lam0
+    o = o.astype(f32).reshape(Tn, N // PAIR, PAIR, D2)
+    d = o[:, :, 0] - lam * o[:, :, 1]
+    d = d * lax.rsqrt(jnp.mean(jnp.square(d), axis=-1, keepdims=True) + eps)
+    d = d * lp["sub_norm"].astype(f32) * (1.0 - lam0)
+    return d.reshape(Tn, N // PAIR * D2)
+
+
+def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                       scale: float, window: int) -> jax.Array:
+    """Plain causal attention of a dense batch under a window (0: none).
+    q [B, S, N, D]; k, v [B, S, K, D]; position j is visible from i iff
+    ``i - window < j <= i``. float32 softmax."""
+    S, N, K = q.shape[1], q.shape[2], k.shape[2]
+    if K != N:
+        k = jnp.repeat(k, N // K, axis=2)
+        v = jnp.repeat(v, N // K, axis=2)
+    s = jnp.einsum("bsnd,btnd->bnst", q, k).astype(jnp.float32) * scale
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    seen = j <= i
+    if window:
+        seen &= j > i - window
+    p = jax.nn.softmax(jnp.where(seen[None, None], s, -1e30), axis=-1)
+    return jnp.einsum("bnst,btnd->bsnd", p.astype(q.dtype), v)

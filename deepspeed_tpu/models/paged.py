@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deepspeed_tpu.models import hybrid as HY
 from deepspeed_tpu.models import transformer as T
 
 PyTree = Any
@@ -35,9 +36,43 @@ def latent_row_width(cfg: T.TransformerConfig) -> int:
     return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
 
 
+def ring_blocks(cfg: T.TransformerConfig, block_size: int,
+                max_run: int) -> int:
+    """Blocks of a window layer's ring, per sequence: a tick writes a
+    sequence's rows before any of them attends, so the ring holds the
+    window and the longest run of rows one sequence can have in a tick."""
+    return -(-(cfg.attn_window + max_run) // block_size)
+
+
 def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
-                  dtype=None) -> Dict[str, jax.Array]:
-    """Block pool per layer. Block 0 is the trash block for pad writes.
+                  dtype=None, state_slots: int = 0, max_run: int = 0
+                  ) -> Dict[str, jax.Array]:
+    """What a model keeps of its sequences between ticks, as a dict of
+    arrays each ``[layers that keep it, rows, ...]`` (``forward_paged``
+    carries every one flat, ``[layers * rows, ...]``, and a layer owns the
+    range that starts at ``layer * rows``). Three kinds:
+
+    * a BLOCK pool ``[L, NB, bs, ...]``, which grows with a sequence a
+      block at a time through its block table; block 0 is the trash block
+      pad rows write into. ``{"k", "v"}`` per head, or ``{"latent"}``;
+    * a RING ``[L, (slots + 1) * RB, bs, ...]``: ``RB`` blocks
+      (:func:`ring_blocks`) a sequence slot, position ``p`` in block
+      ``(p // bs) % RB`` of its slot's, whatever the sequence's length;
+    * STATE ``[L, slots + 1, ...]``: one row a sequence slot.
+
+    A sequence's slot is its first block's id (``tables[:, 0]``: the
+    engine's allocator hands first blocks out of ``1 .. state_slots``);
+    slot 0 is the pad rows' trash, as block 0 is.
+
+    A homogeneous attention stack has the block pool alone, for every
+    layer. A stack of ``layer_kinds`` (``models/hybrid.py``) has the block
+    pool for its ONE ``full`` layer (the ``cross`` layers read it), rings
+    ``{"wk", "wv"}`` for its ``window`` layers and state for its ``mamba``
+    layers: ``{"conv"}`` the convolution's last inputs, ``{"ssm"}`` the
+    recurrence's matrix in float32 (a bfloat16 state was not tried on the
+    chip). Its keys and values are stored as differential attention reads
+    them, ``[.., K/2, 2 D]`` (``hybrid.paired_cache``), a block heads
+    first: ``[K/2, bs, 2 D]``.
 
     MLA models (DeepSeek) pool the LATENTS instead of per-head K/V: one
     row per slot, ``c_kv [kv_lora_rank] ++ k_pe [qk_rope_head_dim]`` (the
@@ -48,6 +83,26 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
     kernel read each position once."""
     dt = dtype or cfg.compute_dtype
     L = cfg.num_layers
+    if cfg.layer_kinds:
+        kinds = cfg.layer_kinds
+        if kinds.count("full") != 1 or state_slots < 1:
+            raise ValueError(
+                "a stack of layer_kinds has one `full` layer (the owner of "
+                f"the block pool; got {kinds.count('full')}) and needs "
+                f"state_slots >= 1 (got {state_slots})")
+        # a block is [K/2, bs, 2 D]: heads first (10 paired heads cannot be
+        # the second-minor dim of a block the kernel's copies slice)
+        head = (cfg.kv_heads // HY.PAIR, block_size, HY.PAIR * cfg.head_dim)
+        ring = (kinds.count("window"), (state_slots + 1)
+                * ring_blocks(cfg, block_size, max_run)) + head
+        state = (kinds.count("mamba"), state_slots + 1)
+        return {"k": jnp.zeros((1, n_blocks) + head, dt),
+                "v": jnp.zeros((1, n_blocks) + head, dt),
+                "wk": jnp.zeros(ring, dt), "wv": jnp.zeros(ring, dt),
+                "conv": jnp.zeros(state + (cfg.ssm_conv - 1, cfg.ssm_inner),
+                                  dt),
+                "ssm": jnp.zeros(state + (cfg.ssm_state, cfg.ssm_inner),
+                                 jnp.float32)}
     if cfg.mla:
         return {"latent": jnp.zeros((L, n_blocks, block_size,
                                      latent_row_width(cfg)), dt)}
@@ -57,8 +112,10 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
 
 def paged_attention_reference(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                               tables: jax.Array, lengths: jax.Array,
-                              alibi: Optional[jax.Array] = None
-                              ) -> jax.Array:
+                              alibi: Optional[jax.Array] = None,
+                              scale: Optional[float] = None,
+                              window: Optional[int] = None,
+                              heads_first: bool = False) -> jax.Array:
     """Pure-XLA paged attention (the CPU/fallback path; the Pallas kernel in
     ``ops/pallas/paged_attention.py`` computes the same thing without
     materializing the gathered KV).
@@ -67,19 +124,23 @@ def paged_attention_reference(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     Token t attends to its sequence's first ``lengths[t]`` cache slots.
     ``alibi``: [N] slopes — cache slot c IS absolute position c, so the
     bias is ``slope · (c − (lengths−1))`` (matches ``cached_attention``).
+    ``scale`` / ``window`` / ``heads_first`` (pools [NB, K, bs, D]): as
+    the kernel's (``paged_attention``).
     """
     Tn, N, D = q.shape
-    bs = kpool.shape[1]
-    K = kpool.shape[2]
     MB = tables.shape[1]
     kg = kpool[tables]                                   # [T, MB, bs, K, D]
     vg = vpool[tables]
+    if heads_first:
+        kg, vg = jnp.swapaxes(kg, 2, 3), jnp.swapaxes(vg, 2, 3)
+    bs, K = kg.shape[2:4]
     kg = kg.reshape(Tn, MB * bs, K, D)
     vg = vg.reshape(Tn, MB * bs, K, D)
     if K != N:
         kg = jnp.repeat(kg, N // K, axis=2)
         vg = jnp.repeat(vg, N // K, axis=2)
-    scale = 1.0 / jnp.sqrt(jnp.float32(D))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(D))
     s = jnp.einsum("tnd,tcnd->tnc", q.astype(jnp.float32),
                    kg.astype(jnp.float32)) * scale       # [T, N, ctx]
     if alibi is not None:
@@ -87,6 +148,9 @@ def paged_attention_reference(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                - (lengths[:, None] - 1)).astype(jnp.float32)  # [T, ctx]
         s = s + alibi.astype(jnp.float32)[None, :, None] * rel[:, None, :]
     mask = jnp.arange(MB * bs)[None, None, :] < lengths[:, None, None]
+    if window is not None:
+        mask &= jnp.arange(MB * bs)[None, None, :] \
+            >= lengths[:, None, None] - window
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("tnc,tcnd->tnd", p, vg.astype(jnp.float32)).astype(q.dtype)
@@ -163,7 +227,27 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
     latent instantiation, which is the kernel with one KV head.
 
     Dense pools: ``fn(q, kpool, vpool, tables, lengths[, alibi=])``;
-    the latent pool: ``fn(q_row, pool, tables, lengths, kvr, scale)``."""
+    the latent pool: ``fn(q_row, pool, tables, lengths, kvr, scale)``;
+    a stack of ``layer_kinds``: ``fn(q, kpool, vpool, tables, lengths,
+    scale=, window=, heads_first=)`` over paired heads
+    (``hybrid.paired_queries``), the dense kernel under the names ``window_paged_attention`` (a ring, ``window``
+    positions) and ``shared_paged_attention`` (``window`` None: the one
+    block pool, read by the ``full`` layer and every ``cross`` layer)."""
+    if cfg.layer_kinds:
+        if not use_kernel:
+            return paged_attention_reference, 0
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            paged_attention, tile_rows)
+
+        def attend(q, kpool, vpool, tables, lengths, scale, window,
+                   heads_first):
+            return paged_attention(
+                q, kpool, vpool, tables, lengths, scale=scale, window=window,
+                heads_first=heads_first,
+                name="shared_paged_attention" if window is None
+                else "window_paged_attention")
+
+        return attend, tile_rows(cfg.num_heads, cfg.kv_heads // HY.PAIR)
     if not use_kernel or cfg.pos_emb == "alibi":
         return (latent_attention_reference if cfg.mla
                 else paged_attention_reference), 0
@@ -304,6 +388,88 @@ def _latent_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
     return layer
 
 
+def _kinds_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
+                 rows: _Rows, attend: Callable) -> Callable:
+    """The caches of a stack of ``layer_kinds`` (``init_paged_kv``): the
+    mixer of one layer as ``layer(kind, h, lp, flat, step, index, memory)
+    -> (mixed [T, .] before ``wo``, flat, memory)``. ``step`` counts the
+    stack's PAIRS of layers, which is the index of a pair's ``mamba``
+    layer among the state's layers and of its ``window`` layer among the
+    rings' (every pair up to the ``full`` layer's has one of each);
+    ``index`` is the layer's own. ``memory`` [T, inner] is the last
+    ``mamba`` layer's scan output, which the ``gmu`` layers gate: an
+    activation of the tick, not a cache.
+
+    A row's slot is its table's first block. A tick's rows are written
+    before any attends, so a ``window`` row walks its ring through a table
+    of its own: column ``c`` names the slot's block ``c % RB``, which holds
+    positions ``c*bs ..`` if any of them is inside the row's window."""
+    dt = cfg.compute_dtype
+    Tn, MB = rows.tables.shape
+    N, K, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    bs = pool["k"].shape[3]
+    S1 = pool["conv"].shape[1]
+    ring_rows = pool["wk"].shape[1]
+    RB = ring_rows // S1
+    slot = rows.tables[:, 0]
+    runs = HY.runs_of(slot, rows.positions)
+    ring_tables = slot[:, None] * RB + (jnp.arange(MB, dtype=jnp.int32)
+                                        % RB)[None, :]
+    ring_block = slot * RB + (rows.positions // bs) % RB
+    scale = D ** -0.5                       # of the unpaired heads
+
+    def heads(h, w, n):
+        return (h @ w.astype(dt)).reshape(Tn, n, D)
+
+    def write(flat, names, at, lp, h):
+        new = dict(flat)
+        for name, w in zip(names, ("wk", "wv")):
+            # (block, head, slot) index every written row, so that the
+            # scatter's one window dim is the array's minor one
+            new[name] = flat[name].at[
+                at[:, None], jnp.arange(K // HY.PAIR)[None, :],
+                rows.offsets[:, None]].set(
+                HY.paired_cache(heads(h, lp[w], K)).astype(flat[name].dtype),
+                mode="drop")
+        return new
+
+    def layer(kind, h, lp, flat, step, index, memory):
+        if kind == "mamba":
+            at = step * S1 + slot
+            out, memory, conv, ssm = HY.mamba(
+                h, lp, cfg, runs, flat["conv"][at], flat["ssm"][at])
+            # the state after a run's last row is its sequence's; the
+            # other rows' index lies past the array and is dropped
+            put = jnp.where(runs.last, at, flat["conv"].shape[0])
+            flat = {**flat,
+                    "conv": flat["conv"].at[put].set(
+                        conv.astype(flat["conv"].dtype), mode="drop"),
+                    "ssm": flat["ssm"].at[put].set(ssm, mode="drop")}
+            return out, flat, memory
+        if kind == "gmu":
+            return HY.gmu(h, lp, memory), flat, memory
+        q = HY.paired_queries(heads(h, lp["wq"], N))
+        if kind == "window":
+            base = step * ring_rows
+            flat = write(flat, ("wk", "wv"), base + ring_block, lp, h)
+            o = attend(q, flat["wk"], flat["wv"], ring_tables + base,
+                       rows.lengths, scale=scale, window=cfg.attn_window,
+                       heads_first=True)
+        else:
+            if kind == "full":
+                flat = write(flat, ("k", "v"), rows.block_idx, lp, h)
+            o = attend(q, flat["k"], flat["v"], rows.tables, rows.lengths,
+                       scale=scale, window=None, heads_first=True)
+        return (HY.differential_merge(o, lp, index, cfg.norm_eps).astype(dt),
+                flat, memory)
+
+    return layer
+
+
+#: the scope a kind's mixer runs under (``attn`` also holds ``ln1``, ``wo``)
+_KIND_SCOPES = {"mamba": "ssm", "gmu": "gmu"}
+
+
 def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
                   tables: jax.Array, pool: Dict[str, jax.Array],
                   cfg: T.TransformerConfig,
@@ -321,7 +487,9 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     stack; the pool's layers in the same order), the residual form, FFN or
     experts, the head. What a layer's attention projects, writes into the
     pool and attends to is the cache kind's (:func:`_dense_cache`,
-    :func:`_latent_cache`), picked once from ``cfg.mla``.
+    :func:`_latent_cache`), picked once from ``cfg.mla``. A segment of
+    ``layer_kinds`` steps a period of layers at a time, each with the mixer
+    of its kind over the cache of its kind (:func:`_kinds_cache`).
 
     ``attention_fn`` says whether kernels are wanted: ``None`` or a
     reference means no, anything else yes; which function then runs is
@@ -336,7 +504,9 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
 
     attend, _ = tick_attention(cfg, attention_fn not in _REFERENCES)
     dt = cfg.compute_dtype
-    NB, bs = next(iter(pool.values())).shape[1:3]
+    NB, bs = pool["latent" if cfg.mla else "k"].shape[1:3]
+    if cfg.layer_kinds:
+        bs = pool["k"].shape[3]             # its blocks are [K/2, bs, 2 D]
 
     with jax.named_scope("embed"):
         x = params["tok_emb"].astype(dt)[tokens]             # [T, H]
@@ -350,8 +520,8 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
                      tables, (positions // bs)[:, None], axis=1)[:, 0],
                  offsets=positions % bs, lengths=positions + 1)
     valid = tables[:, 0] > 0     # a pad row's table is all trash block
-    attention = (_latent_cache if cfg.mla else _dense_cache)(
-        cfg, pool, rows, attend)
+    attention = (_kinds_cache if cfg.layer_kinds else _latent_cache
+                 if cfg.mla else _dense_cache)(cfg, pool, rows, attend)
 
     def make_body(seg: T.TransformerConfig, first: int, stack):
         def body(carry, lp):
@@ -386,6 +556,26 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
 
         return body
 
+    def make_period_body(seg: T.TransformerConfig):
+        """A step of a segment of ``layer_kinds``: its period's layers,
+        each ``x += wo(Mixer(ln1 x)); x += FFN(ln2 x)``."""
+        def body(carry, lps):
+            x, flat, step, memory = carry
+            for i, kind in enumerate(seg.period):
+                lp = dequant_params(lps[kind], dt)
+                with jax.named_scope(_KIND_SCOPES.get(kind, "attn")):
+                    h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
+                    # ``step`` counts pairs from the stack's first layer
+                    mixed, flat, memory = attention(
+                        kind, h, lp, flat, step, 2 * step + i, memory)
+                    x = x + mixed @ lp["wo"].astype(dt)
+                with jax.named_scope("mlp"):
+                    h2 = T._norm(x, lp["ln2"], seg.norm, seg.norm_eps)
+                    x = x + T._ffn(h2, lp, seg)[0]
+            return (x, flat, step + 1, memory), None
+
+        return body
+
     # The pool rides the layer scans as a FLAT [L*NB, bs, ...] carry that is
     # scattered in place (layer l owns block range [l*NB, (l+1)*NB)); the
     # attention kernel gathers through layer-offset tables, reading only the
@@ -395,9 +585,14 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     # in-place carry touches only the written rows.
     carry = (x, {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()},
              jnp.int32(0))
+    if cfg.layer_kinds:
+        carry += (jnp.zeros((x.shape[0], cfg.ssm_inner), dt),)
     stats = {}
     first = 0
     for key, seg in cfg.segments:
+        if seg.period:
+            carry, _ = lax.scan(make_period_body(seg), carry, params[key])
+            continue
         # the experts' matrices stay out of the scan's sliced operands: the
         # grouped matmul takes the stack whole (``moe.layer.grouped_dot``;
         # a slice is a copy of a layer's experts before each matmul);
@@ -410,7 +605,7 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
         first += seg.num_layers
         if n_rows is not None:
             stats["expert_rows"] = n_rows
-    x, flat, _ = carry
+    x, flat = carry[:2]
     with jax.named_scope("lm_head"):
         x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
         head = T._lm_head_of(params, cfg)

@@ -180,6 +180,18 @@ class TransformerConfig:
     # program). Numerics are identical either way; the engine sets this
     # from stage3_prefetch_bucket_size / reduce_bucket_size.
     scan_chunks: int = 0
+    # layers of more than one kind in one stack (``models/hybrid.py``; the
+    # ``phi4flash`` family): the kind of EVERY layer, in order. Layers pair
+    # up, runs of equal pairs are one scan each (``segments``). Empty: the
+    # homogeneous attention stack above.
+    layer_kinds: Tuple[str, ...] = ()
+    attn_window: int = 0                # positions a ``window`` layer sees
+    ssm_inner: int = 0                  # Mamba / gated-unit inner width
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
+    # set on a SEGMENT's config only: the kinds of one step of its scan
+    period: Tuple[str, ...] = ()
 
     @property
     def kv_heads(self) -> int:
@@ -246,7 +258,15 @@ class TransformerConfig:
     def segments(self) -> Tuple[Tuple[str, "TransformerConfig"], ...]:
         """The layer stack as (key under ``params``, config of that run of
         layers) in order: one homogeneous scan each. ``num_layers`` of a
-        segment's config is the segment's depth."""
+        segment's config is the segment's depth: the steps of its scan.
+
+        A stack of ``layer_kinds`` is cut into pairs of layers and each run
+        of equal pairs is a segment whose scan steps a PERIOD (the pair) at
+        a time; its parameters are ``params[key][kind]``, stacked by step,
+        and its config carries ``period`` and the index of its first layer
+        (``first_dense_layers`` is free there: no such stack has experts)."""
+        if self.layer_kinds:
+            return self._period_segments()
         d = self.first_dense_layers
         if not d:
             return (("blocks", self),)
@@ -260,7 +280,44 @@ class TransformerConfig:
         return (("dense_blocks", dataclasses.replace(
             rest, num_layers=d, n_experts=0)), ("blocks", rest))
 
+    def _period_segments(self):
+        kinds, L = self.layer_kinds, self.num_layers
+        if len(kinds) != L or L % 2 or self.n_experts or self.mla:
+            raise ValueError(
+                f"layer_kinds names {len(kinds)} layers of num_layers={L}; "
+                "a stack of kinds has an even depth, no experts and no "
+                "latent attention")
+        pairs = [kinds[i:i + 2] for i in range(0, L, 2)]
+        out, i = [], 0
+        while i < len(pairs):
+            n = 1
+            while i + n < len(pairs) and pairs[i + n] == pairs[i]:
+                n += 1
+            if pairs[i][0] == pairs[i][1]:
+                raise ValueError(f"a period of one kind twice: {pairs[i]}")
+            out.append(("_".join(pairs[i]) + "_blocks", dataclasses.replace(
+                self, layer_kinds=(), period=tuple(pairs[i]), num_layers=n,
+                first_dense_layers=2 * i)))
+            i += n
+        return tuple(out)
+
     def num_params(self) -> int:
+        if self.layer_kinds:
+            from deepspeed_tpu.models.hybrid import mixer_specs
+
+            h, f = self.hidden_size, self.ffn_size
+            norm = 2 * h if self.norm == "layernorm" else h
+            ffn = (3 if self.activation == "swiglu" else 2) * h * f
+            if self.use_bias:
+                ffn += f + h
+            total = self.vocab_size * h * (1 if self.tie_embeddings else 2) \
+                + norm
+            for kind in self.layer_kinds:
+                total += 2 * norm + ffn + sum(
+                    math.prod(leaf[0]) for leaf in jax.tree.leaves(
+                        mixer_specs(self, kind),
+                        is_leaf=lambda x: isinstance(x, tuple)))
+            return total
         if self.first_dense_layers:
             shared = dataclasses.replace(self, first_dense_layers=0,
                                          num_layers=0).num_params()
@@ -312,6 +369,8 @@ class TransformerConfig:
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     """fp32 master parameters. Output projections scaled by 1/sqrt(2L) (GPT-2)."""
+    if cfg.layer_kinds:
+        return _init_kinds(cfg, rng)
     if cfg.first_dense_layers:
         (dkey, dcfg), (_, rest) = cfg.segments
         params = init_params(rest, rng)
@@ -418,6 +477,9 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
 
 def param_logical_axes(cfg: TransformerConfig) -> PyTree:
     """Logical axis names per parameter dim (consumed by the sharding policy)."""
+    if cfg.layer_kinds:
+        return _kinds_tree(cfg, lambda shape, axes, how, n: ("layers",) + axes,
+                           lambda shape, axes: axes)
     if cfg.first_dense_layers:
         (dkey, dcfg), (_, rest) = cfg.segments
         axes = param_logical_axes(rest)
@@ -500,6 +562,70 @@ def param_logical_axes(cfg: TransformerConfig) -> PyTree:
         if cfg.lm_head_bias:
             axes["lm_head_b"] = ("vocab",)
     return axes
+
+
+def _kinds_tree(cfg: TransformerConfig, stacked: Callable,
+                top: Callable) -> PyTree:
+    """The parameter tree of a stack of ``layer_kinds``, leaf by leaf:
+    ``stacked(shape, axes, how, n)`` for a leaf of ``n`` stacked layers,
+    ``top(shape, axes)`` for the embedding, the head and the final norm."""
+    from deepspeed_tpu.models.hybrid import mixer_specs
+
+    h, f = cfg.hidden_size, cfg.ffn_size
+
+    def norm(make):
+        p = {"scale": make((h,), ("embed",), "ones")}
+        if cfg.norm == "layernorm":
+            p["bias"] = make((h,), ("embed",), "zeros")
+        return p
+
+    def layer(kind, n):
+        make = lambda shape, axes, how: stacked(shape, axes, how, n)  # noqa
+        lp = jax.tree.map(lambda leaf: make(*leaf), mixer_specs(cfg, kind),
+                          is_leaf=lambda x: isinstance(x, tuple))
+        lp.update(ln1=norm(make), ln2=norm(make),
+                  w_up=make((h, f), ("embed", "mlp"), "std"),
+                  w_down=make((f, h), ("mlp", "embed"), "out"))
+        if cfg.activation == "swiglu":
+            lp["w_gate"] = make((h, f), ("embed", "mlp"), "std")
+        if cfg.use_bias:
+            lp["b_up"] = make((f,), ("mlp",), "zeros")
+            lp["b_down"] = make((h,), ("embed",), "zeros")
+        return lp
+
+    tree = {key: {kind: layer(kind, seg.num_layers) for kind in seg.period}
+            for key, seg in cfg.segments}
+    tree["tok_emb"] = top((cfg.vocab_size, h), ("vocab", "embed"))
+    tree["final_norm"] = norm(lambda shape, axes, how: top(shape, axes))
+    if not cfg.tie_embeddings:
+        tree["lm_head"] = top((h, cfg.vocab_size), ("embed", "vocab"))
+    return tree
+
+
+def _init_kinds(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
+    from deepspeed_tpu.models.hybrid import init_leaf
+
+    if cfg.pos_emb != "none" or cfg.emb_norm or cfg.lm_head_bias \
+            or cfg.qk_norm or cfg.attn_bias_enabled:
+        raise NotImplementedError(
+            "a stack of layer_kinds has no positional encoding, no "
+            "embedding norm, no attention or head bias and no qk-norm")
+    std, out_std = cfg.init_std, cfg.init_std / math.sqrt(2 * cfg.num_layers)
+    count = [0]
+
+    def key():
+        count[0] += 1
+        return jax.random.fold_in(rng, count[0])
+
+    tree = _kinds_tree(
+        cfg,
+        lambda shape, axes, how, n: init_leaf(how, (n,) + shape, key(), std,
+                                              out_std),
+        lambda shape, axes: init_leaf("std", shape, key(), std, out_std))
+    tree["final_norm"] = {
+        k: (jnp.ones if k == "scale" else jnp.zeros)(
+            (cfg.hidden_size,), jnp.float32) for k in tree["final_norm"]}
+    return tree
 
 
 # --------------------------------------------------------------------------- #
@@ -989,6 +1115,11 @@ def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
 
 
 def _require_one_stack(cfg: TransformerConfig, what: str) -> None:
+    if cfg.layer_kinds:
+        raise NotImplementedError(
+            f"{what} runs one homogeneous layer stack; a stack of layer "
+            "kinds (state-space, windowed and shared-cache layers) is "
+            "served by FastGenEngine and run whole by forward()")
     if cfg.first_dense_layers:
         raise NotImplementedError(
             f"{what} runs one homogeneous layer stack; a model with leading "
@@ -1029,6 +1160,15 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     the chunked forward is numerically the single-scan forward. The
     random-LTD path keeps its own first/middle/last split and ignores
     chunking (its stacks are already scan-segmented)."""
+    if cfg.layer_kinds:
+        if pld_keep is not None or random_ltd_idx is not None \
+                or param_sync is not None:
+            raise NotImplementedError(
+                "progressive layer drop, random-LTD and the chunked "
+                "gradient sync assume one homogeneous stack; a stack of "
+                "layer kinds runs without them")
+        return _forward_kinds(params, tokens, cfg,
+                              activation_constraint or (lambda x: x))
     attention_fn = attention_fn or dot_product_attention
     constrain = activation_constraint or (lambda x: x)
     dt = cfg.compute_dtype
@@ -1132,6 +1272,76 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     head = _lm_head_of(params, full_cfg)
     return x, head, aux_total
+
+
+def _forward_kinds(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
+                   constrain: Callable) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``forward_hidden`` of a stack of ``layer_kinds``: the whole
+    sequence at once, no cache. The state-space layers see the batch as
+    ``B * S`` rows in runs of ``S`` from position 0 (``models/hybrid.py``);
+    attention is plain jnp under an explicit mask (the flash kernel has no
+    window), whatever ``attention_fn`` the caller named."""
+    from deepspeed_tpu.models import hybrid as HY
+
+    dt = cfg.compute_dtype
+    B, S = tokens.shape
+    H, N, K, D = cfg.hidden_size, cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    with jax.named_scope("embed"):
+        x = constrain(params["tok_emb"].astype(dt)[tokens])
+    positions = jnp.tile(jnp.arange(S, dtype=jnp.int32), B)
+    runs = HY.runs_of(jnp.repeat(jnp.arange(B, dtype=jnp.int32), S),
+                      positions)
+    conv0 = jnp.zeros((B * S, cfg.ssm_conv - 1, cfg.ssm_inner), dt)
+    ssm0 = jnp.zeros((B * S, cfg.ssm_state, cfg.ssm_inner), jnp.float32)
+
+    def attend(h, lp, kind, layer, shared):
+        q = HY.paired_queries((h @ lp["wq"].astype(dt)).reshape(B * S, N, D))
+        if kind != "cross":
+            shared = tuple(
+                HY.paired_cache((h @ lp[w].astype(dt)).reshape(B, S, K, D))
+                for w in ("wk", "wv"))
+        o = HY.windowed_attention(
+            q.reshape(B, S, N, 2 * D), *shared, D ** -0.5,
+            cfg.attn_window if kind == "window" else 0)
+        o = HY.differential_merge(o.reshape(B * S, N, 2 * D), lp, layer,
+                                  cfg.norm_eps)
+        return o.astype(dt), shared
+
+    def make_body(seg):
+        def body(carry, xs):
+            x, memory, shared = carry
+            lps, step = xs
+            for i, kind in enumerate(seg.period):
+                lp = lps[kind]
+                h = _norm(x, lp["ln1"], seg.norm, seg.norm_eps).reshape(
+                    B * S, H)
+                if kind == "mamba":
+                    with jax.named_scope("ssm"):
+                        mix, memory, _, _ = HY.mamba(h, lp, seg, runs, conv0,
+                                                     ssm0)
+                elif kind == "gmu":
+                    with jax.named_scope("gmu"):
+                        mix = HY.gmu(h, lp, memory)
+                else:
+                    with jax.named_scope("attn"):
+                        mix, shared = attend(
+                            h, lp, kind,
+                            seg.first_dense_layers + 2 * step + i, shared)
+                x = x + (mix @ lp["wo"].astype(dt)).reshape(B, S, H)
+                with jax.named_scope("mlp"):
+                    h2 = _norm(x, lp["ln2"], seg.norm, seg.norm_eps)
+                    x = constrain(x + _ffn(h2, lp, seg)[0])
+            return (x, memory, shared), None
+
+        return _remat_wrap(body, cfg.remat)
+
+    kv = jnp.zeros((B, S, K // 2, 2 * D), dt)
+    carry = (x, jnp.zeros((B * S, cfg.ssm_inner), dt), (kv, kv))
+    for key, seg in cfg.segments:
+        carry, _ = lax.scan(make_body(seg), carry,
+                            (params[key], jnp.arange(seg.num_layers)))
+    x = _norm(carry[0], params["final_norm"], cfg.norm, cfg.norm_eps)
+    return x, _lm_head_of(params, cfg), jnp.float32(0.0)
 
 
 def forward(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,

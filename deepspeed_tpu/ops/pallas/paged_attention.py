@@ -78,8 +78,17 @@ def _blocks_per_fetch(bs: int, row_bytes: int) -> int:
 
 
 def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
-            n_pool: int, scale: float, mxu_dtype):
-    """``refs``: the key pool and, where ``n_pool`` is 2, the value pool
+            n_pool: int, scale: float, mxu_dtype, window: Optional[int],
+            heads_first: bool):
+    """``heads_first``: a pool block is ``[K, bs, D]`` and not ``[bs, K,
+    D]`` (a head count off the sublane tiling, 10 say, cannot be the
+    second-minor dim of a block a copy slices); the fetch slots are then
+    ``[2, K, P, bs, D]``, filled a head-strided copy a block, and are
+    head-major as they lie.
+    ``window``: a row sees its last ``window`` cache positions alone
+    (``limit - window <= c < limit``) and a walk starts at the block of its
+    rows' lowest such position instead of block 0; None: the whole context.
+    ``refs``: the key pool and, where ``n_pool`` is 2, the value pool
     (with one pool the value is the leading columns of the key's block:
     latent attention, whose value is the latent itself), the output, then
     the scratch: a fetch buffer per pool, the semaphores, the queries
@@ -87,8 +96,11 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     pools, o_ref = refs[:n_pool], refs[n_pool]
     bufs = refs[n_pool + 1:2 * n_pool + 1]
     sems, q3_ref, len_ref, m_ref, l_ref, acc_ref = refs[2 * n_pool + 1:]
-    P, bs = bufs[0].shape[1:3]
-    K = bufs[0].shape[3] if bufs[0].ndim == 5 else 1
+    if heads_first:
+        K, P, bs = bufs[0].shape[1:4]
+    else:
+        P, bs = bufs[0].shape[1:3]
+        K = bufs[0].shape[3] if bufs[0].ndim == 5 else 1
     R, N, _ = q_ref.shape
     rep, T = N // K, meta_ref.shape[0] // 2
     M, C = R * rep, P * bs
@@ -127,6 +139,8 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     def head_major(buf, slot):
         """A fetched slot as ``[K, C, D]``."""
         x = buf[slot].astype(mxu_dtype)
+        if heads_first:
+            return x.reshape(K, C, x.shape[-1])
         if buf.ndim == 4:                      # a pool without a head axis
             return x.reshape(1, C, x.shape[-1])
         return jnp.swapaxes(x.reshape(C, K, x.shape[-1]), 0, 1)
@@ -140,6 +154,8 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
             preferred_element_type=jnp.float32) * scale
         col = i * C + jax.lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
         live = col < limit
+        if window is not None:
+            live &= col >= limit - window
         s = jnp.where(live, s, NEG_INF)
         m_prev = m_ref[:, rows, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -157,7 +173,8 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
 
     def fetch(t, nblk, i, slot, start):
         """Start, or wait for, the copies of blocks ``i*P ..`` of row
-        ``t``'s table that lie under ``nblk``."""
+        ``t``'s table that lie under ``nblk`` (``i`` counts fetch steps
+        from block 0, also where a windowed walk starts later)."""
         def page(p, _):
             j = i * P + p
 
@@ -166,7 +183,8 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
                 blk = tables_ref[t, j]
                 for n, (pool, buf) in enumerate(zip(pools, bufs)):
                     copy = pltpu.make_async_copy(
-                        pool.at[blk], buf.at[slot, p], sems.at[n, slot])
+                        pool.at[blk], buf.at[slot, :, p] if heads_first
+                        else buf.at[slot, p], sems.at[n, slot])
                     copy.start() if start else copy.wait()
 
         jax.lax.fori_loop(0, P, page, None)
@@ -180,6 +198,12 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
         row = jax.lax.broadcasted_iota(jnp.int32, (1, M, 1), 1)
         limit = jnp.where((row >= r0 * rep) & (row < r1 * rep),
                           len_ref[:, 0:1][None], 0)         # [1, M, 1]
+
+        # a windowed walk starts at the fetch step that holds the lowest
+        # position any of its rows sees
+        i0 = 0 if window is None else jnp.maximum(jax.lax.fori_loop(
+            r0, r1, lambda r, n: jnp.minimum(n, length(r)),
+            jnp.int32(2 ** 30)) - window, 0) // C
 
         def step(i, _):
             slot = i % 2
@@ -199,8 +223,12 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
                                        length(r0)),
                 lambda: online_softmax(i, kt, vt, slice(None), limit))
 
-        fetch(t, nblk, 0, 0, True)
-        jax.lax.fori_loop(0, pl.cdiv(nblk, P), step, None)
+        if window is None:
+            fetch(t, nblk, 0, 0, True)
+            jax.lax.fori_loop(0, pl.cdiv(nblk, P), step, None)
+        else:
+            fetch(t, nblk, i0, i0 % 2, True)
+            jax.lax.fori_loop(i0, pl.cdiv(nblk, P), step, None)
 
         def put(r, _):
             rows = pl.ds(r * rep, rep)
@@ -221,17 +249,21 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "value_dim", "scale", "name", "mxu_dtype", "interpret"))
+    "value_dim", "scale", "name", "mxu_dtype", "interpret", "window",
+    "heads_first"))
 def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
-           interpret):
+           interpret, window=None, heads_first=False):
     """The kernel over whole tiles: ``pools`` the key pool and the value
     pool, or the key pool alone where the value is the first ``value_dim``
     columns of the key's block. Jitted (inlined into the caller's program)
     for its trace cache alone: the kernel body is traced once per set of
     operand shapes, not once per program that calls it."""
     T, N, _ = q.shape
-    bs = pools[0].shape[1]
-    K = pools[0].shape[2] if pools[0].ndim == 4 else 1
+    if heads_first:
+        K, bs = pools[0].shape[1:3]
+    else:
+        bs = pools[0].shape[1]
+        K = pools[0].shape[2] if pools[0].ndim == 4 else 1
     rep = N // K
     R = tile_rows(N, K)
     P = _blocks_per_fetch(bs, sum(
@@ -245,8 +277,9 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
         + [hbm] * len(pools),
         out_specs=pl.BlockSpec((R, N, value_dim),
                                lambda i, tbl, meta: (i, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((2, P) + x.shape[1:], x.dtype)
-                        for x in pools] + [
+        scratch_shapes=[pltpu.VMEM(
+            (2, K, P) + x.shape[2:] if heads_first else (2, P) + x.shape[1:],
+            x.dtype) for x in pools] + [
             pltpu.SemaphoreType.DMA((len(pools), 2)),
             pltpu.VMEM((K, R * rep, q.shape[2]), mxu_dtype),
             pltpu.VMEM((R * rep, _LANES), jnp.int32),
@@ -263,7 +296,8 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
             dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         functools.partial(_kernel, n_pool=len(pools), scale=scale,
-                          mxu_dtype=mxu_dtype),
+                          mxu_dtype=mxu_dtype, window=window,
+                          heads_first=heads_first),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N, value_dim), q.dtype),
         compiler_params=compiler_params,
@@ -273,7 +307,7 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
 
 
 def _walk(q, pools, tables, lengths, *, kv_heads, value_dim, scale, name,
-          mxu_dtype, interpret):
+          mxu_dtype, interpret, window=None, heads_first=False):
     """Pads the rows to whole tiles, says which rows share a table, and
     runs the kernel: what both entry points below are."""
     if interpret is None:
@@ -294,18 +328,31 @@ def _walk(q, pools, tables, lengths, *, kv_heads, value_dim, scale, name,
     meta = jnp.concatenate([lengths.astype(jnp.int32),
                             same.astype(jnp.int32)])
     return _tiles(tables, meta, q, *pools, value_dim=value_dim, scale=scale,
-                  name=name, mxu_dtype=mxu_dtype, interpret=interpret)[:Tn]
+                  name=name, mxu_dtype=mxu_dtype, interpret=interpret,
+                  window=window, heads_first=heads_first)[:Tn]
 
 
 def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                     tables: jax.Array, lengths: jax.Array,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Drop-in for ``models.paged.paged_attention_reference``."""
-    D, K = q.shape[2], kpool.shape[2]
+                    interpret: Optional[bool] = None, *,
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None,
+                    heads_first: bool = False,
+                    name: str = "paged_attention") -> jax.Array:
+    """Drop-in for ``models.paged.paged_attention_reference``. ``scale``:
+    the scores' factor where it is not ``D ** -0.5``; ``window``: each row
+    attends to its last ``window`` positions alone, and the table may then
+    be a RING (column ``c`` naming the block that holds positions
+    ``c*bs ..`` now: a walk reads columns from the window's start on);
+    ``heads_first``: the pools are ``[NB, K, bs, D]``, which a head count
+    that is no multiple of 8 needs; ``name``: the Mosaic call's name in a
+    device trace."""
+    D, K = q.shape[2], kpool.shape[1 if heads_first else 2]
     assert D == kpool.shape[3] and q.shape[1] % K == 0
     return _walk(q, (kpool, vpool), tables, lengths, kv_heads=K,
-                 value_dim=D, scale=D ** -0.5, name="paged_attention",
-                 mxu_dtype=jnp.float32, interpret=interpret)
+                 value_dim=D, scale=D ** -0.5 if scale is None else scale,
+                 name=name, mxu_dtype=jnp.float32, interpret=interpret,
+                 window=window, heads_first=heads_first)
 
 
 def latent_paged_attention(q: jax.Array, pool: jax.Array,

@@ -87,3 +87,42 @@ def test_latent_paged_attention_compiles_for_v5e(one_chip, shape):
     # by the name and reads the pool at operand 3
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
     assert "%latent_paged_attention" in text
+
+
+# (rows of the tick bucket, table tier, window) of the stack-of-kinds serving
+# cell: differential attention's 40 paired query heads of 128 on 10 paired
+# KV heads, blocks [10, 32, 128] heads first (10 is off the sublane tiling:
+# as a block's second-minor dim Mosaic refuses the copy's slice), a ring of
+# 73 slots x 32 blocks x 8 layers or the one layer's 10,900 blocks
+KINDS_SHAPES = {
+    "phi4flash-window-512x136": (512, 136, 512, 8 * 73 * 32),
+    "phi4flash-window-64x34": (64, 34, 512, 8 * 73 * 32),
+    "phi4flash-shared-512x136": (512, 136, None, 10900),
+    "phi4flash-shared-64x34": (64, 34, None, 10900),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(KINDS_SHAPES))
+def test_paired_head_attention_compiles_for_v5e(one_chip, shape):
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    T, MB, window, rows = KINDS_SHAPES[shape]
+    name = "shared_paged_attention" if window is None \
+        else "window_paged_attention"
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = arg((rows, 10, 32, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, t, n: paged_attention(
+            q, k, v, t, n, interpret=False, scale=0.125, window=window,
+            heads_first=True, name=name)
+    ).lower(arg((T, 40, 128), jnp.bfloat16), pool, pool,
+            arg((T, MB), jnp.int32), arg((T,), jnp.int32)).compile()
+    text = compiled.as_text()
+    # one Mosaic call under its own name, operands (tables, lengths+same,
+    # q, kpool, vpool): benchmarks/roofline/{window,shared}_paged_attention
+    # classify by the name and read the pool at operand 3
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert f"%{name}" in text

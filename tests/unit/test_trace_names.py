@@ -49,9 +49,18 @@ def test_every_pallas_call_is_named():
             if isinstance(node, ast.Call) and \
                     getattr(node.func, "id", "") == "_walk":
                 # paged_attention.py: one call site, named by each of the
-                # kernel's two instantiations
+                # kernel's instantiations; the dense one by its ``name=``
+                # parameter, whose default is the literal (a stack of layer
+                # kinds passes ``window_paged_attention`` /
+                # ``shared_paged_attention`` from models/paged.py)
                 literal.update(k.value.value for k in node.keywords
-                               if k.arg == "name")
+                               if k.arg == "name"
+                               and isinstance(k.value, ast.Constant))
+            if isinstance(node, ast.FunctionDef):
+                literal.update(
+                    d.value for a, d in zip(node.args.kwonlyargs,
+                                            node.args.kw_defaults)
+                    if a.arg == "name" and isinstance(d, ast.Constant))
     assert sites == 12
     assert literal == KERNEL_NAMES
 

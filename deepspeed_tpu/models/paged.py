@@ -247,7 +247,7 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
                 name="shared_paged_attention" if window is None
                 else "window_paged_attention")
 
-        return attend, tile_rows(cfg.num_heads, cfg.kv_heads // HY.PAIR)
+        return attend, tile_rows(cfg.num_heads, HY.PAIR * cfg.head_dim)
     if not use_kernel or cfg.pos_emb == "alibi":
         return (latent_attention_reference if cfg.mla
                 else paged_attention_reference), 0
@@ -255,8 +255,9 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
         latent_paged_attention, paged_attention, tile_rows)
 
     if cfg.mla:
-        return latent_paged_attention, tile_rows(cfg.num_heads, 1)
-    return paged_attention, tile_rows(cfg.num_heads, cfg.kv_heads)
+        return latent_paged_attention, tile_rows(cfg.num_heads,
+                                                 cfg.kv_lora_rank)
+    return paged_attention, tile_rows(cfg.num_heads, cfg.head_dim)
 
 
 _EXPERT_LEAVES = ("w_up", "w_down", "w_gate")

@@ -5,12 +5,12 @@ Parity: reference ``inference/v2/kernels/ragged_ops`` (blocked flash attention
 over the blocked KV cache) — the CUDA tree walks each sequence's block list
 once for all of that sequence's query rows; so does this kernel.
 
-Grid: one step per tile of ``R`` consecutive rows of the flat token batch
-(``tile_rows``: a constant of the head shapes). The pools stay in HBM
-(``memory_space=ANY``); a step splits its tile into RUNS of consecutive rows
-that carry the same block table and walks each run's table ONCE, ``P`` blocks
-at a time, with double-buffered ``make_async_copy`` fetches whose trip count
-is read from the prefetched lengths:
+Grid: one step per tile of ``R`` consecutive rows of the flat token batch.
+The pools stay in HBM (``memory_space=ANY``); a step splits its tile into
+RUNS of consecutive rows that carry the same block table and walks each
+run's table ONCE, ``P`` blocks at a time, with double-buffered
+``make_async_copy`` fetches whose trip count is read from the prefetched
+lengths:
 
 - a run of two or more rows (a prompt chunk of one sequence, which
   ``FastGenEngine._step_impl`` lays out contiguously; the pad rows of a tick)
@@ -18,6 +18,17 @@ is read from the prefetched lengths:
   head, ``[R*rep, D] x [D, P*bs]``, and the per-row causal limit
   ``c < lengths[row]`` — 0 for the tile's rows outside the run — is a mask;
 - a run of one row (a decode row) walks alone, ``[rep, D] x [D, P*bs]``.
+
+``R`` and ``P`` are one rule of the operands' shapes (``tile_rows``,
+``_blocks_per_fetch``). A fetch step has a cost of its own, 0.3 us for a
+decode row and more for a tile, so a tile takes the rows and a step the
+cache positions that 1 MB each of accumulator, scores and fetched bytes
+leave room for; K/V pools, whose blocks a step casts and re-lays before it
+multiplies them, stay at one lane width of positions, where wider steps
+measured no faster. Mistral and Phi-4-mini-flash: 32 rows x 128 positions;
+Pythia 32 x 64; Moonlight's latent pool 32 x 512, which took a mixed tick's
+call from 1,419 to 840 us and a decode tick's from 470 to 314 on the v5e
+(PERF.md section 6, PR 32).
 
 Which rows share a table is DATA: ``same[t] = all(tables[t] == tables[t-1])``
 is computed on the device beside the call and rides in the second scalar
@@ -53,9 +64,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# one fetch step holds at most this many bytes of K plus V blocks (times two
-# slots in VMEM) and at most a lane width of cache positions
+# what one fetch step holds of K plus V blocks (times two slots in VMEM), and
+# what a tile's float32 accumulator and a step's float32 scores may each take
 _FETCH_BYTES = 1024 * 1024
+_TILE_BYTES = 1024 * 1024
 _LANES = 128
 # block tables are widened to a multiple of this many columns (see _tiles)
 _TABLE_COLS = 64
@@ -65,16 +77,50 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def tile_rows(num_heads: int, kv_heads: int) -> int:
-    """Rows of the flat token batch a grid step works on: enough that the
-    ``R * rep`` query rows a KV head meets fill the MXU's 128 rows, held to
-    16-32 so the tile's float32 state stays a few hundred KB of VMEM."""
-    return max(16, min(32, _LANES // (num_heads // kv_heads)))
+def tile_rows(num_heads: int, value_dim: int) -> int:
+    """Rows of the flat token batch a grid step works on: 32, and 16 (the q
+    block's bf16 sublane tile) where the tile's float32 accumulator
+    ``[R, num_heads, value_dim]`` would pass ``_TILE_BYTES``. A chunk's
+    context is fetched and walked once a tile, so a tile takes the rows its
+    state leaves room for. Past 32 the rows compete with the positions of a
+    step for the scores' room (see ``_blocks_per_fetch``), and positions
+    serve decode rows too: on the v5e the latent kernel read 840 us a mixed
+    tick's call at 32 rows x 512 positions, 837 at 64 x 256, 1,419 at
+    16 x 128 (PERF.md section 6, PR 32)."""
+    return max(16, min(32, _TILE_BYTES // (4 * num_heads * value_dim)
+                       // 16 * 16))
 
 
-def _blocks_per_fetch(bs: int, row_bytes: int) -> int:
-    """``row_bytes``: what one cache position holds over every pool."""
-    return max(1, min(_LANES // bs, _FETCH_BYTES // (bs * row_bytes)))
+def _blocks_per_fetch(bs: int, row_bytes: int, query_rows: int,
+                      head_axis: bool) -> int:
+    """Blocks a fetch step copies and multiplies. ``row_bytes``: what one
+    cache position holds over every pool; ``query_rows``: the rows of a
+    step's scores over every KV head (``tile_rows`` x query heads);
+    ``head_axis``: the pools have one (K/V pools), so a step casts what it
+    fetched to float32 and re-lays it head-major before it multiplies.
+
+    A live step costs 0.3 us for a decode row and more for a tile whatever
+    it carries, so it carries what VMEM has room for: as many cache
+    positions as keep the fetch under ``_FETCH_BYTES`` and the float32
+    scores ``[query_rows, positions]`` under ``_TILE_BYTES``, in whole lane
+    widths (the scores' minor dim), or whole blocks where not one lane
+    width fits (Pythia: 64 positions are 1 MB). With a head axis one lane
+    width is all: the cast and the relayout grow with the step and are
+    most of it, so a wider step saves nothing and multiplies more masked
+    positions of a short context (Mistral's shapes at 256 positions a
+    step against 128: 594.2 / 594.0 us a mixed tick's call, 301.8 / 306.9
+    a decode tick's at 600 positions, 222.8 / 194.9 at 300; Phi-4's window
+    walk +5 %). The latent pool's row is multiplied as it lies, 1,280 B a
+    position: 128 positions are 0.2 us of HBM time, and 512 a step read
+    314 against 470 us a decode tick's call, 840 against 1,278 a mixed
+    tick's (PERF.md section 6, PR 32)."""
+    positions = min(_FETCH_BYTES // row_bytes,
+                    _TILE_BYTES // (4 * query_rows))
+    if head_axis:
+        positions = min(positions, _LANES)
+    if positions >= _LANES:
+        positions -= positions % _LANES
+    return max(1, positions // bs)
 
 
 def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
@@ -248,6 +294,21 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     jax.lax.while_loop(lambda r: r < R, next_run, 0)
 
 
+def _geometry(q, pools, value_dim, heads_first):
+    """(KV heads, block size, rows a tile, blocks a fetch step) of a call,
+    from its operands' shapes alone."""
+    if heads_first:
+        K, bs = pools[0].shape[1:3]
+    else:
+        bs = pools[0].shape[1]
+        K = pools[0].shape[2] if pools[0].ndim == 4 else 1
+    N = q.shape[1]
+    R = tile_rows(N, value_dim)
+    return K, bs, R, _blocks_per_fetch(
+        bs, sum(K * x.shape[-1] * x.dtype.itemsize for x in pools), R * N,
+        pools[0].ndim == 4)
+
+
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "value_dim", "scale", "name", "mxu_dtype", "interpret", "window",
     "heads_first"))
@@ -259,15 +320,8 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
     for its trace cache alone: the kernel body is traced once per set of
     operand shapes, not once per program that calls it."""
     T, N, _ = q.shape
-    if heads_first:
-        K, bs = pools[0].shape[1:3]
-    else:
-        bs = pools[0].shape[1]
-        K = pools[0].shape[2] if pools[0].ndim == 4 else 1
+    K, bs, R, P = _geometry(q, pools, value_dim, heads_first)
     rep = N // K
-    R = tile_rows(N, K)
-    P = _blocks_per_fetch(bs, sum(
-        K * x.shape[-1] * x.dtype.itemsize for x in pools))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -306,14 +360,14 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
     )(tables, meta, q, *pools)
 
 
-def _walk(q, pools, tables, lengths, *, kv_heads, value_dim, scale, name,
-          mxu_dtype, interpret, window=None, heads_first=False):
+def _walk(q, pools, tables, lengths, *, value_dim, scale, name, mxu_dtype,
+          interpret, window=None, heads_first=False):
     """Pads the rows to whole tiles, says which rows share a table, and
     runs the kernel: what both entry points below are."""
     if interpret is None:
         interpret = _use_interpret()
     Tn, N, _ = q.shape
-    pad = -Tn % tile_rows(N, kv_heads)
+    pad = -Tn % tile_rows(N, value_dim)
     if pad:                            # pad rows: zero table, length 1
         q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
         tables = jnp.pad(tables, ((0, pad), (0, 0)))
@@ -349,8 +403,8 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     device trace."""
     D, K = q.shape[2], kpool.shape[1 if heads_first else 2]
     assert D == kpool.shape[3] and q.shape[1] % K == 0
-    return _walk(q, (kpool, vpool), tables, lengths, kv_heads=K,
-                 value_dim=D, scale=D ** -0.5 if scale is None else scale,
+    return _walk(q, (kpool, vpool), tables, lengths, value_dim=D,
+                 scale=D ** -0.5 if scale is None else scale,
                  name=name, mxu_dtype=jnp.float32, interpret=interpret,
                  window=window, heads_first=heads_first)
 
@@ -373,7 +427,7 @@ def latent_paged_attention(q: jax.Array, pool: jax.Array,
     MXU-bound, unlike the dense cells' shapes) and accumulate in float32;
     the jnp path rounds its probabilities the same way."""
     assert q.shape[2] == pool.shape[2] and pool.ndim == 3
-    return _walk(q, (pool,), tables, lengths, kv_heads=1,
-                 value_dim=value_dim, scale=float(scale),
+    return _walk(q, (pool,), tables, lengths, value_dim=value_dim,
+                 scale=float(scale),
                  name="latent_paged_attention", mxu_dtype=jnp.bfloat16,
                  interpret=interpret)

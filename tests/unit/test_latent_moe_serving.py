@@ -21,7 +21,8 @@ from benchmarks.reference import deepseek_lm as R
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.hf_import import config_from_hf
-from deepspeed_tpu.ops.pallas.paged_attention import (latent_paged_attention,
+from deepspeed_tpu.ops.pallas.paged_attention import (_geometry,
+                                                      latent_paged_attention,
                                                       paged_attention,
                                                       tile_rows)
 
@@ -321,18 +322,21 @@ def test_a_capacity_would_have_dropped_what_the_tick_keeps(toy):
 # ------------------------------------------------------------------ #
 # the latent kernel, interpreted, against the jnp path
 # ------------------------------------------------------------------ #
-def _latent_case(lengths_and_tables, cfg, seed=0):
+def _latent_case(lengths_and_tables, cfg, bs=BS, table_blocks=6, seed=0):
     """Random pool and queries; rows as (length, table id) pairs, table id
-    0 the pad rows' all-zero table."""
+    0 the pad rows' all-zero table; a table names ``table_blocks`` blocks
+    of ``bs`` positions."""
     rng = np.random.default_rng(seed)
-    W, NB = PG.latent_row_width(cfg), 40
+    W = PG.latent_row_width(cfg)
     used = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-    pool = np.zeros((NB, BS, W), np.float32)
-    pool[:, :, :used] = rng.normal(size=(NB, BS, used))
     n_tab = max(t for _, t in lengths_and_tables)
-    tabs = np.zeros((n_tab + 1, 16), np.int32)
+    NB = max(40, n_tab * table_blocks // 2)       # tables share blocks
+    pool = np.zeros((NB, bs, W), np.float32)
+    pool[:, :, :used] = rng.normal(size=(NB, bs, used))
+    tabs = np.zeros((n_tab + 1, -(-table_blocks // 16) * 16), np.int32)
     for t in range(1, n_tab + 1):
-        tabs[t, :6] = rng.permutation(np.arange(1, NB))[:6]
+        tabs[t, :table_blocks] = rng.permutation(
+            np.arange(1, NB))[:table_blocks]
     Tn = len(lengths_and_tables)
     q = rng.normal(size=(Tn, cfg.num_heads,
                          cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
@@ -345,28 +349,52 @@ def _latent_case(lengths_and_tables, cfg, seed=0):
             jnp.asarray(w_kv_b, jnp.bfloat16))
 
 
+# the published widths, blocks of 32: 16 heads on one 640-wide row, where a
+# fetch step is C = 512 positions (a toy row's step holds a toy table whole)
+C = 512
 LATENT_CASES = {
     # decode rows, each its own table: runs of one row
     "runs-of-one": [(n, i + 1) for i, n in enumerate(
         [1, 8, 9, 33, 40, 47, 17, 25])],
-    # a chunk of 40 rows of one sequence across tiles (toy tiles hold 32
-    # rows), after three decode rows
+    # a chunk of 40 rows of one sequence across tiles (tiles hold 32 rows),
+    # after three decode rows
     "chunk-across-two-tiles": [(30, 1), (12, 2), (44, 3)] + [
         (n, 4) for n in range(3, 43)],
     # a short chunk, then pad rows (zero table, length 1) to the bucket
     "pad-rows": [(n, 1) for n in range(5, 15)] + [(1, 0)] * 22,
+    # walks that end a position before, on and after a step's edge and in a
+    # third step
+    "published-runs-of-one-at-the-steps-edges": [(n, i + 1) for i, n in
+        enumerate([1, C - 1, C, C + 1, 2 * C + 17, 32, C + 32])],
+    # a chunk crossing a tile boundary and a step's edge, its first rows in
+    # one tile with three decode rows; then a run of pad rows
+    "published-chunk-across-a-tile-and-a-steps-edge": [
+        (C + 1, 1), (1, 2), (2 * C + 17, 3)] + [
+        (n, 4) for n in range(C - 20, C + 25)] + [(1, 0)] * 16,
 }
 
 
 @pytest.mark.parametrize("case", sorted(LATENT_CASES))
 def test_latent_kernel_matches_the_jnp_path(toy, case):
-    cfg, _, _ = toy
-    q, pool, tables, lengths, w_kv_b = _latent_case(LATENT_CASES[case], cfg)
+    published = case.startswith("published")
+    cfg = model_config.build(_config_file(), "serve") if published else toy[0]
+    q, pool, tables, lengths, w_kv_b = _latent_case(
+        LATENT_CASES[case], cfg,
+        **(dict(bs=32, table_blocks=-(-(2 * C + 17) // 32)) if published
+           else {}))
+    if published:
+        q_row = jax.ShapeDtypeStruct(
+            (q.shape[0], cfg.num_heads, pool.shape[2]), pool.dtype)
+        assert 32 * _geometry(q_row, (pool,), cfg.kv_lora_rank,
+                              False)[3] == C
     want = PG.paged_mla_attention_reference(q, pool, tables, lengths, w_kv_b,
                                             cfg)
+    # the kernel is handed the widest tier's table: the columns past a
+    # walk's last block are never read
+    wide = jnp.pad(tables, ((0, 0), (0, 256 - tables.shape[1])))
     got = PG._absorbed(
         q, w_kv_b, cfg, lambda q_row: latent_paged_attention(
-            q_row, pool, tables, lengths, cfg.kv_lora_rank,
+            q_row, pool, wide, lengths, cfg.kv_lora_rank,
             PG.mla_softmax_scale(cfg), interpret=True))
     real = np.asarray(tables)[:, 0] > 0
     assert _rel(got[real].astype(jnp.float32),
@@ -381,7 +409,7 @@ def test_latent_kernel_is_the_dense_kernels_walk():
 
     assert K.paged_attention.__code__.co_names.count("_walk") == 1
     assert K.latent_paged_attention.__code__.co_names.count("_walk") == 1
-    assert tile_rows(16, 1) == 16      # 16 query heads on one KV head
+    assert tile_rows(16, 512) == 32    # 16 query heads on one 512-wide value
 
 
 # ------------------------------------------------------------------ #
@@ -396,7 +424,7 @@ def test_fastgen_serves_it_and_reports_the_experts_load(toy):
     eng = FastGenEngine(cfg, params, n_blocks=96, block_size=BS,
                         max_blocks_per_seq=MB, token_budget=32,
                         use_pallas_kernel=True, seed=0)
-    assert eng._tile_rows == tile_rows(cfg.num_heads, 1)
+    assert eng._tile_rows == tile_rows(cfg.num_heads, cfg.kv_lora_rank)
     assert eng._expert_layers == 2 and set(eng.pool) == {"latent"}
     fe = ServingFrontend(eng)
     hist = telemetry.histogram("fastgen_expert_load_imbalance")

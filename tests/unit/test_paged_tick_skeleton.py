@@ -207,10 +207,10 @@ CHOICES = {
     "kernels-off-latent": (
         dict(DENSE, **LATENT, num_kv_heads=None), False,
         PG.latent_attention_reference, 0),
-    "dense-kernel": (DENSE, True, paged_attention, tile_rows(4, 2)),
+    "dense-kernel": (DENSE, True, paged_attention, tile_rows(4, H // 4)),
     "latent-kernel": (
         dict(DENSE, **LATENT, num_kv_heads=None), True,
-        latent_paged_attention, tile_rows(4, 1)),
+        latent_paged_attention, tile_rows(4, LATENT["kv_lora_rank"])),
 }
 
 

@@ -346,6 +346,20 @@ class FastGenEngine:
             "over the expert layers (1 = even routing)",
             buckets=(1.0, 1.25, 1.5, 1.75, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0,
                      12.0, 16.0, 32.0, 64.0))
+        self._tm_expert_pairs = telemetry.counter(
+            "fastgen_expert_pairs_total",
+            "(row, expert) pairs the routers of step() ticks chose over "
+            "the ticks' real rows and the expert layers, by whether the "
+            "expert is held here (held=no: a share of the experts, "
+            "TransformerConfig.moe_router_experts; the pair adds nothing "
+            "and costs no row)")
+        self._tm_held_rows = telemetry.histogram(
+            "fastgen_held_expert_rows",
+            "step() ticks of an expert model, by tick bucket: mean rows a "
+            "held expert got (pairs on held experts over the experts held, "
+            "a layer)",
+            buckets=(0.25, 0.5, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0,
+                     128.0, 256.0, 512.0))
         self._tm_preempt = telemetry.counter(
             "fastgen_preemptions_total",
             "sequences deferred a tick by KV-pool backpressure")
@@ -1164,19 +1178,31 @@ class FastGenEngine:
                 sampled = np.asarray(sampled)
             expert_rows, commit_attrs = None, None
             if self._expert_layers:
-                expert_rows = sampled[Tn:].reshape(self._expert_layers, -1)
+                all_rows = sampled[Tn:].reshape(self._expert_layers, -1)
+                # the experts held here (all of them, but for a share of
+                # the layer): the rows that were computed
+                lo = self.cfg.moe_first_expert
+                expert_rows = all_rows[:, lo:lo + self.cfg.n_experts]
+                pairs, pairs_held = int(all_rows.sum()), \
+                    int(expert_rows.sum())
                 tick_span.note(
                     experts_max_rows=int(expert_rows.max()),
                     experts_mean_rows=float(expert_rows.mean()))
                 # the tick's own annotation was written at entry: what
                 # came back with the tokens rides on the span that follows.
-                # Experts with a row, summed over the expert layers: times
-                # an expert's bytes, the weights this tick had to read
+                # Held experts with a row, summed over the expert layers:
+                # times an expert's bytes, the weights this tick had to
+                # read; the pairs on held experts: the rows it multiplied
                 commit_attrs = {
                     "tick": self._ticks_run,
-                    "experts_active": int((expert_rows > 0).sum())}
+                    "experts_active": int((expert_rows > 0).sum()),
+                    "expert_pairs": pairs, "expert_pairs_held": pairs_held}
         with telemetry.span("tick_commit", attrs=commit_attrs):
             if expert_rows is not None:
+                self._tm_expert_pairs.inc(pairs_held, held="yes")
+                self._tm_expert_pairs.inc(pairs - pairs_held, held="no")
+                self._tm_held_rows.observe(
+                    pairs_held / expert_rows.size, bucket=str(Tn))
                 self._tm_expert_imbalance.observe(
                     float(expert_rows.max(axis=1).sum())
                     / max(float(expert_rows.mean(axis=1).sum()), 1e-9),

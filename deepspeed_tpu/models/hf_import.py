@@ -260,12 +260,14 @@ def _llama_attn_blocks(sd: Dict[str, Any], cfg: TransformerConfig,
     return blocks, params
 
 
-def _qwen_moe_experts(sd: Dict[str, Any], moe_fmt: str, L, E: int):
-    """Stack per-expert gate/up/down ModuleList weights → [L, E, in, out]."""
+def _qwen_moe_experts(sd: Dict[str, Any], moe_fmt: str, L, E: int,
+                      first: int = 0):
+    """Stack per-expert gate/up/down ModuleList weights → [L, E, in, out]
+    (the ``E`` experts from ``first``: a share of the checkpoint's)."""
     def experts(wname):
         return np.stack([
             np.stack([_np(sd[moe_fmt.format(i) + f"experts.{e}.{wname}.weight"]).T
-                      for e in range(E)])
+                      for e in range(first, first + E)])
             for i in _layers(L)])
 
     return {"w_gate": experts("gate_proj"), "w_up": experts("up_proj"),
@@ -974,7 +976,128 @@ def params_from_phi4flash(sd, cfg):
         "the published shape); the checkpoint's tensor names are not mapped")
 
 
+# --------------------------------------------------------------------------- #
+# AFMoE (Arcee Trinity: window and full attention layers over expert layers)
+# --------------------------------------------------------------------------- #
+
+def config_from_afmoe(hf_config) -> TransformerConfig:
+    """``model_type`` ``afmoe``: grouped-query attention with per-head q/k
+    RMSNorm and an elementwise sigmoid output gate, under four RMSNorms a
+    layer (before and after attention, before and after the FFN);
+    ``layer_types`` says which layers see ``sliding_window`` positions
+    (rotary applied) and which every one (no rotary at all); the first
+    ``num_dense_layers`` layers have a dense SwiGLU FFN, the others
+    ``num_experts`` routed experts beside ``num_shared_experts`` shared
+    ones, under a sigmoid router with a selection bias; ``mup_enabled``
+    multiplies the embedding by ``sqrt(hidden_size)``.
+
+    A SHARE of the expert layers (``TransformerConfig.moe_router_experts``):
+    ``num_experts`` is then the experts held, ``router_experts`` the
+    router's width (the published count) and ``first_expert`` the first
+    one held; without ``router_experts`` every expert is held."""
+    kinds = tuple({"sliding_attention": "window", "full_attention": "full"}[t]
+                  for t in hf_config.layer_types)
+    L = hf_config.num_hidden_layers
+    if len(kinds) != L:
+        raise ValueError(f"afmoe: layer_types names {len(kinds)} layers of "
+                         f"num_hidden_layers={L}")
+    if int(getattr(hf_config, "n_group", 1) or 1) != 1 \
+            or getattr(hf_config, "score_func", "sigmoid") != "sigmoid" \
+            or getattr(hf_config, "rope_scaling", None):
+        raise NotImplementedError(
+            "afmoe: one routing group, sigmoid scores and unscaled rotary "
+            "are what is written")
+    held = hf_config.num_experts
+    router = int(getattr(hf_config, "router_experts", held))
+    h = hf_config.hidden_size
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size, hidden_size=h, num_layers=L,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        attn_head_dim=int(getattr(hf_config, "head_dim",
+                                  h // hf_config.num_attention_heads)),
+        ffn_hidden_size=hf_config.intermediate_size,
+        max_seq_len=hf_config.max_position_embeddings,
+        pos_emb="rope", norm="rmsnorm", activation="swiglu", use_bias=False,
+        tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
+        rope_theta=float(getattr(hf_config, "rope_theta", 10000.0)),
+        norm_eps=hf_config.rms_norm_eps, dtype="float32",
+        qk_norm=True, post_norms=True, attn_gate=True,
+        full_layers_rope=False,
+        emb_multiplier=float(h) ** 0.5 if getattr(
+            hf_config, "mup_enabled", False) else 1.0,
+        layer_kinds=kinds, attn_window=int(hf_config.sliding_window),
+        n_experts=held, moe_top_k=hf_config.num_experts_per_tok,
+        moe_ffn_size=hf_config.moe_intermediate_size,
+        moe_shared_size=int(getattr(hf_config, "num_shared_experts", 0) or 0)
+        * hf_config.moe_intermediate_size,
+        moe_score_func="sigmoid",
+        moe_route_norm=bool(getattr(hf_config, "route_norm", True)),
+        moe_route_scale=float(getattr(hf_config, "route_scale", 1.0)),
+        moe_gate_bias=True, moe_dispatch="ragged",
+        moe_router_experts=router if router != held else 0,
+        moe_first_expert=int(getattr(hf_config, "first_expert", 0)),
+        first_dense_layers=min(int(hf_config.num_dense_layers), L))
+
+
+def params_from_afmoe(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
+    """The family's tensor names (``modeling_afmoe.py``): four norms a
+    layer, ``self_attn.{q,k,v,o}_proj`` + ``gate_proj`` (the output gate) +
+    ``{q,k}_norm``; ``mlp.{gate,up,down}_proj`` in a dense layer;
+    ``mlp.router.gate``, ``mlp.expert_bias``, ``mlp.experts.<e>`` and
+    ``mlp.shared_experts`` in an expert layer. A share of the experts
+    takes its own from the checkpoint's."""
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lyr = pre + "layers.{}."
+
+    def stack_of(layers: range, experts: bool) -> PyTree:
+        L = layers
+        blocks = {name: {"scale": _stack(sd, lyr + theirs + ".weight", L)}
+                  for name, theirs in (
+                      ("ln1", "input_layernorm"),
+                      ("ln1_post", "post_attention_layernorm"),
+                      ("ln2", "pre_mlp_layernorm"),
+                      ("ln2_post", "post_mlp_layernorm"))}
+        for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                             ("wv", "v_proj"), ("wo", "o_proj"),
+                             ("wg", "gate_proj")):
+            blocks[ours] = _stack(sd, lyr + f"self_attn.{theirs}.weight", L,
+                                  transpose=True)
+        blocks["q_norm"] = _stack(sd, lyr + "self_attn.q_norm.weight", L)
+        blocks["k_norm"] = _stack(sd, lyr + "self_attn.k_norm.weight", L)
+        mlp = lyr + "mlp."
+        if not experts:
+            for ours, theirs in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                                 ("w_down", "down_proj")):
+                blocks[ours] = _stack(sd, mlp + theirs + ".weight", L,
+                                      transpose=True)
+            return blocks
+        blocks["gate_w"] = _stack(sd, mlp + "router.gate.weight", L,
+                                  transpose=True)
+        blocks["gate_bias"] = _stack(sd, mlp + "expert_bias", L)
+        for ours, theirs in (("sw_gate", "gate_proj"), ("sw_up", "up_proj"),
+                             ("sw_down", "down_proj")):
+            blocks[ours] = _stack(sd, mlp + f"shared_experts.{theirs}.weight",
+                                  L, transpose=True)
+        blocks.update(_qwen_moe_experts(sd, mlp, L, cfg.n_experts,
+                                        cfg.moe_first_expert))
+        return blocks
+
+    d = cfg.first_dense_layers
+    params = {
+        "tok_emb": _np(sd[pre + "embed_tokens.weight"]),
+        "blocks": stack_of(range(d, cfg.num_layers), True),
+        "final_norm": {"scale": _np(sd[pre + "norm.weight"])},
+    }
+    if d:
+        params["dense_blocks"] = stack_of(range(d), False)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _np(sd["lm_head.weight"]).T
+    return params
+
+
 _ARCH_TABLE = {
+    "afmoe": (config_from_afmoe, params_from_afmoe),
     "phi4flash": (config_from_phi4flash, params_from_phi4flash),
     "gpt2": (config_from_gpt2, params_from_gpt2),
     "llama": (config_from_llama, params_from_llama),

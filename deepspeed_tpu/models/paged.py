@@ -65,7 +65,11 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
     slot 0 is the pad rows' trash, as block 0 is.
 
     A homogeneous attention stack has the block pool alone, for every
-    layer. A stack of ``layer_kinds`` (``models/hybrid.py``) has the block
+    layer. ``window`` and ``full`` layers of the standard block in one
+    stack (``cfg.standard_blocks``) have a block range for each ``full``
+    layer and rings ``{"wk", "wv"}`` for the ``window`` layers, blocks
+    ``[bs, K, D]`` as the homogeneous stack's. A stack of ``layer_kinds``
+    with mixers of its own (``models/hybrid.py``) has the block
     pool for its ONE ``full`` layer (the ``cross`` layers read it), rings
     ``{"wk", "wv"}`` for its ``window`` layers and state for its ``mamba``
     layers: ``{"conv"}`` the convolution's last inputs, ``{"ssm"}`` the
@@ -83,6 +87,26 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
     kernel read each position once."""
     dt = dtype or cfg.compute_dtype
     L = cfg.num_layers
+    if cfg.standard_blocks:
+        # ordinary grouped-query blocks ``[bs, K, D]``: a block range for
+        # EVERY full layer, a ring per sequence slot for every window layer
+        # (``[layers, slots + 1, RB, bs, K, D]``: slots and ring length are
+        # read off the array), nothing for a kind the stack lacks
+        if state_slots < 1:
+            raise ValueError("window and full layers in one stack keep "
+                             "rings per sequence: state_slots >= 1 "
+                             f"(got {state_slots})")
+        kinds, block = cfg.layer_kinds, (block_size, cfg.kv_heads,
+                                         cfg.head_dim)
+        pool = {}
+        if "full" in kinds:
+            full = (kinds.count("full"), n_blocks) + block
+            pool.update(k=jnp.zeros(full, dt), v=jnp.zeros(full, dt))
+        if "window" in kinds:
+            ring = (kinds.count("window"), state_slots + 1,
+                    ring_blocks(cfg, block_size, max_run)) + block
+            pool.update(wk=jnp.zeros(ring, dt), wv=jnp.zeros(ring, dt))
+        return pool
     if cfg.layer_kinds:
         kinds = cfg.layer_kinds
         if kinds.count("full") != 1 or state_slots < 1:
@@ -213,8 +237,20 @@ def paged_mla_attention_reference(q: jax.Array, pool: jax.Array,
         mla_softmax_scale(cfg)))
 
 
+def span_attention_reference(q: jax.Array, kpool: jax.Array,
+                             vpool: jax.Array, tables: jax.Array,
+                             lengths: jax.Array, window: Optional[int],
+                             row_table: jax.Array) -> jax.Array:
+    """:func:`paged_attention_reference` given one table a sequence slot
+    and each row's slot, as the kernel of a stack of window and full
+    layers is (``paged_attention(row_table=)``)."""
+    return paged_attention_reference(q, kpool, vpool, tables[row_table],
+                                     lengths, window=window)
+
+
 #: what ``forward_paged(attention_fn=)`` reads as "no kernel"
-_REFERENCES = (None, paged_attention_reference, latent_attention_reference)
+_REFERENCES = (None, paged_attention_reference, latent_attention_reference,
+               span_attention_reference)
 
 
 def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
@@ -232,7 +268,31 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
     scale=, window=, heads_first=)`` over paired heads
     (``hybrid.paired_queries``), the dense kernel under the names ``window_paged_attention`` (a ring, ``window``
     positions) and ``shared_paged_attention`` (``window`` None: the one
-    block pool, read by the ``full`` layer and every ``cross`` layer)."""
+    block pool, read by the ``full`` layer and every ``cross`` layer);
+    ``window`` and ``full`` layers of the standard block
+    (``cfg.standard_blocks``): ``fn(q, kpool, vpool, tables, lengths,
+    window=, row_table=)`` with one table a sequence slot and each row's
+    slot, the dense kernel under the names ``swa_attention`` (a
+    ring) and ``global_attention`` (a full layer's own block range)."""
+    if cfg.standard_blocks:
+        if not use_kernel:
+            return span_attention_reference, 0
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            paged_attention, tile_rows)
+
+        def attend(q, kpool, vpool, tables, lengths, window, row_table):
+            # a chunk of the token budget against thousands of positions
+            # is MXU-bound, unlike the homogeneous cells' shapes: the
+            # products take the operands in the model's own type (bfloat16
+            # as served, as the latent instantiation's) and accumulate in
+            # float32
+            return paged_attention(
+                q, kpool, vpool, tables, lengths, window=window,
+                mxu_dtype=q.dtype, row_table=row_table,
+                name="global_attention" if window is None
+                else "swa_attention")
+
+        return attend, tile_rows(cfg.num_heads, cfg.head_dim)
     if cfg.layer_kinds:
         if not use_kernel:
             return paged_attention_reference, 0
@@ -303,6 +363,32 @@ class _Rows(NamedTuple):
 # block range (which starts at block ``base``), attend, and return the
 # attention output before ``wo`` with the new carry.
 
+def _project_qkv(cfg: T.TransformerConfig, h: jax.Array,
+                 lp: Dict[str, jax.Array], positions: jax.Array,
+                 rope: Optional[Tuple[jax.Array, jax.Array]]
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A layer's queries, keys and values from its normed rows ``h [T, H]``:
+    the projections with their biases, ``qk_norm``, and rotary at the rows'
+    positions where ``rope`` (the cos and sin tables) is given."""
+    dt = cfg.compute_dtype
+
+    def proj(name, heads):
+        out = h @ lp[f"w{name}"].astype(dt)
+        if cfg.attn_bias_enabled:
+            out = out + lp[f"b{name}"].astype(dt)
+        return out.reshape(h.shape[0], heads, cfg.head_dim)
+
+    q, k, v = (proj("q", cfg.num_heads), proj("k", cfg.kv_heads),
+               proj("v", cfg.kv_heads))
+    if cfg.qk_norm:
+        q = T._head_rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+        k = T._head_rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+    if rope is not None:
+        q = T.apply_rope_at(q[None], *rope, positions[None])[0]
+        k = T.apply_rope_at(k[None], *rope, positions[None])[0]
+    return q, k, v
+
+
 def _dense_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
                  rows: _Rows, attend: Callable) -> Callable:
     """Per-head K/V pools ``{"k", "v"}`` [L, NB, bs, K, D]: q/k/v
@@ -321,22 +407,9 @@ def _dense_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
         bias["alibi"] = T.alibi_slopes(cfg.num_heads) * cfg.alibi_bias_scale
 
     def layer(h, lp, flat, base):
-        def proj(name, shape):
-            w = lp[f"w{name}"].astype(dt)
-            out = h @ w
-            if cfg.attn_bias_enabled:
-                out = out + lp[f"b{name}"].astype(dt)
-            return out.reshape(shape)
-
-        q = proj("q", (Tn, cfg.num_heads, cfg.head_dim))
-        k = proj("k", (Tn, cfg.kv_heads, cfg.head_dim))
-        v = proj("v", (Tn, cfg.kv_heads, cfg.head_dim))
-        if cfg.qk_norm:
-            q = T._head_rmsnorm(q, lp["q_norm"], cfg.norm_eps)
-            k = T._head_rmsnorm(k, lp["k_norm"], cfg.norm_eps)
-        if cfg.pos_emb == "rope":
-            q = T.apply_rope_at(q[None], cos_t, sin_t, rows.positions[None])[0]
-            k = T.apply_rope_at(k[None], cos_t, sin_t, rows.positions[None])[0]
+        q, k, v = _project_qkv(cfg, h, lp, rows.positions,
+                               (cos_t, sin_t) if cfg.pos_emb == "rope"
+                               else None)
         # blocked KV write (reference ragged_ops KV-copy kernels): token t →
         # pool[base + block_idx[t], offsets[t]]. Pad tokens hit this layer's
         # trash block (block 0 of its range — never allocated).
@@ -385,6 +458,76 @@ def _latent_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
             cfg.kv_lora_rank, mla_softmax_scale(cfg)))
         return (attn.reshape(Tn, cfg.num_heads * cfg.v_head_dim),
                 {"latent": plat})
+
+    return layer
+
+
+def pool_block(pool: Dict[str, jax.Array]) -> int:
+    """Positions of a block ``[bs, K, D]`` of a standard-block pool."""
+    return next(iter(pool.values())).shape[-3]
+
+
+def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
+                rows: _Rows, attend: Callable) -> Callable:
+    """The caches of ``window`` and ``full`` layers of the standard block
+    (``cfg.standard_blocks``; ``init_paged_kv``): a layer's attention as
+    ``layer(kind, h, lp, flat, nth) -> (attn [T, N*D], flat)``, ``nth`` the
+    layer's index among the layers of its KIND (which ring, which block
+    range). Projections as :func:`_dense_cache`'s (``qk_norm``; rotary at
+    the rows' positions, on ``full`` layers only where the config says
+    so), then the elementwise output gate where the model has one.
+
+    A ``full`` layer writes and walks its own block range through the
+    rows' tables. A ``window`` layer's cache is a ring of its sequence's
+    slot (the table's first block): a tick's rows are written before any
+    attends, so a row walks the ring through a table of its own, column
+    ``c`` naming the slot's block ``c % RB``, which holds positions
+    ``c*bs ..`` if any of them is inside the row's window."""
+    dt = cfg.compute_dtype
+    Tn, MB = rows.tables.shape
+    cos_t = sin_t = None
+    if cfg.pos_emb == "rope":
+        # a position lies within the tables' reach
+        cos_t, sin_t = T.rope_table(MB * pool_block(pool), cfg.rope_dim,
+                                    cfg.rope_theta, cfg.rope_scaling_dict)
+    # the kernel is given one table a sequence SLOT and each row's slot (a
+    # budget of thousands of rows, each with a table of hundreds of blocks
+    # of its own, has no room in scalar memory); a stack without window
+    # layers has no slots: a table a row
+    slot, by_slot = jnp.arange(Tn, dtype=jnp.int32), rows.tables
+    if "wk" in pool:
+        S1, RB, bs = pool["wk"].shape[1:4]
+        slot = rows.tables[:, 0]
+        by_slot = jnp.zeros((S1, MB), jnp.int32).at[slot].set(rows.tables)
+        ring_by_slot = (jnp.arange(S1, dtype=jnp.int32) * RB)[:, None] + (
+            jnp.arange(MB, dtype=jnp.int32) % RB)[None, :]
+        ring_block = slot * RB + (rows.positions // bs) % RB
+    NB = pool["k"].shape[1] if "k" in pool else 0
+
+    def layer(kind, h, lp, flat, nth):
+        q, k, v = _project_qkv(
+            cfg, h, lp, rows.positions,
+            (cos_t, sin_t) if cfg.pos_emb == "rope" and (
+                kind == "window" or cfg.full_layers_rope) else None)
+        if kind == "window":
+            names, base = ("wk", "wv"), nth * (S1 * RB)
+            at, tables, window = base + ring_block, ring_by_slot + base, \
+                cfg.attn_window
+        else:
+            names, base = ("k", "v"), nth * NB
+            at, tables, window = base + rows.block_idx, by_slot + base, None
+        new = dict(flat)
+        for name, x in zip(names, (k, v)):
+            new[name] = flat[name].at[at, rows.offsets].set(
+                x.astype(flat[name].dtype), mode="drop")
+        # the scope a device trace tells the two kinds' attention by
+        with jax.named_scope("swa" if kind == "window" else "global"):
+            attn = attend(q, new[names[0]], new[names[1]], tables,
+                          rows.lengths, window=window, row_table=slot)
+        attn = attn.reshape(Tn, cfg.num_heads * cfg.head_dim)
+        if cfg.attn_gate:
+            attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
+        return attn, new
 
     return layer
 
@@ -505,12 +648,17 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
 
     attend, _ = tick_attention(cfg, attention_fn not in _REFERENCES)
     dt = cfg.compute_dtype
-    NB, bs = pool["latent" if cfg.mla else "k"].shape[1:3]
-    if cfg.layer_kinds:
+    if cfg.standard_blocks:
+        NB, bs = 0, pool_block(pool)
+    else:
+        NB, bs = pool["latent" if cfg.mla else "k"].shape[1:3]
+    if cfg.layer_kinds and not cfg.standard_blocks:
         bs = pool["k"].shape[3]             # its blocks are [K/2, bs, 2 D]
 
     with jax.named_scope("embed"):
         x = params["tok_emb"].astype(dt)[tokens]             # [T, H]
+        if cfg.emb_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.emb_multiplier, dt)
         if cfg.pos_emb == "learned":
             x = x + params["pos_emb"].astype(dt)[positions]
         if cfg.emb_norm:
@@ -521,7 +669,8 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
                      tables, (positions // bs)[:, None], axis=1)[:, 0],
                  offsets=positions % bs, lengths=positions + 1)
     valid = tables[:, 0] > 0     # a pad row's table is all trash block
-    attention = (_kinds_cache if cfg.layer_kinds else _latent_cache
+    attention = (_span_cache if cfg.standard_blocks else _kinds_cache
+                 if cfg.layer_kinds else _latent_cache
                  if cfg.mla else _dense_cache)(cfg, pool, rows, attend)
 
     def make_body(seg: T.TransformerConfig, first: int, stack):
@@ -577,6 +726,56 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
 
         return body
 
+    def blocks_body_of(seg: T.TransformerConfig, first: int, stack,
+                       before: Dict[str, int]):
+        """A step of a segment of standard blocks under ``layer_kinds``
+        (``T.scan_periods``): a period's layers, each the sandwich or the
+        plain sequential block around the attention of its kind and a
+        dense FFN or the experts. ``before``: the layers of each kind
+        ahead of the segment (a layer's ring or block range is its index
+        among its kind's)."""
+        def body_of(period, run_first):
+            per = {k: period.count(k) for k in set(period)}
+            ahead = {k: before.get(k, 0)
+                     + seg.layer_kinds[:run_first].count(k) for k in per}
+
+            def body(carry, lps):
+                x, flat, li = carry
+                step = (li - first - run_first) // len(period)
+                n_rows = []
+                for i, kind in enumerate(period):
+                    lp = dequant_params(
+                        jax.tree.map(lambda a: a[i], lps), dt)
+                    with jax.named_scope("attn"):
+                        h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
+                        attn, flat = attention(
+                            kind, h, lp, flat, ahead[kind]
+                            + step * per[kind] + period[:i].count(kind))
+                        attn_out = attn @ lp["wo"].astype(dt)
+                        if seg.post_norms:
+                            attn_out = T._norm(attn_out, lp["ln1_post"],
+                                               seg.norm, seg.norm_eps)
+                        x = x + attn_out
+                    with contextlib.nullcontext() if seg.n_experts \
+                            else jax.named_scope("mlp"):
+                        h2 = T._norm(x, lp["ln2"], seg.norm, seg.norm_eps)
+                        if seg.n_experts:
+                            down, rows_e = _tick_experts(
+                                h2, lp, seg, valid, stack, li + i - first)
+                            n_rows.append(rows_e)
+                        else:
+                            down = T._ffn(h2, lp, seg)[0]
+                        if seg.post_norms:
+                            down = T._norm(down, lp["ln2_post"], seg.norm,
+                                           seg.norm_eps)
+                        x = x + down
+                return (x, flat, li + len(period)), \
+                    jnp.stack(n_rows) if n_rows else None
+
+            return body
+
+        return body_of
+
     # The pool rides the layer scans as a FLAT [L*NB, bs, ...] carry that is
     # scattered in place (layer l owns block range [l*NB, (l+1)*NB)); the
     # attention kernel gathers through layer-offset tables, reading only the
@@ -584,12 +783,15 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     # layout) re-stacks the ENTIRE pool every call — measured 25 ms/tick at
     # 512 blocks inside a decode scan, linear in pool size — where the
     # in-place carry touches only the written rows.
-    carry = (x, {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()},
-             jnp.int32(0))
-    if cfg.layer_kinds:
+    # (of standard blocks under kinds every leaf ends in a block [bs, K, D])
+    carry = (x, {k: v.reshape((-1,) + (v.shape[-3:] if cfg.standard_blocks
+                                       else v.shape[2:]))
+                 for k, v in pool.items()}, jnp.int32(0))
+    if cfg.layer_kinds and not cfg.standard_blocks:
         carry += (jnp.zeros((x.shape[0], cfg.ssm_inner), dt),)
     stats = {}
     first = 0
+    before: Dict[str, int] = {}
     for key, seg in cfg.segments:
         if seg.period:
             carry, _ = lax.scan(make_period_body(seg), carry, params[key])
@@ -602,6 +804,18 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
         stack = {k: v for k, v in params[key].items() if seg.n_experts
                  and k in _EXPERT_LEAVES and hasattr(v, "ndim")}
         xs = {k: v for k, v in params[key].items() if k not in stack}
+        if cfg.standard_blocks:
+            carry, n_rows = T.scan_periods(
+                blocks_body_of(seg, first, stack, dict(before)), carry, xs,
+                seg.layer_kinds)
+            first += seg.num_layers
+            for kind in seg.layer_kinds:
+                before[kind] = before.get(kind, 0) + 1
+            if seg.n_experts:
+                # [steps, period, E] a run -> [expert layers, E]
+                stats["expert_rows"] = jnp.concatenate(
+                    [r.reshape((-1,) + r.shape[2:]) for r in n_rows])
+            continue
         carry, n_rows = lax.scan(make_body(seg, first, stack), carry, xs)
         first += seg.num_layers
         if n_rows is not None:

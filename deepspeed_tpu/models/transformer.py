@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -192,6 +192,25 @@ class TransformerConfig:
     ssm_dt_rank: int = 0
     # set on a SEGMENT's config only: the kinds of one step of its scan
     period: Tuple[str, ...] = ()
+    # the standard attention block with more to it (the ``afmoe`` family):
+    # RMSNorms after attention and after the FFN too, before each joins the
+    # residual stream (``ln1_post`` / ``ln2_post``); an elementwise sigmoid
+    # gate on the attention output, projected from the block's normed
+    # input (``wg``), ahead of ``wo``; an embedding multiplier; and, where
+    # ``layer_kinds`` names ``window`` and ``full`` layers of this one
+    # block, rotary on the window layers alone
+    post_norms: bool = False
+    attn_gate: bool = False
+    emb_multiplier: float = 1.0
+    full_layers_rope: bool = True
+    # a SHARE of an expert layer (expert parallelism's unit; the one-chip
+    # cut of the ``model-configs`` guide, section 4): the router is
+    # ``moe_router_experts`` wide and chooses ``moe_top_k`` of all of them;
+    # this program holds the ``n_experts`` contiguous experts from
+    # ``moe_first_expert`` and adds their part of the result alone.
+    # 0: the router is ``n_experts`` wide and every expert is held
+    moe_router_experts: int = 0
+    moe_first_expert: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -255,6 +274,23 @@ class TransformerConfig:
         return not (self.parallel_block and self.shared_parallel_norm)
 
     @property
+    def router_experts(self) -> int:
+        """Outputs of the router: every expert of the model, held or not."""
+        return self.moe_router_experts or self.n_experts
+
+    @property
+    def standard_blocks(self) -> bool:
+        """``layer_kinds`` over ONE block (``_block_forward``; one parameter
+        tree a layer, stacked as a homogeneous model's): a kind then says
+        what a layer sees and where its cache lives (``window``: its last
+        ``attn_window`` positions, a per-sequence ring; ``full``: every
+        position, a block range of its own), and which attention it
+        computes is the config's. False with kinds: a mixer of its own
+        per kind (``models/hybrid.py``, ``params[key][kind]``)."""
+        return bool(self.layer_kinds) and not self.ssm_inner \
+            and set(self.layer_kinds) <= {"window", "full"}
+
+    @property
     def segments(self) -> Tuple[Tuple[str, "TransformerConfig"], ...]:
         """The layer stack as (key under ``params``, config of that run of
         layers) in order: one homogeneous scan each. ``num_layers`` of a
@@ -265,8 +301,12 @@ class TransformerConfig:
         a time; its parameters are ``params[key][kind]``, stacked by step,
         and its config carries ``period`` and the index of its first layer
         (``first_dense_layers`` is free there: no such stack has experts)."""
-        if self.layer_kinds:
+        if self.layer_kinds and not self.standard_blocks:
             return self._period_segments()
+        kinds = self.layer_kinds
+        if kinds and len(kinds) != self.num_layers:
+            raise ValueError(f"layer_kinds names {len(kinds)} layers of "
+                             f"num_layers={self.num_layers}")
         d = self.first_dense_layers
         if not d:
             return (("blocks", self),)
@@ -275,10 +315,13 @@ class TransformerConfig:
                 f"first_dense_layers={d} needs an expert model with more "
                 f"than {d} layers (num_layers={self.num_layers}, "
                 f"n_experts={self.n_experts})")
+        # a segment of standard blocks keeps the kinds of its own layers
         rest = dataclasses.replace(self, first_dense_layers=0,
-                                   num_layers=self.num_layers - d)
+                                   num_layers=self.num_layers - d,
+                                   layer_kinds=kinds[d:])
         return (("dense_blocks", dataclasses.replace(
-            rest, num_layers=d, n_experts=0)), ("blocks", rest))
+            rest, num_layers=d, n_experts=0, layer_kinds=kinds[:d])),
+            ("blocks", rest))
 
     def _period_segments(self):
         kinds, L = self.layer_kinds, self.num_layers
@@ -302,7 +345,7 @@ class TransformerConfig:
         return tuple(out)
 
     def num_params(self) -> int:
-        if self.layer_kinds:
+        if self.layer_kinds and not self.standard_blocks:
             from deepspeed_tpu.models.hybrid import mixer_specs
 
             h, f = self.hidden_size, self.ffn_size
@@ -340,17 +383,22 @@ class TransformerConfig:
             kv = self.kv_heads * self.head_dim
             qdim = self.num_heads * self.head_dim
             per_layer = h * qdim + 2 * h * kv + qdim * h  # q, k, v, o
+            if self.attn_gate:
+                per_layer += h * qdim
         ffn_mats = 3 if self.activation == "swiglu" else 2
         if self.n_experts > 0:
-            per_layer += self.n_experts * ffn_mats * h * self.moe_ffn + h * self.n_experts
+            per_layer += self.n_experts * ffn_mats * h * self.moe_ffn \
+                + h * self.router_experts
             per_layer += ffn_mats * h * self.moe_shared_size  # shared expert
             if self.moe_shared_gate:
                 per_layer += h
             if self.moe_gate_bias:
-                per_layer += self.n_experts
+                per_layer += self.router_experts
         else:
             per_layer += ffn_mats * h * f
         per_layer += (2 * h if self.has_ln2 else h)  # norms
+        if self.post_norms:
+            per_layer += 2 * h
         if self.qk_norm:
             per_layer += 2 * self.head_dim
         total = l * per_layer + v * h + 2 * h
@@ -369,7 +417,7 @@ class TransformerConfig:
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     """fp32 master parameters. Output projections scaled by 1/sqrt(2L) (GPT-2)."""
-    if cfg.layer_kinds:
+    if cfg.layer_kinds and not cfg.standard_blocks:
         return _init_kinds(cfg, rng)
     if cfg.first_dense_layers:
         (dkey, dcfg), (_, rest) = cfg.segments
@@ -419,8 +467,14 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
             "wv": dense(keys[2], (L, h, kvdim), std),
             "wo": dense(keys[3], (L, qdim, h), out_std),
         })
+        if cfg.attn_gate:
+            block["wg"] = dense(jax.random.fold_in(rng, 16), (L, h, qdim),
+                                std)
     if cfg.has_ln2:
         block["ln2"] = norm_init((L, h))
+    if cfg.post_norms:
+        block["ln1_post"] = norm_init((L, h))
+        block["ln2_post"] = norm_init((L, h))
     if cfg.qk_norm:
         block["q_norm"] = jnp.ones((L, cfg.head_dim), jnp.float32)
         block["k_norm"] = jnp.ones((L, cfg.head_dim), jnp.float32)
@@ -428,7 +482,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     if E > 0:
         # MoE FFN: per-expert weights (no biases), router gate per layer
         fe = cfg.moe_ffn
-        block["gate_w"] = dense(keys[10], (L, h, E), std)
+        block["gate_w"] = dense(keys[10], (L, h, cfg.router_experts), std)
         block["w_up"] = dense(keys[4], (L, E, h, fe), std)
         block["w_down"] = dense(keys[5], (L, E, fe, h), out_std)
         if cfg.activation == "swiglu":
@@ -443,7 +497,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
             if cfg.moe_shared_gate:
                 block["shared_gate_w"] = dense(keys[14], (L, h, 1), std)
         if cfg.moe_gate_bias:
-            block["gate_bias"] = jnp.zeros((L, E), jnp.float32)
+            block["gate_bias"] = jnp.zeros((L, cfg.router_experts),
+                                           jnp.float32)
     else:
         block["w_up"] = dense(keys[4], (L, h, f), std)
         block["w_down"] = dense(keys[5], (L, f, h), out_std)
@@ -477,7 +532,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
 
 def param_logical_axes(cfg: TransformerConfig) -> PyTree:
     """Logical axis names per parameter dim (consumed by the sharding policy)."""
-    if cfg.layer_kinds:
+    if cfg.layer_kinds and not cfg.standard_blocks:
         return _kinds_tree(cfg, lambda shape, axes, how, n: ("layers",) + axes,
                            lambda shape, axes: axes)
     if cfg.first_dense_layers:
@@ -514,8 +569,13 @@ def param_logical_axes(cfg: TransformerConfig) -> PyTree:
             "wv": lyr + ("embed", "kv_heads"),
             "wo": lyr + ("heads", "embed"),
         })
+        if cfg.attn_gate:
+            block["wg"] = lyr + ("embed", "heads")
     if cfg.has_ln2:
         block["ln2"] = norm_axes(lyr)
+    if cfg.post_norms:
+        block["ln1_post"] = norm_axes(lyr)
+        block["ln2_post"] = norm_axes(lyr)
     if cfg.qk_norm:
         block["q_norm"] = lyr + (None,)
         block["k_norm"] = lyr + (None,)
@@ -939,9 +999,15 @@ def _mla_absorbed_attention(q: jax.Array, ckv: jax.Array, kpe: jax.Array,
 
 def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig,
                    cos: Optional[jax.Array], sin: Optional[jax.Array],
-                   attention_fn: AttentionFn) -> Tuple[jax.Array, jax.Array]:
+                   attention_fn: AttentionFn, kind: Optional[str] = None
+                   ) -> Tuple[jax.Array, jax.Array]:
     """One transformer block; lp holds this layer's (unstacked) params.
     Returns (output, moe aux loss — 0.0 for dense blocks).
+
+    ``kind`` (a layer of ``cfg.layer_kinds`` over this one block,
+    ``cfg.standard_blocks``): ``window`` sees its last ``cfg.attn_window``
+    positions, ``full`` every one, both under an explicit mask in plain jnp
+    (the flash kernel has no window), whatever ``attention_fn`` says.
 
     Sequential (GPT/Llama) or parallel (Falcon/NeoX/Phi: attn and FFN both
     branch off the residual stream and are summed back).
@@ -1026,19 +1092,32 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         if cfg.qk_norm:
             q = _head_rmsnorm(q, lp["q_norm"], cfg.norm_eps)
             k = _head_rmsnorm(k, lp["k_norm"], cfg.norm_eps)
-        if cfg.pos_emb == "rope":
+        if cfg.pos_emb == "rope" and (kind != "full"
+                                      or cfg.full_layers_rope):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
         attn_kwargs = {}
         if cfg.pos_emb == "alibi":
             attn_kwargs["bias"] = \
                 alibi_bias(cfg.num_heads, S) * cfg.alibi_bias_scale
-        attn = attention_fn(q, k, v, causal=cfg.causal, **attn_kwargs)
+        if kind is None:
+            attn = attention_fn(q, k, v, causal=cfg.causal, **attn_kwargs)
+        else:
+            from deepspeed_tpu.models.hybrid import windowed_attention
+
+            attn = windowed_attention(
+                q, k, v, cfg.head_dim ** -0.5,
+                cfg.attn_window if kind == "window" else 0)
         attn = attn.reshape(B, S, cfg.num_heads * cfg.head_dim)
+        if cfg.attn_gate:
+            attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
         attn = _ckpt_name(attn, "attn_out")
         attn_out = attn @ lp["wo"].astype(dt)
         if cfg.use_bias:
             attn_out = attn_out + lp["bo"].astype(dt)
+        if cfg.post_norms:
+            attn_out = _norm(attn_out, lp["ln1_post"], cfg.norm,
+                             cfg.norm_eps)
         return attn_out
 
     if cfg.remat == "attn_block":
@@ -1064,7 +1143,10 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
     @jax.named_scope("mlp")
     def _ffn_delta(xr):
         h2 = _aq(_norm(xr, lp["ln2"], cfg.norm, cfg.norm_eps))
-        return _ffn(h2, lp, cfg)
+        down, aux = _ffn(h2, lp, cfg)
+        if cfg.post_norms:
+            down = _norm(down, lp["ln2_post"], cfg.norm, cfg.norm_eps)
+        return down, aux
 
     if cfg.remat == "ffn_block":
         # converse structural remat: bwd recomputes norm2 → FFN (~63% of
@@ -1088,6 +1170,21 @@ def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
         experts = {k_: lp[k_] for k_ in ("w_up", "w_down", "w_gate") if k_ in lp}
         shared = {k_: lp[k_] for k_ in ("sw_up", "sw_down", "sw_gate",
                                         "shared_gate_w") if k_ in lp}
+        if cfg.moe_router_experts:
+            # a share of the experts: the dropless form alone knows of
+            # experts that are not here (forward only: no auxiliary loss)
+            from deepspeed_tpu.moe.layer import dropless_moe_ffn
+
+            down, _ = dropless_moe_ffn(
+                h.reshape(-1, h.shape[-1]), lp["gate_w"], experts,
+                cfg.activation, cfg.moe_top_k,
+                score_func=cfg.moe_score_func,
+                route_norm=cfg.moe_route_norm,
+                route_scale=cfg.moe_route_scale, shared=shared or None,
+                gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
+                topk_group=cfg.moe_topk_group,
+                first_expert=cfg.moe_first_expert)
+            return down.reshape(h.shape), aux
         down, aux = moe_ffn(
             h, lp["gate_w"], experts, activation=cfg.activation,
             k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
@@ -1118,7 +1215,8 @@ def _require_one_stack(cfg: TransformerConfig, what: str) -> None:
     if cfg.layer_kinds:
         raise NotImplementedError(
             f"{what} runs one homogeneous layer stack; a stack of layer "
-            "kinds (state-space, windowed and shared-cache layers) is "
+            "kinds (state-space, windowed and shared-cache layers; window "
+            "and full attention layers in one stack) is "
             "served by FastGenEngine and run whole by forward()")
     if cfg.first_dense_layers:
         raise NotImplementedError(
@@ -1167,8 +1265,9 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
                 "progressive layer drop, random-LTD and the chunked "
                 "gradient sync assume one homogeneous stack; a stack of "
                 "layer kinds runs without them")
-        return _forward_kinds(params, tokens, cfg,
-                              activation_constraint or (lambda x: x))
+        return (_forward_blocks_of_kinds if cfg.standard_blocks
+                else _forward_kinds)(params, tokens, cfg,
+                                     activation_constraint or (lambda x: x))
     attention_fn = attention_fn or dot_product_attention
     constrain = activation_constraint or (lambda x: x)
     dt = cfg.compute_dtype
@@ -1186,6 +1285,8 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
 
     with jax.named_scope("embed"):
         x = params["tok_emb"].astype(dt)[tokens]
+        if cfg.emb_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.emb_multiplier, dt)
         if cfg.pos_emb == "learned":
             x = x + params["pos_emb"].astype(dt)[:S][None]
         if cfg.emb_norm:
@@ -1272,6 +1373,79 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     head = _lm_head_of(params, full_cfg)
     return x, head, aux_total
+
+
+def kind_runs(kinds: Sequence[str]) -> List[Tuple[int, Tuple[str, ...], int]]:
+    """A run of layer kinds as scans: ``(first layer, period, steps)``
+    each, in order. The period is the shortest the run repeats with from
+    its first layer on (any rotation of the model's own: a run may start
+    inside one); layers past the last whole period are a run of their own
+    (a stack need not end on a period's boundary)."""
+    out, first, kinds = [], 0, tuple(kinds)
+    while kinds:
+        p = next(p for p in range(1, len(kinds) + 1)
+                 if all(kinds[i] == kinds[i - p]
+                        for i in range(p, len(kinds))))
+        steps = len(kinds) // p
+        out.append((first, kinds[:p], steps))
+        first, kinds = first + p * steps, kinds[p * steps:]
+    return out
+
+
+def scan_periods(body_of: Callable, carry, blocks: PyTree,
+                 kinds: Sequence[str]):
+    """Scan a segment of standard blocks a PERIOD of its kinds at a time:
+    ``body_of(period, first layer of the run)(carry, lps)`` takes the
+    period's layers' parameters stacked ``[len(period), ...]``. The stacked leaves ``[layers, ...]``
+    are read as ``[steps, period, ...]`` (no copy where the segment is
+    whole periods)."""
+    outs = []
+    for first, period, steps in kind_runs(kinds):
+        n = len(period) * steps
+        xs = jax.tree.map(
+            lambda a: (a if n == a.shape[0] else a[first:first + n]).reshape(
+                (steps, len(period)) + a.shape[1:]), blocks)
+        carry, out = lax.scan(body_of(period, first), carry, xs)
+        outs.append(out)
+    return carry, outs
+
+
+def _forward_blocks_of_kinds(params: PyTree, tokens: jax.Array,
+                             cfg: TransformerConfig, constrain: Callable
+                             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``forward_hidden`` of ``layer_kinds`` over the standard block
+    (``cfg.standard_blocks``): each segment (leading dense layers, then
+    the stack) scanned a period of its kinds at a time, ``_block_forward``
+    told each layer's kind."""
+    dt = cfg.compute_dtype
+    B, S = tokens.shape
+    with jax.named_scope("embed"):
+        x = params["tok_emb"].astype(dt)[tokens]
+        if cfg.emb_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.emb_multiplier, dt)
+        x = constrain(x)
+    cos = sin = None
+    if cfg.pos_emb == "rope":
+        cos, sin = rope_table(S, cfg.rope_dim, cfg.rope_theta,
+                              cfg.rope_scaling_dict)
+    aux_total = jnp.float32(0.0)
+    for key, seg in cfg.segments:
+        def body_of(period, _, seg=seg):
+            def body(x, lps):
+                aux = jnp.float32(0.0)
+                for i, kind in enumerate(period):
+                    lp = jax.tree.map(lambda a: a[i], lps)
+                    x, a = _block_forward(x, lp, seg, cos, sin,
+                                          dot_product_attention, kind)
+                    x, aux = constrain(x), aux + a
+                return x, aux
+
+            return _remat_wrap(body, cfg.remat)
+
+        x, auxes = scan_periods(body_of, x, params[key], seg.layer_kinds)
+        aux_total = aux_total + sum(jnp.sum(a) for a in auxes)
+    x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+    return x, _lm_head_of(params, cfg), aux_total
 
 
 def _forward_kinds(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,

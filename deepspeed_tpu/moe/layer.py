@@ -138,9 +138,28 @@ def _pick_tile(dim: int, prefer: int) -> Optional[int]:
 _WHOLE_TILE_ELEMS = 2048 * 1536
 
 
+def _whole_k_tile(K: int, N: int, itemsize: int = 2
+                  ) -> Optional[Tuple[int, int]]:
+    """(tk, tn) of the serving form's weight tile: ``[K, N]`` whole where
+    that fits VMEM (``_WHOLE_TILE_ELEMS`` elements of two bytes), else K
+    whole and the widest power-of-two part of N, from 128 columns up, that
+    does; None where not even that fits."""
+    room = _WHOLE_TILE_ELEMS * 2 // itemsize
+    tn = N
+    while K * tn > room and tn % 256 == 0:
+        tn //= 2
+    return (K, tn) if K * tn <= room else None
+
+
 def grouped_dot(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
-                layer: Optional[jax.Array] = None) -> jax.Array:
+                layer: Optional[jax.Array] = None,
+                rows_share: float = 1.0) -> jax.Array:
     """Grouped GEMM ``x[rows of group e] @ w[e]`` → [M, N].
+
+    ``rows_share``: the part of the ``M`` rows the groups are expected to
+    take (a share of an expert layer: the pairs on experts that are not
+    here lie past ``sum(group_sizes)`` and are never multiplied), for the
+    serving form's choice of tile only.
 
     ``layer`` (serving): ``w`` is a layer stack's ``[L, E, K, N]`` and the
     (traced) index says whose experts to use. The stack goes to the kernel
@@ -176,11 +195,12 @@ def grouped_dot(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
             jnp.zeros((L * E,), group_sizes.dtype), group_sizes,
             (layer * E,))
         tm = _pick_tile(M, 128)
-        if jax.default_backend() == "tpu" and tm and M <= 128 * E \
-                and K * N <= _WHOLE_TILE_ELEMS:
+        tile = _whole_k_tile(K, N, w.dtype.itemsize)
+        if jax.default_backend() == "tpu" and tm and tile \
+                and M * rows_share <= 128 * E:
             from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-            return gmm(x, w, group_sizes, x.dtype, (tm, K, N))
+            return gmm(x, w, group_sizes, x.dtype, (tm,) + tile)
     if jax.default_backend() == "tpu":
         import os
 
@@ -220,13 +240,15 @@ def grouped_dot(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
 
 def ragged_expert_ffn(x_sorted: jax.Array, group_sizes: jax.Array,
                       experts: Dict[str, jax.Array], activation: str,
-                      layer: Optional[jax.Array] = None) -> jax.Array:
+                      layer: Optional[jax.Array] = None,
+                      rows_share: float = 1.0) -> jax.Array:
     """Grouped expert FFN on expert-sorted tokens.
 
     x_sorted [M, H] — rows grouped contiguously by expert; group_sizes [E]
     int32 summing to M. Each weight application is ONE grouped GEMM
     (:func:`grouped_dot`) instead of E small matmuls or a [T,E,C] einsum.
-    ``layer``: :func:`grouped_dot`'s (the leaves are a layer stack's).
+    ``layer``, ``rows_share``: :func:`grouped_dot`'s (the leaves are a
+    layer stack's; the groups take that part of the rows).
     """
     dt = x_sorted.dtype
     # named so remat="moe_selective" can store up/act (backward then never
@@ -234,14 +256,14 @@ def ragged_expert_ffn(x_sorted: jax.Array, group_sizes: jax.Array,
     # the bench shapes, kept for bigger-expert configs where the trade flips
     up = _ckpt_name(
         grouped_dot(x_sorted, experts["w_up"].astype(dt), group_sizes,
-                    layer), "moe_up")
+                    layer, rows_share), "moe_up")
     g = (_ckpt_name(
         grouped_dot(x_sorted, experts["w_gate"].astype(dt), group_sizes,
-                    layer), "moe_up")
+                    layer, rows_share), "moe_up")
         if "w_gate" in experts else None)
     act = _ckpt_name(_expert_act(up, g, activation), "moe_act")
     return grouped_dot(act, experts["w_down"].astype(dt), group_sizes,
-                       layer)
+                       layer, rows_share)
 
 
 def expert_sort(flat: jax.Array, E: int
@@ -435,6 +457,41 @@ def _ragged_dispatch_local(xt: jax.Array, weights: jax.Array, idx: jax.Array,
     x_s = dispatch_gather(xt, order, inv2d)
     y_s = ragged_expert_ffn(x_s, group_sizes, experts, activation, layer)
     return combine_gather(y_s, weights.astype(xt.dtype), order, inv2d)
+
+
+def held_group_sizes(idx: jax.Array, held: int, first_expert: int
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The sort of a tick's (row, expert) pairs for a layer that holds the
+    ``held`` experts from ``first_expert`` of those ``idx`` [T, k] names:
+    (order, inverse [T, k], rows of each HELD expert [held], which pairs
+    fall on a held expert [T, k]). Pairs on held experts come first, by
+    expert; the pairs on experts that are not here sort behind them, in
+    no group: the grouped matmul spends no row on them."""
+    local = idx - first_expert
+    here = (local >= 0) & (local < held)
+    order, inv, counts = expert_sort(
+        jnp.where(here, local, held).reshape(-1), held + 1)
+    return order, inv.reshape(idx.shape), counts[:held], here
+
+
+def _ragged_dispatch_held(xt: jax.Array, weights: jax.Array, idx: jax.Array,
+                          experts: Dict[str, jax.Array], activation: str,
+                          layer: Optional[jax.Array], first_expert: int,
+                          router_experts: int) -> jax.Array:
+    """:func:`_ragged_dispatch_local` for a SHARE of the layer's experts
+    (:func:`held_group_sizes`): this share's part of the routed result.
+    Forward only. The rows behind the groups are undefined under the
+    grouped matmul's kernel, so a pair that is not here is masked out of
+    the sum, not weighted by zero."""
+    held = experts["w_up"].shape[-3]
+    order, inv2d, group_sizes, here = held_group_sizes(
+        idx, held, first_expert)
+    x_s = jnp.take(xt, order // idx.shape[-1], axis=0)
+    y_s = ragged_expert_ffn(x_s, group_sizes, experts, activation, layer,
+                            rows_share=held / router_experts)
+    picked = jnp.where(here[..., None], jnp.take(y_s, inv2d, axis=0)
+                       * weights.astype(xt.dtype)[..., None], 0)
+    return jnp.sum(picked, axis=1)
 
 
 def _token_axes(mesh) -> Tuple[Tuple[str, ...], Optional[str]]:
@@ -855,7 +912,8 @@ def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
                      gate_bias: Optional[jax.Array] = None,
                      n_group: int = 1, topk_group: int = 1,
                      valid: Optional[jax.Array] = None,
-                     layer: Optional[jax.Array] = None
+                     layer: Optional[jax.Array] = None,
+                     first_expert: int = 0
                      ) -> Tuple[jax.Array, jax.Array]:
     """The serving form of :func:`moe_ffn` on flat local rows ``xt [T, H]``:
     always the dropless sort + grouped matmul, whatever ``moe_dispatch``
@@ -864,7 +922,17 @@ def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
     Returns (y [T, H], rows per expert [E] int32 over the ``valid`` rows:
     a tick's pad rows route too and are left out of the count).
     ``layer``: ``experts`` are the layer stack's ``[L, E, ...]`` leaves and
-    this is the layer to use (:func:`grouped_dot`). The
+    this is the layer to use (:func:`grouped_dot`).
+
+    A SHARE of the layer (expert parallelism's unit without its exchange):
+    where ``experts`` holds fewer experts than ``gate_w`` has columns, they
+    are the contiguous ones from ``first_expert``. The router still scores
+    and chooses over all of them, the pairs that fall here are computed,
+    the others add nothing and cost no row of the grouped matmul
+    (:func:`held_group_sizes`), and the shared expert runs for every row;
+    the rows come back for every expert of the router.
+
+    The
     scopes ``router`` / ``experts`` / ``shared_experts`` are what a device
     trace sorts the layer's operations by."""
     with jax.named_scope("router"):
@@ -876,8 +944,13 @@ def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
             picked = picked * valid.astype(jnp.int32)[:, None, None]
         rows = jnp.sum(picked, axis=(0, 1))
     with jax.named_scope("experts"):
-        y = _ragged_dispatch_local(xt, gate.weights, gate.experts, experts,
-                                   activation, layer)
+        if experts["w_up"].shape[-3] < gate_w.shape[1]:
+            y = _ragged_dispatch_held(
+                xt, gate.weights, gate.experts, experts, activation, layer,
+                first_expert, gate_w.shape[1])
+        else:
+            y = _ragged_dispatch_local(xt, gate.weights, gate.experts,
+                                       experts, activation, layer)
         if route_scale != 1.0:
             y = y * jnp.asarray(route_scale, xt.dtype)
     if shared:

@@ -125,12 +125,14 @@ def _blocks_per_fetch(bs: int, row_bytes: int, query_rows: int,
 
 def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
             n_pool: int, scale: float, mxu_dtype, window: Optional[int],
-            heads_first: bool):
+            heads_first: bool, indirect: bool = False):
     """``heads_first``: a pool block is ``[K, bs, D]`` and not ``[bs, K,
     D]`` (a head count off the sublane tiling, 10 say, cannot be the
     second-minor dim of a block a copy slices); the fetch slots are then
     ``[2, K, P, bs, D]``, filled a head-strided copy a block, and are
     head-major as they lie.
+    ``indirect``: the tables are one a SEQUENCE and a third section of
+    ``meta`` names each row's (``paged_attention``'s ``row_table``).
     ``window``: a row sees its last ``window`` cache positions alone
     (``limit - window <= c < limit``) and a walk starts at the block of its
     rows' lowest such position instead of block 0; None: the whole context.
@@ -148,7 +150,7 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
         P, bs = bufs[0].shape[1:3]
         K = bufs[0].shape[3] if bufs[0].ndim == 5 else 1
     R, N, _ = q_ref.shape
-    rep, T = N // K, meta_ref.shape[0] // 2
+    rep, T = N // K, meta_ref.shape[0] // (3 if indirect else 2)
     M, C = R * rep, P * bs
     Dv = acc_ref.shape[2]
     t0 = pl.program_id(0) * R
@@ -170,7 +172,7 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     # the tile's queries head-major, [K, R*rep, D]: row r of the tile is
     # rows r*rep .. of every KV head; its lengths beside them; its softmax
     # statistics and accumulator opened once, each run closes its own rows
-    q = q_ref[...].astype(mxu_dtype).reshape(R, K, rep, q_ref.shape[2])
+    q = q_ref[...].astype(q3_ref.dtype).reshape(R, K, rep, q_ref.shape[2])
     q3_ref[...] = jnp.swapaxes(q, 0, 1).reshape(K, M, q_ref.shape[2])
 
     def put_length(r, _):
@@ -196,7 +198,8 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
         ``R*rep``) against ``[K, C, D]`` keys and values; ``limit``
         broadcasts against the ``[K, rows, C]`` scores."""
         s = jax.lax.dot_general(
-            q3_ref[:, rows, :], kt, (((2,), (2,)), ((0,), (0,))),
+            q3_ref[:, rows, :].astype(mxu_dtype), kt,
+            (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
         col = i * C + jax.lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
         live = col < limit
@@ -226,7 +229,7 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
 
             @pl.when(j < nblk)
             def _():
-                blk = tables_ref[t, j]
+                blk = tables_ref[meta_ref[2 * T + t] if indirect else t, j]
                 for n, (pool, buf) in enumerate(zip(pools, bufs)):
                     copy = pltpu.make_async_copy(
                         pool.at[blk], buf.at[slot, :, p] if heads_first
@@ -311,9 +314,9 @@ def _geometry(q, pools, value_dim, heads_first):
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "value_dim", "scale", "name", "mxu_dtype", "interpret", "window",
-    "heads_first"))
+    "heads_first", "indirect"))
 def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
-           interpret, window=None, heads_first=False):
+           interpret, window=None, heads_first=False, indirect=False):
     """The kernel over whole tiles: ``pools`` the key pool and the value
     pool, or the key pool alone where the value is the first ``value_dim``
     columns of the key's block. Jitted (inlined into the caller's program)
@@ -322,6 +325,11 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
     T, N, _ = q.shape
     K, bs, R, P = _geometry(q, pools, value_dim, heads_first)
     rep = N // K
+    # a decode row's queries are ``rep`` rows of the head-major scratch
+    # from a run-time offset: a 16-bit scratch takes that only where the
+    # rows fill its packed sublane tile (16), so it is float32 otherwise
+    # (a group of 6 query heads a KV head) and cast where it is multiplied
+    q3_dtype = mxu_dtype if rep % 16 == 0 else jnp.float32
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -335,7 +343,7 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
             (2, K, P) + x.shape[2:] if heads_first else (2, P) + x.shape[1:],
             x.dtype) for x in pools] + [
             pltpu.SemaphoreType.DMA((len(pools), 2)),
-            pltpu.VMEM((K, R * rep, q.shape[2]), mxu_dtype),
+            pltpu.VMEM((K, R * rep, q.shape[2]), q3_dtype),
             pltpu.VMEM((R * rep, _LANES), jnp.int32),
             pltpu.VMEM((K, R * rep, _LANES), jnp.float32),
             pltpu.VMEM((K, R * rep, _LANES), jnp.float32),
@@ -351,7 +359,7 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
     return pl.pallas_call(
         functools.partial(_kernel, n_pool=len(pools), scale=scale,
                           mxu_dtype=mxu_dtype, window=window,
-                          heads_first=heads_first),
+                          heads_first=heads_first, indirect=indirect),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N, value_dim), q.dtype),
         compiler_params=compiler_params,
@@ -361,7 +369,7 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
 
 
 def _walk(q, pools, tables, lengths, *, value_dim, scale, name, mxu_dtype,
-          interpret, window=None, heads_first=False):
+          interpret, window=None, heads_first=False, row_table=None):
     """Pads the rows to whole tiles, says which rows share a table, and
     runs the kernel: what both entry points below are."""
     if interpret is None:
@@ -370,8 +378,24 @@ def _walk(q, pools, tables, lengths, *, value_dim, scale, name, mxu_dtype,
     pad = -Tn % tile_rows(N, value_dim)
     if pad:                            # pad rows: zero table, length 1
         q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
-        tables = jnp.pad(tables, ((0, pad), (0, 0)))
+        if row_table is None:
+            tables = jnp.pad(tables, ((0, pad), (0, 0)))
         lengths = jnp.pad(lengths, (0, pad), constant_values=1)
+    if row_table is not None:
+        # one table a sequence (its row 0 the pad rows'): the tables lie in
+        # scalar memory, where a row's own copy of a wide table for each
+        # of thousands of rows has no room
+        which = jnp.pad(row_table.astype(jnp.int32), (0, pad))
+        tables = jnp.pad(tables, ((0, 0),
+                                  (0, -tables.shape[1] % _TABLE_COLS)))
+        same = jnp.concatenate([jnp.zeros((1,), jnp.bool_),
+                                which[1:] == which[:-1]])
+        meta = jnp.concatenate([lengths.astype(jnp.int32),
+                                same.astype(jnp.int32), which])
+        return _tiles(tables, meta, q, *pools, value_dim=value_dim,
+                      scale=scale, name=name, mxu_dtype=mxu_dtype,
+                      interpret=interpret, window=window,
+                      heads_first=heads_first, indirect=True)[:Tn]
     # every table tier of a tick bucket hands the kernel the same shapes, so
     # its body is traced once a bucket, not once a (Tn, mb) program; the
     # columns added are never read (walks end at ceil(length / bs))
@@ -392,7 +416,9 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                     scale: Optional[float] = None,
                     window: Optional[int] = None,
                     heads_first: bool = False,
-                    name: str = "paged_attention") -> jax.Array:
+                    name: str = "paged_attention",
+                    mxu_dtype=jnp.float32,
+                    row_table: Optional[jax.Array] = None) -> jax.Array:
     """Drop-in for ``models.paged.paged_attention_reference``. ``scale``:
     the scores' factor where it is not ``D ** -0.5``; ``window``: each row
     attends to its last ``window`` positions alone, and the table may then
@@ -400,13 +426,18 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     ``c*bs ..`` now: a walk reads columns from the window's start on);
     ``heads_first``: the pools are ``[NB, K, bs, D]``, which a head count
     that is no multiple of 8 needs; ``name``: the Mosaic call's name in a
-    device trace."""
+    device trace; ``mxu_dtype``: the products' operand type (float32: the
+    homogeneous cells are not MXU-bound; bfloat16 where a long chunk
+    against a long context is); ``row_table`` [T]: ``tables`` is then one
+    table a SEQUENCE, ``[sequences, MB]`` with row 0 the pad rows', and
+    this names each row's (rows of one sequence are told by it)."""
     D, K = q.shape[2], kpool.shape[1 if heads_first else 2]
     assert D == kpool.shape[3] and q.shape[1] % K == 0
     return _walk(q, (kpool, vpool), tables, lengths, value_dim=D,
                  scale=D ** -0.5 if scale is None else scale,
-                 name=name, mxu_dtype=jnp.float32, interpret=interpret,
-                 window=window, heads_first=heads_first)
+                 name=name, mxu_dtype=mxu_dtype, interpret=interpret,
+                 window=window, heads_first=heads_first,
+                 row_table=row_table)
 
 
 def latent_paged_attention(q: jax.Array, pool: jax.Array,

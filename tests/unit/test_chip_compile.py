@@ -126,3 +126,44 @@ def test_paired_head_attention_compiles_for_v5e(one_chip, shape):
     # classify by the name and read the pool at operand 3
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
     assert f"%{name}" in text
+
+
+# (rows of the tick bucket, table tier, window, rows of the pool) of the
+# cell of window and full layers over experts (the ``afmoe`` family): 48
+# query heads on 8 KV heads of 128 (a group of 6: a decode row's queries are
+# 6 rows of the head-major scratch from a run-time offset, which a 16-bit
+# scratch refuses), bfloat16 products, ONE table a sequence slot (a table a
+# row, 2,048 x 384 words, does not fit scalar memory), rings of 28 + 1
+# slots x 192 blocks x 4 layers or the one full layer's 12,288 blocks
+SPAN_SHAPES = {
+    "trinity-swa-2048x360": (2048, 360, 4096, 4 * 29 * 192),
+    "trinity-swa-256x90": (256, 90, 4096, 4 * 29 * 192),
+    "trinity-global-2048x360": (2048, 360, None, 12288),
+    "trinity-global-256x180": (256, 180, None, 12288),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SPAN_SHAPES))
+def test_window_and_full_attention_compile_for_v5e(one_chip, shape):
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    T, MB, window, rows = SPAN_SHAPES[shape]
+    name = "global_attention" if window is None else "swa_attention"
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = arg((rows, 32, 8, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, t, n, w: paged_attention(
+            q, k, v, t, n, interpret=False, window=window, name=name,
+            mxu_dtype=jnp.bfloat16, row_table=w)
+    ).lower(arg((T, 48, 128), jnp.bfloat16), pool, pool,
+            arg((29, MB), jnp.int32), arg((T,), jnp.int32),
+            arg((T,), jnp.int32)).compile()
+    text = compiled.as_text()
+    # one Mosaic call under its own name, operands (tables, lengths + same
+    # + slots, q, kpool, vpool): benchmarks/roofline/{swa,global}_attention
+    # classify by the name and read the pool at operand 3
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert f"%{name}" in text

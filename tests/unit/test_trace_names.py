@@ -130,3 +130,40 @@ def test_serving_tick_carries_scopes_and_the_kernel_name():
     assert {"embed", "attn", "mlp", "lm_head", "sample"} <= parts
     # the paged kernel by name, inside a layer's attention scope
     assert any("/attn/paged_attention" in s for s in stacks)
+
+
+def test_a_tick_of_window_and_full_layers_tells_its_kernels_and_experts_apart():
+    """The ``afmoe`` tick: the window layers' and the full layers'
+    attention under scopes and kernel names of their own (neither is
+    ``paged_attention``, which the dense kernel's roofline reader takes
+    for its own), the expert layer's three parts beside a dense layer's
+    ``mlp``."""
+    import types
+
+    from deepspeed_tpu.models.hf_import import config_from_hf
+
+    cfg = config_from_hf(types.SimpleNamespace(
+        model_type="afmoe", hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, head_dim=16, num_attention_heads=6,
+        num_key_value_heads=2, num_hidden_layers=5, num_dense_layers=1,
+        layer_types=["sliding_attention"] * 4 + ["full_attention"],
+        num_experts=4, router_experts=16, num_experts_per_tok=4,
+        num_shared_experts=1, rms_norm_eps=1e-5, rope_theta=10000,
+        route_norm=True, route_scale=2.448, sliding_window=16,
+        tie_word_embeddings=False, vocab_size=128,
+        max_position_embeddings=512, mup_enabled=True))
+    eng = FastGenEngine(cfg, n_blocks=16, block_size=4, max_blocks_per_seq=8,
+                        token_budget=32, state_slots=2, seed=0,
+                        use_pallas_kernel=True)
+    tn, mb = 32, eng.max_blocks_per_seq
+    stacks = _stacks(eng._build_tick(tn, mb).lower(
+        eng.params, eng.pool, eng._pack_tick(
+            np.zeros((tn,), np.int32), np.zeros((tn,), np.int32),
+            np.zeros((tn, mb), np.int32), np.zeros((2,), np.uint32))),
+        "tick")
+    parts = {part for s in stacks for part in s.split("/")}
+    assert {"embed", "attn", "mlp", "swa", "global", "router", "experts",
+            "shared_experts", "lm_head", "sample"} <= parts
+    assert any("/attn/swa/swa_attention" in s for s in stacks)
+    assert any("/attn/global/global_attention" in s for s in stacks)
+    assert not any("/paged_attention" in s for s in stacks)

@@ -276,6 +276,10 @@ class FastGenEngine:
         # (0 where it is not a kernel)
         self._attention, self._tile_rows = PG.tick_attention(
             cfg, use_pallas_kernel)
+        # (layers, window, positions a fetch step) of the kernel's calls
+        # in a tick: what the count of a tick's fetch steps needs
+        self._walks = PG.tick_walks(cfg, self.pool) if self._tile_rows \
+            else []
         # expert layers whose per-expert row counts ride back with a
         # tick's sampled tokens; 0 for a model without experts
         self._expert_layers = sum(
@@ -339,6 +343,11 @@ class FastGenEngine:
             "prompt rows of step() ticks that sat in a kernel tile wholly "
             "inside one chunk (their tile walked the sequence's blocks "
             "once); over fastgen_prefill_tokens_total: the hit share")
+        self._tm_attn_steps = telemetry.counter(
+            "fastgen_attention_steps_total",
+            "fetch steps the paged-attention kernel's calls of step() "
+            "ticks walked, by form: open (every column live for every row "
+            "of the step: computed without a mask) / masked")
         self._tm_expert_imbalance = telemetry.histogram(
             "fastgen_expert_load_imbalance",
             "step() ticks of an expert model, by tick bucket: the busiest "
@@ -1048,6 +1057,7 @@ class FastGenEngine:
             # prompt rows in kernel tiles wholly inside one chunk
             shared_rows = 0
             R = self._tile_rows
+            chunk_starts: List[int] = []       # each chunk's first row
 
             # 1) decode tokens — one per fully-prefilled live sequence,
             # starting from a rotating offset so tails never starve when
@@ -1111,6 +1121,7 @@ class FastGenEngine:
                 positions[row:row + chunk] = np.arange(seq.pos,
                                                        seq.pos + chunk)
                 tables[row:row + chunk] = seq.table
+                chunk_starts.append(row)
                 if R:
                     shared_rows += R * max(
                         0, (row + chunk) // R - -(-row // R))
@@ -1173,6 +1184,28 @@ class FastGenEngine:
                     packed if self._rep_sh is None
                     else jax.device_put(packed, self._rep_sh))
                 sampled.copy_to_host_async()
+            # while the device runs: the fetch steps its attention calls
+            # walk and those that take the unmasked form, by the kernel's
+            # own rule of the tick's lengths
+            attn_steps = attn_open = 0
+            if self._walks:
+                from deepspeed_tpu.ops.pallas.paged_attention import \
+                    count_steps
+
+                # the rows of whole tiles (the kernel's wrapper pads as the
+                # tick does: length 1, the zero table) in runs that carry
+                # one table: every decode row, every chunk, the pads
+                lengths = np.ones((-(-Tn // R) * R,), np.int32)
+                lengths[:Tn] = positions + 1
+                starts = np.zeros(lengths.shape, bool)
+                starts[:n_decode_rows] = True
+                starts[chunk_starts + [row] * (row < len(starts))] = True
+                for layers, window, step in self._walks:
+                    n, n_open = count_steps(lengths, starts, R, step, window)
+                    attn_steps += layers * n
+                    attn_open += layers * n_open
+                tick_span.note(attn_steps=attn_steps,
+                               attn_open_steps=attn_open)
             # the wait for the device and for the copy queued behind it
             with telemetry.span("tick_readback"):
                 sampled = np.asarray(sampled)
@@ -1222,6 +1255,10 @@ class FastGenEngine:
             self._tm_h2d.inc(packed.nbytes)
             self._tm_prefill_tok.inc(row - n_decode_rows)
             self._tm_shared_rows.inc(shared_rows)
+            if attn_steps:
+                self._tm_attn_steps.inc(attn_open, form="open")
+                self._tm_attn_steps.inc(attn_steps - attn_open,
+                                        form="masked")
             self._tm_occup.set(row / Tn, phase="mixed")
             self._tm_sched_gauges()
 

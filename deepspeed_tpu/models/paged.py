@@ -17,7 +17,8 @@ from __future__ import annotations
 
 import contextlib
 import math
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -318,6 +319,42 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
         return latent_paged_attention, tile_rows(cfg.num_heads,
                                                  cfg.kv_lora_rank)
     return paged_attention, tile_rows(cfg.num_heads, cfg.head_dim)
+
+
+def tick_walks(cfg: T.TransformerConfig, pool: Dict[str, jax.Array]
+               ) -> List[Tuple[int, Optional[int], int]]:
+    """(layers, window, cache positions a fetch step) of each kind of call
+    :func:`tick_attention`'s kernel makes in a tick of ``cfg`` over
+    ``pool``: what ``ops.pallas.paged_attention.count_steps`` needs, beside
+    a tick's lengths, to say how many fetch steps the tick walks. The step
+    is the kernel's own rule of the operands' shapes (``_geometry``)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import _geometry
+
+    def step(names, heads_first=False):
+        # a call sees one block of each pool and the queries' heads
+        blocks = [jax.ShapeDtypeStruct(
+            (1,) + pool[n].shape[-2 if cfg.mla else -3:], pool[n].dtype)
+            for n in names]
+        width = blocks[0].shape[-1]
+        q = jax.ShapeDtypeStruct((1, cfg.num_heads, width), blocks[0].dtype)
+        _, bs, _, P = _geometry(
+            q, blocks, cfg.kv_lora_rank if cfg.mla else width, heads_first)
+        return bs * P
+
+    kinds = cfg.layer_kinds or ()
+    if cfg.standard_blocks:
+        return [(kinds.count(kind), window, step(names))
+                for kind, window, names in (
+                    ("window", cfg.attn_window, ("wk", "wv")),
+                    ("full", None, ("k", "v"))) if kind in kinds]
+    if kinds:
+        return [(kinds.count("window"), cfg.attn_window,
+                 step(("wk", "wv"), True)),
+                (kinds.count("full") + kinds.count("cross"), None,
+                 step(("k", "v"), True))]
+    if cfg.mla:
+        return [(cfg.num_layers, None, step(("latent",)))]
+    return [(cfg.num_layers, None, step(("k", "v")))]
 
 
 _EXPERT_LEAVES = ("w_up", "w_down", "w_gate")

@@ -20,15 +20,40 @@ lengths:
 - a run of one row (a decode row) walks alone, ``[rep, D] x [D, P*bs]``.
 
 ``R`` and ``P`` are one rule of the operands' shapes (``tile_rows``,
-``_blocks_per_fetch``). A fetch step has a cost of its own, 0.3 us for a
-decode row and more for a tile, so a tile takes the rows and a step the
-cache positions that 1 MB each of accumulator, scores and fetched bytes
-leave room for; K/V pools, whose blocks a step casts and re-lays before it
-multiplies them, stay at one lane width of positions, where wider steps
-measured no faster. Mistral and Phi-4-mini-flash: 32 rows x 128 positions;
-Pythia 32 x 64; Moonlight's latent pool 32 x 512, which took a mixed tick's
-call from 1,419 to 840 us and a decode tick's from 470 to 314 on the v5e
-(PERF.md section 6, PR 32).
+``_blocks_per_fetch``). A fetch step has a cost of its own, so a tile takes
+the rows and a step the cache positions that 1 MB each of accumulator,
+scores and fetched bytes leave room for; K/V pools stay at one lane width of
+positions, a rule measured when a step cast and transposed what it fetched
+(PR 32) and not measured again since. Mistral, Trinity and
+Phi-4-mini-flash: 32 rows x 128 positions; Pythia 32 x 64; Moonlight's
+latent pool 32 x 512.
+
+A step does what its columns need and no more (PERF.md sections 5-6, PR 34:
+Trinity's tile-step 2.56 -> 1.67 us and a decode row's 1.21 -> 0.85 on the
+v5e, every other instantiation 9 to 34 % a call, every output equal to the
+bit):
+
+- two FORMS of a step. A walk's steps ``begin .. end`` are cut once a walk
+  by :func:`step_ranges` into edge steps, OPEN steps ``first .. last`` and
+  edge steps. On an open step every row of the walk sees every column:
+  ``(i + 1) * C <= min(length)`` and ``i * C >= max(length) - window``, and
+  the walk is a row alone or a run that is its whole tile. It runs
+  ``online_softmax`` with no mask: no iota, no compares, no select over the
+  scores or the probabilities (the mask was all true). Edge steps, and every
+  step of a run that shares its tile, take the masked form. The host counts
+  both with the same rule (:func:`count_steps`; the engine's
+  ``fastgen_attention_steps_total{form}``);
+- keys and values are multiplied as they lie. A fetched ``[C, K, D]`` slot is
+  rows ``c*K + k`` of a matrix, so KV head k is every K-th row: a strided
+  load of the slot's 32-bit words (two bfloat16 heads a word) and integer
+  shifts put a head's positions together; no value is transposed
+  (``head_major``). ``heads_first`` and latent slots are head-major already;
+- the softmax statistics ``m`` and ``l`` lie replicated over their 128 lanes
+  (as upstream Pallas TPU flash attention holds them), so no step slices a
+  lane out of them or broadcasts one back: the largest item, a third of a
+  tile-step;
+- whether a walk is a row alone or a tile is chosen once a walk (a loop of
+  steps for each form and stretch), not by a branch a step.
 
 Which rows share a table is DATA: ``same[t] = all(tables[t] == tables[t-1])``
 is computed on the device beside the call and rides in the second scalar
@@ -43,11 +68,12 @@ product bought nothing: the kernel is not MXU-bound.
 
 A serving set-up builds a tick program per ``(Tn, mb)``, seven of them, and
 tracing this kernel's body for each was a tenth of the benchmark's
-``setup_s``. So the traced program is kept small (both kinds of run share one
-walk, whose step branches on the run's length; every loop over rows is a
-``fori_loop``) and is traced once a tick bucket: the call is an inlined inner
-``jit``, whose trace is cached by operand shapes, and the table is widened to
-a multiple of 64 columns so that every tier of a bucket has the same shapes.
+``setup_s``. So the traced program is kept small (one ``online_softmax``
+with a static flag for its two forms, one ``steps`` loop instantiated a
+stretch of a walk; every loop over rows is a ``fori_loop``) and is traced
+once a tick bucket: the call is an inlined inner ``jit``, whose trace is
+cached by operand shapes, and the table is widened to a multiple of 64
+columns so that every tier of a bucket has the same shapes.
 
 Shapes: q [T, N, D]; kpool/vpool [NB, bs, K, D]; tables [T, MB] int32;
 lengths [T] int32 (context length per token, pos+1). GQA via in-kernel
@@ -56,10 +82,11 @@ head-group batching (N = K * rep).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -96,8 +123,7 @@ def _blocks_per_fetch(bs: int, row_bytes: int, query_rows: int,
     """Blocks a fetch step copies and multiplies. ``row_bytes``: what one
     cache position holds over every pool; ``query_rows``: the rows of a
     step's scores over every KV head (``tile_rows`` x query heads);
-    ``head_axis``: the pools have one (K/V pools), so a step casts what it
-    fetched to float32 and re-lays it head-major before it multiplies.
+    ``head_axis``: the pools have one (K/V pools).
 
     A live step costs 0.3 us for a decode row and more for a tile whatever
     it carries, so it carries what VMEM has room for: as many cache
@@ -105,12 +131,15 @@ def _blocks_per_fetch(bs: int, row_bytes: int, query_rows: int,
     scores ``[query_rows, positions]`` under ``_TILE_BYTES``, in whole lane
     widths (the scores' minor dim), or whole blocks where not one lane
     width fits (Pythia: 64 positions are 1 MB). With a head axis one lane
-    width is all: the cast and the relayout grow with the step and are
-    most of it, so a wider step saves nothing and multiplies more masked
-    positions of a short context (Mistral's shapes at 256 positions a
-    step against 128: 594.2 / 594.0 us a mixed tick's call, 301.8 / 306.9
-    a decode tick's at 600 positions, 222.8 / 194.9 at 300; Phi-4's window
-    walk +5 %). The latent pool's row is multiplied as it lies, 1,280 B a
+    width is all: measured in PR 32, when a step cast and transposed what
+    it fetched and that grew with the step, a wider step saved nothing and
+    multiplied more masked positions of a short context (Mistral's shapes
+    at 256 positions a step against 128: 594.2 / 594.0 us a mixed tick's
+    call, 301.8 / 306.9 a decode tick's at 600 positions, 222.8 / 194.9 at
+    300; Phi-4's window walk +5 %); since PR 34 a step reads the heads
+    strided and the rule has not been measured again (Trinity's scores
+    hold it to 128 whatever this says). The latent pool's row is
+    multiplied as it lies, 1,280 B a
     position: 128 positions are 0.2 us of HBM time, and 512 a step read
     314 against 470 us a decode tick's call, 840 against 1,278 a mixed
     tick's (PERF.md section 6, PR 32)."""
@@ -121,6 +150,50 @@ def _blocks_per_fetch(bs: int, row_bytes: int, query_rows: int,
     if positions >= _LANES:
         positions -= positions % _LANES
     return max(1, positions // bs)
+
+
+def step_ranges(lo, hi, step: int, window: Optional[int], whole, xp=np):
+    """The fetch steps, ``step`` cache positions each, of a walk whose rows'
+    lengths lie in ``lo .. hi`` (a row of length n sees the columns under n
+    and, with a ``window``, no more than ``window`` of them), as ``(begin,
+    first, last, end)``: the walk takes steps ``begin .. end``, outside
+    which no row sees a column; steps ``first .. last`` are OPEN: every
+    row sees every column, ``(i + 1) * step <= lo`` and ``i * step >= hi -
+    window``, so they are computed without a mask. Only a ``whole`` walk
+    has open steps: a row alone, or a run that is its whole tile (any other
+    meets the blocks with rows of the tile that see nothing).
+
+    The kernel's walk (``xp=jnp``, on scalars) and the host's count of a
+    tick's steps (:func:`count_steps`, on arrays) are this one rule."""
+    end = -(-hi // step)
+    if window is None:
+        begin = first = 0 * lo                 # zeros of ``lo``'s kind
+    else:
+        begin = xp.maximum(lo - window, 0) // step
+        first = xp.clip(-((window - hi) // step), begin, end)
+    last = xp.where(whole, xp.clip(lo // step, first, end), first)
+    return begin, first, last, end
+
+
+def count_steps(lengths, starts, tile: int, step: int,
+                window: Optional[int] = None) -> Tuple[int, int]:
+    """(fetch steps, open fetch steps) one call of the kernel walks over
+    rows of these ``lengths``, ``starts[t]`` true where row t carries
+    another table than row t-1, tiles of ``tile`` rows and steps of
+    ``step`` cache positions: the kernel's own runs (cut at tile
+    boundaries too) under :func:`step_ranges`, on the host. The rows are
+    whole tiles': the caller adds the pad rows the wrapper would (length
+    1, one table)."""
+    lengths, cut = np.asarray(lengths), np.array(starts, bool)
+    if len(lengths) % tile:
+        raise ValueError(f"{len(lengths)} rows are not whole tiles of {tile}")
+    cut[::tile] = True
+    at = np.flatnonzero(cut)
+    rows = np.diff(np.append(at, len(lengths)))
+    begin, first, last, end = step_ranges(
+        np.minimum.reduceat(lengths, at), np.maximum.reduceat(lengths, at),
+        step, window, (rows == 1) | (rows == tile))
+    return int((end - begin).sum()), int((last - first).sum())
 
 
 def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
@@ -180,45 +253,97 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
             (rep, _LANES), length(r), jnp.int32)
 
     jax.lax.fori_loop(0, R, put_length, None)
+    # the statistics lie replicated over their 128 lanes: no step slices a
+    # lane out or broadcasts one back
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def head_major(buf, slot):
-        """A fetched slot as ``[K, C, D]``."""
-        x = buf[slot].astype(mxu_dtype)
-        if heads_first:
-            return x.reshape(K, C, x.shape[-1])
-        if buf.ndim == 4:                      # a pool without a head axis
-            return x.reshape(1, C, x.shape[-1])
-        return jnp.swapaxes(x.reshape(C, K, x.shape[-1]), 0, 1)
+    def lanes(x, n):
+        """``x [.., 128]``, every lane the same, as ``[.., n]``."""
+        if n <= _LANES:
+            return x[..., :n]
+        if n % _LANES:
+            return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
+        return jnp.concatenate([x] * (n // _LANES), axis=x.ndim - 1)
 
-    def online_softmax(i, kt, vt, rows, limit):
-        """One fetch step of the query rows ``rows`` (a slice of the tile's
-        ``R*rep``) against ``[K, C, D]`` keys and values; ``limit``
-        broadcasts against the ``[K, rows, C]`` scores."""
+    def head_major(buf, slot):
+        """A fetched slot as ``[K, C, D]`` in the products' type."""
+        D = buf.shape[-1]
+        if heads_first:
+            return buf[slot].astype(mxu_dtype).reshape(K, C, D)
+        if buf.ndim == 4:                      # a pool without a head axis
+            return buf[slot].astype(mxu_dtype).reshape(1, C, D)
+        # ``[C, K, D]`` as it lies is rows ``c*K + k`` of a matrix: head k
+        # is every K-th row from row k, which a load reads strided, and no
+        # value is transposed
+        if buf.dtype.itemsize == 4:
+            rows = buf.at[slot].reshape(C * K, D)
+            return jnp.stack([rows[pl.ds(k, C, stride=K), :]
+                              for k in range(K)]).astype(mxu_dtype)
+        packed = mxu_dtype == jnp.bfloat16
+        if buf.dtype == jnp.bfloat16 and K % 2 == 0 and not (packed
+                                                             and C % 2):
+            # rows of 16 bits lie two to a 32-bit word, heads 2j (the low
+            # half) and 2j+1: the strided load takes the words
+            words = buf.at[slot].bitcast(jnp.uint32).reshape(C * K // 2, D)
+            low, high = jnp.uint32(0xffff), jnp.uint32(0xffff0000)
+            heads = []
+            for j in range(K // 2):
+                if packed:
+                    # bfloat16 products take positions 2c (low) and 2c+1
+                    # of ONE head a word: the halves of an even and an odd
+                    # position's words, put together as they are
+                    even = words[pl.ds(j, C // 2, stride=K), :]
+                    odd = words[pl.ds(K // 2 + j, C // 2, stride=K), :]
+                    heads += [(even & low) | (odd << 16),
+                              (even >> 16) | (odd & high)]
+                else:
+                    # a bfloat16 is the high half of the float32 it rounds
+                    w = words[pl.ds(j, C, stride=K // 2), :]
+                    heads += [w << 16, w & high]
+            if packed:
+                return pltpu.bitcast(jnp.stack(heads), jnp.bfloat16)
+            return pltpu.bitcast(jnp.stack(heads),
+                                 jnp.float32).astype(mxu_dtype)
+        x = buf[slot].astype(mxu_dtype)
+        return jnp.swapaxes(x.reshape(C, K, D), 0, 1)
+
+    def online_softmax(i, rows, limit, slot):
+        """Fetch step ``i`` of the query rows ``rows`` (a slice of the
+        tile's ``R*rep``) against the keys and values in ``slot``.
+        ``limit`` broadcasts against the ``[K, rows, C]`` scores:
+        a row sees the columns under it (and, with a window, no more than
+        ``window`` of them); None on an OPEN step, whose every column
+        every row sees: no mask is built and nothing selected."""
+        kt = head_major(bufs[0], slot)
+        vt = kt[:, :, :Dv] if n_pool == 1 else head_major(bufs[1], slot)
         s = jax.lax.dot_general(
             q3_ref[:, rows, :].astype(mxu_dtype), kt,
             (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
-        col = i * C + jax.lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
-        live = col < limit
-        if window is not None:
-            live &= col >= limit - window
-        s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[:, rows, 0:1]
+        if limit is not None:
+            col = i * C + jax.lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
+            live = col < limit
+            if window is not None:
+                live &= col >= limit - window
+            s = jnp.where(live, s, NEG_INF)
+        m_prev = m_ref[:, rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        # a row outside the run has no live column: its max stays NEG_INF,
-        # so the probabilities are zeroed by the mask, not by the exponent
-        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - lanes(m_new, C))
+        if limit is not None:
+            # a row outside the run has no live column: its max stays
+            # NEG_INF, so the probabilities are zeroed by the mask, not by
+            # the exponent
+            p = jnp.where(live, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         pv = jax.lax.dot_general(
             p.astype(mxu_dtype), vt, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        m_ref[:, rows, 0:1] = m_new
-        l_ref[:, rows, 0:1] = alpha * l_ref[:, rows, 0:1] + jnp.sum(
+        m_ref[:, rows, :] = m_new
+        l_ref[:, rows, :] = alpha * l_ref[:, rows, :] + jnp.sum(
             p, axis=2, keepdims=True)
-        acc_ref[:, rows, :] = acc_ref[:, rows, :] * alpha + pv
+        acc_ref[:, rows, :] = acc_ref[:, rows, :] * lanes(alpha, Dv) + pv
 
     def fetch(t, nblk, i, slot, start):
         """Start, or wait for, the copies of blocks ``i*P ..`` of row
@@ -242,47 +367,53 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
         """Rows ``r0 .. r1`` of the tile carry one table: walk it once,
         ``P`` blocks a step, fetch i+1 in flight while i is computed."""
         t = t0 + r0
-        nblk = pl.cdiv(jax.lax.fori_loop(
-            r0, r1, lambda r, n: jnp.maximum(n, length(r)), 0), bs)
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, M, 1), 1)
-        limit = jnp.where((row >= r0 * rep) & (row < r1 * rep),
-                          len_ref[:, 0:1][None], 0)         # [1, M, 1]
+        lo, hi = jax.lax.fori_loop(
+            r0, r1, lambda r, n: (jnp.minimum(n[0], length(r)),
+                                  jnp.maximum(n[1], length(r))),
+            (jnp.int32(2 ** 30), jnp.int32(0)))
+        nblk = pl.cdiv(hi, bs)
+        # a row alone (a decode row) meets the blocks alone; a run meets
+        # them with the whole tile, rows outside it masked out. Which of
+        # the two, and which steps are open, is settled once a walk
+        alone = r1 - r0 == 1
+        begin, first, last, end = step_ranges(
+            lo, hi, C, window, alone | (r1 - r0 == R), jnp)
 
-        # a windowed walk starts at the fetch step that holds the lowest
-        # position any of its rows sees
-        i0 = 0 if window is None else jnp.maximum(jax.lax.fori_loop(
-            r0, r1, lambda r, n: jnp.minimum(n, length(r)),
-            jnp.int32(2 ** 30)) - window, 0) // C
+        def steps(start, stop, rows, limit):
+            def step(i, _):
+                slot = i % 2
 
-        def step(i, _):
-            slot = i % 2
+                @pl.when((i + 1) * P < nblk)
+                def _():
+                    fetch(t, nblk, i + 1, 1 - slot, True)
 
-            @pl.when((i + 1) * P < nblk)
-            def _():
-                fetch(t, nblk, i + 1, 1 - slot, True)
+                fetch(t, nblk, i, slot, False)
+                online_softmax(i, rows, limit, slot)
 
-            fetch(t, nblk, i, slot, False)
-            kt = head_major(bufs[0], slot)
-            vt = kt[:, :, :Dv] if n_pool == 1 else head_major(bufs[1], slot)
-            # a row alone (a decode row) meets the blocks alone; a run
-            # meets them with the whole tile, rows outside it masked out
-            jax.lax.cond(
-                r1 - r0 == 1,
-                lambda: online_softmax(i, kt, vt, pl.ds(r0 * rep, rep),
-                                       length(r0)),
-                lambda: online_softmax(i, kt, vt, slice(None), limit))
+            jax.lax.fori_loop(start, stop, step, None)
 
-        if window is None:
-            fetch(t, nblk, 0, 0, True)
-            jax.lax.fori_loop(0, pl.cdiv(nblk, P), step, None)
-        else:
-            fetch(t, nblk, i0, i0 % 2, True)
-            jax.lax.fori_loop(i0, pl.cdiv(nblk, P), step, None)
+        def attend(rows, limit):
+            if window is not None:         # else the walk begins open
+                steps(begin, first, rows, limit)
+            steps(first, last, rows, None)
+            steps(last, end, rows, limit)
+
+        def alone_form():
+            attend(pl.ds(r0 * rep, rep), length(r0))
+
+        def tile_form():
+            row = jax.lax.broadcasted_iota(jnp.int32, (1, M, 1), 1)
+            limit = jnp.where((row >= r0 * rep) & (row < r1 * rep),
+                              len_ref[:, 0:1][None], 0)         # [1, M, 1]
+            attend(slice(None), limit)
+
+        fetch(t, nblk, begin, begin % 2, True)
+        jax.lax.cond(alone, alone_form, tile_form)
 
         def put(r, _):
             rows = pl.ds(r * rep, rep)
-            l = l_ref[:, rows, 0:1]
-            out = acc_ref[:, rows, :] / jnp.where(l == 0.0, 1.0, l)
+            l = l_ref[:, rows, :]
+            out = acc_ref[:, rows, :] / lanes(jnp.where(l == 0.0, 1.0, l), Dv)
             o_ref[pl.ds(r, 1)] = out.reshape(1, N, Dv).astype(o_ref.dtype)
 
         jax.lax.fori_loop(r0, r1, put, None)

@@ -18,26 +18,34 @@ from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.fastgen import FastGenEngine
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.ops.pallas.paged_attention import (_geometry,
+                                                      count_steps,
                                                       paged_attention,
-                                                      tile_rows)
+                                                      step_ranges, tile_rows)
 
-def _shape(N, K, D, BS, MB, NB, T, dtype):
+def _shape(N, K, D, BS, MB, NB, T, dtype, span=False):
     """A head layout with its pool and tick sizes; ``C``: the cache
-    positions of one fetch step there, by the kernel's own rule."""
+    positions of one fetch step there, by the kernel's own rule. ``span``:
+    the calls of window and full layers over experts: bfloat16 products,
+    one table a sequence beside each row's (``row_table``), and a window
+    of two and a half steps, so that a walk has an edge at either end."""
     pool = jax.ShapeDtypeStruct((NB, BS, K, D), jnp.dtype(dtype))
     C = BS * _geometry(jax.ShapeDtypeStruct((T, N, D), pool.dtype),
                        (pool, pool), D, False)[3]
     return types.SimpleNamespace(N=N, K=K, D=D, BS=BS, MB=MB, NB=NB, T=T,
-                                 dtype=dtype, C=C)
+                                 dtype=dtype, C=C, span=span,
+                                 window=2 * C + C // 2 if span else None)
 
 
 # toy widths: T = 80 is 2.5 tiles of 32 rows (the wrapper pads); a walk is
 # one fetch step there. "mistral": the chat cell's heads, blocks and bf16
-# pool, where a step is C positions and the walks below take up to three
+# pool, where a step is C positions and the walks below take up to three;
+# "trinity": the published head layout of the window and full layers over
+# experts (48 query heads on 8 of 128: a group of 6)
 SHAPES = {
     "rep4": _shape(8, 2, 64, 8, 8, 96, 80, "float32"),
     "rep1": _shape(2, 2, 64, 8, 8, 96, 80, "float32"),
     "mistral": _shape(32, 8, 128, 32, 24, 72, 64, "bfloat16"),
+    "trinity": _shape(48, 8, 128, 32, 24, 72, 64, "bfloat16", span=True),
 }
 
 
@@ -118,9 +126,21 @@ STEP_LAYOUTS = {
     "chunk_across_a_tile_and_a_steps_edge": lambda g, rng: (
         _decode(g, rng, 3, [g.C + 1, 1, 2 * g.C + 17])
         + _chunk(g, rng, g.C - 20, 45)),
+    # the open / edge rule: a window's lower edge a position before, on and
+    # after a step's boundary (without a window: contexts of whole steps);
+    # a chunk none of whose steps is open (its tile's rows end either side
+    # of a step's edge); a tile that is one run, with open steps, beside a
+    # tile of two runs, which has none
+    "windows_lower_edge_at_a_steps_boundary": lambda g, rng: _decode(
+        g, rng, 6, [(g.window or g.C) + g.C + d for d in (-1, 0, 1)]
+        + [(g.window or g.C) + d for d in (-1, 0, 1)]),
+    "chunk_whose_every_step_is_an_edge": lambda g, rng: _chunk(
+        g, rng, g.C - 20, 40),
+    "tile_of_one_run_beside_a_tile_of_two": lambda g, rng: (
+        _chunk(g, rng, 2 * g.C + 5, 45) + _chunk(g, rng, 2 * g.C - 3, 19)),
 }
 CASES = [(h, l) for h in ("rep1", "rep4") for l in sorted(LAYOUTS)] + [
-    ("mistral", l) for l in sorted(STEP_LAYOUTS)]
+    (h, l) for h in ("mistral", "trinity") for l in sorted(STEP_LAYOUTS)]
 
 
 @functools.lru_cache(maxsize=None)
@@ -133,13 +153,28 @@ def _case(heads, dtype):
     q = jnp.asarray(rng.normal(size=(g.T, g.N, g.D)), dt)
     kpool = jnp.asarray(rng.normal(size=(g.NB, g.BS, g.K, g.D)), dt)
     vpool = jnp.asarray(rng.normal(size=(g.NB, g.BS, g.K, g.D)), dt)
-    kernel = jax.jit(functools.partial(paged_attention, interpret=True))
+    if g.span:
+        def kernel(q, kpool, vpool, tables, lengths):
+            # one table a run of rows (row 0 the pad rows'), each row's
+            new = jnp.concatenate([jnp.ones((1,), bool), jnp.any(
+                tables[1:] != tables[:-1], axis=1)])
+            which = jnp.where(jnp.any(tables != 0, axis=1),
+                              jnp.cumsum(new), 0).astype(jnp.int32)
+            by_run = jnp.zeros((g.T + 1, g.MB), jnp.int32).at[which].set(
+                tables)
+            return paged_attention(q, kpool, vpool, by_run, lengths,
+                                   interpret=True, window=g.window,
+                                   mxu_dtype=dt, row_table=which)
+        kernel = jax.jit(kernel)
+    else:
+        kernel = jax.jit(functools.partial(paged_attention, interpret=True))
 
     def reference(q, kpool, vpool, tables, lengths):
         with jax.default_matmul_precision("highest"):
             return PG.paged_attention_reference(
                 q.astype(jnp.float32), kpool.astype(jnp.float32),
-                vpool.astype(jnp.float32), tables, lengths)
+                vpool.astype(jnp.float32), tables, lengths,
+                window=g.window)
 
     return q, kpool, vpool, kernel, jax.jit(reference)
 
@@ -182,10 +217,14 @@ def _operands(q, *pools):
             tuple(jax.ShapeDtypeStruct(p, jnp.bfloat16) for p in pools))
 
 
-# the four serving configurations' operands (bf16, blocks of 32) -> (rows a
+# the five serving configurations' operands (bf16, blocks of 32) -> (rows a
 # tile, blocks a fetch step): a change to one configuration's geometry
 # changes its tick programs, and shows here
+TRINITY = _operands((2048, 48, 128), *[(4 * 29 * 192, 32, 8, 128)] * 2), \
+    _operands((2048, 48, 128), *[(12288, 32, 8, 128)] * 2)
 GEOMETRY = {
+    "trinity-large-preview-swa": (TRINITY[0], 128, False, (32, 4)),
+    "trinity-large-preview-global": (TRINITY[1], 128, False, (32, 4)),
     "mistral-7b": (_operands((512, 32, 128), *[(2400, 32, 8, 128)] * 2),
                    128, False, (32, 4)),
     "pythia-6.9b": (_operands((512, 32, 128), *[(640, 32, 32, 128)] * 2),
@@ -211,6 +250,79 @@ def test_tile_rows_follow_the_accumulator():
     assert tile_rows(32, 128) == 32      # Mistral, Pythia: 0.5 MB
     assert tile_rows(16, 512) == 32      # Moonlight's latents: 1 MB
     assert tile_rows(128, 512) == 16     # never under 16
+
+
+def _brute_steps(lengths, starts, tile, C, window):
+    """(steps, open steps) of a call, column by column: a run's walk takes
+    every step that holds a column some row of it sees; a step is open if
+    the run is one row or its whole tile and every row sees every column
+    of it."""
+    lengths = list(lengths) + [1] * (-len(lengths) % tile)
+    starts = list(starts) + [False] * (len(lengths) - len(starts))
+    steps = n_open = 0
+    r0 = 0
+    while r0 < len(lengths):
+        r1 = r0 + 1
+        while r1 % tile and not starts[r1]:
+            r1 += 1
+        sees = [set(range(max(n - window, 0) if window else 0, n))
+                for n in lengths[r0:r1]]
+        for i in range(max(lengths[r0:r1]) // C + 1):
+            cols = set(range(i * C, (i + 1) * C))
+            # the walk is one stretch of steps: from the first that holds
+            # a live column to the last
+            steps += any(cols & s for s in sees)
+            n_open += r1 - r0 in (1, tile) and all(cols <= s for s in sees)
+        r0 = r1
+    return steps, n_open
+
+
+RULE_CASES = [(C, window) for C in (8, 64, 128)
+              for window in (None, 1, C - 1, C, C + 31, 3 * C, 5 * C + 7)]
+
+
+@pytest.mark.parametrize("C,window", RULE_CASES)
+def test_step_ranges_against_every_column(C, window):
+    """The rule the kernel's walk and the host's count share, against a
+    check of every column of every step for every row."""
+    rng = np.random.default_rng(C + (window or 0))
+    edges = [1, C - 1, C, C + 1, 2 * C, (window or C) + C,
+             (window or C) + C + 1, 4 * C - 1]
+    for trial in range(120):
+        lo = int(edges[trial % len(edges)] if trial < 40
+                 else rng.integers(1, 6 * C))
+        hi = lo + int(rng.integers(0, 32))
+        for whole in (True, False):
+            begin, first, last, end = (int(x) for x in step_ranges(
+                np.int64(lo), np.int64(hi), C, window, whole))
+            assert 0 <= begin <= first <= last <= end
+            sees = [set(range(max(n - window, 0) if window else 0, n))
+                    for n in range(lo, hi + 1)]
+            for i in range(hi // C + 2):
+                cols = set(range(i * C, (i + 1) * C))
+                live = any(cols & s for s in sees)
+                # outside the walk no row sees a column (inside it a step
+                # may hold none: the stretch between a window's end and the
+                # next row's start is never longer than the rows' spread)
+                assert begin <= i < end or not live, (lo, hi, i)
+                assert (first <= i < last) == (
+                    whole and all(cols <= s for s in sees)), (lo, hi, i)
+
+
+@pytest.mark.parametrize("heads,layout",
+                         [(h, l) for h in ("mistral", "trinity")
+                          for l in sorted(STEP_LAYOUTS)],
+                         ids=lambda x: x)
+def test_count_steps_against_every_column(heads, layout):
+    g = SHAPES[heads]
+    rng = np.random.default_rng(sorted(STEP_LAYOUTS).index(layout))
+    rows = STEP_LAYOUTS[layout](g, rng)
+    lengths = np.array([n for _, n in rows] + [1] * (g.T - len(rows)))
+    tabs = [tuple(t) for t, _ in rows] + [(0,) * g.MB] * (g.T - len(rows))
+    starts = [True] + [a != b for a, b in zip(tabs[1:], tabs[:-1])]
+    want = _brute_steps(lengths, starts, 32, g.C, g.window)
+    assert count_steps(lengths, starts, 32, g.C, g.window) == want
+    assert want[0] > 0
 
 
 # --------------------------------------------------------------------- #
@@ -291,3 +403,60 @@ def test_decode_only_run_shares_no_rows():
         assert eng.step()
     assert _counter(PREFILL) > 0
     assert _counter(SHARED) == before
+
+
+def test_tick_span_counts_fetch_steps_and_open_ones():
+    """``attn_steps`` / ``attn_open_steps`` on ``decode_tick`` and
+    ``fastgen_attention_steps_total{form}``: a 150-token prompt in chunks
+    of 64 rows beside a short one, then decode rows, one of them past a
+    step's 128 positions (its first step is open); every tick against the
+    column-by-column count of its rows, two layers a tick."""
+    from deepspeed_tpu.telemetry import tracing
+
+    eng = FastGenEngine("tiny", n_blocks=64, block_size=16,
+                        max_blocks_per_seq=16, token_budget=64,
+                        temperature=0.0, seed=0, use_pallas_kernel=True,
+                        **CFG)
+    (layers, window, C), = eng._walks
+    assert (layers, window, C) == (2, None, 128)
+    rng = np.random.default_rng(8)
+    eng.put([0, 1], [rng.integers(0, 512, n).tolist() for n in (150, 20)])
+
+    def totals():
+        return [_counter(f'fastgen_attention_steps_total{{form="{f}"}}')
+                for f in ("open", "masked")]
+
+    before = totals()
+    tracer = tracing.get_tracer()
+    was, tracer.enabled = tracer.enabled, True
+    want = []
+    try:
+        for _ in range(7):
+            # the tick's rows as the scheduler will lay them: decode rows
+            # (a sequence each), then the chunks in admission order, pads
+            seqs = [eng.seqs[u] for u in eng._admit_order]
+            runs = [[s.pos + 1] for s in seqs
+                    if not s.prefill_remaining and not s.done]
+            room = 64 - len(runs)
+            for s in seqs:
+                n = min(s.prefill_remaining, room)
+                if n:
+                    runs.append(range(s.pos + 1, s.pos + n + 1))
+                    room -= n
+            lengths = [n for run in runs for n in run]
+            starts = [i == 0 for run in runs for i, _ in enumerate(run)]
+            pads = -len(lengths) % 32      # the bucket's and the wrapper's
+            want.append(_brute_steps(
+                lengths + [1] * pads, starts + [True] + [False] * (pads - 1)
+                if pads else starts, 32, C, window))
+            eng.step()
+        events = tracer.export_chrome()["traceEvents"]
+    finally:
+        tracer.enabled = was
+    ticks = [e["args"] for e in events if e.get("name") == "decode_tick"][-7:]
+    assert [(a["attn_steps"], a["attn_open_steps"]) for a in ticks] == [
+        (layers * n, layers * n_open) for n, n_open in want]
+    got = [b - a for a, b in zip(before, totals())]
+    assert got == [layers * sum(o for _, o in want),
+                   layers * sum(n - o for n, o in want)]
+    assert got[0] > 0             # the long sequence's decode rows

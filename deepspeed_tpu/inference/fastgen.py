@@ -31,6 +31,7 @@ prompts stream in (the SplitFuse headline property).
 from __future__ import annotations
 
 import collections
+import itertools
 import time
 from typing import Any, Dict, List, Optional, Sequence, Union
 
@@ -45,6 +46,28 @@ from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.utils.compile_cache import ensure_compile_cache
 
 PyTree = Any
+
+#: a step() tick's phases, in the order their boundaries are read
+#: (``FastGenEngine._account_tick``); ``outside`` is what of a tick's
+#: period lies before its ``schedule_tick``: the caller and the frontend
+TICK_PHASES = ("schedule", "pack", "dispatch", "overlap", "readback",
+               "commit")
+_PERIOD_PARTS = ("outside",) + TICK_PHASES
+#: a tick is slow when its period is this much over its program's typical
+#: period, and by at least this many seconds
+SLOW_TICK_RATIO, SLOW_TICK_MIN_S = 1.25, 1e-3
+#: ticks of a program that only feed its typical values (a plain mean);
+#: after them a mean over about the last ``_TYPICAL_SPAN`` ticks inside
+#: the limit; a program slow this many ticks running is learned anew (its
+#: contexts grew, the machine changed: slow is then the new typical)
+_TYPICAL_WARMUP, _TYPICAL_SPAN, _SLOW_STREAK = 16, 32, 16
+#: ticks between two refreshes of ``process_*`` (``telemetry/host.py``)
+_PROCESS_REFRESH_TICKS = 16
+#: edges of ``fastgen_tick_period_seconds``: a tenth apart from 2 to
+#: 250 ms, so that a p99 is placed to within 5 %
+_PERIOD_BUCKETS = (0.0005, 0.001) + tuple(
+    round(0.002 * 1.1 ** i, 7) for i in range(52)) + (
+    0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
 
 
 class BlockAllocator:
@@ -150,6 +173,15 @@ class _Seq:
 class FastGenEngine:
     """``put/query/flush`` continuous-batching engine (engine_v2 analog)."""
 
+    #: the last 128 slow step() ticks (``_account_tick``), oldest first:
+    #: one record a tick with its number (the ``decode_tick`` span's
+    #: ``tick``), program, period, typical period and every part of both.
+    #: Process-wide like the registry its counters live in, so that a
+    #: reader needs no handle on an engine that may be gone; a record's
+    #: ``engine`` is the ``engine_no`` of the engine that made it
+    slow_ticks: collections.deque = collections.deque(maxlen=128)
+    _engine_numbers = itertools.count()
+
     def __init__(self, cfg: Union[str, T.TransformerConfig],
                  params: Optional[PyTree] = None,
                  n_blocks: int = 128, block_size: int = 32,
@@ -201,6 +233,7 @@ class FastGenEngine:
         self._admit_order: List[int] = []
         self._decode_rr = 0
         self._ticks_run = 0     # step() ticks dispatched, for span attributes
+        self.engine_no = next(FastGenEngine._engine_numbers)
         # HOST-side key stream: deriving per-call subkeys with an eager
         # jax.random.split is a whole device dispatch for an 8-byte op. Any
         # uint32[2] is a valid raw threefry key, so a host PCG stream
@@ -411,6 +444,50 @@ class FastGenEngine:
             "fastgen_kv_blocks_in_use",
             "allocated KV blocks bucketed by the owning sequence's "
             "block-table width tier (quarter/half/full)")
+        # the account of a step() tick's period (``_account_tick``)
+        self._tm_period = telemetry.histogram(
+            "fastgen_tick_period_seconds",
+            "end of the previous step() tick's tick_commit to the end of "
+            "this one's while the engine held a live sequence (after an "
+            "idle stretch: from this tick's schedule_tick), by the tick's "
+            "kind and row bucket",
+            buckets=_PERIOD_BUCKETS)
+        self._tm_phase = telemetry.counter(
+            "fastgen_tick_phase_seconds_total",
+            "seconds of step() ticks by phase (schedule / pack / dispatch "
+            "/ overlap / readback / commit: consecutive clock readings, so "
+            "a tick's phases sum to its schedule_tick entry to tick_commit "
+            "exit) and kind")
+        self._tm_idle = telemetry.counter(
+            "fastgen_engine_idle_seconds_total",
+            "seconds between two step() ticks during which the engine "
+            "held no live sequence: in no tick's period")
+        self._tm_slow = telemetry.counter(
+            "fastgen_slow_ticks_total",
+            "step() ticks whose period was over 1.25 x and 1 ms over their "
+            "program's typical period, by the phase (or outside: caller "
+            "and frontend) whose excess over its own typical was largest")
+        self._tm_slow_excess = telemetry.counter(
+            "fastgen_slow_tick_excess_seconds_total",
+            "seconds by which slow ticks' periods passed their programs' "
+            "typical period, by the phase that owned the tick")
+        self._period_keys: Dict[tuple, tuple] = {}   # (kind, Tn) -> keys
+        # the last step() tick's end (None before the first and after a
+        # fused window, whose ticks are not accounted), and whether the
+        # engine has been without a live sequence since
+        self._tick_end_t: Optional[float] = None
+        self._idle = False
+        self._gc_seen_s = telemetry.gc_pause_seconds()
+        # (tick end, process CPU seconds, this thread's) when the CPU
+        # clocks were last read: every sixteenth tick and at a slow one
+        # (two system calls: not every tick's to pay)
+        self._cpu_seen = (time.perf_counter(), time.process_time(),
+                          time.thread_time())
+        # program -> [ticks fed, slow ticks running, typical seconds of
+        # each of _PERIOD_PARTS]
+        self._typical: Dict[tuple, List[float]] = {}
+        self._tracer = telemetry.get_tracer()
+        telemetry.install_gc_span()
 
     def _mb_tier_name(self, mb: int) -> str:
         """Label for a table width, derived from the SAME bounds as
@@ -423,6 +500,8 @@ class FastGenEngine:
     def _tm_sched_gauges(self) -> None:
         """Refresh queue/pool gauges from host scheduler state."""
         live = [s for s in self.seqs.values() if not s.done]
+        if not live:
+            self._idle = True       # until the next tick: no one waits
         waiting = sum(1 for s in live if s.prefill_remaining > 0)
         self._tm_queue.set(waiting, state="waiting")
         self._tm_queue.set(len(live) - waiting, state="running")
@@ -439,14 +518,16 @@ class FastGenEngine:
         for tier, n in in_use.items():
             self._tm_kv_tier.set(n, tier=tier)
 
-    def _observe_tok_lat(self, per_token_s: float, n: int) -> None:
+    def _observe_tok_lat(self, per_token_s: float, n: int,
+                         now: float) -> None:
         """One funnel for every decode-latency observation: the global
         histogram AND the per-engine accumulators est_token_seconds
-        reads (keeping multi-engine processes unblended)."""
+        reads (keeping multi-engine processes unblended). ``now``: the
+        ``time.perf_counter()`` reading that ended the measured section."""
         self._tm_tok_lat.observe(per_token_s, n=n)
         self._tok_lat_sum += per_token_s * n
         self._tok_lat_n += n
-        idx = int(time.perf_counter() // self._tok_lat_win_interval_s)
+        idx = int(now // self._tok_lat_win_interval_s)
         ring = self._tok_lat_win
         if not ring or ring[-1][0] != idx:
             ring.append([idx, 0.0, 0])
@@ -662,8 +743,9 @@ class FastGenEngine:
             # a cold key folds the XLA compile into the window wall time
             # (~seconds vs ~ms/token) — keep the latency histogram steady-
             # state only, same reason the train side uses best-window
-            self._observe_tok_lat(
-                (time.perf_counter() - t0) / (n * B), n=n * B)
+            now = time.perf_counter()
+            self._observe_tok_lat((now - t0) / (n * B), n * B, now)
+        self._tick_end_t = None     # a fused window is in no tick's period
         self._tm_ticks.inc(n, kind="decode", mb_tier=self._mb_tier_name(mb))
         self._tm_occup.set(B / Bt, phase="decode")
         self._tm_sched_gauges()
@@ -734,8 +816,9 @@ class FastGenEngine:
                 # over the window's tokens IS the per-token serving rate
                 self._observe_tok_lat(
                     (now - prev_drain_t[0]) / max(1, p_n * len(p_live)),
-                    n=p_n * len(p_live))
+                    p_n * len(p_live), now)
             prev_drain_t[0] = now
+            self._tick_end_t = None   # as after decode_steps
             return self._drain_decode_out(
                 out_h, p_live, p_n, pos_advanced=True, pos0=p_pos0)
 
@@ -1035,7 +1118,7 @@ class FastGenEngine:
         # the host-side SplitFuse packing gets its own span so a tick's
         # timeline splits into schedule (host) vs dispatch (device) —
         # the first question about a slow tick is which side it was
-        with telemetry.span("schedule_tick"):
+        with telemetry.span("schedule_tick") as sched_span:
             need = sum(1 for s in live
                        if s.prefill_remaining == 0
                        and s.last_tok is not None)
@@ -1151,7 +1234,6 @@ class FastGenEngine:
         kind = "decode" if n_decode_rows == row else "mixed"
         tier = self._mb_tier_name(mb)
         self._ticks_run += 1
-        t0 = time.perf_counter()
         slot_attrs = {}
         if self.allocator.state_slots:
             slot_attrs = {"state_runs": int(state_runs),
@@ -1177,8 +1259,7 @@ class FastGenEngine:
             # to the device is the call's own), until it returns (the
             # device may still be running), and the copy back queued
             # behind the program
-            with telemetry.span("tick_dispatch",
-                                attrs={"h2d_bytes": packed.nbytes}):
+            with telemetry.span("tick_dispatch") as dispatch_span:
                 sampled, self.pool = self._ticks[key](
                     self.params, self.pool,
                     packed if self._rep_sh is None
@@ -1207,7 +1288,7 @@ class FastGenEngine:
                 tick_span.note(attn_steps=attn_steps,
                                attn_open_steps=attn_open)
             # the wait for the device and for the copy queued behind it
-            with telemetry.span("tick_readback"):
+            with telemetry.span("tick_readback") as readback_span:
                 sampled = np.asarray(sampled)
             expert_rows, commit_attrs = None, None
             if self._expert_layers:
@@ -1230,7 +1311,7 @@ class FastGenEngine:
                     "tick": self._ticks_run,
                     "experts_active": int((expert_rows > 0).sum()),
                     "expert_pairs": pairs, "expert_pairs_held": pairs_held}
-        with telemetry.span("tick_commit", attrs=commit_attrs):
+        with telemetry.span("tick_commit", attrs=commit_attrs) as commit_span:
             if expert_rows is not None:
                 self._tm_expert_pairs.inc(pairs_held, held="yes")
                 self._tm_expert_pairs.inc(pairs - pairs_held, held="no")
@@ -1249,8 +1330,8 @@ class FastGenEngine:
                 # for those hints. Cold keys fold the XLA compile into
                 # wall time and are skipped, same policy as decode_steps.
                 self._observe_tok_lat(
-                    (time.perf_counter() - t0) / n_decode_rows,
-                    n=n_decode_rows)
+                    (commit_span.t0 - tick_span.t0) / n_decode_rows,
+                    n_decode_rows, commit_span.t0)
             self._tm_ticks.inc(kind=kind, mb_tier=tier)
             self._tm_h2d.inc(packed.nbytes)
             self._tm_prefill_tok.inc(row - n_decode_rows)
@@ -1259,7 +1340,7 @@ class FastGenEngine:
                 self._tm_attn_steps.inc(attn_open, form="open")
                 self._tm_attn_steps.inc(attn_steps - attn_open,
                                         form="masked")
-            self._tm_occup.set(row / Tn, phase="mixed")
+            self._tm_occup.set(row / Tn, phase=kind)
             self._tm_sched_gauges()
 
             out: Dict[int, int] = {}
@@ -1270,7 +1351,115 @@ class FastGenEngine:
                 seq.last_tok = tok
                 self._note_token(seq, tok)
                 out[seq.uid] = tok
+            # collections queued since the last tick: in the registry by
+            # the time the tick ends; the process's own counters every
+            # sixteenth tick (and at a slow one)
+            telemetry.refresh_host_counters(
+                process=not self._ticks_run % _PROCESS_REFRESH_TICKS)
+        # the spans' own readings, each the end of one phase and the
+        # start of the next
+        self._account_tick(
+            kind, Tn, mb, tier, row, cold,
+            (sched_span.t0, tick_span.t0, dispatch_span.t0, dispatch_span.t1,
+             readback_span.t0, readback_span.t1, commit_span.t1))
         return out
+
+    def _account_tick(self, kind: str, Tn: int, mb: int, tier: str,
+                      rows: int, cold: bool, at: tuple) -> None:
+        """Where this tick's period went, and whether it was slow.
+
+        ``at``: the clock readings that bound the tick's phases, first the
+        entry of ``schedule_tick``, last the exit of ``tick_commit``. The
+        period runs from the end of the previous tick to the end of this
+        one; what of it lies before ``at[0]`` is ``outside`` the engine
+        (the caller, the frontend around ``step()``). After a stretch
+        without a live sequence the period starts at ``at[0]`` and the
+        stretch counts as idle, so over any run of ticks wall time =
+        periods + idle, and a period = outside + the six phases.
+
+        A program is (kind, row bucket, table width). Its typical parts
+        are a mean fed by the ticks inside the limit only, so a slow tick
+        moves nothing; a slow tick is counted under the part whose own
+        excess over its typical value is largest, and remembered in
+        ``slow_ticks``. Cold ticks (the program compiled inside them) and
+        a program's first ticks are not judged.
+
+        6.2 us a tick measured on the sandbox's CPU, 8.5 with the rest of
+        what a tick pays for its account
+        (``tests/unit/test_tick_account.py``'s guard)."""
+        start, end = at[0], at[-1]
+        if self._tick_end_t is not None:
+            if self._idle:
+                self._tm_idle.inc(start - self._tick_end_t)
+            else:
+                start = self._tick_end_t
+        self._tick_end_t, self._idle = end, False
+        gc_seen = telemetry.gc_pause_seconds()
+        gc_s, self._gc_seen_s = gc_seen - self._gc_seen_s, gc_seen
+        parts = (at[0] - start, at[1] - at[0], at[2] - at[1], at[3] - at[2],
+                 at[4] - at[3], at[5] - at[4], at[6] - at[5])
+        period = end - start
+        keys = self._period_keys.get((kind, Tn))
+        if keys is None:
+            keys = self._period_keys[(kind, Tn)] = (
+                telemetry.label_key(kind=kind, bucket=f"T{Tn}"),
+                tuple(telemetry.label_key(phase=p, kind=kind)
+                      for p in TICK_PHASES))
+        self._tm_period.observe_key(keys[0], period)
+        self._tm_phase.inc_keys(keys[1], parts[1:])
+        if cold:
+            return
+        typ = self._typical.get((kind, Tn, mb))
+        if typ is None:
+            self._typical[(kind, Tn, mb)] = [1, 0, *parts]
+            return
+        typical = sum(typ[2:])
+        if typ[0] >= _TYPICAL_WARMUP and period - typical >= max(
+                SLOW_TICK_MIN_S, (SLOW_TICK_RATIO - 1.0) * typical):
+            self._note_slow_tick(kind, Tn, mb, tier, rows, parts, typ, gc_s,
+                                 end)
+            return
+        if not self._ticks_run % _PROCESS_REFRESH_TICKS:
+            self._cpu_seen = (end, time.process_time(), time.thread_time())
+        typ[0] += 1
+        typ[1] = 0
+        share = 1.0 / min(typ[0], _TYPICAL_SPAN)
+        for i, x in enumerate(parts, 2):
+            typ[i] += share * (x - typ[i])
+
+    def _note_slow_tick(self, kind: str, Tn: int, mb: int, tier: str,
+                        rows: int, parts: tuple, typ: List[float],
+                        gc_s: float, end: float) -> None:
+        typical = typ[2:]
+        over = [x - t for x, t in zip(parts, typical)]
+        owner = _PERIOD_PARTS[over.index(max(over))]
+        period, usual = sum(parts), sum(typical)
+        seen = self._cpu_seen
+        now = self._cpu_seen = (end, time.process_time(), time.thread_time())
+        self._tm_slow.inc(phase=owner, kind=kind)
+        self._tm_slow_excess.inc(period - usual, phase=owner, kind=kind)
+        # the tick's number is the one its decode_tick annotation carries:
+        # a slow tick of a traced stretch is found in the device trace
+        # through that span's run_id
+        record = {"tick": self._ticks_run, "engine": self.engine_no,
+                  "kind": kind, "bucket": Tn, "mb_tier": tier, "rows": rows,
+                  "phase": owner, "period_s": period,
+                  "typical_period_s": usual, "gc_s": gc_s,
+                  # CPU seconds, every thread's and this one's, of the
+                  # ``cpu_wall_s`` that end with this tick (at most sixteen
+                  # ticks): short of that wall by about the stall, the
+                  # process was not running (descheduled, or frozen)
+                  "cpu_s": now[1] - seen[1], "thread_cpu_s": now[2] - seen[2],
+                  "cpu_wall_s": end - seen[0],
+                  **{f"{p}_s": x for p, x in zip(_PERIOD_PARTS, parts)},
+                  **{f"typical_{p}_s": t
+                     for p, t in zip(_PERIOD_PARTS, typical)}}
+        self.slow_ticks.append(record)
+        self._tracer.event("slow_tick", **record)
+        telemetry.refresh_host_counters()
+        typ[1] += 1
+        if typ[1] >= _SLOW_STREAK:
+            del self._typical[(kind, Tn, mb)]
 
     def _note_token(self, seq: _Seq, tok: int,
                     pos: Optional[int] = None) -> None:

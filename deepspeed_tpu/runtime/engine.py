@@ -2739,6 +2739,11 @@ class DeepSpeedTPUEngine:
             self._tm_heartbeat.set(time.time())
             if self._watchdog is not None:
                 self._watchdog.beat()
+            # the process's CPU seconds and context switches, as a serving
+            # tick refreshes them (telemetry/host.py)
+            from deepspeed_tpu import telemetry
+
+            telemetry.refresh_host_counters()
         if self.lr_scheduler is not None:
             self.lr_scheduler.step(self.global_steps)
         if self.global_steps % max(1, self.config.steps_per_print) == 0:

@@ -83,6 +83,10 @@ FAILED = "failed"
 REJECTED = "rejected"
 ACTIVE = "active"
 
+#: the two series of ``serving_loop_seconds_total``, keyed once
+_LOOP_PARTS = (telemetry.label_key(part="tick"),
+               telemetry.label_key(part="caller"))
+
 
 @dataclasses.dataclass
 class RequestResult:
@@ -186,6 +190,9 @@ class ServingFrontend:
         # in the same thread can't observe a hang while it's blocked inside
         # the tick, so post-hoc duration is its hang-vs-crash evidence
         self.last_tick_duration_s: float = 0.0   # guarded-by: single-writer
+        # the last return of run_tick while a request was still active
+        # (None: none was, and the wait for the next is no one's share)
+        self._loop_return_t: Optional[float] = None
         # the default tracer is a stable singleton (configure mutates it
         # in place) — cache the handle; every call is a no-op while
         # tracing is disabled
@@ -230,6 +237,14 @@ class ServingFrontend:
 
     # ------------------------------------------------------------------ #
     def _setup_telemetry(self) -> None:
+        telemetry.install_gc_span()
+        self._tm_loop = telemetry.counter(
+            "serving_loop_seconds_total",
+            "seconds of the serving loop by part: tick (run_tick entry to "
+            "return) / caller (the previous return to this entry while a "
+            "request was active). Over a run of ticks the engine's tick "
+            "periods sum to caller + tick; tick less the engine's six "
+            "phases is the frontend's own share")
         self._tm_admit = telemetry.counter(
             "serving_admitted_total", "requests admitted past the front-end")
         self._tm_reject = telemetry.counter(
@@ -686,6 +701,10 @@ class ServingFrontend:
         succeeded; False when the circuit rejected it or it failed (the
         failure is absorbed — the loop NEVER sees the exception)."""
         t0 = self.clock()
+        # since the last return, with a request waiting: the caller's
+        # share of the loop (its submits, its reads of what is new)
+        caller_s = t0 - self._loop_return_t \
+            if self._loop_return_t is not None else 0.0
         self.last_tick_t = t0              # heartbeat: the loop is alive
         try:
             return self._run_tick_guarded()
@@ -693,7 +712,10 @@ class ServingFrontend:
             # every exit (success, rejection, absorbed failure, even a
             # propagating KeyboardInterrupt) stamps the duration a router
             # reads for post-hoc hang detection
-            self.last_tick_duration_s = self.clock() - t0
+            t1 = self.clock()
+            self.last_tick_duration_s = t1 - t0
+            self._tm_loop.inc_keys(_LOOP_PARTS, (t1 - t0, caller_s))
+            self._loop_return_t = t1 if self._reqs else None
 
     def _run_tick_guarded(self) -> bool:
         if not self.breaker.allow():

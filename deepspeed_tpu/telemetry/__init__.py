@@ -48,9 +48,15 @@ from deepspeed_tpu.telemetry.registry import (
     Gauge,
     Histogram,
     MetricsRegistry,
+    label_key,
 )
 from deepspeed_tpu.telemetry.spans import StallWatchdog, span as _span
-from deepspeed_tpu.telemetry import tracing
+from deepspeed_tpu.telemetry import host, tracing
+from deepspeed_tpu.telemetry.host import (
+    gc_pause_seconds,
+    install_gc_span,
+    refresh as refresh_host_counters,
+)
 from deepspeed_tpu.telemetry.tracing import (
     Tracer,
     configure as configure_tracing,
@@ -65,6 +71,8 @@ __all__ = [
     "start_metrics_server", "stop_metrics_server", "add_collector", "reset",
     "register_health_probe", "unregister_health_probe", "health_report",
     "health_probe_names", "clear_health_probes", "unique_health_probe_name",
+    "label_key", "install_gc_span", "gc_pause_seconds",
+    "refresh_host_counters",
 ]
 
 
@@ -116,10 +124,11 @@ def stop_metrics_server() -> None:
 
 def reset() -> None:
     """Tests only: stop the server, clear the default registry, drop any
-    registered health probes and /slo provider, and disable/clear the
-    default tracer."""
+    registered health probes and /slo provider, take the ``gc_pause``
+    span out of ``gc.callbacks``, and disable/clear the default tracer."""
     _stop_server()
     clear_health_probes()
     clear_slo_provider()
+    host.reset()
     tracing.reset()
     _default_registry.reset()

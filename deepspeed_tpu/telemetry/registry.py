@@ -71,6 +71,13 @@ def _label_key(labels: Dict[str, Any]) -> LabelKey:
     return tuple(sorted((k, str(v)) for k, v in labels.items()))
 
 
+def label_key(**labels) -> LabelKey:
+    """The key of one label set, for a caller that records the same few
+    series every tick and builds their keys once (``Counter.inc_keys``,
+    ``Histogram.observe_key``)."""
+    return _label_key(labels)
+
+
 def format_labels(key: LabelKey) -> str:
     if not key:
         return ""
@@ -108,6 +115,20 @@ class Counter(_Metric):
         key = _label_key(labels)
         with self._lock:
             self._children[key] = self._children.get(key, 0.0) + amount
+
+    def inc_keys(self, keys: Sequence[LabelKey],
+                 amounts: Sequence[float]) -> None:
+        """``inc`` of several series under one acquisition of the lock,
+        by keys built once (``label_key``): a per-tick account pays for
+        no sorting of label names and no ``str()``."""
+        if not self._enabled():
+            return
+        if min(amounts) < 0:
+            raise ValueError(f"counter {self.name} cannot decrease")
+        children = self._children
+        with self._lock:
+            for key, amount in zip(keys, amounts):
+                children[key] = children.get(key, 0.0) + amount
 
     def value(self, **labels) -> float:
         with self._lock:
@@ -232,6 +253,12 @@ class Histogram(_Metric):
             return
         with self._lock:
             self._observe_locked(_label_key(labels), float(value), n)
+
+    def observe_key(self, key: LabelKey, value: float) -> None:
+        """``observe`` by a key built once (``label_key``)."""
+        if self._enabled():
+            with self._lock:
+                self._observe_locked(key, float(value), 1)
 
     def _observe_locked(self, key: LabelKey, value: float, n: int) -> None:
         """The observation itself; the caller holds the lock and has

@@ -76,10 +76,15 @@ class span:
     A slotted class, not a generator context manager; the histogram is
     held by the registry and the annotation class and the tracer by this
     module, so nothing is looked up by name on a call: a tick pays for
-    eight of these."""
+    eight of these.
+
+    ``t0`` / ``t1`` are the span's own two readings of
+    ``time.perf_counter()``, kept so that an account of consecutive
+    sections (a serving tick's phases) reads no clock of its own: the
+    boundary between two phases is one reading, used once."""
 
     __slots__ = ("_name", "_registry", "_key", "_attrs", "_labels",
-                 "_ann", "_rec", "_t0")
+                 "_ann", "_rec", "t0", "t1")
 
     def __init__(self, name: str,
                  registry: MetricsRegistry = DEFAULT_REGISTRY,
@@ -103,7 +108,7 @@ class span:
             rec.__enter__()
         else:
             self._rec = None
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def note(self, **attrs) -> None:
@@ -114,11 +119,11 @@ class span:
             self._rec.rec.attrs.update(attrs)
 
     def __exit__(self, exc_type, exc, tb):
-        dt = time.perf_counter() - self._t0
+        t1 = self.t1 = time.perf_counter()
         if self._rec is not None:
             self._rec.__exit__(exc_type, exc, tb)
         self._ann.__exit__(exc_type, exc, tb)
-        self._registry.observe_span(self._key, self._name, dt)
+        self._registry.observe_span(self._key, self._name, t1 - self.t0)
         return False
 
 

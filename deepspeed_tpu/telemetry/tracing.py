@@ -259,12 +259,14 @@ class Tracer:
         self._push(rec)
 
     def record_span(self, name: str, duration_s: float, cat: str = "span",
-                    **attrs) -> None:
+                    end: Optional[float] = None, **attrs) -> None:
         """Record an already-measured section ending now (the compile-log
-        path: the caller timed the work itself)."""
+        path: the caller timed the work itself), or at ``end``, a
+        ``time.perf_counter()`` reading (a garbage-collection pause is
+        handed over after the fact: ``telemetry/host.py``)."""
         if not self.enabled:
             return
-        now = time.perf_counter()
+        now = time.perf_counter() if end is None else end
         span_id = self._alloc_id()
         rec = _SpanRecord(span_id, span_id, 0, name, cat,
                           threading.get_ident(), now - max(0.0, duration_s),

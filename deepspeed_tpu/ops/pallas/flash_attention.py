@@ -13,14 +13,18 @@ triton flash path ``ops/transformer/inference/triton/attention.py``). Online
 * GQA: kv tensors stay at [batch*kv_heads, S, D]; the q-head → kv-head
   mapping happens in the BlockSpec index maps (no ``jnp.repeat`` in HBM, and
   VJP residuals hold the small kv tensors);
-* causal masking skips fully-masked kv blocks (upper-triangular block tiles
-  are never computed);
+* a grid step does only what its block needs: a block above the diagonal is
+  neither computed nor copied (the index maps hold the moving block where
+  it was); a block goes tile by tile, with no mask where every column is
+  live, and a diagonal or edge block skips its tiles above the diagonal or
+  past a length; :func:`step_account` counts all of it from the shapes;
 * CPU fallback = ``interpret=True`` (the role the reference's CPU op builders
   play for its CUDA ops).
 """
 from __future__ import annotations
 
 import functools
+import inspect
 import math
 from typing import Optional
 
@@ -44,9 +48,12 @@ def _compiler_params():
     return None
 
 
-def _block_mask(q_start, kv_start, shape, causal, kv_len, q_len=None):
-    row = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    col = kv_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+def _block_mask(q_start, kv_start, shape, causal, kv_len, q_len=None,
+                q_axis=0):
+    """Which scores of a block count; ``q_axis`` 1: of a block laid keys
+    down and queries across."""
+    row = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    col = kv_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     mask = col < kv_len
     if q_len is not None:
         mask = jnp.logical_and(mask, row < q_len)
@@ -56,13 +63,192 @@ def _block_mask(q_start, kv_start, shape, causal, kv_len, q_len=None):
 
 
 # --------------------------------------------------------------------------- #
+# which steps of a grid are live, which of those need a mask, and which block
+# a step names: ONE set of rules for the kernels' bodies, their index maps and
+# ``step_account``. Every function takes Python ints (the account) or traced
+# scalars (a kernel, an index map).
+# --------------------------------------------------------------------------- #
+
+def _least(a, b):
+    both = isinstance(a, int) and isinstance(b, int)
+    return min(a, b) if both else jnp.minimum(a, b)
+
+
+def _most(a, b):
+    both = isinstance(a, int) and isinstance(b, int)
+    return max(a, b) if both else jnp.maximum(a, b)
+
+
+def _step_kind(q_start, kv_start, *, causal, kv_len, q_len, block_q, block_kv):
+    """``(live, masked)`` of the block or tile at ``(q_start, kv_start)``:
+    live if any of its columns counts for any of its rows; masked if some do
+    not (it crosses the diagonal or an unpadded length), else every one does
+    and the body builds no mask. A block of a grid always starts inside the
+    lengths (they are padded to the next block, no further); a tile of an
+    edge block may lie past them. ``q_len`` None: rows past the length are
+    computed and sliced off (forward, ``dq``)."""
+    live = kv_start < kv_len
+    masked = kv_start + block_kv > kv_len
+    if q_len is not None:
+        live = live & (q_start < q_len)
+        masked = masked | (q_start + block_q > q_len)
+    if causal:
+        live = live & (kv_start <= q_start + block_q - 1)
+        masked = masked | (kv_start + block_kv - 1 > q_start)
+    return live, masked
+
+
+# rows and columns of the tiles a block is computed in: a block is what a step
+# copies, a tile what one product covers, and a tile of a diagonal or edge
+# block with no live column is skipped like a dead block of the grid
+_TILE = 512
+
+
+def _tiles(q_start, kv_start, tile, *, causal, kv_len, q_len, block_q,
+           block_kv):
+    """``(row offset, column offset, rows, columns, live)`` of each tile of
+    the block at ``(q_start, kv_start)``, rows outermost (a row's tiles in
+    the order of their columns)."""
+    tq = tile if block_q % tile == 0 else block_q
+    tkv = tile if block_kv % tile == 0 else block_kv
+    return [(r, c, tq, tkv, _step_kind(
+        q_start + r, kv_start + c, causal=causal, kv_len=kv_len, q_len=q_len,
+        block_q=tq, block_kv=tkv)[0])
+        for r in range(0, block_q, tq) for c in range(0, block_kv, tkv)]
+
+
+def _kv_block(i, j, *, causal, block_q, block_kv):
+    """The K / V block that step ``(i, j)`` of ``fwd`` / ``dq`` names: ``j``
+    held at the last block the row's diagonal reaches, so that a dead step
+    names the block the step before it had and nothing is copied."""
+    return _least(j, ((i + 1) * block_q - 1) // block_kv) if causal else j
+
+
+def _q_block(i, j, *, causal, q_len, block_q, block_kv):
+    """The Q / dO / lse / delta block that step ``(j, i)`` of ``dkv`` names:
+    ``i`` held at or under the column's diagonal (a dead step above it names
+    the first live block, which the next live step wants anyway; keys past
+    the last query, Skv > S, have none and name the last block)."""
+    if not causal:
+        return i
+    return _least(_most(i, (j * block_kv) // block_q), (q_len - 1) // block_q)
+
+
+def step_account(S: int, Skv: int, causal: bool, block_q: int, block_kv: int,
+                 rep: int = 1):
+    """What the three grids do at these blocks, from the shapes alone (the
+    grids are static): ``{kernel: {"steps", "live", "masked", "open",
+    "fetched", "computed"}}`` for ONE index of the grid's leading axis (a
+    query head for ``flash_fwd`` / ``flash_dq``, a KV head with its ``rep``
+    query heads for ``flash_dkv``). ``masked``: live steps that build a mask
+    (diagonal or edge) and go tile by tile, ``open``: live steps that do not;
+    ``fetched``: steps whose moving block differs from the step before (a
+    copy from HBM); ``computed``: score elements multiplied out (open blocks
+    whole, masked blocks' live tiles)."""
+    n_q = -(-S // block_q)
+    n_kv = -(-Skv // block_kv)
+    shape = dict(causal=causal, block_q=block_q, block_kv=block_kv)
+
+    def walk(steps, q_len):
+        out = dict(steps=0, live=0, masked=0, open=0, fetched=0, computed=0)
+        before = None
+        at = dict(kv_len=Skv, q_len=q_len, **shape)
+        for i, j, block in steps:
+            live, masked = _step_kind(i * block_q, j * block_kv, **at)
+            out["steps"] += 1
+            out["live"] += live
+            out["masked"] += live and masked
+            out["open"] += live and not masked
+            out["fetched"] += block != before
+            if live and masked:
+                out["computed"] += sum(
+                    tq * tkv * t_live for _, _, tq, tkv, t_live in _tiles(
+                        i * block_q, j * block_kv, _TILE, **at))
+            elif live:
+                out["computed"] += block_q * block_kv
+            before = block
+        return out
+
+    rows = walk(((i, j, _kv_block(i, j, **shape))
+                 for i in range(n_q) for j in range(n_kv)), None)
+    cols = walk(((i, j, (h, _q_block(i, j, q_len=S, **shape)))
+                 for j in range(n_kv) for h in range(rep)
+                 for i in range(n_q)), S)
+    return {"flash_fwd": rows, "flash_dq": dict(rows), "flash_dkv": cols}
+
+
+def _set_gauges(kernels, *shape):
+    """The account of the calls being traced (``step_account``'s arguments),
+    in the registry."""
+    from deepspeed_tpu import telemetry
+
+    gauge = telemetry.gauge(
+        "flash_steps", "grid steps of the last traced flash kernel call for "
+        "one index of its leading axis, by kind (step_account)")
+    account = step_account(*shape)
+    for kernel in kernels:
+        for kind, n in account[kernel].items():
+            gauge.set(n, kernel=kernel, kind=kind)
+
+
+def _run_step(tile, q_start, kv_start, tile_size, **shape):
+    """A live step of the block at ``(q_start, kv_start)``, tile after tile.
+    An open block builds no mask and runs straight through,
+    ``tile(rows, cols, None)`` (in the forward, where a tile's exp waits for
+    its rows' max, 1,024 x 1,024 as four tiles takes 8 % less than as one
+    product; the backward kernels take the same either way); a diagonal or
+    edge block goes under its mask, ``tile(rows, cols, (the tile's q_start,
+    kv_start))``, its dead tiles skipped."""
+    live, masked = _step_kind(q_start, kv_start, **shape)
+    tiles = _tiles(q_start, kv_start, tile_size, **shape)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(masked)))
+    def _open():
+        for r, c, tq, tkv, _ in tiles:
+            tile(pl.ds(r, tq), pl.ds(c, tkv), None)
+
+    @pl.when(jnp.logical_and(live, masked))
+    def _tile_by_tile():
+        for r, c, tq, tkv, t_live in tiles:
+            pl.when(t_live)(functools.partial(
+                tile, pl.ds(r, tq), pl.ds(c, tkv),
+                (q_start + r, kv_start + c)))
+
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_LANES = 128
+
+
+def _lanes(x, n):
+    """``x [rows, 128]``, every lane the same, as ``[rows, n]``: a row's
+    statistic beside each of its ``n`` columns without a lane broadcast."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return jnp.concatenate([x] * (n // _LANES), axis=1)
+
+
+_NT = ((1,), (1,))     # a @ b.T
+_NN = ((1,), (0,))     # a @ b
+
+
+# --------------------------------------------------------------------------- #
 # forward kernel
 # --------------------------------------------------------------------------- #
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
                 *, scale: float, causal: bool, kv_len: int,
-                block_q: int, block_kv: int):
+                block_q: int, block_kv: int, tile: int):
     i = pl.program_id(1)
     j = pl.program_id(2)
     n_kv = pl.num_programs(2)
@@ -73,69 +259,80 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # causal: skip blocks strictly above the diagonal; always skip blocks
-    # fully beyond the (unpadded) kv length
-    q_start = i * block_q
-    kv_start = j * block_kv
-    run = kv_start < kv_len
-    if causal:
-        run = jnp.logical_and(run, kv_start <= q_start + block_q - 1)
+    def _tile(rows, cols, mask_at):
+        # operands widened, probabilities left float32: the MXU rounds
+        # them as a cast would (results equal to the bit) and a cast of
+        # p / ds costs 1-2 % of a call. The scale stays on the scores:
+        # folded into bfloat16 queries it would round them.
+        s = _dot(_f32(q_ref[0, rows, :]), _f32(k_ref[0, cols, :]),
+                 _NT) * scale
+        if mask_at is not None:
+            s = jnp.where(_block_mask(*mask_at, s.shape, causal, kv_len),
+                          s, NEG_INF)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [bq, bkv]
-        s = jnp.where(_block_mask(q_start, kv_start, s.shape, causal, kv_len),
-                      s, NEG_INF)
-
-        m_prev = m_ref[:, 0:1]                            # [bq, 1]
-        l_prev = l_ref[:, 0:1]
+        # the statistics lie replicated over their 128 lanes: no step
+        # slices a lane out of them or broadcasts one back
+        m_prev = m_ref[rows, :]                           # [tq, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                            # [bq, bkv]
-        alpha = jnp.exp(m_prev - m_new)                   # [bq, 1]
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))        # [tq, tkv]
+        alpha = jnp.exp(m_prev - m_new)                   # [tq, 128]
+        l_ref[rows, :] = alpha * l_ref[rows, :] + jnp.sum(
+            p, axis=1, keepdims=True)
+        m_ref[rows, :] = m_new
 
-        v = v_ref[0].astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, d]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:, 0:1] = m_new
-        l_ref[:, 0:1] = l_new
+        pv = _dot(p, _f32(v_ref[0, cols, :]), _NN)        # [tq, d]
+        acc_ref[rows, :] = acc_ref[rows, :] * _lanes(alpha, pv.shape[1]) + pv
+
+    _run_step(_tile, i * block_q, j * block_kv, tile, causal=causal,
+              kv_len=kv_len, q_len=None, block_q=block_q, block_kv=block_kv)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
-        l = l_ref[:, 0:1]
+        l = l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse = m_ref[:, 0:1] + jnp.log(l_safe)
-        lse_ref[0] = jnp.where(l == 0.0, NEG_INF, lse)
+        o_ref[0] = (acc_ref[...] / _lanes(l_safe, acc_ref.shape[1])).astype(
+            o_ref.dtype)
+        lse = jnp.where(l == 0.0, NEG_INF, m_ref[...] + jnp.log(l_safe))
+        lse_ref[0] = lse[:, 0:1]
 
 
-def _fwd(q, k, v, *, scale, causal, kv_len, rep, block_q, block_kv, interpret):
+def _row_specs(rep, D, block_q, block_kv, kv_block):
+    """Block specs of a ``(BN, n_q, n_kv)`` grid: a row's own block and the
+    moving K / V block."""
+    kv_of = _kv_index(rep)
+    row = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    stat = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    kv = pl.BlockSpec((1, block_kv, D),
+                      lambda b, i, j: (kv_of(b), kv_block(i, j), 0))
+    return row, stat, kv
+
+
+# Each call sits in an inlined inner ``jit``: a training step traces the
+# forward and the backward rule several times over (linearize, remat, the
+# transpose), and a body of eight tiles traced anew each time cost 3-5 s of
+# the one-chip cell's ``setup_s``; the inner trace is cached by shapes.
+def _inlined_call(build):
+    static = tuple(
+        name for name, arg in inspect.signature(build).parameters.items()
+        if arg.kind is arg.KEYWORD_ONLY)
+    return jax.jit(build, inline=True, static_argnames=static)
+
+
+@_inlined_call
+def _fwd(q, k, v, *, scale, causal, kv_len, rep, block_q, block_kv, tile,
+         interpret):
     BN, S_pad, D = q.shape
     BK, Skv_pad, _ = k.shape
-    n_q = S_pad // block_q
-    n_kv = Skv_pad // block_kv
-    kv_of = _kv_index(rep)
+    shape = dict(causal=causal, block_q=block_q, block_kv=block_kv)
+    row, stat, kv = _row_specs(rep, D, block_q, block_kv,
+                               functools.partial(_kv_block, **shape))
 
     o, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel, scale=scale, causal=causal, kv_len=kv_len,
-            block_q=block_q, block_kv=block_kv),
-        grid=(BN, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, i, j: (kv_of(b), j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, i, j: (kv_of(b), j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
+        functools.partial(_fwd_kernel, scale=scale, kv_len=kv_len, tile=tile,
+                          **shape),
+        grid=(BN, S_pad // block_q, Skv_pad // block_kv),
+        in_specs=[row, kv, kv],
+        out_specs=[row, stat],
         out_shape=[
             jax.ShapeDtypeStruct((BN, S_pad, D), q.dtype),
             # per-row logsumexp; trailing dim 1 == array dim keeps the TPU
@@ -160,7 +357,7 @@ def _fwd(q, k, v, *, scale, causal, kv_len, rep, block_q, block_kv, interpret):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    acc_ref, *, scale: float, causal: bool, kv_len: int,
-                   block_q: int, block_kv: int):
+                   block_q: int, block_kv: int, tile: int):
     i = pl.program_id(1)
     j = pl.program_id(2)
     n_kv = pl.num_programs(2)
@@ -169,44 +366,30 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q_start = i * block_q
-    kv_start = j * block_kv
-    run = kv_start < kv_len
-    if causal:
-        run = jnp.logical_and(run, kv_start <= q_start + block_q - 1)
+    def _tile(rows, cols, mask_at):
+        k = _f32(k_ref[0, cols, :])
+        s = _dot(_f32(q_ref[0, rows, :]), k, _NT) * scale
+        p = jnp.exp(s - lse_ref[0, rows, :])               # [tq, tkv]
+        if mask_at is not None:
+            p = jnp.where(_block_mask(*mask_at, s.shape, causal, kv_len),
+                          p, 0.0)
+        dp = _dot(_f32(do_ref[0, rows, :]), _f32(v_ref[0, cols, :]), _NT)
+        # the scale: once, at _finalize
+        ds = p * (dp - delta_ref[0, rows, :])
+        acc_ref[rows, :] += _dot(ds, k, _NN)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]                                   # [bq, 1]
-        delta = delta_ref[0]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(q_start, kv_start, s.shape, causal, kv_len)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)         # [bq, bkv]
-
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bkv]
-        ds = p * (dp - delta) * scale
-        acc_ref[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _run_step(_tile, i * block_q, j * block_kv, tile, causal=causal,
+              kv_len=kv_len, q_len=None, block_q=block_q, block_kv=block_kv)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale: float, causal: bool, kv_len: int, q_len: int,
-                    n_q: int, block_q: int, block_kv: int):
+                    n_q: int, block_q: int, block_kv: int, tile: int):
     j = pl.program_id(1)       # kv block (outer)
     inner = pl.program_id(2)   # (q-head-in-group, q block) flattened (inner)
     n_inner = pl.num_programs(2)
@@ -217,43 +400,30 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start = i * block_q
-    kv_start = j * block_kv
-    run = jnp.logical_and(kv_start < kv_len, q_start < q_len)
-    if causal:
-        run = jnp.logical_and(run, kv_start <= q_start + block_q - 1)
+    def _tile(rows, cols, mask_at):
+        # scores and probabilities TRANSPOSED, keys down and queries
+        # across: dv and dk take them as they lie (no transposed left
+        # operand), and a query's lse / delta is a lane's, laid beside the
+        # keys by a sublane broadcast
+        q = _f32(q_ref[0, rows, :])
+        do = _f32(do_ref[0, rows, :])
+        s = _dot(_f32(k_ref[0, cols, :]), q, _NT) * scale  # [tkv, tq]
+        p = jnp.exp(s - lse_ref[0, 0, :, rows])
+        if mask_at is not None:
+            p = jnp.where(_block_mask(*mask_at, s.shape, causal, kv_len,
+                                      q_len, q_axis=1), p, 0.0)
+        dv_acc[cols, :] += _dot(p, do, _NN)
+        dp = _dot(_f32(v_ref[0, cols, :]), do, _NT)        # [tkv, tq]
+        # the scale: once, at _finalize
+        ds = p * (dp - delta_ref[0, 0, :, rows])
+        dk_acc[cols, :] += _dot(ds, q, _NN)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [bq, bkv]
-        mask = _block_mask(q_start, kv_start, s.shape, causal, kv_len, q_len)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-
-        # dv += p^T @ do
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bkv, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bkv]
-        ds = p * (dp - delta) * scale
-        # dk += ds^T @ q
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _run_step(_tile, i * block_q, j * block_kv, tile, causal=causal,
+              kv_len=kv_len, q_len=q_len, block_q=block_q, block_kv=block_kv)
 
     @pl.when(inner == n_inner - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
@@ -271,34 +441,20 @@ def _kv_index(rep: int):
     return kv_of
 
 
-def _bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
-         residuals, g):
-    q, k, v, o, lse = residuals
-    do = g
-    interpret = _use_interpret()
+@_inlined_call
+def _bwd_dq(q, k, v, do, lse, delta, *, scale, causal, kv_len, rep, block_q,
+            block_kv, tile, interpret):
     BN, S_pad, D = q.shape
-    BK, Skv_pad, _ = k.shape
-    n_q = S_pad // block_q
-    n_kv = Skv_pad // block_kv
-    kv_of = _kv_index(rep)
-
-    # delta_r = rowsum(dO * O) — cheap elementwise, let XLA fuse it
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)                # [BN, S_pad, 1]
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          kv_len=kv_len, block_q=block_q, block_kv=block_kv),
-        grid=(BN, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, i, j: (kv_of(b), j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, i, j: (kv_of(b), j, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+    Skv_pad = k.shape[1]
+    shape = dict(causal=causal, block_q=block_q, block_kv=block_kv)
+    row, stat, kv = _row_specs(rep, D, block_q, block_kv,
+                               functools.partial(_kv_block, **shape))
+    return pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=scale, kv_len=kv_len,
+                          tile=tile, **shape),
+        grid=(BN, S_pad // block_q, Skv_pad // block_kv),
+        in_specs=[row, kv, kv, row, stat, stat],
+        out_specs=row,
         out_shape=jax.ShapeDtypeStruct((BN, S_pad, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(),
@@ -306,32 +462,38 @@ def _bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
         name="flash_dq",
     )(q, k, v, do, lse, delta)
 
-    # dk/dv: grid batch dim is the KV batch; the inner dim flattens
-    # (q-head-in-group × q-block) so the accumulator sums the whole GQA group
-    def q_of(b, inner):
-        return b * rep + inner // n_q
 
-    dk, dv = pl.pallas_call(
+@_inlined_call
+def _bwd_dkv(q, k, v, do, lse, delta, *, scale, causal, kv_len, q_len, rep,
+             block_q, block_kv, tile, interpret):
+    """dk/dv: the grid's leading dim is the KV batch; the inner dim flattens
+    (q-head-in-group × q-block) so the accumulator sums the whole GQA
+    group."""
+    S_pad, D = q.shape[1:]
+    BK, Skv_pad, _ = k.shape
+    n_q = S_pad // block_q
+    q_block = functools.partial(_q_block, causal=causal, q_len=q_len,
+                                block_q=block_q, block_kv=block_kv)
+
+    moving = pl.BlockSpec(
+        (1, block_q, D),
+        lambda b, j, t: (b * rep + t // n_q, q_block(t % n_q, j), 0))
+    # lse and delta as ROWS, a q block's to itself: ``[BN, n_q, 1, block_q]``
+    # (the same bytes in HBM), whose block's last two dims are the array's
+    # whatever ``block_q`` is
+    stat = pl.BlockSpec(
+        (1, 1, 1, block_q),
+        lambda b, j, t: (b * rep + t // n_q, q_block(t % n_q, j), 0, 0))
+    lse = lse.reshape(lse.shape[0], n_q, 1, block_q)
+    delta = delta.reshape(delta.shape[0], n_q, 1, block_q)
+    col = pl.BlockSpec((1, block_kv, D), lambda b, j, t: (b, j, 0))
+    return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, q_len=q_len, n_q=n_q,
-                          block_q=block_q, block_kv=block_kv),
-        grid=(BK, n_kv, rep * n_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D),
-                         lambda b, j, t: (q_of(b, t), t % n_q, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, block_q, D),
-                         lambda b, j, t: (q_of(b, t), t % n_q, 0)),
-            pl.BlockSpec((1, block_q, 1),
-                         lambda b, j, t: (q_of(b, t), t % n_q, 0)),
-            pl.BlockSpec((1, block_q, 1),
-                         lambda b, j, t: (q_of(b, t), t % n_q, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_kv, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, j, t: (b, j, 0)),
-        ],
+                          block_q=block_q, block_kv=block_kv, tile=tile),
+        grid=(BK, Skv_pad // block_kv, rep * n_q),
+        in_specs=[moving, col, col, moving, stat, stat],
+        out_specs=[col, col],
         out_shape=[
             jax.ShapeDtypeStruct((BK, Skv_pad, D), k.dtype),
             jax.ShapeDtypeStruct((BK, Skv_pad, D), v.dtype),
@@ -344,7 +506,6 @@ def _bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
         interpret=interpret,
         name="flash_dkv",
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
 
 
 # --------------------------------------------------------------------------- #
@@ -353,22 +514,32 @@ def _bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, scale, causal, kv_len, q_len, rep, block_q, block_kv):
-    o, _ = _fwd(q, k, v, scale=scale, causal=causal, kv_len=kv_len, rep=rep,
-                block_q=block_q, block_kv=block_kv, interpret=_use_interpret())
-    return o
+    return _flash_fwd(q, k, v, scale, causal, kv_len, q_len, rep, block_q,
+                      block_kv)[0]
 
 
 def _flash_fwd(q, k, v, scale, causal, kv_len, q_len, rep, block_q, block_kv):
+    _set_gauges(("flash_fwd",), q_len, kv_len, causal, block_q, block_kv, rep)
     o, lse = _fwd(q, k, v, scale=scale, causal=causal, kv_len=kv_len, rep=rep,
-                  block_q=block_q, block_kv=block_kv,
+                  block_q=block_q, block_kv=block_kv, tile=_TILE,
                   interpret=_use_interpret())
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
-               residuals, g):
-    return _bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
-                residuals, g)
+               residuals, do):
+    q, k, v, o, lse = residuals
+    # delta_r = rowsum(dO * O) — cheap elementwise, let XLA fuse it
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)                # [BN, S_pad, 1]
+    shape = dict(scale=scale, causal=causal, kv_len=kv_len, rep=rep,
+                 block_q=block_q, block_kv=block_kv, tile=_TILE,
+                 interpret=_use_interpret())
+    _set_gauges(("flash_dq", "flash_dkv"), q_len, kv_len, causal, block_q,
+                block_kv, rep)
+    dq = _bwd_dq(q, k, v, do, lse, delta, **shape)
+    dk, dv = _bwd_dkv(q, k, v, do, lse, delta, q_len=q_len, **shape)
+    return dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -415,17 +586,17 @@ def _mesh_partition(B: int, N: int, K: int):
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     segment_mask: Optional[jax.Array] = None,
-                    block_q: int = 512, block_kv: int = 1024) -> jax.Array:
+                    block_q: Optional[int] = None,
+                    block_kv: Optional[int] = None) -> jax.Array:
     """Drop-in for ``models.transformer.dot_product_attention``.
 
     q: [B, S, N, D]; k, v: [B, S, K, D] (K divides N → GQA via kernel index
     maps, no repetition in HBM). Arbitrary masks fall back to the XLA
     reference implementation (the Pallas kernel handles causal/full only).
 
-    Default blocks (512, 1024): big tiles amortize the per-grid-step
-    overhead and keep the MXU fed — the fastest of five shapes tried on a
-    v5e at S=2048 with 32/8 heads of 128 (forward 1.4 ms, against 3.4 ms
-    at 128x128; PERF.md, PR 21). Blocks are capped to the (pow2-rounded)
+    ``block_q`` / ``block_kv``: the three kernels' blocks, for a caller that
+    names them; left out, they are chosen from the shapes
+    (:func:`choose_blocks`). Blocks are capped to the (pow2-rounded)
     sequence length for short sequences. Under a multi-device mesh the
     kernel runs per shard (:func:`_mesh_partition`).
     """
@@ -450,12 +621,28 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          check_vma=False)(q, k, v)
 
 
+def choose_blocks(S: int, Skv: int):
+    """``(block_q, block_kv)`` of the three kernels from what a call can see.
+
+    1,024 x 1,024, capped to the (pow2-rounded) lengths. A grid step costs
+    0.3 us whatever it holds and a block's time goes with its area, so the
+    fewest steps win; what a large block wastes on the diagonal it gets back
+    by skipping its dead 512-wide tiles. Timed on a v5e at 1 x 4,096 x 32 / 8
+    heads and 2 x 2,048 x 32 / 32 heads of 128 in bfloat16, forward, ``dq``
+    and ``dk/dv`` each, nine shapes from 256 x 256 to 2,048 x 512: the three
+    kernels want the same (PERF.md section 5, PR 36). Heads of 128 and 256
+    in bfloat16 and float32 compile for the v5e at these blocks
+    (``tests/unit/test_chip_compile.py``)."""
+    return min(1024, _round_pow2(S)), min(1024, _round_pow2(Skv))
+
+
 def _flash_local(q, k, v, *, causal, block_q, block_kv):
     B, S, N, D = q.shape
     rep = N // k.shape[2]
     Skv = k.shape[1]
-    block_q = min(block_q, _round_pow2(S))
-    block_kv = min(block_kv, _round_pow2(Skv))
+    chosen = choose_blocks(S, Skv)
+    block_q = min(block_q or chosen[0], _round_pow2(S))
+    block_kv = min(block_kv or chosen[1], _round_pow2(Skv))
 
     # [B, S, H, D] → [B*H, S, D]
     def to_bn(x):

@@ -8,6 +8,7 @@ Keep every such compile in THIS file: the topology is described inside a
 fixture, so only the pytest worker that is handed this file loads libtpu.
 """
 import os
+import re
 
 import pytest
 
@@ -167,3 +168,62 @@ def test_window_and_full_attention_compile_for_v5e(one_chip, shape):
     # classify by the name and read the pool at operand 3
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
     assert f"%{name}" in text
+
+
+# (sequences a chip, their length, query heads, KV heads, head size, dtype,
+# blocks a caller names): the two training cells at the blocks
+# ``choose_blocks`` gives them, and what only Mosaic refuses: a length under a
+# lane row that is no power of two (a q block of 64 in arrays padded to 128),
+# a named block of 64, and float32 operands and heads of 256 at whole blocks
+FLASH_SHAPES = {
+    "mistral7b-1x4096": (1, 4096, 32, 8, 128, jnp.bfloat16, {}),
+    "pythia69b-2x2048": (2, 2048, 32, 32, 128, jnp.bfloat16, {}),
+    "short-1x100": (1, 100, 4, 2, 128, jnp.bfloat16, {}),
+    "named-64x128": (1, 512, 4, 4, 128, jnp.bfloat16,
+                     dict(block_q=64, block_kv=128)),
+    "float32-1x2048": (1, 2048, 4, 4, 128, jnp.float32, {}),
+    "float32-256-1x2048": (1, 2048, 4, 2, 256, jnp.float32, {}),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+def test_flash_kernels_compile_for_v5e(one_chip, shape, monkeypatch):
+    import importlib
+
+    from benchmarks.roofline import flash_attention as need
+    from benchmarks.trace_reduce import Op
+
+    # the package exports the function under the module's own name
+    F = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(F, "_use_interpret", lambda: False)
+    B, S, N, K, D, dtype, blocks = FLASH_SHAPES[shape]
+    block_q = min(blocks.get("block_q") or F.choose_blocks(S, S)[0],
+                  F._round_pow2(S))
+    S_pad = -(-S // block_q) * block_q
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((B, S, heads, D), dtype,
+                                    sharding=one_chip)
+
+    def grads(q, k, v, do):
+        o, back = jax.vjp(lambda q, k, v: F.flash_attention(
+            q, k, v, causal=True, **blocks), q, k, v)
+        return (o,) + back(do)
+
+    text = jax.jit(grads).lower(arg(N), arg(K), arg(K), arg(N)) \
+        .compile().as_text()
+    # a trace names an event by the instruction with its operands' types;
+    # the compiled text keeps those under ``operand_layout_constraints``
+    calls = [Op("", "custom-call", re.sub(
+        r"custom-call\(.*?\), (.*operand_layout_constraints=\{(.+?\})\}, )",
+        r"custom-call(\2), \1", line), 0.0, 0.0)
+        for line in text.splitlines()
+        if "custom_call_target=\"tpu_custom_call\"" in line]
+    # the benchmark's reader tells the three apart by operand and result
+    # counts and reads B*N, S, D off operand 0: what it finds here is what
+    # ``flash_attention_roofline`` is reckoned from
+    assert sorted(need.classify(c) for c in calls) == ["dkv", "dq", "fwd"]
+    for c in calls:
+        matmuls = need._MATMULS[need.classify(c)]
+        assert need.ops_and_bytes(need.classify(c), c.text)[0] == \
+            matmuls * B * N * S_pad * S_pad * D

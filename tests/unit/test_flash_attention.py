@@ -9,7 +9,9 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models.transformer import dot_product_attention
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.flash_attention import (choose_blocks,
+                                                      flash_attention,
+                                                      step_account)
 
 
 def _rand_qkv(key, B, S, N, D, K=None, dtype=jnp.float32):
@@ -21,6 +23,51 @@ def _rand_qkv(key, B, S, N, D, K=None, dtype=jnp.float32):
     return q, k, v
 
 
+# (S, query heads a KV head, dtype, block_q, block_kv) with blocks small enough
+# that a grid holds dead steps (above the diagonal), open steps (wholly under
+# it: no mask), diagonal steps and, where S is no multiple of a block, steps
+# on the edge of ``kv_len`` (and of ``q_len`` in dk/dv): the one-block cases
+# above and below never run the skip, the clamped index maps or the open body.
+# Tiles of 128 (``small_tiles``), so that a masked block is 1 x 2 or 2 x 2
+# tiles with a dead one among them, as a 1,024-wide block is of 512-wide tiles
+BLOCKED = [
+    (512, 1, "float32", 128, 256),
+    (512, 4, "bfloat16", 256, 256),
+    (640, 4, "float32", 128, 256),      # keys padded to 768: a kv_len edge
+    (640, 1, "bfloat16", 128, 256),
+    (600, 4, "float32", 256, 256),      # both lengths end inside a block
+]
+_TOL = {"float32": 2e-5, "bfloat16": 3e-2}
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    import importlib
+
+    # the package exports the function under the module's own name
+    monkeypatch.setattr(importlib.import_module(
+        "deepspeed_tpu.ops.pallas.flash_attention"), "_TILE", 128)
+
+
+def _blocked_qkv(seed, S, rep, dtype):
+    q, k, v = _rand_qkv(jax.random.PRNGKey(seed), 1, S, 4, 32, K=4 // rep,
+                        dtype=jnp.dtype(dtype))
+    return (q, k, v), [x.astype(jnp.float32) for x in (q, k, v)]
+
+
+def _kinds_met(S, causal, block_q, block_kv, rep):
+    """Which kinds of step the three grids of a case hold."""
+    met = set()
+    for steps in step_account(S, S, causal, block_q, block_kv, rep).values():
+        met |= {kind for kind in ("masked", "open") if steps[kind]}
+        met |= {"dead"} if steps["live"] < steps["steps"] else set()
+        assert steps["live"] == steps["masked"] + steps["open"]
+        assert steps["fetched"] <= steps["steps"]
+        if causal:      # a diagonal block's tiles above the diagonal are dead
+            assert steps["computed"] < steps["live"] * block_q * block_kv
+    return met
+
+
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("S", [128, 256])
 def test_forward_matches_reference(causal, S):
@@ -29,6 +76,22 @@ def test_forward_matches_reference(causal, S):
     ref = dot_product_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("S,rep,dtype,block_q,block_kv", BLOCKED)
+def test_forward_over_many_blocks(small_tiles, causal, S, rep, dtype, block_q,
+                                  block_kv):
+    met = _kinds_met(S, causal, block_q, block_kv, rep)
+    assert met >= ({"dead", "masked", "open"} if causal else {"open"})
+    assert "masked" in met or S % block_kv == 0
+    (q, k, v), f32 = _blocked_qkv(7, S, rep, dtype)
+    out = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                          block_kv=block_kv)
+    assert out.dtype == q.dtype
+    ref = dot_product_attention(*f32, causal=causal)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=_TOL[dtype], rtol=_TOL[dtype])
 
 
 def test_forward_unaligned_seq_len():
@@ -67,6 +130,91 @@ def test_gradients_match_reference(causal, S, K):
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    atol=5e-4, rtol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("S,rep,dtype,block_q,block_kv", BLOCKED)
+def test_gradients_over_many_blocks(small_tiles, causal, S, rep, dtype, block_q,
+                                    block_kv):
+    (q, k, v), f32 = _blocked_qkv(8, S, rep, dtype)
+
+    def loss(attn, **blocks):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, causal=causal, **blocks).astype(jnp.float32) ** 2)
+
+    g_flash = jax.grad(loss(flash_attention, block_q=block_q,
+                            block_kv=block_kv), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(dot_product_attention), argnums=(0, 1, 2))(*f32)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        assert gf.dtype == q.dtype
+        # against the float32 reference's largest entry: a bfloat16
+        # gradient is rounded once on its way out
+        top = float(np.abs(np.asarray(gr)).max())
+        np.testing.assert_allclose(
+            np.asarray(gf, np.float32) / top, np.asarray(gr) / top,
+            atol=5e-6 if dtype == "float32" else 1e-2, err_msg=name)
+
+
+# what ``choose_blocks`` gives the two training cells (a chip's share of a
+# step: length, query heads a KV head; heads of 128, bfloat16) and what the
+# three grids then do, for one index of each grid's leading axis: steps, live,
+# masked, open, fetched, score elements computed over the causal need
+# S * (S + 1) / 2 (a KV head's ``rep`` query heads in ``flash_dkv``)
+GEOMETRY = {
+    (4096, 4): ((1024, 1024),
+                {"flash_fwd": (16, 10, 4, 6, 9), "flash_dq": (16, 10, 4, 6, 9),
+                 "flash_dkv": (64, 40, 16, 24, 40)}, 36 * 512 * 512),
+    (2048, 1): ((1024, 1024),
+                {"flash_fwd": (4, 3, 2, 1, 2), "flash_dq": (4, 3, 2, 1, 2),
+                 "flash_dkv": (4, 3, 2, 1, 2)}, 10 * 512 * 512),
+}
+
+
+@pytest.mark.parametrize("S,rep", sorted(GEOMETRY))
+def test_chosen_blocks_and_step_account(S, rep):
+    blocks, steps, computed = GEOMETRY[S, rep]
+    assert choose_blocks(S, S) == blocks
+    account = step_account(S, S, True, *blocks, rep)
+    kinds = ("steps", "live", "masked", "open", "fetched")
+    for kernel, want in steps.items():
+        assert tuple(account[kernel][k] for k in kinds) == want, kernel
+        group = rep if kernel == "flash_dkv" else 1
+        assert account[kernel]["computed"] == group * computed
+    # the parent's 512 x 1,024 blocks, whole under their masks, computed
+    # 20 x 512 x 1,024 (4,096) and 6 x 512 x 1,024 (2,048) for this need
+    assert computed * 2 / (S * (S + 1)) < 1.25
+    # shorter than a block: capped
+    assert choose_blocks(192, 100) == (128, 64)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("S,Skv", [(512, 512), (600, 600), (256, 512),
+                                   (512, 256)])
+def test_moving_blocks_stay_inside_their_arrays(causal, S, Skv):
+    # keys past the last query (Skv > S, causal) are dead for every row:
+    # their steps of dk/dv must still name a block the arrays have
+    from deepspeed_tpu.ops.pallas.flash_attention import _kv_block, _q_block
+
+    shape = dict(causal=causal, block_q=128, block_kv=128)
+    n_q, n_kv = -(-S // 128), -(-Skv // 128)
+    for i in range(n_q):
+        for j in range(n_kv):
+            assert 0 <= _kv_block(i, j, **shape) < n_kv
+            assert 0 <= _q_block(i, j, q_len=S, **shape) < n_q
+
+
+def test_traced_kernels_set_their_gauges():
+    from deepspeed_tpu import telemetry
+
+    telemetry.reset()
+    q, k, v = _rand_qkv(jax.random.PRNGKey(9), 1, 256, 4, 32, K=1)
+    jax.grad(lambda q: jnp.sum(flash_attention(
+        q, k, v, causal=True, block_q=64, block_kv=128)))(q)
+    gauge = telemetry.gauge("flash_steps")
+    for kernel, steps in step_account(256, 256, True, 64, 128, 4).items():
+        for kind, n in steps.items():
+            assert gauge.value(kernel=kernel, kind=kind) == n, (kernel, kind)
+    assert gauge.value(kernel="flash_dkv", kind="steps") == 2 * 4 * 4
 
 
 def test_bf16_forward():

@@ -127,7 +127,11 @@ class BlockAllocator:
         if n > len(self._free):
             raise RuntimeError(
                 f"KV pool exhausted: want {n} blocks, {len(self._free)} free")
-        out, self._free = self._free[:n], self._free[n:]
+        # the free list is as long as the pool: take from it in place (a
+        # copy of it for every decode row of a tick was most of a wide
+        # tick's scheduling time)
+        out = self._free[:n]
+        del self._free[:n]
         return out
 
     def free(self, blocks: Sequence[int]) -> None:
@@ -217,7 +221,8 @@ class FastGenEngine:
         self.request_deadline_s = request_deadline_s
 
         # sequence slots: what a model of ``layer_kinds`` keeps per
-        # sequence beside its blocks (rings, recurrent state) lives in one
+        # sequence beside its blocks (rings, recurrent and convolution
+        # state) lives in one
         # of ``state_slots`` rows, acquired with a sequence's first block
         # and freed with it; 0 for every other model. The default holds a
         # small tick bucket's decode rows.
@@ -471,6 +476,19 @@ class FastGenEngine:
             "fastgen_slow_tick_excess_seconds_total",
             "seconds by which slow ticks' periods passed their programs' "
             "typical period, by the phase that owned the tick")
+        # what a sequence slot holds beside its blocks, by kind of state
+        # (``models/paged.init_paged_kv``): constants of the engine
+        state_bytes = telemetry.gauge(
+            "fastgen_state_bytes_per_slot",
+            "bytes a sequence slot holds whatever its sequence's length, by "
+            "kind: conv (a convolution's last inputs) / ring (window "
+            "layers' keys and values) / scan (a recurrence's matrix)")
+        for kind, names in (("conv", ("conv",)), ("ring", ("wk", "wv")),
+                            ("scan", ("ssm",))):
+            held = [self.pool[n] for n in names if n in self.pool]
+            if held:
+                state_bytes.set(sum(x.nbytes for x in held)
+                                / (self.allocator.state_slots + 1), kind=kind)
         self._period_keys: Dict[tuple, tuple] = {}   # (kind, Tn) -> keys
         # the last step() tick's end (None before the first and after a
         # fused window, whose ticks are not accounted), and whether the
@@ -512,9 +530,12 @@ class FastGenEngine:
         self._tm_slots.set(self.allocator.slots_in_use)
         self._tm_slots_peak.set_max(self.allocator.slots_in_use)
         in_use = {"quarter": 0, "half": 0, "full": 0}
+        quarter, half = self._mb_tier_bounds()     # as _mb_tier_name's
         for s in live:
-            if s.blocks:
-                in_use[self._mb_tier_name(len(s.blocks))] += len(s.blocks)
+            n = len(s.blocks)
+            if n:
+                in_use["quarter" if n <= quarter else
+                       "half" if n <= half else "full"] += n
         for tier, n in in_use.items():
             self._tm_kv_tier.set(n, tier=tier)
 
@@ -1077,10 +1098,12 @@ class FastGenEngine:
         backpressure, reference ``scheduling_utils`` CacheBlock result)."""
         need = upto_pos // self.block_size + 1
         grow = need - len(seq.blocks)
+        if grow <= 0:
+            return True
         if grow > self.allocator.available(starting=not seq.blocks):
             return False
         # a sequence's first block comes with its slot (``BlockAllocator``)
-        new = self.allocator.grow(max(grow, 0)) if seq.blocks \
+        new = self.allocator.grow(grow) if seq.blocks \
             else self.allocator.allocate(grow)
         for blk in new:
             seq.table[len(seq.blocks)] = blk
@@ -1240,6 +1263,11 @@ class FastGenEngine:
                           "state_slots": self.allocator.slots_in_use,
                           "window_positions": int(window_positions),
                           "window_attended": window_attended}
+            if "conv" in self.cfg.layer_kinds:
+                # rows that close a run (a decode row, a chunk's last):
+                # each writes its slot's state in every conv layer
+                slot_attrs["conv_state_rows"] = \
+                    n_decode_rows + len(chunk_starts)
         with telemetry.span("decode_tick", attrs={
                 **slot_attrs,
                 "tick": self._ticks_run, "kind": kind, "rows": row,
@@ -1344,13 +1372,15 @@ class FastGenEngine:
             self._tm_sched_gauges()
 
             out: Dict[int, int] = {}
-            for r, seq, is_decode in heads:
-                tok = int(sampled[r])
+            kept = 0
+            for (r, seq, is_decode), tok in zip(
+                    heads, sampled[[h[0] for h in heads]].tolist()):
                 if is_decode:
                     seq.pos += 1   # the decode input token entered the cache
                 seq.last_tok = tok
-                self._note_token(seq, tok)
+                kept += self._note_token(seq, tok, count=False)
                 out[seq.uid] = tok
+            self._tm_gen_tok.inc(kept)
             # collections queued since the last tick: in the registry by
             # the time the tick ends; the process's own counters every
             # sixteenth tick (and at a slow one)
@@ -1462,24 +1492,28 @@ class FastGenEngine:
             del self._typical[(kind, Tn, mb)]
 
     def _note_token(self, seq: _Seq, tok: int,
-                    pos: Optional[int] = None) -> None:
+                    pos: Optional[int] = None, count: bool = True) -> int:
         """``pos``: the sequence position at the tick that PRODUCED this
         token — decode_stream drains with ``seq.pos`` already advanced one
         to two windows ahead, so the max-len cutoff must use the tick-time
-        position, not the optimistic current one."""
+        position, not the optimistic current one. Returns the tokens kept
+        (0 or 1); ``count`` False leaves ``fastgen_generated_tokens_total``
+        to the caller, who adds a tick's tokens at once."""
         if seq.done:
-            return
+            return 0
         # TTFT anchors on the FIRST sampled token even when it's EOS —
         # excluding immediate-EOS sequences would bias the distribution
         # toward longer-lived ones
         self._tm_first_token(seq)
         if self.eos_token_id is not None and tok == self.eos_token_id:
             self._finish(seq)
-            return
+            return 0
         seq.generated.append(tok)
-        self._tm_gen_tok.inc()
+        if count:
+            self._tm_gen_tok.inc()
         if (seq.pos if pos is None else pos) + 1 >= self.max_len:
             self._finish(seq)
+        return 1
 
     def _finish(self, seq: _Seq) -> None:
         """Mark done and release KV blocks immediately — a finished sequence

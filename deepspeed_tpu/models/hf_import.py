@@ -261,17 +261,20 @@ def _llama_attn_blocks(sd: Dict[str, Any], cfg: TransformerConfig,
 
 
 def _qwen_moe_experts(sd: Dict[str, Any], moe_fmt: str, L, E: int,
-                      first: int = 0):
+                      first: int = 0,
+                      names=("gate_proj", "up_proj", "down_proj")):
     """Stack per-expert gate/up/down ModuleList weights → [L, E, in, out]
-    (the ``E`` experts from ``first``: a share of the checkpoint's)."""
+    (the ``E`` experts from ``first``: a share of the checkpoint's;
+    ``names``: what the family calls the three)."""
     def experts(wname):
         return np.stack([
             np.stack([_np(sd[moe_fmt.format(i) + f"experts.{e}.{wname}.weight"]).T
                       for e in range(first, first + E)])
             for i in _layers(L)])
 
-    return {"w_gate": experts("gate_proj"), "w_up": experts("up_proj"),
-            "w_down": experts("down_proj")}
+    gate, up, down = names
+    return {"w_gate": experts(gate), "w_up": experts(up),
+            "w_down": experts(down)}
 
 
 def params_from_qwen2_moe(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
@@ -1096,8 +1099,133 @@ def params_from_afmoe(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
     return params
 
 
+# --------------------------------------------------------------------------- #
+# LFM2-MoE (Liquid: gated short-convolution layers among attention layers,
+# over expert layers)
+# --------------------------------------------------------------------------- #
+
+def config_from_lfm2_moe(hf_config) -> TransformerConfig:
+    """``model_type`` ``lfm2_moe``: every layer ``x += mixer(operator_norm
+    x); x += ffn(ffn_norm x)`` under RMSNorm; ``layer_types`` says which
+    layers mix with a gated short convolution of ``conv_L_cache`` taps
+    (``conv``) and which with grouped-query attention (per-head q/k RMSNorm,
+    then rotary over the whole head); the first ``num_dense_layers`` layers
+    have a dense SwiGLU FFN of ``intermediate_size``, the others
+    ``num_experts`` routed experts of ``moe_intermediate_size`` under a
+    sigmoid router with a selection bias, weights normalised by ``sum +
+    1e-6``; no bias anywhere, no shared expert, the head tied."""
+    kinds = tuple({"conv": "conv", "full_attention": "full"}[t]
+                  for t in hf_config.layer_types)
+    L = hf_config.num_hidden_layers
+    if len(kinds) != L:
+        raise ValueError(f"lfm2_moe: layer_types names {len(kinds)} layers "
+                         f"of num_hidden_layers={L}")
+    rope = dict(getattr(hf_config, "rope_parameters", None) or {})
+    if getattr(hf_config, "conv_bias", False) \
+            or rope.get("rope_type", "default") != "default":
+        raise NotImplementedError(
+            "lfm2_moe: a convolution without bias and unscaled rotary are "
+            "what is written")
+    h = hf_config.hidden_size
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size, hidden_size=h, num_layers=L,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        ffn_hidden_size=hf_config.intermediate_size,
+        max_seq_len=hf_config.max_position_embeddings,
+        pos_emb="rope", norm="rmsnorm", activation="swiglu", use_bias=False,
+        tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", True)),
+        rope_theta=float(rope.get("rope_theta", getattr(
+            hf_config, "rope_theta", 1000000.0))),
+        norm_eps=hf_config.norm_eps, dtype="float32", qk_norm=True,
+        layer_kinds=kinds, conv_taps=int(hf_config.conv_L_cache),
+        n_experts=hf_config.num_experts,
+        moe_top_k=hf_config.num_experts_per_tok,
+        moe_ffn_size=hf_config.moe_intermediate_size,
+        moe_score_func="sigmoid",
+        moe_route_norm=bool(getattr(hf_config, "norm_topk_prob", True)),
+        moe_route_norm_eps=1e-6,
+        moe_route_scale=float(getattr(hf_config, "routed_scaling_factor",
+                                      1.0)),
+        moe_gate_bias=bool(getattr(hf_config, "use_expert_bias", True)),
+        moe_dispatch="ragged",
+        first_dense_layers=min(int(hf_config.num_dense_layers), L))
+
+
+def params_from_lfm2_moe(sd: Dict[str, Any], cfg: TransformerConfig
+                         ) -> PyTree:
+    """The family's tensor names (``modeling_lfm2_moe.py``):
+    ``operator_norm`` / ``ffn_norm``; ``conv.in_proj`` ``[3 H, H]`` (its
+    output chunks ``B, C, x`` in that order), ``conv.conv`` ``[H, 1,
+    taps]`` (depthwise, the last tap on the row itself), ``conv.out_proj``;
+    ``self_attn.{q,k,v}_proj``, ``out_proj``, ``{q,k}_layernorm``;
+    ``feed_forward.w1 / w3 / w2`` (gate, up, down) in a dense layer;
+    ``feed_forward.gate``, ``expert_bias`` and ``experts.<e>.w1 / w3 / w2``
+    in an expert layer; ``embedding_norm`` after the last layer. The
+    mixers' leaves are stacked by mixer (``TransformerConfig.
+    mixer_layers``)."""
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lyr = pre + "layers.{}."
+    ff = lyr + "feed_forward."
+
+    def stack_of(layers: range, experts: bool) -> PyTree:
+        kinds = cfg.layer_kinds
+        conv = [i for i in layers if kinds[i] == "conv"]
+        attn = [i for i in layers if kinds[i] != "conv"]
+        blocks = {
+            "ln1": {"scale": _stack(sd, lyr + "operator_norm.weight",
+                                    layers)},
+            "ln2": {"scale": _stack(sd, lyr + "ffn_norm.weight", layers)}}
+        if conv:
+            blocks["conv"] = {
+                "w_in": _stack(sd, lyr + "conv.in_proj.weight", conv,
+                               transpose=True),
+                # [H, 1, taps] -> [taps, H]
+                "conv_w": np.stack([_np(sd[(lyr + "conv.conv.weight")
+                                           .format(i)])[:, 0, :].T
+                                    for i in conv]),
+                "wo": _stack(sd, lyr + "conv.out_proj.weight", conv,
+                             transpose=True)}
+        if attn:
+            blocks["attn"] = {
+                ours: _stack(sd, lyr + f"self_attn.{theirs}.weight", attn,
+                             transpose=True)
+                for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                     ("wv", "v_proj"), ("wo", "out_proj"))}
+            blocks["attn"]["q_norm"] = _stack(
+                sd, lyr + "self_attn.q_layernorm.weight", attn)
+            blocks["attn"]["k_norm"] = _stack(
+                sd, lyr + "self_attn.k_layernorm.weight", attn)
+        names = (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2"))
+        if not experts:
+            for ours, theirs in names:
+                blocks[ours] = _stack(sd, ff + theirs + ".weight", layers,
+                                      transpose=True)
+            return blocks
+        blocks["gate_w"] = _stack(sd, ff + "gate.weight", layers,
+                                  transpose=True)
+        if cfg.moe_gate_bias:
+            blocks["gate_bias"] = _stack(sd, ff + "expert_bias", layers)
+        blocks.update(_qwen_moe_experts(
+            sd, ff, layers, cfg.n_experts, names=("w1", "w3", "w2")))
+        return blocks
+
+    d = cfg.first_dense_layers
+    params = {
+        "tok_emb": _np(sd[pre + "embed_tokens.weight"]),
+        "blocks": stack_of(range(d, cfg.num_layers), True),
+        "final_norm": {"scale": _np(sd[pre + "embedding_norm.weight"])},
+    }
+    if d:
+        params["dense_blocks"] = stack_of(range(d), False)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _np(sd["lm_head.weight"]).T
+    return params
+
+
 _ARCH_TABLE = {
     "afmoe": (config_from_afmoe, params_from_afmoe),
+    "lfm2_moe": (config_from_lfm2_moe, params_from_lfm2_moe),
     "phi4flash": (config_from_phi4flash, params_from_phi4flash),
     "gpt2": (config_from_gpt2, params_from_gpt2),
     "llama": (config_from_llama, params_from_llama),

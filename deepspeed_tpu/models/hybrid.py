@@ -16,6 +16,10 @@ and the mixer is one of
 * ``gmu``: a gated memory unit, which gates the scan output (``memory``)
   the last ``mamba`` layer handed on.
 
+Beside them :func:`short_conv`, the mixer of the ``conv`` layers that stand
+among standard attention blocks (``TransformerConfig.standard_blocks``; the
+``lfm2`` family): the segmented convolution below without a scan.
+
 Rows are a flat batch ``[T, ...]`` in which a sequence's rows are
 consecutive and in order (a SplitFuse tick; a dense ``[B, S]`` batch
 flattened is the same thing with every run starting at position 0), so the
@@ -27,7 +31,7 @@ nothing imports ``models/transformer.py``.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +66,10 @@ def mixer_specs(cfg: Any, kind: str) -> Dict[str, Tuple[tuple, tuple, str]]:
             "skip_scale": ((di,), ("mlp",), "ones"),
             "wo": ((di, h), ("mlp", "embed"), "out"),
         }
+    if kind == "conv":
+        return {"w_in": ((h, 3 * h), ("embed", "mlp"), "std"),
+                "conv_w": ((cfg.conv_taps, h), (None, "mlp"), "conv"),
+                "wo": ((h, h), ("mlp", "embed"), "out")}
     if kind == "gmu":
         return {"w_in": ((h, di), ("embed", "mlp"), "std"),
                 "wo": ((di, h), ("mlp", "embed"), "out")}
@@ -220,6 +228,24 @@ def mamba(h: jax.Array, lp: Dict[str, Any], cfg: Any, runs: Runs,
     return out, y, conv_new, s
 
 
+def short_conv(h: jax.Array, lp: Dict[str, Any], runs: Runs,
+               conv0: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The gated short convolution (the ``lfm2`` family's ``conv`` layers)
+    on normed rows h [T, H], before its output projection: ``[B | C | z]
+    = h W_in``, ``g = B * z``, a depthwise causal convolution of ``g``
+    over the row's run (no bias, no activation, no scan), times ``C``.
+    conv0 [T, taps-1, H]: the inputs ``g`` before each row's run (zeroed
+    here for a run at position 0, whatever was handed in). Returns (the
+    gated output [T, H], ``g`` up to and including each row
+    [T, taps-1, H]: a sequence's state is its last row's)."""
+    dt_, H = h.dtype, lp["conv_w"].shape[-1]
+    bcz = h @ lp["w_in"].astype(dt_)
+    g = bcz[:, :H] * bcz[:, 2 * H:]
+    conv0 = jnp.where(runs.fresh[:, None, None], 0, conv0).astype(dt_)
+    conv, conv_new = _segmented_conv(g, lp["conv_w"], runs, conv0)
+    return bcz[:, H:2 * H] * conv.astype(dt_), conv_new
+
+
 def gmu(h: jax.Array, lp: Dict[str, Any], memory: jax.Array) -> jax.Array:
     """Gated memory unit on normed rows, before its output projection."""
     return jax.nn.silu(h @ lp["w_in"].astype(h.dtype)) * memory
@@ -234,7 +260,8 @@ def lambda_init(layer: jax.Array) -> jax.Array:
     return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, jnp.float32))
 
 
-def paired_queries(q: jax.Array) -> jax.Array:
+def paired_queries(q: jax.Array, odd: Optional[jax.Array] = None
+                   ) -> jax.Array:
     """Differential attention as ONE grouped-query attention. Heads pair by
     parity (``q1 = q[0::2]``, ``k1 = k[0::2]`` ...), so key heads ``2g,
     2g+1`` side by side are one key of ``2 D`` columns ``[k1_g | k2_g]``,
@@ -244,12 +271,16 @@ def paired_queries(q: jax.Array) -> jax.Array:
     score is ``q1 . k1`` or ``q2 . k2`` and either softmax's output is
     over ``[v1 | v2]``, which are the four products. Query heads
     ``4g .. 4g+3`` (``q1, q2, q1, q2``) belong to paired key head ``g``,
-    so no head moves. q [T, N, D] -> [T, N, 2 D]."""
+    so no head moves. q [T, N, D] -> [T, N, 2 D]. ``odd`` [N] bool: the
+    query heads that take the pair's second half, where that is not the
+    heads of odd index (``paged._lane_packed``: grouped queries on KV
+    heads stored two to a row)."""
     zeros = jnp.zeros_like(q)
     first = jnp.concatenate([q, zeros], axis=-1)
     second = jnp.concatenate([zeros, q], axis=-1)
-    odd = (jnp.arange(q.shape[1]) % PAIR == 1)[None, :, None]
-    return jnp.where(odd, second, first)
+    if odd is None:
+        odd = jnp.arange(q.shape[1]) % PAIR == 1
+    return jnp.where(odd[None, :, None], second, first)
 
 
 def paired_cache(x: jax.Array) -> jax.Array:

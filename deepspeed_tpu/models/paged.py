@@ -45,6 +45,33 @@ def ring_blocks(cfg: T.TransformerConfig, block_size: int,
     return -(-(cfg.attn_window + max_run) // block_size)
 
 
+def kv_lane_pack(cfg: T.TransformerConfig) -> int:
+    """KV heads that lie side by side in one row of a standard-block pool:
+    2 where a head is 64 wide, so that a row fills the TPU's 128 lanes
+    (``[bs, K/2, 128]``), else 1. A block ``[bs, K, 64]`` would be padded
+    to the lanes in HBM (twice the bytes held and fetched), and Mosaic
+    refuses the kernel's strided load of a slot whose last dim is not 128
+    (found by compiling for a described v5e, PERF.md, PR 37). Heads ``2g``
+    and ``2g + 1`` are one key of 128 columns, the values likewise; a
+    query of head ``2g`` is ``[q | 0]``, of ``2g + 1`` ``[0 | q]``, and its
+    output its own half (:func:`_lane_packed`, as differential attention's
+    ``hybrid.paired_queries``): no head moves, the products are twice as
+    wide, which a kernel bound by its bytes does not feel."""
+    return 2 if cfg.standard_blocks and cfg.head_dim == 64 \
+        and cfg.kv_heads % 2 == 0 else 1
+
+
+def _lane_packed(q: jax.Array, kv_heads: int) -> Tuple[jax.Array, Callable]:
+    """Queries q [T, N, D] against KV heads stored two to a row
+    (:func:`kv_lane_pack`): (the queries [T, N, 2 D], zero in the half of
+    the other head of the pair; a function that takes each head's own half
+    of the attended values [T, N, 2 D] -> [T, N, D])."""
+    N, D = q.shape[1:]
+    odd = (jnp.arange(N) // (N // kv_heads)) % 2 == 1
+    return HY.paired_queries(q, odd), lambda o: jnp.where(
+        odd[None, :, None], o[..., D:], o[..., :D])
+
+
 def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
                   dtype=None, state_slots: int = 0, max_run: int = 0
                   ) -> Dict[str, jax.Array]:
@@ -69,7 +96,10 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
     layer. ``window`` and ``full`` layers of the standard block in one
     stack (``cfg.standard_blocks``) have a block range for each ``full``
     layer and rings ``{"wk", "wv"}`` for the ``window`` layers, blocks
-    ``[bs, K, D]`` as the homogeneous stack's. A stack of ``layer_kinds``
+    ``[bs, K, D]`` as the homogeneous stack's (heads of 64 two to a row,
+    ``[bs, K/2, 2 D]``: :func:`kv_lane_pack`), and state ``{"conv"}`` for
+    its ``conv`` layers: the short convolution's last inputs. A stack of
+    ``layer_kinds``
     with mixers of its own (``models/hybrid.py``) has the block
     pool for its ONE ``full`` layer (the ``cross`` layers read it), rings
     ``{"wk", "wv"}`` for its ``window`` layers and state for its ``mamba``
@@ -95,10 +125,12 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
         # read off the array), nothing for a kind the stack lacks
         if state_slots < 1:
             raise ValueError("window and full layers in one stack keep "
-                             "rings per sequence: state_slots >= 1 "
+                             "rings (and conv layers their state) per "
+                             "sequence: state_slots >= 1 "
                              f"(got {state_slots})")
-        kinds, block = cfg.layer_kinds, (block_size, cfg.kv_heads,
-                                         cfg.head_dim)
+        pack = kv_lane_pack(cfg)
+        kinds, block = cfg.layer_kinds, (block_size, cfg.kv_heads // pack,
+                                         pack * cfg.head_dim)
         pool = {}
         if "full" in kinds:
             full = (kinds.count("full"), n_blocks) + block
@@ -107,6 +139,11 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
             ring = (kinds.count("window"), state_slots + 1,
                     ring_blocks(cfg, block_size, max_run)) + block
             pool.update(wk=jnp.zeros(ring, dt), wv=jnp.zeros(ring, dt))
+        if "conv" in kinds:
+            # the short convolution's last inputs, a row a sequence slot
+            pool["conv"] = jnp.zeros(
+                (kinds.count("conv"), state_slots + 1, cfg.conv_taps - 1,
+                 cfg.hidden_size), dt)
         return pool
     if cfg.layer_kinds:
         kinds = cfg.layer_kinds
@@ -241,12 +278,13 @@ def paged_mla_attention_reference(q: jax.Array, pool: jax.Array,
 def span_attention_reference(q: jax.Array, kpool: jax.Array,
                              vpool: jax.Array, tables: jax.Array,
                              lengths: jax.Array, window: Optional[int],
-                             row_table: jax.Array) -> jax.Array:
+                             row_table: jax.Array,
+                             scale: Optional[float] = None) -> jax.Array:
     """:func:`paged_attention_reference` given one table a sequence slot
     and each row's slot, as the kernel of a stack of window and full
     layers is (``paged_attention(row_table=)``)."""
     return paged_attention_reference(q, kpool, vpool, tables[row_table],
-                                     lengths, window=window)
+                                     lengths, window=window, scale=scale)
 
 
 #: what ``forward_paged(attention_fn=)`` reads as "no kernel"
@@ -272,7 +310,8 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
     block pool, read by the ``full`` layer and every ``cross`` layer);
     ``window`` and ``full`` layers of the standard block
     (``cfg.standard_blocks``): ``fn(q, kpool, vpool, tables, lengths,
-    window=, row_table=)`` with one table a sequence slot and each row's
+    window=, row_table=, scale=)`` with one table a sequence slot and each
+    row's
     slot, the dense kernel under the names ``swa_attention`` (a
     ring) and ``global_attention`` (a full layer's own block range)."""
     if cfg.standard_blocks:
@@ -281,7 +320,8 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
         from deepspeed_tpu.ops.pallas.paged_attention import (
             paged_attention, tile_rows)
 
-        def attend(q, kpool, vpool, tables, lengths, window, row_table):
+        def attend(q, kpool, vpool, tables, lengths, window, row_table,
+                   scale=None):
             # a chunk of the token budget against thousands of positions
             # is MXU-bound, unlike the homogeneous cells' shapes: the
             # products take the operands in the model's own type (bfloat16
@@ -289,11 +329,12 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
             # float32
             return paged_attention(
                 q, kpool, vpool, tables, lengths, window=window,
-                mxu_dtype=q.dtype, row_table=row_table,
+                mxu_dtype=q.dtype, row_table=row_table, scale=scale,
                 name="global_attention" if window is None
                 else "swa_attention")
 
-        return attend, tile_rows(cfg.num_heads, cfg.head_dim)
+        return attend, tile_rows(cfg.num_heads,
+                                 kv_lane_pack(cfg) * cfg.head_dim)
     if cfg.layer_kinds:
         if not use_kernel:
             return paged_attention_reference, 0
@@ -380,7 +421,8 @@ def _tick_experts(h: jax.Array, lp: Dict[str, jax.Array],
         route_scale=cfg.moe_route_scale, shared=shared or None,
         gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
         topk_group=cfg.moe_topk_group, valid=valid,
-        layer=layer if stack else None)
+        layer=layer if stack else None,
+        route_norm_eps=cfg.moe_route_norm_eps)
 
 
 class _Rows(NamedTuple):
@@ -501,16 +543,19 @@ def _latent_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
 
 def pool_block(pool: Dict[str, jax.Array]) -> int:
     """Positions of a block ``[bs, K, D]`` of a standard-block pool."""
-    return next(iter(pool.values())).shape[-3]
+    return pool["k" if "k" in pool else "wk"].shape[-3]
 
 
 def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
                 rows: _Rows, attend: Callable) -> Callable:
-    """The caches of ``window`` and ``full`` layers of the standard block
-    (``cfg.standard_blocks``; ``init_paged_kv``): a layer's attention as
-    ``layer(kind, h, lp, flat, nth) -> (attn [T, N*D], flat)``, ``nth`` the
-    layer's index among the layers of its KIND (which ring, which block
-    range). Projections as :func:`_dense_cache`'s (``qk_norm``; rotary at
+    """The caches of ``window``, ``full`` and ``conv`` layers of the
+    standard block (``cfg.standard_blocks``; ``init_paged_kv``): a layer's
+    mixer as ``layer(kind, h, lp, flat, nth) -> (mixed [T, .] before
+    ``wo``, flat)``, ``nth`` the layer's index among the layers of its KIND
+    (which ring, which block range, which state rows). A ``conv`` layer
+    reads its rows' runs' state from its sequence slots' rows and writes
+    the state after each run's last row (``hybrid.short_conv``).
+    Projections as :func:`_dense_cache`'s (``qk_norm``; rotary at
     the rows' positions, on ``full`` layers only where the config says
     so), then the elementwise output gate where the model has one.
 
@@ -532,16 +577,29 @@ def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
     # of its own, has no room in scalar memory); a stack without window
     # layers has no slots: a table a row
     slot, by_slot = jnp.arange(Tn, dtype=jnp.int32), rows.tables
-    if "wk" in pool:
-        S1, RB, bs = pool["wk"].shape[1:4]
+    if "wk" in pool or "conv" in pool:
+        S1 = pool["wk" if "wk" in pool else "conv"].shape[1]
         slot = rows.tables[:, 0]
         by_slot = jnp.zeros((S1, MB), jnp.int32).at[slot].set(rows.tables)
+    if "wk" in pool:
+        RB, bs = pool["wk"].shape[2:4]
         ring_by_slot = (jnp.arange(S1, dtype=jnp.int32) * RB)[:, None] + (
             jnp.arange(MB, dtype=jnp.int32) % RB)[None, :]
         ring_block = slot * RB + (rows.positions // bs) % RB
     NB = pool["k"].shape[1] if "k" in pool else 0
+    pack = kv_lane_pack(cfg)
+    if "conv" in pool:
+        runs = HY.runs_of(slot, rows.positions)
 
     def layer(kind, h, lp, flat, nth):
+        if kind == "conv":
+            at = nth * S1 + slot
+            mixed, conv = HY.short_conv(h, lp, runs, flat["conv"][at])
+            # the state after a run's last row is its sequence's; the
+            # other rows' index lies past the array and is dropped
+            put = jnp.where(runs.last, at, flat["conv"].shape[0])
+            return mixed, {**flat, "conv": flat["conv"].at[put].set(
+                conv.astype(flat["conv"].dtype), mode="drop")}
         q, k, v = _project_qkv(
             cfg, h, lp, rows.positions,
             (cos_t, sin_t) if cfg.pos_emb == "rope" and (
@@ -553,14 +611,23 @@ def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
         else:
             names, base = ("k", "v"), nth * NB
             at, tables, window = base + rows.block_idx, by_slot + base, None
-        new = dict(flat)
+        new, scale, own = dict(flat), None, None
+        if pack > 1:
+            # heads of 64 lie two to a pool row (``kv_lane_pack``); the
+            # scores' factor stays the unpacked head's
+            k, v = (x.reshape(Tn, cfg.kv_heads // pack, -1) for x in (k, v))
+            q, own = _lane_packed(q, cfg.kv_heads)
+            scale = cfg.head_dim ** -0.5
         for name, x in zip(names, (k, v)):
             new[name] = flat[name].at[at, rows.offsets].set(
                 x.astype(flat[name].dtype), mode="drop")
         # the scope a device trace tells the two kinds' attention by
         with jax.named_scope("swa" if kind == "window" else "global"):
             attn = attend(q, new[names[0]], new[names[1]], tables,
-                          rows.lengths, window=window, row_table=slot)
+                          rows.lengths, window=window, row_table=slot,
+                          scale=scale)
+        if own is not None:
+            attn = own(attn)
         attn = attn.reshape(Tn, cfg.num_heads * cfg.head_dim)
         if cfg.attn_gate:
             attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
@@ -648,7 +715,7 @@ def _kinds_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
 
 
 #: the scope a kind's mixer runs under (``attn`` also holds ``ln1``, ``wo``)
-_KIND_SCOPES = {"mamba": "ssm", "gmu": "gmu"}
+_KIND_SCOPES = {"mamba": "ssm", "gmu": "gmu", "conv": "conv"}
 
 
 def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
@@ -781,9 +848,8 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
                 step = (li - first - run_first) // len(period)
                 n_rows = []
                 for i, kind in enumerate(period):
-                    lp = dequant_params(
-                        jax.tree.map(lambda a: a[i], lps), dt)
-                    with jax.named_scope("attn"):
+                    lp = dequant_params(T.period_layer(lps, period, i), dt)
+                    with jax.named_scope(_KIND_SCOPES.get(kind, "attn")):
                         h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
                         attn, flat = attention(
                             kind, h, lp, flat, ahead[kind]
@@ -820,10 +886,11 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     # layout) re-stacks the ENTIRE pool every call — measured 25 ms/tick at
     # 512 blocks inside a decode scan, linear in pool size — where the
     # in-place carry touches only the written rows.
-    # (of standard blocks under kinds every leaf ends in a block [bs, K, D])
-    carry = (x, {k: v.reshape((-1,) + (v.shape[-3:] if cfg.standard_blocks
-                                       else v.shape[2:]))
-                 for k, v in pool.items()}, jnp.int32(0))
+    # (a ring of standard blocks is [layers, slots, RB, bs, K, D]: its rows
+    # are blocks too)
+    carry = (x, {k: v.reshape((-1,) + (
+        v.shape[-3:] if cfg.standard_blocks and k in ("wk", "wv")
+        else v.shape[2:])) for k, v in pool.items()}, jnp.int32(0))
     if cfg.layer_kinds and not cfg.standard_blocks:
         carry += (jnp.zeros((x.shape[0], cfg.ssm_inner), dt),)
     stats = {}

@@ -211,6 +211,15 @@ class TransformerConfig:
     # 0: the router is ``n_experts`` wide and every expert is held
     moe_router_experts: int = 0
     moe_first_expert: int = 0
+    # what guards the division that normalises a token's chosen scores: 0
+    # divides by ``max(sum, 1e-9)``, a value by ``sum + value`` (the form
+    # the ``lfm2_moe`` family publishes, 1e-6)
+    moe_route_norm_eps: float = 0.0
+    # ``conv`` layers among ``layer_kinds`` of the standard block (the
+    # ``lfm2_moe`` family): the mixer is a gated short convolution
+    # (``models/hybrid.short_conv``) of this many taps, whose state a
+    # sequence is its last ``conv_taps - 1`` inputs, whatever its length
+    conv_taps: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -285,10 +294,26 @@ class TransformerConfig:
         what a layer sees and where its cache lives (``window``: its last
         ``attn_window`` positions, a per-sequence ring; ``full``: every
         position, a block range of its own), and which attention it
-        computes is the config's. False with kinds: a mixer of its own
-        per kind (``models/hybrid.py``, ``params[key][kind]``)."""
+        computes is the config's. A ``conv`` layer has the block's norms
+        and its FFN or experts around a mixer of its own with a state a
+        sequence slot (:func:`mixer_of`: in a segment with such layers the
+        mixers' leaves are stacked by mixer, ``blocks["attn"]`` over its
+        attention layers and ``blocks["conv"]`` over its ``conv`` layers).
+        False with kinds: every kind a whole layer of its own
+        (``models/hybrid.py``, ``params[key][kind]``)."""
         return bool(self.layer_kinds) and not self.ssm_inner \
-            and set(self.layer_kinds) <= {"window", "full"}
+            and set(self.layer_kinds) <= {"window", "full", "conv"}
+
+    @property
+    def mixer_layers(self) -> Dict[str, int]:
+        """Layers of each mixer in a stack that has ``conv`` layers (the
+        leading dims of ``blocks["attn"]`` / ``blocks["conv"]``); empty
+        where every layer attends: one parameter tree a layer."""
+        if "conv" not in self.layer_kinds:
+            return {}
+        n = self.layer_kinds.count("conv")
+        return {m: c for m, c in (("attn", self.num_layers - n), ("conv", n))
+                if c}
 
     @property
     def segments(self) -> Tuple[Tuple[str, "TransformerConfig"], ...]:
@@ -363,7 +388,8 @@ class TransformerConfig:
             return total
         if self.first_dense_layers:
             shared = dataclasses.replace(self, first_dense_layers=0,
-                                         num_layers=0).num_params()
+                                         num_layers=0,
+                                         layer_kinds=()).num_params()
             return shared + sum(c.num_params() - shared
                                 for _, c in self.segments)
         h, f, v, l = self.hidden_size, self.ffn_size, self.vocab_size, self.num_layers
@@ -402,6 +428,14 @@ class TransformerConfig:
         if self.qk_norm:
             per_layer += 2 * self.head_dim
         total = l * per_layer + v * h + 2 * h
+        n_conv = self.layer_kinds.count("conv")
+        if n_conv:
+            # a ``conv`` layer has its mixer (in, taps, out) where the
+            # others have their attention
+            attn = h * qdim + 2 * h * kv + qdim * h \
+                + (h * qdim if self.attn_gate else 0) \
+                + (2 * self.head_dim if self.qk_norm else 0)
+            total += n_conv * (4 * h * h + self.conv_taps * h - attn)
         if self.emb_norm:
             total += 2 * h
         if not self.tie_embeddings:
@@ -414,6 +448,30 @@ class TransformerConfig:
 # --------------------------------------------------------------------------- #
 # init
 # --------------------------------------------------------------------------- #
+
+#: the standard block's attention leaves: in a stack with ``conv`` layers
+#: they are ``blocks["attn"]``, stacked over the attention layers alone
+_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+#: the sub-trees of a segment's blocks that are stacked by mixer
+MIXERS = ("attn", "conv")
+
+
+def mixer_of(kind: str) -> str:
+    """The mixer a layer of ``kind`` computes: whose leaves it reads in a
+    stack whose mixers' leaves are stacked apart."""
+    return "conv" if kind == "conv" else "attn"
+
+
+def _plain_blocks_beside_conv(cfg: TransformerConfig) -> None:
+    if "conv" in cfg.layer_kinds and (
+            cfg.mla or cfg.attn_bias_enabled or cfg.use_bias
+            or cfg.attn_gate or cfg.post_norms or cfg.parallel_block
+            or cfg.conv_taps < 2):
+        raise NotImplementedError(
+            "conv layers (conv_taps >= 2) stand in a stack of plain "
+            "sequential grouped-query blocks: no biases, latent attention, "
+            "output gate, post-norms or parallel residual")
+
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     """fp32 master parameters. Output projections scaled by 1/sqrt(2L) (GPT-2)."""
@@ -445,6 +503,11 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
         return jax.random.normal(key, shape, jnp.float32) * s
 
     block = {"ln1": norm_init((L, h))}
+    # in a stack with ``conv`` layers the mixers' leaves are stacked by
+    # mixer (``cfg.mixer_layers``): attention's over the attention layers
+    mixers = cfg.mixer_layers
+    _plain_blocks_beside_conv(cfg)
+    La = mixers.get("attn", 0) if mixers else L
     if cfg.mla:
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
@@ -462,10 +525,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
         block["wo"] = dense(keys[3], (L, N * dv, h), out_std)
     else:
         block.update({
-            "wq": dense(keys[0], (L, h, qdim), std),
-            "wk": dense(keys[1], (L, h, kvdim), std),
-            "wv": dense(keys[2], (L, h, kvdim), std),
-            "wo": dense(keys[3], (L, qdim, h), out_std),
+            "wq": dense(keys[0], (La, h, qdim), std),
+            "wk": dense(keys[1], (La, h, kvdim), std),
+            "wv": dense(keys[2], (La, h, kvdim), std),
+            "wo": dense(keys[3], (La, qdim, h), out_std),
         })
         if cfg.attn_gate:
             block["wg"] = dense(jax.random.fold_in(rng, 16), (L, h, qdim),
@@ -476,8 +539,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
         block["ln1_post"] = norm_init((L, h))
         block["ln2_post"] = norm_init((L, h))
     if cfg.qk_norm:
-        block["q_norm"] = jnp.ones((L, cfg.head_dim), jnp.float32)
-        block["k_norm"] = jnp.ones((L, cfg.head_dim), jnp.float32)
+        block["q_norm"] = jnp.ones((La, cfg.head_dim), jnp.float32)
+        block["k_norm"] = jnp.ones((La, cfg.head_dim), jnp.float32)
     E = cfg.n_experts
     if E > 0:
         # MoE FFN: per-expert weights (no biases), router gate per layer
@@ -513,6 +576,18 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
         if E == 0:
             block["b_up"] = jnp.zeros((L, f), jnp.float32)
             block["b_down"] = jnp.zeros((L, h), jnp.float32)
+
+    if mixers:
+        from deepspeed_tpu.models.hybrid import init_leaf, mixer_specs
+
+        attn = {k: block.pop(k) for k in _ATTN_LEAVES if k in block}
+        if La:
+            block["attn"] = attn
+        block["conv"] = {
+            name: init_leaf(how, (mixers["conv"],) + shape,
+                            jax.random.fold_in(rng, 17 + i), std, out_std)
+            for i, (name, (shape, _, how)) in enumerate(
+                sorted(mixer_specs(cfg, "conv").items()))}
 
     params = {
         "tok_emb": dense(keys[7], (cfg.vocab_size, h), std),
@@ -608,6 +683,14 @@ def param_logical_axes(cfg: TransformerConfig) -> PyTree:
         block["bo"] = lyr + ("embed",)
         if cfg.n_experts == 0:
             block.update({"b_up": lyr + ("mlp",), "b_down": lyr + ("embed",)})
+    if cfg.mixer_layers:
+        from deepspeed_tpu.models.hybrid import mixer_specs
+
+        attn = {k: block.pop(k) for k in _ATTN_LEAVES if k in block}
+        if "attn" in cfg.mixer_layers:
+            block["attn"] = attn
+        block["conv"] = {name: lyr + axes for name, (_, axes, _) in
+                         mixer_specs(cfg, "conv").items()}
     axes = {
         "tok_emb": ("vocab", "embed"),
         "blocks": block,
@@ -1007,7 +1090,10 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
     ``kind`` (a layer of ``cfg.layer_kinds`` over this one block,
     ``cfg.standard_blocks``): ``window`` sees its last ``cfg.attn_window``
     positions, ``full`` every one, both under an explicit mask in plain jnp
-    (the flash kernel has no window), whatever ``attention_fn`` says.
+    (the flash kernel has no window), whatever ``attention_fn`` says;
+    ``conv`` has a gated short convolution where the others attend
+    (``hybrid.short_conv``; ``lp`` then holds that mixer's leaves), and
+    the norms, the residual form and the FFN or experts are the block's.
 
     Sequential (GPT/Llama) or parallel (Falcon/NeoX/Phi: attn and FFN both
     branch off the residual stream and are summed back).
@@ -1047,7 +1133,7 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
 
     # the scopes a device trace sorts a block's operations by
     # (``attn`` / ``mlp``, under the engine's ``loss_and_grads``)
-    with jax.named_scope("attn"):
+    with jax.named_scope("conv" if kind == "conv" else "attn"):
         h = _aq(_norm(x, lp["ln1"], cfg.norm, cfg.norm_eps))
     if cfg.mla:
         with jax.named_scope("attn"):
@@ -1120,7 +1206,21 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
                              cfg.norm_eps)
         return attn_out
 
-    if cfg.remat == "attn_block":
+    @jax.named_scope("conv")
+    def _conv_from_norm(h):
+        # every sequence of the batch a run of rows from position 0
+        from deepspeed_tpu.models import hybrid as HY
+
+        runs = HY.runs_of(jnp.repeat(jnp.arange(B, dtype=jnp.int32), S),
+                          jnp.tile(jnp.arange(S, dtype=jnp.int32), B))
+        mixed, _ = HY.short_conv(
+            h.reshape(B * S, H), lp, runs,
+            jnp.zeros((B * S, cfg.conv_taps - 1, H), dt))
+        return (mixed @ lp["wo"].astype(dt)).reshape(B, S, H)
+
+    if kind == "conv":
+        attn_out = _conv_from_norm(h)
+    elif cfg.remat == "attn_block":
         # structural remat: bwd recomputes ONLY norm1 → attention → wo
         # (~37% of layer FLOPs at 4h² vs FFN's 8h²); every FFN intermediate
         # stays saved by the scan's AD — no names policy, so XLA's scan
@@ -1183,7 +1283,8 @@ def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
                 route_scale=cfg.moe_route_scale, shared=shared or None,
                 gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
                 topk_group=cfg.moe_topk_group,
-                first_expert=cfg.moe_first_expert)
+                first_expert=cfg.moe_first_expert,
+                route_norm_eps=cfg.moe_route_norm_eps)
             return down.reshape(h.shape), aux
         down, aux = moe_ffn(
             h, lp["gate_w"], experts, activation=cfg.activation,
@@ -1192,7 +1293,8 @@ def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
             score_func=cfg.moe_score_func, route_norm=cfg.moe_route_norm,
             route_scale=cfg.moe_route_scale, shared=shared or None,
             gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
-            topk_group=cfg.moe_topk_group, dispatch=cfg.moe_dispatch)
+            topk_group=cfg.moe_topk_group, dispatch=cfg.moe_dispatch,
+            route_norm_eps=cfg.moe_route_norm_eps)
     else:
         up = h @ lp["w_up"].astype(dt)
         if cfg.use_bias:
@@ -1396,18 +1498,48 @@ def scan_periods(body_of: Callable, carry, blocks: PyTree,
                  kinds: Sequence[str]):
     """Scan a segment of standard blocks a PERIOD of its kinds at a time:
     ``body_of(period, first layer of the run)(carry, lps)`` takes the
-    period's layers' parameters stacked ``[len(period), ...]``. The stacked leaves ``[layers, ...]``
-    are read as ``[steps, period, ...]`` (no copy where the segment is
-    whole periods)."""
-    outs = []
+    period's layers' parameters stacked ``[len(period), ...]``
+    (:func:`period_layer` takes one layer's out of them). The stacked
+    leaves ``[layers, ...]`` are read as ``[steps, period, ...]`` (no copy
+    where the segment is whole periods); leaves stacked by mixer
+    (``blocks["attn"]`` / ``blocks["conv"]``) by their own count a period."""
+    def cut(tree, ahead: int, per: int, steps: int):
+        n = per * steps
+        return jax.tree.map(
+            lambda a: (a if n == a.shape[0] else a[ahead:ahead + n]).reshape(
+                (steps, per) + a.shape[1:]), tree)
+
+    kinds, outs = tuple(kinds), []
     for first, period, steps in kind_runs(kinds):
-        n = len(period) * steps
-        xs = jax.tree.map(
-            lambda a: (a if n == a.shape[0] else a[first:first + n]).reshape(
-                (steps, len(period)) + a.shape[1:]), blocks)
+        # leaves stacked by mixer (a stack with ``conv`` layers) are cut
+        # by the layers of their mixer: those ahead of the run, those of
+        # a period (a run may hold none: its steps then take no such leaf)
+        per = {m: sum(mixer_of(k) == m for k in period)
+               for m in MIXERS if m in blocks}
+        xs = cut({k: v for k, v in blocks.items() if k not in per}, first,
+                 len(period), steps)
+        for m in (m for m, n in per.items() if n):
+            xs[m] = cut(blocks[m], sum(mixer_of(k) == m
+                                       for k in kinds[:first]),
+                        per[m], steps)
         carry, out = lax.scan(body_of(period, first), carry, xs)
         outs.append(out)
     return carry, outs
+
+
+def period_layer(lps: PyTree, period: Sequence[str], i: int) -> PyTree:
+    """Layer ``i`` of a period's parameters as :func:`scan_periods` hands
+    them to a step, as ONE flat tree: the leaves every layer has and, in a
+    stack whose mixers' leaves are stacked apart, those of the layer's own
+    mixer (the layer's index among its mixer's in the period)."""
+    mixer = mixer_of(period[i])
+    if mixer not in lps:
+        return jax.tree.map(lambda a: a[i], lps)
+    lp = jax.tree.map(lambda a: a[i],
+                      {k: v for k, v in lps.items() if k not in MIXERS})
+    j = sum(mixer_of(k) == mixer for k in period[:i])
+    lp.update(jax.tree.map(lambda a: a[j], lps[mixer]))
+    return lp
 
 
 def _forward_blocks_of_kinds(params: PyTree, tokens: jax.Array,
@@ -1434,7 +1566,7 @@ def _forward_blocks_of_kinds(params: PyTree, tokens: jax.Array,
             def body(x, lps):
                 aux = jnp.float32(0.0)
                 for i, kind in enumerate(period):
-                    lp = jax.tree.map(lambda a: a[i], lps)
+                    lp = period_layer(lps, period, i)
                     x, a = _block_forward(x, lp, seg, cos, sin,
                                           dot_product_attention, kind)
                     x, aux = constrain(x), aux + a

@@ -91,6 +91,13 @@ def _gate_scores(logits: jax.Array, score_func: str,
     return gate_source, probs, sel_logits
 
 
+def _normalized(gates: jax.Array, total: jax.Array, eps: float) -> jax.Array:
+    """A token's chosen scores over their sum. Published routers guard the
+    division differently: ``eps`` 0 divides by ``max(sum, 1e-9)``, a
+    value by ``sum + eps`` (``lfm2_moe``: 1e-6)."""
+    return gates / (total + eps if eps else jnp.maximum(total, 1e-9))
+
+
 def _iter_topk(sel_logits: jax.Array, gate_source: jax.Array, k: int):
     """Iterative argmax top-k (k small + static — unrolled).
     Returns (gates_list: k×[T], idx_list: k×[T] int32, masks: k×[T,E])."""
@@ -120,8 +127,8 @@ def topk_gating_indices(logits: jax.Array, k: int = 2,
                         normalize: bool = True,
                         score_func: str = "softmax",
                         select_bias: Optional[jax.Array] = None,
-                        n_group: int = 1, topk_group: int = 1
-                        ) -> IndexGateOutput:
+                        n_group: int = 1, topk_group: int = 1,
+                        normalize_eps: float = 0.0) -> IndexGateOutput:
     """Index-form top-k gate for DROPLESS dispatch — identical selection math
     to :func:`topk_gating` but no capacity and no [T,E,C] tensors.
 
@@ -135,8 +142,8 @@ def topk_gating_indices(logits: jax.Array, k: int = 2,
     gates = jnp.stack(gates_list, axis=1)                    # [T, k]
     experts = jnp.stack(idx_list, axis=1)                    # [T, k]
     if normalize:
-        gates = gates / jnp.maximum(
-            jnp.sum(gates, axis=1, keepdims=True), 1e-9)
+        gates = _normalized(gates, jnp.sum(gates, axis=1, keepdims=True),
+                            normalize_eps)
     return IndexGateOutput(gates, experts, aux, probs)
 
 
@@ -147,7 +154,8 @@ def topk_gating(logits: jax.Array, k: int = 2, capacity_factor: float = 1.25,
                 normalize: bool = True,
                 score_func: str = "softmax",
                 select_bias: Optional[jax.Array] = None,
-                n_group: int = 1, topk_group: int = 1) -> GateOutput:
+                n_group: int = 1, topk_group: int = 1,
+                normalize_eps: float = 0.0) -> GateOutput:
     """Generic top-k gate (k=1 → top1gating, k=2 → top2gating semantics).
 
     ``score_func``: 'softmax' (GShard/Mixtral/Qwen-MoE) or 'sigmoid'
@@ -184,7 +192,8 @@ def topk_gating(logits: jax.Array, k: int = 2, capacity_factor: float = 1.25,
         per_choice.append((mask, locations, gates_list[i]))
 
     for mask, locations, gate_raw in per_choice:
-        gate = gate_raw / jnp.maximum(denom, 1e-9) if normalize else gate_raw
+        gate = _normalized(gate_raw, denom, normalize_eps) if normalize \
+            else gate_raw
         loc_oh = jax.nn.one_hot(locations.astype(jnp.int32), C, dtype=jnp.float32)
         combine = combine + gate[:, None, None] * mask[:, :, None] * loc_oh
 

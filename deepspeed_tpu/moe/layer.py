@@ -49,6 +49,7 @@ from deepspeed_tpu.comm.mesh import (
 from deepspeed_tpu.moe.gating import (
     GateOutput,
     IndexGateOutput,
+    _normalized,
     topk_gating,
     topk_gating_indices,
 )
@@ -605,12 +606,13 @@ def routing_drop_stats(logits: jax.Array, k: int, capacity_factor: float,
 
 def _gate_indices(xt: jax.Array, gate_w: jax.Array,
                   gate_bias: Optional[jax.Array], k: int, score_func: str,
-                  route_norm: bool, n_group: int, topk_group: int
-                  ) -> IndexGateOutput:
+                  route_norm: bool, n_group: int, topk_group: int,
+                  route_norm_eps: float = 0.0) -> IndexGateOutput:
     logits = xt.astype(jnp.float32) @ gate_w.astype(jnp.float32)
     return topk_gating_indices(
         logits, k=k, normalize=route_norm, score_func=score_func,
-        select_bias=gate_bias, n_group=n_group, topk_group=topk_group)
+        select_bias=gate_bias, n_group=n_group, topk_group=topk_group,
+        normalize_eps=route_norm_eps)
 
 
 def ep_shard_capacity(local_choices: int, ep: int) -> int:
@@ -628,7 +630,8 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
                    experts: Dict[str, jax.Array],
                    gate_bias: Optional[jax.Array], *, activation: str, k: int,
                    score_func: str, route_norm: bool, n_group: int,
-                   topk_group: int) -> Tuple[jax.Array, jax.Array]:
+                   topk_group: int, route_norm_eps: float = 0.0
+                   ) -> Tuple[jax.Array, jax.Array]:
     """Dropless routed-expert computation. Returns (y [B,S,H], aux).
 
     Three lowerings by mesh shape: single-shard sort+ragged_dot; per-shard
@@ -650,7 +653,7 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
         # inputs are actually sharded.
         xt = x.reshape(-1, H)
         gate = _gate_indices(xt, gate_w, gate_bias, k, score_func,
-                             route_norm, n_group, topk_group)
+                             route_norm, n_group, topk_group, route_norm_eps)
         y = _ragged_dispatch_local(xt, gate.weights, gate.experts, experts,
                                    activation)
         return y.reshape(B, S, H), gate.aux_loss
@@ -688,7 +691,7 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
             b, s, _ = x_l.shape
             xt = x_l.reshape(-1, H)
             gate = _gate_indices(xt, gw_l, gb_l, k, score_func, route_norm,
-                                 n_group, topk_group)
+                                 n_group, topk_group, route_norm_eps)
             y = _ragged_dispatch_local(xt, gate.weights, gate.experts, ex_l,
                                        activation)
             if tp is not None:
@@ -705,7 +708,7 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
             t = xt.shape[0]
             dt = xt.dtype
             gate = _gate_indices(xt, gw_l, gb_l, k, score_func, route_norm,
-                                 n_group, topk_group)
+                                 n_group, topk_group, route_norm_eps)
             tk = t * k
             Cs = ep_shard_capacity(tk, ep)
             flat_e = gate.experts.reshape(tk)
@@ -775,7 +778,8 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
             keep = (slot < ep * Cs).reshape(t, k).astype(jnp.float32)
             w = gate.weights * keep
             if route_norm:
-                w = w / jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-9)
+                w = _normalized(w, jnp.sum(w, axis=1, keepdims=True),
+                                route_norm_eps)
             contrib = buffer_exchange(y_back, slot, slot2row) * \
                 w.reshape(tk)[:, None].astype(dt)
             y = contrib.reshape(t, k, H).sum(axis=1)
@@ -799,8 +803,9 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
     # unmonitored trace stays the zero-cost constant path
     monitored = (_DROP_MONITOR is not None and ep > 1
                  and not already_manual_axes())
-    cache_key = (sm_mesh, k, activation, score_func, route_norm, n_group,
-                 topk_group, x.shape, str(x.dtype), gate_w.shape,
+    cache_key = (sm_mesh, k, activation, score_func, route_norm,
+                 route_norm_eps, n_group, topk_group, x.shape, str(x.dtype),
+                 gate_w.shape,
                  monitored,
                  tuple(sorted((kk, v.shape, str(v.dtype))
                               for kk, v in experts.items())))
@@ -832,7 +837,7 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
             shared: Optional[Dict[str, jax.Array]] = None,
             gate_bias: Optional[jax.Array] = None,
             n_group: int = 1, topk_group: int = 1,
-            dispatch: str = "auto"
+            dispatch: str = "auto", route_norm_eps: float = 0.0
             ) -> Tuple[jax.Array, jax.Array]:
     """Mixture-of-experts FFN.
 
@@ -843,7 +848,8 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
     'dense' (capacity-factor GShard einsums) — see module docstring.
 
     Routing variants (AutoEP presets): ``score_func`` softmax|sigmoid,
-    ``route_norm`` renormalizes top-k weights, ``route_scale`` scales the
+    ``route_norm`` renormalizes top-k weights (``route_norm_eps``: what
+    guards that division, ``gating._normalized``), ``route_scale`` scales the
     routed output (DeepSeek routed_scaling_factor). ``shared`` adds an
     always-on shared expert (sw_up [H,Fs], sw_down [Fs,H], optional sw_gate
     [H,Fs], optional shared_gate_w [H,1] sigmoid gate — Qwen2-MoE).
@@ -858,7 +864,7 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
         y, aux = _ragged_routed(
             x, gate_w, experts, gate_bias, activation=activation, k=k,
             score_func=score_func, route_norm=route_norm, n_group=n_group,
-            topk_group=topk_group)
+            topk_group=topk_group, route_norm_eps=route_norm_eps)
         y = y.reshape(T, H)
     else:
         logits = xt.astype(jnp.float32) @ gate_w.astype(jnp.float32)   # [T, E]
@@ -866,7 +872,8 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
             logits, k=k, capacity_factor=capacity_factor,
             min_capacity=min_capacity, rng=rng, noise_std=noise_std,
             normalize=route_norm, score_func=score_func,
-            select_bias=gate_bias, n_group=n_group, topk_group=topk_group)
+            select_bias=gate_bias, n_group=n_group, topk_group=topk_group,
+            normalize_eps=route_norm_eps)
         aux = gate.aux_loss
 
         # dispatch: [T,E,C] × [T,H] → [E,C,H]; GSPMD turns the resharding of
@@ -913,7 +920,7 @@ def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
                      n_group: int = 1, topk_group: int = 1,
                      valid: Optional[jax.Array] = None,
                      layer: Optional[jax.Array] = None,
-                     first_expert: int = 0
+                     first_expert: int = 0, route_norm_eps: float = 0.0
                      ) -> Tuple[jax.Array, jax.Array]:
     """The serving form of :func:`moe_ffn` on flat local rows ``xt [T, H]``:
     always the dropless sort + grouped matmul, whatever ``moe_dispatch``
@@ -937,7 +944,7 @@ def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
     trace sorts the layer's operations by."""
     with jax.named_scope("router"):
         gate = _gate_indices(xt, gate_w, gate_bias, k, score_func,
-                             route_norm, n_group, topk_group)
+                             route_norm, n_group, topk_group, route_norm_eps)
         picked = jax.nn.one_hot(gate.experts, gate_w.shape[1],
                                 dtype=jnp.int32)              # [T, k, E]
         if valid is not None:

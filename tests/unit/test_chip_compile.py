@@ -170,6 +170,43 @@ def test_window_and_full_attention_compile_for_v5e(one_chip, shape):
     assert f"%{name}" in text
 
 
+# (rows of the tick bucket) of the ``lfm2_moe`` serving cell: 32 query heads
+# on 8 KV heads of 64 stored two to a pool row (``paged.kv_lane_pack``:
+# ``[bs, 4, 128]``; a block ``[bs, 8, 64]`` Mosaic refuses at the strided
+# load of a slot whose last dim is not 128), 272 + 1 sequence slots with a
+# table of 64 blocks each, two attention layers x 20,480 blocks
+PACKED_SHAPES = {"lfm2-global-2048x64": 2048, "lfm2-global-256x64": 256}
+
+
+@pytest.mark.parametrize("shape", sorted(PACKED_SHAPES))
+def test_lane_packed_attention_compiles_for_v5e(one_chip, shape):
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    T = PACKED_SHAPES[shape]
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def lowered(pool_dims, head):
+        pool = arg(pool_dims, jnp.bfloat16)
+        return jax.jit(
+            lambda q, k, v, t, n, w: paged_attention(
+                q, k, v, t, n, interpret=False, name="global_attention",
+                scale=0.125, mxu_dtype=jnp.bfloat16, row_table=w)
+        ).lower(arg((T, 32, head), jnp.bfloat16), pool, pool,
+                arg((273, 64), jnp.int32), arg((T,), jnp.int32),
+                arg((T,), jnp.int32))
+
+    text = lowered((40960, 32, 4, 128), 128).compile().as_text()
+    # one Mosaic call under the full layers' name, the pool at operand 3
+    # as ``benchmarks/roofline/swa_attention.position_bytes`` reads it
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%global_attention" in text
+    if T == 256:
+        with pytest.raises(Exception, match="last dim size is not 128"):
+            lowered((40960, 32, 8, 64), 64).compile()
+
+
 # (sequences a chip, their length, query heads, KV heads, head size, dtype,
 # blocks a caller names): the two training cells at the blocks
 # ``choose_blocks`` gives them, and what only Mosaic refuses: a length under a

@@ -402,17 +402,48 @@ READERS = ("win_ticks_per_s", "win_period_decode_ms", "win_period_mixed_ms",
            "gc_pause_ms_per_s", "host_preempts_per_s")
 
 
+class _Ticker:
+    """The toy run's clock: every reading lies a microsecond after the one
+    before it, whoever reads. The account's identities are sums of clock
+    readings, and on the host's own clock under six loaded test workers a
+    pre-emption between two of them (the snapshot at a window's edge; a
+    tick's entry to ``run_tick`` and its ``schedule_tick``) was worth more
+    than the 0.05 ms a tick the identities are held to (PR 37). Here a
+    reading the account does not cover costs a microsecond, as on an idle
+    machine, however long the machine took."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def __call__(self) -> float:
+        self.now += 1e-6
+        return self.now
+
+
 @pytest.fixture(scope="module")
 def toy_run(tiny_params):
     """A short run of the engine behind a frontend with the benchmark's
     two snapshots around it: what ``runners/serve.py`` hands the readers,
-    without its model, traffic and checks."""
+    without its model, traffic and checks, on an injected clock
+    (:class:`_Ticker`) for the program's spans and the frontend alike."""
     from benchmarks import harness
 
     telemetry.reset()
     FastGenEngine.slow_ticks.clear()
+    real, time.perf_counter = time.perf_counter, _Ticker()
+    try:
+        run = _toy_run(tiny_params, harness)
+    finally:
+        time.perf_counter = real
+    yield run
+    telemetry.reset()
+    FastGenEngine.slow_ticks.clear()
+
+
+def _toy_run(tiny_params, harness):
     eng = _engine(tiny_params)
-    fe = ServingFrontend(eng, register_health=False)
+    fe = ServingFrontend(eng, register_health=False,
+                         clock=time.perf_counter)
     rng = np.random.default_rng(5)
     ticks = []
 
@@ -433,15 +464,12 @@ def toy_run(tiny_params):
     marks["close"] = {"telemetry": harness.telemetry_snapshot(),
                       "ticks": len(ticks), "t": time.perf_counter()}
     fe.close()
-    run = harness.RunRecord(
+    return harness.RunRecord(
         cell=None, seconds=marks["close"]["t"] - marks["open"]["t"],
         chips=1, device={}, peaks=None, model=None, setup_s=0.0,
         client={"marks": marks, "ticks": ticks},
         telemetry=harness.Telemetry(marks["open"]["telemetry"],
                                     marks["close"]["telemetry"]))
-    yield run
-    telemetry.reset()
-    FastGenEngine.slow_ticks.clear()
 
 
 @pytest.mark.parametrize("name", READERS)

@@ -167,3 +167,41 @@ def test_a_tick_of_window_and_full_layers_tells_its_kernels_and_experts_apart():
     assert any("/attn/swa/swa_attention" in s for s in stacks)
     assert any("/attn/global/global_attention" in s for s in stacks)
     assert not any("/paged_attention" in s for s in stacks)
+
+
+def test_a_tick_with_conv_layers_sorts_the_mixers_time_apart():
+    """The ``lfm2_moe`` tick: a ``conv`` layer's mixer (its norm, the
+    projections, the taps, the state read and written) under a scope of
+    its own, beside the attention layers' ``attn/global`` and the expert
+    layers' parts: what ``conv_share_pct`` reads."""
+    import types
+
+    from deepspeed_tpu.models.hf_import import config_from_hf
+
+    cfg = config_from_hf(types.SimpleNamespace(
+        model_type="lfm2_moe", hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_attention_heads=4,
+        num_key_value_heads=2, num_hidden_layers=6, num_dense_layers=2,
+        layer_types=["conv", "conv", "full_attention", "conv", "conv",
+                     "conv"], num_experts=8, num_experts_per_tok=4,
+        norm_eps=1e-5, norm_topk_prob=True, routed_scaling_factor=1,
+        use_expert_bias=True, conv_L_cache=3, conv_bias=False,
+        rope_parameters={"rope_theta": 1000000, "rope_type": "default"},
+        vocab_size=128, max_position_embeddings=512))
+    eng = FastGenEngine(cfg, n_blocks=16, block_size=4, max_blocks_per_seq=8,
+                        token_budget=32, state_slots=2, seed=0,
+                        use_pallas_kernel=True)
+    tn, mb = 32, eng.max_blocks_per_seq
+    stacks = _stacks(eng._build_tick(tn, mb).lower(
+        eng.params, eng.pool, eng._pack_tick(
+            np.zeros((tn,), np.int32), np.zeros((tn,), np.int32),
+            np.zeros((tn, mb), np.int32), np.zeros((2,), np.uint32))),
+        "tick")
+    parts = {part for s in stacks for part in s.split("/")}
+    assert {"embed", "conv", "attn", "global", "mlp", "router", "experts",
+            "lm_head", "sample"} <= parts
+    assert any("/attn/global/global_attention" in s for s in stacks)
+    # a conv layer's operations are not under ``attn``, nor the other way
+    assert not any("/conv/" in s and "/attn/" in s for s in stacks)
+    assert any(s.split("/conv/")[-1].startswith("dot_general")
+               for s in stacks if "/conv/" in s)

@@ -370,12 +370,19 @@ class FastGenEngine:
         self._tm_h2d = telemetry.counter(
             "fastgen_tick_h2d_bytes_total",
             "bytes of the one host array a step() tick hands to its "
-            "program: tokens, positions, block tables and the key words")
+            "program: tokens, positions, block tables, the sampled rows of "
+            "a full-budget tick and the key words")
         self._tm_gen_tok = telemetry.counter(
             "fastgen_generated_tokens_total", "tokens sampled and kept")
         self._tm_prefill_tok = telemetry.counter(
             "fastgen_prefill_tokens_total",
             "prompt tokens written into the KV cache")
+        self._tm_head_rows = telemetry.counter(
+            "fastgen_head_rows_total",
+            "rows the head (final norm, vocabulary matmul, sampling) ran "
+            "for in step() ticks, by form: gathered (the sampled rows of a "
+            "full-budget tick, the small bucket's row count a tick) / all "
+            "(every row of the bucket)")
         self._tm_shared_rows = telemetry.counter(
             "fastgen_paged_shared_rows_total",
             "prompt rows of step() ticks that sat in a kernel tile wholly "
@@ -603,42 +610,75 @@ class FastGenEngine:
         return small if need <= small else self.token_budget
 
     # ------------------------------------------------------------------ #
-    @staticmethod
-    def _pack_tick(tokens: np.ndarray, positions: np.ndarray,
-                   tables: np.ndarray, key: np.ndarray) -> np.ndarray:
+    def _pack_tick(self, tokens: np.ndarray, positions: np.ndarray,
+                   tables: np.ndarray, key: np.ndarray,
+                   head_rows: Sequence[int] = ()) -> np.ndarray:
         """All a tick sends to the device, as ONE fresh contiguous int32
-        host array: ``[tables row by row | tokens | positions | the two
-        key words]``. Fresh a tick: a numpy buffer handed to the runtime
-        may not change until its transfer is done."""
+        host array: ``[tables row by row | tokens | positions | the
+        sampled rows | the two key words]``. The sampled rows are there
+        for a tick larger than the small bucket ``S`` alone: the first
+        ``S`` of ``head_rows`` (the rows whose token the host will read),
+        the last repeated to fill, and behind them their count. Fresh a
+        tick: a numpy buffer handed to the runtime may not change until
+        its transfer is done."""
+        S, count = self._bucket(0), len(head_rows)
+        head = np.empty((0,), np.int32)
+        if len(tokens) > S:
+            head = np.full((S + 1,), head_rows[-1] if count else 0, np.int32)
+            head[:min(count, S)] = head_rows[:S]
+            head[S] = count
         return np.concatenate(
-            (tables.ravel(), tokens, positions, key.view(np.int32)))
+            (tables.ravel(), tokens, positions, head, key.view(np.int32)))
 
     def _build_tick(self, Tn: int, mb: int):
         """The tick program of ``Tn`` rows over tables ``mb`` blocks wide.
         It takes ``_pack_tick``'s array and cuts it apart with static
-        slices, so a tick crosses to the device once."""
+        slices, so a tick crosses to the device once.
+
+        A tick larger than the small bucket ``S`` samples few of its rows
+        (the decode rows, a finished prompt's last): where they fit in
+        ``S`` the head runs for those rows, gathered from the last hidden
+        state, and their tokens come back in the order sent, at the front
+        of the ``[Tn]`` result; where they do not, for every row as in the
+        small bucket, a row's token at its row. One program either way:
+        the count in the packed array chooses."""
         cfg, attn = self.cfg, self._attention
         n = Tn * mb
+        S = self._bucket(0)
 
         def tick(params, pool, packed):
             tables = packed[:n].reshape(Tn, mb)
             tokens = packed[n:n + Tn]
             positions = packed[n + Tn:n + 2 * Tn]
             # the same bits the host drew: a raw uint32[2] threefry key
-            rng = jax.lax.bitcast_convert_type(
-                packed[n + 2 * Tn:], jnp.uint32)
-            logits, pool, *stats = PG.forward_paged(
+            rng = jax.lax.bitcast_convert_type(packed[-2:], jnp.uint32)
+            x, pool, stats = PG.forward_hidden(
                 params, tokens, positions, tables, pool, cfg,
-                attention_fn=attn, with_stats=bool(self._expert_layers))
-            with jax.named_scope("sample"):
-                sampled = sample_logits(
-                    logits, rng, self.temperature, self.top_k,
-                    self.top_p).astype(jnp.int32)
-            if stats:
+                attention_fn=attn)
+
+            def sample(x):
+                # with a temperature a row's draw comes from the key and
+                # the row's place in ``x``: the gathered rows draw from
+                # the same distribution by another stream
+                logits = PG.head_logits(params, x, cfg)
+                with jax.named_scope("sample"):
+                    return sample_logits(
+                        logits, rng, self.temperature, self.top_k,
+                        self.top_p).astype(jnp.int32)
+
+            if Tn > S:
+                head_rows = packed[n + 2 * Tn:n + 2 * Tn + S]
+                sampled = jax.lax.cond(
+                    packed[n + 2 * Tn + S] <= S,
+                    lambda x: jnp.pad(sample(x[head_rows]), (0, Tn - S)),
+                    sample, x)
+            else:
+                sampled = sample(x)
+            if self._expert_layers:
                 # one array, one read-back: the rows each expert got
                 # ([layers, E]) behind the sampled tokens
                 sampled = jnp.concatenate(
-                    [sampled, stats[0]["expert_rows"].reshape(-1)])
+                    [sampled, stats["expert_rows"].reshape(-1)])
             return sampled, pool
 
         return jax.jit(tick, donate_argnums=(1,))
@@ -1252,6 +1292,11 @@ class FastGenEngine:
         if cold:
             self._ticks[key] = self._build_tick(Tn, mb)
         n_decode_rows = sum(1 for _, _, is_d in heads if is_d)
+        head_rows = [h[0] for h in heads]
+        # the tick program's own rule (``_build_tick``)
+        S = self._bucket(0)
+        gathered = Tn > S and len(heads) <= S
+        head_computed = S if gathered else Tn
         # a tick that holds no prompt row is a decode tick, whatever
         # entry point ran it
         kind = "decode" if n_decode_rows == row else "mixed"
@@ -1279,10 +1324,13 @@ class FastGenEngine:
                 "prompt_attended": int(positions[n_decode_rows:row].sum())
                 + row - n_decode_rows,
                 "shared_rows": shared_rows, "bucket": Tn,
+                # rows whose token is read back, rows the head ran for
+                "head_rows": len(heads), "head_computed": head_computed,
                 "mb_tier": tier}) as tick_span:
             packed = self._pack_tick(
                 tokens, positions, tables[:, :mb],
-                self._host_rng.integers(0, 2 ** 32, 2, dtype=np.uint32))
+                self._host_rng.integers(0, 2 ** 32, 2, dtype=np.uint32),
+                head_rows)
             # enqueue: the jitted call on the one host array (its copy
             # to the device is the call's own), until it returns (the
             # device may still be running), and the copy back queued
@@ -1363,6 +1411,8 @@ class FastGenEngine:
             self._tm_ticks.inc(kind=kind, mb_tier=tier)
             self._tm_h2d.inc(packed.nbytes)
             self._tm_prefill_tok.inc(row - n_decode_rows)
+            self._tm_head_rows.inc(
+                head_computed, form="gathered" if gathered else "all")
             self._tm_shared_rows.inc(shared_rows)
             if attn_steps:
                 self._tm_attn_steps.inc(attn_open, form="open")
@@ -1374,7 +1424,8 @@ class FastGenEngine:
             out: Dict[int, int] = {}
             kept = 0
             for (r, seq, is_decode), tok in zip(
-                    heads, sampled[[h[0] for h in heads]].tolist()):
+                    heads, (sampled[:len(heads)] if gathered
+                            else sampled[head_rows]).tolist()):
                 if is_decode:
                     seq.pos += 1   # the decode input token entered the cache
                 seq.last_tok = tok

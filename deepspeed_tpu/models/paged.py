@@ -722,13 +722,51 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
                   tables: jax.Array, pool: Dict[str, jax.Array],
                   cfg: T.TransformerConfig,
                   attention_fn: Optional[Callable] = None,
-                  with_stats: bool = False):
+                  with_stats: bool = False,
+                  head_rows: Optional[jax.Array] = None):
     """One SplitFuse tick over a flat token batch.
 
     tokens [T] int32, positions [T] int32, tables [T, MB] int32 (rows shared
     by tokens of the same sequence). Returns (logits [T, vocab] fp32,
     updated pool). Parity: the reference's model-implementation forward over
     a RaggedBatchWrapper (``inference/v2/model_implementations``).
+
+    ``head_rows`` [S] int32: the head runs for those rows of the tick only
+    (gathered from the last hidden state, before the final norm) and the
+    logits are [S, vocab], row ``i`` those of tick row ``head_rows[i]``.
+
+    ``with_stats`` adds a third result, ``{"expert_rows": [expert layers,
+    E] int32}`` (rows each expert got from the tick's real rows; ``{}`` for
+    a model without experts).
+    """
+    x, new_pool, stats = forward_hidden(
+        params, tokens, positions, tables, pool, cfg, attention_fn)
+    logits = head_logits(
+        params, x if head_rows is None else x[head_rows], cfg)
+    if with_stats:
+        return logits, new_pool, stats
+    return logits, new_pool
+
+
+def head_logits(params: PyTree, x: jax.Array,
+                cfg: T.TransformerConfig) -> jax.Array:
+    """The head over rows of the last hidden state: final norm and the
+    vocabulary matmul, [rows, vocab] fp32."""
+    with jax.named_scope("lm_head"):
+        x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        head = T._lm_head_of(params, cfg)
+        logits = T.head_matmul(x, head.astype(x.dtype))
+        if cfg.lm_head_bias:
+            logits = logits + params["lm_head_b"].astype(jnp.float32)
+    return logits
+
+
+def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
+                   tables: jax.Array, pool: Dict[str, jax.Array],
+                   cfg: T.TransformerConfig,
+                   attention_fn: Optional[Callable] = None):
+    """:func:`forward_paged` up to the head: (the last hidden state [T, H],
+    updated pool, stats).
 
     One skeleton for every model: embed, the rows' blocks and lengths, one
     scan per segment of ``cfg.segments`` (leading dense layers, then the
@@ -743,10 +781,8 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     reference means no, anything else yes; which function then runs is
     ``cfg``'s to say, not the caller's (:func:`tick_attention`).
 
-    Expert layers run dropless (:func:`_tick_experts`). ``with_stats``
-    adds a third result, ``{"expert_rows": [expert layers, E] int32}``
-    (rows each expert got from the tick's real rows; ``{}`` for a model
-    without experts).
+    Expert layers run dropless (:func:`_tick_experts`); ``stats`` is
+    ``{"expert_rows": ...}`` for a model with experts, else ``{}``.
     """
     from deepspeed_tpu.ops.quantization import dequant_params
 
@@ -925,13 +961,4 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
         if n_rows is not None:
             stats["expert_rows"] = n_rows
     x, flat = carry[:2]
-    with jax.named_scope("lm_head"):
-        x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-        head = T._lm_head_of(params, cfg)
-        logits = T.head_matmul(x, head.astype(x.dtype))
-        if cfg.lm_head_bias:
-            logits = logits + params["lm_head_b"].astype(jnp.float32)
-    new_pool = {k: flat[k].reshape(v.shape) for k, v in pool.items()}
-    if with_stats:
-        return logits, new_pool, stats
-    return logits, new_pool
+    return x, {k: flat[k].reshape(v.shape) for k, v in pool.items()}, stats

@@ -743,16 +743,17 @@ def test_step_tick_on_one_packed_array_matches_the_unpacked_operands(
     with_stats = bool(eng._expert_layers)
 
     @jax.jit
-    def unpacked(params, pool, tokens, positions, tables, key):
+    def unpacked(params, pool, tokens, positions, tables, key, head_rows):
         logits, pool, *stats = PG.forward_paged(
             params, tokens, positions, tables, pool, cfg,
-            attention_fn=attn, with_stats=with_stats)
+            attention_fn=attn, with_stats=with_stats, head_rows=head_rows)
         sampled = sample_logits(logits, key, temperature, 50,
                                 1.0).astype(jnp.int32)
         return sampled, pool, [s["expert_rows"] for s in stats]
 
     draws = np.random.default_rng(seed)
     pool = PG.init_paged_kv(cfg, 48, 8)
+    gathered_ticks = 0
     for c in calls:
         Tn, mb, packed = c["Tn"], c["mb"], c["packed"]
         # (params, pool, the one host array): nothing else crosses over
@@ -763,25 +764,111 @@ def test_step_tick_on_one_packed_array_matches_the_unpacked_operands(
         # nor after
         np.testing.assert_array_equal(packed, c["then"])
         n = Tn * mb
-        assert packed.size == n + 2 * Tn + 2
-        key = packed[n + 2 * Tn:].view(np.uint32)
+        # the full bucket alone carries the rows it samples (as many as
+        # the small bucket has rows) and their count
+        head = packed[n + 2 * Tn:-2]
+        assert head.size == (9 if Tn == 32 else 0)
+        gathered = Tn == 32 and head[8] <= 8
+        gathered_ticks += gathered
+        key = packed[-2:].view(np.uint32)
         np.testing.assert_array_equal(
             key, draws.integers(0, 2 ** 32, 2, dtype=np.uint32))
         want, pool, rows = unpacked(
             params, pool, packed[n:n + Tn], packed[n + Tn:n + 2 * Tn],
-            packed[:n].reshape(Tn, mb), key)
+            packed[:n].reshape(Tn, mb), key,
+            head[:8] if gathered else None)
         got = np.asarray(c["sampled"])
-        np.testing.assert_array_equal(got[:Tn], np.asarray(want))
+        if gathered:
+            np.testing.assert_array_equal(got[:8], np.asarray(want))
+            assert not got[8:Tn].any()
+        else:
+            np.testing.assert_array_equal(got[:Tn], np.asarray(want))
         if with_stats:
             np.testing.assert_array_equal(
                 got[Tn:], np.asarray(rows[0]).reshape(-1))
         else:
             assert got.shape == (Tn,)
+    assert gathered_ticks
     # a fresh array a tick: none is handed over twice
     assert len({id(c["packed"]) for c in calls}) == len(calls)
     # the counter says what crossed, tick by tick
     sent = [c["packed"].nbytes for c in calls]
     assert [b - a for a, b in zip(counted, counted[1:])] == sent
+
+
+@pytest.mark.parametrize("model", sorted(PACKED_MODELS))
+def test_step_runs_the_head_for_the_rows_it_samples(packed_models, model):
+    """Budget 32, small bucket 8: chunk ticks and decode ticks alternate,
+    and a late prompt's chunks meet ten decoding sequences, more than the
+    small bucket holds. Every tick's tokens are the argmax of
+    ``forward_paged``'s full logits at the rows sampled, whichever branch
+    of the full-bucket program ran; the branch is not a program of its
+    own; the tick's span and the counter say which ran."""
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.telemetry import tracing
+
+    cfg, params = packed_models[model]
+    eng = FastGenEngine(cfg, params, n_blocks=96, block_size=8,
+                        max_blocks_per_seq=16, token_budget=32,
+                        temperature=0.0, seed=0)
+    calls = _recorded_ticks(eng)
+    counter = telemetry.counter("fastgen_head_rows_total")
+    before = {f: counter.value(form=f) for f in ("gathered", "all")}
+    tracer = tracing.get_tracer()
+    was, tracer.enabled = tracer.enabled, True
+    rng = np.random.default_rng(5)
+    outs = []
+    try:
+        eng.put([1, 2, 3], _prompts(rng, [40, 5, 11]))
+        outs += [eng.step() for _ in range(5)]
+        eng.put(list(range(4, 11)), _prompts(rng, [3, 4, 2, 5, 3, 2, 4]))
+        outs += [eng.step() for _ in range(2)]
+        eng.put([11], _prompts(rng, [50]))
+        outs += [eng.step() for _ in range(4)]
+        # the tracer is the process's: this engine's ticks are the last
+        events = [e["args"] for e in tracer.export_chrome()["traceEvents"]
+                  if e.get("name") == "decode_tick"][-len(calls):]
+    finally:
+        tracer.enabled = was
+    assert len(calls) == len(outs) == len(events) == 11
+
+    attn = eng._attention
+    fwd = jax.jit(lambda params, pool, t, pos, tb: PG.forward_paged(
+        params, t, pos, tb, pool, cfg, attention_fn=attn))
+    pool = PG.init_paged_kv(cfg, 96, 8)
+    forms = {"gathered": 0, "all": 0}
+    for c, out, ev in zip(calls, outs, events):
+        Tn, mb, packed = c["Tn"], c["mb"], c["packed"]
+        n = Tn * mb
+        logits, pool = fwd(params, pool, packed[n:n + Tn],
+                           packed[n + Tn:n + 2 * Tn],
+                           packed[:n].reshape(Tn, mb))
+        want = np.argmax(np.asarray(logits), -1)
+        got = np.asarray(c["sampled"])[:Tn]
+        head = packed[n + 2 * Tn:-2]
+        gathered = Tn == 32 and head[8] <= 8
+        if gathered:
+            np.testing.assert_array_equal(got[:head[8]],
+                                          want[head[:head[8]]])
+            sampled = set(got[:head[8]].tolist())
+        else:
+            np.testing.assert_array_equal(got, want)
+            sampled = set(got[:ev["rows"]].tolist())
+        # what step() handed back is among the rows' tokens
+        assert set(out.values()) <= sampled
+        assert ev["bucket"] == Tn and ev["head_rows"] == len(out)
+        assert ev["head_computed"] == (8 if gathered else Tn)
+        if Tn == 32:
+            assert head[8] == len(out)
+        forms["gathered" if gathered else "all"] += ev["head_computed"]
+    full = [ev for ev in events if ev["bucket"] == 32]
+    assert {ev["head_computed"] for ev in full} == {8, 32}
+    assert any(ev["head_rows"] > 8 for ev in full)
+    assert any(ev["bucket"] == 8 for ev in events)
+    for form, rows in forms.items():
+        assert counter.value(form=form) - before[form] == rows
+    # one program a (rows, table width) key, as before there was a branch
+    assert len(eng._ticks) == len({(c["Tn"], c["mb"]) for c in calls})
 
 
 def test_step_tick_queues_the_copy_back_inside_the_dispatch(packed_models):

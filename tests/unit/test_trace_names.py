@@ -31,6 +31,16 @@ def _stacks(lowered, program):
     return {n for n in names if n.startswith(f"jit({program})/")}
 
 
+def _assert_both_heads_are_scoped(stacks):
+    """A tick larger than the small bucket holds its head twice, behind a
+    conditional (the sampled rows alone, or every row): the operations of
+    either branch sit under ``lm_head`` and ``sample``."""
+    for branch in ("branch_0_fun", "branch_1_fun"):
+        for scope in ("lm_head", "sample"):
+            assert any(s.startswith(f"jit(tick)/cond/{branch}/{scope}")
+                       for s in stacks), (branch, scope)
+
+
 def test_every_pallas_call_is_named():
     sites, literal = 0, set()
     for path in sorted(glob.glob(os.path.join(PALLAS, "*.py"))):
@@ -128,6 +138,7 @@ def test_serving_tick_carries_scopes_and_the_kernel_name():
         "tick")
     parts = {part for s in stacks for part in s.split("/")}
     assert {"embed", "attn", "mlp", "lm_head", "sample"} <= parts
+    _assert_both_heads_are_scoped(stacks)
     # the paged kernel by name, inside a layer's attention scope
     assert any("/attn/paged_attention" in s for s in stacks)
 
@@ -164,6 +175,7 @@ def test_a_tick_of_window_and_full_layers_tells_its_kernels_and_experts_apart():
     parts = {part for s in stacks for part in s.split("/")}
     assert {"embed", "attn", "mlp", "swa", "global", "router", "experts",
             "shared_experts", "lm_head", "sample"} <= parts
+    _assert_both_heads_are_scoped(stacks)
     assert any("/attn/swa/swa_attention" in s for s in stacks)
     assert any("/attn/global/global_attention" in s for s in stacks)
     assert not any("/paged_attention" in s for s in stacks)
@@ -200,6 +212,7 @@ def test_a_tick_with_conv_layers_sorts_the_mixers_time_apart():
     parts = {part for s in stacks for part in s.split("/")}
     assert {"embed", "conv", "attn", "global", "mlp", "router", "experts",
             "lm_head", "sample"} <= parts
+    _assert_both_heads_are_scoped(stacks)
     assert any("/attn/global/global_attention" in s for s in stacks)
     # a conv layer's operations are not under ``attn``, nor the other way
     assert not any("/conv/" in s and "/attn/" in s for s in stacks)

@@ -32,8 +32,9 @@ from __future__ import annotations
 
 import collections
 import itertools
+import math
 import time
-from typing import Any, Dict, List, Optional, Sequence, Union
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +42,7 @@ import numpy as np
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.sampling import sample_logits
+from deepspeed_tpu.models import hybrid as HY
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.utils.compile_cache import ensure_compile_cache
@@ -225,11 +227,34 @@ class FastGenEngine:
         # state) lives in one
         # of ``state_slots`` rows, acquired with a sequence's first block
         # and freed with it; 0 for every other model. The default holds a
-        # small tick bucket's decode rows.
+        # small tick bucket's decode rows, or as many as take no more
+        # memory than the blocks do (a slot of delta-rule state is
+        # megabytes: ``_pool_bytes``).
+        def pool_bytes(slots):
+            return self._pool_bytes(cfg, n_blocks, block_size, slots,
+                                    token_budget)
+
         if not cfg.layer_kinds:
             state_slots = 0
         elif state_slots is None:
             state_slots = max(1, min(token_budget // 8, (n_blocks - 1) // 2))
+            blocks, one = pool_bytes(1)
+            per_slot = pool_bytes(2)[1] - one
+            state_slots = max(1, min(state_slots, int(
+                max(blocks, 256 << 20) // max(per_slot, 1))))
+        # reckoned before it is built: a pool that cannot fit says so here
+        # and not as an allocation failure in the middle of a tick
+        blocks, state = pool_bytes(state_slots)
+        stats = jax.devices()[0].memory_stats() or {}
+        weights = sum(x.nbytes for x in jax.tree.leaves(self.params))
+        if stats.get("bytes_limit") and \
+                weights + blocks + state > stats["bytes_limit"]:
+            raise ValueError(
+                f"the pool does not fit the device: {n_blocks} blocks of "
+                f"{block_size} take {blocks / 1e9:.2f} GB and {state_slots} "
+                f"sequence slots' state {state / 1e9:.2f} GB beside "
+                f"{weights / 1e9:.2f} GB of weights, of "
+                f"{stats['bytes_limit'] / 1e9:.2f} GB")
         self.allocator = BlockAllocator(n_blocks, state_slots)
         self.pool = PG.init_paged_kv(cfg, n_blocks, block_size,
                                      state_slots=state_slots,
@@ -322,6 +347,22 @@ class FastGenEngine:
         # tick's sampled tokens; 0 for a model without experts
         self._expert_layers = sum(
             c.num_layers for _, c in cfg.segments if c.n_experts)
+
+    #: the pool's arrays that hold a row a sequence SLOT, not blocks
+    _STATE_STORES = ("wk", "wv", "conv", "ssm", "kda", "kda_conv")
+
+    @classmethod
+    def _pool_bytes(cls, cfg, n_blocks: int, block_size: int,
+                    state_slots: int, max_run: int) -> Tuple[int, int]:
+        """(bytes of the block stores, bytes of the per-slot state stores)
+        of the pool ``init_paged_kv`` would build, without building it."""
+        shapes = jax.eval_shape(lambda: PG.init_paged_kv(
+            cfg, n_blocks, block_size, state_slots=state_slots,
+            max_run=max_run))
+        size = {k: math.prod(v.shape) * v.dtype.itemsize
+                for k, v in shapes.items()}
+        state = sum(v for k, v in size.items() if k in cls._STATE_STORES)
+        return sum(size.values()) - state, state
 
     def _dev(self, x) -> jax.Array:
         """Host array → device; REPLICATED across the mesh under TP (a
@@ -489,13 +530,26 @@ class FastGenEngine:
             "fastgen_state_bytes_per_slot",
             "bytes a sequence slot holds whatever its sequence's length, by "
             "kind: conv (a convolution's last inputs) / ring (window "
-            "layers' keys and values) / scan (a recurrence's matrix)")
-        for kind, names in (("conv", ("conv",)), ("ring", ("wk", "wv")),
-                            ("scan", ("ssm",))):
+            "layers' keys and values) / scan (a recurrence's matrix) / "
+            "rule (a delta rule's matrices)")
+        for kind, names in (("conv", ("conv", "kda_conv")),
+                            ("ring", ("wk", "wv")), ("scan", ("ssm",)),
+                            ("rule", ("kda",))):
             held = [self.pool[n] for n in names if n in self.pool]
             if held:
                 state_bytes.set(sum(x.nbytes for x in held)
                                 / (self.allocator.state_slots + 1), kind=kind)
+        telemetry.gauge(
+            "fastgen_state_bytes",
+            "bytes of the stores that hold a row a sequence slot (rings, "
+            "convolution, recurrence and delta-rule state), all slots"
+        ).set(sum(self.pool[n].nbytes for n in self._STATE_STORES
+                  if n in self.pool))
+        self._tm_kda_rows = telemetry.counter(
+            "fastgen_kda_rows_total",
+            "rows of ticks through the delta-rule layers, by the form of "
+            "the rule that took them: step (runs of one row: one read and "
+            "one write of the sequence's state) / chunk (chunkwise)")
         self._period_keys: Dict[tuple, tuple] = {}   # (kind, Tn) -> keys
         # the last step() tick's end (None before the first and after a
         # fused window, whose ticks are not accounted), and whether the
@@ -1197,7 +1251,7 @@ class FastGenEngine:
             # of a model with window layers, the cache positions inside
             # the rows' windows (what a window layer must read, a
             # sequence) and those the prompt rows score (a row)
-            state_runs = 0
+            state_runs = runs_of_one = 0
             W = self.cfg.attn_window
             window_positions = window_attended = 0
             # prompt rows in kernel tiles wholly inside one chunk
@@ -1258,6 +1312,7 @@ class FastGenEngine:
                     continue
                 self._ensure_blocks(seq, seq.pos + chunk - 1)
                 state_runs += seq.pos > 0
+                runs_of_one += chunk == 1
                 if W:
                     window_positions += min(seq.pos + chunk, chunk + W - 1)
                     window_attended += int(np.minimum(
@@ -1313,6 +1368,17 @@ class FastGenEngine:
                 # each writes its slot's state in every conv layer
                 slot_attrs["conv_state_rows"] = \
                     n_decode_rows + len(chunk_starts)
+            if "kda" in self.cfg.layer_kinds:
+                # the rule's two forms by the program's own rule
+                # (``hybrid.delta_rule``): runs of one row, up to the one-
+                # row form's count, and the rows of every other run; and
+                # the rows that close a run, each of which writes its
+                # slot's state in every kda layer
+                kda_step = min(n_decode_rows + runs_of_one, Tn,
+                               HY.KDA_STEP_ROWS)
+                slot_attrs.update(
+                    kda_step_rows=kda_step, kda_chunk_rows=row - kda_step,
+                    kda_state_rows=n_decode_rows + len(chunk_starts))
         with telemetry.span("decode_tick", attrs={
                 **slot_attrs,
                 "tick": self._ticks_run, "kind": kind, "rows": row,
@@ -1414,6 +1480,11 @@ class FastGenEngine:
             self._tm_head_rows.inc(
                 head_computed, form="gathered" if gathered else "all")
             self._tm_shared_rows.inc(shared_rows)
+            if "kda_step_rows" in slot_attrs:
+                self._tm_kda_rows.inc(slot_attrs["kda_step_rows"],
+                                      form="step")
+                self._tm_kda_rows.inc(slot_attrs["kda_chunk_rows"],
+                                      form="chunk")
             if attn_steps:
                 self._tm_attn_steps.inc(attn_open, form="open")
                 self._tm_attn_steps.inc(attn_steps - attn_open,

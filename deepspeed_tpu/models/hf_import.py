@@ -1223,8 +1223,176 @@ def params_from_lfm2_moe(sd: Dict[str, Any], cfg: TransformerConfig
     return params
 
 
+# --------------------------------------------------------------------------- #
+# Kimi-Linear (Moonshot: delta-rule linear-attention layers, three to every
+# latent-attention layer without rotary, over expert layers)
+# --------------------------------------------------------------------------- #
+
+def config_from_kimi_linear(hf_config) -> TransformerConfig:
+    """``model_type`` ``kimi_linear``: every layer ``x += mixer(input_
+    layernorm x); x += ffn(post_attention_layernorm x)`` under RMSNorm;
+    ``linear_attn_config`` lists (from 1) the layers whose mixer is Kimi
+    Delta Attention (``kda_layers``: ``num_heads`` heads of ``head_dim``
+    behind convolutions of ``short_conv_kernel_size`` taps; the low-rank
+    width of its decay and output gates is ``head_dim``, which the config
+    does not carry) and those with latent attention (``full_attn_layers``;
+    DeepSeek's, direct queries, and NO rotary where ``mla_use_nope``); the
+    first ``first_k_dense_replace`` layers have a dense SwiGLU FFN, the
+    others ``num_experts`` routed experts beside ``num_shared_experts``
+    shared ones under a sigmoid router with a selection bias, one routing
+    group. A SHARE of the expert layers as ``afmoe``'s: ``num_experts`` is
+    then the experts held, ``router_experts`` the router's width and
+    ``first_expert`` the first one held."""
+    la = dict(hf_config.linear_attn_config)
+    L = hf_config.num_hidden_layers
+    kda, full = set(la["kda_layers"]), set(la["full_attn_layers"])
+    if kda & full or kda | full != set(range(1, L + 1)):
+        raise ValueError(
+            "kimi_linear: kda_layers and full_attn_layers name every layer "
+            f"1 .. {L} once (got {sorted(kda)} and {sorted(full)})")
+    if getattr(hf_config, "q_lora_rank", None) \
+            or getattr(hf_config, "rope_scaling", None) \
+            or not getattr(hf_config, "mla_use_nope", False) \
+            or int(getattr(hf_config, "num_expert_group", 1) or 1) != 1 \
+            or int(getattr(hf_config, "moe_layer_freq", 1) or 1) != 1 \
+            or getattr(hf_config, "moe_router_activation_func",
+                       "sigmoid") != "sigmoid":
+        raise NotImplementedError(
+            "kimi_linear: direct queries, latent attention without rotary "
+            "(mla_use_nope), one routing group, an expert layer every "
+            "layer and sigmoid scores are what is written")
+    held = hf_config.num_experts
+    router = int(getattr(hf_config, "router_experts", held))
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size, hidden_size=hf_config.hidden_size,
+        num_layers=L, num_heads=hf_config.num_attention_heads,
+        ffn_hidden_size=hf_config.intermediate_size,
+        max_seq_len=int(getattr(hf_config, "model_max_length", None)
+                        or hf_config.max_position_embeddings),
+        pos_emb="none", norm="rmsnorm", activation="swiglu", use_bias=False,
+        tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
+        norm_eps=hf_config.rms_norm_eps, dtype="float32",
+        mla=True, q_lora_rank=None, kv_lora_rank=hf_config.kv_lora_rank,
+        qk_nope_head_dim=hf_config.qk_nope_head_dim,
+        qk_rope_head_dim=hf_config.qk_rope_head_dim,
+        v_head_dim=hf_config.v_head_dim, rope_interleave=False,
+        layer_kinds=tuple("kda" if i in kda else "latent"
+                          for i in range(1, L + 1)),
+        kda_heads=int(la["num_heads"]), kda_head_dim=int(la["head_dim"]),
+        kda_rank=int(la["head_dim"]),
+        kda_conv=int(la["short_conv_kernel_size"]),
+        n_experts=held, moe_top_k=hf_config.num_experts_per_token,
+        moe_ffn_size=hf_config.moe_intermediate_size,
+        moe_shared_size=int(getattr(hf_config, "num_shared_experts", 0) or 0)
+        * hf_config.moe_intermediate_size,
+        moe_score_func="sigmoid",
+        moe_route_norm=bool(getattr(hf_config, "moe_renormalize", True)),
+        moe_route_scale=float(getattr(hf_config, "routed_scaling_factor",
+                                      1.0)),
+        moe_gate_bias=True, moe_dispatch="ragged",
+        moe_router_experts=router if router != held else 0,
+        moe_first_expert=int(getattr(hf_config, "first_expert", 0)),
+        first_dense_layers=min(int(getattr(
+            hf_config, "first_k_dense_replace", 0) or 0), L))
+
+
+#: a ``kda`` layer's leaves as the family's modelling code names them
+#: (``self_attn.<name>.weight`` but for the two parameters), and whether the
+#: tensor is a matrix ``[out, in]``
+_KDA_TENSORS = {
+    "wq": ("q_proj.weight", True), "wk": ("k_proj.weight", True),
+    "wv": ("v_proj.weight", True), "w_fa": ("f_a_proj.weight", True),
+    "w_fb": ("f_b_proj.weight", True), "w_b": ("b_proj.weight", True),
+    "w_ga": ("g_a_proj.weight", True), "w_gb": ("g_b_proj.weight", True),
+    "wo": ("o_proj.weight", True), "o_norm": ("o_norm.weight", False),
+    "a_log": ("A_log", False), "dt_bias": ("dt_bias", False)}
+
+
+def params_from_kimi_linear(sd: Dict[str, Any], cfg: TransformerConfig
+                            ) -> PyTree:
+    """The family's tensor names: ``input_layernorm`` /
+    ``post_attention_layernorm``; in a ``kda`` layer ``self_attn.{q,k,v}_
+    proj``, ``{q,k,v}_conv1d.weight`` ``[N D, 1, taps]`` (depthwise, the
+    last tap on the row itself), ``f_a_proj`` / ``f_b_proj`` (the decay),
+    ``b_proj``, ``g_a_proj`` / ``g_b_proj`` (the output gate), ``A_log``
+    (any shape of ``num_heads`` values), ``dt_bias``, ``o_norm``,
+    ``o_proj``; in a latent layer DeepSeek's ``q_proj``,
+    ``kv_a_proj_with_mqa``, ``kv_a_layernorm``, ``kv_b_proj``, ``o_proj``;
+    ``mlp.{gate,up,down}_proj`` in a dense layer; ``block_sparse_moe.gate``
+    with ``e_score_correction_bias``, ``experts.<e>.w1 / w3 / w2`` and
+    ``shared_experts.{gate,up,down}_proj`` in an expert layer. The mixers'
+    leaves are stacked by mixer (``TransformerConfig.mixer_layers``)."""
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lyr = pre + "layers.{}."
+    attn = lyr + "self_attn."
+    moe = lyr + "block_sparse_moe."
+
+    def stack_of(layers: range, experts: bool) -> PyTree:
+        kinds = cfg.layer_kinds
+        kda = [i for i in layers if kinds[i] == "kda"]
+        lat = [i for i in layers if kinds[i] == "latent"]
+        blocks = {
+            "ln1": {"scale": _stack(sd, lyr + "input_layernorm.weight",
+                                    layers)},
+            "ln2": {"scale": _stack(
+                sd, lyr + "post_attention_layernorm.weight", layers)}}
+        if kda:
+            blocks["kda"] = {
+                ours: _stack(sd, attn + theirs, kda, transpose=matrix)
+                for ours, (theirs, matrix) in _KDA_TENSORS.items()}
+            blocks["kda"]["a_log"] = blocks["kda"]["a_log"].reshape(
+                len(kda), cfg.kda_heads)
+            for x in "qkv":       # [N D, 1, taps] -> [taps, N D]
+                blocks["kda"][f"conv_{x}"] = np.stack([
+                    _np(sd[(attn + f"{x}_conv1d.weight").format(i)])[:, 0].T
+                    for i in kda])
+        if lat:
+            blocks["attn"] = {
+                ours: _stack(sd, attn + theirs + ".weight", lat,
+                             transpose=True)
+                for ours, theirs in (("wq", "q_proj"),
+                                     ("wkv_a", "kv_a_proj_with_mqa"),
+                                     ("wkv_b", "kv_b_proj"),
+                                     ("wo", "o_proj"))}
+            blocks["attn"]["kv_a_norm"] = _stack(
+                sd, attn + "kv_a_layernorm.weight", lat)
+        names = (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                 ("w_down", "down_proj"))
+        if not experts:
+            for ours, theirs in names:
+                blocks[ours] = _stack(sd, lyr + f"mlp.{theirs}.weight",
+                                      layers, transpose=True)
+            return blocks
+        blocks["gate_w"] = _stack(sd, moe + "gate.weight", layers,
+                                  transpose=True)
+        blocks["gate_bias"] = _stack(
+            sd, moe + "gate.e_score_correction_bias", layers)
+        if cfg.moe_shared_size > 0:
+            for ours, theirs in names:
+                blocks["s" + ours] = _stack(
+                    sd, moe + f"shared_experts.{theirs}.weight", layers,
+                    transpose=True)
+        blocks.update(_qwen_moe_experts(
+            sd, moe, layers, cfg.n_experts, first=cfg.moe_first_expert,
+            names=("w1", "w3", "w2")))
+        return blocks
+
+    d = cfg.first_dense_layers
+    params = {
+        "tok_emb": _np(sd[pre + "embed_tokens.weight"]),
+        "blocks": stack_of(range(d, cfg.num_layers), True),
+        "final_norm": {"scale": _np(sd[pre + "norm.weight"])},
+    }
+    if d:
+        params["dense_blocks"] = stack_of(range(d), False)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _np(sd["lm_head.weight"]).T
+    return params
+
+
 _ARCH_TABLE = {
     "afmoe": (config_from_afmoe, params_from_afmoe),
+    "kimi_linear": (config_from_kimi_linear, params_from_kimi_linear),
     "lfm2_moe": (config_from_lfm2_moe, params_from_lfm2_moe),
     "phi4flash": (config_from_phi4flash, params_from_phi4flash),
     "gpt2": (config_from_gpt2, params_from_gpt2),

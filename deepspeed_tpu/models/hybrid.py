@@ -18,7 +18,11 @@ and the mixer is one of
 
 Beside them :func:`short_conv`, the mixer of the ``conv`` layers that stand
 among standard attention blocks (``TransformerConfig.standard_blocks``; the
-``lfm2`` family): the segmented convolution below without a scan.
+``lfm2`` family): the segmented convolution below without a scan; and
+:func:`kda_inputs` / :func:`delta_rule` / :func:`kda_output`, the mixer of
+the ``kda`` layers (Kimi Delta Attention, the ``kimi_linear`` family): a
+gated delta rule whose state a sequence is one ``[keys, values]`` matrix a
+head in float32 and the last inputs of three short convolutions.
 
 Rows are a flat batch ``[T, ...]`` in which a sequence's rows are
 consecutive and in order (a SplitFuse tick; a dense ``[B, S]`` batch
@@ -73,6 +77,23 @@ def mixer_specs(cfg: Any, kind: str) -> Dict[str, Tuple[tuple, tuple, str]]:
     if kind == "gmu":
         return {"w_in": ((h, di), ("embed", "mlp"), "std"),
                 "wo": ((di, h), ("mlp", "embed"), "out")}
+    if kind == "kda":
+        n, d, r, c = (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank,
+                      cfg.kda_conv)
+        w = n * d
+        specs = {"w_fa": ((h, r), ("embed", None), "std"),
+                 "w_fb": ((r, w), (None, "heads"), "std"),
+                 "dt_bias": ((w,), ("heads",), "dt_bias"),
+                 "a_log": ((n,), (None,), "a_log_heads"),
+                 "w_b": ((h, n), ("embed", None), "std"),
+                 "w_ga": ((h, r), ("embed", None), "std"),
+                 "w_gb": ((r, w), (None, "heads"), "std"),
+                 "o_norm": ((d,), (None,), "ones"),
+                 "wo": ((w, h), ("heads", "embed"), "out")}
+        for x in "qkv":
+            specs[f"w{x}"] = ((h, w), ("embed", "heads"), "std")
+            specs[f"conv_{x}"] = ((c, w), (None, "heads"), "conv")
+        return specs
     if kind not in ATTENTION_KINDS:
         raise ValueError(f"unknown layer kind {kind!r}; one of {KINDS}")
     d = cfg.head_dim
@@ -107,6 +128,8 @@ def init_leaf(how: str, shape: tuple, key: jax.Array, std: float,
         n = shape[-2]
         return jnp.broadcast_to(
             jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None], shape)
+    if how == "a_log_heads":             # A = -U(1, 16), one a head
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
     if how == "dt_bias":                 # softplus^-1 of dt ~ logU[1e-3, 1e-1]
         dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
                      * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
@@ -244,6 +267,124 @@ def short_conv(h: jax.Array, lp: Dict[str, Any], runs: Runs,
     conv0 = jnp.where(runs.fresh[:, None, None], 0, conv0).astype(dt_)
     conv, conv_new = _segmented_conv(g, lp["conv_w"], runs, conv0)
     return bcz[:, H:2 * H] * conv.astype(dt_), conv_new
+
+
+# --------------------------------------------------------------------------- #
+# Kimi Delta Attention: a gated delta rule
+# --------------------------------------------------------------------------- #
+
+#: rows of a tick the one-row form of the rule takes (:func:`delta_rule`)
+KDA_STEP_ROWS = 256
+L2_EPS = 1e-6
+
+
+def kda_state_shapes(cfg: Any) -> Tuple[tuple, tuple]:
+    """What a sequence keeps in a ``kda`` layer: (the rule's matrix
+    ``[heads, keys, values]``, float32; the last inputs of the three
+    convolutions ``[taps - 1, 3 x heads x keys]``, q | k | v)."""
+    n, d = cfg.kda_heads, cfg.kda_head_dim
+    return (n, d, d), (cfg.kda_conv - 1, 3 * n * d)
+
+
+def kda_inputs(h: jax.Array, lp: Dict[str, Any], cfg: Any, runs: Runs,
+               conv0: jax.Array):
+    """A ``kda`` layer's normed rows h [T, H] up to the rule. ``q~, k~,
+    v~ = h W_q, h W_k, h W_v`` each through its own depthwise causal
+    convolution over the row's run (conv0 [T, taps-1, 3 N D]: the inputs
+    before each row's run, q | k | v, zeroed here for a run at position 0)
+    and SiLU; per head ``q = l2norm(q) D^-0.5``, ``k = l2norm(k)``; the
+    log-decay a head and CHANNEL ``g = -exp(A_log) softplus(W_fb (W_fa h) +
+    dt_bias)`` (``a = exp(g)`` in (0, 1)); the step size ``b = sigmoid(h
+    W_b)``, one a head. Returns ((q, k, v, g [T, N, D], b [T, N]), all
+    float32, and the convolutions' inputs up to and including each row
+    [T, taps-1, 3 N D])."""
+    dt_, f32 = h.dtype, jnp.float32
+    Tn, n, d = h.shape[0], cfg.kda_heads, cfg.kda_head_dim
+    qkv = jnp.concatenate([h @ lp[f"w{x}"].astype(dt_) for x in "qkv"],
+                          axis=-1)
+    taps = jnp.concatenate([lp[f"conv_{x}"] for x in "qkv"], axis=-1)
+    conv0 = jnp.where(runs.fresh[:, None, None], 0, conv0).astype(dt_)
+    conv, conv_new = _segmented_conv(qkv, taps, runs, conv0)
+    q, k, v = (x.reshape(Tn, n, d) for x in jnp.split(
+        jax.nn.silu(conv), 3, axis=-1))
+
+    def l2norm(x):
+        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+    f = (h @ lp["w_fa"].astype(dt_)) @ lp["w_fb"].astype(dt_)
+    g = -jnp.exp(lp["a_log"].astype(f32))[None, :, None] * jax.nn.softplus(
+        f.astype(f32) + lp["dt_bias"].astype(f32)).reshape(Tn, n, d)
+    b = jax.nn.sigmoid((h @ lp["w_b"].astype(dt_)).astype(f32))
+    return (l2norm(q) * d ** -0.5, l2norm(k), v, g, b), conv_new
+
+
+def kda_output(o: jax.Array, h: jax.Array, lp: Dict[str, Any], cfg: Any
+               ) -> jax.Array:
+    """From the rule's read-out o [T, N, D] (float32) to the mixer's output
+    before ``wo`` [T, N D]: RMSNorm over each head's D with one gain, times
+    ``sigmoid(W_gb (W_ga h))``."""
+    dt_, f32 = h.dtype, jnp.float32
+    o = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                      + cfg.norm_eps) * lp["o_norm"].astype(f32)
+    gate = (h @ lp["w_ga"].astype(dt_)) @ lp["w_gb"].astype(dt_)
+    return (o.reshape(o.shape[0], -1)
+            * jax.nn.sigmoid(gate.astype(f32))).astype(dt_)
+
+
+def kda_recurrence(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                   b: jax.Array, runs: Runs, s0: jax.Array
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """The rule one row after another, the arbiter of the two forms below:
+    ``S' = diag(exp g) S``; ``S = S' + b k (v - S'^T k)^T``; ``o = S^T q``
+    a head, a run's first row taking ``s0[t]`` [N, D, D] for the state
+    before it. Returns (o [T, N, D], the state after every row
+    [T, N, D, D]): for tests and small sizes only."""
+    def step(s, row):
+        q_, k_, v_, g_, b_, start, s0_ = row
+        s = jnp.exp(g_)[..., None] * jnp.where(start, s0_, s)
+        u = b_[:, None] * (v_ - jnp.einsum("nk,nkv->nv", k_, s))
+        s = s + k_[..., None] * u[:, None, :]
+        return s, (jnp.einsum("nk,nkv->nv", q_, s), s)
+
+    _, (o, s) = lax.scan(step, jnp.zeros_like(s0[0]),
+                         (q, k, v, g, b, runs.start, s0))
+    return o, s
+
+
+def delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+               b: jax.Array, runs: Runs, state: jax.Array, slot: jax.Array,
+               use_kernel: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """The rule over a flat batch of rows in runs. ``state``
+    [rows of state, N, D, D] float32 holds a matrix a sequence; row t's
+    sequence is ``slot[t]`` (0: a pad row, which reads and writes nothing).
+    A run reads its sequence's matrix at its first row (zero where the run
+    starts at position 0, whatever is stored) and writes it after its last;
+    between the two it exists in neither form below.
+
+    Runs of ONE row (decode rows), the first ``KDA_STEP_ROWS`` of them,
+    take the one-row form (``ops.pallas.kda.kda_step``: one read and one
+    write of the matrix); every other run the chunkwise form
+    (``ops.pallas.kda.kda_chunk``). Returns (o [T, N, D] float32, state)."""
+    from deepspeed_tpu.ops.pallas import kda as K
+
+    Tn = q.shape[0]
+    real = slot > 0
+    alone = runs.start & runs.last & real
+    nth = jnp.cumsum(alone) - 1
+    step_row = alone & (nth < KDA_STEP_ROWS)
+    R = min(Tn, KDA_STEP_ROWS)
+    # the rows of the one-row form, gathered (the index past the end for
+    # the places no row takes: they are dropped)
+    rows = jnp.nonzero(step_row, size=R, fill_value=Tn)[0]
+    at = jnp.clip(rows, 0, Tn - 1)
+    took = rows < Tn
+    step = K.kda_step if use_kernel else K.kda_step_reference
+    o_step, state = step(
+        q[at], k[at], v[at], jnp.exp(g[at]), b[at], state,
+        jnp.where(took, slot[at], 0), (runs.fresh[at] & took))
+    o, state = K.kda_chunk(q, k, v, g, b, runs, real & ~step_row, state,
+                           slot)
+    return o.at[rows].set(o_step, mode="drop"), state
 
 
 def gmu(h: jax.Array, lp: Dict[str, Any], memory: jax.Array) -> jax.Array:

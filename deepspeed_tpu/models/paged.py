@@ -98,8 +98,13 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
     layer and rings ``{"wk", "wv"}`` for the ``window`` layers, blocks
     ``[bs, K, D]`` as the homogeneous stack's (heads of 64 two to a row,
     ``[bs, K/2, 2 D]``: :func:`kv_lane_pack`), and state ``{"conv"}`` for
-    its ``conv`` layers: the short convolution's last inputs. A stack of
-    ``layer_kinds``
+    its ``conv`` layers: the short convolution's last inputs. Where the
+    block's attention is latent (``cfg.mla``) the stack has a range of
+    latent blocks ``{"latent"}`` for each ``latent`` layer (the row
+    below) and, for its ``kda`` layers, state ``{"kda"}``: the delta rule's
+    matrix ``[heads, keys, values]`` in float32, and ``{"kda_conv"}``: the
+    last inputs of its three convolutions (``hybrid.kda_state_shapes``).
+    A stack of ``layer_kinds``
     with mixers of its own (``models/hybrid.py``) has the block
     pool for its ONE ``full`` layer (the ``cross`` layers read it), rings
     ``{"wk", "wv"}`` for its ``window`` layers and state for its ``mamba``
@@ -139,11 +144,20 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
             ring = (kinds.count("window"), state_slots + 1,
                     ring_blocks(cfg, block_size, max_run)) + block
             pool.update(wk=jnp.zeros(ring, dt), wv=jnp.zeros(ring, dt))
+        if "latent" in kinds:
+            pool["latent"] = jnp.zeros(
+                (kinds.count("latent"), n_blocks, block_size,
+                 latent_row_width(cfg)), dt)
         if "conv" in kinds:
             # the short convolution's last inputs, a row a sequence slot
             pool["conv"] = jnp.zeros(
                 (kinds.count("conv"), state_slots + 1, cfg.conv_taps - 1,
                  cfg.hidden_size), dt)
+        if "kda" in kinds:
+            rule, conv = HY.kda_state_shapes(cfg)
+            rows = (kinds.count("kda"), state_slots + 1)
+            pool["kda"] = jnp.zeros(rows + rule, jnp.float32)
+            pool["kda_conv"] = jnp.zeros(rows + conv, dt)
         return pool
     if cfg.layer_kinds:
         kinds = cfg.layer_kinds
@@ -244,12 +258,17 @@ def mla_softmax_scale(cfg: T.TransformerConfig) -> float:
 
 def latent_attention_reference(q_row: jax.Array, pool: jax.Array,
                                tables: jax.Array, lengths: jax.Array,
-                               kvr: int, scale: float) -> jax.Array:
+                               kvr: int, scale: float,
+                               row_table: Optional[jax.Array] = None
+                               ) -> jax.Array:
     """``ops/pallas/paged_attention.latent_paged_attention`` in plain jnp
     (the CPU path and the kernel's oracle): latent-space queries
     q_row [T, N, W] against pool rows [NBf, bs, W] -> the attended
     latents [T, N, kvr]. It gathers every row's whole table, so it is for
-    short tables only."""
+    short tables only. ``row_table``: ``tables`` is one a sequence slot and
+    this each row's slot, as the kernel's."""
+    if row_table is not None:
+        tables = tables[row_table]
     Tn = q_row.shape[0]
     bs, MB = pool.shape[1], tables.shape[1]
     rows = pool[tables].reshape(Tn, MB * bs, pool.shape[2])
@@ -314,7 +333,7 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
     row's
     slot, the dense kernel under the names ``swa_attention`` (a
     ring) and ``global_attention`` (a full layer's own block range)."""
-    if cfg.standard_blocks:
+    if cfg.standard_blocks and not cfg.mla:
         if not use_kernel:
             return span_attention_reference, 0
         from deepspeed_tpu.ops.pallas.paged_attention import (
@@ -335,7 +354,7 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
 
         return attend, tile_rows(cfg.num_heads,
                                  kv_lane_pack(cfg) * cfg.head_dim)
-    if cfg.layer_kinds:
+    if cfg.layer_kinds and not cfg.standard_blocks:
         if not use_kernel:
             return paged_attention_reference, 0
         from deepspeed_tpu.ops.pallas.paged_attention import (
@@ -383,6 +402,9 @@ def tick_walks(cfg: T.TransformerConfig, pool: Dict[str, jax.Array]
         return bs * P
 
     kinds = cfg.layer_kinds or ()
+    if cfg.mla:
+        return [(kinds.count("latent") if kinds else cfg.num_layers, None,
+                 step(("latent",)))]
     if cfg.standard_blocks:
         return [(kinds.count(kind), window, step(names))
                 for kind, window, names in (
@@ -393,8 +415,6 @@ def tick_walks(cfg: T.TransformerConfig, pool: Dict[str, jax.Array]
                  step(("wk", "wv"), True)),
                 (kinds.count("full") + kinds.count("cross"), None,
                  step(("k", "v"), True))]
-    if cfg.mla:
-        return [(cfg.num_layers, None, step(("latent",)))]
     return [(cfg.num_layers, None, step(("k", "v")))]
 
 
@@ -422,6 +442,7 @@ def _tick_experts(h: jax.Array, lp: Dict[str, jax.Array],
         gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
         topk_group=cfg.moe_topk_group, valid=valid,
         layer=layer if stack else None,
+        first_expert=cfg.moe_first_expert,
         route_norm_eps=cfg.moe_route_norm_eps)
 
 
@@ -505,18 +526,27 @@ def _dense_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
 
 
 def _latent_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
-                  rows: _Rows, attend: Callable) -> Callable:
+                  rows: _Rows, attend: Callable,
+                  by_slot: Optional[Tuple[jax.Array, jax.Array]] = None
+                  ) -> Callable:
     """The MLA (DeepSeek) pool ``{"latent"}`` [L, NB, bs, W]: a row's
     latent (``c_kv ++ k_pe``, padded to the lanes) is what is written,
     and attention is weight-absorbed (:func:`_absorbed`; same math as the
-    v1 engine's latent-cache decode)."""
+    v1 engine's latent-cache decode). ``by_slot`` (one table a sequence
+    slot, each row's slot), where the stack has slots: the kernel is then
+    given those (:func:`_span_cache` says why) and not a table a row."""
+    tables, which = (rows.tables, {}) if by_slot is None \
+        else (by_slot[0], {"row_table": by_slot[1]})
     lat = pool["latent"]
     Tn = rows.positions.shape[0]
     NB, bs, W = lat.shape[1:]
-    cos_t, sin_t = T.rope_table(NB * bs, cfg.qk_rope_head_dim,
-                                cfg.rope_theta, cfg.rope_scaling_dict)
+    if cfg.pos_emb == "rope":
+        cos_t, sin_t = T.rope_table(NB * bs, cfg.qk_rope_head_dim,
+                                    cfg.rope_theta, cfg.rope_scaling_dict)
 
     def rope_fn(v):                                   # v [T, 1, n, dr]
+        if cfg.pos_emb != "rope":
+            return v           # the model rotates nothing (``mla_use_nope``)
         return T.apply_rope_at(v, cos_t, sin_t, rows.positions[:, None])
 
     row_pad = jnp.zeros(
@@ -533,8 +563,8 @@ def _latent_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
         plat = plat.at[base + rows.block_idx, rows.offsets].set(
             row, mode="drop")
         attn = _absorbed(q, lp["wkv_b"], cfg, lambda q_row: attend(
-            q_row, plat, rows.tables + base, rows.lengths,
-            cfg.kv_lora_rank, mla_softmax_scale(cfg)))
+            q_row, plat, tables + base, rows.lengths,
+            cfg.kv_lora_rank, mla_softmax_scale(cfg), **which))
         return (attn.reshape(Tn, cfg.num_heads * cfg.v_head_dim),
                 {"latent": plat})
 
@@ -542,19 +572,28 @@ def _latent_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
 
 
 def pool_block(pool: Dict[str, jax.Array]) -> int:
-    """Positions of a block ``[bs, K, D]`` of a standard-block pool."""
+    """Positions of a block of a standard-block pool: ``[bs, K, D]``, or a
+    latent block ``[bs, W]``."""
+    if "latent" in pool:
+        return pool["latent"].shape[-2]
     return pool["k" if "k" in pool else "wk"].shape[-3]
 
 
 def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
                 rows: _Rows, attend: Callable) -> Callable:
-    """The caches of ``window``, ``full`` and ``conv`` layers of the
-    standard block (``cfg.standard_blocks``; ``init_paged_kv``): a layer's
+    """The caches of ``window``, ``full``, ``latent``, ``conv`` and ``kda``
+    layers of the standard block (``cfg.standard_blocks``;
+    ``init_paged_kv``: block ranges ``k, v`` or ``latent``, rings ``wk,
+    wv``, state ``conv`` or ``kda, kda_conv``): a layer's
     mixer as ``layer(kind, h, lp, flat, nth) -> (mixed [T, .] before
     ``wo``, flat)``, ``nth`` the layer's index among the layers of its KIND
     (which ring, which block range, which state rows). A ``conv`` layer
     reads its rows' runs' state from its sequence slots' rows and writes
-    the state after each run's last row (``hybrid.short_conv``).
+    the state after each run's last row (``hybrid.short_conv``); a ``kda``
+    layer likewise, its convolutions' inputs so and the rule's matrix
+    inside ``hybrid.delta_rule`` (a run's is read at its first row and
+    written after its last, in place); a ``latent`` layer is
+    :func:`_latent_cache`'s over its own range of latent blocks.
     Projections as :func:`_dense_cache`'s (``qk_norm``; rotary at
     the rows' positions, on ``full`` layers only where the config says
     so), then the elementwise output gate where the model has one.
@@ -577,8 +616,9 @@ def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
     # of its own, has no room in scalar memory); a stack without window
     # layers has no slots: a table a row
     slot, by_slot = jnp.arange(Tn, dtype=jnp.int32), rows.tables
-    if "wk" in pool or "conv" in pool:
-        S1 = pool["wk" if "wk" in pool else "conv"].shape[1]
+    per_slot = [n for n in ("wk", "conv", "kda") if n in pool]
+    if per_slot:
+        S1 = pool[per_slot[0]].shape[1]
         slot = rows.tables[:, 0]
         by_slot = jnp.zeros((S1, MB), jnp.int32).at[slot].set(rows.tables)
     if "wk" in pool:
@@ -588,10 +628,29 @@ def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
         ring_block = slot * RB + (rows.positions // bs) % RB
     NB = pool["k"].shape[1] if "k" in pool else 0
     pack = kv_lane_pack(cfg)
-    if "conv" in pool:
+    if "conv" in pool or "kda" in pool:
         runs = HY.runs_of(slot, rows.positions)
+    if "latent" in pool:
+        latent = _latent_cache(cfg, pool, rows, attend,
+                               (by_slot, slot) if per_slot else None)
 
     def layer(kind, h, lp, flat, nth):
+        if kind == "latent":
+            attn, new = latent(h, lp, flat, nth * pool["latent"].shape[1])
+            return attn, {**flat, **new}
+        if kind == "kda":
+            at = nth * S1 + slot
+            inputs, conv = HY.kda_inputs(h, lp, cfg, runs,
+                                         flat["kda_conv"][at])
+            put = jnp.where(runs.last, at, flat["kda_conv"].shape[0])
+            # a pad row's sequence is none: row 0 of the store
+            o, state = HY.delta_rule(
+                *inputs, runs, flat["kda"], jnp.where(slot > 0, at, 0),
+                use_kernel=attend not in _REFERENCES)
+            return HY.kda_output(o, h, lp, cfg), {
+                **flat, "kda": state,
+                "kda_conv": flat["kda_conv"].at[put].set(
+                    conv.astype(flat["kda_conv"].dtype), mode="drop")}
         if kind == "conv":
             at = nth * S1 + slot
             mixed, conv = HY.short_conv(h, lp, runs, flat["conv"][at])
@@ -715,7 +774,7 @@ def _kinds_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
 
 
 #: the scope a kind's mixer runs under (``attn`` also holds ``ln1``, ``wo``)
-_KIND_SCOPES = {"mamba": "ssm", "gmu": "gmu", "conv": "conv"}
+_KIND_SCOPES = {"mamba": "ssm", "gmu": "gmu", "conv": "conv", "kda": "kda"}
 
 
 def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,

@@ -220,6 +220,19 @@ class TransformerConfig:
     # (``models/hybrid.short_conv``) of this many taps, whose state a
     # sequence is its last ``conv_taps - 1`` inputs, whatever its length
     conv_taps: int = 0
+    # ``kda`` layers among ``layer_kinds`` of the standard block (the
+    # ``kimi_linear`` family): the mixer is Kimi Delta Attention
+    # (``models/hybrid.kda_inputs`` .. ``kda_output``), a gated delta rule
+    # over ``kda_heads`` heads of ``kda_head_dim`` keys and values behind
+    # three short convolutions of ``kda_conv`` taps, its decay and output
+    # gates through a width of ``kda_rank``; a sequence's state is a
+    # ``[keys, values]`` matrix a head in float32, whatever its length.
+    # Beside them ``latent`` layers: the latent attention ``mla`` says,
+    # without rotary where ``pos_emb`` is not ``rope``
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_rank: int = 0
+    kda_conv: int = 4
 
     @property
     def kv_heads(self) -> int:
@@ -293,27 +306,31 @@ class TransformerConfig:
         tree a layer, stacked as a homogeneous model's): a kind then says
         what a layer sees and where its cache lives (``window``: its last
         ``attn_window`` positions, a per-sequence ring; ``full``: every
-        position, a block range of its own), and which attention it
-        computes is the config's. A ``conv`` layer has the block's norms
-        and its FFN or experts around a mixer of its own with a state a
-        sequence slot (:func:`mixer_of`: in a segment with such layers the
-        mixers' leaves are stacked by mixer, ``blocks["attn"]`` over its
-        attention layers and ``blocks["conv"]`` over its ``conv`` layers).
+        position, a block range of its own; ``latent``: every position, a
+        range of latent blocks of its own, where ``mla``), and which
+        attention it computes is the config's. A ``conv`` or ``kda`` layer
+        has the block's norms and its FFN or experts around a mixer of its
+        own with a state a sequence slot (:func:`mixer_of`: in a segment
+        with such layers the mixers' leaves are stacked by mixer,
+        ``blocks["attn"]`` over its attention layers, ``blocks["conv"]``
+        over its ``conv`` and ``blocks["kda"]`` over its ``kda`` layers).
         False with kinds: every kind a whole layer of its own
         (``models/hybrid.py``, ``params[key][kind]``)."""
         return bool(self.layer_kinds) and not self.ssm_inner \
-            and set(self.layer_kinds) <= {"window", "full", "conv"}
+            and set(self.layer_kinds) <= {"window", "full", "conv", "kda",
+                                          "latent"}
 
     @property
     def mixer_layers(self) -> Dict[str, int]:
-        """Layers of each mixer in a stack that has ``conv`` layers (the
-        leading dims of ``blocks["attn"]`` / ``blocks["conv"]``); empty
-        where every layer attends: one parameter tree a layer."""
-        if "conv" not in self.layer_kinds:
+        """Layers of each mixer in a stack that has ``conv`` or ``kda``
+        layers (the leading dims of ``blocks["attn"]`` / ``blocks["conv"]``
+        / ``blocks["kda"]``); empty where every layer attends: one
+        parameter tree a layer."""
+        own = {m: self.layer_kinds.count(m) for m in MIXERS[1:]}
+        if not any(own.values()):
             return {}
-        n = self.layer_kinds.count("conv")
-        return {m: c for m, c in (("attn", self.num_layers - n), ("conv", n))
-                if c}
+        own["attn"] = self.num_layers - sum(own.values())
+        return {m: own[m] for m in MIXERS if own[m]}
 
     @property
     def segments(self) -> Tuple[Tuple[str, "TransformerConfig"], ...]:
@@ -411,6 +428,9 @@ class TransformerConfig:
             per_layer = h * qdim + 2 * h * kv + qdim * h  # q, k, v, o
             if self.attn_gate:
                 per_layer += h * qdim
+        if self.qk_norm:
+            per_layer += 2 * self.head_dim
+        attn = per_layer
         ffn_mats = 3 if self.activation == "swiglu" else 2
         if self.n_experts > 0:
             per_layer += self.n_experts * ffn_mats * h * self.moe_ffn \
@@ -425,17 +445,15 @@ class TransformerConfig:
         per_layer += (2 * h if self.has_ln2 else h)  # norms
         if self.post_norms:
             per_layer += 2 * h
-        if self.qk_norm:
-            per_layer += 2 * self.head_dim
         total = l * per_layer + v * h + 2 * h
-        n_conv = self.layer_kinds.count("conv")
-        if n_conv:
-            # a ``conv`` layer has its mixer (in, taps, out) where the
-            # others have their attention
-            attn = h * qdim + 2 * h * kv + qdim * h \
-                + (h * qdim if self.attn_gate else 0) \
-                + (2 * self.head_dim if self.qk_norm else 0)
-            total += n_conv * (4 * h * h + self.conv_taps * h - attn)
+        # a ``conv`` or ``kda`` layer has its mixer where the others have
+        # their attention
+        from deepspeed_tpu.models.hybrid import mixer_specs
+
+        total += sum(
+            n * (sum(math.prod(shape) for shape, _, _ in
+                     mixer_specs(self, kind).values()) - attn)
+            for kind, n in self.mixer_layers.items() if kind != "attn")
         if self.emb_norm:
             total += 2 * h
         if not self.tie_embeddings:
@@ -451,26 +469,46 @@ class TransformerConfig:
 
 #: the standard block's attention leaves: in a stack with ``conv`` layers
 #: they are ``blocks["attn"]``, stacked over the attention layers alone
-_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm", "wq_a",
+                "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wkv_b")
 #: the sub-trees of a segment's blocks that are stacked by mixer
-MIXERS = ("attn", "conv")
+MIXERS = ("attn", "conv", "kda")
 
 
 def mixer_of(kind: str) -> str:
     """The mixer a layer of ``kind`` computes: whose leaves it reads in a
     stack whose mixers' leaves are stacked apart."""
-    return "conv" if kind == "conv" else "attn"
+    return kind if kind in MIXERS[1:] else "attn"
 
 
-def _plain_blocks_beside_conv(cfg: TransformerConfig) -> None:
-    if "conv" in cfg.layer_kinds and (
-            cfg.mla or cfg.attn_bias_enabled or cfg.use_bias
-            or cfg.attn_gate or cfg.post_norms or cfg.parallel_block
-            or cfg.conv_taps < 2):
+def _check_kinds_of_blocks(cfg: TransformerConfig) -> None:
+    """What a stack of ``layer_kinds`` over the standard block cannot be
+    yet, said precisely."""
+    kinds = set(cfg.layer_kinds)
+    if ("latent" in kinds and not cfg.mla) or (cfg.mla and kinds
+                                               & {"window", "full"}):
         raise NotImplementedError(
-            "conv layers (conv_taps >= 2) stand in a stack of plain "
-            "sequential grouped-query blocks: no biases, latent attention, "
-            "output gate, post-norms or parallel residual")
+            "latent attention (mla) among layer_kinds is the kind `latent`, "
+            "beside `kda` or `conv` layers: latent and grouped-query "
+            "attention layers in one stack, and a latent layer under a "
+            f"window, are not written (mla={cfg.mla}, kinds {sorted(kinds)})")
+    own = kinds & set(MIXERS[1:])
+    if own and (cfg.attn_bias_enabled or cfg.use_bias or cfg.attn_gate
+                or cfg.post_norms or cfg.parallel_block):
+        raise NotImplementedError(
+            f"{' and '.join(sorted(own))} layers stand in a stack of plain "
+            "sequential blocks: biases, an output gate on attention, "
+            "post-norms and the parallel residual are not written beside "
+            "them")
+    if "conv" in kinds and cfg.conv_taps < 2:
+        raise NotImplementedError(
+            f"conv layers need conv_taps >= 2 (got {cfg.conv_taps})")
+    if "kda" in kinds and not (cfg.kda_heads and cfg.kda_head_dim
+                               and cfg.kda_rank and cfg.kda_conv >= 2):
+        raise ValueError(
+            "kda layers need kda_heads, kda_head_dim, kda_rank and "
+            f"kda_conv >= 2 (got {cfg.kda_heads}, {cfg.kda_head_dim}, "
+            f"{cfg.kda_rank}, {cfg.kda_conv})")
 
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
@@ -506,7 +544,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     # in a stack with ``conv`` layers the mixers' leaves are stacked by
     # mixer (``cfg.mixer_layers``): attention's over the attention layers
     mixers = cfg.mixer_layers
-    _plain_blocks_beside_conv(cfg)
+    if cfg.layer_kinds:
+        _check_kinds_of_blocks(cfg)
     La = mixers.get("attn", 0) if mixers else L
     if cfg.mla:
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -514,15 +553,15 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
         kvr, N = cfg.kv_lora_rank, cfg.num_heads
         qout = N * (dn + dr)
         if cfg.q_lora_rank:
-            block["wq_a"] = dense(keys[0], (L, h, cfg.q_lora_rank), std)
-            block["q_a_norm"] = jnp.ones((L, cfg.q_lora_rank), jnp.float32)
-            block["wq_b"] = dense(keys[15], (L, cfg.q_lora_rank, qout), std)
+            block["wq_a"] = dense(keys[0], (La, h, cfg.q_lora_rank), std)
+            block["q_a_norm"] = jnp.ones((La, cfg.q_lora_rank), jnp.float32)
+            block["wq_b"] = dense(keys[15], (La, cfg.q_lora_rank, qout), std)
         else:
-            block["wq"] = dense(keys[0], (L, h, qout), std)
-        block["wkv_a"] = dense(keys[1], (L, h, kvr + dr), std)
-        block["kv_a_norm"] = jnp.ones((L, kvr), jnp.float32)
-        block["wkv_b"] = dense(keys[2], (L, kvr, N * (dn + dv)), std)
-        block["wo"] = dense(keys[3], (L, N * dv, h), out_std)
+            block["wq"] = dense(keys[0], (La, h, qout), std)
+        block["wkv_a"] = dense(keys[1], (La, h, kvr + dr), std)
+        block["kv_a_norm"] = jnp.ones((La, kvr), jnp.float32)
+        block["wkv_b"] = dense(keys[2], (La, kvr, N * (dn + dv)), std)
+        block["wo"] = dense(keys[3], (La, N * dv, h), out_std)
     else:
         block.update({
             "wq": dense(keys[0], (La, h, qdim), std),
@@ -583,11 +622,13 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
         attn = {k: block.pop(k) for k in _ATTN_LEAVES if k in block}
         if La:
             block["attn"] = attn
-        block["conv"] = {
-            name: init_leaf(how, (mixers["conv"],) + shape,
-                            jax.random.fold_in(rng, 17 + i), std, out_std)
-            for i, (name, (shape, _, how)) in enumerate(
-                sorted(mixer_specs(cfg, "conv").items()))}
+        for m, kind in enumerate(k for k in MIXERS[1:] if k in mixers):
+            block[kind] = {
+                name: init_leaf(how, (mixers[kind],) + shape,
+                                jax.random.fold_in(rng, 17 + 32 * m + i),
+                                std, out_std)
+                for i, (name, (shape, _, how)) in enumerate(
+                    sorted(mixer_specs(cfg, kind).items()))}
 
     params = {
         "tok_emb": dense(keys[7], (cfg.vocab_size, h), std),
@@ -689,8 +730,9 @@ def param_logical_axes(cfg: TransformerConfig) -> PyTree:
         attn = {k: block.pop(k) for k in _ATTN_LEAVES if k in block}
         if "attn" in cfg.mixer_layers:
             block["attn"] = attn
-        block["conv"] = {name: lyr + axes for name, (_, axes, _) in
-                         mixer_specs(cfg, "conv").items()}
+        for kind in (k for k in MIXERS[1:] if k in cfg.mixer_layers):
+            block[kind] = {name: lyr + axes for name, (_, axes, _) in
+                           mixer_specs(cfg, kind).items()}
     axes = {
         "tok_emb": ("vocab", "embed"),
         "blocks": block,
@@ -1092,8 +1134,11 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
     positions, ``full`` every one, both under an explicit mask in plain jnp
     (the flash kernel has no window), whatever ``attention_fn`` says;
     ``conv`` has a gated short convolution where the others attend
-    (``hybrid.short_conv``; ``lp`` then holds that mixer's leaves), and
-    the norms, the residual form and the FFN or experts are the block's.
+    (``hybrid.short_conv``; ``lp`` then holds that mixer's leaves), ``kda``
+    Kimi Delta Attention (``hybrid.kda_inputs`` .. ``kda_output``, every
+    sequence of the batch a run from a zero state), ``latent`` the latent
+    attention of ``cfg.mla``, and the norms, the residual form and the FFN
+    or experts are the block's.
 
     Sequential (GPT/Llama) or parallel (Falcon/NeoX/Phi: attn and FFN both
     branch off the residual stream and are summed back).
@@ -1133,12 +1178,15 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
 
     # the scopes a device trace sorts a block's operations by
     # (``attn`` / ``mlp``, under the engine's ``loss_and_grads``)
-    with jax.named_scope("conv" if kind == "conv" else "attn"):
+    with jax.named_scope(kind if kind in MIXERS[1:] else "attn"):
         h = _aq(_norm(x, lp["ln1"], cfg.norm, cfg.norm_eps))
-    if cfg.mla:
+    if cfg.mla and kind in (None, "latent"):
         with jax.named_scope("attn"):
-            q, k, v = _mla_qkv(h, lp, cfg,
-                               lambda t: apply_rope(t, cos, sin))
+            # no rotary where the model has none (``pos_emb`` "none": the
+            # 64 "rope" values of a query and of the shared key are kept
+            # and unrotated)
+            q, k, v = _mla_qkv(h, lp, cfg, (lambda t: t) if cos is None
+                               else lambda t: apply_rope(t, cos, sin))
             if cfg.mla_scale_mult != 1.0:
                 q = q * jnp.asarray(cfg.mla_scale_mult, q.dtype)
             # flash kernels assume one head dim; MLA's split qk/v dims run
@@ -1218,8 +1266,27 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
             jnp.zeros((B * S, cfg.conv_taps - 1, H), dt))
         return (mixed @ lp["wo"].astype(dt)).reshape(B, S, H)
 
+    @jax.named_scope("kda")
+    def _kda_from_norm(h):
+        from deepspeed_tpu.models import hybrid as HY
+
+        owner = jnp.repeat(jnp.arange(B, dtype=jnp.int32), S)
+        runs = HY.runs_of(owner, jnp.tile(jnp.arange(S, dtype=jnp.int32), B))
+        hr = h.reshape(B * S, H)
+        (n, d, _), conv = HY.kda_state_shapes(cfg)
+        inputs, _ = HY.kda_inputs(hr, lp, cfg, runs,
+                                  jnp.zeros((B * S,) + conv, dt))
+        # a row of state a sequence (row 0 is the pad rows')
+        o, _ = HY.delta_rule(*inputs, runs,
+                             jnp.zeros((B + 1, n, d, d), jnp.float32),
+                             owner + 1)
+        return (HY.kda_output(o, hr, lp, cfg)
+                @ lp["wo"].astype(dt)).reshape(B, S, H)
+
     if kind == "conv":
         attn_out = _conv_from_norm(h)
+    elif kind == "kda":
+        attn_out = _kda_from_norm(h)
     elif cfg.remat == "attn_block":
         # structural remat: bwd recomputes ONLY norm1 → attention → wo
         # (~37% of layer FLOPs at 4h² vs FFN's 8h²); every FFN intermediate
@@ -1318,8 +1385,9 @@ def _require_one_stack(cfg: TransformerConfig, what: str) -> None:
         raise NotImplementedError(
             f"{what} runs one homogeneous layer stack; a stack of layer "
             "kinds (state-space, windowed and shared-cache layers; window "
-            "and full attention layers in one stack) is "
-            "served by FastGenEngine and run whole by forward()")
+            "and full attention layers in one stack; short-convolution or "
+            "delta-rule layers beside attention or latent-attention "
+            "layers) is served by FastGenEngine and run whole by forward()")
     if cfg.first_dense_layers:
         raise NotImplementedError(
             f"{what} runs one homogeneous layer stack; a model with leading "

@@ -574,7 +574,9 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
 def latent_paged_attention(q: jax.Array, pool: jax.Array,
                            tables: jax.Array, lengths: jax.Array,
                            value_dim: int, scale: float,
-                           interpret: Optional[bool] = None) -> jax.Array:
+                           interpret: Optional[bool] = None,
+                           row_table: Optional[jax.Array] = None
+                           ) -> jax.Array:
     """Weight-absorbed latent (MLA) attention over the paged latent pool:
     multi-query attention with ONE KV head whose key is a cache position's
     whole row (``c_kv ++ k_pe``, zero-padded to a lane multiple) and whose
@@ -587,9 +589,10 @@ def latent_paged_attention(q: jax.Array, pool: jax.Array,
     the attended latents [T, N, value_dim] (``W_uv`` is the caller's). The
     products take bf16 operands (a prompt chunk against a long context is
     MXU-bound, unlike the dense cells' shapes) and accumulate in float32;
-    the jnp path rounds its probabilities the same way."""
+    the jnp path rounds its probabilities the same way. ``row_table``: as
+    :func:`paged_attention`'s (one table a sequence and each row's)."""
     assert q.shape[2] == pool.shape[2] and pool.ndim == 3
     return _walk(q, (pool,), tables, lengths, value_dim=value_dim,
                  scale=float(scale),
                  name="latent_paged_attention", mxu_dtype=jnp.bfloat16,
-                 interpret=interpret)
+                 interpret=interpret, row_table=row_table)

@@ -207,6 +207,69 @@ def test_lane_packed_attention_compiles_for_v5e(one_chip, shape):
             lowered((40960, 32, 8, 64), 64).compile()
 
 
+# (rows of the tick bucket) of the ``kimi_linear`` serving cell: 32 latent
+# heads on rows of 512 + 64 values stored 640 wide, two latent layers x
+# 40,960 blocks of 32, ONE table a sequence slot (272 + 1 of 128 blocks: a
+# table a row of a 2,048-row tick is a megabyte of scalar memory, which the
+# whole tick compiled here refused, PERF.md, PR 41); and the delta rule's
+# one-row form over six layers' 273 matrices of 32 heads of 128 x 128 in
+# float32, updated in place
+KIMI_SHAPES = {"kimi-linear-2048x128": 2048, "kimi-linear-256x128": 256}
+
+
+@pytest.mark.parametrize("shape", sorted(KIMI_SHAPES))
+def test_latent_attention_by_slot_compiles_for_v5e(one_chip, shape):
+    from deepspeed_tpu.ops.pallas.paged_attention import \
+        latent_paged_attention
+
+    T = KIMI_SHAPES[shape]
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def lowered(tables, by_slot):
+        return jax.jit(
+            lambda q, pool, t, n, w: latent_paged_attention(
+                q, pool, t, n, 512, 192 ** -0.5, interpret=False,
+                row_table=w if by_slot else None)
+        ).lower(arg((T, 32, 640), jnp.bfloat16),
+                arg((2 * 40960, 32, 640), jnp.bfloat16),
+                arg(tables, jnp.int32), arg((T,), jnp.int32),
+                arg((T,), jnp.int32))
+
+    text = lowered((273, 128), True).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%latent_paged_attention" in text
+    if T == 2048:
+        with pytest.raises(Exception, match="smem"):
+            lowered((T, 128), False).compile()
+
+
+def test_kda_step_compiles_for_v5e(one_chip):
+    from deepspeed_tpu.ops.pallas.kda import kda_step
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    row = arg((256, 32, 128), jnp.float32)
+    compiled = jax.jit(
+        lambda q, k, v, a, b, state, slots, fresh: kda_step(
+            q, k, v, a, b, state, slots, fresh, interpret=False),
+        donate_argnums=(5,)
+    ).lower(row, row, row, row, arg((256, 32), jnp.float32),
+            arg((6 * 273, 32, 128, 128), jnp.float32),
+            arg((256,), jnp.int32), arg((256,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    # one Mosaic call under its own name (``benchmarks/roofline/kda_step.py``
+    # classifies by it), the store of states aliased in and out: no copy of
+    # its 3.4 GB, nothing beside the rows' vectors held
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%kda_step" in text
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= 6 * 273 * 32 * 128 * 128 * 4
+    assert stats.temp_size_in_bytes < 64 << 20
+
+
 # (sequences a chip, their length, query heads, KV heads, head size, dtype,
 # blocks a caller names): the two training cells at the blocks
 # ``choose_blocks`` gives them, and what only Mosaic refuses: a length under a

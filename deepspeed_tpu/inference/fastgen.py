@@ -550,6 +550,10 @@ class FastGenEngine:
             "rows of ticks through the delta-rule layers, by the form of "
             "the rule that took them: step (runs of one row: one read and "
             "one write of the sequence's state) / chunk (chunkwise)")
+        self._tm_kda_pieces = telemetry.counter(
+            "fastgen_kda_chunk_pieces_total",
+            "grid steps of the delta rule's chunk form that did work: the "
+            "chunks of 64 rows of a tick that a run it takes has a row in")
         self._period_keys: Dict[tuple, tuple] = {}   # (kind, Tn) -> keys
         # the last step() tick's end (None before the first and after a
         # fused window, whose ticks are not accounted), and whether the
@@ -1376,8 +1380,22 @@ class FastGenEngine:
                 # slot's state in every kda layer
                 kda_step = min(n_decode_rows + runs_of_one, Tn,
                                HY.KDA_STEP_ROWS)
+                # the chunk form's grid steps, by the kernel's own rule:
+                # every run but the first ``kda_step`` runs of one row
+                # (decode rows lie first, a row each)
+                from deepspeed_tpu.ops.pallas.kda import count_pieces
+
+                took = min(n_decode_rows, kda_step)
+                left = kda_step - took      # for the prompts' runs of one
+                chunk_runs = [(r, 1) for r in range(took, n_decode_rows)]
+                for a, b in zip(chunk_starts, chunk_starts[1:] + [row]):
+                    if b - a == 1 and left:
+                        left -= 1
+                    else:
+                        chunk_runs.append((a, b - a))
                 slot_attrs.update(
                     kda_step_rows=kda_step, kda_chunk_rows=row - kda_step,
+                    kda_chunk_pieces=count_pieces(chunk_runs),
                     kda_state_rows=n_decode_rows + len(chunk_starts))
         with telemetry.span("decode_tick", attrs={
                 **slot_attrs,
@@ -1485,6 +1503,7 @@ class FastGenEngine:
                                       form="step")
                 self._tm_kda_rows.inc(slot_attrs["kda_chunk_rows"],
                                       form="chunk")
+                self._tm_kda_pieces.inc(slot_attrs["kda_chunk_pieces"])
             if attn_steps:
                 self._tm_attn_steps.inc(attn_open, form="open")
                 self._tm_attn_steps.inc(attn_steps - attn_open,

@@ -364,7 +364,9 @@ def delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     Runs of ONE row (decode rows), the first ``KDA_STEP_ROWS`` of them,
     take the one-row form (``ops.pallas.kda.kda_step``: one read and one
     write of the matrix); every other run the chunkwise form
-    (``ops.pallas.kda.kda_chunk``). Returns (o [T, N, D] float32, state)."""
+    (``ops.pallas.kda.kda_chunk``); both as Mosaic kernels where
+    ``use_kernel``, else their references in plain XLA. Returns (o [T, N,
+    D] float32, state)."""
     from deepspeed_tpu.ops.pallas import kda as K
 
     Tn = q.shape[0]
@@ -382,8 +384,8 @@ def delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     o_step, state = step(
         q[at], k[at], v[at], jnp.exp(g[at]), b[at], state,
         jnp.where(took, slot[at], 0), (runs.fresh[at] & took))
-    o, state = K.kda_chunk(q, k, v, g, b, runs, real & ~step_row, state,
-                           slot)
+    chunk = K.kda_chunk if use_kernel else K.kda_chunk_reference
+    o, state = chunk(q, k, v, g, b, runs, real & ~step_row, state, slot)
     return o.at[rows].set(o_step, mode="drop"), state
 
 

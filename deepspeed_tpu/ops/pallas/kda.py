@@ -9,9 +9,13 @@ heads of 128) that is updated in place:
   by the row's query. One read and one write of the matrix a row a layer,
   which is all a decode tick's linear-attention layers cost.
 * :func:`kda_chunk`: every other run, chunkwise (the paper's form: a
-  triangular solve a chunk and matrix products), in plain XLA under the
-  named scope ``kda_chunk``: a run's state is carried from chunk to chunk
-  and never exists a row at a time.
+  triangular solve a chunk and matrix products), a Mosaic kernel under the
+  named scope ``kda_chunk`` whose grid is the PIECES of chunks that hold a
+  row of a run, and no more: a run's matrix is read at its first piece,
+  carried in VMEM from piece to piece and written after its last, and never
+  exists a row at a time. :func:`kda_chunk_reference` is the same in plain
+  XLA (all of the bucket's chunks at once, then a loop over the pieces):
+  the CPU path and the kernel's oracle.
 
 Both compute, a head, ``S' = diag(a) S; S = S' + b k (v - S'^T k)^T; o =
 S^T q`` (``hybrid.kda_recurrence`` is the arbiter).
@@ -140,10 +144,12 @@ def _exp_le0(x):
 
 
 @jax.named_scope("kda_chunk")
-def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-              b: jax.Array, runs, rows: jax.Array, state: jax.Array,
-              slot: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """The rule over the runs of ``rows`` [T] bool (whole runs), chunkwise.
+def kda_chunk_reference(q: jax.Array, k: jax.Array, v: jax.Array,
+                        g: jax.Array, b: jax.Array, runs, rows: jax.Array,
+                        state: jax.Array, slot: jax.Array
+                        ) -> Tuple[jax.Array, jax.Array]:
+    """The rule over the runs of ``rows`` [T] bool (whole runs), chunkwise,
+    in plain XLA (the CPU path and the oracle of :func:`kda_chunk`).
     q, k, v, g [T, N, D] float32 (``g`` the LOG decay, <= 0); b [T, N];
     ``runs`` (``hybrid.Runs``); state [rows of state, N, D, D] float32;
     slot [T]: each row's row of ``state``. Returns (o [T, N, D], zero
@@ -285,3 +291,262 @@ def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         (jnp.int32(0), jnp.zeros((N, D, D), f32),
          jnp.zeros((nC, N, C, D), f32), state))
     return jnp.moveaxis(o, 1, 2).reshape(T, N, D)[:T0], state
+
+
+def _pieces(rows, start, last, fresh, slot, C):
+    """The pieces of the runs of ``rows`` [T] (T a multiple of ``C``; the
+    others [T] as ``hybrid.Runs`` has them), in row order, as the kernel's
+    scalars: how many there are [1], then a piece each [T] (most are
+    unused): its chunk, its first and last row in the chunk, its
+    sequence's row of state, and bits 1 | 2 | 4: it opens its run, that run
+    starts from zero, it closes its run."""
+    T = rows.shape[0]
+    t = jnp.arange(T, dtype=jnp.int32)
+    # a row opens a piece where its run starts or a chunk does
+    opens = rows & (start | (t % C == 0))
+    piece = jnp.where(rows, jnp.cumsum(opens), -1)
+    closes = rows & jnp.concatenate([piece[1:] != piece[:-1],
+                                     jnp.ones((1,), bool)])
+    end = lax.cummin(jnp.where(closes, t, T - 1), reverse=True)  # its last
+    t0 = jnp.nonzero(opens, size=T, fill_value=0)[0].astype(jnp.int32)
+    flag = sum(x.astype(jnp.int32) * bit for x, bit in (
+        (start[t0], 1), (fresh[t0], 2), (last[end[t0]], 4)))
+    return (jnp.sum(opens, dtype=jnp.int32)[None], t0 // C, t0 % C,
+            end[t0] % C, slot[t0].astype(jnp.int32), flag)
+
+
+def count_pieces(runs) -> int:
+    """The grid steps :func:`kda_chunk` runs for ``runs``, (first row, rows)
+    of each run it is given, by the kernel's own rule: a piece a chunk of
+    ``CHUNK`` rows of the tick that a run has a row in."""
+    return sum((first + n - 1) // CHUNK - first // CHUNK + 1
+               for first, n in runs)
+
+
+def _dot(x, y, dims=((1,), (0,))):
+    """A product of float32 operands at float32's precision."""
+    return lax.dot_general(x, y, (dims, ((), ())),
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _chunk_kernel(n_ref, chunk_ref, lo_ref, hi_ref, slot_ref, flag_ref,
+                  q_ref, k_ref, v_ref, g_ref, b_ref, s_hbm, o_ref, s_out,
+                  s_buf, sem):
+    """One piece a grid step, every head of it: see :func:`kda_chunk`. The
+    rows of the piece's chunk lie head-minor ([C N, D], row r of head h at
+    ``r N + h``); ``s_buf`` [N, D, D] carries the run's matrices."""
+    del n_ref, chunk_ref
+    f32 = jnp.float32
+    p = pl.program_id(0)
+    N, D, _ = s_buf.shape
+    C, nS = CHUNK, CHUNK // SUB
+    lo, hi, flag = lo_ref[p], hi_ref[p], flag_ref[p]
+    opens, fresh, closes = (flag & 1) != 0, (flag & 2) != 0, (flag & 4) != 0
+
+    @pl.when(opens & ~fresh)
+    def _():
+        copy = pltpu.make_async_copy(s_hbm.at[slot_ref[p]], s_buf, sem.at[0])
+        copy.start()
+        copy.wait()
+
+    @pl.when(opens & fresh)
+    def _():
+        s_buf[...] = jnp.zeros_like(s_buf)
+
+    r = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    mine = (r >= lo) & (r <= hi)                           # [C, 1]
+    ri = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    ci = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    low = (ci >= lo) & (ci <= ri) & (ri <= hi)             # i <= r, both mine
+    strict = low & (ci < ri)
+    eye = jnp.where(ri == ci, 1.0, 0.0).astype(f32)
+    ones_low = jnp.where(ri >= ci, 1.0, 0.0).astype(f32)
+    in_sub = ri // SUB == ci // SUB
+    in_half = ri // (2 * SUB) == ci // (2 * SUB)
+    col = lax.broadcasted_iota(jnp.int32, (SUB // 2, C), 1)
+    head_lane = lax.broadcasted_iota(jnp.int32, (C, N), 1)
+    diag = (lax.broadcasted_iota(jnp.int32, (D, D), 0)
+            == lax.broadcasted_iota(jnp.int32, (D, D), 1))
+
+    def head(h, carry):
+        at = pl.ds(h, C, stride=N)
+        q, k, v, g = (jnp.where(mine, x[at, :], 0.0)
+                      for x in (q_ref, k_ref, v_ref, g_ref))
+        b = jnp.where(mine, jnp.sum(
+            jnp.where(head_lane == h, b_ref[...], 0.0), axis=1,
+            keepdims=True), 0.0)                           # [C, 1]
+        # running log-decay within the piece, a row's own included (g is
+        # masked: the rows past the piece keep its whole)
+        gam = _dot(ones_low, g)                            # [C, D]
+        gam_end = gam[C - 1:C]                             # [1, D]
+        # the log-decay just before each sub-block's first row
+        refs = [gam[s * SUB:s * SUB + 1] - g[s * SUB:s * SUB + 1]
+                for s in range(nS)]
+        to_ref = _exp_le0(gam - jnp.concatenate(
+            [jnp.broadcast_to(x, (SUB, D)) for x in refs], axis=0))
+        kq_ref = (k * to_ref, q * to_ref)
+        m_k, m_q = [], []           # rows of the two pair matrices [., C]
+        for s in range(nS):
+            rows_s = slice(s * SUB, (s + 1) * SUB)
+            if s:
+                # across sub-blocks: row r through its sub-block's
+                # reference, column i (an earlier sub-block's) against it
+                k_from = jnp.where(r < s * SUB,
+                                   k * _exp_le0(refs[s] - gam), 0.0)
+                across = _dot(jnp.concatenate(
+                    [x[rows_s] for x in kq_ref], axis=0), k_from,
+                    ((1,), (1,)))                          # [2 SUB, C]
+            # within a sub-block: the ratio of every pair, a channel; the
+            # rows of its upper half meet the columns of that half alone
+            g_s, k_s, q_s = gam[rows_s], k[rows_s], q[rows_s]
+            H = SUB // 2
+            w = [[jnp.zeros((H, C), f32) for _ in range(2)]
+                 for _ in range(2)]                        # [k | q][half]
+            for i in range(SUB):
+                halves = (0, 1) if i < H else (1,)
+                for half in halves:
+                    rr = slice(half * H, (half + 1) * H)
+                    e = _exp_le0(g_s[rr] - g_s[i:i + 1]) * k_s[i:i + 1]
+                    for x, x_s in enumerate((k_s, q_s)):
+                        w[x][half] = jnp.where(
+                            col == s * SUB + i,
+                            jnp.sum(x_s[rr] * e, axis=1, keepdims=True),
+                            w[x][half])
+            for m, w_x, rows_x in ((m_k, w[0], slice(0, SUB)),
+                                   (m_q, w[1], slice(SUB, 2 * SUB))):
+                within = jnp.concatenate(w_x, axis=0)
+                m.append(within + across[rows_x] if s else within)
+        A = jnp.where(strict, jnp.concatenate(m_k, axis=0), 0.0) * b
+        P = jnp.where(low, jnp.concatenate(m_q, axis=0), 0.0)
+        # (I + A)^-1: the diagonal sub-blocks' by the exact product of a
+        # nilpotent matrix, (I - X)(I + X^2)(I + X^4)(I + X^8), X^16 = 0;
+        # then the blocks below them, a level a time: (I + Y + Z)^-1 =
+        # (I + Y)^-1 - (I + Y)^-1 Z (I + Y)^-1 where that Z's square is 0
+        X = jnp.where(in_sub, A, 0.0)
+        X2 = _dot(X, X)
+        X4 = _dot(X2, X2)
+        inv = eye - X + X2 - _dot(X, X2)
+        inv = inv + _dot(inv, X4)
+        inv = inv + _dot(inv, _dot(X4, X4))
+        for Z in (jnp.where(in_half & ~in_sub, A, 0.0),
+                  jnp.where(in_half, 0.0, A)):
+            inv = inv - _dot(_dot(inv, Z), inv)
+        G = jnp.exp(gam)                                   # <= 1
+        solved = _dot(inv, jnp.concatenate([v, k * G], axis=1) * b)
+        s0 = s_buf[h]                                      # [D keys, D]
+        through = _dot(jnp.concatenate([solved[:, D:], q * G], axis=0), s0)
+        u = solved[:, :D] - through[:C]
+        out = through[C:] + _dot(P, u)
+        o_ref[at, :] = jnp.where(mine, out, o_ref[at, :])
+        # S_end = diag(G_end) S_0 + sum_i (k_i G_end / G_i) u_i^T
+        s_buf[h] = _dot(
+            jnp.concatenate([k * _exp_le0(gam_end - gam), jnp.where(
+                diag, jnp.broadcast_to(jnp.exp(gam_end), (D, D)), 0.0)],
+                axis=0),
+            jnp.concatenate([u, s0], axis=0), ((0,), (0,)))
+        return carry
+
+    lax.fori_loop(0, N, head, 0)
+
+    @pl.when(closes)
+    def _():
+        copy = pltpu.make_async_copy(s_buf, s_out.at[slot_ref[p]], sem.at[0])
+        copy.start()
+        copy.wait()
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("interpret", "name"))
+def _chunk_call(pieces, q, k, v, g, b, state, *, interpret, name):
+    TN, D = q.shape
+    N = b.shape[1]
+    rows = pl.BlockSpec((CHUNK * N, D), lambda p, n, c, *_: (c[p], 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(pieces),
+        # a step a piece: as many as the tick's runs make, none for a tick
+        # without (no bound on them is static: every row may be a run)
+        grid=(pieces[0][0],),
+        in_specs=[rows, rows, rows, rows,
+                  pl.BlockSpec((CHUNK, N), lambda p, n, c, *_: (c[p], 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[rows, pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[pltpu.VMEM((N, D, D), jnp.float32),
+                        pltpu.SemaphoreType.DMA((1,))])
+    compiler_params = None
+    if not interpret:
+        # a chunk's rows of q, k, v, g and o, each twice, and the run's
+        # matrices
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=(10 * CHUNK + D) * N * D * 4 + (16 << 20))
+    return pl.pallas_call(
+        _chunk_kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((TN, D), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        # the store is updated in place (operands count the prefetched)
+        input_output_aliases={len(pieces) + 5: 1},
+        compiler_params=compiler_params, interpret=interpret, name=name,
+    )(*pieces, q, k, v, g, b, state)
+
+
+@jax.named_scope("kda_chunk")
+def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+              b: jax.Array, runs, rows: jax.Array, state: jax.Array,
+              slot: jax.Array, interpret: Optional[bool] = None, *,
+              name: str = "kda_chunk") -> Tuple[jax.Array, jax.Array]:
+    """The rule over the runs of ``rows`` [T] bool (whole runs; no two of
+    one sequence), chunkwise, a Mosaic kernel. Operands and results as
+    :func:`kda_chunk_reference`, whose doc-string is the mathematics; what
+    differs is what is computed when. The grid is the tick's PIECES and
+    nothing else: a step loads its chunk's rows (the block stays where the
+    piece before had the same chunk), reads the run's matrices from the
+    store where the piece opens its run (zero where the run starts at
+    position 0), and a head after the other forms the piece's ``A`` and
+    ``P`` from the rows' running log-decay (the sum within the piece as a
+    product with a triangle of ones), inverts ``I + A`` (the 16-row
+    diagonal blocks as the exact product of a nilpotent matrix, the blocks
+    below them by two levels of ``M^-1 - M^-1 Z M^-1``), and carries the
+    head's matrix in VMEM to the run's next piece; the piece that closes
+    its run writes them to the store. A bucket of 2,048 rows of which 770
+    are prompt rows pays for ~13 pieces, not 32 chunks, and a tick without
+    such runs for none. Nothing of ``[T, ...]`` is built around the call
+    but the result's mask."""
+    if interpret is None:
+        interpret = _use_interpret()
+    f32 = jnp.float32
+    T0, N, D = q.shape
+    # heads as wide as the lanes; the inverse is written for four
+    # sub-blocks of 16 (X^16 = 0, two levels below the diagonal)
+    assert D % 128 == 0 and (CHUNK, SUB) == (64, 16), (D, CHUNK, SUB)
+    T = -(-T0 // CHUNK) * CHUNK
+
+    def padded(x, fill=0):
+        return jnp.pad(x, [(0, T - T0)] + [(0, 0)] * (x.ndim - 1),
+                       constant_values=fill)
+
+    rows = padded(rows, False)
+    pieces = _pieces(rows, padded(runs.start, True), padded(runs.last, True),
+                     padded(runs.fresh, True), padded(slot), CHUNK)
+    q, k, v, g = (padded(x.astype(f32)).reshape(T * N, D)
+                  for x in (q, k, v, g))
+    b = padded(b.astype(f32))
+
+    def call(carry):
+        _, q, state = carry
+        o, state = _chunk_call(pieces, q, k, v, g, b, state,
+                               interpret=interpret, name=name)
+        return False, o, state
+
+    # a tick without such runs (most decode ticks) starts no kernel. A loop
+    # of at most one trip and not a ``cond``: XLA's rematerialization takes
+    # a call that updates the store in place for a second store (3.4 GB)
+    # unless a loop carries it, and then spends ~1 ms a decode tick
+    # re-laying what else is live to make room it does not need. The
+    # queries hold the result's place in the loop (a buffer of zeros would
+    # be 100 us of a 2,048-row tick to fill): every row is masked below
+    _, o, state = lax.while_loop(lambda carry: carry[0], call,
+                                 (pieces[0][0] > 0, q, state))
+    # a row no piece holds is whatever its buffer held
+    o = jnp.where(rows[:, None, None], o.reshape(T, N, D), 0.0)
+    return o[:T0], state

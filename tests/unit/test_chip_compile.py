@@ -270,6 +270,36 @@ def test_kda_step_compiles_for_v5e(one_chip):
     assert stats.temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("rows", [2048, 256])
+def test_kda_chunk_compiles_for_v5e(one_chip, rows):
+    """The chunk form at the Kimi cell's two buckets: one Mosaic call under
+    its own name inside the tick's ``cond`` (``benchmarks/roofline/
+    kda_chunk.py`` finds it by the scope), a grid as long as the tick's
+    pieces, the store aliased through the ``cond`` and the call."""
+    from deepspeed_tpu.models import hybrid as HY
+    from deepspeed_tpu.ops.pallas.kda import kda_chunk
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def form(q, k, v, g, b, state, slot, positions):
+        return kda_chunk(q, k, v, g, b, HY.runs_of(slot, positions),
+                         slot > 0, state, slot, interpret=False)
+
+    row = arg((rows, 32, 128), jnp.float32)
+    compiled = jax.jit(form, donate_argnums=(5,)).lower(
+        row, row, row, row, arg((rows, 32), jnp.float32),
+        arg((6 * 273, 32, 128, 128), jnp.float32),
+        arg((rows,), jnp.int32), arg((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%kda_chunk" in text
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= 6 * 273 * 32 * 128 * 128 * 4
+    # beside the result [rows, 32, 128]: the pieces' scalars and masks
+    assert stats.temp_size_in_bytes < 4 << 20
+
+
 # (sequences a chip, their length, query heads, KV heads, head size, dtype,
 # blocks a caller names): the two training cells at the blocks
 # ``choose_blocks`` gives them, and what only Mosaic refuses: a length under a

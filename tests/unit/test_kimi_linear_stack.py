@@ -18,6 +18,7 @@ expert or state: every fault made on purpose below reads over a hundred
 times the tolerance.
 """
 import dataclasses
+import functools
 import json
 import types
 
@@ -268,15 +269,26 @@ RULE_CASES = {
         [1] + [3] * 100 + [4] * 70 + [5] + [0] * 3,
         [9] + list(range(7, 107)) + list(range(70)) + [3] + [0] * 3, True),
     "every-row-a-run-of-one": (list(range(1, 9)), [5] * 8, False),
+    # a run over three chunks that ends mid-chunk, and a second run that
+    # starts in that chunk (two pieces of one chunk), then a decode row
+    "two-runs-in-one-chunk": (
+        [3] * 150 + [4] * 30 + [5] + [0] * 11,
+        list(range(20, 170)) + list(range(30)) + [8] + [0] * 11, False),
+    # prompt rows that start off the 64-grid after fewer than 64 decode
+    # rows: the run's first piece is the tail of the decode rows' chunk
+    "a-run-after-decode-rows": (
+        list(range(1, 38)) + [40] * 90 + [0],
+        [6] * 37 + list(range(11, 101)) + [0], True),
 }
 
 
 @pytest.mark.parametrize("kernel", [False, True])
 @pytest.mark.parametrize("case", sorted(RULE_CASES))
 def test_both_forms_of_the_rule_match_the_recurrence(case, kernel):
-    """``delta_rule`` (runs of one through ``kda_step``, interpreted where
-    ``kernel``; the others through ``kda_chunk``) against one row after
-    another: outputs and the state each run leaves in its slot."""
+    """``delta_rule`` (runs of one through ``kda_step``, the others through
+    ``kda_chunk``: the two Mosaic kernels interpreted where ``kernel``,
+    else their plain references) against one row after another: outputs
+    and the state each run leaves in its slot."""
     slots, positions, fast = RULE_CASES[case]
     args, o_want, state_want = _rule_case(slots, positions, fast)
     with jax.default_matmul_precision("highest"):
@@ -287,29 +299,126 @@ def test_both_forms_of_the_rule_match_the_recurrence(case, kernel):
     assert _rel(jnp.asarray(state), jnp.asarray(state_want)) < TOL
 
 
+@pytest.mark.parametrize("kernel", [False, True])
 def test_the_chunk_form_alone_takes_runs_of_one_past_the_step_form_s_count(
-        monkeypatch):
+        monkeypatch, kernel):
     """More runs of one than the one-row form takes: the rest go through
     the chunk form, a piece a row."""
     monkeypatch.setattr(HY, "KDA_STEP_ROWS", 3)
     args, o_want, state_want = _rule_case(list(range(1, 9)), [5] * 8)
     with jax.default_matmul_precision("highest"):
-        o, state = HY.delta_rule(*args)
+        o, state = HY.delta_rule(*args, use_kernel=kernel)
     assert _rel(o, o_want) < TOL
     assert _rel(jnp.asarray(state), jnp.asarray(state_want)) < TOL
 
 
-def test_pad_rows_touch_no_state():
+@pytest.mark.parametrize("kernel", [False, True])
+def test_pad_rows_touch_no_state(kernel):
     args, _, _ = _rule_case([0] * 8 + [2] + [0] * 7, [0] * 8 + [3] + [0] * 7)
-    for kernel in (False, True):
-        o, state = HY.delta_rule(*args, use_kernel=kernel)
-        before = args[6]
-        np.testing.assert_array_equal(np.asarray(state[0]),
-                                      np.asarray(before[0]))
-        np.testing.assert_array_equal(np.asarray(state[1]),
-                                      np.asarray(before[1]))
-        assert float(jnp.abs(state[2] - before[2]).max()) > 0
-        assert float(jnp.abs(o[:8]).max()) == 0.0
+    o, state = HY.delta_rule(*args, use_kernel=kernel)
+    before = args[6]
+    np.testing.assert_array_equal(np.asarray(state[0]), np.asarray(before[0]))
+    np.testing.assert_array_equal(np.asarray(state[1]), np.asarray(before[1]))
+    assert float(jnp.abs(state[2] - before[2]).max()) > 0
+    assert float(jnp.abs(o[:8]).max()) == 0.0
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_a_bucket_whose_chunks_hold_no_row_starts_nothing(kernel):
+    """Rows of the one-row form and pads alone: the chunk form has no
+    piece, the store is as it was bit for bit and its output zero."""
+    slots = list(range(1, 6)) + [0] * 123
+    args, _, _ = _rule_case(slots, [4] * 5 + [0] * 123)
+    q, k, v, g, b, runs, state, slot = args
+    chunk = jax.jit(functools.partial(KD.kda_chunk, interpret=True)) \
+        if kernel else KD.kda_chunk_reference
+    o, after = chunk(q, k, v, g, b, runs, jnp.zeros((128,), bool), state,
+                     slot)
+    np.testing.assert_array_equal(np.asarray(after), np.asarray(state))
+    assert o.shape == q.shape and float(jnp.abs(o).max()) == 0.0
+    n, *_ = KD._pieces(jnp.zeros((128,), bool), runs.start, runs.last,
+                       runs.fresh, slot, KD.CHUNK)
+    assert int(n[0]) == 0 == KD.count_pieces([])
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("mistake", ["decay-dropped", "b-is-one"])
+def test_a_mistake_in_the_rule_is_seen_in_both_forms(mistake, kernel):
+    """What the comparison above can see: either form against the
+    recurrence that makes a mistake reads far over the tolerance."""
+    slots, positions, _ = RULE_CASES["two-runs-in-one-chunk"]
+    args, _, _ = _rule_case(slots, positions)
+    q, k, v, g, b, runs, state, slot = args
+    if mistake == "decay-dropped":
+        g = jnp.zeros_like(g)
+    else:
+        b = jnp.ones_like(b)
+    s0 = jnp.where(runs.fresh[:, None, None, None], 0.0, state[slot])
+    o_wrong, _ = HY.kda_recurrence(q, k, v, g, b, runs, s0)
+    with jax.default_matmul_precision("highest"):
+        o, _ = HY.delta_rule(*args, use_kernel=kernel)
+    assert _rel(o, jnp.where((slot > 0)[:, None, None], o_wrong, 0.0)) \
+        > 100 * TOL
+
+
+@pytest.mark.parametrize("case", sorted(RULE_CASES))
+def test_the_host_counts_the_pieces_the_kernel_runs(case):
+    """``count_pieces`` (the span's ``kda_chunk_pieces``) is the grid the
+    kernel is given, for the runs ``delta_rule`` hands the chunk form."""
+    slots, positions, _ = RULE_CASES[case]
+    slot = jnp.asarray(slots, jnp.int32)
+    runs = HY.runs_of(slot, jnp.asarray(positions, jnp.int32))
+    start, last = np.asarray(runs.start), np.asarray(runs.last)
+    firsts, ends = np.nonzero(start)[0], np.nonzero(last)[0]
+    taken = [(int(a), int(e - a + 1)) for a, e in zip(firsts, ends)
+             if slots[a] > 0 and e > a]       # runs of one: the step form
+    rows = np.zeros((len(slots),), bool)
+    for a, n in taken:
+        rows[a:a + n] = True
+    T = -(-len(slots) // KD.CHUNK) * KD.CHUNK
+    pad = lambda x, fill: jnp.pad(  # noqa: E731
+        jnp.asarray(x), (0, T - len(slots)), constant_values=fill)
+    n, chunk, lo, hi, _, flag = KD._pieces(
+        pad(rows, False), pad(runs.start, True), pad(runs.last, True),
+        pad(runs.fresh, True), pad(slot, 0), KD.CHUNK)
+    n = int(n[0])
+    assert n == KD.count_pieces(taken)
+    # a piece lies inside one chunk and one run; a run's first opens it
+    # and its last closes it
+    assert bool((lo[:n] <= hi[:n]).all()) and bool((hi[:n] < KD.CHUNK).all())
+    assert int(jnp.sum(flag[:n] & 1)) == len(taken) \
+        == int(jnp.sum((flag[:n] & 4) > 0))
+    assert int(jnp.sum(hi[:n] - lo[:n] + 1)) == int(rows.sum())
+
+
+def test_the_kernel_alone_tool_still_walks():
+    """``tools/kda_kernel_alone.py`` on its tiny cases, interpreted: the
+    three forms run chained and the kernel agrees with the plain form (its
+    times are a chip's to give: none is read here)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
+                        "kda_kernel_alone.py")
+    spec = importlib.util.spec_from_file_location("kda_kernel_alone", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert set(tool.CASES) >= {"mixed-one-run", "mixed-two-runs", "decode"}
+    forms = tool.forms_of(KD, True)
+    assert set(forms) == {"kernel", "plain", "solve"}
+    ops, pieces = tool.operands(np.random.default_rng(0),
+                                tool.TINY["mixed"], (2, 128))
+    assert pieces == 3
+    found = tool.compare(KD, ops, True)
+    assert found["finite"] and found["o_rel"] < TOL \
+        and found["state_rel"] < TOL
+    ops, pieces = tool.operands(np.random.default_rng(0),
+                                tool.TINY["decode"], (2, 128))
+    assert pieces == 0
+    for name in ("plain", "solve"):     # the kernel's trace is above
+        total, state = tool.chained(forms[name], 2)(*ops)
+        assert float(total) == 0.0 or name == "solve"
+        np.testing.assert_array_equal(np.asarray(state), np.asarray(ops[7]))
 
 
 # --------------------------------------------------------------------------- #
@@ -560,6 +669,8 @@ def test_the_tick_s_span_and_gauges_say_which_form_took_which_rows(cut):
 
     counter = telemetry.counter("fastgen_kda_rows_total")
     before = {f: counter.value(form=f) for f in ("step", "chunk")}
+    pieces = telemetry.counter("fastgen_kda_chunk_pieces_total")
+    pieces_before = pieces.value()
     eng.put([1, 2, 3], [toks[0, :20].tolist(), toks[1, :5].tolist(),
                         toks[0, 7:8].tolist()])
     import deepspeed_tpu.inference.fastgen as FG
@@ -572,9 +683,13 @@ def test_the_tick_s_span_and_gauges_say_which_form_took_which_rows(cut):
         FG.telemetry.span = orig
     assert [s["kda_step_rows"] for s in spans] == [0, 1, 3]
     assert [s["kda_chunk_rows"] for s in spans] == [16, 9, 0]
+    # a chunk of 64 rows each run of the chunk form has a row in: the
+    # first prompt's 16 rows; its last 4 and the second prompt's 5
+    assert [s["kda_chunk_pieces"] for s in spans] == [1, 2, 0]
     assert [s["kda_state_rows"] for s in spans] == [1, 3, 3]
     assert counter.value(form="step") - before["step"] == 4
     assert counter.value(form="chunk") - before["chunk"] == 25
+    assert pieces.value() - pieces_before == 3
     n_kda = cfg.layer_kinds.count("kda")
     per_slot = telemetry.gauge("fastgen_state_bytes_per_slot")
     assert per_slot.value(kind="rule") == n_kda * 2 * 128 * 128 * 4

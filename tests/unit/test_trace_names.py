@@ -18,7 +18,7 @@ from deepspeed_tpu.inference.fastgen import FastGenEngine
 PALLAS = os.path.join(os.path.dirname(dst.__file__), "ops", "pallas")
 KERNEL_NAMES = {
     "flash_fwd", "flash_dq", "flash_dkv", "paged_attention",
-    "latent_paged_attention", "kda_step",
+    "latent_paged_attention", "kda_step", "kda_chunk",
     "block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv",
     "evoformer_attention", "fused_adam", "quantize_int8_blocks",
     "dequant_reduce", "rms_norm", "layer_norm"}
@@ -71,7 +71,7 @@ def test_every_pallas_call_is_named():
                     d.value for a, d in zip(node.args.kwonlyargs,
                                             node.args.kw_defaults)
                     if a.arg == "name" and isinstance(d, ast.Constant))
-    assert sites == 13
+    assert sites == 14
     assert literal == KERNEL_NAMES
 
 

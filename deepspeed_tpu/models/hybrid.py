@@ -168,24 +168,33 @@ def runs_of(owner: jax.Array, positions: jax.Array) -> Runs:
 # --------------------------------------------------------------------------- #
 
 def _segmented_conv(x: jax.Array, taps: jax.Array, runs: Runs,
-                    conv0: jax.Array) -> Tuple[jax.Array, jax.Array]:
+                    conv0: Tuple[jax.Array, ...]
+                    ) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
     """Depthwise causal convolution over runs. x [T, di]; taps [c, di]
-    (the last tap meets the row itself); conv0 [T, c-1, di]: the c-1 inputs
-    before each row's RUN (oldest first; read at the run's rows only).
-    Returns (the convolution [T, di] in float32, the c-1 inputs up to and
-    including each row [T, c-1, di])."""
+    (the last tap meets the row itself); conv0: the c-1 inputs before each
+    row's RUN, oldest first, each [T, di] (read at the run's rows only;
+    zeroed here for a run at position 0, whatever was handed in). A tuple
+    of rows and not one ``[T, c-1, di]`` array, as a state store keeps
+    them (``paged.init_paged_kv``): a dimension of 2 or 3 taps ahead of the
+    lanes pads every tile it meets. Returns (the convolution [T, di] in
+    float32, the c-1 inputs up to and including each row, oldest first)."""
     c = taps.shape[0]
+    fresh = runs.fresh[:, None]
+    conv0 = [jnp.where(fresh, 0, s).astype(x.dtype) for s in conv0]
     before = [x]                          # before[k][t]: the input k rows back
     for k in range(1, c):
-        shifted = jnp.pad(x, ((k, 0), (0, 0)))[:-k]
-        # k rows back lies in the run, or (k - offset) rows before it
-        idx = jnp.clip(c - 1 - (k - runs.offset), 0, c - 2)
-        stored = jnp.take_along_axis(conv0, idx[:, None, None], axis=1)[:, 0]
-        before.append(jnp.where((runs.offset >= k)[:, None], shifted, stored))
-    window = jnp.stack(before[::-1], axis=1)             # [T, c, di]
-    out = jnp.einsum("tcd,cd->td", window.astype(jnp.float32),
-                     taps.astype(jnp.float32))
-    return out, window[:, 1:]
+        # k rows back lies in the run, or (k - offset) rows before it:
+        # the stored input ``c - 1 - k + offset``
+        back = conv0[c - 1 - k]
+        for o in range(1, k):
+            back = jnp.where((runs.offset == o)[:, None],
+                             conv0[c - 1 - k + o], back)
+        before.append(jnp.where((runs.offset >= k)[:, None],
+                                jnp.pad(x, ((k, 0), (0, 0)))[:-k], back))
+    taps = taps.astype(jnp.float32)
+    out = sum(before[k].astype(jnp.float32) * taps[c - 1 - k]
+              for k in range(c))
+    return out, tuple(before[c - 2::-1])
 
 
 def _selective_scan(delta: jax.Array, xc: jax.Array, bm: jax.Array,
@@ -215,19 +224,18 @@ def _selective_scan(delta: jax.Array, xc: jax.Array, bm: jax.Array,
 
 
 def mamba(h: jax.Array, lp: Dict[str, Any], cfg: Any, runs: Runs,
-          conv0: jax.Array, ssm0: jax.Array
-          ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+          conv0: Tuple[jax.Array, ...], ssm0: jax.Array
+          ) -> Tuple[jax.Array, jax.Array, Tuple[jax.Array, ...], jax.Array]:
     """The selective state-space mixer on normed rows h [T, H], before its
-    output projection. conv0 [T, c-1, di], ssm0 [T, n, di]: the state each
-    row's run starts from (zeroed here for a run at position 0, whatever
-    was handed in). Returns (gated output [T, di], ungated scan output
-    ``y`` [T, di] (the memory a gated unit reads), and the state after
-    every row: conv [T, c-1, di], ssm [T, n, di] float32)."""
+    output projection. conv0 (c-1 inputs [T, di], oldest first), ssm0
+    [T, n, di]: the state each row's run starts from (zeroed here for a run
+    at position 0, whatever was handed in). Returns (gated output [T, di],
+    ungated scan output ``y`` [T, di] (the memory a gated unit reads), and
+    the state after every row: conv (c-1 inputs [T, di]), ssm [T, n, di]
+    float32)."""
     dt_, di, n = h.dtype, cfg.ssm_inner, cfg.ssm_state
     r = cfg.ssm_dt_rank
-    fresh = runs.fresh[:, None, None]
-    conv0 = jnp.where(fresh, 0, conv0).astype(dt_)
-    ssm0 = jnp.where(fresh, 0.0, ssm0)
+    ssm0 = jnp.where(runs.fresh[:, None, None], 0.0, ssm0)
     with jax.named_scope("ssm_proj"):
         xz = h @ lp["w_in"].astype(dt_)
         x, z = xz[:, :di], xz[:, di:]
@@ -252,19 +260,19 @@ def mamba(h: jax.Array, lp: Dict[str, Any], cfg: Any, runs: Runs,
 
 
 def short_conv(h: jax.Array, lp: Dict[str, Any], runs: Runs,
-               conv0: jax.Array) -> Tuple[jax.Array, jax.Array]:
+               conv0: Tuple[jax.Array, ...]
+               ) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
     """The gated short convolution (the ``lfm2`` family's ``conv`` layers)
     on normed rows h [T, H], before its output projection: ``[B | C | z]
     = h W_in``, ``g = B * z``, a depthwise causal convolution of ``g``
     over the row's run (no bias, no activation, no scan), times ``C``.
-    conv0 [T, taps-1, H]: the inputs ``g`` before each row's run (zeroed
-    here for a run at position 0, whatever was handed in). Returns (the
-    gated output [T, H], ``g`` up to and including each row
-    [T, taps-1, H]: a sequence's state is its last row's)."""
+    conv0: the taps-1 inputs ``g`` before each row's run, oldest first,
+    each [T, H] (zeroed for a run at position 0, whatever was handed in).
+    Returns (the gated output [T, H], ``g`` up to and including each row,
+    taps-1 of [T, H]: a sequence's state is its last row's)."""
     dt_, H = h.dtype, lp["conv_w"].shape[-1]
     bcz = h @ lp["w_in"].astype(dt_)
     g = bcz[:, :H] * bcz[:, 2 * H:]
-    conv0 = jnp.where(runs.fresh[:, None, None], 0, conv0).astype(dt_)
     conv, conv_new = _segmented_conv(g, lp["conv_w"], runs, conv0)
     return bcz[:, H:2 * H] * conv.astype(dt_), conv_new
 
@@ -287,23 +295,23 @@ def kda_state_shapes(cfg: Any) -> Tuple[tuple, tuple]:
 
 
 def kda_inputs(h: jax.Array, lp: Dict[str, Any], cfg: Any, runs: Runs,
-               conv0: jax.Array):
+               conv0: Tuple[jax.Array, ...]):
     """A ``kda`` layer's normed rows h [T, H] up to the rule. ``q~, k~,
     v~ = h W_q, h W_k, h W_v`` each through its own depthwise causal
-    convolution over the row's run (conv0 [T, taps-1, 3 N D]: the inputs
-    before each row's run, q | k | v, zeroed here for a run at position 0)
+    convolution over the row's run (conv0: the taps-1 inputs before each
+    row's run, oldest first, each [T, 3 N D], q | k | v; zeroed for a run at
+    position 0)
     and SiLU; per head ``q = l2norm(q) D^-0.5``, ``k = l2norm(k)``; the
     log-decay a head and CHANNEL ``g = -exp(A_log) softplus(W_fb (W_fa h) +
     dt_bias)`` (``a = exp(g)`` in (0, 1)); the step size ``b = sigmoid(h
     W_b)``, one a head. Returns ((q, k, v, g [T, N, D], b [T, N]), all
-    float32, and the convolutions' inputs up to and including each row
-    [T, taps-1, 3 N D])."""
+    float32, and the convolutions' inputs up to and including each row,
+    taps-1 of [T, 3 N D])."""
     dt_, f32 = h.dtype, jnp.float32
     Tn, n, d = h.shape[0], cfg.kda_heads, cfg.kda_head_dim
     qkv = jnp.concatenate([h @ lp[f"w{x}"].astype(dt_) for x in "qkv"],
                           axis=-1)
     taps = jnp.concatenate([lp[f"conv_{x}"] for x in "qkv"], axis=-1)
-    conv0 = jnp.where(runs.fresh[:, None, None], 0, conv0).astype(dt_)
     conv, conv_new = _segmented_conv(qkv, taps, runs, conv0)
     q, k, v = (x.reshape(Tn, n, d) for x in jnp.split(
         jax.nn.silu(conv), 3, axis=-1))

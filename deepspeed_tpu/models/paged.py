@@ -86,7 +86,9 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
     * a RING ``[L, (slots + 1) * RB, bs, ...]``: ``RB`` blocks
       (:func:`ring_blocks`) a sequence slot, position ``p`` in block
       ``(p // bs) % RB`` of its slot's, whatever the sequence's length;
-    * STATE ``[L, slots + 1, ...]``: one row a sequence slot.
+    * STATE ``[L, slots + 1, ...]``: one row a sequence slot; a
+      convolution's last inputs ``[L x inputs x (slots + 1), channels]``,
+      a row an input of a slot (:func:`_conv_store`).
 
     A sequence's slot is its first block's id (``tables[:, 0]``: the
     engine's allocator hands first blocks out of ``1 .. state_slots``);
@@ -149,15 +151,16 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
                 (kinds.count("latent"), n_blocks, block_size,
                  latent_row_width(cfg)), dt)
         if "conv" in kinds:
-            # the short convolution's last inputs, a row a sequence slot
-            pool["conv"] = jnp.zeros(
-                (kinds.count("conv"), state_slots + 1, cfg.conv_taps - 1,
-                 cfg.hidden_size), dt)
+            # the short convolution's last inputs
+            pool["conv"] = _conv_store(
+                kinds.count("conv"), state_slots,
+                (cfg.conv_taps - 1, cfg.hidden_size), dt)
         if "kda" in kinds:
             rule, conv = HY.kda_state_shapes(cfg)
             rows = (kinds.count("kda"), state_slots + 1)
             pool["kda"] = jnp.zeros(rows + rule, jnp.float32)
-            pool["kda_conv"] = jnp.zeros(rows + conv, dt)
+            pool["kda_conv"] = _conv_store(kinds.count("kda"), state_slots,
+                                           conv, dt)
         return pool
     if cfg.layer_kinds:
         kinds = cfg.layer_kinds
@@ -175,8 +178,8 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
         return {"k": jnp.zeros((1, n_blocks) + head, dt),
                 "v": jnp.zeros((1, n_blocks) + head, dt),
                 "wk": jnp.zeros(ring, dt), "wv": jnp.zeros(ring, dt),
-                "conv": jnp.zeros(state + (cfg.ssm_conv - 1, cfg.ssm_inner),
-                                  dt),
+                "conv": _conv_store(kinds.count("mamba"), state_slots,
+                                    (cfg.ssm_conv - 1, cfg.ssm_inner), dt),
                 "ssm": jnp.zeros(state + (cfg.ssm_state, cfg.ssm_inner),
                                  jnp.float32)}
     if cfg.mla:
@@ -184,6 +187,66 @@ def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
                                      latent_row_width(cfg)), dt)}
     shape = (L, n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+
+def _conv_store(layers: int, state_slots: int, kept: tuple, dtype
+                ) -> jax.Array:
+    """The store of a convolution's last inputs: ``kept`` is what a
+    sequence keeps a layer, ``(inputs, channels)`` (taps - 1 of them,
+    oldest first); the store holds a ROW an input of a slot, inputs-major:
+    ``[layers x inputs x (slots + 1), channels]``, layer ``l``'s input
+    ``k`` the PLANE of ``slots + 1`` rows from ``(l x inputs + k) x (slots
+    + 1)`` (:func:`_conv_rows`). The minor two dimensions of a state store
+    fill their tiles: with the 2 or 3 inputs second-minor (``[.., slots +
+    1, inputs, channels]``) a tile of 4 or 8 sublanes is a quarter or more
+    padding, and XLA re-laid the whole store to the unpadded form and back
+    wherever it pleased: on entry, on exit and as ``remat_compressed``
+    pairs around the delta rule's calls, 4.6 ms of a 28.8 ms decode tick.
+    Two dimensions and not ``[layers x inputs, slots + 1, channels]``: the
+    device lays an array out by its shape, and puts 16 planes ahead of 273
+    slots as the second-minor dimension, which is a copy of the whole
+    store in and out of every tick again (PERF.md, PR 43)."""
+    inputs, channels = kept
+    return jnp.zeros((layers * inputs * (state_slots + 1), channels), dtype)
+
+
+#: the stores of :func:`_conv_store`: rows already, they ride the layer
+#: scans as they are
+_CONV_STORES = ("conv", "kda_conv")
+
+
+def _conv_rows(S1: int, slot: jax.Array, closes: jax.Array):
+    """The two uses a layer makes of a store of :func:`_conv_store`:
+    ``read(store, layer, inputs) -> inputs x [T, channels]``, the tick
+    rows' slots' rows, and ``write(store, layer, new) -> store``, ``new``
+    the inputs up to and including each row, each ``[T, channels]``: the
+    state after a row that ``closes`` a run (its last row, of a real
+    sequence) is its sequence's. A plane is written WHOLE: each slot's row
+    is the row of ``new`` that closes the slot's run, or what the slot
+    held where none does (so a pad row writes nothing) — a gather of
+    ``S1`` rows, a select and the plane updated in place. A scatter of the
+    tick's rows walks them one index after another, ~0.9 us each however
+    wide: 18 of them cost a 2,048-row tick 29 ms where the planes take
+    one (PERF.md, PR 43)."""
+    # [S1, T]: the one row of the tick, if any, that closes each slot's run
+    mine = closes[None, :] & (
+        slot[None, :] == jnp.arange(S1, dtype=slot.dtype)[:, None])
+    closing, closed = jnp.argmax(mine, axis=1), mine.any(axis=1)[:, None]
+
+    def read(store, layer, inputs):
+        return tuple(store[(layer * inputs + k) * S1 + slot]
+                     for k in range(inputs))
+
+    def write(store, layer, new):
+        for k, x in enumerate(new):
+            first = (layer * len(new) + k) * S1
+            held = lax.dynamic_slice_in_dim(store, first, S1)
+            store = lax.dynamic_update_slice_in_dim(
+                store, jnp.where(closed, x[closing].astype(store.dtype),
+                                 held), first, 0)
+        return store
+
+    return read, write
 
 
 def paged_attention_reference(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
@@ -618,7 +681,10 @@ def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
     slot, by_slot = jnp.arange(Tn, dtype=jnp.int32), rows.tables
     per_slot = [n for n in ("wk", "conv", "kda") if n in pool]
     if per_slot:
-        S1 = pool[per_slot[0]].shape[1]
+        # slots + 1: a convolution store's rows are (layer, input, slot)
+        S1 = pool["conv"].shape[0] // (cfg.layer_kinds.count("conv") * (
+            cfg.conv_taps - 1)) if per_slot[0] == "conv" \
+            else pool[per_slot[0]].shape[1]
         slot = rows.tables[:, 0]
         by_slot = jnp.zeros((S1, MB), jnp.int32).at[slot].set(rows.tables)
     if "wk" in pool:
@@ -630,6 +696,8 @@ def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
     pack = kv_lane_pack(cfg)
     if "conv" in pool or "kda" in pool:
         runs = HY.runs_of(slot, rows.positions)
+        read_conv, write_conv = _conv_rows(S1, slot,
+                                           runs.last & (slot > 0))
     if "latent" in pool:
         latent = _latent_cache(cfg, pool, rows, attend,
                                (by_slot, slot) if per_slot else None)
@@ -639,26 +707,22 @@ def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
             attn, new = latent(h, lp, flat, nth * pool["latent"].shape[1])
             return attn, {**flat, **new}
         if kind == "kda":
-            at = nth * S1 + slot
-            inputs, conv = HY.kda_inputs(h, lp, cfg, runs,
-                                         flat["kda_conv"][at])
-            put = jnp.where(runs.last, at, flat["kda_conv"].shape[0])
+            inputs, conv = HY.kda_inputs(
+                h, lp, cfg, runs,
+                read_conv(flat["kda_conv"], nth, cfg.kda_conv - 1))
             # a pad row's sequence is none: row 0 of the store
             o, state = HY.delta_rule(
-                *inputs, runs, flat["kda"], jnp.where(slot > 0, at, 0),
+                *inputs, runs, flat["kda"],
+                jnp.where(slot > 0, nth * S1 + slot, 0),
                 use_kernel=attend not in _REFERENCES)
             return HY.kda_output(o, h, lp, cfg), {
                 **flat, "kda": state,
-                "kda_conv": flat["kda_conv"].at[put].set(
-                    conv.astype(flat["kda_conv"].dtype), mode="drop")}
+                "kda_conv": write_conv(flat["kda_conv"], nth, conv)}
         if kind == "conv":
-            at = nth * S1 + slot
-            mixed, conv = HY.short_conv(h, lp, runs, flat["conv"][at])
-            # the state after a run's last row is its sequence's; the
-            # other rows' index lies past the array and is dropped
-            put = jnp.where(runs.last, at, flat["conv"].shape[0])
-            return mixed, {**flat, "conv": flat["conv"].at[put].set(
-                conv.astype(flat["conv"].dtype), mode="drop")}
+            mixed, conv = HY.short_conv(
+                h, lp, runs, read_conv(flat["conv"], nth, cfg.conv_taps - 1))
+            return mixed, {**flat,
+                           "conv": write_conv(flat["conv"], nth, conv)}
         q, k, v = _project_qkv(
             cfg, h, lp, rows.positions,
             (cos_t, sin_t) if cfg.pos_emb == "rope" and (
@@ -715,11 +779,12 @@ def _kinds_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
     Tn, MB = rows.tables.shape
     N, K, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     bs = pool["k"].shape[3]
-    S1 = pool["conv"].shape[1]
+    S1 = pool["ssm"].shape[1]
     ring_rows = pool["wk"].shape[1]
     RB = ring_rows // S1
     slot = rows.tables[:, 0]
     runs = HY.runs_of(slot, rows.positions)
+    read_conv, write_conv = _conv_rows(S1, slot, runs.last & (slot > 0))
     ring_tables = slot[:, None] * RB + (jnp.arange(MB, dtype=jnp.int32)
                                         % RB)[None, :]
     ring_block = slot * RB + (rows.positions // bs) % RB
@@ -744,13 +809,14 @@ def _kinds_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
         if kind == "mamba":
             at = step * S1 + slot
             out, memory, conv, ssm = HY.mamba(
-                h, lp, cfg, runs, flat["conv"][at], flat["ssm"][at])
+                h, lp, cfg, runs,
+                read_conv(flat["conv"], step, cfg.ssm_conv - 1),
+                flat["ssm"][at])
             # the state after a run's last row is its sequence's; the
             # other rows' index lies past the array and is dropped
-            put = jnp.where(runs.last, at, flat["conv"].shape[0])
+            put = jnp.where(runs.last, at, flat["ssm"].shape[0])
             flat = {**flat,
-                    "conv": flat["conv"].at[put].set(
-                        conv.astype(flat["conv"].dtype), mode="drop"),
+                    "conv": write_conv(flat["conv"], step, conv),
                     "ssm": flat["ssm"].at[put].set(ssm, mode="drop")}
             return out, flat, memory
         if kind == "gmu":
@@ -982,8 +1048,8 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
     # 512 blocks inside a decode scan, linear in pool size — where the
     # in-place carry touches only the written rows.
     # (a ring of standard blocks is [layers, slots, RB, bs, K, D]: its rows
-    # are blocks too)
-    carry = (x, {k: v.reshape((-1,) + (
+    # are blocks too; the stores of ``_CONV_STORES`` are rows already)
+    carry = (x, {k: v if k in _CONV_STORES else v.reshape((-1,) + (
         v.shape[-3:] if cfg.standard_blocks and k in ("wk", "wv")
         else v.shape[2:])) for k, v in pool.items()}, jnp.int32(0))
     if cfg.layer_kinds and not cfg.standard_blocks:

@@ -1263,7 +1263,7 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
                           jnp.tile(jnp.arange(S, dtype=jnp.int32), B))
         mixed, _ = HY.short_conv(
             h.reshape(B * S, H), lp, runs,
-            jnp.zeros((B * S, cfg.conv_taps - 1, H), dt))
+            (jnp.zeros((B * S, H), dt),) * (cfg.conv_taps - 1))
         return (mixed @ lp["wo"].astype(dt)).reshape(B, S, H)
 
     @jax.named_scope("kda")
@@ -1273,9 +1273,9 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         owner = jnp.repeat(jnp.arange(B, dtype=jnp.int32), S)
         runs = HY.runs_of(owner, jnp.tile(jnp.arange(S, dtype=jnp.int32), B))
         hr = h.reshape(B * S, H)
-        (n, d, _), conv = HY.kda_state_shapes(cfg)
-        inputs, _ = HY.kda_inputs(hr, lp, cfg, runs,
-                                  jnp.zeros((B * S,) + conv, dt))
+        (n, d, _), (kept, channels) = HY.kda_state_shapes(cfg)
+        inputs, _ = HY.kda_inputs(
+            hr, lp, cfg, runs, (jnp.zeros((B * S, channels), dt),) * kept)
         # a row of state a sequence (row 0 is the pad rows')
         o, _ = HY.delta_rule(*inputs, runs,
                              jnp.zeros((B + 1, n, d, d), jnp.float32),
@@ -1665,7 +1665,7 @@ def _forward_kinds(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     positions = jnp.tile(jnp.arange(S, dtype=jnp.int32), B)
     runs = HY.runs_of(jnp.repeat(jnp.arange(B, dtype=jnp.int32), S),
                       positions)
-    conv0 = jnp.zeros((B * S, cfg.ssm_conv - 1, cfg.ssm_inner), dt)
+    conv0 = (jnp.zeros((B * S, cfg.ssm_inner), dt),) * (cfg.ssm_conv - 1)
     ssm0 = jnp.zeros((B * S, cfg.ssm_state, cfg.ssm_inner), jnp.float32)
 
     def attend(h, lp, kind, layer, shared):

@@ -357,3 +357,73 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, monkeypatch):
         matmuls = need._MATMULS[need.classify(c)]
         assert need.ops_and_bytes(need.classify(c), c.text)[0] == \
             matmuls * B * N * S_pad * S_pad * D
+
+
+# (cell, rows of its small tick bucket, its widest table tier, the
+# convolution-state store): the decode programs of the three cells whose
+# sequences keep a convolution's last inputs, whole, at the cells' real
+# sizes. A store whose second-minor dimension was its 2 or 3 stored inputs
+# padded every tile, and XLA re-laid all of it on entry, on exit and (the
+# 121 MB ``kda_conv``) as four ``remat_compressed`` pairs a tick (PERF.md,
+# PR 43); a row an input of a slot is re-laid nowhere
+TICK_PROGRAMS = {
+    "kimi-linear-256x80": ("serve-kimi-linear-48b-rollout-closed", 256, 80,
+                           "kda_conv"),
+    "lfm2-256x64": ("serve-lfm2-24b-concurrent-closed", 256, 64, "conv"),
+    "phi4flash-64x136": ("serve-phi4flash-reason-closed", 64, 136, "conv"),
+}
+
+
+def _copies_tool():
+    """``tools/tick_program_copies.py``: the one place that compiles a
+    cell's tick and counts its copies."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
+                        "tick_program_copies.py")
+    spec = importlib.util.spec_from_file_location("tick_program_copies", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+@pytest.mark.parametrize("program", sorted(TICK_PROGRAMS))
+def test_a_tick_re_lays_no_state_store(one_chip, program):
+    import math
+
+    tool = _copies_tool()
+    cell, rows, tier, store = TICK_PROGRAMS[program]
+    cfg, sizes, programs = tool.cell_programs(cell)
+    assert (rows, tier) in programs
+    lowered, pool = tool.lower_tick(cfg, sizes, rows, tier, one_chip)
+    compiled = lowered.compile()
+    found = tool.count_copies(compiled.as_text(),
+                              [math.prod(pool[store].shape)])
+    assert found["remat"] == {} and found["whole_store_copies"] == {}
+    # the whole pool rides the tick in place (and pads next to nothing: a
+    # store's rows up to a multiple of 8)
+    held = sum(math.prod(x.shape) * x.dtype.itemsize for x in pool.values())
+    assert held <= compiled.memory_analysis().alias_size_in_bytes \
+        < 1.0001 * held
+
+
+def test_the_copy_count_sees_what_it_is_for():
+    """``count_copies`` on the lines the parent's decode tick held."""
+    tool = _copies_tool()
+    text = "\n".join([
+        "  %copy.976 = bf16[6,273,3,12288]{3,2,1,0:T(4,128)(2,1)} "
+        "copy(%param.3)",
+        "  %fusion.207.remat_compressed = bf16[1638,3,12288]"
+        "{2,0,1:T(8,128)(2,1)} fusion(%x), kind=kLoop",
+        "  %reshape.1 = bf16[18,273,12288]{2,1,0:T(8,128)(2,1)} "
+        "reshape(%fusion.268)",
+        "  ROOT %copy.2 = bf16[256,3,12288]{2,1,0:T(4,128)(2,1)S(1)} "
+        "copy(%y)",
+        "  %bitcast.7 = bf16[1638,3,12288]{2,1,0:T(4,128)(2,1)} "
+        "bitcast(%param.3)"])
+    found = tool.count_copies(text, [6 * 273 * 3 * 12288], min_bytes=16 << 20)
+    assert found["remat"] == {"bf16[1638,3,12288]{2,0,1:T(8,128)(2,1)}": 1}
+    assert sum(found["whole_store_copies"].values()) == 2   # copy, reshape
+    assert found["copies"] == {
+        "bf16[6,273,3,12288]{3,2,1,0:T(4,128)(2,1)}": 1,
+        "bf16[256,3,12288]{2,1,0:T(4,128)(2,1)S(1)}": 1}

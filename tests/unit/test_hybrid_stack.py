@@ -176,7 +176,10 @@ def test_state_outside_the_block_pool_does_not_grow(model):
     shapes = {k: v.shape for k, v in eng.pool.items()}
     assert shapes["k"] == shapes["v"] == (1, 40, 2, 4, 16)
     assert shapes["wk"] == (2, 4 * 8, 2, 4, 16)    # 2 window layers
-    assert shapes["conv"] == (3, 4, 3, 128) and shapes["ssm"] == (3, 4, 16, 128)
+    # a mamba layer's three stored inputs a row each, inputs-major: a
+    # slot keeps 3 layers x 3 x 128 values of them
+    assert shapes["conv"] == (3 * 3 * 4, 128) and shapes["ssm"] == (3, 4, 16, 128)
+    assert eng.pool["conv"].nbytes // 4 == 3 * 3 * 128 * 4
     assert eng.pool["ssm"].dtype == jnp.float32
     ring = 8 * 4
     eng.put([7], [toks[0, :50].tolist()])
@@ -239,6 +242,49 @@ def test_allocator_hands_first_blocks_out_of_the_slots():
     assert b.allocate(2) == [1, 2] and b.grow(1) == [3] and b.free_slots == 0
 
 
+# (layers, stored inputs a layer, channels) in the proportions of the three
+# convolution-state stores: the delta-rule layers' q | k | v, the gated short
+# convolution's, the state-space layers'
+CONV_STORES = {"kda_conv": (2, 3, 96), "conv": (3, 2, 32), "mamba": (2, 3, 40)}
+
+
+@pytest.mark.parametrize("name", sorted(CONV_STORES))
+def test_what_a_tick_writes_is_what_the_next_tick_reads(name):
+    """A store of ``paged._conv_store`` through ``paged._conv_rows``: the
+    rows that close a run write their slot's row of every stored input,
+    the next tick's rows read them back in the order written, and nothing
+    else moves: not the other layers' rows, not a slot that had no row,
+    not slot 0 under any number of pad rows."""
+    layers, inputs, channels = CONV_STORES[name]
+    slots, layer = 3, 1
+    rng = np.random.default_rng(5)
+    store = PG._conv_store(layers, slots, (inputs, channels), jnp.float32)
+    assert store.shape == (layers * inputs * (slots + 1), channels)
+    store = store + 7.0                             # whatever was there
+    # a tick: a run of three rows of slot 2, a pad, slot 1 alone, two pads
+    slot = jnp.asarray([2, 2, 2, 0, 1, 0, 0], jnp.int32)
+    runs = HY.runs_of(slot, jnp.asarray([4, 5, 6, 0, 9, 0, 0], jnp.int32))
+    read, write = PG._conv_rows(slots + 1, slot, runs.last & (slot > 0))
+    assert all(np.all(np.asarray(x) == 7.0) and x.shape == (7, channels)
+               for x in read(store, layer, inputs))
+    new = tuple(jnp.asarray(rng.normal(size=(7, channels)), jnp.float32)
+                for _ in range(inputs))
+    after = write(store, jnp.int32(layer), new)
+    # the next tick: one row a slot, pads among them
+    slot2 = jnp.asarray([0, 1, 2, 3, 0], jnp.int32)
+    read2, _ = PG._conv_rows(slots + 1, slot2, slot2 > 0)
+    got = read2(after, jnp.int32(layer), inputs)
+    assert len(got) == inputs
+    for k in range(inputs):
+        np.testing.assert_array_equal(got[k][1], new[k][4])   # slot 1
+        np.testing.assert_array_equal(got[k][2], new[k][2])   # slot 2
+        assert np.all(np.asarray(got[k][3]) == 7.0)           # no row
+        assert np.all(np.asarray(got[k][0]) == 7.0)           # the pads'
+    by_row = np.asarray(after).reshape(layers, inputs, slots + 1, channels)
+    assert np.all(np.delete(by_row, layer, axis=0) == 7.0)
+    assert int((by_row != 7.0).any(axis=-1).sum()) == 2 * inputs
+
+
 @pytest.mark.parametrize("case", ["mid-tick", "decode", "one-run"])
 def test_segmented_scan_and_conv_against_a_sequential_scan(case):
     """Runs that start mid-tick, from stored state or from position 0,
@@ -265,11 +311,16 @@ def test_segmented_scan_and_conv_against_a_sequential_scan(case):
         draw(4, c - 1, di)
     got_s, got_y = HY._selective_scan(*map(jnp.asarray, (
         delta, xc, bm, cm, a_neg)), runs, jnp.asarray(s0[owner]))
-    got_c, got_w = HY._segmented_conv(jnp.asarray(x), jnp.asarray(taps), runs,
-                                      jnp.asarray(c0[owner]))
+    # the stored inputs a tuple of rows, oldest first; a run from position
+    # 0 starts from zeros whatever its slot held
+    got_c, got_w = HY._segmented_conv(
+        jnp.asarray(x), jnp.asarray(taps), runs,
+        tuple(jnp.asarray(c0[owner, k]) for k in range(c - 1)))
+    assert len(got_w) == c - 1
+    got_w = np.stack(got_w, axis=1)
     t = 0
-    for o, _, rows in layout:
-        s, hist = s0[o], list(c0[o])
+    for o, first, rows in layout:
+        s, hist = s0[o], list(c0[o] * (first > 0))
         for _ in range(rows):
             s = np.exp(delta[t][None] * a_neg) * s \
                 + (delta[t] * xc[t])[None] * bm[t][:, None]

@@ -502,7 +502,11 @@ def test_segments_mixers_and_pools(model):
     assert pool["latent"].shape == (kinds.count("latent"), 40, 4, 128)
     assert pool["kda"].shape == (kinds.count("kda"), 4, 2, 128, 128)
     assert pool["kda"].dtype == jnp.float32
-    assert pool["kda_conv"].shape == (kinds.count("kda"), 4, 3, 3 * 256)
+    # a row of the store is ONE stored input of one slot (inputs-major):
+    # rows second-minor, lanes full, the bytes a slot unchanged
+    assert pool["kda_conv"].shape == (kinds.count("kda") * 3 * 4, 3 * 256)
+    assert pool["kda_conv"].nbytes // 4 == kinds.count("kda") * int(
+        np.prod(HY.kda_state_shapes(cfg)[1])) * 4
     axes = T.param_logical_axes(cfg)
     flat_p = dict(jax.tree_util.tree_flatten_with_path(params)[0])
     flat_a = dict(jax.tree_util.tree_flatten_with_path(

@@ -187,7 +187,11 @@ def test_a_slot_handed_on_starts_from_zero(model):
     others = toks[::-1, ::-1].copy()
     out, second = _drive(eng, cfg, others, None, 11, n_prompt=25)
     assert sorted(first) == sorted(second) == [1, 2]
-    assert float(jnp.abs(eng.pool["conv"][:, 1:]).min()) > 0
+    # rows (layer, input, slot): both slots hold the first pair's state,
+    # the pad rows' slot 0 was never written
+    by_slot = eng.pool["conv"].reshape(-1, 3, eng.pool["conv"].shape[-1])
+    assert float(jnp.abs(by_slot[:, 1:]).min()) > 0
+    assert float(jnp.abs(by_slot[:, 0]).max()) == 0
     assert _rel(out, R.forward_logits(params, others, arch)) < TOL
 
 
@@ -246,7 +250,10 @@ def test_segments_mixers_and_pools(model):
     assert pack == (2 if cfg.head_dim == 64 else 1)
     assert pool["k"].shape == (n_full, 40, 4, cfg.kv_heads // pack,
                                pack * cfg.head_dim)
-    assert pool["conv"].shape == (kinds.count("conv"), 4, 2, h)
+    # a row of the store is ONE stored input of one slot (inputs-major)
+    assert pool["conv"].shape == (kinds.count("conv") * 2 * 4, h)
+    assert pool["conv"].nbytes // 4 == kinds.count("conv") * 2 * h * \
+        pool["conv"].dtype.itemsize
     assert set(pool) == {"k", "v", "conv"}
     axes = T.param_logical_axes(cfg)
     flat_p = dict(jax.tree_util.tree_flatten_with_path(params)[0])

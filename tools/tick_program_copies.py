@@ -1,0 +1,213 @@
+#!/usr/bin/env python3
+"""What XLA re-lays in a serving cell's tick programs, read with no chip:
+each ``(rows, table tier)`` program of a cell compiled at the cell's real
+sizes for a described v5e, and its copies counted.
+
+    python tools/tick_program_copies.py serve-kimi-linear-48b-rollout-closed
+    python tools/tick_program_copies.py <cell> --programs 256x80 --min-mb 8
+
+One JSON line a program: the ``remat_compressed`` / ``remat_uncompressed``
+fusions by result shape (the rematerialization pass re-laying an array to
+"save" the padding of its tiles), the ``copy`` instructions whose result is
+above ``--min-mb`` by shape, the ``copy`` / ``reshape`` / ``transpose``
+instructions as large as a per-slot state store of the pool
+(``whole_store_copies``: a reshape the compiler could not make a
+``bitcast`` moves every byte too), ``memory_analysis()``'s arguments /
+alias / temporaries, and a hash of the program as lowered (equal on two
+trees: the change between them left that program alone). The pool rides a tick aliased in place: a
+copy of a whole store, or a ``remat`` pair on one, is device time no layer
+needs (PERF.md, PR 43: a store whose second-minor dimension was 3 taps was
+re-laid ten times a decode tick).
+
+The configuration, the pool and the packed tick are ``ShapeDtypeStruct``s:
+nothing is allocated and nothing runs (~25 s a program of the largest cell
+on this box). It reads the cell's and its configuration's files and edits
+nothing. Say ``JAX_PLATFORMS=cpu``: the topology is described, not reached.
+"""
+import argparse
+import collections
+import hashlib
+import json
+import math
+import os
+import re
+import sys
+from unittest import mock
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+#: `` %name = type[dims]{layout} opcode(``: an instruction of compiled text
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\](\{[^ ]*\})? ([\w\-]+)\(")
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+             "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+             "u64": 8}
+
+
+def count_copies(text: str, stores=(), min_bytes: int = 8 << 20):
+    """The re-layouts of a compiled program's text. ``stores``: element
+    counts of the arrays no copy should be as large as (the pool's state
+    stores). Returns ``{"remat": {shape: n}, "copies": {shape: n},
+    "whole_store_copies": {shape: n}}``: instructions named ``*remat_
+    compressed*`` / ``*remat_uncompressed*``, ``copy`` results of at least
+    ``min_bytes``, and the ``copy`` / ``reshape`` / ``transpose`` results
+    with a store's element count (what is left of a reshape in compiled
+    text is no ``bitcast``: it moves the array)."""
+    found = {k: collections.Counter()
+             for k in ("remat", "copies", "whole_store_copies")}
+    stores = set(stores)
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, dtype, dims, layout, opcode = m.groups()
+        shape = f"{dtype}[{dims}]{layout or ''}"
+        if "remat_compressed" in name or "remat_uncompressed" in name:
+            found["remat"][shape] += 1
+        elements = math.prod(int(d) for d in dims.split(",") if d)
+        if opcode in ("copy", "reshape", "transpose") and elements in stores:
+            found["whole_store_copies"][f"{shape} {opcode}"] += 1
+        if opcode == "copy" and \
+                elements * _ITEMSIZE.get(dtype, 4) >= min_bytes:
+            found["copies"][shape] += 1
+    return {k: dict(v) for k, v in found.items()}
+
+
+def describe_chip():
+    """A v5e chip to compile for (raises where none can be described)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _bare_engine(cfg, sizes):
+    """A ``FastGenEngine`` that was never built: ``_build_tick``, the
+    bucket and the tier rules need its configuration, its sizes and its
+    sampling constants alone."""
+    from deepspeed_tpu.inference.fastgen import FastGenEngine
+
+    eng = FastGenEngine.__new__(FastGenEngine)
+    eng.cfg, eng.token_budget = cfg, sizes["token_budget"]
+    eng.max_blocks_per_seq = sizes["max_blocks_per_seq"]
+    eng.temperature, eng.top_k, eng.top_p = 0.0, 0, 1.0
+    eng._expert_layers = sum(
+        c.num_layers for _, c in cfg.segments if c.n_experts)
+    return eng
+
+
+def cell_programs(cell_name: str):
+    """(model config, the cell's engine sizes, its ``(rows, tier)``
+    programs) of a serving cell of the benchmark."""
+    from benchmarks import model_config
+    from benchmarks.manifest import load_cell
+
+    cell = load_cell(cell_name)
+    cfg, sizes = model_config.build(cell.config, "serve"), \
+        cell.deploy["engine"]
+    eng = _bare_engine(cfg, sizes)
+    tiers = sorted({*eng._mb_tier_bounds(), eng.max_blocks_per_seq})
+    rows = sorted({eng._bucket(0), eng.token_budget})
+    return cfg, sizes, [(r, t) for r in rows for t in tiers]
+
+
+def lower_tick(cfg, sizes, rows: int, tier: int, chip):
+    """One tick program lowered for ``chip``: (lowered, the pool's
+    shapes)."""
+    import numpy as np
+
+    from deepspeed_tpu.models import paged as PG
+    from deepspeed_tpu.models import transformer as T
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    dt = cfg.compute_dtype
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, dt if jnp.issubdtype(x.dtype, jnp.floating)
+            else x.dtype),
+        jax.eval_shape(lambda k: T.init_params(cfg, k),
+                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    pool = jax.eval_shape(lambda: PG.init_paged_kv(
+        cfg, sizes["n_blocks"], sizes["block_size"],
+        state_slots=sizes.get("state_slots", 0) if cfg.layer_kinds else 0,
+        max_run=sizes["token_budget"]))
+    eng = _bare_engine(cfg, sizes)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        eng._attention, eng._tile_rows = PG.tick_attention(cfg, True)
+        packed = eng._pack_tick(
+            np.zeros((rows,), np.int32), np.zeros((rows,), np.int32),
+            np.zeros((rows, tier), np.int32), np.zeros((2,), np.uint32))
+        lowered = eng._build_tick(rows, tier).lower(
+            on_chip(params), on_chip(pool), jax.ShapeDtypeStruct(
+                packed.shape, jnp.int32, sharding=chip))
+    return lowered, pool
+
+
+def program_line(lowered, pool, min_bytes: int, text_path: str = ""):
+    """What ``count_copies`` finds in a tick program once compiled, its
+    memory, and a hash of the program as LOWERED, which a change that left
+    this program alone leaves equal (``main`` keeps source lines out of
+    it)."""
+    from deepspeed_tpu.inference.fastgen import FastGenEngine
+
+    compiled = lowered.compile()
+    if text_path:
+        with open(text_path, "w") as f:
+            f.write(compiled.as_text())
+    stores = {k: math.prod(v.shape) for k, v in pool.items()
+              if k in FastGenEngine._STATE_STORES}
+    stats = compiled.memory_analysis()
+    return {**count_copies(compiled.as_text(), stores.values(), min_bytes),
+            "state_stores": {k: list(pool[k].shape) for k in stores},
+            "pool_bytes": sum(math.prod(v.shape) * v.dtype.itemsize
+                              for v in pool.values()),
+            "argument_gb": round(stats.argument_size_in_bytes / 1e9, 3),
+            "alias_gb": round(stats.alias_size_in_bytes / 1e9, 3),
+            "temp_gb": round(stats.temp_size_in_bytes / 1e9, 3),
+            "lowered_sha256": hashlib.sha256(
+                lowered.as_text().encode()).hexdigest()[:16]}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("cell", help="a serving cell of BENCHMARK.json")
+    ap.add_argument("--programs", default="",
+                    help="ROWSxTIER,... (default: every program of the cell)")
+    ap.add_argument("--min-mb", type=float, default=8.0,
+                    help="list copies of at least this many MiB")
+    ap.add_argument("--text-dir", default="",
+                    help="keep each program's compiled text here")
+    args = ap.parse_args(argv)
+    # a Mosaic call serialises its body with the call stack of every
+    # operation: without this an edit that shifts a line moves the hash
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    chip = describe_chip()
+    cfg, sizes, programs = cell_programs(args.cell)
+    if args.programs:
+        programs = [tuple(int(n) for n in p.split("x"))
+                    for p in args.programs.split(",")]
+    if args.text_dir:
+        os.makedirs(args.text_dir, exist_ok=True)
+    for rows, tier in programs:
+        lowered, pool = lower_tick(cfg, sizes, rows, tier, chip)
+        print(json.dumps({
+            "cell": args.cell, "rows": rows, "tier": tier,
+            **program_line(lowered, pool, int(args.min_mb * 2 ** 20),
+                           args.text_dir and os.path.join(
+                               args.text_dir,
+                               f"{args.cell}-{rows}x{tier}.txt"))}),
+            flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

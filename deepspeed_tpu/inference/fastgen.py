@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import collections
 import itertools
-import math
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -42,7 +41,6 @@ import numpy as np
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.sampling import sample_logits
-from deepspeed_tpu.models import hybrid as HY
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.utils.compile_cache import ensure_compile_cache
@@ -343,26 +341,27 @@ class FastGenEngine:
         # in a tick: what the count of a tick's fetch steps needs
         self._walks = PG.tick_walks(cfg, self.pool) if self._tile_rows \
             else []
+        # what a tick of a given shape does to each kind of layer's state,
+        # by the kind's own rule: attributes of the tick's span
+        self._kind_spans = [kind.span for kind in PG.cache_kinds(cfg).values()
+                            if kind.span is not None]
         # expert layers whose per-expert row counts ride back with a
         # tick's sampled tokens; 0 for a model without experts
         self._expert_layers = sum(
             c.num_layers for _, c in cfg.segments if c.n_experts)
 
-    #: the pool's arrays that hold a row a sequence SLOT, not blocks
-    _STATE_STORES = ("wk", "wv", "conv", "ssm", "kda", "kda_conv")
-
     @classmethod
     def _pool_bytes(cls, cfg, n_blocks: int, block_size: int,
                     state_slots: int, max_run: int) -> Tuple[int, int]:
         """(bytes of the block stores, bytes of the per-slot state stores)
-        of the pool ``init_paged_kv`` would build, without building it."""
-        shapes = jax.eval_shape(lambda: PG.init_paged_kv(
+        of the pool ``init_paged_kv`` would build, without building it:
+        which store is which is its class in the model's table of cache
+        kinds (``paged.store_bytes``)."""
+        held = PG.store_bytes(cfg, jax.eval_shape(lambda: PG.init_paged_kv(
             cfg, n_blocks, block_size, state_slots=state_slots,
-            max_run=max_run))
-        size = {k: math.prod(v.shape) * v.dtype.itemsize
-                for k, v in shapes.items()}
-        state = sum(v for k, v in size.items() if k in cls._STATE_STORES)
-        return sum(size.values()) - state, state
+            max_run=max_run)))
+        blocks = sum(n for s, n in held if s.cls == PG.BLOCKS)
+        return blocks, sum(n for _, n in held) - blocks
 
     def _dev(self, x) -> jax.Array:
         """Host array → device; REPLICATED across the mesh under TP (a
@@ -532,19 +531,17 @@ class FastGenEngine:
             "kind: conv (a convolution's last inputs) / ring (window "
             "layers' keys and values) / scan (a recurrence's matrix) / "
             "rule (a delta rule's matrices)")
-        for kind, names in (("conv", ("conv", "kda_conv")),
-                            ("ring", ("wk", "wv")), ("scan", ("ssm",)),
-                            ("rule", ("kda",))):
-            held = [self.pool[n] for n in names if n in self.pool]
-            if held:
-                state_bytes.set(sum(x.nbytes for x in held)
-                                / (self.allocator.state_slots + 1), kind=kind)
+        by_kind: Dict[str, int] = {}
+        for s, n in PG.store_bytes(self.cfg, self.pool):
+            if s.cls != PG.BLOCKS:
+                by_kind[s.holds] = by_kind.get(s.holds, 0) + n
+        for kind, n in by_kind.items():
+            state_bytes.set(n / (self.allocator.state_slots + 1), kind=kind)
         telemetry.gauge(
             "fastgen_state_bytes",
             "bytes of the stores that hold a row a sequence slot (rings, "
             "convolution, recurrence and delta-rule state), all slots"
-        ).set(sum(self.pool[n].nbytes for n in self._STATE_STORES
-                  if n in self.pool))
+        ).set(sum(by_kind.values()))
         self._tm_kda_rows = telemetry.counter(
             "fastgen_kda_rows_total",
             "rows of ticks through the delta-rule layers, by the form of "
@@ -1255,7 +1252,7 @@ class FastGenEngine:
             # of a model with window layers, the cache positions inside
             # the rows' windows (what a window layer must read, a
             # sequence) and those the prompt rows score (a row)
-            state_runs = runs_of_one = 0
+            state_runs = 0
             W = self.cfg.attn_window
             window_positions = window_attended = 0
             # prompt rows in kernel tiles wholly inside one chunk
@@ -1316,7 +1313,6 @@ class FastGenEngine:
                     continue
                 self._ensure_blocks(seq, seq.pos + chunk - 1)
                 state_runs += seq.pos > 0
-                runs_of_one += chunk == 1
                 if W:
                     window_positions += min(seq.pos + chunk, chunk + W - 1)
                     window_attended += int(np.minimum(
@@ -1367,36 +1363,8 @@ class FastGenEngine:
                           "state_slots": self.allocator.slots_in_use,
                           "window_positions": int(window_positions),
                           "window_attended": window_attended}
-            if "conv" in self.cfg.layer_kinds:
-                # rows that close a run (a decode row, a chunk's last):
-                # each writes its slot's state in every conv layer
-                slot_attrs["conv_state_rows"] = \
-                    n_decode_rows + len(chunk_starts)
-            if "kda" in self.cfg.layer_kinds:
-                # the rule's two forms by the program's own rule
-                # (``hybrid.delta_rule``): runs of one row, up to the one-
-                # row form's count, and the rows of every other run; and
-                # the rows that close a run, each of which writes its
-                # slot's state in every kda layer
-                kda_step = min(n_decode_rows + runs_of_one, Tn,
-                               HY.KDA_STEP_ROWS)
-                # the chunk form's grid steps, by the kernel's own rule:
-                # every run but the first ``kda_step`` runs of one row
-                # (decode rows lie first, a row each)
-                from deepspeed_tpu.ops.pallas.kda import count_pieces
-
-                took = min(n_decode_rows, kda_step)
-                left = kda_step - took      # for the prompts' runs of one
-                chunk_runs = [(r, 1) for r in range(took, n_decode_rows)]
-                for a, b in zip(chunk_starts, chunk_starts[1:] + [row]):
-                    if b - a == 1 and left:
-                        left -= 1
-                    else:
-                        chunk_runs.append((a, b - a))
-                slot_attrs.update(
-                    kda_step_rows=kda_step, kda_chunk_rows=row - kda_step,
-                    kda_chunk_pieces=count_pieces(chunk_runs),
-                    kda_state_rows=n_decode_rows + len(chunk_starts))
+            for span in self._kind_spans:
+                slot_attrs.update(span(n_decode_rows, chunk_starts, row, Tn))
         with telemetry.span("decode_tick", attrs={
                 **slot_attrs,
                 "tick": self._ticks_run, "kind": kind, "rows": row,

@@ -46,7 +46,8 @@ def ring_blocks(cfg: T.TransformerConfig, block_size: int,
 
 
 def kv_lane_pack(cfg: T.TransformerConfig) -> int:
-    """KV heads that lie side by side in one row of a standard-block pool:
+    """KV heads that lie side by side in one row of a pool of the standard
+    block under kinds (:func:`cache_kinds` says which stacks):
     2 where a head is 64 wide, so that a row fills the TPU's 128 lanes
     (``[bs, K/2, 128]``), else 1. A block ``[bs, K, 64]`` would be padded
     to the lanes in HBM (twice the bytes held and fetched), and Mosaic
@@ -57,8 +58,8 @@ def kv_lane_pack(cfg: T.TransformerConfig) -> int:
     output its own half (:func:`_lane_packed`, as differential attention's
     ``hybrid.paired_queries``): no head moves, the products are twice as
     wide, which a kernel bound by its bytes does not feel."""
-    return 2 if cfg.standard_blocks and cfg.head_dim == 64 \
-        and cfg.kv_heads % 2 == 0 else 1
+    return max((kind.attend.pack for kind in cache_kinds(cfg).values()
+                if kind.attend is not None), default=1)
 
 
 def _lane_packed(q: jax.Array, kv_heads: int) -> Tuple[jax.Array, Callable]:
@@ -72,121 +73,270 @@ def _lane_packed(q: jax.Array, kv_heads: int) -> Tuple[jax.Array, Callable]:
         odd[None, :, None], o[..., D:], o[..., :D])
 
 
+# --------------------------------------------------------------------------- #
+# the table of cache kinds
+# --------------------------------------------------------------------------- #
+
+#: the classes of a pool's stores: what a store's rows are, and so where
+#: its leading dimensions come from (:func:`init_paged_kv`)
+BLOCKS, RING, SLOT, CONV = "blocks", "ring", "slot", "conv"
+
+
+class Store(NamedTuple):
+    """One array of a pool, as the kind of layer that owns it states it.
+
+    * ``BLOCKS`` ``[layers, n_blocks, *unit]``: grows with a sequence a
+      block at a time through its block table; block 0 is the trash block
+      pad rows write into;
+    * ``RING`` ``[layers, slots + 1, RB, *unit]``: ``RB`` blocks
+      (:func:`ring_blocks`) a sequence slot, position ``p`` in block
+      ``(p // bs) % RB`` of its slot's, whatever the sequence's length
+      (``flat``: slots and ring blocks are ONE dimension, ``[layers,
+      (slots + 1) * RB, *unit]``);
+    * ``SLOT`` ``[layers, slots + 1, *unit]``: one row a sequence slot;
+    * ``CONV`` ``[layers x inputs x (slots + 1), channels]``: a
+      convolution's last inputs, a row an input of a slot
+      (:func:`_conv_store`); ``unit`` is ``(inputs, channels)``.
+
+    ``unit(block_size)``: the shape of one block, or of a slot's row;
+    ``positions``: the axis of a block that counts positions; ``holds``:
+    what a slot's rows are, for the engine's gauge of bytes a slot."""
+    name: str
+    cls: str
+    unit: Callable[[int], tuple]
+    dtype: Any = None                   # None: the pool's own
+    holds: str = ""
+    positions: int = -3
+    flat: bool = False
+
+
+class Attend(NamedTuple):
+    """The attention call a kind of layer makes: what
+    ``ops.pallas.paged_attention``'s kernel is told beside its operands."""
+    stores: Tuple[str, ...]             # the stores it walks
+    name: str                           # the Mosaic call's name
+    window: Optional[int]
+    width: int                          # the kernel's value columns
+    heads_first: bool = False           # a block is [K, bs, D]
+    # the products take the operands in the queries' own type (else the
+    # kernel's default, float32): a chunk of the token budget against
+    # thousands of positions is MXU-bound, unlike the homogeneous cells'
+    # shapes
+    own_dtype: bool = False
+    # one table a sequence SLOT and each row's slot (a budget of thousands
+    # of rows, each with a table of hundreds of blocks of its own, has no
+    # room in scalar memory), else a table a row
+    by_slot: bool = False
+    scope: Optional[str] = None         # the scope a trace tells the call by
+    rope: int = 0                       # columns rotated at the rows' positions
+    pack: int = 1                       # KV heads a pool row (kv_lane_pack)
+
+
+class CacheKind(NamedTuple):
+    """What a kind of layer keeps of its sequences between ticks and how
+    its mixer is built: one entry of :func:`cache_kinds`."""
+    layers: int                         # layers of the kind in the stack
+    scope: str                          # the scope its mixer runs under
+    stores: Tuple[Store, ...]           # what its layers own
+    attend: Optional[Attend]
+    mixer: Callable                     # builds its mixer: see below
+    # what its layers hand on to later layers of a tick: name -> columns
+    acts: Tuple[Tuple[str, int], ...] = ()
+    # ``(decode rows, chunk starts, rows, bucket) -> span attributes``: what
+    # a tick of that shape does to the kind's state, for the engine's span
+    span: Optional[Callable] = None
+
+
+def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
+    """The ONE table of what a model keeps of its sequences between ticks:
+    an entry for every kind of layer the stack has, in the order the
+    kinds' calls are counted (:func:`tick_walks`). A homogeneous stack is
+    its ``full`` (or ``latent``) entry ``num_layers`` times. The pool
+    (:func:`init_paged_kv`), the tick's carry, the engine's bytes and
+    gauges, the mixers and the kernel calls all come from here, and this is
+    the one place that reads which family ``cfg`` is: grouped-query,
+    latent (``cfg.mla``) or differential attention; the standard block
+    (``cfg.standard_blocks``: a kind says what a layer sees and where its
+    cache lives) or mixers that are whole layers of their own
+    (``models/hybrid.py``).
+
+    MLA models (DeepSeek) pool the LATENTS instead of per-head K/V: one
+    row per position, ``c_kv [kv_lora_rank] ++ k_pe [qk_rope_head_dim]``
+    (the shared post-rope key) ++ zeros up to a lane multiple
+    (:func:`latent_row_width`; reference ``ragged/kv_cache.py`` + the v2
+    engine's DeepSeek containers). That small row (kvr+dr vs 2·K·D) is
+    where paged KV pays off, and one row a position is what lets the
+    kernel read each position once. The delta rule's matrix and the
+    recurrence's are float32 (a bfloat16 state was not tried on the chip).
+    The state-space family's ONE ``full`` layer owns the block pool its
+    ``cross`` layers read; its keys and values are stored as differential
+    attention reads them, ``[.., K/2, 2 D]`` (``hybrid.paired_cache``), a
+    block heads first, ``[K/2, bs, 2 D]``: 10 paired heads cannot be the
+    second-minor dim of a block the kernel's copies slice."""
+    kinds, of_kinds = cfg.layer_kinds, cfg.standard_blocks
+    n = kinds.count
+    if kinds and not of_kinds:
+        if n("full") != 1:
+            raise ValueError(
+                "a stack of layer_kinds has one `full` layer (the owner of "
+                f"the block pool; got {n('full')})")
+
+        def head(bs):
+            return (cfg.kv_heads // HY.PAIR, bs, HY.PAIR * cfg.head_dim)
+
+        def paired(stores, window, name):
+            return Attend(stores, name, window, HY.PAIR * cfg.head_dim,
+                          heads_first=True)
+
+        shared = paired(("k", "v"), None, "shared_paged_attention")
+        table = {
+            "mamba": CacheKind(
+                n("mamba"), "ssm",
+                (Store("conv", CONV, lambda bs: (cfg.ssm_conv - 1,
+                                                 cfg.ssm_inner), holds="conv"),
+                 Store("ssm", SLOT, lambda bs: (cfg.ssm_state, cfg.ssm_inner),
+                       jnp.float32, holds="scan")),
+                None, _mamba_mixer, acts=(("memory", cfg.ssm_inner),)),
+            "window": CacheKind(
+                n("window"), "attn",
+                tuple(Store(s, RING, head, holds="ring", positions=-2,
+                            flat=True) for s in ("wk", "wv")),
+                paired(("wk", "wv"), cfg.attn_window,
+                       "window_paged_attention"), _differential_mixer),
+            "full": CacheKind(
+                1, "attn", tuple(Store(s, BLOCKS, head, positions=-2)
+                                 for s in ("k", "v")),
+                shared, _differential_mixer),
+            "cross": CacheKind(n("cross"), "attn", (), shared,
+                               _differential_mixer),
+            # a gated memory unit gates the last state-space layer's scan
+            "gmu": CacheKind(n("gmu"), "gmu", (), None, lambda *_: (
+                lambda h, lp, flat, li, nth, acts: (
+                    HY.gmu(h, lp, acts["memory"]), flat, acts))),
+        }
+        return {k: v for k, v in table.items() if v.layers}
+    # the standard block: under kinds its calls are told apart by name and
+    # scope, take one table a slot and the operands in their own type
+    rope = cfg.pos_emb == "rope"
+    pack = 2 if of_kinds and cfg.head_dim == 64 and cfg.kv_heads % 2 == 0 \
+        else 1
+
+    def block(bs):
+        return (bs, cfg.kv_heads // pack, pack * cfg.head_dim)
+
+    def grouped(stores, window, name, scope, rotates):
+        return Attend(stores, name if of_kinds else "paged_attention",
+                      window, pack * cfg.head_dim, own_dtype=of_kinds,
+                      by_slot=of_kinds, scope=scope if of_kinds else None,
+                      rope=cfg.rope_dim if rope and rotates else 0,
+                      pack=pack)
+
+    table = {
+        "window": CacheKind(
+            n("window"), "attn",
+            tuple(Store(s, RING, block, holds="ring") for s in ("wk", "wv")),
+            grouped(("wk", "wv"), cfg.attn_window, "swa_attention", "swa",
+                    True), _grouped_mixer),
+        "full": CacheKind(
+            n("full") if kinds else 0 if cfg.mla else cfg.num_layers, "attn",
+            tuple(Store(s, BLOCKS, block) for s in ("k", "v")),
+            grouped(("k", "v"), None, "global_attention", "global",
+                    cfg.full_layers_rope), _grouped_mixer),
+        "latent": CacheKind(
+            n("latent") if kinds else cfg.num_layers if cfg.mla else 0,
+            "attn",
+            (Store("latent", BLOCKS, lambda bs: (bs, latent_row_width(cfg)),
+                   positions=-2),),
+            Attend(("latent",), "latent_paged_attention", None,
+                   cfg.kv_lora_rank, by_slot=of_kinds,
+                   rope=cfg.qk_rope_head_dim if rope else 0), _latent_mixer),
+        "conv": CacheKind(
+            n("conv"), "conv",
+            (Store("conv", CONV, lambda bs: (cfg.conv_taps - 1,
+                                             cfg.hidden_size), holds="conv"),),
+            None, _conv_mixer,
+            # rows that close a run (a decode row, a chunk's last): each
+            # writes its slot's state in every conv layer
+            span=lambda decode_rows, chunk_starts, rows, bucket: {
+                "conv_state_rows": decode_rows + len(chunk_starts)}),
+        "kda": CacheKind(
+            n("kda"), "kda",
+            (Store("kda", SLOT, lambda bs: HY.kda_state_shapes(cfg)[0],
+                   jnp.float32, holds="rule"),
+             Store("kda_conv", CONV, lambda bs: HY.kda_state_shapes(cfg)[1],
+                   holds="conv")),
+            None, _kda_mixer, span=_kda_span),
+    }
+    return {k: v for k, v in table.items() if v.layers}
+
+
+def stack_kinds(cfg: T.TransformerConfig,
+                seg: T.TransformerConfig) -> Tuple[str, ...]:
+    """The kind of every layer of a segment of ``cfg``'s stack, in order:
+    its own ``layer_kinds``, its period as often as it has steps, or the
+    homogeneous stack's one kind."""
+    return seg.layer_kinds or seg.period * seg.num_layers \
+        or tuple(cache_kinds(cfg)) * seg.num_layers
+
+
+def pool_stores(cfg: T.TransformerConfig) -> List[Tuple[int, Store]]:
+    """(layers that keep it, store) of every array of ``cfg``'s pool."""
+    return [(kind.layers, s) for kind in cache_kinds(cfg).values()
+            for s in kind.stores]
+
+
 def init_paged_kv(cfg: T.TransformerConfig, n_blocks: int, block_size: int,
                   dtype=None, state_slots: int = 0, max_run: int = 0
                   ) -> Dict[str, jax.Array]:
     """What a model keeps of its sequences between ticks, as a dict of
-    arrays each ``[layers that keep it, rows, ...]`` (``forward_paged``
-    carries every one flat, ``[layers * rows, ...]``, and a layer owns the
-    range that starts at ``layer * rows``). Three kinds:
-
-    * a BLOCK pool ``[L, NB, bs, ...]``, which grows with a sequence a
-      block at a time through its block table; block 0 is the trash block
-      pad rows write into. ``{"k", "v"}`` per head, or ``{"latent"}``;
-    * a RING ``[L, (slots + 1) * RB, bs, ...]``: ``RB`` blocks
-      (:func:`ring_blocks`) a sequence slot, position ``p`` in block
-      ``(p // bs) % RB`` of its slot's, whatever the sequence's length;
-    * STATE ``[L, slots + 1, ...]``: one row a sequence slot; a
-      convolution's last inputs ``[L x inputs x (slots + 1), channels]``,
-      a row an input of a slot (:func:`_conv_store`).
+    arrays: every store of every entry of :func:`cache_kinds`, laid out by
+    its class (:class:`Store`), each ``[layers that keep it, rows, ...]``
+    (``forward_paged`` carries every one flat, ``[layers * rows, ...]``,
+    and a layer owns the range that starts at ``layer * rows``).
 
     A sequence's slot is its first block's id (``tables[:, 0]``: the
     engine's allocator hands first blocks out of ``1 .. state_slots``);
-    slot 0 is the pad rows' trash, as block 0 is.
-
-    A homogeneous attention stack has the block pool alone, for every
-    layer. ``window`` and ``full`` layers of the standard block in one
-    stack (``cfg.standard_blocks``) have a block range for each ``full``
-    layer and rings ``{"wk", "wv"}`` for the ``window`` layers, blocks
-    ``[bs, K, D]`` as the homogeneous stack's (heads of 64 two to a row,
-    ``[bs, K/2, 2 D]``: :func:`kv_lane_pack`), and state ``{"conv"}`` for
-    its ``conv`` layers: the short convolution's last inputs. Where the
-    block's attention is latent (``cfg.mla``) the stack has a range of
-    latent blocks ``{"latent"}`` for each ``latent`` layer (the row
-    below) and, for its ``kda`` layers, state ``{"kda"}``: the delta rule's
-    matrix ``[heads, keys, values]`` in float32, and ``{"kda_conv"}``: the
-    last inputs of its three convolutions (``hybrid.kda_state_shapes``).
-    A stack of ``layer_kinds``
-    with mixers of its own (``models/hybrid.py``) has the block
-    pool for its ONE ``full`` layer (the ``cross`` layers read it), rings
-    ``{"wk", "wv"}`` for its ``window`` layers and state for its ``mamba``
-    layers: ``{"conv"}`` the convolution's last inputs, ``{"ssm"}`` the
-    recurrence's matrix in float32 (a bfloat16 state was not tried on the
-    chip). Its keys and values are stored as differential attention reads
-    them, ``[.., K/2, 2 D]`` (``hybrid.paired_cache``), a block heads
-    first: ``[K/2, bs, 2 D]``.
-
-    MLA models (DeepSeek) pool the LATENTS instead of per-head K/V: one
-    row per slot, ``c_kv [kv_lora_rank] ++ k_pe [qk_rope_head_dim]`` (the
-    shared post-rope key) ++ zeros up to a lane multiple
-    (:func:`latent_row_width`; reference ``ragged/kv_cache.py`` + the v2
-    engine's DeepSeek containers). That small row (kvr+dr vs 2·K·D) is
-    where paged KV pays off, and one row a position is what lets the
-    kernel read each position once."""
+    slot 0 is the pad rows' trash, as block 0 is. ``max_run``: the longest
+    run of rows one sequence can have in a tick (:func:`ring_blocks`)."""
     dt = dtype or cfg.compute_dtype
-    L = cfg.num_layers
-    if cfg.standard_blocks:
-        # ordinary grouped-query blocks ``[bs, K, D]``: a block range for
-        # EVERY full layer, a ring per sequence slot for every window layer
-        # (``[layers, slots + 1, RB, bs, K, D]``: slots and ring length are
-        # read off the array), nothing for a kind the stack lacks
-        if state_slots < 1:
-            raise ValueError("window and full layers in one stack keep "
-                             "rings (and conv layers their state) per "
-                             "sequence: state_slots >= 1 "
-                             f"(got {state_slots})")
-        pack = kv_lane_pack(cfg)
-        kinds, block = cfg.layer_kinds, (block_size, cfg.kv_heads // pack,
-                                         pack * cfg.head_dim)
-        pool = {}
-        if "full" in kinds:
-            full = (kinds.count("full"), n_blocks) + block
-            pool.update(k=jnp.zeros(full, dt), v=jnp.zeros(full, dt))
-        if "window" in kinds:
-            ring = (kinds.count("window"), state_slots + 1,
-                    ring_blocks(cfg, block_size, max_run)) + block
-            pool.update(wk=jnp.zeros(ring, dt), wv=jnp.zeros(ring, dt))
-        if "latent" in kinds:
-            pool["latent"] = jnp.zeros(
-                (kinds.count("latent"), n_blocks, block_size,
-                 latent_row_width(cfg)), dt)
-        if "conv" in kinds:
-            # the short convolution's last inputs
-            pool["conv"] = _conv_store(
-                kinds.count("conv"), state_slots,
-                (cfg.conv_taps - 1, cfg.hidden_size), dt)
-        if "kda" in kinds:
-            rule, conv = HY.kda_state_shapes(cfg)
-            rows = (kinds.count("kda"), state_slots + 1)
-            pool["kda"] = jnp.zeros(rows + rule, jnp.float32)
-            pool["kda_conv"] = _conv_store(kinds.count("kda"), state_slots,
-                                           conv, dt)
-        return pool
-    if cfg.layer_kinds:
-        kinds = cfg.layer_kinds
-        if kinds.count("full") != 1 or state_slots < 1:
-            raise ValueError(
-                "a stack of layer_kinds has one `full` layer (the owner of "
-                f"the block pool; got {kinds.count('full')}) and needs "
-                f"state_slots >= 1 (got {state_slots})")
-        # a block is [K/2, bs, 2 D]: heads first (10 paired heads cannot be
-        # the second-minor dim of a block the kernel's copies slice)
-        head = (cfg.kv_heads // HY.PAIR, block_size, HY.PAIR * cfg.head_dim)
-        ring = (kinds.count("window"), (state_slots + 1)
-                * ring_blocks(cfg, block_size, max_run)) + head
-        state = (kinds.count("mamba"), state_slots + 1)
-        return {"k": jnp.zeros((1, n_blocks) + head, dt),
-                "v": jnp.zeros((1, n_blocks) + head, dt),
-                "wk": jnp.zeros(ring, dt), "wv": jnp.zeros(ring, dt),
-                "conv": _conv_store(kinds.count("mamba"), state_slots,
-                                    (cfg.ssm_conv - 1, cfg.ssm_inner), dt),
-                "ssm": jnp.zeros(state + (cfg.ssm_state, cfg.ssm_inner),
-                                 jnp.float32)}
-    if cfg.mla:
-        return {"latent": jnp.zeros((L, n_blocks, block_size,
-                                     latent_row_width(cfg)), dt)}
-    shape = (L, n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    if cfg.layer_kinds and state_slots < 1:
+        raise ValueError("layers of more than one kind in one stack keep "
+                         "rings and state per sequence: state_slots >= 1 "
+                         f"(got {state_slots})")
+    pool = {}
+    for layers, s in pool_stores(cfg):
+        unit = s.unit(block_size)
+        if s.cls == CONV:
+            pool[s.name] = _conv_store(layers, state_slots, unit,
+                                       s.dtype or dt)
+            continue
+        lead = {BLOCKS: (n_blocks,), SLOT: (state_slots + 1,), RING: (
+            state_slots + 1, ring_blocks(cfg, block_size, max_run))}[s.cls]
+        pool[s.name] = jnp.zeros(
+            (layers,) + ((math.prod(lead),) if s.flat else lead) + unit,
+            s.dtype or dt)
+    return pool
+
+
+def store_bytes(cfg: T.TransformerConfig, pool: Dict[str, Any]
+                ) -> List[Tuple[Store, int]]:
+    """(store, its bytes) of every array of ``pool`` (arrays, or their
+    shapes): what is no ``BLOCKS`` is a sequence slot's, whatever its
+    sequence's length."""
+    return [(s, math.prod(pool[s.name].shape) * pool[s.name].dtype.itemsize)
+            for _, s in pool_stores(cfg)]
+
+
+def _slots(cfg: T.TransformerConfig, pool: Dict[str, jax.Array]) -> int:
+    """``slots + 1`` of ``pool``, read off a store by its class: a SLOT
+    store's or a RING's rows a layer, else a CONV store's over its layers'
+    inputs; 0 for a pool of blocks alone."""
+    stores = pool_stores(cfg)
+    for _, s in stores:
+        if s.cls == SLOT or (s.cls == RING and not s.flat):
+            return pool[s.name].shape[1]
+    return next((pool[s.name].shape[0] // (layers * s.unit(0)[0])
+                 for layers, s in stores if s.cls == CONV), 0)
 
 
 def _conv_store(layers: int, state_slots: int, kept: tuple, dtype
@@ -209,10 +359,6 @@ def _conv_store(layers: int, state_slots: int, kept: tuple, dtype
     inputs, channels = kept
     return jnp.zeros((layers * inputs * (state_slots + 1), channels), dtype)
 
-
-#: the stores of :func:`_conv_store`: rows already, they ride the layer
-#: scans as they are
-_CONV_STORES = ("conv", "kda_conv")
 
 
 def _conv_rows(S1: int, slot: jax.Array, closes: jax.Array):
@@ -357,21 +503,8 @@ def paged_mla_attention_reference(q: jax.Array, pool: jax.Array,
         mla_softmax_scale(cfg)))
 
 
-def span_attention_reference(q: jax.Array, kpool: jax.Array,
-                             vpool: jax.Array, tables: jax.Array,
-                             lengths: jax.Array, window: Optional[int],
-                             row_table: jax.Array,
-                             scale: Optional[float] = None) -> jax.Array:
-    """:func:`paged_attention_reference` given one table a sequence slot
-    and each row's slot, as the kernel of a stack of window and full
-    layers is (``paged_attention(row_table=)``)."""
-    return paged_attention_reference(q, kpool, vpool, tables[row_table],
-                                     lengths, window=window, scale=scale)
-
-
 #: what ``forward_paged(attention_fn=)`` reads as "no kernel"
-_REFERENCES = (None, paged_attention_reference, latent_attention_reference,
-               span_attention_reference)
+_REFERENCES = (None, paged_attention_reference, latent_attention_reference)
 
 
 def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
@@ -383,65 +516,22 @@ def tick_attention(cfg: T.TransformerConfig, use_kernel: bool
     (correct, rectangular-gather cost); a latent pool takes the kernel's
     latent instantiation, which is the kernel with one KV head.
 
-    Dense pools: ``fn(q, kpool, vpool, tables, lengths[, alibi=])``;
-    the latent pool: ``fn(q_row, pool, tables, lengths, kvr, scale)``;
-    a stack of ``layer_kinds``: ``fn(q, kpool, vpool, tables, lengths,
-    scale=, window=, heads_first=)`` over paired heads
-    (``hybrid.paired_queries``), the dense kernel under the names ``window_paged_attention`` (a ring, ``window``
-    positions) and ``shared_paged_attention`` (``window`` None: the one
-    block pool, read by the ``full`` layer and every ``cross`` layer);
-    ``window`` and ``full`` layers of the standard block
-    (``cfg.standard_blocks``): ``fn(q, kpool, vpool, tables, lengths,
-    window=, row_table=, scale=)`` with one table a sequence slot and each
-    row's
-    slot, the dense kernel under the names ``swa_attention`` (a
-    ring) and ``global_attention`` (a full layer's own block range)."""
-    if cfg.standard_blocks and not cfg.mla:
-        if not use_kernel:
-            return span_attention_reference, 0
-        from deepspeed_tpu.ops.pallas.paged_attention import (
-            paged_attention, tile_rows)
-
-        def attend(q, kpool, vpool, tables, lengths, window, row_table,
-                   scale=None):
-            # a chunk of the token budget against thousands of positions
-            # is MXU-bound, unlike the homogeneous cells' shapes: the
-            # products take the operands in the model's own type (bfloat16
-            # as served, as the latent instantiation's) and accumulate in
-            # float32
-            return paged_attention(
-                q, kpool, vpool, tables, lengths, window=window,
-                mxu_dtype=q.dtype, row_table=row_table, scale=scale,
-                name="global_attention" if window is None
-                else "swa_attention")
-
-        return attend, tile_rows(cfg.num_heads,
-                                 kv_lane_pack(cfg) * cfg.head_dim)
-    if cfg.layer_kinds and not cfg.standard_blocks:
-        if not use_kernel:
-            return paged_attention_reference, 0
-        from deepspeed_tpu.ops.pallas.paged_attention import (
-            paged_attention, tile_rows)
-
-        def attend(q, kpool, vpool, tables, lengths, scale, window,
-                   heads_first):
-            return paged_attention(
-                q, kpool, vpool, tables, lengths, scale=scale, window=window,
-                heads_first=heads_first,
-                name="shared_paged_attention" if window is None
-                else "window_paged_attention")
-
-        return attend, tile_rows(cfg.num_heads, HY.PAIR * cfg.head_dim)
-    if not use_kernel or cfg.pos_emb == "alibi":
-        return (latent_attention_reference if cfg.mla
+    Pools of keys and values: ``fn(q, kpool, vpool, tables, lengths,
+    ...)``; the latent pool: ``fn(q_row, pool, tables, lengths, kvr,
+    scale)``. What a kind's call tells the function beside its operands
+    (the call's name, a window, the block's layout, the products' type, a
+    table a row or a slot) is its entry's (:class:`Attend`)."""
+    call = next((kind.attend for kind in cache_kinds(cfg).values()
+                 if kind.attend is not None), None)
+    latent = call is not None and call.stores == ("latent",)
+    if call is None or not use_kernel or cfg.pos_emb == "alibi":
+        return (latent_attention_reference if latent
                 else paged_attention_reference), 0
     from deepspeed_tpu.ops.pallas.paged_attention import (
         latent_paged_attention, paged_attention, tile_rows)
 
-    if cfg.mla:
-        return latent_paged_attention, tile_rows(cfg.num_heads,
-                                                 cfg.kv_lora_rank)
-    return paged_attention, tile_rows(cfg.num_heads, cfg.head_dim)
+    return (latent_paged_attention if latent else paged_attention), \
+        tile_rows(cfg.num_heads, call.width)
 
 
 def tick_walks(cfg: T.TransformerConfig, pool: Dict[str, jax.Array]
@@ -450,35 +540,26 @@ def tick_walks(cfg: T.TransformerConfig, pool: Dict[str, jax.Array]
     :func:`tick_attention`'s kernel makes in a tick of ``cfg`` over
     ``pool``: what ``ops.pallas.paged_attention.count_steps`` needs, beside
     a tick's lengths, to say how many fetch steps the tick walks. The step
-    is the kernel's own rule of the operands' shapes (``_geometry``)."""
+    is the kernel's own rule of the operands' shapes (``_geometry``);
+    kinds that make the same call over the same stores are one walk."""
     from deepspeed_tpu.ops.pallas.paged_attention import _geometry
 
-    def step(names, heads_first=False):
+    units = {s.name: len(s.unit(0)) for _, s in pool_stores(cfg)}
+    walks: Dict[Attend, int] = {}
+    for kind in cache_kinds(cfg).values():
+        if kind.attend is not None:
+            walks[kind.attend] = walks.get(kind.attend, 0) + kind.layers
+    out = []
+    for call, layers in walks.items():
         # a call sees one block of each pool and the queries' heads
         blocks = [jax.ShapeDtypeStruct(
-            (1,) + pool[n].shape[-2 if cfg.mla else -3:], pool[n].dtype)
-            for n in names]
-        width = blocks[0].shape[-1]
-        q = jax.ShapeDtypeStruct((1, cfg.num_heads, width), blocks[0].dtype)
-        _, bs, _, P = _geometry(
-            q, blocks, cfg.kv_lora_rank if cfg.mla else width, heads_first)
-        return bs * P
-
-    kinds = cfg.layer_kinds or ()
-    if cfg.mla:
-        return [(kinds.count("latent") if kinds else cfg.num_layers, None,
-                 step(("latent",)))]
-    if cfg.standard_blocks:
-        return [(kinds.count(kind), window, step(names))
-                for kind, window, names in (
-                    ("window", cfg.attn_window, ("wk", "wv")),
-                    ("full", None, ("k", "v"))) if kind in kinds]
-    if kinds:
-        return [(kinds.count("window"), cfg.attn_window,
-                 step(("wk", "wv"), True)),
-                (kinds.count("full") + kinds.count("cross"), None,
-                 step(("k", "v"), True))]
-    return [(cfg.num_layers, None, step(("k", "v")))]
+            (1,) + pool[n].shape[-units[n]:], pool[n].dtype)
+            for n in call.stores]
+        q = jax.ShapeDtypeStruct(
+            (1, cfg.num_heads, blocks[0].shape[-1]), blocks[0].dtype)
+        _, bs, _, P = _geometry(q, blocks, call.width, call.heads_first)
+        out.append((layers, call.window, bs * P))
+    return out
 
 
 _EXPERT_LEAVES = ("w_up", "w_down", "w_gate")
@@ -509,22 +590,68 @@ def _tick_experts(h: jax.Array, lp: Dict[str, jax.Array],
         route_norm_eps=cfg.moe_route_norm_eps)
 
 
-class _Rows(NamedTuple):
+class _Tick(NamedTuple):
     """A tick's rows as the skeleton derives them once for every layer."""
     positions: jax.Array    # [T]
     tables: jax.Array       # [T, MB] blocks within one layer's range
     block_idx: jax.Array    # [T] the block row t writes into
     offsets: jax.Array      # [T] its slot in that block
     lengths: jax.Array      # [T] cache slots row t attends to (= pos+1)
+    valid: jax.Array        # [T] bool: no pad row (its table is all trash)
+    bs: int                 # positions of a block
+    # a row's sequence slot (its table's first block) and one table a slot;
+    # a pool without slots: the row's own index, the rows' tables
+    slot: jax.Array         # [T]
+    by_slot: jax.Array      # [S1 or T, MB]
+    S1: int                 # slots + 1; 0 without
+    # where sequences keep state: their rows' runs, and the read and write
+    # of a store of convolution inputs (:func:`_conv_rows`)
+    runs: Optional[HY.Runs]
+    conv: Optional[Tuple[Callable, Callable]]
+    rope: Dict[str, Tuple[jax.Array, jax.Array]]   # kind -> (cos, sin)
+    attend: Callable        # :func:`tick_attention`'s choice
+    kernels: bool           # kernels are wanted
 
 
-# The two cache kinds. Each takes (cfg, pool as stored, rows, the function
-# ``tick_attention`` chose) and returns a layer's attention as
-# ``layer(h, lp, flat, base) -> (attn [T, N*dv], flat)``: from the normed
-# rows ``h [T, H]``, the layer's parameters and the flat pool carry
-# (``forward_paged``), project, write the tick's rows into the layer's
-# block range (which starts at block ``base``), attend, and return the
-# attention output before ``wo`` with the new carry.
+def _tick_of(cfg: T.TransformerConfig, kinds: Dict[str, CacheKind],
+             pool: Dict[str, jax.Array], positions: jax.Array,
+             tables: jax.Array, kernels: bool) -> _Tick:
+    Tn, MB = tables.shape
+    blocks = next(s for _, s in pool_stores(cfg) if s.cls in (BLOCKS, RING))
+    bs = pool[blocks.name].shape[blocks.positions]
+    block_idx = jnp.take_along_axis(
+        tables, (positions // bs)[:, None], axis=1)[:, 0]
+    # the rotary tables: a position lies within the tables' reach where
+    # the call takes a table a slot, else the pool's
+    rope = {kind: T.rope_table(
+        bs * (MB if e.attend.by_slot else pool[e.attend.stores[0]].shape[1]),
+        e.attend.rope, cfg.rope_theta, cfg.rope_scaling_dict)
+        for kind, e in kinds.items() if e.attend and e.attend.rope}
+    S1 = _slots(cfg, pool)
+    slot, by_slot = jnp.arange(Tn, dtype=jnp.int32), tables
+    runs = conv = None
+    if S1:
+        slot = tables[:, 0]
+        by_slot = jnp.zeros((S1, MB), jnp.int32).at[slot].set(tables)
+    if any(s.cls in (SLOT, CONV) for _, s in pool_stores(cfg)):
+        runs = HY.runs_of(slot, positions)
+        conv = _conv_rows(S1, slot, runs.last & (slot > 0))
+    attend, tile = tick_attention(cfg, kernels)
+    return _Tick(positions, tables, block_idx, positions % bs, positions + 1,
+                 tables[:, 0] > 0, bs, slot, by_slot, S1, runs, conv, rope,
+                 attend, tile > 0)
+
+
+# A kind's mixer is built once a tick by its entry's ``mixer(cfg, tick: _Tick,
+# pool: as stored, entry: CacheKind, kind: its name)`` and is ``mixer(h, lp,
+# flat, li, nth, acts) -> (mixed [T, .] before wo, flat, acts)``. From the
+# normed rows ``h [T, H]``, the layer's parameters and the flat pool carry
+# (``forward_hidden``): project, write the tick's rows into the layer's
+# range of its kind's stores, mix, and return the mixer's output with the
+# new carry. ``li``: the layer's index in the stack; ``nth``: its index
+# among its KIND's layers from the stack's first layer on (which block
+# range, which ring, which state rows); ``acts``: what the tick's layers
+# hand on (the state-space family's ``memory``): an activation, no cache.
 
 def _project_qkv(cfg: T.TransformerConfig, h: jax.Array,
                  lp: Dict[str, jax.Array], positions: jax.Array,
@@ -552,295 +679,257 @@ def _project_qkv(cfg: T.TransformerConfig, h: jax.Array,
     return q, k, v
 
 
-def _dense_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
-                 rows: _Rows, attend: Callable) -> Callable:
-    """Per-head K/V pools ``{"k", "v"}`` [L, NB, bs, K, D]: q/k/v
-    projections with their biases, ``qk_norm``, rotary at the rows'
-    positions; ALiBi models (BLOOM/Falcon) bias the paged scores by head
-    slope × relative position."""
+def _kv_cache(tick: _Tick, pool: Dict[str, jax.Array], entry: CacheKind
+              ) -> Tuple[Callable, Callable]:
+    """The write and the walk of a kind that attends over keys and values,
+    whatever the block's layout: ``write(flat, nth, k, v) -> (flat, the
+    layer's tables)`` and ``attend(q, flat, tables, scale, **bias)``.
+
+    A ``BLOCKS`` layer writes and walks its own block range through the
+    rows' tables. A ``RING`` layer's cache is a ring of its sequence's slot:
+    a tick's rows are written before any attends, so a row walks the ring
+    through a table of its own (or its slot's), column ``c`` naming the
+    slot's block ``c % RB``, which holds positions ``c*bs ..`` if any of
+    them is inside the row's window."""
+    call = entry.attend
+    MB = tick.tables.shape[1]
+    first = pool[call.stores[0]]
+    if entry.stores and entry.stores[0].cls == RING:
+        RB = math.prod(first.shape[1:first.ndim - 3]) // tick.S1
+        rows_a_layer = tick.S1 * RB
+        owners = jnp.arange(tick.S1, dtype=jnp.int32) if call.by_slot \
+            else tick.slot
+        tables = (owners * RB)[:, None] + (
+            jnp.arange(MB, dtype=jnp.int32) % RB)[None, :]
+        block = tick.slot * RB + (tick.positions // tick.bs) % RB
+    else:
+        rows_a_layer = first.shape[1]
+        tables = tick.by_slot if call.by_slot else tick.tables
+        block = tick.block_idx
+
+    def write(flat, nth, k, v):
+        base = nth * rows_a_layer
+        at, new = base + block, dict(flat)
+        # blocked KV write (reference ragged_ops KV-copy kernels): token t
+        # -> pool[base + block[t], offsets[t]]. Pad tokens hit the layer's
+        # trash block (block 0 of its range: never allocated). Heads first:
+        # (block, head, slot) index every written row, so that the
+        # scatter's one window dim is the array's minor one
+        index = (at[:, None], jnp.arange(k.shape[1])[None, :],
+                 tick.offsets[:, None]) if call.heads_first \
+            else (at, tick.offsets)
+        for name, x in zip(call.stores, (k, v)):
+            new[name] = flat[name].at[index].set(
+                x.astype(flat[name].dtype), mode="drop")
+        return new, tables + base
+
+    def attend(q, flat, tables, scale=None, **bias):
+        k, v = (flat[name] for name in call.stores)
+        with contextlib.nullcontext() if call.scope is None \
+                else jax.named_scope(call.scope):
+            if tick.kernels:
+                return tick.attend(
+                    q, k, v, tables, tick.lengths, scale=scale,
+                    window=call.window, heads_first=call.heads_first,
+                    name=call.name,
+                    mxu_dtype=q.dtype if call.own_dtype else jnp.float32,
+                    row_table=tick.slot if call.by_slot else None)
+            return tick.attend(
+                q, k, v, tables[tick.slot] if call.by_slot else tables,
+                tick.lengths, scale=scale, window=call.window,
+                heads_first=call.heads_first, **bias)
+
+    return write, attend
+
+
+def _grouped_mixer(cfg, tick, pool, entry, kind):
+    """Grouped-query attention over per-head K/V (``full``: a block range
+    a layer, ``window``: a ring): q/k/v projections with their biases,
+    ``qk_norm``, rotary at the rows' positions where the kind rotates;
+    ALiBi models (BLOOM/Falcon) bias the paged scores by head slope x
+    relative position; then the elementwise output gate where the model
+    has one."""
     dt = cfg.compute_dtype
-    Tn = rows.positions.shape[0]
-    NB, bs = pool["k"].shape[1:3]
-    cos_t = sin_t = None
-    if cfg.pos_emb == "rope":
-        cos_t, sin_t = T.rope_table(NB * bs, cfg.rope_dim, cfg.rope_theta,
-                                    cfg.rope_scaling_dict)
+    Tn = tick.positions.shape[0]
+    call = entry.attend
+    write, attend = _kv_cache(tick, pool, entry)
     bias = {}
     if cfg.pos_emb == "alibi":
         bias["alibi"] = T.alibi_slopes(cfg.num_heads) * cfg.alibi_bias_scale
 
-    def layer(h, lp, flat, base):
-        q, k, v = _project_qkv(cfg, h, lp, rows.positions,
-                               (cos_t, sin_t) if cfg.pos_emb == "rope"
-                               else None)
-        # blocked KV write (reference ragged_ops KV-copy kernels): token t →
-        # pool[base + block_idx[t], offsets[t]]. Pad tokens hit this layer's
-        # trash block (block 0 of its range — never allocated).
-        pk, pv = flat["k"], flat["v"]
-        pk = pk.at[base + rows.block_idx, rows.offsets].set(
-            k.astype(pk.dtype), mode="drop")
-        pv = pv.at[base + rows.block_idx, rows.offsets].set(
-            v.astype(pv.dtype), mode="drop")
-        attn = attend(q, pk, pv, rows.tables + base, rows.lengths, **bias)
-        return (attn.reshape(Tn, cfg.num_heads * cfg.head_dim),
-                {"k": pk, "v": pv})
+    def mixer(h, lp, flat, li, nth, acts):
+        q, k, v = _project_qkv(cfg, h, lp, tick.positions,
+                               tick.rope.get(kind))
+        scale = own = None
+        if call.pack > 1:
+            # heads of 64 lie two to a pool row (``kv_lane_pack``); the
+            # scores' factor stays the unpacked head's
+            k, v = (x.reshape(Tn, cfg.kv_heads // call.pack, -1)
+                    for x in (k, v))
+            q, own = _lane_packed(q, cfg.kv_heads)
+            scale = cfg.head_dim ** -0.5
+        flat, tables = write(flat, nth, k, v)
+        attn = attend(q, flat, tables, scale, **bias)
+        if own is not None:
+            attn = own(attn)
+        attn = attn.reshape(Tn, cfg.num_heads * cfg.head_dim)
+        if cfg.attn_gate:
+            attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
+        return attn, flat, acts
 
-    return layer
+    return mixer
 
 
-def _latent_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
-                  rows: _Rows, attend: Callable,
-                  by_slot: Optional[Tuple[jax.Array, jax.Array]] = None
-                  ) -> Callable:
+def _differential_mixer(cfg, tick, pool, entry, kind):
+    """Differential attention as one grouped-query attention over paired
+    heads (``hybrid.paired_queries``): a ``window`` layer over its ring, the
+    ``full`` layer over the block pool, a ``cross`` layer with queries
+    alone over what the ``full`` layer wrote."""
+    dt = cfg.compute_dtype
+    Tn = tick.positions.shape[0]
+    N, K, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    write, attend = _kv_cache(tick, pool, entry)
+    scale = D ** -0.5                       # of the unpaired heads
+
+    def heads(h, w, n):
+        return (h @ w.astype(dt)).reshape(Tn, n, D)
+
+    def mixer(h, lp, flat, li, nth, acts):
+        q = HY.paired_queries(heads(h, lp["wq"], N))
+        tables = tick.tables
+        if entry.stores:
+            flat, tables = write(flat, nth, *(
+                HY.paired_cache(heads(h, lp[w], K)) for w in ("wk", "wv")))
+        o = attend(q, flat, tables, scale)
+        return (HY.differential_merge(o, lp, li, cfg.norm_eps).astype(dt),
+                flat, acts)
+
+    return mixer
+
+
+def _latent_mixer(cfg, tick, pool, entry, kind):
     """The MLA (DeepSeek) pool ``{"latent"}`` [L, NB, bs, W]: a row's
     latent (``c_kv ++ k_pe``, padded to the lanes) is what is written,
     and attention is weight-absorbed (:func:`_absorbed`; same math as the
-    v1 engine's latent-cache decode). ``by_slot`` (one table a sequence
-    slot, each row's slot), where the stack has slots: the kernel is then
-    given those (:func:`_span_cache` says why) and not a table a row."""
-    tables, which = (rows.tables, {}) if by_slot is None \
-        else (by_slot[0], {"row_table": by_slot[1]})
-    lat = pool["latent"]
-    Tn = rows.positions.shape[0]
-    NB, bs, W = lat.shape[1:]
-    if cfg.pos_emb == "rope":
-        cos_t, sin_t = T.rope_table(NB * bs, cfg.qk_rope_head_dim,
-                                    cfg.rope_theta, cfg.rope_scaling_dict)
+    v1 engine's latent-cache decode)."""
+    call = entry.attend
+    Tn = tick.positions.shape[0]
+    NB, _, W = pool["latent"].shape[1:]
+    tables, which = (tick.by_slot, {"row_table": tick.slot}) \
+        if call.by_slot else (tick.tables, {})
 
     def rope_fn(v):                                   # v [T, 1, n, dr]
-        if cfg.pos_emb != "rope":
+        if kind not in tick.rope:
             return v           # the model rotates nothing (``mla_use_nope``)
-        return T.apply_rope_at(v, cos_t, sin_t, rows.positions[:, None])
+        return T.apply_rope_at(v, *tick.rope[kind], tick.positions[:, None])
 
     row_pad = jnp.zeros(
-        (Tn, W - cfg.kv_lora_rank - cfg.qk_rope_head_dim), lat.dtype)
+        (Tn, W - cfg.kv_lora_rank - cfg.qk_rope_head_dim),
+        pool["latent"].dtype)
 
-    def layer(h, lp, flat, base):
-        plat = flat["latent"]
+    def mixer(h, lp, flat, li, nth, acts):
+        plat, base = flat["latent"], nth * NB
         hB = h[:, None, :]                            # [T, 1, H]
         q = T._mla_q(hB, lp, cfg, rope_fn)[:, 0]      # [T, N, dn+dr]
         c_kv, k_pe = T._mla_latents(hB, lp, cfg, rope_fn)
         row = jnp.concatenate(
             [c_kv[:, 0].astype(plat.dtype),
              k_pe[:, 0, 0].astype(plat.dtype), row_pad], axis=-1)
-        plat = plat.at[base + rows.block_idx, rows.offsets].set(
+        plat = plat.at[base + tick.block_idx, tick.offsets].set(
             row, mode="drop")
-        attn = _absorbed(q, lp["wkv_b"], cfg, lambda q_row: attend(
-            q_row, plat, tables + base, rows.lengths,
+        attn = _absorbed(q, lp["wkv_b"], cfg, lambda q_row: tick.attend(
+            q_row, plat, tables + base, tick.lengths,
             cfg.kv_lora_rank, mla_softmax_scale(cfg), **which))
         return (attn.reshape(Tn, cfg.num_heads * cfg.v_head_dim),
-                {"latent": plat})
+                {**flat, "latent": plat}, acts)
 
-    return layer
-
-
-def pool_block(pool: Dict[str, jax.Array]) -> int:
-    """Positions of a block of a standard-block pool: ``[bs, K, D]``, or a
-    latent block ``[bs, W]``."""
-    if "latent" in pool:
-        return pool["latent"].shape[-2]
-    return pool["k" if "k" in pool else "wk"].shape[-3]
+    return mixer
 
 
-def _span_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
-                rows: _Rows, attend: Callable) -> Callable:
-    """The caches of ``window``, ``full``, ``latent``, ``conv`` and ``kda``
-    layers of the standard block (``cfg.standard_blocks``;
-    ``init_paged_kv``: block ranges ``k, v`` or ``latent``, rings ``wk,
-    wv``, state ``conv`` or ``kda, kda_conv``): a layer's
-    mixer as ``layer(kind, h, lp, flat, nth) -> (mixed [T, .] before
-    ``wo``, flat)``, ``nth`` the layer's index among the layers of its KIND
-    (which ring, which block range, which state rows). A ``conv`` layer
-    reads its rows' runs' state from its sequence slots' rows and writes
-    the state after each run's last row (``hybrid.short_conv``); a ``kda``
-    layer likewise, its convolutions' inputs so and the rule's matrix
-    inside ``hybrid.delta_rule`` (a run's is read at its first row and
-    written after its last, in place); a ``latent`` layer is
-    :func:`_latent_cache`'s over its own range of latent blocks.
-    Projections as :func:`_dense_cache`'s (``qk_norm``; rotary at
-    the rows' positions, on ``full`` layers only where the config says
-    so), then the elementwise output gate where the model has one.
+def _conv_mixer(cfg, tick, pool, entry, kind):
+    """The gated short convolution: a layer reads its rows' runs' state
+    from its sequence slots' rows and writes the state after each run's
+    last row (``hybrid.short_conv``)."""
+    read, write = tick.conv
 
-    A ``full`` layer writes and walks its own block range through the
-    rows' tables. A ``window`` layer's cache is a ring of its sequence's
-    slot (the table's first block): a tick's rows are written before any
-    attends, so a row walks the ring through a table of its own, column
-    ``c`` naming the slot's block ``c % RB``, which holds positions
-    ``c*bs ..`` if any of them is inside the row's window."""
-    dt = cfg.compute_dtype
-    Tn, MB = rows.tables.shape
-    cos_t = sin_t = None
-    if cfg.pos_emb == "rope":
-        # a position lies within the tables' reach
-        cos_t, sin_t = T.rope_table(MB * pool_block(pool), cfg.rope_dim,
-                                    cfg.rope_theta, cfg.rope_scaling_dict)
-    # the kernel is given one table a sequence SLOT and each row's slot (a
-    # budget of thousands of rows, each with a table of hundreds of blocks
-    # of its own, has no room in scalar memory); a stack without window
-    # layers has no slots: a table a row
-    slot, by_slot = jnp.arange(Tn, dtype=jnp.int32), rows.tables
-    per_slot = [n for n in ("wk", "conv", "kda") if n in pool]
-    if per_slot:
-        # slots + 1: a convolution store's rows are (layer, input, slot)
-        S1 = pool["conv"].shape[0] // (cfg.layer_kinds.count("conv") * (
-            cfg.conv_taps - 1)) if per_slot[0] == "conv" \
-            else pool[per_slot[0]].shape[1]
-        slot = rows.tables[:, 0]
-        by_slot = jnp.zeros((S1, MB), jnp.int32).at[slot].set(rows.tables)
-    if "wk" in pool:
-        RB, bs = pool["wk"].shape[2:4]
-        ring_by_slot = (jnp.arange(S1, dtype=jnp.int32) * RB)[:, None] + (
-            jnp.arange(MB, dtype=jnp.int32) % RB)[None, :]
-        ring_block = slot * RB + (rows.positions // bs) % RB
-    NB = pool["k"].shape[1] if "k" in pool else 0
-    pack = kv_lane_pack(cfg)
-    if "conv" in pool or "kda" in pool:
-        runs = HY.runs_of(slot, rows.positions)
-        read_conv, write_conv = _conv_rows(S1, slot,
-                                           runs.last & (slot > 0))
-    if "latent" in pool:
-        latent = _latent_cache(cfg, pool, rows, attend,
-                               (by_slot, slot) if per_slot else None)
+    def mixer(h, lp, flat, li, nth, acts):
+        mixed, conv = HY.short_conv(
+            h, lp, tick.runs, read(flat["conv"], nth, cfg.conv_taps - 1))
+        return mixed, {**flat, "conv": write(flat["conv"], nth, conv)}, acts
 
-    def layer(kind, h, lp, flat, nth):
-        if kind == "latent":
-            attn, new = latent(h, lp, flat, nth * pool["latent"].shape[1])
-            return attn, {**flat, **new}
-        if kind == "kda":
-            inputs, conv = HY.kda_inputs(
-                h, lp, cfg, runs,
-                read_conv(flat["kda_conv"], nth, cfg.kda_conv - 1))
-            # a pad row's sequence is none: row 0 of the store
-            o, state = HY.delta_rule(
-                *inputs, runs, flat["kda"],
-                jnp.where(slot > 0, nth * S1 + slot, 0),
-                use_kernel=attend not in _REFERENCES)
-            return HY.kda_output(o, h, lp, cfg), {
-                **flat, "kda": state,
-                "kda_conv": write_conv(flat["kda_conv"], nth, conv)}
-        if kind == "conv":
-            mixed, conv = HY.short_conv(
-                h, lp, runs, read_conv(flat["conv"], nth, cfg.conv_taps - 1))
-            return mixed, {**flat,
-                           "conv": write_conv(flat["conv"], nth, conv)}
-        q, k, v = _project_qkv(
-            cfg, h, lp, rows.positions,
-            (cos_t, sin_t) if cfg.pos_emb == "rope" and (
-                kind == "window" or cfg.full_layers_rope) else None)
-        if kind == "window":
-            names, base = ("wk", "wv"), nth * (S1 * RB)
-            at, tables, window = base + ring_block, ring_by_slot + base, \
-                cfg.attn_window
+    return mixer
+
+
+def _kda_mixer(cfg, tick, pool, entry, kind):
+    """Kimi Delta Attention: the convolutions' inputs as a ``conv`` layer's
+    and the rule's matrix inside ``hybrid.delta_rule`` (a run's is read at
+    its first row and written after its last, in place)."""
+    read, write = tick.conv
+    slot, S1 = tick.slot, tick.S1
+
+    def mixer(h, lp, flat, li, nth, acts):
+        inputs, conv = HY.kda_inputs(
+            h, lp, cfg, tick.runs,
+            read(flat["kda_conv"], nth, cfg.kda_conv - 1))
+        # a pad row's sequence is none: row 0 of the store
+        o, state = HY.delta_rule(
+            *inputs, tick.runs, flat["kda"],
+            jnp.where(slot > 0, nth * S1 + slot, 0),
+            use_kernel=tick.kernels)
+        return HY.kda_output(o, h, lp, cfg), {
+            **flat, "kda": state,
+            "kda_conv": write(flat["kda_conv"], nth, conv)}, acts
+
+    return mixer
+
+
+def _kda_span(decode_rows: int, chunk_starts: List[int], rows: int,
+              bucket: int) -> Dict[str, int]:
+    """The rule's two forms by the program's own rule
+    (``hybrid.delta_rule``): runs of one row, up to the one-row form's
+    count, and the rows of every other run; the chunk form's grid steps by
+    the kernel's own rule (``ops.pallas.kda.count_pieces``): every run but
+    the first ``kda_step_rows`` runs of one row (decode rows lie first, a
+    row each); and the rows that close a run, each of which writes its
+    slot's state in every kda layer."""
+    from deepspeed_tpu.ops.pallas.kda import count_pieces
+
+    runs = [(a, b - a) for a, b in zip(chunk_starts,
+                                       chunk_starts[1:] + [rows])]
+    step = min(decode_rows + sum(n == 1 for _, n in runs), bucket,
+               HY.KDA_STEP_ROWS)
+    took = min(decode_rows, step)
+    left = step - took                  # for the prompts' runs of one
+    chunk_runs = [(r, 1) for r in range(took, decode_rows)]
+    for a, n in runs:
+        if n == 1 and left:
+            left -= 1
         else:
-            names, base = ("k", "v"), nth * NB
-            at, tables, window = base + rows.block_idx, by_slot + base, None
-        new, scale, own = dict(flat), None, None
-        if pack > 1:
-            # heads of 64 lie two to a pool row (``kv_lane_pack``); the
-            # scores' factor stays the unpacked head's
-            k, v = (x.reshape(Tn, cfg.kv_heads // pack, -1) for x in (k, v))
-            q, own = _lane_packed(q, cfg.kv_heads)
-            scale = cfg.head_dim ** -0.5
-        for name, x in zip(names, (k, v)):
-            new[name] = flat[name].at[at, rows.offsets].set(
-                x.astype(flat[name].dtype), mode="drop")
-        # the scope a device trace tells the two kinds' attention by
-        with jax.named_scope("swa" if kind == "window" else "global"):
-            attn = attend(q, new[names[0]], new[names[1]], tables,
-                          rows.lengths, window=window, row_table=slot,
-                          scale=scale)
-        if own is not None:
-            attn = own(attn)
-        attn = attn.reshape(Tn, cfg.num_heads * cfg.head_dim)
-        if cfg.attn_gate:
-            attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
-        return attn, new
-
-    return layer
+            chunk_runs.append((a, n))
+    return dict(kda_step_rows=step, kda_chunk_rows=rows - step,
+                kda_chunk_pieces=count_pieces(chunk_runs),
+                kda_state_rows=decode_rows + len(chunk_starts))
 
 
-def _kinds_cache(cfg: T.TransformerConfig, pool: Dict[str, jax.Array],
-                 rows: _Rows, attend: Callable) -> Callable:
-    """The caches of a stack of ``layer_kinds`` (``init_paged_kv``): the
-    mixer of one layer as ``layer(kind, h, lp, flat, step, index, memory)
-    -> (mixed [T, .] before ``wo``, flat, memory)``. ``step`` counts the
-    stack's PAIRS of layers, which is the index of a pair's ``mamba``
-    layer among the state's layers and of its ``window`` layer among the
-    rings' (every pair up to the ``full`` layer's has one of each);
-    ``index`` is the layer's own. ``memory`` [T, inner] is the last
-    ``mamba`` layer's scan output, which the ``gmu`` layers gate: an
-    activation of the tick, not a cache.
+def _mamba_mixer(cfg, tick, pool, entry, kind):
+    """The selective state-space layer: its scan output is the ``memory``
+    the ``gmu`` layers gate."""
+    read, write = tick.conv
+    slot, S1, runs = tick.slot, tick.S1, tick.runs
 
-    A row's slot is its table's first block. A tick's rows are written
-    before any attends, so a ``window`` row walks its ring through a table
-    of its own: column ``c`` names the slot's block ``c % RB``, which holds
-    positions ``c*bs ..`` if any of them is inside the row's window."""
-    dt = cfg.compute_dtype
-    Tn, MB = rows.tables.shape
-    N, K, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    bs = pool["k"].shape[3]
-    S1 = pool["ssm"].shape[1]
-    ring_rows = pool["wk"].shape[1]
-    RB = ring_rows // S1
-    slot = rows.tables[:, 0]
-    runs = HY.runs_of(slot, rows.positions)
-    read_conv, write_conv = _conv_rows(S1, slot, runs.last & (slot > 0))
-    ring_tables = slot[:, None] * RB + (jnp.arange(MB, dtype=jnp.int32)
-                                        % RB)[None, :]
-    ring_block = slot * RB + (rows.positions // bs) % RB
-    scale = D ** -0.5                       # of the unpaired heads
+    def mixer(h, lp, flat, li, nth, acts):
+        at = nth * S1 + slot
+        out, memory, conv, ssm = HY.mamba(
+            h, lp, cfg, runs, read(flat["conv"], nth, cfg.ssm_conv - 1),
+            flat["ssm"][at])
+        # the state after a run's last row is its sequence's; the
+        # other rows' index lies past the array and is dropped
+        put = jnp.where(runs.last, at, flat["ssm"].shape[0])
+        flat = {**flat, "conv": write(flat["conv"], nth, conv),
+                "ssm": flat["ssm"].at[put].set(ssm, mode="drop")}
+        return out, flat, {**acts, "memory": memory}
 
-    def heads(h, w, n):
-        return (h @ w.astype(dt)).reshape(Tn, n, D)
-
-    def write(flat, names, at, lp, h):
-        new = dict(flat)
-        for name, w in zip(names, ("wk", "wv")):
-            # (block, head, slot) index every written row, so that the
-            # scatter's one window dim is the array's minor one
-            new[name] = flat[name].at[
-                at[:, None], jnp.arange(K // HY.PAIR)[None, :],
-                rows.offsets[:, None]].set(
-                HY.paired_cache(heads(h, lp[w], K)).astype(flat[name].dtype),
-                mode="drop")
-        return new
-
-    def layer(kind, h, lp, flat, step, index, memory):
-        if kind == "mamba":
-            at = step * S1 + slot
-            out, memory, conv, ssm = HY.mamba(
-                h, lp, cfg, runs,
-                read_conv(flat["conv"], step, cfg.ssm_conv - 1),
-                flat["ssm"][at])
-            # the state after a run's last row is its sequence's; the
-            # other rows' index lies past the array and is dropped
-            put = jnp.where(runs.last, at, flat["ssm"].shape[0])
-            flat = {**flat,
-                    "conv": write_conv(flat["conv"], step, conv),
-                    "ssm": flat["ssm"].at[put].set(ssm, mode="drop")}
-            return out, flat, memory
-        if kind == "gmu":
-            return HY.gmu(h, lp, memory), flat, memory
-        q = HY.paired_queries(heads(h, lp["wq"], N))
-        if kind == "window":
-            base = step * ring_rows
-            flat = write(flat, ("wk", "wv"), base + ring_block, lp, h)
-            o = attend(q, flat["wk"], flat["wv"], ring_tables + base,
-                       rows.lengths, scale=scale, window=cfg.attn_window,
-                       heads_first=True)
-        else:
-            if kind == "full":
-                flat = write(flat, ("k", "v"), rows.block_idx, lp, h)
-            o = attend(q, flat["k"], flat["v"], rows.tables, rows.lengths,
-                       scale=scale, window=None, heads_first=True)
-        return (HY.differential_merge(o, lp, index, cfg.norm_eps).astype(dt),
-                flat, memory)
-
-    return layer
-
-
-#: the scope a kind's mixer runs under (``attn`` also holds ``ln1``, ``wo``)
-_KIND_SCOPES = {"mamba": "ssm", "gmu": "gmu", "conv": "conv", "kda": "kda"}
+    return mixer
 
 
 def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
@@ -893,14 +982,13 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
     """:func:`forward_paged` up to the head: (the last hidden state [T, H],
     updated pool, stats).
 
-    One skeleton for every model: embed, the rows' blocks and lengths, one
-    scan per segment of ``cfg.segments`` (leading dense layers, then the
-    stack; the pool's layers in the same order), the residual form, FFN or
-    experts, the head. What a layer's attention projects, writes into the
-    pool and attends to is the cache kind's (:func:`_dense_cache`,
-    :func:`_latent_cache`), picked once from ``cfg.mla``. A segment of
-    ``layer_kinds`` steps a period of layers at a time, each with the mixer
-    of its kind over the cache of its kind (:func:`_kinds_cache`).
+    One skeleton for every model: embed, the rows' blocks and lengths
+    (:class:`_Tick`), one block (``ln1``, the mixer of the layer's kind,
+    ``wo``, the residual form, FFN or experts) stepped a PERIOD of kinds at
+    a time by ``T.scan_periods`` over each segment of ``cfg.segments``
+    (leading dense layers, then the stack; a homogeneous segment is one
+    run of period 1), the head. What a layer's mixer projects, writes into
+    the pool and attends to is its kind's entry of :func:`cache_kinds`.
 
     ``attention_fn`` says whether kernels are wanted: ``None`` or a
     reference means no, anything else yes; which function then runs is
@@ -911,14 +999,8 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
     """
     from deepspeed_tpu.ops.quantization import dequant_params
 
-    attend, _ = tick_attention(cfg, attention_fn not in _REFERENCES)
     dt = cfg.compute_dtype
-    if cfg.standard_blocks:
-        NB, bs = 0, pool_block(pool)
-    else:
-        NB, bs = pool["latent" if cfg.mla else "k"].shape[1:3]
-    if cfg.layer_kinds and not cfg.standard_blocks:
-        bs = pool["k"].shape[3]             # its blocks are [K/2, bs, 2 D]
+    kinds = cache_kinds(cfg)
 
     with jax.named_scope("embed"):
         x = params["tok_emb"].astype(dt)[tokens]             # [T, H]
@@ -929,116 +1011,93 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
         if cfg.emb_norm:
             x = T._norm(x, params["emb_norm"], cfg.norm, cfg.norm_eps)
 
-    rows = _Rows(positions, tables,
-                 block_idx=jnp.take_along_axis(
-                     tables, (positions // bs)[:, None], axis=1)[:, 0],
-                 offsets=positions % bs, lengths=positions + 1)
-    valid = tables[:, 0] > 0     # a pad row's table is all trash block
-    attention = (_span_cache if cfg.standard_blocks else _kinds_cache
-                 if cfg.layer_kinds else _latent_cache
-                 if cfg.mla else _dense_cache)(cfg, pool, rows, attend)
+    tick = _tick_of(cfg, kinds, pool, positions, tables,
+                    attention_fn not in _REFERENCES)
+    mixers = {kind: entry.mixer(cfg, tick, pool, entry, kind)
+              for kind, entry in kinds.items()}
 
-    def make_body(seg: T.TransformerConfig, first: int, stack):
-        def body(carry, lp):
-            x, flat, li = carry
-            lp = dequant_params(lp, dt)   # weight-only quant: per-layer dequant
-            with jax.named_scope("attn"):
-                h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
-                attn, flat = attention(h, lp, flat, li * NB)
-                attn_out = attn @ lp["wo"].astype(dt)
-                if seg.use_bias:
-                    attn_out = attn_out + lp["bo"].astype(dt)
-            # ``mlp`` is a dense FFN's scope; an expert layer's operations
-            # carry ``router`` / ``experts`` / ``shared_experts``
-            with contextlib.nullcontext() if seg.n_experts \
-                    else jax.named_scope("mlp"):
-                # the parallel residual norms the block's input (or shares
-                # ``ln1``'s output), the sequential one what attention left
-                resid = x + attn_out
-                if not seg.parallel_block:
-                    h2 = T._norm(resid, lp["ln2"], seg.norm, seg.norm_eps)
-                elif seg.shared_parallel_norm:
-                    h2 = h
-                else:
-                    h2 = T._norm(x, lp["ln2"], seg.norm, seg.norm_eps)
-                if seg.n_experts:
-                    down, n_rows = _tick_experts(h2, lp, seg, valid, stack,
-                                                 li - first)
-                else:
-                    down, n_rows = T._ffn(h2, lp, seg)[0], None
-                x = resid + down
-            return (x, flat, li + 1), n_rows
+    def block(seg, kind, x, lp, flat, li, nth, acts, experts):
+        """One layer: ``x + wo(Mixer(ln1 x))``, then the FFN or the
+        experts on the sequential or a parallel residual, post-norms
+        where the block has them. ``experts``: (the whole stack's
+        matrices, the layer's index among them)."""
+        lp = dequant_params(lp, dt)       # weight-only quant: per-layer dequant
+        with jax.named_scope(kinds[kind].scope):
+            h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
+            mixed, flat, acts = mixers[kind](h, lp, flat, li, nth, acts)
+            out = mixed @ lp["wo"].astype(dt)
+            if seg.use_bias:
+                out = out + lp["bo"].astype(dt)
+            if seg.post_norms:
+                out = T._norm(out, lp["ln1_post"], seg.norm, seg.norm_eps)
+            resid = x + out
+        # ``mlp`` is a dense FFN's scope; an expert layer's operations
+        # carry ``router`` / ``experts`` / ``shared_experts``
+        with contextlib.nullcontext() if seg.n_experts \
+                else jax.named_scope("mlp"):
+            # the parallel residual norms the block's input (or shares
+            # ``ln1``'s output), the sequential one what the mixer left
+            if not seg.parallel_block:
+                h2 = T._norm(resid, lp["ln2"], seg.norm, seg.norm_eps)
+            elif seg.shared_parallel_norm:
+                h2 = h
+            else:
+                h2 = T._norm(x, lp["ln2"], seg.norm, seg.norm_eps)
+            if seg.n_experts:
+                down, n_rows = _tick_experts(h2, lp, seg, tick.valid,
+                                             *experts)
+            else:
+                down, n_rows = T._ffn(h2, lp, seg)[0], None
+            if seg.post_norms:
+                down = T._norm(down, lp["ln2_post"], seg.norm, seg.norm_eps)
+        return resid + down, flat, acts, n_rows
 
-        return body
+    # the kind of every layer of the stack: ``nth`` counts a kind's layers
+    # from its first
+    whole = sum((stack_kinds(cfg, seg) for _, seg in cfg.segments), ())
 
-    def make_period_body(seg: T.TransformerConfig):
-        """A step of a segment of ``layer_kinds``: its period's layers,
-        each ``x += wo(Mixer(ln1 x)); x += FFN(ln2 x)``."""
-        def body(carry, lps):
-            x, flat, step, memory = carry
-            for i, kind in enumerate(seg.period):
-                lp = dequant_params(lps[kind], dt)
-                with jax.named_scope(_KIND_SCOPES.get(kind, "attn")):
-                    h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
-                    # ``step`` counts pairs from the stack's first layer
-                    mixed, flat, memory = attention(
-                        kind, h, lp, flat, step, 2 * step + i, memory)
-                    x = x + mixed @ lp["wo"].astype(dt)
-                with jax.named_scope("mlp"):
-                    h2 = T._norm(x, lp["ln2"], seg.norm, seg.norm_eps)
-                    x = x + T._ffn(h2, lp, seg)[0]
-            return (x, flat, step + 1, memory), None
+    def body_of(seg, first, taken, stack):
+        """A step of a segment for ``T.scan_periods``: its period's layers,
+        one :func:`block` each. ``first``: the segment's first layer's
+        index in the stack; ``taken``: first layer of a run -> the scan
+        steps taken before it (the carry counts steps from the stack's
+        first: no division finds a layer's index or its ``nth``)."""
+        # the one thing that is a family's own: where a period's step finds
+        # layer i's leaves: stacked by layer under kinds of the standard
+        # block, else by step already (a homogeneous stack's one layer a
+        # step, the state-space family's by kind)
+        layer_of = T.period_layer if seg.layer_kinds else (
+            lambda lps, period, i: lps[period[i]] if seg.period else lps)
 
-        return body
-
-    def blocks_body_of(seg: T.TransformerConfig, first: int, stack,
-                       before: Dict[str, int]):
-        """A step of a segment of standard blocks under ``layer_kinds``
-        (``T.scan_periods``): a period's layers, each the sandwich or the
-        plain sequential block around the attention of its kind and a
-        dense FFN or the experts. ``before``: the layers of each kind
-        ahead of the segment (a layer's ring or block range is its index
-        among its kind's)."""
-        def body_of(period, run_first):
+        def of_run(period, run_first):
+            # a layer's index and its ``nth`` are what the run starts from,
+            # less its earlier steps' share, plus the count's
+            P, before = len(period), taken[run_first]
+            li0 = first + run_first - before * P
             per = {k: period.count(k) for k in set(period)}
-            ahead = {k: before.get(k, 0)
-                     + seg.layer_kinds[:run_first].count(k) for k in per}
+            nth0 = {k: whole[:first + run_first].count(k) - before * per[k]
+                    for k in per}
 
             def body(carry, lps):
-                x, flat, li = carry
-                step = (li - first - run_first) // len(period)
+                x, flat, step, acts = carry
                 n_rows = []
                 for i, kind in enumerate(period):
-                    lp = dequant_params(T.period_layer(lps, period, i), dt)
-                    with jax.named_scope(_KIND_SCOPES.get(kind, "attn")):
-                        h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
-                        attn, flat = attention(
-                            kind, h, lp, flat, ahead[kind]
-                            + step * per[kind] + period[:i].count(kind))
-                        attn_out = attn @ lp["wo"].astype(dt)
-                        if seg.post_norms:
-                            attn_out = T._norm(attn_out, lp["ln1_post"],
-                                               seg.norm, seg.norm_eps)
-                        x = x + attn_out
-                    with contextlib.nullcontext() if seg.n_experts \
-                            else jax.named_scope("mlp"):
-                        h2 = T._norm(x, lp["ln2"], seg.norm, seg.norm_eps)
-                        if seg.n_experts:
-                            down, rows_e = _tick_experts(
-                                h2, lp, seg, valid, stack, li + i - first)
-                            n_rows.append(rows_e)
-                        else:
-                            down = T._ffn(h2, lp, seg)[0]
-                        if seg.post_norms:
-                            down = T._norm(down, lp["ln2_post"], seg.norm,
-                                           seg.norm_eps)
-                        x = x + down
-                return (x, flat, li + len(period)), \
-                    jnp.stack(n_rows) if n_rows else None
+                    li = li0 + step * P + i
+                    # (a kind's only layer is its 0th)
+                    nth = 0 if kinds[kind].layers == 1 else nth0[kind] \
+                        + step * per[kind] + period[:i].count(kind)
+                    x, flat, acts, rows_e = block(
+                        seg, kind, x, layer_of(lps, period, i), flat, li,
+                        nth, acts, (stack, li - first))
+                    n_rows.append(rows_e)
+                # (a period of one layer has nothing to stack)
+                return (x, flat, step + 1, acts), None \
+                    if not seg.n_experts else n_rows[0] \
+                    if P == 1 else jnp.stack(n_rows)
 
             return body
 
-        return body_of
+        return of_run
 
     # The pool rides the layer scans as a FLAT [L*NB, bs, ...] carry that is
     # scattered in place (layer l owns block range [l*NB, (l+1)*NB)); the
@@ -1046,21 +1105,17 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
     # listed blocks. Threading per-layer slices as scan xs→ys (the naive
     # layout) re-stacks the ENTIRE pool every call — measured 25 ms/tick at
     # 512 blocks inside a decode scan, linear in pool size — where the
-    # in-place carry touches only the written rows.
-    # (a ring of standard blocks is [layers, slots, RB, bs, K, D]: its rows
-    # are blocks too; the stores of ``_CONV_STORES`` are rows already)
-    carry = (x, {k: v if k in _CONV_STORES else v.reshape((-1,) + (
-        v.shape[-3:] if cfg.standard_blocks and k in ("wk", "wv")
-        else v.shape[2:])) for k, v in pool.items()}, jnp.int32(0))
-    if cfg.layer_kinds and not cfg.standard_blocks:
-        carry += (jnp.zeros((x.shape[0], cfg.ssm_inner), dt),)
+    # in-place carry touches only the written rows. (A ring's rows are
+    # blocks too; a store of convolution inputs is rows already.)
+    flat = {s.name: pool[s.name] if s.cls == CONV else pool[s.name].reshape(
+        (-1,) + pool[s.name].shape[-len(s.unit(0)):])
+        for _, s in pool_stores(cfg)}
+    acts = {name: jnp.zeros((x.shape[0], width), dt)
+            for kind in kinds.values() for name, width in kind.acts}
+    carry = (x, flat, jnp.int32(0), acts)
     stats = {}
-    first = 0
-    before: Dict[str, int] = {}
+    first = steps = 0
     for key, seg in cfg.segments:
-        if seg.period:
-            carry, _ = lax.scan(make_period_body(seg), carry, params[key])
-            continue
         # the experts' matrices stay out of the scan's sliced operands: the
         # grouped matmul takes the stack whole (``moe.layer.grouped_dot``;
         # a slice is a copy of a layer's experts before each matmul);
@@ -1069,21 +1124,17 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
         stack = {k: v for k, v in params[key].items() if seg.n_experts
                  and k in _EXPERT_LEAVES and hasattr(v, "ndim")}
         xs = {k: v for k, v in params[key].items() if k not in stack}
-        if cfg.standard_blocks:
-            carry, n_rows = T.scan_periods(
-                blocks_body_of(seg, first, stack, dict(before)), carry, xs,
-                seg.layer_kinds)
-            first += seg.num_layers
-            for kind in seg.layer_kinds:
-                before[kind] = before.get(kind, 0) + 1
-            if seg.n_experts:
-                # [steps, period, E] a run -> [expert layers, E]
-                stats["expert_rows"] = jnp.concatenate(
-                    [r.reshape((-1,) + r.shape[2:]) for r in n_rows])
-            continue
-        carry, n_rows = lax.scan(make_body(seg, first, stack), carry, xs)
-        first += seg.num_layers
-        if n_rows is not None:
-            stats["expert_rows"] = n_rows
+        layers, taken = stack_kinds(cfg, seg), {}
+        for at, _, n in T.kind_runs(layers):
+            taken[at], steps = steps, steps + n
+        carry, n_rows = T.scan_periods(
+            body_of(seg, first, taken, stack), carry, xs, layers,
+            by_step=not seg.layer_kinds)
+        first += len(layers)
+        if seg.n_experts:
+            # [steps, period, E] (or [steps, E]) a run -> [expert layers, E]
+            n_rows = [r.reshape((-1, r.shape[-1])) for r in n_rows]
+            stats["expert_rows"] = n_rows[0] if len(n_rows) == 1 \
+                else jnp.concatenate(n_rows)
     x, flat = carry[:2]
     return x, {k: flat[k].reshape(v.shape) for k, v in pool.items()}, stats

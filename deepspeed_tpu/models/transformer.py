@@ -1563,14 +1563,17 @@ def kind_runs(kinds: Sequence[str]) -> List[Tuple[int, Tuple[str, ...], int]]:
 
 
 def scan_periods(body_of: Callable, carry, blocks: PyTree,
-                 kinds: Sequence[str]):
+                 kinds: Sequence[str], by_step: bool = False):
     """Scan a segment of standard blocks a PERIOD of its kinds at a time:
     ``body_of(period, first layer of the run)(carry, lps)`` takes the
     period's layers' parameters stacked ``[len(period), ...]``
     (:func:`period_layer` takes one layer's out of them). The stacked
     leaves ``[layers, ...]`` are read as ``[steps, period, ...]`` (no copy
     where the segment is whole periods); leaves stacked by mixer
-    (``blocks["attn"]`` / ``blocks["conv"]``) by their own count a period."""
+    (``blocks["attn"]`` / ``blocks["conv"]``) by their own count a period.
+    ``by_step``: the leaves are stacked by step already (a homogeneous
+    stack's, one layer a step; ``blocks[kind]`` of a stack whose kinds are
+    whole layers of their own): a step takes them as they are."""
     def cut(tree, ahead: int, per: int, steps: int):
         n = per * steps
         return jax.tree.map(
@@ -1583,9 +1586,10 @@ def scan_periods(body_of: Callable, carry, blocks: PyTree,
         # by the layers of their mixer: those ahead of the run, those of
         # a period (a run may hold none: its steps then take no such leaf)
         per = {m: sum(mixer_of(k) == m for k in period)
-               for m in MIXERS if m in blocks}
-        xs = cut({k: v for k, v in blocks.items() if k not in per}, first,
-                 len(period), steps)
+               for m in MIXERS if m in blocks and not by_step}
+        xs = blocks if by_step else cut(
+            {k: v for k, v in blocks.items() if k not in per}, first,
+            len(period), steps)
         for m in (m for m, n in per.items() if n):
             xs[m] = cut(blocks[m], sum(mixer_of(k) == m
                                        for k in kinds[:first]),

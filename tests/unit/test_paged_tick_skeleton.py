@@ -1,7 +1,10 @@
-"""One tick skeleton, two cache kinds (``models/paged.forward_paged``): what
-a dense-attention model gets from sharing the skeleton the latent tick
-brought (``cfg.segments``, the whole-stack expert call, ``with_stats``), and
-the one function that says which attention a tick runs. Toy width, float32.
+"""One tick block, one table of cache kinds (``models/paged.forward_paged``):
+what a dense-attention model gets from sharing the skeleton the latent tick
+brought (``cfg.segments``, the whole-stack expert call, ``with_stats``), the
+one function that says which attention a tick runs, what is frozen of a
+pool (keys, shapes, dtypes, bytes, walks, for every serving cell), that the
+engine books a pool by the table's classes, and that every family's tick is
+one ``wo`` product a layer under ``T.scan_periods``. Toy width, float32.
 """
 import functools
 import re
@@ -254,3 +257,304 @@ def test_a_kernel_handed_in_selects_the_pools_own(models):
             debug_info=True)
     assert "attn/latent_paged_attention/" in text
     assert "attn/paged_attention/" not in text
+
+
+# ------------------------------------------------------------------ #
+# what is frozen of a pool: keys, shapes, dtypes, bytes, walks
+# ------------------------------------------------------------------ #
+#: serving cell -> family
+CELLS = {
+    "serve-mistral7b-chat-steady-v2": "grouped-query",
+    "serve-pythia69b-decode-closed": "parallel-block",
+    "serve-moonlight16b-longdoc-closed": "latent",
+    "serve-phi4flash-reason-closed": "state-space",
+    "serve-trinity-large-agentctx-closed": "window-and-full",
+    "serve-lfm2-24b-concurrent-closed": "short-convolution",
+    "serve-kimi-linear-48b-rollout-closed": "delta-rule",
+}
+#: what a toy pool is built with: blocks, block size, slots, longest run
+TOY = (64, 8, 3, 16)
+BF, F32 = "bfloat16", "float32"
+#: ``init_paged_kv`` of each cell's configuration at its published widths
+#: with the cell's own sizes, and (``toy:``) of the configuration's
+#: rehearsal widths with ``TOY``: written from the tree of PR 43. The
+#: benchmark's readers read a pool operand's shape; they are frozen.
+POOLS = {
+    "serve-mistral7b-chat-steady-v2": {
+        "k": ((16, 2400, 32, 8, 128), BF), "v": ((16, 2400, 32, 8, 128), BF)},
+    "toy:serve-mistral7b-chat-steady-v2": {
+        "k": ((2, 64, 8, 2, 16), BF), "v": ((2, 64, 8, 2, 16), BF)},
+    "serve-pythia69b-decode-closed": {
+        "k": ((16, 640, 32, 32, 128), BF), "v": ((16, 640, 32, 32, 128), BF)},
+    "toy:serve-pythia69b-decode-closed": {
+        "k": ((2, 64, 8, 4, 16), BF), "v": ((2, 64, 8, 4, 16), BF)},
+    "serve-moonlight16b-longdoc-closed": {
+        "latent": ((9, 4352, 32, 640), BF)},
+    "toy:serve-moonlight16b-longdoc-closed": {
+        "latent": ((3, 64, 8, 128), BF)},
+    "serve-phi4flash-reason-closed": {
+        "conv": ((1971, 5120), BF), "k": ((1, 10900, 10, 32, 128), BF),
+        "ssm": ((9, 73, 16, 5120), F32), "v": ((1, 10900, 10, 32, 128), BF),
+        "wk": ((8, 2336, 10, 32, 128), BF),
+        "wv": ((8, 2336, 10, 32, 128), BF)},
+    "toy:serve-phi4flash-reason-closed": {
+        "conv": ((36, 128), BF), "k": ((1, 64, 2, 8, 16), BF),
+        "ssm": ((3, 4, 16, 128), F32), "v": ((1, 64, 2, 8, 16), BF),
+        "wk": ((2, 16, 2, 8, 16), BF), "wv": ((2, 16, 2, 8, 16), BF)},
+    "serve-trinity-large-agentctx-closed": {
+        "k": ((1, 12288, 32, 8, 128), BF), "v": ((1, 12288, 32, 8, 128), BF),
+        "wk": ((4, 29, 192, 32, 8, 128), BF),
+        "wv": ((4, 29, 192, 32, 8, 128), BF)},
+    "toy:serve-trinity-large-agentctx-closed": {
+        "k": ((1, 64, 8, 2, 16), BF), "v": ((1, 64, 8, 2, 16), BF),
+        "wk": ((4, 4, 4, 8, 2, 16), BF), "wv": ((4, 4, 4, 8, 2, 16), BF)},
+    "serve-lfm2-24b-concurrent-closed": {
+        "conv": ((4368, 2048), BF), "k": ((2, 20480, 32, 4, 128), BF),
+        "v": ((2, 20480, 32, 4, 128), BF)},
+    "toy:serve-lfm2-24b-concurrent-closed": {
+        "conv": ((64, 256), BF), "k": ((2, 64, 8, 1, 128), BF),
+        "v": ((2, 64, 8, 1, 128), BF)},
+    "serve-kimi-linear-48b-rollout-closed": {
+        "kda": ((6, 273, 32, 128, 128), F32),
+        "kda_conv": ((4914, 12288), BF),
+        "latent": ((2, 40960, 32, 640), BF)},
+    "toy:serve-kimi-linear-48b-rollout-closed": {
+        "kda": ((6, 4, 2, 128, 128), F32), "kda_conv": ((72, 768), BF),
+        "latent": ((2, 64, 8, 128), BF)},
+}
+#: ``FastGenEngine._pool_bytes``: (block stores, per-slot state stores)
+BYTES = {
+    "serve-mistral7b-chat-steady-v2": (5033164800, 0),
+    "toy:serve-mistral7b-chat-steady-v2": (131072, 0),
+    "serve-pythia69b-decode-closed": (5368709120, 0),
+    "toy:serve-pythia69b-decode-closed": (262144, 0),
+    "serve-moonlight16b-longdoc-closed": (1604321280, 0),
+    "toy:serve-moonlight16b-longdoc-closed": (393216, 0),
+    "serve-phi4flash-reason-closed": (1785856000, 3297310720),
+    "toy:serve-phi4flash-reason-closed": (65536, 140288),
+    "serve-trinity-large-agentctx-closed": (1610612736, 2919235584),
+    "toy:serve-trinity-large-agentctx-closed": (65536, 65536),
+    "serve-lfm2-24b-concurrent-closed": (2684354560, 17891328),
+    "toy:serve-lfm2-24b-concurrent-closed": (524288, 32768),
+    "serve-kimi-linear-48b-rollout-closed": (3355443200, 3555901440),
+    "toy:serve-kimi-linear-48b-rollout-closed": (262144, 3256320),
+}
+#: ``tick_walks``: (layers, window, cache positions a fetch step) a kind
+#: of kernel call
+WALKS = {
+    "serve-mistral7b-chat-steady-v2": [(16, None, 128)],
+    "toy:serve-mistral7b-chat-steady-v2": [(2, None, 128)],
+    "serve-pythia69b-decode-closed": [(16, None, 64)],
+    "toy:serve-pythia69b-decode-closed": [(2, None, 128)],
+    "serve-moonlight16b-longdoc-closed": [(9, None, 512)],
+    "toy:serve-moonlight16b-longdoc-closed": [(3, None, 2048)],
+    "serve-phi4flash-reason-closed": [(8, 512, 128), (8, None, 128)],
+    "toy:serve-phi4flash-reason-closed": [(2, 16, 128), (2, None, 128)],
+    "serve-trinity-large-agentctx-closed": [(4, 4096, 128), (1, None, 128)],
+    "toy:serve-trinity-large-agentctx-closed": [(4, 16, 128),
+                                                (1, None, 128)],
+    "serve-lfm2-24b-concurrent-closed": [(2, None, 128)],
+    "toy:serve-lfm2-24b-concurrent-closed": [(2, None, 128)],
+    "serve-kimi-linear-48b-rollout-closed": [(2, None, 512)],
+    "toy:serve-kimi-linear-48b-rollout-closed": [(2, None, 2048)],
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _sized(case: str):
+    """(config, blocks, block size, slots, longest run) of a case of
+    ``POOLS``: a cell's own, or its configuration's toy widths."""
+    from benchmarks import model_config
+    from benchmarks.manifest import load_cell
+
+    toy, name = case.startswith("toy:"), case.split(":")[-1]
+    cell = load_cell(name)
+    cfg = model_config.build(cell.config, "serve", rehearse=toy)
+    eng = cell.deploy["engine"]
+    nb, bs, slots, run = TOY if toy else (
+        eng["n_blocks"], eng["block_size"], eng.get("state_slots", 0),
+        eng["token_budget"])
+    return cfg, nb, bs, slots if cfg.layer_kinds else 0, run
+
+
+def _pool_shapes(case: str):
+    cfg, nb, bs, slots, run = _sized(case)
+    return jax.eval_shape(lambda: PG.init_paged_kv(
+        cfg, nb, bs, state_slots=slots, max_run=run))
+
+
+def test_the_cases_cover_every_serving_cell():
+    from benchmarks.manifest import load_manifest
+
+    serving = {w["name"] for w in load_manifest()["workloads"]
+               if w["name"].startswith("serve-")}
+    assert serving == set(CELLS)
+    assert set(POOLS) == set(BYTES) == set(WALKS) == {
+        p + c for c in CELLS for p in ("", "toy:")}
+
+
+@pytest.mark.parametrize("case", sorted(POOLS))
+def test_a_pool_has_its_frozen_keys_shapes_and_dtypes(case):
+    got = {k: (tuple(v.shape), str(v.dtype))
+           for k, v in _pool_shapes(case).items()}
+    assert got == POOLS[case]
+
+
+@pytest.mark.parametrize("case", sorted(BYTES))
+def test_the_engine_books_a_pools_bytes_as_blocks_or_state(case):
+    cfg, nb, bs, slots, run = _sized(case)
+    assert FastGenEngine._pool_bytes(cfg, nb, bs, slots, run) == BYTES[case]
+
+
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_tick_walks_of_a_pool(case, monkeypatch):
+    cfg = _sized(case)[0]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert PG.tick_walks(cfg, _pool_shapes(case)) == WALKS[case]
+
+
+# ------------------------------------------------------------------ #
+# one table of cache kinds: the engine books by class, names no store
+# ------------------------------------------------------------------ #
+def _bytes(x):
+    return int(np.prod(x.shape)) * x.dtype.itemsize
+
+
+@pytest.mark.parametrize("case", sorted(BYTES))
+def test_pool_bytes_are_the_tables_by_class(case):
+    cfg, nb, bs, slots, run = _sized(case)
+    pool = _pool_shapes(case)
+    stores = [s for kind in PG.cache_kinds(cfg).values()
+              for s in kind.stores]
+    # every array of the pool is one entry's, once
+    assert sorted(s.name for s in stores) == sorted(pool)
+    blocks = sum(_bytes(pool[s.name]) for s in stores if s.cls == PG.BLOCKS)
+    state = sum(_bytes(pool[s.name]) for s in stores if s.cls != PG.BLOCKS)
+    assert FastGenEngine._pool_bytes(cfg, nb, bs, slots, run) == (
+        blocks, state)
+    # a homogeneous stack is its one entry ``num_layers`` times
+    if not cfg.layer_kinds:
+        (entry,) = PG.cache_kinds(cfg).values()
+        assert entry.layers == cfg.num_layers
+
+
+def test_a_made_up_kind_with_a_slot_store_is_booked_as_state(monkeypatch):
+    """A kind the engine never heard of: its SLOT store is built, booked
+    as state by ``_pool_bytes`` and shown by the gauge of bytes a slot,
+    with no edit to ``inference/fastgen.py``."""
+    import dataclasses
+    import inspect
+
+    import deepspeed_tpu.inference.fastgen as FG
+    from deepspeed_tpu import telemetry
+
+    case = "toy:serve-lfm2-24b-concurrent-closed"
+    cfg, nb, bs, slots, run = _sized(case)
+    real = PG.cache_kinds
+
+    def with_more(c):
+        return {**real(c), "made_up": PG.CacheKind(
+            2, "made_up", (PG.Store("made_up", PG.SLOT, lambda bs: (5, 7),
+                                    jnp.float32, holds="made_up"),),
+            None, lambda *a: None)}
+
+    monkeypatch.setattr(PG, "cache_kinds", with_more)
+    more = 2 * (slots + 1) * 5 * 7 * 4
+    assert FastGenEngine._pool_bytes(cfg, nb, bs, slots, run) == (
+        BYTES[case][0], BYTES[case][1] + more)
+    eng = FastGenEngine(dataclasses.replace(cfg, dtype="float32"),
+                        n_blocks=nb, block_size=bs, max_blocks_per_seq=8,
+                        token_budget=run, state_slots=slots,
+                        use_pallas_kernel=False, seed=0)
+    assert eng.pool["made_up"].shape == (2, slots + 1, 5, 7)
+    per_slot = telemetry.gauge("fastgen_state_bytes_per_slot")
+    assert per_slot.value(kind="made_up") == 2 * 5 * 7 * 4
+    assert telemetry.gauge("fastgen_state_bytes").value() == sum(
+        _bytes(eng.pool[s.name]) for kind in with_more(cfg).values()
+        for s in kind.stores if s.cls != PG.BLOCKS)
+    # the engine names no store and no kind of its model
+    source = inspect.getsource(FG)
+    for name in ("kda_conv", "wk", "wv", "ssm", "kda", "conv"):
+        assert f'"{name}"' not in source
+
+
+# ------------------------------------------------------------------ #
+# one block, one driver
+# ------------------------------------------------------------------ #
+#: what hands an array on as it is (a slice of it, a cast, a reshape)
+_PASS = {"reshape", "slice", "dynamic_slice", "squeeze", "gather",
+         "convert_element_type", "copy", "broadcast_in_dim"}
+
+
+def _count_products(jaxpr, tainted):
+    """dot_generals of ``jaxpr`` (and the scans and calls inside it) that
+    take an operand handed down from a ``tainted`` variable."""
+    tainted, n = set(tainted), 0
+    for eqn in jaxpr.eqns:
+        hit = [i for i, v in enumerate(eqn.invars)
+               if not hasattr(v, "val") and v in tainted]
+        name = eqn.primitive.name
+        if name == "dot_general":
+            n += bool(hit)
+        elif name in _PASS and 0 in hit:
+            tainted.update(eqn.outvars)
+        elif hit and any(k in eqn.params for k in ("jaxpr", "call_jaxpr")):
+            inner = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
+            inner = getattr(inner, "jaxpr", inner)
+            n += _count_products(inner, [inner.invars[i] for i in hit])
+    return n
+
+
+@functools.lru_cache(maxsize=None)
+def _traced(case: str, attention_fn=None):
+    """(the float32 paged forward of a toy case traced, its config, its
+    flat parameters' paths)."""
+    import dataclasses
+
+    cfg, nb, bs, slots, run = _sized(case)
+    cfg = dataclasses.replace(cfg, dtype="float32")
+    params = jax.eval_shape(lambda k: T.init_params(cfg, k),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    pool = jax.eval_shape(lambda: PG.init_paged_kv(
+        cfg, nb, bs, state_slots=slots, max_run=run))
+    ints = jax.ShapeDtypeStruct((run,), jnp.int32)
+    closed = jax.make_jaxpr(
+        lambda p, pool, t, pos, tb: PG.forward_paged(
+            p, t, pos, tb, pool, cfg, attention_fn=attention_fn))(
+        params, pool, ints, ints, jax.ShapeDtypeStruct((run, 8), jnp.int32))
+    paths = [path for path, _ in jax.tree_util.tree_flatten_with_path(
+        params)[0]]
+    return closed, cfg, paths
+
+
+@pytest.mark.parametrize("case", sorted(c for c in POOLS if "toy:" in c))
+def test_a_tick_holds_one_wo_product_a_layer_and_one_scan_a_run(case):
+    """The lowered tick of every family: the only scans of the skeleton
+    are ``T.scan_periods``', one a run of a period of kinds, and a step
+    multiplies by ``wo`` once a layer of its period (``paged.py`` names
+    the leaf once and scans nothing itself)."""
+    import inspect
+
+    closed, cfg, paths = _traced(case)
+    runs = [run for _, seg in cfg.segments
+            for run in T.kind_runs(PG.stack_kinds(cfg, seg))]
+    assert sum(steps * len(period) for _, period, steps in runs) \
+        == cfg.num_layers
+    scans = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == [s for _, _, s in runs]
+    wo = [v for v, path in zip(closed.jaxpr.invars, paths)
+          if getattr(path[-1], "key", None) == "wo"]
+    assert wo
+    assert _count_products(closed.jaxpr, wo) == sum(
+        len(period) for _, period, _ in runs)
+    source = inspect.getsource(PG)
+    assert source.count('lp["wo"]') == 1 and "lax.scan" not in source
+
+
+@pytest.mark.parametrize("case", sorted(c for c in POOLS if "toy:" in c))
+def test_no_attention_fn_and_a_reference_are_one_program(case):
+    """``attention_fn=None`` and a reference trace to the same program in
+    float32, operation for operation: their logits are bit-equal."""
+    ref = PG.tick_attention(_sized(case)[0], False)[0]
+    assert ref in PG._REFERENCES
+    assert str(_traced(case)[0]) == str(_traced(case, ref)[0])

@@ -13,10 +13,16 @@ above ``--min-mb`` by shape, the ``copy`` / ``reshape`` / ``transpose``
 instructions as large as a per-slot state store of the pool
 (``whole_store_copies``: a reshape the compiler could not make a
 ``bitcast`` moves every byte too), ``memory_analysis()``'s arguments /
-alias / temporaries, and a hash of the program as lowered (equal on two
-trees: the change between them left that program alone). The pool rides a tick aliased in place: a
-copy of a whole store, or a ``remat`` pair on one, is device time no layer
-needs (PERF.md, PR 43: a store whose second-minor dimension was 3 taps was
+alias / temporaries, and two hashes: of the program as lowered, and of the
+COMPILED text with source metadata stripped and instructions renumbered in
+their order of appearance (:func:`normalised`). Either equal on two trees:
+the change between them left that program alone; the second alone equal:
+it changed what was traced and nothing the device runs. Beside the tick
+programs, the benchmark runner's ``check_logits`` program (``forward_paged``
+over the whole token budget and the widest table, the pool donated) as
+``"form": "check_logits"``. The pool rides a tick aliased in place: a copy
+of a whole store, or a ``remat`` pair on one, is device time no layer needs
+(PERF.md, PR 43: a store whose second-minor dimension was 3 taps was
 re-laid ten times a decode tick).
 
 The configuration, the pool and the packed tick are ``ShapeDtypeStruct``s:
@@ -76,6 +82,44 @@ def count_copies(text: str, stores=(), min_bytes: int = 8 << 20):
     return {k: dict(v) for k, v in found.items()}
 
 
+#: what of a compiled program's text is its source's and not the program's
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+_TABLES = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(.*\n)*?\n",
+    re.M)
+_NAME = re.compile(r"%[A-Za-z_][\w.\-]*")
+#: a parameter's name in a computation's header: ``(param_0.38: s32[29], ``
+_HEADER_NAME = re.compile(r"(?<=[(,] )[A-Za-z_][\w.\-]*(?=: )|(?<=\()"
+                          r"[A-Za-z_][\w.\-]*(?=: )")
+_CUSTOM_CALL = re.compile(
+    r"^\s*(?:ROOT )?(%[\w.\-]+) = [^=]*? custom-call\(", re.M)
+
+
+def normalised(text: str) -> str:
+    """Compiled text as two trees can be compared by: the ``metadata``
+    of every instruction and the tables of file and function names taken
+    out, and every name (``%fusion.207``, ``%region_3.41.clone.sunk``)
+    replaced by the order of its first appearance, so that the same
+    instructions with the same wiring in the same order are the same
+    text, whichever numbers the tracer's counters gave them. A custom
+    call keeps the stem of its name (a Mosaic call's name is what the
+    benchmark's readers find it by); any other name's stem is that of
+    whichever source operation XLA derived the instruction from last (a
+    ``broadcast`` named ``add.1551`` on one tree and
+    ``broadcast_in_dim.1551`` on the other), which says nothing of what
+    it computes: its opcode, operands, shape and layout stay."""
+    text = _HEADER_NAME.sub("", _TABLES.sub("", _METADATA.sub("", text)))
+    calls = set(_CUSTOM_CALL.findall(text))
+    seen = {}
+
+    def renumber(m):
+        name = m.group(0)
+        stem = name.rsplit(".", 1)[0] if name in calls else "%"
+        return seen.setdefault(name, f"{stem}#{len(seen)}")
+
+    return _NAME.sub(renumber, text)
+
+
 def describe_chip():
     """A v5e chip to compile for (raises where none can be described)."""
     from jax.experimental import topologies
@@ -117,9 +161,11 @@ def cell_programs(cell_name: str):
     return cfg, sizes, [(r, t) for r in rows for t in tiers]
 
 
-def lower_tick(cfg, sizes, rows: int, tier: int, chip):
+def lower_tick(cfg, sizes, rows: int, tier: int, chip,
+               form: str = "tick"):
     """One tick program lowered for ``chip``: (lowered, the pool's
-    shapes)."""
+    shapes). ``form`` ``check_logits``: the runner's check instead, the
+    paged forward over ``rows`` rows and tables of ``tier`` blocks."""
     import numpy as np
 
     from deepspeed_tpu.models import paged as PG
@@ -142,6 +188,19 @@ def lower_tick(cfg, sizes, rows: int, tier: int, chip):
         max_run=sizes["token_budget"]))
     eng = _bare_engine(cfg, sizes)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        if form == "check_logits":
+            from deepspeed_tpu.ops.pallas.paged_attention import \
+                paged_attention
+
+            ints = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=chip)
+            return jax.jit(
+                lambda params, pool, tokens, positions, tables:
+                PG.forward_paged(params, tokens, positions, tables, pool, cfg,
+                                 attention_fn=paged_attention),
+                donate_argnums=(1,)).lower(
+                    on_chip(params), on_chip(pool), ints, ints,
+                    jax.ShapeDtypeStruct((rows, tier), jnp.int32,
+                                         sharding=chip)), pool
         eng._attention, eng._tile_rows = PG.tick_attention(cfg, True)
         packed = eng._pack_tick(
             np.zeros((rows,), np.int32), np.zeros((rows,), np.int32),
@@ -152,21 +211,23 @@ def lower_tick(cfg, sizes, rows: int, tier: int, chip):
     return lowered, pool
 
 
-def program_line(lowered, pool, min_bytes: int, text_path: str = ""):
+def program_line(cfg, lowered, pool, min_bytes: int, text_path: str = ""):
     """What ``count_copies`` finds in a tick program once compiled, its
-    memory, and a hash of the program as LOWERED, which a change that left
-    this program alone leaves equal (``main`` keeps source lines out of
-    it)."""
-    from deepspeed_tpu.inference.fastgen import FastGenEngine
+    memory, a hash of the program as LOWERED and one of the compiled text
+    :func:`normalised` (``main`` keeps source lines out of both). The
+    state stores are the pool's that are no BLOCKS of the model's table
+    of cache kinds."""
+    from deepspeed_tpu.models import paged as PG
 
     compiled = lowered.compile()
+    text = compiled.as_text()
     if text_path:
         with open(text_path, "w") as f:
-            f.write(compiled.as_text())
-    stores = {k: math.prod(v.shape) for k, v in pool.items()
-              if k in FastGenEngine._STATE_STORES}
+            f.write(text)
+    stores = {s.name: math.prod(pool[s.name].shape)
+              for _, s in PG.pool_stores(cfg) if s.cls != PG.BLOCKS}
     stats = compiled.memory_analysis()
-    return {**count_copies(compiled.as_text(), stores.values(), min_bytes),
+    return {**count_copies(text, stores.values(), min_bytes),
             "state_stores": {k: list(pool[k].shape) for k in stores},
             "pool_bytes": sum(math.prod(v.shape) * v.dtype.itemsize
                               for v in pool.values()),
@@ -174,7 +235,9 @@ def program_line(lowered, pool, min_bytes: int, text_path: str = ""):
             "alias_gb": round(stats.alias_size_in_bytes / 1e9, 3),
             "temp_gb": round(stats.temp_size_in_bytes / 1e9, 3),
             "lowered_sha256": hashlib.sha256(
-                lowered.as_text().encode()).hexdigest()[:16]}
+                lowered.as_text().encode()).hexdigest()[:16],
+            "compiled_sha256": hashlib.sha256(
+                normalised(text).encode()).hexdigest()[:16]}
 
 
 def main(argv=None) -> int:
@@ -197,14 +260,17 @@ def main(argv=None) -> int:
                     for p in args.programs.split(",")]
     if args.text_dir:
         os.makedirs(args.text_dir, exist_ok=True)
-    for rows, tier in programs:
-        lowered, pool = lower_tick(cfg, sizes, rows, tier, chip)
+    # the runner's check beside them: the budget's rows, the widest table
+    check = (sizes["token_budget"], sizes["max_blocks_per_seq"])
+    for form, (rows, tier) in [("tick", p) for p in programs] + [
+            ("check_logits", check)] * (not args.programs):
+        lowered, pool = lower_tick(cfg, sizes, rows, tier, chip, form)
         print(json.dumps({
-            "cell": args.cell, "rows": rows, "tier": tier,
-            **program_line(lowered, pool, int(args.min_mb * 2 ** 20),
+            "cell": args.cell, "form": form, "rows": rows, "tier": tier,
+            **program_line(cfg, lowered, pool, int(args.min_mb * 2 ** 20),
                            args.text_dir and os.path.join(
                                args.text_dir,
-                               f"{args.cell}-{rows}x{tier}.txt"))}),
+                               f"{args.cell}-{form}-{rows}x{tier}.txt"))}),
             flush=True)
     return 0
 

@@ -240,19 +240,35 @@ class FastGenEngine:
             per_slot = pool_bytes(2)[1] - one
             state_slots = max(1, min(state_slots, int(
                 max(blocks, 256 << 20) // max(per_slot, 1))))
+        # a pool of blocks alone whose calls take a table a sequence: the
+        # tick counts its sequences itself, and has room for so many tables
+        most = PG.tick_tables(token_budget, max_blocks_per_seq)
+        if token_budget > most <= state_slots and all(
+                s.cls == PG.BLOCKS for _, s in PG.pool_stores(cfg)):
+            raise ValueError(
+                f"state_slots={state_slots}: a tick of {token_budget} rows "
+                f"with tables of {max_blocks_per_seq} blocks holds {most} "
+                "sequences, its pad rows' among them")
         # reckoned before it is built: a pool that cannot fit says so here
         # and not as an allocation failure in the middle of a tick
         blocks, state = pool_bytes(state_slots)
+        # what a layer of a kind materialises inside the largest tick
+        # (a sparse layer's scores of a chunk: 2,048 rows x 18k positions)
+        tick = max((kind.tick_bytes(token_budget,
+                                    block_size * max_blocks_per_seq)
+                    for kind in PG.cache_kinds(cfg).values()
+                    if kind.tick_bytes is not None), default=0)
         stats = jax.devices()[0].memory_stats() or {}
         weights = sum(x.nbytes for x in jax.tree.leaves(self.params))
         if stats.get("bytes_limit") and \
-                weights + blocks + state > stats["bytes_limit"]:
+                weights + blocks + state + tick > stats["bytes_limit"]:
             raise ValueError(
                 f"the pool does not fit the device: {n_blocks} blocks of "
                 f"{block_size} take {blocks / 1e9:.2f} GB and {state_slots} "
                 f"sequence slots' state {state / 1e9:.2f} GB beside "
-                f"{weights / 1e9:.2f} GB of weights, of "
-                f"{stats['bytes_limit'] / 1e9:.2f} GB")
+                f"{weights / 1e9:.2f} GB of weights"
+                + (f" and {tick / 1e9:.2f} GB a tick holds" if tick else "")
+                + f", of {stats['bytes_limit'] / 1e9:.2f} GB")
         self.allocator = BlockAllocator(n_blocks, state_slots)
         self.pool = PG.init_paged_kv(cfg, n_blocks, block_size,
                                      state_slots=state_slots,
@@ -551,6 +567,19 @@ class FastGenEngine:
             "fastgen_kda_chunk_pieces_total",
             "grid steps of the delta rule's chunk form that did work: the "
             "chunks of 64 rows of a tick that a run it takes has a row in")
+        self._tm_index_positions = telemetry.counter(
+            "fastgen_index_positions_total",
+            "cache positions the sparse layers' indexers scored: the "
+            "lengths of the ticks' real rows, summed over the layers")
+        self._tm_sparse_selected = telemetry.counter(
+            "fastgen_sparse_selected_total",
+            "cache positions the rows of sparse layers attended to: "
+            "min(length, topk) a row, summed over the layers")
+        self._tm_sparse_read = telemetry.counter(
+            "fastgen_sparse_positions_read_total",
+            "cache positions the sparse layers' attention met for those "
+            "rows: a row walks its sequence with the choice as a mask, so "
+            "its length, summed over the layers")
         self._period_keys: Dict[tuple, tuple] = {}   # (kind, Tn) -> keys
         # the last step() tick's end (None before the first and after a
         # fused window, whose ticks are not accounted), and whether the
@@ -1364,7 +1393,8 @@ class FastGenEngine:
                           "window_positions": int(window_positions),
                           "window_attended": window_attended}
             for span in self._kind_spans:
-                slot_attrs.update(span(n_decode_rows, chunk_starts, row, Tn))
+                slot_attrs.update(span(n_decode_rows, chunk_starts, row, Tn,
+                                       positions[:row] + 1))
         with telemetry.span("decode_tick", attrs={
                 **slot_attrs,
                 "tick": self._ticks_run, "kind": kind, "rows": row,
@@ -1472,6 +1502,10 @@ class FastGenEngine:
                 self._tm_kda_rows.inc(slot_attrs["kda_chunk_rows"],
                                       form="chunk")
                 self._tm_kda_pieces.inc(slot_attrs["kda_chunk_pieces"])
+            if "sparse_selected" in slot_attrs:
+                self._tm_index_positions.inc(slot_attrs["index_positions"])
+                self._tm_sparse_selected.inc(slot_attrs["sparse_selected"])
+                self._tm_sparse_read.inc(slot_attrs["sparse_positions_read"])
             if attn_steps:
                 self._tm_attn_steps.inc(attn_open, form="open")
                 self._tm_attn_steps.inc(attn_steps - attn_open,

@@ -17,6 +17,7 @@ Supported architectures: gpt2, llama (mistral shares the schema), mixtral
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
@@ -344,6 +345,84 @@ def params_from_qwen3_moe(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
         "k_norm": _stack(sd, lyr + "self_attn.k_norm.weight", L),
     })
     blocks.update(_qwen_moe_experts(sd, moe, L, E))
+    params["blocks"] = blocks
+    return params
+
+
+# --------------------------------------------------------------------------- #
+# Keye-VL-2 (Kwai: the Qwen3-MoE block under a learned sparse-attention
+# indexer; the language model alone)
+# --------------------------------------------------------------------------- #
+
+def config_from_keye_vl2(hf_config) -> TransformerConfig:
+    """``model_type`` ``KeyeVL2``, the language model (the vision tower is
+    not read: a tick's input is token ids, for which the three position
+    streams of ``rope_scaling.mrope_section`` carry one index, which is
+    ordinary rotary over the whole head): the Qwen3-MoE block (per-head q/k
+    RMSNorm, a softmax router over ``num_experts`` with ``norm_topk_prob``,
+    no shared expert) in which every layer is ``sparse``: ``sa_config``'s
+    indexer (``indexer_num_heads`` query heads and one key head of
+    ``indexer_head_dim``) chooses the ``topk`` positions a row attends to
+    (``TransformerConfig.sparse_topk``). ``q_chunk_size`` /
+    ``kv_chunk_size`` are the tile sizes of the published kernels' loops
+    and enter no equation.
+
+    A SHARE of the expert layers (``TransformerConfig.moe_router_experts``):
+    ``num_experts`` is then the experts held, ``router_experts`` the
+    router's width (the published count) and ``first_expert`` the first
+    one held; without ``router_experts`` every expert is held."""
+    sa = dict(hf_config.sa_config)
+    if int(sa.get("indexer_num_kv_heads", 1)) != 1 \
+            or getattr(hf_config, "use_sliding_window", False):
+        raise NotImplementedError(
+            "KeyeVL2: an indexer of one key head and no sliding window are "
+            "what is written")
+    scaling = dict(getattr(hf_config, "rope_scaling", None) or {})
+    if scaling.get("rope_type", scaling.get("type", "default")) != "default":
+        raise NotImplementedError(
+            f"KeyeVL2: unscaled rotary is what is written (got {scaling})")
+    # (what is left of ``rope_scaling`` is ``mrope_section``: see above)
+    plain = copy.copy(hf_config)
+    plain.rope_scaling = None
+    cfg = config_from_qwen3_moe(plain)
+    held = hf_config.num_experts
+    router = int(getattr(hf_config, "router_experts", held))
+    return dataclasses.replace(
+        cfg, layer_kinds=("sparse",) * cfg.num_layers,
+        sparse_topk=int(sa["topk"]),
+        index_heads=int(sa["indexer_num_heads"]),
+        index_head_dim=int(sa["indexer_head_dim"]),
+        moe_dispatch="ragged",
+        moe_router_experts=router if router != held else 0,
+        moe_first_expert=int(getattr(hf_config, "first_expert", 0)))
+
+
+def params_from_keye_vl2(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
+    """The Qwen3-MoE names under the language model's prefix, beside
+    ``self_attn.indexer.{wq, wk, k_norm, weights_proj}`` (the indexer as
+    DeepSeek sparse attention names it; its queries here come from the
+    layer's normed input, ``wq [heads x dim, hidden]``). A share of the
+    experts takes its own from the checkpoint's; tensors of the vision
+    tower are not read."""
+    L = cfg.num_layers
+    pre = next((p for p in ("model.language_model.", "language_model.model.",
+                            "model.") if any(k.startswith(p) for k in sd)), "")
+    lyr = pre + "layers.{}."
+    blocks, params = _llama_attn_blocks(sd, cfg, pre)
+    blocks.update({
+        "gate_w": _stack(sd, lyr + "mlp.gate.weight", L, transpose=True),
+        "q_norm": _stack(sd, lyr + "self_attn.q_norm.weight", L),
+        "k_norm": _stack(sd, lyr + "self_attn.k_norm.weight", L),
+    })
+    idx = lyr + "self_attn.indexer."
+    blocks.update({
+        "idx_wq": _stack(sd, idx + "wq.weight", L, transpose=True),
+        "idx_wk": _stack(sd, idx + "wk.weight", L, transpose=True),
+        "idx_ww": _stack(sd, idx + "weights_proj.weight", L, transpose=True),
+        "idx_k_norm": {"scale": _stack(sd, idx + "k_norm.weight", L),
+                       "bias": _stack(sd, idx + "k_norm.bias", L)}})
+    blocks.update(_qwen_moe_experts(sd, lyr + "mlp.", L, cfg.n_experts,
+                                    cfg.moe_first_expert))
     params["blocks"] = blocks
     return params
 
@@ -1392,6 +1471,7 @@ def params_from_kimi_linear(sd: Dict[str, Any], cfg: TransformerConfig
 
 _ARCH_TABLE = {
     "afmoe": (config_from_afmoe, params_from_afmoe),
+    "KeyeVL2": (config_from_keye_vl2, params_from_keye_vl2),
     "kimi_linear": (config_from_kimi_linear, params_from_kimi_linear),
     "lfm2_moe": (config_from_lfm2_moe, params_from_lfm2_moe),
     "phi4flash": (config_from_phi4flash, params_from_phi4flash),

@@ -460,10 +460,12 @@ def differential_merge(o: jax.Array, lp: Dict[str, Any], layer: jax.Array,
 
 
 def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                       scale: float, window: int) -> jax.Array:
+                       scale: float, window: int,
+                       chosen: Optional[jax.Array] = None) -> jax.Array:
     """Plain causal attention of a dense batch under a window (0: none).
     q [B, S, N, D]; k, v [B, S, K, D]; position j is visible from i iff
-    ``i - window < j <= i``. float32 softmax."""
+    ``i - window < j <= i`` and, with ``chosen [B, S, S]`` bool, it is
+    chosen for i. float32 softmax."""
     S, N, K = q.shape[1], q.shape[2], k.shape[2]
     if K != N:
         k = jnp.repeat(k, N // K, axis=2)
@@ -473,5 +475,7 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     seen = j <= i
     if window:
         seen &= j > i - window
-    p = jax.nn.softmax(jnp.where(seen[None, None], s, -1e30), axis=-1)
+    seen = seen[None, None] if chosen is None \
+        else seen[None, None] & chosen[:, None]
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
     return jnp.einsum("bnst,btnd->bsnd", p.astype(q.dtype), v)

@@ -16,6 +16,7 @@ into reserved trash block 0.
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
                     Tuple)
@@ -35,6 +36,23 @@ def latent_row_width(cfg: T.TransformerConfig) -> int:
     lanes, which is what the array occupies in HBM anyway (576 -> 640) and
     what the kernel's block copies and products must be aligned to."""
     return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def index_row_width(cfg: T.TransformerConfig) -> int:
+    """Columns of a row of a ``sparse`` layer's store of index keys: the
+    indexer's ``index_head_dim`` rounded up to the TPU's 128 lanes, zeros
+    beyond. A row is PADDED and two positions do not share one: a row of
+    64 bfloat16 values is half a lane width, which the array occupies in
+    HBM anyway while its minor dimension is 64, and a store ``[bs / 2,
+    128]`` with two positions a row would make a tick's write a
+    read-modify-write of rows that two of its own rows share (positions p
+    and p + 1 of one chunk). What the padding costs: 256 B a position a
+    layer where 128 B are values (at the published widths 12.5 % on a
+    position's 2,048 B of keys and values instead of 6.25 %; 340 MB of the
+    new cell's 6.1 GB pool) and twice the bytes the indexer's walk
+    fetches; no product is wider for it (a 128-lane MXU contracts 64
+    columns in the passes it contracts 128)."""
+    return -(-cfg.index_head_dim // 128) * 128
 
 
 def ring_blocks(cfg: T.TransformerConfig, block_size: int,
@@ -142,9 +160,14 @@ class CacheKind(NamedTuple):
     mixer: Callable                     # builds its mixer: see below
     # what its layers hand on to later layers of a tick: name -> columns
     acts: Tuple[Tuple[str, int], ...] = ()
-    # ``(decode rows, chunk starts, rows, bucket) -> span attributes``: what
-    # a tick of that shape does to the kind's state, for the engine's span
+    # ``(decode rows, chunk starts, rows, bucket, the rows' lengths) ->
+    # span attributes``: what a tick of that shape does to the kind's
+    # state, for the engine's span
     span: Optional[Callable] = None
+    # ``(rows of a tick, positions a table reaches) -> bytes``: what a
+    # layer of the kind materialises inside a tick beside the pool, where
+    # that is too large to go unreckoned (the engine's check of its memory)
+    tick_bytes: Optional[Callable] = None
 
 
 def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
@@ -257,7 +280,7 @@ def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
             None, _conv_mixer,
             # rows that close a run (a decode row, a chunk's last): each
             # writes its slot's state in every conv layer
-            span=lambda decode_rows, chunk_starts, rows, bucket: {
+            span=lambda decode_rows, chunk_starts, rows, bucket, *_: {
                 "conv_state_rows": decode_rows + len(chunk_starts)}),
         "kda": CacheKind(
             n("kda"), "kda",
@@ -266,6 +289,19 @@ def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
              Store("kda_conv", CONV, lambda bs: HY.kda_state_shapes(cfg)[1],
                    holds="conv")),
             None, _kda_mixer, span=_kda_span),
+        # keys and values as a ``full`` layer's and, at the same (block,
+        # offset), the indexer's key of every position (``index_row_width``)
+        "sparse": CacheKind(
+            n("sparse"), "attn",
+            tuple(Store(s, BLOCKS, block) for s in ("k", "v")) + (
+                Store("idx", BLOCKS, lambda bs: (bs, index_row_width(cfg)),
+                      positions=-2),),
+            grouped(("k", "v"), None, "sparse_attention", "sparse", True),
+            _sparse_mixer,
+            span=functools.partial(_sparse_span, n("sparse"),
+                                   cfg.sparse_topk),
+            # a row's scores and its choice, float32 each
+            tick_bytes=lambda rows, reach: 8 * rows * reach),
     }
     return {k: v for k, v in table.items() if v.layers}
 
@@ -325,6 +361,23 @@ def store_bytes(cfg: T.TransformerConfig, pool: Dict[str, Any]
     sequence's length."""
     return [(s, math.prod(pool[s.name].shape) * pool[s.name].dtype.itemsize)
             for _, s in pool_stores(cfg)]
+
+
+#: words of scalar memory a tick's block tables may take: the kernels hold
+#: them there beside the rows' slots and lengths, a row of a table padded to
+#: whole lane tiles of 128 words, and every call copies them in: an eighth
+#: of the 1 MB (262,144 words) a v5e core's holds
+TABLE_WORDS = 1 << 15
+
+
+def tick_tables(rows: int, blocks_a_table: int) -> int:
+    """The most sequences (its pad rows' trash sequence among them) a tick
+    of ``rows`` rows may hold where the calls take one table a sequence and
+    no store of the pool says how many slots there are (:func:`_tick_of`):
+    one a row, or as many tables of ``blocks_a_table`` entries as scalar
+    memory has room for (``TABLE_WORDS``). The engine holds its sequence
+    slots under it."""
+    return max(1, min(rows, TABLE_WORDS // (-(-blocks_a_table // 128) * 128)))
 
 
 def _slots(cfg: T.TransformerConfig, pool: Dict[str, jax.Array]) -> int:
@@ -400,7 +453,9 @@ def paged_attention_reference(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                               alibi: Optional[jax.Array] = None,
                               scale: Optional[float] = None,
                               window: Optional[int] = None,
-                              heads_first: bool = False) -> jax.Array:
+                              heads_first: bool = False,
+                              chosen: Optional[jax.Array] = None
+                              ) -> jax.Array:
     """Pure-XLA paged attention (the CPU/fallback path; the Pallas kernel in
     ``ops/pallas/paged_attention.py`` computes the same thing without
     materializing the gathered KV).
@@ -410,7 +465,9 @@ def paged_attention_reference(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     ``alibi``: [N] slopes — cache slot c IS absolute position c, so the
     bias is ``slope · (c − (lengths−1))`` (matches ``cached_attention``).
     ``scale`` / ``window`` / ``heads_first`` (pools [NB, K, bs, D]): as
-    the kernel's (``paged_attention``).
+    the kernel's (``paged_attention``). ``chosen`` [T, MB * bs] bool: token
+    t attends to cache slot c only where it is set (a sparse layer's
+    choice).
     """
     Tn, N, D = q.shape
     MB = tables.shape[1]
@@ -436,6 +493,8 @@ def paged_attention_reference(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     if window is not None:
         mask &= jnp.arange(MB * bs)[None, None, :] \
             >= lengths[:, None, None] - window
+    if chosen is not None:
+        mask &= chosen[:, None, :]
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("tnc,tcnd->tnd", p, vg.astype(jnp.float32)).astype(q.dtype)
@@ -600,9 +659,11 @@ class _Tick(NamedTuple):
     valid: jax.Array        # [T] bool: no pad row (its table is all trash)
     bs: int                 # positions of a block
     # a row's sequence slot (its table's first block) and one table a slot;
-    # a pool without slots: the row's own index, the rows' tables
+    # a pool without slots: the row's own index and the rows' tables, or,
+    # where the calls take a table a sequence all the same, the sequence's
+    # number in the tick (:func:`tick_tables`)
     slot: jax.Array         # [T]
-    by_slot: jax.Array      # [S1 or T, MB]
+    by_slot: jax.Array      # [S1, tick_tables + 1 or T, MB]
     S1: int                 # slots + 1; 0 without
     # where sequences keep state: their rows' runs, and the read and write
     # of a store of convolution inputs (:func:`_conv_rows`)
@@ -633,6 +694,16 @@ def _tick_of(cfg: T.TransformerConfig, kinds: Dict[str, CacheKind],
     if S1:
         slot = tables[:, 0]
         by_slot = jnp.zeros((S1, MB), jnp.int32).at[slot].set(tables)
+    elif any(e.attend and e.attend.by_slot for e in kinds.values()):
+        # a pool of blocks alone keeps nothing a slot, so the tick counts
+        # its sequences itself, from 1: a row starts one where its first
+        # block is not the row's before (row 0 of the table stays the
+        # trash block's, for rows a kernel pads its tiles with)
+        start = jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                                 tables[1:, 0] != tables[:-1, 0]])
+        slot = jnp.cumsum(start, dtype=jnp.int32)
+        by_slot = jnp.zeros((tick_tables(Tn, MB) + 1, MB), jnp.int32
+                            ).at[slot].set(tables, mode="drop")
     if any(s.cls in (SLOT, CONV) for _, s in pool_stores(cfg)):
         runs = HY.runs_of(slot, positions)
         conv = _conv_rows(S1, slot, runs.last & (slot > 0))
@@ -707,7 +778,7 @@ def _kv_cache(tick: _Tick, pool: Dict[str, jax.Array], entry: CacheKind
         tables = tick.by_slot if call.by_slot else tick.tables
         block = tick.block_idx
 
-    def write(flat, nth, k, v):
+    def write(flat, nth, k, v, **also):
         base = nth * rows_a_layer
         at, new = base + block, dict(flat)
         # blocked KV write (reference ragged_ops KV-copy kernels): token t
@@ -721,9 +792,14 @@ def _kv_cache(tick: _Tick, pool: Dict[str, jax.Array], entry: CacheKind
         for name, x in zip(call.stores, (k, v)):
             new[name] = flat[name].at[index].set(
                 x.astype(flat[name].dtype), mode="drop")
+        # what else the kind keeps a position, a row each: same place
+        for name, x in also.items():
+            new[name] = flat[name].at[at, tick.offsets].set(
+                x.astype(flat[name].dtype), mode="drop")
         return new, tables + base
 
-    def attend(q, flat, tables, scale=None, **bias):
+    def attend(q, flat, tables, scale=None, chosen=None, **bias):
+        """``chosen``: a sparse layer's choice."""
         k, v = (flat[name] for name in call.stores)
         with contextlib.nullcontext() if call.scope is None \
                 else jax.named_scope(call.scope):
@@ -733,7 +809,10 @@ def _kv_cache(tick: _Tick, pool: Dict[str, jax.Array], entry: CacheKind
                     window=call.window, heads_first=call.heads_first,
                     name=call.name,
                     mxu_dtype=q.dtype if call.own_dtype else jnp.float32,
-                    row_table=tick.slot if call.by_slot else None)
+                    row_table=tick.slot if call.by_slot else None,
+                    **({} if chosen is None else {"chosen": chosen}))
+            if chosen is not None:
+                bias = {**bias, "chosen": chosen}
             return tick.attend(
                 q, k, v, tables[tick.slot] if call.by_slot else tables,
                 tick.lengths, scale=scale, window=call.window,
@@ -778,6 +857,175 @@ def _grouped_mixer(cfg, tick, pool, entry, kind):
         return attn, flat, acts
 
     return mixer
+
+
+def sparse_choice(scores: jax.Array, pos: jax.Array, lengths: jax.Array,
+                  topk: int, axes: Tuple[int, ...], reach: int) -> jax.Array:
+    """The positions each row of a tick attends to in a ``sparse`` layer,
+    as a mask of ``scores``' shape: of a row's positions under its length
+    the ``topk`` of the largest score, the lower position first among
+    equals; all of them while it has no more than ``topk``. EXACT: the
+    cut is the ``topk``-th largest score itself, found by a bisection over
+    the scores' bits (32 counts of the entries at or over a candidate, the
+    high halves of the words and then the low; the float32 order is the
+    order of the bits once a negative's are flipped),
+    and where equal scores straddle the cut a second bisection, over
+    positions, keeps the lowest of them. No sort: a row's 18k scores are
+    never ordered, only counted.
+
+    ``scores`` float32 in any layout; ``pos``: each entry's position,
+    ``lengths``: each row's length, both broadcast against it; ``axes``:
+    the axes that run over a row's positions; ``reach``: positions lie
+    under it. Nothing is chosen, and no count made, in a tick none of
+    whose rows is longer than ``topk``."""
+    valid = pos < lengths
+
+    def count(x):
+        return jnp.sum(x, axis=axes, keepdims=True, dtype=jnp.int32)
+
+    def pick():
+        bits = lax.bitcast_convert_type(scores, jnp.int32)
+        # ascending in the score as an unsigned word; 0 is below every
+        # valid entry's
+        u = lax.bitcast_convert_type(
+            bits ^ ((bits >> 31) & 0x7fffffff), jnp.uint32) \
+            ^ jnp.uint32(0x80000000)
+        u = jnp.where(valid, jnp.maximum(u, jnp.uint32(1)), jnp.uint32(0))
+
+        def bisect(half, ahead):
+            """The largest 16-bit ``h`` with ``ahead + count(half >= h) >=
+            topk``, a bit a pass."""
+            def bit(i, cut):
+                cand = cut | (jnp.uint16(1) << (15 - i).astype(jnp.uint16))
+                return jnp.where(ahead + count(half >= cand) >= topk,
+                                 cand, cut)
+
+            return lax.fori_loop(0, 16, bit, jnp.zeros_like(
+                count(valid), jnp.uint16))
+
+        # a word's halves one after the other: 32 passes over 16-bit
+        # entries where the words whole would be read 32 times (a chunk
+        # tick's 2,048 x 18k scores: 7.5 ms a layer on the v5e, bound by
+        # the bytes)
+        high = (u >> 16).astype(jnp.uint16)
+        cut_high = bisect(high, 0)
+        low = jnp.where(high == cut_high, u.astype(jnp.uint16),
+                        jnp.uint16(0))
+        cut_low = bisect(low, count(high > cut_high))
+        cut = (cut_high.astype(jnp.uint32) << 16) \
+            | cut_low.astype(jnp.uint32)
+        over, equal = u > cut, (u == cut) & valid
+        room = topk - count(over)
+
+        def lowest():
+            # the largest P with no more than ``room`` equals under it
+            def bit(i, P):
+                cand = P | (1 << (n_bits - 1 - i))
+                return jnp.where(count(equal & (pos < cand)) <= room,
+                                 cand, P)
+
+            n_bits = max(reach, 2).bit_length()
+            P = lax.fori_loop(0, n_bits, bit, jnp.zeros_like(room))
+            return over | (equal & (pos < P))
+
+        return valid & lax.cond(jnp.any(count(equal) > room), lowest,
+                                lambda: over | equal)
+
+    return lax.cond(jnp.any(lengths > topk), pick, lambda: valid)
+
+
+def _sparse_mixer(cfg, tick, pool, entry, kind):
+    """Grouped-query attention over the positions a learned indexer
+    chooses (``cfg.sparse_topk`` a row; DeepSeek sparse attention). Keys
+    and values are written as a ``full`` layer's, and beside them EVERY
+    position's index key (``index_row_width``), whether or not the tick's
+    rows are long enough to choose; then three parts, a scope each:
+
+    * ``index``: the indexer's projections and its scores of every row
+      against its sequence's positions (``ops.pallas.index_scores``: the
+      store walked once a run of rows that share a table);
+    * ``select``: the exact ``topk`` of each row's own scores, as a mask
+      (:func:`sparse_choice`);
+    * ``sparse``: a row walks its sequence's blocks as a ``full`` layer's
+      rows do and takes the choice as one more term of every step's mask
+      (``paged_attention(chosen=)``): it reads every position where it
+      attends to ``topk`` (the span's ``sparse_positions_read`` against
+      ``sparse_selected`` says by how much). A decode row as a chunk's: a
+      gather of what a decode row chose was slower at the 17k positions
+      it was measured at (PERF.md, PR 46) and is not here.
+
+    Without kernels the same three parts in plain jnp."""
+    Tn = tick.positions.shape[0]
+    topk = cfg.sparse_topk
+    write, attend = _kv_cache(tick, pool, entry)
+    bs, MB = tick.bs, tick.tables.shape[1]
+    N, D = cfg.num_heads, cfg.head_dim
+    pad = index_row_width(cfg) - cfg.index_head_dim
+    rope = T.rope_table(bs * MB, cfg.index_head_dim, cfg.rope_theta,
+                        cfg.rope_scaling_dict)
+    from deepspeed_tpu.ops.pallas import index_scores as IX
+
+    def mixer(h, lp, flat, li, nth, acts):
+        q, k, v = _project_qkv(cfg, h, lp, tick.positions,
+                               tick.rope.get(kind))
+        with jax.named_scope("index"):
+            qi, ki, w = (x[0] for x in T.index_projections(
+                h[None], lp, cfg, rope, tick.positions[None]))
+            qi = jnp.pad(qi, ((0, 0), (0, 0), (0, pad)))
+        flat, tables = write(flat, nth, k, v,
+                             idx=jnp.pad(ki, ((0, 0), (0, pad))))
+        if not tick.kernels:
+            with jax.named_scope("index"):
+                scores = IX.index_scores_reference(
+                    qi, w, flat["idx"], tables[tick.slot])
+            with jax.named_scope("select"):
+                chosen = sparse_choice(
+                    scores, jnp.arange(bs * MB, dtype=jnp.int32)[None],
+                    tick.lengths[:, None], topk, (1,), bs * MB)
+            attn = attend(q, flat, tables, chosen=chosen)
+            return attn.reshape(Tn, N * D), flat, acts
+        with jax.named_scope("index"):
+            # [S / C, T', C]: a lane tile of every row together
+            scores = IX.index_scores(qi, w, flat["idx"], tables,
+                                     tick.lengths, tick.slot)
+        nC, Tp, C = scores.shape
+        with jax.named_scope("select"):
+            chosen = sparse_choice(
+                scores,
+                (jnp.arange(nC, dtype=jnp.int32)[:, None, None] * C
+                 + jnp.arange(C, dtype=jnp.int32)),
+                jnp.pad(tick.lengths, (0, Tp - Tn))[None, :, None], topk,
+                (0, 2), nC * C).astype(jnp.float32)
+        attn = attend(q, flat, tables, chosen=chosen)
+        return attn.reshape(Tn, N * D), flat, acts
+
+    return mixer
+
+
+def _sparse_span(layers: int, topk: int, decode_rows: int,
+                 chunk_starts: List[int], rows: int, bucket: int,
+                 lengths) -> Dict[str, int]:
+    """What a tick's sparse layers score, choose and read, summed over
+    the tick's real rows and (but for the last) the layers: the positions
+    the indexer scores (a row's length) and those its walks fetch (a
+    decode row's length, a chunk's longest row's: its rows share a walk),
+    those chosen (``min(length, topk)``; the decode rows' apart), those
+    the attention meets for them (a row walks its sequence with the choice
+    as a mask: its length), and the rows longer than ``topk`` (the others
+    attend to all they have)."""
+    total = int(lengths.sum())
+    ends = chunk_starts[1:] + [rows]
+    chosen = lengths.clip(max=topk)
+    return dict(
+        sparse_layers=layers,
+        index_positions=layers * total,
+        index_walk_positions=layers * int(
+            lengths[:decode_rows].sum() + sum(
+                lengths[a:b].max() for a, b in zip(chunk_starts, ends))),
+        sparse_selected=layers * int(chosen.sum()),
+        sparse_selected_decode=layers * int(chosen[:decode_rows].sum()),
+        sparse_positions_read=layers * total,
+        sparse_rows_choosing=int((lengths > topk).sum()))
 
 
 def _differential_mixer(cfg, tick, pool, entry, kind):
@@ -884,7 +1132,7 @@ def _kda_mixer(cfg, tick, pool, entry, kind):
 
 
 def _kda_span(decode_rows: int, chunk_starts: List[int], rows: int,
-              bucket: int) -> Dict[str, int]:
+              bucket: int, *_) -> Dict[str, int]:
     """The rule's two forms by the program's own rule
     (``hybrid.delta_rule``): runs of one row, up to the one-row form's
     count, and the rows of every other run; the chunk form's grid steps by

@@ -233,6 +233,17 @@ class TransformerConfig:
     kda_head_dim: int = 0
     kda_rank: int = 0
     kda_conv: int = 4
+    # ``sparse`` layers among ``layer_kinds`` of the standard block (the
+    # ``KeyeVL2`` family; DeepSeek sparse attention on grouped queries): a
+    # learned INDEXER of ``index_heads`` query heads and one key head, each
+    # ``index_head_dim`` wide, scores every earlier position for a row,
+    # ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``, and the row
+    # attends to the ``sparse_topk`` positions of the largest score alone
+    # (the lower position first among equals; to all while it has no
+    # more). A position's index key is cached beside its key and value
+    sparse_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -307,7 +318,9 @@ class TransformerConfig:
         what a layer sees and where its cache lives (``window``: its last
         ``attn_window`` positions, a per-sequence ring; ``full``: every
         position, a block range of its own; ``latent``: every position, a
-        range of latent blocks of its own, where ``mla``), and which
+        range of latent blocks of its own, where ``mla``; ``sparse``: the
+        ``sparse_topk`` positions its indexer chooses, a block range of
+        keys, values and index keys), and which
         attention it computes is the config's. A ``conv`` or ``kda`` layer
         has the block's norms and its FFN or experts around a mixer of its
         own with a state a sequence slot (:func:`mixer_of`: in a segment
@@ -318,7 +331,7 @@ class TransformerConfig:
         (``models/hybrid.py``, ``params[key][kind]``)."""
         return bool(self.layer_kinds) and not self.ssm_inner \
             and set(self.layer_kinds) <= {"window", "full", "conv", "kda",
-                                          "latent"}
+                                          "latent", "sparse"}
 
     @property
     def mixer_layers(self) -> Dict[str, int]:
@@ -430,6 +443,11 @@ class TransformerConfig:
                 per_layer += h * qdim
         if self.qk_norm:
             per_layer += 2 * self.head_dim
+        if "sparse" in self.layer_kinds:
+            # the indexer: its queries, its one key under a LayerNorm, the
+            # heads' weights
+            hi, di = self.index_heads, self.index_head_dim
+            per_layer += h * hi * di + h * di + 2 * di + h * hi
         attn = per_layer
         ffn_mats = 3 if self.activation == "swiglu" else 2
         if self.n_experts > 0:
@@ -492,6 +510,16 @@ def _check_kinds_of_blocks(cfg: TransformerConfig) -> None:
             "beside `kda` or `conv` layers: latent and grouped-query "
             "attention layers in one stack, and a latent layer under a "
             f"window, are not written (mla={cfg.mla}, kinds {sorted(kinds)})")
+    if "sparse" in kinds and (
+            kinds != {"sparse"} or cfg.mla or cfg.pos_emb != "rope"
+            or not (cfg.sparse_topk and cfg.index_heads
+                    and cfg.index_head_dim)):
+        raise NotImplementedError(
+            "sparse layers are a whole stack of grouped-query layers under "
+            "rotary (every layer holds an indexer's leaves), with "
+            "sparse_topk, index_heads and index_head_dim (got kinds "
+            f"{sorted(kinds)}, mla={cfg.mla}, pos_emb={cfg.pos_emb!r}, "
+            f"{cfg.sparse_topk}, {cfg.index_heads}, {cfg.index_head_dim})")
     own = kinds & set(MIXERS[1:])
     if own and (cfg.attn_bias_enabled or cfg.use_bias or cfg.attn_gate
                 or cfg.post_norms or cfg.parallel_block):
@@ -580,6 +608,14 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     if cfg.qk_norm:
         block["q_norm"] = jnp.ones((La, cfg.head_dim), jnp.float32)
         block["k_norm"] = jnp.ones((La, cfg.head_dim), jnp.float32)
+    if "sparse" in cfg.layer_kinds:
+        hi, di = cfg.index_heads, cfg.index_head_dim
+        block.update({
+            "idx_wq": dense(jax.random.fold_in(rng, 18), (L, h, hi * di), std),
+            "idx_wk": dense(jax.random.fold_in(rng, 19), (L, h, di), std),
+            "idx_ww": dense(jax.random.fold_in(rng, 20), (L, h, hi), std),
+            "idx_k_norm": {"scale": jnp.ones((L, di), jnp.float32),
+                           "bias": jnp.zeros((L, di), jnp.float32)}})
     E = cfg.n_experts
     if E > 0:
         # MoE FFN: per-expert weights (no biases), router gate per layer
@@ -695,6 +731,12 @@ def param_logical_axes(cfg: TransformerConfig) -> PyTree:
     if cfg.qk_norm:
         block["q_norm"] = lyr + (None,)
         block["k_norm"] = lyr + (None,)
+    if "sparse" in cfg.layer_kinds:
+        block.update({"idx_wq": lyr + ("embed", None),
+                      "idx_wk": lyr + ("embed", None),
+                      "idx_ww": lyr + ("embed", None),
+                      "idx_k_norm": {"scale": lyr + (None,),
+                                     "bias": lyr + (None,)}})
     if cfg.n_experts > 0:
         block["gate_w"] = lyr + ("embed", None)
         block["w_up"] = lyr + ("expert", "embed", "mlp")
@@ -1122,6 +1164,57 @@ def _mla_absorbed_attention(q: jax.Array, ckv: jax.Array, kpe: jax.Array,
     return jnp.einsum("btnk,knd->btnd", out_lat, w_uv)   # [B,T,N,dv]
 
 
+def index_projections(h: jax.Array, lp: Dict[str, jax.Array],
+                      cfg: TransformerConfig,
+                      rope: Tuple[jax.Array, jax.Array],
+                      positions: Optional[jax.Array] = None
+                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A ``sparse`` layer's indexer from its normed rows ``h [B, S, H]``:
+    (queries ``[B, S, heads, d]``, the one key ``[B, S, d]`` under a
+    LayerNorm with gain and bias, the heads' weights ``[B, S, heads]``),
+    queries and key rotated over their ``d`` columns at ``positions [B,
+    S]`` (None: 0 .. S-1) by ``rope``, the cos and sin tables of ``d``
+    columns."""
+    dt = cfg.compute_dtype
+    B, S = h.shape[:2]
+    q = (h @ lp["idx_wq"].astype(dt)).reshape(
+        B, S, cfg.index_heads, cfg.index_head_dim)
+    k = _norm(h @ lp["idx_wk"].astype(dt), lp["idx_k_norm"], "layernorm",
+              cfg.norm_eps)[:, :, None, :]
+    if positions is None:
+        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+    else:
+        q = apply_rope_at(q, *rope, positions)
+        k = apply_rope_at(k, *rope, positions)
+    return q, k[:, :, 0], h @ lp["idx_ww"].astype(dt)
+
+
+def index_scores(q: jax.Array, k: jax.Array, w: jax.Array) -> jax.Array:
+    """``I[b, t, s] = sum_j w[b, t, j] relu(q[b, t, j] . k[b, s])`` in
+    float32, ``[B, S, S]``: what :func:`index_projections` returns, every
+    row against every position (a caller keeps the causal part)."""
+    s = jnp.einsum("btjd,bsd->btjs", q, k,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("btjs,btj->bts", jax.nn.relu(s),
+                      w.astype(jnp.float32))
+
+
+def chosen_positions(scores: jax.Array, topk: int) -> jax.Array:
+    """The positions each row of a causal batch attends to, ``[B, S, S]``
+    bool: of ``scores [B, S, S]`` the ``topk`` largest among ``s <= t``,
+    the lower position first among equals (``lax.top_k``'s own order);
+    every ``s <= t`` while ``t + 1 <= topk``."""
+    S = scores.shape[-1]
+    causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
+    if S <= topk:
+        return jnp.broadcast_to(causal, scores.shape)
+    _, idx = lax.top_k(jnp.where(causal, scores, -jnp.inf), topk)
+    rows = jnp.arange(S)[None, :, None]
+    picked = jnp.zeros(scores.shape, jnp.bool_).at[
+        jnp.arange(scores.shape[0])[:, None, None], rows, idx].set(True)
+    return picked & causal
+
+
 def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig,
                    cos: Optional[jax.Array], sin: Optional[jax.Array],
                    attention_fn: AttentionFn, kind: Optional[str] = None
@@ -1133,6 +1226,8 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
     ``cfg.standard_blocks``): ``window`` sees its last ``cfg.attn_window``
     positions, ``full`` every one, both under an explicit mask in plain jnp
     (the flash kernel has no window), whatever ``attention_fn`` says;
+    ``sparse`` the positions its indexer chooses (:func:`index_scores`,
+    :func:`chosen_positions`);
     ``conv`` has a gated short convolution where the others attend
     (``hybrid.short_conv``; ``lp`` then holds that mixer's leaves), ``kda``
     Kimi Delta Attention (``hybrid.kda_inputs`` .. ``kda_output``, every
@@ -1239,9 +1334,18 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         else:
             from deepspeed_tpu.models.hybrid import windowed_attention
 
+            chosen = None
+            if kind == "sparse":
+                with jax.named_scope("index"):
+                    scores = index_scores(*index_projections(
+                        h, lp, cfg, rope_table(
+                            S, cfg.index_head_dim, cfg.rope_theta,
+                            cfg.rope_scaling_dict)))
+                with jax.named_scope("select"):
+                    chosen = chosen_positions(scores, cfg.sparse_topk)
             attn = windowed_attention(
                 q, k, v, cfg.head_dim ** -0.5,
-                cfg.attn_window if kind == "window" else 0)
+                cfg.attn_window if kind == "window" else 0, chosen)
         attn = attn.reshape(B, S, cfg.num_heads * cfg.head_dim)
         if cfg.attn_gate:
             attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))

@@ -198,7 +198,8 @@ def count_steps(lengths, starts, tile: int, step: int,
 
 def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
             n_pool: int, scale: float, mxu_dtype, window: Optional[int],
-            heads_first: bool, indirect: bool = False):
+            heads_first: bool, indirect: bool = False,
+            chosen: bool = False):
     """``heads_first``: a pool block is ``[K, bs, D]`` and not ``[bs, K,
     D]`` (a head count off the sublane tiling, 10 say, cannot be the
     second-minor dim of a block a copy slices); the fetch slots are then
@@ -213,8 +214,16 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     (with one pool the value is the leading columns of the key's block:
     latent attention, whose value is the latent itself), the output, then
     the scratch: a fetch buffer per pool, the semaphores, the queries
-    head-major, lengths, softmax statistics and accumulator."""
-    pools, o_ref = refs[:n_pool], refs[n_pool]
+    head-major, lengths, softmax statistics and accumulator.
+    ``chosen``: behind the pools lies the tile's choice, ``[steps, R, C]``
+    (nonzero: row r of the tile attends to position ``step * C + c``; a
+    sparse layer's), and EVERY step of a walk takes the masked form with
+    the choice as one more term of its mask: a third form of a step beside
+    open and edge. Every other instantiation traces what it traced."""
+    pools = refs[:n_pool]
+    chosen_ref = refs[n_pool] if chosen else None
+    refs = refs[int(chosen):]
+    o_ref = refs[n_pool]
     bufs = refs[n_pool + 1:2 * n_pool + 1]
     sems, q3_ref, len_ref, m_ref, l_ref, acc_ref = refs[2 * n_pool + 1:]
     if heads_first:
@@ -309,24 +318,35 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
         x = buf[slot].astype(mxu_dtype)
         return jnp.swapaxes(x.reshape(C, K, D), 0, 1)
 
-    def online_softmax(i, rows, limit, slot):
+    # bfloat16 products are pinned to the one precision Mosaic has for
+    # them, whatever ``jax.default_matmul_precision`` says around the call
+    # (under "highest" it refused them: ``Bad lhs type``); float32 products
+    # take the context's, as they did
+    precision = jax.lax.Precision.DEFAULT if mxu_dtype == jnp.bfloat16 \
+        else None
+
+    def online_softmax(i, rows, limit, slot, pick=None):
         """Fetch step ``i`` of the query rows ``rows`` (a slice of the
         tile's ``R*rep``) against the keys and values in ``slot``.
         ``limit`` broadcasts against the ``[K, rows, C]`` scores:
         a row sees the columns under it (and, with a window, no more than
         ``window`` of them); None on an OPEN step, whose every column
-        every row sees: no mask is built and nothing selected."""
+        every row sees: no mask is built and nothing selected. ``pick(i)``:
+        the rows' choice among the step's columns, one more term of the
+        mask."""
         kt = head_major(bufs[0], slot)
         vt = kt[:, :, :Dv] if n_pool == 1 else head_major(bufs[1], slot)
         s = jax.lax.dot_general(
             q3_ref[:, rows, :].astype(mxu_dtype), kt,
-            (((2,), (2,)), ((0,), (0,))),
+            (((2,), (2,)), ((0,), (0,))), precision=precision,
             preferred_element_type=jnp.float32) * scale
         if limit is not None:
             col = i * C + jax.lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
             live = col < limit
             if window is not None:
                 live &= col >= limit - window
+            if pick is not None:
+                live &= pick(i)
             s = jnp.where(live, s, NEG_INF)
         m_prev = m_ref[:, rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -339,7 +359,7 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
         alpha = jnp.exp(m_prev - m_new)
         pv = jax.lax.dot_general(
             p.astype(mxu_dtype), vt, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+            precision=precision, preferred_element_type=jnp.float32)
         m_ref[:, rows, :] = m_new
         l_ref[:, rows, :] = alpha * l_ref[:, rows, :] + jnp.sum(
             p, axis=2, keepdims=True)
@@ -379,7 +399,7 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
         begin, first, last, end = step_ranges(
             lo, hi, C, window, alone | (r1 - r0 == R), jnp)
 
-        def steps(start, stop, rows, limit):
+        def steps(start, stop, rows, limit, pick=None):
             def step(i, _):
                 slot = i % 2
 
@@ -388,24 +408,44 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
                     fetch(t, nblk, i + 1, 1 - slot, True)
 
                 fetch(t, nblk, i, slot, False)
-                online_softmax(i, rows, limit, slot)
+                online_softmax(i, rows, limit, slot, pick)
 
             jax.lax.fori_loop(start, stop, step, None)
 
-        def attend(rows, limit):
+        def attend(rows, limit, pick=None):
+            if chosen:                     # no step of a choice is open
+                return steps(begin, end, rows, limit, pick)
             if window is not None:         # else the walk begins open
                 steps(begin, first, rows, limit)
             steps(first, last, rows, None)
             steps(last, end, rows, limit)
 
         def alone_form():
-            attend(pl.ds(r0 * rep, rep), length(r0))
+            pick = None
+            if chosen:                     # the row's own plane of a step
+                pick = lambda i: (chosen_ref[i, pl.ds(r0, 1), :]  # noqa: E731
+                                  != 0)[None]                   # [1, 1, C]
+            attend(pl.ds(r0 * rep, rep), length(r0), pick)
 
         def tile_form():
             row = jax.lax.broadcasted_iota(jnp.int32, (1, M, 1), 1)
             limit = jnp.where((row >= r0 * rep) & (row < r1 * rep),
                               len_ref[:, 0:1][None], 0)         # [1, M, 1]
-            attend(slice(None), limit)
+            pick = None
+            if chosen:
+                # a row's choice is its ``rep`` query rows': a product with
+                # ``E[m, r] = (m // rep == r)`` repeats the step's ``[R, C]``
+                # plane ``rep`` times a row on the MXU, exactly (one term a
+                # sum), where a repeat along sublanes is a relayout
+                m = jax.lax.broadcasted_iota(jnp.int32, (M, R), 0)
+                r = jax.lax.broadcasted_iota(jnp.int32, (M, R), 1)
+                spread = ((m >= r * rep) & (m < (r + 1) * rep)).astype(
+                    chosen_ref.dtype)
+                pick = lambda i: (jax.lax.dot_general(        # noqa: E731
+                    spread, chosen_ref[i], (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.DEFAULT,
+                    preferred_element_type=jnp.float32) > 0.5)[None]
+            attend(slice(None), limit, pick)
 
         fetch(t, nblk, begin, begin % 2, True)
         jax.lax.cond(alone, alone_form, tile_form)
@@ -447,7 +487,8 @@ def _geometry(q, pools, value_dim, heads_first):
     "value_dim", "scale", "name", "mxu_dtype", "interpret", "window",
     "heads_first", "indirect"))
 def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
-           interpret, window=None, heads_first=False, indirect=False):
+           interpret, window=None, heads_first=False, indirect=False,
+           chosen=None):
     """The kernel over whole tiles: ``pools`` the key pool and the value
     pool, or the key pool alone where the value is the first ``value_dim``
     columns of the key's block. Jitted (inlined into the caller's program)
@@ -467,7 +508,11 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
         grid=(T // R,),
         in_specs=[pl.BlockSpec((R,) + q.shape[1:],
                                lambda i, tbl, meta: (i, 0, 0))]
-        + [hbm] * len(pools),
+        + [hbm] * len(pools)
+        # a tile's choice, every step's plane of it, lies in VMEM
+        + ([] if chosen is None else [pl.BlockSpec(
+            (chosen.shape[0], R, chosen.shape[2]),
+            lambda i, tbl, meta: (0, i, 0))]),
         out_specs=pl.BlockSpec((R, N, value_dim),
                                lambda i, tbl, meta: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM(
@@ -490,23 +535,32 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
     return pl.pallas_call(
         functools.partial(_kernel, n_pool=len(pools), scale=scale,
                           mxu_dtype=mxu_dtype, window=window,
-                          heads_first=heads_first, indirect=indirect),
+                          heads_first=heads_first, indirect=indirect,
+                          chosen=chosen is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N, value_dim), q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
         name=name,
-    )(tables, meta, q, *pools)
+    )(tables, meta, q, *pools, *(() if chosen is None else (chosen,)))
 
 
 def _walk(q, pools, tables, lengths, *, value_dim, scale, name, mxu_dtype,
-          interpret, window=None, heads_first=False, row_table=None):
+          interpret, window=None, heads_first=False, row_table=None,
+          chosen=None):
     """Pads the rows to whole tiles, says which rows share a table, and
     runs the kernel: what both entry points below are."""
     if interpret is None:
         interpret = _use_interpret()
     Tn, N, _ = q.shape
     pad = -Tn % tile_rows(N, value_dim)
+    if chosen is not None:
+        assert row_table is not None and window is None
+        K, bs, _, P = _geometry(q, pools, value_dim, heads_first)
+        assert chosen.shape[1] >= Tn + pad and chosen.shape[2] == P * bs \
+            and chosen.shape[0] * P * bs >= tables.shape[1] * bs, (
+                chosen.shape, Tn + pad, P * bs, tables.shape)
+        chosen = chosen[:, :Tn + pad]
     if pad:                            # pad rows: zero table, length 1
         q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
         if row_table is None:
@@ -526,7 +580,8 @@ def _walk(q, pools, tables, lengths, *, value_dim, scale, name, mxu_dtype,
         return _tiles(tables, meta, q, *pools, value_dim=value_dim,
                       scale=scale, name=name, mxu_dtype=mxu_dtype,
                       interpret=interpret, window=window,
-                      heads_first=heads_first, indirect=True)[:Tn]
+                      heads_first=heads_first, indirect=True,
+                      chosen=chosen)[:Tn]
     # every table tier of a tick bucket hands the kernel the same shapes, so
     # its body is traced once a bucket, not once a (Tn, mb) program; the
     # columns added are never read (walks end at ceil(length / bs))
@@ -549,7 +604,8 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                     heads_first: bool = False,
                     name: str = "paged_attention",
                     mxu_dtype=jnp.float32,
-                    row_table: Optional[jax.Array] = None) -> jax.Array:
+                    row_table: Optional[jax.Array] = None,
+                    chosen: Optional[jax.Array] = None) -> jax.Array:
     """Drop-in for ``models.paged.paged_attention_reference``. ``scale``:
     the scores' factor where it is not ``D ** -0.5``; ``window``: each row
     attends to its last ``window`` positions alone, and the table may then
@@ -561,14 +617,27 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     homogeneous cells are not MXU-bound; bfloat16 where a long chunk
     against a long context is); ``row_table`` [T]: ``tables`` is then one
     table a SEQUENCE, ``[sequences, MB]`` with row 0 the pad rows', and
-    this names each row's (rows of one sequence are told by it)."""
+    this names each row's (rows of one sequence are told by it);
+    ``chosen`` [steps, T', C] (``T'``: the rows up to whole tiles; ``C``:
+    a fetch step's positions, :func:`step_positions`; with ``row_table``):
+    row t attends to position ``step * C + c`` only where the entry is
+    nonzero (and the position lies under its length): a sparse layer's
+    choice, which every step of the walk then takes as a mask."""
     D, K = q.shape[2], kpool.shape[1 if heads_first else 2]
     assert D == kpool.shape[3] and q.shape[1] % K == 0
     return _walk(q, (kpool, vpool), tables, lengths, value_dim=D,
                  scale=D ** -0.5 if scale is None else scale,
                  name=name, mxu_dtype=mxu_dtype, interpret=interpret,
                  window=window, heads_first=heads_first,
-                 row_table=row_table)
+                 row_table=row_table, chosen=chosen)
+
+
+def step_positions(q, kpool, vpool) -> int:
+    """Cache positions a fetch step of :func:`paged_attention` meets, and
+    so the minor dimension of ``chosen``: the kernel's own rule of the
+    operands' shapes (arrays or their ``ShapeDtypeStruct``)."""
+    _, bs, _, P = _geometry(q, (kpool, vpool), q.shape[2], False)
+    return bs * P
 
 
 def latent_paged_attention(q: jax.Array, pool: jax.Array,

@@ -387,6 +387,138 @@ def _copies_tool():
     return tool
 
 
+# (cell, rows of a tick bucket, its table tier): both tick programs of the
+# cell of sparse layers, whole, at its real sizes: keys, values and index
+# keys ride the tick in place, and neither the walks nor the scatter at
+# (block, offset) copies a store (a layer's share of any of the three is
+# over 100 MB)
+SPARSE_TICKS = {
+    "keye-vl2-256x144": ("serve-keye-vl2-30b-longctx-closed", 256, 144),
+    "keye-vl2-2048x144": ("serve-keye-vl2-30b-longctx-closed", 2048, 144),
+    # the most rows against the narrowest tables: the most tables a tick
+    # takes into scalar memory (``paged.tick_tables``; a table a row of
+    # this program does not fit there)
+    "keye-vl2-2048x36": ("serve-keye-vl2-30b-longctx-closed", 2048, 36),
+}
+
+
+@pytest.mark.parametrize("program", sorted(SPARSE_TICKS))
+def test_a_tick_of_sparse_layers_copies_no_store(one_chip, program):
+    import math
+
+    tool = _copies_tool()
+    cell, rows, tier = SPARSE_TICKS[program]
+    cfg, sizes, programs = tool.cell_programs(cell)
+    assert (rows, tier) in programs
+    lowered, pool = tool.lower_tick(cfg, sizes, rows, tier, one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    stores = [math.prod(pool[name].shape) for name in ("k", "v", "idx")]
+    found = tool.count_copies(text, stores + [n // cfg.num_layers
+                                              for n in stores])
+    assert found["remat"] == {} and found["whole_store_copies"] == {}
+    held = sum(math.prod(x.shape) * x.dtype.itemsize for x in pool.values())
+    stats = compiled.memory_analysis()
+    assert held <= stats.alias_size_in_bytes < 1.0001 * held
+    # the scores and the choice of a chunk tick are the largest things a
+    # tick holds: 2 x 4 B x rows x 18,432, well inside what the engine
+    # reserves for them (``CacheKind.tick_bytes`` a layer)
+    assert stats.temp_size_in_bytes < 3 * 8 * rows * 18432 + (160 << 20)
+    # the two Mosaic calls a layer, by the names the benchmark reads
+    assert "%index_scores" in text and "%sparse_attention" in text
+
+
+# (rows of the tick bucket, table tier) of the cell of sparse layers: 32
+# query heads on 4 KV heads of 128; the indexer's 16 heads against index
+# keys stored 128 wide, 6 layers x 4,353 blocks of 128, ONE table a slot
+SPARSE_SHAPES = {"keye-vl2-2048x144": (2048, 144),
+                 "keye-vl2-256x36": (256, 36)}
+
+
+@pytest.mark.parametrize("shape", sorted(SPARSE_SHAPES))
+def test_sparse_layer_kernels_compile_for_v5e(one_chip, shape):
+    from deepspeed_tpu.ops.pallas.index_scores import index_scores
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    T, MB = SPARSE_SHAPES[shape]
+    NB, bf = 6 * 4353, jnp.bfloat16
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    text = jax.jit(
+        lambda q, w, store, t, n, s: index_scores(q, w, store, t, n, s,
+                                                  interpret=False)
+    ).lower(arg((T, 16, 128), bf), arg((T, 16), bf), arg((NB, 128, 128), bf),
+            arg((29, MB), jnp.int32), arg((T,), jnp.int32),
+            arg((T,), jnp.int32)).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%index_scores" in text
+    steps = MB                      # blocks of 128: a lane tile a block
+    pool = arg((NB, 128, 4, 128), bf)
+
+    def lowered(choice):
+        return jax.jit(
+            lambda q, k, v, t, n, s, c: paged_attention(
+                q, k, v, t, n, interpret=False, name="sparse_attention",
+                mxu_dtype=bf, row_table=s, chosen=c)
+        ).lower(arg((T, 32, 128), bf), pool, pool, arg((29, MB), jnp.int32),
+                arg((T,), jnp.int32), arg((T,), jnp.int32),
+                arg((steps, T, 128), choice))
+
+    text = lowered(jnp.float32).compile().as_text()
+    # one Mosaic call, the pool still at operand 3, the choice behind it
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%sparse_attention" in text
+    if T == 256:
+        # a row alone reads its plane of the choice at a run-time sublane,
+        # which a 16-bit plane refuses: the choice is float32
+        with pytest.raises(Exception, match="multiple of 8"):
+            lowered(bf).compile()
+
+
+@pytest.mark.parametrize("kernel", ["latent", "global"])
+def test_bfloat16_products_are_pinned_whatever_the_context(one_chip, kernel):
+    """``jax.default_matmul_precision("highest")`` around a call (the
+    float32 witness of a probe sets it) reached the kernel's products of
+    bfloat16 operands, which Mosaic then refused (``Bad lhs type``: PERF.md
+    section 7, of PRs 41-43). Their precision is pinned inside the kernel:
+    the served type's program is the same text with the context and
+    without."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        latent_paged_attention, paged_attention)
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    bf, ints = jnp.bfloat16, (arg((29, 128), jnp.int32),
+                              arg((256,), jnp.int32), arg((256,), jnp.int32))
+    if kernel == "latent":
+        fn = jax.jit(lambda q, pool, t, n, w: latent_paged_attention(
+            q, pool, t, n, 512, 192 ** -0.5, interpret=False, row_table=w))
+        args = (arg((256, 32, 640), bf), arg((8192, 32, 640), bf)) + ints
+    else:
+        fn = jax.jit(lambda q, k, v, t, n, w: paged_attention(
+            q, k, v, t, n, interpret=False, name="global_attention",
+            mxu_dtype=bf, row_table=w))
+        pool = arg((12288, 32, 8, 128), bf)
+        args = (arg((256, 48, 128), bf), pool, pool) + ints
+    # (a Mosaic call serialises its body with its call stack, and the
+    # ``with`` below is a line of it)
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        plain = fn.lower(*args).compile().as_text()
+        jax.clear_caches()
+        with jax.default_matmul_precision("highest"):
+            under = fn.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    # (but for what of the text is its source's: metadata, names)
+    same = _copies_tool().normalised
+    assert same(plain) == same(under)
+
+
 @pytest.mark.parametrize("program", sorted(TICK_PROGRAMS))
 def test_a_tick_re_lays_no_state_store(one_chip, program):
     import math

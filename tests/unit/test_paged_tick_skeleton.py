@@ -271,6 +271,7 @@ CELLS = {
     "serve-trinity-large-agentctx-closed": "window-and-full",
     "serve-lfm2-24b-concurrent-closed": "short-convolution",
     "serve-kimi-linear-48b-rollout-closed": "delta-rule",
+    "serve-keye-vl2-30b-longctx-closed": "learned-sparse",
 }
 #: what a toy pool is built with: blocks, block size, slots, longest run
 TOY = (64, 8, 3, 16)
@@ -321,6 +322,15 @@ POOLS = {
     "toy:serve-kimi-linear-48b-rollout-closed": {
         "kda": ((6, 4, 2, 128, 128), F32), "kda_conv": ((72, 768), BF),
         "latent": ((2, 64, 8, 128), BF)},
+    # (PR 46) keys, values and, a third store of a layer's block range,
+    # the indexer's key of every position, padded to the lanes; blocks
+    # alone: nothing a sequence slot
+    "serve-keye-vl2-30b-longctx-closed": {
+        "idx": ((6, 4353, 128, 128), BF), "k": ((6, 4353, 128, 4, 128), BF),
+        "v": ((6, 4353, 128, 4, 128), BF)},
+    "toy:serve-keye-vl2-30b-longctx-closed": {
+        "idx": ((6, 64, 8, 128), BF), "k": ((6, 64, 8, 2, 16), BF),
+        "v": ((6, 64, 8, 2, 16), BF)},
 }
 #: ``FastGenEngine._pool_bytes``: (block stores, per-slot state stores)
 BYTES = {
@@ -338,6 +348,8 @@ BYTES = {
     "toy:serve-lfm2-24b-concurrent-closed": (524288, 32768),
     "serve-kimi-linear-48b-rollout-closed": (3355443200, 3555901440),
     "toy:serve-kimi-linear-48b-rollout-closed": (262144, 3256320),
+    "serve-keye-vl2-30b-longctx-closed": (7702511616, 0),
+    "toy:serve-keye-vl2-30b-longctx-closed": (1179648, 0),
 }
 #: ``tick_walks``: (layers, window, cache positions a fetch step) a kind
 #: of kernel call
@@ -357,6 +369,8 @@ WALKS = {
     "toy:serve-lfm2-24b-concurrent-closed": [(2, None, 128)],
     "serve-kimi-linear-48b-rollout-closed": [(2, None, 512)],
     "toy:serve-kimi-linear-48b-rollout-closed": [(2, None, 2048)],
+    "serve-keye-vl2-30b-longctx-closed": [(6, None, 128)],
+    "toy:serve-keye-vl2-30b-longctx-closed": [(6, None, 128)],
 }
 
 

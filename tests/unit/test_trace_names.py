@@ -18,7 +18,7 @@ from deepspeed_tpu.inference.fastgen import FastGenEngine
 PALLAS = os.path.join(os.path.dirname(dst.__file__), "ops", "pallas")
 KERNEL_NAMES = {
     "flash_fwd", "flash_dq", "flash_dkv", "paged_attention",
-    "latent_paged_attention", "kda_step", "kda_chunk",
+    "latent_paged_attention", "kda_step", "kda_chunk", "index_scores",
     "block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv",
     "evoformer_attention", "fused_adam", "quantize_int8_blocks",
     "dequant_reduce", "rms_norm", "layer_norm"}
@@ -71,7 +71,7 @@ def test_every_pallas_call_is_named():
                     d.value for a, d in zip(node.args.kwonlyargs,
                                             node.args.kw_defaults)
                     if a.arg == "name" and isinstance(d, ast.Constant))
-    assert sites == 14
+    assert sites == 15
     assert literal == KERNEL_NAMES
 
 
@@ -218,3 +218,46 @@ def test_a_tick_with_conv_layers_sorts_the_mixers_time_apart():
     assert not any("/conv/" in s and "/attn/" in s for s in stacks)
     assert any(s.split("/conv/")[-1].startswith("dot_general")
                for s in stacks if "/conv/" in s)
+
+
+def test_a_tick_of_sparse_layers_tells_its_three_parts_apart():
+    """The ``KeyeVL2`` tick: a sparse layer's indexer, its choice and its
+    attention over the chosen under scopes of their own inside ``attn``
+    (``index_share_pct`` / ``select_share_pct`` /
+    ``sparse_attention_share_pct`` and ``roofline/sparse_attention.py`` read
+    them), the two Mosaic calls by name (``roofline/index_scores.py``
+    classifies by it), beside the expert layers' parts."""
+    import types
+
+    from deepspeed_tpu.models.hf_import import config_from_hf
+
+    cfg = config_from_hf(types.SimpleNamespace(
+        model_type="KeyeVL2", hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, head_dim=16, num_attention_heads=4,
+        num_key_value_heads=2, num_hidden_layers=2, num_experts=4,
+        router_experts=8, num_experts_per_tok=2, norm_topk_prob=True,
+        rms_norm_eps=1e-6, rope_theta=10000000,
+        rope_scaling={"mrope_section": [2, 3, 3], "rope_type": "default"},
+        sa_config={"indexer_head_dim": 8, "indexer_num_heads": 4,
+                   "indexer_num_kv_heads": 1, "topk": 16},
+        tie_word_embeddings=False, vocab_size=128,
+        max_position_embeddings=512))
+    eng = FastGenEngine(cfg, n_blocks=16, block_size=4, max_blocks_per_seq=8,
+                        token_budget=32, state_slots=2, seed=0,
+                        use_pallas_kernel=True)
+    tn, mb = 32, eng.max_blocks_per_seq
+    stacks = _stacks(eng._build_tick(tn, mb).lower(
+        eng.params, eng.pool, eng._pack_tick(
+            np.zeros((tn,), np.int32), np.zeros((tn,), np.int32),
+            np.zeros((tn, mb), np.int32), np.zeros((2,), np.uint32))),
+        "tick")
+    parts = {part for s in stacks for part in s.split("/")}
+    assert {"embed", "attn", "index", "select", "sparse", "router",
+            "experts", "lm_head", "sample"} <= parts
+    _assert_both_heads_are_scoped(stacks)
+    assert any("/attn/index/index_scores" in s for s in stacks)
+    assert any("/attn/sparse/sparse_attention" in s for s in stacks)
+    assert not any("/paged_attention" in s or "/global_attention" in s
+                   for s in stacks)
+    # the choice is plain XLA under its scope: compares and counts
+    assert any("/attn/select/" in s for s in stacks)

@@ -945,7 +945,8 @@ def _sparse_mixer(cfg, tick, pool, entry, kind):
       against its sequence's positions (``ops.pallas.index_scores``: the
       store walked once a run of rows that share a table);
     * ``select``: the exact ``topk`` of each row's own scores, as a mask
-      (:func:`sparse_choice`);
+      (``ops.pallas.sparse_choice``: a tile of rows' scores read once and
+      counted in VMEM; :func:`sparse_choice` is its plain form);
     * ``sparse``: a row walks its sequence's blocks as a ``full`` layer's
       rows do and takes the choice as one more term of every step's mask
       (``paged_attention(chosen=)``): it reads every position where it
@@ -964,6 +965,7 @@ def _sparse_mixer(cfg, tick, pool, entry, kind):
     rope = T.rope_table(bs * MB, cfg.index_head_dim, cfg.rope_theta,
                         cfg.rope_scaling_dict)
     from deepspeed_tpu.ops.pallas import index_scores as IX
+    from deepspeed_tpu.ops.pallas import sparse_choice as SC
 
     def mixer(h, lp, flat, li, nth, acts):
         q, k, v = _project_qkv(cfg, h, lp, tick.positions,
@@ -988,14 +990,8 @@ def _sparse_mixer(cfg, tick, pool, entry, kind):
             # [S / C, T', C]: a lane tile of every row together
             scores = IX.index_scores(qi, w, flat["idx"], tables,
                                      tick.lengths, tick.slot)
-        nC, Tp, C = scores.shape
         with jax.named_scope("select"):
-            chosen = sparse_choice(
-                scores,
-                (jnp.arange(nC, dtype=jnp.int32)[:, None, None] * C
-                 + jnp.arange(C, dtype=jnp.int32)),
-                jnp.pad(tick.lengths, (0, Tp - Tn))[None, :, None], topk,
-                (0, 2), nC * C).astype(jnp.float32)
+            chosen = SC.sparse_choice(scores, tick.lengths, topk)
         attn = attend(q, flat, tables, chosen=chosen)
         return attn.reshape(Tn, N * D), flat, acts
 
@@ -1012,10 +1008,15 @@ def _sparse_span(layers: int, topk: int, decode_rows: int,
     those chosen (``min(length, topk)``; the decode rows' apart), those
     the attention meets for them (a row walks its sequence with the choice
     as a mask: its length), and the rows longer than ``topk`` (the others
-    attend to all they have)."""
+    attend to all they have); and the tiles of real rows the choice's kernel
+    steps over, with those of them that count (a tile none of whose rows is
+    longer than ``topk`` writes its rows' positions and counts nothing)."""
+    from deepspeed_tpu.ops.pallas.sparse_choice import count_tiles
+
     total = int(lengths.sum())
     ends = chunk_starts[1:] + [rows]
     chosen = lengths.clip(max=topk)
+    tiles, counting, _ = count_tiles(lengths, topk)
     return dict(
         sparse_layers=layers,
         index_positions=layers * total,
@@ -1025,7 +1026,9 @@ def _sparse_span(layers: int, topk: int, decode_rows: int,
         sparse_selected=layers * int(chosen.sum()),
         sparse_selected_decode=layers * int(chosen[:decode_rows].sum()),
         sparse_positions_read=layers * total,
-        sparse_rows_choosing=int((lengths > topk).sum()))
+        sparse_rows_choosing=int((lengths > topk).sum()),
+        sparse_choice_tiles=layers * tiles,
+        sparse_choice_tiles_counting=layers * counting)
 
 
 def _differential_mixer(cfg, tick, pool, entry, kind):

@@ -421,24 +421,28 @@ def test_a_tick_of_sparse_layers_copies_no_store(one_chip, program):
     stats = compiled.memory_analysis()
     assert held <= stats.alias_size_in_bytes < 1.0001 * held
     # the scores and the choice of a chunk tick are the largest things a
-    # tick holds: 2 x 4 B x rows x 18,432, well inside what the engine
-    # reserves for them (``CacheKind.tick_bytes`` a layer)
-    assert stats.temp_size_in_bytes < 3 * 8 * rows * 18432 + (160 << 20)
-    # the two Mosaic calls a layer, by the names the benchmark reads
+    # tick holds: 2 x 4 B x rows x 18,432 a layer in flight and no more
+    # (the choice is a Mosaic call that reads the scores and writes the
+    # mask: no words, halves or counts of XLA's beside them), what the
+    # engine reserves for them (``CacheKind.tick_bytes`` a layer)
+    assert stats.temp_size_in_bytes < 8 * rows * 18432 + (176 << 20)
+    # the three Mosaic calls a layer, by the names the benchmark reads
     assert "%index_scores" in text and "%sparse_attention" in text
+    assert "%sparse_choice" in text
 
 
 # (rows of the tick bucket, table tier) of the cell of sparse layers: 32
 # query heads on 4 KV heads of 128; the indexer's 16 heads against index
 # keys stored 128 wide, 6 layers x 4,353 blocks of 128, ONE table a slot
-SPARSE_SHAPES = {"keye-vl2-2048x144": (2048, 144),
-                 "keye-vl2-256x36": (256, 36)}
+SPARSE_SHAPES = {f"keye-vl2-{rows}x{tier}": (rows, tier)
+                 for rows in (256, 2048) for tier in (36, 72, 144)}
 
 
 @pytest.mark.parametrize("shape", sorted(SPARSE_SHAPES))
 def test_sparse_layer_kernels_compile_for_v5e(one_chip, shape):
     from deepspeed_tpu.ops.pallas.index_scores import index_scores
     from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+    from deepspeed_tpu.ops.pallas.sparse_choice import sparse_choice
 
     T, MB = SPARSE_SHAPES[shape]
     NB, bf = 6 * 4353, jnp.bfloat16
@@ -455,6 +459,14 @@ def test_sparse_layer_kernels_compile_for_v5e(one_chip, shape):
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
     assert "%index_scores" in text
     steps = MB                      # blocks of 128: a lane tile a block
+    # the choice: a tile of 32 rows' scores, their words and the mask in
+    # VMEM at once (12 MB at 144 blocks)
+    text = jax.jit(
+        lambda scores, n: sparse_choice(scores, n, 2048, interpret=False)
+    ).lower(arg((steps, T, 128), jnp.float32),
+            arg((T,), jnp.int32)).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%sparse_choice" in text
     pool = arg((NB, 128, 4, 128), bf)
 
     def lowered(choice):

@@ -34,6 +34,7 @@ from deepspeed_tpu.models.hf_import import (config_from_hf, import_hf_model,
                                             params_from_keye_vl2)
 from deepspeed_tpu.moe import layer as MOE
 from deepspeed_tpu.ops.pallas import index_scores as IX
+from deepspeed_tpu.ops.pallas import sparse_choice as SC
 from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
 
 TOL = 2e-5
@@ -228,24 +229,26 @@ def test_contexts_no_longer_than_topk_equal_the_dense_kind(model):
 # the choice
 # ------------------------------------------------------------------ #
 def _choice_by_top_k(scores, lengths, topk):
+    """``lax.top_k``'s set a row, in the order of the scores' BITS: it
+    holds ``-0.0`` equal to ``+0.0``, the choice ranks it under."""
     S = scores.shape[1]
     valid = np.arange(S)[None] < lengths[:, None]
+    scores = np.where((scores == 0) & np.signbit(scores),
+                      np.float32(-1e-30), scores)
     _, idx = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), topk)
     picked = np.zeros(scores.shape, bool)
     np.put_along_axis(picked, np.asarray(idx), True, axis=1)
     return picked & valid
 
 
-@pytest.mark.parametrize("layout", ["rows", "lane-tiles"])
-@pytest.mark.parametrize("case", ["random", "ties", "zeros", "short"])
-def test_the_choice_is_the_exact_top_k(case, layout):
-    """``sparse_choice`` (a bisection over the scores' bits, then over
-    positions among equals) against ``lax.top_k`` a row, in the plain
-    path's layout and the kernels': random scores of both signs, scores
-    drawn from five values (equals straddle the cut: the lower position
-    first), all zeros, and rows no longer than ``topk``."""
+def _choice_case(case):
+    """(scores [T, S], lengths [T], topk) of a case of the choice."""
     rng = np.random.default_rng(3)
     Tn, S, topk = 12, 256, 40
+    if case in ("a-tile-counts-nothing", "three-tiles"):
+        Tn, S = (64, 384) if case == "a-tile-counts-nothing" else (70, 384)
+    elif case == "lengths-inside-a-lane-tile":
+        S = 384
     scores = rng.normal(size=(Tn, S)).astype(np.float32) * 1e3
     lengths = rng.integers(topk + 1, S + 1, Tn).astype(np.int32)
     if case == "ties":
@@ -255,18 +258,76 @@ def test_the_choice_is_the_exact_top_k(case, layout):
     elif case == "short":
         lengths = rng.integers(1, topk + 1, Tn).astype(np.int32)
         lengths[0] = topk + 5           # one row chooses, the others cannot
+    elif case == "pad-rows":
+        # rows of no position at all among rows that choose, and what lies
+        # past a row's length is never looked at
+        lengths[::3] = 0
+        scores = np.where(np.arange(S)[None] < lengths[:, None], scores,
+                          np.float32(np.inf))
+    elif case == "a-tile-counts-nothing":
+        # the kernel's first tile of 32 rows has no row over ``topk``
+        lengths[:32] = rng.integers(1, topk + 1, 32)
+    elif case == "lengths-inside-a-lane-tile":
+        lengths = np.asarray([41, 127, 128, 129, 130, 200, 255, 256, 257,
+                              258, 300, 383], np.int32)
+    elif case == "three-tiles":
+        # the second tile's longest row ends in the second of three planes
+        lengths[32:64] = rng.integers(topk + 1, 201, 32)
+    elif case == "signed-zeros":
+        # ten scores over zero, then zeros of both signs: the cut is
+        # ``+0.0`` where a row has thirty of them and ``-0.0`` where not
+        plus = rng.random((Tn, S)) < np.linspace(0.02, 0.9, Tn)[:, None]
+        scores = np.where(plus, np.float32(0.0), np.float32(-0.0))
+        scores[:, 3:33:3] = rng.integers(1, 9, (Tn, 10))
+    elif case == "ties-in-one-row":
+        scores[5] = rng.integers(-2, 3, S)
+    return scores, lengths, topk
+
+
+CHOICE_CASES = ["random", "ties", "zeros", "short", "pad-rows",
+                "a-tile-counts-nothing", "lengths-inside-a-lane-tile",
+                "three-tiles", "signed-zeros", "ties-in-one-row"]
+
+
+@pytest.mark.parametrize("layout", ["rows", "lane-tiles", "kernel"])
+@pytest.mark.parametrize("case", CHOICE_CASES)
+def test_the_choice_is_the_exact_top_k(case, layout):
+    """``sparse_choice`` (a bisection over the scores' bits, then over
+    positions among equals) against ``lax.top_k`` a row, in the plain
+    path's layout, the kernels' and by the kernel itself (interpreted; its
+    mask is the plain form's element for element too): random scores of
+    both signs, scores drawn from five values (equals straddle the cut: the
+    lower position first) in every row or in one, all zeros, zeros of both
+    signs astride the cut, rows no longer than ``topk`` alone or a whole
+    tile of them, rows of no position, lengths on either side of a lane
+    tile's end, more rows than a tile."""
+    scores, lengths, topk = _choice_case(case)
+    Tn, S = scores.shape
     want = _choice_by_top_k(scores, lengths, topk)
     pos = jnp.arange(S, dtype=jnp.int32)
+    tiles = jnp.asarray(scores).reshape(Tn, S // 128, 128).transpose(1, 0, 2)
+
+    def plain_tiles():
+        return PG.sparse_choice(
+            tiles, pos.reshape(S // 128, 1, 128),
+            jnp.asarray(lengths)[None, :, None], topk, (0, 2), S)
+
     if layout == "rows":
         got = PG.sparse_choice(jnp.asarray(scores), pos[None],
                                jnp.asarray(lengths)[:, None], topk, (1,), S)
+    elif layout == "lane-tiles":
+        got = plain_tiles().transpose(1, 0, 2).reshape(Tn, S)
     else:
-        tiles = jnp.asarray(scores).reshape(Tn, S // 128, 128).transpose(
-            1, 0, 2)
-        got = PG.sparse_choice(
-            tiles, pos.reshape(S // 128, 1, 128),
-            jnp.asarray(lengths)[None, :, None], topk, (0, 2), S)
-        got = got.transpose(1, 0, 2).reshape(Tn, S)
+        # whole tiles of rows, as ``index_scores`` hands them over: the
+        # rows past the tick's choose nothing whatever their scores hold
+        got = SC.sparse_choice(
+            jnp.pad(tiles, ((0, 0), (0, -Tn % IX.TILE_ROWS), (0, 0)),
+                    constant_values=np.nan),
+            jnp.asarray(lengths), topk, interpret=True)
+        assert got.dtype == jnp.float32 and not bool(got[:, Tn:].any())
+        np.testing.assert_array_equal(np.asarray(got[:, :Tn]),
+                                      np.asarray(plain_tiles()))
+        got = got[:, :Tn].transpose(1, 0, 2).reshape(Tn, S)
     np.testing.assert_array_equal(np.asarray(got), want)
     np.testing.assert_array_equal(want.sum(1), np.minimum(lengths, topk))
     if case == "zeros":     # equal scores: the lowest positions
@@ -279,6 +340,25 @@ def test_equal_scores_choose_the_lower_position_in_the_whole_forward():
     for t in range(40):
         np.testing.assert_array_equal(
             np.flatnonzero(chosen[t]), np.arange(min(t + 1, 8)))
+
+
+def test_the_span_counts_the_tiles_that_count():
+    """``sparse_choice_tiles`` / ``_counting``: tiles of the choice's
+    kernel that hold a real row, and those of them with a row over
+    ``topk``: a prompt's first chunk behind three decode rows (the first
+    tile counts for them, the second has no row over 64, the third has),
+    and a decode tick's one tile of a bucket of eight."""
+    lengths = np.concatenate([[380, 370, 375], np.arange(1, 94)])
+    mixed = PG._sparse_span(6, 64, 3, [3], 96, 2048, lengths)
+    assert mixed["sparse_choice_tiles"] == 6 * 3
+    assert mixed["sparse_choice_tiles_counting"] == 6 * 2
+    assert mixed["sparse_rows_choosing"] == 3 + 93 - 64
+    decode = PG._sparse_span(6, 64, 3, [], 3, 256, lengths[:3])
+    assert decode["sparse_choice_tiles"] == 6 \
+        == decode["sparse_choice_tiles_counting"]
+    short = PG._sparse_span(6, 64, 0, [0], 40, 256, np.arange(1, 41))
+    assert short["sparse_choice_tiles"] == 12
+    assert short["sparse_choice_tiles_counting"] == 0
 
 
 # ------------------------------------------------------------------ #
@@ -321,6 +401,37 @@ def test_index_scores_kernel_matches_plain_jnp():
     live = np.arange(MB * bs)[None] < np.asarray(lengths)[:, None]
     np.testing.assert_allclose(np.where(live, rows, 0),
                                np.where(live, want, 0), rtol=1e-5, atol=1e-4)
+
+
+def test_the_choice_alone_tool_still_walks():
+    """``tools/choice_kernel_alone.py`` on its tiny cases, interpreted:
+    both forms run chained and give one mask (its times are a chip's to
+    give: none is read here)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
+                        "choice_kernel_alone.py")
+    spec = importlib.util.spec_from_file_location("choice_kernel_alone",
+                                                  path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    # the cell's six tick programs, by (rows, table tier)
+    assert {case[:2] for case in tool.CASES.values()} == {
+        (rows, tier) for rows in (256, 2048) for tier in (36, 72, 144)}
+    forms = tool.forms_of(None, tool.TINY_TOPK, True)
+    for case in tool.TINY.values():
+        scores, lengths = tool.operands(np.random.default_rng(0), case)
+        assert scores.shape == (case[1], case[0], 128)
+        tiles, counting, planes = SC.count_tiles(lengths, tool.TINY_TOPK)
+        assert tiles == case[0] // 32 and counting == (2 if case[3] else 1)
+        assert planes == (4 if case[3] else 2)
+        totals = [float(tool.chained(forms[f], 2)(scores, lengths))
+                  for f in ("kernel", "plain")]
+        assert totals[0] == totals[1]
+        np.testing.assert_array_equal(
+            np.asarray(forms["kernel"](scores, lengths)),
+            np.asarray(forms["plain"](scores, lengths)))
 
 
 def test_attention_under_a_choice_matches_plain_jnp():
@@ -468,6 +579,9 @@ def test_engine_serves_greedy_tokens_of_the_whole_forward(model):
     assert first["sparse_selected"] == 3 * sum(
         min(n, TOPK) for n in range(1, 33))
     assert first["sparse_rows_choosing"] == 32 - TOPK
+    # one tile of rows a layer, and a row of it is longer than ``topk``
+    assert first["sparse_choice_tiles"] == 3 \
+        == first["sparse_choice_tiles_counting"]
     assert first["sparse_selected_decode"] == 0
     # every row walks its sequence under the choice as a mask
     assert first["sparse_positions_read"] == first["index_positions"]

@@ -19,7 +19,7 @@ PALLAS = os.path.join(os.path.dirname(dst.__file__), "ops", "pallas")
 KERNEL_NAMES = {
     "flash_fwd", "flash_dq", "flash_dkv", "paged_attention",
     "latent_paged_attention", "kda_step", "kda_chunk", "index_scores",
-    "block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv",
+    "sparse_choice", "block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv",
     "evoformer_attention", "fused_adam", "quantize_int8_blocks",
     "dequant_reduce", "rms_norm", "layer_norm"}
 
@@ -71,7 +71,7 @@ def test_every_pallas_call_is_named():
                     d.value for a, d in zip(node.args.kwonlyargs,
                                             node.args.kw_defaults)
                     if a.arg == "name" and isinstance(d, ast.Constant))
-    assert sites == 15
+    assert sites == 16
     assert literal == KERNEL_NAMES
 
 
@@ -225,7 +225,7 @@ def test_a_tick_of_sparse_layers_tells_its_three_parts_apart():
     attention over the chosen under scopes of their own inside ``attn``
     (``index_share_pct`` / ``select_share_pct`` /
     ``sparse_attention_share_pct`` and ``roofline/sparse_attention.py`` read
-    them), the two Mosaic calls by name (``roofline/index_scores.py``
+    them), the three Mosaic calls by name (``roofline/index_scores.py``
     classifies by it), beside the expert layers' parts."""
     import types
 
@@ -259,5 +259,6 @@ def test_a_tick_of_sparse_layers_tells_its_three_parts_apart():
     assert any("/attn/sparse/sparse_attention" in s for s in stacks)
     assert not any("/paged_attention" in s or "/global_attention" in s
                    for s in stacks)
-    # the choice is plain XLA under its scope: compares and counts
-    assert any("/attn/select/" in s for s in stacks)
+    # the choice is a Mosaic call under its scope (``select_share_pct``
+    # reads the scope)
+    assert any("/attn/select/sparse_choice" in s for s in stacks)

@@ -1443,8 +1443,12 @@ class FastGenEngine:
                     n, n_open = count_steps(lengths, starts, R, step, window)
                     attn_steps += layers * n
                     attn_open += layers * n_open
+                # which geometry ran: the positions a step of the tick's
+                # widest walk carries
                 tick_span.note(attn_steps=attn_steps,
-                               attn_open_steps=attn_open)
+                               attn_open_steps=attn_open,
+                               attn_step_positions=max(
+                                   step for _, _, step in self._walks))
             # the wait for the device and for the copy queued behind it
             with telemetry.span("tick_readback") as readback_span:
                 sampled = np.asarray(sampled)

@@ -22,11 +22,10 @@ lengths:
 ``R`` and ``P`` are one rule of the operands' shapes (``tile_rows``,
 ``_blocks_per_fetch``). A fetch step has a cost of its own, so a tile takes
 the rows and a step the cache positions that 1 MB each of accumulator,
-scores and fetched bytes leave room for; K/V pools stay at one lane width of
-positions, a rule measured when a step cast and transposed what it fetched
-(PR 32) and not measured again since. Mistral, Trinity and
-Phi-4-mini-flash: 32 rows x 128 positions; Pythia 32 x 64; Moonlight's
-latent pool 32 x 512.
+scores and fetched bytes leave room for, K/V pools within eight blocks a
+step. Mistral, LFM2 and Keye: 32 rows x 256 positions; Trinity and
+Phi-4-mini-flash, by their scores, 32 x 128; Pythia 32 x 64; the latent
+pools of Moonlight and Kimi-Linear 32 x 512.
 
 A step does what its columns need and no more (PERF.md sections 5-6, PR 34:
 Trinity's tile-step 2.56 -> 1.67 us and a decode row's 1.21 -> 0.85 on the
@@ -96,6 +95,8 @@ NEG_INF = -1e30
 _FETCH_BYTES = 1024 * 1024
 _TILE_BYTES = 1024 * 1024
 _LANES = 128
+# blocks a fetch step of K/V pools copies at most, past one lane width
+_STEP_BLOCKS = 8
 # block tables are widened to a multiple of this many columns (see _tiles)
 _TABLE_COLS = 64
 
@@ -126,27 +127,36 @@ def _blocks_per_fetch(bs: int, row_bytes: int, query_rows: int,
     ``head_axis``: the pools have one (K/V pools).
 
     A live step costs 0.3 us for a decode row and more for a tile whatever
-    it carries, so it carries what VMEM has room for: as many cache
+    it carries (a tile's statistics and accumulator are read, rescaled and
+    written back every step: half of a masked tile-step's vector work at
+    128 positions), so it carries what VMEM has room for: as many cache
     positions as keep the fetch under ``_FETCH_BYTES`` and the float32
     scores ``[query_rows, positions]`` under ``_TILE_BYTES``, in whole lane
     widths (the scores' minor dim), or whole blocks where not one lane
-    width fits (Pythia: 64 positions are 1 MB). With a head axis one lane
-    width is all: measured in PR 32, when a step cast and transposed what
-    it fetched and that grew with the step, a wider step saved nothing and
-    multiplied more masked positions of a short context (Mistral's shapes
-    at 256 positions a step against 128: 594.2 / 594.0 us a mixed tick's
-    call, 301.8 / 306.9 a decode tick's at 600 positions, 222.8 / 194.9 at
-    300; Phi-4's window walk +5 %); since PR 34 a step reads the heads
-    strided and the rule has not been measured again (Trinity's scores
-    hold it to 128 whatever this says). The latent pool's row is
-    multiplied as it lies, 1,280 B a
-    position: 128 positions are 0.2 us of HBM time, and 512 a step read
-    314 against 470 us a decode tick's call, 840 against 1,278 a mixed
-    tick's (PERF.md section 6, PR 32)."""
+    width fits (Pythia: 64 positions are 1 MB). A walk's last step
+    multiplies the positions past its context all the same, so with a head
+    axis a step stays within ``_STEP_BLOCKS`` blocks where that is a lane
+    width or more: a pool's block is its deployment's word on how long its
+    contexts are (toy pools of 8-position blocks keep one lane width).
+
+    Read on the v5e, the call alone at its cell's tick shapes, 128 -> 256
+    positions a step (PERF.md section 6, PR 48; PR 32's one-lane-width rule
+    for K/V pools dated from a step that cast and transposed what it
+    fetched): Keye's masked walk over blocks of 128 (every step masked by
+    the choice) 6,052 -> 4,576 us a chunk tick's call at ~8k, 1,835 -> 1,288
+    a decode tick's 24 rows at ~17k (512 a step, which the scores' room
+    does not give: 4,440 and 1,197); LFM2's 256 rows at 300-2,000 1,584 ->
+    1,214 and its chunk tick 1,809 -> 1,440; Mistral's 41 rows at 150-1,150
+    203-209 -> 199-205 and its chunk tick 301-304 -> 291-294 (every context
+    at 300: 125 -> 134; at 600: 190 -> 185). Trinity's and Phi-4's scores
+    hold them to 128 whatever this says. The latent pool's row is
+    multiplied as it lies, 1,280 B a position: 128 positions are 0.2 us of
+    HBM time, and 512 a step read 314 against 470 us a decode tick's call,
+    840 against 1,278 a mixed tick's (PERF.md section 6, PR 32)."""
     positions = min(_FETCH_BYTES // row_bytes,
                     _TILE_BYTES // (4 * query_rows))
     if head_axis:
-        positions = min(positions, _LANES)
+        positions = min(positions, max(_LANES, _STEP_BLOCKS * bs))
     if positions >= _LANES:
         positions -= positions % _LANES
     return max(1, positions // bs)
@@ -215,11 +225,13 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     latent attention, whose value is the latent itself), the output, then
     the scratch: a fetch buffer per pool, the semaphores, the queries
     head-major, lengths, softmax statistics and accumulator.
-    ``chosen``: behind the pools lies the tile's choice, ``[steps, R, C]``
-    (nonzero: row r of the tile attends to position ``step * C + c``; a
-    sparse layer's), and EVERY step of a walk takes the masked form with
-    the choice as one more term of its mask: a third form of a step beside
-    open and edge. Every other instantiation traces what it traced."""
+    ``chosen``: behind the pools lies the tile's choice, ``[planes, R, L]``
+    (nonzero: row r of the tile attends to position ``plane * L + l``; a
+    sparse layer's, in planes of one lane width as ``sparse_choice`` writes
+    them), and EVERY step of a walk takes the masked form with its ``C //
+    L`` planes of the choice, side by side, as one more term of its mask: a
+    third form of a step beside open and edge. Every other instantiation
+    traces what it traced."""
     pools = refs[:n_pool]
     chosen_ref = refs[n_pool] if chosen else None
     refs = refs[int(chosen):]
@@ -236,6 +248,13 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     M, C = R * rep, P * bs
     Dv = acc_ref.shape[2]
     t0 = pl.program_id(0) * R
+
+    def choice(i, rows):
+        """Step ``i``'s planes of the choice for ``rows`` of the tile, side
+        by side as the step's columns lie: ``[rows, C]``."""
+        n = C // chosen_ref.shape[2]
+        planes = [chosen_ref[i * n + j, rows, :] for j in range(n)]
+        return planes[0] if n == 1 else jnp.concatenate(planes, axis=1)
 
     def length(r):
         return meta_ref[t0 + r]
@@ -422,8 +441,8 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
 
         def alone_form():
             pick = None
-            if chosen:                     # the row's own plane of a step
-                pick = lambda i: (chosen_ref[i, pl.ds(r0, 1), :]  # noqa: E731
+            if chosen:                     # the row's own planes of a step
+                pick = lambda i: (choice(i, pl.ds(r0, 1))    # noqa: E731
                                   != 0)[None]                   # [1, 1, C]
             attend(pl.ds(r0 * rep, rep), length(r0), pick)
 
@@ -435,14 +454,14 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
             if chosen:
                 # a row's choice is its ``rep`` query rows': a product with
                 # ``E[m, r] = (m // rep == r)`` repeats the step's ``[R, C]``
-                # plane ``rep`` times a row on the MXU, exactly (one term a
+                # planes ``rep`` times a row on the MXU, exactly (one term a
                 # sum), where a repeat along sublanes is a relayout
                 m = jax.lax.broadcasted_iota(jnp.int32, (M, R), 0)
                 r = jax.lax.broadcasted_iota(jnp.int32, (M, R), 1)
                 spread = ((m >= r * rep) & (m < (r + 1) * rep)).astype(
                     chosen_ref.dtype)
                 pick = lambda i: (jax.lax.dot_general(        # noqa: E731
-                    spread, chosen_ref[i], (((1,), (0,)), ((), ())),
+                    spread, choice(i, slice(None)), (((1,), (0,)), ((), ())),
                     precision=jax.lax.Precision.DEFAULT,
                     preferred_element_type=jnp.float32) > 0.5)[None]
             attend(slice(None), limit, pick)
@@ -557,10 +576,14 @@ def _walk(q, pools, tables, lengths, *, value_dim, scale, name, mxu_dtype,
     if chosen is not None:
         assert row_table is not None and window is None
         K, bs, _, P = _geometry(q, pools, value_dim, heads_first)
-        assert chosen.shape[1] >= Tn + pad and chosen.shape[2] == P * bs \
-            and chosen.shape[0] * P * bs >= tables.shape[1] * bs, (
+        planes, rows, L = chosen.shape
+        assert rows >= Tn + pad and P * bs % L == 0 \
+            and planes * L >= tables.shape[1] * bs, (
                 chosen.shape, Tn + pad, P * bs, tables.shape)
         chosen = chosen[:, :Tn + pad]
+        short = -planes % (P * bs // L)
+        if short:       # a step reads whole planes (the serving tiers' are)
+            chosen = jnp.pad(chosen, ((0, short), (0, 0), (0, 0)))
     if pad:                            # pad rows: zero table, length 1
         q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
         if row_table is None:
@@ -618,11 +641,13 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     against a long context is); ``row_table`` [T]: ``tables`` is then one
     table a SEQUENCE, ``[sequences, MB]`` with row 0 the pad rows', and
     this names each row's (rows of one sequence are told by it);
-    ``chosen`` [steps, T', C] (``T'``: the rows up to whole tiles; ``C``:
-    a fetch step's positions, :func:`step_positions`; with ``row_table``):
-    row t attends to position ``step * C + c`` only where the entry is
-    nonzero (and the position lies under its length): a sparse layer's
-    choice, which every step of the walk then takes as a mask."""
+    ``chosen`` [planes, T', L] (``T'``: the rows up to whole tiles; ``L``
+    divides a fetch step's positions, :func:`step_positions`: one lane
+    width as ``sparse_choice`` writes it; with ``row_table``): row t
+    attends to position ``plane * L + l`` only where the entry is nonzero
+    (and the position lies under its length): a sparse layer's choice,
+    which every step of the walk then takes as a mask, its own planes of
+    it side by side."""
     D, K = q.shape[2], kpool.shape[1 if heads_first else 2]
     assert D == kpool.shape[3] and q.shape[1] % K == 0
     return _walk(q, (kpool, vpool), tables, lengths, value_dim=D,
@@ -634,8 +659,9 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
 
 def step_positions(q, kpool, vpool) -> int:
     """Cache positions a fetch step of :func:`paged_attention` meets, and
-    so the minor dimension of ``chosen``: the kernel's own rule of the
-    operands' shapes (arrays or their ``ShapeDtypeStruct``)."""
+    so the positions of the planes of ``chosen`` that a step reads: the
+    kernel's own rule of the operands' shapes (arrays or their
+    ``ShapeDtypeStruct``)."""
     _, bs, _, P = _geometry(q, (kpool, vpool), q.shape[2], False)
     return bs * P
 

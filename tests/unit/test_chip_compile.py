@@ -441,7 +441,8 @@ SPARSE_SHAPES = {f"keye-vl2-{rows}x{tier}": (rows, tier)
 @pytest.mark.parametrize("shape", sorted(SPARSE_SHAPES))
 def test_sparse_layer_kernels_compile_for_v5e(one_chip, shape):
     from deepspeed_tpu.ops.pallas.index_scores import index_scores
-    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+    from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention,
+                                                          step_positions)
     from deepspeed_tpu.ops.pallas.sparse_choice import sparse_choice
 
     T, MB = SPARSE_SHAPES[shape]
@@ -478,6 +479,10 @@ def test_sparse_layer_kernels_compile_for_v5e(one_chip, shape):
                 arg((T,), jnp.int32), arg((T,), jnp.int32),
                 arg((steps, T, 128), choice))
 
+    # a step carries two blocks of 128 and reads two planes of the choice
+    # (10.54 MiB of scoped VMEM at 2,048 x 144, of the 16 MiB default: the
+    # call sets no limit)
+    assert step_positions(arg((T, 32, 128), bf), pool, pool) == 256
     text = lowered(jnp.float32).compile().as_text()
     # one Mosaic call, the pool still at operand 3, the choice behind it
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1

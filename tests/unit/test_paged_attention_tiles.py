@@ -38,7 +38,8 @@ def _shape(N, K, D, BS, MB, NB, T, dtype, span=False):
 
 # toy widths: T = 80 is 2.5 tiles of 32 rows (the wrapper pads); a walk is
 # one fetch step there. "mistral": the chat cell's heads, blocks and bf16
-# pool, where a step is C positions and the walks below take up to three;
+# pool, where a step is C positions (eight blocks) and the walks below take
+# up to three;
 # "trinity": the published head layout of the window and full layers over
 # experts (48 query heads on 8 of 128: a group of 6)
 SHAPES = {
@@ -46,6 +47,9 @@ SHAPES = {
     "rep1": _shape(2, 2, 64, 8, 8, 96, 80, "float32"),
     "mistral": _shape(32, 8, 128, 32, 24, 72, 64, "bfloat16"),
     "trinity": _shape(48, 8, 128, 32, 24, 72, 64, "bfloat16", span=True),
+    # the sparse layers' head layout over blocks of 128: a step carries two
+    # lane widths, 256 positions (the walks of STEP_LAYOUTS reach 5 blocks)
+    "keye": _shape(32, 4, 128, 128, 5, 24, 64, "bfloat16"),
 }
 
 
@@ -143,6 +147,17 @@ CASES = [(h, l) for h in ("rep1", "rep4") for l in sorted(LAYOUTS)] + [
     (h, l) for h in ("mistral", "trinity") for l in sorted(STEP_LAYOUTS)]
 
 
+def _by_run(g, tables):
+    """One table a run of rows (row 0 the pad rows') and each row's: what
+    a call with ``row_table`` takes, of a tick's table a row."""
+    new = jnp.concatenate([jnp.ones((1,), bool), jnp.any(
+        tables[1:] != tables[:-1], axis=1)])
+    which = jnp.where(jnp.any(tables != 0, axis=1),
+                      jnp.cumsum(new), 0).astype(jnp.int32)
+    return jnp.zeros((g.T + 1, g.MB), jnp.int32).at[which].set(
+        tables), which
+
+
 @functools.lru_cache(maxsize=None)
 def _case(heads, dtype):
     """One compiled kernel, one compiled reference, one pool per head
@@ -155,13 +170,7 @@ def _case(heads, dtype):
     vpool = jnp.asarray(rng.normal(size=(g.NB, g.BS, g.K, g.D)), dt)
     if g.span:
         def kernel(q, kpool, vpool, tables, lengths):
-            # one table a run of rows (row 0 the pad rows'), each row's
-            new = jnp.concatenate([jnp.ones((1,), bool), jnp.any(
-                tables[1:] != tables[:-1], axis=1)])
-            which = jnp.where(jnp.any(tables != 0, axis=1),
-                              jnp.cumsum(new), 0).astype(jnp.int32)
-            by_run = jnp.zeros((g.T + 1, g.MB), jnp.int32).at[which].set(
-                tables)
+            by_run, which = _by_run(g, tables)
             return paged_attention(q, kpool, vpool, by_run, lengths,
                                    interpret=True, window=g.window,
                                    mxu_dtype=dt, row_table=which)
@@ -217,16 +226,16 @@ def _operands(q, *pools):
             tuple(jax.ShapeDtypeStruct(p, jnp.bfloat16) for p in pools))
 
 
-# the five serving configurations' operands (bf16, blocks of 32) -> (rows a
-# tile, blocks a fetch step): a change to one configuration's geometry
-# changes its tick programs, and shows here
+# the serving configurations' operands (bf16; blocks of 32 but for Keye's,
+# of 128) -> (rows a tile, blocks a fetch step): a change to one
+# configuration's geometry changes its tick programs, and shows here
 TRINITY = _operands((2048, 48, 128), *[(4 * 29 * 192, 32, 8, 128)] * 2), \
     _operands((2048, 48, 128), *[(12288, 32, 8, 128)] * 2)
 GEOMETRY = {
     "trinity-large-preview-swa": (TRINITY[0], 128, False, (32, 4)),
     "trinity-large-preview-global": (TRINITY[1], 128, False, (32, 4)),
     "mistral-7b": (_operands((512, 32, 128), *[(2400, 32, 8, 128)] * 2),
-                   128, False, (32, 4)),
+                   128, False, (32, 8)),
     "pythia-6.9b": (_operands((512, 32, 128), *[(640, 32, 32, 128)] * 2),
                     128, False, (32, 2)),
     "phi-4-mini-flash-window": (
@@ -237,6 +246,14 @@ GEOMETRY = {
         128, True, (32, 4)),
     "moonlight-16b-a3b": (_operands((512, 16, 640), (9 * 4352, 32, 640)),
                           512, False, (32, 16)),
+    # 8 KV heads of 64 lie two to a pool row: the kernel sees 4 of 128
+    "lfm2-24b-a2b": (
+        _operands((2048, 32, 128), *[(2 * 20480, 32, 4, 128)] * 2),
+        128, False, (32, 8)),
+    # the one pool of 128-position blocks: two of them a step
+    "keye-vl-2.0-30b-a3b": (
+        _operands((2048, 32, 128), *[(6 * 4353, 128, 4, 128)] * 2),
+        128, False, (32, 2)),
 }
 
 
@@ -277,7 +294,7 @@ def _brute_steps(lengths, starts, tile, C, window):
     return steps, n_open
 
 
-RULE_CASES = [(C, window) for C in (8, 64, 128)
+RULE_CASES = [(C, window) for C in (8, 64, 128, 256)
               for window in (None, 1, C - 1, C, C + 31, 3 * C, 5 * C + 7)]
 
 
@@ -310,11 +327,13 @@ def test_step_ranges_against_every_column(C, window):
 
 
 @pytest.mark.parametrize("heads,layout",
-                         [(h, l) for h in ("mistral", "trinity")
+                         [(h, l) for h in ("mistral", "trinity", "keye")
                           for l in sorted(STEP_LAYOUTS)],
                          ids=lambda x: x)
 def test_count_steps_against_every_column(heads, layout):
     g = SHAPES[heads]
+    # Trinity's scores hold a step to one lane width; the others' to two
+    assert g.C == (128 if heads == "trinity" else 256)
     rng = np.random.default_rng(sorted(STEP_LAYOUTS).index(layout))
     rows = STEP_LAYOUTS[layout](g, rng)
     lengths = np.array([n for _, n in rows] + [1] * (g.T - len(rows)))
@@ -323,6 +342,40 @@ def test_count_steps_against_every_column(heads, layout):
     want = _brute_steps(lengths, starts, 32, g.C, g.window)
     assert count_steps(lengths, starts, 32, g.C, g.window) == want
     assert want[0] > 0
+
+
+@pytest.mark.parametrize("layout", [
+    "chunk_across_a_tile_and_a_steps_edge",      # the tile form, and rows
+    "decode_rows_at_the_steps_edges"])           # rows alone
+def test_a_choice_in_lane_planes_at_two_planes_a_step(layout):
+    """``paged_attention(chosen=)`` where a step carries 256 positions and
+    the choice lies in planes of 128, as ``sparse_choice`` writes them: a
+    step reads two consecutive planes (five planes here: the wrapper adds
+    the sixth), the tile form's spread product over both and a row alone
+    its own row of each. Against the jnp reference under the same choice,
+    at the tolerance of the file's bfloat16 cases."""
+    g = SHAPES["keye"]
+    assert g.C == 256 and g.MB * g.BS // 128 == 5
+    q, kpool, vpool, _, _ = _case("keye", g.dtype)
+    rng = np.random.default_rng(sorted(STEP_LAYOUTS).index(layout))
+    tables, lengths = _tick(g, STEP_LAYOUTS[layout](g, rng))
+    chosen = rng.random((g.T, g.MB * g.BS)) < 0.3
+    chosen[np.arange(g.T), np.asarray(lengths) - 1] = True   # a row, itself
+    planes = jnp.asarray(chosen, jnp.float32).reshape(
+        g.T, -1, 128).transpose(1, 0, 2)
+    by_run, which = _by_run(g, tables)
+    kernel = functools.partial(
+        paged_attention, q, kpool, vpool, by_run, lengths, interpret=True,
+        mxu_dtype=jnp.bfloat16, row_table=which)
+    got = np.asarray(kernel(chosen=planes), np.float32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(PG.paged_attention_reference(
+            q.astype(jnp.float32), kpool.astype(jnp.float32),
+            vpool.astype(jnp.float32), tables, lengths,
+            chosen=jnp.asarray(chosen)))
+    np.testing.assert_allclose(got, want, rtol=2 ** -8, atol=2 ** -8)
+    # and the choice bites: without it the rows attend to more
+    assert np.abs(got - np.asarray(kernel(), np.float32)).max() > 2 ** -4
 
 
 # --------------------------------------------------------------------- #
@@ -456,6 +509,8 @@ def test_tick_span_counts_fetch_steps_and_open_ones():
     ticks = [e["args"] for e in events if e.get("name") == "decode_tick"][-7:]
     assert [(a["attn_steps"], a["attn_open_steps"]) for a in ticks] == [
         (layers * n, layers * n_open) for n, n_open in want]
+    # which geometry ran: the positions a step carries, the kernel's rule
+    assert {a["attn_step_positions"] for a in ticks} == {C}
     got = [b - a for a, b in zip(before, totals())]
     assert got == [layers * sum(o for _, o in want),
                    layers * sum(n - o for n, o in want)]

@@ -354,7 +354,7 @@ BYTES = {
 #: ``tick_walks``: (layers, window, cache positions a fetch step) a kind
 #: of kernel call
 WALKS = {
-    "serve-mistral7b-chat-steady-v2": [(16, None, 128)],
+    "serve-mistral7b-chat-steady-v2": [(16, None, 256)],
     "toy:serve-mistral7b-chat-steady-v2": [(2, None, 128)],
     "serve-pythia69b-decode-closed": [(16, None, 64)],
     "toy:serve-pythia69b-decode-closed": [(2, None, 128)],
@@ -365,11 +365,13 @@ WALKS = {
     "serve-trinity-large-agentctx-closed": [(4, 4096, 128), (1, None, 128)],
     "toy:serve-trinity-large-agentctx-closed": [(4, 16, 128),
                                                 (1, None, 128)],
-    "serve-lfm2-24b-concurrent-closed": [(2, None, 128)],
+    "serve-lfm2-24b-concurrent-closed": [(2, None, 256)],
     "toy:serve-lfm2-24b-concurrent-closed": [(2, None, 128)],
     "serve-kimi-linear-48b-rollout-closed": [(2, None, 512)],
     "toy:serve-kimi-linear-48b-rollout-closed": [(2, None, 2048)],
-    "serve-keye-vl2-30b-longctx-closed": [(6, None, 128)],
+    # two blocks of 128 a step, as Mistral's and LFM2's eight of 32 (the
+    # toys' blocks of 8 keep one lane width)
+    "serve-keye-vl2-30b-longctx-closed": [(6, None, 256)],
     "toy:serve-keye-vl2-30b-longctx-closed": [(6, None, 128)],
 }
 

@@ -350,6 +350,110 @@ def params_from_qwen3_moe(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
 
 
 # --------------------------------------------------------------------------- #
+# Mellum 2 (JetBrains: the Qwen3-MoE block over window and full attention
+# layers, rotary by layer type)
+# --------------------------------------------------------------------------- #
+
+def config_from_mellum(hf_config) -> TransformerConfig:
+    """``model_type`` ``mellum``: the Qwen3-MoE block (grouped-query
+    attention under per-head q/k RMSNorm, no biases; a softmax router,
+    ``num_experts_per_tok`` of ``num_experts`` SwiGLU experts, weights
+    renormalised where ``norm_topk_prob``, no shared expert) in every layer
+    (``mlp_layer_types`` all ``sparse``); ``layer_types`` says which layers
+    see ``sliding_window`` positions and which every one, and
+    ``rope_parameters`` gives each type its own rotary: theta and scaling
+    (the full layers' YaRN with its ``attention_factor``), carried as
+    ``TransformerConfig.kind_rope``.
+
+    A SHARE of the expert layers (``TransformerConfig.moe_router_experts``):
+    ``num_experts`` is then the experts held, ``router_experts`` the
+    router's width (the published count) and ``first_expert`` the first
+    one held; without ``router_experts`` every expert is held.
+    ``router_init_std``, where given, is what a router drawn from scratch
+    is drawn with (``TransformerConfig.moe_router_init_std``)."""
+    names = {"sliding_attention": "window", "full_attention": "full"}
+    kinds = tuple(names[t] for t in hf_config.layer_types)
+    L = hf_config.num_hidden_layers
+    mlp = list(getattr(hf_config, "mlp_layer_types", None) or ["sparse"] * L)
+    if len(kinds) != L or len(mlp) != L or set(mlp) != {"sparse"}:
+        raise NotImplementedError(
+            f"mellum: layer_types names {len(kinds)} and mlp_layer_types "
+            f"{len(mlp)} layers of num_hidden_layers={L}, and every layer's "
+            f"FFN is `sparse` in what is written (got {sorted(set(mlp))})")
+    if "window" in kinds and not (getattr(hf_config, "use_sliding_window",
+                                          True)
+                                  and hf_config.sliding_window):
+        raise ValueError("mellum: sliding_attention layers need "
+                         "use_sliding_window and a sliding_window")
+
+    def rope(section):
+        sc = {k: v for k, v in dict(section).items() if v is not None}
+        theta = float(sc.pop("rope_theta"))
+        if sc.get("rope_type", "default") == "default":
+            return theta, None
+        from deepspeed_tpu.models.transformer import _scaled_inv_freq
+
+        _scaled_inv_freq(64, theta, sc)         # type / keys validation
+        return theta, tuple(sorted(sc.items()))
+
+    ropes = {names[t]: rope(sec)
+             for t, sec in dict(hf_config.rope_parameters).items()}
+    missing = set(kinds) - set(ropes)
+    if missing:
+        raise ValueError(f"mellum: rope_parameters has no section for the "
+                         f"layers of kind {sorted(missing)}")
+    # the model's own table is its first layer's kind's; a kind that
+    # differs from it carries its own
+    theta, scaling = ropes[kinds[0]]
+    held = hf_config.num_experts
+    router = int(getattr(hf_config, "router_experts", held))
+    h = hf_config.hidden_size
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size, hidden_size=h, num_layers=L,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        attn_head_dim=int(getattr(hf_config, "head_dim",
+                                  h // hf_config.num_attention_heads)),
+        ffn_hidden_size=hf_config.intermediate_size,
+        max_seq_len=hf_config.max_position_embeddings,
+        pos_emb="rope", norm="rmsnorm", activation="swiglu", use_bias=False,
+        qkv_bias=bool(getattr(hf_config, "attention_bias", False)),
+        tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
+        rope_theta=theta, rope_scaling=scaling,
+        kind_rope=tuple(sorted((k, v) for k, v in ropes.items()
+                               if k in kinds and v != (theta, scaling))),
+        norm_eps=hf_config.rms_norm_eps, dtype="float32",
+        qk_norm=bool(getattr(hf_config, "qk_norm", True)),
+        layer_kinds=kinds, attn_window=int(hf_config.sliding_window or 0),
+        n_experts=held, moe_top_k=hf_config.num_experts_per_tok,
+        moe_ffn_size=hf_config.moe_intermediate_size,
+        moe_route_norm=bool(hf_config.norm_topk_prob),
+        moe_aux_coef=float(getattr(hf_config, "router_aux_loss_coef", 0.001)),
+        moe_dispatch="ragged",
+        moe_router_experts=router if router != held else 0,
+        moe_first_expert=int(getattr(hf_config, "first_expert", 0)),
+        moe_router_init_std=float(getattr(hf_config, "router_init_std", 0.0)))
+
+
+def params_from_mellum(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
+    """The Qwen3-MoE family's tensor names; a share of the experts takes
+    its own from the checkpoint's."""
+    L = cfg.num_layers
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lyr = pre + "layers.{}."
+    moe = lyr + "mlp."
+    blocks, params = _llama_attn_blocks(sd, cfg, pre)
+    blocks["gate_w"] = _stack(sd, moe + "gate.weight", L, transpose=True)
+    if cfg.qk_norm:
+        blocks["q_norm"] = _stack(sd, lyr + "self_attn.q_norm.weight", L)
+        blocks["k_norm"] = _stack(sd, lyr + "self_attn.k_norm.weight", L)
+    blocks.update(_qwen_moe_experts(sd, moe, L, cfg.n_experts,
+                                    cfg.moe_first_expert))
+    params["blocks"] = blocks
+    return params
+
+
+# --------------------------------------------------------------------------- #
 # Keye-VL-2 (Kwai: the Qwen3-MoE block under a learned sparse-attention
 # indexer; the language model alone)
 # --------------------------------------------------------------------------- #
@@ -1105,7 +1209,7 @@ def config_from_afmoe(hf_config) -> TransformerConfig:
         rope_theta=float(getattr(hf_config, "rope_theta", 10000.0)),
         norm_eps=hf_config.rms_norm_eps, dtype="float32",
         qk_norm=True, post_norms=True, attn_gate=True,
-        full_layers_rope=False,
+        kind_rope=(("full", None),),
         emb_multiplier=float(h) ** 0.5 if getattr(
             hf_config, "mup_enabled", False) else 1.0,
         layer_kinds=kinds, attn_window=int(hf_config.sliding_window),
@@ -1474,6 +1578,7 @@ _ARCH_TABLE = {
     "KeyeVL2": (config_from_keye_vl2, params_from_keye_vl2),
     "kimi_linear": (config_from_kimi_linear, params_from_kimi_linear),
     "lfm2_moe": (config_from_lfm2_moe, params_from_lfm2_moe),
+    "mellum": (config_from_mellum, params_from_mellum),
     "phi4flash": (config_from_phi4flash, params_from_phi4flash),
     "gpt2": (config_from_gpt2, params_from_gpt2),
     "llama": (config_from_llama, params_from_llama),

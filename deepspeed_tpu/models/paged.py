@@ -198,6 +198,11 @@ def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
     second-minor dim of a block the kernel's copies slice."""
     kinds, of_kinds = cfg.layer_kinds, cfg.standard_blocks
     n = kinds.count
+    if any(own is not None for _, own in cfg.kind_rope):
+        raise NotImplementedError(
+            "a rotary table of its own for a layer kind (kind_rope) is "
+            "trained and run whole by forward(); a paged tick rotates every "
+            "layer by the model's one table")
     if kinds and not of_kinds:
         if n("full") != 1:
             raise ValueError(
@@ -264,7 +269,7 @@ def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
             n("full") if kinds else 0 if cfg.mla else cfg.num_layers, "attn",
             tuple(Store(s, BLOCKS, block) for s in ("k", "v")),
             grouped(("k", "v"), None, "global_attention", "global",
-                    cfg.full_layers_rope), _grouped_mixer),
+                    cfg.rope_of("full") is not None), _grouped_mixer),
         "latent": CacheKind(
             n("latent") if kinds else cfg.num_layers if cfg.mla else 0,
             "attn",

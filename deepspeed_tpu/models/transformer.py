@@ -196,13 +196,17 @@ class TransformerConfig:
     # RMSNorms after attention and after the FFN too, before each joins the
     # residual stream (``ln1_post`` / ``ln2_post``); an elementwise sigmoid
     # gate on the attention output, projected from the block's normed
-    # input (``wg``), ahead of ``wo``; an embedding multiplier; and, where
-    # ``layer_kinds`` names ``window`` and ``full`` layers of this one
-    # block, rotary on the window layers alone
+    # input (``wg``), ahead of ``wo``; an embedding multiplier
     post_norms: bool = False
     attn_gate: bool = False
     emb_multiplier: float = 1.0
-    full_layers_rope: bool = True
+    # rotary by layer kind, where the kinds of ``layer_kinds`` differ in it
+    # (:meth:`rope_of`): ``(kind, None)`` is no rotary on that kind's layers
+    # (the ``afmoe`` family's full layers), ``(kind, (theta, rope_scaling))``
+    # a table of its own (the ``mellum`` family's full layers: YaRN, whose
+    # ``attention_factor`` multiplies that table alone); a kind not named
+    # rotates by ``rope_theta`` / ``rope_scaling``
+    kind_rope: Tuple[Tuple[str, Any], ...] = ()
     # a SHARE of an expert layer (expert parallelism's unit; the one-chip
     # cut of the ``model-configs`` guide, section 4): the router is
     # ``moe_router_experts`` wide and chooses ``moe_top_k`` of all of them;
@@ -211,6 +215,12 @@ class TransformerConfig:
     # 0: the router is ``n_experts`` wide and every expert is held
     moe_router_experts: int = 0
     moe_first_expert: int = 0
+    # standard deviation the router's columns are DRAWN with
+    # (``init_params``); 0: ``init_std``. Adam moves a score by about
+    # ``0.8 * sqrt(hidden) * lr`` a step whatever the gradient's size, so
+    # how many steps a drawn routing lasts is set by how far apart this
+    # draws the scores
+    moe_router_init_std: float = 0.0
     # what guards the division that normalises a token's chosen scores: 0
     # divides by ``max(sum, 1e-9)``, a value by ``sum + value`` (the form
     # the ``lfm2_moe`` family publishes, 1e-6)
@@ -281,6 +291,19 @@ class TransformerConfig:
     @property
     def rope_scaling_dict(self) -> Optional[Dict[str, Any]]:
         return dict(self.rope_scaling) if self.rope_scaling else None
+
+    def rope_of(self, kind: Optional[str]
+                ) -> Optional[Tuple[float, Optional[Dict[str, Any]]]]:
+        """``(theta, rope_scaling)`` of the rotary table a layer of ``kind``
+        rotates by (``kind_rope``, else the model's own), or None where it
+        has no rotary."""
+        own = dict(self.kind_rope)
+        if kind not in own:
+            return self.rope_theta, self.rope_scaling_dict
+        if own[kind] is None:
+            return None
+        theta, scaling = own[kind]
+        return float(theta), dict(scaling) if scaling else None
 
     @property
     def mla_scale_mult(self) -> float:
@@ -620,7 +643,20 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     if E > 0:
         # MoE FFN: per-expert weights (no biases), router gate per layer
         fe = cfg.moe_ffn
-        block["gate_w"] = dense(keys[10], (L, h, cfg.router_experts), std)
+        R = cfg.router_experts
+        gate = dense(keys[10], (L, h, R), cfg.moe_router_init_std or std)
+        if R > E:
+            # a SHARE drawn from scratch is one of EQUAL shares: the columns
+            # of the experts held, repeated over the router's width. Equal
+            # scores stand together in a row's top-k, so a row sends each
+            # share ``moe_top_k * E / R`` of its pairs (exactly, where
+            # ``R / E`` divides ``moe_top_k``), as a router trained under
+            # its balance term spreads them. With every column a draw of
+            # its own the share's work is the draw's: rows at initialisation
+            # choose much alike, and a layer of a quarter held 0.2 % to
+            # 53 % of the pairs (PERF.md, PR 49). Training unties them.
+            gate = jnp.tile(gate[..., :E], (1, 1, -(-R // E)))[..., :R]
+        block["gate_w"] = gate
         block["w_up"] = dense(keys[4], (L, E, h, fe), std)
         block["w_down"] = dense(keys[5], (L, E, fe, h), out_std)
         if cfg.activation == "swiglu":
@@ -1044,9 +1080,12 @@ head_matmul.defvjp(_head_matmul_fwd, _head_matmul_bwd)
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           causal: bool = True,
                           segment_mask: Optional[jax.Array] = None,
-                          bias: Optional[jax.Array] = None) -> jax.Array:
+                          bias: Optional[jax.Array] = None,
+                          window: int = 0) -> jax.Array:
     """Reference (XLA-fused) attention. q:[B,S,N,D] k,v:[B,S,K,D]. fp32 softmax.
-    ``bias``: additive [N, S, S] (ALiBi) applied before masking."""
+    ``bias``: additive [N, S, S] (ALiBi) applied before masking. ``window``:
+    a causal row sees its last ``window`` positions, itself included (0:
+    every earlier one)."""
     B, S, N, D = q.shape
     K = k.shape[2]
     if K != N:
@@ -1058,6 +1097,8 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         scores = scores + bias[None]
     if causal:
         mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
+        if window:
+            mask &= ~jnp.tril(jnp.ones((S, S), jnp.bool_), -window)
         scores = jnp.where(mask[None, None], scores, -1e30)
     if segment_mask is not None:
         scores = jnp.where(segment_mask[:, None], scores, -1e30)
@@ -1224,10 +1265,12 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
 
     ``kind`` (a layer of ``cfg.layer_kinds`` over this one block,
     ``cfg.standard_blocks``): ``window`` sees its last ``cfg.attn_window``
-    positions, ``full`` every one, both under an explicit mask in plain jnp
-    (the flash kernel has no window), whatever ``attention_fn`` says;
+    positions, ``full`` every one, both through ``attention_fn`` (``window=``
+    names the window; ``cos`` / ``sin`` are the KIND's tables, None where
+    it has no rotary: ``cfg.rope_of``);
     ``sparse`` the positions its indexer chooses (:func:`index_scores`,
-    :func:`chosen_positions`);
+    :func:`chosen_positions`), under an explicit mask in plain jnp: it has
+    no kernel;
     ``conv`` has a gated short convolution where the others attend
     (``hybrid.short_conv``; ``lp`` then holds that mixer's leaves), ``kda``
     Kimi Delta Attention (``hybrid.kda_inputs`` .. ``kda_output``, every
@@ -1321,31 +1364,30 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         if cfg.qk_norm:
             q = _head_rmsnorm(q, lp["q_norm"], cfg.norm_eps)
             k = _head_rmsnorm(k, lp["k_norm"], cfg.norm_eps)
-        if cfg.pos_emb == "rope" and (kind != "full"
-                                      or cfg.full_layers_rope):
+        if cfg.pos_emb == "rope" and cos is not None:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
         attn_kwargs = {}
         if cfg.pos_emb == "alibi":
             attn_kwargs["bias"] = \
                 alibi_bias(cfg.num_heads, S) * cfg.alibi_bias_scale
-        if kind is None:
-            attn = attention_fn(q, k, v, causal=cfg.causal, **attn_kwargs)
-        else:
+        if kind == "window":
+            attn_kwargs["window"] = cfg.attn_window
+        if kind == "sparse":
+            # no kernel takes a choice of positions: plain jnp
             from deepspeed_tpu.models.hybrid import windowed_attention
 
-            chosen = None
-            if kind == "sparse":
-                with jax.named_scope("index"):
-                    scores = index_scores(*index_projections(
-                        h, lp, cfg, rope_table(
-                            S, cfg.index_head_dim, cfg.rope_theta,
-                            cfg.rope_scaling_dict)))
-                with jax.named_scope("select"):
-                    chosen = chosen_positions(scores, cfg.sparse_topk)
-            attn = windowed_attention(
-                q, k, v, cfg.head_dim ** -0.5,
-                cfg.attn_window if kind == "window" else 0, chosen)
+            with jax.named_scope("index"):
+                scores = index_scores(*index_projections(
+                    h, lp, cfg, rope_table(
+                        S, cfg.index_head_dim, cfg.rope_theta,
+                        cfg.rope_scaling_dict)))
+            with jax.named_scope("select"):
+                chosen = chosen_positions(scores, cfg.sparse_topk)
+            attn = windowed_attention(q, k, v, cfg.head_dim ** -0.5, 0,
+                                      chosen)
+        else:
+            attn = attention_fn(q, k, v, causal=cfg.causal, **attn_kwargs)
         attn = attn.reshape(B, S, cfg.num_heads * cfg.head_dim)
         if cfg.attn_gate:
             attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
@@ -1441,31 +1483,19 @@ def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
         experts = {k_: lp[k_] for k_ in ("w_up", "w_down", "w_gate") if k_ in lp}
         shared = {k_: lp[k_] for k_ in ("sw_up", "sw_down", "sw_gate",
                                         "shared_gate_w") if k_ in lp}
-        if cfg.moe_router_experts:
-            # a share of the experts: the dropless form alone knows of
-            # experts that are not here (forward only: no auxiliary loss)
-            from deepspeed_tpu.moe.layer import dropless_moe_ffn
-
-            down, _ = dropless_moe_ffn(
-                h.reshape(-1, h.shape[-1]), lp["gate_w"], experts,
-                cfg.activation, cfg.moe_top_k,
-                score_func=cfg.moe_score_func,
-                route_norm=cfg.moe_route_norm,
-                route_scale=cfg.moe_route_scale, shared=shared or None,
-                gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
-                topk_group=cfg.moe_topk_group,
-                first_expert=cfg.moe_first_expert,
-                route_norm_eps=cfg.moe_route_norm_eps)
-            return down.reshape(h.shape), aux
+        # flat rows ``[T, H]`` (a caller outside the block) are one batch
         down, aux = moe_ffn(
-            h, lp["gate_w"], experts, activation=cfg.activation,
+            h.reshape((-1,) + h.shape[-2:]), lp["gate_w"], experts,
+            activation=cfg.activation,
             k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
             min_capacity=cfg.moe_min_capacity,
             score_func=cfg.moe_score_func, route_norm=cfg.moe_route_norm,
             route_scale=cfg.moe_route_scale, shared=shared or None,
             gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
             topk_group=cfg.moe_topk_group, dispatch=cfg.moe_dispatch,
-            route_norm_eps=cfg.moe_route_norm_eps)
+            route_norm_eps=cfg.moe_route_norm_eps,
+            first_expert=cfg.moe_first_expert)
+        down = down.reshape(h.shape)
     else:
         up = h @ lp["w_up"].astype(dt)
         if cfg.use_bias:
@@ -1539,9 +1569,11 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
                 "progressive layer drop, random-LTD and the chunked "
                 "gradient sync assume one homogeneous stack; a stack of "
                 "layer kinds runs without them")
-        return (_forward_blocks_of_kinds if cfg.standard_blocks
-                else _forward_kinds)(params, tokens, cfg,
-                                     activation_constraint or (lambda x: x))
+        constrain = activation_constraint or (lambda x: x)
+        if cfg.standard_blocks:
+            return _forward_blocks_of_kinds(params, tokens, cfg, constrain,
+                                            attention_fn)
+        return _forward_kinds(params, tokens, cfg, constrain)
     attention_fn = attention_fn or dot_product_attention
     constrain = activation_constraint or (lambda x: x)
     dt = cfg.compute_dtype
@@ -1719,12 +1751,16 @@ def period_layer(lps: PyTree, period: Sequence[str], i: int) -> PyTree:
 
 
 def _forward_blocks_of_kinds(params: PyTree, tokens: jax.Array,
-                             cfg: TransformerConfig, constrain: Callable
+                             cfg: TransformerConfig, constrain: Callable,
+                             attention_fn: AttentionFn = None
                              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``forward_hidden`` of ``layer_kinds`` over the standard block
     (``cfg.standard_blocks``): each segment (leading dense layers, then
     the stack) scanned a period of its kinds at a time, ``_block_forward``
-    told each layer's kind."""
+    told each layer's kind and handed its kind's rotary tables
+    (``cfg.rope_of``; none for a kind without rotary). Remat is a LAYER's, not a period's: a
+    step's backward holds one layer's intermediates, whatever the period."""
+    attention_fn = attention_fn or dot_product_attention
     dt = cfg.compute_dtype
     B, S = tokens.shape
     with jax.named_scope("embed"):
@@ -1732,23 +1768,34 @@ def _forward_blocks_of_kinds(params: PyTree, tokens: jax.Array,
         if cfg.emb_multiplier != 1.0:
             x = x * jnp.asarray(cfg.emb_multiplier, dt)
         x = constrain(x)
-    cos = sin = None
-    if cfg.pos_emb == "rope":
-        cos, sin = rope_table(S, cfg.rope_dim, cfg.rope_theta,
-                              cfg.rope_scaling_dict)
+    # (kinds that share theta and scaling trace the same constants: XLA
+    # keeps one table)
+    ropes = {}
+    for kind in dict.fromkeys(cfg.layer_kinds):    # in the stack's own order
+        of = cfg.rope_of(kind) if cfg.pos_emb == "rope" else None
+        ropes[kind] = rope_table(S, cfg.rope_dim, *of) if of \
+            else (None, None)
     aux_total = jnp.float32(0.0)
     for key, seg in cfg.segments:
         def body_of(period, _, seg=seg):
+            def layer_of(kind):
+                def layer(x, lp):
+                    y, a = _block_forward(x, lp, seg, *ropes[kind],
+                                          attention_fn, kind)
+                    return constrain(y), a
+
+                return _remat_wrap(layer, cfg.remat)
+
+            layers = [layer_of(kind) for kind in period]
+
             def body(x, lps):
                 aux = jnp.float32(0.0)
-                for i, kind in enumerate(period):
-                    lp = period_layer(lps, period, i)
-                    x, a = _block_forward(x, lp, seg, cos, sin,
-                                          dot_product_attention, kind)
-                    x, aux = constrain(x), aux + a
+                for i, layer in enumerate(layers):
+                    x, a = layer(x, period_layer(lps, period, i))
+                    aux = aux + a
                 return x, aux
 
-            return _remat_wrap(body, cfg.remat)
+            return body
 
         x, auxes = scan_periods(body_of, x, params[key], seg.layer_kinds)
         aux_total = aux_total + sum(jnp.sum(a) for a in auxes)
@@ -1761,8 +1808,10 @@ def _forward_kinds(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     """``forward_hidden`` of a stack of ``layer_kinds``: the whole
     sequence at once, no cache. The state-space layers see the batch as
     ``B * S`` rows in runs of ``S`` from position 0 (``models/hybrid.py``);
-    attention is plain jnp under an explicit mask (the flash kernel has no
-    window), whatever ``attention_fn`` the caller named."""
+    attention is plain jnp under an explicit mask, whatever
+    ``attention_fn`` the caller named: the family's differential heads of
+    ``2 D`` columns over a cache two layers share have no flash kernel
+    (the window is not what stands in the way: the kernel has one)."""
     from deepspeed_tpu.models import hybrid as HY
 
     dt = cfg.compute_dtype

@@ -28,6 +28,7 @@ Two dispatch modes (``dispatch=`` / ``TransformerConfig.moe_dispatch``):
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -70,6 +71,24 @@ on_reset_mesh(_SHARDED_FN_CACHE.clear)
 # all-to-all buffer and the overflowed choices silently fall through to the
 # residual. The engine installs a monitor so that degradation is visible.
 _DROP_MONITOR = None
+
+
+# Installed observer of a SHARE of an expert layer in training (None: no
+# callback is traced): the rows each held expert got in one layer's call.
+_HELD_ROWS_MONITOR = None
+
+
+def set_held_rows_monitor(fn) -> None:
+    """``fn(rows [held] int32, pairs: int)`` called (async, via
+    jax.debug.callback, with no fence of its own) once for each call of a
+    layer that holds a share of its experts (:func:`moe_ffn`,
+    ``first_expert=``): the rows of each held expert and the (row, expert)
+    pairs the router chose over all of its experts. Under full
+    rematerialisation a layer calls twice a step (forward, recompute), with
+    equal values: keep means, not sums. Pass None to uninstall. Trace-time
+    gated: install BEFORE the step is compiled."""
+    global _HELD_ROWS_MONITOR
+    _HELD_ROWS_MONITOR = fn
 
 
 def set_drop_monitor(fn) -> None:
@@ -475,24 +494,99 @@ def held_group_sizes(idx: jax.Array, held: int, first_expert: int
     return order, inv.reshape(idx.shape), counts[:held], here
 
 
-def _ragged_dispatch_held(xt: jax.Array, weights: jax.Array, idx: jax.Array,
-                          experts: Dict[str, jax.Array], activation: str,
-                          layer: Optional[jax.Array], first_expert: int,
-                          router_experts: int) -> jax.Array:
+@jax.custom_vjp
+def held_dispatch_gather(x: jax.Array, order: jax.Array, inv2d: jax.Array,
+                         here: jax.Array) -> jax.Array:
+    """:func:`dispatch_gather` for a share of the experts
+    (:func:`held_group_sizes`): ``out[j] = x[order[j] // k]``. The
+    transpose takes a pair's cotangent only where the pair is HERE: a row
+    behind the held experts' groups holds nothing of this share's (under the
+    grouped matmul's kernel it is undefined where no group covers it), in
+    the backward too, and never reaches ``dx``."""
+    return jnp.take(x, order // inv2d.shape[-1], axis=0)
+
+
+def _held_dispatch_gather_fwd(x, order, inv2d, here):
+    return held_dispatch_gather(x, order, inv2d, here), (inv2d, here)
+
+
+def _held_dispatch_gather_bwd(res, g):
+    inv2d, here = res
+    picked = jnp.take(g, inv2d, axis=0)
+    return jnp.sum(jnp.where(here[..., None], picked, 0), axis=1), \
+        None, None, None
+
+
+held_dispatch_gather.defvjp(_held_dispatch_gather_fwd,
+                            _held_dispatch_gather_bwd)
+
+
+@jax.custom_vjp
+def held_combine_gather(y_s: jax.Array, weights: jax.Array, order: jax.Array,
+                        inv2d: jax.Array, here: jax.Array) -> jax.Array:
+    """:func:`combine_gather` for a share of the experts: ``out[t] = sum
+    over the pairs of t that are HERE of weights[t, c] * y_s[inv2d[t, c]]``.
+    A pair that is not here is masked out of the sum, not weighted by zero
+    (its row of ``y_s`` is another expert's result, or undefined); in the
+    transpose its row gets a zero cotangent and its weight a zero
+    gradient."""
+    picked = jnp.where(here[..., None], jnp.take(y_s, inv2d, axis=0)
+                       * weights.astype(y_s.dtype)[..., None], 0)
+    return jnp.sum(picked, axis=1)
+
+
+def _held_combine_gather_fwd(y_s, weights, order, inv2d, here):
+    return held_combine_gather(y_s, weights, order, inv2d, here), \
+        (y_s, weights, order, inv2d, here)
+
+
+def _held_combine_gather_bwd(res, g):
+    y_s, weights, order, inv2d, here = res
+    k = inv2d.shape[-1]
+    w_s = jnp.take(jnp.where(here, weights, 0).reshape(-1), order
+                   ).astype(y_s.dtype)
+    dy = jnp.take(g, order // k, axis=0) * w_s[:, None]
+    picked = jnp.take(y_s, inv2d, axis=0)
+    dw = jnp.where(here, jnp.einsum(
+        "tkh,th->tk", jnp.where(here[..., None], picked, 0), g,
+        preferred_element_type=jnp.float32), 0).astype(weights.dtype)
+    return dy, dw, None, None, None
+
+
+held_combine_gather.defvjp(_held_combine_gather_fwd,
+                           _held_combine_gather_bwd)
+
+
+def _held_routed(xt: jax.Array, weights: jax.Array, idx: jax.Array,
+                 experts: Dict[str, jax.Array], activation: str,
+                 first_expert: int, router_experts: int,
+                 layer: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
     """:func:`_ragged_dispatch_local` for a SHARE of the layer's experts
-    (:func:`held_group_sizes`): this share's part of the routed result.
-    Forward only. The rows behind the groups are undefined under the
-    grouped matmul's kernel, so a pair that is not here is masked out of
-    the sum, not weighted by zero."""
+    (:func:`held_group_sizes`), for a serving tick and a training step
+    alike: (this share's part of the routed result, the rows of each held
+    expert), forward and backward (:func:`held_dispatch_gather`,
+    :func:`held_combine_gather`: gradients reach the router through the
+    weights of the pairs that are here).
+
+    A row a pair, the groups as they fall: the pairs that are not here sort
+    behind the held experts' groups, where the grouped matmuls (``gmm``
+    forward and for the rows' gradient, ``tgmm`` for the matrices') spend
+    no tile on them, so the work follows the held pairs and a step's time
+    follows its routing. Those rows are undefined under the kernel, in the
+    backward too, so a pair that is not here is masked out of the sum and
+    out of every gradient, not weighted by zero."""
     held = experts["w_up"].shape[-3]
     order, inv2d, group_sizes, here = held_group_sizes(
         idx, held, first_expert)
-    x_s = jnp.take(xt, order // idx.shape[-1], axis=0)
+    order = _ckpt_name(order, "moe_gate")
+    inv2d = _ckpt_name(inv2d, "moe_gate")
+    group_sizes = _ckpt_name(group_sizes, "moe_gate")
+    weights = _ckpt_name(weights, "moe_gate").astype(xt.dtype)
+    x_s = held_dispatch_gather(xt, order, inv2d, here)
     y_s = ragged_expert_ffn(x_s, group_sizes, experts, activation, layer,
                             rows_share=held / router_experts)
-    picked = jnp.where(here[..., None], jnp.take(y_s, inv2d, axis=0)
-                       * weights.astype(xt.dtype)[..., None], 0)
-    return jnp.sum(picked, axis=1)
+    return held_combine_gather(y_s, weights, order, inv2d, here), group_sizes
 
 
 def _token_axes(mesh) -> Tuple[Tuple[str, ...], Optional[str]]:
@@ -630,9 +724,11 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
                    experts: Dict[str, jax.Array],
                    gate_bias: Optional[jax.Array], *, activation: str, k: int,
                    score_func: str, route_norm: bool, n_group: int,
-                   topk_group: int, route_norm_eps: float = 0.0
-                   ) -> Tuple[jax.Array, jax.Array]:
+                   topk_group: int, route_norm_eps: float = 0.0,
+                   first_expert: int = 0) -> Tuple[jax.Array, jax.Array]:
     """Dropless routed-expert computation. Returns (y [B,S,H], aux).
+    ``first_expert``: where ``experts`` holds fewer experts than ``gate_w``
+    has columns, the first of the contiguous ones held (:func:`moe_ffn`).
 
     Three lowerings by mesh shape: single-shard sort+ragged_dot; per-shard
     sort inside ``shard_map`` when only token axes are sharded; and the
@@ -652,10 +748,20 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
         # so run the plain local program and let GSPMD place it however the
         # inputs are actually sharded.
         xt = x.reshape(-1, H)
-        gate = _gate_indices(xt, gate_w, gate_bias, k, score_func,
-                             route_norm, n_group, topk_group, route_norm_eps)
-        y = _ragged_dispatch_local(xt, gate.weights, gate.experts, experts,
-                                   activation)
+        with jax.named_scope("router"):
+            gate = _gate_indices(xt, gate_w, gate_bias, k, score_func,
+                                 route_norm, n_group, topk_group,
+                                 route_norm_eps)
+        with jax.named_scope("experts"):
+            if experts["w_up"].shape[0] < E:
+                y, rows = _held_routed(xt, gate.weights, gate.experts,
+                                       experts, activation, first_expert, E)
+                if _HELD_ROWS_MONITOR is not None:
+                    jax.debug.callback(functools.partial(
+                        _HELD_ROWS_MONITOR, pairs=gate.experts.size), rows)
+            else:
+                y = _ragged_dispatch_local(xt, gate.weights, gate.experts,
+                                           experts, activation)
         return y.reshape(B, S, H), gate.aux_loss
 
     batch_axes, seq_ax, ep, tp = plan
@@ -690,14 +796,28 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
         def local_fn(x_l, gw_l, ex_l, gb_l):
             b, s, _ = x_l.shape
             xt = x_l.reshape(-1, H)
-            gate = _gate_indices(xt, gw_l, gb_l, k, score_func, route_norm,
-                                 n_group, topk_group, route_norm_eps)
-            y = _ragged_dispatch_local(xt, gate.weights, gate.experts, ex_l,
-                                       activation)
-            if tp is not None:
-                y = lax.psum(y, tp)
+            with jax.named_scope("router"):
+                gate = _gate_indices(xt, gw_l, gb_l, k, score_func,
+                                     route_norm, n_group, topk_group,
+                                     route_norm_eps)
+            with jax.named_scope("experts"):
+                if ex_l["w_up"].shape[0] < E:
+                    # a share, replicated over the token shards: each
+                    # computes its own rows' pairs on the experts held
+                    y, _ = _held_routed(xt, gate.weights, gate.experts,
+                                        ex_l, activation, first_expert, E)
+                else:
+                    y = _ragged_dispatch_local(xt, gate.weights,
+                                               gate.experts, ex_l, activation)
+                if tp is not None:
+                    y = lax.psum(y, tp)
             return y.reshape(b, s, H), _global_aux(gate), jnp.float32(0.0)
     else:
+        if experts["w_up"].shape[0] < E:
+            raise NotImplementedError(
+                "a share of an expert layer (first_expert=) beside an "
+                "`expert` mesh axis: the exchange between shares is not "
+                f"written (mesh {dict(mesh.shape)})")
         if E % ep:
             raise ValueError(f"n_experts={E} not divisible by expert mesh axis {ep}")
         E_l = E // ep
@@ -804,7 +924,8 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
     monitored = (_DROP_MONITOR is not None and ep > 1
                  and not already_manual_axes())
     cache_key = (sm_mesh, k, activation, score_func, route_norm,
-                 route_norm_eps, n_group, topk_group, x.shape, str(x.dtype),
+                 route_norm_eps, n_group, topk_group, first_expert, x.shape,
+                 str(x.dtype),
                  gate_w.shape,
                  monitored,
                  tuple(sorted((kk, v.shape, str(v.dtype))
@@ -837,8 +958,8 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
             shared: Optional[Dict[str, jax.Array]] = None,
             gate_bias: Optional[jax.Array] = None,
             n_group: int = 1, topk_group: int = 1,
-            dispatch: str = "auto", route_norm_eps: float = 0.0
-            ) -> Tuple[jax.Array, jax.Array]:
+            dispatch: str = "auto", route_norm_eps: float = 0.0,
+            first_expert: int = 0) -> Tuple[jax.Array, jax.Array]:
     """Mixture-of-experts FFN.
 
     x: [B, S, H]; gate_w: [H, E]; experts: w_up [E, H, F], w_down [E, F, H],
@@ -853,18 +974,30 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
     routed output (DeepSeek routed_scaling_factor). ``shared`` adds an
     always-on shared expert (sw_up [H,Fs], sw_down [Fs,H], optional sw_gate
     [H,Fs], optional shared_gate_w [H,1] sigmoid gate — Qwen2-MoE).
+
+    A SHARE of the layer (expert parallelism's unit without its exchange),
+    forward and backward: where ``experts`` holds fewer experts than
+    ``gate_w`` has columns, they are the contiguous ones from
+    ``first_expert``. The router scores and chooses over all of its
+    columns, the pairs that fall here are computed
+    (:func:`_held_routed`: always the dropless form, a row a pair), the
+    others add nothing, forward or backward; the auxiliary loss is over the router's whole width, and gradients
+    reach ``gate_w`` through the weights of the pairs here and through it.
     """
     B, S, H = x.shape
     dt = x.dtype
     T = B * S
     xt = x.reshape(T, H)
 
-    mode = resolve_dispatch(dispatch, rng, noise_std, B, S, gate_w.shape[1])
+    held = experts["w_up"].shape[0] < gate_w.shape[1]
+    mode = "ragged" if held else resolve_dispatch(
+        dispatch, rng, noise_std, B, S, gate_w.shape[1])
     if mode == "ragged":
         y, aux = _ragged_routed(
             x, gate_w, experts, gate_bias, activation=activation, k=k,
             score_func=score_func, route_norm=route_norm, n_group=n_group,
-            topk_group=topk_group, route_norm_eps=route_norm_eps)
+            topk_group=topk_group, route_norm_eps=route_norm_eps,
+            first_expert=first_expert)
         y = y.reshape(T, H)
     else:
         logits = xt.astype(jnp.float32) @ gate_w.astype(jnp.float32)   # [T, E]
@@ -952,9 +1085,9 @@ def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
         rows = jnp.sum(picked, axis=(0, 1))
     with jax.named_scope("experts"):
         if experts["w_up"].shape[-3] < gate_w.shape[1]:
-            y = _ragged_dispatch_held(
-                xt, gate.weights, gate.experts, experts, activation, layer,
-                first_expert, gate_w.shape[1])
+            y, _ = _held_routed(
+                xt, gate.weights, gate.experts, experts, activation,
+                first_expert, gate_w.shape[1], layer)
         else:
             y = _ragged_dispatch_local(xt, gate.weights, gate.experts,
                                        experts, activation, layer)

@@ -13,11 +13,12 @@ triton flash path ``ops/transformer/inference/triton/attention.py``). Online
 * GQA: kv tensors stay at [batch*kv_heads, S, D]; the q-head → kv-head
   mapping happens in the BlockSpec index maps (no ``jnp.repeat`` in HBM, and
   VJP residuals hold the small kv tensors);
-* a grid step does only what its block needs: a block above the diagonal is
-  neither computed nor copied (the index maps hold the moving block where
-  it was); a block goes tile by tile, with no mask where every column is
-  live, and a diagonal or edge block skips its tiles above the diagonal or
-  past a length; :func:`step_account` counts all of it from the shapes;
+* a grid step does only what its block needs: a block above the diagonal
+  or wholly behind a ``window`` is neither computed nor copied (the index
+  maps hold the moving block where it was); a block goes tile by tile, with
+  no mask where every column is live, and a diagonal, window-edge or edge
+  block skips its tiles above the diagonal, behind the window or past a
+  length; :func:`step_account` counts all of it from the shapes;
 * CPU fallback = ``interpret=True`` (the role the reference's CPU op builders
   play for its CUDA ops).
 """
@@ -49,9 +50,10 @@ def _compiler_params():
 
 
 def _block_mask(q_start, kv_start, shape, causal, kv_len, q_len=None,
-                q_axis=0):
+                q_axis=0, window=0):
     """Which scores of a block count; ``q_axis`` 1: of a block laid keys
-    down and queries across."""
+    down and queries across. ``window``: a row sees its last ``window``
+    columns, itself included (0: every one)."""
     row = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     col = kv_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     mask = col < kv_len
@@ -59,6 +61,8 @@ def _block_mask(q_start, kv_start, shape, causal, kv_len, q_len=None,
         mask = jnp.logical_and(mask, row < q_len)
     if causal:
         mask = jnp.logical_and(mask, col <= row)
+    if window:
+        mask = jnp.logical_and(mask, col > row - window)
     return mask
 
 
@@ -79,11 +83,12 @@ def _most(a, b):
     return max(a, b) if both else jnp.maximum(a, b)
 
 
-def _step_kind(q_start, kv_start, *, causal, kv_len, q_len, block_q, block_kv):
+def _step_kind(q_start, kv_start, *, causal, kv_len, q_len, block_q, block_kv,
+               window=0):
     """``(live, masked)`` of the block or tile at ``(q_start, kv_start)``:
     live if any of its columns counts for any of its rows; masked if some do
-    not (it crosses the diagonal or an unpadded length), else every one does
-    and the body builds no mask. A block of a grid always starts inside the
+    not (it crosses the diagonal, the edge of the ``window`` or an unpadded
+    length), else every one does and the body builds no mask. A block of a grid always starts inside the
     lengths (they are padded to the next block, no further); a tile of an
     edge block may lie past them. ``q_len`` None: rows past the length are
     computed and sliced off (forward, ``dq``)."""
@@ -95,6 +100,10 @@ def _step_kind(q_start, kv_start, *, causal, kv_len, q_len, block_q, block_kv):
     if causal:
         live = live & (kv_start <= q_start + block_q - 1)
         masked = masked | (kv_start + block_kv - 1 > q_start)
+    if window:
+        # row r sees the columns r - window < c <= r
+        live = live & (kv_start + block_kv - 1 > q_start - window)
+        masked = masked | (kv_start <= q_start + block_q - 1 - window)
     return live, masked
 
 
@@ -105,7 +114,7 @@ _TILE = 512
 
 
 def _tiles(q_start, kv_start, tile, *, causal, kv_len, q_len, block_q,
-           block_kv):
+           block_kv, window=0):
     """``(row offset, column offset, rows, columns, live)`` of each tile of
     the block at ``(q_start, kv_start)``, rows outermost (a row's tiles in
     the order of their columns)."""
@@ -113,29 +122,38 @@ def _tiles(q_start, kv_start, tile, *, causal, kv_len, q_len, block_q,
     tkv = tile if block_kv % tile == 0 else block_kv
     return [(r, c, tq, tkv, _step_kind(
         q_start + r, kv_start + c, causal=causal, kv_len=kv_len, q_len=q_len,
-        block_q=tq, block_kv=tkv)[0])
+        block_q=tq, block_kv=tkv, window=window)[0])
         for r in range(0, block_q, tq) for c in range(0, block_kv, tkv)]
 
 
-def _kv_block(i, j, *, causal, block_q, block_kv):
+def _kv_block(i, j, *, causal, block_q, block_kv, window=0):
     """The K / V block that step ``(i, j)`` of ``fwd`` / ``dq`` names: ``j``
     held at the last block the row's diagonal reaches, so that a dead step
-    names the block the step before it had and nothing is copied."""
+    names the block the step before it had and nothing is copied; under a
+    ``window`` held too at the first block the row's window reaches (the
+    dead steps ahead of it name the block the first live step wants)."""
+    if window:
+        j = _most(j, _most(i * block_q - window + 1, 0) // block_kv)
     return _least(j, ((i + 1) * block_q - 1) // block_kv) if causal else j
 
 
-def _q_block(i, j, *, causal, q_len, block_q, block_kv):
+def _q_block(i, j, *, causal, q_len, block_q, block_kv, window=0):
     """The Q / dO / lse / delta block that step ``(j, i)`` of ``dkv`` names:
     ``i`` held at or under the column's diagonal (a dead step above it names
     the first live block, which the next live step wants anyway; keys past
-    the last query, Skv > S, have none and name the last block)."""
+    the last query, Skv > S, have none and name the last block) and, under
+    a ``window``, at or above the last block whose rows still see the
+    column block (the dead steps past it name the block the last live step
+    had)."""
     if not causal:
         return i
+    if window:
+        i = _least(i, ((j + 1) * block_kv + window - 2) // block_q)
     return _least(_most(i, (j * block_kv) // block_q), (q_len - 1) // block_q)
 
 
 def step_account(S: int, Skv: int, causal: bool, block_q: int, block_kv: int,
-                 rep: int = 1):
+                 rep: int = 1, window: int = 0):
     """What the three grids do at these blocks, from the shapes alone (the
     grids are static): ``{kernel: {"steps", "live", "masked", "open",
     "fetched", "computed"}}`` for ONE index of the grid's leading axis (a
@@ -147,7 +165,8 @@ def step_account(S: int, Skv: int, causal: bool, block_q: int, block_kv: int,
     whole, masked blocks' live tiles)."""
     n_q = -(-S // block_q)
     n_kv = -(-Skv // block_kv)
-    shape = dict(causal=causal, block_q=block_q, block_kv=block_kv)
+    shape = dict(causal=causal, block_q=block_q, block_kv=block_kv,
+                 window=window)
 
     def walk(steps, q_len):
         out = dict(steps=0, live=0, masked=0, open=0, fetched=0, computed=0)
@@ -188,7 +207,7 @@ def _set_gauges(kernels, *shape):
     account = step_account(*shape)
     for kernel in kernels:
         for kind, n in account[kernel].items():
-            gauge.set(n, kernel=kernel, kind=kind)
+            gauge.set(n, kernel=_call_name(kernel, shape[-1]), kind=kind)
 
 
 def _run_step(tile, q_start, kv_start, tile_size, **shape):
@@ -248,7 +267,7 @@ _NN = ((1,), (0,))     # a @ b
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
                 *, scale: float, causal: bool, kv_len: int,
-                block_q: int, block_kv: int, tile: int):
+                block_q: int, block_kv: int, tile: int, window: int):
     i = pl.program_id(1)
     j = pl.program_id(2)
     n_kv = pl.num_programs(2)
@@ -267,8 +286,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = _dot(_f32(q_ref[0, rows, :]), _f32(k_ref[0, cols, :]),
                  _NT) * scale
         if mask_at is not None:
-            s = jnp.where(_block_mask(*mask_at, s.shape, causal, kv_len),
-                          s, NEG_INF)
+            s = jnp.where(_block_mask(*mask_at, s.shape, causal, kv_len,
+                                      window=window), s, NEG_INF)
 
         # the statistics lie replicated over their 128 lanes: no step
         # slices a lane out of them or broadcasts one back
@@ -284,7 +303,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_ref[rows, :] = acc_ref[rows, :] * _lanes(alpha, pv.shape[1]) + pv
 
     _run_step(_tile, i * block_q, j * block_kv, tile, causal=causal,
-              kv_len=kv_len, q_len=None, block_q=block_q, block_kv=block_kv)
+              kv_len=kv_len, q_len=None, block_q=block_q, block_kv=block_kv,
+              window=window)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
@@ -307,6 +327,12 @@ def _row_specs(rep, D, block_q, block_kv, kv_block):
     return row, stat, kv
 
 
+def _call_name(kernel: str, window: int) -> str:
+    """The call's name in a trace: a window call carries one of its own, so
+    that a reader tells it from a full one (its live area differs)."""
+    return f"window_{kernel}" if window else kernel
+
+
 # Each call sits in an inlined inner ``jit``: a training step traces the
 # forward and the backward rule several times over (linearize, remat, the
 # transpose), and a body of eight tiles traced anew each time cost 3-5 s of
@@ -320,10 +346,11 @@ def _inlined_call(build):
 
 @_inlined_call
 def _fwd(q, k, v, *, scale, causal, kv_len, rep, block_q, block_kv, tile,
-         interpret):
+         interpret, window=0):
     BN, S_pad, D = q.shape
     BK, Skv_pad, _ = k.shape
-    shape = dict(causal=causal, block_q=block_q, block_kv=block_kv)
+    shape = dict(causal=causal, block_q=block_q, block_kv=block_kv,
+                 window=window)
     row, stat, kv = _row_specs(rep, D, block_q, block_kv,
                                functools.partial(_kv_block, **shape))
 
@@ -346,7 +373,7 @@ def _fwd(q, k, v, *, scale, causal, kv_len, rep, block_q, block_kv, tile,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="flash_fwd",
+        name=_call_name("flash_fwd", window),
     )(q, k, v)
     return o, lse
 
@@ -357,7 +384,7 @@ def _fwd(q, k, v, *, scale, causal, kv_len, rep, block_q, block_kv, tile,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    acc_ref, *, scale: float, causal: bool, kv_len: int,
-                   block_q: int, block_kv: int, tile: int):
+                   block_q: int, block_kv: int, tile: int, window: int):
     i = pl.program_id(1)
     j = pl.program_id(2)
     n_kv = pl.num_programs(2)
@@ -371,15 +398,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = _dot(_f32(q_ref[0, rows, :]), k, _NT) * scale
         p = jnp.exp(s - lse_ref[0, rows, :])               # [tq, tkv]
         if mask_at is not None:
-            p = jnp.where(_block_mask(*mask_at, s.shape, causal, kv_len),
-                          p, 0.0)
+            p = jnp.where(_block_mask(*mask_at, s.shape, causal, kv_len,
+                                      window=window), p, 0.0)
         dp = _dot(_f32(do_ref[0, rows, :]), _f32(v_ref[0, cols, :]), _NT)
         # the scale: once, at _finalize
         ds = p * (dp - delta_ref[0, rows, :])
         acc_ref[rows, :] += _dot(ds, k, _NN)
 
     _run_step(_tile, i * block_q, j * block_kv, tile, causal=causal,
-              kv_len=kv_len, q_len=None, block_q=block_q, block_kv=block_kv)
+              kv_len=kv_len, q_len=None, block_q=block_q, block_kv=block_kv,
+              window=window)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
@@ -389,7 +417,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale: float, causal: bool, kv_len: int, q_len: int,
-                    n_q: int, block_q: int, block_kv: int, tile: int):
+                    n_q: int, block_q: int, block_kv: int, tile: int,
+                    window: int):
     j = pl.program_id(1)       # kv block (outer)
     inner = pl.program_id(2)   # (q-head-in-group, q block) flattened (inner)
     n_inner = pl.num_programs(2)
@@ -411,7 +440,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p = jnp.exp(s - lse_ref[0, 0, :, rows])
         if mask_at is not None:
             p = jnp.where(_block_mask(*mask_at, s.shape, causal, kv_len,
-                                      q_len, q_axis=1), p, 0.0)
+                                      q_len, q_axis=1, window=window),
+                          p, 0.0)
         dv_acc[cols, :] += _dot(p, do, _NN)
         dp = _dot(_f32(v_ref[0, cols, :]), do, _NT)        # [tkv, tq]
         # the scale: once, at _finalize
@@ -419,7 +449,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[cols, :] += _dot(ds, q, _NN)
 
     _run_step(_tile, i * block_q, j * block_kv, tile, causal=causal,
-              kv_len=kv_len, q_len=q_len, block_q=block_q, block_kv=block_kv)
+              kv_len=kv_len, q_len=q_len, block_q=block_q, block_kv=block_kv,
+              window=window)
 
     @pl.when(inner == n_inner - 1)
     def _finalize():
@@ -443,10 +474,11 @@ def _kv_index(rep: int):
 
 @_inlined_call
 def _bwd_dq(q, k, v, do, lse, delta, *, scale, causal, kv_len, rep, block_q,
-            block_kv, tile, interpret):
+            block_kv, tile, interpret, window=0):
     BN, S_pad, D = q.shape
     Skv_pad = k.shape[1]
-    shape = dict(causal=causal, block_q=block_q, block_kv=block_kv)
+    shape = dict(causal=causal, block_q=block_q, block_kv=block_kv,
+                 window=window)
     row, stat, kv = _row_specs(rep, D, block_q, block_kv,
                                functools.partial(_kv_block, **shape))
     return pl.pallas_call(
@@ -459,13 +491,13 @@ def _bwd_dq(q, k, v, do, lse, delta, *, scale, causal, kv_len, rep, block_q,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="flash_dq",
+        name=_call_name("flash_dq", window),
     )(q, k, v, do, lse, delta)
 
 
 @_inlined_call
 def _bwd_dkv(q, k, v, do, lse, delta, *, scale, causal, kv_len, q_len, rep,
-             block_q, block_kv, tile, interpret):
+             block_q, block_kv, tile, interpret, window=0):
     """dk/dv: the grid's leading dim is the KV batch; the inner dim flattens
     (q-head-in-group × q-block) so the accumulator sums the whole GQA
     group."""
@@ -473,7 +505,8 @@ def _bwd_dkv(q, k, v, do, lse, delta, *, scale, causal, kv_len, q_len, rep,
     BK, Skv_pad, _ = k.shape
     n_q = S_pad // block_q
     q_block = functools.partial(_q_block, causal=causal, q_len=q_len,
-                                block_q=block_q, block_kv=block_kv)
+                                block_q=block_q, block_kv=block_kv,
+                                window=window)
 
     moving = pl.BlockSpec(
         (1, block_q, D),
@@ -490,7 +523,8 @@ def _bwd_dkv(q, k, v, do, lse, delta, *, scale, causal, kv_len, q_len, rep,
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, q_len=q_len, n_q=n_q,
-                          block_q=block_q, block_kv=block_kv, tile=tile),
+                          block_q=block_q, block_kv=block_kv, tile=tile,
+                          window=window),
         grid=(BK, Skv_pad // block_kv, rep * n_q),
         in_specs=[moving, col, col, moving, stat, stat],
         out_specs=[col, col],
@@ -504,7 +538,7 @@ def _bwd_dkv(q, k, v, do, lse, delta, *, scale, causal, kv_len, q_len, rep,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="flash_dkv",
+        name=_call_name("flash_dkv", window),
     )(q, k, v, do, lse, delta)
 
 
@@ -512,21 +546,24 @@ def _bwd_dkv(q, k, v, do, lse, delta, *, scale, causal, kv_len, q_len, rep,
 # public entry — custom VJP over the padded [B*heads, S, D] layout
 # --------------------------------------------------------------------------- #
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, scale, causal, kv_len, q_len, rep, block_q, block_kv):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, scale, causal, kv_len, q_len, rep, block_q, block_kv,
+           window):
     return _flash_fwd(q, k, v, scale, causal, kv_len, q_len, rep, block_q,
-                      block_kv)[0]
+                      block_kv, window)[0]
 
 
-def _flash_fwd(q, k, v, scale, causal, kv_len, q_len, rep, block_q, block_kv):
-    _set_gauges(("flash_fwd",), q_len, kv_len, causal, block_q, block_kv, rep)
+def _flash_fwd(q, k, v, scale, causal, kv_len, q_len, rep, block_q, block_kv,
+               window):
+    _set_gauges(("flash_fwd",), q_len, kv_len, causal, block_q, block_kv, rep,
+                window)
     o, lse = _fwd(q, k, v, scale=scale, causal=causal, kv_len=kv_len, rep=rep,
                   block_q=block_q, block_kv=block_kv, tile=_TILE,
-                  interpret=_use_interpret())
+                  interpret=_use_interpret(), window=window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
+def _flash_bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv, window,
                residuals, do):
     q, k, v, o, lse = residuals
     # delta_r = rowsum(dO * O) — cheap elementwise, let XLA fuse it
@@ -534,9 +571,9 @@ def _flash_bwd(scale, causal, kv_len, q_len, rep, block_q, block_kv,
                     axis=-1, keepdims=True)                # [BN, S_pad, 1]
     shape = dict(scale=scale, causal=causal, kv_len=kv_len, rep=rep,
                  block_q=block_q, block_kv=block_kv, tile=_TILE,
-                 interpret=_use_interpret())
+                 interpret=_use_interpret(), window=window)
     _set_gauges(("flash_dq", "flash_dkv"), q_len, kv_len, causal, block_q,
-                block_kv, rep)
+                block_kv, rep, window)
     dq = _bwd_dq(q, k, v, do, lse, delta, **shape)
     dk, dv = _bwd_dkv(q, k, v, do, lse, delta, q_len=q_len, **shape)
     return dq, dk, dv
@@ -587,12 +624,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     segment_mask: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
-                    block_kv: Optional[int] = None) -> jax.Array:
+                    block_kv: Optional[int] = None,
+                    window: int = 0) -> jax.Array:
     """Drop-in for ``models.transformer.dot_product_attention``.
 
     q: [B, S, N, D]; k, v: [B, S, K, D] (K divides N → GQA via kernel index
-    maps, no repetition in HBM). Arbitrary masks fall back to the XLA
-    reference implementation (the Pallas kernel handles causal/full only).
+    maps, no repetition in HBM). ``window`` (a Python int, closed over like
+    ``causal``; 0: none): a row sees its last ``window`` positions, itself
+    included; the calls then carry a name of their own
+    (``window_flash_fwd`` / ``_dq`` / ``_dkv``). Arbitrary masks fall back
+    to the XLA reference implementation (the Pallas kernel handles causal,
+    causal under a window, and full).
 
     ``block_q`` / ``block_kv``: the three kernels' blocks, for a caller that
     names them; left out, they are chosen from the shapes
@@ -604,14 +646,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         from deepspeed_tpu.models.transformer import dot_product_attention
 
         return dot_product_attention(q, k, v, causal=causal,
-                                     segment_mask=segment_mask)
+                                     segment_mask=segment_mask,
+                                     window=window)
+    if window and not causal:
+        raise ValueError("a window is the last positions a causal row sees: "
+                         f"window={window} needs causal=True")
 
     B, _, N, _ = q.shape
     K = k.shape[2]
     if N % K != 0:
         raise ValueError(f"q heads {N} not divisible by kv heads {K}")
     local = functools.partial(_flash_local, causal=causal, block_q=block_q,
-                              block_kv=block_kv)
+                              block_kv=block_kv, window=int(window))
     part = _mesh_partition(B, N, K)
     if part is None:
         return local(q, k, v)
@@ -636,7 +682,7 @@ def choose_blocks(S: int, Skv: int):
     return min(1024, _round_pow2(S)), min(1024, _round_pow2(Skv))
 
 
-def _flash_local(q, k, v, *, causal, block_q, block_kv):
+def _flash_local(q, k, v, *, causal, block_q, block_kv, window=0):
     B, S, N, D = q.shape
     rep = N // k.shape[2]
     Skv = k.shape[1]
@@ -654,7 +700,8 @@ def _flash_local(q, k, v, *, causal, block_q, block_kv):
     vb = _pad_seq(to_bn(v), block_kv)
 
     scale = 1.0 / math.sqrt(D)
-    o = _flash(qb, kb, vb, scale, causal, Skv, S, rep, block_q, block_kv)
+    o = _flash(qb, kb, vb, scale, causal, Skv, S, rep, block_q, block_kv,
+               window)
     o = o[:, :S]
     return o.reshape(B, N, S, D).transpose(0, 2, 1, 3)
 

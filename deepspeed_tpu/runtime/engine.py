@@ -416,6 +416,41 @@ class DeepSpeedTPUEngine:
 
             set_drop_monitor(_sink)
 
+        # a model that holds a SHARE of each expert layer (expert
+        # parallelism's unit): the rows of each held expert reach the
+        # registry by an async callback that rides the step, no fence
+        if self._tm is not None and getattr(
+                getattr(model, "config", None), "moe_router_experts", 0):
+            from deepspeed_tpu import telemetry
+            from deepspeed_tpu.moe.layer import set_held_rows_monitor
+
+            rows_h = telemetry.histogram(
+                "train_moe_held_expert_rows",
+                "calls of an expert layer that holds a share of its "
+                "experts: mean rows a held expert got",
+                buckets=tuple(2.0 ** i for i in range(4, 18)))
+            imbalance_h = telemetry.histogram(
+                "train_moe_load_imbalance",
+                "calls of an expert layer that holds a share of its "
+                "experts: the busiest held expert's rows over the mean "
+                "(1 = even routing)",
+                buckets=(1.0, 1.05, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0))
+            share_h = telemetry.histogram(
+                "train_moe_held_pair_share",
+                "calls of an expert layer that holds a share of its "
+                "experts: (row, expert) pairs on held experts over all the "
+                "router chose",
+                buckets=tuple(i / 16 for i in range(1, 17)))
+
+            def _held_rows(rows, pairs):
+                rows = np.asarray(rows, np.float64)
+                mean = float(rows.mean())
+                rows_h.observe(mean)
+                imbalance_h.observe(float(rows.max()) / max(mean, 1e-9))
+                share_h.observe(float(rows.sum()) / pairs)
+
+            set_held_rows_monitor(_held_rows)
+
         n_params = model.num_params
         log_dist(
             f"engine up: model={model.name} params={n_params or '?'} "
@@ -774,6 +809,10 @@ class DeepSpeedTPUEngine:
         n_layers = getattr(spec_cfg, "num_layers", 0) or 0
         can_chunk = (model.builder is not None and spec_cfg is not None
                      and hasattr(spec_cfg, "scan_chunks") and n_layers > 1
+                     # a stack of layer kinds scans a period at a time
+                     # (``scan_periods``): no chunked scan to hang the
+                     # gathers and the gradient sync points on
+                     and not getattr(spec_cfg, "layer_kinds", ())
                      and self.mesh_manager.axis_size("pipe") == 1)
         bounds = []
         if can_chunk:

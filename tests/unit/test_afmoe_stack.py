@@ -447,7 +447,7 @@ def test_importer_reads_the_published_config():
             cfg.moe_ffn, cfg.moe_shared_size) == (48, 8, 128, 12288, 3072,
                                                   3072)
     assert cfg.post_norms and cfg.attn_gate and cfg.qk_norm
-    assert not cfg.full_layers_rope and not cfg.moe_router_experts
+    assert cfg.rope_of("full") is None and not cfg.moe_router_experts
     assert cfg.emb_multiplier == pytest.approx(3072 ** 0.5)
     assert cfg.moe_route_scale == 2.448
     # 398.6 B in all (published: 400B)
@@ -562,7 +562,7 @@ def test_a_mistake_made_on_purpose_is_seen(mistake):
     want = R.forward_logits(params, toks, R.arch_from_config(hf, hf))
     wrong, p = {
         "no-gate": (dict(attn_gate=False), params),
-        "rope-on-full": (dict(full_layers_rope=True), params),
+        "rope-on-full": (dict(kind_rope=()), params),
         "no-window": (dict(attn_window=4096), params),
         "top-3": (dict(moe_top_k=3), params),
         "no-route-scale": (dict(moe_route_scale=1.0), params),

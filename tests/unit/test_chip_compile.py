@@ -359,6 +359,106 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, monkeypatch):
             matmuls * B * N * S_pad * S_pad * D
 
 
+def _mosaic_calls(text):
+    """The Mosaic calls of a compiled program as a trace would name them:
+    the instruction's name, and its text with the operands' types (which
+    the compiled text keeps under ``operand_layout_constraints``)."""
+    from benchmarks.trace_reduce import Op
+
+    calls = []
+    for line in text.splitlines():
+        if "custom_call_target=\"tpu_custom_call\"" not in line:
+            continue
+        name = line.split("=", 1)[0].strip().lstrip("%")
+        calls.append(Op(name, "custom-call", re.sub(
+            r"custom-call\(.*?\), (.*operand_layout_constraints=\{(.+?\})\}, )",
+            r"custom-call(\2), \1", line), 0.0, 0.0))
+    return calls
+
+
+# the flash kernels under a WINDOW at the training cell that has one: a
+# chip's share of a step (2 x 8,192, 32 query / 4 KV heads of 128, window
+# 1,024) and, so that the geometry is not the one case, a window longer than
+# a block at Mistral's 1 x 4,096 x 32 / 8
+WINDOW_FLASH_SHAPES = {
+    "mellum2-2x8192-w1024": (2, 8192, 32, 4, 128, 1024),
+    "gqa-1x4096-w1536": (1, 4096, 32, 8, 128, 1536),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(WINDOW_FLASH_SHAPES))
+def test_window_flash_kernels_compile_for_v5e(one_chip, shape, monkeypatch):
+    import importlib
+
+    from benchmarks.roofline import flash_attention as full
+    from benchmarks.roofline import window_flash_attention as need
+
+    F = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(F, "_use_interpret", lambda: False)
+    B, S, N, K, D, window = WINDOW_FLASH_SHAPES[shape]
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((B, S, heads, D), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def grads(q, k, v, do):
+        o, back = jax.vjp(lambda q, k, v: F.flash_attention(
+            q, k, v, causal=True, window=window), q, k, v)
+        return (o,) + back(do)
+
+    text = jax.jit(grads).lower(arg(N), arg(K), arg(K), arg(N)) \
+        .compile().as_text()
+    calls = _mosaic_calls(text)
+    # three Mosaic calls under names of their own: the window's reader finds
+    # them by name, with the live area for their need
+    assert sorted(need.classify(c) for c in calls) == ["dkv", "dq", "fwd"]
+    assert all("window_flash_" in c.name for c in calls)
+    area = S * window - window * window / 2
+    for c in calls:
+        kind = need.classify(c)
+        assert need.ops_and_bytes(kind, c.text, window)[0] == \
+            need._MATMULS[kind] * 2 * B * N * area * D
+        # the full kernels' reader would take it for a causal square
+        assert full.ops_and_bytes(kind, c.text)[0] > \
+            3.9 * need.ops_and_bytes(kind, c.text, window)[0] * (
+                1 if window == 1024 else 0.4)
+
+
+# the grouped matmuls of the training cell with experts, forward and
+# backward, at a layer's shapes: a row a pair of the step's 2 x 8,192 x 8 x 2,304
+# against 16 held experts' [2,304, 896] and [896, 2,304], under the training
+# ladder's tiles
+def test_training_gmm_and_tgmm_compile_for_v5e(one_chip, monkeypatch):
+    from benchmarks.roofline import train_expert_gmm as need
+    from deepspeed_tpu.moe import layer as MOE
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows = 2 * 8192 * 8
+    bf = jnp.bfloat16
+
+    def arg(dims, dtype=bf):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def ffn(x, experts, sizes):
+        return jnp.sum(MOE.ragged_expert_ffn(
+            x, sizes, experts, "swiglu").astype(jnp.float32))
+
+    experts = {"w_up": arg((16, 2304, 896)), "w_gate": arg((16, 2304, 896)),
+               "w_down": arg((16, 896, 2304))}
+    text = jax.jit(jax.grad(ffn, (0, 1))).lower(
+        arg((rows, 2304)), experts, arg((16,), jnp.int32)).compile().as_text()
+    calls = _mosaic_calls(text)
+    kinds = [need.classify(c) for c in calls]
+    # forward three, the rows' gradient three (XLA may share one of them:
+    # the sum's cotangent is a constant), the matrices' three
+    assert kinds.count("gmm") in (5, 6) and kinds.count("tgmm") == 3
+    pairs = 2 * 8192 * 8 // 4
+    for c, kind in zip(calls, kinds):
+        ops, moved = need.ops_and_bytes(kind, c.text, pairs)
+        assert ops == 2.0 * pairs * 2304 * 896, (kind, c.text[:200])
+        assert moved >= 2 * (16 * 2304 * 896 + pairs * 896)
+
+
 # (cell, rows of its small tick bucket, its widest table tier, the
 # convolution-state store): the decode programs of the three cells whose
 # sequences keep a convolution's last inputs, whole, at the cells' real

@@ -324,3 +324,131 @@ def test_zero3_train_step_maps_every_kernel(data_mesh):
             engine.state, batch)
     assert "pallas_call" in str(traced)
     assert _unmapped_pallas_calls(traced.jaxpr) == []
+
+
+# ------------------------------------------------------------------ #
+# a window: a causal row sees its last ``window`` positions
+# ------------------------------------------------------------------ #
+# (S, query heads a KV head, block_q, block_kv, window): windows smaller
+# than, equal to and larger than a block, one of a single position, one
+# longer than the sequence, lengths that end inside a block; tiles of 128,
+# so that a block on the window's edge has a dead tile among its four
+WINDOWED = [
+    (512, 1, 128, 256, 64),
+    (512, 4, 256, 256, 256),
+    (512, 2, 128, 128, 300),
+    (640, 4, 128, 256, 128),
+    (600, 1, 256, 256, 1),
+    (600, 4, 256, 128, 200),
+    (384, 2, 128, 128, 1000),
+]
+
+
+def _windowed_reference(q, k, v, window):
+    from deepspeed_tpu.models.hybrid import windowed_attention
+
+    return windowed_attention(q, k, v, q.shape[-1] ** -0.5, window)
+
+
+@pytest.mark.parametrize("S,rep,block_q,block_kv,window", WINDOWED)
+def test_window_forward_and_gradients(S, rep, block_q, block_kv, window,
+                                      small_tiles):
+    (q, k, v), _ = _blocked_qkv(11, S, rep, "float32")
+    ct = jax.random.normal(jax.random.PRNGKey(12), q.shape)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block_q,
+                               block_kv=block_kv, window=window)
+
+    out, vjp = jax.vjp(kernel, q, k, v)
+    ref, ref_vjp = jax.vjp(
+        lambda q, k, v: _windowed_reference(q, k, v, window), q, k, v)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for name, got, want in zip(("dq", "dk", "dv"), vjp(ct), ref_vjp(ct)):
+        np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5,
+                                   err_msg=name)
+    # the plain attention takes the same argument
+    np.testing.assert_allclose(
+        dot_product_attention(q, k, v, causal=True, window=window), ref,
+        atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("S,rep,block_q,block_kv", [b[:2] + b[3:]
+                                                    for b in BLOCKED])
+def test_no_window_is_todays_call(S, rep, block_q, block_kv):
+    """``window=0`` is the call without the argument: equal to the bit,
+    forward and backward, under the same names."""
+    (q, k, v), _ = _blocked_qkv(13, S, rep, "float32")
+
+    def run(**kw):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=block_q, block_kv=block_kv, **kw),
+            q, k, v)
+        return (out,) + vjp(out)
+
+    for a, b in zip(run(), run(window=0)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_window_needs_causal():
+    q, k, v = _rand_qkv(jax.random.PRNGKey(14), 1, 128, 2, 32)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=16)
+
+
+# (S, block, window, rep) -> live steps a head, counted by hand. At 8,192
+# under 1,024 x 1,024 blocks a row block sees its own block and the one
+# before it (15 live of 64; the causal triangle has 36), every one masked
+# (the diagonal, or the window's edge), each as four 512-wide tiles of
+# which one is dead: (7 x 6 + 3) tiles; ``dk/dv`` walks the same pairs from
+# the columns, for each of the group's query heads
+WINDOW_GEOMETRY = {
+    (8192, 1024, 1024, 8): (15, 45 * 512 * 512),
+    (8192, 1024, 0, 8): (36, (28 * 4 + 8 * 3) * 512 * 512),
+    (4096, 512, 1024, 1): (8 + 7 + 6, (8 + 7 + 6) * 512 * 512),
+    (2048, 1024, 4096, 1): (3, 10 * 512 * 512),
+}
+
+
+@pytest.mark.parametrize("S,block,window,rep", sorted(WINDOW_GEOMETRY))
+def test_step_account_under_a_window(S, block, window, rep):
+    live, computed = WINDOW_GEOMETRY[S, block, window, rep]
+    account = step_account(S, S, True, block, block, rep, window)
+    n = S // block
+    for kernel in ("flash_fwd", "flash_dq"):
+        assert account[kernel]["steps"] == n * n
+        assert account[kernel]["live"] == live
+        assert account[kernel]["computed"] == computed
+    assert account["flash_dkv"]["live"] == rep * live
+    assert account["flash_dkv"]["computed"] == rep * computed
+    # by brute force: a block is live iff one of its scores counts
+    rows, cols = np.arange(S)[:, None], np.arange(S)[None, :]
+    seen = (cols <= rows) & ((cols > rows - window) if window else True)
+    blocks = seen.reshape(n, block, n, block).any(axis=(1, 3))
+    assert int(blocks.sum()) == live
+    # the kernels' moving blocks stay inside their arrays, and a dead step
+    # names a block that a live step of its row (column) also names
+    from deepspeed_tpu.ops.pallas.flash_attention import _kv_block, _q_block
+
+    shape = dict(causal=True, block_q=block, block_kv=block, window=window)
+    for i in range(n):
+        named = {_kv_block(i, j, **shape) for j in range(n)}
+        assert named == set(np.flatnonzero(blocks[i]))
+    for j in range(n):
+        named = {_q_block(i, j, q_len=S, **shape) for i in range(n)}
+        assert named == set(np.flatnonzero(blocks[:, j]))
+
+
+def test_window_calls_set_their_own_gauges():
+    from deepspeed_tpu import telemetry
+
+    telemetry.reset()
+    q, k, v = _rand_qkv(jax.random.PRNGKey(15), 1, 256, 4, 32, K=1)
+    jax.grad(lambda q: jnp.sum(flash_attention(
+        q, k, v, causal=True, block_q=64, block_kv=64, window=64)))(q)
+    gauge = telemetry.gauge("flash_steps")
+    account = step_account(256, 256, True, 64, 64, 4, 64)
+    for kernel, steps in account.items():
+        for kind, n in steps.items():
+            assert gauge.value(kernel=f"window_{kernel}", kind=kind) == n
+    assert account["flash_fwd"]["live"] == 7

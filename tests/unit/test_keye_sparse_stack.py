@@ -75,7 +75,12 @@ def _noisy(params, seed=1):
         noise = jax.random.normal(k, x.shape)
         if "norm" in name or "ln" in name:
             return x + 0.1 * noise
-        return x * (10.0 if "idx" in name or "gate_w" in name else 3.0)
+        if "gate_w" in name:
+            # ``init_params`` draws a share's router as one of EQUAL shares
+            # (the held experts' columns repeated): a column of its own
+            # again, so that another share's columns are another result
+            return 10.0 * (x + 0.02 * noise)
+        return x * (10.0 if "idx" in name else 3.0)
 
     return jax.tree_util.tree_map_with_path(one, params)
 

@@ -9,15 +9,18 @@ import glob
 import os
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import deepspeed_tpu as dst
 from deepspeed_tpu.inference.fastgen import FastGenEngine
 
 PALLAS = os.path.join(os.path.dirname(dst.__file__), "ops", "pallas")
 KERNEL_NAMES = {
-    "flash_fwd", "flash_dq", "flash_dkv", "paged_attention",
+    "flash_fwd", "flash_dq", "flash_dkv", "window_flash_fwd",
+    "window_flash_dq", "window_flash_dkv", "paged_attention",
     "latent_paged_attention", "kda_step", "kda_chunk", "index_scores",
     "sparse_choice", "block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv",
     "evoformer_attention", "fused_adam", "quantize_int8_blocks",
@@ -56,6 +59,13 @@ def test_every_pallas_call_is_named():
             if isinstance(node, ast.Call) and \
                     getattr(node.func, "id", "") == "_run_rows":
                 literal.add(node.args[0].value)   # norms.py: by the caller
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "id", "") == "_call_name" and \
+                    isinstance(node.args[0], ast.Constant):
+                # flash_attention.py: a call under a window carries a name
+                # of its own, ``window_<kernel>``
+                literal.update((node.args[0].value,
+                                "window_" + node.args[0].value))
             if isinstance(node, ast.Call) and \
                     getattr(node.func, "id", "") == "_walk":
                 # paged_attention.py: one call site, named by each of the
@@ -120,6 +130,74 @@ def test_training_step_carries_phases_and_kernel_names():
     assert len(by_phase.get(None, [])) <= 4
     assert gap_chain.phase_of(next(
         s for s in stacks if "rematted_computation/attn" in s)) == "recompute"
+    engine.shutdown_telemetry()
+
+
+def test_training_step_of_held_experts_carries_its_scopes_and_counters():
+    """A stack of window and full layers over experts held as a share: the
+    window layers' flash calls under names of their own, ``router`` and
+    ``experts`` scopes in forward, recompute and backward, and the rows of
+    each held expert in the registry with no fence of their own."""
+    import dataclasses
+    import json
+    import os
+
+    from benchmarks import model_config
+    from deepspeed_tpu import telemetry
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            dst.__file__)), "benchmarks", "configs",
+            "mellum2-12b-a2.5b.json")) as f:
+        cfg = model_config.build(json.load(f), "train", remat="full",
+                                 rehearse=True)
+    cfg = dataclasses.replace(cfg, dtype="float32")
+    spec = dst.causal_lm_spec(cfg, attention="flash")
+    engine, *_ = dst.initialize(model=spec, config={
+        "train_batch_size": 8, "train_micro_batch_size_per_gpu": 1,
+        "gradient_accumulation_steps": 1,
+        "optimizer": {"type": "adam", "params": {"lr": 1e-3}},
+        "zero_optimization": {"stage": 3}})
+    step = engine._select_step_builder(1)
+    batch = {"tokens": jnp.zeros((1, 8, 64), jnp.int32)}
+    with engine.mesh:
+        stacks = _stacks(step.lower(engine.state, batch), "train_step")
+
+    def some(*parts):
+        return any(all(p in s for p in parts) for s in stacks)
+
+    for scope in ("/router/", "/experts/"):
+        assert some("loss_and_grads/jvp(", "/mlp/", scope), scope
+        assert some("rematted_computation", "/mlp/", scope), scope
+        assert some("transpose(", "/mlp/", scope), scope
+    assert some("/attn/", "/window_flash_fwd")
+    assert some("/attn/", "/flash_fwd")
+    assert some("transpose(", "/window_flash_dq")
+    assert some("transpose(", "/window_flash_dkv")
+    # the benchmark's readers find the scopes as the serving form's
+    from benchmarks import gap_chain
+
+    assert {gap_chain.phase_of(s) for s in stacks if "/experts/" in s} \
+        >= {"fwd", "recompute", "bwd"}
+    # and a program of one chip's rows (no axis of the mesh divides two
+    # sequences) leaves the held experts' rows in the registry: four
+    # layers, forward and recompute, 2 x 64 x 8 pairs, a quarter of them here
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (2, 64)).astype(np.int32)
+    params = jax.tree.map(lambda x: x.astype(jnp.float32),
+                          engine.state["master"])
+    jax.block_until_ready(jax.jit(jax.grad(spec.loss_fn))(
+        params, {"tokens": tokens}))
+    jax.effects_barrier()
+    got = {}
+    for name in ("train_moe_held_expert_rows", "train_moe_held_pair_share",
+                 "train_moe_load_imbalance"):
+        (_, child), = telemetry.histogram(name).labels_items()
+        assert child.count == 8, name
+        got[name] = child.sum / child.count
+    assert got["train_moe_held_expert_rows"] == pytest.approx(
+        got["train_moe_held_pair_share"] * 2 * 64 * 8 / 4)
+    assert 0.15 < got["train_moe_held_pair_share"] < 0.35
+    assert got["train_moe_load_imbalance"] >= 1.0
     engine.shutdown_telemetry()
 
 

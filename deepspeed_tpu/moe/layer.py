@@ -54,6 +54,7 @@ from deepspeed_tpu.moe.gating import (
     topk_gating,
     topk_gating_indices,
 )
+from deepspeed_tpu.ops.pallas.unwritten import unwritten
 
 PyTree = Any
 
@@ -79,11 +80,13 @@ _HELD_ROWS_MONITOR = None
 
 
 def set_held_rows_monitor(fn) -> None:
-    """``fn(rows [held] int32, pairs: int)`` called (async, via
-    jax.debug.callback, with no fence of its own) once for each call of a
-    layer that holds a share of its experts (:func:`moe_ffn`,
-    ``first_expert=``): the rows of each held expert and the (row, expert)
-    pairs the router chose over all of its experts. Under full
+    """``fn(rows [held] int32, pairs: int, tile: int | None)`` called
+    (async, via jax.debug.callback, with no fence of its own) once for each
+    call of a layer that holds a share of its experts (:func:`moe_ffn`,
+    ``first_expert=``): the rows of each held expert, the (row, expert)
+    pairs the router chose over all of its experts, and the sorted rows a
+    step of the layer's movers takes (:func:`held_tiles`; None: the plain
+    forms moved a row a pair). Under full
     rematerialisation a layer calls twice a step (forward, recompute), with
     equal values: keep means, not sums. Pass None to uninstall. Trace-time
     gated: install BEFORE the step is compiled."""
@@ -261,7 +264,9 @@ def grouped_dot(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
 def ragged_expert_ffn(x_sorted: jax.Array, group_sizes: jax.Array,
                       experts: Dict[str, jax.Array], activation: str,
                       layer: Optional[jax.Array] = None,
-                      rows_share: float = 1.0) -> jax.Array:
+                      rows_share: float = 1.0,
+                      live_rows: Optional[Tuple[jax.Array, int]] = None
+                      ) -> jax.Array:
     """Grouped expert FFN on expert-sorted tokens.
 
     x_sorted [M, H] — rows grouped contiguously by expert; group_sizes [E]
@@ -269,8 +274,15 @@ def ragged_expert_ffn(x_sorted: jax.Array, group_sizes: jax.Array,
     (:func:`grouped_dot`) instead of E small matmuls or a [T,E,C] einsum.
     ``layer``, ``rows_share``: :func:`grouped_dot`'s (the leaves are a
     layer stack's; the groups take that part of the rows).
+    ``live_rows``: (``sum(group_sizes)``, rows a step) of a share of an
+    expert layer (:func:`_held_routed`): the activation and its gradient
+    run over the row tiles below that many alone (:func:`held_expert_act`),
+    as the grouped matmuls do.
     """
     dt = x_sorted.dtype
+    x_gate = x_sorted
+    if live_rows is not None and "w_gate" in experts:
+        x_sorted, x_gate = held_two_readers(x_sorted, *live_rows)
     # named so remat="moe_selective" can store up/act (backward then never
     # re-runs the grouped GEMMs); measured slower than recompute on v5e at
     # the bench shapes, kept for bigger-expert configs where the trade flips
@@ -278,10 +290,13 @@ def ragged_expert_ffn(x_sorted: jax.Array, group_sizes: jax.Array,
         grouped_dot(x_sorted, experts["w_up"].astype(dt), group_sizes,
                     layer, rows_share), "moe_up")
     g = (_ckpt_name(
-        grouped_dot(x_sorted, experts["w_gate"].astype(dt), group_sizes,
+        grouped_dot(x_gate, experts["w_gate"].astype(dt), group_sizes,
                     layer, rows_share), "moe_up")
         if "w_gate" in experts else None)
-    act = _ckpt_name(_expert_act(up, g, activation), "moe_act")
+    act = _ckpt_name(
+        _expert_act(up, g, activation) if live_rows is None
+        else held_expert_act(up, g, live_rows[0], activation, live_rows[1]),
+        "moe_act")
     return grouped_dot(act, experts["w_down"].astype(dt), group_sizes,
                        layer, rows_share)
 
@@ -498,7 +513,10 @@ def held_group_sizes(idx: jax.Array, held: int, first_expert: int
 def held_dispatch_gather(x: jax.Array, order: jax.Array, inv2d: jax.Array,
                          here: jax.Array) -> jax.Array:
     """:func:`dispatch_gather` for a share of the experts
-    (:func:`held_group_sizes`): ``out[j] = x[order[j] // k]``. The
+    (:func:`held_group_sizes`), the plain form: a row a PAIR is moved,
+    ``out[j] = x[order[j] // k]`` for every sorted slot, held or not (a
+    call of fewer pairs than a tile, and the oracle of
+    :func:`held_rows_out`, which moves a row a HELD pair). The
     transpose takes a pair's cotangent only where the pair is HERE: a row
     behind the held experts' groups holds nothing of this share's (under the
     grouped matmul's kernel it is undefined where no group covers it), in
@@ -524,8 +542,11 @@ held_dispatch_gather.defvjp(_held_dispatch_gather_fwd,
 @jax.custom_vjp
 def held_combine_gather(y_s: jax.Array, weights: jax.Array, order: jax.Array,
                         inv2d: jax.Array, here: jax.Array) -> jax.Array:
-    """:func:`combine_gather` for a share of the experts: ``out[t] = sum
-    over the pairs of t that are HERE of weights[t, c] * y_s[inv2d[t, c]]``.
+    """:func:`combine_gather` for a share of the experts, the plain form
+    (every pair's row fetched, the absent masked: a call of fewer pairs
+    than a tile, and the oracle of :func:`held_pairs_in`, which fetches a
+    row a HELD pair): ``out[t] = sum over the pairs of t that are HERE of
+    weights[t, c] * y_s[inv2d[t, c]]``.
     A pair that is not here is masked out of the sum, not weighted by zero
     (its row of ``y_s`` is another expert's result, or undefined); in the
     transpose its row gets a zero cotangent and its weight a zero
@@ -557,6 +578,290 @@ held_combine_gather.defvjp(_held_combine_gather_fwd,
                            _held_combine_gather_bwd)
 
 
+#: sorted rows one step of a share's movers takes: what they move and
+#: activate follows ``sum(group_sizes)`` to a tile of this many rows
+HELD_TILE_ROWS = 512
+
+
+def held_tiles(pairs: int, tokens: int) -> Optional[Tuple[int, int]]:
+    """(sorted rows a step, rows of the call a step) of the movers of a
+    share's rows (:func:`_held_routed`) for a call of ``tokens`` rows and
+    ``pairs`` (row, expert) pairs, or None where the plain forms stay (a
+    row a pair moved, the pairs that are not here masked).
+
+    The rule reads the static shapes alone: the movers engage wherever a
+    call has a tile of pairs to skip, ``pairs >= HELD_TILE_ROWS``, and its
+    rows come in whole tiles (a step of the combine walks an eighth of the
+    call's rows, between 64 and 512: few enough that a decode tick's 256
+    rows end their walk where the held pairs end). A step is one trip of a
+    device loop around XLA's own gather: Mosaic takes no slice of one row
+    of a tiled array (``Slice shape along dimension 0 must be aligned to
+    tiling (8)``, HBM to HBM too), so a kernel could move a row no finer
+    than XLA does (~25 ns a row of 2,304; PERF.md, PR 50), and a trip of
+    the loop leaves no gap in the device's line. Measured alone on the v5e
+    (``tools/held_rows_alone.py``, us a call, plain -> movers; PERF.md
+    section 5 has the table): the training step's 131,072 pairs of 2,304 at
+    a quarter here, dispatch 4,891 -> 1,086, combine 6,697 -> 1,357,
+    activation 1,027 -> 345; a chunk tick's 8,192-16,384 pairs at an eighth
+    here (Trinity / Kimi-Linear / Keye), the three together 957 / 1,328 /
+    1,049 -> 194 / 245 / 236; a decode tick's 1,024-2,048 pairs, 121 / 140 /
+    122 -> 103 / 58 / 55. Under a tile of pairs there is nothing to skip."""
+    tile = HELD_TILE_ROWS
+    ttile = min(tokens, max(64, min(tokens // 8, tile)))
+    if pairs < tile or pairs % tile or tokens % ttile:
+        return None
+    return tile, ttile
+
+
+def _rows_of(a: jax.Array, at: jax.Array) -> jax.Array:
+    """``a[at]`` along the rows for indices the sort made (every one a row
+    of ``a``): no test of an index against the bounds, no fill."""
+    return a.at[at].get(mode="promise_in_bounds")
+
+
+def _over_live_rows(fn, n: jax.Array, tile: int, *operands: jax.Array
+                    ) -> Tuple[jax.Array, ...]:
+    """``fn`` over the tiles of ``tile`` rows that hold a row below ``n``,
+    one trip of a device loop a tile (``ceil(n / tile)`` trips: a run-time
+    count, static shapes): ``fn(*tiles)`` takes the tile of every operand
+    ``[M, ...]`` and returns a tuple of ``[tile, ...]``; the results are
+    ``[M, ...]``, written in place, and from the first tile wholly at or
+    past ``n`` not written at all."""
+    M = operands[0].shape[0]
+
+    def tiles_at(start):
+        return [lax.dynamic_slice_in_dim(a, start, tile, 0) for a in operands]
+
+    outs = jax.eval_shape(lambda: fn(*tiles_at(0)))
+
+    def trip(i, bufs):
+        return tuple(lax.dynamic_update_slice_in_dim(b, v, i * tile, 0)
+                     for b, v in zip(bufs, fn(*tiles_at(i * tile))))
+
+    return lax.fori_loop(
+        0, (n + tile - 1) // tile, trip, unwritten(
+            [jax.ShapeDtypeStruct((M,) + o.shape[1:], o.dtype) for o in outs],
+            operands))
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("ttile",))
+def _held_pairs_in(src: jax.Array, weights: Optional[jax.Array],
+                   slots: jax.Array, here: jax.Array, ttile: int
+                   ) -> jax.Array:
+    """``out[t] = sum over the pairs c of t that are HERE of weights[t, c] *
+    src[slots[t, c]]`` (``weights`` None: ones), fetching the held pairs'
+    rows alone: products and sum in float32, in the order of ``c``.
+
+    XLA's gather fetches every index it is handed, so the indices handed
+    are the held pairs': a row's held slots move to the front of its ``k``,
+    the rows are walked by how many they hold, the most first (a counting
+    sort over ``k + 1`` counts), and a tile of ``ttile`` rows fetches a
+    column of slots at a time, as many as its first row holds (a trip of an
+    inner device loop a column, the sum carried in float32):
+    ``sum(group_sizes)`` rows in all, and under ``ttile`` more wherever the
+    count falls inside a tile. One gather of ``T`` rows puts the sums back
+    in the rows' order. (One loop body for every count: a ``lax.switch``
+    over ``k + 1`` static counts fetched a tile's columns in one gather,
+    but cost the training step 14 s of compilation and left the device idle
+    between a tile's conditional and the next: PERF.md, PR 50.)"""
+    T, k = slots.shape
+    H = src.shape[-1]
+    rank = jnp.cumsum(here, axis=1) - 1
+    to_front = here[:, :, None] & (rank[:, :, None] == jnp.arange(k))
+
+    def held_first(a):                       # [T, k]: held pairs first
+        return jnp.sum(jnp.where(to_front, a[:, :, None], 0), axis=1)
+
+    # the walk: a counting sort of the rows by the pairs they do NOT hold
+    # (one scatter of a row's slots and weights to its place: every gather
+    # XLA compiles costs the step's set-up a sixth of a second)
+    absent = k - jnp.sum(here, axis=1, dtype=jnp.int32)
+    onehot = absent[:, None] == jnp.arange(k + 1)                # [T, k+1]
+    sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(sizes)
+    place = jnp.sum(jnp.where(
+        onehot, jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1
+        + ends - sizes, 0), axis=1)
+    c_all = k - jnp.sum(jnp.arange(T)[:, None] >= ends, axis=1,
+                        dtype=jnp.int32)
+    walk = [held_first(slots)] + ([] if weights is None else [
+        lax.bitcast_convert_type(
+            held_first(weights.astype(jnp.float32)), jnp.int32)])
+    walk = jnp.zeros((T, len(walk) * k), jnp.int32).at[place].set(
+        jnp.concatenate(walk, axis=1), unique_indices=True,
+        mode="promise_in_bounds").T
+    s_all = walk[:k]
+    w_all = None if weights is None else lax.bitcast_convert_type(
+        walk[k:], jnp.float32)
+
+    def a_tile(i, out):
+        c = lax.dynamic_slice_in_dim(c_all, i * ttile, ttile, 0)
+
+        def a_column(r, acc):
+            def of(a):
+                return lax.dynamic_slice(a, (r, i * ttile), (1, ttile))[0]
+
+            rows = _rows_of(src, of(s_all)).astype(jnp.float32)
+            if w_all is not None:
+                rows = rows * of(w_all)[:, None]
+            return acc + jnp.where((r < c)[:, None], rows, 0)
+
+        acc = lax.fori_loop(0, c[0], a_column,
+                            jnp.zeros((ttile, H), jnp.float32))
+        return lax.dynamic_update_slice_in_dim(
+            out, acc.astype(src.dtype), i * ttile, 0)
+
+    walked = lax.fori_loop(
+        0, T // ttile, a_tile,
+        unwritten([jax.ShapeDtypeStruct((T, H), src.dtype)], (src,))[0])
+    return _rows_of(walked, place)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def held_rows_out(x: jax.Array, order: jax.Array, inv2d: jax.Array,
+                  here: jax.Array, n: jax.Array, tiles: Tuple[int, int]
+                  ) -> jax.Array:
+    """:func:`held_dispatch_gather` over the held pairs' rows alone:
+    ``out[j] = x[order[j] // k]`` for the sorted slots ``j`` below ``n =
+    sum(group_sizes)`` (to a tile of ``tiles[0]`` rows: the held pairs sort
+    first); the rows behind them are not read and not written. The
+    transpose fetches a row's held pairs alone (:func:`_held_pairs_in`)."""
+    return _rows_out(x, order, n, k=inv2d.shape[-1], tile=tiles[0])
+
+
+# (the movers' bodies sit in inlined inner jits: a step traces a layer's
+# forward and each transposition rule several times over, four layers
+# long, and a body traced once is a cache hit after that)
+@functools.partial(jax.jit, inline=True, static_argnames=("k", "tile"))
+def _rows_out(x, order, n, *, k, tile):
+    return _over_live_rows(lambda o: (_rows_of(x, o // k),),
+                           n, tile, order)[0]
+
+
+def _held_rows_out_fwd(x, order, inv2d, here, n, tiles):
+    return held_rows_out(x, order, inv2d, here, n, tiles), (inv2d, here)
+
+
+def _held_rows_out_bwd(tiles, res, g):
+    inv2d, here = res
+    return (_held_pairs_in(g, None, inv2d, here, tiles[1]),
+            None, None, None, None)
+
+
+held_rows_out.defvjp(_held_rows_out_fwd, _held_rows_out_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def held_pairs_in(y_s: jax.Array, weights: jax.Array, order: jax.Array,
+                  inv2d: jax.Array, here: jax.Array, n: jax.Array,
+                  tiles: Tuple[int, int]) -> jax.Array:
+    """:func:`held_combine_gather` over the held pairs' rows alone
+    (:func:`_held_pairs_in`: two of a row's eight fetched in the mean, not
+    eight fetched and six masked). The transpose makes ``dy[j] = w[j] *
+    g[order[j] // k]`` for the slots below ``n`` alone, and has ``y_s[j]``
+    beside ``g``'s row there, so a weight's gradient ``<y_s[j], g[order[j]
+    // k]>`` is one float32 a sorted row and ``dw`` a gather of scalars:
+    no second gather of ``y_s`` by row."""
+    return _held_pairs_in(y_s, weights, inv2d, here, tiles[1])
+
+
+def _held_pairs_in_fwd(y_s, weights, order, inv2d, here, n, tiles):
+    return held_pairs_in(y_s, weights, order, inv2d, here, n, tiles), \
+        (y_s, weights, order, inv2d, here, n)
+
+
+def _held_pairs_in_bwd(tiles, res, g):
+    dy, dw = _pairs_in_transposed(*res, g, tile=tiles[0])
+    return dy, dw, None, None, None, None
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("tile",))
+def _pairs_in_transposed(y_s, weights, order, inv2d, here, n, g, *, tile):
+    k = inv2d.shape[-1]
+    w_flat = weights.reshape(-1)
+
+    def a_tile(o, y):
+        rows = _rows_of(g, o // k)
+        dots = jnp.sum(y.astype(jnp.float32) * rows.astype(jnp.float32),
+                       axis=-1)
+        return rows * _rows_of(w_flat, o).astype(y.dtype)[:, None], dots
+
+    dy, dots = _over_live_rows(a_tile, n, tile, order, y_s)
+    return dy, jnp.where(here, _rows_of(dots, inv2d), 0).astype(weights.dtype)
+
+
+held_pairs_in.defvjp(_held_pairs_in_fwd, _held_pairs_in_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def held_two_readers(x_s: jax.Array, n: jax.Array, tile: int
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """``x_s`` for each of its two readers (the grouped matmuls of ``up``
+    and ``gate``), so that the sum of their two cotangents runs over the
+    row tiles below ``n`` alone: left to the transposition it is one
+    addition of two ``[T x k, H]`` arrays (2.7 ms a layer in the training
+    cell, where a quarter of the rows holds anything)."""
+    return x_s, x_s
+
+
+def _held_two_readers_fwd(x_s, n, tile):
+    return (x_s, x_s), n
+
+
+def _held_two_readers_bwd(tile, n, g):
+    return _sum_of_two(*g, n, tile=tile), None
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("tile",))
+def _sum_of_two(a, b, n, *, tile):
+    return _over_live_rows(lambda a, b: (a + b,), n, tile, a, b)[0]
+
+
+held_two_readers.defvjp(_held_two_readers_fwd, _held_two_readers_bwd)
+
+
+def _act_operands(up, gate):
+    return (up,) if gate is None else (up, gate)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def held_expert_act(up: jax.Array, gate: Optional[jax.Array], n: jax.Array,
+                    activation: str, tile: int) -> jax.Array:
+    """:func:`_expert_act` over the row tiles below ``n`` alone, forward
+    and backward; the rows behind them are not read and not written."""
+    return _act_rows(up, gate, n, activation=activation, tile=tile)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("activation", "tile"))
+def _act_rows(up, gate, n, *, activation, tile):
+    return _over_live_rows(
+        lambda u, g=None: (_expert_act(u, g, activation),),
+        n, tile, *_act_operands(up, gate))[0]
+
+
+def _held_expert_act_fwd(up, gate, n, activation, tile):
+    return held_expert_act(up, gate, n, activation, tile), (up, gate, n)
+
+
+def _held_expert_act_bwd(activation, tile, res, d):
+    grads = _act_rows_transposed(*res, d, activation=activation, tile=tile)
+    return grads[0], (None if res[1] is None else grads[1]), None
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("activation", "tile"))
+def _act_rows_transposed(up, gate, n, d, *, activation, tile):
+    def a_tile(d, *tiles):
+        return jax.vjp(lambda u, g=None: _expert_act(u, g, activation),
+                       *tiles)[1](d)
+
+    return _over_live_rows(a_tile, n, tile, d, *_act_operands(up, gate))
+
+
+held_expert_act.defvjp(_held_expert_act_fwd, _held_expert_act_bwd)
+
+
 def _held_routed(xt: jax.Array, weights: jax.Array, idx: jax.Array,
                  experts: Dict[str, jax.Array], activation: str,
                  first_expert: int, router_experts: int,
@@ -565,17 +870,26 @@ def _held_routed(xt: jax.Array, weights: jax.Array, idx: jax.Array,
     """:func:`_ragged_dispatch_local` for a SHARE of the layer's experts
     (:func:`held_group_sizes`), for a serving tick and a training step
     alike: (this share's part of the routed result, the rows of each held
-    expert), forward and backward (:func:`held_dispatch_gather`,
-    :func:`held_combine_gather`: gradients reach the router through the
+    expert), forward and backward (gradients reach the router through the
     weights of the pairs that are here).
 
-    A row a pair, the groups as they fall: the pairs that are not here sort
-    behind the held experts' groups, where the grouped matmuls (``gmm``
-    forward and for the rows' gradient, ``tgmm`` for the matrices') spend
-    no tile on them, so the work follows the held pairs and a step's time
-    follows its routing. Those rows are undefined under the kernel, in the
-    backward too, so a pair that is not here is masked out of the sum and
-    out of every gradient, not weighted by zero."""
+    A row a pair is the SHAPE of every sorted array; the work is a row a
+    HELD pair. The pairs that are here sort first, by expert, so they are
+    the rows ``[0, n)``, ``n = sum(group_sizes)``, a value the sort leaves
+    on the device; the pairs that are not here lie behind them, in no
+    group. The grouped matmuls (``gmm`` forward and for the rows' gradient,
+    ``tgmm`` for the matrices') spend no tile there, and neither do the
+    dispatch (:func:`held_rows_out`), the activation
+    (:func:`held_expert_act`), the combine (:func:`held_pairs_in`) or
+    their transposes: each walks the row tiles below ``n`` and no other, so
+    a step's time follows its routing a tile at a time, and ``T x k`` is
+    the bound no routing outgrows (no capacity, no bucket, no pair
+    dropped). The rows behind ``n`` are undefined, in the backward too: a
+    pair that is not here is left out of every sum and every gradient, not
+    weighted by zero. Where a call's pairs are too few for the tiles to
+    pay (:func:`held_tiles`) the plain forms stay
+    (:func:`held_dispatch_gather`, :func:`held_combine_gather`: a row a
+    pair moved, the absent masked)."""
     held = experts["w_up"].shape[-3]
     order, inv2d, group_sizes, here = held_group_sizes(
         idx, held, first_expert)
@@ -583,10 +897,20 @@ def _held_routed(xt: jax.Array, weights: jax.Array, idx: jax.Array,
     inv2d = _ckpt_name(inv2d, "moe_gate")
     group_sizes = _ckpt_name(group_sizes, "moe_gate")
     weights = _ckpt_name(weights, "moe_gate").astype(xt.dtype)
-    x_s = held_dispatch_gather(xt, order, inv2d, here)
+    tiles = held_tiles(idx.size, idx.shape[0])
+    if tiles is None:
+        x_s = held_dispatch_gather(xt, order, inv2d, here)
+        y_s = ragged_expert_ffn(x_s, group_sizes, experts, activation, layer,
+                                rows_share=held / router_experts)
+        return held_combine_gather(y_s, weights, order, inv2d, here), \
+            group_sizes
+    n = jnp.sum(group_sizes)
+    x_s = held_rows_out(xt, order, inv2d, here, n, tiles)
     y_s = ragged_expert_ffn(x_s, group_sizes, experts, activation, layer,
-                            rows_share=held / router_experts)
-    return held_combine_gather(y_s, weights, order, inv2d, here), group_sizes
+                            rows_share=held / router_experts,
+                            live_rows=(n, tiles[0]))
+    return held_pairs_in(y_s, weights, order, inv2d, here, n, tiles), \
+        group_sizes
 
 
 def _token_axes(mesh) -> Tuple[Tuple[str, ...], Optional[str]]:
@@ -757,8 +1081,10 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
                 y, rows = _held_routed(xt, gate.weights, gate.experts,
                                        experts, activation, first_expert, E)
                 if _HELD_ROWS_MONITOR is not None:
+                    tiles = held_tiles(gate.experts.size, xt.shape[0])
                     jax.debug.callback(functools.partial(
-                        _HELD_ROWS_MONITOR, pairs=gate.experts.size), rows)
+                        _HELD_ROWS_MONITOR, pairs=gate.experts.size,
+                        tile=tiles and tiles[0]), rows)
             else:
                 y = _ragged_dispatch_local(xt, gate.weights, gate.experts,
                                            experts, activation)

@@ -442,12 +442,22 @@ class DeepSpeedTPUEngine:
                 "router chose",
                 buckets=tuple(i / 16 for i in range(1, 17)))
 
-            def _held_rows(rows, pairs):
+            moved_h = telemetry.histogram(
+                "train_moe_moved_row_share",
+                "calls of an expert layer that holds a share of its "
+                "experts: sorted rows its dispatch, activation and combine "
+                "covered (the held pairs, to a tile of rows) over the "
+                "(row, expert) pairs; 1 = the movers did not engage",
+                buckets=tuple(i / 16 for i in range(1, 17)))
+
+            def _held_rows(rows, pairs, tile=None):
                 rows = np.asarray(rows, np.float64)
                 mean = float(rows.mean())
                 rows_h.observe(mean)
                 imbalance_h.observe(float(rows.max()) / max(mean, 1e-9))
                 share_h.observe(float(rows.sum()) / pairs)
+                moved_h.observe(1.0 if tile is None else
+                                -(-rows.sum() // tile) * tile / pairs)
 
             set_held_rows_monitor(_held_rows)
 

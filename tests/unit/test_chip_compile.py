@@ -459,6 +459,49 @@ def test_training_gmm_and_tgmm_compile_for_v5e(one_chip, monkeypatch):
         assert moved >= 2 * (16 * 2304 * 896 + pairs * 896)
 
 
+def test_a_shares_movers_compile_for_v5e_and_copy_no_sorted_array(
+        one_chip, monkeypatch):
+    """A share of an expert layer at the training cell's shape, forward
+    and backward: the movers' loops and the calls that hand them their
+    arrays compile, no ``[131072, .]`` array is copied around them (an
+    array two loops shared was: PR 50), and the layer's temporaries are
+    the step's (4.3 GB here, 4.47 in the step; the plain forms' step 4.02)."""
+    from benchmarks.roofline import train_expert_gmm as need
+    from deepspeed_tpu.moe import layer as MOE
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, k, width, inter, held = 2 * 8192, 8, 2304, 896, 16
+    bf = jnp.bfloat16
+
+    def arg(dims, dtype=bf):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def share(x, w, idx, experts):
+        y, _ = MOE._held_routed(x, w, idx, experts, "swiglu", 0, 64)
+        return jnp.sum(y.astype(jnp.float32))
+
+    experts = {"w_up": arg((held, width, inter)),
+               "w_gate": arg((held, width, inter)),
+               "w_down": arg((held, inter, width))}
+    operands = (arg((rows, width)), arg((rows, k)),
+                arg((rows, k), jnp.int32), experts)
+
+    assert MOE.held_tiles(rows * k, rows) == (512, 512)
+    live = jax.jit(jax.grad(share, (0, 1, 3))).lower(*operands).compile()
+    text = live.as_text()
+    assert " while(" in text
+    assert not [s for s in re.findall(r"= (\S+) copy\(", text)
+                if s.startswith(f"bf16[{rows * k},")]
+    # the roofline of the grouped matmuls takes the calls it took: the
+    # nine of the forward, the rows' gradient and the matrices', none new
+    kinds = [need.classify(c) for c in _mosaic_calls(text)]
+    assert kinds.count(None) >= 5
+    assert kinds.count("gmm") in (5, 6) and kinds.count("tgmm") == 3
+    # the arrays the loops fill are made when a loop can start and share
+    # memory like any other (as ``lax.empty`` they did not: 17.8 GB a step)
+    assert live.memory_analysis().temp_size_in_bytes < 4.6e9
+
+
 # (cell, rows of its small tick bucket, its widest table tier, the
 # convolution-state store): the decode programs of the three cells whose
 # sequences keep a convolution's last inputs, whole, at the cells' real

@@ -24,7 +24,7 @@ KERNEL_NAMES = {
     "latent_paged_attention", "kda_step", "kda_chunk", "index_scores",
     "sparse_choice", "block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv",
     "evoformer_attention", "fused_adam", "quantize_int8_blocks",
-    "dequant_reduce", "rms_norm", "layer_norm"}
+    "dequant_reduce", "rms_norm", "layer_norm", "unwritten_rows"}
 
 
 def _stacks(lowered, program):
@@ -81,7 +81,7 @@ def test_every_pallas_call_is_named():
                     d.value for a, d in zip(node.args.kwonlyargs,
                                             node.args.kw_defaults)
                     if a.arg == "name" and isinstance(d, ast.Constant))
-    assert sites == 16
+    assert sites == 17
     assert literal == KERNEL_NAMES
 
 
@@ -190,7 +190,7 @@ def test_training_step_of_held_experts_carries_its_scopes_and_counters():
     jax.effects_barrier()
     got = {}
     for name in ("train_moe_held_expert_rows", "train_moe_held_pair_share",
-                 "train_moe_load_imbalance"):
+                 "train_moe_load_imbalance", "train_moe_moved_row_share"):
         (_, child), = telemetry.histogram(name).labels_items()
         assert child.count == 8, name
         got[name] = child.sum / child.count
@@ -198,6 +198,9 @@ def test_training_step_of_held_experts_carries_its_scopes_and_counters():
         got["train_moe_held_pair_share"] * 2 * 64 * 8 / 4)
     assert 0.15 < got["train_moe_held_pair_share"] < 0.35
     assert got["train_moe_load_imbalance"] >= 1.0
+    # the movers walked ceil(n / tile) tiles of 512 sorted rows of the
+    # 1,024 pairs a call: one tile while no more than half the pairs are here
+    assert got["train_moe_moved_row_share"] == 0.5
     engine.shutdown_telemetry()
 
 

@@ -186,6 +186,11 @@ class FastGenEngine:
     slow_ticks: collections.deque = collections.deque(maxlen=128)
     _engine_numbers = itertools.count()
 
+    # what this process traces, lowers, loads and compiles from here on is
+    # accounted by program (telemetry/host.py); the constructor is the span
+    # ``engine_init`` and the parts of it that a replica's cold start is
+    # suspected of are spans inside it
+    @telemetry.engine_init()
     def __init__(self, cfg: Union[str, T.TransformerConfig],
                  params: Optional[PyTree] = None,
                  n_blocks: int = 128, block_size: int = 32,
@@ -200,12 +205,17 @@ class FastGenEngine:
         if isinstance(cfg, str):
             cfg = T.get_model_config(cfg, **overrides)
         self.cfg = cfg
-        if params is None:
-            params = T.init_params(cfg, jax.random.PRNGKey(seed))
-        self.params = jax.tree.map(
-            lambda x: jnp.asarray(x, cfg.compute_dtype)
-            if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating)
-            else jnp.asarray(x), params)
+        # the first touch of the devices in a process that had none: the
+        # runtime's start, seconds on a TPU host
+        with telemetry.span("device_attach"):
+            jax.devices()
+        with telemetry.span("params_init"):
+            if params is None:
+                params = T.init_params(cfg, jax.random.PRNGKey(seed))
+            self.params = jax.tree.map(
+                lambda x: jnp.asarray(x, cfg.compute_dtype)
+                if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating)
+                else jnp.asarray(x), params)
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
         self.token_budget = token_budget
@@ -270,9 +280,10 @@ class FastGenEngine:
                 + (f" and {tick / 1e9:.2f} GB a tick holds" if tick else "")
                 + f", of {stats['bytes_limit'] / 1e9:.2f} GB")
         self.allocator = BlockAllocator(n_blocks, state_slots)
-        self.pool = PG.init_paged_kv(cfg, n_blocks, block_size,
-                                     state_slots=state_slots,
-                                     max_run=token_budget)
+        with telemetry.span("state_init"):
+            self.pool = PG.init_paged_kv(cfg, n_blocks, block_size,
+                                         state_slots=state_slots,
+                                         max_run=token_budget)
         self.seqs: Dict[int, _Seq] = {}
         self._admit_order: List[int] = []
         self._decode_rr = 0
@@ -587,6 +598,7 @@ class FastGenEngine:
         self._tick_end_t: Optional[float] = None
         self._idle = False
         self._gc_seen_s = telemetry.gc_pause_seconds()
+        self._compile_seen_s = telemetry.compile_seconds()
         # (tick end, process CPU seconds, this thread's) when the CPU
         # clocks were last read: every sixteenth tick and at a slow one
         # (two system calls: not every tick's to pay)
@@ -1573,6 +1585,11 @@ class FastGenEngine:
         self._tick_end_t, self._idle = end, False
         gc_seen = telemetry.gc_pause_seconds()
         gc_s, self._gc_seen_s = gc_seen - self._gc_seen_s, gc_seen
+        # seconds under JAX's compile path since the last tick's end (a
+        # tick that traced, lowered or compiled anything says so itself)
+        compile_seen = telemetry.compile_seconds()
+        compile_s = compile_seen - self._compile_seen_s
+        self._compile_seen_s = compile_seen
         parts = (at[0] - start, at[1] - at[0], at[2] - at[1], at[3] - at[2],
                  at[4] - at[3], at[5] - at[4], at[6] - at[5])
         period = end - start
@@ -1594,7 +1611,7 @@ class FastGenEngine:
         if typ[0] >= _TYPICAL_WARMUP and period - typical >= max(
                 SLOW_TICK_MIN_S, (SLOW_TICK_RATIO - 1.0) * typical):
             self._note_slow_tick(kind, Tn, mb, tier, rows, parts, typ, gc_s,
-                                 end)
+                                 compile_s, end)
             return
         if not self._ticks_run % _PROCESS_REFRESH_TICKS:
             self._cpu_seen = (end, time.process_time(), time.thread_time())
@@ -1606,7 +1623,7 @@ class FastGenEngine:
 
     def _note_slow_tick(self, kind: str, Tn: int, mb: int, tier: str,
                         rows: int, parts: tuple, typ: List[float],
-                        gc_s: float, end: float) -> None:
+                        gc_s: float, compile_s: float, end: float) -> None:
         typical = typ[2:]
         over = [x - t for x, t in zip(parts, typical)]
         owner = _PERIOD_PARTS[over.index(max(over))]
@@ -1622,6 +1639,7 @@ class FastGenEngine:
                   "kind": kind, "bucket": Tn, "mb_tier": tier, "rows": rows,
                   "phase": owner, "period_s": period,
                   "typical_period_s": usual, "gc_s": gc_s,
+                  "compile_s": compile_s,
                   # CPU seconds, every thread's and this one's, of the
                   # ``cpu_wall_s`` that end with this tick (at most sixteen
                   # ticks): short of that wall by about the stall, the

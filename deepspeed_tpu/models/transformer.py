@@ -21,8 +21,10 @@ fp32 layernorm/rmsnorm accumulation — MXU-friendly per the TPU guide.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
+import threading
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -1259,9 +1261,12 @@ def chosen_positions(scores: jax.Array, topk: int) -> jax.Array:
 def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig,
                    cos: Optional[jax.Array], sin: Optional[jax.Array],
                    attention_fn: AttentionFn, kind: Optional[str] = None
-                   ) -> Tuple[jax.Array, jax.Array]:
+                   ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """One transformer block; lp holds this layer's (unstacked) params.
-    Returns (output, moe aux loss — 0.0 for dense blocks).
+    Returns (output, moe aux loss — 0.0 for dense blocks, the layer's
+    meter — ``moe.layer.held_meter`` of a share of an expert layer, None
+    for every other block: a tree with no leaf, so a scan or a checkpoint
+    carries it at no cost and a dense program is what it was).
 
     ``kind`` (a layer of ``cfg.layer_kinds`` over this one block,
     ``cfg.standard_blocks``): ``window`` sees its last ``cfg.attn_window``
@@ -1337,8 +1342,8 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
             x = x + attn_out
         with jax.named_scope("mlp"):
             h2 = _aq(_norm(x, lp["ln2"], cfg.norm, cfg.norm_eps))
-            down, aux = _ffn(h2, lp, cfg)
-            return x + down, aux
+            down, aux, meter = _ffn_metered(h2, lp, cfg)
+            return x + down, aux, meter
 
     @jax.named_scope("attn")
     def _attn_from_norm(h):
@@ -1448,35 +1453,42 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         with jax.named_scope("mlp"):
             h2 = h if cfg.shared_parallel_norm else \
                 _aq(_norm(x, lp["ln2"], cfg.norm, cfg.norm_eps))
-            down, aux = _ffn(h2, lp, cfg)
-            return x + attn_out + down, aux
+            down, aux, meter = _ffn_metered(h2, lp, cfg)
+            return x + attn_out + down, aux, meter
 
     x = x + attn_out
 
     @jax.named_scope("mlp")
     def _ffn_delta(xr):
         h2 = _aq(_norm(xr, lp["ln2"], cfg.norm, cfg.norm_eps))
-        down, aux = _ffn(h2, lp, cfg)
+        down, aux, meter = _ffn_metered(h2, lp, cfg)
         if cfg.post_norms:
             down = _norm(down, lp["ln2_post"], cfg.norm, cfg.norm_eps)
-        return down, aux
+        return down, aux, meter
 
     if cfg.remat == "ffn_block":
         # converse structural remat: bwd recomputes norm2 → FFN (~63% of
         # layer FLOPs); attention residuals (q/k/v/out + flash lse) stay
         # saved. Memory ≈ 6·B·S·H bf16 per layer — the cheaper-storage,
         # smaller-win sibling of attn_block.
-        down, aux = jax.checkpoint(_ffn_delta)(x)
+        down, aux, meter = jax.checkpoint(_ffn_delta)(x)
     else:
-        down, aux = _ffn_delta(x)
-    return x + down, aux
+        down, aux, meter = _ffn_delta(x)
+    return x + down, aux, meter
 
 
 def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
          ) -> Tuple[jax.Array, jax.Array]:
     """Dense or MoE FFN on normed input; returns (output, aux loss)."""
+    return _ffn_metered(h, lp, cfg)[:2]
+
+
+def _ffn_metered(h: jax.Array, lp: Dict[str, jax.Array],
+                 cfg: TransformerConfig
+                 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """:func:`_ffn` and the layer's meter (:func:`_block_forward`)."""
     dt = cfg.compute_dtype
-    aux = jnp.float32(0.0)
+    aux, meter = jnp.float32(0.0), None
     if cfg.n_experts > 0:
         from deepspeed_tpu.moe.layer import moe_ffn
 
@@ -1484,7 +1496,7 @@ def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
         shared = {k_: lp[k_] for k_ in ("sw_up", "sw_down", "sw_gate",
                                         "shared_gate_w") if k_ in lp}
         # flat rows ``[T, H]`` (a caller outside the block) are one batch
-        down, aux = moe_ffn(
+        down, aux, meter = moe_ffn(
             h.reshape((-1,) + h.shape[-2:]), lp["gate_w"], experts,
             activation=cfg.activation,
             k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
@@ -1494,7 +1506,7 @@ def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
             gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
             topk_group=cfg.moe_topk_group, dispatch=cfg.moe_dispatch,
             route_norm_eps=cfg.moe_route_norm_eps,
-            first_expert=cfg.moe_first_expert)
+            first_expert=cfg.moe_first_expert, with_meter=True)
         down = down.reshape(h.shape)
     else:
         up = h @ lp["w_up"].astype(dt)
@@ -1511,7 +1523,7 @@ def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
         down = act @ lp["w_down"].astype(dt)
         if cfg.use_bias:
             down = down + lp["b_down"].astype(dt)
-    return down, aux
+    return down, aux, meter
 
 
 def _require_one_stack(cfg: TransformerConfig, what: str) -> None:
@@ -1532,6 +1544,38 @@ def _require_one_stack(cfg: TransformerConfig, what: str) -> None:
 # --------------------------------------------------------------------------- #
 # forward
 # --------------------------------------------------------------------------- #
+
+# What the layers of one forward pass meter of themselves
+# (``_block_forward``'s third value, stacked by layer), handed to whoever
+# asked at the SAME trace level as the call of ``forward_hidden``: the
+# training step opens ``collect_meters`` around the model's ``loss_fn``
+# inside the function it differentiates and returns the dict as that
+# function's auxiliary output, so the values leave the compiled step in its
+# ``metrics`` (no host callback; whatever wraps or replaces a spec's
+# ``loss_fn`` is metered alike as long as it calls ``forward_hidden`` in
+# its own trace). One list a thread: a trace runs on the thread that asked.
+_METER_SINKS = threading.local()
+
+
+@contextlib.contextmanager
+def collect_meters():
+    """``with collect_meters() as meters``: the dict that the calls of
+    :func:`forward_hidden` inside the block fill (``moe_held``:
+    ``int32[expert layers, held + 2]``, a ``moe.layer.held_meter`` a layer
+    that holds a share of its experts; nothing for any other model)."""
+    sinks = _METER_SINKS.__dict__.setdefault("sinks", [])
+    sinks.append({})
+    try:
+        yield sinks[-1]
+    finally:
+        sinks.pop()
+
+
+def _emit_meters(**meters) -> None:
+    sinks = getattr(_METER_SINKS, "sinks", None)
+    if sinks:
+        sinks[-1].update({k: v for k, v in meters.items() if v is not None})
+
 
 def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
                    attention_fn: Optional[AttentionFn] = None,
@@ -1610,13 +1654,13 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
                 layer_params, keep = xs
             else:
                 layer_params, keep = xs, None
-            y, aux = _block_forward(carry, layer_params, cfg, cos_b, sin_b,
-                                    attention_fn)
+            y, aux, meter = _block_forward(carry, layer_params, cfg, cos_b,
+                                           sin_b, attention_fn)
             if keep is not None:
                 k = keep.astype(y.dtype)   # don't promote the bf16 carry
                 y = k * y + (1 - k) * carry
                 aux = keep * aux
-            return constrain(y), aux
+            return constrain(y), (aux, meter)
 
         return _remat_wrap(body, cfg.remat)
 
@@ -1634,15 +1678,17 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
         bounds = even_chunk_bounds(L, max(cfg.scan_chunks, 1))
         if len(bounds) <= 1 and param_sync is None:
             return run(x, blocks, cos_b, sin_b, keep)
-        aux_parts = []
+        aux_parts, meter_parts = [], []
         for start, stop in bounds:
             blk = jax.tree.map(lambda p: p[start:stop], blocks)
             if param_sync is not None:
                 blk = param_sync(blk)
             kk = keep[start:stop] if keep is not None else None
-            x, aux = run(x, blk, cos_b, sin_b, kk)
+            x, (aux, meters) = run(x, blk, cos_b, sin_b, kk)
             aux_parts.append(aux)
-        return x, jnp.concatenate([a.reshape(-1) for a in aux_parts])
+            meter_parts.append(meters)
+        return x, (jnp.concatenate([a.reshape(-1) for a in aux_parts]),
+                   _cat_meters(meter_parts))
 
     if random_ltd_idx is not None and cfg.pos_emb == "alibi":
         raise NotImplementedError(
@@ -1656,7 +1702,8 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
             pld_keep = pld_keep[seg.num_layers:]
         x, _ = lax.scan(make_body(cos, sin, with_pld, seg), x, xs)
     if random_ltd_idx is None or L < 3:
-        x, auxes = run_chunked(x, params["blocks"], cos, sin, pld_keep)
+        x, (auxes, meters) = run_chunked(x, params["blocks"], cos, sin,
+                                         pld_keep)
         aux_total = jnp.sum(auxes)
     else:
         blk = params["blocks"]
@@ -1669,16 +1716,25 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
         cos_k = sin_k = None
         if cos is not None:
             cos_k, sin_k = cos[random_ltd_idx], sin[random_ltd_idx]
-        x, a1 = run(x, first, cos, sin, k1)
+        x, (a1, m1) = run(x, first, cos, sin, k1)
         xk = jnp.take(x, random_ltd_idx, axis=1)          # gather kept
-        xk, a2 = run(xk, middle, cos_k, sin_k, k2)
+        xk, (a2, m2) = run(xk, middle, cos_k, sin_k, k2)
         x = x.at[:, random_ltd_idx].set(xk)               # scatter back
-        x, a3 = run(x, last, cos, sin, k3)
+        x, (a3, m3) = run(x, last, cos, sin, k3)
         aux_total = jnp.sum(a1) + jnp.sum(a2) + jnp.sum(a3)
+        meters = _cat_meters([m1, m2, m3])
 
+    _emit_meters(moe_held=meters)
     x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     head = _lm_head_of(params, full_cfg)
     return x, head, aux_total
+
+
+def _cat_meters(parts: Sequence[Optional[jax.Array]]) -> Optional[jax.Array]:
+    """Runs of layers' meters ``[layers of the run, ...]`` as one array in
+    layer order; None where no layer of any run meters itself."""
+    parts = [p for p in parts if p is not None]
+    return jnp.concatenate(parts) if parts else None
 
 
 def kind_runs(kinds: Sequence[str]) -> List[Tuple[int, Tuple[str, ...], int]]:
@@ -1775,30 +1831,37 @@ def _forward_blocks_of_kinds(params: PyTree, tokens: jax.Array,
         of = cfg.rope_of(kind) if cfg.pos_emb == "rope" else None
         ropes[kind] = rope_table(S, cfg.rope_dim, *of) if of \
             else (None, None)
-    aux_total = jnp.float32(0.0)
+    aux_total, meter_parts = jnp.float32(0.0), []
     for key, seg in cfg.segments:
         def body_of(period, _, seg=seg):
             def layer_of(kind):
                 def layer(x, lp):
-                    y, a = _block_forward(x, lp, seg, *ropes[kind],
-                                          attention_fn, kind)
-                    return constrain(y), a
+                    y, a, meter = _block_forward(x, lp, seg, *ropes[kind],
+                                                 attention_fn, kind)
+                    return constrain(y), (a, meter)
 
                 return _remat_wrap(layer, cfg.remat)
 
             layers = [layer_of(kind) for kind in period]
 
             def body(x, lps):
-                aux = jnp.float32(0.0)
+                aux, meters = jnp.float32(0.0), []
                 for i, layer in enumerate(layers):
-                    x, a = layer(x, period_layer(lps, period, i))
+                    x, (a, meter) = layer(x, period_layer(lps, period, i))
                     aux = aux + a
-                return x, aux
+                    meters.append(meter)
+                # a period's layers all meter themselves or none does
+                return x, (aux, None if meters[0] is None
+                           else jnp.stack(meters))
 
             return body
 
-        x, auxes = scan_periods(body_of, x, params[key], seg.layer_kinds)
-        aux_total = aux_total + sum(jnp.sum(a) for a in auxes)
+        x, outs = scan_periods(body_of, x, params[key], seg.layer_kinds)
+        aux_total = aux_total + sum(jnp.sum(a) for a, _ in outs)
+        # a run's meters [steps, period, ...] are its layers' in order
+        meter_parts += [m if m is None else m.reshape((-1,) + m.shape[2:])
+                        for _, m in outs]
+    _emit_meters(moe_held=_cat_meters(meter_parts))
     x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     return x, _lm_head_of(params, cfg), aux_total
 
@@ -2117,8 +2180,8 @@ def _pipeline_parts(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
 
     def stage_fn(x_in, blocks_l, ex):
         def body(carry, lp):
-            y, aux = _block_forward(carry, lp, cfg, ex.get("cos"), ex.get("sin"),
-                                    attention_fn)
+            y, aux, _ = _block_forward(carry, lp, cfg, ex.get("cos"),
+                                       ex.get("sin"), attention_fn)
             return constrain(y), aux
 
         body = _remat_wrap(body, cfg.remat)

@@ -74,24 +74,26 @@ on_reset_mesh(_SHARDED_FN_CACHE.clear)
 _DROP_MONITOR = None
 
 
-# Installed observer of a SHARE of an expert layer in training (None: no
-# callback is traced): the rows each held expert got in one layer's call.
-_HELD_ROWS_MONITOR = None
+def held_meter(rows: jax.Array, pairs: int,
+               tile: Optional[int]) -> jax.Array:
+    """What one call of a layer that holds a SHARE of its experts
+    (:func:`moe_ffn`, ``first_expert=``) says of itself, as ONE
+    ``int32[held + 2]`` that rides out of the compiled step beside the
+    auxiliary loss (``moe_ffn(with_meter=True)``; no host callback: a
+    program that holds one is never written to JAX's persistent cache):
+    the rows each held expert got, then the (row, expert) pairs the router
+    chose over all of its experts and the sorted rows a step of the
+    layer's movers takes (:func:`held_tiles`; 0: the plain forms moved a
+    row a pair). The last two are the call's static shapes; they ride
+    with the rows so that a reader needs nothing else
+    (:func:`read_held_meter`)."""
+    return jnp.concatenate([rows.astype(jnp.int32), jnp.asarray(
+        [pairs, tile or 0], jnp.int32)])
 
 
-def set_held_rows_monitor(fn) -> None:
-    """``fn(rows [held] int32, pairs: int, tile: int | None)`` called
-    (async, via jax.debug.callback, with no fence of its own) once for each
-    call of a layer that holds a share of its experts (:func:`moe_ffn`,
-    ``first_expert=``): the rows of each held expert, the (row, expert)
-    pairs the router chose over all of its experts, and the sorted rows a
-    step of the layer's movers takes (:func:`held_tiles`; None: the plain
-    forms moved a row a pair). Under full
-    rematerialisation a layer calls twice a step (forward, recompute), with
-    equal values: keep means, not sums. Pass None to uninstall. Trace-time
-    gated: install BEFORE the step is compiled."""
-    global _HELD_ROWS_MONITOR
-    _HELD_ROWS_MONITOR = fn
+def read_held_meter(meter) -> Tuple[Any, int, Optional[int]]:
+    """``(rows [held], pairs, tile or None)`` of one :func:`held_meter`."""
+    return meter[:-2], int(meter[-2]), int(meter[-1]) or None
 
 
 def set_drop_monitor(fn) -> None:
@@ -1049,8 +1051,11 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
                    gate_bias: Optional[jax.Array], *, activation: str, k: int,
                    score_func: str, route_norm: bool, n_group: int,
                    topk_group: int, route_norm_eps: float = 0.0,
-                   first_expert: int = 0) -> Tuple[jax.Array, jax.Array]:
-    """Dropless routed-expert computation. Returns (y [B,S,H], aux).
+                   first_expert: int = 0
+                   ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """Dropless routed-expert computation. Returns (y [B,S,H], aux, the
+    call's :func:`held_meter` or None: a share's rows are counted where the
+    call is one local program, not under a token-sharded mesh).
     ``first_expert``: where ``experts`` holds fewer experts than ``gate_w``
     has columns, the first of the contiguous ones held (:func:`moe_ffn`).
 
@@ -1076,19 +1081,18 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
             gate = _gate_indices(xt, gate_w, gate_bias, k, score_func,
                                  route_norm, n_group, topk_group,
                                  route_norm_eps)
+        meter = None
         with jax.named_scope("experts"):
             if experts["w_up"].shape[0] < E:
                 y, rows = _held_routed(xt, gate.weights, gate.experts,
                                        experts, activation, first_expert, E)
-                if _HELD_ROWS_MONITOR is not None:
-                    tiles = held_tiles(gate.experts.size, xt.shape[0])
-                    jax.debug.callback(functools.partial(
-                        _HELD_ROWS_MONITOR, pairs=gate.experts.size,
-                        tile=tiles and tiles[0]), rows)
+                tiles = held_tiles(gate.experts.size, xt.shape[0])
+                meter = held_meter(rows, gate.experts.size,
+                                   tiles and tiles[0])
             else:
                 y = _ragged_dispatch_local(xt, gate.weights, gate.experts,
                                            experts, activation)
-        return y.reshape(B, S, H), gate.aux_loss
+        return y.reshape(B, S, H), gate.aux_loss, meter
 
     batch_axes, seq_ax, ep, tp = plan
     used_axes = set(batch_axes) | ({seq_ax} if seq_ax else set()) \
@@ -1272,7 +1276,7 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
         # ENCLOSING manual context (compressed-collective step) where debug
         # callbacks can't lower — those runs still have routing_drop_stats.
         jax.debug.callback(_DROP_MONITOR, drop_frac)
-    return y, aux
+    return y, aux, None
 
 
 def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
@@ -1285,11 +1289,13 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
             gate_bias: Optional[jax.Array] = None,
             n_group: int = 1, topk_group: int = 1,
             dispatch: str = "auto", route_norm_eps: float = 0.0,
-            first_expert: int = 0) -> Tuple[jax.Array, jax.Array]:
+            first_expert: int = 0, with_meter: bool = False):
     """Mixture-of-experts FFN.
 
     x: [B, S, H]; gate_w: [H, E]; experts: w_up [E, H, F], w_down [E, F, H],
-    optional w_gate [E, H, F] (swiglu). Returns (y [B,S,H], aux_loss scalar).
+    optional w_gate [E, H, F] (swiglu). Returns (y [B,S,H], aux_loss scalar)
+    and, ``with_meter``, a third: the call's :func:`held_meter` where it is
+    a share that counts its rows, else None.
 
     ``dispatch``: 'auto' | 'ragged' (dropless sort + grouped matmul) |
     'dense' (capacity-factor GShard einsums) — see module docstring.
@@ -1318,8 +1324,9 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
     held = experts["w_up"].shape[0] < gate_w.shape[1]
     mode = "ragged" if held else resolve_dispatch(
         dispatch, rng, noise_std, B, S, gate_w.shape[1])
+    meter = None
     if mode == "ragged":
-        y, aux = _ragged_routed(
+        y, aux, meter = _ragged_routed(
             x, gate_w, experts, gate_bias, activation=activation, k=k,
             score_func=score_func, route_norm=route_norm, n_group=n_group,
             topk_group=topk_group, route_norm_eps=route_norm_eps,
@@ -1353,7 +1360,8 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
         y = y * jnp.asarray(route_scale, dt)
     if shared:
         y = y + _shared_experts(xt, shared, activation)
-    return y.reshape(B, S, H), aux
+    y = y.reshape(B, S, H)
+    return (y, aux, meter) if with_meter else (y, aux)
 
 
 def _shared_experts(xt: jax.Array, shared: Dict[str, jax.Array],

@@ -6,49 +6,20 @@ count MACs as the model runs (:880); on TPU the compiled HLO *is* the ground
 truth, so the profiler asks XLA's cost analysis for flops/bytes — exact, free,
 and inclusive of fusion effects the reference can't see.
 
-Every cost-analysis compile also lands in a bounded per-process **compile
-log** (:func:`compile_log`: fn name, compile wall time, flops, bytes) and
-— when ``telemetry.tracing`` is on — as a ``compile/<fn>`` trace event,
-so a retracing storm shows up as a wall of compile spans in the flight
-recorder's timeline instead of only via the dslint retracing rule.
+What a cost-analysis compile costs is accounted where every compile of the
+process is: ``xla_program_seconds_total{program=<fn>}`` and the flight
+recorder's ``xla_compile`` span (``telemetry/host.py``), once an engine has
+installed that account.
 """
 from __future__ import annotations
 
-import collections
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import numpy as np
 
 PyTree = Any
-
-#: newest-N per-process compile records ({fn, compile_seconds, flops,
-#: bytes_accessed}) — bounded so a pathological retracing loop can't grow
-#: host memory while it burns the compiler
-_COMPILE_LOG: collections.deque = collections.deque(maxlen=256)
-
-
-def compile_log() -> List[Dict[str, Any]]:
-    """Per-jit-entry compile records observed by this module (newest-256)."""
-    return list(_COMPILE_LOG)
-
-
-def _note_compile(name: str, compile_s: float,
-                  costs: Dict[str, float]) -> None:
-    entry = {
-        "fn": name,
-        "compile_seconds": round(compile_s, 6),
-        "flops": float(costs.get("flops", 0.0)),
-        "bytes_accessed": float(costs.get("bytes accessed", 0.0)),
-    }
-    _COMPILE_LOG.append(entry)
-    from deepspeed_tpu.telemetry import tracing
-
-    tracing.get_tracer().record_span(
-        f"compile/{name}", compile_s, cat="compile",
-        flops=entry["flops"], bytes_accessed=entry["bytes_accessed"])
-
 
 def normalize_costs(raw: Any) -> Dict[str, float]:
     """Normalize ``compiled.cost_analysis()`` across jax versions: a dict,
@@ -71,18 +42,14 @@ def cost_analysis_available(costs: Dict[str, float]) -> bool:
 
 
 def _cost_analysis(fn: Callable, *args, **kwargs) -> Dict[str, float]:
-    t0 = time.perf_counter()
     compiled = jax.jit(fn).lower(*args, **kwargs).compile()
-    compile_s = time.perf_counter() - t0
     try:
         raw = compiled.cost_analysis()
     except (RuntimeError, NotImplementedError, TypeError):
         # some backends/builds don't implement cost analysis at all —
         # degrade to the explicit unavailable flag, same as an empty dict
         raw = None
-    costs = normalize_costs(raw)
-    _note_compile(getattr(fn, "__name__", "<fn>"), compile_s, costs)
-    return costs
+    return normalize_costs(raw)
 
 
 def profile_fn(fn: Callable, *args, **kwargs) -> Dict[str, float]:

@@ -40,6 +40,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu import comm as dist
+from deepspeed_tpu import telemetry
 from deepspeed_tpu.analysis.racelint.sanitizer import make_lock
 from deepspeed_tpu.comm.mesh import MeshManager, get_mesh_manager
 from deepspeed_tpu.models.api import ModelSpec
@@ -74,6 +75,11 @@ PyTree = Any
 
 
 class DeepSpeedTPUEngine:
+    # what this process traces, lowers, loads and compiles from here on is
+    # accounted by program (telemetry/host.py); the constructor is the span
+    # ``engine_init`` and the parts of it that set-up is suspected of are
+    # spans inside it
+    @telemetry.engine_init()
     def __init__(
         self,
         model: ModelSpec,
@@ -130,22 +136,28 @@ class DeepSpeedTPUEngine:
                 raise DeepSpeedConfigError(
                     f"zero_optimization.{key}={subgroup} does not fit the "
                     f"mesh: {e}") from None
-        if not dist.is_initialized():
-            dist.init_distributed(mesh_config=self.config.mesh.to_mesh_config())
-        if mesh_manager is None:
-            import jax as _jax
+        # the first touch of the devices in a process that had none (the
+        # runtime's start: seconds on a TPU host) and the mesh over them
+        with telemetry.span("device_attach"):
+            if not dist.is_initialized():
+                dist.init_distributed(
+                    mesh_config=self.config.mesh.to_mesh_config())
+            if mesh_manager is None:
+                from deepspeed_tpu.comm.mesh import initialize_mesh
 
-            from deepspeed_tpu.comm.mesh import initialize_mesh
-
-            mesh_manager = get_mesh_manager()
-            want = self.config.mesh.to_mesh_config().resolve(_jax.device_count())
-            have = {a: mesh_manager.axis_size(a) for a in mesh_manager.axis_names()}
-            if want != have:
-                # config disagrees with the live mesh (e.g. a second engine with a
-                # different layout) — rebuild rather than silently reuse
-                mesh_manager = initialize_mesh(self.config.mesh.to_mesh_config())
-        self.mesh_manager = mesh_manager
-        self.mesh = self.mesh_manager.mesh
+                mesh_manager = get_mesh_manager()
+                want = self.config.mesh.to_mesh_config().resolve(
+                    jax.device_count())
+                have = {a: mesh_manager.axis_size(a)
+                        for a in mesh_manager.axis_names()}
+                if want != have:
+                    # config disagrees with the live mesh (e.g. a second
+                    # engine with a different layout) — rebuild rather
+                    # than silently reuse
+                    mesh_manager = initialize_mesh(
+                        self.config.mesh.to_mesh_config())
+            self.mesh_manager = mesh_manager
+            self.mesh = self.mesh_manager.mesh
 
         # batch triad: dp width = replicas of the model over the batch dim
         self.dp_world_size = (self.mesh_manager.axis_size("data")
@@ -236,15 +248,19 @@ class DeepSpeedTPUEngine:
 
         dist.configure(self.config)
 
-        # sharding spec trees
-        self._axes = model.axes_fn()
-        seed = self.config.seed if seed is None else seed
-        self._init_rng = jax.random.PRNGKey(seed)
-        self._shapes = jax.eval_shape(model.init_fn, self._init_rng)
-        self.master_spec = self.policy.state_spec(self._axes, self._shapes)
-        self.param_spec = self.policy.param_spec(self._axes, self._shapes)
-        self.grad_spec = self.policy.grad_spec(self._axes, self._shapes)
-        self.batch_spec = self.policy.batch_spec()
+        # sharding spec trees (the model's shapes are read off an abstract
+        # trace of its ``init_fn``)
+        with telemetry.span("state_init"):
+            self._axes = model.axes_fn()
+            seed = self.config.seed if seed is None else seed
+            self._init_rng = jax.random.PRNGKey(seed)
+            self._shapes = jax.eval_shape(model.init_fn, self._init_rng)
+            self.master_spec = self.policy.state_spec(self._axes,
+                                                      self._shapes)
+            self.param_spec = self.policy.param_spec(self._axes,
+                                                     self._shapes)
+            self.grad_spec = self.policy.grad_spec(self._axes, self._shapes)
+            self.batch_spec = self.policy.batch_spec()
 
         # ZeRO-Offload: optimizer state lives in host memory between steps
         # (reference runtime/zero/offload_config.py + swap_tensor swappers;
@@ -350,7 +366,10 @@ class DeepSpeedTPUEngine:
         # progressive_layer_drop.py — config-driven, engine-injected)
         self._setup_data_efficiency()
 
-        self.state = self._init_state()
+        # ONE program draws the parameters (``init_fn``, in float32) and
+        # the optimizer's state from them and places both
+        with telemetry.span("params_init"):
+            self.state = self._init_state()
         self._compiled: Dict[Any, Any] = {}
         # step-phase overlap: seed the double-buffered param publish so
         # the FIRST step's forward has a buffer to consume
@@ -393,6 +412,11 @@ class DeepSpeedTPUEngine:
         self._ft_lock = make_lock("engine._ft_lock")
         self._last_save_dir: Optional[str] = None
         self._prev_sig_handlers: Dict[int, Any] = {}
+        # a step's meters, kept for the next step to observe
+        # (``_observe_meters``; the registry's collector reads them too)
+        self._meters_lock = make_lock("engine._meters_lock")
+        self._meters_pending: Optional[Dict[str, jax.Array]] = None   # guarded-by: self._meters_lock
+        self._metered = False
         self._setup_telemetry()
 
         # EP-dispatch drop visibility: under an 'expert' mesh axis the ragged
@@ -417,49 +441,40 @@ class DeepSpeedTPUEngine:
             set_drop_monitor(_sink)
 
         # a model that holds a SHARE of each expert layer (expert
-        # parallelism's unit): the rows of each held expert reach the
-        # registry by an async callback that rides the step, no fence
-        if self._tm is not None and getattr(
-                getattr(model, "config", None), "moe_router_experts", 0):
-            from deepspeed_tpu import telemetry
-            from deepspeed_tpu.moe.layer import set_held_rows_monitor
-
-            rows_h = telemetry.histogram(
-                "train_moe_held_expert_rows",
-                "calls of an expert layer that holds a share of its "
-                "experts: mean rows a held expert got",
-                buckets=tuple(2.0 ** i for i in range(4, 18)))
-            imbalance_h = telemetry.histogram(
-                "train_moe_load_imbalance",
-                "calls of an expert layer that holds a share of its "
-                "experts: the busiest held expert's rows over the mean "
-                "(1 = even routing)",
-                buckets=(1.0, 1.05, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0))
-            share_h = telemetry.histogram(
-                "train_moe_held_pair_share",
-                "calls of an expert layer that holds a share of its "
-                "experts: (row, expert) pairs on held experts over all the "
-                "router chose",
-                buckets=tuple(i / 16 for i in range(1, 17)))
-
-            moved_h = telemetry.histogram(
-                "train_moe_moved_row_share",
-                "calls of an expert layer that holds a share of its "
-                "experts: sorted rows its dispatch, activation and combine "
-                "covered (the held pairs, to a tile of rows) over the "
-                "(row, expert) pairs; 1 = the movers did not engage",
-                buckets=tuple(i / 16 for i in range(1, 17)))
-
-            def _held_rows(rows, pairs, tile=None):
-                rows = np.asarray(rows, np.float64)
-                mean = float(rows.mean())
-                rows_h.observe(mean)
-                imbalance_h.observe(float(rows.max()) / max(mean, 1e-9))
-                share_h.observe(float(rows.sum()) / pairs)
-                moved_h.observe(1.0 if tile is None else
-                                -(-rows.sum() // tile) * tile / pairs)
-
-            set_held_rows_monitor(_held_rows)
+        # parallelism's unit): each layer's rows leave the compiled step as
+        # one of its outputs (``metrics["moe_held"]``) and reach the
+        # registry a step late, with no fence and no host callback
+        # (``_observe_meters``)
+        self._metered = self._tm is not None and bool(getattr(
+            getattr(model, "config", None), "moe_router_experts", 0))
+        if self._metered:
+            self._tm_held = (
+                telemetry.histogram(
+                    "train_moe_held_expert_rows",
+                    "calls of an expert layer that holds a share of its "
+                    "experts: mean rows a held expert got",
+                    buckets=tuple(2.0 ** i for i in range(4, 18))),
+                telemetry.histogram(
+                    "train_moe_load_imbalance",
+                    "calls of an expert layer that holds a share of its "
+                    "experts: the busiest held expert's rows over the mean "
+                    "(1 = even routing)",
+                    buckets=(1.0, 1.05, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0,
+                             16.0)),
+                telemetry.histogram(
+                    "train_moe_held_pair_share",
+                    "calls of an expert layer that holds a share of its "
+                    "experts: (row, expert) pairs on held experts over all "
+                    "the router chose",
+                    buckets=tuple(i / 16 for i in range(1, 17))),
+                telemetry.histogram(
+                    "train_moe_moved_row_share",
+                    "calls of an expert layer that holds a share of its "
+                    "experts: sorted rows its dispatch, activation and "
+                    "combine covered (the held pairs, to a tile of rows) "
+                    "over the (row, expert) pairs; 1 = the movers did not "
+                    "engage",
+                    buckets=tuple(i / 16 for i in range(1, 17))))
 
         n_params = model.num_params
         log_dist(
@@ -1347,6 +1362,7 @@ class DeepSpeedTPUEngine:
         (``benchmarks/``: model FLOPs from shapes, no recompute)."""
         from deepspeed_tpu import telemetry
 
+        self._observe_meters(None)
         if self._last_metrics_dev:
             try:
                 host = {k: float(jax.device_get(v))
@@ -1455,10 +1471,44 @@ class DeepSpeedTPUEngine:
         otherwise every watchdog-armed run that simply FINISHES training
         would log a false stall (the watchdog can't distinguish 'done'
         from 'stuck'); long-lived processes that keep the engine alive
-        after the last step should call this explicitly."""
+        after the last step should call this explicitly. The last step's
+        meters, kept for the next step to observe, are observed now."""
+        self._observe_meters(None)
         if self._watchdog is not None:
             self._watchdog.stop()
             self._watchdog = None
+
+    def _observe_meters(self, meters: Optional[Dict[str, jax.Array]]
+                        ) -> None:
+        """What a step's layers metered of themselves (the non-scalar
+        entries of the compiled step's ``metrics``) into the registry, a
+        step late: ``meters`` are the step just dispatched, kept (their
+        copy to the host started) until the next call, which observes them
+        when that step has long ended; so the engine adds no fence of its
+        own, and where a caller does not read its loss the host runs at
+        most a step ahead of the last meter it read. ``None`` observes what
+        is kept (``shutdown_telemetry``, the registry's collector: it may
+        run on the scrape thread, so the swap is locked)."""
+        if meters:
+            for m in meters.values():
+                m.copy_to_host_async()
+        with self._meters_lock:
+            meters, self._meters_pending = self._meters_pending, meters
+        if not meters:
+            return
+        from deepspeed_tpu.moe.layer import read_held_meter
+
+        rows_h, imbalance_h, share_h, moved_h = self._tm_held
+        # [micro-batches, expert layers, held + 2]: a call of a layer each
+        for meter in np.asarray(meters["moe_held"]).reshape(
+                -1, meters["moe_held"].shape[-1]):
+            rows, pairs, tile = read_held_meter(meter)
+            n, mean = int(rows.sum()), float(rows.mean())
+            rows_h.observe(mean)
+            imbalance_h.observe(float(rows.max()) / max(mean, 1e-9))
+            share_h.observe(n / pairs)
+            moved_h.observe(1.0 if tile is None
+                            else -(-n // tile) * tile / pairs)
 
     def __del__(self):
         try:
@@ -1729,8 +1779,12 @@ class DeepSpeedTPUEngine:
             treedef, fenced_bucket_apply(leaves, buckets, fns))
 
     def _loss_and_grads(self, master: PyTree, batch: PyTree, scale,
-                        params_buf: Optional[PyTree] = None
-                        ) -> Tuple[jax.Array, PyTree]:
+                        params_buf: Optional[PyTree] = None,
+                        with_meters: bool = False):
+        """``(loss, grads)``; ``with_meters``: and, last, the dict of what
+        the forward pass's layers meter of themselves
+        (``transformer.collect_meters``; empty for most models): an
+        auxiliary output of the differentiated function, forward only."""
         if self._offload_param:
             # H2D stream OUTSIDE the autodiff: differentiating w.r.t. the
             # host-resident master would put every cotangent in host space
@@ -1754,7 +1808,8 @@ class DeepSpeedTPUEngine:
                 loss, grads = out
                 grads = jax.tree.map(
                     lambda g, m: g.astype(m.dtype), grads, master)
-                return loss, self._constrain_grads(grads)
+                return (loss, self._constrain_grads(grads),
+                        *([{}] if with_meters else []))
 
         def scaled_loss(m):
             if params_buf is not None:
@@ -1765,6 +1820,12 @@ class DeepSpeedTPUEngine:
                 params = self._consume_param_buffer()(m, params_buf)
             else:
                 params = self._compute_params(m)
+            if with_meters:
+                from deepspeed_tpu.models.transformer import collect_meters
+
+                with collect_meters() as meters:
+                    loss = self.model_spec.loss_fn(params, batch)
+                return (loss * scale if scale is not None else loss), meters
             loss = self.model_spec.loss_fn(params, batch)
             return loss * scale if scale is not None else loss
 
@@ -1772,10 +1833,12 @@ class DeepSpeedTPUEngine:
         # (``loss_and_grads/jvp(..)``), backward (``transpose(jvp(..))``)
         # and recompute (``rematted_computation``) apart
         with jax.named_scope("loss_and_grads"):
-            loss, grads = jax.value_and_grad(scaled_loss)(master)
+            out, grads = jax.value_and_grad(
+                scaled_loss, has_aux=with_meters)(master)
+        loss, *meters = out if with_meters else (out,)
         if scale is not None:
             loss = loss / scale
-        return loss, self._constrain_grads(grads)
+        return (loss, self._constrain_grads(grads), *meters)
 
     def _lr_at(self, step):
         if self.lr_scheduler is not None:
@@ -1900,7 +1963,8 @@ class DeepSpeedTPUEngine:
 
     @staticmethod
     def accumulate_microbatches(micro_fn, like, acc_dtype, batch, gas,
-                                constrain=lambda x: x, extra0=None):
+                                constrain=lambda x: x, extra0=None,
+                                has_meters=False):
         """Shared GAS loop: the sum, in ``acc_dtype`` (callers pass
         ``_grad_accum_dtype()``; fp32 default), of the gradients of
         ``micro_fn(mb) -> (loss, grads)`` over the leading micro-batch
@@ -1918,29 +1982,37 @@ class DeepSpeedTPUEngine:
 
         ``extra0``: optional extra carry threaded through the micros (LoCo
         residuals); micro_fn is then called as ``micro_fn(mb, extra) ->
-        (loss, grads, extra)`` and the return gains the final extra."""
+        (loss, grads, extra)`` and the return gains the final extra.
+
+        ``has_meters``: micro_fn returns one value more, LAST: a tree of
+        what the micro-batch's forward pass says of itself
+        (``_loss_and_grads(with_meters=True)``), and the return gains them
+        last, stacked ``[gas, ...]``: a scan's outputs, no carry."""
         with_extra = extra0 is not None
 
         if gas == 1:
             squeezed = jax.tree.map(lambda x: x[0], batch)
-            loss, grads, *extra = (micro_fn(squeezed, extra0) if with_extra
-                                   else micro_fn(squeezed))
+            loss, grads, *rest = (micro_fn(squeezed, extra0) if with_extra
+                                  else micro_fn(squeezed))
             grads = constrain(jax.tree.map(
                 lambda g: g.astype(acc_dtype), grads))
-            return (grads, loss, *extra)
+            if has_meters:
+                rest[-1] = jax.tree.map(lambda m: m[None], rest[-1])
+            return (grads, loss, *rest)
 
         def micro(carry, mb):
             if with_extra:
                 acc, extra = carry
-                loss, grads, extra = micro_fn(mb, extra)
+                loss, grads, extra, *meters = micro_fn(mb, extra)
             else:
                 acc = carry
-                loss, grads = micro_fn(mb)
+                loss, grads, *meters = micro_fn(mb)
             with jax.named_scope("grad_accumulate"):
                 acc = jax.tree.map(
                     lambda a, g: a + g.astype(a.dtype), acc, grads)
             acc = constrain(acc)
-            return ((acc, extra) if with_extra else acc), loss
+            return ((acc, extra) if with_extra else acc), \
+                ((loss, *meters) if has_meters else loss)
 
         with jax.named_scope("grad_accumulate"):
             zeros = jax.tree.map(
@@ -1948,17 +2020,22 @@ class DeepSpeedTPUEngine:
         zeros = constrain(zeros)
         carry, losses = jax.lax.scan(
             micro, (zeros, extra0) if with_extra else zeros, batch)
+        losses, *meters = losses if has_meters else (losses,)
         loss = jnp.mean(losses)
         if with_extra:
             grads_sum, extra = carry
-            return grads_sum, loss, extra
-        return carry, loss
+            return (grads_sum, loss, extra, *meters)
+        return (carry, loss, *meters)
 
     def _train_step_fn(self, gas: int):
         """The raw (unjitted) fused-step body — shared by the single-step
         jit and the multi-step ``lax.scan`` wrapper."""
 
         acc_dt = self._grad_accum_dtype()
+        # a model whose layers meter themselves (a share of an expert
+        # layer's rows): the meters leave the step as outputs. Every other
+        # model's step is traced as it always was
+        metered = self._metered
 
         def train_step(state, batch):
             scale = state["scaler"].scale if self.fp16_enabled else None
@@ -1972,19 +2049,21 @@ class DeepSpeedTPUEngine:
                 if isinstance(mb, dict) and "_nan_grads" in mb:
                     mb = dict(mb)
                     flag = mb.pop("_nan_grads")
-                loss, grads = self._loss_and_grads(
+                loss, grads, *meters = self._loss_and_grads(
                     state["master"], mb, scale,
                     params_buf=(state.get("gathered")
-                                if self._param_buffer else None))
+                                if self._param_buffer else None),
+                    with_meters=metered)
                 if flag is not None:
                     bad = jnp.where(flag > 0, jnp.nan, 1.0)
                     grads = jax.tree.map(
                         lambda g: g * bad.astype(g.dtype), grads)
-                return loss, grads
+                return (loss, grads, *meters)
 
-            grads_sum, mean_loss = self.accumulate_microbatches(
+            grads_sum, mean_loss, *meters = self.accumulate_microbatches(
                 micro_fn, self._shapes, acc_dt, batch, gas,
-                constrain=self._constrain_grads)
+                constrain=self._constrain_grads,
+                **({"has_meters": True} if metered else {}))
 
             grad_scale = jnp.float32(gas) * (scale if scale is not None else 1.0)
             lr_mult = None
@@ -1993,6 +2072,10 @@ class DeepSpeedTPUEngine:
             new_state, metrics = self._apply_update(state, grads_sum,
                                                     grad_scale, lr_mult)
             metrics["loss"] = mean_loss
+            if metered:
+                # ``[gas, ...]`` each, beside the scalars: ``_after_step``
+                # takes them out (``_observe_meters``)
+                metrics.update(meters[0])
             return new_state, metrics
 
         return train_step
@@ -2614,6 +2697,7 @@ class DeepSpeedTPUEngine:
 
     def _dispatch_train_step(self, stacked: PyTree, gas: int) -> jax.Array:
         """Run ONE fused step on an already-stacked [gas, ...] window."""
+        from deepspeed_tpu import telemetry
 
         stacked = self._maybe_inject_nan_grads(stacked, gas)
         if self._host_runner is None:
@@ -2632,8 +2716,9 @@ class DeepSpeedTPUEngine:
         t0 = time.perf_counter()
         self._in_step = True   # a preemption signal now defers to the
         try:                   # boundary check below
-            with self._train_span("train_step"):
+            with self._train_span("train_step") as step_span:
                 chaos_point("train/step")
+                compiled_s = -telemetry.compile_seconds()
                 if self._host_runner is not None:
                     # SuperOffload/ZenFlow host-executed update (runtime/host_step.py)
                     _, metrics = self._host_runner.train_batch(batch, gas)
@@ -2655,6 +2740,11 @@ class DeepSpeedTPUEngine:
                         self._park_master()
                     if self._offload_param_nvme:
                         self._param_nvme_swapper().swap_out_params()
+                # a step that traced, lowered or compiled anything says so
+                # in its own span (the flight recorder's record)
+                compiled_s += telemetry.compile_seconds()
+                if compiled_s > 0 and step_span is not None:
+                    step_span.note(compile_s=compiled_s)
             self.global_steps += 1
             self.micro_steps += gas
             self._after_step(metrics, wall_s=time.perf_counter() - t0,
@@ -2773,6 +2863,10 @@ class DeepSpeedTPUEngine:
                     n_steps: int = 1, wall_s: Optional[float] = None,
                     tokens: int = 0) -> None:
         self.tput_timer.stop(global_step=True, steps=n_steps)
+        if self._metered:
+            # the arrays among the scalars (``_train_step_fn``)
+            self._observe_meters({k: metrics.pop(k) for k in ("moe_held",)
+                                  if k in metrics})
         self._last_metrics_dev = metrics  # lazy: no host sync off the print path
         if self._tm is not None:
             self._tm_steps.inc(n_steps)

@@ -53,7 +53,10 @@ from deepspeed_tpu.telemetry.registry import (
 from deepspeed_tpu.telemetry.spans import StallWatchdog, span as _span
 from deepspeed_tpu.telemetry import host, tracing
 from deepspeed_tpu.telemetry.host import (
+    compile_seconds,
+    engine_init,
     gc_pause_seconds,
+    install_compile_account,
     install_gc_span,
     refresh as refresh_host_counters,
 )
@@ -72,6 +75,7 @@ __all__ = [
     "register_health_probe", "unregister_health_probe", "health_report",
     "health_probe_names", "clear_health_probes", "unique_health_probe_name",
     "label_key", "install_gc_span", "gc_pause_seconds",
+    "install_compile_account", "compile_seconds", "engine_init",
     "refresh_host_counters",
 ]
 
@@ -125,7 +129,8 @@ def stop_metrics_server() -> None:
 def reset() -> None:
     """Tests only: stop the server, clear the default registry, drop any
     registered health probes and /slo provider, take the ``gc_pause``
-    span out of ``gc.callbacks``, and disable/clear the default tracer."""
+    span out of ``gc.callbacks`` and the compile account's listeners out
+    of ``jax.monitoring``, and disable/clear the default tracer."""
     _stop_server()
     clear_health_probes()
     clear_slo_provider()

@@ -193,26 +193,163 @@ def test_a_share_with_the_movers_is_the_share_with_the_plain_forms(
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
 
 
-def test_monitor_is_told_the_tile_the_movers_walk():
-    """``moe_ffn`` of a share hands the monitor the rows a step takes, so
-    the rows moved are ``ceil(n / tile) x tile`` of the pairs."""
-    rng = np.random.default_rng(11)
-    x = jnp.asarray(rng.normal(size=(2, 64, H)), jnp.float32)
+def _share(seed=11):
+    rng = np.random.default_rng(seed)
     gate_w = jnp.asarray(rng.normal(size=(H, 32)), jnp.float32)
     experts = {k: jnp.asarray(rng.normal(size=s) / 8, jnp.float32)
                for k, s in (("w_up", (HELD, H, INTER)),
                             ("w_gate", (HELD, H, INTER)),
                             ("w_down", (HELD, INTER, H)))}
-    seen = []
-    MOE.set_held_rows_monitor(
-        lambda rows, pairs, tile: seen.append((int(rows.sum()), pairs, tile)))
-    try:
-        jax.block_until_ready(jax.jit(lambda x: MOE.moe_ffn(
-            x, gate_w, experts, activation="swiglu", k=K,
-            first_expert=0)[0])(x))
-        jax.effects_barrier()
-    finally:
-        MOE.set_held_rows_monitor(None)
-    (n, pairs, tile), = seen
+    return rng, gate_w, experts
+
+
+def test_meter_is_told_the_tile_the_movers_walk():
+    """``moe_ffn`` of a share hands out, as an OUTPUT of the compiled
+    program (no host callback: ``with_meter``), the rows of each held
+    expert with the pairs the router chose and the rows a step of the
+    movers takes, so the rows moved are ``ceil(n / tile) x tile`` of the
+    pairs."""
+    rng, gate_w, experts = _share()
+    x = jnp.asarray(rng.normal(size=(2, 64, H)), jnp.float32)
+    step = jax.jit(lambda x: MOE.moe_ffn(
+        x, gate_w, experts, activation="swiglu", k=K, first_expert=0,
+        with_meter=True))
+    assert "callback" not in str(step.trace(x).jaxpr)
+    assert "callback" not in step.lower(x).as_text()
+    y, _, meter = step(x)
+    rows, pairs, tile = MOE.read_held_meter(np.asarray(meter))
+    assert rows.shape == (HELD,) and meter.dtype == jnp.int32
     assert pairs == 2 * 64 * K and tile == MOE.HELD_TILE_ROWS
-    assert 0 < n < pairs
+    assert 0 < int(rows.sum()) < pairs
+    # the rows are the router's own: the pairs that chose a held expert
+    gate = MOE._gate_indices(x.reshape(-1, H), gate_w, None, K, "softmax",
+                             True, 1, 1)
+    want = np.bincount(np.asarray(gate.experts).reshape(-1),
+                       minlength=32)[:HELD]
+    assert np.array_equal(rows, want)
+    # a call under a tile of pairs (the plain forms): no tile
+    rows, pairs, tile = MOE.read_held_meter(np.asarray(
+        MOE.held_meter(jnp.arange(3), 24, None)))
+    assert (list(rows), pairs, tile) == ([0, 1, 2], 24, None)
+    # every expert held, or no meter asked for: none comes back
+    whole = {k: jnp.concatenate([v] * 4) for k, v in experts.items()}
+    assert jax.eval_shape(lambda x: MOE.moe_ffn(
+        x, gate_w, whole, activation="swiglu", k=K, with_meter=True),
+        x)[2] is None
+    assert len(jax.eval_shape(lambda x: MOE.moe_ffn(
+        x, gate_w, experts, activation="swiglu", k=K), x)) == 2
+
+
+# ------------------------------------------------------------------ #
+# the meter rides the training step's outputs (runtime/engine.py)
+# ------------------------------------------------------------------ #
+LAYERS = 3
+
+
+def _held_engine(gas=1):
+    import deepspeed_tpu as dst
+    from deepspeed_tpu import comm, telemetry
+    from deepspeed_tpu.comm.mesh import MeshConfig, initialize_mesh
+    from deepspeed_tpu.models import transformer as TR
+
+    telemetry.reset()
+    comm.init_distributed(verbose=False)
+    # one device, as the one-chip cell's mesh (under a token-sharded mesh
+    # a share's rows are not counted)
+    mesh = initialize_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    cfg = TR.get_model_config(
+        "tiny", n_experts=4, moe_router_experts=16, moe_top_k=4,
+        num_layers=LAYERS, remat="full", moe_dispatch="ragged",
+        dtype="float32")
+    engine, *_ = dst.initialize(model=dst.causal_lm_spec(cfg), config={
+        "train_micro_batch_size_per_gpu": 2,
+        "gradient_accumulation_steps": gas, "train_batch_size": 2 * gas,
+        "steps_per_print": 10 ** 9,
+        "optimizer": {"type": "adam", "params": {"lr": 1e-3}}},
+        mesh_manager=mesh)
+    return cfg, engine
+
+
+def _batches(cfg, n, gas=1, seed=3):
+    rng = np.random.default_rng(seed)
+    return [{"tokens": rng.integers(0, cfg.vocab_size, (2, 64),
+                                    dtype=np.int32)}
+            for _ in range(n * gas)]
+
+
+@pytest.fixture
+def one_device():
+    """The global mesh and the registry as the next test expects them."""
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.comm.mesh import reset_mesh
+
+    reset_mesh()
+    yield
+    reset_mesh()
+    telemetry.reset()
+
+
+def test_the_training_step_holds_no_host_callback(one_device):
+    """The compiled step of a model that holds a share of its expert
+    layers: no ``debug_callback`` in its jaxpr, no callback custom call in
+    its lowered text (JAX writes no program that holds one to the
+    persistent cache), and the meter among its outputs."""
+    cfg, engine = _held_engine()
+    batch = engine._shard_batch(jax.tree.map(
+        lambda x: x[None], _batches(cfg, 1)[0]), leading=True)
+    step = engine._select_step_builder(1)
+    with engine.mesh:
+        traced = step.trace(engine.state, batch)
+        assert "callback" not in str(traced.jaxpr)
+        assert "callback" not in traced.lower().as_text()
+    meter = traced.out_info[1]["moe_held"]
+    assert meter.shape == (1, LAYERS, 4 + 2) and meter.dtype == jnp.int32
+    engine.shutdown_telemetry()
+
+
+@pytest.mark.parametrize("gas", [1, 2])
+def test_histograms_follow_the_steps_a_step_late(one_device, gas,
+                                                 monkeypatch):
+    """After N steps the four ``train_moe_*`` histograms hold ``layers x
+    (N - 1)`` observations a micro-batch (step k's meter is observed when
+    step k + 1 has been dispatched: no fence) and ``layers x N`` after the
+    flush, with the means that the rows ``_held_routed`` returned give
+    (the step's ``moe_held`` output is those rows:
+    ``test_meter_is_told_the_tile_the_movers_walk``)."""
+    from deepspeed_tpu import telemetry
+
+    cfg, engine = _held_engine(gas)
+    N = 3
+    names = ("train_moe_held_expert_rows", "train_moe_load_imbalance",
+             "train_moe_held_pair_share", "train_moe_moved_row_share")
+
+    def counts():
+        return [telemetry.get_registry().get(n).summary()["count"]
+                for n in names]
+
+    metrics = []
+    after = engine._after_step
+    monkeypatch.setattr(engine, "_after_step", lambda m, **kw: (
+        metrics.append(np.asarray(m["moe_held"])), after(m, **kw)))
+    batches = iter(_batches(cfg, N, gas))
+    for step in range(N):
+        engine.train_batch(batches)
+        assert counts() == [LAYERS * gas * step] * 4
+    engine.shutdown_telemetry()
+    assert counts() == [LAYERS * gas * N] * 4
+    engine.shutdown_telemetry()                 # nothing is observed twice
+    assert counts() == [LAYERS * gas * N] * 4
+
+    assert all(m.shape == (gas, LAYERS, 4 + 2) for m in metrics)
+    meters = np.concatenate(metrics).reshape(-1, 4 + 2)
+    pairs = 2 * 64 * cfg.moe_top_k
+    assert (meters[:, -2] == pairs).all() and (meters[:, -1] == 512).all()
+    rows = meters[:, :-2].astype(np.float64)
+    summaries = [telemetry.get_registry().get(n).summary() for n in names]
+    assert summaries[0]["mean"] == pytest.approx(rows.mean())
+    assert summaries[1]["mean"] == pytest.approx(
+        (rows.max(1) / rows.mean(1)).mean())
+    assert summaries[2]["mean"] == pytest.approx(rows.sum(1).mean() / pairs)
+    assert summaries[3]["mean"] == pytest.approx(
+        (np.ceil(rows.sum(1) / 512) * 512).mean() / pairs)
+    assert 0 < summaries[2]["mean"] < 1

@@ -339,6 +339,41 @@ def test_a_collection_inside_a_tick_is_a_span_and_in_the_record(
     assert gc.callbacks == found
 
 
+def test_a_compile_inside_a_tick_is_in_the_record(clock, tiny_params,
+                                                  monkeypatch):
+    """A program that compiles inside a tick that is not its own first:
+    the seconds under JAX's compile path are the slow tick's
+    ``compile_s`` (``telemetry/host.py``'s account, differenced a tick as
+    ``gc_s`` is), and the account's listeners are one set however many
+    engines ask and gone after ``telemetry.reset()``."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import monitoring
+
+    found = len(monitoring.get_event_duration_listeners())
+    monkeypatch.setattr(telemetry, "span", _SlowReadback)
+    eng = _engine(tiny_params)
+    _engine(tiny_params)                # a second engine installs nothing
+    assert len(monitoring.get_event_duration_listeners()) == found + 1
+    fe = ServingFrontend(eng, clock=clock, register_health=False)
+    _warm(fe, np.random.default_rng(6))
+    seen = telemetry.compile_seconds()
+    _SlowReadback.inside = staticmethod(
+        lambda: jax.jit(lambda x: jnp.cos(x) * 3.0)(jnp.ones((5,))))
+    _SlowReadback.delays.append(0.05)
+    try:
+        fe.run_tick()
+    finally:
+        _SlowReadback.inside = None
+    (rec,) = eng.slow_ticks
+    assert 0 < rec["compile_s"] <= telemetry.compile_seconds() - seen
+    fe.run_tick()                       # nothing compiled in this one
+    assert eng._compile_seen_s == telemetry.compile_seconds()
+    fe.close()
+    telemetry.reset()
+    assert len(monitoring.get_event_duration_listeners()) == found
+
+
 def test_process_counters_follow_the_kernels(clock):
     """``process_context_switches_total`` and ``process_cpu_seconds_total``
     are the differences of ``getrusage`` / ``process_time`` since the last

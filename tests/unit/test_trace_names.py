@@ -179,20 +179,31 @@ def test_training_step_of_held_experts_carries_its_scopes_and_counters():
     assert {gap_chain.phase_of(s) for s in stacks if "/experts/" in s} \
         >= {"fwd", "recompute", "bwd"}
     # and a program of one chip's rows (no axis of the mesh divides two
-    # sequences) leaves the held experts' rows in the registry: four
-    # layers, forward and recompute, 2 x 64 x 8 pairs, a quarter of them here
+    # sequences) hands the held experts' rows out beside its loss (the
+    # step's ``metrics["moe_held"]``; no host callback), which the engine
+    # puts into the registry: four layers, the forward pass alone, 2 x 64 x
+    # 8 pairs, a quarter of them here
+    from deepspeed_tpu.models import transformer as T
+
     tokens = np.random.default_rng(0).integers(
         0, cfg.vocab_size, (2, 64)).astype(np.int32)
     params = jax.tree.map(lambda x: x.astype(jnp.float32),
                           engine.state["master"])
-    jax.block_until_ready(jax.jit(jax.grad(spec.loss_fn))(
-        params, {"tokens": tokens}))
-    jax.effects_barrier()
+
+    def loss_and_meters(p, b):
+        with T.collect_meters() as meters:
+            return spec.loss_fn(p, b), meters
+
+    (_, meters), _ = jax.jit(jax.value_and_grad(
+        loss_and_meters, has_aux=True))(params, {"tokens": tokens})
+    assert meters["moe_held"].shape == (4, cfg.n_experts + 2)
+    engine._observe_meters({"moe_held": meters["moe_held"][None]})
+    engine.shutdown_telemetry()
     got = {}
     for name in ("train_moe_held_expert_rows", "train_moe_held_pair_share",
                  "train_moe_load_imbalance", "train_moe_moved_row_share"):
         (_, child), = telemetry.histogram(name).labels_items()
-        assert child.count == 8, name
+        assert child.count == 4, name
         got[name] = child.sum / child.count
     assert got["train_moe_held_expert_rows"] == pytest.approx(
         got["train_moe_held_pair_share"] * 2 * 64 * 8 / 4)

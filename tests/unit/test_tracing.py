@@ -434,23 +434,37 @@ class TestFlightRecorder:
         assert trace_dump_main([path, "--top", "ten"]) == 2  # not an int
         assert trace_dump_main([path, "--top", "2"]) == 0
 
-    def test_compile_log_records_trace_events(self):
+    def test_compile_account_records_trace_events(self):
+        """What ``flops_profiler``'s own compile log held (PR 51 took it
+        out) is in the account of every compile of the process: the
+        profiled function under its own label in
+        ``xla_program_seconds_total`` and, a compile, as an ``xla_compile``
+        span in the flight recorder."""
         import jax.numpy as jnp
 
         from deepspeed_tpu.profiling import flops_profiler as fp
+        from deepspeed_tpu.telemetry import host
 
         tr = telemetry.configure_tracing(enabled=True)
+        telemetry.install_compile_account()
+        monkey = pytest.MonkeyPatch()
+        monkey.setattr(host, "SMALL_PROGRAM_S", 0.0)   # 'double' is small
+        try:
+            def double(x):
+                return x * 2.0
 
-        def double(x):
-            return x * 2.0
-
-        out = fp.profile_fn(double, jnp.ones((8,)))
+            out = fp.profile_fn(double, jnp.ones((8,)))
+        finally:
+            monkey.undo()
         assert out["flops"] >= 0
-        entries = fp.compile_log()
-        assert entries and entries[-1]["fn"] == "double"
-        assert entries[-1]["compile_seconds"] > 0
-        names = [e["name"] for e in tr.export_chrome()["traceEvents"]]
-        assert "compile/double" in names
+        seconds = telemetry.get_registry().get("xla_program_seconds_total")
+        assert seconds.value(program="double", phase="compile") > 0
+        assert seconds.value(program="double", phase="lower") > 0
+        telemetry.refresh_host_counters()
+        spans = [e for e in tr.export_chrome()["traceEvents"]
+                 if e["name"] == "xla_compile"]
+        assert any(e["args"].get("program") == "double" and
+                   e["args"].get("phase") == "compile" for e in spans)
 
 
 # --------------------------------------------------------------------- #

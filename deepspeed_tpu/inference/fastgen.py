@@ -375,7 +375,7 @@ class FastGenEngine:
         # expert layers whose per-expert row counts ride back with a
         # tick's sampled tokens; 0 for a model without experts
         self._expert_layers = sum(
-            c.num_layers for _, c in cfg.segments if c.n_experts)
+            c.ffn_layers for _, c in cfg.segments if c.n_experts)
 
     @classmethod
     def _pool_bytes(cls, cfg, n_blocks: int, block_size: int,
@@ -557,7 +557,8 @@ class FastGenEngine:
             "bytes a sequence slot holds whatever its sequence's length, by "
             "kind: conv (a convolution's last inputs) / ring (window "
             "layers' keys and values) / scan (a recurrence's matrix) / "
-            "rule (a delta rule's matrices)")
+            "rule (a delta rule's matrices) / ssd (a state-space duality "
+            "layer's matrices, a head each)")
         by_kind: Dict[str, int] = {}
         for s, n in PG.store_bytes(self.cfg, self.pool):
             if s.cls != PG.BLOCKS:
@@ -578,6 +579,11 @@ class FastGenEngine:
             "fastgen_kda_chunk_pieces_total",
             "grid steps of the delta rule's chunk form that did work: the "
             "chunks of 64 rows of a tick that a run it takes has a row in")
+        self._tm_ssd_rows = telemetry.counter(
+            "fastgen_ssd_rows_total",
+            "rows of ticks through the mamba2 layers, by the form of the "
+            "recurrence that took them: step (runs of one row: one read "
+            "and one write of the sequence's state) / chunk (chunked)")
         self._tm_index_positions = telemetry.counter(
             "fastgen_index_positions_total",
             "cache positions the sparse layers' indexers scored: the "
@@ -1518,6 +1524,11 @@ class FastGenEngine:
                 self._tm_kda_rows.inc(slot_attrs["kda_chunk_rows"],
                                       form="chunk")
                 self._tm_kda_pieces.inc(slot_attrs["kda_chunk_pieces"])
+            if "ssd_step_rows" in slot_attrs:
+                self._tm_ssd_rows.inc(slot_attrs["ssd_step_rows"],
+                                      form="step")
+                self._tm_ssd_rows.inc(slot_attrs["ssd_chunk_rows"],
+                                      form="chunk")
             if "sparse_selected" in slot_attrs:
                 self._tm_index_positions.inc(slot_attrs["index_positions"])
                 self._tm_sparse_selected.inc(slot_attrs["sparse_selected"])

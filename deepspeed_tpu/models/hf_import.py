@@ -1573,12 +1573,173 @@ def params_from_kimi_linear(sd: Dict[str, Any], cfg: TransformerConfig
     return params
 
 
+#: a layer's kind by its letter in ``hybrid_override_pattern``
+_NEMOTRON_H_KINDS = {"M": "mamba2", "E": "ffn", "*": "full", "-": "ffn"}
+
+
+def config_from_nemotron_h(hf_config) -> TransformerConfig:
+    """``model_type`` ``nemotron_h``: every layer is ONE RMSNorm and ONE
+    sublayer, ``x += f(norm x)``, and ``hybrid_override_pattern`` gives a
+    letter a layer: ``M`` a Mamba-2 mixer (``mamba_num_heads`` heads of
+    ``mamba_head_dim``, ``n_groups`` groups of ``ssm_state_size``, a
+    convolution of ``conv_kernel`` taps with a bias, chunks of
+    ``chunk_size``), ``*`` grouped-query attention WITHOUT rotary (the
+    family applies none: ``rope_theta`` and ``partial_rotary_factor`` are
+    keys it does not read), ``E`` an expert layer (``-``: a dense one)
+    whose routed experts take a ``moe_latent_size`` latent of the row and
+    activate by a squared ReLU, not gated, beside ``n_shared_experts``
+    shared experts ``moe_shared_expert_intermediate_size`` wide on the row
+    itself, under a sigmoid router with a selection bias and
+    ``routed_scaling_factor``. A SHARE of the expert layers as
+    ``kimi_linear``'s: ``n_routed_experts`` is then the experts held,
+    ``router_experts`` the router's width and ``first_expert`` the first
+    one held. The multi-token-prediction module (``num_nextn_predict_
+    layers``, ``mtp_hybrid_override_pattern``) is a draft head beside the
+    language model and is left out: its tensors are dropped on import."""
+    pattern = hf_config.hybrid_override_pattern
+    unknown = set(pattern) - set(_NEMOTRON_H_KINDS)
+    held = int(getattr(hf_config, "n_routed_experts", 0) or 0)
+    if unknown or len(pattern) != hf_config.num_hidden_layers \
+            or (held and "-" in pattern):
+        raise ValueError(
+            "nemotron_h: hybrid_override_pattern names every layer by one "
+            f"of {sorted(_NEMOTRON_H_KINDS)} (`-` in a model without routed "
+            f"experts only); got {pattern!r} for num_hidden_layers="
+            f"{hf_config.num_hidden_layers}")
+    nh, p = hf_config.mamba_num_heads, hf_config.mamba_head_dim
+    if any(getattr(hf_config, k, False) for k in (
+            "attention_bias", "mamba_proj_bias", "mlp_bias", "use_bias")) \
+            or nh * p != int(getattr(hf_config, "expand", 2)) \
+            * hf_config.hidden_size \
+            or getattr(hf_config, "mamba_hidden_act", "silu") != "silu" \
+            or getattr(hf_config, "moe_shared_expert_overlap", False) \
+            or int(getattr(hf_config, "n_group", 1) or 1) != 1 \
+            or int(getattr(hf_config, "topk_group", 1) or 1) != 1 \
+            or getattr(hf_config, "sliding_window", None):
+        raise NotImplementedError(
+            "nemotron_h: no bias but the convolution's, an inner width of "
+            "expand x hidden_size, SiLU in the mixer, one routing group and "
+            "no window are what is written")
+    router = int(getattr(hf_config, "router_experts", held))
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size, hidden_size=hf_config.hidden_size,
+        num_layers=len(pattern), num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        attn_head_dim=int(getattr(hf_config, "head_dim", None)
+                          or hf_config.hidden_size
+                          // hf_config.num_attention_heads),
+        ffn_hidden_size=hf_config.intermediate_size,
+        max_seq_len=hf_config.max_position_embeddings,
+        pos_emb="none", norm="rmsnorm",
+        activation=getattr(hf_config, "mlp_hidden_act", "relu2"),
+        use_bias=False,
+        tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
+        norm_eps=float(getattr(hf_config, "layer_norm_epsilon", None)
+                       or hf_config.norm_eps), dtype="float32",
+        layer_kinds=tuple(_NEMOTRON_H_KINDS[c] for c in pattern),
+        mamba2_heads=nh, mamba2_head_dim=p,
+        mamba2_groups=hf_config.n_groups,
+        mamba2_state=hf_config.ssm_state_size,
+        mamba2_conv=hf_config.conv_kernel,
+        mamba2_chunk=int(getattr(hf_config, "chunk_size", 128)),
+        n_experts=held, moe_top_k=int(getattr(
+            hf_config, "num_experts_per_tok", 2)),
+        moe_ffn_size=getattr(hf_config, "moe_intermediate_size", None),
+        moe_shared_size=int(getattr(hf_config, "n_shared_experts", 0) or 0)
+        * int(getattr(hf_config, "moe_shared_expert_intermediate_size", 0)
+              or 0),
+        moe_latent_size=int(getattr(hf_config, "moe_latent_size", 0) or 0),
+        moe_score_func="sigmoid",
+        moe_route_norm=bool(getattr(hf_config, "norm_topk_prob", True)),
+        moe_route_scale=float(getattr(hf_config, "routed_scaling_factor",
+                                      1.0)),
+        moe_gate_bias=bool(held), moe_dispatch="ragged",
+        moe_router_experts=router if router != held else 0,
+        moe_first_expert=int(getattr(hf_config, "first_expert", 0)))
+
+
+#: a ``mamba2`` layer's leaves as the family's modelling code names them
+#: under ``mixer.``, and whether the tensor is a matrix ``[out, in]``
+_MAMBA2_TENSORS = {
+    "w_in": ("in_proj.weight", True), "wo": ("out_proj.weight", True),
+    "conv_b": ("conv1d.bias", False), "dt_bias": ("dt_bias", False),
+    "a_log": ("A_log", False), "skip_scale": ("D", False),
+    "gate_norm": ("norm.weight", False)}
+
+
+def params_from_nemotron_h(sd: Dict[str, Any], cfg: TransformerConfig
+                           ) -> PyTree:
+    """The family's tensor names (ASSUMED from its published modelling
+    code; no checkpoint is here to confirm them): ``backbone.embeddings``,
+    ``backbone.layers.<i>.norm`` and ``.mixer``, ``backbone.norm_f``,
+    ``lm_head``. In an ``M`` layer ``mixer.in_proj`` (``[z | xBC | dt]``),
+    ``conv1d.weight`` ``[channels, 1, taps]`` (depthwise, the last tap on
+    the row itself) and ``.bias``, ``dt_bias``, ``A_log``, ``D``, ``norm``,
+    ``out_proj``; in a ``*`` layer ``{q,k,v,o}_proj``; in an ``E`` layer
+    ``gate.weight`` with ``gate.e_score_correction_bias``,
+    ``experts.<e>.{up,down}_proj``, ``shared_experts.{up,down}_proj`` and
+    the latent's ``fc1_latent_proj`` (down) / ``fc2_latent_proj`` (up).
+    ``mtp.*`` (the draft head) is dropped. Leaves are stacked by what a
+    layer holds (``TransformerConfig.one_sublayer``)."""
+    pre = "backbone." if any(k.startswith("backbone.") for k in sd) else ""
+    lyr = pre + "layers.{}."
+    mix = lyr + "mixer."
+    of = {kind: [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+          for kind in ("mamba2", "full", "ffn")}
+    blocks: Dict[str, Any] = {"ln1": {"scale": _stack(
+        sd, lyr + "norm.weight", range(cfg.num_layers))}}
+    if of["mamba2"]:
+        blocks["mamba2"] = {
+            ours: _stack(sd, mix + theirs, of["mamba2"], transpose=matrix)
+            for ours, (theirs, matrix) in _MAMBA2_TENSORS.items()}
+        # [channels, 1, taps] -> [taps, channels]
+        blocks["mamba2"]["conv_w"] = np.stack([
+            _np(sd[(mix + "conv1d.weight").format(i)])[:, 0].T
+            for i in of["mamba2"]])
+    if of["full"]:
+        blocks["attn"] = {
+            f"w{x}": _stack(sd, mix + f"{x}_proj.weight", of["full"],
+                            transpose=True) for x in "qkvo"}
+    if of["ffn"] and cfg.n_experts:
+        E, first = cfg.n_experts, cfg.moe_first_expert
+        ffn = {"gate_w": _stack(sd, mix + "gate.weight", of["ffn"],
+                                transpose=True),
+               "gate_bias": _stack(
+                   sd, mix + "gate.e_score_correction_bias", of["ffn"])}
+        for ours, theirs in (("w_up", "up_proj"), ("w_down", "down_proj")):
+            ffn[ours] = np.stack([np.stack([
+                _np(sd[(mix + f"experts.{e}.{theirs}.weight").format(i)]).T
+                for e in range(first, first + E)]) for i in of["ffn"]])
+            if cfg.moe_shared_size:
+                ffn["s" + ours] = _stack(
+                    sd, mix + f"shared_experts.{theirs}.weight", of["ffn"],
+                    transpose=True)
+        if cfg.moe_latent_size:
+            ffn["latent_down"] = _stack(
+                sd, mix + "fc1_latent_proj.weight", of["ffn"], transpose=True)
+            ffn["latent_up"] = _stack(
+                sd, mix + "fc2_latent_proj.weight", of["ffn"], transpose=True)
+        blocks["ffn"] = ffn
+    elif of["ffn"]:
+        blocks["ffn"] = {
+            ours: _stack(sd, mix + f"{theirs}.weight", of["ffn"],
+                         transpose=True)
+            for ours, theirs in (("w_up", "up_proj"), ("w_down", "down_proj"))}
+    params = {"tok_emb": _np(sd[pre + "embeddings.weight"]),
+              "blocks": blocks,
+              "final_norm": {"scale": _np(sd[pre + "norm_f.weight"])}}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _np(sd["lm_head.weight"]).T
+    return params
+
+
 _ARCH_TABLE = {
     "afmoe": (config_from_afmoe, params_from_afmoe),
     "KeyeVL2": (config_from_keye_vl2, params_from_keye_vl2),
     "kimi_linear": (config_from_kimi_linear, params_from_kimi_linear),
     "lfm2_moe": (config_from_lfm2_moe, params_from_lfm2_moe),
     "mellum": (config_from_mellum, params_from_mellum),
+    "nemotron_h": (config_from_nemotron_h, params_from_nemotron_h),
     "phi4flash": (config_from_phi4flash, params_from_phi4flash),
     "gpt2": (config_from_gpt2, params_from_gpt2),
     "llama": (config_from_llama, params_from_llama),

@@ -22,7 +22,12 @@ among standard attention blocks (``TransformerConfig.standard_blocks``; the
 :func:`kda_inputs` / :func:`delta_rule` / :func:`kda_output`, the mixer of
 the ``kda`` layers (Kimi Delta Attention, the ``kimi_linear`` family): a
 gated delta rule whose state a sequence is one ``[keys, values]`` matrix a
-head in float32 and the last inputs of three short convolutions.
+head in float32 and the last inputs of three short convolutions; and
+:func:`mamba2_inputs` / :func:`ssd` / :func:`mamba2_output`, the mixer of
+the ``mamba2`` layers (Mamba-2's state-space duality, the ``nemotron_h``
+family): one decay a head, ``B`` and ``C`` shared by a group's heads, a
+``[channels, state]`` matrix a head in float32 and the last inputs of one
+convolution.
 
 Rows are a flat batch ``[T, ...]`` in which a sequence's rows are
 consecutive and in order (a SplitFuse tick; a dense ``[B, S]`` batch
@@ -94,6 +99,17 @@ def mixer_specs(cfg: Any, kind: str) -> Dict[str, Tuple[tuple, tuple, str]]:
             specs[f"w{x}"] = ((h, w), ("embed", "heads"), "std")
             specs[f"conv_{x}"] = ((c, w), (None, "heads"), "conv")
         return specs
+    if kind == "mamba2":
+        nh, p, c = cfg.mamba2_heads, cfg.mamba2_head_dim, cfg.mamba2_conv
+        di, bc = nh * p, 2 * cfg.mamba2_groups * cfg.mamba2_state
+        return {"w_in": ((h, 2 * di + bc + nh), ("embed", "mlp"), "std"),
+                "conv_w": ((c, di + bc), (None, "mlp"), "conv"),
+                "conv_b": ((di + bc,), ("mlp",), "zeros"),
+                "dt_bias": ((nh,), (None,), "dt_bias"),
+                "a_log": ((nh,), (None,), "a_log_heads"),
+                "skip_scale": ((nh,), (None,), "ones"),
+                "gate_norm": ((di,), ("mlp",), "ones"),
+                "wo": ((di, h), ("mlp", "embed"), "out")}
     if kind not in ATTENTION_KINDS:
         raise ValueError(f"unknown layer kind {kind!r}; one of {KINDS}")
     d = cfg.head_dim
@@ -359,6 +375,22 @@ def kda_recurrence(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     return o, s
 
 
+def _runs_of_one(runs: Runs, slot: jax.Array, most: int):
+    """The rows of a tick a one-row form takes: the runs of ONE row of real
+    sequences (``slot`` > 0), the first ``most`` of them. Returns (which
+    rows are real [T], which the form takes [T], those rows' indices
+    gathered [min(T, most)] with the index past the end for the places no
+    row takes (a scatter drops them), the same clipped for a gather, and
+    which places a row took)."""
+    Tn = slot.shape[0]
+    real = slot > 0
+    alone = runs.start & runs.last & real
+    nth = jnp.cumsum(alone) - 1
+    step_row = alone & (nth < most)
+    rows = jnp.nonzero(step_row, size=min(Tn, most), fill_value=Tn)[0]
+    return real, step_row, rows, jnp.clip(rows, 0, Tn - 1), rows < Tn
+
+
 def delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                b: jax.Array, runs: Runs, state: jax.Array, slot: jax.Array,
                use_kernel: bool = False) -> Tuple[jax.Array, jax.Array]:
@@ -377,17 +409,7 @@ def delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     D] float32, state)."""
     from deepspeed_tpu.ops.pallas import kda as K
 
-    Tn = q.shape[0]
-    real = slot > 0
-    alone = runs.start & runs.last & real
-    nth = jnp.cumsum(alone) - 1
-    step_row = alone & (nth < KDA_STEP_ROWS)
-    R = min(Tn, KDA_STEP_ROWS)
-    # the rows of the one-row form, gathered (the index past the end for
-    # the places no row takes: they are dropped)
-    rows = jnp.nonzero(step_row, size=R, fill_value=Tn)[0]
-    at = jnp.clip(rows, 0, Tn - 1)
-    took = rows < Tn
+    real, step_row, rows, at, took = _runs_of_one(runs, slot, KDA_STEP_ROWS)
     step = K.kda_step if use_kernel else K.kda_step_reference
     o_step, state = step(
         q[at], k[at], v[at], jnp.exp(g[at]), b[at], state,
@@ -395,6 +417,130 @@ def delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     chunk = K.kda_chunk if use_kernel else K.kda_chunk_reference
     o, state = chunk(q, k, v, g, b, runs, real & ~step_row, state, slot)
     return o.at[rows].set(o_step, mode="drop"), state
+
+
+# --------------------------------------------------------------------------- #
+# Mamba-2: state-space duality
+# --------------------------------------------------------------------------- #
+
+#: rows of a tick the one-row form of the recurrence takes (:func:`ssd`)
+SSD_STEP_ROWS = 256
+
+
+def mamba2_state_shapes(cfg: Any) -> Tuple[tuple, tuple]:
+    """What a sequence keeps in a ``mamba2`` layer: (the recurrence's
+    matrices, ``[channels, state]`` a head in float32, as the store lays
+    them out (``ops.pallas.ssd.store_shape``: the state values down a
+    tile's rows, two heads of 64 channels along its lanes); the last inputs
+    of the convolution ``[taps - 1, heads x channels + 2 x groups x
+    state]``, x | B | C)."""
+    from deepspeed_tpu.ops.pallas.ssd import store_shape
+
+    nh, p = cfg.mamba2_heads, cfg.mamba2_head_dim
+    return store_shape(nh, cfg.mamba2_groups, p, cfg.mamba2_state), (
+        cfg.mamba2_conv - 1,
+        nh * p + 2 * cfg.mamba2_groups * cfg.mamba2_state)
+
+
+def mamba2_inputs(h: jax.Array, lp: Dict[str, Any], cfg: Any, runs: Runs,
+                  conv0: Tuple[jax.Array, ...]):
+    """A ``mamba2`` layer's normed rows h [T, H] up to the recurrence.
+    ``[z | xBC | dt] = h W_in``; ``xBC = silu(conv(xBC) + b)``, depthwise
+    and causal over the row's run (conv0: the taps-1 inputs before each
+    row's run, oldest first, each [T, inner + 2 G N]; zeroed for a run at
+    position 0); ``[x | B | C] = xBC``; ``delta = softplus(dt + dt_bias)``
+    a head (no clamp: the family's ``time_step_min`` / ``_max`` / ``_floor``
+    shape the bias's start only); the log-decay a head ``g = -exp(A_log)
+    delta`` (``a = exp(g)`` in (0, 1)). Returns ((x [T, nh, P], delta
+    [T, nh], g [T, nh], B, C [T, G, N]) float32, z [T, inner] in h's type,
+    the convolution's inputs up to and including each row)."""
+    dt_, f32 = h.dtype, jnp.float32
+    Tn, nh, p = h.shape[0], cfg.mamba2_heads, cfg.mamba2_head_dim
+    G, N = cfg.mamba2_groups, cfg.mamba2_state
+    di = nh * p
+    # computed ONCE: its three readers (the gate last of all) are far apart,
+    # and XLA otherwise clones the whole product for each of them (13
+    # `.remat` copies of bf16[2048, 18560] in a five-layer chunk tick, 1.7
+    # ms each on the v5e: PERF.md, PR 53)
+    zxd = lax.optimization_barrier(h @ lp["w_in"].astype(dt_))
+    z, xbc, dt = zxd[:, :di], zxd[:, di:2 * di + 2 * G * N], \
+        zxd[:, 2 * di + 2 * G * N:]
+    conv, conv_new = _segmented_conv(xbc, lp["conv_w"], runs, conv0)
+    xbc = jax.nn.silu(conv + lp["conv_b"].astype(f32))
+    x = xbc[:, :di].reshape(Tn, nh, p)
+    B = xbc[:, di:di + G * N].reshape(Tn, G, N)
+    C = xbc[:, di + G * N:].reshape(Tn, G, N)
+    delta = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    g = -jnp.exp(lp["a_log"].astype(f32)) * delta
+    return (x, delta, g, B, C), z, conv_new
+
+
+def mamba2_output(y: jax.Array, x: jax.Array, z: jax.Array,
+                  lp: Dict[str, Any], cfg: Any) -> jax.Array:
+    """From the recurrence's read-out y [T, nh, P] (float32) to the mixer's
+    output before ``wo`` [T, inner]: the skip ``+ D_h x``, the gate BEFORE
+    the norm, ``y silu(z)``, then RMSNorm over each GROUP's ``inner / G``
+    channels with one gain a channel."""
+    f32 = jnp.float32
+    Tn, G = y.shape[0], cfg.mamba2_groups
+    y = y + lp["skip_scale"].astype(f32)[None, :, None] * x
+    y = y.reshape(Tn, -1) * jax.nn.silu(z.astype(f32))
+    yg = y.reshape(Tn, G, -1)
+    yg = yg * lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1, keepdims=True)
+                        + cfg.norm_eps)
+    return (yg.reshape(Tn, -1) * lp["gate_norm"].astype(f32)).astype(z.dtype)
+
+
+def ssd_recurrence(x: jax.Array, delta: jax.Array, g: jax.Array,
+                   B: jax.Array, C: jax.Array, runs: Runs, s0: jax.Array
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence one row after another, the arbiter of the two forms
+    below: a head ``S = exp(g) S + delta x B^T``; ``y = S C`` (``B``, ``C``
+    its group's), a run's first row taking ``s0[t]`` [nh, P, N] for the
+    state before it. Returns (y [T, nh, P], the state after every row
+    [T, nh, P, N]): for tests and small sizes only."""
+    rep = x.shape[1] // B.shape[1]
+
+    def step(s, row):
+        x_, d_, g_, b_, c_, start, s0_ = row
+        b_, c_ = jnp.repeat(b_, rep, axis=0), jnp.repeat(c_, rep, axis=0)
+        s = jnp.exp(g_)[:, None, None] * jnp.where(start, s0_, s) \
+            + (d_[:, None] * x_)[..., None] * b_[:, None, :]
+        return s, (jnp.einsum("hpn,hn->hp", s, c_), s)
+
+    _, (y, s) = lax.scan(step, jnp.zeros_like(s0[0]),
+                         (x, delta, g, B, C, runs.start, s0))
+    return y, s
+
+
+def ssd(x: jax.Array, delta: jax.Array, g: jax.Array, B: jax.Array,
+        C: jax.Array, runs: Runs, state: jax.Array, slot: jax.Array,
+        chunk: int = 128, use_kernel: bool = False
+        ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence over a flat batch of rows in runs. ``state`` [rows of
+    state, *``ops.pallas.ssd.store_shape``] float32 holds a matrix a head a
+    sequence; row t's
+    sequence is ``slot[t]`` (0: a pad row, which reads and writes nothing).
+    A run reads its sequence's state at its first row (zero where the run
+    starts at position 0, whatever is stored) and writes it after its last.
+
+    Runs of ONE row (decode rows), the first ``SSD_STEP_ROWS`` of them,
+    take the one-row form (``ops.pallas.ssd.ssd_step``: one read and one
+    write of the state, in place; a Mosaic kernel where ``use_kernel``);
+    every other run the chunked form (``ops.pallas.ssd.ssd_chunk``: chunks
+    of ``chunk`` rows, matrix products within a chunk, the state carried
+    across). Returns (y [T, nh, P] float32, state)."""
+    from deepspeed_tpu.ops.pallas import ssd as K
+
+    real, step_row, rows, at, took = _runs_of_one(runs, slot, SSD_STEP_ROWS)
+    step = K.ssd_step if use_kernel else K.ssd_step_reference
+    with jax.named_scope("ssd_step"):
+        y_step, state = step(
+            x[at], delta[at], jnp.exp(g[at]), B[at], C[at], state,
+            jnp.where(took, slot[at], 0), runs.fresh[at] & took)
+    y, state = K.ssd_chunk(x, delta, g, B, C, runs, real & ~step_row, state,
+                           slot, chunk)
+    return y.at[rows].set(y_step, mode="drop"), state
 
 
 def gmu(h: jax.Array, lp: Dict[str, Any], memory: jax.Array) -> jax.Array:

@@ -248,26 +248,40 @@ def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
     rope = cfg.pos_emb == "rope"
     pack = 2 if of_kinds and cfg.head_dim == 64 and cfg.kv_heads % 2 == 0 \
         else 1
+    # two or three KV heads as wide as the lanes: heads first, so that the
+    # block's second-minor dimension is its positions. A block ``[bs, 2,
+    # 128]`` has a second-minor dimension of 2, which a tile of 8 (16 for
+    # bfloat16) rows pads four (eight) times over in whatever Mosaic's
+    # copies slice (a head narrower than the lanes is padded whatever the
+    # order, and one head is no dimension)
+    heads_first = of_kinds and 1 < cfg.kv_heads // pack < 4 \
+        and pack * cfg.head_dim % 128 == 0
 
     def block(bs):
-        return (bs, cfg.kv_heads // pack, pack * cfg.head_dim)
+        heads = cfg.kv_heads // pack
+        return (heads, bs, pack * cfg.head_dim) if heads_first \
+            else (bs, heads, pack * cfg.head_dim)
+
+    def kv(cls, names, **kw):
+        return tuple(Store(s, cls, block, **kw,
+                           **({"positions": -2} if heads_first else {}))
+                     for s in names)
 
     def grouped(stores, window, name, scope, rotates):
         return Attend(stores, name if of_kinds else "paged_attention",
                       window, pack * cfg.head_dim, own_dtype=of_kinds,
                       by_slot=of_kinds, scope=scope if of_kinds else None,
                       rope=cfg.rope_dim if rope and rotates else 0,
-                      pack=pack)
+                      pack=pack, heads_first=heads_first)
 
     table = {
         "window": CacheKind(
-            n("window"), "attn",
-            tuple(Store(s, RING, block, holds="ring") for s in ("wk", "wv")),
+            n("window"), "attn", kv(RING, ("wk", "wv"), holds="ring"),
             grouped(("wk", "wv"), cfg.attn_window, "swa_attention", "swa",
                     True), _grouped_mixer),
         "full": CacheKind(
             n("full") if kinds else 0 if cfg.mla else cfg.num_layers, "attn",
-            tuple(Store(s, BLOCKS, block) for s in ("k", "v")),
+            kv(BLOCKS, ("k", "v")),
             grouped(("k", "v"), None, "global_attention", "global",
                     cfg.rope_of("full") is not None), _grouped_mixer),
         "latent": CacheKind(
@@ -294,11 +308,22 @@ def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
              Store("kda_conv", CONV, lambda bs: HY.kda_state_shapes(cfg)[1],
                    holds="conv")),
             None, _kda_mixer, span=_kda_span),
+        "mamba2": CacheKind(
+            n("mamba2"), "ssd",
+            (Store("ssd", SLOT, lambda bs: HY.mamba2_state_shapes(cfg)[0],
+                   jnp.float32, holds="ssd"),
+             Store("ssd_conv", CONV,
+                   lambda bs: HY.mamba2_state_shapes(cfg)[1], holds="conv")),
+            None, _mamba2_mixer,
+            span=functools.partial(_ssd_span, cfg.mamba2_chunk)),
+        # a layer that is a feed-forward part alone (a stack of single
+        # sublayers): nothing kept, nothing mixed
+        "ffn": CacheKind(n("ffn"), "mlp", (), None, lambda *_: None),
         # keys and values as a ``full`` layer's and, at the same (block,
         # offset), the indexer's key of every position (``index_row_width``)
         "sparse": CacheKind(
             n("sparse"), "attn",
-            tuple(Store(s, BLOCKS, block) for s in ("k", "v")) + (
+            kv(BLOCKS, ("k", "v")) + (
                 Store("idx", BLOCKS, lambda bs: (bs, index_row_width(cfg)),
                       positions=-2),),
             grouped(("k", "v"), None, "sparse_attention", "sparse", True),
@@ -643,6 +668,7 @@ def _tick_experts(h: jax.Array, lp: Dict[str, jax.Array],
     experts = stack or {k: lp[k] for k in _EXPERT_LEAVES if k in lp}
     shared = {k: lp[k] for k in ("sw_up", "sw_down", "sw_gate",
                                  "shared_gate_w") if k in lp}
+    latent = {k: lp[k] for k in ("latent_down", "latent_up") if k in lp}
     return dropless_moe_ffn(
         h, lp["gate_w"], experts, cfg.activation, cfg.moe_top_k,
         score_func=cfg.moe_score_func, route_norm=cfg.moe_route_norm,
@@ -651,7 +677,7 @@ def _tick_experts(h: jax.Array, lp: Dict[str, jax.Array],
         topk_group=cfg.moe_topk_group, valid=valid,
         layer=layer if stack else None,
         first_expert=cfg.moe_first_expert,
-        route_norm_eps=cfg.moe_route_norm_eps)
+        route_norm_eps=cfg.moe_route_norm_eps, latent=latent or None)
 
 
 class _Tick(NamedTuple):
@@ -1150,10 +1176,22 @@ def _kda_span(decode_rows: int, chunk_starts: List[int], rows: int,
     slot's state in every kda layer."""
     from deepspeed_tpu.ops.pallas.kda import count_pieces
 
+    step, chunk_runs = _two_forms(decode_rows, chunk_starts, rows, bucket,
+                                  HY.KDA_STEP_ROWS)
+    return dict(kda_step_rows=step, kda_chunk_rows=rows - step,
+                kda_chunk_pieces=count_pieces(chunk_runs),
+                kda_state_rows=decode_rows + len(chunk_starts))
+
+
+def _two_forms(decode_rows: int, chunk_starts: List[int], rows: int,
+               bucket: int, most: int) -> Tuple[int, List[Tuple[int, int]]]:
+    """A tick's rows between a recurrence's two forms, by the program's own
+    rule (``hybrid._runs_of_one``): (the rows the one-row form takes: runs
+    of one row, decode rows first, up to ``most``; the (first row, rows) of
+    every run left to the other form)."""
     runs = [(a, b - a) for a, b in zip(chunk_starts,
                                        chunk_starts[1:] + [rows])]
-    step = min(decode_rows + sum(n == 1 for _, n in runs), bucket,
-               HY.KDA_STEP_ROWS)
+    step = min(decode_rows + sum(n == 1 for _, n in runs), bucket, most)
     took = min(decode_rows, step)
     left = step - took                  # for the prompts' runs of one
     chunk_runs = [(r, 1) for r in range(took, decode_rows)]
@@ -1162,9 +1200,46 @@ def _kda_span(decode_rows: int, chunk_starts: List[int], rows: int,
             left -= 1
         else:
             chunk_runs.append((a, n))
-    return dict(kda_step_rows=step, kda_chunk_rows=rows - step,
-                kda_chunk_pieces=count_pieces(chunk_runs),
-                kda_state_rows=decode_rows + len(chunk_starts))
+    return step, chunk_runs
+
+
+def _mamba2_mixer(cfg, tick, pool, entry, kind):
+    """Mamba-2: the convolution's inputs as a ``conv`` layer's and the
+    recurrence's matrices inside ``hybrid.ssd`` (a run's are read at its
+    first row and written after its last, in place)."""
+    read, write = tick.conv
+    slot, S1 = tick.slot, tick.S1
+
+    def mixer(h, lp, flat, li, nth, acts):
+        (x, *rest), z, conv = HY.mamba2_inputs(
+            h, lp, cfg, tick.runs,
+            read(flat["ssd_conv"], nth, cfg.mamba2_conv - 1))
+        # a pad row's sequence is none: row 0 of the store
+        y, state = HY.ssd(
+            x, *rest, tick.runs, flat["ssd"],
+            jnp.where(slot > 0, nth * S1 + slot, 0),
+            chunk=cfg.mamba2_chunk, use_kernel=tick.kernels)
+        return HY.mamba2_output(y, x, z, lp, cfg), {
+            **flat, "ssd": state,
+            "ssd_conv": write(flat["ssd_conv"], nth, conv)}, acts
+
+    return mixer
+
+
+def _ssd_span(chunk: int, decode_rows: int, chunk_starts: List[int],
+              rows: int, bucket: int, *_) -> Dict[str, int]:
+    """The recurrence's two forms by the program's own rule
+    (``hybrid.ssd``): runs of one row, up to the one-row form's count, and
+    the rows of every other run; the chunked form's pieces
+    (``ops.pallas.ssd.count_pieces``); and the rows that close a run, each
+    of which writes its slot's state in every mamba2 layer."""
+    from deepspeed_tpu.ops.pallas.ssd import count_pieces
+
+    step, chunk_runs = _two_forms(decode_rows, chunk_starts, rows, bucket,
+                                  HY.SSD_STEP_ROWS)
+    return dict(ssd_step_rows=step, ssd_chunk_rows=rows - step,
+                ssd_chunk_pieces=count_pieces(chunk_runs, chunk),
+                ssd_state_rows=decode_rows + len(chunk_starts))
 
 
 def _mamba_mixer(cfg, tick, pool, entry, kind):
@@ -1278,22 +1353,32 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
         where the block has them. ``experts``: (the whole stack's
         matrices, the layer's index among them)."""
         lp = dequant_params(lp, dt)       # weight-only quant: per-layer dequant
-        with jax.named_scope(kinds[kind].scope):
-            h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
-            mixed, flat, acts = mixers[kind](h, lp, flat, li, nth, acts)
-            out = mixed @ lp["wo"].astype(dt)
-            if seg.use_bias:
-                out = out + lp["bo"].astype(dt)
-            if seg.post_norms:
-                out = T._norm(out, lp["ln1_post"], seg.norm, seg.norm_eps)
-            resid = x + out
+        # a stack of single sublayers (``seg.one_sublayer``): a layer is
+        # its mixer alone, or (kind ``ffn``) the part below alone on the
+        # layer's one norm
+        resid, h = x, None
+        if kind != "ffn":
+            with jax.named_scope(kinds[kind].scope):
+                h = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
+                mixed, flat, acts = mixers[kind](h, lp, flat, li, nth, acts)
+                out = mixed @ lp["wo"].astype(dt)
+                if seg.use_bias:
+                    out = out + lp["bo"].astype(dt)
+                if seg.post_norms:
+                    out = T._norm(out, lp["ln1_post"], seg.norm,
+                                  seg.norm_eps)
+                resid = x + out
+            if seg.one_sublayer:
+                return resid, flat, acts, None
         # ``mlp`` is a dense FFN's scope; an expert layer's operations
         # carry ``router`` / ``experts`` / ``shared_experts``
         with contextlib.nullcontext() if seg.n_experts \
                 else jax.named_scope("mlp"):
             # the parallel residual norms the block's input (or shares
             # ``ln1``'s output), the sequential one what the mixer left
-            if not seg.parallel_block:
+            if kind == "ffn":
+                h2 = T._norm(x, lp["ln1"], seg.norm, seg.norm_eps)
+            elif not seg.parallel_block:
                 h2 = T._norm(resid, lp["ln2"], seg.norm, seg.norm_eps)
             elif seg.shared_parallel_norm:
                 h2 = h
@@ -1342,10 +1427,17 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
                     # (a kind's only layer is its 0th)
                     nth = 0 if kinds[kind].layers == 1 else nth0[kind] \
                         + step * per[kind] + period[:i].count(kind)
+                    # the layer's index among the experts' stack: its
+                    # own in the segment, or among the ``ffn`` layers
                     x, flat, acts, rows_e = block(
                         seg, kind, x, layer_of(lps, period, i), flat, li,
-                        nth, acts, (stack, li - first))
+                        nth, acts,
+                        (stack, nth if seg.one_sublayer else li - first))
                     n_rows.append(rows_e)
+                if seg.one_sublayer:    # the ``ffn`` layers' alone
+                    n_rows = [r for r in n_rows if r is not None]
+                    return (x, flat, step + 1, acts), \
+                        jnp.stack(n_rows) if n_rows else None
                 # (a period of one layer has nothing to stack)
                 return (x, flat, step + 1, acts), None \
                     if not seg.n_experts else n_rows[0] \
@@ -1377,9 +1469,12 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
         # a slice is a copy of a layer's experts before each matmul);
         # quantised leaves ({"q", "scale", ...}) are dequantised a layer at
         # a time and stay in
-        stack = {k: v for k, v in params[key].items() if seg.n_experts
+        held = params[key]["ffn"] if seg.one_sublayer else params[key]
+        stack = {k: v for k, v in held.items() if seg.n_experts
                  and k in _EXPERT_LEAVES and hasattr(v, "ndim")}
         xs = {k: v for k, v in params[key].items() if k not in stack}
+        if seg.one_sublayer:
+            xs["ffn"] = {k: v for k, v in held.items() if k not in stack}
         layers, taken = stack_kinds(cfg, seg), {}
         for at, _, n in T.kind_runs(layers):
             taken[at], steps = steps, steps + n
@@ -1389,7 +1484,8 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
         first += len(layers)
         if seg.n_experts:
             # [steps, period, E] (or [steps, E]) a run -> [expert layers, E]
-            n_rows = [r.reshape((-1, r.shape[-1])) for r in n_rows]
+            n_rows = [r.reshape((-1, r.shape[-1])) for r in n_rows
+                      if r is not None]
             stats["expert_rows"] = n_rows[0] if len(n_rows) == 1 \
                 else jnp.concatenate(n_rows)
     x, flat = carry[:2]

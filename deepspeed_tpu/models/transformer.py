@@ -107,7 +107,7 @@ class TransformerConfig:
     max_seq_len: int = 1024
     pos_emb: str = "learned"            # learned | rope | alibi | none
     norm: str = "layernorm"             # layernorm | rmsnorm
-    activation: str = "gelu"            # gelu | swiglu | relu
+    activation: str = "gelu"            # gelu | swiglu | relu | relu2
     use_bias: bool = True
     qkv_bias: bool = False              # bias on q/k/v only (Qwen2-style)
     parallel_block: bool = False        # attn + FFN in parallel (Falcon/NeoX/Phi)
@@ -256,6 +256,30 @@ class TransformerConfig:
     sparse_topk: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
+    # ``mamba2`` layers among ``layer_kinds`` of the standard block (the
+    # ``nemotron_h`` family): the mixer is Mamba-2's state-space duality
+    # (``models/hybrid.mamba2_inputs`` .. ``mamba2_output``): ``mamba2_heads``
+    # heads of ``mamba2_head_dim`` channels under ONE decay a head, ``B`` and
+    # ``C`` of ``mamba2_state`` values shared by the heads of each of
+    # ``mamba2_groups`` groups, behind one convolution of ``mamba2_conv``
+    # taps; a sequence's state is a ``[channels, state]`` matrix a head in
+    # float32 and the convolution's last inputs. ``mamba2_chunk``: rows of
+    # a chunk of the chunked form (``ops/pallas/ssd.py``).
+    # Beside them ``ffn`` layers: in a stack that names one, EVERY layer is
+    # one norm and one sublayer, ``x += f(ln1 x)`` (:attr:`one_sublayer`):
+    # a mixer alone, or (``ffn``) the feed-forward part or the experts alone
+    mamba2_heads: int = 0
+    mamba2_head_dim: int = 0
+    mamba2_groups: int = 1
+    mamba2_state: int = 0
+    mamba2_conv: int = 4
+    mamba2_chunk: int = 128
+    # the routed experts take a LATENT of the row, ``moe_latent_size`` wide
+    # (leaves ``latent_down [H, l]``, ``latent_up [l, H]``: the experts'
+    # matrices are ``[E, l, F]`` / ``[E, F, l]``), their weighted sum goes
+    # back up through ``latent_up``; the router and the shared expert take
+    # the row itself. 0: the experts take the row
+    moe_latent_size: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -329,7 +353,8 @@ class TransformerConfig:
 
     @property
     def has_ln2(self) -> bool:
-        return not (self.parallel_block and self.shared_parallel_norm)
+        return not (self.parallel_block and self.shared_parallel_norm) \
+            and not self.one_sublayer
 
     @property
     def router_experts(self) -> int:
@@ -356,7 +381,23 @@ class TransformerConfig:
         (``models/hybrid.py``, ``params[key][kind]``)."""
         return bool(self.layer_kinds) and not self.ssm_inner \
             and set(self.layer_kinds) <= {"window", "full", "conv", "kda",
-                                          "latent", "sparse"}
+                                          "latent", "sparse", "mamba2",
+                                          "ffn"}
+
+    @property
+    def one_sublayer(self) -> bool:
+        """Every layer of the stack is ONE norm and ONE sublayer, ``x +=
+        f(ln1 x)``: a mixer alone (``mamba2``, ``full``, ...) or, kind
+        ``ffn``, the feed-forward part or the experts alone (the
+        ``nemotron_h`` family). A layer holds no leaves for the half it
+        lacks: the mixers' leaves are stacked by mixer and the FFNs' over
+        the ``ffn`` layers (``blocks["ffn"]``), ``ln1`` over all."""
+        return "ffn" in self.layer_kinds
+
+    @property
+    def expert_in(self) -> int:
+        """Width of the rows the routed experts take."""
+        return self.moe_latent_size or self.hidden_size
 
     @property
     def mixer_layers(self) -> Dict[str, int]:
@@ -369,6 +410,12 @@ class TransformerConfig:
             return {}
         own["attn"] = self.num_layers - sum(own.values())
         return {m: own[m] for m in MIXERS if own[m]}
+
+    @property
+    def ffn_layers(self) -> int:
+        """Layers that hold a feed-forward part (or experts)."""
+        return self.layer_kinds.count("ffn") if self.one_sublayer \
+            else self.num_layers
 
     @property
     def segments(self) -> Tuple[Tuple[str, "TransformerConfig"], ...]:
@@ -424,6 +471,35 @@ class TransformerConfig:
             i += n
         return tuple(out)
 
+    def _sublayer_params(self, active: bool = False) -> int:
+        """:meth:`num_params` of a stack of single sublayers (every norm
+        an RMSNorm's one gain, no bias but a convolution's); ``active``:
+        the parameters a token meets (``moe_top_k`` of the routed experts
+        of a layer)."""
+        from deepspeed_tpu.models.hybrid import mixer_specs
+
+        h, kinds = self.hidden_size, self.layer_kinds
+        qdim, kv = self.num_heads * self.head_dim, \
+            self.kv_heads * self.head_dim
+        mats = 3 if self.activation == "swiglu" else 2
+        if self.n_experts:
+            routed = self.moe_top_k if active else self.n_experts
+            ffn = routed * mats * self.expert_in * self.moe_ffn \
+                + h * self.router_experts \
+                + (self.router_experts if self.moe_gate_bias else 0) \
+                + mats * h * self.moe_shared_size \
+                + (2 * h * self.moe_latent_size)
+        else:
+            ffn = mats * h * self.ffn_size
+        per = {"ffn": ffn, "mamba2": sum(
+            math.prod(shape) for shape, _, _ in
+            mixer_specs(self, "mamba2").values()) if self.mamba2_heads else 0}
+        attn = 2 * h * qdim + 2 * h * kv + (
+            2 * self.head_dim if self.qk_norm else 0)
+        layers = sum(h + per.get(kind, attn) for kind in kinds)
+        return layers + h + self.vocab_size * h * (
+            1 if self.tie_embeddings else 2)
+
     def num_params(self) -> int:
         if self.layer_kinds and not self.standard_blocks:
             from deepspeed_tpu.models.hybrid import mixer_specs
@@ -441,6 +517,8 @@ class TransformerConfig:
                         mixer_specs(self, kind),
                         is_leaf=lambda x: isinstance(x, tuple)))
             return total
+        if self.one_sublayer:
+            return self._sublayer_params()
         if self.first_dense_layers:
             shared = dataclasses.replace(self, first_dense_layers=0,
                                          num_layers=0,
@@ -514,13 +592,20 @@ class TransformerConfig:
 #: they are ``blocks["attn"]``, stacked over the attention layers alone
 _ATTN_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm", "wq_a",
                 "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wkv_b")
-#: the sub-trees of a segment's blocks that are stacked by mixer
-MIXERS = ("attn", "conv", "kda")
+#: the feed-forward part's leaves: in a stack of single sublayers they are
+#: ``blocks["ffn"]``, stacked over the ``ffn`` layers alone
+_FFN_LEAVES = ("gate_w", "gate_bias", "w_up", "w_down", "w_gate", "sw_up",
+               "sw_down", "sw_gate", "shared_gate_w", "latent_down",
+               "latent_up")
+#: the sub-trees of a segment's blocks that are stacked by mixer (the last
+#: is no mixer: the feed-forward parts of a stack of single sublayers)
+MIXERS = ("attn", "conv", "kda", "mamba2", "ffn")
 
 
 def mixer_of(kind: str) -> str:
     """The mixer a layer of ``kind`` computes: whose leaves it reads in a
-    stack whose mixers' leaves are stacked apart."""
+    stack whose mixers' leaves are stacked apart (``ffn``: the layer is a
+    feed-forward part alone, ``TransformerConfig.one_sublayer``)."""
     return kind if kind in MIXERS[1:] else "attn"
 
 
@@ -553,6 +638,23 @@ def _check_kinds_of_blocks(cfg: TransformerConfig) -> None:
             "sequential blocks: biases, an output gate on attention, "
             "post-norms and the parallel residual are not written beside "
             "them")
+    if cfg.one_sublayer and (cfg.first_dense_layers or cfg.mla
+                             or kinds - {"ffn", "mamba2", "full", "window"}):
+        raise NotImplementedError(
+            "a stack that names `ffn` layers is one of single sublayers (a "
+            "mixer OR a feed-forward part a layer): `mamba2`, `full` and "
+            "`window` mixers, no leading dense segment, no latent attention "
+            f"(got kinds {sorted(kinds)}, first_dense_layers="
+            f"{cfg.first_dense_layers}, mla={cfg.mla})")
+    if "mamba2" in kinds and not (
+            cfg.mamba2_heads and cfg.mamba2_head_dim and cfg.mamba2_state
+            and cfg.mamba2_conv >= 2
+            and cfg.mamba2_heads % cfg.mamba2_groups == 0):
+        raise ValueError(
+            "mamba2 layers need mamba2_heads (a multiple of mamba2_groups), "
+            "mamba2_head_dim, mamba2_state and mamba2_conv >= 2 (got "
+            f"{cfg.mamba2_heads}, {cfg.mamba2_groups}, {cfg.mamba2_head_dim}, "
+            f"{cfg.mamba2_state}, {cfg.mamba2_conv})")
     if "conv" in kinds and cfg.conv_taps < 2:
         raise NotImplementedError(
             f"conv layers need conv_taps >= 2 (got {cfg.conv_taps})")
@@ -568,6 +670,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     """fp32 master parameters. Output projections scaled by 1/sqrt(2L) (GPT-2)."""
     if cfg.layer_kinds and not cfg.standard_blocks:
         return _init_kinds(cfg, rng)
+    if cfg.one_sublayer:
+        _check_kinds_of_blocks(cfg)
     if cfg.first_dense_layers:
         (dkey, dcfg), (_, rest) = cfg.segments
         params = init_params(rest, rng)
@@ -642,11 +746,14 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
             "idx_k_norm": {"scale": jnp.ones((L, di), jnp.float32),
                            "bias": jnp.zeros((L, di), jnp.float32)}})
     E = cfg.n_experts
+    # a stack of single sublayers holds a feed-forward part in its ``ffn``
+    # layers alone
+    Lf = cfg.ffn_layers
     if E > 0:
         # MoE FFN: per-expert weights (no biases), router gate per layer
         fe = cfg.moe_ffn
         R = cfg.router_experts
-        gate = dense(keys[10], (L, h, R), cfg.moe_router_init_std or std)
+        gate = dense(keys[10], (Lf, h, R), cfg.moe_router_init_std or std)
         if R > E:
             # a SHARE drawn from scratch is one of EQUAL shares: the columns
             # of the experts held, repeated over the router's width. Equal
@@ -659,27 +766,33 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
             # 53 % of the pairs (PERF.md, PR 49). Training unties them.
             gate = jnp.tile(gate[..., :E], (1, 1, -(-R // E)))[..., :R]
         block["gate_w"] = gate
-        block["w_up"] = dense(keys[4], (L, E, h, fe), std)
-        block["w_down"] = dense(keys[5], (L, E, fe, h), out_std)
+        he = cfg.expert_in
+        block["w_up"] = dense(keys[4], (Lf, E, he, fe), std)
+        block["w_down"] = dense(keys[5], (Lf, E, fe, he), out_std)
         if cfg.activation == "swiglu":
-            block["w_gate"] = dense(keys[6], (L, E, h, fe), std)
+            block["w_gate"] = dense(keys[6], (Lf, E, he, fe), std)
+        if cfg.moe_latent_size:
+            block["latent_down"] = dense(jax.random.fold_in(rng, 1021),
+                                         (Lf, h, he), std)
+            block["latent_up"] = dense(jax.random.fold_in(rng, 1022),
+                                       (Lf, he, h), out_std)
         fs = cfg.moe_shared_size
         if fs > 0:
             # always-on shared expert (Qwen2-MoE/DeepSeek)
-            block["sw_up"] = dense(keys[11], (L, h, fs), std)
-            block["sw_down"] = dense(keys[12], (L, fs, h), out_std)
+            block["sw_up"] = dense(keys[11], (Lf, h, fs), std)
+            block["sw_down"] = dense(keys[12], (Lf, fs, h), out_std)
             if cfg.activation == "swiglu":
-                block["sw_gate"] = dense(keys[13], (L, h, fs), std)
+                block["sw_gate"] = dense(keys[13], (Lf, h, fs), std)
             if cfg.moe_shared_gate:
-                block["shared_gate_w"] = dense(keys[14], (L, h, 1), std)
+                block["shared_gate_w"] = dense(keys[14], (Lf, h, 1), std)
         if cfg.moe_gate_bias:
-            block["gate_bias"] = jnp.zeros((L, cfg.router_experts),
+            block["gate_bias"] = jnp.zeros((Lf, cfg.router_experts),
                                            jnp.float32)
     else:
-        block["w_up"] = dense(keys[4], (L, h, f), std)
-        block["w_down"] = dense(keys[5], (L, f, h), out_std)
+        block["w_up"] = dense(keys[4], (Lf, h, f), std)
+        block["w_down"] = dense(keys[5], (Lf, f, h), out_std)
         if cfg.activation == "swiglu":
-            block["w_gate"] = dense(keys[6], (L, h, f), std)
+            block["w_gate"] = dense(keys[6], (Lf, h, f), std)
     if cfg.attn_bias_enabled:
         block["bq"] = jnp.zeros((L, qdim), jnp.float32)
         block["bk"] = jnp.zeros((L, kvdim), jnp.float32)
@@ -696,7 +809,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
         attn = {k: block.pop(k) for k in _ATTN_LEAVES if k in block}
         if La:
             block["attn"] = attn
-        for m, kind in enumerate(k for k in MIXERS[1:] if k in mixers):
+        if cfg.one_sublayer:
+            block["ffn"] = {k: block.pop(k) for k in _FFN_LEAVES
+                            if k in block}
+        for m, kind in enumerate(k for k in MIXERS[1:-1] if k in mixers):
             block[kind] = {
                 name: init_leaf(how, (mixers[kind],) + shape,
                                 jax.random.fold_in(rng, 17 + 32 * m + i),
@@ -790,6 +906,9 @@ def param_logical_axes(cfg: TransformerConfig) -> PyTree:
                 block["shared_gate_w"] = lyr + ("embed", None)
         if cfg.moe_gate_bias:
             block["gate_bias"] = lyr + (None,)
+        if cfg.moe_latent_size:
+            block["latent_down"] = lyr + ("embed", None)
+            block["latent_up"] = lyr + (None, "embed")
     else:
         block["w_up"] = lyr + ("embed", "mlp")
         block["w_down"] = lyr + ("mlp", "embed")
@@ -810,7 +929,10 @@ def param_logical_axes(cfg: TransformerConfig) -> PyTree:
         attn = {k: block.pop(k) for k in _ATTN_LEAVES if k in block}
         if "attn" in cfg.mixer_layers:
             block["attn"] = attn
-        for kind in (k for k in MIXERS[1:] if k in cfg.mixer_layers):
+        if cfg.one_sublayer:
+            block["ffn"] = {k: block.pop(k) for k in _FFN_LEAVES
+                            if k in block}
+        for kind in (k for k in MIXERS[1:-1] if k in cfg.mixer_layers):
             block[kind] = {name: lyr + axes for name, (_, axes, _) in
                            mixer_specs(cfg, kind).items()}
     axes = {
@@ -1321,8 +1443,15 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
 
     # the scopes a device trace sorts a block's operations by
     # (``attn`` / ``mlp``, under the engine's ``loss_and_grads``)
-    with jax.named_scope(kind if kind in MIXERS[1:] else "attn"):
+    with jax.named_scope({"mamba2": "ssd", "ffn": "mlp"}.get(
+            kind, kind if kind in MIXERS[1:] else "attn")):
         h = _aq(_norm(x, lp["ln1"], cfg.norm, cfg.norm_eps))
+    if kind == "ffn":
+        # a stack of single sublayers: the feed-forward part alone
+        with contextlib.nullcontext() if cfg.n_experts \
+                else jax.named_scope("mlp"):
+            down, aux, meter = _ffn_metered(h, lp, cfg)
+        return x + down, aux, meter
     if cfg.mla and kind in (None, "latent"):
         with jax.named_scope("attn"):
             # no rotary where the model has none (``pos_emb`` "none": the
@@ -1434,10 +1563,29 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         return (HY.kda_output(o, hr, lp, cfg)
                 @ lp["wo"].astype(dt)).reshape(B, S, H)
 
+    @jax.named_scope("ssd")
+    def _mamba2_from_norm(h):
+        from deepspeed_tpu.models import hybrid as HY
+
+        owner = jnp.repeat(jnp.arange(B, dtype=jnp.int32), S)
+        runs = HY.runs_of(owner, jnp.tile(jnp.arange(S, dtype=jnp.int32), B))
+        hr = h.reshape(B * S, H)
+        matrix, (kept, channels) = HY.mamba2_state_shapes(cfg)
+        (xs, *rest), z, _ = HY.mamba2_inputs(
+            hr, lp, cfg, runs, (jnp.zeros((B * S, channels), dt),) * kept)
+        # a row of state a sequence (row 0 is the pad rows')
+        y, _ = HY.ssd(xs, *rest, runs,
+                      jnp.zeros((B + 1,) + matrix, jnp.float32), owner + 1,
+                      chunk=cfg.mamba2_chunk)
+        return (HY.mamba2_output(y, xs, z, lp, cfg)
+                @ lp["wo"].astype(dt)).reshape(B, S, H)
+
     if kind == "conv":
         attn_out = _conv_from_norm(h)
     elif kind == "kda":
         attn_out = _kda_from_norm(h)
+    elif kind == "mamba2":
+        attn_out = _mamba2_from_norm(h)
     elif cfg.remat == "attn_block":
         # structural remat: bwd recomputes ONLY norm1 → attention → wo
         # (~37% of layer FLOPs at 4h² vs FFN's 8h²); every FFN intermediate
@@ -1449,6 +1597,8 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
     else:
         attn_out = _attn_from_norm(h)
 
+    if cfg.one_sublayer:
+        return x + attn_out, jnp.float32(0.0), None
     if cfg.parallel_block:
         with jax.named_scope("mlp"):
             h2 = h if cfg.shared_parallel_norm else \
@@ -1506,7 +1656,9 @@ def _ffn_metered(h: jax.Array, lp: Dict[str, jax.Array],
             gate_bias=lp.get("gate_bias"), n_group=cfg.moe_n_group,
             topk_group=cfg.moe_topk_group, dispatch=cfg.moe_dispatch,
             route_norm_eps=cfg.moe_route_norm_eps,
-            first_expert=cfg.moe_first_expert, with_meter=True)
+            first_expert=cfg.moe_first_expert, with_meter=True,
+            latent={k_: lp[k_] for k_ in ("latent_down", "latent_up")
+                    if k_ in lp} or None)
         down = down.reshape(h.shape)
     else:
         up = h @ lp["w_up"].astype(dt)
@@ -1517,6 +1669,8 @@ def _ffn_metered(h: jax.Array, lp: Dict[str, jax.Array],
             act = jax.nn.silu(gate) * up
         elif cfg.activation == "relu":
             act = jax.nn.relu(up)
+        elif cfg.activation == "relu2":
+            act = jnp.square(jax.nn.relu(up))
         else:
             act = jax.nn.gelu(up, approximate=True)
         act = _ckpt_name(act, "ffn_act")
@@ -1850,9 +2004,11 @@ def _forward_blocks_of_kinds(params: PyTree, tokens: jax.Array,
                     x, (a, meter) = layer(x, period_layer(lps, period, i))
                     aux = aux + a
                     meters.append(meter)
-                # a period's layers all meter themselves or none does
-                return x, (aux, None if meters[0] is None
-                           else jnp.stack(meters))
+                # the layers of a period that meter themselves (all or
+                # none of them; of a stack of single sublayers its ``ffn``
+                # layers)
+                meters = [m for m in meters if m is not None]
+                return x, (aux, jnp.stack(meters) if meters else None)
 
             return body
 

@@ -120,21 +120,21 @@ def _dense_ffn(xt: jax.Array, w_up: jax.Array, w_down: jax.Array,
     """Plain FFN on flat tokens [T,H] (the shared-expert path)."""
     dt = xt.dtype
     up = xt @ w_up.astype(dt)
-    if w_gate is not None:
-        up = jax.nn.silu(xt @ w_gate.astype(dt)) * up
-    elif activation == "gelu":
-        up = jax.nn.gelu(up, approximate=True)
-    else:
-        up = jax.nn.relu(up)
-    return up @ w_down.astype(dt)
+    gate = None if w_gate is None else xt @ w_gate.astype(dt)
+    return _expert_act(up, gate, activation) @ w_down.astype(dt)
 
 
 def _expert_act(up: jax.Array, gate: Optional[jax.Array], activation: str
                 ) -> jax.Array:
+    """The file's ONE form of an expert's activation, for the routed and
+    the shared experts, serving and training (its derivative is autodiff's:
+    ``relu2``'s is ``2 relu(up)``)."""
     if gate is not None:
         return jax.nn.silu(gate) * up
     if activation == "gelu":
         return jax.nn.gelu(up, approximate=True)
+    if activation == "relu2":
+        return jnp.square(jax.nn.relu(up))
     return jax.nn.relu(up)
 
 
@@ -1165,13 +1165,17 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
                    gate_bias: Optional[jax.Array], *, activation: str, k: int,
                    score_func: str, route_norm: bool, n_group: int,
                    topk_group: int, route_norm_eps: float = 0.0,
-                   first_expert: int = 0
+                   first_expert: int = 0,
+                   router_x: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """Dropless routed-expert computation. Returns (y [B,S,H], aux, the
     call's :func:`held_meter` or None: a share's rows are counted where the
     call is one local program, not under a token-sharded mesh).
     ``first_expert``: where ``experts`` holds fewer experts than ``gate_w``
     has columns, the first of the contiguous ones held (:func:`moe_ffn`).
+    ``router_x`` [B, S, .]: the rows the router scores, where they are not
+    the rows ``x`` the experts take (a layer whose experts take a latent of
+    the row, :func:`moe_ffn`'s ``latent``); one local program only.
 
     Three lowerings by mesh shape: single-shard sort+ragged_dot; per-shard
     sort inside ``shard_map`` when only token axes are sharded; and the
@@ -1184,6 +1188,11 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
     mesh = maybe_mesh()
 
     kind, plan = ragged_mesh_plan(mesh, B, S, E)
+    if kind == "shard" and router_x is not None:
+        raise NotImplementedError(
+            "experts that take a latent of the row (moe_latent_size) under "
+            "a mesh that shards tokens or experts: the router's rows and "
+            "the experts' are sharded as one array there")
     if kind != "shard":
         # 'local': nothing sharded (a pipe-only mesh never shards tokens or
         # experts). 'indivisible' (e.g. direct small-batch calls under a
@@ -1192,9 +1201,11 @@ def _ragged_routed(x: jax.Array, gate_w: jax.Array,
         # inputs are actually sharded.
         xt = x.reshape(-1, H)
         with jax.named_scope("router"):
-            gate = _gate_indices(xt, gate_w, gate_bias, k, score_func,
-                                 route_norm, n_group, topk_group,
-                                 route_norm_eps)
+            gate = _gate_indices(
+                xt if router_x is None
+                else router_x.reshape(-1, router_x.shape[-1]), gate_w,
+                gate_bias, k, score_func, route_norm, n_group, topk_group,
+                route_norm_eps)
         meter = None
         with jax.named_scope("experts"):
             if experts["w_up"].shape[0] < E:
@@ -1403,7 +1414,8 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
             gate_bias: Optional[jax.Array] = None,
             n_group: int = 1, topk_group: int = 1,
             dispatch: str = "auto", route_norm_eps: float = 0.0,
-            first_expert: int = 0, with_meter: bool = False):
+            first_expert: int = 0, with_meter: bool = False,
+            latent: Optional[Dict[str, jax.Array]] = None):
     """Mixture-of-experts FFN.
 
     x: [B, S, H]; gate_w: [H, E]; experts: w_up [E, H, F], w_down [E, F, H],
@@ -1429,6 +1441,12 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
     (:func:`_held_routed`: always the dropless form, a row a pair), the
     others add nothing, forward or backward; the auxiliary loss is over the router's whole width, and gradients
     reach ``gate_w`` through the weights of the pairs here and through it.
+
+    ``latent`` (``latent_down [H, l]``, ``latent_up [l, H]``): the routed
+    experts take ``x W_down`` (their matrices are ``[E, l, F]`` / ``[E, F,
+    l]``) and their weighted, scaled sum goes back up through ``W_up``;
+    the router and the shared expert take the row itself. Always the
+    dropless form.
     """
     B, S, H = x.shape
     dt = x.dtype
@@ -1436,16 +1454,20 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
     xt = x.reshape(T, H)
 
     held = experts["w_up"].shape[0] < gate_w.shape[1]
-    mode = "ragged" if held else resolve_dispatch(
+    mode = "ragged" if held or latent else resolve_dispatch(
         dispatch, rng, noise_std, B, S, gate_w.shape[1])
     meter = None
     if mode == "ragged":
+        rows = x
+        if latent:
+            with jax.named_scope("latent_proj"):
+                rows = x @ latent["latent_down"].astype(dt)
         y, aux, meter = _ragged_routed(
-            x, gate_w, experts, gate_bias, activation=activation, k=k,
+            rows, gate_w, experts, gate_bias, activation=activation, k=k,
             score_func=score_func, route_norm=route_norm, n_group=n_group,
             topk_group=topk_group, route_norm_eps=route_norm_eps,
-            first_expert=first_expert)
-        y = y.reshape(T, H)
+            first_expert=first_expert, router_x=x if latent else None)
+        y = y.reshape(T, -1)
     else:
         logits = xt.astype(jnp.float32) @ gate_w.astype(jnp.float32)   # [T, E]
         gate: GateOutput = topk_gating(
@@ -1472,6 +1494,9 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: Dict[str, jax.Array],
         y = jnp.einsum("tec,ech->th", gate.combine.astype(dt), ye)
     if route_scale != 1.0:
         y = y * jnp.asarray(route_scale, dt)
+    if latent:
+        with jax.named_scope("latent_proj"):
+            y = y @ latent["latent_up"].astype(dt)
     if shared:
         y = y + _shared_experts(xt, shared, activation)
     y = y.reshape(B, S, H)
@@ -1501,7 +1526,8 @@ def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
                      n_group: int = 1, topk_group: int = 1,
                      valid: Optional[jax.Array] = None,
                      layer: Optional[jax.Array] = None,
-                     first_expert: int = 0, route_norm_eps: float = 0.0
+                     first_expert: int = 0, route_norm_eps: float = 0.0,
+                     latent: Optional[Dict[str, jax.Array]] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """The serving form of :func:`moe_ffn` on flat local rows ``xt [T, H]``:
     always the dropless sort + grouped matmul, whatever ``moe_dispatch``
@@ -1520,6 +1546,10 @@ def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
     (:func:`held_group_sizes`), and the shared expert runs for every row;
     the rows come back for every expert of the router.
 
+    ``latent``: :func:`moe_ffn`'s (the router scores the row, the routed
+    experts take its latent, their sum goes back up; both projections under
+    the scope ``latent_proj``).
+
     The
     scopes ``router`` / ``experts`` / ``shared_experts`` are what a device
     trace sorts the layer's operations by."""
@@ -1531,16 +1561,23 @@ def dropless_moe_ffn(xt: jax.Array, gate_w: jax.Array,
         if valid is not None:
             picked = picked * valid.astype(jnp.int32)[:, None, None]
         rows = jnp.sum(picked, axis=(0, 1))
+    x_e = xt
+    if latent:
+        with jax.named_scope("latent_proj"):
+            x_e = xt @ latent["latent_down"].astype(xt.dtype)
     with jax.named_scope("experts"):
         if experts["w_up"].shape[-3] < gate_w.shape[1]:
             y, _ = _held_routed(
-                xt, gate.weights, gate.experts, experts, activation,
+                x_e, gate.weights, gate.experts, experts, activation,
                 first_expert, gate_w.shape[1], layer)
         else:
-            y = _ragged_dispatch_local(xt, gate.weights, gate.experts,
+            y = _ragged_dispatch_local(x_e, gate.weights, gate.experts,
                                        experts, activation, layer)
         if route_scale != 1.0:
             y = y * jnp.asarray(route_scale, xt.dtype)
+    if latent:
+        with jax.named_scope("latent_proj"):
+            y = y @ latent["latent_up"].astype(xt.dtype)
     if shared:
         y = y + _shared_experts(xt, shared, activation)
     return y, rows

@@ -270,6 +270,95 @@ def test_kda_step_compiles_for_v5e(one_chip):
     assert stats.temp_size_in_bytes < 64 << 20
 
 
+def test_ssd_step_compiles_for_v5e(one_chip):
+    """The one-row form of the Mamba-2 recurrence at the ``nemotron_h``
+    cell's size: 128 heads of 64 x 128 in 8 groups, five layers x (136 + 1)
+    slots' matrices stored ``[64, 128, 128]`` a slot (the state values down
+    a tile's rows, two heads' channels along its lanes)."""
+    from deepspeed_tpu.ops.pallas.ssd import ssd_step, store_shape
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    assert store_shape(128, 8, 64, 128) == (64, 128, 128)
+    compiled = jax.jit(
+        lambda x, d, a, B, C, state, slots, fresh: ssd_step(
+            x, d, a, B, C, state, slots, fresh, interpret=False),
+        donate_argnums=(5,)
+    ).lower(arg((256, 128, 64), jnp.float32), arg((256, 128), jnp.float32),
+            arg((256, 128), jnp.float32), arg((256, 8, 128), jnp.float32),
+            arg((256, 8, 128), jnp.float32),
+            arg((5 * 137, 64, 128, 128), jnp.float32),
+            arg((256,), jnp.int32), arg((256,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    # one Mosaic call under its own name (``benchmarks/roofline/ssd_step.py``
+    # classifies by it), the store of states aliased in and out: no copy of
+    # its 2.9 GB, nothing beside the rows' vectors held
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%ssd_step" in text
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= 5 * 137 * 64 * 128 * 128 * 4
+    assert stats.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rows", [2048, 128])
+def test_ssd_chunk_compiles_for_v5e(one_chip, rows):
+    """The chunked form (plain XLA today) at the cell's chunk bucket and at
+    one chunk: no Mosaic call, the store carried through the pieces' loop
+    in place, and nothing as large as the store made beside it."""
+    from deepspeed_tpu.models import hybrid as HY
+    from deepspeed_tpu.ops.pallas.ssd import ssd_chunk
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def form(x, d, g, B, C, state, slot, positions):
+        return ssd_chunk(x, d, g, B, C, HY.runs_of(slot, positions),
+                         slot > 0, state, slot, 128)
+
+    store = 5 * 137 * 64 * 128 * 128 * 4
+    compiled = jax.jit(form, donate_argnums=(5,)).lower(
+        arg((rows, 128, 64), jnp.float32), arg((rows, 128), jnp.float32),
+        arg((rows, 128), jnp.float32), arg((rows, 8, 128), jnp.float32),
+        arg((rows, 8, 128), jnp.float32),
+        arg((5 * 137, 64, 128, 128), jnp.float32),
+        arg((rows,), jnp.int32), arg((rows,), jnp.int32)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= store
+    # the sums within the chunks: [rows / 128, 128 heads, 128, 128] float32
+    # three times over, and the rows' own arrays
+    assert stats.temp_size_in_bytes < (1 << 30 if rows == 2048 else 128 << 20)
+
+
+@pytest.mark.parametrize("rows", [2048, 256])
+def test_attention_over_a_two_head_pool_compiles_for_v5e(one_chip, rows):
+    """The ``nemotron_h`` cell's one attention layer: 32 query heads on TWO
+    key-value heads of 128, a block heads first, ``[2, 32, 128]`` (a block
+    ``[32, 2, 128]`` has a second-minor dimension of 2, which a tile pads
+    eight times over: ``paged.cache_kinds``), 12,288 blocks, one table of
+    80 blocks a sequence slot."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = arg((12288, 2, 32, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, t, n, w: paged_attention(
+            q, k, v, t, n, interpret=False, name="global_attention",
+            heads_first=True, mxu_dtype=jnp.bfloat16, row_table=w)
+    ).lower(arg((rows, 32, 128), jnp.bfloat16), pool, pool,
+            arg((137, 80), jnp.int32), arg((rows,), jnp.int32),
+            arg((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%global_attention" in text
+    # the pool as it lies, unpadded: two pools of 12,288 x 2 x 32 x 128 x 2 B
+    stats = compiled.memory_analysis()
+    assert stats.argument_size_in_bytes < 2 * 12288 * 2 * 32 * 128 * 2 * 1.05
+
+
 @pytest.mark.parametrize("rows", [2048, 256])
 def test_kda_chunk_compiles_for_v5e(one_chip, rows):
     """The chunk form at the Kimi cell's two buckets: one Mosaic call under

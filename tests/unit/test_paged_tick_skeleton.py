@@ -272,6 +272,7 @@ CELLS = {
     "serve-lfm2-24b-concurrent-closed": "short-convolution",
     "serve-kimi-linear-48b-rollout-closed": "delta-rule",
     "serve-keye-vl2-30b-longctx-closed": "learned-sparse",
+    "serve-nemotron3-super-120b-agents-closed": "single-sublayers",
 }
 #: what a toy pool is built with: blocks, block size, slots, longest run
 TOY = (64, 8, 3, 16)
@@ -331,6 +332,16 @@ POOLS = {
     "toy:serve-keye-vl2-30b-longctx-closed": {
         "idx": ((6, 64, 8, 128), BF), "k": ((6, 64, 8, 2, 16), BF),
         "v": ((6, 64, 8, 2, 16), BF)},
+    # (PR 53) two key-value heads of 128: a block heads first; a Mamba-2
+    # layer's matrices with the state values down a tile's rows and two
+    # heads' channels along its lanes, and its convolution's inputs
+    "serve-nemotron3-super-120b-agents-closed": {
+        "k": ((1, 12288, 2, 32, 128), BF), "v": ((1, 12288, 2, 32, 128), BF),
+        "ssd": ((5, 137, 64, 128, 128), F32),
+        "ssd_conv": ((2055, 10240), BF)},
+    "toy:serve-nemotron3-super-120b-agents-closed": {
+        "k": ((1, 64, 2, 8, 128), BF), "v": ((1, 64, 2, 8, 128), BF),
+        "ssd": ((5, 4, 8, 128, 16), F32), "ssd_conv": ((60, 2176), BF)},
 }
 #: ``FastGenEngine._pool_bytes``: (block stores, per-slot state stores)
 BYTES = {
@@ -350,6 +361,8 @@ BYTES = {
     "toy:serve-kimi-linear-48b-rollout-closed": (262144, 3256320),
     "serve-keye-vl2-30b-longctx-closed": (7702511616, 0),
     "toy:serve-keye-vl2-30b-longctx-closed": (1179648, 0),
+    "serve-nemotron3-super-120b-agents-closed": (402653184, 2915184640),
+    "toy:serve-nemotron3-super-120b-agents-closed": (524288, 1571840),
 }
 #: ``tick_walks``: (layers, window, cache positions a fetch step) a kind
 #: of kernel call
@@ -373,6 +386,8 @@ WALKS = {
     # toys' blocks of 8 keep one lane width)
     "serve-keye-vl2-30b-longctx-closed": [(6, None, 256)],
     "toy:serve-keye-vl2-30b-longctx-closed": [(6, None, 128)],
+    "serve-nemotron3-super-120b-agents-closed": [(1, None, 256)],
+    "toy:serve-nemotron3-super-120b-agents-closed": [(1, None, 128)],
 }
 
 
@@ -547,8 +562,9 @@ def _traced(case: str, attention_fn=None):
 def test_a_tick_holds_one_wo_product_a_layer_and_one_scan_a_run(case):
     """The lowered tick of every family: the only scans of the skeleton
     are ``T.scan_periods``', one a run of a period of kinds, and a step
-    multiplies by ``wo`` once a layer of its period (``paged.py`` names
-    the leaf once and scans nothing itself)."""
+    multiplies by ``wo`` once a layer of its period that has a mixer (a
+    stack of single sublayers' ``ffn`` layers have none; ``paged.py``
+    names the leaf once and scans nothing itself)."""
     import inspect
 
     closed, cfg, paths = _traced(case)
@@ -562,7 +578,7 @@ def test_a_tick_holds_one_wo_product_a_layer_and_one_scan_a_run(case):
           if getattr(path[-1], "key", None) == "wo"]
     assert wo
     assert _count_products(closed.jaxpr, wo) == sum(
-        len(period) for _, period, _ in runs)
+        sum(kind != "ffn" for kind in period) for _, period, _ in runs)
     source = inspect.getsource(PG)
     assert source.count('lp["wo"]') == 1 and "lax.scan" not in source
 

@@ -21,7 +21,8 @@ PALLAS = os.path.join(os.path.dirname(dst.__file__), "ops", "pallas")
 KERNEL_NAMES = {
     "flash_fwd", "flash_dq", "flash_dkv", "window_flash_fwd",
     "window_flash_dq", "window_flash_dkv", "paged_attention",
-    "latent_paged_attention", "kda_step", "kda_chunk", "index_scores",
+    "latent_paged_attention", "kda_step", "kda_chunk", "ssd_step",
+    "index_scores",
     "sparse_choice", "block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv",
     "evoformer_attention", "fused_adam", "quantize_int8_blocks",
     "dequant_reduce", "rms_norm", "layer_norm", "unwritten_rows"}
@@ -81,7 +82,7 @@ def test_every_pallas_call_is_named():
                     d.value for a, d in zip(node.args.kwonlyargs,
                                             node.args.kw_defaults)
                     if a.arg == "name" and isinstance(d, ast.Constant))
-    assert sites == 17
+    assert sites == 18
     assert literal == KERNEL_NAMES
 
 
@@ -310,6 +311,54 @@ def test_a_tick_with_conv_layers_sorts_the_mixers_time_apart():
     assert not any("/conv/" in s and "/attn/" in s for s in stacks)
     assert any(s.split("/conv/")[-1].startswith("dot_general")
                for s in stacks if "/conv/" in s)
+
+
+def test_a_tick_of_single_sublayers_sorts_its_mixers_and_latent_apart():
+    """The ``nemotron_h`` tick: a Mamba-2 layer under ``ssd`` with the
+    one-row form's call under ``ssd_step`` and the chunked form under
+    ``ssd_chunk``; an expert layer's two latent projections under
+    ``latent_proj`` beside ``router`` / ``experts`` / ``shared_experts``;
+    the attention layer's ``attn/global``: what ``ssd_share_pct``,
+    ``ssd_chunk_roofline`` and ``latent_proj_share_pct`` read."""
+    import types
+
+    from deepspeed_tpu.models.hf_import import config_from_hf
+
+    cfg = config_from_hf(types.SimpleNamespace(
+        model_type="nemotron_h", hidden_size=64, head_dim=128,
+        num_attention_heads=4, num_key_value_heads=2, num_hidden_layers=4,
+        hybrid_override_pattern="ME*E", mamba_num_heads=16, mamba_head_dim=8,
+        n_groups=8, ssm_state_size=128, conv_kernel=4, chunk_size=16,
+        expand=2, intermediate_size=48, moe_intermediate_size=48,
+        moe_latent_size=32, moe_shared_expert_intermediate_size=96,
+        n_shared_experts=1, n_routed_experts=4, router_experts=16,
+        num_experts_per_tok=9, routed_scaling_factor=5.0,
+        mlp_hidden_act="relu2", layer_norm_epsilon=1e-5, vocab_size=128,
+        max_position_embeddings=512))
+    eng = FastGenEngine(cfg, n_blocks=16, block_size=4, max_blocks_per_seq=8,
+                        token_budget=32, state_slots=2, seed=0,
+                        use_pallas_kernel=True)
+    tn, mb = 32, eng.max_blocks_per_seq
+    stacks = _stacks(eng._build_tick(tn, mb).lower(
+        eng.params, eng.pool, eng._pack_tick(
+            np.zeros((tn,), np.int32), np.zeros((tn,), np.int32),
+            np.zeros((tn, mb), np.int32), np.zeros((2,), np.uint32))),
+        "tick")
+    parts = {part for s in stacks for part in s.split("/")}
+    assert {"embed", "ssd", "ssd_step", "ssd_chunk", "attn", "global",
+            "latent_proj", "router", "experts", "shared_experts", "lm_head",
+            "sample"} <= parts
+    _assert_both_heads_are_scoped(stacks)
+    assert any("/ssd/ssd_step/ssd_step" in s for s in stacks)
+    assert any("/ssd/ssd_chunk/" in s for s in stacks)
+    assert any("/attn/global/global_attention" in s for s in stacks)
+    assert any(s.split("/latent_proj/")[-1].startswith("dot_general")
+               for s in stacks if "/latent_proj/" in s)
+    # a mixer's operations are not under another's scope, and a
+    # feed-forward layer's are under neither
+    assert not any("/ssd/" in s and "/attn/" in s for s in stacks)
+    assert not any("/experts/" in s and ("/ssd/" in s or "/attn/" in s)
+                   for s in stacks)
 
 
 def test_a_tick_of_sparse_layers_tells_its_three_parts_apart():

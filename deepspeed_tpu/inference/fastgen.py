@@ -89,8 +89,13 @@ class BlockAllocator:
         if not 0 <= state_slots < n_blocks:
             raise ValueError(f"state_slots={state_slots} of {n_blocks} blocks")
         self.n_blocks, self.state_slots = n_blocks, state_slots
-        self._heads: List[int] = list(range(1, state_slots + 1))
-        self._free: List[int] = list(range(state_slots + 1, n_blocks))
+        # blocks leave at the front and come back at the back: the free
+        # list is as long as the pool, and a list moves all of it to give
+        # up its first entry
+        self._heads = collections.deque(range(1, state_slots + 1))
+        self._free = collections.deque(range(state_slots + 1, n_blocks))
+        # (queue, block, taken) of every block moved since begin(), or None
+        self._journal: Optional[List[tuple]] = None
 
     @property
     def free_blocks(self) -> int:
@@ -112,6 +117,12 @@ class BlockAllocator:
             return len(self._free)
         return 1 + len(self._free) if self._heads else 0
 
+    def _take(self, queue: collections.deque, n: int) -> List[int]:
+        out = [queue.popleft() for _ in range(n)]
+        if self._journal is not None:
+            self._journal.extend((queue, b, True) for b in out)
+        return out
+
     def allocate(self, n: int = 1) -> List[int]:
         """The ``n`` blocks of a NEW sequence."""
         if not self.state_slots or n < 1:
@@ -120,58 +131,224 @@ class BlockAllocator:
             raise RuntimeError(
                 f"no sequence slot free ({self.state_slots} in use)")
         rest = self.grow(n - 1)
-        return [self._heads.pop(0)] + rest
+        return self._take(self._heads, 1) + rest
 
     def grow(self, n: int = 1) -> List[int]:
         """``n`` more blocks for a sequence that has its first."""
         if n > len(self._free):
             raise RuntimeError(
                 f"KV pool exhausted: want {n} blocks, {len(self._free)} free")
-        # the free list is as long as the pool: take from it in place (a
-        # copy of it for every decode row of a tick was most of a wide
-        # tick's scheduling time)
-        out = self._free[:n]
-        del self._free[:n]
-        return out
+        return self._take(self._free, n)
 
     def free(self, blocks: Sequence[int]) -> None:
         for b in blocks:
             if b:
-                (self._heads if b <= self.state_slots
-                 else self._free).append(b)
+                queue = self._heads if b <= self.state_slots else self._free
+                queue.append(b)
+                if self._journal is not None:
+                    self._journal.append((queue, b, False))
+
+    def begin(self) -> None:
+        """Remember every block that moves from here on, for
+        :meth:`rollback`: a tick takes a few blocks off the front and
+        appends a finished sequence's at the back, so what it did is a few
+        entries where a copy of the free list is the whole pool."""
+        self._journal = []
+
+    def commit(self) -> None:
+        self._journal = None
+
+    def rollback(self) -> None:
+        """Both lists as they were at :meth:`begin`, order included."""
+        for queue, b, taken in reversed(self._journal or ()):
+            if taken:
+                queue.appendleft(b)
+            else:
+                queue.pop()
+        self._journal = None
 
     def snapshot(self) -> tuple:
+        """Both lists, in order (a reader for tests: a tick's roll-back is
+        :meth:`begin` / :meth:`rollback`)."""
         return list(self._free), list(self._heads)
 
+
+class _Rows:
+    """The scheduler's state of every sequence, a row a sequence, in arrays
+    the engine owns: a phase of a tick is one pass over them, and a
+    sequence meets per-row Python only in a tick in which something
+    happens to it (``FastGenEngine._step_impl``).
+
+    A sequence holds a row from ``put()`` to ``flush()``
+    (:meth:`acquire` / :meth:`release`); ``_Seq`` reads and writes its row
+    through properties. A released row is the next one handed out, so
+    the rows ever used are ``[:hi]`` and ``hi`` is the most sequences the
+    engine has held at once.
+
+    A field a tick changes is in ``TICK_FIELDS``: ``step()``'s roll-back
+    copies those (``snapshot`` / ``restore``), and a new one that is not
+    listed there is not rolled back."""
+
+    #: what ``step()`` may change between its snapshot and its return
+    TICK_FIELDS = ("pos", "prefilled", "last_tok", "live", "held",
+                   "first_seen", "gen_len", "table")
+
+    def __init__(self, capacity: int, max_blocks: int):
+        self.capacity = capacity
+        self.pos = np.zeros(capacity, np.int32)        # tokens in cache
+        self.prefilled = np.zeros(capacity, np.int32)  # prompt, written
+        self.prompt_len = np.zeros(capacity, np.int32)
+        self.last_tok = np.full(capacity, -1, np.int32)  # -1: none yet
+        self.live = np.zeros(capacity, bool)    # admitted and not done
+        self.held = np.zeros(capacity, np.int32)       # blocks held
+        self.first_seen = np.zeros(capacity, bool)     # TTFT observed
+        self.gen_len = np.zeros(capacity, np.int32)    # len(generated)
+        self.deadline = np.full(capacity, np.inf)      # perf_counter clock
+        self.uid = np.empty(capacity, object)
+        # the block tables, ``table[r, :held[r]]`` a sequence's blocks in
+        # order and zeros (the trash block) behind them
+        self.table = np.zeros((capacity, max_blocks), np.int32)
+        self.seqs: List[Optional["_Seq"]] = [None] * capacity
+        self.free: List[int] = []     # released rows below ``hi``
+        self.hi = 0
+
+    def acquire(self, seq: "_Seq") -> int:
+        if self.free:
+            r = self.free.pop()
+        else:
+            if self.hi == self.capacity:
+                self._widen()
+            r, self.hi = self.hi, self.hi + 1
+        self.seqs[r] = seq
+        self.uid[r] = seq.uid
+        self.prompt_len[r] = len(seq.prompt)
+        self.deadline[r] = np.inf if seq.deadline is None else seq.deadline
+        self.live[r] = True
+        return r
+
+    def release(self, seq: "_Seq") -> None:
+        """Give ``seq``'s row back. The descriptor keeps its last values
+        in a row of its own: a ``decode_stream`` window in flight may
+        still hold it and drain into it, and must not write into the row's
+        next owner."""
+        r, own = seq.row, _Rows(1, self.table.shape[1])
+        for name in ("pos", "prefilled", "prompt_len", "last_tok",
+                     "first_seen", "gen_len"):
+            getattr(own, name)[0] = getattr(self, name)[r]
+        seq._st, seq.row = own, 0
+        for name in self.TICK_FIELDS:
+            getattr(self, name)[r] = 0
+        self.last_tok[r] = -1
+        self.uid[r] = self.seqs[r] = None
+        self.free.append(r)
+
+    def _widen(self) -> None:
+        wider = _Rows(2 * self.capacity, self.table.shape[1])
+        for name, arr in vars(self).items():
+            if isinstance(arr, np.ndarray):
+                getattr(wider, name)[:self.capacity] = arr
+        wider.seqs[:self.capacity] = self.seqs
+        wider.free, wider.hi = self.free, self.hi
+        self.__dict__ = wider.__dict__
+
+    def snapshot(self) -> tuple:
+        n = self.hi
+        return tuple(getattr(self, name)[:n].copy()
+                     for name in self.TICK_FIELDS)
+
     def restore(self, snap: tuple) -> None:
-        self._free, self._heads = list(snap[0]), list(snap[1])
+        """The rows as :meth:`snapshot` saw them, and what of a sequence
+        lives on its descriptor with them: ``done`` and the tokens kept."""
+        for name, was in zip(self.TICK_FIELDS, snap):
+            getattr(self, name)[:len(was)] = was
+        for seq in self.seqs[:len(snap[0])]:
+            if seq is not None:
+                seq.done = not self.live.item(seq.row)
+                del seq.generated[self.gen_len[seq.row]:]
 
 
 class _Seq:
-    """Host-side descriptor (reference ``sequence_descriptor.py``)."""
+    """Host-side descriptor (reference ``sequence_descriptor.py``).
 
-    def __init__(self, uid: int, prompt: List[int], max_blocks: int,
+    What the scheduler changes tick by tick (``pos``, ``prefilled``,
+    ``last_tok``, the blocks and their table, whether the first token was
+    seen) lives in the engine's ``_Rows``, in this sequence's ``row``, and
+    is read here through properties; ``generated`` is a plain list and
+    ``done`` / ``expired`` plain attributes, mirrored by ``_Rows.live``
+    and ``_Rows.gen_len`` (``_finish``, ``_note_token`` and a tick's
+    commit keep both). A new field the scheduler mutates goes into
+    ``_Rows`` and its ``TICK_FIELDS``, or ``step()`` does not roll it
+    back."""
+
+    def __init__(self, uid: int, prompt: List[int], rows: _Rows,
                  deadline_s: Optional[float] = None):
         self.uid = uid
         self.prompt = prompt
-        self.prefilled = 0            # prompt tokens written to cache
-        self.pos = 0                  # total tokens in cache
-        self.blocks: List[int] = []   # block table (grows)
-        self.table = np.zeros((max_blocks,), np.int32)
         self.generated: List[int] = []
-        self.last_tok: Optional[int] = None   # next decode input
         self.done = False
         self.admit_t = time.perf_counter()    # TTFT anchor (telemetry)
-        self.first_tok_seen = False
         # absolute expiry (perf_counter clock); None = no deadline
         self.deadline = (self.admit_t + deadline_s
                          if deadline_s is not None else None)
         self.expired = False
         self.slot_waited = False      # counted in state_slot_waits_total
+        self._st = rows
+        self.row = rows.acquire(self)
+
+    @property
+    def pos(self) -> int:
+        """Total tokens in cache."""
+        return self._st.pos.item(self.row)
+
+    @pos.setter
+    def pos(self, value: int) -> None:
+        self._st.pos[self.row] = value
+
+    @property
+    def prefilled(self) -> int:
+        """Prompt tokens written to cache."""
+        return self._st.prefilled.item(self.row)
+
+    @prefilled.setter
+    def prefilled(self, value: int) -> None:
+        self._st.prefilled[self.row] = value
 
     @property
     def prefill_remaining(self) -> int:
-        return len(self.prompt) - self.prefilled
+        return len(self.prompt) - self._st.prefilled.item(self.row)
+
+    @property
+    def last_tok(self) -> Optional[int]:
+        """The next decode input; None before the first sampled token."""
+        tok = self._st.last_tok.item(self.row)
+        return tok if tok >= 0 else None
+
+    @last_tok.setter
+    def last_tok(self, value: Optional[int]) -> None:
+        self._st.last_tok[self.row] = -1 if value is None else value
+
+    @property
+    def first_tok_seen(self) -> bool:
+        return self._st.first_seen.item(self.row)
+
+    @first_tok_seen.setter
+    def first_tok_seen(self, value: bool) -> None:
+        self._st.first_seen[self.row] = value
+
+    @property
+    def held(self) -> int:
+        """Blocks held."""
+        return self._st.held.item(self.row)
+
+    @property
+    def table(self) -> np.ndarray:
+        """The block table: a view of the engine's row."""
+        return self._st.table[self.row]
+
+    @property
+    def blocks(self) -> List[int]:
+        """The blocks held, in order (a copy: ``table`` is the store)."""
+        return self._st.table[self.row, :self.held].tolist()
 
 
 class FastGenEngine:
@@ -285,7 +462,12 @@ class FastGenEngine:
                                          state_slots=state_slots,
                                          max_run=token_budget)
         self.seqs: Dict[int, _Seq] = {}
+        # the scheduler's state, a row a sequence (``_Rows``), and the
+        # live and finished-but-unflushed sequences in admission order:
+        # their uids, and their rows as an array (None: stale)
+        self._rows = _Rows(256, max_blocks_per_seq)
         self._admit_order: List[int] = []
+        self._order: Optional[np.ndarray] = None
         self._decode_rr = 0
         self._ticks_run = 0     # step() ticks dispatched, for span attributes
         self.engine_no = next(FastGenEngine._engine_numbers)
@@ -481,6 +663,15 @@ class FastGenEngine:
             "a layer)",
             buckets=(0.25, 0.5, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0,
                      128.0, 256.0, 512.0))
+        self._tm_tick_rows = telemetry.counter(
+            "fastgen_tick_rows_total",
+            "sequences a step() tick considered (a decode row, a prompt "
+            "chunk, a row that waited for the pool), by path: python (it "
+            "took per-row Python: grew a block or waited for one, was a "
+            "chunk's, saw its first token, finished) / array (the passes "
+            "over the scheduler's arrays alone)")
+        self._tick_rows_keys = (telemetry.label_key(path="array"),
+                                telemetry.label_key(path="python"))
         self._tm_preempt = telemetry.counter(
             "fastgen_preemptions_total",
             "sequences deferred a tick by KV-pool backpressure")
@@ -626,27 +817,29 @@ class FastGenEngine:
 
     def _tm_sched_gauges(self) -> None:
         """Refresh queue/pool gauges from host scheduler state."""
-        live = [s for s in self.seqs.values() if not s.done]
-        if not live:
+        st = self._rows
+        live, held = st.live[:st.hi], st.held[:st.hi]
+        n_live = int(np.count_nonzero(live))
+        if not n_live:
             self._idle = True       # until the next tick: no one waits
-        waiting = sum(1 for s in live if s.prefill_remaining > 0)
+        waiting = int(np.count_nonzero(
+            live & (st.prefilled[:st.hi] < st.prompt_len[:st.hi])))
         self._tm_queue.set(waiting, state="waiting")
-        self._tm_queue.set(len(live) - waiting, state="running")
-        self._tm_queue_peak.set_max(len(live))
+        self._tm_queue.set(n_live - waiting, state="running")
+        self._tm_queue_peak.set_max(n_live)
         util = self.kv_utilization()
         self._tm_kv.set(util)
         self._tm_kv_peak.set_max(util)
         self._tm_slots.set(self.allocator.slots_in_use)
         self._tm_slots_peak.set_max(self.allocator.slots_in_use)
-        in_use = {"quarter": 0, "half": 0, "full": 0}
+        # a sequence that is done holds no block
         quarter, half = self._mb_tier_bounds()     # as _mb_tier_name's
-        for s in live:
-            n = len(s.blocks)
-            if n:
-                in_use["quarter" if n <= quarter else
-                       "half" if n <= half else "full"] += n
-        for tier, n in in_use.items():
-            self._tm_kv_tier.set(n, tier=tier)
+        in_quarter = int(held[held <= quarter].sum())
+        in_full = int(held[held > half].sum())
+        self._tm_kv_tier.set(in_quarter, tier="quarter")
+        self._tm_kv_tier.set(int(held.sum()) - in_quarter - in_full,
+                             tier="half")
+        self._tm_kv_tier.set(in_full, tier="full")
 
     def _observe_tok_lat(self, per_token_s: float, n: int,
                          now: float) -> None:
@@ -833,7 +1026,7 @@ class FastGenEngine:
         return ledger_for_fastgen(self, n_tokens=n_tokens, fold=fold)[0]
 
     def _blocks_needed(self, seq: _Seq, upto_pos: int) -> int:
-        return max(0, upto_pos // self.block_size + 1 - len(seq.blocks))
+        return max(0, upto_pos // self.block_size + 1 - seq.held)
 
     #: fused-decode scan lengths — a FIXED short ladder so the compile
     #: cache stays a small grid however max_new/EOS shrink the remaining work
@@ -1084,9 +1277,9 @@ class FastGenEngine:
         stale)."""
         grew = False
         for s in live:
-            before = len(s.blocks)
+            before = s.held
             self._ensure_blocks(s, s.pos + n - 1)
-            grew |= len(s.blocks) != before
+            grew |= s.held != before
         mb_need = (max(s.pos for s in live) + n - 1) // self.block_size + 1
         mb = self._mb_tier(mb_need)
         tables = np.zeros((Bt, self.max_blocks_per_seq), np.int32)
@@ -1140,29 +1333,39 @@ class FastGenEngine:
             seen.add(uid)
             batch.append((uid, prompt))
         for uid, prompt in batch:
-            self.seqs[uid] = _Seq(uid, prompt, self.max_blocks_per_seq,
+            self.seqs[uid] = _Seq(uid, prompt, self._rows,
                                   deadline_s=deadline_s)
             self._admit_order.append(uid)
+        self._order = None
         self._tm_sched_gauges()
+
+    def _order_rows(self) -> np.ndarray:
+        """The rows of ``_admit_order``'s sequences, in its order."""
+        if self._order is None:
+            seqs = self.seqs
+            self._order = np.array([seqs[u].row for u in self._admit_order],
+                                   np.intp)
+        return self._order
 
     def _expire_deadlines(self) -> int:
         """Drop live sequences past their deadline (blocks freed, marked
         done+expired) — the scheduler-side half of request cancellation.
         Runs at every dynamic scheduling entry point; a dropped request
         answers ``query()`` with done=True and whatever it generated."""
-        now = time.perf_counter()
-        n = 0
-        for seq in self.seqs.values():
-            if seq.done or seq.deadline is None or now <= seq.deadline:
-                continue
+        st = self._rows
+        late = st.live[:st.hi] & (st.deadline[:st.hi] < time.perf_counter())
+        if not late.any():
+            return 0
+        order = self._order_rows()
+        late = order[late[order]].tolist()        # in admission order
+        for r in late:
+            seq = st.seqs[r]
             state = "waiting" if seq.prefill_remaining > 0 else "running"
             seq.expired = True
             self._finish(seq)
             self._tm_deadline.inc(state=state)
-            n += 1
-        if n:
-            self._tm_sched_gauges()
-        return n
+        self._tm_sched_gauges()
+        return len(late)
 
     def expired(self, uid: int) -> bool:
         """Whether ``uid`` was dropped by deadline expiry. Unknown or
@@ -1202,54 +1405,42 @@ class FastGenEngine:
             return win_sum / win_n
         return self._tok_lat_sum / self._tok_lat_n
 
-    def _snapshot_host(self, seqs) -> tuple:
-        """Snapshot every scheduler-mutated host field of ``seqs`` plus
-        the allocator free list, for step()'s roll-back on a tick
-        failure: a new ``_Seq`` field the scheduler mutates goes here. Already-
-        emitted metric OBSERVATIONS (TTFT, token counters) cannot be
-        unobserved — a tick that fails after sampling may leave a phantom
-        sample; state consistency is the contract here, not metric
-        exactness."""
-        # generated is append-only within a tick (nothing replaces or
-        # shrinks it mid-dispatch), so its snapshot is just the LENGTH —
-        # copying the full history would make every step() O(tokens
-        # generated so far) for a failure path that almost never fires
-        return ({s.uid: (s.prefilled, s.pos, list(s.blocks), s.table.copy(),
-                         len(s.generated), s.last_tok, s.done,
-                         s.first_tok_seen)
-                 for s in seqs},
-                self.allocator.snapshot())
+    def _snapshot_host(self) -> tuple:
+        """What step() needs to undo a failed tick: copies of the rows'
+        arrays a tick may change (``_Rows.TICK_FIELDS``: a new field the
+        scheduler mutates goes there), the rotation's offset, and from
+        here on the allocator's journal of the blocks it moves. A few
+        array copies whatever the rows, where a tuple, a list and a table
+        a sequence grew with them. Already-emitted metric OBSERVATIONS
+        (TTFT, token counters) cannot be unobserved: a tick that fails
+        after sampling may leave a phantom sample; state consistency is
+        the contract here, not metric exactness."""
+        self.allocator.begin()
+        return self._rows.snapshot(), self._decode_rr
 
     def _restore_host(self, snap: tuple) -> None:
-        seq_snap, free = snap
-        for u, st in seq_snap.items():
-            s = self.seqs.get(u)
-            if s is None:
-                continue
-            s.prefilled, s.pos = st[0], st[1]
-            s.blocks, s.table = st[2], st[3]
-            del s.generated[st[4]:]
-            s.last_tok, s.done = st[5], st[6]
-            s.first_tok_seen = st[7]
-        self.allocator.restore(free)
+        # generated is append-only within a tick, so the rows' ``gen_len``
+        # restores it: what a tick kept is cut off again
+        self._rows.restore(snap[0])
+        self._decode_rr = snap[1]
+        self.allocator.rollback()
 
     def _ensure_blocks(self, seq: _Seq, upto_pos: int) -> bool:
         """Grow the sequence's block table to cover ``upto_pos``. Returns
         False (leaving per-seq state untouched) when the pool can't supply
         the blocks — the scheduler then defers that sequence (capacity
         backpressure, reference ``scheduling_utils`` CacheBlock result)."""
-        need = upto_pos // self.block_size + 1
-        grow = need - len(seq.blocks)
+        st, r = seq._st, seq.row
+        held = st.held.item(r)
+        grow = upto_pos // self.block_size + 1 - held
         if grow <= 0:
             return True
-        if grow > self.allocator.available(starting=not seq.blocks):
+        if grow > self.allocator.available(starting=not held):
             return False
         # a sequence's first block comes with its slot (``BlockAllocator``)
-        new = self.allocator.grow(grow) if seq.blocks \
+        st.table[r, held:held + grow] = self.allocator.grow(grow) if held \
             else self.allocator.allocate(grow)
-        for blk in new:
-            seq.table[len(seq.blocks)] = blk
-            seq.blocks.append(blk)
+        st.held[r] = held + grow
         return True
 
     def step(self) -> Dict[int, int]:
@@ -1265,43 +1456,49 @@ class FastGenEngine:
         circuit breaker relies on). A fault inside the dispatched program
         itself may still invalidate the donated KV pool; that is a
         dead-device condition the breaker answers with backoff, not state
-        this rollback can save."""
+        this rollback can save.
+
+        The state a tick changes lives in the engine's ``_Rows`` and the
+        allocator's two lists; the snapshot is the engine's
+        (``_snapshot_host``), taken after the deadlines' sweep (an expiry
+        is not undone) and before ``schedule_tick`` opens."""
         self._assert_stream_drained()
         self._expire_deadlines()
-        live = [self.seqs[u] for u in self._admit_order
-                if u in self.seqs and not self.seqs[u].done]
-        snap = self._snapshot_host(live)
-        rr_snap = self._decode_rr
+        snap = self._snapshot_host()
         try:
-            return self._step_impl(live)
+            out = self._step_impl()
         except BaseException:
             self._restore_host(snap)
-            self._decode_rr = rr_snap
             raise
+        self.allocator.commit()
+        return out
 
-    def _step_impl(self, live: List[_Seq]) -> Dict[int, int]:
+    def _step_impl(self) -> Dict[int, int]:
+        st, bs = self._rows, self.block_size
         # the host-side SplitFuse packing gets its own span so a tick's
         # timeline splits into schedule (host) vs dispatch (device) —
         # the first question about a slow tick is which side it was
         with telemetry.span("schedule_tick") as sched_span:
-            need = sum(1 for s in live
-                       if s.prefill_remaining == 0
-                       and s.last_tok is not None)
-            need += sum(s.prefill_remaining for s in live)
-            Tn = self._bucket(need)
+            # every test of the schedule is a pass over the rows in
+            # admission order; a sequence meets Python below only where
+            # something happens to it (``touched``: what
+            # fastgen_tick_rows_total counts as the python path)
+            order = self._order_rows()
+            live = st.live[order]
+            left = np.where(live, st.prompt_len[order] - st.prefilled[order],
+                            0)              # prompt tokens still to write
+            ready = live & (left == 0) & (st.last_tok[order] >= 0)
+            Tn = self._bucket(int(np.count_nonzero(ready)) + int(left.sum()))
             tokens = np.zeros((Tn,), np.int32)
             positions = np.zeros((Tn,), np.int32)
             tables = np.zeros((Tn, self.max_blocks_per_seq), np.int32)
-            # (row, seq, is_decode): rows whose logits get sampled this tick
-            heads: List[tuple] = []
-            row = 0
+            touched = np.zeros((st.hi,), bool)
             # runs of rows that start from a sequence's stored state; and,
             # of a model with window layers, the cache positions inside
             # the rows' windows (what a window layer must read, a
             # sequence) and those the prompt rows score (a row)
-            state_runs = 0
             W = self.cfg.attn_window
-            window_positions = window_attended = 0
+            window_attended = 0
             # prompt rows in kernel tiles wholly inside one chunk
             shared_rows = 0
             R = self._tile_rows
@@ -1311,46 +1508,58 @@ class FastGenEngine:
             # starting from a rotating offset so tails never starve when
             # live sequences exceed the budget (the reference scheduler's
             # fairness rotation)
-            order = self._admit_order
-            rr = self._decode_rr % max(len(order), 1)
-            for uid in order[rr:] + order[:rr]:
-                seq = self.seqs.get(uid)
-                if seq is None or seq.done or seq.prefill_remaining > 0 \
-                        or seq.last_tok is None:
-                    continue
-                if row >= Tn:
-                    break
-                if not self._ensure_blocks(seq, seq.pos):
-                    self._tm_preempt.inc(phase="decode")
-                    continue   # pool full — this sequence waits a tick
-                tokens[row] = seq.last_tok
-                positions[row] = seq.pos
-                tables[row] = seq.table
-                heads.append((row, seq, True))
-                row += 1
-                state_runs += 1
-                window_positions += min(seq.pos + 1, W)
+            at = np.flatnonzero(ready)
+            cut = np.searchsorted(at, self._decode_rr % max(len(order), 1))
+            dec = order[np.concatenate((at[cut:], at[:cut]))]
+            # the rows whose next position lies past their blocks (one
+            # tick in block_size): those the pool cannot serve wait a tick
+            short = np.flatnonzero(st.pos[dec] // bs >= st.held[dec])
+            waited = 0
+            if short.size:
+                stays = np.ones(dec.shape, bool)
+                for i in short.tolist():
+                    if i - waited >= Tn:
+                        break           # past the budget: not this tick's
+                    r = dec[i]
+                    touched[r] = True
+                    if not self._ensure_blocks(st.seqs[r], st.pos.item(r)):
+                        self._tm_preempt.inc(phase="decode")
+                        stays[i] = False
+                        waited += 1
+                dec = dec[stays]
+            dec = dec[:Tn]
+            row = n_decode_rows = len(dec)
+            tokens[:row] = st.last_tok[dec]
+            positions[:row] = st.pos[dec]
+            tables[:row] = st.table[dec]
+            state_runs = row
+            window_positions = int(np.minimum(positions[:row] + 1, W).sum())
             self._decode_rr += 1
 
             # 2) prefill chunks — FIFO admission, split to fit the
             # remaining budget (Dynamic SplitFuse: long prompts stream
-            # across ticks)
-            for uid in self._admit_order:
-                seq = self.seqs.get(uid)
-                if seq is None or seq.done or seq.prefill_remaining == 0:
-                    continue
+            # across ticks). A loop a chunk: there are few
+            chunks = 0
+            # a prompt's last row and its sequence's row: the first
+            # generated token is sampled there
+            end_rows: List[int] = []
+            end_seqs: List[int] = []
+            for r in order[left > 0].tolist():
                 if row >= Tn:
                     break
-                chunk = min(seq.prefill_remaining, Tn - row)
+                seq, pos, held = st.seqs[r], st.pos.item(r), st.held.item(r)
+                touched[r] = True
+                lo = st.prefilled.item(r)
+                chunk = min(len(seq.prompt) - lo, Tn - row)
                 # capacity backpressure: shrink the chunk to the blocks
                 # the pool can actually supply; zero → the prompt waits
                 # for a flush
-                starting = not seq.blocks
-                fits = (len(seq.blocks) + self.allocator.available(starting)) \
-                    * self.block_size - seq.pos
+                starting = not held
+                fits = (held + self.allocator.available(starting)) * bs - pos
                 chunk = min(chunk, fits)
                 if chunk <= 0:
                     self._tm_preempt.inc(phase="prefill")
+                    waited += 1
                     if starting and self.allocator.state_slots \
                             and not self.allocator.free_slots \
                             and not seq.slot_waited:
@@ -1358,27 +1567,26 @@ class FastGenEngine:
                         seq.slot_waited = True
                         self._tm_slot_waits.inc()
                     continue
-                self._ensure_blocks(seq, seq.pos + chunk - 1)
-                state_runs += seq.pos > 0
+                self._ensure_blocks(seq, pos + chunk - 1)
+                chunks += 1
+                state_runs += pos > 0
                 if W:
-                    window_positions += min(seq.pos + chunk, chunk + W - 1)
+                    window_positions += min(pos + chunk, chunk + W - 1)
                     window_attended += int(np.minimum(
-                        np.arange(seq.pos, seq.pos + chunk) + 1, W).sum())
-                lo = seq.prefilled
+                        np.arange(pos, pos + chunk) + 1, W).sum())
                 tokens[row:row + chunk] = seq.prompt[lo:lo + chunk]
-                positions[row:row + chunk] = np.arange(seq.pos,
-                                                       seq.pos + chunk)
-                tables[row:row + chunk] = seq.table
+                positions[row:row + chunk] = np.arange(pos, pos + chunk)
+                tables[row:row + chunk] = st.table[r]
                 chunk_starts.append(row)
                 if R:
                     shared_rows += R * max(
                         0, (row + chunk) // R - -(-row // R))
                 row += chunk
-                seq.prefilled += chunk
-                seq.pos += chunk
-                if seq.prefill_remaining == 0:
-                    heads.append((row - 1, seq, False))  # first generated
-                    # token of a just-finished prefill
+                st.prefilled[r] = lo + chunk
+                st.pos[r] = pos + chunk
+                if lo + chunk == len(seq.prompt):
+                    end_rows.append(row - 1)
+                    end_seqs.append(r)
 
         if row == 0:
             return {}
@@ -1393,11 +1601,15 @@ class FastGenEngine:
         cold = key not in self._ticks
         if cold:
             self._ticks[key] = self._build_tick(Tn, mb)
-        n_decode_rows = sum(1 for _, _, is_d in heads if is_d)
-        head_rows = [h[0] for h in heads]
+        # the rows whose logits get sampled this tick, and the sequences'
+        # rows they belong to: every decode row, then the prompts' ends
+        n_heads = n_decode_rows + len(end_rows)
+        head_rows = np.arange(n_heads)
+        head_rows[n_decode_rows:] = end_rows
+        heads = np.concatenate((dec, np.array(end_seqs, np.intp)))
         # the tick program's own rule (``_build_tick``)
         S = self._bucket(0)
-        gathered = Tn > S and len(heads) <= S
+        gathered = Tn > S and n_heads <= S
         head_computed = S if gathered else Tn
         # a tick that holds no prompt row is a decode tick, whatever
         # entry point ran it
@@ -1425,7 +1637,7 @@ class FastGenEngine:
                 + row - n_decode_rows,
                 "shared_rows": shared_rows, "bucket": Tn,
                 # rows whose token is read back, rows the head ran for
-                "head_rows": len(heads), "head_computed": head_computed,
+                "head_rows": n_heads, "head_computed": head_computed,
                 "mb_tier": tier}) as tick_span:
             packed = self._pack_tick(
                 tokens, positions, tables[:, :mb],
@@ -1467,6 +1679,10 @@ class FastGenEngine:
                                attn_open_steps=attn_open,
                                attn_step_positions=max(
                                    step for _, _, step in self._walks))
+            # the queue's and the pool's gauges too: they read what the
+            # schedule left and nothing the tokens will change
+            self._tm_occup.set(row / Tn, phase=kind)
+            self._tm_sched_gauges()
             # the wait for the device and for the copy queued behind it
             with telemetry.span("tick_readback") as readback_span:
                 sampled = np.asarray(sampled)
@@ -1537,20 +1753,40 @@ class FastGenEngine:
                 self._tm_attn_steps.inc(attn_open, form="open")
                 self._tm_attn_steps.inc(attn_steps - attn_open,
                                         form="masked")
-            self._tm_occup.set(row / Tn, phase=kind)
-            self._tm_sched_gauges()
 
-            out: Dict[int, int] = {}
-            kept = 0
-            for (r, seq, is_decode), tok in zip(
-                    heads, (sampled[:len(heads)] if gathered
-                            else sampled[head_rows]).tolist()):
-                if is_decode:
-                    seq.pos += 1   # the decode input token entered the cache
-                seq.last_tok = tok
-                kept += self._note_token(seq, tok, count=False)
-                out[seq.uid] = tok
-            self._tm_gen_tok.inc(kept)
+            # what ``_note_token`` does a sequence, for all the heads at
+            # once: a sequence meets Python where it sees its first token
+            # or ends
+            toks = sampled[:n_heads] if gathered else sampled[head_rows]
+            st.pos[dec] += 1        # the decode input token entered the cache
+            st.last_tok[heads] = toks
+            # TTFT anchors on the FIRST sampled token even when it's EOS
+            first = heads[~st.first_seen[heads]]
+            if first.size:
+                now = time.perf_counter()
+                for r in first.tolist():
+                    self._tm_ttft.observe(now - st.seqs[r].admit_t)
+                st.first_seen[first] = touched[first] = True
+            over = st.pos[heads] + 1 >= self.max_len
+            answers = toks.tolist()
+            kept_rows, kept_toks = heads, answers
+            if self.eos_token_id is not None:
+                kept = toks != self.eos_token_id
+                over |= ~kept
+                kept_rows, kept_toks = heads[kept], toks[kept].tolist()
+            seqs = st.seqs
+            for r, tok in zip(kept_rows.tolist(), kept_toks):
+                seqs[r].generated.append(tok)
+            st.gen_len[kept_rows] += 1
+            for r in heads[over].tolist():      # in the heads' order: the
+                touched[r] = True               # free list's order
+                self._finish(seqs[r])
+            out = dict(zip(st.uid[heads].tolist(), answers))
+            self._tm_gen_tok.inc(len(kept_rows))
+            python = int(np.count_nonzero(touched))
+            self._tm_tick_rows.inc_keys(
+                self._tick_rows_keys,
+                (n_decode_rows + chunks + waited - python, python))
             # collections queued since the last tick: in the registry by
             # the time the tick ends; the process's own counters every
             # sixteenth tick (and at a slow one)
@@ -1668,40 +1904,42 @@ class FastGenEngine:
             del self._typical[(kind, Tn, mb)]
 
     def _note_token(self, seq: _Seq, tok: int,
-                    pos: Optional[int] = None, count: bool = True) -> int:
-        """``pos``: the sequence position at the tick that PRODUCED this
-        token — decode_stream drains with ``seq.pos`` already advanced one
-        to two windows ahead, so the max-len cutoff must use the tick-time
-        position, not the optimistic current one. Returns the tokens kept
-        (0 or 1); ``count`` False leaves ``fastgen_generated_tokens_total``
-        to the caller, who adds a tick's tokens at once."""
+                    pos: Optional[int] = None) -> None:
+        """A sampled token of a fused window, folded into its sequence
+        (``step()``'s commit does the same for all of a tick's heads at
+        once). ``pos``: the sequence position at the tick that PRODUCED
+        this token — decode_stream drains with ``seq.pos`` already advanced
+        one to two windows ahead, so the max-len cutoff must use the
+        tick-time position, not the optimistic current one."""
         if seq.done:
-            return 0
+            return
         # TTFT anchors on the FIRST sampled token even when it's EOS —
         # excluding immediate-EOS sequences would bias the distribution
         # toward longer-lived ones
         self._tm_first_token(seq)
         if self.eos_token_id is not None and tok == self.eos_token_id:
             self._finish(seq)
-            return 0
+            return
         seq.generated.append(tok)
-        if count:
-            self._tm_gen_tok.inc()
+        seq._st.gen_len[seq.row] += 1
+        self._tm_gen_tok.inc()
         if (seq.pos if pos is None else pos) + 1 >= self.max_len:
             self._finish(seq)
-        return 1
 
     def _finish(self, seq: _Seq) -> None:
         """Mark done and release KV blocks immediately — a finished sequence
         never decodes again, and holding its blocks until flush() starves
         waiting prompts (livelock if the caller only flushes at the end)."""
+        st, r = seq._st, seq.row
         seq.done = True
-        if seq.blocks:
-            self._tm_evict.inc(len(seq.blocks))
+        st.live[r] = False
+        held = st.held.item(r)
+        if held:
+            self._tm_evict.inc(held)
         self._tm_finished.inc()
-        self.allocator.free(seq.blocks)
-        seq.blocks = []
-        seq.table[:] = 0
+        self.allocator.free(st.table[r, :held].tolist())
+        st.held[r] = 0
+        st.table[r, :held] = 0
 
     def query(self, uid: int):
         d = self.seqs[uid]
@@ -1726,17 +1964,19 @@ class FastGenEngine:
         for uid in uids:
             d = self.seqs.pop(uid, None)
             if d is not None:
-                if d.blocks:
-                    self._tm_evict.inc(len(d.blocks))
-                self.allocator.free(d.blocks)
+                blocks = d.blocks
+                if blocks:
+                    self._tm_evict.inc(len(blocks))
+                self.allocator.free(blocks)
                 # an in-flight decode_stream window may still hold a
-                # reference to this _Seq and drain into it later: clear the
-                # block list (or _finish would double-free into the
-                # allocator) and mark done (so _note_token no-ops)
-                d.blocks = []
+                # reference to this _Seq and drain into it later: it is
+                # done (so _note_token no-ops) and keeps a row of its own
+                # that holds no block (or _finish would double-free into
+                # the allocator)
                 d.done = True
-                if uid in self._admit_order:
-                    self._admit_order.remove(uid)
+                self._rows.release(d)
+                self._admit_order.remove(uid)
+                self._order = None
         self._tm_sched_gauges()
 
     def generate_all(self, uids, prompts, max_new_tokens: int = 32):

@@ -924,3 +924,97 @@ def test_step_tick_queues_the_copy_back_inside_the_dispatch(packed_models):
         assert order[i][1][-2:] == ["decode_tick", "tick_dispatch"]
         assert order[i + 1][1][-2:] == ["decode_tick", "tick_dispatch"]
         assert order[i + 2][1][-2:] == ["decode_tick", "tick_readback"]
+
+
+# --------------------------------------------------------------------- #
+# the scheduler's state in arrays (FG._Rows), a row a sequence
+# --------------------------------------------------------------------- #
+def _rows_of(n_seqs, capacity=2):
+    from deepspeed_tpu.inference.fastgen import _Rows, _Seq
+
+    rows = _Rows(capacity, 4)
+    return rows, [_Seq(u, [7] * (u + 1), rows, deadline_s=None)
+                  for u in range(n_seqs)]
+
+
+@pytest.mark.parametrize("what", ["widen", "reuse", "stale", "restore"])
+def test_rows_hold_a_sequence_from_put_to_flush(what):
+    rows, seqs = _rows_of(5)
+    if what == "widen":
+        # five sequences in a store made for two: the arrays doubled twice
+        # and every descriptor still reads its own row
+        assert rows.capacity == 8 and rows.hi == 5
+        for u, s in enumerate(seqs):
+            s.pos, s.last_tok = 10 + u, 100 + u
+        assert [s.pos for s in seqs] == [10, 11, 12, 13, 14]
+        assert [s.last_tok for s in seqs] == [100, 101, 102, 103, 104]
+        assert rows.prompt_len[:5].tolist() == [1, 2, 3, 4, 5]
+        assert rows.uid[:5].tolist() == [0, 1, 2, 3, 4]
+        assert rows.live[:5].all() and not rows.live[5:].any()
+        assert seqs[2].last_tok == 102 and seqs[0].prefill_remaining == 1
+    elif what == "reuse":
+        # a released row is the next one handed out, blank
+        seqs[1].pos = seqs[1].prefilled = 2
+        seqs[1].last_tok = 9
+        rows.table[seqs[1].row, :2] = (5, 6)
+        rows.held[seqs[1].row] = 2
+        assert seqs[1].blocks == [5, 6] and seqs[1].table.base is rows.table
+        row = seqs[1].row
+        rows.release(seqs[1])
+        new = type(seqs[0])(9, [1, 2, 3], rows)
+        assert new.row == row and rows.hi == 5
+        assert (new.pos, new.prefilled, new.last_tok, new.blocks,
+                new.first_tok_seen, new.prefill_remaining) == \
+            (0, 0, None, [], False, 3)
+        assert not rows.table[row].any() and rows.uid[row] == 9
+    elif what == "stale":
+        # a descriptor that outlives its row (a decode_stream window in
+        # flight) keeps its values and writes to nobody else's
+        seqs[3].pos, seqs[3].last_tok = 21, 55
+        row = seqs[3].row
+        rows.release(seqs[3])
+        new = type(seqs[0])(9, [1], rows)
+        assert new.row == row
+        seqs[3].pos += 4
+        seqs[3].last_tok = 56
+        assert (seqs[3].pos, seqs[3].last_tok, seqs[3].held) == (25, 56, 0)
+        assert (new.pos, new.last_tok) == (0, None)
+    else:
+        # restore(): the arrays, and what lives on the descriptors
+        seqs[0].generated.extend([1, 2])
+        rows.gen_len[seqs[0].row] = 2
+        snap = rows.snapshot()
+        seqs[0].generated.append(3)
+        rows.gen_len[seqs[0].row] = 3
+        seqs[4].done, rows.live[seqs[4].row] = True, False
+        rows.pos[:5] += 7
+        rows.table[2, 0] = 11
+        rows.restore(snap)
+        assert seqs[0].generated == [1, 2] and seqs[4].done is False
+        assert not rows.pos[:5].any() and not rows.table.any()
+        assert rows.live[:5].all()
+
+
+def test_block_allocator_rolls_back_to_its_mark():
+    """What a tick did to the free lists is undone in order: blocks taken
+    go back to the front, blocks freed leave the back, head blocks too."""
+    from deepspeed_tpu.inference.fastgen import BlockAllocator
+
+    alloc = BlockAllocator(12, state_slots=2)
+    first = alloc.allocate(2)              # before the mark: stays
+    assert first == [1, 3]
+    before = alloc.snapshot()
+    alloc.begin()
+    assert alloc.allocate(3) == [2, 4, 5] and alloc.grow(1) == [6]
+    alloc.free(first)
+    alloc.free([4])
+    assert alloc.snapshot() == ([7, 8, 9, 10, 11, 3, 4], [1])
+    alloc.rollback()
+    assert alloc.snapshot() == before == ([4, 5, 6, 7, 8, 9, 10, 11], [2])
+    # outside a mark nothing is remembered
+    alloc.grow(2)
+    assert alloc._journal is None
+    alloc.begin()
+    alloc.grow(1)
+    alloc.commit()
+    assert alloc._journal is None and alloc.free_blocks == 6

@@ -233,10 +233,11 @@ def test_allocator_hands_first_blocks_out_of_the_slots():
     with pytest.raises(RuntimeError, match="slot"):
         a.allocate(1)
     snap = a.snapshot()
+    a.begin()
     a.free(s1)
     assert a.free_slots == 1 and a.allocate(1) == [1]
-    a.restore(snap)
-    assert a.free_slots == 0 and s3 == [3]
+    a.rollback()
+    assert a.free_slots == 0 and s3 == [3] and a.snapshot() == snap
     # without slots the two calls are one free list
     b = BlockAllocator(6)
     assert b.allocate(2) == [1, 2] and b.grow(1) == [3] and b.free_slots == 0

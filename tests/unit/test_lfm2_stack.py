@@ -479,7 +479,7 @@ def test_a_row_that_needs_no_block_leaves_the_free_list_alone(cut):
     del eng.allocator.grow
     before = list(free)
     assert eng._ensure_blocks(seq, 8) and eng.allocator._free is free
-    assert seq.blocks[-1] == before[0] and free == before[1:]
+    assert seq.blocks[-1] == before[0] and list(free) == before[1:]
     # the tokens a tick kept are counted once a tick, not once a row
     from deepspeed_tpu import telemetry
 

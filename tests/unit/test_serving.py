@@ -292,7 +292,7 @@ class TestCircuitBreaker:
         free0 = eng2.allocator.free_blocks
         orig = eng2._step_impl
 
-        def boom(live):
+        def boom():
             raise RuntimeError("device fell over")
 
         eng2._step_impl = boom
@@ -304,6 +304,92 @@ class TestCircuitBreaker:
         eng2._step_impl = orig
         out = eng2.step()             # clean retry proceeds normally
         assert eng2.seqs[2].prefilled > 0 or out
+
+    @staticmethod
+    def _host_state(eng):
+        """Everything a tick may change on the host: the rows' arrays (the
+        table matrix among them), the allocator's lists in order, the
+        rotation, and what lives on the descriptors."""
+        rows = eng._rows
+        return ({name: getattr(rows, name)[:rows.hi].copy()
+                 for name in rows.TICK_FIELDS},
+                eng.allocator.snapshot(), eng._decode_rr,
+                {u: (s.done, list(s.generated), s.pos, s.prefilled,
+                     s.last_tok, s.blocks, s.table.copy().tolist())
+                 for u, s in eng.seqs.items()})
+
+    @staticmethod
+    def _assert_same_state(got, want):
+        for name, arr in want[0].items():
+            np.testing.assert_array_equal(got[0][name], arr, err_msg=name)
+        assert got[1:] == want[1:]
+
+    def _mid_history(self, **kw):
+        """An engine whose next tick holds a decode row that has to grow
+        a block and a chunk that ends in mid-prompt."""
+        eng = _engine(**kw)
+        eng.put([2], [_prompt(15)])
+        eng.step()
+        eng.step()                    # position 16: the second block next
+        eng.put([3], [_prompt(40, seed=1)])
+        assert eng.seqs[2].pos == 16 and eng.seqs[2].held == 1
+        return eng
+
+    def test_a_failed_tick_leaves_every_row_as_it_was(self):
+        """The schedule has grown a decode row's table and advanced a
+        chunk when the program raises: every array row, the table matrix
+        and the allocator are what they were, and the retry is the tick a
+        never-faulted engine runs."""
+        ref = self._mid_history()
+        want = dict(ref.step())
+        assert ref.seqs[2].held == 2 and 0 < ref.seqs[3].prefilled < 40
+
+        eng = self._mid_history()
+        before = self._host_state(eng)
+        good = eng._ticks
+
+        class Boom(dict):
+            def __getitem__(self, key):
+                raise RuntimeError("injected")
+
+        eng._ticks = Boom(good)
+        with pytest.raises(RuntimeError, match="injected"):
+            eng.step()
+        eng._ticks = good
+        self._assert_same_state(self._host_state(eng), before)
+        assert eng.allocator._journal is None
+        assert dict(eng.step()) == want
+        self._assert_same_state(self._host_state(eng),
+                                self._host_state(ref))
+
+    def test_a_tick_that_fails_in_its_commit_is_undone(self, monkeypatch):
+        """The last statement of ``tick_commit`` raises: by then the tick
+        has kept its tokens, a sequence has ended on its end-of-sequence
+        token and its blocks are back on the free list. All of it is
+        undone, the free list's order too."""
+        from deepspeed_tpu.inference import fastgen
+
+        eos = self._mid_history().step()[2]
+        ref, eng = self._mid_history(), self._mid_history()
+        ref.eos_token_id = eng.eos_token_id = eos
+        want = dict(ref.step())
+        assert ref.seqs[2].done and not ref.seqs[2].blocks
+        before = self._host_state(eng)
+        calls = []
+
+        def refresh(**kw):
+            calls.append(kw)
+            if len(calls) == 1:
+                raise KeyboardInterrupt
+
+        monkeypatch.setattr(fastgen.telemetry, "refresh_host_counters",
+                            refresh)
+        with pytest.raises(KeyboardInterrupt):
+            eng.step()
+        self._assert_same_state(self._host_state(eng), before)
+        assert dict(eng.step()) == want
+        self._assert_same_state(self._host_state(eng),
+                                self._host_state(ref))
 
 
 # --------------------------------------------------------------------- #

@@ -429,6 +429,101 @@ def test_tick_account_overhead_guard(tiny_params):
 
 
 # --------------------------------------------------------------------- #
+# a tick's bookkeeping is passes over arrays, not a call a row
+# --------------------------------------------------------------------- #
+#: Python function calls a decode tick of 256 rows may make beyond one of
+#: 16 rows when no row grows a block, sees its first token or finishes
+#: (2 measured; the tree before PR 54 made 2,642 more)
+CALLS_A_TICK_OVER_16_ROWS = 32
+
+
+def _python_calls(fn) -> int:
+    """Frames of Python functions entered under ``fn()``: a count, no
+    clock (calls of C functions, a list's ``append`` among them, are not
+    frames)."""
+    import sys
+
+    n = [0]
+
+    def profile(frame, event, arg):
+        n[0] += event == "call"
+
+    sys.setprofile(profile)
+    try:
+        fn()
+    finally:
+        sys.setprofile(None)
+    return n[0]
+
+
+def _wide_engine(tiny_params, prompts):
+    """256-row ticks over blocks of 32 positions, four to a sequence (the
+    tiny model's 128 positions); the sequences all decoding."""
+    eng = _engine(tiny_params, n_blocks=4 * 256 + 8, block_size=32,
+                  max_blocks_per_seq=4, token_budget=256)
+    eng.put(range(len(prompts)), prompts)
+    while any(s.prefill_remaining for s in eng.seqs.values()):
+        eng.step()
+    return eng
+
+
+def test_a_decode_ticks_python_does_not_grow_with_its_rows(tiny_params):
+    telemetry.reset()
+    rng = np.random.default_rng(0)
+    python_rows = telemetry.counter("fastgen_tick_rows_total")
+    eng, calls = _wide_engine(tiny_params, []), {}
+    for rows in (16, 256):
+        eng.put(range(len(eng.seqs), rows),
+                [_prompt(rng, 3) for _ in range(len(eng.seqs), rows)])
+        while any(s.prefill_remaining for s in eng.seqs.values()):
+            eng.step()
+        eng.step()                       # the decode program is warm
+        before = python_rows.value(path="python")
+        calls[rows] = min(_python_calls(eng.step) for _ in range(3))
+        # no row of these ticks left the arrays
+        assert python_rows.value(path="python") == before
+        assert all(s.pos < 31 for s in eng.seqs.values())
+    assert calls[256] - calls[16] <= CALLS_A_TICK_OVER_16_ROWS, calls
+    telemetry.reset()
+
+
+def test_the_python_path_takes_the_rows_that_grow_a_block_or_finish(
+        tiny_params):
+    """64 ticks of 256 rows: ``fastgen_tick_rows_total{path="python"}`` is
+    the rows that grew a block or finished in each tick, counted here
+    from the arrays before and after it, and nothing else; about one row
+    in 32 a tick."""
+    telemetry.reset()
+    rng = np.random.default_rng(1)
+    # three prompts long enough to run into max_len (128) inside the run;
+    # staggered lengths, so that every tick some rows cross a block's end
+    prompts = [_prompt(rng, 56 + 4 * i) for i in range(3)] \
+        + [_prompt(rng, 3 + i % 13) for i in range(253)]
+    eng = _wide_engine(tiny_params, prompts)
+    rows, st = telemetry.counter("fastgen_tick_rows_total"), eng._rows
+    base = {path: rows.value(path=path) for path in ("array", "python")}
+    want = {"array": 0, "python": 0}
+    grew_total = finished_total = 0
+    for _ in range(64):
+        held, live = st.held[:st.hi].copy(), st.live[:st.hi].copy()
+        out = eng.step()
+        grew = (st.held[:st.hi] > held) & live
+        finished = live & ~st.live[:st.hi]
+        want["python"] += int((grew | finished).sum())
+        want["array"] += len(out) - int((grew | finished).sum())
+        grew_total += int(grew.sum())
+        finished_total += int(finished.sum())
+    assert finished_total == 3 and grew_total > 64
+    got = {path: rows.value(path=path) - base[path] for path in want}
+    assert got == want
+    # one row in block_size a tick, and the three that ended
+    assert got["python"] == grew_total + finished_total
+    assert 0.02 < got["python"] / (got["array"] + got["python"]) < 0.05
+    eng.flush(list(eng.seqs))
+    telemetry.reset()
+
+
+# --------------------------------------------------------------------- #
 # the benchmark's readers
 # --------------------------------------------------------------------- #
 READERS = ("win_ticks_per_s", "win_period_decode_ms", "win_period_mixed_ms",

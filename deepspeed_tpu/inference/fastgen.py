@@ -558,6 +558,13 @@ class FastGenEngine:
         # tick's sampled tokens; 0 for a model without experts
         self._expert_layers = sum(
             c.ffn_layers for _, c in cfg.segments if c.n_experts)
+        # a looped stack (``cfg.loop_passes`` > 1): what its ticks say of
+        # themselves on their span (its rows' exit distribution rides back
+        # with a tick's sampled tokens); nothing for any other model
+        self._loop_attrs = {
+            "loop_passes": cfg.loop_passes,
+            "cache_layers": cfg.loop_passes * cfg.num_layers} \
+            if cfg.loop_passes > 1 else {}
 
     @classmethod
     def _pool_bytes(cls, cfg, n_blocks: int, block_size: int,
@@ -788,6 +795,22 @@ class FastGenEngine:
             "cache positions the sparse layers' attention met for those "
             "rows: a row walks its sequence with the choice as a mask, so "
             "its length, summed over the layers")
+        self._tm_layer_apps = telemetry.counter(
+            "fastgen_layer_applications_total",
+            "layer applications of step() ticks of a looped stack "
+            "(TransformerConfig.loop_passes): ticks x passes x layers, each "
+            "a walk of a cache layer of its own")
+        self._tm_exit_mass = telemetry.counter(
+            "fastgen_exit_mass_total",
+            "a looped stack's exit distribution summed over the rows whose "
+            "token step() ticks read, by pass: over the rows, the share of "
+            "tokens an exit threshold would release at each pass")
+        telemetry.gauge(
+            "fastgen_cache_layers",
+            "layers of the pool's block stores: the stack's layers that "
+            "keep keys and values, times the passes of a looped stack"
+        ).set(max((layers for layers, s in PG.pool_stores(self.cfg)
+                   if s.cls == PG.BLOCKS), default=0))
         self._period_keys: Dict[tuple, tuple] = {}   # (kind, Tn) -> keys
         # the last step() tick's end (None before the first and after a
         # fused window, whose ticks are not accounted), and whether the
@@ -940,6 +963,7 @@ class FastGenEngine:
         cfg, attn = self.cfg, self._attention
         n = Tn * mb
         S = self._bucket(0)
+        looped = cfg.loop_passes > 1
 
         def tick(params, pool, packed):
             tables = packed[:n].reshape(Tn, mb)
@@ -955,17 +979,28 @@ class FastGenEngine:
                 # with a temperature a row's draw comes from the key and
                 # the row's place in ``x``: the gathered rows draw from
                 # the same distribution by another stream
-                logits = PG.head_logits(params, x, cfg)
+                logits = PG.head_logits(params, x, cfg, with_exit=looped)
+                if looped:
+                    logits, pdf = logits
                 with jax.named_scope("sample"):
-                    return sample_logits(
+                    toks = sample_logits(
                         logits, rng, self.temperature, self.top_k,
                         self.top_p).astype(jnp.int32)
+                if not looped:
+                    return toks
+                # one array, one read-back: a row's token and, behind it,
+                # the bits of its exit distribution ([rows, 1 + passes])
+                return jnp.concatenate(
+                    [toks[:, None],
+                     jax.lax.bitcast_convert_type(pdf, jnp.int32)], axis=1)
 
             if Tn > S:
                 head_rows = packed[n + 2 * Tn:n + 2 * Tn + S]
                 sampled = jax.lax.cond(
                     packed[n + 2 * Tn + S] <= S,
-                    lambda x: jnp.pad(sample(x[head_rows]), (0, Tn - S)),
+                    lambda x: jnp.pad(
+                        sample(x[head_rows]),
+                        ((0, Tn - S),) + ((0, 0),) * looped),
                     sample, x)
             else:
                 sampled = sample(x)
@@ -1626,7 +1661,7 @@ class FastGenEngine:
                 slot_attrs.update(span(n_decode_rows, chunk_starts, row, Tn,
                                        positions[:row] + 1))
         with telemetry.span("decode_tick", attrs={
-                **slot_attrs,
+                **slot_attrs, **self._loop_attrs,
                 "tick": self._ticks_run, "kind": kind, "rows": row,
                 "decode_rows": n_decode_rows,
                 "prefill_tokens": row - n_decode_rows,
@@ -1686,6 +1721,14 @@ class FastGenEngine:
             # the wait for the device and for the copy queued behind it
             with telemetry.span("tick_readback") as readback_span:
                 sampled = np.asarray(sampled)
+            exit_mass = None
+            if self._loop_attrs:
+                # [Tn, 1 + passes]: the exit distribution of the rows
+                # whose token is read, summed by pass
+                pdf = sampled[:, 1:].view(np.float32)
+                sampled = sampled[:, 0]
+                exit_mass = (pdf[:n_heads] if gathered
+                             else pdf[head_rows]).sum(axis=0)
             expert_rows, commit_attrs = None, None
             if self._expert_layers:
                 all_rows = sampled[Tn:].reshape(self._expert_layers, -1)
@@ -1753,6 +1796,10 @@ class FastGenEngine:
                 self._tm_attn_steps.inc(attn_open, form="open")
                 self._tm_attn_steps.inc(attn_steps - attn_open,
                                         form="masked")
+            if exit_mass is not None:
+                self._tm_layer_apps.inc(self._loop_attrs["cache_layers"])
+                for t, mass in enumerate(exit_mass.tolist()):
+                    self._tm_exit_mass.inc(mass, **{"pass": str(t)})
 
             # what ``_note_token`` does a sequence, for all the heads at
             # once: a sequence meets Python where it sees its first token

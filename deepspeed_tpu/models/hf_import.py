@@ -1733,6 +1733,53 @@ def params_from_nemotron_h(sd: Dict[str, Any], cfg: TransformerConfig
     return params
 
 
+# --------------------------------------------------------------------------- #
+# Ouro (ByteDance: a LOOPED language model, the Llama schema run several
+# times over the same weights)
+# --------------------------------------------------------------------------- #
+
+def config_from_ouro(hf_config) -> TransformerConfig:
+    """``model_type`` ``ouro``: the Llama block under four RMSNorms a layer
+    (before and after attention, before and after the FFN: the "sandwich"),
+    the whole stack run ``total_ut_steps`` times over the same weights with
+    the final norm after every pass, and an exit gate (``Linear(hidden,
+    1)``) whose cumulative probability, at ``early_exit_threshold``,
+    chooses the pass whose state feeds the head
+    (``TransformerConfig.loop_passes`` / ``exit_threshold``)."""
+    if getattr(hf_config, "rope_scaling", None) \
+            or getattr(hf_config, "attention_bias", False) \
+            or getattr(hf_config, "hidden_act", "silu") != "silu":
+        raise NotImplementedError(
+            "ouro: unscaled rotary, no attention bias and a SiLU-gated "
+            "feed-forward part are what is written")
+    return dataclasses.replace(
+        config_from_llama(hf_config), post_norms=True,
+        attn_head_dim=getattr(hf_config, "head_dim", None),
+        loop_passes=int(hf_config.total_ut_steps),
+        exit_threshold=float(getattr(hf_config, "early_exit_threshold",
+                                     1.0)))
+
+
+def params_from_ouro(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
+    """The family's tensor names (``modeling_ouro.py``): the Llama
+    schema's, a layer's four norms ``input_layernorm`` (before attention),
+    ``input_layernorm_2`` (after it), ``post_attention_layernorm`` (before
+    the FFN), ``post_attention_layernorm_2`` (after it), and
+    ``model.early_exit_gate`` beside ``model.norm``."""
+    L = cfg.num_layers
+    params = params_from_llama(sd, cfg)
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lyr = pre + "layers.{}."
+    for ours, theirs in (("ln1_post", "input_layernorm_2"),
+                         ("ln2_post", "post_attention_layernorm_2")):
+        params["blocks"][ours] = {"scale": _stack(sd, lyr + theirs
+                                                  + ".weight", L)}
+    params["exit_gate"] = {
+        "w": _np(sd[pre + "early_exit_gate.weight"]).T,        # [H, 1]
+        "b": _np(sd[pre + "early_exit_gate.bias"])}
+    return params
+
+
 _ARCH_TABLE = {
     "afmoe": (config_from_afmoe, params_from_afmoe),
     "KeyeVL2": (config_from_keye_vl2, params_from_keye_vl2),
@@ -1740,6 +1787,7 @@ _ARCH_TABLE = {
     "lfm2_moe": (config_from_lfm2_moe, params_from_lfm2_moe),
     "mellum": (config_from_mellum, params_from_mellum),
     "nemotron_h": (config_from_nemotron_h, params_from_nemotron_h),
+    "ouro": (config_from_ouro, params_from_ouro),
     "phi4flash": (config_from_phi4flash, params_from_phi4flash),
     "gpt2": (config_from_gpt2, params_from_gpt2),
     "llama": (config_from_llama, params_from_llama),

@@ -174,7 +174,11 @@ def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
     """The ONE table of what a model keeps of its sequences between ticks:
     an entry for every kind of layer the stack has, in the order the
     kinds' calls are counted (:func:`tick_walks`). A homogeneous stack is
-    its ``full`` (or ``latent``) entry ``num_layers`` times. The pool
+    its ``full`` (or ``latent``) entry ``num_layers`` times; a LOOPED one
+    (``cfg.loop_passes``) ``loop_passes x num_layers`` times: an entry
+    counts CACHE layers, and pass ``t`` of layer ``l`` owns cache layer
+    ``t * num_layers + l`` (a later position's pass ``t`` attends to this
+    position's pass-``t`` keys and values). The pool
     (:func:`init_paged_kv`), the tick's carry, the engine's bytes and
     gauges, the mixers and the kernel calls all come from here, and this is
     the one place that reads which family ``cfg`` is: grouped-query,
@@ -198,6 +202,7 @@ def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
     second-minor dim of a block the kernel's copies slice."""
     kinds, of_kinds = cfg.layer_kinds, cfg.standard_blocks
     n = kinds.count
+    T._check_loop(cfg)
     if any(own is not None for _, own in cfg.kind_rope):
         raise NotImplementedError(
             "a rotary table of its own for a layer kind (kind_rope) is "
@@ -280,7 +285,8 @@ def cache_kinds(cfg: T.TransformerConfig) -> Dict[str, CacheKind]:
             grouped(("wk", "wv"), cfg.attn_window, "swa_attention", "swa",
                     True), _grouped_mixer),
         "full": CacheKind(
-            n("full") if kinds else 0 if cfg.mla else cfg.num_layers, "attn",
+            n("full") if kinds else 0 if cfg.mla
+            else cfg.loop_passes * cfg.num_layers, "attn",
             kv(BLOCKS, ("k", "v")),
             grouped(("k", "v"), None, "global_attention", "global",
                     cfg.rope_of("full") is not None), _grouped_mixer),
@@ -1277,7 +1283,8 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     a RaggedBatchWrapper (``inference/v2/model_implementations``).
 
     ``head_rows`` [S] int32: the head runs for those rows of the tick only
-    (gathered from the last hidden state, before the final norm) and the
+    (gathered from the last hidden state, before the final norm; a looped
+    stack's: from every pass's normed state) and the
     logits are [S, vocab], row ``i`` those of tick row ``head_rows[i]``.
 
     ``with_stats`` adds a third result, ``{"expert_rows": [expert layers,
@@ -1293,17 +1300,28 @@ def forward_paged(params: PyTree, tokens: jax.Array, positions: jax.Array,
     return logits, new_pool
 
 
-def head_logits(params: PyTree, x: jax.Array,
-                cfg: T.TransformerConfig) -> jax.Array:
+def head_logits(params: PyTree, x: jax.Array, cfg: T.TransformerConfig,
+                with_exit: bool = False):
     """The head over rows of the last hidden state: final norm and the
-    vocabulary matmul, [rows, vocab] fp32."""
+    vocabulary matmul, [rows, vocab] fp32.
+
+    A looped stack's ``x`` is every pass's state of those rows, ``[rows,
+    passes, H]``, normed already (:func:`forward_hidden`): its exit gates
+    choose the pass whose state feeds the head (``T.loop_exit``: on these
+    rows alone, never on logits a pass) and nothing is normed again.
+    ``with_exit``: (logits, the rows' exit distribution ``[rows, passes]``
+    float32)."""
+    pdf = None
+    if cfg.loop_passes > 1:
+        x, pdf = T.loop_exit(params, x, cfg)
     with jax.named_scope("lm_head"):
-        x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        if pdf is None:
+            x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
         head = T._lm_head_of(params, cfg)
         logits = T.head_matmul(x, head.astype(x.dtype))
         if cfg.lm_head_bias:
             logits = logits + params["lm_head_b"].astype(jnp.float32)
-    return logits
+    return (logits, pdf) if with_exit else logits
 
 
 def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
@@ -1327,6 +1345,13 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
 
     Expert layers run dropless (:func:`_tick_experts`); ``stats`` is
     ``{"expert_rows": ...}`` for a model with experts, else ``{}``.
+
+    A looped stack (``cfg.loop_passes`` = R > 1) scans its segments R times
+    over the SAME leaves (``xs`` is never copied a pass): pass ``t``'s
+    layers write and walk the cache layers from ``t * num_layers``, the
+    final norm follows every pass, and the state returned is every pass's,
+    ``[T, R, H]`` (so ``x[rows]`` gathers rows as of any model's), for
+    :func:`head_logits` to choose from.
     """
     from deepspeed_tpu.ops.quantization import dequant_params
 
@@ -1397,12 +1422,14 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
     # from its first
     whole = sum((stack_kinds(cfg, seg) for _, seg in cfg.segments), ())
 
-    def body_of(seg, first, taken, stack):
+    def body_of(seg, first, taken, stack, ahead):
         """A step of a segment for ``T.scan_periods``: its period's layers,
         one :func:`block` each. ``first``: the segment's first layer's
         index in the stack; ``taken``: first layer of a run -> the scan
         steps taken before it (the carry counts steps from the stack's
-        first: no division finds a layer's index or its ``nth``)."""
+        first: no division finds a layer's index or its ``nth``);
+        ``ahead``: kind -> the cache layers of the kind that earlier passes
+        of a looped stack own."""
         # the one thing that is a family's own: where a period's step finds
         # layer i's leaves: stacked by layer under kinds of the standard
         # block, else by step already (a homogeneous stack's one layer a
@@ -1416,8 +1443,8 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
             P, before = len(period), taken[run_first]
             li0 = first + run_first - before * P
             per = {k: period.count(k) for k in set(period)}
-            nth0 = {k: whole[:first + run_first].count(k) - before * per[k]
-                    for k in per}
+            nth0 = {k: ahead[k] + whole[:first + run_first].count(k)
+                    - before * per[k] for k in per}
 
             def body(carry, lps):
                 x, flat, step, acts = carry
@@ -1460,33 +1487,46 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
         for _, s in pool_stores(cfg)}
     acts = {name: jnp.zeros((x.shape[0], width), dt)
             for kind in kinds.values() for name, width in kind.acts}
-    carry = (x, flat, jnp.int32(0), acts)
-    stats = {}
-    first = steps = 0
-    for key, seg in cfg.segments:
-        # the experts' matrices stay out of the scan's sliced operands: the
-        # grouped matmul takes the stack whole (``moe.layer.grouped_dot``;
-        # a slice is a copy of a layer's experts before each matmul);
-        # quantised leaves ({"q", "scale", ...}) are dequantised a layer at
-        # a time and stay in
-        held = params[key]["ffn"] if seg.one_sublayer else params[key]
-        stack = {k: v for k, v in held.items() if seg.n_experts
-                 and k in _EXPERT_LEAVES and hasattr(v, "ndim")}
-        xs = {k: v for k, v in params[key].items() if k not in stack}
-        if seg.one_sublayer:
-            xs["ffn"] = {k: v for k, v in held.items() if k not in stack}
-        layers, taken = stack_kinds(cfg, seg), {}
-        for at, _, n in T.kind_runs(layers):
-            taken[at], steps = steps, steps + n
-        carry, n_rows = T.scan_periods(
-            body_of(seg, first, taken, stack), carry, xs, layers,
-            by_step=not seg.layer_kinds)
-        first += len(layers)
-        if seg.n_experts:
-            # [steps, period, E] (or [steps, E]) a run -> [expert layers, E]
-            n_rows = [r.reshape((-1, r.shape[-1])) for r in n_rows
-                      if r is not None]
-            stats["expert_rows"] = n_rows[0] if len(n_rows) == 1 \
-                else jnp.concatenate(n_rows)
-    x, flat = carry[:2]
+    stats, states = {}, []
+    for t in range(cfg.loop_passes):   # once; a looped stack: R times
+        carry = (x, flat, jnp.int32(0), acts)
+        first = steps = 0
+        ahead = {k: t * whole.count(k) for k in kinds}
+        with T.pass_scope(cfg, t):
+            for key, seg in cfg.segments:
+                # the experts' matrices stay out of the scan's sliced
+                # operands: the grouped matmul takes the stack whole
+                # (``moe.layer.grouped_dot``; a slice is a copy of a
+                # layer's experts before each matmul); quantised leaves
+                # ({"q", "scale", ...}) are dequantised a layer at a time
+                # and stay in
+                held = params[key]["ffn"] if seg.one_sublayer \
+                    else params[key]
+                stack = {k: v for k, v in held.items() if seg.n_experts
+                         and k in _EXPERT_LEAVES and hasattr(v, "ndim")}
+                xs = {k: v for k, v in params[key].items() if k not in stack}
+                if seg.one_sublayer:
+                    xs["ffn"] = {k: v for k, v in held.items()
+                                 if k not in stack}
+                layers, taken = stack_kinds(cfg, seg), {}
+                for at, _, n in T.kind_runs(layers):
+                    taken[at], steps = steps, steps + n
+                carry, n_rows = T.scan_periods(
+                    body_of(seg, first, taken, stack, ahead), carry, xs,
+                    layers, by_step=not seg.layer_kinds)
+                first += len(layers)
+                if seg.n_experts:
+                    # [steps, period, E] (or [steps, E]) a run -> [expert
+                    # layers, E]
+                    n_rows = [r.reshape((-1, r.shape[-1])) for r in n_rows
+                              if r is not None]
+                    stats["expert_rows"] = n_rows[0] if len(n_rows) == 1 \
+                        else jnp.concatenate(n_rows)
+        x, flat = carry[:2]
+        if cfg.loop_passes > 1:
+            # the final norm after EVERY pass: it feeds the next
+            x = T.loop_norm(x, params, cfg)
+            states.append(x)
+    if states:
+        x = jnp.stack(states, axis=1)                       # [T, R, H]
     return x, {k: flat[k].reshape(v.shape) for k, v in pool.items()}, stats

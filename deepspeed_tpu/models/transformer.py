@@ -280,6 +280,17 @@ class TransformerConfig:
     # back up through ``latent_up``; the router and the shared expert take
     # the row itself. 0: the experts take the row
     moe_latent_size: int = 0
+    # a LOOPED stack (the ``ouro`` family): the ``num_layers`` layers run
+    # ``loop_passes`` times over the SAME leaves, the final norm after every
+    # pass (it feeds the next), and an exit gate (``params["exit_gate"]``: a
+    # float32 linear map of the normed state to one logit) after each. Every
+    # pass always runs; the gates choose which pass's state feeds the head
+    # (:func:`exit_pdf`, :func:`chosen_pass`): the first whose cumulative
+    # exit probability reaches ``exit_threshold``, else the last. A served
+    # pass keeps keys and values of its own: cache layer ``t * num_layers +
+    # l`` (``models/paged.cache_kinds``). 1: every layer runs once, no gate
+    loop_passes: int = 1
+    exit_threshold: float = 1.0
 
     @property
     def kv_heads(self) -> int:
@@ -581,6 +592,11 @@ class TransformerConfig:
             total += v * h
         if self.pos_emb == "learned":
             total += self.max_seq_len * h
+        if self.loop_passes > 1:
+            # the exit gate and its bias; and the count is the leaves' own
+            # here: an RMSNorm's final norm is one gain (the ``2 * h`` above
+            # reckons a LayerNorm's two for every model)
+            total += h + 1 - (h if self.norm == "rmsnorm" else 0)
         return total
 
 
@@ -666,8 +682,95 @@ def _check_kinds_of_blocks(cfg: TransformerConfig) -> None:
             f"{cfg.kda_rank}, {cfg.kda_conv})")
 
 
+def _check_loop(cfg: TransformerConfig, what: Optional[str] = None) -> None:
+    """What a looped stack (``loop_passes`` > 1) cannot be yet, said
+    precisely; ``what``: a path that assumes ONE application a layer and
+    refuses every looped config by name."""
+    R = cfg.loop_passes
+    if R == 1:
+        return
+    if R < 1 or not 0.0 <= cfg.exit_threshold <= 1.0:
+        raise ValueError(
+            f"loop_passes={R}, exit_threshold={cfg.exit_threshold}: a stack "
+            "runs one pass or more and exits at a cumulative probability "
+            "in [0, 1]")
+    if what is not None:
+        raise NotImplementedError(
+            f"{what} applies each layer's weights once a token; a looped "
+            f"stack (loop_passes={R}) is run whole by forward() and served "
+            "by FastGenEngine, whose pool keeps a cache layer a (pass, "
+            "layer)")
+    if cfg.layer_kinds or cfg.first_dense_layers or cfg.mla \
+            or cfg.n_experts:
+        raise NotImplementedError(
+            f"a looped stack (loop_passes={R}) is one homogeneous stack of "
+            "grouped-query attention blocks with dense feed-forward parts: "
+            "layer kinds, leading dense layers, latent attention and "
+            "experts are not written under a loop (got layer_kinds="
+            f"{bool(cfg.layer_kinds)}, first_dense_layers="
+            f"{cfg.first_dense_layers}, mla={cfg.mla}, n_experts="
+            f"{cfg.n_experts})")
+
+
+def pass_scope(cfg: TransformerConfig, t: int):
+    """The scope a looped stack's pass ``t`` runs under (``pass<t>``: a
+    trace tells pass 0's attention from pass 3's by its path); nothing for
+    a stack that runs once."""
+    return jax.named_scope(f"pass{t}") if cfg.loop_passes > 1 \
+        else contextlib.nullcontext()
+
+
+def loop_norm(x: jax.Array, params: PyTree, cfg: TransformerConfig
+              ) -> jax.Array:
+    """The final norm as a looped stack applies it after EVERY pass (under
+    the scope ``loop_norm``: the normed state feeds the next pass, the
+    exit gate and, if chosen, the head, which norms nothing again)."""
+    with jax.named_scope("loop_norm"):
+        return _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+
+
+def exit_pdf(gate_logits: jax.Array) -> jax.Array:
+    """The exit distribution of a looped stack from its passes' gate logits
+    ``[..., R]`` (float32): ``lambda_t = sigmoid(logit_t)``; ``p_t =
+    lambda_t * prod_{s<t} (1 - lambda_s)`` for ``t < R - 1`` and the last
+    pass takes what remains, ``p_{R-1} = prod_{s<R-1} (1 - lambda_s)``."""
+    lam = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    stay = jnp.cumprod(1.0 - lam[..., :-1], axis=-1)       # after pass t
+    before = jnp.concatenate([jnp.ones_like(lam[..., :1]), stay], axis=-1)
+    return jnp.concatenate([lam[..., :-1] * before[..., :-1],
+                            before[..., -1:]], axis=-1)
+
+
+def chosen_pass(pdf: jax.Array, threshold: float) -> jax.Array:
+    """The pass whose state feeds the head, ``int32[...]``: the first whose
+    cumulative exit probability reaches ``threshold``, else the last."""
+    reached = jnp.cumsum(pdf, axis=-1) >= jnp.float32(threshold)
+    return jnp.where(reached.any(axis=-1), jnp.argmax(reached, axis=-1),
+                     pdf.shape[-1] - 1).astype(jnp.int32)
+
+
+def loop_exit(params: PyTree, states: jax.Array, cfg: TransformerConfig
+              ) -> Tuple[jax.Array, jax.Array]:
+    """A looped stack's exit rule on its passes' normed states ``[..., R,
+    H]``: (the chosen pass's state ``[..., H]``, the exit distribution
+    ``[..., R]`` float32). The gate and the distribution are float32
+    whatever the stream's type; the threshold is the config's."""
+    with jax.named_scope("exit_gate"):
+        gate = params["exit_gate"]
+        logits = jnp.einsum(
+            "...rh,h->...r", states.astype(jnp.float32),
+            gate["w"].astype(jnp.float32)[:, 0],
+            precision=lax.Precision.HIGHEST) + gate["b"].astype(jnp.float32)
+        pdf = exit_pdf(logits)
+        at = chosen_pass(pdf, cfg.exit_threshold)
+        chosen = jnp.take_along_axis(
+            states, at[..., None, None], axis=-2)[..., 0, :]
+    return chosen, pdf
+
+
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     """fp32 master parameters. Output projections scaled by 1/sqrt(2L) (GPT-2)."""
+    _check_loop(cfg)
     if cfg.layer_kinds and not cfg.standard_blocks:
         return _init_kinds(cfg, rng)
     if cfg.one_sublayer:
@@ -833,6 +936,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
         params["lm_head"] = dense(keys[9], (h, cfg.vocab_size), std)
         if cfg.lm_head_bias:
             params["lm_head_b"] = jnp.zeros((cfg.vocab_size,), jnp.float32)
+    if cfg.loop_passes > 1:
+        params["exit_gate"] = {
+            "w": dense(jax.random.fold_in(rng, 2048), (h, 1), std),
+            "b": jnp.zeros((1,), jnp.float32)}
     return params
 
 
@@ -948,6 +1055,8 @@ def param_logical_axes(cfg: TransformerConfig) -> PyTree:
         axes["lm_head"] = ("embed", "vocab")
         if cfg.lm_head_bias:
             axes["lm_head_b"] = ("vocab",)
+    if cfg.loop_passes > 1:
+        axes["exit_gate"] = {"w": ("embed", None), "b": (None,)}
     return axes
 
 
@@ -1681,6 +1790,7 @@ def _ffn_metered(h: jax.Array, lp: Dict[str, jax.Array],
 
 
 def _require_one_stack(cfg: TransformerConfig, what: str) -> None:
+    _check_loop(cfg, what)
     if cfg.layer_kinds:
         raise NotImplementedError(
             f"{what} runs one homogeneous layer stack; a stack of layer "
@@ -1759,7 +1869,13 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     sharding constraint is emitted mid-backward. Both are identities —
     the chunked forward is numerically the single-scan forward. The
     random-LTD path keeps its own first/middle/last split and ignores
-    chunking (its stacks are already scan-segmented)."""
+    chunking (its stacks are already scan-segmented).
+
+    A looped stack (``cfg.loop_passes`` > 1) runs the stack that many times
+    over the same leaves, the final norm after every pass, and returns the
+    state of the pass its exit gates choose (:func:`loop_exit`), normed
+    already; PLD, random-LTD and chunking refuse it by name."""
+    _check_loop(cfg)
     if cfg.layer_kinds:
         if pld_keep is not None or random_ltd_idx is not None \
                 or param_sync is not None:
@@ -1772,6 +1888,12 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
             return _forward_blocks_of_kinds(params, tokens, cfg, constrain,
                                             attention_fn)
         return _forward_kinds(params, tokens, cfg, constrain)
+    for used, what in ((pld_keep is not None, "progressive layer drop"),
+                       (random_ltd_idx is not None, "random-LTD"),
+                       (cfg.scan_chunks > 1 or param_sync is not None,
+                        "the chunked layer scan (scan_chunks)")):
+        if used:
+            _check_loop(cfg, what)
     attention_fn = attention_fn or dot_product_attention
     constrain = activation_constraint or (lambda x: x)
     dt = cfg.compute_dtype
@@ -1849,38 +1971,50 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
             "random-LTD with ALiBi positions is unsupported: the middle-stack "
             "bias would be computed from compacted indices (rope tables are "
             "index-gathered; ALiBi distances cannot be)")
-    for key, seg in lead:
-        xs = params[key]
-        if with_pld:
-            xs = (xs, pld_keep[:seg.num_layers])
-            pld_keep = pld_keep[seg.num_layers:]
-        x, _ = lax.scan(make_body(cos, sin, with_pld, seg), x, xs)
-    if random_ltd_idx is None or L < 3:
-        x, (auxes, meters) = run_chunked(x, params["blocks"], cos, sin,
-                                         pld_keep)
-        aux_total = jnp.sum(auxes)
-    else:
-        blk = params["blocks"]
-        first = jax.tree.map(lambda p: p[:1], blk)
-        middle = jax.tree.map(lambda p: p[1:L - 1], blk)
-        last = jax.tree.map(lambda p: p[L - 1:], blk)
-        k1 = k2 = k3 = None
-        if with_pld:
-            k1, k2, k3 = pld_keep[:1], pld_keep[1:L - 1], pld_keep[L - 1:]
-        cos_k = sin_k = None
-        if cos is not None:
-            cos_k, sin_k = cos[random_ltd_idx], sin[random_ltd_idx]
-        x, (a1, m1) = run(x, first, cos, sin, k1)
-        xk = jnp.take(x, random_ltd_idx, axis=1)          # gather kept
-        xk, (a2, m2) = run(xk, middle, cos_k, sin_k, k2)
-        x = x.at[:, random_ltd_idx].set(xk)               # scatter back
-        x, (a3, m3) = run(x, last, cos, sin, k3)
-        aux_total = jnp.sum(a1) + jnp.sum(a2) + jnp.sum(a3)
-        meters = _cat_meters([m1, m2, m3])
+    R, states = full_cfg.loop_passes, []
+    for t in range(R):     # once; a looped stack: the SAME leaves every pass
+        with pass_scope(full_cfg, t):
+            for key, seg in lead:
+                xs = params[key]
+                if with_pld:
+                    xs = (xs, pld_keep[:seg.num_layers])
+                    pld_keep = pld_keep[seg.num_layers:]
+                x, _ = lax.scan(make_body(cos, sin, with_pld, seg), x, xs)
+            if random_ltd_idx is None or L < 3:
+                x, (auxes, meters) = run_chunked(x, params["blocks"], cos,
+                                                 sin, pld_keep)
+                aux_total = jnp.sum(auxes)
+            else:
+                blk = params["blocks"]
+                first = jax.tree.map(lambda p: p[:1], blk)
+                middle = jax.tree.map(lambda p: p[1:L - 1], blk)
+                last = jax.tree.map(lambda p: p[L - 1:], blk)
+                k1 = k2 = k3 = None
+                if with_pld:
+                    k1, k2, k3 = (pld_keep[:1], pld_keep[1:L - 1],
+                                  pld_keep[L - 1:])
+                cos_k = sin_k = None
+                if cos is not None:
+                    cos_k, sin_k = cos[random_ltd_idx], sin[random_ltd_idx]
+                x, (a1, m1) = run(x, first, cos, sin, k1)
+                xk = jnp.take(x, random_ltd_idx, axis=1)      # gather kept
+                xk, (a2, m2) = run(xk, middle, cos_k, sin_k, k2)
+                x = x.at[:, random_ltd_idx].set(xk)           # scatter back
+                x, (a3, m3) = run(x, last, cos, sin, k3)
+                aux_total = jnp.sum(a1) + jnp.sum(a2) + jnp.sum(a3)
+                meters = _cat_meters([m1, m2, m3])
+        if R > 1:
+            # the final norm after EVERY pass: it feeds the next
+            x = loop_norm(x, params, cfg)
+            states.append(x)
 
     _emit_meters(moe_held=meters)
-    x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     head = _lm_head_of(params, full_cfg)
+    if R > 1:
+        # the gates choose which pass's state feeds the head; it is normed
+        return loop_exit(params, jnp.stack(states, axis=-2),
+                         full_cfg)[0], head, aux_total
+    x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     return x, head, aux_total
 
 

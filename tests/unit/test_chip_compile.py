@@ -36,6 +36,10 @@ PAGED_SHAPES = {
     "mistral7b-64x16": (64, 32, 8, 2400, 16),
     "pythia69b-512x24": (512, 32, 32, 640, 24),
     "pythia69b-64x24": (64, 32, 32, 640, 24),
+    # the looped cell: 192 cache layers (4 passes x 48 layers) of 193 blocks
+    # = 16 x 2,316; 16 query = 16 KV heads
+    "ouro26b-512x16": (512, 16, 16, 2316, 16),
+    "ouro26b-64x16": (64, 16, 16, 2316, 16),
 }
 
 
@@ -815,6 +819,41 @@ def test_a_tick_re_lays_no_state_store(one_chip, program):
     held = sum(math.prod(x.shape) * x.dtype.itemsize for x in pool.values())
     assert held <= compiled.memory_analysis().alias_size_in_bytes \
         < 1.0001 * held
+
+
+def test_a_looped_tick_holds_its_layers_once(one_chip):
+    """The 64-row decode tick of the looped cell at its real size: 192
+    layer applications over ONE set of leaves. The program's arguments are
+    the weights (5.34 GB) and the pool (9.71 GB: 192 cache layers of 193
+    blocks), the pool rides in place, and no layer's weights are copied a
+    PASS: what the tick holds beside its arguments is XLA's one re-laid
+    copy a TICK of three of the square projection leaves (``bf16[48, 2048,
+    2048]``, 403 MB each, minor dimensions exchanged: with one pass it
+    re-lays a layer's slice on its way into VMEM instead; PERF.md, PR 55).
+    Four scans, one ``paged_attention`` call each."""
+    import math
+
+    tool = _copies_tool()
+    cfg, sizes, programs = tool.cell_programs("serve-ouro-2.6b-cot-closed")
+    assert (cfg.loop_passes, cfg.num_layers) == (4, 48)
+    assert (64, 16) in programs and (512, 4) in programs
+    lowered, pool = tool.lower_tick(cfg, sizes, 64, 16, one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert pool["k"].shape == (192, 193, 32, 16, 128)
+    held = sum(math.prod(x.shape) * x.dtype.itemsize for x in pool.values())
+    weights = 2 * cfg.num_params()
+    stats = compiled.memory_analysis()
+    assert held == 9_714_008_064 and weights == 5_335_949_314
+    assert held <= stats.alias_size_in_bytes < 1.0001 * held
+    assert held + weights <= stats.argument_size_in_bytes \
+        < 1.001 * (held + weights)
+    assert stats.temp_size_in_bytes < 1.25e9
+    found = tool.count_copies(text, [math.prod(pool["k"].shape)])
+    assert found["remat"] == {} and found["whole_store_copies"] == {}
+    assert sum(found["copies"].values()) <= 3
+    assert len(_mosaic_calls(text)) == 4
+    assert text.count("%paged_attention") >= 4
 
 
 def test_the_copy_count_sees_what_it_is_for():

@@ -273,6 +273,7 @@ CELLS = {
     "serve-kimi-linear-48b-rollout-closed": "delta-rule",
     "serve-keye-vl2-30b-longctx-closed": "learned-sparse",
     "serve-nemotron3-super-120b-agents-closed": "single-sublayers",
+    "serve-ouro-2.6b-cot-closed": "looped",
 }
 #: what a toy pool is built with: blocks, block size, slots, longest run
 TOY = (64, 8, 3, 16)
@@ -342,6 +343,13 @@ POOLS = {
     "toy:serve-nemotron3-super-120b-agents-closed": {
         "k": ((1, 64, 2, 8, 128), BF), "v": ((1, 64, 2, 8, 128), BF),
         "ssd": ((5, 4, 8, 128, 16), F32), "ssd_conv": ((60, 2176), BF)},
+    # (PR 55) a looped stack: a cache layer a (pass, layer), 4 x 48 (the
+    # toy: 4 x 3) of a grouped-query stack's blocks
+    "serve-ouro-2.6b-cot-closed": {
+        "k": ((192, 193, 32, 16, 128), BF),
+        "v": ((192, 193, 32, 16, 128), BF)},
+    "toy:serve-ouro-2.6b-cot-closed": {
+        "k": ((12, 64, 8, 4, 16), BF), "v": ((12, 64, 8, 4, 16), BF)},
 }
 #: ``FastGenEngine._pool_bytes``: (block stores, per-slot state stores)
 BYTES = {
@@ -363,6 +371,10 @@ BYTES = {
     "toy:serve-keye-vl2-30b-longctx-closed": (1179648, 0),
     "serve-nemotron3-super-120b-agents-closed": (402653184, 2915184640),
     "toy:serve-nemotron3-super-120b-agents-closed": (524288, 1571840),
+    # 192 x 193 blocks of 32 x 16 x 128 x 2 B, keys and values: 1,572,864 B
+    # a token
+    "serve-ouro-2.6b-cot-closed": (9714008064, 0),
+    "toy:serve-ouro-2.6b-cot-closed": (1572864, 0),
 }
 #: ``tick_walks``: (layers, window, cache positions a fetch step) a kind
 #: of kernel call
@@ -388,6 +400,9 @@ WALKS = {
     "toy:serve-keye-vl2-30b-longctx-closed": [(6, None, 128)],
     "serve-nemotron3-super-120b-agents-closed": [(1, None, 256)],
     "toy:serve-nemotron3-super-120b-agents-closed": [(1, None, 128)],
+    # 192 calls a tick: a walk a (pass, layer), four blocks of 32 a step
+    "serve-ouro-2.6b-cot-closed": [(192, None, 128)],
+    "toy:serve-ouro-2.6b-cot-closed": [(12, None, 128)],
 }
 
 
@@ -463,10 +478,11 @@ def test_pool_bytes_are_the_tables_by_class(case):
     state = sum(_bytes(pool[s.name]) for s in stores if s.cls != PG.BLOCKS)
     assert FastGenEngine._pool_bytes(cfg, nb, bs, slots, run) == (
         blocks, state)
-    # a homogeneous stack is its one entry ``num_layers`` times
+    # a homogeneous stack is its one entry ``num_layers`` times; a looped
+    # one: a cache layer a (pass, layer)
     if not cfg.layer_kinds:
         (entry,) = PG.cache_kinds(cfg).values()
-        assert entry.layers == cfg.num_layers
+        assert entry.layers == cfg.loop_passes * cfg.num_layers
 
 
 def test_a_made_up_kind_with_a_slot_store_is_booked_as_state(monkeypatch):
@@ -564,7 +580,8 @@ def test_a_tick_holds_one_wo_product_a_layer_and_one_scan_a_run(case):
     are ``T.scan_periods``', one a run of a period of kinds, and a step
     multiplies by ``wo`` once a layer of its period that has a mixer (a
     stack of single sublayers' ``ffn`` layers have none; ``paged.py``
-    names the leaf once and scans nothing itself)."""
+    names the leaf once and scans nothing itself); a looped stack: all of
+    it once a pass."""
     import inspect
 
     closed, cfg, paths = _traced(case)
@@ -573,11 +590,12 @@ def test_a_tick_holds_one_wo_product_a_layer_and_one_scan_a_run(case):
     assert sum(steps * len(period) for _, period, steps in runs) \
         == cfg.num_layers
     scans = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
-    assert [e.params["length"] for e in scans] == [s for _, _, s in runs]
+    assert [e.params["length"] for e in scans] \
+        == [s for _, _, s in runs] * cfg.loop_passes
     wo = [v for v, path in zip(closed.jaxpr.invars, paths)
           if getattr(path[-1], "key", None) == "wo"]
     assert wo
-    assert _count_products(closed.jaxpr, wo) == sum(
+    assert _count_products(closed.jaxpr, wo) == cfg.loop_passes * sum(
         sum(kind != "ffn" for kind in period) for _, period, _ in runs)
     source = inspect.getsource(PG)
     assert source.count('lp["wo"]') == 1 and "lax.scan" not in source

@@ -361,6 +361,61 @@ def test_a_tick_of_single_sublayers_sorts_its_mixers_and_latent_apart():
                    for s in stacks)
 
 
+def test_a_looped_tick_tells_its_passes_its_norm_and_its_gate_apart():
+    """The ``ouro`` tick: every pass's layers under ``pass<t>`` (a trace
+    tells pass 0's attention from pass 3's by its path), the norm between
+    passes under ``loop_norm``, the exit gate under ``exit_gate`` ahead of
+    ``lm_head`` in both heads of the full-budget tick; the kernel is the
+    homogeneous stack's ``paged_attention``, under each pass."""
+    import types
+
+    from deepspeed_tpu.models.hf_import import config_from_hf
+
+    cfg = config_from_hf(types.SimpleNamespace(
+        model_type="ouro", hidden_size=64, num_attention_heads=4,
+        num_key_value_heads=4, num_hidden_layers=2, intermediate_size=96,
+        rms_norm_eps=1e-6, rope_theta=1000000, vocab_size=128,
+        max_position_embeddings=512, total_ut_steps=3,
+        early_exit_threshold=0.5))
+    eng = FastGenEngine(cfg, n_blocks=16, block_size=16, max_blocks_per_seq=8,
+                        token_budget=32, seed=0, use_pallas_kernel=True)
+    tn, mb = 32, eng.max_blocks_per_seq
+    stacks = _stacks(eng._build_tick(tn, mb).lower(
+        eng.params, eng.pool, eng._pack_tick(
+            np.zeros((tn,), np.int32), np.zeros((tn,), np.int32),
+            np.zeros((tn, mb), np.int32), np.zeros((2,), np.uint32))),
+        "tick")
+    parts = {part for s in stacks for part in s.split("/")}
+    assert {"embed", "pass0", "pass1", "pass2", "attn", "mlp", "loop_norm",
+            "exit_gate", "lm_head", "sample"} <= parts
+    assert "pass3" not in parts
+    _assert_both_heads_are_scoped(stacks)
+    for t in range(3):
+        assert any(f"/pass{t}/" in s and "/attn/paged_attention" in s
+                   for s in stacks), t
+        assert any(f"/pass{t}/" in s and "/mlp/" in s for s in stacks), t
+    for branch in ("branch_0_fun", "branch_1_fun"):
+        assert any(s.startswith(f"jit(tick)/cond/{branch}/exit_gate")
+                   for s in stacks), branch
+    # the norm between passes is no pass's and no layer's; the gate is
+    # outside the head's scope, and a pass's layers outside both
+    assert any(s.startswith("jit(tick)/loop_norm") for s in stacks)
+    assert not any("/loop_norm/" in s and "/pass" in s for s in stacks)
+    assert not any("/exit_gate/" in s and "/lm_head/" in s for s in stacks)
+    # an unlooped tick carries none of them
+    plain = FastGenEngine("tiny", n_blocks=16, block_size=16,
+                          max_blocks_per_seq=8, token_budget=32, seed=0,
+                          use_pallas_kernel=True, hidden_size=64,
+                          num_layers=2, num_heads=4, max_seq_len=128,
+                          vocab_size=512, dtype="float32")
+    names = {part for s in _stacks(plain._build_tick(tn, mb).lower(
+        plain.params, plain.pool, plain._pack_tick(
+            np.zeros((tn,), np.int32), np.zeros((tn,), np.int32),
+            np.zeros((tn, mb), np.int32), np.zeros((2,), np.uint32))),
+        "tick") for part in s.split("/")}
+    assert not names & {"pass0", "loop_norm", "exit_gate"}
+
+
 def test_a_tick_of_sparse_layers_tells_its_three_parts_apart():
     """The ``KeyeVL2`` tick: a sparse layer's indexer, its choice and its
     attention over the chosen under scopes of their own inside ``attn``

@@ -978,9 +978,9 @@ def _sparse_mixer(cfg, tick, pool, entry, kind):
     position's index key (``index_row_width``), whether or not the tick's
     rows are long enough to choose; then three parts, a scope each:
 
-    * ``index``: the indexer's projections and its scores of every row
-      against its sequence's positions (``ops.pallas.index_scores``: the
-      store walked once a run of rows that share a table);
+    * ``index``: the indexer's projections and its scores of every row at
+      its sequence's positions (``ops.pallas.index_scores``: one walk a run
+      of rows that share a table, one product a step, the heads summed f32);
     * ``select``: the exact ``topk`` of each row's own scores, as a mask
       (``ops.pallas.sparse_choice``: a tile of rows' scores read once and
       counted in VMEM; :func:`sparse_choice` is its plain form);

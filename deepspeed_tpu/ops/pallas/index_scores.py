@@ -6,20 +6,30 @@ The walk is ``paged_attention.py``'s: one grid step a tile of ``R``
 consecutive rows of the flat token batch, the store in HBM, a tile split
 into RUNS of rows that carry one block table (``row_table``: one table a
 sequence slot and each row's slot), each run's table walked ONCE with
-double-buffered ``make_async_copy`` fetches of ``P`` blocks whose trip count
-is read from the prefetched lengths. The step differs: where that kernel
+``make_async_copy`` fetches of ``P`` blocks whose trip count is read from
+the prefetched lengths. The fetches land in a ring of ``_SLOTS`` slabs, all
+but the one a step reads in flight ahead of it, and a step scores up to
+``_STEP_POSITIONS`` positions: what a step costs whatever it carries (its
+fetches' issue and latency, the loop's turn: ~0.2 us of a 512-position
+step's 0.4-0.7, PERF.md, PR 56) is paid half as often and behind two more
+fetches than a double buffer hides. The step differs: where that kernel
 keeps an online softmax and returns a value a row, this one has no state
 between steps and returns a score a (row, position):
 
 - a run of two or more rows meets a fetched ``[C, W]`` slab of index keys
-  with all the tile's rows in one product, ``[R * heads, W] x [W, C]``, takes
-  ``relu``, and sums the heads under their weights with a second product,
-  ``[R, R * heads] x [R * heads, C]``, whose left operand is the tile's
-  weights laid block-diagonally (built beside the call: no value is
-  broadcast along lanes or reduced along sublanes inside the kernel); the
+  with all the tile's rows in ONE product, ``[heads * R, W] x [W, C]``, the
+  queries laid head-major (the wrapper hands them over ``[heads, T', W]``
+  too), so that the result is a slab ``[R, C]`` a head: the ``R`` rows'
+  scores under that head. The heads' weighted sum is taken where that
+  result lies, in float32 on the vector unit: ``out = wb[0] * relu(s[0])``,
+  then ``out += wb[j] * relu(s[j])`` for ``j = 1 .. heads - 1`` in
+  ascending order, ``wb[j]`` the rows' weight of head ``j`` repeated over a
+  lane tile (a ``[heads * R, 128]`` scratch built once a tile, before its
+  walks: no step broadcasts along lanes or reduces along sublanes); the
   rows outside the run keep what they hold;
-- a run of one row (a decode row) walks alone: ``[heads, W] x [W, C]`` and
-  ``[1, heads] x [heads, C]``.
+- a run of one row (a decode row) walks alone: ``[heads, W] x [W, C]``, its
+  weights as a column (a strided read of the same scratch) times ``relu``
+  of the result, and one sum over the ``heads`` sublanes.
 
 The result is laid ``[S / 128, T, 128]``: lane tile ``c`` of every row
 together, so that a step writes whole ``[R, 128]`` planes at a leading
@@ -29,8 +39,12 @@ made from these scores the same way. Position ``s`` of row ``t`` is
 either never written or scored against stale keys: the caller masks it.
 
 Arithmetic: the keys as stored, the queries in the keys' type, float32 from
-the first product on; the second product is float32 at HIGHEST precision
-(a rounded score would exchange positions near the cut).
+the product on (a rounded score would exchange positions near the cut):
+``relu``, the multiply by a weight and the sum over the heads are float32
+operations of the vector unit, one rounding each. A tile's sum runs over
+the heads in ascending order, left to right; a row alone's is the sublane
+reduction's (a tree over the heads), so the two forms may differ in a
+score's last bit, as either does from the plain form's product.
 """
 from __future__ import annotations
 
@@ -44,9 +58,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.paged_attention import _LANES, _use_interpret
 
-#: rows of a tile, and the most cache positions a fetch step scores
+#: rows of a tile, the most cache positions a fetch step scores, and the
+#: slabs of fetched keys a walk holds (one read, the others in flight)
 TILE_ROWS = 32
-_STEP_POSITIONS = 512
+_STEP_POSITIONS = 1024
+_SLOTS = 4
 
 
 def table_cols(blocks: int, block_size: int) -> int:
@@ -60,20 +76,22 @@ def table_cols(blocks: int, block_size: int) -> int:
 
 def step_positions(block_size: int, reach: int) -> int:
     """Cache positions a fetch step scores: whole blocks and whole lane
-    tiles, as many of ``_STEP_POSITIONS`` as divide ``reach`` (the
-    positions a table covers, a multiple of 128)."""
+    tiles, the most of them within ``_STEP_POSITIONS`` that divide
+    ``reach`` (the positions a table covers, a multiple of 128): 1,024 of
+    a tier of 72 or 144 blocks of 128, 768 of one of 36."""
     assert reach % _LANES == 0 and _LANES % block_size == 0, (reach,
                                                               block_size)
     tiles = reach // _LANES
-    return _LANES * max(n for n in (4, 2, 1) if tiles % n == 0
-                        and n * _LANES <= _STEP_POSITIONS)
+    return _LANES * max(n for n in range(1, _STEP_POSITIONS // _LANES + 1)
+                        if tiles % n == 0)
 
 
-def _kernel(tables_ref, meta_ref, q_ref, w_ref, wd_ref, store, o_ref,
-            buf, sem, *, product):
-    P, bs, W = buf.shape[1:]
+def _kernel(tables_ref, meta_ref, q_ref, qh_ref, w_ref, store, o_ref,
+            buf, wb, sem, *, product):
+    NS, P, bs, W = buf.shape
     R, H = w_ref.shape
     C = P * bs
+    planes = C // _LANES
     T = meta_ref.shape[0] // 3
     t0 = pl.program_id(0) * R
 
@@ -89,6 +107,14 @@ def _kernel(tables_ref, meta_ref, q_ref, w_ref, wd_ref, store, o_ref,
         # there is scored (and masked by the caller), so it must be finite
         buf[...] = jnp.zeros_like(buf)
 
+    # the tile's weights head-major, each repeated over a lane tile: rows
+    # j * R .. of ``wb`` are the R rows' weight of head j. Built once a
+    # tile, so that no step broadcasts along lanes
+    w = w_ref[...]
+    for j in range(H):
+        wb[j * R:(j + 1) * R, :] = jnp.broadcast_to(w[:, j:j + 1],
+                                                    (R, _LANES))
+
     def fetch(t, nblk, i, slot, start):
         def page(p, _):
             j = i * P + p
@@ -100,19 +126,16 @@ def _kernel(tables_ref, meta_ref, q_ref, w_ref, wd_ref, store, o_ref,
                     buf.at[slot, p], sem.at[slot])
                 copy.start() if start else copy.wait()
 
-        jax.lax.fori_loop(0, P, page, None)
+        # unrolled: up to eight descriptors a step behind a rolled loop's
+        # branches cost a chunk tick's call 14 %, a decode tick's 35 %
+        jax.lax.fori_loop(0, P, page, None, unroll=True)
 
-    def scores(q, wd, slot):
-        """``wd [rows, heads]`` x relu(``q [heads, W]`` x keys) -> [rows, C]
-        float32."""
-        keys = buf[slot].reshape(C, W)
+    def relu_scores(q, slot):
+        """relu(``q [rows, W]`` x keys) -> [rows, C] float32."""
         s = jax.lax.dot_general(
-            q, keys, (((1,), (1,)), ((), ())), precision=product,
-            preferred_element_type=jnp.float32)
-        return jax.lax.dot_general(
-            wd, jnp.maximum(s, 0.0), (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
+            q, buf[slot].reshape(C, W), (((1,), (1,)), ((), ())),
+            precision=product, preferred_element_type=jnp.float32)
+        return jnp.maximum(s, 0.0)
 
     def walk(r0, r1):
         t = t0 + r0
@@ -122,45 +145,56 @@ def _kernel(tables_ref, meta_ref, q_ref, w_ref, wd_ref, store, o_ref,
 
         def steps(step_of):
             def step(i, _):
-                slot = i % 2
+                ahead = i + NS - 1
 
-                @pl.when((i + 1) * P < nblk)
+                @pl.when(ahead * P < nblk)
                 def _():
-                    fetch(t, nblk, i + 1, 1 - slot, True)
+                    fetch(t, nblk, ahead, ahead % NS, True)
 
-                fetch(t, nblk, i, slot, False)
-                step_of(i, slot)
+                fetch(t, nblk, i, i % NS, False)
+                step_of(i, i % NS)
 
             jax.lax.fori_loop(0, pl.cdiv(hi, C), step, None)
 
         def alone_form():
             q = q_ref[pl.ds(r0, 1)].reshape(H, W)
-            wd = w_ref[pl.ds(r0, 1), :]
+            # [H, 128]: the row's weight of head j along sublane j
+            wcol = wb[pl.ds(r0, H, stride=R), :]
 
             def step(i, slot):
-                out = scores(q, wd, slot)                       # [1, C]
-                for n in range(C // _LANES):
-                    o_ref[i * (C // _LANES) + n, pl.ds(r0, 1), :] = \
-                        out[:, n * _LANES:(n + 1) * _LANES]
+                s = relu_scores(q, slot)                        # [H, C]
+                for n in range(planes):
+                    o_ref[i * planes + n, pl.ds(r0, 1), :] = jnp.sum(
+                        wcol * s[:, n * _LANES:(n + 1) * _LANES], axis=0,
+                        keepdims=True)
 
             steps(step)
 
         def tile_form():
-            q = q_ref[...].reshape(R * H, W)
-            wd = wd_ref[...]
+            q = qh_ref[...].reshape(H * R, W)
             row = jax.lax.broadcasted_iota(jnp.int32, (R, _LANES), 0)
             mine = (row >= r0) & (row < r1)
 
             def step(i, slot):
-                out = scores(q, wd, slot)                       # [R, C]
-                for n in range(C // _LANES):
-                    at = i * (C // _LANES) + n
-                    o_ref[at] = jnp.where(
-                        mine, out[:, n * _LANES:(n + 1) * _LANES], o_ref[at])
+                s = relu_scores(q, slot)        # [H * R, C]: a slab a head
+                for n in range(planes):
+                    at = i * planes + n
+                    lanes = slice(n * _LANES, (n + 1) * _LANES)
+                    out = wb[0:R, :] * s[0:R, lanes]
+                    for j in range(1, H):
+                        rows = slice(j * R, (j + 1) * R)
+                        out = out + wb[rows, :] * s[rows, lanes]
+                    o_ref[at] = jnp.where(mine, out, o_ref[at])
 
             steps(step)
 
-        fetch(t, nblk, 0, 0, True)
+        # a walk's first fetches; then step i starts the fetch of step
+        # i + NS - 1 into the slot that step i - 1 read
+        for ahead in range(NS - 1):
+            @pl.when(ahead * P < nblk)
+            def _(ahead=ahead):
+                fetch(t, nblk, ahead, ahead, True)
+
         jax.lax.cond(r1 - r0 == 1, alone_form, tile_form)
 
     def next_run(r0):
@@ -175,7 +209,7 @@ def _kernel(tables_ref, meta_ref, q_ref, w_ref, wd_ref, store, o_ref,
 
 @functools.partial(jax.jit, inline=True,
                    static_argnames=("name", "interpret"))
-def _tiles(tables, meta, q, w, wd, store, *, name, interpret):
+def _tiles(tables, meta, q, w, store, *, name, interpret):
     T, H, W = q.shape
     bs = store.shape[1]
     reach = tables.shape[1] * bs
@@ -185,13 +219,17 @@ def _tiles(tables, meta, q, w, wd, store, *, name, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T // R,),
+        # the queries twice: row-major for a row alone (it reads its heads
+        # at a run-time LEADING index; a 16-bit array takes a run-time
+        # sublane at whole packed tiles alone), head-major for a tile
         in_specs=[pl.BlockSpec((R, H, W), lambda i, *_: (i, 0, 0)),
-                  pl.BlockSpec((R, H), lambda i, *_: (i, 0)),
-                  pl.BlockSpec((R, R * H), lambda i, *_: (i, 0)), hbm],
+                  pl.BlockSpec((H, R, W), lambda i, *_: (0, i, 0)),
+                  pl.BlockSpec((R, H), lambda i, *_: (i, 0)), hbm],
         out_specs=pl.BlockSpec((reach // _LANES, R, _LANES),
                                lambda i, *_: (0, i, 0)),
-        scratch_shapes=[pltpu.VMEM((2, C // bs, bs, W), store.dtype),
-                        pltpu.SemaphoreType.DMA((2,))])
+        scratch_shapes=[pltpu.VMEM((_SLOTS, C // bs, bs, W), store.dtype),
+                        pltpu.VMEM((H * R, _LANES), jnp.float32),
+                        pltpu.SemaphoreType.DMA((_SLOTS,))])
     compiler_params = None
     if not interpret:
         # the fetch slots are cleared on the first step: tiles in order
@@ -206,7 +244,7 @@ def _tiles(tables, meta, q, w, wd, store, *, name, interpret):
         out_shape=jax.ShapeDtypeStruct((reach // _LANES, T, _LANES),
                                        jnp.float32),
         compiler_params=compiler_params, interpret=interpret, name=name,
-    )(tables, meta, q, w, wd, store)
+    )(tables, meta, q, q.transpose(1, 0, 2), w, store)
 
 
 def index_scores(q: jax.Array, w: jax.Array, store: jax.Array,
@@ -239,14 +277,7 @@ def index_scores(q: jax.Array, w: jax.Array, store: jax.Array,
                             which[1:] == which[:-1]])
     meta = jnp.concatenate([lengths.astype(jnp.int32),
                             same.astype(jnp.int32), which])
-    # the tile's weights block-diagonally: row r of a tile holds its heads'
-    # weights at columns r * heads .., so that ``wd x relu(scores)`` sums
-    # each row's own heads
-    r = jnp.arange(Tn + pad) % TILE_ROWS
-    wd = (w[:, None, :] * (r[:, None] == jnp.arange(TILE_ROWS))[:, :, None]
-          .astype(w.dtype)).reshape(Tn + pad, TILE_ROWS * H)
-    return _tiles(tables, meta, q, w, wd, store, name=name,
-                  interpret=interpret)
+    return _tiles(tables, meta, q, w, store, name=name, interpret=interpret)
 
 
 def index_scores_reference(q: jax.Array, w: jax.Array, store: jax.Array,

@@ -380,32 +380,179 @@ def _tick_rows(rng, S1, MB, bs, runs):
     return slot.astype(np.int32), pos.astype(np.int32), tables
 
 
-def test_index_scores_kernel_matches_plain_jnp():
-    """Runs that share a tile, a run that crosses tiles, rows alone, pad
-    rows: the kernel's scores under each row's length are the plain
-    path's."""
+# (slot, first position, rows) of a tick's runs; tiles are of 32 rows
+INDEX_RUNS = {
+    "whole-tile": [(1, 100, 32)],
+    "share-a-tile": [(1, 200, 5), (2, 31, 12), (3, 100, 15)],
+    "across-tiles": [(3, 100, 40)],
+    "rows-alone": [(1, 200, 1), (2, 31, 1), (3, 250, 1)],
+    "pad-rows": [(1, 200, 1), (2, 31, 1), (3, 100, 40), (1, 201, 7),
+                 (0, 0, 3)],
+}
+INDEX_CASES = [(runs, heads, store, "random") for runs in INDEX_RUNS
+               for heads in (4, 16) for store in ("float32", "bfloat16")] \
+    + [("pad-rows", 16, "float32", weights)
+       for weights in ("negative-rows", "zero-head")]
+
+
+def _index_case(runs, heads, store, weights, MB=32):
+    """(q, w, store, tables, lengths, slot) of a tick of ``runs`` (a name
+    of ``INDEX_RUNS`` or a list), tables of ``MB`` blocks of 8."""
     rng = np.random.default_rng(5)
-    S1, MB, bs, H, W = 4, 32, 8, 4, 128
-    slot, pos, tables = _tick_rows(rng, S1, MB, bs, [
-        (1, 200, 1), (2, 31, 1), (3, 100, 40), (1, 201, 7), (0, 0, 3)])
+    S1, bs, W = 4, 8, 128
+    slot, pos, tables = _tick_rows(
+        rng, S1, MB, bs, INDEX_RUNS[runs] if isinstance(runs, str) else runs)
     Tn = len(slot)
-    store = jnp.asarray(rng.normal(size=(1 + (S1 - 1) * MB, bs, W)),
-                        jnp.float32).at[..., 8:].set(0.0)
-    q = jnp.asarray(rng.normal(size=(Tn, H, W)), jnp.float32).at[
+    dtype = jnp.dtype(store)
+    keys = jnp.asarray(rng.normal(size=(1 + (S1 - 1) * MB, bs, W)),
+                       dtype).at[..., 8:].set(0.0)
+    q = jnp.asarray(rng.normal(size=(Tn, heads, W)), dtype).at[
         ..., 8:].set(0.0)
-    w = jnp.asarray(rng.normal(size=(Tn, H)), jnp.float32)
-    lengths = jnp.asarray(pos + 1)
-    with jax.default_matmul_precision("highest"):
-        got = IX.index_scores(q, w, store, jnp.asarray(tables), lengths,
-                              jnp.asarray(slot), interpret=True)
-        want = IX.index_scores_reference(q, w, store,
-                                         jnp.asarray(tables)[slot])
+    w = rng.normal(size=(Tn, heads)).astype(np.float32)
+    if weights == "negative-rows":      # the first row alone, one of a run
+        w[[0, 5]] = -np.abs(w[[0, 5]])
+    elif weights == "zero-head":
+        w[:, 2] = 0.0
+    return (q, jnp.asarray(w), keys, jnp.asarray(tables),
+            jnp.asarray(pos + 1), jnp.asarray(slot))
+
+
+def _index_rows(got, Tn, S):
+    """``index_scores``' planes as ``[Tn, S]``."""
     nC, Tp, C = got.shape
-    assert Tp % IX.TILE_ROWS == 0 and nC * C >= MB * bs
-    rows = got.transpose(1, 0, 2).reshape(Tp, nC * C)[:Tn, :MB * bs]
+    assert Tp % IX.TILE_ROWS == 0 and nC * C >= S
+    return got.transpose(1, 0, 2).reshape(Tp, nC * C)[:Tn, :S]
+
+
+@pytest.mark.parametrize("runs,heads,store,weights", INDEX_CASES)
+def test_index_scores_kernel_matches_plain_jnp(runs, heads, store, weights):
+    """A run that is its whole tile, runs that share a tile, a run that
+    crosses tiles, rows alone, pad rows: the kernel's scores under each
+    row's length are the plain path's, for few heads and the cell's 16, a
+    float32 store and the served bfloat16 (whose products are exact in
+    float32: the same tolerance). A row whose weights are all negative
+    scores nothing above zero (the ``relu`` comes BEFORE the weights), and
+    a head of weight zero leaves no trace of its queries."""
+    q, w, keys, tables, lengths, slot = _index_case(runs, heads, store,
+                                                    weights)
+    Tn, MB, bs = len(slot), tables.shape[1], keys.shape[1]
+
+    def rows_of(q):
+        with jax.default_matmul_precision("highest"):
+            return _index_rows(IX.index_scores(
+                q, w, keys, tables, lengths, slot, interpret=True),
+                Tn, MB * bs)
+
+    with jax.default_matmul_precision("highest"):
+        want = IX.index_scores_reference(q, w, keys, tables[slot])
+    rows = rows_of(q)
     live = np.arange(MB * bs)[None] < np.asarray(lengths)[:, None]
     np.testing.assert_allclose(np.where(live, rows, 0),
                                np.where(live, want, 0), rtol=1e-5, atol=1e-4)
+    if weights == "negative-rows":
+        assert (np.where(live, rows, 0)[[0, 5]] <= 0).all()
+        assert (np.where(live, rows, 0)[[0, 5]] < 0).any()
+    elif weights == "zero-head":
+        np.testing.assert_array_equal(
+            np.where(live, rows_of(q.at[:, 2].multiply(-3.0)), 0),
+            np.where(live, rows, 0))
+
+
+def test_index_scores_walks_longer_than_its_ring(monkeypatch):
+    """Walks of one step, of fewer steps than the ring has slots and of
+    twice as many (a step cut to 128 positions: tables of 128 blocks of 8
+    are this test's alone, so no other trace of the call is met): every
+    fetch lands in the slot its step reads, rows alone and runs alike."""
+    monkeypatch.setattr(IX, "_STEP_POSITIONS", 128)
+    q, w, keys, tables, lengths, slot = _index_case(
+        [(1, 99, 1), (2, 299, 1), (3, 1000, 1), (1, 100, 3), (2, 990, 30),
+         (3, 600, 12)], 4, "float32", "random", MB=128)
+    assert IX.step_positions(8, 1024) == 128 and IX._SLOTS == 4
+    assert sorted(set(-(-np.asarray(lengths) // 128)))[:3] == [1, 3, 5]
+    with jax.default_matmul_precision("highest"):
+        got = IX.index_scores(q, w, keys, tables, lengths, slot,
+                              interpret=True)
+        want = IX.index_scores_reference(q, w, keys, tables[slot])
+    live = np.arange(1024)[None] < np.asarray(lengths)[:, None]
+    np.testing.assert_allclose(
+        np.where(live, _index_rows(got, len(slot), 1024), 0),
+        np.where(live, want, 0), rtol=1e-5, atol=1e-4)
+
+
+def _sub_jaxprs(jaxpr):
+    """``jaxpr`` and every jaxpr under its equations' parameters."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _sub_jaxprs(sub)
+
+
+def test_the_index_kernel_sums_its_heads_off_the_mxu():
+    """The mechanism of PR 56, pinned where a CPU can see it: the kernel's
+    body holds ONE product a form (a row alone, a tile: two in all; the
+    heads' weighted sum was a second product in each, over the tile's
+    weights laid block-diagonally), and no operand ``[.., R * heads]``
+    reaches the call."""
+    q, w, keys, tables, lengths, slot = _index_case("pad-rows", 16,
+                                                    "bfloat16", "random")
+    H = q.shape[1]
+    traced = jax.make_jaxpr(lambda *a: IX.index_scores(*a, interpret=False))(
+        q, w, keys, tables, lengths, slot)
+    calls = [e for j in _sub_jaxprs(traced.jaxpr) for e in j.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    call, = calls
+    assert call.params["name"] == "index_scores"
+    products = [e for j in _sub_jaxprs(call.params["jaxpr"]) for e in j.eqns
+                if e.primitive.name == "dot_general"]
+    assert len(products) == 2
+    for e in products:                  # the keys' type in, float32 out
+        assert {v.aval.dtype for v in e.invars} == {jnp.dtype(jnp.bfloat16)}
+        assert e.outvars[0].aval.dtype == jnp.float32
+    wide = IX.TILE_ROWS * H
+    assert not [v.aval.shape for v in call.invars
+                if v.aval.shape and v.aval.shape[-1] == wide]
+
+
+def test_the_index_alone_tool_still_walks():
+    """``tools/index_kernel_alone.py`` on its tiny cases, interpreted: the
+    call runs chained at two trip counts, the steps it reckons are the
+    runs', and the tree's kernel beside itself differs nowhere (its times
+    are a chip's to give: none is read here)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
+                        "index_kernel_alone.py")
+    spec = importlib.util.spec_from_file_location("index_kernel_alone", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    # the cell's six tick programs, by (rows, table tier)
+    assert {case[:2] for case in tool.CASES.values()} == {
+        (rows, tier) for rows in (256, 2048) for tier in (36, 72, 144)}
+    H, _, W, bs, NB = tool.TINY_DIMS
+    for case in tool.TINY.values():
+        ops = tool.operands(np.random.default_rng(0), case, tool.TINY_DIMS,
+                            jnp.float32)
+        q, w, store, tables, lengths, slot = ops
+        assert q.shape == (case[0], H, W) and store.shape == (NB, bs, W)
+        assert tables.shape == (case[2] + 2, case[1])
+        positions = IX.step_positions(bs, case[1] * bs)
+        tile, alone = tool.count_steps(lengths, slot, positions)
+        # the decode rows walk alone, the chunk's rows a tile together (a
+        # decode tick's pads: one run of one step)
+        assert alone == sum(-(-int(n) // positions)
+                            for n in np.asarray(lengths)[:case[2]])
+        assert tile == (case[0] // 32 if case[3] else 1)
+        one = float(IX.index_scores(*ops, interpret=True)[0, 0, 0])
+        totals = [float(tool.chained(IX, n, True)(*ops)) for n in (1, 3)]
+        np.testing.assert_allclose(totals, [one, 3 * one], rtol=1e-6)
+        same = tool.compare(IX, IX, ops, True)
+        assert same["differ"] == 0 and same["live"] == int(
+            np.asarray(lengths).sum())
 
 
 def test_the_choice_alone_tool_still_walks():

@@ -373,8 +373,8 @@ def fastgen_sla_bench(model="gpt2_125m", n_req=24, max_new=48,
     per-token latency percentiles), not just closed-batch throughput.
 
     Poisson arrivals at ``load`` x the engine's measured decode capacity;
-    the serve loop admits due requests, runs one SplitFuse tick while any
-    prefill is pending, else a short fused decode window. Reported per
+    the serve loop admits due requests and runs one ``step()`` tick at a
+    time. Reported per
     load: achieved tok/s, TTFT p50/p95, per-output-token latency p50/p95,
     e2e p95."""
     import numpy as np
@@ -407,17 +407,9 @@ def fastgen_sla_bench(model="gpt2_125m", n_req=24, max_new=48,
 
         def note(emitted):
             now = time.perf_counter() - t0
-            for uid, toks in emitted.items():
-                cnt = len(toks) if isinstance(toks, list) else 1
-                # the post-break reconciliation can replay tokens already
-                # counted — clamp so n_out never exceeds max_new (an
-                # overcount deflates the per-token latency percentiles)
-                cnt = min(cnt, max_new - n_out.get(uid, 0))
-                if cnt:
-                    first_tok.setdefault(uid, now)
-                n_out[uid] = n_out.get(uid, 0) + cnt
-                # a flushed uid can reappear once (the closed stream's
-                # in-flight window) — completion time must not move
+            for uid in emitted:
+                first_tok.setdefault(uid, now)
+                n_out[uid] = n_out.get(uid, 0) + 1
                 if n_out[uid] >= max_new and uid not in done_at:
                     done_at[uid] = now
                     fg.flush([uid])
@@ -430,36 +422,17 @@ def fastgen_sla_bench(model="gpt2_125m", n_req=24, max_new=48,
             if not fg.seqs:
                 time.sleep(min(0.005, max(0.0, pending[0][0] - now)))
                 continue
-            if any(s.prefill_remaining > 0 for s in fg.seqs.values()):
-                note(fg.step())
-            else:
-                # async double-buffered decode (engine.decode_stream):
-                # window N+1 runs on device while N drains; break out when
-                # the next arrival is due so admission latency stays bounded
-                served = False
-                for emitted in fg.decode_stream(window=8):
-                    served = True
-                    note(emitted)
-                    now = time.perf_counter() - t0
-                    if pending and pending[0][0] <= now:
-                        break
-                # early break closes the stream mid-flight; its last window
-                # drains into engine state without being yielded — reconcile
-                note({uid: s.generated[n_out.get(uid, 0):]
-                      for uid, s in list(fg.seqs.items())
-                      if len(s.generated) > n_out.get(uid, 0)})
-                if not served:
-                    # no ladder rung fits (headroom < 8 near max_len, or
-                    # block exhaustion): single-tick fallback, same as
-                    # _generate_dynamic's — without it this loop busy-spins
-                    emitted = fg.step()
-                    note(emitted)
-                    if not emitted:       # truly stuck — don't spin forever
-                        for uid in list(fg.seqs):
-                            done_at.setdefault(uid,
-                                               time.perf_counter() - t0)
-                            first_tok.setdefault(uid, done_at[uid])
-                            fg.flush([uid])
+            emitted = fg.step()
+            note(emitted)
+            if not emitted and not any(
+                    s.prefill_remaining > 0 and not s.done
+                    for s in fg.seqs.values()):
+                # truly stuck (a sequence at max_len, or no block left):
+                # do not spin forever
+                for uid in list(fg.seqs):
+                    done_at.setdefault(uid, time.perf_counter() - t0)
+                    first_tok.setdefault(uid, done_at[uid])
+                    fg.flush([uid])
         if not record:
             return None
         tts = sorted(first_tok[u] - arrival[i] for i, u in enumerate(uids))

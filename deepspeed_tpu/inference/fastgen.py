@@ -227,15 +227,12 @@ class _Rows:
         return r
 
     def release(self, seq: "_Seq") -> None:
-        """Give ``seq``'s row back. The descriptor keeps its last values
-        in a row of its own: a ``decode_stream`` window in flight may
-        still hold it and drain into it, and must not write into the row's
-        next owner."""
-        r, own = seq.row, _Rows(1, self.table.shape[1])
-        for name in ("pos", "prefilled", "prompt_len", "last_tok",
-                     "first_seen", "gen_len"):
-            getattr(own, name)[0] = getattr(self, name)[r]
-        seq._st, seq.row = own, 0
+        """Give ``seq``'s row back. The descriptor keeps ``generated``,
+        ``done`` and ``expired`` and loses its row: ``flush`` has taken it
+        out of ``seqs``, where ``query``, ``rematerialize`` and the
+        frontend find a descriptor, so nothing reads its row again."""
+        r = seq.row
+        seq._st = seq.row = None
         for name in self.TICK_FIELDS:
             getattr(self, name)[r] = 0
         self.last_tok[r] = -1
@@ -275,8 +272,8 @@ class _Seq:
     seen) lives in the engine's ``_Rows``, in this sequence's ``row``, and
     is read here through properties; ``generated`` is a plain list and
     ``done`` / ``expired`` plain attributes, mirrored by ``_Rows.live``
-    and ``_Rows.gen_len`` (``_finish``, ``_note_token`` and a tick's
-    commit keep both). A new field the scheduler mutates goes into
+    and ``_Rows.gen_len`` (``_finish`` and a tick's commit keep
+    both). A new field the scheduler mutates goes into
     ``_Rows`` and its ``TICK_FIELDS``, or ``step()`` does not roll it
     back."""
 
@@ -474,8 +471,7 @@ class FastGenEngine:
         # HOST-side key stream: deriving per-call subkeys with an eager
         # jax.random.split is a whole device dispatch for an 8-byte op. Any
         # uint32[2] is a valid raw threefry key, so a host PCG stream
-        # supplies them; in-program splits (inside the fused scans) stay
-        # jax.random.
+        # supplies them (two words of a tick's packed array).
         self._host_rng = np.random.default_rng(seed)
         self._ticks: Dict[int, Any] = {}   # bucketed by tick token count
         self._setup_telemetry()
@@ -579,21 +575,6 @@ class FastGenEngine:
         blocks = sum(n for s, n in held if s.cls == PG.BLOCKS)
         return blocks, sum(n for _, n in held) - blocks
 
-    def _dev(self, x) -> jax.Array:
-        """Host array → device; REPLICATED across the mesh under TP (a
-        plain asarray lands on one device and clashes with sharded params
-        inside jit)."""
-        x = jnp.asarray(x)
-        if self._rep_sh is not None:
-            x = jax.device_put(x, self._rep_sh)
-        return x
-
-    def _next_key(self) -> jax.Array:
-        """Raw uint32[2] threefry key from the host PCG stream (no device
-        dispatch — see ``_host_rng``)."""
-        return self._dev(self._host_rng.integers(
-            0, 2 ** 32, 2, dtype=np.uint32))
-
     # ------------------------------------------------------------------ #
     # telemetry (README "Observability" — fastgen_* metric catalog)
     # ------------------------------------------------------------------ #
@@ -621,7 +602,7 @@ class FastGenEngine:
         self._tm_ticks = telemetry.counter(
             "fastgen_ticks_total",
             "engine ticks by kind (mixed: the tick held prompt rows / "
-            "decode: it held none, from step() or a fused window) and "
+            "decode: it held none) and "
             "block-table width tier")
         self._tm_h2d = telemetry.counter(
             "fastgen_tick_h2d_bytes_total",
@@ -812,9 +793,8 @@ class FastGenEngine:
         ).set(max((layers for layers, s in PG.pool_stores(self.cfg)
                    if s.cls == PG.BLOCKS), default=0))
         self._period_keys: Dict[tuple, tuple] = {}   # (kind, Tn) -> keys
-        # the last step() tick's end (None before the first and after a
-        # fused window, whose ticks are not accounted), and whether the
-        # engine has been without a live sequence since
+        # the last step() tick's end (None before the first), and whether
+        # the engine has been without a live sequence since
         self._tick_end_t: Optional[float] = None
         self._idle = False
         self._gc_seen_s = telemetry.gc_pause_seconds()
@@ -887,15 +867,6 @@ class FastGenEngine:
             seq.first_tok_seen = True
             self._tm_ttft.observe(time.perf_counter() - seq.admit_t)
 
-    @staticmethod
-    def _slot_tier(n_slots: int) -> int:
-        """Pow2 slot-count tier (min 4): the rows of a fused decode
-        window's program, so that the live count never adds one."""
-        ns = 4
-        while ns < n_slots:
-            ns *= 2
-        return ns
-
     def _mb_tier_bounds(self):
         """(quarter, half) table-width tier bounds — the single source both
         _mb_tier (compile-cache keys) and _mb_tier_name (metric labels)
@@ -905,9 +876,8 @@ class FastGenEngine:
 
     def _mb_tier(self, mb_need: int) -> int:
         """Table-width tiers (quarter/half/full) — ONE rule for every
-        compile-cache key (step and decode-scan must agree or the
-        small-grid property of the caches breaks). The tier is the
-        width of the block tables a tick carries: the reference path
+        compile-cache key, so that the cache stays a small grid. The tier
+        is the width of the block tables a tick carries: the reference path
         gathers every covered block, the Pallas kernel holds the table in
         scalar memory and fetches only the blocks a row's length reaches
         (measured in PR 22, and by construction since PR 24: its walks
@@ -1013,41 +983,6 @@ class FastGenEngine:
 
         return jax.jit(tick, donate_argnums=(1,))
 
-    def _build_decode_scan(self, n_ticks: int):
-        """``n_ticks`` pure-decode ticks in ONE dispatch.
-
-        Per-dispatch host latency is of the order of a decode tick's device
-        time, so the tick-per-dispatch loop serializes at host speed. Decode
-        growth is deterministic (one token/seq/tick)
-        so the host pre-allocates KV blocks for all ``n_ticks`` and the
-        whole loop — forward, paged KV writes, SAMPLING — runs on device in
-        a ``lax.scan``; one bulk [n, B] token fetch replaces n round trips.
-        Reference bar: ``inference/v2/engine_v2.py:107-242`` (whose CUDA
-        host loop is cheap per step; on TPU the scan is the idiomatic
-        equivalent).
-        """
-        cfg, attn = self.cfg, self._attention
-
-        def decode_n(params, pool, tokens, positions, tables, rng):
-            def body(carry, _):
-                pool, toks, pos, rng = carry
-                rng, sub = jax.random.split(rng)
-                logits, pool = PG.forward_paged(
-                    params, toks, pos, tables, pool, cfg, attention_fn=attn)
-                sampled = sample_logits(
-                    logits, sub, self.temperature, self.top_k,
-                    self.top_p).astype(jnp.int32)
-                return (pool, sampled, pos + 1, rng), sampled
-
-            (pool, toks, pos, _), out = jax.lax.scan(
-                body, (pool, tokens, positions, rng), None, length=n_ticks)
-            # final (toks, pos) are returned ON DEVICE so a follow-up window
-            # can chain on them without a host round trip (decode_stream's
-            # double buffering)
-            return out, pool, toks, pos              # out [n_ticks, B]
-
-        return jax.jit(decode_n, donate_argnums=(1,))
-
     def collective_ledger(self, n_tokens: Optional[int] = None,
                           fold: bool = True):
         """Compiled-collective ledger of one mixed tick at the given
@@ -1060,282 +995,9 @@ class FastGenEngine:
 
         return ledger_for_fastgen(self, n_tokens=n_tokens, fold=fold)[0]
 
-    def _blocks_needed(self, seq: _Seq, upto_pos: int) -> int:
-        return max(0, upto_pos // self.block_size + 1 - seq.held)
-
-    #: fused-decode scan lengths — a FIXED short ladder so the compile
-    #: cache stays a small grid however max_new/EOS shrink the remaining work
-    DECODE_TIERS = (64, 32, 8)
-
-    def decode_steps(self, max_ticks: int,
-                     allow_overshoot: bool = False) -> Dict[int, List[int]]:
-        """Fused multi-tick decode for an all-decode state. Returns
-        {uid: [tokens]} (EOS/max-len trimmed). Returns {} — caller falls
-        back to :meth:`step` — when any live sequence still needs prefill
-        or the pool/length headroom allows no ladder rung.
-
-        ``allow_overshoot``: run the smallest ladder rung even when it
-        exceeds ``max_ticks`` — callers with a fixed total budget
-        (generate_all) trim the extras; servers keeping admission latency
-        bounded leave it False.
-        """
-        self._assert_stream_drained()
-        self._expire_deadlines()
-        live = [self.seqs[u] for u in self._admit_order
-                if u in self.seqs and not self.seqs[u].done]
-        if not live or any(s.prefill_remaining > 0 or s.last_tok is None
-                           for s in live):
-            return {}
-        if max_ticks < 1:
-            return {}
-        headroom = min(self.max_len - 1 - s.pos for s in live)
-
-        def fits(tier):
-            return tier <= headroom and sum(
-                self._blocks_needed(s, s.pos + tier - 1)
-                for s in live) <= self.allocator.available(False)
-
-        n = 0
-        if allow_overshoot:
-            # round UP to the smallest tier covering the remaining work —
-            # one overshooting window (extras trimmed by the caller) beats
-            # a cascade of smaller windows each paying dispatch latency
-            for tier in reversed(self.DECODE_TIERS):
-                if tier >= max_ticks and fits(tier):
-                    n = tier
-                    break
-        if n < 1:
-            n = self._fit_decode_tier(
-                live, max_ticks if not allow_overshoot
-                else max(max_ticks, self.DECODE_TIERS[-1]))
-        if n < 1:
-            return {}
-        B = len(live)
-        Bt = self._slot_tier(B)
-        mb, tables, _ = self._decode_window_tensors(live, Bt, n)
-        tokens = np.zeros((Bt,), np.int32)
-        positions = np.zeros((Bt,), np.int32)
-        for i, s in enumerate(live):
-            tokens[i] = s.last_tok
-            positions[i] = s.pos                    # pad rows → trash block 0
-
-        key = ("dec", Bt, n, mb)
-        cold = key not in self._ticks
-        if cold:
-            self._ticks[key] = self._build_decode_scan(n)
-        sub = self._next_key()
-        t0 = time.perf_counter()
-        with telemetry.span("decode_window", ticks=n):
-            out, self.pool, _, _ = self._ticks[key](
-                self.params, self.pool, self._dev(tokens),
-                self._dev(positions), self._dev(tables[:, :mb]), sub)
-            out = np.asarray(jax.device_get(out))   # [n, Bt]
-        if not cold:
-            # a cold key folds the XLA compile into the window wall time
-            # (~seconds vs ~ms/token) — keep the latency histogram steady-
-            # state only, same reason the train side uses best-window
-            now = time.perf_counter()
-            self._observe_tok_lat((now - t0) / (n * B), n * B, now)
-        self._tick_end_t = None     # a fused window is in no tick's period
-        self._tm_ticks.inc(n, kind="decode", mb_tier=self._mb_tier_name(mb))
-        self._tm_occup.set(B / Bt, phase="decode")
-        self._tm_sched_gauges()
-        return self._drain_decode_out(out, live, n, pos_advanced=False)
-
-    def _drain_decode_out(self, out, live, n: int, pos_advanced: bool,
-                          pos0: Optional[List[int]] = None
-                          ) -> Dict[int, List[int]]:
-        """Fold a fused window's [n, Bt] sampled tokens into host
-        bookkeeping. ``pos_advanced``: decode_stream advances ``s.pos`` at
-        DISPATCH time (the next window chains on device before this one
-        drains) and passes ``pos0`` — each row's position BEFORE the
-        window — so the max-len cutoff applies at tick-time positions; the
-        synchronous path advances ``s.pos`` here."""
-        result: Dict[int, List[int]] = {}
-        for i, s in enumerate(live):
-            got: List[int] = []
-            for t in range(n):
-                tok = int(out[t, i])
-                if not pos_advanced:
-                    s.pos += 1      # this tick's input token entered the cache
-                s.last_tok = tok
-                before = len(s.generated)
-                self._note_token(
-                    s, tok,
-                    pos=None if pos0 is None else pos0[i] + t + 1)
-                if len(s.generated) > before:
-                    got.append(tok)
-                if s.done:
-                    break           # post-EOS rows are garbage — discard
-            result[s.uid] = got
-        return result
-
-    def decode_stream(self, window: int = 8):
-        """Generator of fused decode windows with ONE window always in
-        flight: window N+1 is dispatched chained on window N's on-device
-        final (tokens, positions) BEFORE N's tokens are fetched, so the
-        device never idles on the host loop (round-3 verdict: "the host
-        still sits in the loop between fused windows"). Yields
-        {uid: [tokens]} per drained window.
-
-        The chain holds while the live set, slot tier and window tier are
-        unchanged and no admission is pending; any change (EOS discovered
-        at drain, new put(), block exhaustion) drains the in-flight window
-        and the generator returns — callers re-enter after rescheduling.
-        A sequence that hits EOS one window early costs at most one
-        window of wasted ticks (same class as decode_steps' overshoot).
-
-        If the CALLER breaks out (closing the generator), the in-flight
-        window is still drained into engine bookkeeping — those tokens are
-        visible via ``query``/``seqs[uid].generated`` but were never
-        yielded; interactive callers should reconcile counts from engine
-        state after an early exit.
-        """
-        self._assert_stream_drained()   # a 2nd concurrent stream would
-        # read the optimistic pos/stale last_tok and corrupt both chains
-        pending = None          # (out_dev, live, n, pos0)
-        toks_dev = pos_dev = tables_dev = tables_mb = None
-        chain = None            # (tier Bt, n, live uids) the chain was built on
-        prev_drain_t = [None]   # drain-to-drain timing = steady-state rate
-
-        def drain(p):
-            p_out, p_live, p_n, p_pos0 = p
-            out_h = np.asarray(jax.device_get(p_out))
-            now = time.perf_counter()
-            if prev_drain_t[0] is not None:
-                # with a window always in flight, drain-to-drain wall time
-                # over the window's tokens IS the per-token serving rate
-                self._observe_tok_lat(
-                    (now - prev_drain_t[0]) / max(1, p_n * len(p_live)),
-                    p_n * len(p_live), now)
-            prev_drain_t[0] = now
-            self._tick_end_t = None   # as after decode_steps
-            return self._drain_decode_out(
-                out_h, p_live, p_n, pos_advanced=True, pos0=p_pos0)
-
-        last = None
-        try:
-            while True:
-                # deadline expiry changes the live set, which breaks the
-                # chain below and drains — same contract as a flush()
-                # mid-stream (the in-flight window's rows for an expired
-                # sequence fold into a _note_token no-op)
-                self._expire_deadlines()
-                live = [self.seqs[u] for u in self._admit_order
-                        if u in self.seqs and not self.seqs[u].done]
-                n = self._fit_decode_tier(live, window)
-                Bt = self._slot_tier(len(live)) if live else 0
-                key_now = (Bt, n, tuple(s.uid for s in live))
-                if n < 1 or (chain is not None and key_now != chain):
-                    break       # drain in-flight below; caller reschedules
-                chain = key_now
-                mb, tables, grew = self._decode_window_tensors(live, Bt, n)
-                if tables_dev is None or grew or mb != tables_mb:
-                    # upload tables only when a block was added or the mb
-                    # tier changed — most windows reuse the cached device
-                    # copy, keeping the chained dispatch free of host
-                    # transfers (the whole point of the double buffer)
-                    tables_dev = self._dev(tables[:, :mb])
-                    tables_mb = mb
-                if toks_dev is None:
-                    toks = np.zeros((Bt,), np.int32)
-                    pos = np.zeros((Bt,), np.int32)
-                    for i, s in enumerate(live):
-                        toks[i] = s.last_tok
-                        pos[i] = s.pos
-                    toks_dev, pos_dev = self._dev(toks), self._dev(pos)
-                key = ("dec", Bt, n, mb)
-                if key not in self._ticks:
-                    self._ticks[key] = self._build_decode_scan(n)
-                pos0 = [s.pos for s in live]
-                with telemetry.span("decode_window", ticks=n):
-                    out, self.pool, toks_dev, pos_dev = self._ticks[key](
-                        self.params, self.pool, toks_dev, pos_dev,
-                        tables_dev, self._next_key())
-                self._tm_ticks.inc(n, kind="decode",
-                                   mb_tier=self._mb_tier_name(mb))
-                self._tm_occup.set(len(live) / Bt, phase="decode")
-                self._tm_sched_gauges()
-                # device is now computing THIS window; positions advance
-                # optimistically so the next iteration's block math is right
-                for s in live:
-                    s.pos += n
-                prev, pending = pending, (out, live, n, pos0)
-                # while a window is in flight, s.pos is optimistically a
-                # window AHEAD of s.last_tok: any interleaved step()/put()
-                # would decode a stale token at an advanced position and
-                # silently corrupt greedy parity — flag it so those entry
-                # points fail loudly instead (cleared when drained)
-                self._stream_inflight = True
-                if prev is not None:
-                    yield drain(prev)
-                    if any(s.done for s in prev[1]):
-                        # EOS discovered late: the in-flight window runs
-                        # garbage for that row (bounded waste); drain it
-                        # and break the chain
-                        res = drain(pending)
-                        pending = None
-                        self._stream_inflight = False
-                        yield res
-                        return
-        finally:
-            # caller broke out (GeneratorExit) or chain ended: the
-            # in-flight window MUST fold into host bookkeeping or
-            # last_tok/pos go stale and later windows decode garbage
-            if pending is not None:
-                last = drain(pending)
-                pending = None
-            self._stream_inflight = False
-        if last is not None:
-            yield last
-
-    def _fit_decode_tier(self, live: List[_Seq], cap: int) -> int:
-        """Largest DECODE_TIERS rung ≤ ``cap`` that fits every live row's
-        length headroom and the allocator's free blocks (shared by
-        decode_steps and decode_stream — the two paths must never diverge
-        on block accounting or greedy parity breaks)."""
-        if not live or any(s.prefill_remaining > 0 or s.last_tok is None
-                           for s in live):
-            return 0
-        headroom = min(self.max_len - 1 - s.pos for s in live)
-        for tier in self.DECODE_TIERS:
-            if tier <= min(cap, headroom) and sum(
-                    self._blocks_needed(s, s.pos + tier - 1)
-                    for s in live) <= self.allocator.available(False):
-                return tier
-        return 0
-
-    def _decode_window_tensors(self, live: List[_Seq], Bt: int, n: int):
-        """Allocate blocks for an n-tick window and build the padded block
-        tables; returns (mb tier, tables [Bt, max_blocks], grew — whether
-        any table changed, so chained callers know a cached device copy is
-        stale)."""
-        grew = False
-        for s in live:
-            before = s.held
-            self._ensure_blocks(s, s.pos + n - 1)
-            grew |= s.held != before
-        mb_need = (max(s.pos for s in live) + n - 1) // self.block_size + 1
-        mb = self._mb_tier(mb_need)
-        tables = np.zeros((Bt, self.max_blocks_per_seq), np.int32)
-        for i, s in enumerate(live):
-            tables[i] = s.table
-        return mb, tables, grew
-
     # ------------------------------------------------------------------ #
     def can_schedule(self) -> bool:
         return self.allocator.free_blocks > 0
-
-    def _assert_stream_drained(self) -> None:
-        """decode_stream misuse guard: while its double-buffered window is
-        in flight, s.pos is one window ahead of s.last_tok — interleaving
-        step()/decode_steps()/put() would decode a stale token at an
-        advanced position and silently corrupt output. Exhaust or close()
-        the generator first (closing drains the window)."""
-        if getattr(self, "_stream_inflight", False):
-            raise RuntimeError(
-                "decode_stream window in flight — exhaust or close the "
-                "stream before step()/decode_steps()/put()")
 
     def put(self, uids: Sequence[int], prompts: Sequence[Sequence[int]],
             deadline_s: Optional[float] = None) -> None:
@@ -1345,10 +1007,6 @@ class FastGenEngine:
         overrides the engine's ``request_deadline_s`` for this admission
         batch: past the deadline the request is dropped at the next
         scheduling tick (``fastgen_deadline_expired_total``)."""
-        # NOT guarded by _assert_stream_drained: mid-stream admission is a
-        # documented pattern (decode_stream drains + returns when the live
-        # set changes) and put() is host bookkeeping only — it cannot
-        # observe the optimistic s.pos/last_tok skew
         if deadline_s is None:
             deadline_s = self.request_deadline_s
         # validate the WHOLE batch before mutating anything: a ValueError
@@ -1497,7 +1155,6 @@ class FastGenEngine:
         allocator's two lists; the snapshot is the engine's
         (``_snapshot_host``), taken after the deadlines' sweep (an expiry
         is not undone) and before ``schedule_tick`` opens."""
-        self._assert_stream_drained()
         self._expire_deadlines()
         snap = self._snapshot_host()
         try:
@@ -1767,7 +1424,7 @@ class FastGenEngine:
                 # time over decode rows slightly OVERcounts when prefill
                 # shares the tick — conservative in the right direction
                 # for those hints. Cold keys fold the XLA compile into
-                # wall time and are skipped, same policy as decode_steps.
+                # wall time and are skipped.
                 self._observe_tok_lat(
                     (commit_span.t0 - tick_span.t0) / n_decode_rows,
                     n_decode_rows, commit_span.t0)
@@ -1801,9 +1458,8 @@ class FastGenEngine:
                 for t, mass in enumerate(exit_mass.tolist()):
                     self._tm_exit_mass.inc(mass, **{"pass": str(t)})
 
-            # what ``_note_token`` does a sequence, for all the heads at
-            # once: a sequence meets Python where it sees its first token
-            # or ends
+            # every head's token folded into its sequence at once: a
+            # sequence meets Python where it sees its first token or ends
             toks = sampled[:n_heads] if gathered else sampled[head_rows]
             st.pos[dec] += 1        # the decode input token entered the cache
             st.last_tok[heads] = toks
@@ -1950,29 +1606,6 @@ class FastGenEngine:
         if typ[1] >= _SLOW_STREAK:
             del self._typical[(kind, Tn, mb)]
 
-    def _note_token(self, seq: _Seq, tok: int,
-                    pos: Optional[int] = None) -> None:
-        """A sampled token of a fused window, folded into its sequence
-        (``step()``'s commit does the same for all of a tick's heads at
-        once). ``pos``: the sequence position at the tick that PRODUCED
-        this token — decode_stream drains with ``seq.pos`` already advanced
-        one to two windows ahead, so the max-len cutoff must use the
-        tick-time position, not the optimistic current one."""
-        if seq.done:
-            return
-        # TTFT anchors on the FIRST sampled token even when it's EOS —
-        # excluding immediate-EOS sequences would bias the distribution
-        # toward longer-lived ones
-        self._tm_first_token(seq)
-        if self.eos_token_id is not None and tok == self.eos_token_id:
-            self._finish(seq)
-            return
-        seq.generated.append(tok)
-        seq._st.gen_len[seq.row] += 1
-        self._tm_gen_tok.inc()
-        if (seq.pos if pos is None else pos) + 1 >= self.max_len:
-            self._finish(seq)
-
     def _finish(self, seq: _Seq) -> None:
         """Mark done and release KV blocks immediately — a finished sequence
         never decodes again, and holding its blocks until flush() starves
@@ -2015,11 +1648,6 @@ class FastGenEngine:
                 if blocks:
                     self._tm_evict.inc(len(blocks))
                 self.allocator.free(blocks)
-                # an in-flight decode_stream window may still hold a
-                # reference to this _Seq and drain into it later: it is
-                # done (so _note_token no-ops) and keeps a row of its own
-                # that holds no block (or _finish would double-free into
-                # the allocator)
                 d.done = True
                 self._rows.release(d)
                 self._admit_order.remove(uid)
@@ -2027,34 +1655,22 @@ class FastGenEngine:
         self._tm_sched_gauges()
 
     def generate_all(self, uids, prompts, max_new_tokens: int = 32):
-        """Convenience driver: put + serve. SplitFuse ticks stream the
-        prefill and the fused decode scan covers pure-decode phases."""
+        """The tests' driver: ``put``, ``step()`` until every uid has
+        ``max_new_tokens`` tokens or is done (a sequence is finished at its
+        count, so its blocks go back while the others run), ``query``,
+        ``flush``. Returns ``{uid: tokens}``."""
         self.put(uids, prompts)
-        self._generate_dynamic(uids, max_new_tokens)
-        out = {u: self.query(u)[1][:max_new_tokens] for u in uids}
-        self.flush(uids)
-        return out
-
-    def _generate_dynamic(self, uids, max_new_tokens: int) -> None:
         while True:
             for u in uids:
-                s = self.seqs.get(u)
-                if s and not s.done and len(s.generated) >= max_new_tokens:
+                s = self.seqs[u]
+                if not s.done and len(s.generated) >= max_new_tokens:
                     self._finish(s)
-            live = [self.seqs[u] for u in uids
-                    if u in self.seqs and not self.seqs[u].done]
-            if not live:
+            if all(self.seqs[u].done for u in uids):
                 break
-            # max (not min) remaining: sequences that hit max_new mid-scan
-            # keep decoding into their own blocks and get trimmed at the
-            # loop top — fewer, larger fused dispatches win over exactness
-            remaining = max(max_new_tokens - len(s.generated) for s in live)
-            got = self.decode_steps(remaining, allow_overshoot=True) \
-                if remaining > 0 else {}
-            if got:
-                continue
-            out = self.step()
-            if not out and not any(
+            if not self.step() and not any(
                     s.prefill_remaining > 0 and not s.done
                     for s in self.seqs.values()):
                 break  # stalled: no tokens and nothing left to prefill
+        out = {u: self.query(u)[1][:max_new_tokens] for u in uids}
+        self.flush(uids)
+        return out

@@ -22,18 +22,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmarks.reference import afmoe_lm as R
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.inference.fastgen import FastGenEngine
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.hf_import import (config_from_hf, import_hf_model,
                                             params_from_afmoe)
 from deepspeed_tpu.moe import layer as MOE
 from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+from family_harness import CATALOG, TOL, rel
 
-TOL = 2e-5
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 _TYPES = {"w": "sliding_attention", "f": "full_attention"}
 
 
@@ -55,106 +54,56 @@ def _hf(kinds: str, dense: int, **kw):
 #: 4 of 16 experts held) and the published pattern at toy width: a dense
 #: prefix of three layers, three full layers, an expert stack that starts
 #: inside a period and does not end on a period's boundary
-MODELS = {
+FAMILY = H.Family(R, tokens=(2, 60), models={
     "cut": _hf("wwwwf", 1, num_experts=4, router_experts=16),
     "published": _hf("wwwfwwwfwwwfww"[:13], 3),
-}
+})
+MODELS = FAMILY.models
+STACKS = sorted(MODELS)
 
 
-def _rel(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-
-
-def _noisy(params, seed=1, std=0.05):
-    """Norm gains, the router's bias and every matrix off their start, so
-    a dropped one shows."""
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return tree.unflatten([x + std * jax.random.normal(k, x.shape)
-                           for x, k in zip(leaves, keys)])
-
-
-@pytest.fixture(scope="module", params=sorted(MODELS))
+@pytest.fixture(scope="module", params=STACKS)
 def model(request):
-    hf = MODELS[request.param]
-    cfg = config_from_hf(types.SimpleNamespace(**hf))
-    params = _noisy(T.init_params(cfg, jax.random.PRNGKey(0)))
-    toks = np.random.default_rng(0).integers(0, 128, (2, 60)).astype(np.int32)
-    with jax.default_matmul_precision("highest"):
-        whole = T.forward(params, jnp.asarray(toks), cfg)
-    return cfg, params, toks, whole, R.arch_from_config(hf, hf)
+    m = FAMILY.model(request.param)
+    return m.cfg, m.params, m.toks, H.whole_forward(FAMILY, m), m.arch
 
 
-def _engine(cfg, params, **kw):
-    kw = {"n_blocks": 64, "block_size": 4, "max_blocks_per_seq": 16,
-          "token_budget": 16, "state_slots": 3, "use_pallas_kernel": False,
-          **kw}
-    return FastGenEngine(cfg, params, **kw)
+MISTAKES = ("no-gate", "rope-on-full", "no-window", "top-3", "no-route-scale",
+            "no-shared", "no-emb-multiplier", "no-post-norms", "other-experts")
 
 
-def _drive(eng, cfg, toks, attn, chunk, n_prompt):
-    """The runner's check (``benchmarks/runners/serve.py::check_logits``) in
-    small: every sequence ``allocate``d once, ticks of the flat prompt rows
-    ``chunk`` at a time, then decode ticks; logits of every position."""
-    Tn, mb, bs = eng.token_budget, eng.max_blocks_per_seq, eng.block_size
-    S = toks.shape[1]
-    tabs, blocks = [], []
-    for _ in toks:
-        b = eng.allocator.allocate(S // bs + 1)
-        t = np.zeros(mb, np.int32)
-        t[:len(b)] = b
-        tabs.append(t)
-        blocks.append(b)
-    fwd = jax.jit(lambda pr, pool, t, p, tb: PG.forward_paged(
-        pr, t, p, tb, pool, cfg, attention_fn=attn))
-    got = {}
-
-    def tick(rows):
-        t = np.zeros(Tn, np.int32)
-        p = np.zeros(Tn, np.int32)
-        tb = np.zeros((Tn, mb), np.int32)
-        for r, (i, pos) in enumerate(rows):
-            t[r], p[r], tb[r] = toks[i, pos], pos, tabs[i]
-        with jax.default_matmul_precision("highest"):
-            lg, eng.pool = fwd(eng.params, eng.pool, jnp.asarray(t),
-                               jnp.asarray(p), jnp.asarray(tb))
-        for r, (i, pos) in enumerate(rows):
-            got[(i, pos)] = lg[r]
-
-    flat = [(i, p) for i in range(len(toks)) for p in range(n_prompt)]
-    for lo in range(0, len(flat), chunk):
-        tick(flat[lo:lo + chunk])
-    for p in range(n_prompt, S):
-        tick([(i, p) for i in range(len(toks))])
-    for b in blocks:
-        eng.allocator.free(b)
-    return jnp.stack([jnp.stack([got[(i, p)] for p in range(S)])
-                      for i in range(len(toks))])
+def _mistakes(cfg, params):
+    """Each fault the cell's notes list, made in the program's config (or
+    its parameters)."""
+    return {
+        "no-gate": (dict(attn_gate=False), params),
+        "rope-on-full": (dict(kind_rope=()), params),
+        "no-window": (dict(attn_window=4096), params),
+        "top-3": (dict(moe_top_k=3), params),
+        "no-route-scale": (dict(moe_route_scale=1.0), params),
+        "no-shared": (dict(moe_shared_size=0), {**params, "blocks": {
+            k: v for k, v in params["blocks"].items()
+            if not k.startswith("sw_")}}),
+        "no-emb-multiplier": (dict(emb_multiplier=1.0), params),
+        "no-post-norms": (dict(post_norms=False), params),
+        "other-experts": (dict(moe_first_expert=4), params),
+    }
 
 
-def test_whole_forward_matches_the_reference(model):
-    cfg, params, toks, whole, arch = model
-    assert _rel(whole, R.forward_logits(params, toks, arch)) < TOL
-
-
-@pytest.mark.parametrize("attn,chunk", [
-    (None, 13),               # chunk and sequence boundaries fall mid-tick
-    (paged_attention, 13),    # the kernels (interpret mode) under the tick
-    (None, 16),               # a full tick: the ring holds window + run
-])
-def test_paged_ticks_match_whole_forward_and_reference(model, attn, chunk):
-    """60 positions under a window of 16 and a ring of 32: the ring wraps,
-    the window's edge falls inside chunks, runs are longer than a block,
-    the second sequence starts in the tick that ends the first, and every
-    pool starts full of garbage (a position outside a window, or a ring
-    block not yet written, must not be read)."""
-    cfg, params, toks, whole, arch = model
-    eng = _engine(cfg, params)
-    eng.pool = jax.tree.map(lambda x: x + 7.0, eng.pool)
-    out = _drive(eng, cfg, toks, attn, chunk, n_prompt=50)
-    assert _rel(out, whole) < TOL
-    assert _rel(out, R.forward_logits(params, toks, arch)) < TOL
-    assert eng.allocator.free_slots == 3
+test_whole_forward_matches_the_reference = H.whole_forward_test(
+    FAMILY, STACKS)
+# 60 positions under a window of 16 and a ring of 32: the ring wraps, the
+# window's edge falls inside chunks, runs are longer than a block (a
+# position outside a window, or a ring block not yet written, must not be
+# read)
+test_paged_ticks_match_whole_forward_and_reference = H.paged_ticks_test(
+    FAMILY, STACKS, n_prompt=50, cases=[
+        (None, 13, TOL, {}),      # chunk and sequence boundaries fall mid-tick
+        (paged_attention, 13, TOL, {}),   # the kernels (interpret mode)
+        (None, 16, TOL, {}),      # a full tick: the ring holds window + run
+    ])
+test_a_mistake_made_on_purpose_is_seen = H.program_mistake_test(
+    FAMILY, "cut", MISTAKES, _mistakes, toks_of=lambda t: t[:1, :40])
 
 
 def test_segments_periods_and_pools(model):
@@ -181,12 +130,7 @@ def test_segments_periods_and_pools(model):
     assert pool["k"].shape == (kinds.count("full"), 40, 4, 2, 16)
     assert pool["wk"].shape == (kinds.count("window"), 4, 8, 4, 2, 16)
     assert PG.ring_blocks(cfg, 4, 16) * 4 == cfg.attn_window + 16
-    axes = T.param_logical_axes(cfg)
-    flat_p = dict(jax.tree_util.tree_flatten_with_path(params)[0])
-    flat_a = dict(jax.tree_util.tree_flatten_with_path(
-        axes, is_leaf=lambda x: isinstance(x, tuple))[0])
-    assert flat_p.keys() == flat_a.keys()
-    assert all(len(flat_a[k]) == flat_p[k].ndim for k in flat_p)
+    H.assert_axes_name_every_leaf(cfg, params)
     # (num_params counts a bias on the final RMSNorm: test_latent_moe_serving)
     assert cfg.num_params() - cfg.hidden_size == sum(
         x.size for x in jax.tree.leaves(params))
@@ -207,7 +151,7 @@ def test_window_layers_hold_a_ring_and_full_layers_grow(model):
     """After 3 x the ring's positions a sequence holds one slot, the full
     layers' blocks alone grew, and no other slot's ring was touched."""
     cfg, params, toks, *_ = model
-    eng = _engine(cfg, params, n_blocks=40, max_blocks_per_seq=32)
+    eng = H.engine(FAMILY, cfg, params, n_blocks=40, max_blocks_per_seq=32)
     n_full = cfg.layer_kinds.count("full")
     ring = 8 * 4
     eng.put([7], [toks[0, :50].tolist()])
@@ -224,39 +168,24 @@ def test_window_layers_hold_a_ring_and_full_layers_grow(model):
     assert wk[:, 1].all() and not wk[:, 2:].any()
 
 
-def test_slots_are_freed_and_admission_waits_for_one(model):
+@pytest.mark.parametrize("name", STACKS)
+def test_slots_are_freed_and_admission_waits_for_one(name):
     """Three requests on two slots: the third's first chunk waits, is
     counted once, takes the slot the first to end hands on; greedy tokens
     are the reference's; finish, flush and expiry give slot and blocks
     back."""
-    cfg, params, toks, _, arch = model
-    eng = _engine(cfg, params, state_slots=2)
+    m = FAMILY.model(name)
+    toks = m.toks
+    eng = H.engine(FAMILY, m.cfg, m.params, state_slots=2)
     prompts = {1: toks[0, :9].tolist(), 2: toks[1, :30].tolist(),
                3: toks[0, 20:37].tolist()}
     want = {1: 3, 2: 12, 3: 4}
     waits = eng._tm_slot_waits.total()
-    eng.put(list(prompts), list(prompts.values()))
-    slots_seen = {}
-    with jax.default_matmul_precision("highest"):
-        for _ in range(200):
-            eng.step()
-            for u, s in eng.seqs.items():
-                if s.blocks:
-                    slots_seen[u] = s.blocks[0]
-                if not s.done and len(s.generated) >= want[u]:
-                    eng._finish(s)
-            if all(s.done for s in eng.seqs.values()):
-                break
+    slots_seen, _ = H.serve_greedy(eng, prompts, want)
     assert eng._tm_slot_waits.total() - waits == 1
     assert all(b in (1, 2) for b in slots_seen.values())
     assert slots_seen[3] == slots_seen[1]     # handed on by the first to end
-    for u in (1, 2, 3):
-        out = eng.query(u)[1][:want[u]]
-        seq = np.asarray(prompts[u] + out, np.int32)[None]
-        ref = R.forward_logits(params, seq, arch)[0]
-        n = len(prompts[u])
-        assert out == [int(t) for t in jnp.argmax(
-            ref[n - 1:n - 1 + want[u]], axis=-1)]
+    H.assert_greedy_tokens_are_the_reference_s(FAMILY, m, eng, prompts, want)
     eng.flush([1, 2, 3])
     assert eng.allocator.free_slots == 2 and eng.allocator.free_blocks == 63
     # flush of a live sequence, and a deadline that has passed
@@ -270,7 +199,7 @@ def test_slots_are_freed_and_admission_waits_for_one(model):
 
 def test_failed_tick_leaves_slots_and_rings_as_they_were(model):
     cfg, params, toks, *_ = model
-    eng = _engine(cfg, params)
+    eng = H.engine(FAMILY, cfg, params)
     eng.put([1], [toks[0, :20].tolist()])
     eng.step()
     eng.put([2], [toks[1, :9].tolist()])
@@ -337,8 +266,8 @@ def test_eight_shares_add_up_to_the_uncut_layer():
         # one share with the shared expert is that share's chip
         one, _ = _share(x, lp, experts, k, 6, 2)
     total = sum(p for p, _ in parts) + shared_once
-    assert _rel(total, want) < TOL and _rel(whole, want) < TOL
-    assert _rel(one, parts[3][0] + shared_once) < TOL
+    assert rel(total, want) < TOL and rel(whole, want) < TOL
+    assert rel(one, parts[3][0] + shared_once) < TOL
     for _, r in parts:
         np.testing.assert_array_equal(r, rows)
     assert rows.shape == (16,) and int(rows.sum()) == 24 * k
@@ -347,7 +276,7 @@ def test_eight_shares_add_up_to_the_uncut_layer():
         ref_share, _ = R._moe(
             x, lp, {n: w[None, 6:8] for n, w in experts.items()}, 0,
             {**arch, "first_expert": 6})
-    assert _rel(one, ref_share) < TOL
+    assert rel(one, ref_share) < TOL
 
 
 def test_a_share_spends_no_grouped_matmul_rows_on_absent_experts():
@@ -401,10 +330,8 @@ def test_serving_weight_tile_keeps_k_whole_where_vmem_has_room(shape, want):
 def test_tick_reads_back_rows_for_held_and_for_all_experts():
     """The engine's counters and span attributes of a share: pairs on held
     experts against all pairs, held experts with rows."""
-    hf = MODELS["cut"]
-    cfg = config_from_hf(types.SimpleNamespace(**hf))
-    params = _noisy(T.init_params(cfg, jax.random.PRNGKey(0)))
-    eng = _engine(cfg, params)
+    m = FAMILY.model("cut")
+    eng = H.engine(FAMILY, m.cfg, m.params)
     pairs = telemetry.counter("fastgen_expert_pairs_total")
     before = pairs.total()
     spans = []
@@ -473,7 +400,7 @@ def test_state_dict_under_the_family_s_names_imports(model):
     full = dataclasses.replace(cfg, n_experts=cfg.router_experts,
                                moe_router_experts=0)
     if cfg.moe_router_experts:            # a checkpoint holds every expert
-        params = _noisy(T.init_params(full, jax.random.PRNGKey(2)))
+        params = H.noisy(T.init_params(full, jax.random.PRNGKey(2)))
     sd = {"model.embed_tokens.weight": params["tok_emb"],
           "model.norm.weight": params["final_norm"]["scale"],
           "lm_head.weight": params["lm_head"].T}
@@ -514,65 +441,13 @@ def test_state_dict_under_the_family_s_names_imports(model):
     else:
         want = params
         with jax.default_matmul_precision("highest"):
-            assert _rel(T.forward(got, jnp.asarray(toks), cfg), whole) < TOL
-    flat_w = dict(jax.tree_util.tree_flatten_with_path(want)[0])
-    flat_g = dict(jax.tree_util.tree_flatten_with_path(got)[0])
-    assert flat_w.keys() == flat_g.keys()
-    for k in flat_w:
-        np.testing.assert_array_equal(np.asarray(flat_w[k]), flat_g[k])
+            assert rel(T.forward(got, jnp.asarray(toks), cfg), whole) < TOL
+    H.assert_same_tree(want, got)
     hf = types.SimpleNamespace(**MODELS[
         "cut" if cfg.moe_router_experts else "published"])
     assert import_hf_model((sd, hf))[0] == cfg
 
 
-@pytest.mark.parametrize("entry", ["forward_decode", "pipeline", "tp", "pld"])
-def test_entry_points_that_refuse_window_and_full_layers(model, entry):
-    cfg, params, toks, *_ = model
-    with pytest.raises(NotImplementedError, match="layer kinds|layer_kinds"):
-        if entry == "forward_decode":
-            T.forward_decode(params, jnp.asarray(toks[:, :4]), {},
-                             jnp.zeros((2,), jnp.int32), cfg)
-        elif entry == "pipeline":
-            T.pipelined_lm_loss(params, jnp.asarray(toks), cfg, 2)
-        elif entry == "pld":
-            T.forward_hidden(params, jnp.asarray(toks), cfg,
-                             pld_keep=jnp.ones((cfg.num_layers,)))
-        else:
-            from deepspeed_tpu.comm.mesh import (MeshConfig, initialize_mesh,
-                                                 reset_mesh)
+test_entry_points_that_refuse_window_and_full_layers = H.entry_points_refuse_test(
+    FAMILY, STACKS)
 
-            reset_mesh()
-            initialize_mesh(MeshConfig(data=4, tensor=2))
-            try:
-                _engine(cfg, params, tp=True)
-            finally:
-                reset_mesh()
-
-
-@pytest.mark.parametrize("mistake", [
-    "no-gate", "rope-on-full", "no-window", "top-3", "no-route-scale",
-    "no-shared", "no-emb-multiplier", "no-post-norms", "other-experts"])
-def test_a_mistake_made_on_purpose_is_seen(mistake):
-    """Each fault the cell's notes list, made in the program's config (or
-    its parameters), moves the logits by far more than the tolerance."""
-    hf = MODELS["cut"]
-    cfg = config_from_hf(types.SimpleNamespace(**hf))
-    params = _noisy(T.init_params(cfg, jax.random.PRNGKey(0)))
-    toks = np.random.default_rng(0).integers(0, 128, (1, 40)).astype(np.int32)
-    want = R.forward_logits(params, toks, R.arch_from_config(hf, hf))
-    wrong, p = {
-        "no-gate": (dict(attn_gate=False), params),
-        "rope-on-full": (dict(kind_rope=()), params),
-        "no-window": (dict(attn_window=4096), params),
-        "top-3": (dict(moe_top_k=3), params),
-        "no-route-scale": (dict(moe_route_scale=1.0), params),
-        "no-shared": (dict(moe_shared_size=0), {**params, "blocks": {
-            k: v for k, v in params["blocks"].items()
-            if not k.startswith("sw_")}}),
-        "no-emb-multiplier": (dict(emb_multiplier=1.0), params),
-        "no-post-norms": (dict(post_norms=False), params),
-        "other-experts": (dict(moe_first_expert=4), params),
-    }[mistake]
-    with jax.default_matmul_precision("highest"):
-        got = T.forward(p, jnp.asarray(toks), dataclasses.replace(cfg, **wrong))
-    assert _rel(got, want) > 100 * TOL

@@ -98,125 +98,90 @@ def test_fastgen_greedy_matches_slot_engine():
         assert got[u] == want[u], (u, got[u], want[u])
 
 
-def test_decode_steps_matches_per_tick_steps():
-    """The fused lax.scan decode (one dispatch) produces exactly the greedy
-    tokens of N individual step() ticks, with identical host bookkeeping
-    (pos, blocks, generated)."""
-    rng = np.random.default_rng(4)
-    prompts = _prompts(rng, [7, 21])
-    uids = [1, 2]
-
-    def mk():
-        return FastGenEngine("tiny", n_blocks=32, block_size=16,
-                             max_blocks_per_seq=8, token_budget=32,
-                             temperature=0.0, seed=0, **CFG)
-
-    a, b = mk(), mk()
-    for eng in (a, b):
-        eng.put(uids, prompts)
-        while any(eng.seqs[u].prefill_remaining > 0 for u in uids):
-            eng.step()
-
-    for _ in range(8):
-        a.step()
-    got = b.decode_steps(8)
-    assert set(got) == set(uids)
-    for u in uids:
-        assert a.seqs[u].generated == b.seqs[u].generated, u
-        assert a.seqs[u].pos == b.seqs[u].pos, u
-        assert got[u] == b.seqs[u].generated[-len(got[u]):]
-
-    # fused path falls back (returns {}) while prefill is pending
-    c = mk()
-    c.put([9], _prompts(rng, [40]))
-    assert c.decode_steps(4) == {}
-
-
-def test_decode_stream_matches_decode_steps():
-    """decode_stream (double-buffered windows chained on device) produces
-    the same greedy tokens and host bookkeeping as synchronous
-    decode_steps windows, including after an EARLY BREAK (the in-flight
-    window must fold into engine state, not vanish)."""
-    rng = np.random.default_rng(5)
-    prompts = _prompts(rng, [7, 21, 13])
-    uids = [1, 2, 3]
-
-    def mk():
-        return FastGenEngine("tiny", n_blocks=64, block_size=16,
-                             max_blocks_per_seq=8, token_budget=32,
-                             temperature=0.0, seed=0, **CFG)
-
-    a, b, c = mk(), mk(), mk()
-    for eng in (a, b, c):
-        eng.put(uids, prompts)
-        while any(eng.seqs[u].prefill_remaining > 0 for u in uids):
-            eng.step()
-
-    for _ in range(3):
-        a.decode_steps(8)
-
-    base = {u: len(b.seqs[u].generated) for u in uids}  # prefill-emitted
-    served = []
-    for emitted in b.decode_stream(window=8):
-        served.append(emitted)
-        if len(served) == 3:
-            break
-    # yielded windows + in-flight drain must equal engine state
-    for u in uids:
-        assert a.seqs[u].generated[:24] == b.seqs[u].generated[:24], u
-    yielded = {u: sum((e.get(u, []) for e in served), []) for u in uids}
-    for u in uids:
-        # engine state may be AHEAD of what was yielded (the closed
-        # stream's in-flight window) but never behind; yielded tokens
-        # follow the prefill-emitted ones
-        got = b.seqs[u].generated[base[u]:]
-        assert got[:len(yielded[u])] == yielded[u]
-        assert len(got) >= len(yielded[u])
-
-    # run-to-exhaustion (no break) matches too, via repeated re-entry
-    for _ in range(3):
-        for emitted in c.decode_stream(window=8):
-            pass
-        if all(len(c.seqs[u].generated) >= 24 for u in uids):
-            break
-    for u in uids:
-        assert a.seqs[u].generated[:24] == c.seqs[u].generated[:24], u
-
-
-def test_decode_stream_max_len_tail_matches_sync():
-    """Sequences approaching max_len: the stream drain must apply the
-    length cutoff at TICK-TIME positions (s.pos runs 1-2 windows ahead of
-    the drain) — equal FINAL lengths with the sync path, not just a common
-    prefix (the prefix check masks tail truncation)."""
+@pytest.mark.parametrize("short_of_the_wall", [0, 1])
+def test_step_stops_at_the_max_len_wall_where_the_slot_engine_stops(
+        short_of_the_wall):
+    """Through ``step()`` to the ``max_len`` wall: a prompt that lands
+    exactly on ``max_len - 1`` (or one short of it) beside one the wall
+    stops later, asked for more tokens than the wall leaves: every sequence
+    ends where the slot engine ends it, token for token, and its blocks
+    come back when it does."""
     rng = np.random.default_rng(6)
-    # max_len 128, window 8: prompts ≡ 7 (mod 8) land pos EXACTLY on
-    # max_len-1 after whole windows, so the length cutoff fires on the
-    # final drained tick (the case the tick-time position check protects)
-    prompts = _prompts(rng, [103, 95])
-    uids = [1, 2]
-
-    def mk():
-        return FastGenEngine("tiny", n_blocks=64, block_size=16,
-                             max_blocks_per_seq=8, token_budget=128,
-                             temperature=0.0, seed=0, **CFG)
-
-    a, b = mk(), mk()
-    for eng in (a, b):
-        eng.put(uids, prompts)
-        while any(eng.seqs[u].prefill_remaining > 0 for u in uids):
-            eng.step()
-    while a.decode_steps(8):        # sync: run to the max_len wall
-        pass
-    for _ in range(8):              # stream: re-enter until exhausted
-        served = False
-        for _e in b.decode_stream(window=8):
-            served = True
-        if not served:
+    prompts = _prompts(rng, [127 - short_of_the_wall, 100])
+    uids, new = [1, 2], 40
+    slot = RaggedInferenceEngine("tiny", max_slots=4, max_len=128,
+                                 temperature=0.0, seed=0, **CFG)
+    want = slot.generate_all(uids, prompts, max_new_tokens=new)
+    assert [len(want[u]) for u in uids] == [1 + short_of_the_wall, 28]
+    fg = FastGenEngine("tiny", n_blocks=64, block_size=16,
+                       max_blocks_per_seq=8, token_budget=128,
+                       temperature=0.0, seed=0, **CFG)
+    assert fg.max_len == 128
+    fg.put(uids, prompts)
+    for _ in range(60):
+        fg.step()
+        if all(fg.seqs[u].done for u in uids):
             break
     for u in uids:
-        assert len(a.seqs[u].generated) == len(b.seqs[u].generated), u
-        assert a.seqs[u].generated == b.seqs[u].generated, u
-        assert a.seqs[u].done == b.seqs[u].done, u
+        assert fg.query(u) == (True, want[u]), u
+        assert not fg.seqs[u].blocks        # given back at the wall
+    assert fg.step() == {}                  # nothing decodes past it
+    fg.flush(uids)
+    assert fg.allocator.free_blocks == 63
+
+
+def test_generate_all_under_a_temperature_is_reproducible_from_the_seed():
+    """Sampled tokens come from ``step()``'s key stream (two words of the
+    host's stream a tick, in the packed array): the engine's seed fixes
+    them, another seed gives others, and a second call goes on from where
+    the stream stood."""
+    rng = np.random.default_rng(8)
+    prompts = _prompts(rng, [9, 21, 40])
+
+    def engine(seed, like=None):
+        args = ("tiny",) if like is None else (like.cfg, like.params)
+        return FastGenEngine(*args, n_blocks=32, block_size=16,
+                             max_blocks_per_seq=8, token_budget=32,
+                             temperature=0.8, seed=seed,
+                             **(CFG if like is None else {}))
+
+    a = engine(3)
+    b, c = engine(3, like=a), engine(4, like=a)
+    first = a.generate_all([1, 2, 3], prompts, max_new_tokens=10)
+    assert all(len(first[u]) == 10 for u in (1, 2, 3))
+    assert b.generate_all([1, 2, 3], prompts, max_new_tokens=10) == first
+    assert c.generate_all([1, 2, 3], prompts, max_new_tokens=10) != first
+    again = a.generate_all([4, 5, 6], prompts, max_new_tokens=10)
+    assert [again[u] for u in (4, 5, 6)] != [first[u] for u in (1, 2, 3)]
+    assert all(isinstance(k, tuple) and len(k) == 2 for k in a._ticks)
+
+
+FAMILY_FILES = ("test_kimi_linear_stack", "test_nemotron_h_stack",
+                "test_afmoe_stack", "test_mellum_stack",
+                "test_keye_sparse_stack", "test_lfm2_stack",
+                "test_latent_moe_serving", "test_hybrid_stack",
+                "test_ouro_loop")
+
+
+def test_no_family_file_keeps_a_private_copy_of_the_harness():
+    """``_drive``, ``_engine``, ``_noisy``, ``_rel`` and ``_build`` are
+    ``family_harness.py``'s; a file that defines one of its own says in the
+    comment above it why it differs."""
+    import os
+    import re
+
+    here = os.path.dirname(__file__)
+    for name in FAMILY_FILES:
+        with open(os.path.join(here, name + ".py")) as f:
+            lines = f.read().split("\n")
+        for i, line in enumerate(lines):
+            if re.match(r"def (_drive|_engine|_noisy|_rel|_build)\(", line):
+                above = i - 1
+                while lines[above].startswith("#") and \
+                        not lines[above].startswith("# differs"):
+                    above -= 1
+                assert lines[above].startswith("# differs"), (name, line)
+        assert "family_harness" in "\n".join(lines), name
 
 
 def test_fastgen_no_recompile_on_admission():
@@ -371,11 +336,7 @@ def test_fastgen_throughput_vs_slot_engine():
     # wall-clock gate, so the count carries the 2x claim and wall clock
     # gets a 1.5x floor.
     slot_programs = len(slot._compiled)
-    # count SplitFuse tick programs only: the fused decode-scan ("dec")
-    # tiers are a fixed grid independent of prompt diversity
-    fg_programs = len([k for k in fg._ticks
-                       if not (isinstance(k, tuple) and k
-                               and k[0] == "dec")])
+    fg_programs = len(fg._ticks)
     assert slot_programs > 2 * fg_programs, (slot_programs, fg_programs)
     assert t_fg_cold * 1.5 <= t_slot_cold, (
         f"FastGen cold {t_fg_cold:.2f}s not clearly faster than slot "
@@ -457,24 +418,6 @@ class TestFastGenTP:
         assert fg2.mesh is not None
         got = fg2.generate_all([1, 2, 3], prompts, max_new_tokens=12)
         assert ref == got
-
-    def test_tp2_decode_stream(self):
-        from deepspeed_tpu.comm.mesh import MeshConfig, initialize_mesh, \
-            reset_mesh
-
-        reset_mesh()
-        initialize_mesh(MeshConfig(data=4, tensor=2))
-        fg = self._engine()
-        rng = np.random.default_rng(1)
-        fg.put([1, 2], [rng.integers(0, 500, 10).tolist() for _ in range(2)])
-        while any(s.prefill_remaining > 0 for s in fg.seqs.values()):
-            fg.step()
-        got = 0
-        for emitted in fg.decode_stream(window=8):
-            got += sum(len(v) for v in emitted.values())
-            if got >= 16:
-                break
-        assert got >= 16
 
     def test_tp_refusals(self):
         import dataclasses
@@ -618,22 +561,6 @@ def test_fastgen_est_token_seconds_is_per_engine():
     a.generate_all([3, 4], _prompts(rng, [7, 21]), max_new_tokens=8)
     assert a.est_token_seconds() is not None and a.est_token_seconds() > 0
     assert b.est_token_seconds() is None, "engine b never ticked"
-
-
-def test_fastgen_decode_stream_drops_expired():
-    """Deadline expiry must also cover the decode_stream scheduling path:
-    an expired request is dropped at stream entry (blocks freed) instead
-    of pinning KV blocks while the stream loops."""
-    rng = np.random.default_rng(13)
-    fg = FastGenEngine("tiny", n_blocks=16, block_size=16,
-                       max_blocks_per_seq=8, token_budget=32,
-                       temperature=0.0, seed=0, **CFG)
-    fg.put([1], _prompts(rng, [8]), deadline_s=0.15)
-    fg.step()            # prefill + first token
-    time.sleep(0.2)      # deadline passes
-    list(fg.decode_stream(window=4))
-    assert fg.expired(1) and fg.seqs[1].done
-    assert not fg.seqs[1].blocks
 
 
 # ------------------------------------------------------------------ #
@@ -968,17 +895,19 @@ def test_rows_hold_a_sequence_from_put_to_flush(what):
             (0, 0, None, [], False, 3)
         assert not rows.table[row].any() and rows.uid[row] == 9
     elif what == "stale":
-        # a descriptor that outlives its row (a decode_stream window in
-        # flight) keeps its values and writes to nobody else's
+        # a flushed descriptor keeps what it generated and has no row: a
+        # read or a write of a row's field fails where it stands, and the
+        # row's next owner starts blank
         seqs[3].pos, seqs[3].last_tok = 21, 55
+        seqs[3].generated.extend([4, 5])
         row = seqs[3].row
         rows.release(seqs[3])
         new = type(seqs[0])(9, [1], rows)
-        assert new.row == row
-        seqs[3].pos += 4
-        seqs[3].last_tok = 56
-        assert (seqs[3].pos, seqs[3].last_tok, seqs[3].held) == (25, 56, 0)
-        assert (new.pos, new.last_tok) == (0, None)
+        assert new.row == row and seqs[3].row is None
+        assert seqs[3].generated == [4, 5]
+        with pytest.raises(AttributeError):
+            seqs[3].pos += 4
+        assert (new.pos, new.last_tok, new.held) == (0, None, 0)
     else:
         # restore(): the arrays, and what lives on the descriptors
         seqs[0].generated.extend([1, 2])

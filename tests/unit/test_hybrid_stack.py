@@ -21,148 +21,66 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmarks.reference import sambay_lm as R
-from deepspeed_tpu.inference.fastgen import BlockAllocator, FastGenEngine
+from deepspeed_tpu.inference.fastgen import BlockAllocator
 from deepspeed_tpu.models import hybrid as HY
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.hf_import import config_from_hf
 from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+from family_harness import CATALOG, TOL
 
-TOL = 2e-5
 HF = dict(model_type="phi4flash", hidden_size=64, intermediate_size=96,
           layer_norm_eps=1e-5, max_position_embeddings=4096, mb_per_layer=2,
           num_attention_heads=8, num_hidden_layers=8, num_key_value_heads=4,
           sliding_window=16, tie_word_embeddings=True, vocab_size=128,
           hidden_act="silu")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-
-
-def _rel(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+FAMILY = H.Family(R, {"model": HF}, tokens=(2, 60))
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = config_from_hf(types.SimpleNamespace(**HF))
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
-    # biases and norm offsets off zero, so a dropped one shows
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
-    params = tree.unflatten([x + 0.05 * jax.random.normal(k, x.shape)
-                             for x, k in zip(leaves, keys)])
-    toks = np.random.default_rng(0).integers(0, 128, (2, 60)).astype(np.int32)
-    with jax.default_matmul_precision("highest"):
-        whole = T.forward(params, jnp.asarray(toks), cfg)
-    return cfg, params, toks, whole, R.arch_from_config(HF, HF)
+    m = FAMILY.model("model")
+    return m.cfg, m.params, m.toks, H.whole_forward(FAMILY, m), m.arch
 
 
-def _engine(cfg, params, **kw):
-    kw = {"n_blocks": 64, "block_size": 4, "max_blocks_per_seq": 16,
-          "token_budget": 16, "state_slots": 3, "use_pallas_kernel": False,
-          **kw}
-    return FastGenEngine(cfg, params, **kw)
-
-
-def _drive(eng, cfg, toks, attn, chunk, n_prompt):
-    """The runner's check (``benchmarks/runners/serve.py::check_logits``) in
-    small: every sequence ``allocate``d once, ticks of the flat prompt rows
-    ``chunk`` at a time, then decode ticks; logits of every position."""
-    Tn, mb, bs = eng.token_budget, eng.max_blocks_per_seq, eng.block_size
-    S = toks.shape[1]
-    tabs, blocks = [], []
-    for _ in toks:
-        b = eng.allocator.allocate(S // bs + 1)
-        t = np.zeros(mb, np.int32)
-        t[:len(b)] = b
-        tabs.append(t)
-        blocks.append(b)
-    fwd = jax.jit(lambda pr, pool, t, p, tb: PG.forward_paged(
-        pr, t, p, tb, pool, cfg, attention_fn=attn))
-    got = {}
-
-    def tick(rows):
-        t = np.zeros(Tn, np.int32)
-        p = np.zeros(Tn, np.int32)
-        tb = np.zeros((Tn, mb), np.int32)
-        for r, (i, pos) in enumerate(rows):
-            t[r], p[r], tb[r] = toks[i, pos], pos, tabs[i]
-        with jax.default_matmul_precision("highest"):
-            lg, eng.pool = fwd(eng.params, eng.pool, jnp.asarray(t),
-                               jnp.asarray(p), jnp.asarray(tb))
-        for r, (i, pos) in enumerate(rows):
-            got[(i, pos)] = lg[r]
-
-    flat = [(i, p) for i in range(len(toks)) for p in range(n_prompt)]
-    for lo in range(0, len(flat), chunk):
-        tick(flat[lo:lo + chunk])
-    for p in range(n_prompt, S):
-        tick([(i, p) for i in range(len(toks))])
-    for b in blocks:
-        eng.allocator.free(b)
-    return jnp.stack([jnp.stack([got[(i, p)] for p in range(S)])
-                      for i in range(len(toks))])
-
-
-def test_whole_forward_matches_the_reference(model):
-    cfg, params, toks, whole, arch = model
-    assert _rel(whole, R.forward_logits(params, toks, arch)) < TOL
-
-
-@pytest.mark.parametrize("attn,chunk", [
-    (None, 13),               # chunk and sequence boundaries fall mid-tick
-    (paged_attention, 13),    # the kernels (interpret mode) under the tick
-    (None, 16),               # a full tick: the ring holds window + run
-])
-def test_paged_ticks_match_whole_forward_and_reference(model, attn, chunk):
-    """60 positions under a window of 16 and a ring of 32: the ring wraps,
-    the window's edge falls inside chunks, the second sequence starts in
-    the tick that ends the first, and every pool starts full of garbage
-    (a slot's last tenant): state is zero at position 0 whatever is there."""
-    cfg, params, toks, whole, arch = model
-    eng = _engine(cfg, params)
+def _rings_and_blocks(eng):
     assert eng.pool["wk"].shape[1] == 4 * 8      # (3 slots + trash) x 8
-    eng.pool = {k: v + 1.0 for k, v in eng.pool.items()}
-    got = _drive(eng, cfg, toks, attn, chunk, n_prompt=57)
-    assert _rel(got, whole) < TOL
-    assert _rel(got, R.forward_logits(params, toks, arch)) < TOL
-    assert eng.allocator.free_blocks == 63 and eng.allocator.free_slots == 3
+    assert eng.allocator.free_blocks == 63
 
 
-def test_engine_serves_two_interleaved_and_reuses_a_slot(model):
+test_whole_forward_matches_the_reference = H.whole_forward_test(
+    FAMILY, ["model"])
+# 60 positions under a window of 16 and a ring of 32: the ring wraps, the
+# window's edge falls inside chunks, and every pool starts full of garbage
+# (a slot's last tenant): state is zero at position 0 whatever is there
+test_paged_ticks_match_whole_forward_and_reference = H.paged_ticks_test(
+    FAMILY, ["model"], n_prompt=57, garbage=1.0, also=_rings_and_blocks,
+    cases=[
+        (None, 13, TOL, {}),      # chunk and sequence boundaries fall mid-tick
+        (paged_attention, 13, TOL, {}),   # the kernels (interpret mode)
+        (None, 16, TOL, {}),      # a full tick: the ring holds window + run
+    ])
+
+
+def test_engine_serves_two_interleaved_and_reuses_a_slot():
     """Through ``FastGenEngine`` itself: token budget under the prompts'
     length, two sequences interleaved, a third admitted into the slot the
     first left (two slots only, so it waits for one). Greedy tokens against
     the reference's logits, teacher-forced on the engine's own output."""
-    cfg, params, toks, _, arch = model
-    eng = _engine(cfg, params, state_slots=2)
+    m = FAMILY.model("model")
+    eng = H.engine(FAMILY, m.cfg, m.params, state_slots=2)
     # 47 positions each: the reference compiles one length
-    prompts = {1: toks[0, :41].tolist(), 2: toks[1, :35].tolist(),
-               3: toks[0, 5:47].tolist()}
+    prompts = {1: m.toks[0, :41].tolist(), 2: m.toks[1, :35].tolist(),
+               3: m.toks[0, 5:47].tolist()}
     want = {1: 6, 2: 12, 3: 5}
-    eng.put([1, 2, 3], [prompts[u] for u in (1, 2, 3)])
     waits = eng._tm_slot_waits.total()
-    slots_seen = {}
-    with jax.default_matmul_precision("highest"):
-        for _ in range(200):
-            eng.step()
-            for u, s in eng.seqs.items():
-                if s.blocks:
-                    slots_seen[u] = s.blocks[0]
-                if not s.done and len(s.generated) >= want[u]:
-                    eng._finish(s)
-            if all(s.done for s in eng.seqs.values()):
-                break
+    slots_seen, _ = H.serve_greedy(eng, prompts, want)
     assert eng._tm_slot_waits.total() - waits == 1
     assert all(b in (1, 2) for b in slots_seen.values())
     assert slots_seen[3] == slots_seen[1]     # handed on by the first to end
-    for u in (1, 2, 3):
-        out = eng.query(u)[1][:want[u]]
-        seq = np.asarray(prompts[u] + out, np.int32)[None]
-        ref = R.forward_logits(params, seq, arch)[0]
-        n = len(prompts[u])
-        assert out == [int(t) for t in jnp.argmax(
-            ref[n - 1:n - 1 + want[u]], axis=-1)]
+    H.assert_greedy_tokens_are_the_reference_s(FAMILY, m, eng, prompts, want)
     eng.flush([1, 2, 3])
     assert eng.allocator.free_slots == 2 and eng.allocator.free_blocks == 63
 
@@ -172,7 +90,7 @@ def test_state_outside_the_block_pool_does_not_grow(model):
     length: after 3 x the ring's positions the sequence holds one slot and
     only the one ``full`` layer's blocks; the other layers wrote no block."""
     cfg, params, toks, _, _ = model
-    eng = _engine(cfg, params, n_blocks=40, max_blocks_per_seq=32)
+    eng = H.engine(FAMILY, cfg, params, n_blocks=40, max_blocks_per_seq=32)
     shapes = {k: v.shape for k, v in eng.pool.items()}
     assert shapes["k"] == shapes["v"] == (1, 40, 2, 4, 16)
     assert shapes["wk"] == (2, 4 * 8, 2, 4, 16)    # 2 window layers
@@ -197,7 +115,7 @@ def test_state_outside_the_block_pool_does_not_grow(model):
 
 def test_failed_tick_leaves_slots_and_state_as_they_were(model):
     cfg, params, toks, _, _ = model
-    eng = _engine(cfg, params)
+    eng = H.engine(FAMILY, cfg, params)
     eng.put([1], [toks[0, :20].tolist()])
     eng.step()
     eng.put([2], [toks[1, :9].tolist()])
@@ -414,34 +332,10 @@ def test_importer_places_the_five_kinds_and_counts_the_published_size():
 
 def test_axes_tree_matches_the_parameters(model):
     cfg, params, *_ = model
-    axes = T.param_logical_axes(cfg)
-    flat_p = dict(jax.tree_util.tree_flatten_with_path(params)[0])
-    flat_a = dict(jax.tree_util.tree_flatten_with_path(
-        axes, is_leaf=lambda x: isinstance(x, tuple))[0])
-    assert flat_p.keys() == flat_a.keys()
-    assert all(len(flat_a[k]) == flat_p[k].ndim for k in flat_p)
+    H.assert_axes_name_every_leaf(cfg, params)
     assert cfg.num_params() == sum(x.size for x in jax.tree.leaves(params))
 
 
-@pytest.mark.parametrize("entry", ["forward_decode", "pipeline", "tp", "pld"])
-def test_entry_points_that_refuse_a_stack_of_kinds(model, entry):
-    cfg, params, toks, *_ = model
-    with pytest.raises(NotImplementedError, match="layer kinds|layer_kinds"):
-        if entry == "forward_decode":
-            T.forward_decode(params, jnp.asarray(toks[:, :4]), {},
-                             jnp.zeros((2,), jnp.int32), cfg)
-        elif entry == "pipeline":
-            T.pipelined_lm_loss(params, jnp.asarray(toks), cfg, 2)
-        elif entry == "pld":
-            T.forward_hidden(params, jnp.asarray(toks), cfg,
-                             pld_keep=jnp.ones((8,)))
-        else:
-            from deepspeed_tpu.comm.mesh import (MeshConfig, initialize_mesh,
-                                                 reset_mesh)
+test_entry_points_that_refuse_a_stack_of_kinds = H.entry_points_refuse_test(
+    FAMILY, ["model"])
 
-            reset_mesh()
-            initialize_mesh(MeshConfig(data=4, tensor=2))
-            try:
-                _engine(cfg, params, tp=True)
-            finally:
-                reset_mesh()

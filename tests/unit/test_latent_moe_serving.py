@@ -3,7 +3,8 @@ dense layer, routed experts beside shared ones) through the normal path, at
 toy width: 1 dense + 2 expert layers, 8 experts top-2, 1 shared, blocks of
 8 positions, tables of 64 blocks. The oracle is the benchmark's plain
 reference (``benchmarks/reference/deepseek_lm.py``), which imports nothing
-of the program.
+of the program. (No ``Family`` table: the toy model is the configuration
+file's own ``rehearse`` widths under the benchmark's weights, one model.)
 """
 import dataclasses
 import json
@@ -17,7 +18,9 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks import model_config, weights
+from family_harness import drive, rel
 from benchmarks.reference import deepseek_lm as R
+from deepspeed_tpu.inference.fastgen import FastGenEngine
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.hf_import import config_from_hf
@@ -52,10 +55,6 @@ def _toy(**over):
 def toy():
     cfg, arch, _ = _toy()
     return cfg, arch, weights.init_on_device(cfg, 3)
-
-
-def _rel(got, want):
-    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
 
 
 # ------------------------------------------------------------------ #
@@ -167,7 +166,7 @@ def test_forward_matches_the_reference(toy):
         0, cfg.vocab_size, (2, 50)).astype(np.int32)
     want = R.forward_logits(params, toks, arch)
     got = T.forward(params, jnp.asarray(toks), cfg)
-    assert _rel(got, want) < 1e-5
+    assert rel(got, want) < 1e-5
 
 
 @pytest.mark.parametrize("what", ["forward_decode", "pipeline"])
@@ -205,48 +204,21 @@ def test_training_through_dst_initialize_runs(toy):
 # ------------------------------------------------------------------ #
 # the paged tick against the reference
 # ------------------------------------------------------------------ #
-def _serve_through_pool(cfg, params, toks, n_prompt, attn, Tn=32, chunk=24):
-    """Chunked prefill then decode steps of one sequence through a paged
-    pool, as the benchmark's ``check_logits`` drives it; logits at every
-    position."""
-    pool = PG.init_paged_kv(cfg, 128, BS)
-    table = np.zeros((MB,), np.int32)
-    table[:len(toks) // BS + 1] = np.arange(1, len(toks) // BS + 2)
-    fwd = jax.jit(lambda p, pool, t, pos, tb: PG.forward_paged(
-        p, t, pos, tb, pool, cfg, attention_fn=attn))
-    out = {}
-
-    def tick(rows):
-        nonlocal pool
-        t = np.zeros((Tn,), np.int32)
-        pos = np.zeros((Tn,), np.int32)
-        tb = np.zeros((Tn, MB), np.int32)
-        for r, p in enumerate(rows):
-            t[r], pos[r], tb[r] = toks[p], p, table
-        logits, pool = fwd(params, pool, jnp.asarray(t), jnp.asarray(pos),
-                           jnp.asarray(tb))
-        for r, p in enumerate(rows):
-            out[p] = logits[r]
-
-    prompt = list(range(n_prompt))
-    for lo in range(0, n_prompt, chunk):
-        tick(prompt[lo:lo + chunk])
-    for p in range(n_prompt, len(toks)):
-        tick([p])
-    return jnp.stack([out[p] for p in range(len(toks))])
-
-
 @pytest.mark.parametrize("path", ["jnp", "kernel"])
 def test_chunked_prefill_and_decode_match_the_reference(toy, path):
     cfg, arch, params = toy
     toks = np.random.default_rng(1).integers(
         0, cfg.vocab_size, 60).astype(np.int32)
     want = R.forward_logits(params, toks[None], arch)[0]
-    # any kernel handed in selects the latent kernel (interpreted here)
-    got = _serve_through_pool(cfg, params, toks, 44,
-                              paged_attention if path == "kernel" else None)
+    # chunked prefill of 44 positions 24 at a time, then decode steps; any
+    # kernel handed in selects the latent kernel (interpreted here)
+    eng = FastGenEngine(cfg, params, n_blocks=128, block_size=BS,
+                        max_blocks_per_seq=MB, token_budget=32,
+                        use_pallas_kernel=False)
+    got = drive(eng, toks[None], paged_attention if path == "kernel" else None,
+                24, 44)[0][0]
     # the kernel's products take bf16 operands
-    assert _rel(got, want) < (5e-3 if path == "kernel" else 1e-5)
+    assert rel(got, want) < (5e-3 if path == "kernel" else 1e-5)
 
 
 def test_the_latent_tick_holds_the_latent_kernel_not_the_dense_one(toy):
@@ -292,7 +264,7 @@ def test_a_rows_logits_do_not_depend_on_its_tick_mates(toy):
 
     alone, rows_alone = run(1)
     crowded, rows_crowded = run(500)
-    assert _rel(crowded, alone) < 1e-5
+    assert rel(crowded, alone) < 1e-5
     # the counts are of real rows only (pad rows route too)
     assert rows_alone.shape == (2, cfg.n_experts)
     assert rows_alone.sum(axis=1).tolist() == [2 * cfg.moe_top_k] * 2
@@ -315,8 +287,8 @@ def test_a_capacity_would_have_dropped_what_the_tick_keeps(toy):
     dense, _ = moe_ffn(x[None], lp["gate_w"], experts, activation="swiglu",
                        capacity_factor=1.25, dispatch="dense", **kw)
     assert int(rows.max()) == 256
-    assert _rel(dense[0, 0], y[0]) < 1e-5       # the first rows fit
-    assert _rel(dense[0, -1], y[-1]) > 0.5      # the last were dropped
+    assert rel(dense[0, 0], y[0]) < 1e-5       # the first rows fit
+    assert rel(dense[0, -1], y[-1]) > 0.5      # the last were dropped
 
 
 # ------------------------------------------------------------------ #
@@ -397,7 +369,7 @@ def test_latent_kernel_matches_the_jnp_path(toy, case):
             q_row, pool, wide, lengths, cfg.kv_lora_rank,
             PG.mla_softmax_scale(cfg), interpret=True))
     real = np.asarray(tables)[:, 0] > 0
-    assert _rel(got[real].astype(jnp.float32),
+    assert rel(got[real].astype(jnp.float32),
                 want[real].astype(jnp.float32)) < 2e-2   # bf16 values
     assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
 
@@ -417,7 +389,6 @@ def test_latent_kernel_is_the_dense_kernels_walk():
 # ------------------------------------------------------------------ #
 def test_fastgen_serves_it_and_reports_the_experts_load(toy):
     from deepspeed_tpu import telemetry
-    from deepspeed_tpu.inference.fastgen import FastGenEngine
     from deepspeed_tpu.serving import ServingFrontend
 
     cfg, arch, params = toy
@@ -521,7 +492,7 @@ def deep_toy():
     system = T.forward(
         jax.tree.map(lambda a: a.astype(jnp.bfloat16), params),
         jnp.asarray(toks), dataclasses.replace(cfg, dtype="bfloat16"))
-    return arch, params, toks, want, _rel(system[0, -9:], want)
+    return arch, params, toks, want, rel(system[0, -9:], want)
 
 
 BROKEN = {
@@ -561,4 +532,4 @@ def test_each_broken_variant_fails_the_cells_tolerance(deep_toy, variant):
     tol = system * spec["rel_tol"] / spec["chip_readings"]["system_max"]
     arch2, params2 = BROKEN[variant](arch, params)
     got = R.forward_logits(params2, toks, arch2)[0, -9:]
-    assert _rel(got, want) > tol, (variant, tol)
+    assert rel(got, want) > tol, (variant, tol)

@@ -24,18 +24,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmarks.reference import lfm2_lm as R
-from deepspeed_tpu.inference.fastgen import FastGenEngine
-from deepspeed_tpu.models import hybrid as HY
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.hf_import import (config_from_hf, import_hf_model,
                                             params_from_lfm2_moe)
 from deepspeed_tpu.moe.gating import topk_gating, topk_gating_indices
 from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+from family_harness import CATALOG, TOL, rel
 
-TOL = 2e-5
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 _TYPES = {"c": "conv", "f": "full_attention"}
 
 
@@ -58,163 +56,79 @@ def _hf(kinds: str, dense: int, **kw):
 #: layers), the published pattern cut where a period is not whole (a period
 #: of four with a remainder of three), and heads of 64, which lie two to a
 #: pool row (``paged.kv_lane_pack``)
-MODELS = {
+FAMILY = H.Family(R, {
     "cut": _hf("ccfcccfccc", 2),
     "remainder": _hf("ccfcccfcccfcc", 2),
     "heads-of-64": _hf("ccfccc", 2, hidden_size=128, num_attention_heads=2,
                        num_key_value_heads=2),
-}
+})
+MODELS = FAMILY.models
+STACKS = sorted(MODELS)
 
 
-def _rel(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-
-
-def _noisy(params, seed=1, std=0.05):
-    """Norm gains, the router's bias and every matrix off their start, so
-    a dropped one shows."""
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return tree.unflatten([x + std * jax.random.normal(k, x.shape)
-                           for x, k in zip(leaves, keys)])
-
-
-def _build(hf):
-    cfg = config_from_hf(types.SimpleNamespace(**hf))
-    params = _noisy(T.init_params(cfg, jax.random.PRNGKey(0)))
-    toks = np.random.default_rng(0).integers(0, 128, (2, 40)).astype(np.int32)
-    return cfg, params, toks
-
-
-@pytest.fixture(scope="module", params=sorted(MODELS))
+@pytest.fixture(scope="module", params=STACKS)
 def model(request):
-    hf = MODELS[request.param]
-    cfg, params, toks = _build(hf)
-    with jax.default_matmul_precision("highest"):
-        whole = T.forward(params, jnp.asarray(toks), cfg)
-    return cfg, params, toks, whole, R.arch_from_config(hf, hf)
+    m = FAMILY.model(request.param)
+    return m.cfg, m.params, m.toks, H.whole_forward(FAMILY, m), m.arch
 
 
 @pytest.fixture(scope="module")
 def cut():
-    hf = MODELS["cut"]
-    return _build(hf) + (R.arch_from_config(hf, hf),)
+    m = FAMILY.model("cut")
+    return m.cfg, m.params, m.toks, m.arch
 
 
-def _engine(cfg, params, **kw):
-    kw = {"n_blocks": 64, "block_size": 4, "max_blocks_per_seq": 16,
-          "token_budget": 16, "state_slots": 3, "use_pallas_kernel": False,
-          **kw}
-    return FastGenEngine(cfg, params, **kw)
-
-
-def _drive(eng, cfg, toks, attn, chunk, n_prompt, between=None):
-    """The runner's check (``benchmarks/runners/serve.py::check_logits``) in
-    small: every sequence ``allocate``d once, ticks of the flat prompt rows
-    ``chunk`` at a time (sequence and chunk boundaries fall where they
-    fall), then decode ticks of one row a sequence; logits of every
-    position. ``between(eng)`` runs between two ticks. Returns (logits
-    [B, S, V], the sequences' slots)."""
-    Tn, mb, bs = eng.token_budget, eng.max_blocks_per_seq, eng.block_size
-    S = toks.shape[1]
-    tabs, blocks = [], []
-    for _ in toks:
-        b = eng.allocator.allocate(S // bs + 1)
-        t = np.zeros(mb, np.int32)
-        t[:len(b)] = b
-        tabs.append(t)
-        blocks.append(b)
-    fwd = jax.jit(lambda pr, pool, t, p, tb: PG.forward_paged(
-        pr, t, p, tb, pool, cfg, attention_fn=attn))
-    got = {}
-
-    def tick(rows):
-        t = np.zeros(Tn, np.int32)
-        p = np.zeros(Tn, np.int32)
-        tb = np.zeros((Tn, mb), np.int32)
-        for r, (i, pos) in enumerate(rows):
-            t[r], p[r], tb[r] = toks[i, pos], pos, tabs[i]
-        with jax.default_matmul_precision("highest"):
-            lg, eng.pool = fwd(eng.params, eng.pool, jnp.asarray(t),
-                               jnp.asarray(p), jnp.asarray(tb))
-        for r, (i, pos) in enumerate(rows):
-            got[(i, pos)] = lg[r]
-        if between is not None:
-            between(eng)
-
-    flat = [(i, p) for i in range(len(toks)) for p in range(n_prompt)]
-    for lo in range(0, len(flat), chunk):
-        tick(flat[lo:lo + chunk])
-    for p in range(n_prompt, S):
-        tick([(i, p) for i in range(len(toks))])
-    for b in blocks:
-        eng.allocator.free(b)
-    return jnp.stack([jnp.stack([got[(i, p)] for p in range(S)])
-                      for i in range(len(toks))]), [b[0] for b in blocks]
-
-
-def test_whole_forward_matches_the_reference(model):
-    cfg, params, toks, whole, arch = model
-    assert _rel(whole, R.forward_logits(params, toks, arch)) < TOL
-
-
-@pytest.mark.parametrize("attn,chunk", [
-    (None, 13),               # chunk and sequence boundaries fall mid-tick
-    (paged_attention, 13),    # the kernel (interpret mode) under the tick
-    (None, 16),               # a full tick
-])
-def test_paged_ticks_match_whole_forward_and_reference(model, attn, chunk):
-    """Chunked prefill of two prompts in one stream of ticks, then decode
-    ticks of both sequences: the second sequence starts in the tick that
-    ends the first, a chunk boundary falls inside a prompt, a decode row
-    starts from the state its slot stored, and every pool starts full of
-    garbage (a run at position 0 must not read its slot's state)."""
-    cfg, params, toks, whole, arch = model
-    eng = _engine(cfg, params)
-    eng.pool = jax.tree.map(lambda x: x + 7.0, eng.pool)
-    out, _ = _drive(eng, cfg, toks, attn, chunk, n_prompt=30)
-    assert _rel(out, whole) < TOL
-    assert _rel(out, R.forward_logits(params, toks, arch)) < TOL
-    assert eng.allocator.free_slots == 3
-
-
-def test_a_slot_handed_on_starts_from_zero(model):
-    """Two sequences, freed, then two others that take the same slots with
-    the first pair's state still in them: the logits are the reference's."""
-    cfg, params, toks, whole, arch = model
-    eng = _engine(cfg, params, state_slots=2)
-    _, first = _drive(eng, cfg, toks, None, 13, n_prompt=30)
-    others = toks[::-1, ::-1].copy()
-    out, second = _drive(eng, cfg, others, None, 11, n_prompt=25)
-    assert sorted(first) == sorted(second) == [1, 2]
+def _stores_hold_the_first_pair(eng):
     # rows (layer, input, slot): both slots hold the first pair's state,
     # the pad rows' slot 0 was never written
     by_slot = eng.pool["conv"].reshape(-1, 3, eng.pool["conv"].shape[-1])
     assert float(jnp.abs(by_slot[:, 1:]).min()) > 0
     assert float(jnp.abs(by_slot[:, 0]).max()) == 0
-    assert _rel(out, R.forward_logits(params, others, arch)) < TOL
 
 
-@pytest.mark.parametrize("fault", ["state-dropped-at-a-tick-boundary",
-                                   "state-carried-into-the-next-sequence"])
-def test_a_fault_in_the_state_is_seen(fault, monkeypatch):
-    """The two faults a state a slot invites, made on purpose in the tick:
-    both move the logits by a thousand times the tolerance."""
-    cfg, params, toks = _build(MODELS["cut"])
-    arch = R.arch_from_config(MODELS["cut"], MODELS["cut"])
-    want = R.forward_logits(params, toks, arch)
-    eng = _engine(cfg, params)
-    between = None
-    if fault == "state-dropped-at-a-tick-boundary":
-        def between(e):
-            e.pool = {**e.pool, "conv": jnp.zeros_like(e.pool["conv"])}
-    else:
-        eng.pool = jax.tree.map(lambda x: x + 7.0, eng.pool)
-        runs_of = HY.runs_of
-        monkeypatch.setattr(HY, "runs_of", lambda o, p: runs_of(o, p)._replace(
-            fresh=jnp.zeros(o.shape, jnp.bool_)))
-    out, _ = _drive(eng, cfg, toks, None, 13, n_prompt=30, between=between)
-    assert _rel(out, want) > 1000 * TOL
+MISTAKES = ("no-qk-norm", "no-rope", "no-expert-bias", "taps-reversed",
+            "top-3", "conv-for-attention")
+
+
+def _mistakes(cfg, params):
+    """Each fault the cell's notes list, made in the program's config (or
+    its parameters)."""
+    blocks = params["blocks"]
+    flip = lambda b: {**b, "conv": {  # noqa: E731
+        **b["conv"], "conv_w": b["conv"]["conv_w"][:, ::-1]}}
+    return dict(zip(MISTAKES, (
+        (dict(qk_norm=False), params),
+        (dict(pos_emb="none"), params),
+        (dict(moe_gate_bias=False), {**params, "blocks": {
+            k: v for k, v in blocks.items() if k != "gate_bias"}}),
+        ({}, {**params, "blocks": flip(blocks),
+              "dense_blocks": flip(params["dense_blocks"])}),
+        (dict(moe_top_k=3), params),
+        (dict(layer_kinds=("conv", "conv", "conv", "full")
+              + cfg.layer_kinds[4:]), {**params, "blocks": {
+                  **blocks, "conv": jax.tree.map(
+                      lambda a: a[jnp.asarray([1, 0, 2, 3, 4, 5])],
+                      blocks["conv"])}}))))
+
+
+test_whole_forward_matches_the_reference = H.whole_forward_test(
+    FAMILY, STACKS)
+test_paged_ticks_match_whole_forward_and_reference = H.paged_ticks_test(
+    FAMILY, STACKS, n_prompt=30, cases=[
+        (None, 13, TOL, {}),      # chunk and sequence boundaries fall mid-tick
+        (paged_attention, 13, TOL, {}),   # the kernel (interpret mode)
+        (None, 16, TOL, {}),      # a full tick
+    ])
+test_a_slot_handed_on_starts_from_zero = H.slot_handed_on_test(
+    FAMILY, STACKS, _stores_hold_the_first_pair)
+test_a_fault_in_the_state_is_seen = H.state_fault_test(
+    FAMILY, "cut", times=1000, faults={
+        "state-dropped-at-a-tick-boundary": "conv",
+        "state-carried-into-the-next-sequence": H.CARRIED})
+test_a_mistake_made_on_purpose_is_seen = H.program_mistake_test(
+    FAMILY, "cut", MISTAKES, _mistakes)
+test_two_sequences_decode_in_one_tick_and_a_slot_is_handed_on = \
+    H.two_sequences_test(FAMILY, "cut")
 
 
 def test_segments_mixers_and_pools(model):
@@ -255,12 +169,7 @@ def test_segments_mixers_and_pools(model):
     assert pool["conv"].nbytes // 4 == kinds.count("conv") * 2 * h * \
         pool["conv"].dtype.itemsize
     assert set(pool) == {"k", "v", "conv"}
-    axes = T.param_logical_axes(cfg)
-    flat_p = dict(jax.tree_util.tree_flatten_with_path(params)[0])
-    flat_a = dict(jax.tree_util.tree_flatten_with_path(
-        axes, is_leaf=lambda x: isinstance(x, tuple))[0])
-    assert flat_p.keys() == flat_a.keys()
-    assert all(len(flat_a[k]) == flat_p[k].ndim for k in flat_p)
+    H.assert_axes_name_every_leaf(cfg, params)
     # (num_params counts a bias on the final RMSNorm: test_latent_moe_serving)
     assert cfg.num_params() - h == sum(
         x.size for x in jax.tree.leaves(params))
@@ -384,76 +293,26 @@ def test_state_dict_under_the_family_s_names_imports(model):
                 sd[ff + f"experts.{e}.{theirs}.weight"] = blocks[ours][at, e].T
     sd = {k: np.asarray(v) for k, v in sd.items()}
     got = params_from_lfm2_moe(sd, cfg)
-    flat_w = dict(jax.tree_util.tree_flatten_with_path(params)[0])
-    flat_g = dict(jax.tree_util.tree_flatten_with_path(got)[0])
-    assert flat_w.keys() == flat_g.keys()
-    for k in flat_w:
-        np.testing.assert_array_equal(np.asarray(flat_w[k]), flat_g[k])
+    H.assert_same_tree(params, got)
     with jax.default_matmul_precision("highest"):
-        assert _rel(T.forward(got, jnp.asarray(toks), cfg), whole) < TOL
+        assert rel(T.forward(got, jnp.asarray(toks), cfg), whole) < TOL
     name = next(n for n, hf in MODELS.items()
                 if config_from_hf(types.SimpleNamespace(**hf)) == cfg)
     assert import_hf_model((sd, types.SimpleNamespace(**MODELS[name])))[0] \
         == cfg
 
 
-def test_two_sequences_decode_in_one_tick_and_a_slot_is_handed_on(cut):
-    """Through ``FastGenEngine.step``: three requests on two slots; the
-    third waits, takes the slot of the first to end, and every greedy
-    token is the reference's."""
-    cfg, params, toks, arch = cut
-    eng = _engine(cfg, params, state_slots=2)
-    prompts = {1: toks[0, :9].tolist(), 2: toks[1, :30].tolist(),
-               3: toks[0, 20:37].tolist()}
-    want = {1: 3, 2: 12, 3: 4}
-    eng.put(list(prompts), list(prompts.values()))
-    slots_seen, both_decoded = {}, False
-    with jax.default_matmul_precision("highest"):
-        for _ in range(200):
-            out = eng.step()
-            both_decoded |= {1, 2} <= set(out) and eng.seqs[1].pos > 10
-            for u, s in eng.seqs.items():
-                if s.blocks:
-                    slots_seen[u] = s.blocks[0]
-                if not s.done and len(s.generated) >= want[u]:
-                    eng._finish(s)
-            if all(s.done for s in eng.seqs.values()):
-                break
-    assert both_decoded
-    assert slots_seen[3] == slots_seen[1]     # handed on by the first to end
-    for u in (1, 2, 3):
-        out = eng.query(u)[1][:want[u]]
-        seq = np.asarray(prompts[u] + out, np.int32)[None]
-        ref = R.forward_logits(params, seq, arch)[0]
-        n = len(prompts[u])
-        assert out == [int(t) for t in jnp.argmax(
-            ref[n - 1:n - 1 + want[u]], axis=-1)]
-    eng.flush([1, 2, 3])
-    assert eng.allocator.free_slots == 2 and eng.allocator.free_blocks == 63
-
-
-def test_the_tick_s_span_and_gauges_say_what_state_was_written(cut):
+def test_the_tick_s_span_and_gauges_say_what_state_was_written(
+        cut, monkeypatch):
     from deepspeed_tpu import telemetry
 
     cfg, params, toks, _ = cut
-    eng = _engine(cfg, params)
-    spans = []
-    real = telemetry.span
-
-    def spy(name, attrs=None, **kw):
-        if name == "decode_tick":
-            spans.append(attrs)
-        return real(name, attrs=attrs, **kw)
-
+    eng = H.engine(FAMILY, cfg, params)
     eng.put([1, 2], [toks[0, :20].tolist(), toks[1, :5].tolist()])
-    import deepspeed_tpu.inference.fastgen as FG
-    orig, FG.telemetry.span = FG.telemetry.span, spy
-    try:
-        eng.step()        # 16 rows: one chunk of the first prompt
-        eng.step()        # the rest of it + the second prompt whole
-        eng.step()        # two decode rows
-    finally:
-        FG.telemetry.span = orig
+    spans = H.spy_on_spans(monkeypatch, "decode_tick")
+    eng.step()        # 16 rows: one chunk of the first prompt
+    eng.step()        # the rest of it + the second prompt whole
+    eng.step()        # two decode rows
     assert [s["conv_state_rows"] for s in spans] == [1, 2, 2]
     assert [s["state_slots"] for s in spans] == [1, 2, 2]
     per_slot = telemetry.gauge("fastgen_state_bytes_per_slot")
@@ -468,7 +327,7 @@ def test_a_row_that_needs_no_block_leaves_the_free_list_alone(cut):
     touch the allocator (a copy of its free list a row was most of a
     256-row tick's scheduling time on the chip: PERF.md, PR 37)."""
     cfg, params, toks, _ = cut
-    eng = _engine(cfg, params)
+    eng = H.engine(FAMILY, cfg, params)
     eng.put([1], [toks[0, :6].tolist()])
     eng.step()
     seq, free = eng.seqs[1], eng.allocator._free
@@ -511,28 +370,8 @@ def test_the_router_divides_as_published():
                                rtol=1e-6)
 
 
-@pytest.mark.parametrize("entry", ["forward_decode", "pipeline", "tp", "pld"])
-def test_entry_points_that_refuse_conv_layers(model, entry):
-    cfg, params, toks, *_ = model
-    with pytest.raises(NotImplementedError, match="layer kinds|layer_kinds"):
-        if entry == "forward_decode":
-            T.forward_decode(params, jnp.asarray(toks[:, :4]), {},
-                             jnp.zeros((2,), jnp.int32), cfg)
-        elif entry == "pipeline":
-            T.pipelined_lm_loss(params, jnp.asarray(toks), cfg, 2)
-        elif entry == "pld":
-            T.forward_hidden(params, jnp.asarray(toks), cfg,
-                             pld_keep=jnp.ones((cfg.num_layers,)))
-        else:
-            from deepspeed_tpu.comm.mesh import (MeshConfig, initialize_mesh,
-                                                 reset_mesh)
-
-            reset_mesh()
-            initialize_mesh(MeshConfig(data=4, tensor=2))
-            try:
-                _engine(cfg, params, tp=True)
-            finally:
-                reset_mesh()
+test_entry_points_that_refuse_conv_layers = H.entry_points_refuse_test(
+    FAMILY, STACKS)
 
 
 def test_a_stack_with_conv_layers_refuses_what_it_does_not_write():
@@ -544,36 +383,3 @@ def test_a_stack_with_conv_layers_refuses_what_it_does_not_write():
                           jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="state_slots"):
         PG.init_paged_kv(cfg, 16, 4, state_slots=0)
-
-
-@pytest.mark.parametrize("mistake", [
-    "no-qk-norm", "no-rope", "no-expert-bias", "taps-reversed", "top-3",
-    "conv-for-attention"])
-def test_a_mistake_made_on_purpose_is_seen(mistake):
-    """Each fault the cell's notes list, made in the program's config (or
-    its parameters), moves the logits by far more than the tolerance."""
-    hf = MODELS["cut"]
-    cfg, params, toks = _build(hf)
-    want = R.forward_logits(params, toks[:1], R.arch_from_config(hf, hf))
-    blocks = params["blocks"]
-    flip = lambda b: {**b, "conv": {  # noqa: E731
-        **b["conv"], "conv_w": b["conv"]["conv_w"][:, ::-1]}}
-    wrong, p = {
-        "no-qk-norm": (dict(qk_norm=False), params),
-        "no-rope": (dict(pos_emb="none"), params),
-        "no-expert-bias": (dict(moe_gate_bias=False), {**params, "blocks": {
-            k: v for k, v in blocks.items() if k != "gate_bias"}}),
-        "taps-reversed": ({}, {**params, "blocks": flip(blocks),
-                               "dense_blocks": flip(params["dense_blocks"])}),
-        "top-3": (dict(moe_top_k=3), params),
-        "conv-for-attention": (dict(layer_kinds=(
-            "conv", "conv", "conv", "full") + cfg.layer_kinds[4:]), {
-            **params, "blocks": {
-                **blocks, "conv": jax.tree.map(
-                    lambda a: a[jnp.asarray([1, 0, 2, 3, 4, 5])],
-                    blocks["conv"])}}),
-    }[mistake]
-    with jax.default_matmul_precision("highest"):
-        got = T.forward(p, jnp.asarray(toks[:1]),
-                        dataclasses.replace(cfg, **wrong))
-    assert _rel(got, want) > 100 * TOL

@@ -11,6 +11,7 @@ rounding, ~1e-6 relative; the tolerance 2e-5 leaves room for the order of
 float32 sums and none for a wrong mask, rotary table, expert or weight.
 """
 import dataclasses
+import functools
 import json
 import re
 import types
@@ -27,8 +28,8 @@ from deepspeed_tpu.models.hf_import import (config_from_hf, import_hf_model,
                                             params_from_mellum)
 from deepspeed_tpu.moe import layer as MOE
 
-TOL = 2e-5
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+from family_harness import CATALOG, TOL
+
 CONFIG = "benchmarks/configs/mellum2-12b-a2.5b.json"
 _ROPE = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000,
                             "factor": 16,
@@ -55,6 +56,8 @@ def _hf(**kw):
     return hf
 
 
+# differs from the harness's ``build``: typed keys and a seed a leaf, a
+# config in float32 under full remat, an ``arch`` of the file's ``assumed``
 def _model(hf, seed=0):
     cfg = dataclasses.replace(config_from_hf(types.SimpleNamespace(**hf)),
                               dtype="float32", remat="full")
@@ -69,6 +72,8 @@ def _model(hf, seed=0):
     return cfg, params, arch
 
 
+# differs from the harness's ``rel``: gradients are compared leaf by leaf by
+# their largest entry, not by their norm
 def _rel(a, b):
     return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
 
@@ -85,19 +90,29 @@ CASES = {"share-from-4": {}, "share-from-0": dict(first_expert=0),
                               first_expert=0)}
 
 
+@functools.lru_cache(maxsize=None)
+def _case(case):
+    """A case's model and tokens with the reference's logits, loss and
+    gradients: once a case, whatever attention the system runs."""
+    cfg, params, arch = _model(_hf(**CASES[case]))
+    tokens = _tokens(cfg)
+    with jax.default_matmul_precision("highest"):
+        ref_loss, ref_grads = jax.value_and_grad(
+            lambda p: R.loss(p, tokens, arch))(params)
+    return (cfg, params, arch, tokens,
+            R.forward_logits(params, tokens, arch), ref_loss, ref_grads)
+
+
 @pytest.mark.parametrize("attention", [None, "flash"])
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_system_matches_reference(case, attention):
-    cfg, params, arch = _model(_hf(**CASES[case]))
-    tokens = _tokens(cfg)
+    cfg, params, arch, tokens, ref_logits, ref_loss, ref_grads = _case(case)
     spec = dst.causal_lm_spec(cfg, attention=attention, loss_impl="exact")
     with jax.default_matmul_precision("highest"):
         logits = spec.apply_fn(params, {"tokens": tokens})
         loss, grads = jax.value_and_grad(spec.loss_fn)(
             params, {"tokens": tokens})
-        ref_loss, ref_grads = jax.value_and_grad(
-            lambda p: R.loss(p, tokens, arch))(params)
-    assert _rel(logits, R.forward_logits(params, tokens, arch)) < TOL
+    assert _rel(logits, ref_logits) < TOL
     assert abs(float(loss) - float(ref_loss)) < TOL * float(ref_loss)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     ref_flat = jax.tree.leaves(ref_grads)
@@ -113,15 +128,19 @@ MISTAKES = {"no-window": 1e-3, "no-yarn": 1e-3, "no-renorm": 1e-3,
             "share-off-by-one": 1e-3}
 
 
+@functools.lru_cache(maxsize=None)
+def _whole_forward(case):
+    cfg, params, _, tokens, *_ = _case(case)
+    with jax.default_matmul_precision("highest"):
+        return T.forward(params, tokens, cfg)
+
+
 @pytest.mark.parametrize("mistake", sorted(MISTAKES))
 def test_a_mistaken_reference_is_seen(mistake):
-    cfg, params, arch = _model(_hf())
-    tokens = _tokens(cfg)
-    with jax.default_matmul_precision("highest"):
-        logits = T.forward(params, tokens, cfg)
+    _, params, arch, tokens, *_ = _case("share-from-4")
     wrong = dict(arch, faults=(mistake,))
-    assert _rel(logits, R.forward_logits(params, tokens, wrong)) \
-        > MISTAKES[mistake]
+    assert _rel(_whole_forward("share-from-4"),
+                R.forward_logits(params, tokens, wrong)) > MISTAKES[mistake]
 
 
 # ------------------------------------------------------------------ #

@@ -27,19 +27,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmarks.reference import nemotron_h_lm as R
-from deepspeed_tpu.inference.fastgen import FastGenEngine
 from deepspeed_tpu.models import hybrid as HY
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.hf_import import (_MAMBA2_TENSORS, config_from_hf,
                                             import_hf_model)
 from deepspeed_tpu.moe import layer as ML
-from deepspeed_tpu.ops.pallas import ssd as SD
 from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+from family_harness import CATALOG, TOL, rel
 
-TOL = 2e-5
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CONFIG = "benchmarks/configs/nemotron-3-super-120b-a12b.json"
 
 
@@ -66,337 +64,67 @@ def _hf(pattern: str, **kw):
 
 
 #: the benchmark's cut (one whole period), a stack that ends inside a
-#: period, and a share of the experts that does not start at the first
-MODELS = {
+#: period, a share of the experts that does not start at the first, and a
+#: layer of each kind (the engine's tick programs unroll a period: the
+#: eleven layers of the benchmark's cut are the three-way comparisons')
+FAMILY = H.Family(R, {
     "cut": _hf("MEMEMEMEM*E"),
     "remainder": _hf("M*EME"),
     "a-later-share": _hf("ME*E", first_expert=8),
-}
+    "a-layer-a-kind": _hf("ME*E"),
+})
+STACKS = ["a-later-share", "cut", "remainder"]
+MODELS = {name: FAMILY.models[name] for name in STACKS}
 
 
-def _rel(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-
-
-def _noisy(params, seed=1, std=0.05):
-    """Norm gains, the router's bias, the skip and every matrix off their
-    start, so a dropped one shows."""
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return tree.unflatten([x + std * jax.random.normal(k, x.shape)
-                           for x, k in zip(leaves, keys)])
-
-
-def _build(hf):
-    cfg = config_from_hf(types.SimpleNamespace(**hf))
-    params = _noisy(T.init_params(cfg, jax.random.PRNGKey(0)))
-    toks = np.random.default_rng(0).integers(0, 128, (2, 40)).astype(np.int32)
-    return cfg, params, toks
-
-
-@pytest.fixture(scope="module", params=sorted(MODELS))
+@pytest.fixture(scope="module", params=STACKS)
 def model(request):
-    hf = MODELS[request.param]
-    cfg, params, toks = _build(hf)
-    with jax.default_matmul_precision("highest"):
-        whole = T.forward(params, jnp.asarray(toks), cfg)
-    return cfg, params, toks, whole, R.arch_from_config(hf, hf)
+    m = FAMILY.model(request.param)
+    return m.cfg, m.params, m.toks, H.whole_forward(FAMILY, m), m.arch
 
 
 @pytest.fixture(scope="module")
 def cut():
-    """A layer of each kind (the engine's tick programs unroll a period:
-    the eleven layers of the benchmark's cut are the ``model`` fixture's)."""
-    hf = _hf("ME*E")
-    return _build(hf) + (R.arch_from_config(hf, hf),)
+    m = FAMILY.model("a-layer-a-kind")
+    return m.cfg, m.params, m.toks, m.arch
 
 
-def _engine(cfg, params, **kw):
-    kw = {"n_blocks": 64, "block_size": 4, "max_blocks_per_seq": 16,
-          "token_budget": 16, "state_slots": 3, "use_pallas_kernel": False,
-          **kw}
-    return FastGenEngine(cfg, params, **kw)
-
-
-def _drive(eng, cfg, toks, attn, chunk, n_prompt, between=None):
-    """The runner's check (``benchmarks/runners/serve.py::check_logits``) in
-    small: every sequence ``allocate``d once, ticks of the flat prompt rows
-    ``chunk`` at a time (sequence and chunk boundaries fall where they
-    fall), then decode ticks of one row a sequence; logits of every
-    position. ``between(eng)`` runs between two ticks. Returns (logits
-    [B, S, V], the sequences' slots)."""
-    Tn, mb, bs = eng.token_budget, eng.max_blocks_per_seq, eng.block_size
-    S = toks.shape[1]
-    tabs, blocks = [], []
-    for _ in toks:
-        b = eng.allocator.allocate(S // bs + 1)
-        t = np.zeros(mb, np.int32)
-        t[:len(b)] = b
-        tabs.append(t)
-        blocks.append(b)
-    fwd = jax.jit(lambda pr, pool, t, p, tb: PG.forward_paged(
-        pr, t, p, tb, pool, cfg, attention_fn=attn))
-    got = {}
-
-    def tick(rows):
-        t = np.zeros(Tn, np.int32)
-        p = np.zeros(Tn, np.int32)
-        tb = np.zeros((Tn, mb), np.int32)
-        for r, (i, pos) in enumerate(rows):
-            t[r], p[r], tb[r] = toks[i, pos], pos, tabs[i]
-        with jax.default_matmul_precision("highest"):
-            lg, eng.pool = fwd(eng.params, eng.pool, jnp.asarray(t),
-                               jnp.asarray(p), jnp.asarray(tb))
-        for r, (i, pos) in enumerate(rows):
-            got[(i, pos)] = lg[r]
-        if between is not None:
-            between(eng)
-
-    flat = [(i, p) for i in range(len(toks)) for p in range(n_prompt)]
-    for lo in range(0, len(flat), chunk):
-        tick(flat[lo:lo + chunk])
-    for p in range(n_prompt, S):
-        tick([(i, p) for i in range(len(toks))])
-    for b in blocks:
-        eng.allocator.free(b)
-    return jnp.stack([jnp.stack([got[(i, p)] for p in range(S)])
-                      for i in range(len(toks))]), [b[0] for b in blocks]
-
-
-def test_whole_forward_matches_the_reference(model):
-    cfg, params, toks, whole, arch = model
-    assert _rel(whole, R.forward_logits(params, toks, arch)) < TOL
-
-
-@pytest.mark.parametrize("attn,chunk,tol", [
-    (None, 13, TOL),          # chunk and sequence boundaries fall mid-tick
-    # the kernels (interpret mode) under the tick: ``ssd_step`` is exact,
-    # the paged kernel multiplies in bfloat16 by design
-    (paged_attention, 13, 2e-3),
-])
-def test_paged_ticks_match_whole_forward_and_reference(model, attn, chunk,
-                                                       tol):
-    """Chunked prefill of two prompts in one stream of ticks, then decode
-    ticks of both sequences: the second sequence starts in the tick that
-    ends the first (two runs a tick, the second cut mid-chunk: its state
-    handed from ``ssd_chunk`` to ``ssd_chunk`` and to ``ssd_step``), a
-    decode row starts from the state its slot stored, and every store
-    starts full of garbage (a run at position 0 must not read its slot's
-    state)."""
-    cfg, params, toks, whole, arch = model
-    eng = _engine(cfg, params)
-    eng.pool = jax.tree.map(lambda x: x + 7.0, eng.pool)
-    out, _ = _drive(eng, cfg, toks, attn, chunk, n_prompt=30)
-    assert _rel(out, whole) < tol
-    assert _rel(out, R.forward_logits(params, toks, arch)) < tol
-    assert eng.allocator.free_slots == 3
-
-
-def test_a_slot_handed_on_starts_from_zero(cut):
-    """Two sequences, freed, then two others that take the same slots with
-    the first pair's state still in them: the logits are the reference's."""
-    cfg, params, toks, arch = cut
-    eng = _engine(cfg, params, state_slots=2)
-    _, first = _drive(eng, cfg, toks, None, 13, n_prompt=30)
-    others = toks[::-1, ::-1].copy()
-    out, second = _drive(eng, cfg, others, None, 11, n_prompt=25)
-    assert sorted(first) == sorted(second) == [1, 2]
+def _stores_hold_the_first_pair(eng):
     assert float(jnp.abs(eng.pool["ssd"][:, 1:]).max(axis=(2, 3, 4)).min()) > 0
-    assert _rel(out, R.forward_logits(params, others, arch)) < TOL
 
 
-@pytest.mark.parametrize("fault", ["state-dropped-at-a-tick-boundary",
-                                   "conv-inputs-dropped-at-a-tick-boundary",
-                                   "state-carried-into-the-next-sequence"])
-def test_a_fault_in_the_state_is_seen(fault, monkeypatch, cut):
-    """The faults a state a slot invites, made on purpose in the tick: each
-    moves the logits by over a hundred times the tolerance."""
-    cfg, params, toks, arch = cut
-    want = R.forward_logits(params, toks, arch)
-    eng = _engine(cfg, params)
-    between = None
-    if fault == "state-carried-into-the-next-sequence":
-        eng.pool = jax.tree.map(lambda x: x + 7.0, eng.pool)
-        runs_of = HY.runs_of
-        monkeypatch.setattr(HY, "runs_of", lambda o, p: runs_of(o, p)._replace(
-            fresh=jnp.zeros(o.shape, jnp.bool_)))
-    else:
-        name = "ssd" if fault.startswith("state") else "ssd_conv"
-
-        def between(e):
-            e.pool = {**e.pool, name: jnp.zeros_like(e.pool[name])}
-    out, _ = _drive(eng, cfg, toks, None, 13, n_prompt=30, between=between)
-    assert _rel(out, want) > 100 * TOL
-
-
-# --------------------------------------------------------------------------- #
-# the recurrence's two forms against one row after another
-# --------------------------------------------------------------------------- #
-
-def _ssd_case(slots, positions, fast=False, nh=16, P=8, G=8, N=128, seed=0):
-    rng = np.random.default_rng(seed)
-    Tn = len(slots)
-    slot = jnp.asarray(slots, jnp.int32)
-    runs = HY.runs_of(slot, jnp.asarray(positions, jnp.int32))
-    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
-    x, B, C = f(Tn, nh, P), f(Tn, G, N) / 11, f(Tn, G, N) / 11
-    delta = jnp.asarray(rng.uniform(1e-3, 1.0, (Tn, nh)), jnp.float32)
-    g = -jnp.asarray(rng.uniform(1e-3, 2.0, (Tn, nh)), jnp.float32)
-    if fast:
-        # a head that decays by e^-30 a row: 1 / G overflows float32
-        # within three rows of a chunk
-        g = g.at[:, 3].set(-30.0)
-    plain = f(max(slots) + 1, nh, P, N)
-    s0 = jnp.where(runs.fresh[:, None, None, None], 0.0, plain[slot])
-    y, after = HY.ssd_recurrence(x, delta, g, B, C, runs, s0)
-    want = np.array(plain)
-    for t in range(Tn):
-        if bool(runs.last[t]) and slots[t] > 0:
-            want[slots[t]] = after[t]
-    # the store's layout: the state values down a tile's rows
-    state, want = SD.to_store(plain, G), SD.to_store(jnp.asarray(want), G)
-    assert _rel(SD.from_store(state, nh), plain) == 0
-    return (x, delta, g, B, C, runs, state, slot), \
-        jnp.where((slot > 0)[:, None, None], y, 0.0), want
-
-
-SSD_CASES = {
-    # two decode rows, a run that goes on from stored state, a fresh run,
-    # two pad rows
-    "a-tick-of-16": ([1, 2] + [3] * 5 + [4] * 7 + [0, 0],
-                     [9, 4] + list(range(7, 12)) + list(range(7)) + [0, 0],
-                     False),
-    # runs of 100 and 70 rows (several chunks, cut mid-chunk), decode rows
-    # before and after them, a fast head
-    "chunks-and-a-fast-head": (
-        [1] + [3] * 100 + [4] * 70 + [5] + [0] * 3,
-        [9] + list(range(7, 107)) + list(range(70)) + [3] + [0] * 3, True),
-    "every-row-a-run-of-one": (list(range(1, 9)), [5] * 8, False),
-    # a run over several chunks that ends mid-chunk, and a second run that
-    # starts in that chunk (two pieces of one chunk), then a decode row
-    "two-runs-in-one-chunk": (
-        [3] * 150 + [4] * 30 + [5] + [0] * 11,
-        list(range(20, 170)) + list(range(30)) + [8] + [0] * 11, False),
-    # prompt rows that start off the chunks' grid after decode rows
-    "a-run-after-decode-rows": (
-        list(range(1, 38)) + [40] * 90 + [0],
-        [6] * 37 + list(range(11, 101)) + [0], True),
-}
-
-
-@pytest.mark.parametrize("kernel", [False, True])
-@pytest.mark.parametrize("case", sorted(SSD_CASES))
-def test_both_forms_of_the_recurrence_match_one_row_after_another(case,
-                                                                  kernel):
-    """``hybrid.ssd`` (runs of one through ``ssd_step``, the Mosaic kernel
-    interpreted where ``kernel``, else its plain reference; the others
-    through ``ssd_chunk``) against ``ssd_recurrence``: outputs and the
-    state each run leaves in its slot."""
-    slots, positions, fast = SSD_CASES[case]
-    args, y_want, state_want = _ssd_case(slots, positions, fast)
-    with jax.default_matmul_precision("highest"):
-        y, state = jax.jit(lambda *a: HY.ssd(
-            *a, chunk=16, use_kernel=kernel))(*args)
-    assert bool(jnp.isfinite(y).all())
-    assert _rel(y, y_want) < TOL
-    assert _rel(jnp.asarray(state), jnp.asarray(state_want)) < TOL
-
-
-@pytest.mark.parametrize("kernel", [False, True])
-def test_the_chunked_form_takes_runs_of_one_past_the_step_form_s_count(
-        monkeypatch, kernel):
-    monkeypatch.setattr(HY, "SSD_STEP_ROWS", 3)
-    args, y_want, state_want = _ssd_case(list(range(1, 9)), [5] * 8)
-    with jax.default_matmul_precision("highest"):
-        y, state = HY.ssd(*args, chunk=16, use_kernel=kernel)
-    assert _rel(y, y_want) < TOL
-    assert _rel(jnp.asarray(state), jnp.asarray(state_want)) < TOL
-
-
-@pytest.mark.parametrize("kernel", [False, True])
-def test_pad_rows_touch_no_state(kernel):
-    args, _, _ = _ssd_case([0] * 8 + [2] + [0] * 7, [0] * 8 + [3] + [0] * 7)
-    y, state = HY.ssd(*args, chunk=16, use_kernel=kernel)
-    before = args[6]
-    np.testing.assert_array_equal(np.asarray(state[0]), np.asarray(before[0]))
-    np.testing.assert_array_equal(np.asarray(state[1]), np.asarray(before[1]))
-    assert float(jnp.abs(state[2] - before[2]).max()) > 0
-    assert float(jnp.abs(y[:8]).max()) == 0.0
-
-
-def test_the_step_kernel_skips_rows_of_slot_zero_wherever_they_lie():
-    """Called alone with a skipped row BETWEEN live ones (``hybrid.ssd``
-    never does): the kernel's grid is the count of live rows, which lie
-    first by contract, so the caller sorts; a live row behind a skipped
-    one is the contract broken, and the reference says what was meant."""
-    args, _, _ = _ssd_case([1, 2, 0, 0], [5, 6, 0, 0])
-    x, delta, g, B, C, runs, state, slot = args
-    a = jnp.exp(g)
-    y_k, s_k = SD.ssd_step(x, delta, a, B, C, state, slot, runs.fresh)
-    y_r, s_r = SD.ssd_step_reference(x, delta, a, B, C, state, slot,
-                                     runs.fresh)
-    assert _rel(y_k[:2], y_r[:2]) < TOL and float(jnp.abs(y_k[2:]).max()) == 0
-    assert _rel(s_k, s_r) < TOL
-
-
-@pytest.mark.parametrize("case", sorted(SSD_CASES))
-def test_the_host_counts_the_pieces_the_loop_runs(case):
-    """``paged._ssd_span`` (what the engine writes on a tick's span) against
-    the tick's own pieces."""
-    slots, positions, _ = SSD_CASES[case]
-    real = [s for s in slots if s > 0]
-    slot = np.asarray(slots)
-    runs = HY.runs_of(jnp.asarray(slot), jnp.asarray(positions, jnp.int32))
-    start = np.asarray(runs.start) & (slot > 0)
-    alone = start & np.asarray(runs.last)
-    decode = 0
-    while decode < len(real) and alone[decode]:
-        decode += 1
-    starts = [int(t) for t in np.nonzero(start)[0] if t >= decode]
-    span = PG._ssd_span(16, decode, starts, len(real), len(slots))
-    step = min(int(alone.sum()), HY.SSD_STEP_ROWS)
-    assert span["ssd_step_rows"] == step
-    assert span["ssd_chunk_rows"] == len(real) - step
-    assert span["ssd_state_rows"] == int(start.sum())
-    chunk_rows = (slot > 0) & ~alone
-    t = np.arange(len(slots))
-    opens = chunk_rows & (np.asarray(runs.start) | (t % 16 == 0))
-    assert span["ssd_chunk_pieces"] == int(opens.sum())
-
-
-@pytest.mark.parametrize("kernel", [False, True])
-@pytest.mark.parametrize("mistake", ["decay-dropped", "wrong-group"])
-def test_a_mistake_in_the_recurrence_is_seen_in_both_forms(mistake, kernel):
-    slots, positions, _ = SSD_CASES["a-tick-of-16"]
-    (x, delta, g, B, C, runs, state, slot), y_want, _ = _ssd_case(
-        slots, positions)
-    if mistake == "decay-dropped":
-        g = jnp.zeros_like(g)
-    else:
-        B, C = jnp.roll(B, 1, axis=1), jnp.roll(C, 1, axis=1)
-    y, _ = HY.ssd(x, delta, g, B, C, runs, state, slot, chunk=16,
-                  use_kernel=kernel)
-    assert _rel(y, y_want) > 100 * TOL
+test_whole_forward_matches_the_reference = H.whole_forward_test(
+    FAMILY, STACKS)
+# the second sequence's state is handed from ``ssd_chunk`` to ``ssd_chunk``
+# and to ``ssd_step``
+test_paged_ticks_match_whole_forward_and_reference = H.paged_ticks_test(
+    FAMILY, STACKS, n_prompt=30, cases=[
+        (None, 13, TOL, {}),      # chunk and sequence boundaries fall mid-tick
+        # the kernels (interpret mode) under the tick: ``ssd_step`` is
+        # exact, the paged kernel multiplies in bfloat16 by design
+        (paged_attention, 13, 2e-3, {}),
+    ])
+test_a_slot_handed_on_starts_from_zero = H.slot_handed_on_test(
+    FAMILY, ["a-layer-a-kind"], _stores_hold_the_first_pair)
+test_a_fault_in_the_state_is_seen = H.state_fault_test(
+    FAMILY, "a-layer-a-kind", times=100, faults={
+        "state-dropped-at-a-tick-boundary": "ssd",
+        "conv-inputs-dropped-at-a-tick-boundary": "ssd_conv",
+        "state-carried-into-the-next-sequence": H.CARRIED})
+# the reference with one equation wrong against the system, here in float32
+# (the least of nine chosen experts, of which a quarter are held, moves two
+# layers' logits by 1.3e-3: sixty times the tolerance)
+test_a_mistake_made_on_purpose_is_seen = H.reference_mistake_test(
+    FAMILY, "a-layer-a-kind",
+    seen=lambda mistake: (50 if mistake == "top-k-less-one" else 100) * TOL,
+    mistakes={m: {"faults": frozenset({m})} for m in R.FAULTS})
+test_two_sequences_decode_in_one_tick_and_a_slot_is_handed_on = \
+    H.two_sequences_test(FAMILY, "a-layer-a-kind", both_decode=False)
 
 
 # --------------------------------------------------------------------------- #
-# the equations: mistakes, shares, the published count
+# the equations: shares, the published count
 # --------------------------------------------------------------------------- #
-
-@pytest.mark.parametrize("mistake", R.FAULTS)
-def test_a_mistake_made_on_purpose_is_seen(mistake, cut):
-    """The reference with one equation wrong against the system: what the
-    comparison that decides ``correct`` has to fail, here in float32 (the
-    least of nine chosen experts, of which a quarter are held, moves two
-    layers' logits by 1.3e-3: sixty times the tolerance)."""
-    cfg, params, toks, arch = cut
-    with jax.default_matmul_precision("highest"):
-        whole = T.forward(params, jnp.asarray(toks), cfg)
-    wrong = R.forward_logits(params, toks,
-                             {**arch, "faults": frozenset({mistake})})
-    assert _rel(whole, wrong) > (50 if mistake == "top-k-less-one"
-                                 else 100) * TOL
-
 
 def test_the_four_shares_add_up():
     """Four shares of an expert layer (4 of 16 experts each, the shared
@@ -404,7 +132,7 @@ def test_the_four_shares_add_up():
     latent's up-projection, which is linear) give the reference's uncut
     layer."""
     hf = _hf("ME*E", n_routed_experts=16, router_experts=16)
-    cfg, params, _ = _build(hf)
+    cfg, params, _ = H.build(hf)
     arch = R.arch_from_config(hf, hf)
     lp = jax.tree.map(lambda a: a[0], params["blocks"]["ffn"])
     u = jax.random.normal(jax.random.PRNGKey(5), (24, cfg.hidden_size))
@@ -421,7 +149,7 @@ def test_the_four_shares_add_up():
             lp_i = {**lp, **{k: lp[k][4 * i:4 * i + 4]
                              for k in ("w_up", "w_down")}}
             total = total + T._ffn(u, lp_i, share)[0] - shared
-    assert _rel(total, want) < TOL
+    assert rel(total, want) < TOL
 
 
 def test_the_published_config_counts_its_parameters():
@@ -528,11 +256,7 @@ def test_state_dict_under_the_family_s_names_imports(model):
     got_cfg, got = import_hf_model((_state_dict(cfg, params),
                                     types.SimpleNamespace(**hf)))
     assert got_cfg == cfg
-    flat_w = dict(jax.tree_util.tree_flatten_with_path(params)[0])
-    flat_g = dict(jax.tree_util.tree_flatten_with_path(got)[0])
-    assert flat_w.keys() == flat_g.keys()
-    for k in flat_w:
-        np.testing.assert_array_equal(np.asarray(flat_w[k]), flat_g[k])
+    H.assert_same_tree(params, got)
 
 
 # --------------------------------------------------------------------------- #
@@ -593,71 +317,26 @@ def test_a_share_s_gradients_reach_the_latent_and_the_router(cut):
         g_ref = jax.grad(plain)(lp, u)
     for k in ("latent_down", "latent_up", "gate_w", "w_up", "w_down",
               "sw_up"):
-        assert _rel(g_sys[k], g_ref[k]) < 1e-4, k
+        assert rel(g_sys[k], g_ref[k]) < 1e-4, k
 
 
 # --------------------------------------------------------------------------- #
 # the engine
 # --------------------------------------------------------------------------- #
-
-def test_two_sequences_decode_in_one_tick_and_a_slot_is_handed_on(cut):
-    """Through ``FastGenEngine.step``: three requests on two slots; the
-    third waits, takes the slot of the first to end, and every greedy
-    token is the reference's."""
-    cfg, params, toks, arch = cut
-    eng = _engine(cfg, params, state_slots=2)
-    prompts = {1: toks[0, :9].tolist(), 2: toks[1, :30].tolist(),
-               3: toks[0, 20:37].tolist()}
-    want = {1: 3, 2: 12, 3: 4}
-    eng.put(list(prompts), list(prompts.values()))
-    slots_seen = {}
-    with jax.default_matmul_precision("highest"):
-        for _ in range(200):
-            eng.step()
-            for u, s in eng.seqs.items():
-                if s.blocks:
-                    slots_seen[u] = s.blocks[0]
-                if not s.done and len(s.generated) >= want[u]:
-                    eng._finish(s)
-            if all(s.done for s in eng.seqs.values()):
-                break
-    assert slots_seen[3] == slots_seen[1]     # handed on by the first to end
-    for u in (1, 2, 3):
-        out = eng.query(u)[1][:want[u]]
-        seq = np.asarray(prompts[u] + out, np.int32)[None]
-        ref = R.forward_logits(params, seq, arch)[0]
-        n = len(prompts[u])
-        assert out == [int(t) for t in jnp.argmax(
-            ref[n - 1:n - 1 + want[u]], axis=-1)]
-    eng.flush([1, 2, 3])
-    assert eng.allocator.free_slots == 2 and eng.allocator.free_blocks == 63
-
-
-def test_the_tick_s_span_and_gauges_say_which_form_took_which_rows(cut):
+def test_the_tick_s_span_and_gauges_say_which_form_took_which_rows(
+        cut, monkeypatch):
     from deepspeed_tpu import telemetry
 
     cfg, params, toks, _ = cut
-    eng = _engine(cfg, params)
-    spans = []
-    real = telemetry.span
-
-    def spy(name, attrs=None, **kw):
-        if name == "decode_tick":
-            spans.append(attrs)
-        return real(name, attrs=attrs, **kw)
-
+    eng = H.engine(FAMILY, cfg, params)
     counter = telemetry.counter("fastgen_ssd_rows_total")
     before = {f: counter.value(form=f) for f in ("step", "chunk")}
     eng.put([1, 2, 3], [toks[0, :20].tolist(), toks[1, :5].tolist(),
                         toks[0, 7:8].tolist()])
-    import deepspeed_tpu.inference.fastgen as FG
-    orig, FG.telemetry.span = FG.telemetry.span, spy
-    try:
-        eng.step()    # 16 rows: one chunk of the first prompt
-        eng.step()    # its last 4 rows, the second prompt whole, the third
-        eng.step()    # three decode rows
-    finally:
-        FG.telemetry.span = orig
+    spans = H.spy_on_spans(monkeypatch, "decode_tick")
+    eng.step()    # 16 rows: one chunk of the first prompt
+    eng.step()    # its last 4 rows, the second prompt whole, the third
+    eng.step()    # three decode rows
     assert [s["ssd_step_rows"] for s in spans] == [0, 1, 3]
     assert [s["ssd_chunk_rows"] for s in spans] == [16, 9, 0]
     assert [s["ssd_chunk_pieces"] for s in spans] == [1, 2, 0]
@@ -681,7 +360,7 @@ def test_a_pool_that_does_not_fit_says_what_takes_what(cut, monkeypatch):
     with pytest.raises(ValueError, match=r"blocks of 4 take .* GB and 3 "
                                          r"sequence slots' state .* GB "
                                          r"beside .* GB of weights"):
-        _engine(cfg, params)
+        H.engine(FAMILY, cfg, params)
 
 
 def test_what_the_stack_does_not_write_is_refused_by_name(cut):
@@ -732,4 +411,5 @@ def test_the_reference_s_mixer_is_transformers_mamba2():
                 faults=frozenset())
     with jax.default_matmul_precision("highest"):
         got = R._mamba2(jnp.asarray(u.numpy()[0]), R._f32(lp), arch)
-    assert _rel(got, jnp.asarray(want)) < 1e-5
+    assert rel(got, jnp.asarray(want)) < 1e-5
+

@@ -23,15 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmarks.reference import ouro_lm as R
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.inference.fastgen import FastGenEngine
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.hf_import import config_from_hf, import_hf_model
+from family_harness import CATALOG, TOL, rel
 
-TOL = 2e-5
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CONFIG = "benchmarks/configs/ouro-2.6b.json"
 L, V = 3, 128
 
@@ -49,107 +48,55 @@ def _hf(passes: int, threshold: float = 1.0, **kw):
     return hf
 
 
-def _rel(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-
-
-def _noisy(params, seed=1, std=0.1):
+def _off_their_start(params):
     """Norm gains, the gate's bias and every matrix off their start, so a
     dropped one shows (a norm made twice is the identity at gains of 1)."""
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return tree.unflatten([x + std * jax.random.normal(k, x.shape)
-                           for x, k in zip(leaves, keys)])
-
-
-def _build(passes: int, threshold: float = 1.0):
-    hf = _hf(passes, threshold)
-    cfg = dataclasses.replace(config_from_hf(types.SimpleNamespace(**hf)),
-                              init_std=0.2)
-    params = _noisy(T.init_params(cfg, jax.random.PRNGKey(0)))
-    if passes > 1:
+    params = H.noisy(params, std=0.1)
+    if "exit_gate" in params:
         params["exit_gate"]["b"] = params["exit_gate"]["b"] + 0.4
-    toks = np.random.default_rng(0).integers(0, V, (2, 40)).astype(np.int32)
-    return cfg, params, toks, R.arch_from_config(hf, hf)
+    return params
 
 
-@pytest.fixture(scope="module", params=[1, 2, 4])
-def model(request):
-    return _build(request.param, 0.6)
+#: one, two and four passes under a threshold that divides the rows, and an
+#: unlooped stack as published
+FAMILY = H.Family(
+    R, {"1-pass": _hf(1, 0.6), "2-passes": _hf(2, 0.6),
+        "4-passes": _hf(4, 0.6), "unlooped": _hf(1)},
+    noise=_off_their_start,
+    configure=lambda cfg: dataclasses.replace(cfg, init_std=0.2),
+    engine_kw={"n_blocks": 33, "max_blocks_per_seq": 12, "state_slots": None})
+PASSES = ["1-pass", "2-passes", "4-passes"]
 
 
 @pytest.fixture(scope="module")
 def looped():
-    return _build(4, 0.6)
-
-
-def _engine(cfg, params, **kw):
-    kw = {"n_blocks": 33, "block_size": 4, "max_blocks_per_seq": 12,
-          "token_budget": 16, "use_pallas_kernel": False, **kw}
-    return FastGenEngine(cfg, params, **kw)
-
-
-def _drive(eng, cfg, toks, lens, chunk, n_prompt):
-    """The runner's check (``benchmarks/runners/serve.py::check_logits``) in
-    small: sequences of ``lens`` positions, ticks of the flat prompt rows
-    ``chunk`` at a time (sequence and chunk boundaries fall where they
-    fall; a tick's other rows are pads), then decode ticks of one row a
-    live sequence; the logits of every position."""
-    Tn, mb, bs = eng.token_budget, eng.max_blocks_per_seq, eng.block_size
-    tabs, blocks = [], []
-    for n in lens:
-        b = eng.allocator.allocate(n // bs + 1)
-        t = np.zeros(mb, np.int32)
-        t[:len(b)] = b
-        tabs.append(t)
-        blocks.append(b)
-    fwd = jax.jit(lambda pr, pool, t, p, tb: PG.forward_paged(
-        pr, t, p, tb, pool, cfg))
-    got = {}
-
-    def tick(rows):
-        t = np.zeros(Tn, np.int32)
-        p = np.zeros(Tn, np.int32)
-        tb = np.zeros((Tn, mb), np.int32)
-        for r, (i, pos) in enumerate(rows):
-            t[r], p[r], tb[r] = toks[i, pos], pos, tabs[i]
-        with jax.default_matmul_precision("highest"):
-            lg, eng.pool = fwd(eng.params, eng.pool, jnp.asarray(t),
-                               jnp.asarray(p), jnp.asarray(tb))
-        for r, (i, pos) in enumerate(rows):
-            got[(i, pos)] = lg[r]
-
-    flat = [(i, p) for i, n in enumerate(lens)
-            for p in range(min(n_prompt, n))]
-    for lo in range(0, len(flat), chunk):
-        tick(flat[lo:lo + chunk])
-    for p in range(n_prompt, max(lens)):
-        tick([(i, p) for i, n in enumerate(lens) if p < n])
-    return [jnp.stack([got[(i, p)] for p in range(n)])
-            for i, n in enumerate(lens)], tabs
+    m = FAMILY.model("4-passes")
+    return m.cfg, m.params, m.toks, m.arch
 
 
 # --------------------------------------------------------------------------- #
 # three implementations of the same equations
 # --------------------------------------------------------------------------- #
 
-def test_whole_forward_matches_the_reference(model):
-    cfg, params, toks, arch = model
-    with jax.default_matmul_precision("highest"):
-        whole = T.forward(params, jnp.asarray(toks), cfg)
-    assert _rel(whole, R.forward_logits(params, toks, arch)) < TOL
+test_whole_forward_matches_the_reference = H.whole_forward_test(
+    FAMILY, PASSES)
+test_a_mistake_made_on_purpose_is_seen = H.reference_mistake_test(
+    FAMILY, "4-passes", seen=lambda mistake: 100 * TOL,
+    mistakes={m: {"faults": frozenset([m])} for m in R.FAULTS})
 
 
 @pytest.mark.parametrize("chunk", [16, 7])
-def test_paged_ticks_match_the_reference(model, chunk):
+@pytest.mark.parametrize("name", PASSES)
+def test_paged_ticks_match_the_reference(name, chunk):
     """Chunked prefill, then decode through the pool: prompts split across
     ticks, pad rows, two sequences of unequal length."""
-    cfg, params, toks, arch = model
+    m = FAMILY.model(name)
     lens = (40, 23)
-    got, _ = _drive(_engine(cfg, params), cfg, toks, lens, chunk, 18)
+    got, _ = H.drive(H.engine(FAMILY, m.cfg, m.params), m.toks, None, chunk,
+                     18, lens=lens)
     for i, n in enumerate(lens):
-        want = R.forward_logits(params, toks[i:i + 1, :n], arch)[0]
-        assert _rel(got[i], want) < TOL
+        want = H.reference_logits(FAMILY, m, m.toks[i:i + 1, :n])[0]
+        assert rel(got[i], want) < TOL
 
 
 def test_the_pool_has_a_cache_layer_a_pass_and_layer(looped):
@@ -157,10 +104,11 @@ def test_the_pool_has_a_cache_layer_a_pass_and_layer(looped):
     t's keys and values of layer l (the reference's, one application after
     another) and no other pass's."""
     cfg, params, toks, arch = looped
-    eng = _engine(cfg, params)
+    eng = H.engine(FAMILY, cfg, params)
     assert eng.pool["k"].shape == (4 * L, 33, 4, 4, 16)
     n = 14
-    _, tabs = _drive(eng, cfg, toks, (n,), 16, n)
+    _, blocks = H.drive(eng, toks, None, 16, n, lens=(n,))
+    tabs = [np.asarray(b) for b in blocks]
     arch = R._Frozen(arch)
     with jax.default_matmul_precision("highest"):
         x = jnp.asarray(params["tok_emb"])[toks[0, :n]]
@@ -171,10 +119,10 @@ def test_the_pool_has_a_cache_layer_a_pass_and_layer(looped):
                 for name, want in (("k", k), ("v", v)):
                     held = eng.pool[name][t * L + l][tabs[0][:4]].reshape(
                         16, 4, 16)[:n]
-                    assert _rel(held, want) < TOL, (t, l, name)
+                    assert rel(held, want) < TOL, (t, l, name)
             x = R._rms_norm(x, params["final_norm"]["scale"], arch["eps"])
     # the passes' keys differ: a layer shared by the passes would not pass
-    assert _rel(eng.pool["k"][L][tabs[0][0]], eng.pool["k"][0][tabs[0][0]]) \
+    assert rel(eng.pool["k"][L][tabs[0][0]], eng.pool["k"][0][tabs[0][0]]) \
         > 0.1
 
 
@@ -202,20 +150,10 @@ def test_a_threshold_chooses_a_pass_a_row(looped, threshold):
     np.testing.assert_allclose(np.asarray(pdf), want["exit_pdf"][0],
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(pdf).sum(-1), 1.0, atol=1e-6)
-    assert _rel(logits, want["logits"][0]) < TOL
+    assert rel(logits, want["logits"][0]) < TOL
     assert set(chosen) == {1.0: {3}, 0.0: {0}}.get(threshold, set(chosen))
     if threshold == 0.6:
         assert len(set(chosen)) >= 3       # different passes in one tick
-
-
-@pytest.mark.parametrize("mistake", R.FAULTS)
-def test_a_mistake_made_on_purpose_is_seen(mistake, looped):
-    cfg, params, toks, arch = looped
-    with jax.default_matmul_precision("highest"):
-        whole = T.forward(params, jnp.asarray(toks), cfg)
-    wrong = R.forward_logits(params, toks,
-                             {**arch, "faults": frozenset([mistake])})
-    assert _rel(whole, wrong) > 100 * TOL
 
 
 # --------------------------------------------------------------------------- #
@@ -304,11 +242,7 @@ def test_state_dict_under_the_family_s_names_imports(looped):
     got_cfg, got = import_hf_model((_state_dict(cfg, params),
                                     types.SimpleNamespace(**hf)))
     assert got_cfg == dataclasses.replace(cfg, init_std=got_cfg.init_std)
-    flat_w = dict(jax.tree_util.tree_flatten_with_path(params)[0])
-    flat_g = dict(jax.tree_util.tree_flatten_with_path(got)[0])
-    assert flat_w.keys() == flat_g.keys()
-    for k in flat_w:
-        np.testing.assert_array_equal(np.asarray(flat_w[k]), flat_g[k])
+    H.assert_same_tree(params, got)
 
 
 # --------------------------------------------------------------------------- #
@@ -320,7 +254,7 @@ def test_the_layers_leaves_appear_once_in_the_tick(looped):
     program's inputs, no copy or slice of them a pass). What the compiled
     program holds at the cell's size is ``test_chip_compile.py``'s."""
     cfg, params, *_ = looped
-    eng = _engine(cfg, params, n_blocks=9)
+    eng = H.engine(FAMILY, cfg, params, n_blocks=9)
     packed = eng._pack_tick(np.zeros(16, np.int32), np.zeros(16, np.int32),
                             np.zeros((16, 12), np.int32),
                             np.zeros(2, np.uint32))
@@ -382,50 +316,24 @@ def test_what_assumes_one_application_a_layer_refuses_by_name(looped):
 # the engine
 # --------------------------------------------------------------------------- #
 
-def test_the_engine_s_tokens_spans_counters_and_gauge(looped):
+def test_the_engine_s_tokens_spans_counters_and_gauge(monkeypatch):
     """Through ``FastGenEngine.step``: two requests of unequal length, a
     prompt split across ticks; every greedy token is the reference's; the
     ``decode_tick`` span says the passes and the cache layers; the exit
     mass of the rows whose token was read sums to their count."""
-    import deepspeed_tpu.inference.fastgen as FG
-
-    cfg, params, toks, arch = looped
-    eng = _engine(cfg, params)
+    m = FAMILY.model("4-passes")
+    eng = H.engine(FAMILY, m.cfg, m.params)
     assert telemetry.gauge("fastgen_cache_layers").value() == 4 * L
-    prompts = {1: toks[0, :21].tolist(), 2: toks[1, :6].tolist()}
+    prompts = {1: m.toks[0, :21].tolist(), 2: m.toks[1, :6].tolist()}
     want = {1: 5, 2: 9}
-    spans, real = [], telemetry.span
-
-    def spy(name, attrs=None, **kw):
-        if name == "decode_tick":
-            spans.append(attrs)
-        return real(name, attrs=attrs, **kw)
-
     apps = telemetry.counter("fastgen_layer_applications_total")
     mass = telemetry.counter("fastgen_exit_mass_total")
     gen = telemetry.counter("fastgen_generated_tokens_total")
     before = (apps.total(), mass.total(), gen.total(),
               [mass.value(**{"pass": str(t)}) for t in range(4)])
-    eng.put(list(prompts), list(prompts.values()))
-    orig, FG.telemetry.span = FG.telemetry.span, spy
-    try:
-        with jax.default_matmul_precision("highest"):
-            for _ in range(40):
-                eng.step()
-                for u, s in eng.seqs.items():
-                    if not s.done and len(s.generated) >= want[u]:
-                        eng._finish(s)
-                if all(s.done for s in eng.seqs.values()):
-                    break
-    finally:
-        FG.telemetry.span = orig
-    for u in (1, 2):
-        out = eng.query(u)[1][:want[u]]
-        seq = np.asarray(prompts[u] + out, np.int32)[None]
-        ref = R.forward_logits(params, seq, arch)[0]
-        n = len(prompts[u])
-        assert out == [int(t) for t in jnp.argmax(
-            ref[n - 1:n - 1 + want[u]], axis=-1)]
+    spans = H.spy_on_spans(monkeypatch, "decode_tick")
+    H.serve_greedy(eng, prompts, want, ticks=40)
+    H.assert_greedy_tokens_are_the_reference_s(FAMILY, m, eng, prompts, want)
     assert spans and all(s["loop_passes"] == 4 and s["cache_layers"] == 4 * L
                          for s in spans)
     assert apps.total() - before[0] == len(spans) * 4 * L
@@ -440,7 +348,8 @@ def test_the_engine_s_tokens_spans_counters_and_gauge(looped):
 
 
 def test_an_unlooped_engine_says_nothing_of_passes():
-    cfg, params, toks, _ = _build(1)
-    eng = _engine(cfg, params)
+    m = FAMILY.model("unlooped")
+    params = m.params
+    eng = H.engine(FAMILY, m.cfg, params)
     assert eng._loop_attrs == {} and "exit_gate" not in params
     assert eng.pool["k"].shape[0] == L

@@ -4,6 +4,7 @@ chooses between the gathered head and the all-rows head from the count the
 packed array carries), for every cache kind the tick skeleton serves. Toy
 widths, float32.
 """
+import contextlib
 import types
 
 import jax
@@ -13,6 +14,7 @@ import pytest
 
 from deepspeed_tpu.inference.fastgen import FastGenEngine
 from deepspeed_tpu.models import paged as PG
+from family_harness import rel, tick_program
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.hf_import import config_from_hf
 
@@ -87,25 +89,30 @@ def models():
     return out
 
 
-def _engine(cfg, params):
-    return FastGenEngine(cfg, params, n_blocks=96, block_size=4,
-                         max_blocks_per_seq=16, token_budget=BUDGET,
-                         state_slots=14, temperature=0.0,
-                         use_pallas_kernel=False, seed=0)
+@pytest.fixture(scope="module")
+def engine_of(models):
+    """One engine a kind for the file: the first test borrows its allocator
+    and its (untouched) pool, the second serves through it."""
+    engines = {}
 
-
-def _rel(got, want):
-    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    def get(kind):
+        if kind not in engines:
+            cfg, params = models[kind]
+            engines[kind] = FastGenEngine(
+                cfg, params, n_blocks=96, block_size=4, max_blocks_per_seq=16,
+                token_budget=BUDGET, state_slots=14, temperature=0.0,
+                use_pallas_kernel=False, seed=0)
+        return engines[kind]
+    return get
 
 
 # ------------------------------------------------------------------ #
 # the model: logits of the rows asked for
 # ------------------------------------------------------------------ #
 @pytest.mark.parametrize("kind", KINDS)
-def test_head_rows_gives_those_rows_of_the_all_rows_head(models, kind):
-    cfg, params = models[kind]
-    eng = _engine(cfg, params)
-    mb = eng.max_blocks_per_seq
+def test_head_rows_gives_those_rows_of_the_all_rows_head(engine_of, kind):
+    eng = engine_of(kind)
+    cfg, mb, held = eng.cfg, eng.max_blocks_per_seq, []
     rng = np.random.default_rng(2)
     tokens = rng.integers(0, cfg.vocab_size, BUDGET).astype(np.int32)
     positions = np.zeros((BUDGET,), np.int32)
@@ -113,19 +120,24 @@ def test_head_rows_gives_those_rows_of_the_all_rows_head(models, kind):
     # two decode rows first, then a chunk of 20 rows, then pads
     for row, pos in ((0, 5), (1, 9)):
         blocks = eng.allocator.allocate(pos // eng.block_size + 1)
+        held.append(blocks)
         positions[row] = pos
         tables[row, :len(blocks)] = blocks
     blocks = eng.allocator.allocate(20 // eng.block_size + 1)
+    held.append(blocks)
     positions[2:22] = np.arange(20)
     tables[2:22, :len(blocks)] = blocks
+    for blocks in held:             # the tables stay: nothing else runs here
+        eng.allocator.free(blocks)
     operands = (eng.params, eng.pool, jnp.asarray(tokens),
                 jnp.asarray(positions), jnp.asarray(tables))
 
-    def fwd(params, pool, t, pos, tb, head_rows=None):
+    def fwd(params, pool, t, pos, tb, head_rows):
         return PG.forward_paged(params, t, pos, tb, pool, cfg,
                                 head_rows=head_rows)
 
-    every, pool = jax.jit(fwd)(*operands)
+    # the all-rows program is the one the next test checks ticks against
+    every, pool = tick_program(cfg, None, BUDGET, mb)(*operands)
     assert every.shape == (BUDGET, cfg.vocab_size)
     assert every.dtype == jnp.float32
     # the decode rows, the chunk's last row, and that one again as a pad
@@ -133,7 +145,7 @@ def test_head_rows_gives_those_rows_of_the_all_rows_head(models, kind):
     some, pool_some = jax.jit(fwd)(*operands, jnp.asarray(r))
     assert some.shape == (len(r), cfg.vocab_size)
     assert some.dtype == jnp.float32
-    assert _rel(some, every[r]) < 1e-5
+    assert rel(some, every[r]) < 1e-5
     np.testing.assert_array_equal(np.argmax(some, -1),
                                   np.argmax(every, -1)[r])
     # the cache is written for every row whatever the head runs for
@@ -150,17 +162,15 @@ def _all_rows_tokens(eng, calls):
     ``forward_paged``'s full logits over a pool of its own, fed the
     packed arrays in order. Returns (tick bucket, sampled rows' count or
     None in the small bucket, tokens the engine read, tokens wanted)."""
-    cfg, attn = eng.cfg, eng._attention
-    fwd = jax.jit(lambda params, pool, t, pos, tb: PG.forward_paged(
-        params, t, pos, tb, pool, cfg, attention_fn=attn))
+    assert eng._attention in PG._REFERENCES       # no kernel: ``None``'s
     pool = jax.tree.map(jnp.zeros_like, eng.pool)
     out = []
     for c in calls:
         Tn, mb, packed = c["Tn"], c["mb"], c["packed"]
         n = Tn * mb
-        logits, pool = fwd(eng.params, pool, packed[n:n + Tn],
-                           packed[n + Tn:n + 2 * Tn],
-                           packed[:n].reshape(Tn, mb))
+        logits, pool = tick_program(eng.cfg, None, Tn, mb)(
+            eng.params, pool, packed[n:n + Tn], packed[n + Tn:n + 2 * Tn],
+            packed[:n].reshape(Tn, mb))
         want = np.argmax(np.asarray(logits), -1)
         got = np.asarray(c["sampled"])[:Tn]
         head = packed[n + 2 * Tn:-2]
@@ -177,7 +187,10 @@ def _all_rows_tokens(eng, calls):
     return out
 
 
+@contextlib.contextmanager
 def _recorded_ticks(eng):
+    """Every tick ``eng`` runs inside the block, with what it was handed
+    and what it sampled; the engine's programs are its own again after."""
     calls = []
     build = eng._build_tick
 
@@ -189,19 +202,23 @@ def _recorded_ticks(eng):
             calls.append({"Tn": Tn, "mb": mb, "packed": packed,
                           "sampled": sampled})
             return sampled, pool
+        tick.fn = fn
         return tick
     eng._build_tick = _build_tick
-    return calls
+    try:
+        yield calls
+    finally:
+        del eng._build_tick
+        eng._ticks = {k: getattr(t, "fn", t) for k, t in eng._ticks.items()}
 
 
 @pytest.mark.parametrize("kind", KINDS)
-def test_greedy_step_tokens_are_the_all_rows_heads(models, kind):
+def test_greedy_step_tokens_are_the_all_rows_heads(engine_of, kind):
     """Chunk ticks and decode ticks alternate; a late long prompt meets
     more decoding sequences than the small bucket has rows, so its chunk
     ticks take the all-rows branch of the same program."""
-    cfg, params = models[kind]
-    eng = _engine(cfg, params)
-    calls = _recorded_ticks(eng)
+    eng = engine_of(kind)
+    cfg = eng.cfg
     rng = np.random.default_rng(4)
 
     def prompts(lens):
@@ -214,14 +231,16 @@ def test_greedy_step_tokens_are_the_all_rows_heads(models, kind):
             for uid, tok in eng.step().items():
                 stream.setdefault(uid, []).append(tok)
 
-    eng.put([1, 2, 3], prompts([40, 5, 11]))
-    run(4)
-    eng.put([4], prompts([45]))            # chunks beside 3 decode rows
-    run(3)
-    eng.put(list(range(5, 12)), prompts([3, 4, 2, 5, 3, 2, 4]))
-    run(2)
-    eng.put([12], prompts([50]))           # chunks beside 11 decode rows
-    run(4)
+    with _recorded_ticks(eng) as calls:
+        eng.put([1, 2, 3], prompts([40, 5, 11]))
+        run(4)
+        eng.put([4], prompts([45]))            # chunks beside 3 decode rows
+        run(3)
+        eng.put(list(range(5, 12)), prompts([3, 4, 2, 5, 3, 2, 4]))
+        run(2)
+        eng.put([12], prompts([50]))           # chunks beside 11 decode rows
+        run(4)
+    eng.flush(list(range(1, 13)))
     ticks = _all_rows_tokens(eng, calls)
     for Tn, count, got, want in ticks:
         np.testing.assert_array_equal(got, want)
@@ -233,3 +252,40 @@ def test_greedy_step_tokens_are_the_all_rows_heads(models, kind):
     assert len(eng._ticks) == len({(c["Tn"], c["mb"]) for c in calls})
     # and the streams are what each tick sampled, in order
     assert all(len(v) >= 2 for v in stream.values()) and len(stream) == 12
+
+
+# ------------------------------------------------------------------ #
+# the tests' driver is a loop of ticks
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("kind", ["dense", "latent", "conv"])
+def test_generate_all_is_a_hand_loop_of_put_step_query_flush(engine_of, kind):
+    """``generate_all`` against ``put`` / ``step()`` / ``query`` / ``flush``
+    written out, on the engine the tests above compiled: token for token
+    (greedy, so the same engine gives both), every block and slot back, and
+    nothing in ``_ticks`` but ``(rows, table width)`` tick programs."""
+    eng = engine_of(kind)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, eng.cfg.vocab_size, n).tolist()
+               for n in (40, 5, 11)]
+    new, free = 7, (eng.allocator.free_blocks, eng.allocator.free_slots)
+    got = eng.generate_all([21, 22, 23], prompts, max_new_tokens=new)
+    assert (eng.allocator.free_blocks, eng.allocator.free_slots) == free
+    assert not eng.seqs
+    uids = [31, 32, 33]
+    eng.put(uids, prompts)
+    for _ in range(40):
+        eng.step()
+        for u in uids:
+            seq = eng.seqs[u]
+            if not seq.done and len(seq.generated) >= new:
+                eng._finish(seq)
+        if all(eng.seqs[u].done for u in uids):
+            break
+    want = [eng.query(u)[1][:new] for u in uids]
+    eng.flush(uids)
+    assert [got[u] for u in (21, 22, 23)] == want
+    assert all(len(toks) == new for toks in want)
+    assert (eng.allocator.free_blocks, eng.allocator.free_slots) == free
+    assert all(isinstance(k, tuple) and len(k) == 2
+               and all(isinstance(n, int) for n in k) for k in eng._ticks)
+    assert {Tn for Tn, _ in eng._ticks} <= {BUDGET, SMALL}

@@ -216,19 +216,25 @@ def test_training_step_of_held_experts_carries_its_scopes_and_counters():
     engine.shutdown_telemetry()
 
 
-def test_serving_tick_carries_scopes_and_the_kernel_name():
+@pytest.fixture(scope="module")
+def tiny_tick_stacks():
+    """The name stacks of the tiny dense model's greedy tick of 32 rows,
+    lowered and compiled once for the two tests that read them."""
     eng = FastGenEngine("tiny", n_blocks=16, block_size=16,
                         max_blocks_per_seq=8, token_budget=32,
                         temperature=0.0, seed=0, use_pallas_kernel=True,
                         hidden_size=64, num_layers=2, num_heads=4,
                         max_seq_len=128, vocab_size=512, dtype="float32")
     tn, mb = 32, eng.max_blocks_per_seq
-    tick = eng._build_tick(tn, mb)
-    stacks = _stacks(tick.lower(
+    return _stacks(eng._build_tick(tn, mb).lower(
         eng.params, eng.pool, eng._pack_tick(
             np.zeros((tn,), np.int32), np.zeros((tn,), np.int32),
             np.zeros((tn, mb), np.int32), np.zeros((2,), np.uint32))),
         "tick")
+
+
+def test_serving_tick_carries_scopes_and_the_kernel_name(tiny_tick_stacks):
+    stacks = tiny_tick_stacks
     parts = {part for s in stacks for part in s.split("/")}
     assert {"embed", "attn", "mlp", "lm_head", "sample"} <= parts
     _assert_both_heads_are_scoped(stacks)
@@ -361,7 +367,8 @@ def test_a_tick_of_single_sublayers_sorts_its_mixers_and_latent_apart():
                    for s in stacks)
 
 
-def test_a_looped_tick_tells_its_passes_its_norm_and_its_gate_apart():
+def test_a_looped_tick_tells_its_passes_its_norm_and_its_gate_apart(
+        tiny_tick_stacks):
     """The ``ouro`` tick: every pass's layers under ``pass<t>`` (a trace
     tells pass 0's attention from pass 3's by its path), the norm between
     passes under ``loop_norm``, the exit gate under ``exit_gate`` ahead of
@@ -403,16 +410,7 @@ def test_a_looped_tick_tells_its_passes_its_norm_and_its_gate_apart():
     assert not any("/loop_norm/" in s and "/pass" in s for s in stacks)
     assert not any("/exit_gate/" in s and "/lm_head/" in s for s in stacks)
     # an unlooped tick carries none of them
-    plain = FastGenEngine("tiny", n_blocks=16, block_size=16,
-                          max_blocks_per_seq=8, token_budget=32, seed=0,
-                          use_pallas_kernel=True, hidden_size=64,
-                          num_layers=2, num_heads=4, max_seq_len=128,
-                          vocab_size=512, dtype="float32")
-    names = {part for s in _stacks(plain._build_tick(tn, mb).lower(
-        plain.params, plain.pool, plain._pack_tick(
-            np.zeros((tn,), np.int32), np.zeros((tn,), np.int32),
-            np.zeros((tn, mb), np.int32), np.zeros((2,), np.uint32))),
-        "tick") for part in s.split("/")}
+    names = {part for s in tiny_tick_stacks for part in s.split("/")}
     assert not names & {"pass0", "loop_norm", "exit_gate"}
 
 

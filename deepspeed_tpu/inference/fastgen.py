@@ -1346,12 +1346,13 @@ class FastGenEngine:
                     else jax.device_put(packed, self._rep_sh))
                 sampled.copy_to_host_async()
             # while the device runs: the fetch steps its attention calls
-            # walk and those that take the unmasked form, by the kernel's
-            # own rule of the tick's lengths
+            # walk, those that take the unmasked form and the walks (one a
+            # run of rows under one table), by the kernel's own rule of the
+            # tick's lengths
             attn_steps = attn_open = 0
             if self._walks:
-                from deepspeed_tpu.ops.pallas.paged_attention import \
-                    count_steps
+                from deepspeed_tpu.ops.pallas.paged_attention import (
+                    count_steps, count_walks)
 
                 # the rows of whole tiles (the kernel's wrapper pads as the
                 # tick does: length 1, the zero table) in runs that carry
@@ -1369,6 +1370,8 @@ class FastGenEngine:
                 # widest walk carries
                 tick_span.note(attn_steps=attn_steps,
                                attn_open_steps=attn_open,
+                               attn_walks=count_walks(starts, R) * sum(
+                                   layers for layers, _, _ in self._walks),
                                attn_step_positions=max(
                                    step for _, _, step in self._walks))
             # the queue's and the pool's gauges too: they read what the

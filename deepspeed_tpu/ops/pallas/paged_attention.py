@@ -10,7 +10,7 @@ The pools stay in HBM (``memory_space=ANY``); a step splits its tile into
 RUNS of consecutive rows that carry the same block table and walks each
 run's table ONCE, ``P`` blocks at a time, with double-buffered
 ``make_async_copy`` fetches whose trip count is read from the prefetched
-lengths:
+lengths (how the fetches of a call follow one another: "The chain" below):
 
 - a run of two or more rows (a prompt chunk of one sequence, which
   ``FastGenEngine._step_impl`` lays out contiguously; the pad rows of a tick)
@@ -53,6 +53,30 @@ bit):
   tile-step;
 - whether a walk is a row alone or a tile is chosen once a walk (a loop of
   steps for each form and stretch), not by a branch a step.
+
+The chain. A call's fetches are ONE chain over all its walks, so that the
+copy engine always has the next step's blocks in flight (PERF.md section 6,
+PR 58: a walk used to start its first fetch itself and wait for it, ~2 us a
+walk of nothing in flight, 12 walks a call 192 times a tick in the looped
+cell). While a step is computed out of one slot, the other is being filled
+with the chain's next step: the walk's step i+1 or, during a walk's LAST
+step, the first step of the run after it, in this tile or, past the tile's
+last row, the next tile's first run (the slots, their semaphores and the
+plan below outlive a grid step; the lengths and ``same`` of every row of the
+call lie in scalar memory). A walk finds its run in ``plan_ref`` (scalar
+scratch: the run's end, the least and greatest of its lengths, the slot of
+its first step, then its first fetch's row, blocks and step), left there by
+the walk before it, which looked it up to start its fetch; it looks up its
+own successor before its first step, and waits for a fetch with the
+descriptors of the start it answers, the same ``(row, blocks, step,
+slot)``. A slot is therefore the parity of a step's
+number in the CHAIN, not in its walk: two in flight at once at most, one
+computed and one filling. Only the call's first run starts cold (at the top
+of the first grid step, ahead of the tile's opening), and the call's last
+run starts nothing. What a step computes, and from which blocks, is what it
+was: every output is equal to the bit. The host counts a call's walks as it
+counts its steps (:func:`count_walks`; the engine's ``attn_walks`` on the
+``decode_tick`` span).
 
 Which rows share a table is DATA: ``same[t] = all(tables[t] == tables[t-1])``
 is computed on the device beside the call and rides in the second scalar
@@ -185,25 +209,39 @@ def step_ranges(lo, hi, step: int, window: Optional[int], whole, xp=np):
     return begin, first, last, end
 
 
+def _runs(starts, tile: int):
+    """The first rows of the kernel's runs, ``starts[t]`` true where row t
+    carries another table than row t-1: a run ends at a tile's last row
+    too. The rows are whole tiles': the caller adds the pad rows the
+    wrapper would (length 1, one table)."""
+    cut = np.array(starts, bool)
+    if len(cut) % tile:
+        raise ValueError(f"{len(cut)} rows are not whole tiles of {tile}")
+    cut[::tile] = True
+    return np.flatnonzero(cut)
+
+
 def count_steps(lengths, starts, tile: int, step: int,
                 window: Optional[int] = None) -> Tuple[int, int]:
     """(fetch steps, open fetch steps) one call of the kernel walks over
-    rows of these ``lengths``, ``starts[t]`` true where row t carries
-    another table than row t-1, tiles of ``tile`` rows and steps of
-    ``step`` cache positions: the kernel's own runs (cut at tile
-    boundaries too) under :func:`step_ranges`, on the host. The rows are
-    whole tiles': the caller adds the pad rows the wrapper would (length
-    1, one table)."""
-    lengths, cut = np.asarray(lengths), np.array(starts, bool)
-    if len(lengths) % tile:
-        raise ValueError(f"{len(lengths)} rows are not whole tiles of {tile}")
-    cut[::tile] = True
-    at = np.flatnonzero(cut)
+    rows of these ``lengths`` in tiles of ``tile`` rows and steps of
+    ``step`` cache positions: the kernel's own runs (:func:`_runs`) under
+    :func:`step_ranges`, on the host."""
+    lengths = np.asarray(lengths)
+    at = _runs(starts, tile)
     rows = np.diff(np.append(at, len(lengths)))
     begin, first, last, end = step_ranges(
         np.minimum.reduceat(lengths, at), np.maximum.reduceat(lengths, at),
         step, window, (rows == 1) | (rows == tile))
     return int((end - begin).sum()), int((last - first).sum())
+
+
+def count_walks(starts, tile: int) -> int:
+    """The walks of one call of the kernel, one a run of :func:`_runs`. Every
+    walk but a call's first finds its first fetch in flight (see the
+    module's docstring), so walks less calls is how often the chain
+    engages."""
+    return len(_runs(starts, tile))
 
 
 def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
@@ -223,8 +261,9 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     ``refs``: the key pool and, where ``n_pool`` is 2, the value pool
     (with one pool the value is the leading columns of the key's block:
     latent attention, whose value is the latent itself), the output, then
-    the scratch: a fetch buffer per pool, the semaphores, the queries
-    head-major, lengths, softmax statistics and accumulator.
+    the scratch: a fetch buffer per pool, the semaphores, the plan of the
+    run to walk next (scalar memory), the queries head-major, lengths,
+    softmax statistics and accumulator.
     ``chosen``: behind the pools lies the tile's choice, ``[planes, R, L]``
     (nonzero: row r of the tile attends to position ``plane * L + l``; a
     sparse layer's, in planes of one lane width as ``sparse_choice`` writes
@@ -237,7 +276,8 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     refs = refs[int(chosen):]
     o_ref = refs[n_pool]
     bufs = refs[n_pool + 1:2 * n_pool + 1]
-    sems, q3_ref, len_ref, m_ref, l_ref, acc_ref = refs[2 * n_pool + 1:]
+    sems, plan_ref, q3_ref, len_ref, m_ref, l_ref, acc_ref = \
+        refs[2 * n_pool + 1:]
     if heads_first:
         K, P, bs = bufs[0].shape[1:4]
     else:
@@ -247,7 +287,8 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     rep, T = N // K, meta_ref.shape[0] // (3 if indirect else 2)
     M, C = R * rep, P * bs
     Dv = acc_ref.shape[2]
-    t0 = pl.program_id(0) * R
+    tile = pl.program_id(0)
+    t0 = tile * R
 
     def choice(i, rows):
         """Step ``i``'s planes of the choice for ``rows`` of the tile, side
@@ -259,16 +300,64 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
     def length(r):
         return meta_ref[t0 + r]
 
-    def same(r):                       # row r carries row r-1's table
-        return meta_ref[T + t0 + r] != 0
+    def fetch(t, nblk, i, slot, start):
+        """Start, or wait for, the copies into ``slot`` of blocks ``i*P ..``
+        of row ``t``'s table that lie under ``nblk`` (``i`` counts fetch
+        steps from block 0, also where a windowed walk starts later). A
+        wait answers the start of the same ``(t, nblk, i, slot)``, also
+        where another walk, a tile earlier, made that start."""
+        def page(p, _):
+            j = i * P + p
 
-    @pl.when(pl.program_id(0) == 0)
-    def _clear():
+            @pl.when(j < nblk)
+            def _():
+                blk = tables_ref[meta_ref[2 * T + t] if indirect else t, j]
+                for n, (pool, buf) in enumerate(zip(pools, bufs)):
+                    copy = pltpu.make_async_copy(
+                        pool.at[blk], buf.at[slot, :, p] if heads_first
+                        else buf.at[slot, p], sems.at[n, slot])
+                    copy.start() if start else copy.wait()
+
+        jax.lax.fori_loop(0, P, page, None)
+
+    def plan(t, r, slot, live=True):
+        """Finds the run that begins at row ``r`` of the tile whose first
+        row is ``t`` (any tile of the call: the lengths and ``same`` lie in
+        scalar memory whole) and leaves in ``plan_ref``, for its walk, its
+        end, the least and the greatest of its lengths and the ``slot`` of
+        its first step and, for the walk before it, its first fetch,
+        ``(t, nblk, i)`` of :func:`fetch`: no block where no such run is
+        ``live``. They lie in scalar memory and not in registers over a
+        walk's steps: Moonlight's call alone read 1 % less so (PR 58)."""
+        def shares(c):                 # row c[0] carries the row before's table
+            return jnp.logical_and(
+                c[0] < R, meta_ref[T + t + jnp.minimum(c[0], R - 1)] != 0)
+
+        def take(c):
+            n = meta_ref[t + c[0]]
+            return c[0] + 1, jnp.minimum(c[1], n), jnp.maximum(c[2], n)
+
+        n = meta_ref[t + r]
+        r1, lo, hi = jax.lax.while_loop(shares, take, (r + 1, n, n))
+        for at, x in enumerate((
+                r1, lo, hi, slot, t + r, jnp.where(live, pl.cdiv(hi, bs), 0),
+                step_ranges(lo, hi, C, window, False, jnp)[0])):
+            plan_ref[at] = x
+
+    def ahead():                       # the planned run's first fetch
+        return plan_ref[4], plan_ref[5], plan_ref[6]
+
+    @pl.when(tile == 0)
+    def _open():
         # a fetch step skips the blocks past a walk's last; what the slots
         # hold there is masked out of the scores but multiplies the (zero)
         # probabilities, so it must be finite from the first step on
         for buf in bufs:
             buf[...] = jnp.zeros_like(buf)
+        # the one fetch of a call that starts cold, and ahead of the tile's
+        # own opening below
+        plan(0, 0, 0)
+        fetch(*ahead(), 0, True)
 
     # the tile's queries head-major, [K, R*rep, D]: row r of the tile is
     # rows r*rep .. of every KV head; its lengths beside them; its softmax
@@ -384,32 +473,14 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
             p, axis=2, keepdims=True)
         acc_ref[:, rows, :] = acc_ref[:, rows, :] * lanes(alpha, Dv) + pv
 
-    def fetch(t, nblk, i, slot, start):
-        """Start, or wait for, the copies of blocks ``i*P ..`` of row
-        ``t``'s table that lie under ``nblk`` (``i`` counts fetch steps
-        from block 0, also where a windowed walk starts later)."""
-        def page(p, _):
-            j = i * P + p
-
-            @pl.when(j < nblk)
-            def _():
-                blk = tables_ref[meta_ref[2 * T + t] if indirect else t, j]
-                for n, (pool, buf) in enumerate(zip(pools, bufs)):
-                    copy = pltpu.make_async_copy(
-                        pool.at[blk], buf.at[slot, :, p] if heads_first
-                        else buf.at[slot, p], sems.at[n, slot])
-                    copy.start() if start else copy.wait()
-
-        jax.lax.fori_loop(0, P, page, None)
-
-    def walk(r0, r1):
-        """Rows ``r0 .. r1`` of the tile carry one table: walk it once,
-        ``P`` blocks a step, fetch i+1 in flight while i is computed."""
+    def walk(r0):
+        """The run at row ``r0`` of the tile, as ``plan_ref`` holds it: rows
+        ``r0 .. r1`` carry one table. Walk it once, ``P`` blocks a step,
+        the chain's next fetch in flight while a step is computed: this
+        walk's step i+1 or, on its last step, the first of the run after
+        it, in this tile or the next. Returns ``r1``."""
+        r1, lo, hi, slot0 = (plan_ref[at] for at in range(4))
         t = t0 + r0
-        lo, hi = jax.lax.fori_loop(
-            r0, r1, lambda r, n: (jnp.minimum(n[0], length(r)),
-                                  jnp.maximum(n[1], length(r))),
-            (jnp.int32(2 ** 30), jnp.int32(0)))
         nblk = pl.cdiv(hi, bs)
         # a row alone (a decode row) meets the blocks alone; a run meets
         # them with the whole tile, rows outside it masked out. Which of
@@ -417,14 +488,33 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
         alone = r1 - r0 == 1
         begin, first, last, end = step_ranges(
             lo, hi, C, window, alone | (r1 - r0 == R), jnp)
+        # the run after this one begins at r1 or, past the tile's last row,
+        # at the next tile's first; the call's last run has none (a dummy
+        # plan of rows that are there, no block of which is fetched). Its
+        # first step takes the slot after this walk's last
+        wraps = r1 == R
+        live = jnp.logical_not(wraps & (tile == pl.num_programs(0) - 1))
+        after = (slot0 + jnp.maximum(end - begin, 0)) & 1
+        shift = slot0 - begin
+        plan(jnp.where(wraps & live, t0 + R, t0), jnp.where(wraps, 0, r1),
+             after, live)
 
         def steps(start, stop, rows, limit, pick=None):
             def step(i, _):
-                slot = i % 2
+                slot = (i + shift) & 1
+                more = i + 1 < end
 
-                @pl.when((i + 1) * P < nblk)
+                # two starts and not one of selected operands: a step's
+                # pages are then copied from operands that do not change
+                # over the walk (Moonlight's call alone read 5 % over the
+                # parent's with the select, 1 % so: PERF.md, PR 58)
+                @pl.when(more)
                 def _():
                     fetch(t, nblk, i + 1, 1 - slot, True)
+
+                @pl.when(jnp.logical_not(more))
+                def _():
+                    fetch(*ahead(), 1 - slot, True)
 
                 fetch(t, nblk, i, slot, False)
                 online_softmax(i, rows, limit, slot, pick)
@@ -466,8 +556,11 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
                     preferred_element_type=jnp.float32) > 0.5)[None]
             attend(slice(None), limit, pick)
 
-        fetch(t, nblk, begin, begin % 2, True)
         jax.lax.cond(alone, alone_form, tile_form)
+
+        @pl.when(end <= begin)
+        def _():        # no step (no row has a length) handed the chain on
+            fetch(*ahead(), after, True)
 
         def put(r, _):
             rows = pl.ds(r * rep, rep)
@@ -476,15 +569,9 @@ def _kernel(tables_ref, meta_ref, q_ref, *refs,     # 2 scalar prefetch
             o_ref[pl.ds(r, 1)] = out.reshape(1, N, Dv).astype(o_ref.dtype)
 
         jax.lax.fori_loop(r0, r1, put, None)
-
-    def next_run(r0):
-        r1 = jax.lax.while_loop(
-            lambda r: jnp.logical_and(r < R, same(jnp.minimum(r, R - 1))),
-            lambda r: r + 1, r0 + 1)
-        walk(r0, r1)
         return r1
 
-    jax.lax.while_loop(lambda r: r < R, next_run, 0)
+    jax.lax.while_loop(lambda r: r < R, walk, 0)
 
 
 def _geometry(q, pools, value_dim, heads_first):
@@ -538,6 +625,7 @@ def _tiles(tables, meta, q, *pools, value_dim, scale, name, mxu_dtype,
             (2, K, P) + x.shape[2:] if heads_first else (2, P) + x.shape[1:],
             x.dtype) for x in pools] + [
             pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SMEM((7,), jnp.int32),
             pltpu.VMEM((K, R * rep, q.shape[2]), q3_dtype),
             pltpu.VMEM((R * rep, _LANES), jnp.int32),
             pltpu.VMEM((K, R * rep, _LANES), jnp.float32),

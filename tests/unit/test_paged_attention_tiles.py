@@ -19,20 +19,29 @@ from deepspeed_tpu.inference.fastgen import FastGenEngine
 from deepspeed_tpu.models import paged as PG
 from deepspeed_tpu.ops.pallas.paged_attention import (_geometry,
                                                       count_steps,
+                                                      count_walks,
+                                                      latent_paged_attention,
                                                       paged_attention,
                                                       step_ranges, tile_rows)
 
-def _shape(N, K, D, BS, MB, NB, T, dtype, span=False):
+def _shape(N, K, D, BS, MB, NB, T, dtype, span=False, heads_first=False,
+           latent=None):
     """A head layout with its pool and tick sizes; ``C``: the cache
     positions of one fetch step there, by the kernel's own rule. ``span``:
     the calls of window and full layers over experts: bfloat16 products,
     one table a sequence beside each row's (``row_table``), and a window
-    of two and a half steps, so that a walk has an edge at either end."""
-    pool = jax.ShapeDtypeStruct((NB, BS, K, D), jnp.dtype(dtype))
+    of two and a half steps, so that a walk has an edge at either end.
+    ``heads_first``: pool blocks ``[K, BS, D]``; ``latent``: ONE pool of
+    rows ``[BS, D]`` whose first ``latent`` columns are the value (K is 1)."""
+    dims = (NB, BS, D) if latent else (NB, K, BS, D) if heads_first \
+        else (NB, BS, K, D)
+    pool = jax.ShapeDtypeStruct(dims, jnp.dtype(dtype))
     C = BS * _geometry(jax.ShapeDtypeStruct((T, N, D), pool.dtype),
-                       (pool, pool), D, False)[3]
+                       (pool,) if latent else (pool, pool), latent or D,
+                       heads_first)[3]
     return types.SimpleNamespace(N=N, K=K, D=D, BS=BS, MB=MB, NB=NB, T=T,
-                                 dtype=dtype, C=C, span=span,
+                                 dtype=dtype, C=C, span=span, pool=dims,
+                                 heads_first=heads_first, latent=latent,
                                  window=2 * C + C // 2 if span else None)
 
 
@@ -50,6 +59,13 @@ SHAPES = {
     # the sparse layers' head layout over blocks of 128: a step carries two
     # lane widths, 256 positions (the walks of STEP_LAYOUTS reach 5 blocks)
     "keye": _shape(32, 4, 128, 128, 5, 24, 64, "bfloat16"),
+    # the chain of a call's fetches (CHAIN_LAYOUTS): one tile, where a chunk
+    # of 32 rows is a call of ONE walk; blocks that lie heads first, walks
+    # of up to two and a half steps of 128 positions; a latent pool at 16
+    # heads on one row, two and a half steps of 512
+    "rep4x32": _shape(8, 2, 64, 8, 8, 96, 32, "float32"),
+    "paired": _shape(8, 2, 64, 8, 40, 96, 64, "float32", heads_first=True),
+    "latent": _shape(16, 1, 256, 32, 40, 96, 64, "bfloat16", latent=128),
 }
 
 
@@ -143,8 +159,72 @@ STEP_LAYOUTS = {
     "tile_of_one_run_beside_a_tile_of_two": lambda g, rng: (
         _chunk(g, rng, 2 * g.C + 5, 45) + _chunk(g, rng, 2 * g.C - 3, 19)),
 }
+
+
+def _ring(g, rng, length):
+    """A decode row whose table's columns alias a ring of blocks, two more
+    than a window (or a step) holds: what a window layer's slot is."""
+    ring = -(-(g.window or g.C) // g.BS) + 2
+    ids = rng.permutation(np.arange(1, g.NB))[:ring]
+    tab = np.zeros((g.MB,), np.int32)
+    n = (length - 1) // g.BS + 1
+    tab[:n] = ids[np.arange(n) % ring]
+    return tab, length
+
+
+def _begins(g, rng, ring):
+    """Decode rows whose walks begin at steps 1, 0, 2, 0, 3, 1, 0 under the
+    window (without one: walks of 2, 1, 3, 1 .. steps), so that a walk's
+    first step lies in either slot whatever its own first step's number
+    is, then a chunk whose first step is step 1; under a ``ring`` the
+    decode rows' tables alias one."""
+    w, C, top = g.window or 0, g.C, g.MB * g.BS
+    lengths = [min(n, top) for n in (
+        w + C + 5, 3, w + 2 * C + 1, w + 7, w + 3 * C + 1, w + 2 * C, 1)]
+    decode = [_ring(g, rng, n) for n in lengths] if ring \
+        else _decode(g, rng, len(lengths), lengths)
+    return decode + _chunk(g, rng, min(w + C + 10, top - 40), 40)
+
+
+PAD = (0, 1)                           # a pad row: the zero table, length 1
+# what breaks a chain of fetches that is wrong (a walk finds its first fetch
+# started by the walk before it, in this tile or the one before): walks of
+# one step, so that both slots are in flight; a run of one row between two
+# of many; a run that ends with its tile before a tile of pads; first steps
+# of either parity; contexts of one position; pad rows between sequences
+CHAIN_LAYOUTS = {
+    "a_tile_of_one_step_decode_rows": lambda g, rng: _decode(
+        g, rng, 32, [int(n) for n in rng.integers(
+            1, min(g.C, g.MB * g.BS) + 1, 32)]),
+    "one_row_between_two_runs": lambda g, rng: (
+        _chunk(g, rng, 5, 20) + _decode(g, rng, 1) + _chunk(g, rng, 9, 30)),
+    "run_ends_with_its_tile_before_a_tile_of_pads": lambda g, rng: (
+        _decode(g, rng, 4) + _chunk(g, rng, 11, 28)),
+    "first_steps_of_either_parity": lambda g, rng: _begins(g, rng, False),
+    "first_steps_of_either_parity_on_a_ring": lambda g, rng: _begins(
+        g, rng, True),
+    "rows_of_length_one": lambda g, rng: (
+        _decode(g, rng, 20, [1] * 20) + _chunk(g, rng, 0, 5)
+        + _decode(g, rng, 9, [1, 2, 1, g.MB * g.BS, 1, 1,
+                              min(g.C, g.MB * g.BS), 1, 1])),
+    "pad_rows_between_two_sequences": lambda g, rng: (
+        _chunk(g, rng, min(g.C, g.MB * g.BS - 6) - 4, 10) + [PAD] * 5
+        + _chunk(g, rng, 2, 10)
+        + [PAD] + _decode(g, rng, 3)),
+}
+# a tile alone: a chunk that fills it is a call of one walk, which starts
+# cold and hands nothing on; then a tile of 32 walks
+ONE_TILE_LAYOUTS = {
+    "a_call_of_one_walk": lambda g, rng: _chunk(g, rng, 3, g.T),
+    "a_tile_of_one_step_decode_rows": CHAIN_LAYOUTS[
+        "a_tile_of_one_step_decode_rows"],
+}
+ALL_LAYOUTS = {**LAYOUTS, **STEP_LAYOUTS, **CHAIN_LAYOUTS, **ONE_TILE_LAYOUTS}
 CASES = [(h, l) for h in ("rep1", "rep4") for l in sorted(LAYOUTS)] + [
-    (h, l) for h in ("mistral", "trinity") for l in sorted(STEP_LAYOUTS)]
+    (h, l) for h in ("mistral", "trinity") for l in sorted(STEP_LAYOUTS)] + [
+    (h, l) for h in ("rep4", "mistral", "trinity", "paired", "latent")
+    for l in sorted(CHAIN_LAYOUTS)] + [
+    ("rep4x32", l) for l in sorted(ONE_TILE_LAYOUTS)]
 
 
 def _by_run(g, tables):
@@ -166,8 +246,27 @@ def _case(heads, dtype):
     rng = np.random.default_rng(3)
     dt = jnp.dtype(dtype)
     q = jnp.asarray(rng.normal(size=(g.T, g.N, g.D)), dt)
-    kpool = jnp.asarray(rng.normal(size=(g.NB, g.BS, g.K, g.D)), dt)
-    vpool = jnp.asarray(rng.normal(size=(g.NB, g.BS, g.K, g.D)), dt)
+    kpool = jnp.asarray(rng.normal(size=g.pool), dt)
+    vpool = jnp.asarray(rng.normal(size=g.pool), dt)
+    if g.latent:
+        def kernel(q, pool, _, tables, lengths):
+            return latent_paged_attention(q, pool, tables, lengths, g.latent,
+                                          g.D ** -0.5, interpret=True)
+
+        def reference(q, pool, _, tables, lengths):
+            # one KV head: a position's row is its key, the row's leading
+            # columns its value
+            rows = pool[tables].astype(jnp.float32).reshape(
+                g.T, g.MB * g.BS, g.D)
+            with jax.default_matmul_precision("highest"):
+                s = jnp.einsum("tnd,tcd->tnc", q.astype(jnp.float32),
+                               rows) * g.D ** -0.5
+                live = jnp.arange(g.MB * g.BS)[None, None] \
+                    < lengths[:, None, None]
+                p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
+                return jnp.einsum("tnc,tcd->tnd", p, rows[..., :g.latent])
+
+        return q, kpool, None, jax.jit(kernel), jax.jit(reference)
     if g.span:
         def kernel(q, kpool, vpool, tables, lengths):
             by_run, which = _by_run(g, tables)
@@ -176,16 +275,23 @@ def _case(heads, dtype):
                                    mxu_dtype=dt, row_table=which)
         kernel = jax.jit(kernel)
     else:
-        kernel = jax.jit(functools.partial(paged_attention, interpret=True))
+        kernel = jax.jit(functools.partial(paged_attention, interpret=True,
+                                           heads_first=g.heads_first))
 
     def reference(q, kpool, vpool, tables, lengths):
         with jax.default_matmul_precision("highest"):
             return PG.paged_attention_reference(
                 q.astype(jnp.float32), kpool.astype(jnp.float32),
                 vpool.astype(jnp.float32), tables, lengths,
-                window=g.window)
+                window=g.window, heads_first=g.heads_first)
 
     return q, kpool, vpool, kernel, jax.jit(reference)
+
+
+def _tol(g):
+    # a bf16 pool's output is rounded to bf16: half a unit in the last place
+    return dict(rtol=2e-4, atol=2e-5) if g.dtype == "float32" else dict(
+        rtol=2 ** -8, atol=2 ** -8)
 
 
 @pytest.mark.parametrize("heads,layout", CASES,
@@ -193,15 +299,33 @@ def _case(heads, dtype):
 def test_kernel_matches_reference_for_any_row_layout(heads, layout):
     g = SHAPES[heads]
     q, kpool, vpool, kernel, reference = _case(heads, g.dtype)
-    layouts = {**LAYOUTS, **STEP_LAYOUTS}
-    rng = np.random.default_rng(sorted(layouts).index(layout))
-    tables, lengths = _tick(g, layouts[layout](g, rng))
+    rng = np.random.default_rng(sorted(ALL_LAYOUTS).index(layout))
+    tables, lengths = _tick(g, ALL_LAYOUTS[layout](g, rng))
     got = np.asarray(kernel(q, kpool, vpool, tables, lengths), np.float32)
     want = np.asarray(reference(q, kpool, vpool, tables, lengths))
-    # a bf16 pool's output is rounded to bf16: half a unit in the last place
-    tol = dict(rtol=2e-4, atol=2e-5) if g.dtype == "float32" else dict(
-        rtol=2 ** -8, atol=2 ** -8)
-    np.testing.assert_allclose(got, want, **tol)
+    np.testing.assert_allclose(got, want, **_tol(g))
+
+
+@pytest.mark.parametrize("heads", ["rep4", "mistral"])
+def test_a_walk_of_no_step_hands_the_chain_on(heads):
+    """Rows of length 0 (no caller sends them: a tick's are ``pos + 1``)
+    make walks of no step, which wait for nothing and still start the
+    fetch of the run after them: a row alone, a run of five, the last rows
+    of a tile and the first of the next. Their output is zero and every
+    other row's is the reference's."""
+    g = SHAPES[heads]
+    q, kpool, vpool, kernel, reference = _case(heads, g.dtype)
+    rng = np.random.default_rng(17)
+    rows = _decode(g, rng, 3) + _chunk(g, rng, 4, 5) + _decode(g, rng, 20) \
+        + _chunk(g, rng, 0, 8) + _decode(g, rng, 2)
+    tables, lengths = _tick(g, rows)
+    none = np.zeros((g.T,), bool)
+    none[[1, 3, 4, 5, 6, 7, 30, 31, 32, 33]] = True
+    lengths = jnp.where(jnp.asarray(none), 0, lengths)
+    got = np.asarray(kernel(q, kpool, vpool, tables, lengths), np.float32)
+    want = np.asarray(reference(q, kpool, vpool, tables, lengths))
+    np.testing.assert_allclose(got[~none], want[~none], **_tol(g))
+    assert not got[none].any()
 
 
 @pytest.mark.parametrize("heads", ["rep1", "rep4"])
@@ -344,9 +468,46 @@ def test_count_steps_against_every_column(heads, layout):
     assert want[0] > 0
 
 
+def _brute_walks(starts, tile):
+    """A call's walks, a row at a time: a new one wherever the table
+    changes or a tile begins."""
+    return sum(new or r % tile == 0 for r, new in enumerate(starts))
+
+
+def _starts(g, rows):
+    """Whole tiles' rows of a layout: which carry another table than the
+    row before (the pads the zero table)."""
+    tabs = [tuple(t) if np.ndim(t) else (0,) * g.MB for t, _ in rows] \
+        + [(0,) * g.MB] * (g.T + -g.T % 32 - len(rows))
+    return [True] + [a != b for a, b in zip(tabs[1:], tabs[:-1])]
+
+
+@pytest.mark.parametrize("heads,layout", [
+    (h, l) for h, ls in (("rep4", {**LAYOUTS, **CHAIN_LAYOUTS}),
+                         ("mistral", {**STEP_LAYOUTS, **CHAIN_LAYOUTS}),
+                         ("rep4x32", ONE_TILE_LAYOUTS)) for l in sorted(ls)],
+    ids=lambda x: x)
+def test_count_walks_against_the_rows(heads, layout):
+    """The host's count of a call's walks against the runs counted a row
+    at a time."""
+    g = SHAPES[heads]
+    starts = _starts(g, ALL_LAYOUTS[layout](g, np.random.default_rng(
+        sorted(ALL_LAYOUTS).index(layout))))
+    want = _brute_walks(starts, 32)
+    assert count_walks(starts, 32) == want >= len(starts) // 32
+    if layout == "a_call_of_one_walk":
+        assert want == 1
+    with pytest.raises(ValueError):
+        count_walks(starts[:-1], 32)          # not whole tiles
+
+
 @pytest.mark.parametrize("layout", [
     "chunk_across_a_tile_and_a_steps_edge",      # the tile form, and rows
-    "decode_rows_at_the_steps_edges"])           # rows alone
+    "decode_rows_at_the_steps_edges",            # rows alone
+    # the chain of fetches under a choice (every step masked by it)
+    "a_tile_of_one_step_decode_rows", "one_row_between_two_runs",
+    "pad_rows_between_two_sequences",
+    "run_ends_with_its_tile_before_a_tile_of_pads"])
 def test_a_choice_in_lane_planes_at_two_planes_a_step(layout):
     """``paged_attention(chosen=)`` where a step carries 256 positions and
     the choice lies in planes of 128, as ``sparse_choice`` writes them: a
@@ -357,8 +518,8 @@ def test_a_choice_in_lane_planes_at_two_planes_a_step(layout):
     g = SHAPES["keye"]
     assert g.C == 256 and g.MB * g.BS // 128 == 5
     q, kpool, vpool, _, _ = _case("keye", g.dtype)
-    rng = np.random.default_rng(sorted(STEP_LAYOUTS).index(layout))
-    tables, lengths = _tick(g, STEP_LAYOUTS[layout](g, rng))
+    rng = np.random.default_rng(sorted(ALL_LAYOUTS).index(layout))
+    tables, lengths = _tick(g, ALL_LAYOUTS[layout](g, rng))
     chosen = rng.random((g.T, g.MB * g.BS)) < 0.3
     chosen[np.arange(g.T), np.asarray(lengths) - 1] = True   # a row, itself
     planes = jnp.asarray(chosen, jnp.float32).reshape(
@@ -482,7 +643,7 @@ def test_tick_span_counts_fetch_steps_and_open_ones():
     before = totals()
     tracer = tracing.get_tracer()
     was, tracer.enabled = tracer.enabled, True
-    want = []
+    want, walks = [], []
     try:
         for _ in range(7):
             # the tick's rows as the scheduler will lay them: decode rows
@@ -499,9 +660,11 @@ def test_tick_span_counts_fetch_steps_and_open_ones():
             lengths = [n for run in runs for n in run]
             starts = [i == 0 for run in runs for i, _ in enumerate(run)]
             pads = -len(lengths) % 32      # the bucket's and the wrapper's
-            want.append(_brute_steps(
-                lengths + [1] * pads, starts + [True] + [False] * (pads - 1)
-                if pads else starts, 32, C, window))
+            starts = starts + [True] + [False] * (pads - 1) if pads \
+                else starts
+            want.append(_brute_steps(lengths + [1] * pads, starts, 32, C,
+                                     window))
+            walks.append(_brute_walks(starts, 32))
             eng.step()
         events = tracer.export_chrome()["traceEvents"]
     finally:
@@ -509,6 +672,8 @@ def test_tick_span_counts_fetch_steps_and_open_ones():
     ticks = [e["args"] for e in events if e.get("name") == "decode_tick"][-7:]
     assert [(a["attn_steps"], a["attn_open_steps"]) for a in ticks] == [
         (layers * n, layers * n_open) for n, n_open in want]
+    # a walk a run of rows under one table, in each layer's call
+    assert [a["attn_walks"] for a in ticks] == [layers * n for n in walks]
     # which geometry ran: the positions a step carries, the kernel's rule
     assert {a["attn_step_positions"] for a in ticks} == {C}
     got = [b - a for a, b in zip(before, totals())]

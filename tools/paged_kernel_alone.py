@@ -14,7 +14,9 @@ case is a tick's rows as the engine lays them out (decode rows first, then a
 prompt chunk's rows, then pads up to the bucket) over a pool of the cell's
 size, block ids drawn without order. One JSON line a case: ``us_per_call``,
 the fetch steps the call walks and how many of them are open
-(``count_steps``), the cache positions a step carries, ``us_per_step``.
+(``count_steps``), the cache positions a step carries, ``us_per_step``, and
+the call's ``walks`` (runs of rows under one table, the tree's
+``count_walks`` of the timed module's tiles) with ``us_per_walk``.
 ``--module``: time another file's kernel (the parent's, a variant's) under
 the same cases, its own geometry counted; a file without ``count_steps``
 reports no steps. The ``chosen`` form (a sparse layer's masked walk, Keye's)
@@ -37,6 +39,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+
+from deepspeed_tpu.ops.pallas.paged_attention import count_walks  # noqa: E402
 
 _LANES = 128
 # heads, KV heads, head width, block size, then the cell's calls: name ->
@@ -108,6 +112,13 @@ CONFIGS = {
         ticks={"chunk": (2048, 24, 17000, 2024, 7000),
                "chunk_late": (2048, 24, 17000, 2024, 14000),
                "decode": (256, 24, 17000, 0, 0)}),
+    # serve-ouro-2.6b-cot-closed: 10 rows at 64-480 of 16 KV heads, 192 such
+    # calls a tick (a cache layer a pass and a layer: 193 blocks each)
+    "ouro": dict(
+        heads=(16, 16, 128), bs=32, products="float32",
+        calls={"paged_attention": dict(window=None, blocks=193, cols=16)},
+        ticks={"chunk": (512, 9, 272, 112, 0, 208),
+               "decode": (64, 10, 272, 0, 0, 208)}),
 }
 TINY = dict(
     heads=(8, 2, 64), bs=8, products="float32",
@@ -312,9 +323,12 @@ def main():
                             lengths, starts, R, P * bs, call["window"])
                         if call.get("chosen"):  # no step of a choice is open
                             open_ = 0
+                        walks = count_walks(starts, R)
                         line.update(step_positions=P * bs, steps=steps,
                                     open_steps=open_, us_per_step=round(
-                                        line["us_per_call"] / steps, 4))
+                                        line["us_per_call"] / steps, 4),
+                                    walks=walks, us_per_walk=round(
+                                        line["us_per_call"] / walks, 4))
                     print(json.dumps(line), flush=True)
                     log.write(json.dumps(line) + "\n")
 

@@ -196,7 +196,8 @@ def causal_lm_spec(cfg: Union[str, T.TransformerConfig],
                 from deepspeed_tpu.sequence.tiled import tiled_lm_loss
 
                 loss = tiled_lm_loss(hidden, head, tokens, _mask_of(batch),
-                                     num_tiles=loss_tiles)
+                                     num_tiles=loss_tiles,
+                                     logits_divisor=cfg.logits_divisor)
             elif loss_impl == "fused":
                 # default training loss: bf16 logits + fp32 softmax stats
                 # with a bandwidth-tuned custom VJP (torch-autocast CE
@@ -204,9 +205,9 @@ def causal_lm_spec(cfg: Union[str, T.TransformerConfig],
                 # loss_impl="exact"; inference/apply_fn logits are always
                 # exact fp32)
                 loss = T.fused_lm_loss(hidden, head, tokens,
-                                       _mask_of(batch))
+                                       _mask_of(batch), cfg.logits_divisor)
             else:
-                logits = T.head_matmul(hidden, head.astype(hidden.dtype))
+                logits = T.lm_logits(hidden, head, cfg)
                 loss = T.causal_lm_loss(logits, tokens, _mask_of(batch))
         if cfg.n_experts > 0:
             loss = loss + cfg.moe_aux_coef * aux

@@ -1667,6 +1667,17 @@ _MAMBA2_TENSORS = {
     "gate_norm": ("norm.weight", False)}
 
 
+def _mamba2_leaves(sd: Dict[str, Any], mix: str, layers) -> Dict[str, Any]:
+    """The ``mamba2`` layers' leaves, stacked over ``layers``; ``mix``: the
+    mixer's prefix with ``{}`` for the layer's index."""
+    leaves = {ours: _stack(sd, mix + theirs, layers, transpose=matrix)
+              for ours, (theirs, matrix) in _MAMBA2_TENSORS.items()}
+    # [channels, 1, taps] -> [taps, channels]
+    leaves["conv_w"] = np.stack([
+        _np(sd[(mix + "conv1d.weight").format(i)])[:, 0].T for i in layers])
+    return leaves
+
+
 def params_from_nemotron_h(sd: Dict[str, Any], cfg: TransformerConfig
                            ) -> PyTree:
     """The family's tensor names (ASSUMED from its published modelling
@@ -1689,13 +1700,7 @@ def params_from_nemotron_h(sd: Dict[str, Any], cfg: TransformerConfig
     blocks: Dict[str, Any] = {"ln1": {"scale": _stack(
         sd, lyr + "norm.weight", range(cfg.num_layers))}}
     if of["mamba2"]:
-        blocks["mamba2"] = {
-            ours: _stack(sd, mix + theirs, of["mamba2"], transpose=matrix)
-            for ours, (theirs, matrix) in _MAMBA2_TENSORS.items()}
-        # [channels, 1, taps] -> [taps, channels]
-        blocks["mamba2"]["conv_w"] = np.stack([
-            _np(sd[(mix + "conv1d.weight").format(i)])[:, 0].T
-            for i in of["mamba2"]])
+        blocks["mamba2"] = _mamba2_leaves(sd, mix, of["mamba2"])
     if of["full"]:
         blocks["attn"] = {
             f"w{x}": _stack(sd, mix + f"{x}_proj.weight", of["full"],
@@ -1731,6 +1736,142 @@ def params_from_nemotron_h(sd: Dict[str, Any], cfg: TransformerConfig
     if not cfg.tie_embeddings:
         params["lm_head"] = _np(sd["lm_head.weight"]).T
     return params
+
+
+# --------------------------------------------------------------------------- #
+# Granite 4.0-H (IBM: a Mamba-2 mixer or attention AND experts beside a
+# shared MLP in every block, four scalars of a maximal-update parametrisation)
+# --------------------------------------------------------------------------- #
+
+_GRANITE_KINDS = {"mamba": "mamba2", "attention": "full"}
+
+
+def config_from_granitemoehybrid(hf_config) -> TransformerConfig:
+    """``model_type`` ``granitemoehybrid``: every layer is a PAIRED block,
+    ``x += r * Mixer(norm x)`` then ``x += r * (Experts(u) + Shared(u))`` on
+    ``u = norm x``, ``r`` the ``residual_multiplier``. ``layer_types`` names
+    a layer's mixer: ``mamba`` a Mamba-2 mixer (``mamba_n_heads`` heads of
+    ``mamba_d_head``, ``mamba_n_groups`` groups of ``mamba_d_state``, a
+    convolution of ``mamba_d_conv`` taps with a bias, chunks of
+    ``mamba_chunk_size``), ``attention`` grouped-query attention whose
+    scores' factor is ``attention_multiplier`` and which sees no positions
+    (``position_embedding_type`` ``nope``). ``num_local_experts`` SiLU-gated
+    experts ``intermediate_size`` wide, ``num_experts_per_tok`` a token by
+    the largest router logits, weighted by a softmax over the chosen logits
+    (which IS the softmax over all, top-k, renormalised: that is what is
+    computed), beside a gated shared MLP ``shared_intermediate_size`` wide
+    added without a gate. The embedding is multiplied by
+    ``embedding_multiplier`` and the (tied) head's logits divided by
+    ``logits_scaling``. A SHARE of the experts as ``nemotron_h``'s:
+    ``num_local_experts`` is then the experts held, ``router_experts`` the
+    router's width and ``first_expert`` the first one held."""
+    kinds = tuple(hf_config.layer_types)
+    if set(kinds) - set(_GRANITE_KINDS) \
+            or len(kinds) != hf_config.num_hidden_layers:
+        raise ValueError(
+            "granitemoehybrid: layer_types names every layer `mamba` or "
+            f"`attention`; got {kinds!r} for num_hidden_layers="
+            f"{hf_config.num_hidden_layers}")
+    nh, p = hf_config.mamba_n_heads, hf_config.mamba_d_head
+    pos = getattr(hf_config, "position_embedding_type", "nope")
+    held = int(getattr(hf_config, "num_local_experts", 0) or 0)
+    if getattr(hf_config, "attention_bias", False) \
+            or getattr(hf_config, "mamba_proj_bias", False) \
+            or nh * p != int(getattr(hf_config, "mamba_expand", 2)) \
+            * hf_config.hidden_size \
+            or getattr(hf_config, "hidden_act", "silu") != "silu" \
+            or getattr(hf_config, "normalization_function",
+                       "rmsnorm") != "rmsnorm" \
+            or not getattr(hf_config, "mamba_conv_bias", True) \
+            or pos != "nope" or not held \
+            or not getattr(hf_config, "tie_word_embeddings", True):
+        raise NotImplementedError(
+            "granitemoehybrid: RMSNorms, no bias but the convolution's, an "
+            "inner width of mamba_expand x hidden_size, SiLU, routed experts, "
+            "a tied head and position_embedding_type `nope` are what is "
+            "written")
+    router = int(getattr(hf_config, "router_experts", held))
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size, hidden_size=hf_config.hidden_size,
+        num_layers=len(kinds), num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        max_seq_len=hf_config.max_position_embeddings,
+        pos_emb="none", norm="rmsnorm", activation="swiglu", use_bias=False,
+        tie_embeddings=True,
+        norm_eps=float(hf_config.rms_norm_eps), dtype="float32",
+        layer_kinds=tuple(_GRANITE_KINDS[k] for k in kinds),
+        mamba2_heads=nh, mamba2_head_dim=p,
+        mamba2_groups=hf_config.mamba_n_groups,
+        mamba2_state=hf_config.mamba_d_state,
+        mamba2_conv=hf_config.mamba_d_conv,
+        mamba2_chunk=int(getattr(hf_config, "mamba_chunk_size", 128)),
+        emb_multiplier=float(getattr(hf_config, "embedding_multiplier", 1.0)),
+        residual_multiplier=float(getattr(hf_config, "residual_multiplier",
+                                          1.0)),
+        attn_scale=float(getattr(hf_config, "attention_multiplier", 0.0)
+                         or 0.0),
+        logits_divisor=float(getattr(hf_config, "logits_scaling", 1.0)),
+        n_experts=held,
+        moe_top_k=int(hf_config.num_experts_per_tok),
+        moe_ffn_size=hf_config.intermediate_size,
+        moe_shared_size=int(getattr(hf_config, "shared_intermediate_size", 0)
+                            or 0),
+        moe_score_func="softmax", moe_route_norm=True,
+        moe_dispatch="ragged",
+        moe_router_experts=router if router != held else 0,
+        moe_first_expert=int(getattr(hf_config, "first_expert", 0)))
+
+
+def params_from_granitemoehybrid(sd: Dict[str, Any], cfg: TransformerConfig
+                                 ) -> PyTree:
+    """The family's tensor names (ASSUMED from its published modelling
+    code; no checkpoint is here to confirm them): ``model.embed_tokens``,
+    ``model.layers.<i>.{input_layernorm, post_attention_layernorm}``,
+    ``model.norm``, a tied head. A ``mamba`` layer's mixer under ``mamba.``
+    as ``nemotron_h``'s under ``mixer.`` (``_MAMBA2_TENSORS``); an
+    ``attention`` layer's ``self_attn.{q,k,v,o}_proj``; every layer's
+    ``block_sparse_moe.input_linear [E, 2 F, H]`` (an expert's gate rows,
+    then its up rows), ``.output_linear [E, H, F]``, ``.router.layer [E,
+    H]`` and ``shared_mlp.input_linear [2 Fs, H]`` / ``.output_linear [H,
+    Fs]``. The mixers' leaves are stacked by mixer, everything else over
+    all layers (``TransformerConfig.mixer_layers``)."""
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lyr = pre + "layers.{}."
+    L = cfg.num_layers
+    E, first, F, Fs = cfg.n_experts, cfg.moe_first_expert, cfg.moe_ffn, \
+        cfg.moe_shared_size
+    of = {kind: [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+          for kind in ("mamba2", "full")}
+    # [L, E, 2 F, H] -> gate and up [L, E, H, F]
+    fused = np.stack([_np(sd[(lyr + "block_sparse_moe.input_linear.weight")
+                             .format(i)])[first:first + E] for i in range(L)])
+    blocks: Dict[str, Any] = {
+        "ln1": {"scale": _stack(sd, lyr + "input_layernorm.weight", L)},
+        "ln2": {"scale": _stack(sd, lyr + "post_attention_layernorm.weight",
+                                L)},
+        "gate_w": _stack(sd, lyr + "block_sparse_moe.router.layer.weight", L,
+                         transpose=True),
+        "w_gate": fused[:, :, :F].transpose(0, 1, 3, 2),
+        "w_up": fused[:, :, F:].transpose(0, 1, 3, 2),
+        "w_down": np.stack([
+            _np(sd[(lyr + "block_sparse_moe.output_linear.weight").format(i)]
+                )[first:first + E].transpose(0, 2, 1) for i in range(L)])}
+    if Fs:
+        shared = _stack(sd, lyr + "shared_mlp.input_linear.weight", L,
+                        transpose=True)                     # [L, H, 2 Fs]
+        blocks.update(sw_gate=shared[..., :Fs], sw_up=shared[..., Fs:],
+                      sw_down=_stack(
+                          sd, lyr + "shared_mlp.output_linear.weight", L,
+                          transpose=True))
+    if of["mamba2"]:
+        blocks["mamba2"] = _mamba2_leaves(sd, lyr + "mamba.", of["mamba2"])
+    if of["full"]:
+        blocks["attn"] = {
+            f"w{x}": _stack(sd, lyr + f"self_attn.{x}_proj.weight",
+                            of["full"], transpose=True) for x in "qkvo"}
+    return {"tok_emb": _np(sd[pre + "embed_tokens.weight"]),
+            "blocks": blocks,
+            "final_norm": {"scale": _np(sd[pre + "norm.weight"])}}
 
 
 # --------------------------------------------------------------------------- #
@@ -1782,6 +1923,8 @@ def params_from_ouro(sd: Dict[str, Any], cfg: TransformerConfig) -> PyTree:
 
 _ARCH_TABLE = {
     "afmoe": (config_from_afmoe, params_from_afmoe),
+    "granitemoehybrid": (config_from_granitemoehybrid,
+                         params_from_granitemoehybrid),
     "KeyeVL2": (config_from_keye_vl2, params_from_keye_vl2),
     "kimi_linear": (config_from_kimi_linear, params_from_kimi_linear),
     "lfm2_moe": (config_from_lfm2_moe, params_from_lfm2_moe),

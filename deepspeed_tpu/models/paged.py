@@ -876,14 +876,14 @@ def _grouped_mixer(cfg, tick, pool, entry, kind):
     def mixer(h, lp, flat, li, nth, acts):
         q, k, v = _project_qkv(cfg, h, lp, tick.positions,
                                tick.rope.get(kind))
-        scale = own = None
+        scale, own = cfg.attn_scale or None, None
         if call.pack > 1:
             # heads of 64 lie two to a pool row (``kv_lane_pack``); the
             # scores' factor stays the unpacked head's
             k, v = (x.reshape(Tn, cfg.kv_heads // call.pack, -1)
                     for x in (k, v))
             q, own = _lane_packed(q, cfg.kv_heads)
-            scale = cfg.head_dim ** -0.5
+            scale = cfg.score_scale
         flat, tables = write(flat, nth, k, v)
         attn = attend(q, flat, tables, scale, **bias)
         if own is not None:
@@ -1317,8 +1317,7 @@ def head_logits(params: PyTree, x: jax.Array, cfg: T.TransformerConfig,
     with jax.named_scope("lm_head"):
         if pdf is None:
             x = T._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-        head = T._lm_head_of(params, cfg)
-        logits = T.head_matmul(x, head.astype(x.dtype))
+        logits = T.lm_logits(x, T._lm_head_of(params, cfg), cfg)
         if cfg.lm_head_bias:
             logits = logits + params["lm_head_b"].astype(jnp.float32)
     return (logits, pdf) if with_exit else logits
@@ -1392,7 +1391,7 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
                 if seg.post_norms:
                     out = T._norm(out, lp["ln1_post"], seg.norm,
                                   seg.norm_eps)
-                resid = x + out
+                resid = x + T.scale_residual(out, seg)
             if seg.one_sublayer:
                 return resid, flat, acts, None
         # ``mlp`` is a dense FFN's scope; an expert layer's operations
@@ -1416,7 +1415,7 @@ def forward_hidden(params: PyTree, tokens: jax.Array, positions: jax.Array,
                 down, n_rows = T._ffn(h2, lp, seg)[0], None
             if seg.post_norms:
                 down = T._norm(down, lp["ln2_post"], seg.norm, seg.norm_eps)
-        return resid + down, flat, acts, n_rows
+        return resid + T.scale_residual(down, seg), flat, acts, n_rows
 
     # the kind of every layer of the stack: ``nth`` counts a kind's layers
     # from its first

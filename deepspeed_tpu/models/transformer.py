@@ -202,6 +202,17 @@ class TransformerConfig:
     post_norms: bool = False
     attn_gate: bool = False
     emb_multiplier: float = 1.0
+    # three more scalars of the same parametrisation (the
+    # ``granitemoehybrid`` family), each a number of the config and never
+    # folded into a weight: ``residual_multiplier`` scales what EACH
+    # sublayer (the mixer, then the FFN or experts) adds to the residual
+    # stream; ``attn_scale`` is the factor of a grouped-query layer's
+    # scores (0: ``head_dim ** -0.5``, :attr:`score_scale`);
+    # ``logits_divisor`` divides the head's logits. At their defaults
+    # nothing is traced
+    residual_multiplier: float = 1.0
+    attn_scale: float = 0.0
+    logits_divisor: float = 1.0
     # rotary by layer kind, where the kinds of ``layer_kinds`` differ in it
     # (:meth:`rope_of`): ``(kind, None)`` is no rotary on that kind's layers
     # (the ``afmoe`` family's full layers), ``(kind, (theta, rope_scaling))``
@@ -301,6 +312,18 @@ class TransformerConfig:
         if self.attn_head_dim is not None:
             return self.attn_head_dim
         return self.hidden_size // self.num_heads
+
+    @property
+    def score_scale(self) -> float:
+        """The factor of a grouped-query layer's scores."""
+        return self.attn_scale or self.head_dim ** -0.5
+
+    @property
+    def rescaled(self) -> bool:
+        """One of the four scalars (the embedding's, the residual stream's,
+        the scores', the logits') is off its default."""
+        return (self.emb_multiplier, self.residual_multiplier,
+                self.attn_scale, self.logits_divisor) != (1.0, 1.0, 0.0, 1.0)
 
     @property
     def moe_ffn(self) -> int:
@@ -483,10 +506,12 @@ class TransformerConfig:
         return tuple(out)
 
     def _sublayer_params(self, active: bool = False) -> int:
-        """:meth:`num_params` of a stack of single sublayers (every norm
-        an RMSNorm's one gain, no bias but a convolution's); ``active``:
-        the parameters a token meets (``moe_top_k`` of the routed experts
-        of a layer)."""
+        """:meth:`num_params` of a stack with ``mamba2`` layers (every norm
+        an RMSNorm's one gain, no bias but a convolution's): of single
+        sublayers (:attr:`one_sublayer`), or of paired blocks, whose every
+        layer holds a second norm and a feed-forward part behind its
+        mixer; ``active``: the parameters a token meets (``moe_top_k`` of
+        the routed experts of a layer)."""
         from deepspeed_tpu.models.hybrid import mixer_specs
 
         h, kinds = self.hidden_size, self.layer_kinds
@@ -507,7 +532,8 @@ class TransformerConfig:
             mixer_specs(self, "mamba2").values()) if self.mamba2_heads else 0}
         attn = 2 * h * qdim + 2 * h * kv + (
             2 * self.head_dim if self.qk_norm else 0)
-        layers = sum(h + per.get(kind, attn) for kind in kinds)
+        paired = 0 if self.one_sublayer else h + ffn
+        layers = sum(h + per.get(kind, attn) + paired for kind in kinds)
         return layers + h + self.vocab_size * h * (
             1 if self.tie_embeddings else 2)
 
@@ -528,7 +554,7 @@ class TransformerConfig:
                         mixer_specs(self, kind),
                         is_leaf=lambda x: isinstance(x, tuple)))
             return total
-        if self.one_sublayer:
+        if self.one_sublayer or "mamba2" in self.layer_kinds:
             return self._sublayer_params()
         if self.first_dense_layers:
             shared = dataclasses.replace(self, first_dense_layers=0,
@@ -638,14 +664,17 @@ def _check_kinds_of_blocks(cfg: TransformerConfig) -> None:
             f"window, are not written (mla={cfg.mla}, kinds {sorted(kinds)})")
     if "sparse" in kinds and (
             kinds != {"sparse"} or cfg.mla or cfg.pos_emb != "rope"
+            or cfg.attn_scale
             or not (cfg.sparse_topk and cfg.index_heads
                     and cfg.index_head_dim)):
         raise NotImplementedError(
             "sparse layers are a whole stack of grouped-query layers under "
             "rotary (every layer holds an indexer's leaves), with "
-            "sparse_topk, index_heads and index_head_dim (got kinds "
+            "sparse_topk, index_heads and index_head_dim, their scores' "
+            "factor head_dim ** -0.5 (got kinds "
             f"{sorted(kinds)}, mla={cfg.mla}, pos_emb={cfg.pos_emb!r}, "
-            f"{cfg.sparse_topk}, {cfg.index_heads}, {cfg.index_head_dim})")
+            f"{cfg.sparse_topk}, {cfg.index_heads}, {cfg.index_head_dim}, "
+            f"attn_scale={cfg.attn_scale})")
     own = kinds & set(MIXERS[1:])
     if own and (cfg.attn_bias_enabled or cfg.use_bias or cfg.attn_gate
                 or cfg.post_norms or cfg.parallel_block):
@@ -662,6 +691,16 @@ def _check_kinds_of_blocks(cfg: TransformerConfig) -> None:
             "`window` mixers, no leading dense segment, no latent attention "
             f"(got kinds {sorted(kinds)}, first_dense_layers="
             f"{cfg.first_dense_layers}, mla={cfg.mla})")
+    if "mamba2" in kinds and (
+            kinds - {"mamba2", "full", "window", "ffn"}
+            or cfg.first_dense_layers or cfg.mla or cfg.norm != "rmsnorm"):
+        raise NotImplementedError(
+            "mamba2 layers stand beside `full` and `window` layers under "
+            "RMSNorms (alone in a layer beside `ffn` layers, or each layer "
+            "a mixer and a feed-forward part), with no leading dense "
+            f"segment and no latent attention (got kinds {sorted(kinds)}, "
+            f"first_dense_layers={cfg.first_dense_layers}, mla={cfg.mla}, "
+            f"norm={cfg.norm!r})")
     if "mamba2" in kinds and not (
             cfg.mamba2_heads and cfg.mamba2_head_dim and cfg.mamba2_state
             and cfg.mamba2_conv >= 2
@@ -773,7 +812,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     _check_loop(cfg)
     if cfg.layer_kinds and not cfg.standard_blocks:
         return _init_kinds(cfg, rng)
-    if cfg.one_sublayer:
+    if cfg.one_sublayer or "mamba2" in cfg.layer_kinds:
         _check_kinds_of_blocks(cfg)
     if cfg.first_dense_layers:
         (dkey, dcfg), (_, rest) = cfg.segments
@@ -1102,10 +1141,11 @@ def _init_kinds(cfg: TransformerConfig, rng: jax.Array) -> PyTree:
     from deepspeed_tpu.models.hybrid import init_leaf
 
     if cfg.pos_emb != "none" or cfg.emb_norm or cfg.lm_head_bias \
-            or cfg.qk_norm or cfg.attn_bias_enabled:
+            or cfg.qk_norm or cfg.attn_bias_enabled or cfg.rescaled:
         raise NotImplementedError(
             "a stack of layer_kinds has no positional encoding, no "
-            "embedding norm, no attention or head bias and no qk-norm")
+            "embedding norm, no attention or head bias, no qk-norm and no "
+            "multiplier on its embedding, residual stream, scores or logits")
     std, out_std = cfg.init_std, cfg.init_std / math.sqrt(2 * cfg.num_layers)
     count = [0]
 
@@ -1151,6 +1191,28 @@ def _lm_head_of(params: PyTree, cfg: TransformerConfig) -> jax.Array:
 
         return dequantize_weight(head, cfg.compute_dtype)
     return head
+
+
+def scale_residual(part: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """What a sublayer adds to the residual stream: its output times
+    ``cfg.residual_multiplier`` (at 1: the output itself, nothing traced)."""
+    if cfg.residual_multiplier == 1.0:
+        return part
+    return part * jnp.asarray(cfg.residual_multiplier, part.dtype)
+
+
+def divide_logits(logits: jax.Array, divisor: float) -> jax.Array:
+    """Float32 logits (or their gradient) over ``TransformerConfig.
+    logits_divisor`` (at 1: themselves, nothing traced)."""
+    return logits if divisor == 1.0 else logits / jnp.float32(divisor)
+
+
+def lm_logits(x: jax.Array, head: jax.Array, cfg: TransformerConfig
+              ) -> jax.Array:
+    """The head over final-normed rows: :func:`head_matmul` (float32) and
+    the config's ``logits_divisor`` on its result."""
+    return divide_logits(head_matmul(x, head.astype(x.dtype)),
+                         cfg.logits_divisor)
 
 
 def _head_rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -1314,17 +1376,19 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           causal: bool = True,
                           segment_mask: Optional[jax.Array] = None,
                           bias: Optional[jax.Array] = None,
-                          window: int = 0) -> jax.Array:
+                          window: int = 0,
+                          scale: Optional[float] = None) -> jax.Array:
     """Reference (XLA-fused) attention. q:[B,S,N,D] k,v:[B,S,K,D]. fp32 softmax.
     ``bias``: additive [N, S, S] (ALiBi) applied before masking. ``window``:
     a causal row sees its last ``window`` positions, itself included (0:
-    every earlier one)."""
+    every earlier one). ``scale``: the scores' factor (None: ``D ** -0.5``)."""
     B, S, N, D = q.shape
     K = k.shape[2]
     if K != N:
         k = jnp.repeat(k, N // K, axis=2)
         v = jnp.repeat(v, N // K, axis=2)
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     scores = jnp.einsum("bsnd,btnd->bnst", q, k).astype(jnp.float32) * scale
     if bias is not None:
         scores = scores + bias[None]
@@ -1550,6 +1614,13 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         return fake_quant_symmetric(
             h, float(2 ** (cfg.act_quant_bits - 1) - 1))
 
+    def add(x, *parts):
+        """The residual stream and what the sublayers add to it, each
+        times ``cfg.residual_multiplier``."""
+        for part in parts:
+            x = x + scale_residual(part, cfg)
+        return x
+
     # the scopes a device trace sorts a block's operations by
     # (``attn`` / ``mlp``, under the engine's ``loss_and_grads``)
     with jax.named_scope({"mamba2": "ssd", "ffn": "mlp"}.get(
@@ -1560,7 +1631,7 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         with contextlib.nullcontext() if cfg.n_experts \
                 else jax.named_scope("mlp"):
             down, aux, meter = _ffn_metered(h, lp, cfg)
-        return x + down, aux, meter
+        return add(x, down), aux, meter
     if cfg.mla and kind in (None, "latent"):
         with jax.named_scope("attn"):
             # no rotary where the model has none (``pos_emb`` "none": the
@@ -1577,11 +1648,11 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
             attn = attn.reshape(B, S, cfg.num_heads * cfg.v_head_dim)
             attn = _ckpt_name(attn, "attn_out")
             attn_out = attn @ lp["wo"].astype(dt)
-            x = x + attn_out
+            x = add(x, attn_out)
         with jax.named_scope("mlp"):
             h2 = _aq(_norm(x, lp["ln2"], cfg.norm, cfg.norm_eps))
             down, aux, meter = _ffn_metered(h2, lp, cfg)
-            return x + down, aux, meter
+            return add(x, down), aux, meter
 
     @jax.named_scope("attn")
     def _attn_from_norm(h):
@@ -1616,6 +1687,8 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
                 alibi_bias(cfg.num_heads, S) * cfg.alibi_bias_scale
         if kind == "window":
             attn_kwargs["window"] = cfg.attn_window
+        if cfg.attn_scale:
+            attn_kwargs["scale"] = cfg.attn_scale
         if kind == "sparse":
             # no kernel takes a choice of positions: plain jnp
             from deepspeed_tpu.models.hybrid import windowed_attention
@@ -1707,15 +1780,15 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         attn_out = _attn_from_norm(h)
 
     if cfg.one_sublayer:
-        return x + attn_out, jnp.float32(0.0), None
+        return add(x, attn_out), jnp.float32(0.0), None
     if cfg.parallel_block:
         with jax.named_scope("mlp"):
             h2 = h if cfg.shared_parallel_norm else \
                 _aq(_norm(x, lp["ln2"], cfg.norm, cfg.norm_eps))
             down, aux, meter = _ffn_metered(h2, lp, cfg)
-            return x + attn_out + down, aux, meter
+            return add(x, attn_out, down), aux, meter
 
-    x = x + attn_out
+    x = add(x, attn_out)
 
     @jax.named_scope("mlp")
     def _ffn_delta(xr):
@@ -1733,7 +1806,7 @@ def _block_forward(x: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfi
         down, aux, meter = jax.checkpoint(_ffn_delta)(x)
     else:
         down, aux, meter = _ffn_delta(x)
-    return x + down, aux, meter
+    return add(x, down), aux, meter
 
 
 def _ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: TransformerConfig
@@ -1803,6 +1876,14 @@ def _require_one_stack(cfg: TransformerConfig, what: str) -> None:
             f"{what} runs one homogeneous layer stack; a model with leading "
             f"dense layers (first_dense_layers={cfg.first_dense_layers}) is "
             "served by FastGenEngine and trained without pipeline stages")
+    if cfg.rescaled:
+        raise NotImplementedError(
+            f"{what} applies no multiplier to the embedding, the residual "
+            "stream, the attention scores or the logits (emb_multiplier="
+            f"{cfg.emb_multiplier}, residual_multiplier="
+            f"{cfg.residual_multiplier}, attn_scale={cfg.attn_scale}, "
+            f"logits_divisor={cfg.logits_divisor}); forward() and "
+            "FastGenEngine do")
 
 
 # --------------------------------------------------------------------------- #
@@ -2235,7 +2316,7 @@ def forward(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     """tokens [B, S] int32 → logits [B, S, vocab] in fp32."""
     x, head, _ = forward_hidden(params, tokens, cfg, attention_fn,
                                 activation_constraint)
-    logits = head_matmul(x, head.astype(x.dtype))
+    logits = lm_logits(x, head, cfg)
     if cfg.lm_head_bias:
         logits = logits + params["lm_head_b"].astype(jnp.float32)
     return logits
@@ -2601,7 +2682,8 @@ def pipelined_lm_loss_and_grads(params: PyTree, tokens: jax.Array,
 
 
 def fused_lm_loss(hidden: jax.Array, head: jax.Array, tokens: jax.Array,
-                  loss_mask: Optional[jax.Array] = None) -> jax.Array:
+                  loss_mask: Optional[jax.Array] = None,
+                  logits_divisor: float = 1.0) -> jax.Array:
     """Head projection + next-token CE with a custom VJP tuned for HBM.
 
     torch-autocast semantics (the reference's fp16/bf16 engines compute
@@ -2613,7 +2695,9 @@ def fused_lm_loss(hidden: jax.Array, head: jax.Array, tokens: jax.Array,
     bf16 grad-logits array (softmax − onehot fused into its producing
     pass) instead of AD's fp32 grad + scatter-add + convert chain.
     Loss delta vs the exact path is the bf16 logit rounding (~1e-3),
-    identical in class to the r2 ``head_matmul`` bf16-cotangent change."""
+    identical in class to the r2 ``head_matmul`` bf16-cotangent change.
+    ``logits_divisor`` (``TransformerConfig.logits_divisor``) divides the
+    logits ahead of the softmax, and their gradient on the way back."""
     B, S, H = hidden.shape
     mask = (jnp.ones((B, S), jnp.float32) if loss_mask is None
             else loss_mask.astype(jnp.float32))
@@ -2629,8 +2713,9 @@ def fused_lm_loss(hidden: jax.Array, head: jax.Array, tokens: jax.Array,
         tgt = tokens[:, 1:]
         # one bf16 [B,S-1,V] buffer; the f32-accumulated matmul casts in
         # its epilogue, logsumexp upconverts in its reduce
-        logits = jnp.matmul(xs, wc,
-                            preferred_element_type=jnp.float32).astype(dt)
+        logits = divide_logits(
+            jnp.matmul(xs, wc, preferred_element_type=jnp.float32),
+            logits_divisor).astype(dt)
         lf = logits.astype(jnp.float32)
         logz = jax.nn.logsumexp(lf, axis=-1)
         picked = jnp.take_along_axis(lf, tgt[..., None], axis=-1)[..., 0]
@@ -2647,8 +2732,9 @@ def fused_lm_loss(hidden: jax.Array, head: jax.Array, tokens: jax.Array,
                == tgt[..., None])
         # single fused pass: read bf16 logits, exp, subtract onehot, scale,
         # write bf16 grad-logits — feeds both backward matmuls
-        gl = ((jnp.exp(logits.astype(jnp.float32) - logz[..., None])
-               - one.astype(jnp.float32)) * coef).astype(dt)
+        gl = divide_logits(
+            (jnp.exp(logits.astype(jnp.float32) - logz[..., None])
+             - one.astype(jnp.float32)) * coef, logits_divisor).astype(dt)
         dx = jnp.matmul(gl, wc.T, preferred_element_type=jnp.float32) \
             .astype(dt)
         dw = jnp.matmul(xs.reshape(-1, xs.shape[-1]).T,

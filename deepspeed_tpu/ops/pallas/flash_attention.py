@@ -625,14 +625,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     segment_mask: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_kv: Optional[int] = None,
-                    window: int = 0) -> jax.Array:
+                    window: int = 0,
+                    scale: Optional[float] = None) -> jax.Array:
     """Drop-in for ``models.transformer.dot_product_attention``.
 
     q: [B, S, N, D]; k, v: [B, S, K, D] (K divides N → GQA via kernel index
     maps, no repetition in HBM). ``window`` (a Python int, closed over like
     ``causal``; 0: none): a row sees its last ``window`` positions, itself
     included; the calls then carry a name of their own
-    (``window_flash_fwd`` / ``_dq`` / ``_dkv``). Arbitrary masks fall back
+    (``window_flash_fwd`` / ``_dq`` / ``_dkv``). ``scale``: the scores'
+    factor (None: ``D ** -0.5``). Arbitrary masks fall back
     to the XLA reference implementation (the Pallas kernel handles causal,
     causal under a window, and full).
 
@@ -647,7 +649,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
         return dot_product_attention(q, k, v, causal=causal,
                                      segment_mask=segment_mask,
-                                     window=window)
+                                     window=window, scale=scale)
     if window and not causal:
         raise ValueError("a window is the last positions a causal row sees: "
                          f"window={window} needs causal=True")
@@ -657,7 +659,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if N % K != 0:
         raise ValueError(f"q heads {N} not divisible by kv heads {K}")
     local = functools.partial(_flash_local, causal=causal, block_q=block_q,
-                              block_kv=block_kv, window=int(window))
+                              block_kv=block_kv, window=int(window),
+                              scale=scale)
     part = _mesh_partition(B, N, K)
     if part is None:
         return local(q, k, v)
@@ -682,7 +685,8 @@ def choose_blocks(S: int, Skv: int):
     return min(1024, _round_pow2(S)), min(1024, _round_pow2(Skv))
 
 
-def _flash_local(q, k, v, *, causal, block_q, block_kv, window=0):
+def _flash_local(q, k, v, *, causal, block_q, block_kv, window=0,
+                 scale=None):
     B, S, N, D = q.shape
     rep = N // k.shape[2]
     Skv = k.shape[1]
@@ -699,7 +703,8 @@ def _flash_local(q, k, v, *, causal, block_q, block_kv, window=0):
     kb = _pad_seq(to_bn(k), block_kv)
     vb = _pad_seq(to_bn(v), block_kv)
 
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     o = _flash(qb, kb, vb, scale, causal, Skv, S, rep, block_q, block_kv,
                window)
     o = o[:, :S]

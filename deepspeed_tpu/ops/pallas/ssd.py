@@ -208,6 +208,19 @@ def count_pieces(runs: Sequence[Tuple[int, int]], chunk: int) -> int:
                for first, n in runs)
 
 
+def _as_stored(x):
+    """A sequence's row of the store, read or about to be written, pinned
+    to the store's own order of dimensions. The loop below meets the store
+    only through such rows; left to itself the compiler may instead give
+    the WHOLE store the order its products like inside the loop and re-lay
+    it around every ``ssd_step`` call, whose operand's order is fixed: 1.4
+    GB copied twice a layer in a stack of paired blocks (PERF.md, PR 60)."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
 def _dot(spec, x, y):
     """A product of float32 operands at float32's precision."""
     return jnp.einsum(spec, x, y, precision=lax.Precision.HIGHEST,
@@ -303,8 +316,8 @@ def ssd_chunk(x: jax.Array, delta: jax.Array, g: jax.Array, B: jax.Array,
         c = t0 // L
         at = c * L
         mine = lax.dynamic_slice(piece, (at,), (L,)) == piece[t0]   # [L]
-        stored = lax.dynamic_slice(
-            state, (slot[t0], 0, 0, 0), (1,) + state.shape[1:])
+        stored = _as_stored(lax.dynamic_slice(
+            state, (slot[t0], 0, 0, 0), (1,) + state.shape[1:]))
         s0 = jnp.where(start[t0], jnp.where(
             fresh[t0], 0.0, from_store(stored[0], nh)), s)
         gam_c = lax.dynamic_slice(gam, (at, 0), (L, nh))
@@ -324,7 +337,8 @@ def ssd_chunk(x: jax.Array, delta: jax.Array, g: jax.Array, B: jax.Array,
                 L, G, rep, P), B_c).reshape(nh, P, N)
         # the run ends in this piece: its state is its sequence's
         state = lax.dynamic_update_slice(
-            state, jnp.where(last[end[t0]], to_store(s, G)[None], stored),
+            state, _as_stored(jnp.where(
+                last[end[t0]], to_store(s, G)[None], stored)),
             (slot[t0], 0, 0, 0))
         return i + 1, s, y, state
 

@@ -127,7 +127,7 @@ def domino_lm_loss(params: PyTree, tokens: jax.Array, cfg: T.TransformerConfig,
         hidden, head, aux = T.forward_hidden(
             params, tk, cfg, attention_fn=attention_fn,
             activation_constraint=activation_constraint)
-        logits = T.head_matmul(hidden, head.astype(hidden.dtype))
+        logits = T.lm_logits(hidden, head, cfg)
         mk = None
         if loss_mask is not None:
             mk = jax.lax.slice_in_dim(loss_mask, c * step, (c + 1) * step, 0)

@@ -56,8 +56,10 @@ def sequence_tiled_compute(fn: Callable[[jax.Array], jax.Array], x: jax.Array,
 
 def tiled_lm_loss(hidden: jax.Array, head: jax.Array, tokens: jax.Array,
                   loss_mask: Optional[jax.Array] = None,
-                  num_tiles: int = 8, remat: bool = True) -> jax.Array:
-    """Next-token CE without materializing [B, S, vocab] logits.
+                  num_tiles: int = 8, remat: bool = True,
+                  logits_divisor: float = 1.0) -> jax.Array:
+    """Next-token CE without materializing [B, S, vocab] logits
+    (``logits_divisor``: ``TransformerConfig.logits_divisor``).
 
     Parity: ``TiledFusedLogitsLoss`` (``ulysses_sp.py:1065``). hidden: [B,S,H]
     (pre-head final activations), head: [H,V]. Scans sequence tiles, computing
@@ -84,10 +86,12 @@ def tiled_lm_loss(hidden: jax.Array, head: jax.Array, tokens: jax.Array,
     head_c = head.astype(hidden.dtype)
 
     def tile_body(carry, operand):
-        from deepspeed_tpu.models.transformer import head_matmul
+        from deepspeed_tpu.models.transformer import (divide_logits,
+                                                      head_matmul)
 
         h, t, mk = operand                     # [tile,B,H], [tile,B], [tile,B]
-        logits = head_matmul(h, head_c)                  # [tile, B, V] fp32
+        logits = divide_logits(head_matmul(h, head_c),   # [tile, B, V] fp32
+                               logits_divisor)
         logz = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, t[..., None], axis=-1)[..., 0]
         nll = (logz - picked) * mk

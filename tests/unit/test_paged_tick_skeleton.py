@@ -274,6 +274,7 @@ CELLS = {
     "serve-keye-vl2-30b-longctx-closed": "learned-sparse",
     "serve-nemotron3-super-120b-agents-closed": "single-sublayers",
     "serve-ouro-2.6b-cot-closed": "looped",
+    "serve-granite4-hsmall-rag-closed": "paired-mamba2-blocks",
 }
 #: what a toy pool is built with: blocks, block size, slots, longest run
 TOY = (64, 8, 3, 16)
@@ -350,6 +351,16 @@ POOLS = {
         "v": ((192, 193, 32, 16, 128), BF)},
     "toy:serve-ouro-2.6b-cot-closed": {
         "k": ((12, 64, 8, 4, 16), BF), "v": ((12, 64, 8, 4, 16), BF)},
+    # (PR 60) eight key-value heads of 128 in the ONE attention layer: a
+    # block positions first; nine Mamba-2 layers' matrices (ONE group: two
+    # heads' channels along a tile's lanes at the published widths, all
+    # eight at the toy's) and their convolutions' inputs
+    "serve-granite4-hsmall-rag-closed": {
+        "k": ((1, 11520, 32, 8, 128), BF), "v": ((1, 11520, 32, 8, 128), BF),
+        "ssd": ((9, 37, 64, 128, 128), F32), "ssd_conv": ((999, 8448), BF)},
+    "toy:serve-granite4-hsmall-rag-closed": {
+        "k": ((1, 64, 8, 2, 16), BF), "v": ((1, 64, 8, 2, 16), BF),
+        "ssd": ((9, 4, 1, 128, 128), F32), "ssd_conv": ((108, 384), BF)},
 }
 #: ``FastGenEngine._pool_bytes``: (block stores, per-slot state stores)
 BYTES = {
@@ -375,6 +386,8 @@ BYTES = {
     # a token
     "serve-ouro-2.6b-cot-closed": (9714008064, 0),
     "toy:serve-ouro-2.6b-cot-closed": (1572864, 0),
+    "serve-granite4-hsmall-rag-closed": (1509949440, 1413582336),
+    "toy:serve-granite4-hsmall-rag-closed": (65536, 2442240),
 }
 #: ``tick_walks``: (layers, window, cache positions a fetch step) a kind
 #: of kernel call
@@ -403,6 +416,8 @@ WALKS = {
     # 192 calls a tick: a walk a (pass, layer), four blocks of 32 a step
     "serve-ouro-2.6b-cot-closed": [(192, None, 128)],
     "toy:serve-ouro-2.6b-cot-closed": [(12, None, 128)],
+    "serve-granite4-hsmall-rag-closed": [(1, None, 256)],
+    "toy:serve-granite4-hsmall-rag-closed": [(1, None, 128)],
 }
 
 

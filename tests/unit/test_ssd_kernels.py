@@ -2,7 +2,9 @@
 ``ssd_step``, the others through ``ssd_chunk``; ``ops/pallas/ssd.py``)
 against one row after another, and the pieces the host counts. The toy
 widths are ``test_nemotron_h_stack.py``'s mixer: 16 heads of 8 over 8 groups
-of a state 128 wide.
+of a state 128 wide; and ONE group for every head (the ``granitemoehybrid``
+family's: 4 heads of 64, two to a tile of the store as published, the
+kernel's loop over groups run once).
 """
 import jax
 import jax.numpy as jnp
@@ -67,16 +69,25 @@ SSD_CASES = {
 }
 
 
+#: (heads, channels, groups) of the mixer, a sequence's row of the store
+GEOMETRIES = {"8-groups": ((16, 8, 8), (8, 128, 16)),
+              "one-group": ((4, 64, 1), (2, 128, 128))}
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
 @pytest.mark.parametrize("kernel", [False, True])
 @pytest.mark.parametrize("case", sorted(SSD_CASES))
-def test_both_forms_of_the_recurrence_match_one_row_after_another(case,
-                                                                  kernel):
+def test_both_forms_of_the_recurrence_match_one_row_after_another(
+        case, kernel, geometry):
     """``hybrid.ssd`` (runs of one through ``ssd_step``, the Mosaic kernel
     interpreted where ``kernel``, else its plain reference; the others
     through ``ssd_chunk``) against ``ssd_recurrence``: outputs and the
     state each run leaves in its slot."""
     slots, positions, fast = SSD_CASES[case]
-    args, y_want, state_want = _ssd_case(slots, positions, fast)
+    (nh, P, G), store = GEOMETRIES[geometry]
+    assert SD.store_shape(nh, G, P, 128) == store
+    args, y_want, state_want = _ssd_case(slots, positions, fast, nh=nh, P=P,
+                                         G=G)
     with jax.default_matmul_precision("highest"):
         y, state = jax.jit(lambda *a: HY.ssd(
             *a, chunk=16, use_kernel=kernel))(*args)

@@ -483,8 +483,13 @@ def mamba2_output(y: jax.Array, x: jax.Array, z: jax.Array,
     channels with one gain a channel."""
     f32 = jnp.float32
     Tn, G = y.shape[0], cfg.mamba2_groups
-    y = y + lp["skip_scale"].astype(f32)[None, :, None] * x
-    y = y.reshape(Tn, -1) * jax.nn.silu(z.astype(f32))
+    # flat, a row's heads x channels along the lanes, as the mixer made x
+    # and the recurrence's kernels hand y: an array [T, 128, 64] of the
+    # tick either pads its lanes or is re-laid heads-minor and back (five
+    # copies of 67 MB a layer of a 2,048-row tick: PERF.md, PR 61)
+    skip = jnp.repeat(lp["skip_scale"].astype(f32), x.shape[-1])
+    y = y.reshape(Tn, -1) + skip[None, :] * x.reshape(Tn, -1)
+    y = y * jax.nn.silu(z.astype(f32))
     yg = y.reshape(Tn, G, -1)
     yg = yg * lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1, keepdims=True)
                         + cfg.norm_eps)
@@ -529,18 +534,25 @@ def ssd(x: jax.Array, delta: jax.Array, g: jax.Array, B: jax.Array,
     write of the state, in place; a Mosaic kernel where ``use_kernel``);
     every other run the chunked form (``ops.pallas.ssd.ssd_chunk``: chunks
     of ``chunk`` rows, matrix products within a chunk, the state carried
-    across). Returns (y [T, nh, P] float32, state)."""
+    across; a Mosaic kernel where ``use_kernel``, else its plain
+    reference). Returns (y [T, nh, P] float32, state)."""
     from deepspeed_tpu.ops.pallas import ssd as K
 
     real, step_row, rows, at, took = _runs_of_one(runs, slot, SSD_STEP_ROWS)
     step = K.ssd_step if use_kernel else K.ssd_step_reference
+    chunked = K.ssd_chunk if use_kernel else K.ssd_chunk_reference
+    # rows are taken from x and put into y flat (``mamba2_output`` says why)
+    flat = x.reshape(x.shape[0], -1)
     with jax.named_scope("ssd_step"):
         y_step, state = step(
-            x[at], delta[at], jnp.exp(g[at]), B[at], C[at], state,
-            jnp.where(took, slot[at], 0), runs.fresh[at] & took)
-    y, state = K.ssd_chunk(x, delta, g, B, C, runs, real & ~step_row, state,
-                           slot, chunk)
-    return y.at[rows].set(y_step, mode="drop"), state
+            flat[at].reshape((-1,) + x.shape[1:]), delta[at], jnp.exp(g[at]),
+            B[at], C[at], state, jnp.where(took, slot[at], 0),
+            runs.fresh[at] & took)
+    y, state = chunked(x, delta, g, B, C, runs, real & ~step_row, state,
+                       slot, chunk)
+    y = y.reshape(flat.shape).at[rows].set(
+        y_step.reshape(-1, flat.shape[1]), mode="drop")
+    return y.reshape(x.shape), state
 
 
 def gmu(h: jax.Array, lp: Dict[str, Any], memory: jax.Array) -> jax.Array:

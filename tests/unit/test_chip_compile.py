@@ -295,34 +295,53 @@ def test_ssd_step_compiles_for_v5e(one_chip):
     assert stats.temp_size_in_bytes < 64 << 20
 
 
-@pytest.mark.parametrize("rows", [2048, 128])
-def test_ssd_chunk_compiles_for_v5e(one_chip, rows):
-    """The chunked form (plain XLA today) at the cell's chunk bucket and at
-    one chunk: no Mosaic call, the store carried through the pieces' loop
-    in place, and nothing as large as the store made beside it."""
+# (rows of the tick bucket, groups, rows of a chunk) of the two cells whose
+# ``mamba2`` layers take the chunked form, each at its two buckets: 128
+# heads of 64 x 128, eight groups in chunks of 128 (``nemotron_h``) and one
+# group in chunks of 256 (``granitemoehybrid``)
+SSD_CHUNK_SHAPES = {"nemotron3-2048": (2048, 8, 128),
+                    "nemotron3-128": (128, 8, 128),
+                    "granite4-2048": (2048, 1, 256),
+                    "granite4-256": (256, 1, 256)}
+
+
+@pytest.mark.parametrize("shape", sorted(SSD_CHUNK_SHAPES))
+def test_ssd_chunk_compiles_for_v5e(one_chip, shape):
+    """The chunked form at a cell's chunk bucket and at one chunk: ONE
+    Mosaic call under its own name (``benchmarks/roofline/ssd_chunk.py``
+    finds it by the scope), a grid as long as the tick's pieces, the store
+    aliased through the loop of one trip and the call, and nothing of
+    ``[chunks, heads, L, L]`` or ``[rows, heads, channels]`` made beside
+    it."""
     from deepspeed_tpu.models import hybrid as HY
     from deepspeed_tpu.ops.pallas.ssd import ssd_chunk
+
+    rows, groups, chunk = SSD_CHUNK_SHAPES[shape]
 
     def arg(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def form(x, d, g, B, C, state, slot, positions):
         return ssd_chunk(x, d, g, B, C, HY.runs_of(slot, positions),
-                         slot > 0, state, slot, 128)
+                         slot > 0, state, slot, chunk, interpret=False)
 
     store = 5 * 137 * 64 * 128 * 128 * 4
     compiled = jax.jit(form, donate_argnums=(5,)).lower(
         arg((rows, 128, 64), jnp.float32), arg((rows, 128), jnp.float32),
-        arg((rows, 128), jnp.float32), arg((rows, 8, 128), jnp.float32),
-        arg((rows, 8, 128), jnp.float32),
+        arg((rows, 128), jnp.float32), arg((rows, groups, 128), jnp.float32),
+        arg((rows, groups, 128), jnp.float32),
         arg((5 * 137, 64, 128, 128), jnp.float32),
         arg((rows,), jnp.int32), arg((rows,), jnp.int32)).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%ssd_chunk" in text
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= store
-    # the sums within the chunks: [rows / 128, 128 heads, 128, 128] float32
-    # three times over, and the rows' own arrays
-    assert stats.temp_size_in_bytes < (1 << 30 if rows == 2048 else 128 << 20)
+    # beside the result [rows, 128, 64]: the call's own result before its
+    # rows' mask (alone here; a tick's mixer masks it where it reads it),
+    # the pieces' scalars and masks (1 GB was allowed the plain form's sums
+    # within the chunks)
+    assert stats.temp_size_in_bytes < rows * 128 * 64 * 4 + (4 << 20)
 
 
 @pytest.mark.parametrize("rows", [2048, 256])

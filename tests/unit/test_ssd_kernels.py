@@ -1,6 +1,7 @@
 """The Mamba-2 recurrence's two forms (``hybrid.ssd``: runs of one through
-``ssd_step``, the others through ``ssd_chunk``; ``ops/pallas/ssd.py``)
-against one row after another, and the pieces the host counts. The toy
+``ssd_step``, the others through ``ssd_chunk``; ``ops/pallas/ssd.py``), each
+as its Mosaic kernel (interpreted) and as its plain reference, against one
+row after another, and the pieces the host counts. The toy
 widths are ``test_nemotron_h_stack.py``'s mixer: 16 heads of 8 over 8 groups
 of a state 128 wide; and ONE group for every head (the ``granitemoehybrid``
 family's: 4 heads of 64, two to a tile of the store as published, the
@@ -10,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from deepspeed_tpu.models import hybrid as HY
 from deepspeed_tpu.models import paged as PG
@@ -66,6 +68,21 @@ SSD_CASES = {
     "a-run-after-decode-rows": (
         list(range(1, 38)) + [40] * 90 + [0],
         [6] * 37 + list(range(11, 101)) + [0], True),
+    # a run that closes mid-chunk and another that opens behind its last
+    # row and goes on through two more chunks, a fast head across each edge
+    "a-piece-opens-behind-another-run-s-last-row": (
+        [3] * 21 + [4] * 40 + [0] * 3,
+        list(range(5, 26)) + list(range(2, 42)) + [0] * 3, True),
+}
+
+# cases at the published chunk of 256 rows, where the kernel walks a chunk's
+# triangle in sub-blocks and leaves out those above the diagonal: a run of
+# three pieces that goes on from stored state beside decode rows and a
+# second run in its last chunk, a fast head across the pieces' edges
+SSD_CASES_256 = {
+    "three-pieces-of-256": (
+        [1, 2] + [3] * 600 + [4] * 100 + [0] * 66,
+        [5, 6] + list(range(7, 607)) + list(range(100)) + [0] * 66, True),
 }
 
 
@@ -76,24 +93,60 @@ GEOMETRIES = {"8-groups": ((16, 8, 8), (8, 128, 16)),
 
 @pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
 @pytest.mark.parametrize("kernel", [False, True])
-@pytest.mark.parametrize("case", sorted(SSD_CASES))
+@pytest.mark.parametrize("case", sorted(SSD_CASES) + sorted(SSD_CASES_256))
 def test_both_forms_of_the_recurrence_match_one_row_after_another(
         case, kernel, geometry):
-    """``hybrid.ssd`` (runs of one through ``ssd_step``, the Mosaic kernel
-    interpreted where ``kernel``, else its plain reference; the others
-    through ``ssd_chunk``) against ``ssd_recurrence``: outputs and the
+    """``hybrid.ssd`` (runs of one through ``ssd_step``, the others through
+    ``ssd_chunk``: the Mosaic kernels interpreted where ``kernel``, else
+    their plain references) against ``ssd_recurrence``: outputs and the
     state each run leaves in its slot."""
-    slots, positions, fast = SSD_CASES[case]
+    slots, positions, fast = {**SSD_CASES, **SSD_CASES_256}[case]
     (nh, P, G), store = GEOMETRIES[geometry]
     assert SD.store_shape(nh, G, P, 128) == store
     args, y_want, state_want = _ssd_case(slots, positions, fast, nh=nh, P=P,
                                          G=G)
     with jax.default_matmul_precision("highest"):
         y, state = jax.jit(lambda *a: HY.ssd(
-            *a, chunk=16, use_kernel=kernel))(*args)
+            *a, chunk=256 if case in SSD_CASES_256 else 16,
+            use_kernel=kernel))(*args)
     assert bool(jnp.isfinite(y).all())
     assert rel(y, y_want) < TOL
     assert rel(jnp.asarray(state), jnp.asarray(state_want)) < TOL
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_a_tick_with_no_piece_runs_no_step_of_the_chunk_kernel(geometry):
+    """Decode rows and pads alone: the chunked form's grid is the tick's
+    pieces, none here (a step of it reports itself when it RUNS), its
+    output is zero and the store comes back bit for bit; a tick with a run
+    of two rows runs the one step."""
+    (nh, P, G), _ = GEOMETRIES[geometry]
+    (x, delta, g, B, C, runs, state, slot), _, _ = _ssd_case(
+        [1, 2, 3, 4] + [5] * 2 + [0] * 10, [9, 4, 7, 1, 3, 4] + [0] * 10,
+        nh=nh, P=P, G=G)
+    steps = []
+    kernel = SD._chunk_kernel
+
+    def reporting(*refs, **kwargs):
+        jax.debug.callback(lambda p: steps.append(int(p)), pl.program_id(0))
+        return kernel(*refs, **kwargs)
+
+    alone = jnp.asarray(runs.start & runs.last)
+    for rows, pieces in ((jnp.zeros((16,), bool), []),
+                         ((slot > 0) & ~alone, [0])):
+        steps.clear()
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(SD, "_chunk_kernel", reporting)
+            y, after = jax.block_until_ready(SD.ssd_chunk(
+                x, delta, g, B, C, runs, rows, state, slot, 16))
+        assert steps == pieces
+        if not pieces:
+            assert float(jnp.abs(y).max()) == 0.0
+            np.testing.assert_array_equal(np.asarray(after),
+                                          np.asarray(state))
+        else:
+            assert float(jnp.abs(y[4:6]).min()) > 0.0
+            assert float(jnp.abs(after[5] - state[5]).max()) > 0.0
 
 
 @pytest.mark.parametrize("kernel", [False, True])

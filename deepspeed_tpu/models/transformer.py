@@ -2128,34 +2128,60 @@ def scan_periods(body_of: Callable, carry, blocks: PyTree,
     """Scan a segment of standard blocks a PERIOD of its kinds at a time:
     ``body_of(period, first layer of the run)(carry, lps)`` takes the
     period's layers' parameters stacked ``[len(period), ...]``
-    (:func:`period_layer` takes one layer's out of them). The stacked
-    leaves ``[layers, ...]`` are read as ``[steps, period, ...]`` (no copy
-    where the segment is whole periods); leaves stacked by mixer
-    (``blocks["attn"]`` / ``blocks["conv"]``) by their own count a period.
-    ``by_step``: the leaves are stacked by step already (a homogeneous
-    stack's, one layer a step; ``blocks[kind]`` of a stack whose kinds are
-    whole layers of their own): a step takes them as they are."""
-    def cut(tree, ahead: int, per: int, steps: int):
-        n = per * steps
-        return jax.tree.map(
-            lambda a: (a if n == a.shape[0] else a[ahead:ahead + n]).reshape(
-                (steps, per) + a.shape[1:]), tree)
+    (:func:`period_layer` takes one layer's out of them). One scan a run of
+    :func:`kind_runs`; where a step's leaves come from is read off the
+    shapes, a leaf at a time:
 
+    - a run that is the WHOLE of a leaf ``[layers, ...]``: the leaf read as
+      ``[steps, period, ...]`` is the scan's operand (no copy). Leaves
+      stacked by mixer (``blocks["attn"]`` / ``["conv"]`` / ``["mamba2"]``)
+      count their own layers: those ahead of the run, those of a period (a
+      run may hold none: its steps then take no such leaf);
+    - ``by_step``: the leaves are stacked by step already (a homogeneous
+      stack's, one layer a step; ``blocks[kind]`` of a stack whose kinds
+      are whole layers of their own): the scan's operand as they are;
+    - a CUT, a run that is part of its leaf. Of one step: the static slice
+      ``a[ahead:ahead + per]`` is the operand (a loop of one trip is
+      inlined and the slice feeds its reader). Of more: the leaf stays
+      WHOLE outside the loop and step ``s`` takes its ``per`` layers at
+      ``ahead + s * per`` inside the body, which is what ``lax.scan`` does
+      with an operand and what XLA reads in place; a slice ahead of the
+      loop is the operand of a ``while`` and is materialised: the run's
+      weights copied every call (PR 62: 0.97 GB a tick). Under
+      ``jax.grad`` the in-place form is right and wasteful: each step's
+      cotangent is padded to the whole leaf before it is added (no
+      training cell has such a run)."""
     kinds, outs = tuple(kinds), []
     for first, period, steps in kind_runs(kinds):
-        # leaves stacked by mixer (a stack with ``conv`` layers) are cut
-        # by the layers of their mixer: those ahead of the run, those of
-        # a period (a run may hold none: its steps then take no such leaf)
-        per = {m: sum(mixer_of(k) == m for k in period)
-               for m in MIXERS if m in blocks and not by_step}
-        xs = blocks if by_step else cut(
-            {k: v for k, v in blocks.items() if k not in per}, first,
-            len(period), steps)
-        for m in (m for m, n in per.items() if n):
-            xs[m] = cut(blocks[m], sum(mixer_of(k) == m
-                                       for k in kinds[:first]),
-                        per[m], steps)
-        carry, out = lax.scan(body_of(period, first), carry, xs)
+        body, xs = body_of(period, first), blocks
+        if not by_step:
+            # mixer -> (its layers ahead of the run, its layers a period)
+            span = {m: (sum(mixer_of(k) == m for k in kinds[:first]),
+                        sum(mixer_of(k) == m for k in period))
+                    for m in MIXERS if m in blocks}
+            leaves, tree = jax.tree_util.tree_flatten_with_path(
+                {k: v for k, v in blocks.items()
+                 if k not in span or span[k][1]})
+            where = [span.get(path[0].key, (first, len(period)))
+                     for path, _ in leaves]
+            leaves = [a for _, a in leaves]
+            # the scan's operands; None: a cut of several steps
+            given = [a.reshape((steps, per) + a.shape[1:])
+                     if per * steps == a.shape[0] else
+                     a[ahead:ahead + per].reshape((1, per) + a.shape[1:])
+                     if steps == 1 else None
+                     for a, (ahead, per) in zip(leaves, where)]
+            xs = tree.unflatten(given)
+            if any(g is None for g in given):
+                def body(carry, xs, body=body):
+                    s, given = xs
+                    return body(carry, tree.unflatten([
+                        lax.dynamic_slice_in_dim(a, ahead + s * per, per)
+                        if g is None else g for g, a, (ahead, per) in zip(
+                            tree.flatten_up_to(given), leaves, where)]))
+
+                xs = jnp.arange(steps), xs
+        carry, out = lax.scan(body, carry, xs)
         outs.append(out)
     return carry, outs
 
@@ -2164,7 +2190,13 @@ def period_layer(lps: PyTree, period: Sequence[str], i: int) -> PyTree:
     """Layer ``i`` of a period's parameters as :func:`scan_periods` hands
     them to a step, as ONE flat tree: the leaves every layer has and, in a
     stack whose mixers' leaves are stacked apart, those of the layer's own
-    mixer (the layer's index among its mixer's in the period)."""
+    mixer (the layer's index among its mixer's in the period). ``lps`` is
+    ``[len(period), ...]`` a leaf whichever way it reached the step: a
+    whole run's and a one-step cut's as the scan's operand, a cut of
+    several steps' as the body's own slice of the whole leaf (``a[i]`` of
+    it is then a slice of that slice, which XLA reads in place); a
+    ``by_step`` stack's steps take their layers themselves and do not
+    come here."""
     mixer = mixer_of(period[i])
     if mixer not in lps:
         return jax.tree.map(lambda a: a[i], lps)

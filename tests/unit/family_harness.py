@@ -15,9 +15,13 @@ kept by model name and token bytes (``whole_forward``,
 ``reference_logits``), and ``drive`` takes its jitted ``forward_paged`` from
 ``tick_program``, keyed by what fixes the program.
 """
+import contextlib
 import dataclasses
+import functools
+import math
 import types
 from typing import Any, Callable, Dict, Optional, Tuple
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -43,13 +47,66 @@ def rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
+_DRAWS = {"normal": jax.random.normal, "uniform": jax.random.uniform}
+
+
+@functools.lru_cache(maxsize=None)
+def _flat_draw(kind, n):
+    draw = _DRAWS[kind]
+    if kind == "normal":
+        return jax.jit(lambda key: draw(key, (n,), jnp.float32))
+    return jax.jit(lambda key, lo, hi: draw(key, (n,), jnp.float32, lo, hi))
+
+
+def _drawn(kind, key, shape, dtype, *bounds):
+    """``jax.random.<kind>(key, shape)``, bit for bit, out of ONE program a
+    power of two of elements: under ``jax_threefry_partitionable`` (set in
+    ``conftest.py``) an element's bits are a function of the key and of its
+    flat index alone, so a shape's draw is the head of a longer flat one."""
+    assert jax.config.jax_threefry_partitionable and dtype == jnp.float32
+    size = math.prod(shape)
+    n = max(4096, 1 << max(size - 1, 0).bit_length())
+    flat = _flat_draw(kind, n)(key, *[np.float32(b) for b in bounds])
+    return np.asarray(flat)[:size].reshape(shape).astype(dtype)
+
+
+@contextlib.contextmanager
+def drawn_whole(on_host=False):
+    """While a toy model's parameters are built eagerly,
+    ``jax.random.normal`` / ``uniform`` hand out the same float32 numbers
+    without a compile a shape (a second of XLA's time each, fifty leaves a
+    model). ``on_host``: as numpy arrays, so that what is then done to them
+    (``* std``) is numpy's float32 arithmetic, which is the eager
+    operation's, and compiles nothing either."""
+    put = (lambda x: x) if on_host else jnp.asarray
+
+    def normal(key, shape=(), dtype=jnp.float32):
+        return put(_drawn("normal", key, tuple(shape), dtype))
+
+    def uniform(key, shape=(), dtype=jnp.float32, minval=0.0, maxval=1.0):
+        return put(_drawn("uniform", key, tuple(shape), dtype, minval,
+                          maxval))
+
+    with mock.patch.object(jax.random, "normal", normal), \
+            mock.patch.object(jax.random, "uniform", uniform):
+        yield
+
+
 def noisy(params, seed=1, std=0.05):
     """Norm gains, biases and every matrix off their start, so a dropped
     one shows."""
     leaves, tree = jax.tree_util.tree_flatten(params)
     keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return tree.unflatten([x + std * jax.random.normal(k, x.shape)
-                           for x, k in zip(leaves, keys)])
+    with drawn_whole(on_host=True):
+        return tree.unflatten([
+            jnp.asarray(np.asarray(x) + np.float32(std) * jax.random.normal(
+                k, x.shape)) for x, k in zip(leaves, keys)])
+
+
+def init_params(cfg, key):
+    """``T.init_params``, its draws made whole (``drawn_whole``)."""
+    with drawn_whole(on_host=True):
+        return jax.tree.map(jnp.asarray, T.init_params(cfg, key))
 
 
 def build(hf, tokens=(2, 40), noise=noisy, configure=None):
@@ -57,7 +114,7 @@ def build(hf, tokens=(2, 40), noise=noisy, configure=None):
     cfg = config_from_hf(types.SimpleNamespace(**hf))
     if configure is not None:
         cfg = configure(cfg)
-    params = noise(T.init_params(cfg, jax.random.PRNGKey(0)))
+    params = noise(init_params(cfg, jax.random.PRNGKey(0)))
     toks = np.random.default_rng(0).integers(
         0, cfg.vocab_size, tokens).astype(np.int32)
     return cfg, params, toks
@@ -71,6 +128,13 @@ class Model:
     params: Any
     toks: np.ndarray
     arch: Dict[str, Any]
+
+    @functools.cached_property
+    def on_host(self):
+        """``params`` as numpy arrays, for the reference: it takes a layer's
+        leaves by ``a[layer]``, which on a device array is a compile a
+        leaf's shape and a dispatch a leaf a layer."""
+        return jax.tree.map(np.asarray, self.params)
 
 
 @dataclasses.dataclass
@@ -116,7 +180,7 @@ def reference_logits(family, model, toks=None, arch=None):
     key = ("reference", model.name, toks.shape, toks.tobytes(), repr(arch))
     if key not in family._memo:
         family._memo[key] = family.reference.forward_logits(
-            model.params, toks, arch)
+            model.on_host, toks, arch)
     return family._memo[key]
 
 
@@ -171,6 +235,7 @@ def drive(eng, toks, attn, chunk, n_prompt, between=None, lens=None,
         with jax.default_matmul_precision("highest"):
             lg, eng.pool = fwd(eng.params, eng.pool, jnp.asarray(t),
                                jnp.asarray(p), jnp.asarray(tb))
+        lg = np.asarray(lg)     # (a row read by ``lg[r]`` is a compile)
         for r, (i, pos) in enumerate(rows):
             got[(i, pos)] = lg[r]
         if between is not None:
@@ -183,7 +248,7 @@ def drive(eng, toks, attn, chunk, n_prompt, between=None, lens=None,
         tick([(i, p) for i, n in enumerate(ns) if p < n])
     for b in blocks:
         eng.allocator.free(b)
-    out = [jnp.stack([got[(i, p)] for p in range(n)])
+    out = [jnp.asarray(np.stack([got[(i, p)] for p in range(n)]))
            for i, n in enumerate(ns)]
     return (jnp.stack(out) if lens is None else out), blocks
 
@@ -215,7 +280,7 @@ def assert_greedy_tokens_are_the_reference_s(family, model, eng, prompts,
     for u, prompt in prompts.items():
         out = eng.query(u)[1][:want[u]]
         seq = np.asarray(prompt + out, np.int32)[None]
-        ref = family.reference.forward_logits(model.params, seq,
+        ref = family.reference.forward_logits(model.on_host, seq,
                                               model.arch)[0]
         n = len(prompt)
         assert out == [int(t) for t in jnp.argmax(

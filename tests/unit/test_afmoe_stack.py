@@ -400,7 +400,7 @@ def test_state_dict_under_the_family_s_names_imports(model):
     full = dataclasses.replace(cfg, n_experts=cfg.router_experts,
                                moe_router_experts=0)
     if cfg.moe_router_experts:            # a checkpoint holds every expert
-        params = H.noisy(T.init_params(full, jax.random.PRNGKey(2)))
+        params = H.noisy(H.init_params(full, jax.random.PRNGKey(2)))
     sd = {"model.embed_tokens.weight": params["tok_emb"],
           "model.norm.weight": params["final_norm"]["scale"],
           "lm_head.weight": params["lm_head"].T}

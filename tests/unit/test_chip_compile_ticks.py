@@ -144,3 +144,44 @@ def test_the_copy_count_sees_what_it_is_for():
     assert found["copies"] == {
         "bf16[6,273,3,12288]{3,2,1,0:T(4,128)(2,1)}": 1,
         "bf16[256,3,12288]{2,1,0:T(4,128)(2,1)S(1)}": 1}
+
+
+def test_the_copy_count_sees_a_run_of_layers_cut_ahead_of_its_loop():
+    """``weight_copies`` on the lines the paired cell's decode tick held
+    before PR 62 (a run of four ``mamba2`` layers sliced out of the leaves
+    ahead of its scan), and beside them what it must NOT count: a product
+    that takes a parameter and rows, a prefetch into another memory space,
+    a slice under the size asked, an instruction outside the entry."""
+    tool = load_tool("tick_program_copies")
+    w_in = "%params__blocks____mamba2____w_in__.1"
+    text = "\n".join([
+        "%fused_computation.882 (param_0.1: bf16[9,4096,16768]) -> "
+        "bf16[4,1,4096,16768] {",
+        "  %slice.1 = bf16[4,1,4096,16768]{3,2,1,0:T(8,128)(2,1)} "
+        f"slice({w_in})",
+        "}",
+        "ENTRY %main.413 (params__blocks____mamba2____w_in__.1: "
+        "bf16[9,4096,16768]) -> bf16[256,4096] {",
+        f"  {w_in} = bf16[9,4096,16768]{{2,1,0:T(8,128)(2,1)}} parameter(13)"
+        ", sharding={replicated}",
+        "  %params__blocks____attn____wo__.1 = bf16[1,4096,4096]"
+        "{2,1,0:T(8,128)(2,1)} parameter(1)",
+        "  %slice_bitcast_fusion = bf16[4,1,4096,16768]"
+        f"{{3,2,1,0:T(8,128)(2,1)}} fusion({w_in}), kind=kLoop, "
+        "calls=%fused_computation.882",
+        "  %slice_bitcast_fusion.12 = bf16[4,1,128]{2,0,1:T(4,128)(2,1)} "
+        f"fusion({w_in}), kind=kLoop, calls=%fused_computation.3006",
+        "  %fusion.623 = bf16[2048,16768]{1,0:T(8,128)(2,1)} "
+        f"fusion({w_in}, %fusion.61), kind=kOutput, "
+        "calls=%fused_computation.886",
+        "  %bitcast.2778 = bf16[4096,4096]{1,0:T(8,128)(2,1)} "
+        "bitcast(%params__blocks____attn____wo__.1)",
+        "  %copy.860 = bf16[4096,4096]{1,0:T(8,128)(2,1)S(1)} "
+        "copy(%bitcast.2778)",
+        "  %copy.861 = bf16[4096,4096]{0,1:T(8,128)(2,1)} "
+        "copy(%bitcast.2778)",
+        "}"])
+    assert tool.count_copies(text)["weight_copies"] == {
+        "params/blocks/mamba2/w_in -> "
+        "bf16[4,1,4096,16768]{3,2,1,0:T(8,128)(2,1)}": 1,
+        "params/blocks/attn/wo -> bf16[4096,4096]{0,1:T(8,128)(2,1)}": 1}

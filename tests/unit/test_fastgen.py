@@ -13,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import family_harness as H
 from deepspeed_tpu.inference.fastgen import BlockAllocator, FastGenEngine
 from deepspeed_tpu.inference.ragged import RaggedInferenceEngine
 from deepspeed_tpu.models import paged as PG
@@ -182,6 +183,42 @@ def test_no_family_file_keeps_a_private_copy_of_the_harness():
                     above -= 1
                 assert lines[above].startswith("# differs"), (name, line)
         assert "family_harness" in "\n".join(lines), name
+
+
+@pytest.mark.parametrize("kind,shape,bounds", [
+    ("normal", (3, 5, 7), ()), ("normal", (4097,), ()), ("normal", (), ()),
+    ("uniform", (2, 64, 33), (-0.5, 0.5)), ("uniform", (1, 9), (1.0, 16.0)),
+    ("uniform", (8,), ())])
+def test_the_harness_draws_the_numbers_jax_random_draws(kind, shape, bounds):
+    """``family_harness.drawn_whole``: a toy model's leaves come out of one
+    program a power of two of elements, and are, to the bit, what
+    ``jax.random.normal`` / ``uniform`` give for the leaf's own shape: the
+    family files' tolerances and purposely made mistakes were set on those
+    numbers."""
+    key = jax.random.fold_in(jax.random.PRNGKey(3), len(shape))
+    want = getattr(jax.random, kind)(key, shape, jnp.float32, *bounds)
+    for on_host in (False, True):
+        with H.drawn_whole(on_host=on_host):
+            got = getattr(jax.random, kind)(key, shape, jnp.float32, *bounds)
+        assert isinstance(got, np.ndarray if on_host else jax.Array)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert getattr(jax.random, kind) is getattr(H._DRAWS[kind], "__wrapped__",
+                                                H._DRAWS[kind])
+
+
+def test_the_harness_builds_the_parameters_init_params_builds():
+    """``family_harness.init_params`` and ``noisy`` against the eager forms
+    they stand for, every leaf to the bit."""
+    cfg = T.get_model_config("tiny")
+    key = jax.random.PRNGKey(4)
+    want = T.init_params(cfg, key)
+    leaves, tree = jax.tree_util.tree_flatten(want)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    noised = tree.unflatten([x + 0.05 * jax.random.normal(k, x.shape)
+                             for x, k in zip(leaves, keys)])
+    H.assert_same_tree(want, H.init_params(cfg, key))
+    H.assert_same_tree(noised, H.noisy(H.init_params(cfg, key)))
 
 
 def test_fastgen_no_recompile_on_admission():
@@ -594,7 +631,7 @@ def packed_models():
     out = {}
     for name, kw in PACKED_MODELS.items():
         cfg = T.TransformerConfig(**kw)
-        out[name] = cfg, T.init_params(cfg, jax.random.PRNGKey(5))
+        out[name] = cfg, H.init_params(cfg, jax.random.PRNGKey(5))
     return out
 
 

@@ -67,7 +67,8 @@ def _noisy(params, seed=1):
         name = jax.tree_util.keystr(path)
         k = jax.random.fold_in(jax.random.PRNGKey(seed),
                                hash(name) % (2 ** 31))
-        noise = jax.random.normal(k, x.shape)
+        with H.drawn_whole():
+            noise = jax.random.normal(k, x.shape)
         if "norm" in name or "ln" in name:
             return x + 0.1 * noise
         if "gate_w" in name:
@@ -392,7 +393,7 @@ def test_state_dict_under_the_family_s_names_imports(model):
     cfg, params, toks, whole, _ = model
     hf = _hf(num_experts=8, num_local_experts=8, router_experts=8)
     full = config_from_hf(types.SimpleNamespace(**hf))
-    tree = _noisy(T.init_params(full, jax.random.PRNGKey(2)), seed=4)
+    tree = _noisy(H.init_params(full, jax.random.PRNGKey(2)), seed=4)
     b = jax.tree.map(np.asarray, tree["blocks"])
     pre = "model.language_model."
     sd = {pre + "embed_tokens.weight": np.asarray(tree["tok_emb"]),

@@ -334,7 +334,7 @@ def test_the_default_slots_reckon_a_slot_s_bytes(cut):
     assert blocks == 2 * 64 * 4 * 128 * 4
     assert state == 6 * 4 * (2 * 128 * 128 * 4 + 3 * 768 * 4)
     wide = dataclasses.replace(cfg, kda_heads=64)
-    eng = FastGenEngine(wide, T.init_params(wide, jax.random.PRNGKey(0)),
+    eng = FastGenEngine(wide, H.init_params(wide, jax.random.PRNGKey(0)),
                         n_blocks=4096, block_size=4, max_blocks_per_seq=16,
                         token_budget=1024, use_pallas_kernel=False)
     per_slot = 6 * (64 * 128 * 128 * 4 + 3 * 3 * 64 * 128 * 4)

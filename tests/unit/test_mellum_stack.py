@@ -28,6 +28,7 @@ from deepspeed_tpu.models.hf_import import (config_from_hf, import_hf_model,
                                             params_from_mellum)
 from deepspeed_tpu.moe import layer as MOE
 
+import family_harness as H
 from family_harness import CATALOG, TOL
 
 CONFIG = "benchmarks/configs/mellum2-12b-a2.5b.json"
@@ -61,13 +62,14 @@ def _hf(**kw):
 def _model(hf, seed=0):
     cfg = dataclasses.replace(config_from_hf(types.SimpleNamespace(**hf)),
                               dtype="float32", remat="full")
-    params = T.init_params(cfg, jax.random.key(seed))
+    params = H.init_params(cfg, jax.random.key(seed))
     # norms off one and every leaf off its initial law: a dropped gain or
     # branch must show
     leaves, tree = jax.tree.flatten(params)
-    params = tree.unflatten([
-        x + 0.05 * jax.random.normal(jax.random.key(100 + i), x.shape)
-        for i, x in enumerate(leaves)])
+    with H.drawn_whole():
+        params = tree.unflatten([
+            x + 0.05 * jax.random.normal(jax.random.key(100 + i), x.shape)
+            for i, x in enumerate(leaves)])
     arch = R.arch_from_config({"assumed": {}}, hf)
     return cfg, params, arch
 
@@ -96,9 +98,11 @@ def _case(case):
     gradients: once a case, whatever attention the system runs."""
     cfg, params, arch = _model(_hf(**CASES[case]))
     tokens = _tokens(cfg)
+    # (ONE program: differentiated eagerly the reference is a compile an
+    # operation, fifteen hundred of them a case)
     with jax.default_matmul_precision("highest"):
-        ref_loss, ref_grads = jax.value_and_grad(
-            lambda p: R.loss(p, tokens, arch))(params)
+        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+            lambda p: R.loss(p, tokens, arch)))(params)
     return (cfg, params, arch, tokens,
             R.forward_logits(params, tokens, arch), ref_loss, ref_grads)
 
@@ -110,7 +114,7 @@ def test_system_matches_reference(case, attention):
     spec = dst.causal_lm_spec(cfg, attention=attention, loss_impl="exact")
     with jax.default_matmul_precision("highest"):
         logits = spec.apply_fn(params, {"tokens": tokens})
-        loss, grads = jax.value_and_grad(spec.loss_fn)(
+        loss, grads = jax.jit(jax.value_and_grad(spec.loss_fn))(
             params, {"tokens": tokens})
     assert _rel(logits, ref_logits) < TOL
     assert abs(float(loss) - float(ref_loss)) < TOL * float(ref_loss)

@@ -15,6 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import family_harness
 from deepspeed_tpu.inference.fastgen import FastGenEngine
 from deepspeed_tpu.inference.ragged import RaggedInferenceEngine
 from deepspeed_tpu.models import paged as PG
@@ -54,7 +55,8 @@ def models():
     out = {}
     for name, kw in MODELS.items():
         cfg = T.TransformerConfig(**kw)
-        out[name] = cfg, T.init_params(cfg, jax.random.PRNGKey(3))
+        out[name] = cfg, family_harness.init_params(
+            cfg, jax.random.PRNGKey(3))
     return out
 
 
@@ -614,6 +616,37 @@ def test_a_tick_holds_one_wo_product_a_layer_and_one_scan_a_run(case):
         sum(kind != "ffn" for kind in period) for _, period, _ in runs)
     source = inspect.getsource(PG)
     assert source.count('lp["wo"]') == 1 and "lax.scan" not in source
+
+
+@pytest.mark.parametrize("case", sorted(c for c in POOLS if "toy:" in c))
+def test_no_run_of_several_steps_is_cut_ahead_of_its_scan(case):
+    """The traced tick, not its result: no scan of more than one step takes
+    an operand that is a ``slice`` / ``dynamic_slice`` / ``gather`` of a
+    stacked leaf made OUTSIDE it. Such a slice is the operand of a
+    ``while`` and XLA materialises it: a copy of the run's weights every
+    tick (0.97 GB in ``serve-granite4-hsmall-rag-closed`` before PR 62);
+    ``T.scan_periods`` reads a run of several steps out of the whole leaf
+    inside the loop's body. (A loop of one trip is inlined: its static
+    slice feeds its reader.)"""
+    closed, cfg, paths = _traced(case)
+    stacked = {key for key, _ in cfg.segments}
+    # a variable handed down from a stacked leaf -> (the leaf, cut from it?)
+    seen = {v: (path, False) for v, path in zip(closed.jaxpr.invars, paths)
+            if getattr(path[0], "key", None) in stacked}
+    for eqn in closed.jaxpr.eqns:
+        name, taken = eqn.primitive.name, [
+            v for v in eqn.invars if not hasattr(v, "val")]
+        if name in _PASS and taken and taken[0] in seen:
+            path, cut = seen[taken[0]]
+            seen.update(dict.fromkeys(eqn.outvars, (path, cut or name in (
+                "slice", "dynamic_slice", "gather"))))
+        elif name == "scan" and eqn.params["length"] > 1:
+            assert [jax.tree_util.keystr(seen[v][0]) for v in taken
+                    if v in seen and seen[v][1]] == []
+    if "granite4" in case:    # the case that has such a run
+        assert [(steps, len(period)) for _, seg in cfg.segments
+                for _, period, steps in T.kind_runs(
+                    PG.stack_kinds(cfg, seg))] == [(1, 6), (4, 1)]
 
 
 @pytest.mark.parametrize("case", sorted(c for c in POOLS if "toy:" in c))

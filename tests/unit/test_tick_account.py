@@ -216,6 +216,16 @@ def test_a_slow_tick_is_named_and_moves_no_typical_value(
     that part with the period's excess, remembered with its number, and
     feeds none of its program's typical values."""
     monkeypatch.setattr(telemetry, "span", _SlowReadback)
+    # ``conftest.py`` has LLVM optimise nothing (the lane's time is its
+    # compiles); this test holds the REAL CPU seconds of sixteen ticks
+    # under the 50 ms of the fake wait, so its tick program is compiled as
+    # a deployment's is
+    import jax
+
+    jit = jax.jit
+    monkeypatch.setattr(jax, "jit", lambda f, **kw: jit(
+        f, **kw, compiler_options={"xla_backend_optimization_level": 3,
+                                   "xla_llvm_disable_expensive_passes": False}))
     eng = _engine(tiny_params)
     fe = ServingFrontend(eng, clock=clock, register_health=False,
                          health_name="acct")

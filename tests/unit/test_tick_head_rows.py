@@ -14,6 +14,7 @@ import pytest
 
 from deepspeed_tpu.inference.fastgen import FastGenEngine
 from deepspeed_tpu.models import paged as PG
+import family_harness as H
 from family_harness import rel, tick_program
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.hf_import import config_from_hf
@@ -78,14 +79,15 @@ BUDGET, SMALL = 32, 8          # the two tick buckets of the engines below
 def models():
     out = {}
     for name, cfg in _configs().items():
-        params = T.init_params(cfg, jax.random.PRNGKey(7))
+        params = H.init_params(cfg, jax.random.PRNGKey(7))
         # wide enough that the argmax is not the last token's echo, and
         # biases off zero
         leaves, tree = jax.tree_util.tree_flatten(params)
         keys = jax.random.split(jax.random.PRNGKey(8), len(leaves))
-        out[name] = cfg, tree.unflatten(
-            [x + 0.05 * jax.random.normal(k, x.shape, x.dtype)
-             for x, k in zip(leaves, keys)])
+        with H.drawn_whole():
+            out[name] = cfg, tree.unflatten(
+                [x + 0.05 * jax.random.normal(k, x.shape, x.dtype)
+                 for x, k in zip(leaves, keys)])
     return out
 
 

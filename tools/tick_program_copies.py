@@ -12,10 +12,15 @@ fusions by result shape (the rematerialization pass re-laying an array to
 above ``--min-mb`` by shape, the ``copy`` / ``reshape`` / ``transpose``
 instructions as large as a per-slot state store of the pool
 (``whole_store_copies``: a reshape the compiler could not make a
-``bitcast`` moves every byte too), ``memory_analysis()``'s arguments /
-alias / temporaries, and two hashes: of the program as lowered, and of the
-COMPILED text with source metadata stripped and instructions renumbered in
-their order of appearance (:func:`normalised`). Either equal on two trees:
+``bitcast`` moves every byte too), the fusions, copies and slices above
+``--min-mb`` that do nothing but hand on entry parameters under ``params``
+(``weight_copies``: weights written a second time every call; it guards
+``T.scan_periods``, whose run of layers cut from stacked leaves ahead of
+its loop was 0.97 GB of such copies a tick: PERF.md, PR 62),
+``memory_analysis()``'s arguments / alias / temporaries, and two hashes:
+of the program as lowered, and of the COMPILED text with source metadata
+stripped and instructions renumbered in their order of appearance
+(:func:`normalised`). Either equal on two trees:
 the change between them left that program alone; the second alone equal:
 it changed what was traced and nothing the device runs. Beside the tick
 programs, the benchmark runner's ``check_logits`` program (``forward_paged``
@@ -53,31 +58,56 @@ _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
              "u64": 8}
 
 
+#: the operands of an instruction whose line ``_INSTRUCTION`` matched
+_OPERANDS = re.compile(r"%([\w.\-]+)")
+
+
 def count_copies(text: str, stores=(), min_bytes: int = 8 << 20):
     """The re-layouts of a compiled program's text. ``stores``: element
     counts of the arrays no copy should be as large as (the pool's state
     stores). Returns ``{"remat": {shape: n}, "copies": {shape: n},
-    "whole_store_copies": {shape: n}}``: instructions named ``*remat_
-    compressed*`` / ``*remat_uncompressed*``, ``copy`` results of at least
-    ``min_bytes``, and the ``copy`` / ``reshape`` / ``transpose`` results
-    with a store's element count (what is left of a reshape in compiled
-    text is no ``bitcast``: it moves the array)."""
-    found = {k: collections.Counter()
-             for k in ("remat", "copies", "whole_store_copies")}
-    stores = set(stores)
+    "whole_store_copies": {shape: n}, "weight_copies": {shape: n}}``:
+    instructions named ``*remat_compressed*`` / ``*remat_uncompressed*``,
+    ``copy`` results of at least ``min_bytes``, the ``copy`` / ``reshape``
+    / ``transpose`` results with a store's element count (what is left of
+    a reshape in compiled text is no ``bitcast``: it moves the array), and
+    the ``fusion`` / ``copy`` / ``slice`` / ``dynamic-slice`` results of at
+    least ``min_bytes`` in the ENTRY computation whose every operand is an
+    entry parameter under ``params`` or a ``bitcast`` of one (a product
+    takes rows too: such an instruction writes weights again, ahead of the
+    loop that reads them), but for results in another memory space
+    (``S(1)``: a prefetch, no second copy in HBM)."""
+    found = {k: collections.Counter() for k in (
+        "remat", "copies", "whole_store_copies", "weight_copies")}
+    stores, entry, weights = set(stores), False, {}
     for line in text.splitlines():
+        entry = entry and line != "}" or line.startswith("ENTRY ")
         m = _INSTRUCTION.match(line)
         if not m:
             continue
         name, dtype, dims, layout, opcode = m.groups()
         shape = f"{dtype}[{dims}]{layout or ''}"
+        elements = math.prod(int(d) for d in dims.split(",") if d)
+        big = elements * _ITEMSIZE.get(dtype, 4) >= min_bytes
+        if entry:
+            operands = _OPERANDS.findall(
+                line[m.end():line.index(")", m.end())])
+            handed = operands and all(o in weights for o in operands)
+            if opcode == "parameter" and name.startswith("params__"):
+                # ``params__blocks____mamba2____w_in__.1``: the leaf's path
+                weights[name] = "/".join(
+                    filter(None, name.rsplit(".", 1)[0].split("__")))
+            elif opcode == "bitcast" and handed:
+                weights[name] = weights[operands[0]]
+            elif handed and big and "S(" not in shape and opcode in (
+                    "fusion", "copy", "slice", "dynamic-slice"):
+                found["weight_copies"][" ".join(
+                    [*(weights[o] for o in operands), "->", shape])] += 1
         if "remat_compressed" in name or "remat_uncompressed" in name:
             found["remat"][shape] += 1
-        elements = math.prod(int(d) for d in dims.split(",") if d)
         if opcode in ("copy", "reshape", "transpose") and elements in stores:
             found["whole_store_copies"][f"{shape} {opcode}"] += 1
-        if opcode == "copy" and \
-                elements * _ITEMSIZE.get(dtype, 4) >= min_bytes:
+        if opcode == "copy" and big:
             found["copies"][shape] += 1
     return {k: dict(v) for k, v in found.items()}
 
